@@ -1,6 +1,6 @@
 # Developer entry points (reference parity: Taskfile.yml).
 
-.PHONY: generate check test test-fast bench bench-gateway serve gateway lint graftlint typecheck
+.PHONY: generate check test test-fast chip-smoke bench-gateway serve gateway lint graftlint typecheck
 
 generate:  ## regenerate docs/env examples from openapi.yaml + drift check
 	python -m inference_gateway_tpu.codegen
@@ -40,8 +40,8 @@ SLOW_TESTS := test_checkpoint test_chunked_prefill test_distributed \
 test-fast: ## gateway/protocol tier only (~2 min) — no engine builds
 	python -m pytest tests/ -q $(foreach t,$(SLOW_TESTS),--ignore=tests/$(t).py)
 
-bench:     ## TPU serving decode throughput (driver-tracked JSON line)
-	python bench.py
+chip-smoke:  ## the main path on one TPU chip (fails without one)
+	python chip_smoke.py
 
 bench-gateway:  ## CPU gateway micro-benchmarks
 	python benchmarks/gateway_bench.py

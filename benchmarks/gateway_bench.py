@@ -173,7 +173,7 @@ async def bench_relay_fanout(streams: int, n_chunks: int = 500,
     """Relay scaling surface (ISSUE 5): aggregate chunks/s AND p99
     inter-chunk latency at a given fan-out, with the streaming fast path
     (write coalescing, SERVER_STREAM_COALESCE) on or off — the
-    regression gate for `bench.py` relay monotonicity
+    regression gate for relay monotonicity
     (relay_128_streams_chunks_s must stay ≥ relay_32_streams_chunks_s)."""
     from inference_gateway_tpu.netio.server import StreamingResponse
 
@@ -436,8 +436,8 @@ async def relay_cluster_suite(workers: int) -> dict:
 
 async def relay_fanout_suite(fast_path: bool = True,
                              include_512: bool = False) -> dict:
-    """The 1/32/128(/512) fan-out sweep; keys match bench.py's BENCH
-    trajectory (`relay_*_streams_chunks_s`). Sustained-window capacity
+    """The 1/32/128(/512) fan-out sweep; keys are
+    `relay_*_streams_chunks_s`. Sustained-window capacity
     (bench_relay_saturation) is the headline per tier, designed for a
     shared single-core box whose noise swings 2-3× minute to minute:
     the 32/128 tiers are sampled in ABBA order (drift between adjacent
@@ -792,8 +792,7 @@ def compute_efficiency_analytic(profile_name: str = "v5e-8-llama-3-8b") -> dict:
 
     p = PROFILES[profile_name]
     m = StepCostModel.from_profile(p)
-    # Mean occupancy assumption matches bench.py analytic_model():
-    # max_seq_len/4 live tokens per slot.
+    # Mean occupancy assumption: max_seq_len/4 live tokens per slot.
     ctx = p.max_slots * (p.max_seq_len // 4)
     step = m.decode(batch=p.max_slots, context_tokens=ctx)
     return {
@@ -824,7 +823,7 @@ async def bench_compute_efficiency(requests: int = 3, max_tokens: int = 16) -> d
     engine = Engine(EngineConfig(model="test-tiny", max_slots=4, max_seq_len=128,
                                  dtype="float32", max_prefill_batch=2, use_mesh=False))
     sidecar = SidecarServer(engine, served_model_name="test-tiny",
-                            otel=OpenTelemetry())
+                            otel=OpenTelemetry(), accounting_chip="v5e")
     port = await sidecar.start("127.0.0.1", 0)
     client = HTTPClient()
     body = json.dumps({"model": "test-tiny", "stream": True, "max_tokens": max_tokens,
@@ -841,8 +840,8 @@ async def bench_compute_efficiency(requests: int = 3, max_tokens: int = 16) -> d
     decode = report.get("per_kind", {}).get("decode", {})
     out.update({
         "measured": report["measured"],
-        # Percent, matching mfu_analytic and bench.py's on-chip key —
-        # the window gauge itself is a 0..1 fraction.
+        # Percent, matching mfu_analytic — the window gauge itself is
+        # a 0..1 fraction.
         "mfu_measured": round(report["window"]["mfu"] * 100, 2)
         if report["measured"] else None,
         "host_gap_decode": decode.get("gap_factor"),
@@ -867,7 +866,7 @@ async def bench_accounting_overhead(n: int = 60, max_tokens: int = 24) -> dict:
         # Identical telemetry base in both variants — the delta must
         # isolate the accounting, not the otel registry underneath it.
         sidecar = SidecarServer(engine, served_model_name="test-tiny",
-                                otel=OpenTelemetry(),
+                                otel=OpenTelemetry(), accounting_chip="v5e",
                                 accounting_enable=accounting_on)
         port = await sidecar.start("127.0.0.1", 0)
         client = HTTPClient()
@@ -1265,8 +1264,7 @@ def bench_decode_steady_state(chunks=(8, 32, 128), n_requests: int = 4,
     < 1 ms), and (b) the early-exit waste ratio: with max_tokens well
     under a 128-step chunk, the off path computes every step while the
     on path freezes at the finish (wasted_tokens{chunk_overrun} pins
-    it). The artifact rides bench.py so the next TPU window records the
-    decode-step roofline-ratio delta with the same schema."""
+    it)."""
     import queue as _q
 
     from inference_gateway_tpu.otel.perf_accounting import (
@@ -1289,7 +1287,7 @@ def bench_decode_steady_state(chunks=(8, 32, 128), n_requests: int = 4,
             sched = Scheduler(eng)
             sched.timeline = StepTimeline(512)
             sched.accounting = PerfAccounting(
-                StepCostModel.from_engine(eng), model="bench", measured=False)
+                StepCostModel.from_engine(eng, chip="v5e"), model="bench", measured=False)
             sched.start()
             done: _q.Queue = _q.Queue()
             delivered = [0]
@@ -1340,7 +1338,7 @@ def bench_decode_steady_state(chunks=(8, 32, 128), n_requests: int = 4,
 
 
 def decode_steady_state_suite() -> dict:
-    """bench.py hook: the ISSUE 14 steady-state numbers in one line."""
+    """The ISSUE 14 steady-state numbers in one line."""
     return bench_decode_steady_state()
 
 
@@ -1384,8 +1382,7 @@ async def main() -> None:
 
 if __name__ == "__main__":
     if "--relay-fanout" in sys.argv:
-        # bench.py hook: ONE machine-readable line with the 1/32/128
-        # numbers the BENCH trajectory tracks.
+        # ONE machine-readable line with the 1/32/128 numbers.
         print("RESULT=" + json.dumps(asyncio.run(relay_fanout_suite(fast_path=True))))
     elif "--workers" in sys.argv:
         # Multi-worker fleet hook (ISSUE 16): spawn a real SO_REUSEPORT
@@ -1393,7 +1390,7 @@ if __name__ == "__main__":
         n = int(sys.argv[sys.argv.index("--workers") + 1])
         print("RESULT=" + json.dumps(asyncio.run(relay_cluster_suite(n))))
     elif "--decode-steady-state" in sys.argv:
-        # bench.py hook (ISSUE 14): host gap + early-exit waste at
+        # ISSUE 14: host gap + early-exit waste at
         # decode_chunk {8,32,128}, one machine-readable line.
         print("RESULT=" + json.dumps(decode_steady_state_suite()))
     else:

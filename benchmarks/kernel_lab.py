@@ -1,7 +1,7 @@
 """Kernel tuning lab: measure paged-decode / flash-prefill variants on chip.
 
-The round-3 on-chip microbench (benchmarks/TPU_MEASURED_r03.json) showed
-the production paged kernel at ~2,450 us/call for B=64 x 512-token slots
+The one on-chip microbench so far (2026-07-29, TinyLlama) showed the
+production paged kernel at ~2,450 us/call for B=64 x 512-token slots
 — ~60x off the ~41 us HBM roofline for the 32 MiB of KV it streams — and
 the flash prefill kernel slower than XLA's einsum at 8x512. This lab
 exists to close those gaps with measurements, not guesses. Variants:
@@ -208,8 +208,8 @@ def lab_paged_attention(
 # ---------------------------------------------------------------------------
 # bench_kernels (ISSUE 12): ragged-vs-gather-vs-bucketed across the
 # fallback-layout matrix from ops/paged_attention.paged_dispatch — the
-# layouts that USED to force the 10.6×-slower gather path (BENCH_r03:
-# 25,856 µs vs 2,448 µs) and now take a kernel. Each layout runs a mixed
+# layouts that USED to force the 10.6×-slower gather path (25,856 µs
+# vs 2,448 µs on the chip, 2026-07-29) and now take a kernel. Each layout runs a mixed
 # batch (decode rows + one prefill chunk):
 #   ragged   — one ragged kernel launch for the whole batch
 #   gather   — the pure-JAX ragged reference (the old fallback's cost)
@@ -286,7 +286,7 @@ from inference_gateway_tpu.utils.benchtime import timeit_device
 
 def timeit(fn, *args, iters=30):
     """us/call with rotated inputs (see utils/benchtime.py for why:
-    identical repeated dispatches get short-circuited below JAX, and
+    identical repeated dispatches can be answered from a cache, and
     warm-up must block on its own results)."""
     return timeit_device(fn, *args, iters=iters)
 

@@ -12,8 +12,7 @@ script attributes the gap:
    ~2x);
 4. per-kernel sanity: the paged kernel timed over 40 DISTINCT query
    buffers (the kernel-lab's rotated-4 measurement could still be
-   short-circuited if the remote-execution path caches per exact input
-   set).
+   short-circuited by anything that caches per exact input set).
 
 Run from repo root: python benchmarks/profile_decode.py
 """

@@ -29,6 +29,7 @@ from inference_gateway_tpu.cluster.shm import ClusterSegment, PeerHealthView, Wo
 from inference_gateway_tpu.cluster.tenancy import TenantPolicy
 from inference_gateway_tpu.cluster.worker import WorkerRuntime
 from inference_gateway_tpu.config import Config
+from inference_gateway_tpu import native
 from inference_gateway_tpu.logger import Logger, new_logger
 from inference_gateway_tpu.netio.client import ClientConfig, HTTPClient
 from inference_gateway_tpu.netio.server import HTTPServer, Request, Router
@@ -118,7 +119,7 @@ class Gateway:
         self.client.self_host = "127.0.0.1" if host in ("0.0.0.0", "") else host
         self.client.self_port = self.port
         self.logger.info("gateway listening", "app", APPLICATION_NAME, "version", VERSION,
-                         "host", host, "port", self.port)
+                         "host", host, "port", self.port, "framing", native.status)
         self._tasks.append(asyncio.create_task(self._validate_providers()))
         return self.port
 

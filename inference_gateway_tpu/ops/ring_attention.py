@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 NEG_INF = -1e30
 
@@ -91,7 +90,7 @@ def make_ring_attention(mesh: Mesh, axis: str = "sp", causal: bool = True):
         return out.transpose(0, 3, 1, 2, 4).reshape(B, Tq, Hq, D).astype(q.dtype)
 
     seq_spec = P(None, axis, None, None)
-    return shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(seq_spec, seq_spec, seq_spec, P(None)),
         out_specs=seq_spec,

@@ -21,8 +21,7 @@ production telemetry:
 - **Live HBM accounting** — ``device.memory_stats()`` (bytes-in-use /
   peak) against the analytic plan (weights + KV pool) plus the KV
   page-pool high-water mark. Framed ``measured: false`` off-TPU —
-  never fabricated (same honesty contract as PerfAccounting and
-  bench.py's ``hbm_validation``).
+  never fabricated (same honesty contract as PerfAccounting).
 - **Transfer audit** — lightweight h2d/d2h counting on the engine's
   submit/fetch seams as ``engine.transfers{direction,path}``. The PR 14
   invariant becomes a live production metric: chained early-exit
@@ -470,9 +469,11 @@ class DeviceObservatory:
             return {}
 
     def hbm_snapshot(self) -> dict[str, Any]:
-        """Live vs plan. ``measured`` is honest: CPU's memory_stats()
-        returns None and the pane says so — live/peak are never
-        fabricated from the plan (bench.py hbm_validation contract)."""
+        """Live vs plan, summed over every device the process holds and
+        listed per device — a sharded engine that put everything on
+        device 0 shows here. ``measured`` is honest: CPU's
+        memory_stats() returns None and the pane says so — live/peak are
+        never fabricated from the plan."""
         out: dict[str, Any] = {"measured": False, "plan": dict(self._plan)}
         engine = self._engine
         if engine is not None and engine.allocator is not None:
@@ -488,20 +489,25 @@ class DeviceObservatory:
         try:
             import jax
 
-            stats = jax.devices()[0].memory_stats()
+            stats = [(dev.id, dev.memory_stats()) for dev in jax.devices()]
         except Exception:
-            stats = None
-        if stats and "bytes_in_use" in stats:
+            stats = []
+        per_device = [
+            {"id": i, "live_bytes": int(st["bytes_in_use"]),
+             "peak_bytes": int(st.get("peak_bytes_in_use", st["bytes_in_use"]))}
+            for i, st in stats
+        ] if stats and all(st and "bytes_in_use" in st for _i, st in stats) else []
+        if per_device:
             out["measured"] = True
-            out["live_bytes"] = int(stats["bytes_in_use"])
-            out["peak_bytes"] = int(stats.get("peak_bytes_in_use",
-                                              stats["bytes_in_use"]))
+            out["per_device"] = per_device
+            out["live_bytes"] = sum(d["live_bytes"] for d in per_device)
+            out["peak_bytes"] = sum(d["peak_bytes"] for d in per_device)
             plan = self._plan.get("plan_bytes", 0)
             if plan:
                 out["live_vs_plan"] = round(out["live_bytes"] / plan, 4)
         else:
             out["note"] = ("device backend exposes no memory_stats() "
-                           "(CPU/proxy host) — live/peak unavailable, "
+                           "(CPU host) — live/peak unavailable, "
                            "plan is analytic")
         return out
 

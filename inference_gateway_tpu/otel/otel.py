@@ -31,8 +31,7 @@ TOKEN_RATE_BOUNDARIES = (1.0, 2.0, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 500.0, 1
 # heartbeat within a millisecond; a relay saturation stall is 10-100ms+.
 EVENTLOOP_LAG_BOUNDARIES = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5)
 # Engine step durations (ISSUE 4 timeline): kernel times are tens of µs
-# on TPU, milliseconds through a remote-device tunnel, and a fused chunk
-# of decode steps lands in the tens-of-ms band.
+# on TPU, and a fused chunk of decode steps lands in the tens-of-ms band.
 ENGINE_STEP_BOUNDARIES = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
                           0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5)
 # Host gap between chained decode chunks (ISSUE 14), in MILLISECONDS:
@@ -47,8 +46,7 @@ HOST_GAP_MS_BOUNDARIES = (0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
 SCHEMA_COMPILE_BOUNDARIES = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
                              0.1, 0.25, 0.5, 1.0, 2.5, 5.0)
 # XLA program compiles (ISSUE 19): tiny test models trace in tens of
-# milliseconds; flagship-scale programs through a remote-TPU tunnel run
-# tens to hundreds of seconds.
+# milliseconds; a 32-layer step program takes tens of seconds on a chip.
 COMPILE_DURATION_BOUNDARIES = (0.01, 0.05, 0.1, 0.5, 1.0, 2.5, 5.0, 10.0,
                                30.0, 60.0, 120.0, 300.0)
 # Compute-efficiency gauges (ISSUE 6) refresh only while the engine

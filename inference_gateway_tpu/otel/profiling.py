@@ -10,8 +10,8 @@ time went* when a number regresses, with four cooperating pieces:
   on-demand capture (``GET /debug/profile?seconds=N&hz=M``) and an
   always-on continuous mode keeping a ring of recent windows.
 - ``EventLoopWatchdog`` — asyncio scheduling-lag heartbeat. The relay
-  hot path lives and dies on loop latency (BENCH_r05: 58k chunks/s at
-  128 streams vs 84k at 32); the heartbeat measures how late the loop
+  hot path lives and dies on loop latency (a CPU relay run read 58k
+  chunks/s at 128 streams vs 84k at 32); the heartbeat measures how late the loop
   woke it into the ``eventloop.lag`` histogram, and lag beyond the
   threshold is a *stall*: counted, wide-evented through the access-log
   sink with the loop thread's stack. A companion daemon thread snapshots

@@ -39,8 +39,10 @@ def main() -> None:
     p.add_argument("--metrics-push-url", default=None,
                    help="gateway OTLP push endpoint (e.g. http://gateway:8080/v1/metrics)")
     p.add_argument("--platform", default=None, choices=["cpu", "tpu"],
-                   help="force the jax platform (cpu = dev serving without an "
-                        "accelerator, even when a TPU plugin is pre-registered)")
+                   help="bind jax to this platform: tpu fails at start-up when "
+                        "there is no chip, cpu is dev serving with interpreted "
+                        "kernels; unset, jax takes a chip if it finds one and "
+                        "the CPU otherwise")
     args = p.parse_args()
 
     if args.platform:

@@ -8,7 +8,7 @@ an explicit HBM budget (weights + KV pool + activation headroom) that a
 unit test asserts fits the chip (tests/test_profiles.py).
 
 A profile is everything the Engine needs plus the mesh layout; the
-bench (bench.py) and the sidecar server resolve profiles by name, so
+benchmarks and the sidecar server resolve profiles by name, so
 "what shapes does production run" is one `git grep` away instead of
 someone hand-picking numbers under time pressure.
 
@@ -289,10 +289,8 @@ PROFILES: dict[str, ServingProfile] = {
         attention="dense",
         mesh={"pp": 2, "tp": 8},
     ),
-    # Single-chip bench profile (what bench.py builds on the one real
-    # chip the driver exposes): TinyLlama shapes, 64 slots — the
-    # continuous-batching serving point the round-2 verdict's >=10x
-    # target is measured at.
+    # Single-chip TinyLlama profile, 64 slots: the shapes of the one
+    # on-chip reading so far (2026-07-29; ROADMAP re-anchor note).
     "v5e-1-tinyllama": ServingProfile(
         name="v5e-1-tinyllama",
         model="tinyllama-1.1b",

@@ -54,6 +54,7 @@ from inference_gateway_tpu.serving.scheduler import (
     SchedulerStoppedError,
 )
 from inference_gateway_tpu.serving.tokenizer import DetokenizeState
+from inference_gateway_tpu.utils.platform import configure_compile_cache
 
 # OTLP push bucket boundaries (delta histograms; the gateway ingest
 # replays observations at bucket midpoints).
@@ -515,7 +516,7 @@ class SidecarServer:
         try:
             new_engine = await loop.run_in_executor(None, _build)
         except Exception as e:
-            # The rebuild itself failed (dead driver/tunnel): stay
+            # The rebuild itself failed (dead driver): stay
             # degraded — health keeps reporting 503 so pools keep
             # routing around — and surface the failed attempt. The
             # watchdog re-trips after another deadline period (natural
@@ -728,7 +729,7 @@ class SidecarServer:
     async def health(self, req: Request) -> Response:
         """Liveness + device-stall detection: active requests with no
         completed engine step for HEALTH_STALL_SECONDS means the
-        accelerator (or its tunnel) is wedged — report degraded with 503
+        accelerator is wedged — report degraded with 503
         so orchestrators can recycle the replica. During a supervised
         engine restart (ISSUE 7) the same 503 "degraded" flows, and a
         planned drain (ISSUE 11) reports 503 "draining", so failover
@@ -993,6 +994,9 @@ class SidecarServer:
             "preemptions": self.scheduler.preemptions,
             "engine_restarts": self.restarts,
             "streams_migrated_out": self.migrated_out,
+            # The device this process holds, as JAX reports it — never
+            # assumed by a reader (chip_smoke.py checks it from here).
+            "accelerator": getattr(self.engine, "device", None),
             # The paged-attention dispatch verdict (ISSUE 12 satellite):
             # which path this engine's layouts take and why — "gather"
             # here means the ~10.6×-slower fallback is live.
@@ -1755,6 +1759,9 @@ async def serve(config: EngineConfig, host: str = "0.0.0.0", port: int = 8000,
         config.decode_chunk = svcfg.decode_chunk
     if svcfg.decode_pipeline_depth:
         config.pipeline_depth = svcfg.decode_pipeline_depth
+    # Before anything compiles: a restart then loads its programs
+    # instead of compiling them.
+    compile_cache = configure_compile_cache()
     engine = Engine(config)
     # Device observatory (ISSUE 19): attach BEFORE warmup so every boot
     # compile lands in the ledger with its cost analysis — warmup()
@@ -1771,7 +1778,9 @@ async def serve(config: EngineConfig, host: str = "0.0.0.0", port: int = 8000,
             logger.warn("device observatory disabled", "error", str(e))
             observatory = None
     warm = engine.warmup()
-    logger.info("engine warm", "compile_seconds", round(warm, 1), "model", config.model)
+    logger.info("engine warm", "compile_seconds", round(warm, 1), "model", config.model,
+                "attention_path", engine.attention_path, "compile_cache", compile_cache,
+                "accelerator", engine.device)
     tracer = None
     if tcfg.tracing_enable:
         tracer = Tracer("tpu-sidecar", enabled=True, logger=logger,

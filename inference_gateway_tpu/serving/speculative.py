@@ -8,7 +8,7 @@ step emits between 1 and K+1 tokens with the target's exact sampling
 distribution. References: Leviathan et al. 2023 (PAPERS.md); the
 reference gateway has no counterpart (it performs no inference,
 SURVEY.md §6) — this is serving-stack surface introduced by the TPU
-rebuild, listed as a round-3 gap in STATUS.md.
+rebuild.
 
 TPU-first shape discipline (everything here is trace-static):
 

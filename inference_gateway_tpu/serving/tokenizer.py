@@ -9,6 +9,7 @@ encode/decode, chat templating, eos/bos ids, and incremental detokenize.
 
 from __future__ import annotations
 
+import logging
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -89,9 +90,14 @@ class DetokenizeState:
 
 
 def load_tokenizer(path_or_name: str | None):
+    """HF tokenizer for a checkpoint path; the byte tokenizer for presets
+    (no path) — and, logged, when the HF one cannot be loaded: serving
+    goes on hermetically, but with 258 ids of a larger vocabulary."""
     if path_or_name:
         try:
             return HFTokenizer(path_or_name)
-        except Exception:
-            pass
+        except Exception as e:
+            logging.getLogger(__name__).warning(
+                "tokenizer %r failed to load (%s: %s); serving with the byte tokenizer",
+                path_or_name, type(e).__name__, e)
     return ByteTokenizer()

@@ -1,6 +1,6 @@
 """Engine hang watchdog with supervised restart (ISSUE 7 tentpole b).
 
-A wedged device step (driver hang, dead remote-TPU tunnel, XLA deadlock)
+A wedged device step (driver hang, XLA deadlock)
 blocks the scheduler thread inside a fetch forever: requests hold slots,
 the queue backs up, and before this module the only defense was the
 /health stall flag — an external orchestrator had to kill the whole

@@ -1,13 +1,10 @@
 """Shared device-timing helper for the benchmarks.
 
-One implementation of the rotated-input timer (bench.py kernel
-microbench + benchmarks/kernel_lab.py): repeating IDENTICAL dispatches
-through the remote-execution path measured the paged kernel above the
-HBM roofline — physically impossible, so repeats are evidently
-short-circuited somewhere below JAX — and un-awaited warm-up dispatches
-drain inside the timed region if the warm-up blocks on a stale result
-(both round-3 findings). Every timed call gets a distinct first
-argument, and warm-up blocks on its own results.
+The rotated-input timer of benchmarks/kernel_lab.py: every timed call
+gets a distinct first argument, so nothing below JAX can answer a
+repeat of an identical dispatch from a cache, and warm-up blocks on its
+own results, so no un-awaited warm-up dispatch drains inside the timed
+region.
 """
 
 from __future__ import annotations

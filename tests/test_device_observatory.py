@@ -54,6 +54,7 @@ def _req(path, query=None):
 
 
 def _sidecar(engine, **kw):
+    kw.setdefault("accounting_chip", "v5e")  # CPU has no datasheet row
     return SidecarServer(engine, served_model_name="test-tiny",
                          otel=OpenTelemetry(), **kw)
 
@@ -239,7 +240,7 @@ async def test_hbm_pane_reports_plan_and_never_fabricates_live_bytes():
     assert 1 <= pages["high_water"] <= pages["total"]
     assert pages["high_water_bytes"] > 0
     if not snap["measured"]:
-        # CPU/proxy host: the pane says so instead of inventing numbers.
+        # CPU host: the pane says so instead of inventing numbers.
         assert "note" in snap
         assert "live_bytes" not in snap and "peak_bytes" not in snap
     else:  # a real device backend: live/peak come from memory_stats()
@@ -323,3 +324,29 @@ def test_bench_device_observatory_overhead_under_5pct(aloop):
         if result["p99_delta_pct"] < 5.0:
             return
     raise AssertionError(f"p99 overhead above 5% in all 3 runs: {deltas}")
+
+
+# ---------------------------------------------------------------------------
+# 8. HBM pane across devices: a sharded engine is read on every device
+# ---------------------------------------------------------------------------
+def test_hbm_snapshot_sums_and_lists_every_device(monkeypatch):
+    import jax
+
+    from inference_gateway_tpu.otel.device_observatory import DeviceObservatory
+
+    class _Dev:
+        def __init__(self, i, live):
+            self.id, self._live = i, live
+
+        def memory_stats(self):
+            return {"bytes_in_use": self._live, "peak_bytes_in_use": self._live + 10}
+
+    monkeypatch.setattr(jax, "devices", lambda: [_Dev(i, 100 + i) for i in range(4)])
+    obs = DeviceObservatory(model="m")
+    obs._plan = {"plan_bytes": 400}
+    snap = obs.hbm_snapshot()
+    assert snap["measured"] is True
+    assert [d["id"] for d in snap["per_device"]] == [0, 1, 2, 3]
+    assert snap["live_bytes"] == 100 + 101 + 102 + 103
+    assert snap["peak_bytes"] == snap["live_bytes"] + 40
+    assert snap["live_vs_plan"] == round(snap["live_bytes"] / 400, 4)

@@ -19,7 +19,11 @@ import time
 import numpy as np
 import pytest
 
-from inference_gateway_tpu.otel.perf_accounting import PerfAccounting, StepCostModel
+from inference_gateway_tpu.otel.perf_accounting import (
+    CHIP_SPECS,
+    PerfAccounting,
+    StepCostModel,
+)
 from inference_gateway_tpu.serving.engine import (
     Engine,
     EngineConfig,
@@ -204,7 +208,7 @@ def test_step_cost_model_prices_mixed_steps():
     from inference_gateway_tpu.models import llama
 
     cfg = llama.PRESETS["tinyllama-1.1b"]
-    m = StepCostModel(cfg, n_chips=1)
+    m = StepCostModel(cfg, chip=CHIP_SPECS["v5e"], n_chips=1)
     # All-decode mixed step == classic decode step.
     B, ctx = 8, 4096
     dec = m.decode(B, n_steps=1, context_tokens=ctx)
@@ -226,7 +230,7 @@ def test_mixed_steps_reach_roofline_report_and_gauge():
     from inference_gateway_tpu.otel.profiling import StepTimeline
 
     engine = _mk_engine(True, mixed_step_tokens=24)
-    acct = PerfAccounting(StepCostModel.from_engine(engine), measured=False)
+    acct = PerfAccounting(StepCostModel.from_engine(engine, chip="v5e"), measured=False)
     timeline = StepTimeline(64)
     sched = Scheduler(engine)
     sched.accounting = acct

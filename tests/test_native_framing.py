@@ -138,3 +138,17 @@ def test_hostile_inputs_safe_and_identical():
             framing.parse_chunked(bad, 65536)
         with pytest.raises(ValueError):
             _parse_chunked_py(bad, 65536)
+
+
+@needs_native
+def test_binary_name_carries_the_source_hash():
+    """A copy of the tree scrambles mtimes; the hash in the name is what
+    keeps a binary built from other source from being loaded."""
+    import hashlib
+    import pathlib
+
+    from inference_gateway_tpu import native
+
+    source = pathlib.Path(native.__file__).with_name("framing.c").read_bytes()
+    assert hashlib.sha1(source).hexdigest()[:12] in pathlib.Path(framing.__file__).name
+    assert native.status == "native"

@@ -1,8 +1,8 @@
 """Paged-attention dispatch audit (round-4 verdict next #10).
 
 The GSPMD gather fallback measured ~10.6× slower than the Pallas kernel
-at serving shape on a live v5e (BENCH_r03.json extra.kernels_tpu:
-25,856 µs vs 2,448 µs). These tests make the dispatch an assertion, not
+at serving shape on a live v5e (2026-07-29, TinyLlama: 25,856 µs vs
+2,448 µs). These tests make the dispatch an assertion, not
 an accident: every committed serving profile's layout must land on a
 kernel path on TPU, and the decision function must agree with the live
 dispatcher's observable behavior.
@@ -102,3 +102,25 @@ def test_dispatch_matches_live_path_on_cpu():
     got = paged_attention(q, k, v, pt, lengths, Hkv)
     want = paged_attention_jax(q, k, v, pt, lengths, Hkv)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
+def test_engine_on_a_tpu_refuses_a_gather_layout(monkeypatch):
+    """A paged engine that finds itself on a TPU with its attention on
+    the gather fallback — and no IG_TPU_PAGED_KERNEL=0 asking for it —
+    must refuse to build, not look healthy and set a gauge. No layout
+    dispatches there today, so the table is bent to make one."""
+    from inference_gateway_tpu.ops import paged_attention
+    from inference_gateway_tpu.serving import engine as engine_mod
+
+    monkeypatch.setattr(engine_mod, "device_info", lambda: {
+        "platform": "tpu", "device_kind": "TPU v5 lite", "device_count": 1})
+    monkeypatch.setattr(paged_attention, "paged_dispatch",
+                        lambda *a, **k: ("gather", "a layout nobody wrote a kernel for"))
+    cfg = engine_mod.EngineConfig(model="test-tiny", max_slots=2, max_seq_len=64,
+                                  dtype="float32", use_mesh=False, attention="paged",
+                                  page_size=16)
+    with pytest.raises(RuntimeError, match="gather fallback"):
+        engine_mod.Engine(cfg)
+    # The explicit switch is the one way to ask for it.
+    monkeypatch.setattr(paged_attention, "FORCE_PAGED_KERNEL", "0")
+    assert engine_mod.Engine(cfg).attention_path == "gather"

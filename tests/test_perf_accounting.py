@@ -37,7 +37,7 @@ LLAMA8B = resolve_model_cfg("llama-3-8b")
 # StepCostModel closed forms
 # ---------------------------------------------------------------------------
 def test_decode_flops_follow_2n_params_rule():
-    m = StepCostModel(LLAMA8B, n_chips=8)
+    m = StepCostModel(LLAMA8B, chip=CHIP_SPECS["v5e"], n_chips=8)
     N = llama_param_count(LLAMA8B)
     c = m.decode(batch=1, n_steps=1, context_tokens=0)
     assert c.flops == pytest.approx(2 * N)
@@ -52,7 +52,7 @@ def test_decode_flops_follow_2n_params_rule():
 
 
 def test_prefill_quadratic_attention_term():
-    m = StepCostModel(LLAMA8B)
+    m = StepCostModel(LLAMA8B, chip=CHIP_SPECS["v5e"])
     N = llama_param_count(LLAMA8B)
     T = 2048
     c = m.prefill(T, sq_tokens=T * T)
@@ -65,7 +65,7 @@ def test_prefill_quadratic_attention_term():
 
 def test_spec_round_prices_k_plus_1_positions_and_model_draft_adds_draft():
     draft = resolve_model_cfg("llama-draft-150m")
-    m = StepCostModel(LLAMA8B, spec_k=4, draft_cfg=draft)
+    m = StepCostModel(LLAMA8B, chip=CHIP_SPECS["v5e"], spec_k=4, draft_cfg=draft)
     N = llama_param_count(LLAMA8B)
     Nd = llama_param_count(draft)
     B = 8
@@ -93,7 +93,7 @@ def test_decode_roofline_matches_committed_analytic_number():
 
 
 def test_analytic_mfu_monotone_in_batch():
-    m = StepCostModel(LLAMA8B, n_chips=8)
+    m = StepCostModel(LLAMA8B, chip=CHIP_SPECS["v5e"], n_chips=8)
     mfus = []
     for batch in (1, 8, 32, 96):
         c = m.decode(batch=batch, context_tokens=batch * 2048)
@@ -104,7 +104,7 @@ def test_analytic_mfu_monotone_in_batch():
 
 def test_moe_prices_active_experts_only():
     cfg = mixtral.PRESETS["mixtral-8x7b"]
-    m = StepCostModel(cfg, n_chips=16)
+    m = StepCostModel(cfg, chip=CHIP_SPECS["v5e"], n_chips=16)
     # Active params (2 of 8 experts) are well under the full tree, so a
     # decode token costs far less than 2·N-total.
     assert m.active_params < m.n_params
@@ -156,7 +156,7 @@ def test_hbm_plan_matches_engine_allocation_for_tiny_profile():
         assert actual_w == plan["weights_per_chip"]
         # And the cost model agrees with both (keeps /debug/roofline
         # honest as engine layouts evolve).
-        m = StepCostModel.from_engine(engine)
+        m = StepCostModel.from_engine(engine, chip="v5e")
         assert m.weight_bytes == pytest.approx(actual_w)
         assert m.kv_bytes_per_token == kv_bytes_per_token(engine.model_cfg)
     finally:
@@ -284,7 +284,7 @@ class _CountingLogger:
 
 
 def test_scheduler_prices_steps_and_attributes_disconnects(tiny_engine):
-    acc = PerfAccounting(StepCostModel.from_engine(tiny_engine),
+    acc = PerfAccounting(StepCostModel.from_engine(tiny_engine, chip="v5e"),
                          model="test-tiny", measured=False)
     sched = Scheduler(tiny_engine)
     sched.accounting = acc
@@ -343,7 +343,7 @@ def test_spec_waste_attribution():
     engine = Engine(EngineConfig(model="test-tiny", max_slots=2, max_seq_len=128,
                                  dtype="float32", max_prefill_batch=2, use_mesh=False,
                                  spec_draft="ngram", spec_k=4))
-    acc = PerfAccounting(StepCostModel.from_engine(engine),
+    acc = PerfAccounting(StepCostModel.from_engine(engine, chip="v5e"),
                          model="test-tiny", measured=False)
     sched = Scheduler(engine)
     sched.accounting = acc
@@ -363,7 +363,8 @@ def test_spec_waste_attribution():
 @pytest.fixture(scope="module")
 def roofline_stack(aloop, tiny_engine):
     otel = OpenTelemetry()
-    sidecar = SidecarServer(tiny_engine, served_model_name="test-tiny", otel=otel)
+    sidecar = SidecarServer(tiny_engine, served_model_name="test-tiny", otel=otel,
+                            accounting_chip="v5e")
     port = aloop.run(sidecar.start("127.0.0.1", 0))
     yield sidecar, port, otel
     aloop.run(sidecar.shutdown())
@@ -460,7 +461,7 @@ async def test_access_log_carries_per_request_flops():
                                  dtype="float32", max_prefill_batch=2, use_mesh=False))
     log = AccessLog(stream=io.StringIO(), service="tpu-sidecar")
     sidecar = SidecarServer(engine, served_model_name="test-tiny",
-                            access_log=log)
+                            access_log=log, accounting_chip="v5e")
     port = await sidecar.start("127.0.0.1", 0)
     try:
         await _chat(port, stream=True)
@@ -509,7 +510,7 @@ def test_early_exit_zeroes_chunk_overrun_waste():
             model="test-tiny", max_slots=4, max_seq_len=128, dtype="float32",
             max_prefill_batch=2, use_mesh=False, decode_chunk=8,
             decode_early_exit=early_exit))
-        acc = PerfAccounting(StepCostModel.from_engine(eng),
+        acc = PerfAccounting(StepCostModel.from_engine(eng, chip="v5e"),
                              model="test-tiny", measured=False)
         sched = Scheduler(eng)
         sched.accounting = acc
@@ -531,3 +532,34 @@ def test_early_exit_zeroes_chunk_overrun_waste():
                 assert overrun > 0, "legacy path stopped attributing overrun"
         finally:
             sched.stop()
+
+
+# ---------------------------------------------------------------------------
+# The chip row comes from device_kind; an unknown device has no roofline
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("kind,chip", [("TPU v5 lite", "v5e"), ("TPU v4", "v4"),
+                                       ("TPU v6 lite", "v6e"), ("cpu", None),
+                                       ("TPU v9 imaginary", None)])
+def test_chip_row_is_looked_up_by_device_kind_and_never_assumed(
+        monkeypatch, tiny_engine, kind, chip):
+    from inference_gateway_tpu.otel.perf_accounting import UnknownChipError
+    from inference_gateway_tpu.utils import platform
+
+    monkeypatch.setattr(platform, "device_info", lambda: {
+        "platform": "tpu", "device_kind": kind, "device_count": 1})
+    if chip is None:
+        with pytest.raises(UnknownChipError, match="no roofline"):
+            StepCostModel.from_engine(tiny_engine)
+        # Naming a chip is the one way to price against it anyway.
+        assert StepCostModel.from_engine(tiny_engine, chip="v5e").chip is CHIP_SPECS["v5e"]
+    else:
+        assert StepCostModel.from_engine(tiny_engine).chip is CHIP_SPECS[chip]
+    with pytest.raises(UnknownChipError):
+        StepCostModel.from_engine(tiny_engine, chip="v5-typo")
+
+
+def test_sidecar_on_an_unknown_device_serves_without_accounting(tiny_engine):
+    """CPU has no datasheet row: accounting stays off (logged), and
+    /debug/roofline says so — it never shows v5e's figures."""
+    sidecar = SidecarServer(tiny_engine, served_model_name="test-tiny")
+    assert sidecar.accounting is None
