@@ -373,6 +373,22 @@ def journey_shed_middleware(journeys, slo=None):
     return middleware
 
 
+def traceparent_relay_middleware():
+    """Tracing off (ISSUE 24): hand a well-formed inbound ``traceparent``
+    to the provider hop unchanged, where ``tracing_middleware`` would
+    have put its own span's. The sidecar's access-log line then carries
+    the client's trace id whether or not this gateway traces, so a
+    client can join its own records to it."""
+
+    async def middleware(req: Request, nxt: Handler) -> Response:
+        header = req.headers.get("traceparent")
+        if _traceparent_trace_id(header) is not None:
+            req.ctx["traceparent"] = header
+        return await nxt(req)
+
+    return middleware
+
+
 def tracing_middleware(tracer, skip_paths: tuple[str, ...] = ("/health", "/v1/metrics")):
     """otelgin equivalent: span per request, honoring inbound traceparent
     (cmd/gateway/main.go:238-243)."""

@@ -22,6 +22,7 @@ from inference_gateway_tpu.api.middlewares.logger import logger_middleware
 from inference_gateway_tpu.api.middlewares.telemetry import (
     journey_shed_middleware,
     telemetry_middleware,
+    traceparent_relay_middleware,
     tracing_middleware,
 )
 from inference_gateway_tpu.api.routes import RouterImpl, Response
@@ -468,6 +469,8 @@ def build_gateway(cfg: Config | None = None, env: dict[str, str] | None = None,
     middlewares.append(admission_middleware(overload, logger, tenancy=tenancy))
     if otel is not None and cfg.telemetry.tracing_enable:
         middlewares.append(tracing_middleware(otel.tracer))
+    else:
+        middlewares.append(traceparent_relay_middleware())
     middlewares.append(logger_middleware(logger))
     if otel is not None:
         # The telemetry middleware doubles as the gateway-edge forensics

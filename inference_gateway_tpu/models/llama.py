@@ -433,58 +433,60 @@ def forward_paged_impl(
     def body(carry, per_layer):
         x, ck, cv = carry  # ck/cv: (L*P*page_size, HkvD) flat carry
         lp, li = per_layer
-        h = rms_norm(x, _nw(lp["attn_norm"], cfg), cfg.rms_norm_eps)
-        q = qmatmul(h, lp["wq"])
-        k = qmatmul(h, lp["wk"])
-        v = qmatmul(h, lp["wv"])
-        if cfg.qkv_bias:
-            q = q + lp["bq"]
-            k = k + lp["bk"]
-            v = v + lp["bv"]
-        q = q.reshape(B, T, Hq, D)
-        k = k.reshape(B, T, Hkv, D)
-        v = v.reshape(B, T, Hkv, D)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        with jax.named_scope("attention"):
+            h = rms_norm(x, _nw(lp["attn_norm"], cfg), cfg.rms_norm_eps)
+            q = qmatmul(h, lp["wq"])
+            k = qmatmul(h, lp["wk"])
+            v = qmatmul(h, lp["wv"])
+            if cfg.qkv_bias:
+                q = q + lp["bq"]
+                k = k + lp["bk"]
+                v = v + lp["bv"]
+            q = q.reshape(B, T, Hq, D)
+            k = k.reshape(B, T, Hkv, D)
+            v = v.reshape(B, T, Hkv, D)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
 
-        k_flat = k.reshape(B, T, HkvD).astype(ck.dtype)
-        v_flat = v.reshape(B, T, HkvD).astype(cv.dtype)
-        # Per-layer offset; rows that were OOB within the layer (== flat,
-        # the drop convention) must stay OOB for the WHOLE buffer, not
-        # land in layer li+1's first page.
-        w_idx = jnp.where(write_idx >= flat, total, write_idx + li * flat)
-        ck = ck.at[w_idx].set(k_flat, mode="drop")
-        cv = cv.at[w_idx].set(v_flat, mode="drop")
-        pages_k = ck.reshape(L * P, page_size, HkvD)
-        pages_v = cv.reshape(L * P, page_size, HkvD)
-        layer_table = page_table + li * P  # (B, max_pages) into the big pool
+            k_flat = k.reshape(B, T, HkvD).astype(ck.dtype)
+            v_flat = v.reshape(B, T, HkvD).astype(cv.dtype)
+            # Per-layer offset; rows that were OOB within the layer (== flat,
+            # the drop convention) must stay OOB for the WHOLE buffer, not
+            # land in layer li+1's first page.
+            w_idx = jnp.where(write_idx >= flat, total, write_idx + li * flat)
+            ck = ck.at[w_idx].set(k_flat, mode="drop")
+            cv = cv.at[w_idx].set(v_flat, mode="drop")
+            pages_k = ck.reshape(L * P, page_size, HkvD)
+            pages_v = cv.reshape(L * P, page_size, HkvD)
+            layer_table = page_table + li * P  # (B, max_pages) into the big pool
 
-        if decode:
-            attn = paged_attention(q[:, 0], pages_k, pages_v, layer_table, lengths, Hkv,
-                                   window=cfg.sliding_window, mesh=mesh)
-            attn = attn[:, None]  # (B, 1, Hq, D)
-        elif mode == "prefill_chunk":
-            # Gather the slot's pages (prefix + just-written tail) and
-            # attend causally by absolute position.
-            kg = pages_k[layer_table].reshape(B, -1, Hkv, D).astype(q.dtype)
-            vg = pages_v[layer_table].reshape(B, -1, Hkv, D).astype(q.dtype)
-            if use_flash_prefill(T, kg.shape[1], D):
-                attn = flash_prefill_attention(q, kg, vg, lengths, q_offsets=positions[:, 0],
-                                               window=cfg.sliding_window)
+            if decode:
+                attn = paged_attention(q[:, 0], pages_k, pages_v, layer_table, lengths, Hkv,
+                                       window=cfg.sliding_window, mesh=mesh)
+                attn = attn[:, None]  # (B, 1, Hq, D)
+            elif mode == "prefill_chunk":
+                # Gather the slot's pages (prefix + just-written tail) and
+                # attend causally by absolute position.
+                kg = pages_k[layer_table].reshape(B, -1, Hkv, D).astype(q.dtype)
+                vg = pages_v[layer_table].reshape(B, -1, Hkv, D).astype(q.dtype)
+                if use_flash_prefill(T, kg.shape[1], D):
+                    attn = flash_prefill_attention(q, kg, vg, lengths, q_offsets=positions[:, 0],
+                                                   window=cfg.sliding_window)
+                else:
+                    attn = gqa_attend(q, kg, vg, chunk_mask)
+            elif ring_mesh is not None:
+                # Fresh long-prompt prefill over the sp ring; pages were
+                # just written above, attention runs on this call's k/v.
+                from inference_gateway_tpu.ops.ring_attention import make_ring_attention
+
+                attn = make_ring_attention(ring_mesh, axis="sp", causal=True)(q, k, v, lengths)
+            elif use_flash_prefill(T, T, D):
+                attn = flash_prefill_attention(q, k, v, lengths, window=cfg.sliding_window)
             else:
-                attn = gqa_attend(q, kg, vg, chunk_mask)
-        elif ring_mesh is not None:
-            # Fresh long-prompt prefill over the sp ring; pages were
-            # just written above, attention runs on this call's k/v.
-            from inference_gateway_tpu.ops.ring_attention import make_ring_attention
-
-            attn = make_ring_attention(ring_mesh, axis="sp", causal=True)(q, k, v, lengths)
-        elif use_flash_prefill(T, T, D):
-            attn = flash_prefill_attention(q, k, v, lengths, window=cfg.sliding_window)
-        else:
-            attn = gqa_attend(q, k, v, mask)
-        x = x + qmatmul(attn.reshape(B, T, Hq * D), lp["wo"])
-        x = x + ffn(x, lp, cfg)
+                attn = gqa_attend(q, k, v, mask)
+            x = x + qmatmul(attn.reshape(B, T, Hq * D), lp["wo"])
+        with jax.named_scope("mlp"):
+            x = x + ffn(x, lp, cfg)
         return (x, ck, cv), None
 
     ck0 = cache["k"].reshape(total, HkvD)
@@ -495,17 +497,18 @@ def forward_paged_impl(
     new_cache = {"k": ck.reshape(L, P, page_size, HkvD),
                  "v": cv.reshape(L, P, page_size, HkvD)}
 
-    x = rms_norm(x, _nw(params["final_norm"], cfg), cfg.rms_norm_eps)
-    if last_only:
-        if mode == "decode":
-            idx = jnp.zeros_like(lengths)
-        else:  # prefill starts at 0; prefill_chunk at positions[:, 0]
-            idx = jnp.maximum(lengths - 1 - positions[:, 0], 0)
-        x = x[jnp.arange(B), idx]
-    if cfg.tie_word_embeddings:
-        logits = (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
-    else:
-        logits = qmatmul(x, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = rms_norm(x, _nw(params["final_norm"], cfg), cfg.rms_norm_eps)
+        if last_only:
+            if mode == "decode":
+                idx = jnp.zeros_like(lengths)
+            else:  # prefill starts at 0; prefill_chunk at positions[:, 0]
+                idx = jnp.maximum(lengths - 1 - positions[:, 0], 0)
+            x = x[jnp.arange(B), idx]
+        if cfg.tie_word_embeddings:
+            logits = (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
+        else:
+            logits = qmatmul(x, params["lm_head"]).astype(jnp.float32)
     return logits, new_cache
 
 
@@ -579,34 +582,36 @@ def forward_ragged_impl(
     def body(carry, per_layer):
         x, ck, cv = carry
         lp, li = per_layer
-        h = rms_norm(x, _nw(lp["attn_norm"], cfg), cfg.rms_norm_eps)
-        q = qmatmul(h, lp["wq"])
-        k = qmatmul(h, lp["wk"])
-        v = qmatmul(h, lp["wv"])
-        if cfg.qkv_bias:
-            q = q + lp["bq"]
-            k = k + lp["bk"]
-            v = v + lp["bv"]
-        q = q.reshape(B, T, Hq, D)
-        k = k.reshape(B, T, Hkv, D)
-        v = v.reshape(B, T, Hkv, D)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        with jax.named_scope("attention"):
+            h = rms_norm(x, _nw(lp["attn_norm"], cfg), cfg.rms_norm_eps)
+            q = qmatmul(h, lp["wq"])
+            k = qmatmul(h, lp["wk"])
+            v = qmatmul(h, lp["wv"])
+            if cfg.qkv_bias:
+                q = q + lp["bq"]
+                k = k + lp["bk"]
+                v = v + lp["bv"]
+            q = q.reshape(B, T, Hq, D)
+            k = k.reshape(B, T, Hkv, D)
+            v = v.reshape(B, T, Hkv, D)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
 
-        k_flat = k.reshape(B, T, HkvD).astype(ck.dtype)
-        v_flat = v.reshape(B, T, HkvD).astype(cv.dtype)
-        w_idx = jnp.where(write_idx >= flat, total, write_idx + li * flat)
-        ck = ck.at[w_idx].set(k_flat, mode="drop")
-        cv = cv.at[w_idx].set(v_flat, mode="drop")
-        pages_k = ck.reshape(L * P, page_size, HkvD)
-        pages_v = cv.reshape(L * P, page_size, HkvD)
-        layer_table = page_table + li * P
+            k_flat = k.reshape(B, T, HkvD).astype(ck.dtype)
+            v_flat = v.reshape(B, T, HkvD).astype(cv.dtype)
+            w_idx = jnp.where(write_idx >= flat, total, write_idx + li * flat)
+            ck = ck.at[w_idx].set(k_flat, mode="drop")
+            cv = cv.at[w_idx].set(v_flat, mode="drop")
+            pages_k = ck.reshape(L * P, page_size, HkvD)
+            pages_v = cv.reshape(L * P, page_size, HkvD)
+            layer_table = page_table + li * P
 
-        attn = ragged_paged_attention(
-            q[0], pages_k, pages_v, layer_table, q_starts, q_lens, kv_lens,
-            Hkv, window=cfg.sliding_window, mesh=mesh)[None]  # (1, T, Hq, D)
-        x = x + qmatmul(attn.reshape(B, T, Hq * D), lp["wo"])
-        x = x + ffn(x, lp, cfg)
+            attn = ragged_paged_attention(
+                q[0], pages_k, pages_v, layer_table, q_starts, q_lens, kv_lens,
+                Hkv, window=cfg.sliding_window, mesh=mesh)[None]  # (1, T, Hq, D)
+            x = x + qmatmul(attn.reshape(B, T, Hq * D), lp["wo"])
+        with jax.named_scope("mlp"):
+            x = x + ffn(x, lp, cfg)
         return (x, ck, cv), None
 
     ck0 = cache["k"].reshape(total, HkvD)
@@ -617,15 +622,16 @@ def forward_ragged_impl(
     new_cache = {"k": ck.reshape(L, P, page_size, HkvD),
                  "v": cv.reshape(L, P, page_size, HkvD)}
 
-    x = rms_norm(x, _nw(params["final_norm"], cfg), cfg.rms_norm_eps)
-    # Per-ROW logits at each row's last packed query (inactive rows are
-    # clamped to index 0; the caller ignores them).
-    last = jnp.clip(q_starts + q_lens - 1, 0, T - 1)
-    x = x[0, last]  # (R, H)
-    if cfg.tie_word_embeddings:
-        logits = (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
-    else:
-        logits = qmatmul(x, params["lm_head"]).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = rms_norm(x, _nw(params["final_norm"], cfg), cfg.rms_norm_eps)
+        # Per-ROW logits at each row's last packed query (inactive rows are
+        # clamped to index 0; the caller ignores them).
+        last = jnp.clip(q_starts + q_lens - 1, 0, T - 1)
+        x = x[0, last]  # (R, H)
+        if cfg.tie_word_embeddings:
+            logits = (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
+        else:
+            logits = qmatmul(x, params["lm_head"]).astype(jnp.float32)
     return logits, new_cache
 
 

@@ -22,7 +22,11 @@ time went* when a number regresses, with four cooperating pieces:
   prefill/decode/spec kind, batch occupancy, tokens emitted, KV
   utilization) written by the scheduler thread and served at
   ``GET /debug/timeline``; each record also lands in the
-  ``engine.step_duration`` histogram.
+  ``engine.step_duration`` histogram. It is also the scheduler
+  thread's span recorder (ISSUE 24): a record carries its start on the
+  span clock (``span_clock_ns``), its host phases (``phase_span``
+  stamps a phase and opens the same-named ``TraceAnnotation``
+  together), and how long the device was starved before it.
 - ``SlowRequestLog`` — requests breaching configurable TTFT/TPOT/total
   thresholds get their phase clock, trace id, and the surrounding
   engine-step window captured into a bounded log surfaced in
@@ -37,6 +41,7 @@ plain data structures driven by the caller.
 from __future__ import annotations
 
 import asyncio
+import contextlib
 import sys
 import threading
 import time
@@ -52,6 +57,71 @@ OVERFLOW_STACK = "__overflow__"
 # /debug/profile guard rails: a capture blocks one executor thread.
 MAX_CAPTURE_SECONDS = 60.0
 MAX_CAPTURE_HZ = 1000.0
+
+# The span clock (ISSUE 24): one anchor pair a process, taken when this
+# module loads (before any scheduler starts), so every scheduler a
+# supervised restart builds, the start-up stamps and jax_trace_capture
+# read ONE clock. Epoch nanoseconds that advance with perf_counter_ns:
+# monotonic, and comparable with GenRequest.phase_ns and a client's epoch.
+_ANCHOR_EPOCH_NS, _ANCHOR_PERF_NS = time.time_ns(), time.perf_counter_ns()
+
+
+def span_clock_ns(perf_ns: int | None = None) -> int:
+    """Epoch ns of ``perf_ns`` (a ``time.perf_counter_ns()`` reading; now
+    when omitted) on the span clock."""
+    if perf_ns is None:
+        perf_ns = time.perf_counter_ns()
+    return _ANCHOR_EPOCH_NS + (perf_ns - _ANCHOR_PERF_NS)
+
+
+_NO_SPAN = contextlib.nullcontext()
+_annotation: Callable[..., Any] | None = None
+
+
+def trace_annotation(name: str, **stats: Any):
+    """``jax.profiler.TraceAnnotation(name, **stats)``: a host span in the
+    profiler's own trace while a capture runs, next to nothing otherwise.
+    A process without JAX (the gateway) gets a null context."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _annotation = TraceAnnotation
+        except Exception:
+            _annotation = lambda *_a, **_k: _NO_SPAN  # noqa: E731
+    return _annotation(name, **stats)
+
+
+class _PhaseSpan:
+    __slots__ = ("_phases", "_key", "_ann", "_t0")
+
+    def __init__(self, phases: dict[str, float], key: str, name: str) -> None:
+        self._phases, self._key, self._ann = phases, key, trace_annotation(name)
+
+    def __enter__(self) -> None:
+        self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+
+    def __exit__(self, *exc: Any) -> None:
+        ms = (time.perf_counter_ns() - self._t0) / 1e6
+        self._phases[self._key] = self._phases.get(self._key, 0.0) + ms
+        self._ann.__exit__(*exc)
+
+
+def stamp_since(phases: dict[str, float] | None, key: str, t0_ns: int) -> None:
+    """``phases[key]`` = milliseconds since ``t0_ns`` (a ``perf_counter_ns``
+    reading), for a stretch no ``with`` block can wrap (a lock's wait)."""
+    if phases is not None:
+        phases[key] = (time.perf_counter_ns() - t0_ns) / 1e6
+
+
+def phase_span(phases: dict[str, float] | None, key: str, name: str):
+    """One phase of a step on the scheduler thread: adds the elapsed
+    milliseconds to ``phases[key]`` and wraps the same stretch in a
+    ``TraceAnnotation(name)``, so the timeline record and the device trace
+    cannot drift apart. ``phases`` None (no observer attached) stamps
+    nothing and opens nothing."""
+    return _NO_SPAN if phases is None else _PhaseSpan(phases, key, name)
 
 
 class CaptureBusyError(RuntimeError):
@@ -450,18 +520,27 @@ class StepTimeline:
     def record_host_gap(self, kind: str, gap_ms: float) -> None:
         """One host-gap observation (ISSUE 14): wall time the host spent
         between finishing its last device interaction and dispatching
-        the next chunk — recorded per DISPATCH into the
-        engine.host_gap_ms histogram (the latest gap also rides the next
-        step record's host_gap_ms field via record())."""
+        the next program — recorded per DISPATCH (decode chunks fresh or
+        chained, mixed steps, prefills) into the engine.host_gap_ms
+        histogram; the same gap rides that dispatch's step record."""
         if self.otel is not None:
             self.otel.record_host_gap(self.model, kind, gap_ms)
 
     def record(self, kind: str, duration_s: float, *, n_steps: int = 1, batch: int = 0,
                tokens: int = 0, kv_utilization: float = 0.0, queue_depth: int = 0,
                cost: dict[str, Any] | None = None,
-               host_gap_ms: float | None = None) -> None:
+               spans: dict[str, Any] | None = None) -> None:
+        """``spans`` (ISSUE 24) are the scheduler thread's own stamps for
+        this step, merged into the record as they are: ``phases`` (ms by
+        phase), ``starved_ms`` / ``inflight_at_dispatch`` / ``host_gap_ms``
+        of the step's dispatch, ``context_tokens``, a decode chunk's
+        ``submit_ts``, an admission's ``prep``."""
+        end_ns = span_clock_ns()
         rec = {
-            "ts": time.time(),
+            # Both on the span clock: the step's first host instruction
+            # and the moment this record was written, epoch seconds.
+            "start_ts": (end_ns - int(duration_s * 1e9)) / 1e9,
+            "ts": end_ns / 1e9,
             "kind": kind,
             "duration_ms": round(duration_s * 1000, 3),
             "steps": n_steps,
@@ -470,11 +549,8 @@ class StepTimeline:
             "kv_utilization": round(kv_utilization, 4),
             "queue_depth": queue_depth,
         }
-        if host_gap_ms is not None:
-            # Host wall time between the previous fetch and this chunk's
-            # dispatch (ISSUE 14) — the "host-free steady state" measure
-            # /debug/roofline aggregates to p50/p99 per step kind.
-            rec["host_gap_ms"] = round(host_gap_ms, 4)
+        if spans:
+            rec.update(spans)
         if cost:
             # Analytic step cost from the accounting layer (ISSUE 6):
             # flops / hbm_bytes / roofline_ms / bound ride every record
@@ -655,10 +731,21 @@ class SlowRequestLog:
 # ---------------------------------------------------------------------------
 # Guarded device-trace capture
 # ---------------------------------------------------------------------------
-def jax_trace_capture(log_dir: str, seconds: float = 2.0) -> dict[str, Any]:
+def jax_trace_capture(log_dir: str, seconds: float = 2.0, python_tracer: bool = True) -> dict[str, Any]:
     """Record a ``jax.profiler`` device trace into ``log_dir`` when a TPU
     backend is live; a harmless no-op (with the reason) anywhere else.
-    Blocking — call via an executor from async handlers."""
+    Blocking — call via an executor from async handlers.
+
+    The reply places the capture on the span clock: ``start_epoch_ns``
+    immediately before ``start_trace``, ``stop_epoch_ns`` after
+    ``stop_trace`` returned (it gathers and writes for seconds), and
+    between them ``capture_epoch_ns``, the stretch ``start_trace`` returned
+    → ``stop_trace`` called, which is when the device was being traced. The
+    capture opens with a ``clock.sync`` annotation whose ``epoch_ns`` is
+    the span clock at that moment, so the offset between the profiler's
+    zero and this clock can be read off the trace itself.
+    ``python_tracer=False`` turns the profiler's Python tracer off: the
+    program's own annotations stay, at a fraction of the cost."""
     try:
         import jax
 
@@ -670,9 +757,19 @@ def jax_trace_capture(log_dir: str, seconds: float = 2.0) -> dict[str, Any]:
     try:
         import jax.profiler
 
-        jax.profiler.start_trace(log_dir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 1 if python_tracer else 0
+        start_ns = span_clock_ns()
+        jax.profiler.start_trace(log_dir, profiler_options=options)
+        began_ns = span_clock_ns()
+        with trace_annotation("clock.sync", epoch_ns=began_ns):
+            pass
         time.sleep(min(max(float(seconds), 0.1), MAX_CAPTURE_SECONDS))
+        ending_ns = span_clock_ns()
         jax.profiler.stop_trace()
+        stop_ns = span_clock_ns()
     except Exception as e:
         return {"captured": False, "reason": f"trace failed: {e}"}
-    return {"captured": True, "log_dir": log_dir, "seconds": seconds}
+    return {"captured": True, "log_dir": log_dir, "seconds": seconds,
+            "start_epoch_ns": start_ns, "stop_epoch_ns": stop_ns,
+            "capture_epoch_ns": [began_ns, ending_ns], "python_tracer": bool(python_tracer)}

@@ -2,11 +2,15 @@
 
 from __future__ import annotations
 
-import argparse
-import asyncio
+import time
 
-from inference_gateway_tpu.serving.engine import EngineConfig
-from inference_gateway_tpu.serving.server import serve
+STARTED_NS = time.perf_counter_ns()  # before the imports below: start-up spans count from here
+
+import argparse  # noqa: E402
+import asyncio  # noqa: E402
+
+from inference_gateway_tpu.serving.engine import EngineConfig  # noqa: E402
+from inference_gateway_tpu.serving.server import serve  # noqa: E402
 
 
 def main() -> None:
@@ -74,7 +78,7 @@ def main() -> None:
         spec_adaptive=args.spec_adaptive,
     )
     asyncio.run(serve(cfg, host=args.host, port=args.port, served_model_name=args.served_model_name,
-                      metrics_push_url=args.metrics_push_url))
+                      metrics_push_url=args.metrics_push_url, started_ns=STARTED_NS))
 
 
 if __name__ == "__main__":

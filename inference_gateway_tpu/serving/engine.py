@@ -36,6 +36,7 @@ from inference_gateway_tpu.ops.sampling import (
     sample_tokens,
     sample_tokens_pregumbel,
 )
+from inference_gateway_tpu.otel.profiling import phase_span, stamp_since
 from inference_gateway_tpu.parallel.mesh import create_mesh, default_mesh_shape
 from inference_gateway_tpu.parallel.sharding import (
     check_divisibility,
@@ -220,6 +221,9 @@ class _DecodeChunkHandle:
 
     toks_lp: jax.Array
     n_steps: int
+    # Host phases of the submit (ms), stamped when the caller asked for
+    # them (ISSUE 24); the fetch adds its own to the dict the caller holds.
+    phases: dict | None = None
 
 
 @dataclass
@@ -251,6 +255,10 @@ class MixedStepHandle:
 
     toks_lp: jax.Array
     rows: list
+    # Host phases of the submit (ms): lock_wait, pages, assemble,
+    # upload_dispatch; mixed_step_fetch adds fetch_wait. None unless the
+    # caller passed a dict to stamp (ISSUE 24).
+    phases: dict | None = None
 
 
 class Engine:
@@ -259,6 +267,21 @@ class Engine:
     def __init__(self, config: EngineConfig, params=None, model_cfg: llama.LlamaConfig | None = None):
         self.config = config
         self.dtype = jnp.bfloat16 if config.dtype == "bfloat16" else jnp.float32
+        # Start-up spans (ISSUE 24): seconds by construction stage, each
+        # closed by a block_until_ready so that a stage's device work is
+        # not billed to whichever later stage first waits. Served under
+        # /debug/status "startup" with the sidecar's own stages.
+        self.startup: dict[str, float] = dict.fromkeys(
+            ("backend_s", "params_s", "quantize_s", "kv_pool_s", "structured_s"), 0.0)
+        mark = time.perf_counter()
+
+        def lap(key: str | None, *wait_for) -> None:
+            nonlocal mark
+            jax.block_until_ready(wait_for)
+            now = time.perf_counter()
+            if key is not None:
+                self.startup[key] += now - mark
+            mark = now
 
         from inference_gateway_tpu.models import mixtral
 
@@ -286,7 +309,9 @@ class Engine:
 
         self.mesh = None
         self.pp = False
+        lap(None)
         n_dev = len(jax.devices())
+        lap("backend_s")  # the first touch brings the runtime up
         pp_req = (config.mesh_shape or {}).get("pp", 1)
         if config.use_mesh and n_dev > 1 and pp_req > 1:
             # Pipeline-parallel serving (SURVEY §2.4 PP row): layers AND
@@ -398,15 +423,19 @@ class Engine:
                 # small models take the quantize-after-init path below and
                 # stay weight-identical to an unquantized engine with the
                 # same seed (tests/test_quant.py relies on this).
+                # (Built quantised: all of it is params_s, quantize_s 0.)
                 params = init_quantized_llama_params(
                     jax.random.PRNGKey(config.seed), self.model_cfg,
                     mode=config.quantize, group=group, dtype=self.dtype)
+                lap("params_s", params)
             else:
                 if params is None:
                     params = self._model.init_params(
                         jax.random.PRNGKey(config.seed), self.model_cfg, dtype=self.dtype)
+                lap("params_s", params)
                 params = jax.jit(partial(quantize_llama_params, mode=config.quantize,
                                          group=group))(params)
+            lap("quantize_s", params)
         elif params is None:
             params = self._model.init_params(jax.random.PRNGKey(config.seed), self.model_cfg, dtype=self.dtype)
         if self.mesh is not None:
@@ -420,6 +449,7 @@ class Engine:
                     specs = quantized_specs(specs, mode=config.quantize)
             params = shard_params(params, self.mesh, specs)
         self.params = params
+        lap("params_s", params)
 
         # Paged serving for dense AND MoE families. The Pallas decode
         # kernel runs single-device or shard_mapped over tp; the GSPMD
@@ -469,6 +499,8 @@ class Engine:
                 cache_specs = {"k": P(lead, None, None, "tp", None), "v": P(lead, None, None, "tp", None)}
                 cache = jax.device_put(cache, named(self.mesh, cache_specs))
             self.cache = cache
+
+        lap("kv_pool_s", self.cache)
 
         # Ragged mixed-step serving (ISSUE 12): one compiled program per
         # engine step for any prefill/decode mix. Paged dense llama-family
@@ -607,6 +639,7 @@ class Engine:
         # flips sticky-True and every step program recompiles ONCE with
         # the mask gather fused in).
         self.structured = None
+        lap(None)
         if config.structured and config.structured_states > 1:
             from inference_gateway_tpu.structured.runtime import StructuredRuntime
 
@@ -615,6 +648,7 @@ class Engine:
                 states_budget=config.structured_states,
                 cache_size=config.structured_cache,
                 max_schema_bytes=config.structured_max_schema_bytes)
+        lap("structured_s")
         # Placeholder mask args for unmasked programs (ignored at trace
         # time when masked=False, but part of the jit signature).
         self._no_mask_tables = (
@@ -961,10 +995,12 @@ class Engine:
                 pos_att + 1, cache, w[:, None], page_table, mode="decode",
                 last_only=True, mesh=self.mesh)
         if masked:
-            logits = logits + self._mask_bias(mbits, ms, mbias[:-1])
-        nxt = sample_tokens_pregumbel(logits, temps, top_ps, gumbels[i], k_eff)
-        nxt = nxt.astype(jnp.int32)
-        lp = compute_logprobs(logits, nxt)
+            with jax.named_scope("mask_bias"):
+                logits = logits + self._mask_bias(mbits, ms, mbias[:-1])
+        with jax.named_scope("sampling"):
+            nxt = sample_tokens_pregumbel(logits, temps, top_ps, gumbels[i], k_eff)
+            nxt = nxt.astype(jnp.int32)
+            lp = compute_logprobs(logits, nxt)
         nms = mnext[ms, nxt] if masked else ms
         nbud = bud - 1
         ndone = jnp.any(stop_table == nxt[:, None], axis=-1)
@@ -994,9 +1030,10 @@ class Engine:
         masked = mask_args[0]
         mstates = mask_args[1]
         mask_tail = (masked,) + mask_args[2:]
-        keys = chunk_row_keys(rng, seeds, use_seed, positions, n_steps)
-        k_eff = effective_top_k(self.config.top_k, self.model_cfg.vocab_size)
-        gumbels = chunk_gumbels(keys, k_eff)
+        with jax.named_scope("sampling"):  # the chunk's noise, drawn once
+            keys = chunk_row_keys(rng, seeds, use_seed, positions, n_steps)
+            k_eff = effective_top_k(self.config.top_k, self.model_cfg.vocab_size)
+            gumbels = chunk_gumbels(keys, k_eff)
         done0 = self._chunk_done0(tokens, positions, done, budgets, stop_table,
                                   mstates, mask_args[4], masked)
         S = tokens.shape[0]
@@ -1111,10 +1148,12 @@ class Engine:
             # Mixed rows are slot-aligned: mask by each slot's automaton
             # state, bias by its logit_bias row (constrained prefill-tail
             # rows sample their FIRST token here — same mask semantics).
-            logits = logits + self._mask_bias(mbits, mstates, mbias[:-1])
-        keys = per_row_keys(rng, seeds, use_seed, kv_lens)
-        toks = sample_tokens(logits, rng, temps, top_ps, top_k=self.config.top_k, row_keys=keys)
-        logprobs = compute_logprobs(logits, toks)
+            with jax.named_scope("mask_bias"):
+                logits = logits + self._mask_bias(mbits, mstates, mbias[:-1])
+        with jax.named_scope("sampling"):
+            keys = per_row_keys(rng, seeds, use_seed, kv_lens)
+            toks = sample_tokens(logits, rng, temps, top_ps, top_k=self.config.top_k, row_keys=keys)
+            logprobs = compute_logprobs(logits, toks)
         return toks, logprobs, cache
 
     def _audit_transfer(self, direction: str, path: str, *arrays) -> None:
@@ -1129,7 +1168,8 @@ class Engine:
             obs.record_transfer(direction, path, sum(
                 int(getattr(a, "nbytes", 0)) for a in arrays if a is not None))
 
-    def mixed_step_submit(self, rows: "list[MixedRow]") -> "MixedStepHandle":
+    def mixed_step_submit(self, rows: "list[MixedRow]",
+                          phases: dict | None = None) -> "MixedStepHandle":
         """Dispatch one ragged mixed step WITHOUT waiting (ISSUE 12).
 
         Rows are packed back to back into the fixed mixed_budget query
@@ -1139,55 +1179,69 @@ class Engine:
         and ONE jitted program computes every row and samples one token
         per row. The chained decode carry is invalidated — mixed steps
         advance cache positions outside the chain, so the next fused
-        chunk must resubmit from host state (chain=False)."""
+        chunk must resubmit from host state (chain=False).
+
+        ``phases`` (ISSUE 24): a dict the caller wants the submit's host
+        phases stamped into (lock_wait, pages, assemble, upload_dispatch,
+        in ms); it rides the returned handle."""
         S = self.config.max_slots
         T = self.mixed_budget
         total = sum(len(r.token_ids) for r in rows)
         assert rows and total <= T, (total, T)
-        tokens = np.zeros((1, T), np.int32)
-        positions = np.zeros((1, T), np.int32)
-        q_starts = np.zeros((S,), np.int32)
-        q_lens = np.zeros((S,), np.int32)
-        kv_lens = np.zeros((S,), np.int32)
-        temps = np.zeros((S,), np.float32)
-        top_ps = np.ones((S,), np.float32)
-        seeds = np.zeros((S,), np.int32)
-        use_seed = np.zeros((S,), bool)
-        mstates = np.zeros((S,), np.int32)
+        t_lock = time.perf_counter_ns()
         with self._lock:
-            write_idx = np.full((1, T), self._flat_size, np.int64)
-            off = 0
-            n_prefill = 0
-            for r in rows:
-                n = len(r.token_ids)
-                end = r.start + n
-                self._ensure_with_evict(r.slot, end)
-                tokens[0, off:off + n] = r.token_ids
-                positions[0, off:off + n] = r.start + np.arange(n, dtype=np.int32)
-                write_idx[0, off:off + n] = self.allocator.flat_write_indices(
-                    r.slot, r.start, n)
-                q_starts[r.slot] = off
-                q_lens[r.slot] = n
-                kv_lens[r.slot] = end
-                temps[r.slot] = r.temp
-                top_ps[r.slot] = r.top_p
-                if r.seed is not None:
-                    seeds[r.slot] = int(r.seed)
-                    use_seed[r.slot] = True
-                mstates[r.slot] = r.mask_state
-                off += n
-                if r.kind == "prefill":
-                    n_prefill += n
-            masked, mnext, mbits, mbias = self._mask_args()
-            toks, logprobs, self.cache = self._mixed_step_fn(
-                self.params, self.cache, jnp.asarray(tokens), jnp.asarray(positions),
-                jnp.asarray(write_idx), jnp.asarray(self.allocator.page_table()),
-                jnp.asarray(q_starts), jnp.asarray(q_lens), jnp.asarray(kv_lens),
-                jnp.asarray(temps), jnp.asarray(top_ps), jnp.asarray(seeds),
-                jnp.asarray(use_seed), self._next_rng(),
-                mstates=jnp.asarray(mstates), mnext=mnext, mbits=mbits,
-                mbias=mbias, masked=masked,
-            )
+            stamp_since(phases, "lock_wait", t_lock)
+            with phase_span(phases, "pages", "engine.mixed.pages"):
+                write_idx = np.full((1, T), self._flat_size, np.int64)
+                off = 0
+                for r in rows:
+                    n = len(r.token_ids)
+                    self._ensure_with_evict(r.slot, r.start + n)
+                    write_idx[0, off:off + n] = self.allocator.flat_write_indices(
+                        r.slot, r.start, n)
+                    off += n
+                page_table = self.allocator.page_table()
+            with phase_span(phases, "assemble", "engine.mixed.assemble"):
+                tokens = np.zeros((1, T), np.int32)
+                positions = np.zeros((1, T), np.int32)
+                q_starts = np.zeros((S,), np.int32)
+                q_lens = np.zeros((S,), np.int32)
+                kv_lens = np.zeros((S,), np.int32)
+                temps = np.zeros((S,), np.float32)
+                top_ps = np.ones((S,), np.float32)
+                seeds = np.zeros((S,), np.int32)
+                use_seed = np.zeros((S,), bool)
+                mstates = np.zeros((S,), np.int32)
+                off = 0
+                n_prefill = 0
+                for r in rows:
+                    n = len(r.token_ids)
+                    tokens[0, off:off + n] = r.token_ids
+                    positions[0, off:off + n] = r.start + np.arange(n, dtype=np.int32)
+                    q_starts[r.slot] = off
+                    q_lens[r.slot] = n
+                    kv_lens[r.slot] = r.start + n
+                    temps[r.slot] = r.temp
+                    top_ps[r.slot] = r.top_p
+                    if r.seed is not None:
+                        seeds[r.slot] = int(r.seed)
+                        use_seed[r.slot] = True
+                    mstates[r.slot] = r.mask_state
+                    off += n
+                    if r.kind == "prefill":
+                        n_prefill += n
+            with phase_span(phases, "upload_dispatch", "engine.mixed.upload_dispatch"):
+                masked, mnext, mbits, mbias = self._mask_args()
+                toks, logprobs, self.cache = self._mixed_step_fn(
+                    self.params, self.cache, jnp.asarray(tokens), jnp.asarray(positions),
+                    jnp.asarray(write_idx), jnp.asarray(page_table),
+                    jnp.asarray(q_starts), jnp.asarray(q_lens), jnp.asarray(kv_lens),
+                    jnp.asarray(temps), jnp.asarray(top_ps), jnp.asarray(seeds),
+                    jnp.asarray(use_seed), self._next_rng(),
+                    mstates=jnp.asarray(mstates), mnext=mnext, mbits=mbits,
+                    mbias=mbias, masked=masked,
+                )
+                both = jnp.stack([toks.astype(jnp.float32), logprobs])
             # Positions moved outside the chained-carry bookkeeping.
             self._dev_carry = None
             n_decode_tokens = total - n_prefill
@@ -1198,16 +1252,16 @@ class Engine:
                 self.metrics["decode_steps"] += 1
                 self.metrics["decode_tokens"] += n_decode_tokens
             self.metrics["prefill_tokens"] += n_prefill
-            both = jnp.stack([toks.astype(jnp.float32), logprobs])
         self._audit_transfer("h2d", "mixed", tokens, positions, write_idx,
-                             self.allocator.page_table(), q_starts, q_lens,
+                             page_table, q_starts, q_lens,
                              kv_lens, temps, top_ps, seeds, use_seed, mstates)
-        return MixedStepHandle(both, list(rows))
+        return MixedStepHandle(both, list(rows), phases)
 
     def mixed_step_fetch(self, handle: "MixedStepHandle"):
         """Block until a mixed step's sampled tokens are on host.
         Returns (tokens, logprobs) as numpy (max_slots,), row == slot."""
-        both = np.asarray(handle.toks_lp)
+        with phase_span(handle.phases, "fetch_wait", "engine.mixed.fetch"):
+            both = np.asarray(handle.toks_lp)
         self._audit_transfer("d2h", "mixed", both)
         return both[0].astype(np.int32), both[1]
 
@@ -1888,7 +1942,8 @@ class Engine:
                             use_seed: np.ndarray | None = None, chain: bool = False,
                             mstates: np.ndarray | None = None,
                             stop_tables: np.ndarray | None = None,
-                            budgets: np.ndarray | None = None):
+                            budgets: np.ndarray | None = None,
+                            phases: dict | None = None):
         """Dispatch ``n_steps`` fused decode steps WITHOUT waiting.
 
         JAX dispatch is asynchronous — the returned handle's arrays are
@@ -1912,6 +1967,9 @@ class Engine:
         after any prefill (which clears the carry): submitting
         chain=True then raises instead of silently decoding stale
         tokens.
+
+        ``phases`` (ISSUE 24): a dict to stamp the submit's host phases
+        into (lock_wait, upload_dispatch, in ms); it rides the handle.
         """
         S = self.config.max_slots
         n = n_steps or self.config.decode_chunk
@@ -1919,24 +1977,33 @@ class Engine:
             seeds = np.zeros((S,), np.int32)
         if use_seed is None:
             use_seed = np.zeros((S,), bool)
+        t_lock = time.perf_counter_ns()
         if self._early_exit:
             with self._lock:
-                if chain:
-                    # Host-free by construction (everything is device
-                    # resident) — the audit records NOTHING here, which
-                    # is exactly how engine.transfers{h2d,chain} stays a
-                    # scrapeable zero (ISSUE 19 invariant; the series is
-                    # pre-seeded to 0 at attach).
-                    return self._chain_submit_locked(n)
-                handle = self._fresh_submit_ee_locked(
-                    tokens, positions, active, temps, top_ps, n, seeds,
-                    use_seed, mstates, stop_tables, budgets)
-            self._audit_transfer("h2d", "fresh", tokens, positions, active,
-                                 temps, top_ps, seeds, use_seed, mstates,
-                                 stop_tables, budgets)
+                stamp_since(phases, "lock_wait", t_lock)
+                with phase_span(phases, "upload_dispatch", "engine.chunk.upload_dispatch"):
+                    if chain:
+                        # Host-free by construction (everything is device
+                        # resident) — the audit records NOTHING for it,
+                        # which is exactly how engine.transfers{h2d,chain}
+                        # stays a scrapeable zero (ISSUE 19 invariant; the
+                        # series is pre-seeded to 0 at attach).
+                        handle = self._chain_submit_locked(n)
+                    else:
+                        handle = self._fresh_submit_ee_locked(
+                            tokens, positions, active, temps, top_ps, n, seeds,
+                            use_seed, mstates, stop_tables, budgets)
+            handle.phases = phases
+            if not chain:
+                self._audit_transfer("h2d", "fresh", tokens, positions, active,
+                                     temps, top_ps, seeds, use_seed, mstates,
+                                     stop_tables, budgets)
             return handle
         masked, mnext, mbits, mbias = self._mask_args()
-        with self._lock:
+        # The legacy (fixed-scan) chunk assembles its write indices here,
+        # so its upload_dispatch phase holds that host work too.
+        with self._lock, phase_span(phases, "upload_dispatch", "engine.chunk.upload_dispatch"):
+            stamp_since(phases, "lock_wait", t_lock)
             if chain:
                 if self._dev_carry is None:
                     raise RuntimeError(
@@ -1993,7 +2060,7 @@ class Engine:
         else:
             self._audit_transfer("h2d", "fresh", tokens, positions, temps,
                                  top_ps, seeds, use_seed, mstates)
-        return _DecodeChunkHandle(both, n)
+        return _DecodeChunkHandle(both, n, phases)
 
     # -- speculative decoding (serving/speculative.py) ------------------
     @partial(jax.jit, static_argnames=("self",), donate_argnums=(2,))
@@ -2321,7 +2388,8 @@ class Engine:
     def decode_chunk_fetch(self, handle: "_DecodeChunkHandle"):
         """Block until a submitted chunk's results are on the host.
         Returns (tokens, logprobs) as numpy (n_steps, S)."""
-        both = np.asarray(handle.toks_lp)
+        with phase_span(handle.phases, "fetch_wait", "engine.chunk.fetch"):
+            both = np.asarray(handle.toks_lp)
         self._audit_transfer("d2h", "chunk", both)
         n = handle.n_steps
         return both[:n].astype(np.int32), both[n:]

@@ -22,6 +22,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from inference_gateway_tpu.otel.profiling import phase_span, span_clock_ns
 from inference_gateway_tpu.serving.engine import Engine, STOP_TABLE_WIDTH, build_stop_row
 from inference_gateway_tpu.serving.kv_cache import OutOfPagesError
 
@@ -76,7 +77,10 @@ class GenRequest:
     # (decode), written by the scheduler as the request crosses each
     # boundary. The serving sidecar materializes trace child spans and
     # queue-wait/TPOT histograms from these — span timestamps are epoch
-    # ns, hence time_ns() rather than the monotonic clock.
+    # ns, hence time_ns() rather than the monotonic clock. The sidecar's
+    # HTTP edge adds its own two (ISSUE 24): recv (first line of the chat
+    # handler, before parse, template and tokenise) and first_write (the
+    # first content frame handed to the transport).
     phase_ns: dict[str, int] = field(default_factory=dict)
     # KV-pressure preemption bookkeeping (ISSUE 7): how many times this
     # request has been descheduled (slot + pages released, re-enqueued
@@ -175,6 +179,12 @@ class _Inflight:
     handle: object
     states: dict
     n_steps: int
+    # What the submit stamped (ISSUE 24; None with no observer attached):
+    # submit_ts on the span clock and the dispatch's starved_ms /
+    # inflight_at_dispatch / host_gap_ms. They land on this chunk's own
+    # decode record, whose [start_ts, ts] is the fetch and emit a chunk or
+    # two later; the submit's phases ride the engine handle.
+    submit: dict | None = None
 
 
 @dataclass
@@ -184,6 +194,7 @@ class _PendingPrefill:
 
     handle: object
     items: list
+    submit: dict | None = None  # the dispatch's stamps, as on _Inflight
 
 
 class Scheduler:
@@ -295,16 +306,21 @@ class Scheduler:
         # consecutive failures are rate-limited and the timeline is
         # disabled outright after _TIMELINE_MAX_FAILURES in a row.
         self._timeline_failures = 0
-        # Host-gap instrumentation (ISSUE 14 satellite): perf_counter
-        # stamp of the most recent completed device interaction (submit
-        # returned / fetch materialized). The wall time from there to
-        # the NEXT chunk dispatch is the host's contribution to the
-        # steady state — the direct measure of "host-free". Recorded
-        # into the engine.host_gap_ms histogram per dispatch and onto
-        # the next decode StepTimeline record; only stamped while an
-        # observer is attached (same None-is-free discipline).
-        self._dev_touch: float | None = None
-        self._pending_host_gap_ms: float | None = None
+        # Host-gap and starvation instrumentation (ISSUE 14 satellite,
+        # generalised by ISSUE 24), perf_counter_ns stamps, only taken
+        # while an observer is attached (same None-is-free discipline).
+        # _dev_touch: the most recent completed device interaction
+        # (submit returned / fetch materialized); the wall time from
+        # there to the NEXT dispatch is that dispatch's host_gap_ms.
+        # _starve_t0: the moment the programs dispatched and not yet
+        # fetched (len(_handles), plus a synchronous step's own) last
+        # fell to zero WHILE work was pending — a slot active or a
+        # request waiting; an idle scheduler has none, and a request's
+        # arrival into one starts the clock. The next dispatch's return
+        # closes it as that record's starved_ms: time the device had
+        # nothing queued although the scheduler had work.
+        self._dev_touch: int | None = None
+        self._starve_t0: int | None = None
 
     def active_requests(self) -> int:
         return len(self._slots)
@@ -461,12 +477,20 @@ class Scheduler:
         invalidated) drains the queue and resubmits from host state.
         """
         while True:
+            idled = False
             with self._wake:
                 while (not self._stop and not self._waiting and not self._slots
                        and not self._handles):
+                    idled = True
                     self._wake.wait(timeout=0.2)
                 if self._stop:
                     break
+            if idled and self._observing:
+                # Waiting with nothing to do is neither starvation nor a
+                # host gap: the starvation clock starts with the arrival
+                # that ended the wait.
+                self._starve_t0 = time.perf_counter_ns()
+                self._dev_touch = None
             if self.preempt_max and self.preempt_high_water > 0:
                 self._maybe_high_water_preempt()
             with self._wake:
@@ -788,6 +812,8 @@ class Scheduler:
             budgets = np.asarray(
                 [max(r.max_tokens - r.resume_generated - 1, 0) for r in batch],
                 np.int64)
+        observing = self._observing
+        t_submit = time.perf_counter_ns() if observing else 0
         try:
             handle = self.engine.prefill_submit(
                 [r.prompt_ids for r in batch], slots,
@@ -823,19 +849,24 @@ class Scheduler:
         # _slots (a double terminal callback is tolerated; a missed one
         # hangs the client — code-review round 2).
         self._admitting = []
+        pending = _PendingPrefill(handle, list(zip(batch, slots)),
+                                  self._stamp_dispatch("prefill", t_submit) if observing else None)
         if self.engine.spec and self._spec_mode_active():
             # Spec rounds need first tokens host-side immediately.
-            self._process_prefill(_PendingPrefill(handle, list(zip(batch, slots))))
+            self._process_prefill(pending)
         else:
             # Non-spec — or adaptive speculation parked in the normal
             # loop, which keeps its async-admission overlap.
-            self._handles.append(_PendingPrefill(handle, list(zip(batch, slots))))
+            self._handles.append(pending)
 
     def _process_prefill(self, p: "_PendingPrefill") -> None:
         """Materialize a prefill's first tokens and stream them out."""
-        t0 = time.perf_counter() if self._observing else 0.0
+        observing = self._observing
+        t0 = time.perf_counter() if observing else 0.0
+        phases: dict | None = {} if observing else None
         try:
-            results = self.engine.prefill_fetch(p.handle)
+            with phase_span(phases, "fetch_wait", "engine.prefill.fetch"):
+                results = self.engine.prefill_fetch(p.handle)
         except Exception as e:
             self.engine._dev_carry = None  # scatter output is poisoned
             self.logger.error("prefill fetch failed; failing admission batch", e)
@@ -847,34 +878,36 @@ class Scheduler:
             return
         self.last_step_time = self.clock.now()
         self.steps_completed += 1
-        if self._observing:
+        if observing:
             # Device interaction completed: host-gap clocks restart here
             # so a prefill fetch between chunks isn't billed as host gap.
-            self._dev_touch = time.perf_counter()
-        for (req, slot), res in zip(p.items, results):
-            st = self._slots.get(slot)
-            if st is None or st.req is not req:
-                # Failed/released/preempted while in flight — and the
-                # slot may already belong to a NEW request (identity
-                # check, same contract as _Inflight snapshots): these
-                # first tokens describe a stream that no longer runs.
-                continue
-            st.pending_token = res.first_token
-            st.pending_logprob = res.logprob
-            st.catchup = (res.first_token,)
-            if self.engine.spec_ngram:
-                st.history = list(req.prompt_ids) + [res.first_token]
-            finished, reason = self._emit(st, res.first_token, res.logprob)
-            if finished:
-                del self._slots[slot]
-                self._release_guarded(slot, reason, frozen=st.device_stopped)
-            self._flush_emits(req)
-        if self._observing:
+            self._stamp_fetch()
+        with phase_span(phases, "emit", "sched.emit"):
+            for (req, slot), res in zip(p.items, results):
+                st = self._slots.get(slot)
+                if st is None or st.req is not req:
+                    # Failed/released/preempted while in flight — and the
+                    # slot may already belong to a NEW request (identity
+                    # check, same contract as _Inflight snapshots): these
+                    # first tokens describe a stream that no longer runs.
+                    continue
+                st.pending_token = res.first_token
+                st.pending_logprob = res.logprob
+                st.catchup = (res.first_token,)
+                if self.engine.spec_ngram:
+                    st.history = list(req.prompt_ids) + [res.first_token]
+                finished, reason = self._emit(st, res.first_token, res.logprob)
+                if finished:
+                    del self._slots[slot]
+                    self._release_guarded(slot, reason, frozen=st.device_stopped)
+                self._flush_emits(req)
+        if observing:
             prompt_lens = [len(req.prompt_ids) for req, _slot in p.items]
             self._record_step("prefill", t0, n_steps=1, batch=len(p.items),
                               tokens=len(results),
                               work_tokens=sum(prompt_lens),
-                              sq_tokens=sum(t * t for t in prompt_lens))
+                              sq_tokens=sum(t * t for t in prompt_lens),
+                              spans={**(p.submit or {}), "phases": phases})
 
     # -- ragged mixed-step admission (ISSUE 12) ------------------------
     def _build_mixed_rows(self, pending: list) -> tuple[list, int, int, int, int]:
@@ -989,47 +1022,54 @@ class Scheduler:
         # in _admitting or _slots — a missed one hangs the client (same
         # contract as bucketed _admit).
         self._admitting = batch
-        # Structured admission (ISSUE 13): spans + bias rows must be
-        # device-resident (and session bases set) before the first mixed
-        # step reads any global mask state. A failed registration
-        # (StructuredCapacityError: table budget full of live spans)
-        # fails ONLY that request — the bare-raise alternative would
-        # leak every popped slot and hang the whole batch (run()'s
-        # admission handler only logs; review finding).
-        kept: list[GenRequest] = []
-        kept_slots: list[int] = []
-        for req, slot in zip(batch, slots):
-            if req.grammar is not None or req.logit_bias:
-                try:
-                    self.engine.structured_register(slot, req.grammar, req.logit_bias)
-                except Exception as e:
-                    self.logger.warn("structured admission failed",
-                                     "request", req.request_id, "err", repr(e))
-                    self._fail_request(req)
-                    self._release_guarded(slot, "error")
-                    continue
-            kept.append(req)
-            kept_slots.append(slot)
-        batch, slots = kept, kept_slots
-        self._admitting = batch
-        if not batch:
-            self._admitting = []
-            return
-        # Host state must be authoritative before positions move under
-        # the pipeline's feet — and the carry is about to be invalidated.
-        self._drain_all()
-        pending = [{"req": r, "slot": s, "done": 0} for r, s in zip(batch, slots)]
-        if self.engine.prefix_cache is not None:
-            # Prefix-cache fast path, same as bucketed admission: adopt
-            # the longest cached page-aligned prefix and chunk-prefill
-            # only the tail (match always leaves ≥1 token to compute).
-            with self.engine._lock:
-                for item in pending:
-                    shared, matched = self.engine.prefix_cache.match(
-                        item["req"].prompt_ids)
-                    if shared:
-                        self.engine.allocator.adopt_pages(item["slot"], shared)
-                        item["done"] = matched
+        # What the admission costs before its first mixed step (ISSUE 24):
+        # rides that step's record as ``prep`` {ms, register_ms, drain_ms}.
+        prep: dict | None = (dict.fromkeys(("ms", "register_ms", "drain_ms"), 0.0)
+                             if self._observing else None)
+        with phase_span(prep, "ms", "sched.admit.prep"):
+            # Structured admission (ISSUE 13): spans + bias rows must be
+            # device-resident (and session bases set) before the first mixed
+            # step reads any global mask state. A failed registration
+            # (StructuredCapacityError: table budget full of live spans)
+            # fails ONLY that request — the bare-raise alternative would
+            # leak every popped slot and hang the whole batch (run()'s
+            # admission handler only logs; review finding).
+            kept: list[GenRequest] = []
+            kept_slots: list[int] = []
+            for req, slot in zip(batch, slots):
+                if req.grammar is not None or req.logit_bias:
+                    try:
+                        with phase_span(prep, "register_ms", "structured.register_slot"):
+                            self.engine.structured_register(slot, req.grammar, req.logit_bias)
+                    except Exception as e:
+                        self.logger.warn("structured admission failed",
+                                         "request", req.request_id, "err", repr(e))
+                        self._fail_request(req)
+                        self._release_guarded(slot, "error")
+                        continue
+                kept.append(req)
+                kept_slots.append(slot)
+            batch, slots = kept, kept_slots
+            self._admitting = batch
+            if not batch:
+                self._admitting = []
+                return
+            # Host state must be authoritative before positions move under
+            # the pipeline's feet — and the carry is about to be invalidated.
+            with phase_span(prep, "drain_ms", "sched.admit.drain"):
+                self._drain_all()
+            pending = [{"req": r, "slot": s, "done": 0} for r, s in zip(batch, slots)]
+            if self.engine.prefix_cache is not None:
+                # Prefix-cache fast path, same as bucketed admission: adopt
+                # the longest cached page-aligned prefix and chunk-prefill
+                # only the tail (match always leaves ≥1 token to compute).
+                with self.engine._lock:
+                    for item in pending:
+                        shared, matched = self.engine.prefix_cache.match(
+                            item["req"].prompt_ids)
+                        if shared:
+                            self.engine.allocator.adopt_pages(item["slot"], shared)
+                            item["done"] = matched
         try:
             while pending:
                 kept = []
@@ -1048,11 +1088,19 @@ class Scheduler:
                     break
                 observing = self._observing
                 t0 = time.perf_counter() if observing else 0.0
+                phases: dict | None = {} if observing else None
+                spans: dict = {}
                 states = dict(self._slots)  # identity snapshot at build time
-                rows, n_decode, n_prefill, context, pairs = self._build_mixed_rows(pending)
+                with phase_span(phases, "build", "sched.mixed.build"):
+                    rows, n_decode, n_prefill, context, pairs = self._build_mixed_rows(pending)
                 try:
-                    handle = self.engine.mixed_step_submit(rows)
+                    t_submit = time.perf_counter_ns() if observing else 0
+                    handle = self.engine.mixed_step_submit(rows, phases=phases)
+                    if observing:
+                        spans = self._stamp_dispatch("mixed", t_submit)
                     toks, logprobs = self.engine.mixed_step_fetch(handle)
+                    if observing:
+                        self._stamp_fetch()
                 except OutOfPagesError as e:
                     if (self.preempt_max and getattr(e, "recoverable", True)
                             and self._slots):
@@ -1080,53 +1128,58 @@ class Scheduler:
                 self.last_step_time = self.clock.now()
                 self.steps_completed += 1
                 emitted = 0
-                # Decode rows advance exactly one token, same emission
-                # contract as one step of a fused chunk.
-                for row in rows[:n_decode]:
-                    st = self._slots.get(row.slot)
-                    if st is None or st is not states.get(row.slot):
-                        continue  # released mid-step (defensive identity check)
-                    st.pos += 1
-                    st.pending_token = int(toks[row.slot])
-                    st.pending_logprob = float(logprobs[row.slot])
-                    st.generated += 1
-                    emitted += 1
-                    finished, reason = self._emit(st, st.pending_token, st.pending_logprob)
-                    if finished:
-                        del self._slots[row.slot]
-                        self._release_guarded(row.slot, reason)
-                    self._flush_emits(st.req)
-                # Prefill rows whose final chunk just landed become
-                # active slots with their sampled first token.
-                done_items = [it for it in pending
-                              if it["done"] >= len(it["req"].prompt_ids)]
-                pending = [it for it in pending
-                           if it["done"] < len(it["req"].prompt_ids)]
-                for item in done_items:
-                    req, slot = item["req"], item["slot"]
-                    self.engine.metrics["prefill_batches"] += 1
-                    st = _SlotState(
-                        req, pos=len(req.prompt_ids),
-                        pending_token=int(toks[slot]),
-                        pending_logprob=float(logprobs[slot]),
-                        generated=req.resume_generated + 1,
-                        seq=next(self._admit_seq))
-                    self._slots[slot] = st
-                    if self.engine.prefix_cache is not None:
-                        with self.engine._lock:
-                            self.engine.prefix_cache.insert(
-                                req.prompt_ids, self.engine.allocator.pages_of(slot))
-                    emitted += 1
-                    finished, reason = self._emit(st, st.pending_token, st.pending_logprob)
-                    if finished:
-                        del self._slots[slot]
-                        self._release_guarded(slot, reason)
-                    self._flush_emits(req)
+                with phase_span(phases, "emit", "sched.emit"):
+                    # Decode rows advance exactly one token, same emission
+                    # contract as one step of a fused chunk.
+                    for row in rows[:n_decode]:
+                        st = self._slots.get(row.slot)
+                        if st is None or st is not states.get(row.slot):
+                            continue  # released mid-step (defensive identity check)
+                        st.pos += 1
+                        st.pending_token = int(toks[row.slot])
+                        st.pending_logprob = float(logprobs[row.slot])
+                        st.generated += 1
+                        emitted += 1
+                        finished, reason = self._emit(st, st.pending_token, st.pending_logprob)
+                        if finished:
+                            del self._slots[row.slot]
+                            self._release_guarded(row.slot, reason)
+                        self._flush_emits(st.req)
+                    # Prefill rows whose final chunk just landed become
+                    # active slots with their sampled first token.
+                    done_items = [it for it in pending
+                                  if it["done"] >= len(it["req"].prompt_ids)]
+                    pending = [it for it in pending
+                               if it["done"] < len(it["req"].prompt_ids)]
+                    for item in done_items:
+                        req, slot = item["req"], item["slot"]
+                        self.engine.metrics["prefill_batches"] += 1
+                        st = _SlotState(
+                            req, pos=len(req.prompt_ids),
+                            pending_token=int(toks[slot]),
+                            pending_logprob=float(logprobs[slot]),
+                            generated=req.resume_generated + 1,
+                            seq=next(self._admit_seq))
+                        self._slots[slot] = st
+                        if self.engine.prefix_cache is not None:
+                            with self.engine._lock:
+                                self.engine.prefix_cache.insert(
+                                    req.prompt_ids, self.engine.allocator.pages_of(slot))
+                        emitted += 1
+                        finished, reason = self._emit(st, st.pending_token, st.pending_logprob)
+                        if finished:
+                            del self._slots[slot]
+                            self._release_guarded(slot, reason)
+                        self._flush_emits(req)
                 if observing:
+                    spans["phases"] = phases
+                    if prep is not None:  # the admission's first record carries it
+                        spans["prep"] = {k: round(v, 4) for k, v in prep.items()}
+                        prep = None
                     self._record_step(
                         "mixed", t0, n_steps=1, batch=len(rows),
                         tokens=emitted, work_tokens=n_decode + n_prefill,
-                        context_tokens=context, pair_tokens=pairs)
+                        context_tokens=context, pair_tokens=pairs, spans=spans)
         finally:
             self._admitting = []
 
@@ -1163,17 +1216,19 @@ class Scheduler:
             # horizon. NOTHING is assembled here — this branch must stay
             # free of per-slot loops and host-array construction
             # (graftlint jax-hot-path chain-steady scope).
-            gap_t0 = time.perf_counter() if observing else 0.0
+            t_submit = time.perf_counter_ns() if observing else 0
             try:
                 handle = self.engine.decode_chunk_submit(
-                    None, None, None, None, None, n_steps=n, chain=True)
+                    None, None, None, None, None, n_steps=n, chain=True,
+                    phases={"build": 0.0} if observing else None)
             except Exception as e:
                 self._fail_after_decode_error(e)
                 return None
-            if observing:
-                self._stamp_host_gap("decode", gap_t0)
-            return _Inflight(handle, dict(self._slots), n)
+            return _Inflight(handle, dict(self._slots), n,
+                             self._stamp_dispatch("decode", t_submit, t_submit)
+                             if observing else None)
         S = self.engine.config.max_slots
+        t_build = time.perf_counter_ns() if observing else 0
         chunk_handles = [h for h in self._handles if isinstance(h, _Inflight)]
         tokens = np.zeros((S,), np.int32)
         positions = np.zeros((S,), np.int32)
@@ -1208,18 +1263,19 @@ class Scheduler:
                 mstates[slot] = st.req.grammar.global_state
             stop_tables[slot] = build_stop_row(eos_id, st.req.stop_token_ids)
             budgets[slot] = max(st.req.max_tokens - st.generated, 0)
-        gap_t0 = time.perf_counter() if observing else 0.0
+        t_submit = time.perf_counter_ns() if observing else 0
         try:
             handle = self.engine.decode_chunk_submit(
                 tokens, positions, active, temps, top_ps, n_steps=n,
                 seeds=seeds, use_seed=use_seed, chain=chain, mstates=mstates,
-                stop_tables=stop_tables, budgets=budgets)
+                stop_tables=stop_tables, budgets=budgets,
+                phases={"build": (t_submit - t_build) / 1e6} if observing else None)
         except Exception as e:
             self._fail_after_decode_error(e)
             return None
-        if observing:
-            self._stamp_host_gap("decode", gap_t0)
-        return _Inflight(handle, dict(self._slots), n)
+        return _Inflight(handle, dict(self._slots), n,
+                         self._stamp_dispatch("decode", t_submit, t_build)
+                         if observing else None)
 
     def _spec_step(self) -> None:
         """One speculative round: emits 1..K+1 tokens per live slot.
@@ -1258,9 +1314,14 @@ class Scheduler:
         t0 = time.perf_counter() if observing else 0.0
         ctx = sum(st.pos for st in self._slots.values()) if observing else 0
         before_emitted = self.spec_emitted
+        # A speculative round is synchronous: its dispatch is stamped as
+        # the engine call begins, its fetch as it returns.
+        spans = self._stamp_dispatch("spec", time.perf_counter_ns()) if observing else None
         out, logprobs, counts = self.engine.spec_round(
             catchup, catchup_len, catchup_pos, active, temps, top_ps,
             seeds=seeds, use_seed=use_seed, mstates=mstates)
+        if observing:
+            self._stamp_fetch()
         self.last_step_time = self.clock.now()
         self.steps_completed += 1
         self.spec_rounds += 1
@@ -1303,7 +1364,7 @@ class Scheduler:
         if observing:
             self._record_step("spec", t0, n_steps=1, batch=batch,
                               tokens=self.spec_emitted - before_emitted,
-                              context_tokens=ctx)
+                              context_tokens=ctx, spans=spans)
 
     def _spec_step_ngram(self) -> None:
         """One prompt-lookup round: host proposes K continuation tokens
@@ -1346,9 +1407,13 @@ class Scheduler:
         t0 = time.perf_counter() if observing else 0.0
         ctx = sum(st.pos for st in self._slots.values()) if observing else 0
         before_emitted = self.spec_emitted
+        spans = (self._stamp_dispatch("spec_ngram", time.perf_counter_ns())
+                 if observing else None)
         out, logprobs, counts = self.engine.spec_round_ngram(
             pending, positions, draft, active, temps, top_ps,
             seeds=seeds, use_seed=use_seed, mstates=mstates)
+        if observing:
+            self._stamp_fetch()
         self.last_step_time = self.clock.now()
         self.steps_completed += 1
         self.spec_rounds += 1
@@ -1380,7 +1445,7 @@ class Scheduler:
         if observing:
             self._record_step("spec_ngram", t0, n_steps=1, batch=batch,
                               tokens=self.spec_emitted - before_emitted,
-                              context_tokens=ctx)
+                              context_tokens=ctx, spans=spans)
 
     # Timeline failure damping (ISSUE 6 satellite): log the 1st and every
     # 50th consecutive failure, give up entirely after 8 in a row.
@@ -1394,27 +1459,51 @@ class Scheduler:
         context-token summing."""
         return self.timeline is not None or self.accounting is not None
 
-    def _stamp_host_gap(self, kind: str, dispatch_t0: float) -> None:
-        """Record one host gap (ISSUE 14 satellite): wall time from the
-        end of the last device interaction to this chunk's dispatch —
-        what the device would have idled if the pipeline were depth 1.
-        Feeds the engine.host_gap_ms histogram per dispatch; the latest
-        gap also rides the next decode StepTimeline record so
-        /debug/roofline can report p50/p99 per step kind."""
-        now = time.perf_counter()
+    def _stamp_dispatch(self, kind: str, dispatch_t0: int, submit_t0: int | None = None) -> dict:
+        """One dispatch just returned (ISSUE 14 satellite, generalised by
+        ISSUE 24 to every kind). Returns what its step record carries:
+        ``host_gap_ms``, wall time from the end of the last device
+        interaction to ``dispatch_t0``, the moment the engine call began —
+        what the device would have idled if the pipeline were depth 1
+        (also fed to the engine.host_gap_ms histogram);
+        ``inflight_at_dispatch``, the programs dispatched and not yet
+        fetched before this one; ``starved_ms``, when that was none and
+        work was pending, the time since it fell to none — the device had
+        nothing queued and the scheduler had work: the host starved it;
+        and, for a dispatch whose record is written later, ``submit_ts``
+        (``submit_t0`` on the span clock). Times are perf_counter_ns."""
+        now = time.perf_counter_ns()
+        inflight = len(self._handles)
+        spans: dict[str, Any] = {"inflight_at_dispatch": inflight, "starved_ms": 0.0}
+        if inflight == 0 and self._starve_t0 is not None:
+            spans["starved_ms"] = round((now - self._starve_t0) / 1e6, 4)
+        self._starve_t0 = None
         if self._dev_touch is not None:
-            gap_ms = max(dispatch_t0 - self._dev_touch, 0.0) * 1e3
-            self._pending_host_gap_ms = gap_ms
+            gap_ms = max(dispatch_t0 - self._dev_touch, 0) / 1e6
+            spans["host_gap_ms"] = round(gap_ms, 4)
             if self.timeline is not None:
                 try:
                     self.timeline.record_host_gap(kind, gap_ms)
                 except Exception:
                     pass
         self._dev_touch = now
+        if submit_t0 is not None:
+            spans["submit_ts"] = span_clock_ns(submit_t0) / 1e9
+        return spans
+
+    def _stamp_fetch(self) -> None:
+        """One fetch just materialized: the host-gap clock restarts, and
+        if nothing else is in flight while work is pending, so does the
+        starvation clock (a fetched handle has already left _handles)."""
+        now = time.perf_counter_ns()
+        self._dev_touch = now
+        if not self._handles and (self._slots or self._waiting or self._admitting):
+            self._starve_t0 = now
 
     def _record_step(self, kind: str, t0: float, *, n_steps: int, batch: int,
                      tokens: int, work_tokens: int = 0, context_tokens: int = 0,
-                     sq_tokens: int = 0, pair_tokens: int = 0) -> None:
+                     sq_tokens: int = 0, pair_tokens: int = 0,
+                     spans: dict | None = None) -> None:
         """One decode-timeline record (ISSUE 4): duration covers fetch +
         host-side emission — the full per-step cost a request observes.
         kv_utilization/queue_depth reads are GIL-atomic, lock-free. With
@@ -1457,12 +1546,15 @@ class Scheduler:
                         "kind", kind, "recompiles", delta,
                         "step_ms", round(duration * 1e3, 1))
             if self.timeline is not None:
-                gap = self._pending_host_gap_ms if kind == "decode" else None
-                self._pending_host_gap_ms = None
+                spans = {**(spans or {}), "context_tokens": context_tokens}
+                if spans.get("phases"):
+                    spans["phases"] = {k: round(v, 4) for k, v in spans["phases"].items()}
+                else:
+                    spans.pop("phases", None)
                 self.timeline.record(
                     kind, duration, n_steps=n_steps, batch=batch,
                     tokens=tokens, kv_utilization=self.engine.kv_utilization(),
-                    queue_depth=self.queue_depth, cost=cost, host_gap_ms=gap)
+                    queue_depth=self.queue_depth, cost=cost, spans=spans)
             self._timeline_failures = 0
         except Exception as e:
             self._timeline_failures += 1
@@ -1501,6 +1593,10 @@ class Scheduler:
         self._normal_steps += inf.n_steps  # engine steps, for the spec probe cadence
         observing = self._observing
         t0 = time.perf_counter() if observing else 0.0
+        # The dict the submit stamped (build, lock_wait, upload_dispatch);
+        # the fetch and the emit below add theirs. None when the chunk was
+        # submitted with no observer attached.
+        phases = getattr(inf.handle, "phases", None) if observing else None
         try:
             toks, logprobs = self.engine.decode_chunk_fetch(inf.handle)
         except Exception as e:
@@ -1516,56 +1612,58 @@ class Scheduler:
         if observing:
             # Fetch N just completed: the clock for "host time between
             # fetching chunk N and chunk N+1's dispatch" starts here.
-            self._dev_touch = time.perf_counter()
+            self._stamp_fetch()
 
         ctx = sum(s.pos for s in inf.states.values()) if observing else 0
         emitted = 0
         overrun = 0
-        for slot, snap_st in inf.states.items():
-            st = self._slots.get(slot)
-            if st is not snap_st:
-                # Finished, failed, or re-admitted mid-flight: every row
-                # this chunk computed for the slot served a stream that
-                # already ended (bounded wasted work by design — now
-                # *attributed*, ISSUE 6). If the finish was one the
-                # DEVICE also detected (ISSUE 14), the early-exit carry
-                # froze the row before this chunk sampled anything for
-                # it — nothing was wasted, so nothing is billed.
-                if not snap_st.device_stopped:
-                    overrun += toks.shape[0]
-                continue
-            slot_emitted = emitted
-            for j in range(toks.shape[0]):
-                st.pos += 1
-                st.pending_token = int(toks[j, slot])
-                st.pending_logprob = float(logprobs[j, slot])
-                st.generated += 1
-                emitted += 1
-                if self.engine.spec_ngram:
-                    # Keep prompt-lookup history fresh while adaptive
-                    # speculation is parked in the normal loop, so a
-                    # probe's proposals see the full stream.
-                    st.history.append(st.pending_token)
-                finished, reason = self._emit(st, st.pending_token, st.pending_logprob)
-                if finished:
-                    del self._slots[slot]
-                    self._release_guarded(slot, reason, frozen=st.device_stopped)
-                    if not st.device_stopped:
-                        # Device-detected finishes froze the row at this
-                        # very step (ISSUE 14): the trailing block is
-                        # repeats, not computed tokens — zero overrun.
-                        overrun += toks.shape[0] - (j + 1)
-                    break
-            if emitted > slot_emitted:
-                # One flush per request per CHUNK: a pipelined
-                # decode_chunk's whole token block reaches the event
-                # loop as one wakeup instead of n_steps of them.
-                self._flush_emits(st.req)
+        with phase_span(phases, "emit", "sched.emit"):
+            for slot, snap_st in inf.states.items():
+                st = self._slots.get(slot)
+                if st is not snap_st:
+                    # Finished, failed, or re-admitted mid-flight: every row
+                    # this chunk computed for the slot served a stream that
+                    # already ended (bounded wasted work by design — now
+                    # *attributed*, ISSUE 6). If the finish was one the
+                    # DEVICE also detected (ISSUE 14), the early-exit carry
+                    # froze the row before this chunk sampled anything for
+                    # it — nothing was wasted, so nothing is billed.
+                    if not snap_st.device_stopped:
+                        overrun += toks.shape[0]
+                    continue
+                slot_emitted = emitted
+                for j in range(toks.shape[0]):
+                    st.pos += 1
+                    st.pending_token = int(toks[j, slot])
+                    st.pending_logprob = float(logprobs[j, slot])
+                    st.generated += 1
+                    emitted += 1
+                    if self.engine.spec_ngram:
+                        # Keep prompt-lookup history fresh while adaptive
+                        # speculation is parked in the normal loop, so a
+                        # probe's proposals see the full stream.
+                        st.history.append(st.pending_token)
+                    finished, reason = self._emit(st, st.pending_token, st.pending_logprob)
+                    if finished:
+                        del self._slots[slot]
+                        self._release_guarded(slot, reason, frozen=st.device_stopped)
+                        if not st.device_stopped:
+                            # Device-detected finishes froze the row at this
+                            # very step (ISSUE 14): the trailing block is
+                            # repeats, not computed tokens — zero overrun.
+                            overrun += toks.shape[0] - (j + 1)
+                        break
+                if emitted > slot_emitted:
+                    # One flush per request per CHUNK: a pipelined
+                    # decode_chunk's whole token block reaches the event
+                    # loop as one wakeup instead of n_steps of them.
+                    self._flush_emits(st.req)
         self._wasted("chunk_overrun", overrun)
         if observing:
             self._record_step("decode", t0, n_steps=inf.n_steps,
                               batch=len(inf.states), tokens=emitted,
-                              context_tokens=ctx)
+                              context_tokens=ctx,
+                              spans={**(inf.submit or {}), "phases": phases})
 
     def _release_guarded(self, slot: int, reason: str | None,
                          frozen: bool = False) -> None:

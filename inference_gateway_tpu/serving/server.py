@@ -228,6 +228,10 @@ class SidecarServer:
         self.slow_log = slow_log
         self.profiler = profiler
         self.watchdog = watchdog
+        # Set by serve() (start-up spans) and by /debug/jax_trace (the
+        # last capture's place on the span clock); both ride /debug/status.
+        self.startup: dict[str, float] | None = None
+        self.last_jax_trace: dict[str, Any] | None = None
         # Compute-efficiency accounting (ISSUE 6): price every engine
         # step against the chip roofline (TELEMETRY_ACCOUNTING_ENABLE;
         # on by default — the analytic side must move every round, not
@@ -1023,6 +1027,14 @@ class SidecarServer:
             status["engine_watchdog"] = self.engine_watchdog.stats()
         if self.timeline is not None:
             status["timeline"] = self.timeline.stats()
+        if self.startup is not None:
+            # Where the start went (ISSUE 24), seconds by stage; what the
+            # stages leave of total_s is config, tokenizer and mesh set-up.
+            status["startup"] = self.startup
+        if self.last_jax_trace is not None:
+            # The last /debug/jax_trace capture on the span clock, so a
+            # reader can lay timeline records over the device trace.
+            status["jax_trace"] = self.last_jax_trace
         if self.accounting is not None:
             status["compute_efficiency"] = self.accounting.snapshot()
         if self.observatory is not None:
@@ -1047,16 +1059,24 @@ class SidecarServer:
         return Response.text(body, status=status, content_type=ctype)
 
     async def debug_jax_trace(self, req: Request) -> Response:
-        """GET /debug/jax_trace?seconds=N&dir=PATH — guarded
-        ``jax.profiler.trace`` device capture; a no-op (with the reason)
-        off-TPU."""
+        """GET /debug/jax_trace?seconds=N&dir=PATH&python_tracer=0|1 —
+        guarded ``jax.profiler.trace`` device capture; a no-op (with the
+        reason) off-TPU. ``python_tracer=0`` leaves the profiler's Python
+        tracer off: the scheduler thread's annotations still say what the
+        host was doing, without a frame event per call. The reply (and
+        /debug/status ``jax_trace``) places the capture on the span clock
+        the timeline's ``start_ts`` / ``ts`` are on."""
         try:
             seconds = float(req.query_get("seconds", "2") or 2.0)
         except ValueError:
             return Response.json({"error": "seconds must be a number"}, status=400)
         log_dir = req.query_get("dir", "/tmp/jax-trace")
+        python_tracer = req.query_get("python_tracer", "1") != "0"
         result = await asyncio.get_running_loop().run_in_executor(
-            None, jax_trace_capture, log_dir, seconds)
+            None, jax_trace_capture, log_dir, seconds, python_tracer)
+        if result.get("captured"):
+            self.last_jax_trace = {k: result[k] for k in (
+                "start_epoch_ns", "stop_epoch_ns", "capture_epoch_ns", "python_tracer")}
         return Response.json(result, status=200 if result.get("captured") else 409)
 
     # ------------------------------------------------------------------
@@ -1221,6 +1241,9 @@ class SidecarServer:
         return out
 
     async def chat_completions(self, req: Request) -> Response:
+        # The phase clock's first stamp (ISSUE 24): before the body is
+        # parsed, templated and tokenised.
+        recv_ns = time.time_ns()
         try:
             body = req.json()
         except (ValueError, UnicodeDecodeError):
@@ -1239,6 +1262,7 @@ class SidecarServer:
                 None, self._prepare, body)
         except _BadRequest as bad:
             return Response.json(bad.payload, status=400)
+        gen.phase_ns["recv"] = recv_ns
         if len(gen.prompt_ids) >= self.engine.context_window():
             return Response.json({"error": "prompt exceeds context window"}, status=400)
         # Oversized-prompt fast-fail (ISSUE 7 satellite): in modes with
@@ -1451,6 +1475,7 @@ class SidecarServer:
         ph = gen.phase_ns
         submit, admit = ph.get("submit"), ph.get("admit")
         first, finish = ph.get("first_token"), ph.get("finish")
+        recv, first_write = ph.get("recv"), ph.get("first_write")
 
         if submit is not None and admit is not None:
             self.record_queue_wait(max(admit - submit, 0) / 1e9)
@@ -1471,7 +1496,8 @@ class SidecarServer:
         if self.tracer.enabled and submit is not None:
             end_ns = finish or ph.get("first_token") or submit
             root = self.tracer.start_span("tpu_sidecar.chat_completions",
-                                          traceparent=traceparent, start_ns=submit)
+                                          traceparent=traceparent,
+                                          start_ns=submit if recv is None else recv)
             trace_id = root.trace_id
             try:
                 root.set_attribute("gen_ai.request.model", meta["model"])
@@ -1479,8 +1505,9 @@ class SidecarServer:
                 root.set_attribute("request.id", gen.request_id or meta["id"])
                 root.set_attribute("gen_ai.usage.input_tokens", meta["prompt_tokens"])
                 root.set_attribute("gen_ai.usage.output_tokens", completion_tokens)
-                phases = (("queue.wait", submit, admit), ("prefill", admit, first),
-                          ("decode", first, finish))
+                phases = (("http.prepare", recv, submit), ("queue.wait", submit, admit),
+                          ("prefill", admit, first), ("decode", first, finish),
+                          ("first_frame", first, first_write))
                 for name, t0, t1 in phases:
                     if t0 is None or t1 is None:
                         continue
@@ -1511,6 +1538,12 @@ class SidecarServer:
                 "queue_wait_ms": to_ms(submit, admit),
                 "prefill_ms": to_ms(admit, first),
                 "decode_ms": to_ms(first, finish),
+                # The sidecar's own HTTP edges (ISSUE 24): parse, template
+                # and tokenise before the scheduler's clock starts, and
+                # the hop from the scheduler's first token to the first
+                # content frame handed to the transport.
+                "recv_to_submit_ms": to_ms(recv, submit),
+                "first_token_to_write_ms": to_ms(first, first_write),
             }
             if meta.get("resume_tokens"):
                 # Continuation requests (ISSUE 9) are flagged so billing
@@ -1576,6 +1609,10 @@ class SidecarServer:
         suffix = b'},"finish_reason":null}]}\n\n'
 
         def content_frame(text: str) -> bytes:
+            if "first_write" not in gen.phase_ns:
+                # The first content frame is about to be handed to the
+                # transport: the phase clock's last edge (ISSUE 24).
+                gen.phase_ns["first_write"] = time.time_ns()
             return prefix + _json_escape(text).encode() + suffix
 
         # SERVING_EMIT_COALESCE_MS: merge every token produced within the
@@ -1712,7 +1749,8 @@ class SidecarServer:
 
 
 async def serve(config: EngineConfig, host: str = "0.0.0.0", port: int = 8000,
-                served_model_name: str | None = None, metrics_push_url: str | None = None) -> None:
+                served_model_name: str | None = None, metrics_push_url: str | None = None,
+                started_ns: int | None = None) -> None:
     """Run the sidecar until cancelled (entry point for __main__).
 
     The standalone sidecar honors the gateway's TELEMETRY_* env surface:
@@ -1721,8 +1759,22 @@ async def serve(config: EngineConfig, host: str = "0.0.0.0", port: int = 8000,
     TELEMETRY_ACCESS_LOG the per-request wide-event JSON line, and the
     ISSUE 4 introspection knobs — TELEMETRY_PROFILING_* (sampling
     profiler, event-loop watchdog, decode-step timeline) and
-    TELEMETRY_SLOW_REQUEST_* (forensics thresholds)."""
+    TELEMETRY_SLOW_REQUEST_* (forensics thresholds).
+
+    ``started_ns`` is ``time.perf_counter_ns()`` at the process's first
+    instruction (``serving/__main__.py`` stamps it): the start-up spans
+    served under /debug/status ``startup`` count from there."""
     import os
+
+    t_enter = time.perf_counter_ns()  # imports done
+    if started_ns is None:
+        started_ns = t_enter
+    startup: dict[str, float] = {"import_s": (t_enter - started_ns) / 1e9}
+
+    def lap(key: str, since: float) -> float:
+        now = time.perf_counter()
+        startup[key] = now - since
+        return now
 
     from inference_gateway_tpu.config import (
         ServerConfig,
@@ -1763,6 +1815,8 @@ async def serve(config: EngineConfig, host: str = "0.0.0.0", port: int = 8000,
     # instead of compiling them.
     compile_cache = configure_compile_cache()
     engine = Engine(config)
+    startup.update(engine.startup)
+    t_stage = time.perf_counter()
     # Device observatory (ISSUE 19): attach BEFORE warmup so every boot
     # compile lands in the ledger with its cost analysis — warmup()
     # brackets itself, so these classify as warmup, not recompiles.
@@ -1777,10 +1831,13 @@ async def serve(config: EngineConfig, host: str = "0.0.0.0", port: int = 8000,
         except Exception as e:
             logger.warn("device observatory disabled", "error", str(e))
             observatory = None
+    t_stage = lap("observatory_s", t_stage)
     warm = engine.warmup()
+    t_stage = lap("warmup_s", t_stage)
     logger.info("engine warm", "compile_seconds", round(warm, 1), "model", config.model,
                 "attention_path", engine.attention_path, "compile_cache", compile_cache,
-                "accelerator", engine.device)
+                "accelerator", engine.device,
+                "startup", {k: round(v, 3) for k, v in startup.items()})
     tracer = None
     if tcfg.tracing_enable:
         tracer = Tracer("tpu-sidecar", enabled=True, logger=logger,
@@ -1846,7 +1903,11 @@ async def serve(config: EngineConfig, host: str = "0.0.0.0", port: int = 8000,
                            migrate_streams=svcfg.migrate_streams,
                            admin_enabled=svcfg.admin_enabled)
     bound = await server.start(host, port)
-    logger.info("tpu sidecar listening", "host", host, "port", bound)
+    lap("listen_s", t_stage)
+    startup["total_s"] = (time.perf_counter_ns() - started_ns) / 1e9
+    server.startup = {k: round(v, 4) for k, v in startup.items()}
+    logger.info("tpu sidecar listening", "host", host, "port", bound,
+                "listen_s", server.startup["listen_s"], "total_s", server.startup["total_s"])
     try:
         await asyncio.Event().wait()
     finally:

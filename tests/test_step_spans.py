@@ -1,0 +1,289 @@
+"""Step spans inside the scheduler and engine (ISSUE 24), on the CPU with the
+tiny preset: what every timeline record carries (start on the span clock,
+host phases, starvation), the request's two new phase stamps at the sidecar's
+HTTP edges, the start-up spans, and the capture endpoint's new parameter.
+No number here is a speed: the assertions are about order, sign and sums.
+"""
+
+import asyncio
+import json
+import socket
+import time
+
+import pytest
+
+from inference_gateway_tpu.main import build_gateway
+from inference_gateway_tpu.netio.client import HTTPClient, HTTPClientError
+from inference_gateway_tpu.netio.server import Headers
+from inference_gateway_tpu.otel.access_log import AccessLog
+from inference_gateway_tpu.otel.profiling import StepTimeline, phase_span, span_clock_ns
+from inference_gateway_tpu.serving.engine import Engine, EngineConfig
+from inference_gateway_tpu.serving.server import SidecarServer, serve
+
+TRACE_ID = "0af7651916cd43dd8448eb211c80319c"
+TRACEPARENT = f"00-{TRACE_ID}-b7ad6b7169203331-01"
+MIXED_PHASES = ("build", "lock_wait", "pages", "assemble", "upload_dispatch", "fetch_wait", "emit")
+STARTUP_KEYS = ("import_s", "params_s", "quantize_s", "kv_pool_s", "structured_s",
+                "observatory_s", "warmup_s", "listen_s", "total_s")
+
+
+def _engine_cfg() -> EngineConfig:
+    return EngineConfig(model="test-tiny", attention="paged", page_size=16, max_slots=4,
+                        max_seq_len=256, dtype="float32", use_mesh=False, mixed_step=True,
+                        decode_chunk=4)
+
+
+@pytest.fixture(scope="module")
+def sidecar(aloop):
+    engine = Engine(_engine_cfg())
+    engine.warmup()
+    access_log = AccessLog(service="tpu-sidecar", tail_size=64)
+    server = SidecarServer(engine, served_model_name="test-tiny", access_log=access_log)
+    port = aloop.run(server.start("127.0.0.1", 0))
+    yield server, port, access_log
+    aloop.run(server.shutdown())
+
+
+async def _chat(port: int, max_tokens: int = 14, stream: bool = True, path: str = "",
+                model: str = "test-tiny") -> bytes:
+    headers = Headers()
+    headers.set("Content-Type", "application/json")
+    headers.set("traceparent", TRACEPARENT)
+    body = {"model": model, "stream": stream, "max_tokens": max_tokens,
+            "messages": [{"role": "user", "content": "count to twenty, slowly"}],
+            # ASCII only, whatever the seed's weights prefer: every token is a visible frame.
+            "logit_bias": {str(i): 100 for i in range(65, 91)}}
+    resp = await HTTPClient().post(f"http://127.0.0.1:{port}{path}/v1/chat/completions",
+                                   json.dumps(body).encode(), headers=headers, stream=stream)
+    assert resp.status == 200
+    if not stream:
+        return resp.body
+    return b"".join([block async for block in resp.iter_raw()])
+
+
+async def _records(server: SidecarServer, port: int, n_requests: int = 2) -> list[dict]:
+    """The timeline records of ``n_requests`` chats sent one after another
+    with an idle spell before each."""
+    before = server.timeline.stats()["records"]
+    for _ in range(n_requests):
+        await asyncio.sleep(0.4)
+        await _chat(port)
+    await asyncio.sleep(0.1)  # tail chunks of the last stream
+    return server.timeline.tail(server.timeline.stats()["records"] - before)
+
+
+def _adds_up(pairs: list[tuple[float, float]]) -> bool:
+    """(sum of phases, duration_ms) pairs: no sum passes its duration, and what a
+    record leaves unexplained is under 10% of it. On the CPU the tiny model's
+    records last a millisecond and share the GIL with the event loop, so the
+    floor is 1 ms and one record in ten may miss."""
+    assert all(total <= duration + 0.01 for total, duration in pairs), pairs
+    near = [duration - total <= max(0.1 * duration, 1.0) for total, duration in pairs]
+    return sum(near) >= 0.9 * len(near)
+
+
+async def test_every_record_starts_before_it_ends_and_phases_are_not_negative(sidecar):
+    server, port, _ = sidecar
+    recs = [r for r in await _records(server, port) if r["kind"] in ("mixed", "decode")]
+    assert {r["kind"] for r in recs} == {"mixed", "decode"}
+    for r in recs:
+        assert r["start_ts"] <= r["ts"]
+        assert r["ts"] - r["start_ts"] == pytest.approx(r["duration_ms"] / 1e3, abs=2e-6)
+        assert all(v >= 0 for v in r["phases"].values()), r
+        assert r["starved_ms"] >= 0 and r["inflight_at_dispatch"] >= 0
+        assert r["context_tokens"] > 0
+        # What the benchmark's readers take from a record is where it was.
+        assert {"kind", "duration_ms", "steps", "batch", "tokens", "kv_utilization"} <= set(r)
+
+
+async def test_a_mixed_records_phases_add_up_to_its_duration(sidecar):
+    server, port, _ = sidecar
+    mixed = [r for r in await _records(server, port) if r["kind"] == "mixed"]
+    assert mixed
+    assert all(set(r["phases"]) == set(MIXED_PHASES) for r in mixed)
+    assert _adds_up([(sum(r["phases"].values()), r["duration_ms"]) for r in mixed]), mixed
+    # The admission's first step carries what came before it.
+    assert all(set(r["prep"]) == {"ms", "register_ms", "drain_ms"} for r in mixed if "prep" in r)
+    first = next(r for r in mixed if "prep" in r)
+    assert first["prep"]["ms"] >= first["prep"]["register_ms"] + first["prep"]["drain_ms"] > 0
+
+
+async def test_a_decode_records_fetch_and_emit_add_up_and_its_submit_came_earlier(sidecar):
+    server, port, _ = sidecar
+    decode = [r for r in await _records(server, port) if r["kind"] == "decode"]
+    assert decode
+    for r in decode:
+        assert {"build", "upload_dispatch", "fetch_wait", "emit"} <= set(r["phases"])
+        assert r["submit_ts"] < r["start_ts"]
+    assert _adds_up([(r["phases"]["fetch_wait"] + r["phases"]["emit"], r["duration_ms"])
+                     for r in decode]), decode
+    assert any(r["phases"]["build"] > 0 for r in decode)   # a fresh submit assembles
+    assert any(r["phases"]["build"] == 0 for r in decode)  # a chained one does not
+
+
+async def test_starvation_is_zero_on_a_chained_submit_and_positive_after_an_idle_spell(sidecar):
+    server, port, _ = sidecar
+    recs = await _records(server, port, n_requests=2)
+    chained = [r for r in recs if r["kind"] == "decode" and r["inflight_at_dispatch"] > 0]
+    assert chained and all(r["starved_ms"] == 0 for r in chained)
+    firsts = [r for r in recs if r["kind"] == "mixed" and "prep" in r]
+    assert len(firsts) == 2
+    for r in firsts:
+        assert r["inflight_at_dispatch"] == 0 and r["starved_ms"] > 0
+    # The fresh decode submit after a mixed step waited for its emit: starved too.
+    fresh = [r for r in recs if r["kind"] == "decode" and r["phases"]["build"] > 0]
+    assert fresh and all(r["starved_ms"] > 0 for r in fresh)
+
+
+async def test_an_idle_scheduler_accrues_no_starvation(sidecar):
+    server, port, _ = sidecar
+    await _chat(port, max_tokens=4)
+    await asyncio.sleep(0.1)
+    before = server.timeline.stats()["records"]
+    await asyncio.sleep(1.0)  # nothing to do: not starvation, and no host gap
+    await _chat(port, max_tokens=4)
+    await asyncio.sleep(0.1)
+    recs = server.timeline.tail(server.timeline.stats()["records"] - before)
+    first = recs[0]
+    assert first["kind"] == "mixed" and 0 < first["starved_ms"] < 500
+    assert "host_gap_ms" not in first
+    assert sum(r["starved_ms"] for r in recs) < 500
+
+
+async def test_request_stamps_are_ordered_and_the_access_log_gains_two_fields(sidecar):
+    server, port, access_log = sidecar
+    seen = []
+    submit = server.scheduler.submit
+    server.scheduler.submit = lambda gen: (seen.append(gen), submit(gen))[1]
+    try:
+        raw = await _chat(port)
+    finally:
+        server.scheduler.submit = submit
+    assert b"[DONE]" in raw
+    ph = seen[0].phase_ns
+    assert ph["recv"] <= ph["submit"] <= ph["admit"] <= ph["first_token"] <= ph["first_write"]
+    for _ in range(200):
+        line = next((e for e in reversed(access_log.tail) if e.get("trace_id") == TRACE_ID), None)
+        if line is not None:
+            break
+        await asyncio.sleep(0.01)
+    assert line["recv_to_submit_ms"] == round((ph["submit"] - ph["recv"]) / 1e6, 3)
+    assert line["first_token_to_write_ms"] == round((ph["first_write"] - ph["first_token"]) / 1e6, 3)
+    for key in ("queue_wait_ms", "prefill_ms", "decode_ms", "input_tokens", "output_tokens"):
+        assert line[key] is not None  # nothing else on the line changed
+
+
+async def test_a_detached_observer_stamps_nothing(sidecar):
+    server, port, _ = sidecar
+    sched, engine = server.scheduler, server.engine
+    handles = []
+    mixed, chunk = engine.mixed_step_submit, engine.decode_chunk_submit
+    engine.mixed_step_submit = lambda *a, **k: (handles.append(mixed(*a, **k)), handles[-1])[1]
+    engine.decode_chunk_submit = lambda *a, **k: (handles.append(chunk(*a, **k)), handles[-1])[1]
+    timeline, accounting = sched.timeline, sched.accounting
+    records = timeline.stats()["records"]
+    await asyncio.sleep(0.3)  # let the loop go idle with the observers still on
+    sched.timeline = sched.accounting = None
+    sched._dev_touch = sched._starve_t0 = None
+    try:
+        await _chat(port)
+        await asyncio.sleep(0.1)
+        assert not sched._observing
+        assert len(handles) >= 3 and all(h.phases is None for h in handles)
+        assert sched._dev_touch is None and sched._starve_t0 is None
+        assert timeline.stats()["records"] == records
+    finally:
+        engine.mixed_step_submit, engine.decode_chunk_submit = mixed, chunk
+        sched.timeline, sched.accounting = timeline, accounting
+
+
+async def test_jax_trace_without_the_python_tracer_is_still_a_409_off_tpu(sidecar):
+    server, port, _ = sidecar
+    client = HTTPClient()
+    for query in ("seconds=0.1&python_tracer=0", "seconds=0.1&python_tracer=1", "seconds=0.1"):
+        resp = await client.get(f"http://127.0.0.1:{port}/debug/jax_trace?{query}")
+        assert resp.status == 409
+        assert resp.json() == {"captured": False, "reason": "device platform 'cpu' is not tpu"}
+    status = (await client.get(f"http://127.0.0.1:{port}/debug/status")).json()
+    assert "jax_trace" not in status  # nothing was captured
+
+
+async def test_gateway_relays_an_inbound_traceparent_with_its_tracing_off(sidecar, aloop):
+    server, port, access_log = sidecar
+    gw = build_gateway(env={"TPU_API_URL": f"http://127.0.0.1:{port}/v1", "SERVER_PORT": "0",
+                            "OLLAMA_API_URL": "http://127.0.0.1:1/v1",
+                            "LLAMACPP_API_URL": "http://127.0.0.1:1/v1"})
+    assert gw.otel is None or not gw.cfg.telemetry.tracing_enable
+    gw_port = await gw.start("127.0.0.1", 0)
+    try:
+        before = len([e for e in access_log.tail if e.get("trace_id") == TRACE_ID])
+        await _chat(gw_port, max_tokens=4, model="tpu/test-tiny")
+        for _ in range(200):
+            lines = [e for e in access_log.tail if e.get("trace_id") == TRACE_ID]
+            if len(lines) > before:
+                break
+            await asyncio.sleep(0.01)
+        assert len(lines) == before + 1, "the sidecar's line does not carry the client's trace id"
+    finally:
+        await gw.shutdown()
+
+
+async def test_startup_spans_cover_the_start_and_fit_inside_the_time_to_healthy():
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    t0 = time.perf_counter_ns()
+    task = asyncio.ensure_future(serve(
+        EngineConfig(model="test-tiny", max_slots=2, max_seq_len=64, dtype="float32", use_mesh=False),
+        host="127.0.0.1", port=port, started_ns=t0))
+    client = HTTPClient()
+    try:
+        for _ in range(600):
+            assert not task.done(), task.exception()
+            try:
+                if (await client.get(f"http://127.0.0.1:{port}/health")).status == 200:
+                    break
+            except HTTPClientError:
+                pass  # not listening yet
+            await asyncio.sleep(0.05)
+        healthy_s = (time.perf_counter_ns() - t0) / 1e9
+        startup = (await client.get(f"http://127.0.0.1:{port}/debug/status")).json()["startup"]
+    finally:
+        task.cancel()
+        await asyncio.gather(task, return_exceptions=True)
+    assert set(STARTUP_KEYS) <= set(startup)
+    assert all(v >= 0 for v in startup.values())
+    stages = sum(v for k, v in startup.items() if k != "total_s")
+    assert stages <= startup["total_s"] + 1e-3 <= healthy_s + 2e-3
+    assert startup["params_s"] > 0 and startup["warmup_s"] > 0
+
+
+def test_the_span_clock_is_epoch_time_that_never_runs_backwards():
+    a = span_clock_ns()
+    b = span_clock_ns()
+    assert a <= b
+    assert abs(a - time.time_ns()) < 5e9  # epoch, within NTP's reach of the anchor
+    assert span_clock_ns(time.perf_counter_ns()) - span_clock_ns() < 1e8
+
+
+def test_phase_span_adds_up_under_one_key_and_none_stamps_nothing():
+    phases: dict = {}
+    for _ in range(2):
+        with phase_span(phases, "emit", "sched.emit"):
+            time.sleep(0.002)
+    assert list(phases) == ["emit"] and phases["emit"] >= 4.0
+    with phase_span(None, "emit", "sched.emit") as nothing:
+        assert nothing is None
+    assert phase_span(None, "a", "b") is phase_span(None, "c", "d")  # one shared no-op
+
+
+def test_a_bare_record_keeps_every_field_it_had_and_gains_its_start():
+    timeline = StepTimeline(8)
+    timeline.record("decode", 0.012, n_steps=8, batch=3, tokens=24, kv_utilization=0.25)
+    timeline.record("mixed", 0.05, spans={"phases": {"build": 1.0}, "starved_ms": 2.5})
+    plain, spanned = timeline.tail()
+    assert {"ts", "kind", "duration_ms", "steps", "batch", "tokens", "kv_utilization",
+            "queue_depth"} <= set(plain)
+    assert plain["ts"] - plain["start_ts"] == pytest.approx(0.012, abs=1e-6)
+    assert "phases" not in plain and "host_gap_ms" not in plain
+    assert spanned["phases"] == {"build": 1.0} and spanned["starved_ms"] == 2.5
