@@ -41,6 +41,16 @@ Two scopes:
    loop is exactly the per-chunk host work the desynchronized decode
    tentpole removed; vectorized reads of the persistent host mirror
    are fine, loops are not).
+
+4. **Packed-submit scope** (ISSUE 25): the two host-stepped submits —
+   ``Engine.mixed_step_submit`` and ``Engine._fresh_submit_ee_locked``
+   — make ONE host→device transfer each: every descriptor rides a
+   packed buffer and the step key is folded inside the program that
+   unpacks it. A dozen small uploads and an eager ``fold_in`` with the device
+   empty cost 5-9 ms a submit on a v5e (PERF.md §6, PR 25). The scope
+   allows at most two upload calls (``jnp.asarray`` / ``jnp.array`` /
+   ``jax.device_put``) in the body and bans ``jax.random.*`` and the
+   engine's eager ``_next_rng()`` outright.
 """
 
 from __future__ import annotations
@@ -83,7 +93,9 @@ _NP_SYNC = {"np.asarray", "np.array", "numpy.asarray", "numpy.array"}
 # chained submit. decode_chunk_submit additionally gets its `if chain:`
 # branches scanned wherever it is defined (relpath suffix -> names).
 CHAIN_STEADY_SCOPES = {
-    "serving/engine.py": {"_chain_submit_locked"},
+    # _dispatch_chunk_ee_locked is the chained submit's dispatch half,
+    # shared with the fresh submit (ISSUE 25).
+    "serving/engine.py": {"_chain_submit_locked", "_dispatch_chunk_ee_locked"},
 }
 # Uploads are banned in the chain-steady scope too: a chained submit
 # that jnp.asarray's host data re-introduces the per-chunk h2d the
@@ -91,6 +103,15 @@ CHAIN_STEADY_SCOPES = {
 # _reserve_chain_horizon, outside this scope).
 _CHAIN_UPLOADS = {"jnp.asarray", "jnp.array", "jax.numpy.asarray",
                   "jax.numpy.array"}
+
+
+# Packed-submit scope (ISSUE 25): relpath suffix -> host-stepped submit
+# functions that stage one packed buffer and dispatch one program.
+PACKED_SUBMIT_SCOPES = {
+    "serving/engine.py": {"mixed_step_submit", "_fresh_submit_ee_locked"},
+}
+PACKED_SUBMIT_MAX_UPLOADS = 2
+_UPLOADS = _CHAIN_UPLOADS | {"jax.device_put"}
 
 
 def _is_jit_decorated(fn: ast.AST) -> bool:
@@ -109,8 +130,10 @@ def _is_jit_decorated(fn: ast.AST) -> bool:
     return False
 
 
-def _submit_scope_names(mod: ParsedModule) -> set[str]:
-    for suffix, names in SUBMIT_SCOPES.items():
+def _scope_names(mod: ParsedModule, scopes: dict[str, set[str]]) -> set[str]:
+    """The function names a scope table (relpath suffix -> names) gives
+    this module."""
+    for suffix, names in scopes.items():
         if mod.path.endswith(suffix):
             return names
     return set()
@@ -145,13 +168,6 @@ def _scan(fn: ast.AST, mod: ParsedModule, out: list[Finding], *,
             flag(out, mod, CHECKER, node,
                  f"'{func.id}(...)' on a traced value {where} — a "
                  f"concretization error at trace time")
-
-
-def _chain_scope_names(mod: ParsedModule) -> set[str]:
-    for suffix, names in CHAIN_STEADY_SCOPES.items():
-        if mod.path.endswith(suffix):
-            return names
-    return set()
 
 
 def _is_chain_test(test: ast.AST) -> bool:
@@ -202,10 +218,35 @@ def _scan_chain_steady(nodes, mod: ParsedModule, out: list[Finding],
                      f"host mirror, never build arrays per chunk")
 
 
+def _scan_packed_submit(fn: ast.AST, mod: ParsedModule, out: list[Finding]) -> None:
+    """The ISSUE 25 rule set: at most PACKED_SUBMIT_MAX_UPLOADS upload
+    calls, and no eager key derivation (the key is drawn in the
+    program from a counter that rides the packed buffer)."""
+    where = "in a packed host-stepped submit (packed-submit scope)"
+    uploads = 0
+    for node in ast.walk(fn):
+        if not isinstance(node, ast.Call):
+            continue
+        d = dotted_name(node.func)
+        if d in _UPLOADS:
+            uploads += 1
+            if uploads > PACKED_SUBMIT_MAX_UPLOADS:
+                flag(out, mod, CHECKER, node,
+                     f"upload #{uploads} '{d}(...)' {where} — each small "
+                     f"upload is a transfer with the device waiting; write "
+                     f"the field into the packed buffer instead")
+        elif d is not None and (d.startswith("jax.random.") or d == "self._next_rng"):
+            flag(out, mod, CHECKER, node,
+                 f"eager key derivation '{d}(...)' {where} — a dispatch "
+                 f"of its own; fold the key inside the step program from "
+                 f"the counter in the packed buffer")
+
+
 def check(mod: ParsedModule) -> list[Finding]:
     out: list[Finding] = []
-    submit_names = _submit_scope_names(mod)
-    chain_names = _chain_scope_names(mod)
+    submit_names = _scope_names(mod, SUBMIT_SCOPES)
+    chain_names = _scope_names(mod, CHAIN_STEADY_SCOPES)
+    packed_names = _scope_names(mod, PACKED_SUBMIT_SCOPES)
     for fn in ast.walk(mod.tree):
         if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
             continue
@@ -232,6 +273,8 @@ def check(mod: ParsedModule) -> list[Finding]:
                         "(chain-steady scope)")
                     for top in node.body:
                         chain_covered.update(id(n) for n in ast.walk(top))
+        if fn.name in packed_names:
+            _scan_packed_submit(fn, mod, out)
         if fn.name in submit_names:
             _scan(fn, mod, out, jitted=False, exclude=chain_covered)
     return out

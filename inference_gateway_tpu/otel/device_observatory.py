@@ -73,6 +73,7 @@ JIT_ENTRY_POINTS: tuple[str, ...] = (
     "_decode_chunk_fn_paged",
     "_decode_chunk_fn_ee",
     "_decode_chunk_fn_paged_ee",
+    "_fresh_unpack_fn",
     "_mixed_step_fn",
     "_admit_scatter_fn",
     "_admit_scatter_fn_ee",
@@ -94,6 +95,7 @@ _KIND_PREFIXES: tuple[tuple[str, str], ...] = (
     ("draft_prefill", "spec"),
     ("admit_scatter", "admit"),
     ("mark_done", "admit"),
+    ("fresh_unpack", "admit"),
 )
 
 
@@ -336,8 +338,11 @@ class CompileLedger:
 class TransferAudit:
     """h2d/d2h transfer counters keyed by (direction, path).
 
-    Counts host arrays staged at the engine's submit/fetch seams, with
-    best-effort byte totals (sum of the staged host buffers' nbytes).
+    Counts the engine's submit/fetch staging events (``count``), the
+    host arrays they staged (``arrays``) and those arrays' summed nbytes
+    (``bytes``). ``arrays / count`` is how many transfers one submit
+    makes: 1 on the packed ``h2d/mixed`` and ``h2d/fresh`` paths
+    (ISSUE 25), where ``bytes`` is the packed buffer's true size.
     The load-bearing series is ``("h2d", "chain")``: the early-exit
     chained submit stages nothing, so the audit proves the PR 14
     invariant by *never recording there* — the series is pre-seeded to
@@ -351,18 +356,21 @@ class TransferAudit:
 
     def seed(self, direction: str, path: str) -> None:
         with self._lock:
-            self._counts.setdefault((direction, path), {"count": 0, "bytes": 0})
+            self._counts.setdefault((direction, path),
+                                    {"count": 0, "arrays": 0, "bytes": 0})
         if self.otel is not None:
             try:
                 self.otel.record_transfer(self.model, direction, path, 0, 0)
             except Exception:
                 pass
 
-    def record(self, direction: str, path: str, nbytes: int = 0) -> None:
+    def record(self, direction: str, path: str, nbytes: int = 0,
+               arrays: int = 1) -> None:
         with self._lock:
             slot = self._counts.setdefault((direction, path),
-                                           {"count": 0, "bytes": 0})
+                                           {"count": 0, "arrays": 0, "bytes": 0})
             slot["count"] += 1
+            slot["arrays"] += int(arrays)
             slot["bytes"] += int(nbytes)
         if self.otel is not None:
             try:
@@ -431,8 +439,9 @@ class DeviceObservatory:
         self.ledger.mark_warmup_complete()
 
     # -- transfer seam (called from the engine hot path) ---------------
-    def record_transfer(self, direction: str, path: str, nbytes: int = 0) -> None:
-        self.transfers.record(direction, path, nbytes)
+    def record_transfer(self, direction: str, path: str, nbytes: int = 0,
+                        arrays: int = 1) -> None:
+        self.transfers.record(direction, path, nbytes, arrays)
 
     # -- HBM -----------------------------------------------------------
     @staticmethod

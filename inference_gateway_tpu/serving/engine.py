@@ -44,6 +44,7 @@ from inference_gateway_tpu.parallel.sharding import (
     named,
     shard_params,
 )
+from inference_gateway_tpu.serving.packed import PackedLayout
 from inference_gateway_tpu.serving.tokenizer import load_tokenizer
 from inference_gateway_tpu.utils.platform import device_info
 
@@ -224,6 +225,9 @@ class _DecodeChunkHandle:
     # Host phases of the submit (ms), stamped when the caller asked for
     # them (ISSUE 24); the fetch adds its own to the dict the caller holds.
     phases: dict | None = None
+    # Host arrays the submit staged for upload (ISSUE 25): 1 for a fresh
+    # early-exit submit (the packed buffer), 0 for a chained one.
+    h2d_arrays: int = 0
 
 
 @dataclass
@@ -259,6 +263,9 @@ class MixedStepHandle:
     # upload_dispatch; mixed_step_fetch adds fetch_wait. None unless the
     # caller passed a dict to stamp (ISSUE 24).
     phases: dict | None = None
+    # Host arrays the submit staged for upload (ISSUE 25): 1, the packed
+    # descriptor buffer.
+    h2d_arrays: int = 0
 
 
 class Engine:
@@ -656,6 +663,45 @@ class Engine:
             jnp.zeros((1, 1), jnp.float32))
         self._no_term_table = jnp.zeros((1,), bool)
         self._zero_mstates = np.zeros((config.max_slots,), np.int32)
+        # Packed step descriptors (ISSUE 25): the two host-stepped
+        # submits each stage ONE int32 buffer; its layout is fixed here
+        # from max_slots, the mixed budget and the page-table shape, and
+        # the step programs slice it apart with the same static offsets
+        # (docs/observability.md lists the fields).
+        T = self.mixed_budget
+        P = self.page_cfg.max_pages_per_slot if self.paged else 0
+        self._mixed_pack = PackedLayout([
+            ("counter", (1,), "i32", 0),
+            ("tokens", (1, T), "i32", 0),
+            ("positions", (1, T), "i32", 0),
+            ("write_idx", (1, T), "i32", self._flat_size),
+            ("page_table", (S, P), "i32", 0),
+            ("q_starts", (S,), "i32", 0),
+            ("q_lens", (S,), "i32", 0),
+            ("kv_lens", (S,), "i32", 0),
+            ("temps", (S,), "f32", 0.0),
+            ("top_ps", (S,), "f32", 1.0),
+            ("seeds", (S,), "i32", 0),
+            ("use_seed", (S,), "bool", 0),
+            ("mstates", (S,), "i32", 0),
+        ]) if self.paged else None
+        self._fresh_pack = PackedLayout([
+            ("counter", (1,), "i32", 0),
+            ("tokens", (S,), "i32", 0),
+            ("positions", (S,), "i32", 0),
+            ("done", (S,), "bool", 1),
+            # Effectively unbounded: the host max_tokens check remains the
+            # backstop for callers that don't ship budgets.
+            ("budgets", (S,), "i32", 1 << 30),
+            ("mstates", (S,), "i32", 0),
+            ("temps", (S,), "f32", 0.0),
+            ("top_ps", (S,), "f32", 1.0),
+            ("seeds", (S,), "i32", 0),
+            ("use_seed", (S,), "bool", 0),
+            ("stop_table", (S, STOP_TABLE_WIDTH), "i32", self._eos_stop_row),
+            ("page_table", (S, P), "i32", 0),
+            ("reserved", (S if self.paged else 0,), "i32", 0),
+        ])
         # Serving metrics surfaced via the sidecar's /metrics endpoint.
         self.metrics = {
             "prefill_tokens": 0,
@@ -696,9 +742,12 @@ class Engine:
                 return b
         raise PromptTooLongError(length, self.max_prompt_len())
 
-    def _next_rng(self) -> jax.Array:
+    def _bump_step(self) -> int:
         self._step_counter += 1
-        return jax.random.fold_in(self._rng, self._step_counter)
+        return self._step_counter
+
+    def _next_rng(self) -> jax.Array:
+        return jax.random.fold_in(self._rng, self._bump_step())
 
     # -- structured outputs (ISSUE 13) ---------------------------------
     def _mask_args(self):
@@ -1101,6 +1150,27 @@ class Engine:
             top_ps, seeds, use_seed, rng, mask_args, (page_table, reserved),
             n_steps)
 
+    @partial(jax.jit, static_argnames=("self",))
+    def _fresh_unpack_fn(self, packed, base_rng):
+        """Fresh entry into the early-exit chunk, dense or paged (ISSUE
+        25): the host's whole decode state rides ONE packed int32 buffer
+        (``_fresh_pack``); this small program slices it apart and folds
+        the chunk key from the engine's base key and the counter in the
+        buffer. What it returns is exactly the device-resident state a
+        chained submit dispatches from — the carry at chunk entry, the
+        sampling parameters with the stop table, and (paged) the page
+        table and reserved spans — so a fresh submit is one upload, this
+        program, and the chained submit's own dispatch. (A fresh-entry
+        variant of the chunk program would save this 0.3 ms dispatch and
+        cost every start two more 7B executables to load, 2.5 s:
+        PERF.md §6, PR 25.)"""
+        d = self._fresh_pack.unpack(packed)
+        rng = jax.random.fold_in(base_rng, d["counter"][0])
+        carry = (d["tokens"], d["positions"], d["mstates"], d["done"], d["budgets"], rng)
+        sampling = (d["temps"], d["top_ps"], d["seeds"], d["use_seed"], d["stop_table"])
+        tables = (d["page_table"], d["reserved"]) if self.paged else None
+        return carry, sampling, tables
+
     @partial(jax.jit, static_argnames=("self", "ring", "masked"), donate_argnums=(2,))
     def _prefill_fn_paged(self, params, cache, tokens, positions, lengths, write_idx,
                           page_table, temps, top_ps, seeds, use_seed, rng,
@@ -1130,17 +1200,16 @@ class Engine:
         logprobs = compute_logprobs(logits, toks)
         return toks, logprobs, cache
 
-    @partial(jax.jit, static_argnames=("self", "masked"), donate_argnums=(2,))
-    def _mixed_step_fn(self, params, cache, tokens, positions, write_idx, page_table,
-                       q_starts, q_lens, kv_lens, temps, top_ps, seeds, use_seed, rng,
-                       mstates=None, mnext=None, mbits=None, mbias=None, masked=False):
+    def _mixed_step_body(self, params, cache, tokens, positions, write_idx, page_table,
+                         q_starts, q_lens, kv_lens, temps, top_ps, seeds, use_seed, rng,
+                         mstates=None, mnext=None, mbits=None, mbias=None, masked=False):
         """One ragged MIXED step (ISSUE 12): prefill-chunk rows and
         decode rows in a single launch over the paged cache. This is the
         one compiled program that replaces the per-bucket
         _prefill_fn_paged / _prefill_chunk_fn_paged / _decode_fn_paged
         family on the mixed path — packed width is the fixed
         mixed_budget, so admission never recompiles and never pays
-        bucket padding."""
+        bucket padding. Traced inside _mixed_step_fn."""
         logits, cache = self._model.forward_ragged(
             params, self.model_cfg, tokens, positions, cache, write_idx,
             page_table, q_starts, q_lens, kv_lens, mesh=self.mesh)
@@ -1156,17 +1225,37 @@ class Engine:
             logprobs = compute_logprobs(logits, toks)
         return toks, logprobs, cache
 
-    def _audit_transfer(self, direction: str, path: str, *arrays) -> None:
+    @partial(jax.jit, static_argnames=("self", "masked"), donate_argnums=(2,))
+    def _mixed_step_fn(self, params, cache, packed, base_rng,
+                       mnext=None, mbits=None, mbias=None, masked=False):
+        """The mixed step as the submit dispatches it (ISSUE 25): every
+        descriptor rides ONE packed int32 buffer (``_mixed_pack``), the
+        step key is folded from the engine's base key and the counter in
+        that buffer, and the (2, S) tokens-atop-logprobs block the fetch
+        reads is built here — one upload and one dispatch a submit."""
+        d = self._mixed_pack.unpack(packed)
+        rng = jax.random.fold_in(base_rng, d["counter"][0])
+        toks, logprobs, cache = self._mixed_step_body(
+            params, cache, d["tokens"], d["positions"], d["write_idx"], d["page_table"],
+            d["q_starts"], d["q_lens"], d["kv_lens"], d["temps"], d["top_ps"],
+            d["seeds"], d["use_seed"], rng, mstates=d["mstates"], mnext=mnext,
+            mbits=mbits, mbias=mbias, masked=masked)
+        return jnp.stack([toks.astype(jnp.float32), logprobs]), cache
+
+    def _audit_transfer(self, direction: str, path: str, *arrays) -> int:
         """Transfer-audit seam (ISSUE 19): count one host↔device staging
-        event with the summed nbytes of the host arrays involved.
-        Best-effort byte accounting (small scalars and the RNG key are
-        not itemized); the COUNT is the invariant the audit defends —
-        the early-exit chained submit never calls this, so
-        engine.transfers{direction="h2d",path="chain"} stays zero."""
+        event with the number of host arrays staged and their summed
+        nbytes (ISSUE 25: a packed submit stages ONE, and its nbytes is
+        everything the submit uploads). The early-exit chained submit
+        never calls this, so engine.transfers{direction="h2d",
+        path="chain"} stays zero. Returns the number of arrays, for the
+        submit's timeline record."""
+        staged = [a for a in arrays if a is not None]
         obs = self.observatory
         if obs is not None:
             obs.record_transfer(direction, path, sum(
-                int(getattr(a, "nbytes", 0)) for a in arrays if a is not None))
+                int(getattr(a, "nbytes", 0)) for a in staged), arrays=len(staged))
+        return len(staged)
 
     def mixed_step_submit(self, rows: "list[MixedRow]",
                           phases: dict | None = None) -> "MixedStepHandle":
@@ -1184,15 +1273,18 @@ class Engine:
         ``phases`` (ISSUE 24): a dict the caller wants the submit's host
         phases stamped into (lock_wait, pages, assemble, upload_dispatch,
         in ms); it rides the returned handle."""
-        S = self.config.max_slots
-        T = self.mixed_budget
         total = sum(len(r.token_ids) for r in rows)
-        assert rows and total <= T, (total, T)
+        assert rows and total <= self.mixed_budget, (total, self.mixed_budget)
+        pack = self._mixed_pack
         t_lock = time.perf_counter_ns()
         with self._lock:
             stamp_since(phases, "lock_wait", t_lock)
+            # Both phases write straight into views of the one staging
+            # buffer (reset to its fill values: zeros, top_p 1, write
+            # indices out of bounds).
+            v = pack.begin()
             with phase_span(phases, "pages", "engine.mixed.pages"):
-                write_idx = np.full((1, T), self._flat_size, np.int64)
+                write_idx = v["write_idx"]
                 off = 0
                 for r in rows:
                     n = len(r.token_ids)
@@ -1200,18 +1292,12 @@ class Engine:
                     write_idx[0, off:off + n] = self.allocator.flat_write_indices(
                         r.slot, r.start, n)
                     off += n
-                page_table = self.allocator.page_table()
+                v["page_table"][...] = self.allocator.page_table()
             with phase_span(phases, "assemble", "engine.mixed.assemble"):
-                tokens = np.zeros((1, T), np.int32)
-                positions = np.zeros((1, T), np.int32)
-                q_starts = np.zeros((S,), np.int32)
-                q_lens = np.zeros((S,), np.int32)
-                kv_lens = np.zeros((S,), np.int32)
-                temps = np.zeros((S,), np.float32)
-                top_ps = np.ones((S,), np.float32)
-                seeds = np.zeros((S,), np.int32)
-                use_seed = np.zeros((S,), bool)
-                mstates = np.zeros((S,), np.int32)
+                tokens, positions = v["tokens"], v["positions"]
+                q_starts, q_lens, kv_lens = v["q_starts"], v["q_lens"], v["kv_lens"]
+                temps, top_ps = v["temps"], v["top_ps"]
+                seeds, use_seed, mstates = v["seeds"], v["use_seed"], v["mstates"]
                 off = 0
                 n_prefill = 0
                 for r in rows:
@@ -1232,16 +1318,11 @@ class Engine:
                         n_prefill += n
             with phase_span(phases, "upload_dispatch", "engine.mixed.upload_dispatch"):
                 masked, mnext, mbits, mbias = self._mask_args()
-                toks, logprobs, self.cache = self._mixed_step_fn(
-                    self.params, self.cache, jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(write_idx), jnp.asarray(page_table),
-                    jnp.asarray(q_starts), jnp.asarray(q_lens), jnp.asarray(kv_lens),
-                    jnp.asarray(temps), jnp.asarray(top_ps), jnp.asarray(seeds),
-                    jnp.asarray(use_seed), self._next_rng(),
-                    mstates=jnp.asarray(mstates), mnext=mnext, mbits=mbits,
-                    mbias=mbias, masked=masked,
-                )
-                both = jnp.stack([toks.astype(jnp.float32), logprobs])
+                v["counter"][0] = self._bump_step()
+                staged = pack.snapshot()
+                both, self.cache = self._mixed_step_fn(
+                    self.params, self.cache, jnp.asarray(staged), self._rng,
+                    mnext=mnext, mbits=mbits, mbias=mbias, masked=masked)
             # Positions moved outside the chained-carry bookkeeping.
             self._dev_carry = None
             n_decode_tokens = total - n_prefill
@@ -1252,10 +1333,8 @@ class Engine:
                 self.metrics["decode_steps"] += 1
                 self.metrics["decode_tokens"] += n_decode_tokens
             self.metrics["prefill_tokens"] += n_prefill
-        self._audit_transfer("h2d", "mixed", tokens, positions, write_idx,
-                             page_table, q_starts, q_lens,
-                             kv_lens, temps, top_ps, seeds, use_seed, mstates)
-        return MixedStepHandle(both, list(rows), phases)
+        h2d_arrays = self._audit_transfer("h2d", "mixed", staged)
+        return MixedStepHandle(both, list(rows), phases, h2d_arrays)
 
     def mixed_step_fetch(self, handle: "MixedStepHandle"):
         """Block until a mixed step's sampled tokens are on host.
@@ -1789,16 +1868,14 @@ class Engine:
             e.slot = slot
             raise
 
-    def _reserve_chain_horizon(self, need: np.ndarray, n: int) -> None:
+    def _reserve_horizon_pages(self, need: np.ndarray, n: int) -> None:
         """Batched KV-page pre-reservation for the chained-decode horizon
-        (ISSUE 14): every slot flagged in ``need`` gets pages covering
-        pipeline_depth+1 future chunks (falling back to one chunk under
-        page pressure, so deep horizons never manufacture exhaustion the
-        legacy per-chunk path wouldn't have hit), then the device-resident
-        page table and reserved spans are refreshed ONCE. This is the
-        only h2d traffic the chained steady state ever causes, amortized
-        over the whole horizon; the common chained submit finds
-        ``need`` empty and uploads nothing."""
+        (ISSUE 14), host side: every slot flagged in ``need`` gets pages
+        covering pipeline_depth+1 future chunks (falling back to one
+        chunk under page pressure, so deep horizons never manufacture
+        exhaustion the legacy per-chunk path wouldn't have hit) and the
+        host mirror ``_reserved`` is raised to match. The caller ships
+        the page table and the reserved spans to the device."""
         from inference_gateway_tpu.serving.kv_cache import OutOfPagesError
 
         ps = self.config.page_size
@@ -1806,20 +1883,29 @@ class Engine:
         depth = max(self.config.pipeline_depth, 1)
         cap = np.minimum(self._pred_pos + n * (depth + 1), max_len)
         base = np.minimum(self._pred_pos + n, max_len)
+        for slot in np.nonzero(need)[0]:
+            s = int(slot)
+            try:
+                self._ensure_with_evict(s, int(cap[s]))
+                got = int(cap[s])
+            except OutOfPagesError:
+                # Tagged with .slot by _ensure_with_evict if this
+                # raises too — the scheduler's preemption path takes
+                # over.
+                self._ensure_with_evict(s, int(base[s]))
+                got = int(base[s])
+            self._reserved[s] = max(
+                int(self._reserved[s]), (got + ps - 1) // ps * ps)
+
+    def _reserve_chain_horizon(self, need: np.ndarray, n: int) -> None:
+        """Top up the page horizon of a CHAINED steady state and refresh
+        the device-resident page table and reserved spans ONCE. This is
+        the only h2d traffic the chained steady state ever causes,
+        amortized over the whole horizon; the common chained submit
+        finds ``need`` empty and uploads nothing. (A fresh submit ships
+        both inside its packed buffer instead.)"""
         try:
-            for slot in np.nonzero(need)[0]:
-                s = int(slot)
-                try:
-                    self._ensure_with_evict(s, int(cap[s]))
-                    got = int(cap[s])
-                except OutOfPagesError:
-                    # Tagged with .slot by _ensure_with_evict if this
-                    # raises too — the scheduler's preemption path takes
-                    # over.
-                    self._ensure_with_evict(s, int(base[s]))
-                    got = int(base[s])
-                self._reserved[s] = max(
-                    int(self._reserved[s]), (got + ps - 1) // ps * ps)
+            self._reserve_horizon_pages(need, n)
         finally:
             # ALWAYS refresh the device mirrors, even when a later slot's
             # reservation raised: earlier slots in this loop already
@@ -1844,9 +1930,6 @@ class Engine:
                 "decode_chunk_submit(chain=True) with no device carry: "
                 "a prefill or failure invalidated chained decode state; "
                 "resubmit with chain=False")
-        tok_in, pos_in, ms_in, done_in, bud_in, rng = self._dev_carry
-        temps_d, tps_d, seeds_d, used_d, stop_d = self._dev_sampling
-        masked, mnext, mbits, mterm, mbias = self._mask_args_ee()
         if self.paged:
             # Slots already at the cache cap are finishing ("length") —
             # excluding them keeps the reservation check from re-firing
@@ -1856,6 +1939,19 @@ class Engine:
                     & (self._pred_pos < self.config.max_seq_len))
             if need.any():
                 self._reserve_chain_horizon(need, n)
+        return self._dispatch_chunk_ee_locked(n)
+
+    def _dispatch_chunk_ee_locked(self, n: int):
+        """Dispatch one early-exit chunk from the device-resident state
+        (carry, sampling params, page table, reserved spans) and advance
+        the host mirror: the whole of a chained submit once its horizon
+        is known to hold, and the tail of a fresh one once the unpack
+        program has put the host's state there. Chain-steady scope too:
+        no upload, no host-array construction, no loop."""
+        tok_in, pos_in, ms_in, done_in, bud_in, rng = self._dev_carry
+        temps_d, tps_d, seeds_d, used_d, stop_d = self._dev_sampling
+        masked, mnext, mbits, mterm, mbias = self._mask_args_ee()
+        if self.paged:
             toks, logprobs, tok_f, pos_f, ms_f, done_f, bud_f, rng_f, self.cache = \
                 self._decode_chunk_fn_paged_ee(
                     self.params, self.cache, tok_in, pos_in, done_in, bud_in,
@@ -1881,60 +1977,51 @@ class Engine:
     def _fresh_submit_ee_locked(self, tokens, positions, active, temps, top_ps,
                                 n, seeds, use_seed, mstates, stop_tables, budgets):
         """chain=False under decode_early_exit: host state is
-        authoritative — upload it all (first chunk, failure recovery),
-        arm the on-device stop criteria, and (re)build the chained
-        steady-state host mirror the later host-free submits read.
-        Caller holds the engine lock."""
-        S = self.config.max_slots
-        if stop_tables is None:
-            stop_tables = np.broadcast_to(
-                self._eos_stop_row, (S, STOP_TABLE_WIDTH))
-        if budgets is None:
-            # Effectively unbounded: the host max_tokens check remains
-            # the backstop for callers that don't ship budgets.
-            budgets = np.full((S,), 1 << 30, np.int64)
+        authoritative — pack it all into ONE buffer (ISSUE 25), upload
+        that (first chunk, failure recovery), arm the on-device stop
+        criteria, and (re)build the chained steady-state host mirror the
+        later host-free submits read. ``_fresh_unpack_fn`` turns the
+        buffer into the device-resident state a chained submit starts
+        from, and the chunk is dispatched as one. Caller holds the
+        engine lock."""
+        # Until the program below has run, nothing on the device matches
+        # the host: a failure in here must not leave a chainable carry.
+        self._dev_carry = None
         active = np.asarray(active, bool)
-        tok_in = jnp.asarray(np.asarray(tokens, np.int32))
-        pos_in = jnp.asarray(np.asarray(positions, np.int32))
-        done_in = jnp.asarray(~active)
-        bud_in = jnp.asarray(np.asarray(budgets, np.int32))
-        ms_in = jnp.asarray(mstates if mstates is not None
-                            else self._zero_mstates)
-        temps_d, tps_d = jnp.asarray(temps), jnp.asarray(top_ps)
-        seeds_d, used_d = jnp.asarray(seeds), jnp.asarray(use_seed)
-        stop_d = jnp.asarray(np.asarray(stop_tables, np.int32))
-        self._dev_sampling = (temps_d, tps_d, seeds_d, used_d, stop_d)
+        pack = self._fresh_pack
+        v = pack.begin()
+        v["tokens"][:] = tokens
+        v["positions"][:] = positions
+        v["done"][:] = ~active
+        if budgets is not None:  # the fill is the effectively-unbounded budget
+            v["budgets"][:] = budgets
+        if mstates is not None:
+            v["mstates"][:] = mstates
+        v["temps"][:] = temps
+        v["top_ps"][:] = top_ps
+        v["seeds"][:] = seeds
+        v["use_seed"][:] = use_seed
+        if stop_tables is not None:  # the fill is the EOS-only row
+            v["stop_table"][...] = stop_tables
         self._chain_active = active.copy()
         self._pred_pos = np.asarray(positions, np.int64).copy()
-        rng = self._next_rng()
-        masked, mnext, mbits, mterm, mbias = self._mask_args_ee()
         if self.paged:
             # Fresh reservation state: recompute the horizon from the
             # allocator's truth (stale mirrors from a previous stream
             # must not understate OR overstate what is safe to write).
             self._reserved[:] = 0
-            self._reserve_chain_horizon(active, n)
-            toks, logprobs, tok_f, pos_f, ms_f, done_f, bud_f, rng_f, self.cache = \
-                self._decode_chunk_fn_paged_ee(
-                    self.params, self.cache, tok_in, pos_in, done_in, bud_in,
-                    stop_d, self._dev_page_table, self._dev_reserved,
-                    temps_d, tps_d, seeds_d, used_d, rng,
-                    mstates=ms_in, mnext=mnext, mbits=mbits, mterm=mterm,
-                    mbias=mbias, n_steps=n, masked=masked)
-        else:
-            toks, logprobs, tok_f, pos_f, ms_f, done_f, bud_f, rng_f, self.cache = \
-                self._decode_chunk_fn_ee(
-                    self.params, self.cache, tok_in, pos_in, done_in, bud_in,
-                    stop_d, temps_d, tps_d, seeds_d, used_d, rng,
-                    mstates=ms_in, mnext=mnext, mbits=mbits, mterm=mterm,
-                    mbias=mbias, n_steps=n, masked=masked)
-        self._pred_pos = self._pred_pos + n * self._chain_active
-        self._dev_carry = (tok_f, pos_f, ms_f, done_f, bud_f, rng_f)
-        n_active = int(active.sum())
-        self.metrics["decode_tokens"] += n_active * n
-        self.metrics["decode_steps"] += n
-        both = jnp.concatenate([toks.astype(jnp.float32), logprobs], axis=0)
-        return _DecodeChunkHandle(both, n)
+            self._reserve_horizon_pages(active, n)
+            v["page_table"][...] = self.allocator.page_table()
+            v["reserved"][:] = self._reserved
+        v["counter"][0] = self._bump_step()
+        staged = pack.snapshot()
+        self._dev_carry, self._dev_sampling, tables = self._fresh_unpack_fn(
+            jnp.asarray(staged), self._rng)
+        if self.paged:
+            self._dev_page_table, self._dev_reserved = tables
+        handle = self._dispatch_chunk_ee_locked(n)
+        handle.h2d_arrays = self._audit_transfer("h2d", "fresh", staged)
+        return handle
 
     def decode_chunk_submit(self, tokens: np.ndarray, positions: np.ndarray,
                             active: np.ndarray, temps: np.ndarray, top_ps: np.ndarray,
@@ -1994,10 +2081,6 @@ class Engine:
                             tokens, positions, active, temps, top_ps, n, seeds,
                             use_seed, mstates, stop_tables, budgets)
             handle.phases = phases
-            if not chain:
-                self._audit_transfer("h2d", "fresh", tokens, positions, active,
-                                     temps, top_ps, seeds, use_seed, mstates,
-                                     stop_tables, budgets)
             return handle
         masked, mnext, mbits, mbias = self._mask_args()
         # The legacy (fixed-scan) chunk assembles its write indices here,

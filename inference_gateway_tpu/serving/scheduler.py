@@ -1173,6 +1173,7 @@ class Scheduler:
                         self._flush_emits(req)
                 if observing:
                     spans["phases"] = phases
+                    spans["h2d_arrays"] = handle.h2d_arrays
                     if prep is not None:  # the admission's first record carries it
                         spans["prep"] = {k: round(v, 4) for k, v in prep.items()}
                         prep = None
@@ -1663,7 +1664,8 @@ class Scheduler:
             self._record_step("decode", t0, n_steps=inf.n_steps,
                               batch=len(inf.states), tokens=emitted,
                               context_tokens=ctx,
-                              spans={**(inf.submit or {}), "phases": phases})
+                              spans={**(inf.submit or {}), "phases": phases,
+                                     "h2d_arrays": getattr(inf.handle, "h2d_arrays", 0)})
 
     def _release_guarded(self, slot: int, reason: str | None,
                          frozen: bool = False) -> None:

@@ -209,6 +209,9 @@ async def test_chained_submits_read_zero_h2d_on_live_metrics_scrape():
         # ...while the uploads that legitimately happened are accounted.
         assert transfers["h2d/prefill"]["count"] >= 1
         assert transfers["h2d/fresh"]["count"] >= 1
+        # ISSUE 25: a fresh submit stages ONE array, its packed buffer.
+        assert transfers["h2d/fresh"]["arrays"] == transfers["h2d/fresh"]["count"]
+        assert transfers["h2d/chain"]["arrays"] == 0
         assert transfers["d2h/chunk"]["count"] >= 3  # fresh + 2 chained fetches
         assert all(slot["bytes"] > 0 for key, slot in transfers.items()
                    if slot["count"] > 0)
@@ -217,6 +220,46 @@ async def test_chained_submits_read_zero_h2d_on_live_metrics_scrape():
             f"http://127.0.0.1:{port}/metrics?format=prometheus")).body.decode()
         assert "tpu_sidecar_transfers_h2d_chain 0" in prom
         assert "tpu_sidecar_transfers_h2d_fresh" in prom
+    finally:
+        await sidecar.shutdown()
+
+
+async def test_packed_submits_stage_one_array_with_its_true_bytes():
+    """ISSUE 25: `device.transfers` of /debug/status reads arrays / count
+    <= 2 (here 1) for `h2d/mixed` and `h2d/fresh`, `bytes` is the packed
+    buffers' nbytes, and `h2d/chain` stays 0 under the transfer guard."""
+    from inference_gateway_tpu.serving.engine import MixedRow
+
+    eng = Engine(_cfg("paged", decode_early_exit=True, max_seq_len=256, mixed_step=True))
+    sidecar = _sidecar(eng)
+    port = await sidecar.start("127.0.0.1", 0)
+    try:
+        toks = None
+        for start in (0, 5, 10):  # three mixed steps: a prompt in chunks of five
+            toks, _ = eng.mixed_step_fetch(eng.mixed_step_submit([MixedRow(
+                slot=0, token_ids=[9, 8, 7, 6, 5], start=start, kind="prefill")]))
+        S = eng.config.max_slots
+        tokens = np.zeros((S,), np.int32)
+        positions = np.zeros((S,), np.int32)
+        active = np.zeros((S,), bool)
+        tokens[0], positions[0], active[0] = toks[0], 15, True
+        idle = (np.zeros((S,), np.float32), np.ones((S,), np.float32))
+        for _ in range(2):  # two fresh submits from the same host state
+            eng.decode_chunk_fetch(eng.decode_chunk_submit(tokens, positions, active, *idle))
+        with jax.transfer_guard_host_to_device("disallow"):
+            handle = eng.decode_chunk_submit(None, None, None, None, None, chain=True)
+        eng.decode_chunk_fetch(handle)
+        assert handle.h2d_arrays == 0
+
+        client = HTTPClient()
+        status = (await client.get(f"http://127.0.0.1:{port}/debug/status")).json()
+        transfers = status["device"]["transfers"]
+        for path, n, pack in (("h2d/mixed", 3, eng._mixed_pack), ("h2d/fresh", 2, eng._fresh_pack)):
+            assert transfers[path]["count"] == n
+            assert transfers[path]["arrays"] / transfers[path]["count"] <= 2
+            assert transfers[path]["arrays"] == n
+            assert transfers[path]["bytes"] == n * pack.snapshot().nbytes == n * 4 * pack.size
+        assert transfers["h2d/chain"] == {"count": 0, "arrays": 0, "bytes": 0}
     finally:
         await sidecar.shutdown()
 

@@ -413,3 +413,23 @@ def test_mixed_step_submit_is_engine_level_consistent():
                                         start=len(prompt), kind="decode")])
     t2b, _ = e2.mixed_step_fetch(h2)
     assert int(t1[0]) == int(t2b[0])
+
+
+def test_two_mixed_steps_in_flight_do_not_share_their_descriptors():
+    """ISSUE 25: a submit stages ONE packed buffer and uploads a private
+    copy of it, so the next submit's rewrite of the staging views cannot
+    reach a step still in flight (the CPU backend aliases an uploaded
+    numpy array; tests/test_packed_submit.py pins the copy itself). Two
+    submits fetched late equal two fetched in turn."""
+    rng = np.random.default_rng(21)
+    prompts = [[int(x) for x in rng.integers(1, 250, size=n)] for n in (14, 9)]
+    rows = [MixedRow(slot=s, token_ids=p, start=0, kind="prefill", temp=0.9, seed=77 + s)
+            for s, p in enumerate(prompts)]
+    late, in_turn = _mk_engine(True), _mk_engine(True)
+    handles = [late.mixed_step_submit([r]) for r in rows]
+    assert [h.h2d_arrays for h in handles] == [1, 1]
+    assert all(h.toks_lp.shape == (2, late.config.max_slots) for h in handles)
+    got = [late.mixed_step_fetch(h) for h in handles]
+    want = [in_turn.mixed_step_fetch(in_turn.mixed_step_submit([r])) for r in rows]
+    for s, ((gt, gl), (wt, wl)) in enumerate(zip(got, want)):
+        assert int(gt[s]) == int(wt[s]) and gl[s].tobytes() == wl[s].tobytes()

@@ -638,6 +638,85 @@ def test_jax_hot_path_covers_structured_mask_upload_path():
                 select="jax-hot-path") == []
 
 
+_PACKED_BAD_UPLOADS = """
+import jax.numpy as jnp
+
+class Engine:
+    def mixed_step_submit(self, rows):
+        tokens, positions, page_table = self._assemble(rows)
+        return self._mixed_step_fn(
+            self.params, self.cache, jnp.asarray(tokens), jnp.asarray(positions),
+            jnp.asarray(page_table), self._rng)
+"""
+
+_PACKED_BAD_KEY = """
+import jax
+import jax.numpy as jnp
+
+class Engine:
+    def _fresh_submit_ee_locked(self, tokens, n):
+        staged = self._fresh_pack.snapshot()
+        rng = jax.random.fold_in(self._rng, self._step_counter)  # a dispatch of its own
+        self._dev_carry = self._fresh_unpack_fn(jnp.asarray(staged), rng)
+        return self._dispatch_chunk_ee_locked(n)
+"""
+
+_PACKED_BAD_NEXT_RNG = """
+import jax
+
+class Engine:
+    def mixed_step_submit(self, rows):
+        staged = self._mixed_pack.snapshot()
+        return self._mixed_step_fn(self.params, self.cache, jax.device_put(staged),
+                                   self._next_rng())
+"""
+
+_PACKED_GOOD = """
+import jax
+import jax.numpy as jnp
+
+class Engine:
+    def mixed_step_submit(self, rows):
+        v = self._mixed_pack.begin()
+        v["counter"][0] = self._bump_step()
+        staged = self._mixed_pack.snapshot()
+        return self._mixed_step_fn(self.params, self.cache, jnp.asarray(staged), self._rng)
+
+    def _fresh_submit_ee_locked(self, tokens, n):
+        ints, floats = self._pack(tokens)
+        self._dev_carry = self._fresh_unpack_fn(jnp.asarray(ints), jax.device_put(floats), self._rng)
+        return self._dispatch_chunk_ee_locked(n)
+
+    def _next_rng(self):
+        return jax.random.fold_in(self._rng, self._bump_step())  # other paths may
+
+    def prefill_submit(self, prompts):
+        a, b, c = self._bucket(prompts)
+        return self._prefill_fn(self.params, self.cache, jnp.asarray(a), jnp.asarray(b),
+                                jnp.asarray(c), self._next_rng())
+"""
+
+
+@pytest.mark.parametrize("source,needle", [
+    (_PACKED_BAD_UPLOADS, "upload #3 'jnp.asarray(...)'"),
+    (_PACKED_BAD_KEY, "eager key derivation 'jax.random.fold_in(...)'"),
+    (_PACKED_BAD_NEXT_RNG, "eager key derivation 'self._next_rng(...)'"),
+], ids=["third-upload", "fold-in", "next-rng"])
+def test_jax_hot_path_packed_submit_flags_what_brings_the_dispatches_back(source, needle):
+    """ISSUE 25: a host-stepped submit stages one packed buffer (two at
+    most) and draws its key inside the program."""
+    findings = lint(source, path="inference_gateway_tpu/serving/engine.py", select="jax-hot-path")
+    assert len(findings) == 1 and needle in findings[0].message
+    assert "packed-submit scope" in findings[0].message
+
+
+def test_jax_hot_path_packed_submit_allows_two_uploads_and_other_paths():
+    assert lint(_PACKED_GOOD, path="inference_gateway_tpu/serving/engine.py",
+                select="jax-hot-path") == []
+    # the scope is the engine's two submits, not every function of that name
+    assert lint(_PACKED_BAD_UPLOADS, path="somewhere/else.py", select="jax-hot-path") == []
+
+
 # ----------------------------------------------------------------------
 # telemetry-noop-drift
 # ----------------------------------------------------------------------

@@ -135,6 +135,20 @@ async def test_starvation_is_zero_on_a_chained_submit_and_positive_after_an_idle
     assert fresh and all(r["starved_ms"] > 0 for r in fresh)
 
 
+async def test_a_host_stepped_record_says_how_many_arrays_its_submit_uploaded(sidecar):
+    """ISSUE 25: `h2d_arrays` is 1 on every `mixed` record and on the
+    fresh `decode` record (the packed buffer), 0 on a chained one."""
+    server, port, _ = sidecar
+    recs = await _records(server, port)
+    mixed = [r for r in recs if r["kind"] == "mixed"]
+    fresh = [r for r in recs if r["kind"] == "decode" and r["phases"]["build"] > 0]
+    chained = [r for r in recs if r["kind"] == "decode" and r["phases"]["build"] == 0]
+    assert mixed and fresh and chained
+    assert [r["h2d_arrays"] for r in mixed] == [1] * len(mixed)
+    assert [r["h2d_arrays"] for r in fresh] == [1] * len(fresh)
+    assert [r["h2d_arrays"] for r in chained] == [0] * len(chained)
+
+
 async def test_an_idle_scheduler_accrues_no_starvation(sidecar):
     server, port, _ = sidecar
     await _chat(port, max_tokens=4)
