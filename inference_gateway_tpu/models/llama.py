@@ -48,6 +48,12 @@ class LlamaConfig:
     sliding_window: int | None = None  # Mistral-style windowed attention
     norm_offset: bool = False  # Gemma-style RMSNorm weight = (1 + w)
     embed_scale: bool = False  # Gemma scales embeddings by sqrt(hidden)
+    # Per-layer attention kinds (ISSUE 28), one 0/1 flag a layer, or None
+    # for a model whose layers are all alike: layer_rope 0 is a layer with
+    # no position encoding; layer_window 0 is a global layer of a model
+    # whose other layers see only ``sliding_window`` keys.
+    layer_rope: tuple | None = None
+    layer_window: tuple | None = None
     # Stored as a hashable tuple of (key, value) pairs so the config can be
     # a jit static argument; accepts a dict at construction.
     rope_scaling: Any = None
@@ -55,6 +61,11 @@ class LlamaConfig:
     def __post_init__(self):
         if isinstance(self.rope_scaling, dict):
             object.__setattr__(self, "rope_scaling", tuple(sorted(self.rope_scaling.items())))
+        for name in ("layer_rope", "layer_window"):
+            flags = getattr(self, name)
+            if flags is not None:
+                assert len(flags) == self.num_layers, (name, len(flags), self.num_layers)
+                object.__setattr__(self, name, tuple(int(bool(f)) for f in flags))
 
     @property
     def rope_scaling_dict(self) -> dict | None:
@@ -76,6 +87,27 @@ _ACT = {
 def _nw(w, cfg: "LlamaConfig"):
     """Norm weight convention: Gemma stores (w - 1)."""
     return w + 1 if cfg.norm_offset else w
+
+
+def _rope_qk(q, k, cos, sin, cfg: "LlamaConfig", li):
+    """Rotary on q and k; in a model with NoPE layers only where layer
+    ``li``'s flag is set (the rotation is elementwise: both are cheap)."""
+    rq, rk = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    if cfg.layer_rope is None:
+        return rq, rk
+    on = jnp.asarray(cfg.layer_rope, bool)[li]
+    return jnp.where(on, rq, q), jnp.where(on, rk, k)
+
+
+def _attend_by_window(cfg: "LlamaConfig", li, attend):
+    """``attend(window)`` for layer ``li``. ``window`` is a static
+    argument of both Pallas kernels, so a model that mixes windowed and
+    global layers carries one instance of each under a ``lax.cond`` on
+    the layer's flag; a model whose layers are alike traces one."""
+    if cfg.layer_window is None or not cfg.sliding_window:
+        return attend(cfg.sliding_window)
+    return jax.lax.cond(jnp.asarray(cfg.layer_window, bool)[li],
+                        lambda: attend(cfg.sliding_window), lambda: attend(None))
 
 
 def init_params(rng: jax.Array, cfg: LlamaConfig, dtype=jnp.bfloat16) -> Params:
@@ -333,8 +365,10 @@ def loss_fn(params: Params, cfg: LlamaConfig, tokens: jnp.ndarray, targets: jnp.
 # Paged-cache forward (serving fast path)
 # ---------------------------------------------------------------------------
 
-def _dense_ffn(x: jnp.ndarray, lp: Params, cfg: LlamaConfig) -> jnp.ndarray:
-    """Norm + gated MLP residual contribution (the non-MoE FFN block)."""
+def _dense_ffn(x: jnp.ndarray, attn_in: jnp.ndarray, lp: Params, li, valid, cfg: LlamaConfig) -> jnp.ndarray:
+    """Norm + gated MLP residual contribution (the non-MoE FFN block).
+    Of the seam's arguments (below) it reads ``x`` and its own leaves."""
+    del attn_in, li, valid
     h = rms_norm(x, _nw(lp["mlp_norm"], cfg), cfg.rms_norm_eps)
     act = _ACT[cfg.hidden_act]
     return qmatmul(act(qmatmul(h, lp["wg"])) * qmatmul(h, lp["wu"]), lp["wd"])
@@ -362,8 +396,15 @@ def forward_paged(
     path: shared prefix pages are already populated, only the tail is
     computed here."""
     return forward_paged_impl(params, cfg, tokens, positions, lengths, cache,
-                              write_idx, page_table, mode, last_only, mesh, _dense_ffn,
-                              ring_mesh=ring_mesh)
+                              write_idx, page_table, mode, last_only, mesh,
+                              partial(_dense_ffn, cfg=cfg), ring_mesh=ring_mesh)
+
+
+def _scan_layers(params: Params, unscanned: tuple) -> Params:
+    """The leaves a layer scan slices. An FFN that reads a stacked leaf
+    whole, by layer index (the expert stacks: ops/moe.grouped_matmul),
+    names it in ``unscanned`` so that no per-layer slice of it exists."""
+    return {k: v for k, v in params["layers"].items() if k not in unscanned}
 
 
 def forward_paged_impl(
@@ -378,13 +419,24 @@ def forward_paged_impl(
     mode: str,
     last_only: bool,
     mesh,
-    ffn,  # (x, lp, cfg) -> residual FFN contribution; MoE plugs in here
+    ffn,  # the FFN seam, below; MoE plugs in here
     ring_mesh=None,
-) -> tuple[jnp.ndarray, Params]:
+    unscanned: tuple = (),
+    valid: jnp.ndarray | None = None,  # (B,) decode rows that carry a stream
+    with_aux: bool = False,
+):
     """Shared paged-decoder skeleton: attention + cache paging are
     family-independent; the FFN block (dense gated MLP vs MoE) is the
     ``ffn`` callable (models/mixtral.py reuses this for paged MoE
-    serving)."""
+    serving).
+
+    The seam: ``ffn(x, attn_in, lp, li, valid)`` returns the block's
+    residual contribution, or that and a per-layer auxiliary array.
+    ``x`` is the stream after attention, ``attn_in`` the attention's
+    normed input (a router placed before attention reads it), ``lp`` the
+    layer's scanned leaves, ``li`` its index, ``valid`` (B*T,) which
+    tokens are real. With ``with_aux`` the auxiliaries come back stacked
+    over layers as a third result."""
     from inference_gateway_tpu.ops.flash_attention import flash_prefill_attention, use_flash_prefill
     from inference_gateway_tpu.ops.paged_attention import paged_attention
 
@@ -399,22 +451,27 @@ def forward_paged_impl(
     inv_freq = rope_inv_freq(cfg.hd, cfg.rope_theta, cfg.rope_scaling_dict)
     cos, sin = rope_cos_sin(positions, inv_freq)
 
+    def windowed(mask, key_pos, window):
+        if not window:
+            return mask
+        return mask & (key_pos > positions[:, :, None] - window)
+
     if mode == "prefill":
-        mask = causal_prefill_mask(positions, lengths)
-        if cfg.sliding_window:
-            # Keys are this call's tokens at absolute `positions`
-            # (same windowing as the dense path, forward() above).
-            mask = mask & (positions[:, None, :] > positions[:, :, None] - cfg.sliding_window)
+        # Keys are this call's tokens at absolute `positions`
+        # (same windowing as the dense path, forward() above).
+        prefill_mask = causal_prefill_mask(positions, lengths)
+        tok_valid = jnp.arange(T)[None, :] < lengths[:, None]
     elif mode == "prefill_chunk":
         S_gather = page_table.shape[1] * page_size
         key_pos = jnp.arange(S_gather)
         chunk_mask = (key_pos[None, None, :] <= positions[:, :, None]) & (
             key_pos[None, None, :] < lengths[:, None, None]
         )
-        if cfg.sliding_window:
-            chunk_mask = chunk_mask & (
-                key_pos[None, None, :] > positions[:, :, None] - cfg.sliding_window
-            )
+        tok_valid = positions < lengths[:, None]
+    else:
+        tok_valid = None if valid is None else jnp.broadcast_to(valid[:, None], (B, T))
+    if tok_valid is not None:
+        tok_valid = tok_valid.reshape(B * T)
     decode = mode == "decode"
 
     # The layer loop CARRIES the cache as one flat buffer instead of
@@ -445,8 +502,7 @@ def forward_paged_impl(
             q = q.reshape(B, T, Hq, D)
             k = k.reshape(B, T, Hkv, D)
             v = v.reshape(B, T, Hkv, D)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+            q, k = _rope_qk(q, k, cos, sin, cfg, li)
 
             k_flat = k.reshape(B, T, HkvD).astype(ck.dtype)
             v_flat = v.reshape(B, T, HkvD).astype(cv.dtype)
@@ -460,39 +516,41 @@ def forward_paged_impl(
             pages_v = cv.reshape(L * P, page_size, HkvD)
             layer_table = page_table + li * P  # (B, max_pages) into the big pool
 
-            if decode:
-                attn = paged_attention(q[:, 0], pages_k, pages_v, layer_table, lengths, Hkv,
-                                       window=cfg.sliding_window, mesh=mesh)
-                attn = attn[:, None]  # (B, 1, Hq, D)
-            elif mode == "prefill_chunk":
-                # Gather the slot's pages (prefix + just-written tail) and
-                # attend causally by absolute position.
-                kg = pages_k[layer_table].reshape(B, -1, Hkv, D).astype(q.dtype)
-                vg = pages_v[layer_table].reshape(B, -1, Hkv, D).astype(q.dtype)
-                if use_flash_prefill(T, kg.shape[1], D):
-                    attn = flash_prefill_attention(q, kg, vg, lengths, q_offsets=positions[:, 0],
-                                                   window=cfg.sliding_window)
-                else:
-                    attn = gqa_attend(q, kg, vg, chunk_mask)
-            elif ring_mesh is not None:
-                # Fresh long-prompt prefill over the sp ring; pages were
-                # just written above, attention runs on this call's k/v.
-                from inference_gateway_tpu.ops.ring_attention import make_ring_attention
+            def attend(window):
+                if decode:
+                    return paged_attention(q[:, 0], pages_k, pages_v, layer_table, lengths, Hkv,
+                                           window=window, mesh=mesh)[:, None]  # (B, 1, Hq, D)
+                if mode == "prefill_chunk":
+                    # Gather the slot's pages (prefix + just-written tail) and
+                    # attend causally by absolute position.
+                    kg = pages_k[layer_table].reshape(B, -1, Hkv, D).astype(q.dtype)
+                    vg = pages_v[layer_table].reshape(B, -1, Hkv, D).astype(q.dtype)
+                    if use_flash_prefill(T, kg.shape[1], D):
+                        return flash_prefill_attention(q, kg, vg, lengths, q_offsets=positions[:, 0],
+                                                       window=window)
+                    return gqa_attend(q, kg, vg, windowed(chunk_mask, key_pos[None, None, :], window))
+                if ring_mesh is not None:
+                    # Fresh long-prompt prefill over the sp ring; pages were
+                    # just written above, attention runs on this call's k/v.
+                    from inference_gateway_tpu.ops.ring_attention import make_ring_attention
 
-                attn = make_ring_attention(ring_mesh, axis="sp", causal=True)(q, k, v, lengths)
-            elif use_flash_prefill(T, T, D):
-                attn = flash_prefill_attention(q, k, v, lengths, window=cfg.sliding_window)
-            else:
-                attn = gqa_attend(q, k, v, mask)
+                    return make_ring_attention(ring_mesh, axis="sp", causal=True)(q, k, v, lengths)
+                if use_flash_prefill(T, T, D):
+                    return flash_prefill_attention(q, k, v, lengths, window=window)
+                return gqa_attend(q, k, v, windowed(prefill_mask, positions[:, None, :], window))
+
+            attn = _attend_by_window(cfg, li, attend)
             x = x + qmatmul(attn.reshape(B, T, Hq * D), lp["wo"])
         with jax.named_scope("mlp"):
-            x = x + ffn(x, lp, cfg)
-        return (x, ck, cv), None
+            y = ffn(x, h, lp, li, tok_valid)
+            y, aux = y if isinstance(y, tuple) else (y, None)
+            x = x + y
+        return (x, ck, cv), aux
 
     ck0 = cache["k"].reshape(total, HkvD)
     cv0 = cache["v"].reshape(total, HkvD)
-    (x, ck, cv), _ = jax.lax.scan(
-        body, (x, ck0, cv0), (params["layers"], jnp.arange(L))
+    (x, ck, cv), aux = jax.lax.scan(
+        body, (x, ck0, cv0), (_scan_layers(params, unscanned), jnp.arange(L))
     )
     new_cache = {"k": ck.reshape(L, P, page_size, HkvD),
                  "v": cv.reshape(L, P, page_size, HkvD)}
@@ -509,7 +567,7 @@ def forward_paged_impl(
             logits = (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
         else:
             logits = qmatmul(x, params["lm_head"]).astype(jnp.float32)
-    return logits, new_cache
+    return (logits, new_cache, aux) if with_aux else (logits, new_cache)
 
 
 # ---------------------------------------------------------------------------
@@ -544,7 +602,8 @@ def forward_ragged(
     instead of one program per prompt bucket, and no bucket padding —
     only the packed tail beyond the live tokens is dead work."""
     return forward_ragged_impl(params, cfg, tokens, positions, cache, write_idx,
-                               page_table, q_starts, q_lens, kv_lens, mesh, _dense_ffn)
+                               page_table, q_starts, q_lens, kv_lens, mesh,
+                               partial(_dense_ffn, cfg=cfg))
 
 
 def forward_ragged_impl(
@@ -559,8 +618,10 @@ def forward_ragged_impl(
     q_lens: jnp.ndarray,
     kv_lens: jnp.ndarray,
     mesh,
-    ffn,  # (x, lp, cfg) -> residual FFN contribution (MoE plugs in here)
-) -> tuple[jnp.ndarray, Params]:
+    ffn,  # the FFN seam of forward_paged_impl (MoE plugs in here)
+    unscanned: tuple = (),
+    with_aux: bool = False,
+):
     """Shared ragged skeleton, same flat-carry cache discipline as
     forward_paged_impl (the scatter lowers to an in-place row update;
     attention reads pages straight out of the big buffer)."""
@@ -571,13 +632,14 @@ def forward_ragged_impl(
     L, P, page_size, HkvD = cache["k"].shape
     flat = P * page_size
     total = L * flat
-    R = page_table.shape[0]
 
     x = params["embed"][tokens]
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
     inv_freq = rope_inv_freq(cfg.hd, cfg.rope_theta, cfg.rope_scaling_dict)
     cos, sin = rope_cos_sin(positions, inv_freq)
+    # Rows are packed back to back from 0: the tail past them is padding.
+    tok_valid = jnp.arange(T) < jnp.sum(q_lens)
 
     def body(carry, per_layer):
         x, ck, cv = carry
@@ -594,8 +656,7 @@ def forward_ragged_impl(
             q = q.reshape(B, T, Hq, D)
             k = k.reshape(B, T, Hkv, D)
             v = v.reshape(B, T, Hkv, D)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+            q, k = _rope_qk(q, k, cos, sin, cfg, li)
 
             k_flat = k.reshape(B, T, HkvD).astype(ck.dtype)
             v_flat = v.reshape(B, T, HkvD).astype(cv.dtype)
@@ -606,18 +667,20 @@ def forward_ragged_impl(
             pages_v = cv.reshape(L * P, page_size, HkvD)
             layer_table = page_table + li * P
 
-            attn = ragged_paged_attention(
+            attn = _attend_by_window(cfg, li, lambda window: ragged_paged_attention(
                 q[0], pages_k, pages_v, layer_table, q_starts, q_lens, kv_lens,
-                Hkv, window=cfg.sliding_window, mesh=mesh)[None]  # (1, T, Hq, D)
+                Hkv, window=window, mesh=mesh)[None])  # (1, T, Hq, D)
             x = x + qmatmul(attn.reshape(B, T, Hq * D), lp["wo"])
         with jax.named_scope("mlp"):
-            x = x + ffn(x, lp, cfg)
-        return (x, ck, cv), None
+            y = ffn(x, h, lp, li, tok_valid)
+            y, aux = y if isinstance(y, tuple) else (y, None)
+            x = x + y
+        return (x, ck, cv), aux
 
     ck0 = cache["k"].reshape(total, HkvD)
     cv0 = cache["v"].reshape(total, HkvD)
-    (x, ck, cv), _ = jax.lax.scan(
-        body, (x, ck0, cv0), (params["layers"], jnp.arange(L))
+    (x, ck, cv), aux = jax.lax.scan(
+        body, (x, ck0, cv0), (_scan_layers(params, unscanned), jnp.arange(L))
     )
     new_cache = {"k": ck.reshape(L, P, page_size, HkvD),
                  "v": cv.reshape(L, P, page_size, HkvD)}
@@ -632,7 +695,7 @@ def forward_ragged_impl(
             logits = (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
         else:
             logits = qmatmul(x, params["lm_head"]).astype(jnp.float32)
-    return logits, new_cache
+    return (logits, new_cache, aux) if with_aux else (logits, new_cache)
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,11 @@
-"""Mixtral-family sparse-MoE decoder in pure JAX.
+"""Sparse-MoE decoders in pure JAX: the Mixtral block, and the
+SmallThinker block (ISSUE 28: the router reads the attention's normed
+input, ReGLU experts, global NoPE layers beside windowed RoPE layers).
 
 Same TPU-first skeleton as models/llama.py (stacked layers + lax.scan,
-one forward for prefill/decode over the slot cache) with the dense MLP
-replaced by a top-2 mixture of 8 experts (ops/moe.py). Expert weights
-carry a leading expert axis sharded on the mesh's ``ep`` axis — the
-expert-parallel layout for BASELINE config 5 (Mixtral-8x7B over v5e-16).
+the shared paged and ragged forwards) with the dense MLP replaced by a
+top-k mixture of experts (ops/moe.py). Expert weights carry a leading
+expert axis; under a mesh with an ``ep`` axis it is sharded there.
 """
 
 from __future__ import annotations
@@ -16,11 +17,15 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from inference_gateway_tpu.models.llama import LlamaConfig, forward_paged_impl
+from inference_gateway_tpu.models.llama import (
+    LlamaConfig,
+    forward_paged_impl,
+    forward_ragged_impl,
+)
 from inference_gateway_tpu.ops.attention import causal_prefill_mask, decode_mask, gqa_attend
-from inference_gateway_tpu.ops.moe import default_capacity, moe_capacity, moe_dense
+from inference_gateway_tpu.ops.moe import ACTIVATIONS, moe_dense, moe_dropless
 from inference_gateway_tpu.ops.norms import rms_norm
-from inference_gateway_tpu.ops.quant import qeinsum, qmatmul
+from inference_gateway_tpu.ops.quant import qeinsum, qmatmul, seeded_std
 from inference_gateway_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
 
 
@@ -28,8 +33,20 @@ from inference_gateway_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_fr
 class MixtralConfig(LlamaConfig):
     num_experts: int = 8
     experts_per_token: int = 2
-    capacity_factor: float = 2.0
-    moe_impl: str = "capacity"  # "capacity" (EP-shardable) | "dense" (exact)
+    # "dropless": ops/moe.moe_dropless, the serving layer. "dense": every
+    # expert on every token, the tests' oracle. "capacity" is what the
+    # benchmark's Mixtral fixture still calls the serving layer (the one
+    # dropless replaced in PR 28) and means dropless.
+    moe_impl: str = "dropless"
+    # Which normed state the router reads: the experts' own input
+    # ("ffn", Mixtral) or the attention's ("attn", SmallThinker).
+    router_input: str = "ffn"
+
+    def __post_init__(self):
+        if self.moe_impl not in ("dropless", "dense", "capacity"):
+            raise ValueError(f"moe_impl {self.moe_impl!r}: dropless or dense")
+        if self.router_input not in ("ffn", "attn"):
+            raise ValueError(f"router_input {self.router_input!r}: ffn or attn")
 
 
 Params = dict[str, Any]
@@ -40,19 +57,19 @@ def init_params(rng: jax.Array, cfg: MixtralConfig, dtype=jnp.bfloat16) -> Param
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     keys = jax.random.split(rng, 10)
 
-    def norm(key, shape):
-        return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
+    def norm(key, shape, std=0.02):
+        return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
     params: Params = {
-        "embed": norm(keys[0], (V, H)),
+        "embed": norm(keys[0], (V, H), seeded_std(cfg, "embed")),
         "layers": {
             "attn_norm": jnp.ones((L, H), dtype),
             "wq": norm(keys[1], (L, H, Hq * D)),
             "wk": norm(keys[2], (L, H, Hkv * D)),
             "wv": norm(keys[3], (L, H, Hkv * D)),
-            "wo": norm(keys[4], (L, Hq * D, H)),
+            "wo": norm(keys[4], (L, Hq * D, H), seeded_std(cfg, "wo")),
             "moe_norm": jnp.ones((L, H), dtype),
-            "router": norm(keys[5], (L, H, E)),
+            "router": norm(keys[5], (L, H, E), seeded_std(cfg, "router")),
             # Expert FFNs: leading E axis → ep sharding.
             "wg": norm(keys[6], (L, E, H, I)),
             "wu": norm(keys[7], (L, E, H, I)),
@@ -70,27 +87,34 @@ def init_cache(cfg: MixtralConfig, batch: int, max_len: int, dtype=jnp.bfloat16)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
-def _moe_block(x: jnp.ndarray, lp: Params, cfg: MixtralConfig) -> jnp.ndarray:
-    """x: (B, T, H) → MoE FFN output."""
-    B, T, H = x.shape
-    flat = x.reshape(B * T, H)
-    router_logits = (flat @ lp["router"].astype(flat.dtype)).astype(jnp.float32)
+EXPERT_LEAVES = ("wg", "wu", "wd")
 
-    def expert_fn(inp):  # (E, N', H)
-        g = qeinsum("enh,ehi->eni", inp, lp["wg"])
-        u = qeinsum("enh,ehi->eni", inp, lp["wu"])
-        act = (jax.nn.silu(g) * u).astype(inp.dtype)
-        return qeinsum("eni,eih->enh", act, lp["wd"], out_dtype=inp.dtype)
 
+def _moe_block(flat: jnp.ndarray, routed: jnp.ndarray, router, experts: Params, li,
+               cfg: MixtralConfig, valid=None, mesh=None) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """flat: (N, H) the experts' normed input; routed: (N, H) the state
+    the router reads; experts: the stacked expert leaves, read at layer
+    ``li``; mesh: the one they are sharded over (param_specs), or None.
+    Returns (output (N, H), rows each expert multiplied (E,)). The router
+    is never quantised and its logits are float32."""
+    with jax.named_scope("moe.router"):
+        logits = jnp.dot(routed, router.astype(routed.dtype), preferred_element_type=jnp.float32)
     if cfg.moe_impl == "dense":
-        out = moe_dense(flat, router_logits, cfg.experts_per_token, expert_fn)
-    else:
-        cap = default_capacity(B * T, cfg.num_experts, cfg.experts_per_token, cfg.capacity_factor)
-        out = moe_capacity(flat, router_logits, cfg.experts_per_token, expert_fn, cap)
-    return out.reshape(B, T, H)
+        lw = {n: jax.tree.map(lambda a: a[li], experts[n]) for n in EXPERT_LEAVES}
+
+        def expert_fn(inp):  # (E, N, H)
+            g = qeinsum("enh,ehi->eni", inp, lw["wg"])
+            u = qeinsum("enh,ehi->eni", inp, lw["wu"])
+            act = (ACTIVATIONS[cfg.hidden_act](g) * u).astype(inp.dtype)
+            return qeinsum("eni,eih->enh", act, lw["wd"], out_dtype=inp.dtype)
+
+        return (moe_dense(flat, logits, cfg.experts_per_token, expert_fn),
+                jnp.zeros((cfg.num_experts,), jnp.int32))
+    return moe_dropless(flat, logits, cfg.experts_per_token, experts["wg"], experts["wu"],
+                        experts["wd"], li, act=cfg.hidden_act, valid=valid, mesh=mesh)
 
 
-@partial(jax.jit, static_argnames=("cfg", "mode", "last_only"))
+@partial(jax.jit, static_argnames=("cfg", "mode", "last_only", "mesh"))
 def forward(
     params: Params,
     cfg: MixtralConfig,
@@ -101,10 +125,14 @@ def forward(
     mode: str = "prefill",
     last_only: bool = False,
     slot_ids: jnp.ndarray | None = None,
+    mesh=None,
 ) -> tuple[jnp.ndarray, Params | None]:
-    """Same contract as models/llama.forward."""
+    """Same contract as models/llama.forward (contiguous cache, masked
+    einsum attention; ``--attention paged`` serves through forward_paged
+    and forward_ragged). ``mesh``: the one the experts are sharded over."""
     B, T = tokens.shape
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.hd
+    L = cfg.num_layers
     x = params["embed"][tokens]
     inv_freq = rope_inv_freq(cfg.hd, cfg.rope_theta, cfg.rope_scaling_dict)
     cos, sin = rope_cos_sin(positions, inv_freq)
@@ -114,52 +142,62 @@ def forward(
         assert cache is not None
         S = cache["k"].shape[2]
         mask = decode_mask(S, lengths)
+        in_window = jnp.arange(S)[None, None, :] > lengths[:, None, None] - 1 - (cfg.sliding_window or 0)
         scatter_pos = positions
+        tok_valid = None
     else:
         mask = causal_prefill_mask(positions, lengths)
+        in_window = positions[:, None, :] > positions[:, :, None] - (cfg.sliding_window or 0)
+        valid = jnp.arange(T)[None, :] < lengths[:, None]
+        tok_valid = valid.reshape(B * T)
         if cache is not None:
             S = cache["k"].shape[2]
-            valid = jnp.arange(T)[None, :] < lengths[:, None]
             scatter_pos = jnp.where(valid, positions, S)
         else:
             scatter_pos = None
+    # One flag a layer, as the shared skeletons read them (llama._rope_qk,
+    # _attend_by_window); here both kinds of layer are a mask apart.
+    rope_on = jnp.asarray(cfg.layer_rope or (1,) * L, bool)
+    window_on = jnp.asarray(cfg.layer_window or (1,) * L, bool) & bool(cfg.sliding_window)
+    experts = {n: params["layers"][n] for n in EXPERT_LEAVES}
+    scanned = {n: w for n, w in params["layers"].items() if n not in EXPERT_LEAVES}
 
-    def layer(x, lp, kc, vc):
+    def layer(x, lp, li, kc, vc):
         h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
         q = qmatmul(h, lp["wq"]).reshape(B, T, Hq, D)
         k = qmatmul(h, lp["wk"]).reshape(B, T, Hkv, D)
         v = qmatmul(h, lp["wv"]).reshape(B, T, Hkv, D)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        q = jnp.where(rope_on[li], apply_rope(q, cos, sin), q)
+        k = jnp.where(rope_on[li], apply_rope(k, cos, sin), k)
         nk = nv = None
         if kc is not None:
             rows = (jnp.arange(B) if slot_ids is None else slot_ids)[:, None]
             nk = kc.at[rows, scatter_pos].set(k.astype(kc.dtype), mode="drop")
             nv = vc.at[rows, scatter_pos].set(v.astype(vc.dtype), mode="drop")
+        layer_mask = mask & (in_window | ~window_on[li])
         if decode:
-            attn = gqa_attend(q, nk.astype(q.dtype), nv.astype(q.dtype), mask)
+            attn = gqa_attend(q, nk.astype(q.dtype), nv.astype(q.dtype), layer_mask)
         else:
-            attn = gqa_attend(q, k, v, mask)
+            attn = gqa_attend(q, k, v, layer_mask)
         x = x + qmatmul(attn.reshape(B, T, Hq * D), lp["wo"])
-
-        h = rms_norm(x, lp["moe_norm"], cfg.rms_norm_eps)
-        x = x + _moe_block(h, lp, cfg)
-        return x, nk, nv
+        y, _ = _moe_ffn(x, h, lp, li, tok_valid, cfg=cfg, experts=experts, mesh=mesh)
+        return x + y, nk, nv
 
     if cache is not None:
         def body(x, per_layer):
-            lp, kc, vc = per_layer
-            x, nk, nv = layer(x, lp, kc, vc)
+            lp, li, kc, vc = per_layer
+            x, nk, nv = layer(x, lp, li, kc, vc)
             return x, (nk, nv)
 
-        x, (new_k, new_v) = jax.lax.scan(body, x, (params["layers"], cache["k"], cache["v"]))
+        x, (new_k, new_v) = jax.lax.scan(body, x, (scanned, jnp.arange(L), cache["k"], cache["v"]))
         new_cache = {"k": new_k, "v": new_v}
     else:
-        def body(x, lp):
-            x, _, _ = layer(x, lp, None, None)
+        def body(x, per_layer):
+            lp, li = per_layer
+            x, _, _ = layer(x, lp, li, None, None)
             return x, None
 
-        x, _ = jax.lax.scan(body, x, params["layers"])
+        x, _ = jax.lax.scan(body, x, (scanned, jnp.arange(L)))
         new_cache = None
 
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
@@ -173,13 +211,26 @@ def forward(
     return logits, new_cache
 
 
-def _moe_ffn(x: jnp.ndarray, lp: Params, cfg: MixtralConfig) -> jnp.ndarray:
-    """Norm + MoE residual contribution for the shared paged skeleton."""
+def _moe_ffn(x: jnp.ndarray, attn_in: jnp.ndarray, lp: Params, li, valid, *,
+             cfg: MixtralConfig, experts: Params, mesh=None):
+    """The FFN seam of the shared skeletons (llama.forward_paged_impl):
+    norm + mixture; the auxiliary is the rows each expert multiplied."""
+    B, T, H = x.shape
     h = rms_norm(x, lp["moe_norm"], cfg.rms_norm_eps)
-    return _moe_block(h, lp, cfg)
+    routed = attn_in if cfg.router_input == "attn" else h
+    out, rows = _moe_block(h.reshape(B * T, H), routed.reshape(B * T, H), lp["router"],
+                           experts, li, cfg, valid, mesh)
+    return out.reshape(B, T, H), rows
 
 
-@partial(jax.jit, static_argnames=("cfg", "mode", "last_only", "mesh"))
+def _seam(params: Params, cfg: MixtralConfig, mesh) -> dict:
+    """What the shared skeletons take for a mixture: the seam, and the
+    leaves it reads whole by layer index (never sliced per layer)."""
+    experts = {n: params["layers"][n] for n in EXPERT_LEAVES}
+    return {"ffn": partial(_moe_ffn, cfg=cfg, experts=experts, mesh=mesh), "unscanned": EXPERT_LEAVES}
+
+
+@partial(jax.jit, static_argnames=("cfg", "mode", "last_only", "mesh", "with_aux"))
 def forward_paged(
     params: Params,
     cfg: MixtralConfig,
@@ -192,13 +243,37 @@ def forward_paged(
     mode: str = "prefill",
     last_only: bool = True,
     mesh=None,
-) -> tuple[jnp.ndarray, Params]:
-    """Paged-KV MoE serving (round-1 verdict next #10: the engine no
-    longer forces dense slots for Mixtral). Attention/paging is the
-    shared skeleton (llama.forward_paged_impl); experts ride the MoE
-    block."""
+    valid: jnp.ndarray | None = None,
+    with_aux: bool = False,
+):
+    """Paged-KV MoE serving: attention and paging are the shared
+    skeleton (llama.forward_paged_impl); experts ride the seam. With
+    ``with_aux`` a third result is the rows each expert multiplied,
+    (L, E); ``valid`` (B,) marks the decode rows that carry a stream."""
     return forward_paged_impl(params, cfg, tokens, positions, lengths, cache,
-                              write_idx, page_table, mode, last_only, mesh, _moe_ffn)
+                              write_idx, page_table, mode, last_only, mesh,
+                              valid=valid, with_aux=with_aux, **_seam(params, cfg, mesh))
+
+
+@partial(jax.jit, static_argnames=("cfg", "mesh", "with_aux"))
+def forward_ragged(
+    params: Params,
+    cfg: MixtralConfig,
+    tokens: jnp.ndarray,
+    positions: jnp.ndarray,
+    cache: Params,
+    write_idx: jnp.ndarray,
+    page_table: jnp.ndarray,
+    q_starts: jnp.ndarray,
+    q_lens: jnp.ndarray,
+    kv_lens: jnp.ndarray,
+    mesh=None,
+    with_aux: bool = False,
+):
+    """The mixed step for a mixture (llama.forward_ragged's contract)."""
+    return forward_ragged_impl(params, cfg, tokens, positions, cache, write_idx,
+                               page_table, q_starts, q_lens, kv_lens, mesh,
+                               with_aux=with_aux, **_seam(params, cfg, mesh))
 
 
 def param_specs(cfg: MixtralConfig) -> dict:
@@ -226,6 +301,21 @@ def param_specs(cfg: MixtralConfig) -> dict:
     return specs
 
 
+def _smallthinker(num_layers: int, **sizes) -> MixtralConfig:
+    """SmallThinker's block: the router reads the attention's normed
+    input, ReGLU experts, and of every four layers the first is global
+    with no position encoding, the other three windowed with rotary
+    (``rope_layout`` and ``sliding_window_layout`` of its config.json)."""
+    layout = tuple(int(i % 4 != 0) for i in range(num_layers))
+    return MixtralConfig(num_layers=num_layers, layer_rope=layout, layer_window=layout,
+                         hidden_act="relu", router_input="attn", rms_norm_eps=1e-6, **sizes)
+
+
+_SMALLTHINKER_21B = dict(
+    vocab_size=151936, hidden_size=2560, num_heads=28, num_kv_heads=4, head_dim=128,
+    intermediate_size=768, num_experts=64, experts_per_token=6, rope_theta=1500000.0,
+    max_position_embeddings=16384, sliding_window=4096)
+
 PRESETS: dict[str, MixtralConfig] = {
     "mixtral-test-tiny": MixtralConfig(
         vocab_size=256, hidden_size=64, num_layers=2, num_heads=4, num_kv_heads=2,
@@ -237,4 +327,15 @@ PRESETS: dict[str, MixtralConfig] = {
         intermediate_size=14336, num_experts=8, experts_per_token=2,
         rope_theta=1000000.0, max_position_embeddings=32768,
     ),
+    # One period, a window shorter than the tests' sequences.
+    "smallthinker-test-tiny": _smallthinker(
+        4, vocab_size=256, hidden_size=64, num_heads=4, num_kv_heads=2, head_dim=16,
+        intermediate_size=32, num_experts=8, experts_per_token=3, rope_theta=10000.0,
+        max_position_embeddings=512, sliding_window=16),
+    # PowerInfer/SmallThinker-21BA3B-Instruct is 52 layers, 21.9 GB of int8:
+    # no single chip holds it and nothing here serves a pipeline of paged
+    # stages yet. This is its first stage of four: 16 layers = 4 whole
+    # periods, every width, all 64 experts and the whole vocabulary as
+    # published, which one v5e serves.
+    "smallthinker-21b-a3b-stage16": _smallthinker(16, **_SMALLTHINKER_21B),
 }

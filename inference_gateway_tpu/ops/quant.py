@@ -160,6 +160,35 @@ def quantize_llama_params(params: dict, mode: str = "int8", group: int = 128) ->
     return out
 
 
+def seeded_std(cfg, leaf: str) -> float:
+    """Scale of a seeded build's ``leaf``: 0.02, except in a mixture the
+    token embedding (1.0), the attention's output projection (0.005) and
+    the router (0.06).
+
+    Embedding and output projection, so that the routers read states as
+    different as the tokens are: at 0.02 throughout, the map (what all
+    tokens share) -> Wv -> Wo has gain 1.2, attention over seeded weights
+    feeds the shared part back larger in every layer, the residual stream
+    is soon nearly one vector for every token, and a decode step's 48
+    rows x 6 choices fell on ~30 of SmallThinker's 64 experts a layer:
+    half the expert bytes a trained, load-balanced router reads (40 with
+    the embedding alone at 1.0, 62 with both; my chip runs, PR 28).
+
+    The router, so that a choice that flips matters little: served in
+    bf16, a sixth and a seventh logit closer than the rounding noise swap
+    places against a float32 reference, and at 0.02 the sixth expert's
+    weight is a tenth of the layer's output (one run in seven then read a
+    logprob 0.51 nats off, past the benchmark's 0.45). At 0.06 the chosen
+    six weigh about .61 .18 .09 .06 .04 .03: which experts are chosen, and
+    so every byte and operation, is the same.
+
+    An untied head is assumed (tied, unit-scale embeddings would give
+    logits of scale 50)."""
+    if getattr(cfg, "num_experts", 0):
+        return {"embed": 1.0, "wo": 0.005, "router": 0.06}.get(leaf, 0.02)
+    return 0.02
+
+
 def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
                                 group: int = 128, dtype=jnp.bfloat16) -> dict:
     """Random-init + quantize a llama tree WITHOUT materializing the
@@ -177,6 +206,12 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
     Tree structure/dtypes exactly match quantize_llama_params(
     llama.init_params(...)); the random values differ (keys are
     folded per layer), which is irrelevant for perf benches.
+
+    A mixture (``cfg.num_experts``; models/mixtral.py's tree) gets its
+    expert leaves (L, E, in, out) the same way, one layer's E experts
+    at a time, written into the stacked leaf in place (a whole expert
+    leaf of SmallThinker's 16-layer stage is 8 GB in float32; a layer's
+    block is 0.5 GB), and a full-precision router.
     """
     from functools import partial as _partial
 
@@ -186,34 +221,59 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
     quant = quantize_tensor if mode == "int8" else (
         lambda w: quantize_tensor_int4(w, group))
 
-    def norm(key, shape):
-        return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
+    def norm(key, shape, std=0.02):
+        return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
-    @_partial(jax.jit, static_argnums=(1,))
-    def qinit(key, shape):
-        return quant(norm(key, shape))
+    @_partial(jax.jit, static_argnums=(1, 2))
+    def qinit(key, shape, std=0.02):
+        return quant(norm(key, shape, std))
 
-    def qstack(key, shape):
-        per = [qinit(jax.random.fold_in(key, layer), shape[1:]) for layer in range(L)]
+    def qstack(key, shape, std=0.02):
+        per = [qinit(jax.random.fold_in(key, layer), shape[1:], std) for layer in range(L)]
         q = jnp.stack([p.q for p in per])
         scale = jnp.stack([p.scale for p in per])
         return type(per[0])(q, scale)
 
-    params = {
-        "embed": norm(keys[0], (V, H)),
-        "layers": {
-            "attn_norm": jnp.ones((L, H), dtype),
-            "wq": qstack(keys[1], (L, H, Hq * D)),
-            "wk": qstack(keys[2], (L, H, Hkv * D)),
-            "wv": qstack(keys[3], (L, H, Hkv * D)),
-            "wo": qstack(keys[4], (L, Hq * D, H)),
+    @_partial(jax.jit, donate_argnums=(0,))
+    def put(stacked, block, layer):
+        return jax.tree.map(lambda s, b: jax.lax.dynamic_update_index_in_dim(s, b, layer, 0),
+                            stacked, block)
+
+    def qfill(key, shape):
+        """qstack for a leaf too large to hold twice: the stacked leaf is
+        allocated once and each layer's block is written into it."""
+        block = jax.eval_shape(lambda: qinit(key, shape[1:]))
+        stacked = jax.tree.map(lambda b: jnp.zeros((L,) + b.shape, b.dtype), block)
+        for layer in range(L):
+            stacked = put(stacked, qinit(jax.random.fold_in(key, layer), shape[1:]), layer)
+        return stacked
+
+    layers = {
+        "attn_norm": jnp.ones((L, H), dtype),
+        "wq": qstack(keys[1], (L, H, Hq * D)),
+        "wk": qstack(keys[2], (L, H, Hkv * D)),
+        "wv": qstack(keys[3], (L, H, Hkv * D)),
+        "wo": qstack(keys[4], (L, Hq * D, H), seeded_std(cfg, "wo")),
+    }
+    E = getattr(cfg, "num_experts", 0)
+    if E:
+        ekeys = jax.random.split(jax.random.fold_in(rng, 7), 4)
+        layers.update({
+            "moe_norm": jnp.ones((L, H), dtype),
+            "router": norm(ekeys[0], (L, H, E), seeded_std(cfg, "router")),
+            "wg": qfill(ekeys[1], (L, E, H, I)),
+            "wu": qfill(ekeys[2], (L, E, H, I)),
+            "wd": qfill(ekeys[3], (L, E, I, H)),
+        })
+    else:
+        layers.update({
             "mlp_norm": jnp.ones((L, H), dtype),
             "wg": qstack(keys[5], (L, H, I)),
             "wu": qstack(keys[6], (L, H, I)),
             "wd": qstack(keys[7], (L, I, H)),
-        },
-        "final_norm": jnp.ones((H,), dtype),
-    }
+        })
+    params = {"embed": norm(keys[0], (V, H), seeded_std(cfg, "embed")), "layers": layers,
+              "final_norm": jnp.ones((H,), dtype)}
     if cfg.qkv_bias:
         params["layers"]["bq"] = jnp.zeros((L, Hq * D), dtype)
         params["layers"]["bk"] = jnp.zeros((L, Hkv * D), dtype)

@@ -130,10 +130,20 @@ def named(mesh: Mesh, spec_tree):
     )
 
 
-def shard_params(params, mesh: Mesh, specs) -> dict:
-    """Device-put an existing pytree onto the mesh per the spec tree."""
+def shard_params(params, mesh: Mesh, specs, donate: bool = False) -> dict:
+    """Device-put an existing pytree onto the mesh per the spec tree.
+
+    ``donate``: the tree is the caller's to give up (a seeded build).
+    Each leaf is then laid out, waited for and freed before the next, so
+    the device that built the tree holds it plus one leaf's shards at
+    most, never the tree twice: SmallThinker's 7.5 GB stage ran device 0
+    of a four-chip host out of memory laid out in one call (my chip run,
+    PR 28). The build itself still starts on one device (ROADMAP D14)."""
     shardings = named(mesh, specs)
-    return jax.device_put(params, shardings)
+    if not donate:
+        return jax.device_put(params, shardings)
+    return jax.tree.map(lambda leaf, s: jax.block_until_ready(jax.device_put(leaf, s, donate=True)),
+                        params, shardings)
 
 
 def check_divisibility(cfg: LlamaConfig, mesh: Mesh) -> None:

@@ -13,7 +13,7 @@ from inference_gateway_tpu.serving.engine import EngineConfig  # noqa: E402
 from inference_gateway_tpu.serving.server import serve  # noqa: E402
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(description="TPU serving sidecar (OpenAI-compatible)")
     p.add_argument("--model", default="tinyllama-1.1b", help="preset name or local HF checkpoint path")
     p.add_argument("--checkpoint", default=None, help="orbax checkpoint directory to restore")
@@ -47,20 +47,14 @@ def main() -> None:
                         "there is no chip, cpu is dev serving with interpreted "
                         "kernels; unset, jax takes a chip if it finds one and "
                         "the CPU otherwise")
-    args = p.parse_args()
+    return p
 
-    if args.platform:
-        from inference_gateway_tpu.utils.platform import force_platform
 
-        force_platform(args.platform)
-
-    # Multi-host pods: join the jax.distributed world before touching
-    # devices (no-op single-host).
-    from inference_gateway_tpu.parallel.distributed import initialize_distributed
-
-    initialize_distributed()
-
-    cfg = EngineConfig(
+def engine_config(args: argparse.Namespace) -> EngineConfig:
+    """The command line's engine: every flag that shapes the weights, the
+    cache or the step programs, mapped in this one place (the server's
+    own flags, host, port, names and the push URL, are ``main``'s)."""
+    return EngineConfig(
         model=args.model,
         checkpoint_path=args.checkpoint,
         max_slots=args.max_slots,
@@ -77,6 +71,23 @@ def main() -> None:
         spec_k=args.spec_k,
         spec_adaptive=args.spec_adaptive,
     )
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+
+    if args.platform:
+        from inference_gateway_tpu.utils.platform import force_platform
+
+        force_platform(args.platform)
+
+    # Multi-host pods: join the jax.distributed world before touching
+    # devices (no-op single-host).
+    from inference_gateway_tpu.parallel.distributed import initialize_distributed
+
+    initialize_distributed()
+
+    cfg = engine_config(args)
     asyncio.run(serve(cfg, host=args.host, port=args.port, served_model_name=args.served_model_name,
                       metrics_push_url=args.metrics_push_url, started_ns=STARTED_NS))
 

@@ -1174,6 +1174,7 @@ class Scheduler:
                 if observing:
                     spans["phases"] = phases
                     spans["h2d_arrays"] = handle.h2d_arrays
+                    spans.update(handle.stats or {})  # a mixture's expert counts
                     if prep is not None:  # the admission's first record carries it
                         spans["prep"] = {k: round(v, 4) for k, v in prep.items()}
                         prep = None
@@ -1665,7 +1666,9 @@ class Scheduler:
                               batch=len(inf.states), tokens=emitted,
                               context_tokens=ctx,
                               spans={**(inf.submit or {}), "phases": phases,
-                                     "h2d_arrays": getattr(inf.handle, "h2d_arrays", 0)})
+                                     "h2d_arrays": getattr(inf.handle, "h2d_arrays", 0),
+                                     # a mixture's experts_read, from the fetch block
+                                     **(getattr(inf.handle, "stats", None) or {})})
 
     def _release_guarded(self, slot: int, reason: str | None,
                          frozen: bool = False) -> None:

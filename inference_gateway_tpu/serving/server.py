@@ -1001,6 +1001,10 @@ class SidecarServer:
             # The device this process holds, as JAX reports it — never
             # assumed by a reader (chip_smoke.py checks it from here).
             "accelerator": getattr(self.engine, "device", None),
+            # The engine's mesh as axis -> size (ISSUE 28): {"tp": 1} on one
+            # device. A reader that counts a chip's bytes wants to know
+            # how the weights are split, not assume it.
+            "mesh": getattr(self.engine, "mesh_axes", None),
             # The paged-attention dispatch verdict (ISSUE 12 satellite):
             # which path this engine's layouts take and why — "gather"
             # here means the ~10.6×-slower fallback is live.

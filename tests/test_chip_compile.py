@@ -121,3 +121,83 @@ def test_tp4_shard_map_launch_compiles_for_v5e(topo):
         lambda q, k, v, table, starts, lens, kv: ragged_paged_attention_sharded(
             q, k, v, table, starts, lens, kv, HKV, mesh, window=WINDOW, interpret=False),
         heads((MIXED_BUDGET, HQ, D), BF16), _pool(pages), _pool(pages), table, row, row, row)
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """``utils.platform.on_tpu`` says yes, as on the chip: the expert layer
+    then makes its own choice of kernel, compiled and not interpreted."""
+    monkeypatch.setattr("inference_gateway_tpu.utils.platform.device_platform", lambda: "tpu")
+
+
+@pytest.mark.parametrize("tokens", [48, 560], ids=["decode_48_rows", "mixed_step_560_tokens"])
+def test_dropless_expert_layer_compiles_for_v5e_with_no_copy_of_the_stack(one_chip, as_on_tpu, tokens):
+    """SmallThinker's expert layer at its published widths (64 experts of
+    2560 x 768, top-6, int8 stacks of the 16-layer stage) through the chip's
+    compiler: both grouped matmuls are kernels, the stacks reach them whole
+    (neither a bf16 copy of a layer's experts, which ``jax.lax.ragged_dot`` on
+    the dequantised layer makes, nor an int8 slice of a leaf), and the
+    temporaries are the rows', a few MB."""
+    from inference_gateway_tpu.ops.moe import moe_dropless
+    from inference_gateway_tpu.ops.quant import QTensor
+
+    L, E, H, I, K = 16, 64, 2560, 768, 6
+
+    def stack(k, n):
+        return QTensor(one_chip((L, E, k, n), jnp.int8), one_chip((L, E, 1, n), jnp.float32))
+
+    compiled = jax.jit(lambda x, logits, wg, wu, wd, li: moe_dropless(
+        x, logits, K, wg, wu, wd, li, act="relu", valid=jnp.arange(tokens) < tokens - 3)).lower(
+        one_chip((tokens, H), BF16), one_chip((tokens, E), jnp.float32), stack(H, I), stack(H, I), stack(I, H),
+        one_chip((), I32)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "moe_gate_up_gmm" in text and "moe_down_gmm" in text
+    assert f"bf16[{E},{H},{I}]" not in text and f"s8[1,{E},{H},{I}]" not in text and f"s8[{E},{H},{I}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# (sizes L, E, H, I, top-k; tokens): SmallThinker's stage at a decode step and a
+# mixed step, Mixtral-8x7B at a mixed step of chip_smoke.py's budget.
+SMALLTHINKER, MIXTRAL = (16, 64, 2560, 768, 6), (32, 8, 4096, 14336, 2)
+
+
+@pytest.mark.parametrize("sizes,tokens,ep,tp", [
+    (SMALLTHINKER, 48, 4, 1), (SMALLTHINKER, 560, 4, 1), (SMALLTHINKER, 560, 2, 2), (SMALLTHINKER, 48, 1, 4),
+    (MIXTRAL, MIXED_BUDGET, 4, 1), (MIXTRAL, MIXED_BUDGET, 1, 4),
+], ids=["smallthinker_decode_ep4", "smallthinker_mixed_ep4", "smallthinker_mixed_ep2_tp2",
+        "smallthinker_decode_tp4", "mixtral_mixed_ep4", "mixtral_mixed_tp4"])
+def test_dropless_expert_layer_compiles_on_the_four_chip_mesh(topo, as_on_tpu, sizes, tokens, ep, tp):
+    """The layer as the engine calls it (no override: ``on_tpu`` alone picks the
+    kernel) on a v5e:2x2 with the int8 stacks laid out by ``param_specs``: a
+    Mosaic kernel cannot be partitioned automatically, so the layer launches it
+    under ``shard_map`` on each shard's own experts and columns, and the only
+    collective is the one all-reduce of the (tokens, hidden) result; no shard
+    gathers an expert it does not hold, and no copy of a stack is made.
+    SmallThinker's 768 columns over tp = 4 are 192 a shard, not whole lanes: the
+    kernel would have the compiler re-lay the whole stack out per call (1.3 GB),
+    so that shard's gate and up matrices take the twin, one layer's copy."""
+    from inference_gateway_tpu.models import mixtral
+    from inference_gateway_tpu.ops.moe import moe_dropless
+    from inference_gateway_tpu.ops.quant import QTensor
+    from inference_gateway_tpu.parallel.sharding import quantized_specs
+
+    L, E, H, I, K = sizes
+    mesh = Mesh(np.array(topo.devices).reshape(1, 1, ep, tp), ("dp", "sp", "ep", "tp"))
+    specs = quantized_specs(mixtral.param_specs(mixtral.PRESETS["mixtral-test-tiny"]))["layers"]
+
+    def on(spec):
+        return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=NamedSharding(mesh, spec))
+
+    def stack(name, k, n):
+        return QTensor(on(specs[name].q)((L, E, k, n), jnp.int8), on(specs[name].scale)((L, E, 1, n), jnp.float32))
+
+    rep = on(P())
+    compiled = jax.jit(lambda x, logits, wg, wu, wd, li: moe_dropless(
+        x, logits, K, wg, wu, wd, li, act="relu", valid=jnp.arange(tokens) < tokens - 3, mesh=mesh)).lower(
+        rep((tokens, H), BF16), rep((tokens, E), jnp.float32), stack("wg", H, I), stack("wu", H, I),
+        stack("wd", I, H), rep((), I32)).compile()
+    text = compiled.as_text()
+    assert "moe_down_gmm" in text and ("moe_gate_up_gmm" in text) == ((I // tp) % 128 == 0)
+    assert text.count("all-reduce(") == 1 and "all-gather(" not in text and "all-to-all(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20

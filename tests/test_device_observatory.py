@@ -300,6 +300,10 @@ async def test_hbm_pane_reports_plan_and_never_fabricates_live_bytes():
     # /debug/status carries all three panes for the fleet view.
     status = json.loads((await sidecar.debug_status(_req("/debug/status"))).body)
     assert set(status["device"]) == {"compile", "transfers", "hbm"}
+    # ... and the engine's mesh as axis -> size (ISSUE 28): the byte-counting
+    # readers of the benchmark take the layout from here, never assume it.
+    assert status["mesh"] == sidecar.engine.mesh_axes and all(
+        isinstance(v, int) and v >= 1 for v in status["mesh"].values()) and "tp" in status["mesh"]
     brief = json.loads((await sidecar.debug_status(
         _req("/debug/status", {"brief": ["1"]}))).body)
     assert {"compiles", "recompiles", "h2d_chain", "hbm_measured",

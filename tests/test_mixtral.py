@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from inference_gateway_tpu.models import mixtral
-from inference_gateway_tpu.ops.moe import default_capacity, moe_capacity, moe_dense, router_topk
+from inference_gateway_tpu.ops.moe import moe_dense, moe_dropless, router_topk
 
 
 @pytest.fixture(scope="module")
@@ -32,34 +32,25 @@ def test_router_topk():
     np.testing.assert_allclose(float(w.sum()), 1.0, rtol=1e-6)
 
 
-def test_capacity_matches_dense_when_no_drops():
+@pytest.mark.parametrize("kernel", [False, True], ids=["ragged_dot", "pallas_interpret"])
+def test_dropless_matches_dense(kernel):
+    """The dropless layer against the all-experts oracle (re-pointed from
+    the capacity layer's no-drop case, PR 28): stacked leaves, layer 1 of 2."""
     rng = np.random.default_rng(0)
-    N, H, E, k = 16, 8, 4, 2
+    N, H, I, E, k, L = 16, 8, 12, 4, 2, 2
     x = jnp.asarray(rng.normal(size=(N, H)).astype(np.float32))
     logits = jnp.asarray(rng.normal(size=(N, E)).astype(np.float32))
-    w = jnp.asarray(rng.normal(size=(E, H, H)).astype(np.float32) * 0.1)
+    wg, wu = (jnp.asarray(rng.normal(size=(L, E, H, I)).astype(np.float32) * 0.1) for _ in range(2))
+    wd = jnp.asarray(rng.normal(size=(L, E, I, H)).astype(np.float32) * 0.1)
 
     def expert_fn(inp):  # (E, n, H)
-        return jnp.einsum("enh,ehj->enj", inp, w)
+        h = jax.nn.silu(jnp.einsum("enh,ehi->eni", inp, wg[1])) * jnp.einsum("enh,ehi->eni", inp, wu[1])
+        return jnp.einsum("eni,eih->enh", h, wd[1])
 
     dense = moe_dense(x, logits, k, expert_fn)
-    cap = moe_capacity(x, logits, k, expert_fn, capacity=N)  # no drops possible
-    np.testing.assert_allclose(np.asarray(cap), np.asarray(dense), rtol=1e-4, atol=1e-5)
-
-
-def test_capacity_drops_overflow():
-    # All tokens route to expert 0; capacity 4 keeps only the first 4.
-    N, H, E = 8, 4, 2
-    x = jnp.ones((N, H))
-    logits = jnp.asarray(np.tile([10.0, -10.0], (N, 1)).astype(np.float32))
-
-    def expert_fn(inp):
-        return inp
-
-    out = moe_capacity(x, logits, 1, expert_fn, capacity=4)
-    # First 4 tokens pass through (weight 1 on identity expert), rest dropped → 0.
-    np.testing.assert_allclose(np.asarray(out[:4]).sum(), 4 * H, rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(out[4:]).sum(), 0.0, atol=1e-6)
+    out, rows = moe_dropless(x, logits, k, wg, wu, wd, 1, kernel=kernel)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(dense), rtol=1e-4, atol=1e-5)
+    assert int(rows.sum()) == N * k
 
 
 def test_logits_match_hf(hf_tiny):
@@ -69,7 +60,7 @@ def test_logits_match_hf(hf_tiny):
 
     hf_cfg, model = hf_tiny
     cfg = mixtral_config_from_hf(hf_cfg)
-    # Exact comparison requires the no-drop dense path.
+    # The all-experts oracle, as HF computes it.
     cfg = mixtral.MixtralConfig(
         **{**cfg.__dict__, "moe_impl": "dense", "rope_scaling": cfg.rope_scaling}
     )
@@ -107,8 +98,8 @@ def test_prefill_decode_cache_consistency():
             params, cfg, tokens[:, t:t + 1], jnp.full((B, 1), t), jnp.full((B,), t + 1),
             cache, mode="decode",
         )
-        # Capacity path: dispatch groups differ between batched prefill and
-        # single-token decode, so allow small numerical drift.
+        # Summation order differs between batched prefill and single-token
+        # decode, so allow small numerical drift.
         np.testing.assert_allclose(np.asarray(logits[:, 0]), np.asarray(full[:, t]), rtol=1e-3, atol=1e-3)
 
 
@@ -131,8 +122,3 @@ def test_ep_sharded_forward_matches_single_device():
     with jax.sharding.set_mesh(mesh):
         out, _ = mixtral.forward(sharded, cfg, tokens, positions, lengths, mode="prefill")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4)
-
-
-def test_default_capacity():
-    assert default_capacity(128, 8, 2) == 64
-    assert default_capacity(4, 8, 2) == 8
