@@ -321,6 +321,21 @@ class Scheduler:
         # nothing queued although the scheduler had work.
         self._dev_touch: int | None = None
         self._starve_t0: int | None = None
+        # Delivery after dispatch (ISSUE 29). A flush costs the scheduler
+        # thread the event loop's turn (~0.3 ms a request: the wake-up
+        # write hands the GIL over and the loop serialises that stream's
+        # frames before it comes back). Chained chunks hide it behind the
+        # chunk already on the device; an admission's mixed steps and its
+        # fresh decode submit found the device empty. There the decode
+        # rows' flushes wait on this list and _deliver_deferred runs them
+        # right after the next dispatch returns. Nothing is held without
+        # a dispatch behind it: whenever run() is back at its top the list
+        # is empty, or the submit stood back for an admission that is due
+        # (_admission_due): the pass opens with it, on an empty device,
+        # and delivers behind its first step.
+        self._deferred: list[GenRequest] = []
+        self.flushes_deferred = 0   # running totals, /debug/status
+        self.flushes_immediate = 0
 
     def active_requests(self) -> int:
         return len(self._slots)
@@ -406,6 +421,8 @@ class Scheduler:
             for st in list(self._slots.values()):
                 self._fail_request(st.req)
                 failed += 1
+        # The wedged thread may never reach its next dispatch.
+        self._deliver_deferred()
         return failed
 
     # -- adaptive speculation (EngineConfig.spec_adaptive) -------------
@@ -493,8 +510,7 @@ class Scheduler:
                 self._dev_touch = None
             if self.preempt_max and self.preempt_high_water > 0:
                 self._maybe_high_water_preempt()
-            with self._wake:
-                want_admit = bool(self._waiting and self._free) and self._admit_ready()
+            want_admit = self._admission_due()
             if self.engine.spec and self._spec_turn():
                 # Speculative rounds are synchronous (draft + verify per
                 # round, 1..K+1 tokens out); no chunk pipeline.
@@ -548,7 +564,21 @@ class Scheduler:
                 # already-finished streams — drain them now, or the loop
                 # busy-spins on an unprocessable pure-chunk tail.
                 self._drain_all()
+            # Deliveries still waiting: no dispatch came. Where the submit
+            # stood back for an admission, the next pass opens with it and
+            # delivers behind its first step; after a submit that failed,
+            # or the last request finishing, deliver now.
+            if self._deferred and not self._admission_due():
+                self._deliver_deferred()
             self._process_handles()
+        self._deliver_deferred()  # stopped
+
+    def _admission_due(self) -> bool:
+        """A request waits, a slot is free, and pages are not what it
+        waits for: run() admits at its next pass, and _submit_chunk
+        stands back for it."""
+        with self._wake:
+            return bool(self._waiting and self._free) and self._admit_ready()
 
     def _process_handles(self) -> None:
         """Process outstanding handles FIFO, keeping up to the newest
@@ -569,10 +599,10 @@ class Scheduler:
         while self._handles:
             self._process_one(self._handles.popleft())
 
-    def _process_one(self, h: object) -> None:
+    def _process_one(self, h: object, defer: bool = False) -> None:
         try:
             if isinstance(h, _Inflight):
-                self._process_chunk(h)
+                self._process_chunk(h, defer)
             else:
                 self._process_prefill(h)
         except Exception as e:
@@ -581,16 +611,40 @@ class Scheduler:
             # it kill the scheduler thread.
             self._fail_after_decode_error(e)
 
-    @staticmethod
-    def _flush_emits(req: GenRequest) -> None:
+    def _flush_emits(self, req: GenRequest, defer: bool = False) -> None:
         """Step-boundary flush for token-batching consumers; a dead
         client's flush must never kill the batch (same contract as
-        ``callback``)."""
-        if req.flush_callback is not None:
-            try:
-                req.flush_callback()
-            except Exception:
-                pass
+        ``callback``). ``defer``: the device has nothing queued and the
+        caller dispatches next, so the flush waits for that dispatch
+        (_deliver_deferred). A later immediate flush of the request
+        carries the waiting tokens with it, in order."""
+        if req.flush_callback is None:
+            return
+        if defer:
+            self._deferred.append(req)
+            return
+        self.flushes_immediate += 1
+        try:
+            req.flush_callback()
+        except Exception:
+            pass
+
+    def _deliver_deferred(self, spans: dict | None = None,
+                          phases: dict | None = None) -> None:
+        """Run the flushes that waited for a dispatch, in order. Called
+        right after one returned, with that step's ``spans`` and
+        ``phases`` to stamp (``deferred_flushes``, ``deliver``), and bare
+        wherever a pass ends without one."""
+        todo, self._deferred = self._deferred, []
+        if spans is not None:
+            spans["deferred_flushes"] = len(todo)
+        with phase_span(phases, "deliver", "sched.deliver"):
+            for req in todo:
+                try:
+                    req.flush_callback()
+                except Exception:
+                    pass
+        self.flushes_deferred += len(todo)
 
     def _fail_request(self, req: GenRequest) -> None:
         req.phase_ns.setdefault("finish", time.time_ns())
@@ -1010,6 +1064,7 @@ class Scheduler:
                 slots.append(self._free.pop())
             self.queue_depth = len(self._waiting)
         if not batch:
+            self._deliver_deferred()  # what the pass before carried in: no step follows
             if multimodal_head:
                 return self._admit()  # bucketed path carries the embeds
             return
@@ -1053,11 +1108,17 @@ class Scheduler:
             self._admitting = batch
             if not batch:
                 self._admitting = []
+                self._deliver_deferred()
                 return
             # Host state must be authoritative before positions move under
             # the pipeline's feet — and the carry is about to be invalidated.
+            # A chunk with another behind it delivers as it always did;
+            # the last one leaves the device empty, so its delivery waits
+            # for the mixed step's dispatch.
             with phase_span(prep, "drain_ms", "sched.admit.drain"):
-                self._drain_all()
+                while self._handles:
+                    h = self._handles.popleft()
+                    self._process_one(h, defer=not self._handles)
             pending = [{"req": r, "slot": s, "done": 0} for r, s in zip(batch, slots)]
             if self.engine.prefix_cache is not None:
                 # Prefix-cache fast path, same as bucketed admission: adopt
@@ -1070,6 +1131,7 @@ class Scheduler:
                         if shared:
                             self.engine.allocator.adopt_pages(item["slot"], shared)
                             item["done"] = matched
+        stepped = dispatch_follows = False
         try:
             while pending:
                 kept = []
@@ -1098,6 +1160,7 @@ class Scheduler:
                     handle = self.engine.mixed_step_submit(rows, phases=phases)
                     if observing:
                         spans = self._stamp_dispatch("mixed", t_submit)
+                    self._deliver_deferred(spans, phases)
                     toks, logprobs = self.engine.mixed_step_fetch(handle)
                     if observing:
                         self._stamp_fetch()
@@ -1127,10 +1190,15 @@ class Scheduler:
                     return
                 self.last_step_time = self.clock.now()
                 self.steps_completed += 1
+                stepped = True
                 emitted = 0
                 with phase_span(phases, "emit", "sched.emit"):
                     # Decode rows advance exactly one token, same emission
-                    # contract as one step of a fused chunk.
+                    # contract as one step of a fused chunk. Nothing is in
+                    # flight and a dispatch follows (the next mixed step or
+                    # run()'s fresh chunk): a row that goes on decoding
+                    # delivers behind it. Finished rows and first tokens
+                    # leave at once.
                     for row in rows[:n_decode]:
                         st = self._slots.get(row.slot)
                         if st is None or st is not states.get(row.slot):
@@ -1144,7 +1212,7 @@ class Scheduler:
                         if finished:
                             del self._slots[row.slot]
                             self._release_guarded(row.slot, reason)
-                        self._flush_emits(st.req)
+                        self._flush_emits(st.req, defer=not finished)
                     # Prefill rows whose final chunk just landed become
                     # active slots with their sampled first token.
                     done_items = [it for it in pending
@@ -1182,8 +1250,12 @@ class Scheduler:
                         "mixed", t0, n_steps=1, batch=len(rows),
                         tokens=emitted, work_tokens=n_decode + n_prefill,
                         context_tokens=context, pair_tokens=pairs, spans=spans)
+            dispatch_follows = stepped and bool(self._slots)
         finally:
             self._admitting = []
+            if not dispatch_follows:
+                # Requeued, failed, no step at all, or nobody left decoding.
+                self._deliver_deferred()
 
     def _submit_chunk(self, chain: bool) -> "_Inflight | None":
         """Dispatch one fused decode chunk without waiting for it.
@@ -1206,9 +1278,8 @@ class Scheduler:
         # shrinking the chunk to one step). A page-blocked admission
         # (_admit_ready False) must NOT defer the chunk — decode progress
         # is what frees the pages it is waiting for.
-        with self._wake:
-            if self._waiting and self._free and self._admit_ready():
-                return None
+        if self._admission_due():
+            return None
         n = self.engine.config.decode_chunk
         observing = self._observing
         if chain and getattr(self.engine, "_early_exit", False):
@@ -1275,9 +1346,10 @@ class Scheduler:
         except Exception as e:
             self._fail_after_decode_error(e)
             return None
-        return _Inflight(handle, dict(self._slots), n,
-                         self._stamp_dispatch("decode", t_submit, t_build)
-                         if observing else None)
+        submit = self._stamp_dispatch("decode", t_submit, t_build) if observing else None
+        if not chain:
+            self._deliver_deferred(submit, getattr(handle, "phases", None))
+        return _Inflight(handle, dict(self._slots), n, submit)
 
     def _spec_step(self) -> None:
         """One speculative round: emits 1..K+1 tokens per live slot.
@@ -1582,7 +1654,7 @@ class Scheduler:
             except Exception:
                 pass
 
-    def _process_chunk(self, inf: "_Inflight") -> None:
+    def _process_chunk(self, inf: "_Inflight", defer: bool = False) -> None:
         """Fetch a submitted chunk's token block and stream it out.
 
         Requests that finish mid-chunk have their trailing tokens
@@ -1590,7 +1662,9 @@ class Scheduler:
         was submitted are excluded by the submit-time snapshot, and a
         slot released + re-admitted mid-flight is excluded by the state
         IDENTITY check — its rows in this chunk belong to the previous
-        occupant's (already finished) stream.
+        occupant's (already finished) stream. ``defer``: the caller
+        dispatches next and no chunk is behind this one on the device, so
+        the rows that go on decoding deliver after that dispatch.
         """
         self._normal_steps += inf.n_steps  # engine steps, for the spec probe cadence
         observing = self._observing
@@ -1659,7 +1733,7 @@ class Scheduler:
                     # One flush per request per CHUNK: a pipelined
                     # decode_chunk's whole token block reaches the event
                     # loop as one wakeup instead of n_steps of them.
-                    self._flush_emits(st.req)
+                    self._flush_emits(st.req, defer=defer and slot in self._slots)
         self._wasted("chunk_overrun", overrun)
         if observing:
             self._record_step("decode", t0, n_steps=inf.n_steps,

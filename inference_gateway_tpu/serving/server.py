@@ -1020,6 +1020,14 @@ class SidecarServer:
                 "chunk": self.engine.config.decode_chunk,
                 "pipeline_depth": self.engine.config.pipeline_depth,
             },
+            # Delivery after dispatch (ISSUE 29): of the step-boundary
+            # flushes, how many waited for the next dispatch (an
+            # admission's decode rows) and how many ran in place.
+            "scheduler": {
+                "steps_completed": self.scheduler.steps_completed,
+                "flushes_deferred": self.scheduler.flushes_deferred,
+                "flushes_immediate": self.scheduler.flushes_immediate,
+            },
         }
         if self.engine.structured is not None:
             # Structured-outputs snapshot (ISSUE 13): mask-cache hit

@@ -433,3 +433,247 @@ def test_two_mixed_steps_in_flight_do_not_share_their_descriptors():
     want = [in_turn.mixed_step_fetch(in_turn.mixed_step_submit([r])) for r in rows]
     for s, ((gt, gl), (wt, wl)) in enumerate(zip(got, want)):
         assert int(gt[s]) == int(wt[s]) and gl[s].tobytes() == wl[s].tobytes()
+
+
+# ---------------------------------------------------------------------------
+# Delivery after dispatch (ISSUE 29): at an admission the decode rows' flushes
+# wait for the next program to be on the device; nothing else about a stream
+# changes.
+# ---------------------------------------------------------------------------
+class _Stream:
+    """One request's delivery as the serving edge sees it: ``cb`` buffers
+    on the scheduler thread, ``flush`` hands the step's batch over."""
+
+    def __init__(self, name: str, log: list | None = None) -> None:
+        self.name, self.log = name, log if log is not None else []
+        self.pending: list = []
+        self.delivered: list = []
+        self.done = threading.Event()
+        self.req: GenRequest | None = None
+
+    def cb(self, tok, lp, fin, reason):
+        self.pending.append((tok, lp, fin, reason))
+
+    def flush(self):
+        batch = self.pending.copy()
+        self.pending.clear()
+        if batch:
+            self.delivered.extend(batch)
+            self.log.append(("flush", self.name))
+            if batch[-1][2]:
+                self.done.set()
+
+    def request(self, prompt, **kw) -> GenRequest:
+        self.req = GenRequest(prompt_ids=prompt, callback=self.cb,
+                              flush_callback=self.flush, **kw)
+        return self.req
+
+    def wait_tokens(self, n: int, timeout: float = 60.0) -> None:
+        deadline = time.monotonic() + timeout
+        while len(self.delivered) < n:
+            assert time.monotonic() < deadline, f"{self.name}: {len(self.delivered)} of {n} tokens"
+            time.sleep(0.005)
+
+
+def _record_dispatches(engine, log: list, before_mixed_submit=None) -> None:
+    """``log`` gets ("submit" | "fetch", "mixed" | "fresh" | "chained") as
+    each engine call RETURNS, on the scheduler thread, in line with the
+    streams' flush events."""
+    mixed_submit, mixed_fetch = engine.mixed_step_submit, engine.mixed_step_fetch
+    chunk_submit, chunk_fetch = engine.decode_chunk_submit, engine.decode_chunk_fetch
+
+    def _mixed_submit(rows, **kw):
+        if before_mixed_submit is not None:
+            before_mixed_submit(rows)
+        handle = mixed_submit(rows, **kw)
+        log.append(("submit", "mixed"))
+        return handle
+
+    def _chunk_submit(*a, **kw):
+        handle = chunk_submit(*a, **kw)
+        log.append(("submit", "chained" if kw.get("chain") else "fresh"))
+        return handle
+
+    engine.mixed_step_submit = _mixed_submit
+    engine.decode_chunk_submit = _chunk_submit
+    engine.mixed_step_fetch = lambda h: (mixed_fetch(h), log.append(("fetch", "mixed")))[0]
+    engine.decode_chunk_fetch = lambda h: (chunk_fetch(h), log.append(("fetch", "chunk")))[0]
+
+
+def _prompt(rng, n: int) -> list[int]:
+    return [int(x) for x in rng.integers(1, 250, size=n)]
+
+
+def test_decode_rows_deliver_behind_the_next_dispatch_and_a_first_token_before_it():
+    # One request an admission; B's 60 tokens take three mixed steps, C's two.
+    engine = _mk_engine(True, mixed_step_tokens=24, max_prefill_batch=1)
+    log: list = []
+    _record_dispatches(engine, log)
+    sched = Scheduler(engine)
+    a, b, c = _Stream("a", log), _Stream("b", log), _Stream("c", log)
+    rng = np.random.default_rng(5)
+    sched.start()
+    try:
+        sched.submit(a.request(_prompt(rng, 8), max_tokens=200, temperature=0.0))
+        a.wait_tokens(9)  # decoding, chunks chained
+        start = len(log)
+        with sched._wake:  # both queued before the loop sees either
+            sched.submit(b.request(_prompt(rng, 60), max_tokens=30, temperature=0.0))
+            sched.submit(c.request(_prompt(rng, 40), max_tokens=6, temperature=0.0))
+        assert c.done.wait(timeout=120)
+        end = len(log)
+        for s in (a, b):
+            s.req.disconnected = True
+        assert a.done.wait(timeout=60) and b.done.wait(timeout=60)
+    finally:
+        sched.stop()
+    in_flight, at = 0, []
+    for kind, what in log:
+        in_flight += {"submit": 1, "fetch": -1}.get(kind, 0)
+        at.append(in_flight)
+    window = range(start, end)
+    mixed = [i for i in window if log[i] == ("submit", "mixed")]
+    fresh = [i for i in window if log[i] == ("submit", "fresh")]
+    assert len(mixed) >= 5 and fresh
+    # A decodes through both admissions: none of its flushes finds the
+    # device empty, and one follows every dispatch that found it so.
+    assert all(at[i] >= 1 for i in window if log[i] == ("flush", "a")), log[start:end]
+    for i in mixed + fresh[:1]:
+        assert at[i] == 1 and log[i + 1] == ("flush", "a"), log[start:end]
+    # The drained chunk before the first mixed step delivered behind it too.
+    assert log[mixed[0] - 1] == ("fetch", "chunk")
+    # B's first token left at once, before the dispatch that follows its
+    # step. That dispatch is C's first mixed step: the submit stood back for
+    # C's admission, and A's row of B's last step was carried behind it.
+    first_b = log.index(("flush", "b"))
+    assert at[first_b] == 0 and log[first_b - 1] == ("fetch", "mixed")
+    assert log[first_b + 1:first_b + 3] == [("submit", "mixed"), ("flush", "a")]
+    # From then on B is a decoding row like A; C's first token leaves before the fresh chunk.
+    assert all(at[i] >= 1 for i in window if log[i] == ("flush", "b") and i > first_b), log[start:end]
+    first_c = log.index(("flush", "c"))
+    assert at[first_c] == 0 and log[first_c + 1] == ("submit", "fresh")
+    assert sched.flushes_deferred >= len(mixed) + 1 and not sched._deferred
+    assert len(c.delivered) == 6 and not any(s.pending for s in (a, b, c))
+
+
+def _watch_pass_ends(sched: Scheduler) -> list[tuple[int, bool]]:
+    """Each time run() finishes a pass: the deferred list's length, and
+    whether the next pass opens with an admission onto an empty device."""
+    seen: list[tuple[int, bool]] = []
+    inner = sched._process_handles
+
+    def checked():
+        seen.append((len(sched._deferred), sched._admission_due()))
+        inner()
+    sched._process_handles = checked
+    return seen
+
+
+@pytest.mark.parametrize("way_out", ["abort_all", "stop", "requeue", "submit_fails",
+                                     "last_request_finishes"])
+def test_nothing_is_held_without_a_dispatch_behind_it(way_out):
+    """Every way out of an admission pass empties the deferred list (a
+    pass may end holding it only for the admission that opens the next),
+    and every token a callback saw reaches a flush."""
+    if way_out == "requeue":
+        # test_mixed_admission_requeues_on_page_pressure's pool of 10 pages: B's
+        # prompt takes 8 and A's 3 or more, so B cannot fit while A runs.
+        engine = Engine(EngineConfig(
+            model="test-tiny", max_slots=2, max_seq_len=64, dtype="float32",
+            max_prefill_batch=1, use_mesh=False, prefill_buckets=(16, 32, 64),
+            decode_chunk=2, attention="paged", page_size=8, num_pages=10,
+            prefix_cache=False, mixed_step=True))
+        sched = Scheduler(engine, preempt_max=3)
+    else:
+        engine = _mk_engine(True, mixed_step_tokens=24)
+        sched = Scheduler(engine)
+    a, b = _Stream("a"), _Stream("b")
+    held: list[int] = []  # the list's length where the way out was taken
+    armed = threading.Event()  # set once A decodes and B is on its way
+
+    def before_mixed_submit(rows):
+        if not armed.is_set():
+            return
+        if way_out == "abort_all" and not held:
+            held.append(len(sched._deferred))
+            sched.abort_all()  # the watchdog's thread, while this one is in the engine
+            held.append(len(sched._deferred))
+        elif way_out == "submit_fails":
+            held.append(len(sched._deferred))
+            raise RuntimeError("device lost")
+        elif way_out == "last_request_finishes":
+            a.req.disconnected = True  # A's row of this step is its last
+
+    _record_dispatches(engine, [], before_mixed_submit)
+    requeues: list = []
+    requeue = sched._requeue_admission
+    sched._requeue_admission = lambda *args: (requeues.append(len(sched._deferred)), requeue(*args))[1]
+    pass_ends = _watch_pass_ends(sched)
+    rng = np.random.default_rng(31)
+    sched.start()
+    try:
+        sched.submit(a.request(_prompt(rng, 12), max_tokens=48 if way_out == "requeue" else 200,
+                               temperature=0.0))
+        a.wait_tokens(5)
+        armed.set()
+        sched.submit(b.request(_prompt(rng, 58 if way_out == "requeue" else 44),
+                               max_tokens=1 if way_out == "last_request_finishes" else 4,
+                               temperature=0.0))
+        if way_out == "stop":
+            b.wait_tokens(1)
+        else:
+            assert a.done.wait(timeout=120) and b.done.wait(timeout=120)
+    finally:
+        sched.stop()
+    assert not sched._thread.is_alive()
+    assert not sched._deferred
+    assert pass_ends and all(held == 0 or follows for held, follows in pass_ends), pass_ends
+    assert not a.pending and not b.pending  # every callback's token was handed over
+    # The first terminal event a stream saw (after abort_all the thread, not
+    # wedged here, emits on; consumers ignore what follows a terminal event).
+    reasons = tuple(next((t[3] for t in s.delivered if t[2]), None) for s in (a, b))
+    if way_out == "abort_all":
+        assert held[0] >= 1 and held[1] == 0 and reasons == ("error", "error")
+    elif way_out == "submit_fails":
+        assert held[0] >= 1 and reasons == ("error", "error")
+    elif way_out == "requeue":
+        assert requeues and max(requeues) >= 1  # A's drained rows were waiting when B was put back
+        assert reasons == ("length", "length") and len(a.delivered) == 48
+    elif way_out == "last_request_finishes":
+        assert reasons == ("disconnected", "length") and sched.flushes_deferred >= 1
+        assert not sched._slots
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.7])
+def test_each_stream_is_what_it_was_with_every_flush_immediate(temperature):
+    """Six seeded requests queued before the loop starts, on four slots two
+    an admission, so later ones are admitted into a decoding batch: what each
+    stream is handed, in order, does not depend on when it is handed over."""
+    engine = _mk_engine(True, mixed_step_tokens=24, prefix_cache=False)
+    rng = np.random.default_rng(17)
+    shapes = [(9, 14), (40, 6), (17, 22), (5, 9), (33, 12), (12, 17)]
+    prompts = [_prompt(rng, n) for n, _ in shapes]
+
+    def serve(deferral: bool):
+        sched = Scheduler(engine)
+        if not deferral:
+            flush = sched._flush_emits
+            sched._flush_emits = lambda req, defer=False: flush(req)
+        streams = [_Stream(str(i)) for i in range(len(shapes))]
+        for s, p, (_, out) in zip(streams, prompts, shapes):
+            sched.submit(s.request(p, max_tokens=out, temperature=temperature,
+                                   seed=1000 + int(s.name), top_p=0.95 if temperature else 1.0))
+        sched.start()
+        try:
+            assert all(s.done.wait(timeout=120) for s in streams)
+        finally:
+            sched.stop()
+        assert not sched._deferred and not any(s.pending for s in streams)
+        return sched, [s.delivered for s in streams]
+
+    immediate_sched, immediate = serve(deferral=False)
+    deferred_sched, deferred = serve(deferral=True)
+    assert immediate_sched.flushes_deferred == 0 and deferred_sched.flushes_deferred > 0
+    assert [len(d) for d in deferred] == [out for _, out in shapes]
+    assert deferred == immediate
+    assert all(d[-1][2:] == (True, "length") and not any(t[2] for t in d[:-1]) for d in deferred)

@@ -22,7 +22,8 @@ from inference_gateway_tpu.serving.server import SidecarServer, serve
 
 TRACE_ID = "0af7651916cd43dd8448eb211c80319c"
 TRACEPARENT = f"00-{TRACE_ID}-b7ad6b7169203331-01"
-MIXED_PHASES = ("build", "lock_wait", "pages", "assemble", "upload_dispatch", "fetch_wait", "emit")
+MIXED_PHASES = ("build", "lock_wait", "pages", "assemble", "upload_dispatch", "deliver",
+                "fetch_wait", "emit")
 STARTUP_KEYS = ("import_s", "params_s", "quantize_s", "kv_pool_s", "structured_s",
                 "observatory_s", "warmup_s", "listen_s", "total_s")
 
@@ -149,6 +150,41 @@ async def test_a_host_stepped_record_says_how_many_arrays_its_submit_uploaded(si
     assert [r["h2d_arrays"] for r in chained] == [0] * len(chained)
 
 
+async def test_records_and_status_count_what_was_delivered_behind_a_dispatch(sidecar):
+    """ISSUE 29: every `mixed` record and the fresh `decode` record carry
+    `deferred_flushes` and a `deliver` phase, a chained one neither, and
+    /debug/status keeps the running totals. A second stream is admitted
+    while the first decodes, so some flushes did wait."""
+    server, port, _ = sidecar
+    client = HTTPClient()
+    status = (await client.get(f"http://127.0.0.1:{port}/debug/status")).json()
+    totals = status["scheduler"]
+    assert all(type(totals[k]) is int for k in
+               ("steps_completed", "flushes_deferred", "flushes_immediate"))
+    before = server.timeline.stats()["records"]
+    first = asyncio.ensure_future(_chat(port, max_tokens=200))
+    while not server.scheduler._slots:
+        await asyncio.sleep(0.001)
+    await asyncio.gather(first, _chat(port, max_tokens=30))
+    await asyncio.sleep(0.1)
+    recs = server.timeline.tail(server.timeline.stats()["records"] - before)
+    mixed = [r for r in recs if r["kind"] == "mixed"]
+    fresh = [r for r in recs if r["kind"] == "decode" and r["phases"]["build"] > 0]
+    chained = [r for r in recs if r["kind"] == "decode" and r["phases"]["build"] == 0]
+    assert len(mixed) >= 2 and fresh and chained
+    for r in mixed + fresh:
+        assert type(r["deferred_flushes"]) is int and 0 <= r["deferred_flushes"] <= r["batch"]
+        assert type(r["phases"]["deliver"]) is float and r["phases"]["deliver"] >= 0
+    assert all("deferred_flushes" not in r and "deliver" not in r["phases"] for r in chained)
+    hidden = sum(r["deferred_flushes"] for r in mixed + fresh)
+    assert hidden >= 1  # the first stream's rows, behind the second's admission
+    after = (await client.get(f"http://127.0.0.1:{port}/debug/status")).json()["scheduler"]
+    assert after["flushes_deferred"] - totals["flushes_deferred"] == hidden
+    assert after["flushes_immediate"] > totals["flushes_immediate"]
+    assert after["steps_completed"] > totals["steps_completed"]
+    assert not server.scheduler._deferred
+
+
 async def test_an_idle_scheduler_accrues_no_starvation(sidecar):
     server, port, _ = sidecar
     await _chat(port, max_tokens=4)
@@ -195,8 +231,8 @@ async def test_a_detached_observer_stamps_nothing(sidecar):
     engine.mixed_step_submit = lambda *a, **k: (handles.append(mixed(*a, **k)), handles[-1])[1]
     engine.decode_chunk_submit = lambda *a, **k: (handles.append(chunk(*a, **k)), handles[-1])[1]
     timeline, accounting = sched.timeline, sched.accounting
-    records = timeline.stats()["records"]
     await asyncio.sleep(0.3)  # let the loop go idle with the observers still on
+    records = timeline.stats()["records"]  # the last stream's tail chunks are in by now
     sched.timeline = sched.accounting = None
     sched._dev_touch = sched._starve_t0 = None
     try:
