@@ -1251,97 +1251,6 @@ async def bench_affinity_routing(requests: int = 12, max_tokens: int = 8,
     }
 
 
-def bench_decode_steady_state(chunks=(8, 32, 128), n_requests: int = 4,
-                              max_tokens: int = 24) -> dict:
-    """Desynchronized decode (ISSUE 14), CPU-safe: host gap between
-    chained chunks and delivered tokens/s at decode_chunk in {8,32,128},
-    early-exit on vs off.
-
-    On CPU the "device" is the host, so absolute tokens/s is not a
-    kernel number — the two quantities that transfer are (a) the host
-    gap between chained dispatches (pure Python bookkeeping, the thing
-    the host-free steady state minimizes — the acceptance gate is p99
-    < 1 ms), and (b) the early-exit waste ratio: with max_tokens well
-    under a 128-step chunk, the off path computes every step while the
-    on path freezes at the finish (wasted_tokens{chunk_overrun} pins
-    it)."""
-    import queue as _q
-
-    from inference_gateway_tpu.otel.perf_accounting import (
-        PerfAccounting,
-        StepCostModel,
-    )
-    from inference_gateway_tpu.otel.profiling import StepTimeline
-    from inference_gateway_tpu.serving.engine import Engine, EngineConfig
-    from inference_gateway_tpu.serving.scheduler import GenRequest, Scheduler
-
-    configs = []
-    for n_chunk in chunks:
-        for early_exit in (True, False):
-            eng = Engine(EngineConfig(
-                model="test-tiny", max_slots=max(n_requests, 2),
-                max_seq_len=512, dtype="float32", max_prefill_batch=2,
-                use_mesh=False, attention="paged", page_size=32,
-                prefix_cache=False, decode_chunk=n_chunk,
-                prefill_buckets=(16, 32, 64), decode_early_exit=early_exit))
-            sched = Scheduler(eng)
-            sched.timeline = StepTimeline(512)
-            sched.accounting = PerfAccounting(
-                StepCostModel.from_engine(eng, chip="v5e"), model="bench", measured=False)
-            sched.start()
-            done: _q.Queue = _q.Queue()
-            delivered = [0]
-
-            def cb(tok, lp, fin, reason):
-                delivered[0] += 1
-                if fin:
-                    done.put(reason)
-
-            t0 = time.perf_counter()
-            for i in range(n_requests):
-                sched.submit(GenRequest(
-                    prompt_ids=[1 + i, 2, 3, 4], max_tokens=max_tokens,
-                    callback=cb))
-            for _ in range(n_requests):
-                done.get(timeout=300)
-            wall = time.perf_counter() - t0
-            # Drain the pipeline tail before reading the counters: the
-            # in-flight chunks carrying the finished streams are exactly
-            # where the early-exit-off path attributes its overrun.
-            deadline = time.perf_counter() + 30
-            while (time.perf_counter() < deadline
-                   and (sched._handles or sched._slots)):
-                time.sleep(0.01)
-            gaps = sorted(r["host_gap_ms"] for r in sched.timeline.tail()
-                          if "host_gap_ms" in r)
-            sched.stop()
-            pick = lambda q: round(gaps[min(len(gaps) - 1, int(len(gaps) * q))], 4) \
-                if gaps else None
-            configs.append({
-                "decode_chunk": n_chunk,
-                "early_exit": early_exit,
-                "tokens_per_sec": round(delivered[0] / wall, 1),
-                "host_gap_ms_p50": pick(0.50),
-                "host_gap_ms_p99": pick(0.99),
-                "chained_dispatches": len(gaps),
-                "wasted_chunk_overrun": sched.accounting.wasted.get(
-                    "chunk_overrun", 0),
-            })
-    gate = [c for c in configs if c["early_exit"] and c["host_gap_ms_p99"] is not None]
-    return {
-        "bench": "decode_steady_state",
-        "platform": "cpu-proxy",
-        "configs": configs,
-        "host_gap_p99_under_1ms": bool(gate) and all(
-            c["host_gap_ms_p99"] < 1.0 for c in gate),
-    }
-
-
-def decode_steady_state_suite() -> dict:
-    """The ISSUE 14 steady-state numbers in one line."""
-    return bench_decode_steady_state()
-
-
 async def main() -> None:
     results = [
         await bench_chat_completions(),
@@ -1374,7 +1283,6 @@ async def main() -> None:
         await bench_preemption_overhead(),
         await bench_structured_overhead(),
         await bench_affinity_routing(),
-        bench_decode_steady_state(),
     ]
     for r in results:
         print(json.dumps(r))
@@ -1389,9 +1297,5 @@ if __name__ == "__main__":
         # cluster and report the 32/128 sustained pair for that size.
         n = int(sys.argv[sys.argv.index("--workers") + 1])
         print("RESULT=" + json.dumps(asyncio.run(relay_cluster_suite(n))))
-    elif "--decode-steady-state" in sys.argv:
-        # ISSUE 14: host gap + early-exit waste at
-        # decode_chunk {8,32,128}, one machine-readable line.
-        print("RESULT=" + json.dumps(decode_steady_state_suite()))
     else:
         asyncio.run(main())

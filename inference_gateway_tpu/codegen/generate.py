@@ -136,20 +136,19 @@ _SERVING_RAGGED_DOC = [
 _SERVING_DESYNC_DOC = [
     "### Host-free decode steady state",
     "",
-    "`SERVING_DECODE_EARLY_EXIT` (on by default) moves the decode loop's",
-    "control decisions on device: per-slot stop-token tables, max_tokens",
-    "budgets, and the grammar accept-state ride the fused chunk carry, so",
-    "finished slots freeze (no further sampling, KV writes masked) and the",
-    "chunk exits its device loop the moment every slot is done — long",
-    "`SERVING_DECODE_CHUNK` values stop paying chunk-overrun waste, and",
-    "chained chunk submits upload nothing (paged write indices are computed",
-    "on device from a pre-reserved page horizon).",
+    "The decode loop's control decisions are made on device: per-slot",
+    "stop-token tables, max_tokens budgets, and the grammar accept-state",
+    "ride the fused chunk carry, so finished slots freeze (no further",
+    "sampling, KV writes masked) and the chunk exits its device loop the",
+    "moment every slot is done — long `SERVING_DECODE_CHUNK` values pay no",
+    "chunk-overrun waste, and chained chunk submits upload nothing (paged",
+    "write indices are computed on device from a pre-reserved page horizon).",
     "`SERVING_DECODE_PIPELINE_DEPTH` chunks stay in flight so the device",
     "never waits on the host between chunks; the `engine.host_gap_ms`",
     "histogram and `/debug/roofline` host-gap percentiles measure exactly",
-    "that. Greedy and seeded streams are byte-identical with the feature on",
-    "or off; stop *strings* remain a host-side backstop that truncates",
-    "after the fact. Design: [docs/performance.md](docs/performance.md).",
+    "that. Greedy and seeded streams are byte-identical at any chunk size;",
+    "stop *strings* remain a host-side backstop that truncates after the",
+    "fact. Design: [docs/performance.md](docs/performance.md).",
     "",
 ]
 
@@ -533,7 +532,6 @@ def check_config_defaults(spec: dict) -> list[str]:
         "SERVING_ADMIN_ENABLED": cfg.serving.admin_enabled,
         "SERVING_MIXED_STEP_ENABLE": cfg.serving.mixed_step_enable,
         "SERVING_MIXED_STEP_TOKENS": cfg.serving.mixed_step_tokens,
-        "SERVING_DECODE_EARLY_EXIT": cfg.serving.decode_early_exit,
         "SERVING_DECODE_CHUNK": cfg.serving.decode_chunk,
         "SERVING_DECODE_PIPELINE_DEPTH": cfg.serving.decode_pipeline_depth,
         "STRUCTURED_ENABLE": cfg.structured.enable,

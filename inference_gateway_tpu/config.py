@@ -414,12 +414,11 @@ class ServingConfig:
     # max_slots).
     mixed_step_enable: bool = True
     mixed_step_tokens: int = 0
-    # Desynchronized decode (ISSUE 14): on-device stopping + early-exit
-    # fused chunks + host-free chained submits. decode_chunk/pipeline
-    # depth 0 = keep the engine defaults (8 / 2); with early exit on,
-    # much larger chunks are safe — finished slots freeze on device, so
-    # a long chunk no longer pays up to chunk-1 wasted steps per finish.
-    decode_early_exit: bool = True
+    # Desynchronized decode (ISSUE 14): on-device stopping, early-exit
+    # fused chunks, host-free chained submits. decode_chunk/pipeline
+    # depth 0 = keep the engine defaults (8 / 2); much larger chunks
+    # are safe — finished slots freeze on device, so a long chunk pays
+    # no wasted steps per finish the device detects.
     decode_chunk: int = 0
     decode_pipeline_depth: int = 0
 
@@ -446,7 +445,6 @@ class ServingConfig:
             admin_enabled=_get_bool(env, prefix + "ADMIN_ENABLED", True),
             mixed_step_enable=_get_bool(env, prefix + "MIXED_STEP_ENABLE", True),
             mixed_step_tokens=_get_int(env, prefix + "MIXED_STEP_TOKENS", 0),
-            decode_early_exit=_get_bool(env, prefix + "DECODE_EARLY_EXIT", True),
             decode_chunk=_get_int(env, prefix + "DECODE_CHUNK", 0),
             decode_pipeline_depth=_get_int(env, prefix + "DECODE_PIPELINE_DEPTH", 0),
         )

@@ -597,7 +597,7 @@ def forward_ragged(
     for the whole batch, whatever mix of prefill and decode it holds.
     Returns per-ROW last-position logits (R, V) and the updated cache.
 
-    This replaces the bucketed ``_prefill_fn``/``_decode_fn`` family for
+    This replaces the bucketed ``_prefill_fn`` family for
     paged serving: one compiled program at one static packed width
     instead of one program per prompt bucket, and no bucket padding —
     only the packed tail beyond the live tokens is dead work."""

@@ -67,15 +67,10 @@ JIT_ENTRY_POINTS: tuple[str, ...] = (
     "_prefill_fn_paged",
     "_prefill_chunk_fn",
     "_prefill_chunk_fn_paged",
-    "_decode_fn",
-    "_decode_fn_paged",
-    "_decode_chunk_fn",
-    "_decode_chunk_fn_paged",
     "_decode_chunk_fn_ee",
     "_decode_chunk_fn_paged_ee",
     "_fresh_unpack_fn",
     "_mixed_step_fn",
-    "_admit_scatter_fn",
     "_admit_scatter_fn_ee",
     "_draft_prefill_fn",
     "_spec_round_fn",

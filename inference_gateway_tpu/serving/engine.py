@@ -73,34 +73,25 @@ class EngineConfig:
     quantize: str | None = None  # "int8" | "int4" weight-only quantization (ops/quant.py)
     quant_group: int = 0  # int4 group size; 0 = auto (tp-aware, ≤128)
     prefix_cache: bool = True  # share full prefix KV pages across requests (paged mode)
-    # Decode steps fused into one jitted scan per host roundtrip. Token
-    # sampling feeds back on-device; the host reads a (chunk, slots)
-    # token block once per chunk. Larger chunks amortize host↔device
-    # latency at the cost of up to chunk-1 wasted steps per finished
-    # request.
+    # Decode steps fused into one jitted lax.while_loop per host
+    # roundtrip (ISSUE 14). Sampling feeds back on-device, and so does
+    # stopping: per-slot stop token tables (EOS + stop_token_ids),
+    # max_tokens budgets and the grammar accept-state ride the carry, a
+    # finished slot freezes (no further sampling, KV writes masked) and
+    # the loop exits as soon as every active slot is done. The host
+    # reads a (chunk, slots) token block once per chunk; its stop
+    # detection stays authoritative (the device criterion is a strict
+    # subset — stop STRINGS and disconnects are host-side backstops),
+    # so a larger chunk wastes steps only on those finishes.
     decode_chunk: int = 8
     # Decode chunks the scheduler keeps in flight simultaneously.
     # Depth 1 overlaps chunk N's readback with chunk N+1's execution;
     # depth 2 additionally hides the host-side submit gap (allocator
     # bookkeeping + dispatch) behind device execution. Cost: finish
     # detection lags by depth chunks, so up to depth*decode_chunk wasted
-    # steps per finished request — ~zero with decode_early_exit, which
-    # freezes finished slots on device.
+    # steps per finished request for a finish only the host sees —
+    # ~zero otherwise: the chunk freezes finished slots on device.
     pipeline_depth: int = 2
-    # On-device stopping + early-exit chunks (ISSUE 14): per-slot stop
-    # token tables (EOS + stop_token_ids), max_tokens budgets, and the
-    # grammar accept-state ride the fused chunk carry, so a per-slot
-    # ``done`` flag is computed ON DEVICE — finished slots freeze (no
-    # further sampling, KV writes masked) and the chunk exits its
-    # lax.while_loop as soon as every active slot is done. This makes
-    # long decode_chunk values safe (chunk_overrun waste ~0) and makes
-    # chain=True submits genuinely host-free: the paged write indices
-    # are computed on device from a pre-reserved page-table horizon, so
-    # a chained submit uploads NOTHING. Greedy and seeded streams are
-    # byte-identical with the flag on or off (host stop detection stays
-    # authoritative; the device criterion is a strict subset — stop
-    # STRINGS and disconnects remain host-side backstops).
-    decode_early_exit: bool = True
     # Speculative decoding: spec_draft names a llama-family draft model
     # (preset name or HF path, same vocab as the target) that proposes
     # spec_k tokens per round, or the special value "ngram" for
@@ -226,7 +217,7 @@ class _DecodeChunkHandle:
     # them (ISSUE 24); the fetch adds its own to the dict the caller holds.
     phases: dict | None = None
     # Host arrays the submit staged for upload (ISSUE 25): 1 for a fresh
-    # early-exit submit (the packed buffer), 0 for a chained one.
+    # submit (the packed buffer), 0 for a chained one.
     h2d_arrays: int = 0
     # What the program counted and the fetch block carried beside the
     # tokens (ISSUE 28), filled by the fetch: a mixture's ``experts_read``.
@@ -647,14 +638,14 @@ class Engine:
         self._rng = jax.random.PRNGKey(config.seed + 1)
         self._step_counter = 0
         self._lock = threading.Lock()
-        # Device-resident chained decode state (decode_chunk_submit):
-        # (pending token, position, grammar mask state) carry from the
-        # last chunk, plus the uploaded sampling params. With
-        # decode_early_exit the carry additionally holds the per-slot
-        # done flag, the remaining max_tokens budget, and the chunk rng
-        # key (so chained submits derive randomness on device instead of
-        # uploading a fresh key). Any prefill invalidates the carry —
-        # newly admitted slots' tokens exist only on the host.
+        # Device-resident chained decode state (decode_chunk_submit),
+        # one shape each: _dev_carry is the last chunk's (pending token,
+        # position, grammar mask state, done flag, remaining max_tokens
+        # budget, chunk rng key — chained submits derive randomness on
+        # device instead of uploading a fresh key), _dev_sampling the
+        # uploaded (temps, top_ps, seeds, use_seed, stop table). Any
+        # prefill invalidates the carry — newly admitted slots' tokens
+        # exist only on the host.
         self._dev_carry = None
         self._dev_sampling = None
         # Host mirror of the chained steady state (ISSUE 14): which
@@ -665,11 +656,6 @@ class Engine:
         # horizon is exhausted, _reserve_chain_horizon tops it up in one
         # batched allocator pass and refreshes the device-resident page
         # table — the only time a chained steady state touches h2d.
-        # Gated off for pipeline-parallel engines: the pp forward runs
-        # stage-sharded shard_maps whose interaction with a dynamic
-        # while_loop trip count is unexercised (pp is the one layout the
-        # CPU CI cannot compile) — pp keeps the legacy fixed-scan chunk.
-        self._early_exit = bool(config.decode_early_exit) and not self.pp
         S = config.max_slots
         self._chain_active = np.zeros((S,), bool)
         self._pred_pos = np.zeros((S,), np.int64)
@@ -873,21 +859,6 @@ class Engine:
         nstates = mnext[mstates, toks] if masked else jnp.zeros_like(toks)
         return toks, logprobs, nstates, cache
 
-    @partial(jax.jit, static_argnames=("self",), donate_argnums=(2,))
-    def _decode_fn(self, params, cache, tokens, positions, lengths, temps, top_ps, rng):
-        if self.pp:
-            logits, cache = llama.forward_pp(
-                params, self.model_cfg, tokens, positions, lengths, cache,
-                self.mesh, mode="decode", last_only=True)  # (B, V)
-        else:
-            logits, cache = self._forward(
-                params, self.model_cfg, tokens, positions, lengths, cache, mode="decode",
-            )
-            logits = logits[:, 0]
-        toks = sample_tokens(logits, rng, temps, top_ps, top_k=self.config.top_k)
-        logprobs = compute_logprobs(logits, toks)
-        return toks, logprobs, cache
-
     @partial(jax.jit, static_argnames=("self", "masked"), donate_argnums=(2,))
     def _prefill_chunk_fn_paged(self, params, cache, tokens, positions, lengths, write_idx,
                                 page_table, temps, top_ps, seeds, use_seed, rng,
@@ -947,100 +918,6 @@ class Engine:
         nstates = mnext[mstates, toks] if masked else jnp.zeros_like(toks)
         return toks, logprobs, nstates, cache
 
-    @partial(jax.jit, static_argnames=("self", "n_steps", "masked"), donate_argnums=(2,))
-    def _decode_chunk_fn(self, params, cache, tokens, positions, temps, top_ps, seeds, use_seed, rng,
-                         mstates=None, mnext=None, mbits=None, mbias=None,
-                         n_steps=8, masked=False):
-        """n_steps fused decode steps (lax.scan); sampling feeds back
-        on-device so the host syncs once per chunk. RNG (key derivation
-        + gumbel draws) is precomputed for the whole chunk OUTSIDE the
-        scan — one batched dispatch instead of n_steps small ones, which
-        cost ~0.56 ms/step on v5e (round-3 device profile); the streams
-        are bit-identical (see ops/sampling.chunk_gumbels).
-
-        Grammar-constrained rows (masked=True) ride the SAME scan: each
-        step gathers the slot's packed mask row by automaton state,
-        applies it (plus the slot's logit_bias row) as an additive bias
-        before top-k/top-p, and advances the state with one more gather
-        — mask advancement never host-syncs mid-chunk (ISSUE 13)."""
-        keys = chunk_row_keys(rng, seeds, use_seed, positions, n_steps)
-        k_eff = effective_top_k(self.config.top_k, self.model_cfg.vocab_size)
-        gumbels = chunk_gumbels(keys, k_eff)
-
-        def step(carry, xs):
-            cache, tok, pos, ms = carry
-            i, gum = xs
-            if self.pp:
-                logits, cache = llama.forward_pp(
-                    params, self.model_cfg, tok[:, None], pos[:, None], pos + 1,
-                    cache, self.mesh, mode="decode", last_only=True)
-            else:
-                logits, cache = self._forward(
-                    params, self.model_cfg, tok[:, None], pos[:, None], pos + 1, cache, mode="decode",
-                )
-                logits = logits[:, 0]
-            if masked:
-                logits = logits + self._mask_bias(mbits, ms, mbias[:-1])
-            nxt = sample_tokens_pregumbel(logits, temps, top_ps, gum, k_eff)
-            nxt = nxt.astype(jnp.int32)
-            logprobs = compute_logprobs(logits, nxt)
-            if masked:
-                ms = mnext[ms, nxt]
-            # Clamp so attention length never exceeds the cache row even
-            # when a request rides the scan past max_seq_len (the
-            # scheduler discards those trailing tokens).
-            nxt_pos = jnp.minimum(pos + 1, self.config.max_seq_len - 1)
-            return (cache, nxt, nxt_pos, ms), (nxt, logprobs)
-
-        (cache, tok_f, pos_f, ms_f), (toks, logprobs) = jax.lax.scan(
-            step, (cache, tokens, positions, mstates), (jnp.arange(n_steps), gumbels)
-        )
-        # tok_f/pos_f/ms_f: the final sampled token, its position, and
-        # the grammar state per slot — returned so the NEXT chunk can
-        # chain off device-resident state with no host round-trip
-        # (decode_chunk_submit).
-        return toks, logprobs, tok_f, pos_f, ms_f, cache  # (n, S) x2, (S,) x3
-
-    @partial(jax.jit, static_argnames=("self", "n_steps", "masked"), donate_argnums=(2,))
-    def _decode_chunk_fn_paged(self, params, cache, tokens, positions, write_idx,
-                               page_table, temps, top_ps, seeds, use_seed, rng,
-                               mstates=None, mnext=None, mbits=None, mbias=None,
-                               n_steps=8, masked=False):
-        """Paged variant: write_idx is (S, n_steps) precomputed flat cache
-        positions (OOB = drop). Chunk RNG precomputed outside the scan;
-        grammar mask state rides the carry (see _decode_chunk_fn)."""
-        keys = chunk_row_keys(rng, seeds, use_seed, positions, n_steps)
-        k_eff = effective_top_k(self.config.top_k, self.model_cfg.vocab_size)
-        gumbels = chunk_gumbels(keys, k_eff)
-
-        def step(carry, inputs):
-            cache, tok, pos, ms = carry
-            i, w_idx, gum = inputs
-            logits, cache = self._model.forward_paged(
-                params, self.model_cfg, tok[:, None], pos[:, None], pos + 1, cache,
-                w_idx[:, None], page_table, mode="decode", last_only=True, mesh=self.mesh,
-            )
-            if masked:
-                logits = logits + self._mask_bias(mbits, ms, mbias[:-1])
-            nxt = sample_tokens_pregumbel(logits, temps, top_ps, gum, k_eff)
-            nxt = nxt.astype(jnp.int32)
-            logprobs = compute_logprobs(logits, nxt)
-            if masked:
-                ms = mnext[ms, nxt]
-            # Clamp the carried position so the attention length stays
-            # ≤ max_seq_len: past it, n_pages = cdiv(len, page_size)
-            # would exceed max_pages_per_slot and the kernel would read
-            # page_table out of bounds, driving a garbage-page DMA
-            # (advisor round-1 high finding). OOB write_idx already
-            # drops the writes; this bounds the reads too.
-            nxt_pos = jnp.minimum(pos + 1, self.config.max_seq_len - 1)
-            return (cache, nxt, nxt_pos, ms), (nxt, logprobs)
-
-        (cache, tok_f, pos_f, ms_f), (toks, logprobs) = jax.lax.scan(
-            step, (cache, tokens, positions, mstates), (jnp.arange(n_steps), write_idx.T, gumbels)
-        )
-        return toks, logprobs, tok_f, pos_f, ms_f, cache
-
     # -- early-exit fused chunks (ISSUE 14) -----------------------------
     def _chunk_done0(self, tokens, positions, done, budgets, stop_table,
                      mstates, mterm, masked):
@@ -1058,21 +935,29 @@ class Engine:
 
     def _chunk_step_ee(self, params, i, cache, tok, pos, ms, done, bud, gumbels,
                        k_eff, temps, top_ps, stop_table, mstates_args, write_args):
-        """One early-exit decode step, shared by the dense and paged
-        while_loop bodies. Live rows advance exactly as the legacy scan
-        did (same forward, same pre-drawn gumbel, same mask gathers —
-        byte-identical streams); done rows FREEZE: carry unchanged, the
-        emitted token is the frozen one (the host's stop detection
-        re-fires on it and truncates), and paged KV writes are masked.
-        Returns (cache, out_tok, out_lp, new carry..., experts read by
-        this step or None)."""
+        """One early-exit decode step, shared by the dense, paged and
+        pipeline-parallel while_loop bodies. Live rows advance one token
+        (the forward, the chunk's pre-drawn gumbel, and for a
+        grammar-constrained row a mask gather by automaton state before
+        top-k/top-p and one more to advance the state — mask advancement
+        never host-syncs mid-chunk, ISSUE 13); done rows FREEZE: carry
+        unchanged, the emitted token is the frozen one (the host's stop
+        detection re-fires on it and truncates), and paged KV writes
+        are masked. Returns (cache, out_tok, out_lp, new carry...,
+        experts read by this step or None)."""
         masked, mnext, mbits, mterm, mbias = mstates_args
         max_len = self.config.max_seq_len
+        # Clamped so the attended length never passes the cache row when
+        # a request rides the chunk past max_seq_len: past it the paged
+        # kernel's cdiv(len, page_size) would exceed max_pages_per_slot
+        # and read page_table out of bounds (a garbage-page DMA).
         pos_att = jnp.minimum(pos, max_len - 1)
         read = None  # a paged mixture's step counts the experts it read
-        if write_args is None:
-            # pp engines keep the legacy scan (early exit gated off in
-            # __init__), so only the single-program forwards land here.
+        if write_args is None and self.pp:
+            logits, cache = llama.forward_pp(
+                params, self.model_cfg, tok[:, None], pos_att[:, None],
+                pos_att + 1, cache, self.mesh, mode="decode", last_only=True)
+        elif write_args is None:
             logits, cache = self._forward(
                 params, self.model_cfg, tok[:, None], pos_att[:, None],
                 pos_att + 1, cache, mode="decode")
@@ -1082,9 +967,7 @@ class Engine:
             ps = self.config.page_size
             page = jnp.take_along_axis(
                 page_table, (pos_att // ps)[:, None], axis=1)[:, 0]
-            # int32 throughout: the legacy int64 host write_idx was
-            # truncated to int32 at upload anyway (no x64), and a flat
-            # paged cache index always fits.
+            # int32 throughout: a flat paged cache index always fits.
             w = page * ps + pos_att % ps
             valid = (~done) & (pos < max_len) & (pos < reserved)
             w = jnp.where(valid, w, self._flat_size)
@@ -1127,7 +1010,11 @@ class Engine:
         the ITERATION COUNT itself is now a device-side decision. Output
         buffers are pre-filled with each row's frozen token, so steps
         the loop never ran still emit the token the host's stop
-        detection expects."""
+        detection expects. RNG (key derivation + gumbel draws) is
+        precomputed for the whole chunk OUTSIDE the loop — one batched
+        dispatch instead of n_steps small ones, which cost ~0.56 ms/step
+        on v5e (round-3 device profile); the streams are bit-identical
+        (see ops/sampling.chunk_gumbels)."""
         masked = mask_args[0]
         mstates = mask_args[1]
         mask_tail = (masked,) + mask_args[2:]
@@ -1182,12 +1069,14 @@ class Engine:
                             stop_table, temps, top_ps, seeds, use_seed, rng,
                             mstates=None, mnext=None, mbits=None, mterm=None,
                             mbias=None, n_steps=8, masked=False):
-        """Early-exit variant of _decode_chunk_fn (dense cache): on-device
-        stopping (stop table / budget / grammar terminal state in the
-        carry), frozen rows rewrite their last real token's KV (bitwise
-        identical values — a deterministic forward at an unchanged
-        position), and the whole chunk exits early when every slot is
-        done."""
+        """The decode chunk over the dense cache: up to n_steps fused
+        decode steps with on-device stopping (stop table / budget /
+        grammar terminal state in the carry); frozen rows rewrite their
+        last real token's KV (bitwise identical values — a deterministic
+        forward at an unchanged position), and the whole chunk exits
+        early when every slot is done. Returns the (n, S) token and
+        logprob blocks and the final carry, so the NEXT chunk chains off
+        device-resident state with no host round-trip."""
         mask_args = (masked, mstates, mnext, mbits, mterm, mbias)
         return self._run_chunk_ee(
             params, cache, tokens, positions, done, budgets, stop_table, temps,
@@ -1199,11 +1088,11 @@ class Engine:
                                   temps, top_ps, seeds, use_seed, rng,
                                   mstates=None, mnext=None, mbits=None, mterm=None,
                                   mbias=None, n_steps=8, masked=False):
-        """Early-exit variant of _decode_chunk_fn_paged: the flat paged
-        write index is computed ON DEVICE from the resident page table
+        """The decode chunk over the paged cache: the flat paged write
+        index is computed ON DEVICE from the resident page table
         (page_table[slot, pos // page_size] · page_size + pos % page_size)
         and masked OOB for done rows and positions beyond the reserved
-        horizon — the host no longer assembles write_idx per chunk, so a
+        horizon — the host assembles no write_idx per chunk, so a
         chained submit uploads nothing (ISSUE 14 tentpole b)."""
         mask_args = (masked, mstates, mnext, mbits, mterm, mbias)
         return self._run_chunk_ee(
@@ -1250,27 +1139,16 @@ class Engine:
         nstates = mnext[mstates, toks] if masked else jnp.zeros_like(toks)
         return toks, logprobs, nstates, cache
 
-    @partial(jax.jit, static_argnames=("self",), donate_argnums=(2,))
-    def _decode_fn_paged(self, params, cache, tokens, positions, lengths, write_idx,
-                         page_table, temps, top_ps, rng):
-        logits, cache = self._model.forward_paged(
-            params, self.model_cfg, tokens, positions, lengths, cache, write_idx,
-            page_table, mode="decode", last_only=True, mesh=self.mesh,
-        )
-        toks = sample_tokens(logits, rng, temps, top_ps, top_k=self.config.top_k)
-        logprobs = compute_logprobs(logits, toks)
-        return toks, logprobs, cache
-
     def _mixed_step_body(self, params, cache, tokens, positions, write_idx, page_table,
                          q_starts, q_lens, kv_lens, temps, top_ps, seeds, use_seed, rng,
                          mstates=None, mnext=None, mbits=None, mbias=None, masked=False):
         """One ragged MIXED step (ISSUE 12): prefill-chunk rows and
         decode rows in a single launch over the paged cache. This is the
         one compiled program that replaces the per-bucket
-        _prefill_fn_paged / _prefill_chunk_fn_paged / _decode_fn_paged
-        family on the mixed path — packed width is the fixed
-        mixed_budget, so admission never recompiles and never pays
-        bucket padding. Traced inside _mixed_step_fn."""
+        _prefill_fn_paged / _prefill_chunk_fn_paged family on the mixed
+        path — packed width is the fixed mixed_budget, so admission
+        never recompiles and never pays bucket padding. Traced inside
+        _mixed_step_fn."""
         moe = {"with_aux": True} if self.is_moe else {}
         logits, cache, *rows = self._model.forward_ragged(
             params, self.model_cfg, tokens, positions, cache, write_idx,
@@ -1483,29 +1361,19 @@ class Engine:
         return [PrefillResult(slot, int(toks[i]), float(logprobs[i]))
                 for i, slot in enumerate(handle.slots)]
 
-    @partial(jax.jit, static_argnames=("self",), donate_argnums=(1, 2, 3, 4, 5, 6, 7))
-    def _admit_scatter_fn(self, tok, pos, temps, top_ps, seeds, use_seed, mstate,
-                          slot_arr, new_toks, new_lens, new_temps, new_tps,
-                          new_seeds, new_use, new_mstates):
-        """Fold a prefill batch's results into the chained decode state
-        on-device (OOB padding rows drop) — admission stops being a
-        pipeline barrier: the next chunk chains off state that already
-        contains the admitted slots' first tokens, positions, and
-        grammar mask states."""
-        upd = lambda a, v: a.at[slot_arr].set(v.astype(a.dtype), mode="drop")
-        return (upd(tok, new_toks), upd(pos, new_lens), upd(temps, new_temps),
-                upd(top_ps, new_tps), upd(seeds, new_seeds), upd(use_seed, new_use),
-                upd(mstate, new_mstates))
-
     @partial(jax.jit, static_argnames=("self",), donate_argnums=tuple(range(1, 11)))
     def _admit_scatter_fn_ee(self, tok, pos, ms, done, bud, temps, top_ps, seeds,
                              use_seed, stop_tab, slot_arr, new_toks, new_lens,
                              new_mstates, new_buds, new_stops, new_temps, new_tps,
                              new_seeds, new_use):
-        """_admit_scatter_fn for the early-exit carry (ISSUE 14): also
-        re-arms the admitted slots' on-device stop state — done flags
-        clear, fresh max_tokens budgets and stop-token rows land — so
-        the next chained chunk serves them with zero host involvement."""
+        """Fold a prefill batch's results into the chained decode state
+        on-device (OOB padding rows drop) — admission stops being a
+        pipeline barrier: the next chunk chains off state that already
+        contains the admitted slots' first tokens, positions, and
+        grammar mask states. Also re-arms their on-device stop state
+        (ISSUE 14) — done flags clear, fresh max_tokens budgets and
+        stop-token rows land — so the next chained chunk serves them
+        with zero host involvement."""
         upd = lambda a, v: a.at[slot_arr].set(v.astype(a.dtype), mode="drop")
         return (upd(tok, new_toks), upd(pos, new_lens), upd(ms, new_mstates),
                 done.at[slot_arr].set(False, mode="drop"), upd(bud, new_buds),
@@ -1731,15 +1599,13 @@ class Engine:
             # grammar states after the first sampled tokens ride along,
             # as do the per-slot stop rows / budgets arming the on-device
             # stop criteria (padding rows carry defaults and drop OOB).
-            pad_stop = pad_bud = None
-            if self._early_exit:
-                pad_stop = np.broadcast_to(
-                    self._eos_stop_row, (Bp, STOP_TABLE_WIDTH)).copy()
-                pad_bud = np.full((Bp,), 1 << 30, np.int64)
-                if stop_rows is not None:
-                    pad_stop[: len(prompts)] = stop_rows[: len(prompts)]
-                if budgets is not None:
-                    pad_bud[: len(prompts)] = budgets[: len(prompts)]
+            pad_stop = np.broadcast_to(
+                self._eos_stop_row, (Bp, STOP_TABLE_WIDTH)).copy()
+            pad_bud = np.full((Bp,), 1 << 30, np.int64)
+            if stop_rows is not None:
+                pad_stop[: len(prompts)] = stop_rows[: len(prompts)]
+            if budgets is not None:
+                pad_bud[: len(prompts)] = budgets[: len(prompts)]
             scattered = self._scatter_admission(
                 slot_arr, toks, lengths, t_arr, p_arr, seed_arr, use_seed,
                 mstates=nstates, stop_rows=pad_stop, budgets=pad_bud)
@@ -1751,25 +1617,14 @@ class Engine:
     def _scatter_admission(self, slot_arr, toks, lengths, t_arr, p_arr,
                            seed_arr, use_seed, mstates=None, stop_rows=None,
                            budgets=None) -> bool:
-        """Scatter a prefill batch's (token, pos, sampling, mask-state —
-        and under decode_early_exit: stop-row, budget, cleared done)
-        rows into the device-resident chained state, if it exists.
-        Caller holds _lock or is on the scheduler thread."""
+        """Scatter a prefill batch's (token, pos, sampling, mask-state,
+        stop-row, budget, cleared done) rows into the device-resident
+        chained state, if it exists. Caller holds _lock or is on the
+        scheduler thread."""
         if self._dev_carry is None:
             return False
         if mstates is None:
             mstates = np.zeros((len(slot_arr),), np.int32)
-        if not self._early_exit:
-            tok_d, pos_d, ms_d = self._dev_carry
-            te_d, tp_d, se_d, us_d = self._dev_sampling
-            new = self._admit_scatter_fn(
-                tok_d, pos_d, te_d, tp_d, se_d, us_d, ms_d,
-                jnp.asarray(slot_arr), jnp.asarray(toks), jnp.asarray(lengths),
-                jnp.asarray(t_arr), jnp.asarray(p_arr), jnp.asarray(seed_arr),
-                jnp.asarray(use_seed), jnp.asarray(mstates))
-            self._dev_carry = (new[0], new[1], new[6])
-            self._dev_sampling = tuple(new[2:6])
-            return True
         Bp = len(slot_arr)
         if stop_rows is None:
             stop_rows = np.broadcast_to(
@@ -1800,46 +1655,6 @@ class Engine:
             self._dev_page_table = jnp.asarray(self.allocator.page_table())
             self._dev_reserved = jnp.asarray(self._reserved)
         return True
-
-    def decode(self, tokens: np.ndarray, positions: np.ndarray, lengths: np.ndarray, temps: np.ndarray, top_ps: np.ndarray):
-        """One decode step for ALL slots.
-
-        tokens: (S,) pending token per slot; positions: (S,) write index;
-        lengths: (S,) attended span (0 = inactive). Returns (tokens,
-        logprobs) as numpy (S,).
-        """
-        S = self.config.max_slots
-        assert tokens.shape == (S,)
-        with self._lock:
-            if self.paged:
-                write_idx = np.full((S, 1), self._flat_size, np.int64)
-                for slot in range(S):
-                    if lengths[slot] > 0:
-                        pos = int(positions[slot])
-                        self._ensure_with_evict(slot, pos + 1)
-                        write_idx[slot, 0] = self.allocator.flat_write_indices(slot, pos, 1)[0]
-                toks, logprobs, self.cache = self._decode_fn_paged(
-                    self.params, self.cache,
-                    jnp.asarray(tokens[:, None]), jnp.asarray(positions[:, None]),
-                    jnp.asarray(lengths), jnp.asarray(write_idx),
-                    jnp.asarray(self.allocator.page_table()), jnp.asarray(temps),
-                    jnp.asarray(top_ps), self._next_rng(),
-                )
-            else:
-                toks, logprobs, self.cache = self._decode_fn(
-                    self.params, self.cache,
-                    jnp.asarray(tokens[:, None]), jnp.asarray(positions[:, None]),
-                    jnp.asarray(lengths), jnp.asarray(temps), jnp.asarray(top_ps),
-                    self._next_rng(),
-                )
-            active = int((lengths > 0).sum())
-            self.metrics["decode_tokens"] += active
-            self.metrics["decode_steps"] += 1
-        self._audit_transfer("h2d", "decode", tokens, positions, lengths,
-                             temps, top_ps)
-        toks_np, logprobs_np = np.asarray(toks), np.asarray(logprobs)
-        self._audit_transfer("d2h", "decode", toks_np, logprobs_np)
-        return toks_np, logprobs_np
 
     def _prefill_one_chunked(self, prompt: list[int], slot: int, temp: float, top_p: float,
                              seed: int | None = None, grammar=None) -> PrefillResult:
@@ -1948,7 +1763,7 @@ class Engine:
         (ISSUE 14), host side: every slot flagged in ``need`` gets pages
         covering pipeline_depth+1 future chunks (falling back to one
         chunk under page pressure, so deep horizons never manufacture
-        exhaustion the legacy per-chunk path wouldn't have hit) and the
+        exhaustion a chunk-by-chunk reservation wouldn't have hit) and the
         host mirror ``_reserved`` is raised to match. The caller ships
         the page table and the reserved spans to the device."""
         from inference_gateway_tpu.serving.kv_cache import OutOfPagesError
@@ -2051,14 +1866,13 @@ class Engine:
 
     def _fresh_submit_ee_locked(self, tokens, positions, active, temps, top_ps,
                                 n, seeds, use_seed, mstates, stop_tables, budgets):
-        """chain=False under decode_early_exit: host state is
-        authoritative — pack it all into ONE buffer (ISSUE 25), upload
-        that (first chunk, failure recovery), arm the on-device stop
-        criteria, and (re)build the chained steady-state host mirror the
-        later host-free submits read. ``_fresh_unpack_fn`` turns the
-        buffer into the device-resident state a chained submit starts
-        from, and the chunk is dispatched as one. Caller holds the
-        engine lock."""
+        """chain=False: host state is authoritative — pack it all into
+        ONE buffer (ISSUE 25), upload that (first chunk, failure
+        recovery), arm the on-device stop criteria, and (re)build the
+        chained steady-state host mirror the later host-free submits
+        read. ``_fresh_unpack_fn`` turns the buffer into the
+        device-resident state a chained submit starts from, and the
+        chunk is dispatched as one. Caller holds the engine lock."""
         # Until the program below has run, nothing on the device matches
         # the host: a failure in here must not leave a chainable carry.
         self._dev_carry = None
@@ -2074,8 +1888,10 @@ class Engine:
             v["mstates"][:] = mstates
         v["temps"][:] = temps
         v["top_ps"][:] = top_ps
-        v["seeds"][:] = seeds
-        v["use_seed"][:] = use_seed
+        if seeds is not None:  # the fills leave every row unseeded
+            v["seeds"][:] = seeds
+        if use_seed is not None:
+            v["use_seed"][:] = use_seed
         if stop_tables is not None:  # the fill is the EOS-only row
             v["stop_table"][...] = stop_tables
         self._chain_active = active.copy()
@@ -2114,111 +1930,41 @@ class Engine:
 
         chain=False: decode state (pending token, position, sampling
         params) is loaded from the host arrays — required for the first
-        chunk and after any admission or failure recovery. With
-        decode_early_exit, ``stop_tables`` (S, STOP_TABLE_WIDTH) and
-        ``budgets`` (S,) additionally arm the on-device stop criteria
-        (None = EOS-only tables and an effectively-unbounded budget —
-        the host finish checks remain the backstop either way).
+        chunk and after any admission or failure recovery.
+        ``stop_tables`` (S, STOP_TABLE_WIDTH) and ``budgets`` (S,) arm
+        the on-device stop criteria (None = EOS-only tables and an
+        effectively-unbounded budget — the host finish checks remain
+        the backstop either way); ``seeds`` with ``use_seed`` seed rows
+        (None = none seeded).
         chain=True: the previous chunk's device-resident final carry is
-        the input — no host upload, no sync. ``tokens`` is ignored;
-        under decode_early_exit every array argument is ignored (the
-        carry, sampling params, stop state, and page-table horizon are
-        all device/host-mirror resident) and the submit is genuinely
-        host-free. Without early exit, ``positions``/``active`` are used
-        for host-side paged write-index assembly as before. Invalid
-        after any prefill (which clears the carry): submitting
-        chain=True then raises instead of silently decoding stale
-        tokens.
+        the input — no host upload, no sync. Every array argument is
+        ignored (the carry, sampling params, stop state, and page-table
+        horizon are all device/host-mirror resident) and the submit is
+        genuinely host-free. Invalid after any prefill (which clears
+        the carry): submitting chain=True then raises instead of
+        silently decoding stale tokens.
 
         ``phases`` (ISSUE 24): a dict to stamp the submit's host phases
         into (lock_wait, upload_dispatch, in ms); it rides the handle.
         """
-        S = self.config.max_slots
         n = n_steps or self.config.decode_chunk
-        if seeds is None:
-            seeds = np.zeros((S,), np.int32)
-        if use_seed is None:
-            use_seed = np.zeros((S,), bool)
         t_lock = time.perf_counter_ns()
-        if self._early_exit:
-            with self._lock:
-                stamp_since(phases, "lock_wait", t_lock)
-                with phase_span(phases, "upload_dispatch", "engine.chunk.upload_dispatch"):
-                    if chain:
-                        # Host-free by construction (everything is device
-                        # resident) — the audit records NOTHING for it,
-                        # which is exactly how engine.transfers{h2d,chain}
-                        # stays a scrapeable zero (ISSUE 19 invariant; the
-                        # series is pre-seeded to 0 at attach).
-                        handle = self._chain_submit_locked(n)
-                    else:
-                        handle = self._fresh_submit_ee_locked(
-                            tokens, positions, active, temps, top_ps, n, seeds,
-                            use_seed, mstates, stop_tables, budgets)
-            handle.phases = phases
-            return handle
-        masked, mnext, mbits, mbias = self._mask_args()
-        # The legacy (fixed-scan) chunk assembles its write indices here,
-        # so its upload_dispatch phase holds that host work too.
-        with self._lock, phase_span(phases, "upload_dispatch", "engine.chunk.upload_dispatch"):
+        with self._lock:
             stamp_since(phases, "lock_wait", t_lock)
-            if chain:
-                if self._dev_carry is None:
-                    raise RuntimeError(
-                        "decode_chunk_submit(chain=True) with no device carry: "
-                        "a prefill or failure invalidated chained decode state; "
-                        "resubmit with chain=False")
-                tok_in, pos_in, ms_in = self._dev_carry
-                temps_d, tps_d, seeds_d, used_d = self._dev_sampling
-            else:
-                tok_in, pos_in = jnp.asarray(tokens), jnp.asarray(positions)
-                ms_in = jnp.asarray(mstates if mstates is not None
-                                    else self._zero_mstates)
-                temps_d, tps_d = jnp.asarray(temps), jnp.asarray(top_ps)
-                seeds_d, used_d = jnp.asarray(seeds), jnp.asarray(use_seed)
-                self._dev_sampling = (temps_d, tps_d, seeds_d, used_d)
-            if self.paged:
-                write_idx = np.full((S, n), self._flat_size, np.int64)
-                for slot in range(S):
-                    if active[slot]:
-                        pos = int(positions[slot])
-                        cap = min(pos + n, self.config.max_seq_len)
-                        valid = max(0, cap - pos)
-                        if valid:
-                            self._ensure_with_evict(slot, cap)
-                            write_idx[slot, :valid] = self.allocator.flat_write_indices(slot, pos, valid)
-                toks, logprobs, tok_f, pos_f, ms_f, self.cache = self._decode_chunk_fn_paged(
-                    self.params, self.cache, tok_in, pos_in,
-                    jnp.asarray(write_idx), jnp.asarray(self.allocator.page_table()),
-                    temps_d, tps_d, seeds_d, used_d, self._next_rng(),
-                    mstates=ms_in, mnext=mnext, mbits=mbits, mbias=mbias,
-                    n_steps=n, masked=masked,
-                )
-            else:
-                toks, logprobs, tok_f, pos_f, ms_f, self.cache = self._decode_chunk_fn(
-                    self.params, self.cache, tok_in, pos_in,
-                    temps_d, tps_d, seeds_d, used_d, self._next_rng(),
-                    mstates=ms_in, mnext=mnext, mbits=mbits, mbias=mbias,
-                    n_steps=n, masked=masked,
-                )
-            self._dev_carry = (tok_f, pos_f, ms_f)
-            n_active = int(active.sum())
-            self.metrics["decode_tokens"] += n_active * n
-            self.metrics["decode_steps"] += n
-            # Tokens + logprobs fused into one buffer → one readback.
-            both = jnp.concatenate([toks.astype(jnp.float32), logprobs], axis=0)
-        if chain:
-            # The legacy (non-early-exit) chain still assembles write
-            # indices and re-uploads the page table host-side on paged
-            # engines — the audit records that honestly; only the
-            # early-exit chain is h2d-free.
-            if self.paged:
-                self._audit_transfer("h2d", "chain", write_idx,
-                                     self.allocator.page_table())
-        else:
-            self._audit_transfer("h2d", "fresh", tokens, positions, temps,
-                                 top_ps, seeds, use_seed, mstates)
-        return _DecodeChunkHandle(both, n, phases)
+            with phase_span(phases, "upload_dispatch", "engine.chunk.upload_dispatch"):
+                if chain:
+                    # Host-free by construction (everything is device
+                    # resident) — the audit records NOTHING for it,
+                    # which is exactly how engine.transfers{h2d,chain}
+                    # stays a scrapeable zero (ISSUE 19 invariant; the
+                    # series is pre-seeded to 0 at attach).
+                    handle = self._chain_submit_locked(n)
+                else:
+                    handle = self._fresh_submit_ee_locked(
+                        tokens, positions, active, temps, top_ps, n, seeds,
+                        use_seed, mstates, stop_tables, budgets)
+        handle.phases = phases
+        return handle
 
     # -- speculative decoding (serving/speculative.py) ------------------
     @partial(jax.jit, static_argnames=("self",), donate_argnums=(2,))
@@ -2577,8 +2323,7 @@ class Engine:
         must stop sampling AND stop writing KV for it (the device write
         mask keys off this flag). In-flight chunks submitted earlier are
         safe by program ordering — any stale write lands before the
-        page's next occupant prefills over it, the same ordering
-        argument the legacy host-built write_idx path relied on."""
+        page's next occupant prefills over it."""
         return done.at[slot].set(True)
 
     def release_slot(self, slot: int, frozen: bool = False) -> None:
@@ -2592,21 +2337,17 @@ class Engine:
         stays pure-Python. Host-only finishes (stop strings,
         disconnects, preemption, failures) pass False and pay one tiny
         scatter so later chained chunks stop writing into freed pages."""
-        if (self.allocator is None and self.structured is None
-                and not self._early_exit):
-            return
         with self._lock:
             if self.allocator is not None:
                 self.allocator.release(slot)
             if self.structured is not None:
                 self.structured.release_slot(slot)
-            if self._early_exit:
-                self._chain_active[slot] = False
-                if not frozen and self._dev_carry is not None:
-                    tok, pos, ms, done, bud, rng = self._dev_carry
-                    self._dev_carry = (
-                        tok, pos, ms,
-                        self._mark_done_fn(done, jnp.int32(slot)), bud, rng)
+            self._chain_active[slot] = False
+            if not frozen and self._dev_carry is not None:
+                tok, pos, ms, done, bud, rng = self._dev_carry
+                self._dev_carry = (
+                    tok, pos, ms,
+                    self._mark_done_fn(done, jnp.int32(slot)), bud, rng)
 
     @property
     def mesh_axes(self) -> dict:

@@ -486,7 +486,7 @@ class Scheduler:
         the host↔device round trip overlaps chunk N+1's execution
         instead of serializing with it. Admission is asynchronous
         too: prefill results are scattered into the chained device state
-        on-device (engine._admit_scatter_fn), so a prefill dispatch slots
+        on-device (engine._admit_scatter_fn_ee), so a prefill dispatch slots
         between chunks with no drain. Handles (prefills + chunks) are
         processed FIFO — a chunk that includes freshly admitted slots is
         always processed after their prefill, so host bookkeeping sees
@@ -851,21 +851,18 @@ class Scheduler:
         grammars = [r.grammar for r in batch]
         biases = [r.logit_bias for r in batch]
         self._admitting = batch  # visible to abort_all if prefill wedges
-        stop_rows = budgets = None
-        if getattr(self.engine, "_early_exit", False):
-            # Arm the admitted slots' on-device stop state (ISSUE 14):
-            # the async-scattered first tokens chain straight into fused
-            # chunks, so stop tables and max_tokens budgets must be
-            # device-resident before any of those chunks run. The first
-            # emitted token counts toward generated (pending counts as
-            # 1), hence the -1; resumed requests already spent
-            # resume_generated of their budget.
-            eos = getattr(self.engine, "_eos_id", None)
-            stop_rows = np.stack(
-                [build_stop_row(eos, r.stop_token_ids) for r in batch])
-            budgets = np.asarray(
-                [max(r.max_tokens - r.resume_generated - 1, 0) for r in batch],
-                np.int64)
+        # Arm the admitted slots' on-device stop state (ISSUE 14): the
+        # async-scattered first tokens chain straight into fused chunks,
+        # so stop tables and max_tokens budgets must be device-resident
+        # before any of those chunks run. The first emitted token counts
+        # toward generated (pending counts as 1), hence the -1; resumed
+        # requests already spent resume_generated of their budget.
+        eos = getattr(self.engine, "_eos_id", None)
+        stop_rows = np.stack(
+            [build_stop_row(eos, r.stop_token_ids) for r in batch])
+        budgets = np.asarray(
+            [max(r.max_tokens - r.resume_generated - 1, 0) for r in batch],
+            np.int64)
         observing = self._observing
         t_submit = time.perf_counter_ns() if observing else 0
         try:
@@ -1282,7 +1279,7 @@ class Scheduler:
             return None
         n = self.engine.config.decode_chunk
         observing = self._observing
-        if chain and getattr(self.engine, "_early_exit", False):
+        if chain:
             # Host-free steady state (ISSUE 14): the device carry holds
             # tokens, positions, stop state, budgets, grammar states,
             # and the rng; the engine's host mirror holds the page
@@ -1340,15 +1337,14 @@ class Scheduler:
         try:
             handle = self.engine.decode_chunk_submit(
                 tokens, positions, active, temps, top_ps, n_steps=n,
-                seeds=seeds, use_seed=use_seed, chain=chain, mstates=mstates,
+                seeds=seeds, use_seed=use_seed, mstates=mstates,
                 stop_tables=stop_tables, budgets=budgets,
                 phases={"build": (t_submit - t_build) / 1e6} if observing else None)
         except Exception as e:
             self._fail_after_decode_error(e)
             return None
         submit = self._stamp_dispatch("decode", t_submit, t_build) if observing else None
-        if not chain:
-            self._deliver_deferred(submit, getattr(handle, "phases", None))
+        self._deliver_deferred(submit, getattr(handle, "phases", None))
         return _Inflight(handle, dict(self._slots), n, submit)
 
     def _spec_step(self) -> None:
@@ -1780,21 +1776,20 @@ class Scheduler:
         reason = None
         if finished:
             reason = "stop" if is_stop else "length"
-            if getattr(self.engine, "_early_exit", False):
-                # On-device stopping (ISSUE 14): did the early-exit carry
-                # freeze this row at the same step? True for every finish
-                # criterion the device enforces — a stop token that fit
-                # the shipped table (EOS rides the table via engine._eos_id
-                # — the SAME source the device was armed from, so an
-                # engine that couldn't ship EOS never overclaims a
-                # freeze), grammar completion, max_tokens, cache-row
-                # exhaustion. False only for host-side backstops (stop
-                # strings at the serving edge arrive as `disconnected`,
-                # handled below), so _process_chunk knows whether
-                # trailing chunk rows were computed or frozen.
-                st.device_stopped = (
-                    hit_max or out_of_room or grammar_end
-                    or (is_stop and int(token) in self._device_stop_ids(req)))
+            # On-device stopping (ISSUE 14): did the chunk's carry freeze
+            # this row at the same step? True for every finish criterion
+            # the device enforces — a stop token that fit the shipped
+            # table (EOS rides the table via engine._eos_id — the SAME
+            # source the device was armed from, so an engine that
+            # couldn't ship EOS never overclaims a freeze), grammar
+            # completion, max_tokens, cache-row exhaustion. False only
+            # for host-side backstops (stop strings at the serving edge
+            # arrive as `disconnected`, handled below), so
+            # _process_chunk knows whether trailing chunk rows were
+            # computed or frozen.
+            st.device_stopped = (
+                hit_max or out_of_room or grammar_end
+                or (is_stop and int(token) in self._device_stop_ids(req)))
         if req.disconnected and not finished:
             # Early termination (ISSUE 7): the client abandoned the
             # stream — finish at this decode step and free the slot/KV

@@ -1013,10 +1013,9 @@ class SidecarServer:
                 "reason": getattr(self.engine, "attention_path_reason", ""),
                 "mixed_step": getattr(self.engine, "mixed_ok", False),
             },
-            # Desynchronized decode (ISSUE 14): whether the decode loop
-            # stops on device and chains host-free, and at what shape.
+            # Desynchronized decode (ISSUE 14): the shape at which the
+            # decode loop stops on device and chains host-free.
             "decode": {
-                "early_exit": getattr(self.engine, "_early_exit", False),
                 "chunk": self.engine.config.decode_chunk,
                 "pipeline_depth": self.engine.config.pipeline_depth,
             },
@@ -1816,9 +1815,8 @@ async def serve(config: EngineConfig, host: str = "0.0.0.0", port: int = 8000,
         if svcfg.mixed_step_tokens:
             config.mixed_step_tokens = svcfg.mixed_step_tokens
     # Desynchronized decode (ISSUE 14): SERVING_DECODE_* maps onto the
-    # engine's early-exit / chunk-size / pipeline-depth knobs before the
-    # engine is built. 0 keeps the engine defaults.
-    config.decode_early_exit = svcfg.decode_early_exit
+    # engine's chunk-size / pipeline-depth knobs before the engine is
+    # built. 0 keeps the engine defaults.
     if svcfg.decode_chunk:
         config.decode_chunk = svcfg.decode_chunk
     if svcfg.decode_pipeline_depth:

@@ -56,6 +56,7 @@ from inference_gateway_tpu.resilience import (
     OverloadController,
     VirtualClock,
 )
+from inference_gateway_tpu.resilience.faults import EngineFaultInjector
 from inference_gateway_tpu.serving.engine import Engine, EngineConfig
 from inference_gateway_tpu.serving.server import SidecarServer
 
@@ -882,6 +883,51 @@ async def test_sigkill_one_worker_drops_zero_non_streamed_requests(cluster_stack
     assert seg.generation(victim) > victim_gen
 
 
+async def _kill_relay_mid_stream(seg, sidecar, url, body, headers):
+    """Stream a chat completion and SIGKILL the worker relaying it after
+    two content frames. The kill waits on state, not on time: the
+    sidecar's scheduler thread is wedged at a scripted decode submit
+    (``EngineFaultInjector`` "hang", 15 of the request's 96 tokens out),
+    so the stream CANNOT finish until the relay's connection is gone and
+    the wedge is released, however slowly this task is scheduled. Returns
+    (raw frames kept, their content deltas, victim index)."""
+    inj = EngineFaultInjector(sidecar.engine)
+    inj.at("decode_submit", inj.calls["decode_submit"] + 8, "hang")
+    client = HTTPClient()
+    buf, got, contents, victim = b"", b"", [], None
+    try:
+        # The limit is for a wedged stream that never shows two content
+        # frames: its TimeoutError is raised out here, past the except.
+        async with asyncio.timeout(120):
+            try:
+                resp = await client.post(url, body, headers=headers, stream=True)
+                assert resp.status == 200
+                async for block in resp.iter_raw():
+                    buf += block
+                    while b"\n\n" in buf:
+                        raw, buf = buf.split(b"\n\n", 1)
+                        raw += b"\n\n"
+                        got += raw
+                        payload = raw.strip()[5:].strip()
+                        if payload != b"[DONE]":
+                            ev = json.loads(payload)
+                            delta = ((ev.get("choices") or [{}])[0].get("delta") or {})
+                            if delta.get("content"):
+                                contents.append(delta["content"])
+                    if len(contents) >= 2 and victim is None:
+                        victim = next((i for i in seg.live()
+                                       if seg.worker_counter(i, "in_flight_streaming") > 0), None)
+                        assert victim is not None, "no worker holds the stream ticket"
+                        os.kill(seg.pid(victim), signal.SIGKILL)
+            except (HTTPClientError, OSError, ConnectionError, asyncio.IncompleteReadError):
+                pass
+    finally:
+        inj.uninstall()  # releases the wedge: the sidecar runs on
+    assert victim is not None, "stream finished before the kill landed"
+    assert b"[DONE]" not in got, "stream finished before the kill landed"
+    return got, contents, victim
+
+
 async def test_sigkill_mid_stream_completes_byte_identical_via_continuation(
         cluster_stack):
     """THE chaos acceptance: SIGKILL the worker relaying an SSE stream
@@ -896,10 +942,7 @@ async def test_sigkill_mid_stream_completes_byte_identical_via_continuation(
     headers.set("Content-Type", "application/json")
     headers.set("traceparent", TRACEPARENT)
 
-    # 96 tokens instead of the default 24: the kill must land while the
-    # relay is still streaming, and on a slow box a short stream can
-    # finish into the client's socket buffer (ticket already released)
-    # before two content frames are even parsed.
+    # 96 tokens: room for the wedge _kill_relay_mid_stream places at 15.
     body = json.dumps(_chat_body(max_tokens=96)).encode()
 
     # Reference run, unkilled.
@@ -916,34 +959,8 @@ async def test_sigkill_mid_stream_completes_byte_identical_via_continuation(
     # Killed run: read a few content frames, SIGKILL the worker that
     # holds the streaming ticket (visible in its shared slab), keep
     # whatever complete frames arrived.
-    client = HTTPClient()
-    resp = await client.post(url, body, headers=headers, stream=True)
-    assert resp.status == 200
-    buf, got, contents, killed = b"", b"", [], None
-    try:
-        async for block in resp.iter_raw():
-            buf += block
-            while b"\n\n" in buf:
-                raw, buf = buf.split(b"\n\n", 1)
-                raw += b"\n\n"
-                got += raw
-                payload = raw.strip()[5:].strip()
-                if payload != b"[DONE]":
-                    ev = json.loads(payload)
-                    delta = ((ev.get("choices") or [{}])[0].get("delta") or {})
-                    if delta.get("content"):
-                        contents.append(delta["content"])
-            if len(contents) >= 2 and killed is None:
-                for i in seg.live():
-                    if seg.worker_counter(i, "in_flight_streaming") > 0:
-                        killed = seg.pid(i)
-                        os.kill(killed, signal.SIGKILL)
-                        break
-                assert killed is not None, "no worker holds the stream ticket"
-    except (HTTPClientError, OSError, ConnectionError, asyncio.IncompleteReadError):
-        pass
-    assert killed is not None, "stream finished before the kill landed"
-    assert b"[DONE]" not in got, "stream finished before the kill landed"
+    got, contents, _victim = await _kill_relay_mid_stream(
+        seg, sidecar, url, body, headers)
 
     # Ticket reclaim within one reap interval (ISSUE 16 acceptance).
     assert await _await(lambda: seg.counter_total("in_flight_streaming") == 0,
@@ -1020,34 +1037,8 @@ async def test_journey_survives_originating_worker_death(cluster_stack):
     url = f"http://127.0.0.1:{port}/v1/chat/completions"
     body = json.dumps(_chat_body(max_tokens=96)).encode()
 
-    client = HTTPClient()
-    resp = await client.post(url, body, headers=headers, stream=True)
-    assert resp.status == 200
-    buf, got, contents, killed, victim = b"", b"", [], None, None
-    try:
-        async for block in resp.iter_raw():
-            buf += block
-            while b"\n\n" in buf:
-                raw, buf = buf.split(b"\n\n", 1)
-                raw += b"\n\n"
-                got += raw
-                payload = raw.strip()[5:].strip()
-                if payload != b"[DONE]":
-                    ev = json.loads(payload)
-                    delta = ((ev.get("choices") or [{}])[0].get("delta") or {})
-                    if delta.get("content"):
-                        contents.append(delta["content"])
-            if len(contents) >= 2 and killed is None:
-                for i in seg.live():
-                    if seg.worker_counter(i, "in_flight_streaming") > 0:
-                        victim, killed = i, seg.pid(i)
-                        os.kill(killed, signal.SIGKILL)
-                        break
-                assert killed is not None, "no worker holds the stream ticket"
-    except (HTTPClientError, OSError, ConnectionError, asyncio.IncompleteReadError):
-        pass
-    assert killed is not None, "stream finished before the kill landed"
-    assert b"[DONE]" not in got, "stream finished before the kill landed"
+    got, contents, victim = await _kill_relay_mid_stream(
+        seg, sidecar, url, body, headers)
     assert await _await(lambda: seg.counter_total("in_flight_streaming") == 0,
                         timeout=30)
 

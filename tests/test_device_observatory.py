@@ -174,7 +174,7 @@ def test_scheduler_attributes_recompile_stall_to_the_step_that_paid_it():
 # 3. Transfer audit: chained submits read zero h2d on a LIVE scrape
 # ---------------------------------------------------------------------------
 async def test_chained_submits_read_zero_h2d_on_live_metrics_scrape():
-    eng = Engine(_cfg("paged", decode_early_exit=True, max_seq_len=256,
+    eng = Engine(_cfg("paged", max_seq_len=256,
                       pipeline_depth=6))
     sidecar = _sidecar(eng)
     port = await sidecar.start("127.0.0.1", 0)
@@ -230,7 +230,7 @@ async def test_packed_submits_stage_one_array_with_its_true_bytes():
     buffers' nbytes, and `h2d/chain` stays 0 under the transfer guard."""
     from inference_gateway_tpu.serving.engine import MixedRow
 
-    eng = Engine(_cfg("paged", decode_early_exit=True, max_seq_len=256, mixed_step=True))
+    eng = Engine(_cfg("paged", max_seq_len=256, mixed_step=True))
     sidecar = _sidecar(eng)
     port = await sidecar.start("127.0.0.1", 0)
     try:

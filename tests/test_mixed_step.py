@@ -390,8 +390,9 @@ def test_warmup_compiles_mixed_program():
 
 
 def test_mixed_step_submit_is_engine_level_consistent():
-    """MixedRow decode result == Engine.decode for the same state (the
-    collapse of the per-bucket family can't drift from the old paths)."""
+    """MixedRow decode result == a one-step decode chunk from the same
+    state (the collapse of the per-bucket family can't drift from the
+    old paths)."""
     e1 = _mk_engine(False)
     e2 = _mk_engine(True)
     rng = np.random.default_rng(9)
@@ -406,13 +407,12 @@ def test_mixed_step_submit_is_engine_level_consistent():
     tok[0] = r1.first_token
     pos = np.zeros((S,), np.int32)
     pos[0] = len(prompt)
-    lens = np.zeros((S,), np.int32)
-    lens[0] = len(prompt) + 1
-    t1, _ = e1.decode(tok, pos, lens, np.zeros((S,), np.float32), np.ones((S,), np.float32))
+    t1, _ = e1.decode_chunk(tok, pos, pos > 0, np.zeros((S,), np.float32),
+                            np.ones((S,), np.float32), n_steps=1)
     h2 = e2.mixed_step_submit([MixedRow(slot=0, token_ids=[int(t2[0])],
                                         start=len(prompt), kind="decode")])
     t2b, _ = e2.mixed_step_fetch(h2)
-    assert int(t1[0]) == int(t2b[0])
+    assert int(t1[0, 0]) == int(t2b[0])
 
 
 def test_two_mixed_steps_in_flight_do_not_share_their_descriptors():

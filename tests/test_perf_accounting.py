@@ -17,7 +17,7 @@ from inference_gateway_tpu.otel.perf_accounting import (
     StepCostModel,
     roofline_report,
 )
-from inference_gateway_tpu.serving.engine import Engine, EngineConfig
+from inference_gateway_tpu.serving.engine import STOP_TABLE_WIDTH, Engine, EngineConfig
 from inference_gateway_tpu.serving.profiles import (
     PROFILES,
     ServingProfile,
@@ -498,40 +498,54 @@ def test_bench_accounting_overhead_under_5pct(aloop):
 
 
 def test_early_exit_zeroes_chunk_overrun_waste():
-    """ISSUE 14 regression: a stream finishing mid-chunk with early exit
-    ON records ~zero wasted_tokens{reason="chunk_overrun"} — the device
-    froze the row at the finish, so the trailing steps were never
-    computed and must not be double-counted as waste. With the feature
-    OFF, the legacy over-decode is attributed as before (the contrast
-    pins that the suppression keys off device_stopped, not off the
-    accounting path going dead)."""
-    for early_exit, expect_zero in ((True, True), (False, False)):
+    """ISSUE 14 regression: a stream that finishes mid-chunk on a
+    criterion the DEVICE holds (its max_tokens budget) records zero
+    wasted_tokens{reason="chunk_overrun"} — the device froze the row at
+    the finish, so the trailing steps were never computed and must not
+    be double-counted as waste — at eight steps a chunk and at the
+    one-step reference alike. A finish only the HOST sees (a stop id
+    past the device's STOP_TABLE_WIDTH-wide row) over-decodes and is
+    attributed as ever: the contrast pins that the suppression keys off
+    device_stopped, not off the accounting path going dead."""
+    def overrun_of(decode_chunk, **gen):
         eng = Engine(EngineConfig(
             model="test-tiny", max_slots=4, max_seq_len=128, dtype="float32",
-            max_prefill_batch=2, use_mesh=False, decode_chunk=8,
-            decode_early_exit=early_exit))
+            max_prefill_batch=2, use_mesh=False, decode_chunk=decode_chunk))
         acc = PerfAccounting(StepCostModel.from_engine(eng, chip="v5e"),
                              model="test-tiny", measured=False)
         sched = Scheduler(eng)
         sched.accounting = acc
         sched.start()
         try:
-            # max_tokens=3 finishes in the middle of the first 8-step
-            # chunk, with pipeline_depth more chunks already in flight.
-            generate_sync(sched, [1, 2, 3, 4], max_tokens=3)
+            out = generate_sync(sched, [1, 2, 3, 4], **gen)
             # Wait for the pipeline tail (the in-flight chunks carrying
-            # the finished stream) to drain — that is where legacy
-            # overrun is attributed.
+            # the finished stream) to drain — that is where overrun is
+            # attributed.
             deadline = time.monotonic() + 20
             while time.monotonic() < deadline and (sched._handles or sched._slots):
                 time.sleep(0.02)
-            overrun = acc.wasted.get("chunk_overrun", 0)
-            if expect_zero:
-                assert overrun == 0, f"early exit still billed {overrun} overrun tokens"
-            else:
-                assert overrun > 0, "legacy path stopped attributing overrun"
+            return acc.wasted.get("chunk_overrun", 0), out
         finally:
             sched.stop()
+
+    # max_tokens=3 finishes in the middle of the first 8-step chunk,
+    # with pipeline_depth more chunks already in flight.
+    for decode_chunk in (8, 1):
+        overrun, _ = overrun_of(decode_chunk, max_tokens=3)
+        assert overrun == 0, (
+            f"decode_chunk={decode_chunk}: a device-detected finish still "
+            f"billed {overrun} overrun tokens")
+    _, (base, _reason) = overrun_of(8, max_tokens=8)
+    # A token the stream first emits inside the first chunk, large
+    # enough to sort behind a row's worth of smaller stop ids.
+    hit = next(t for i, t in enumerate(base)
+               if 1 <= i <= 5 and base.index(t) == i and t >= 2 * STOP_TABLE_WIDTH)
+    filler = [t for t in range(hit) if t not in base][:STOP_TABLE_WIDTH]
+    assert len(filler) == STOP_TABLE_WIDTH  # they fill the row; the hit falls off it
+    overrun, (toks, reason) = overrun_of(
+        8, max_tokens=8, stop_token_ids=frozenset(filler + [hit]))
+    assert reason == "stop" and toks == base[:base.index(hit)]
+    assert overrun > 0, "a host-only finish stopped attributing overrun"
 
 
 # ---------------------------------------------------------------------------

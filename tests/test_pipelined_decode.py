@@ -115,7 +115,7 @@ def test_chained_submit_carry_and_admission_scatter():
     """chain=True with no carry ever established must raise; once a
     carry exists, a prefill no longer invalidates it — the admitted
     slot's (first token, position, sampling params) are scattered into
-    the device-resident state (engine._admit_scatter_fn), so chained
+    the device-resident state (engine._admit_scatter_fn_ee), so chained
     decoding continues across admissions with no host sync AND the
     admitted slot's chained tokens match an unchained reference."""
     mk = lambda: Engine(EngineConfig(

@@ -317,19 +317,21 @@ def test_continuation_fuzz_seeded_kill_scripts(aloop):
 
 # ---------------------------------------------------------------------------
 # Desynchronized-decode byte-identity fuzz (ISSUE 14): seeded trials
-# mixing early-exit on/off, injected KV-pressure preemption,
-# continuation splices, and stop-token / stop-string-shaped /
-# max_tokens / grammar-end finishes. Two full scheduler stacks run the
-# SAME request scripts — one with on-device stopping, one without — and
-# every stream must come out byte-identical and once-only billed in
-# every combination (preemption and early exit are both transparent).
+# mixing injected KV-pressure preemption, continuation splices, and
+# stop-token / stop-string-shaped / max_tokens / grammar-end finishes.
+# Two full scheduler stacks run the SAME request scripts — one at four
+# steps a chunk, where rows freeze mid-chunk and chunks exit early, and
+# a reference at one step a chunk, where neither can happen and the
+# host decides every stop — and every stream must come out
+# byte-identical and once-only billed in every combination (preemption
+# and on-device stopping are both transparent).
 # ---------------------------------------------------------------------------
 
 DESYNC_SEED = 20260804
 DESYNC_TRIALS = 3
 
 
-def _desync_stack(early_exit: bool):
+def _desync_stack(decode_chunk: int):
     from inference_gateway_tpu.resilience.faults import EngineFaultInjector
     from inference_gateway_tpu.serving.engine import Engine, EngineConfig
     from inference_gateway_tpu.serving.scheduler import Scheduler
@@ -337,8 +339,7 @@ def _desync_stack(early_exit: bool):
     eng = Engine(EngineConfig(
         model="test-tiny", max_slots=4, max_seq_len=128, dtype="float32",
         max_prefill_batch=2, use_mesh=False, attention="paged", page_size=16,
-        prefix_cache=False, decode_chunk=4, prefill_buckets=(16, 32),
-        decode_early_exit=early_exit))
+        prefix_cache=False, decode_chunk=decode_chunk, prefill_buckets=(16, 32)))
     sched = Scheduler(eng, preempt_max=5)
     sched.start()
     return eng, sched, EngineFaultInjector(eng)
@@ -378,8 +379,8 @@ def _desync_run(sched, script, timeout=240.0):
 
 def test_desync_decode_fuzz_byte_identity_and_once_only_billing():
     rng = random.Random(DESYNC_SEED)
-    eng_on, s_on, inj_on = _desync_stack(True)
-    eng_off, s_off, inj_off = _desync_stack(False)
+    eng_on, s_on, inj_on = _desync_stack(4)
+    eng_ref, s_ref, inj_ref = _desync_stack(1)
     try:
         seen_tokens: list = []
         for trial in range(DESYNC_TRIALS):
@@ -413,7 +414,7 @@ def test_desync_decode_fuzz_byte_identity_and_once_only_billing():
                 script[0]["temp"], script[0]["seed"] = 0.0, None
                 script[0]["max_tokens"] = rng.randint(8, 40)
                 g_on = eng_on.structured.session_for({"type": "json_object"})
-                g_off = eng_off.structured.session_for({"type": "json_object"})
+                g_ref = eng_ref.structured.session_for({"type": "json_object"})
             # Inject 0-2 recoverable page exhaustions at a shared future
             # call index: whatever preemption each stack actually
             # performs, streams must stay identical.
@@ -421,16 +422,16 @@ def test_desync_decode_fuzz_byte_identity_and_once_only_billing():
                 off = rng.randint(1, 6)
                 inj_on.at("decode_submit",
                           inj_on.calls["decode_submit"] + off, "exhaust")
-                inj_off.at("decode_submit",
-                           inj_off.calls["decode_submit"] + off, "exhaust")
+                inj_ref.at("decode_submit",
+                           inj_ref.calls["decode_submit"] + off, "exhaust")
             script_on = [dict(s) for s in script]
-            script_off = [dict(s) for s in script]
+            script_ref = [dict(s) for s in script]
             if trial % 2 == 1:
                 script_on[0]["grammar"] = g_on
-                script_off[0]["grammar"] = g_off
+                script_ref[0]["grammar"] = g_ref
             got_on = _desync_run(s_on, script_on)
-            got_off = _desync_run(s_off, script_off)
-            assert got_on == got_off, (trial, got_on, got_off)
+            got_ref = _desync_run(s_ref, script_ref)
+            assert got_on == got_ref, (trial, got_on, got_ref)
             for (toks, reason), spec in zip(got_on, script):
                 # Once-only billing: never more than max_tokens emitted,
                 # across any preemption resume.
@@ -456,11 +457,11 @@ def test_desync_decode_fuzz_byte_identity_and_once_only_billing():
                       "resume": len(head_toks)}
             ref_on = _desync_run(s_on, [dict(extended)])
             spl_on = _desync_run(s_on, [dict(splice)])
-            spl_off = _desync_run(s_off, [dict(splice)])
+            spl_ref = _desync_run(s_ref, [dict(splice)])
             assert head_toks + spl_on[0][0] == ref_on[0][0], trial
-            assert spl_on[0] == spl_off[0], trial
+            assert spl_on[0] == spl_ref[0], trial
     finally:
         inj_on.uninstall()
-        inj_off.uninstall()
+        inj_ref.uninstall()
         s_on.stop()
-        s_off.stop()
+        s_ref.stop()
