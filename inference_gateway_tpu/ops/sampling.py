@@ -4,6 +4,14 @@ Vectorized over the batch with *per-row* temperature and top-p so a
 continuous batch can mix greedy and sampled requests in one jitted decode
 step (no per-request recompiles). top_k is a static cap applied before
 top-p to bound the sort cost on the vocab axis.
+
+The k candidates come from ``select_top_k``: an EXACT grouped top-k.
+The k-th largest group maximum t is a lower bound of the k-th largest
+element, every element above t lies in a group whose maximum is above t,
+and the k groups with the largest maxima hold all of those and k elements
+>= t. Ties go to the lower column id, as lax.top_k's do: groups are taken
+lowest id first and kept in column order, so values and indices are
+lax.top_k's on every input and every sampled stream is unchanged.
 """
 
 from __future__ import annotations
@@ -75,13 +83,59 @@ def chunk_row_keys(
     return jnp.where(use_seed[None, :, None], seeded, unseeded)  # (n, B, 2)
 
 
+# The grouped selection's two constants, from the operation alone on a
+# v5e (hack/topk_bench.py; PERF.md §6, PR 31), k = 64, device time:
+# [48, 152064] lax.top_k 1.43 ms, grouped 0.38 ms (the sort of the 8,192
+# candidates 0.22 of it); [24, 32000] 0.215 ms against 0.26 ms. The sort
+# of the candidates costs the same whatever the vocabulary, so grouping
+# pays from about 512 groups on: 8 groups per candidate asked for.
+GROUP_LANES = 128
+GROUPED_MIN_GROUPS_PER_K = 8
+
+
+def top_k_plan(vocab_size: int, k: int) -> dict:
+    """Which selection ``select_top_k`` takes for a (V, k): a function of
+    the static shape alone. ``grouped`` where the vocabulary holds enough
+    lane-tile groups for the second sort to be much smaller than the
+    first; ``direct`` (plain lax.top_k) otherwise, k >= V included."""
+    groups = -(-vocab_size // GROUP_LANES)
+    grouped = 0 < k < vocab_size and groups >= GROUPED_MIN_GROUPS_PER_K * k
+    return {
+        "top_k": k,
+        "path": "grouped" if grouped else "direct",
+        "groups": groups if grouped else 0,
+        "candidates": k * GROUP_LANES if grouped else vocab_size,
+    }
+
+
+def select_top_k(x: jnp.ndarray, k: int):
+    """``jax.lax.top_k(x, k)`` — the same values AND indices on every
+    input — in one pass over ``x`` and a sort of k * GROUP_LANES
+    candidates where ``top_k_plan`` says grouping pays."""
+    V = x.shape[-1]
+    plan = top_k_plan(V, k)
+    if plan["path"] == "direct":
+        return jax.lax.top_k(x, k)
+    g, G = GROUP_LANES, plan["groups"]
+    if G * g != V:  # pad, never truncate: -inf at the highest ids loses every tie
+        pad = [(0, 0)] * (x.ndim - 1) + [(0, G * g - V)]
+        x = jnp.pad(x, pad, constant_values=-jnp.inf)
+    xg = x.reshape(*x.shape[:-1], G, g)
+    _, gid = jax.lax.top_k(jnp.max(xg, axis=-1), k)  # the k groups with the largest maxima
+    gid = jnp.sort(gid, axis=-1)  # ascending: candidate order is column order
+    cand = jnp.take_along_axis(xg, gid[..., None], axis=-2)  # (..., k, g)
+    vals, pos = jax.lax.top_k(cand.reshape(*x.shape[:-1], k * g), k)
+    idx = jnp.take_along_axis(gid, pos // g, axis=-1) * g + pos % g
+    return vals, idx
+
+
 def top_k_nucleus(scaled: jnp.ndarray, top_p: jnp.ndarray, top_k: int):
     """The one top-k + nucleus filter all samplers share: sort the k
     best (already-tempered) logits, drop everything outside the smallest
     prefix whose probability mass reaches top_p (always keeping the
     argmax). Returns (filtered_vals (..., k) with -inf outside the
     nucleus, idx (..., k))."""
-    vals, idx = jax.lax.top_k(scaled, top_k)  # sorted desc
+    vals, idx = select_top_k(scaled, top_k)  # sorted desc
     probs = jax.nn.softmax(vals, axis=-1)
     cum = jnp.cumsum(probs, axis=-1)
     keep = cum - probs < top_p[..., None]

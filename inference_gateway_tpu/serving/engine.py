@@ -35,6 +35,7 @@ from inference_gateway_tpu.ops.sampling import (
     per_row_keys,
     sample_tokens,
     sample_tokens_pregumbel,
+    top_k_plan,
 )
 from inference_gateway_tpu.otel.profiling import phase_span, stamp_since
 from inference_gateway_tpu.parallel.mesh import create_mesh, default_mesh_shape
@@ -585,6 +586,13 @@ class Engine:
         else:
             self.attention_path = "dense"
             self.attention_path_reason = "contiguous slot cache (paged attention not in use)"
+
+        # The sampler's verdict (ISSUE 31): which selection this engine's
+        # (vocabulary, top_k) takes in ops/sampling.select_top_k — static
+        # per engine, so it is a /debug/status field and not a counter.
+        self.sampler_plan = top_k_plan(
+            self.model_cfg.vocab_size,
+            effective_top_k(config.top_k, self.model_cfg.vocab_size))
 
         # Optional draft model for speculative decoding (config.spec_draft
         # names a llama-family preset/checkpoint sharing the target's

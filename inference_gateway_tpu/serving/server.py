@@ -1013,6 +1013,10 @@ class SidecarServer:
                 "reason": getattr(self.engine, "attention_path_reason", ""),
                 "mixed_step": getattr(self.engine, "mixed_ok", False),
             },
+            # The sampler's verdict (ISSUE 31): the candidate selection
+            # this engine's (vocabulary, top_k) takes — "grouped" sorts
+            # `candidates` of the vocabulary's columns, "direct" all of them.
+            "sampler": getattr(self.engine, "sampler_plan", None),
             # Desynchronized decode (ISSUE 14): the shape at which the
             # decode loop stops on device and chains host-free.
             "decode": {

@@ -289,6 +289,38 @@ def test_attention_path_surfaced_in_status_and_gauge():
     assert active[("tiny", "kernel")] == 0
 
 
+@pytest.mark.parametrize("vocab, top_k, want", [
+    (256, 64, {"top_k": 64, "path": "direct", "groups": 0, "candidates": 256}),
+    (256, 0, {"top_k": 256, "path": "direct", "groups": 0, "candidates": 256}),
+    (16384, 8, {"top_k": 8, "path": "grouped", "groups": 128, "candidates": 1024}),
+])
+def test_sampler_verdict_surfaced_in_status(vocab, top_k, want, monkeypatch):
+    """Which top-k selection an engine's (vocabulary, top_k) takes is
+    static per engine: /debug/status reports it beside attention_path
+    (ISSUE 31), so a deployment whose shapes went the direct way says so."""
+    import asyncio
+    import dataclasses
+    import json
+
+    from inference_gateway_tpu.models import llama
+    from inference_gateway_tpu.netio.server import Headers, Request
+    from inference_gateway_tpu.serving.server import SidecarServer
+
+    monkeypatch.setitem(llama.PRESETS, "test-tiny-vocab",
+                        dataclasses.replace(llama.PRESETS["test-tiny"], vocab_size=vocab))
+    engine = _mk_engine(True, model="test-tiny-vocab", top_k=top_k)
+    assert engine.sampler_plan == want
+
+    async def run():
+        server = SidecarServer(engine, served_model_name="tiny")
+        resp = await server.debug_status(Request(method="GET", path="/debug/status",
+                                                 query={}, headers=Headers(), body=b""))
+        assert json.loads(resp.body)["sampler"] == want
+        server.scheduler.stop()
+
+    asyncio.run(run())
+
+
 def test_mixed_row_multimodal_falls_back_to_bucketed_admission():
     """Requests the ragged program can't serve (embedding overrides)
     take the bucketed admission path — and still finish."""
