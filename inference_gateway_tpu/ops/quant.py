@@ -141,7 +141,12 @@ def qeinsum(eq: str, x: jnp.ndarray, w, out_dtype=None) -> jnp.ndarray:
 
 # Weight names quantized in the decoder pytrees (matmul weights only —
 # embeddings, norms, and routers stay full precision).
-QUANTIZABLE = ("wq", "wk", "wv", "wo", "wg", "wu", "wd")
+QUANTIZABLE = ("wq", "wk", "wv", "wo", "wg", "wu", "wd",
+               # models/phi4flash.py's matmul leaves (its QUANT_LEAVES): the MLP,
+               # the Mamba mixer's and the gated memory unit's projections, the
+               # fused key/value projection. W_x, W_dt, the convolution, A_log, D,
+               # the norms, lambdas and biases stay full precision.
+               "fc1", "fc2", "m_in", "mix_out", "g_in", "wkv")
 
 
 def quantize_llama_params(params: dict, mode: str = "int8", group: int = 128) -> dict:
@@ -183,7 +188,25 @@ def seeded_std(cfg, leaf: str) -> float:
     so every byte and operation, is the same.
 
     An untied head is assumed (tied, unit-scale embeddings would give
-    logits of scale 50)."""
+    logits of scale 50).
+
+    A model with Mamba layers (models/phi4flash.py): the embedding, which
+    is also the head, at 0.02 (logits of unit scale at the published 2,560;
+    a normed stream forgets the embedding's own scale); every other normal
+    leaf at hidden_size ** -0.5, which is 0.0198 at the published width and
+    keeps the tiny test preset (64 wide: 0.125) in the same regime: a
+    projection of a normed stream has unit gain, B and C are O(1) so the
+    state is a fifth of a mixer's output
+    and a stale or rounded one shows in the logits (at 0.02 the 64-wide
+    preset's state was under 1% of it and no test could see it), and W_dt d
+    spreads by ~0.1 around the published b_dt, so the steps stay
+    log-uniform in about [0.001, 0.1] and a state remembers tens to
+    thousands of tokens. Two leaves keep their published spread: the
+    convolution's taps 0.29 (uniform(-1/2, 1/2) of four taps) and the
+    lambdas 0.1 (normal(0, 0.1)). A_log, D and b_dt are not normal at all:
+    the model's initialiser writes them."""
+    if hasattr(cfg, "mamba_d_state"):
+        return {"m_conv_w": 0.29, "lam": 0.1, "embed": 0.02}.get(leaf, cfg.hidden_size ** -0.5)
     if getattr(cfg, "num_experts", 0):
         return {"embed": 1.0, "wo": 0.005, "router": 0.06}.get(leaf, 0.02)
     return 0.02
@@ -214,6 +237,16 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
     block is 0.5 GB), and a full-precision router.
     """
     from functools import partial as _partial
+
+    if hasattr(cfg, "mamba_d_state"):
+        # Its tree is its own (stacked by pair, five kinds of layer): the
+        # model's initialiser builds each matmul leaf quantised, an entry
+        # of the stack at a time, as ``qstack`` does below.
+        from inference_gateway_tpu.models import phi4flash
+
+        if mode != "int8":
+            raise ValueError("phi4flash: int8 is the one weight-only quantisation built for it")
+        return phi4flash.init_params(rng, cfg, dtype=dtype, quant=quantize_tensor)
 
     L, H, I, V = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.hd

@@ -271,6 +271,35 @@ class MixedStepHandle:
 # A mixture's mixed step counts, in the program, the rows each expert
 # multiplied; the fetch block carries one row for each of these (ISSUE 28).
 MIXED_STATS = ("expert_tokens_max", "expert_tokens_mean", "experts_read")
+# A model with windowed layers beside a state store (models/phi4flash.py)
+# counts, in both step programs, the pages its windowed layers read and the
+# pages their rows' contexts hold (ISSUE 32): two rows of the fetch block.
+WINDOW_STATS = ("window_pages_read", "context_pages")
+
+# Presets of models/phi4flash.py, by name: the module is imported when one
+# of them is asked for, and not on the way to any other model.
+PHI4FLASH_PRESETS = ("phi-4-mini-flash", "phi4flash-test-tiny")
+
+
+def preset_config(name: str):
+    """``(model module, config)`` of a preset name, or None."""
+    from inference_gateway_tpu.models import mixtral
+
+    if name in llama.PRESETS:
+        return llama, llama.PRESETS[name]
+    if name in mixtral.PRESETS:
+        return mixtral, mixtral.PRESETS[name]
+    if name in PHI4FLASH_PRESETS:
+        from inference_gateway_tpu.models import phi4flash
+
+        return phi4flash, phi4flash.PRESETS[name]
+    return None
+
+
+def carries_state(model_cfg) -> bool:
+    """A model whose layers keep a recurrent state per slot beside the
+    cache (models/phi4flash.py)."""
+    return hasattr(model_cfg, "mamba_d_state")
 
 
 def _experts_read(rows: jnp.ndarray) -> jnp.ndarray:
@@ -285,7 +314,8 @@ def choose_mesh(config: EngineConfig, model_cfg, n_dev: int):
     ``mesh_shape`` with pp > 1: layers AND the KV cache shard by stage
     over "pp", tp within a stage (dense-cache llama family only). A
     mixture without a ``mesh_shape``: experts on a dedicated ``ep`` axis,
-    tp inside each expert. Everything else: ``mesh_shape``'s dp/sp/tp (a
+    tp inside each expert. A model with a state store: none, and a
+    ``mesh_shape`` is refused by name. Everything else: ``mesh_shape``'s dp/sp/tp (a
     mixture's ep too) or the default factoring, tp degraded toward dp
     until it tiles the model."""
     from inference_gateway_tpu.models import mixtral
@@ -293,6 +323,15 @@ def choose_mesh(config: EngineConfig, model_cfg, n_dev: int):
     is_moe = isinstance(model_cfg, mixtral.MixtralConfig)
     shape = config.mesh_shape or {}
     if not config.use_mesh or n_dev <= 1:
+        return None, False
+    if carries_state(model_cfg):
+        # No mesh carries the state store yet. Asked for by name it is
+        # refused; left to the default, the model is served on one device
+        # (``mesh_axes`` and /debug/status say {"tp": 1}).
+        if shape:
+            raise ValueError(
+                f"{config.model}: no mesh ({shape}) carries the Mamba state store yet; "
+                "serve it on one device")
         return None, False
     if shape.get("pp", 1) > 1:
         # Pipeline-parallel serving (SURVEY §2.4 PP row). Only the
@@ -457,27 +496,39 @@ class Engine:
 
         from inference_gateway_tpu.models import mixtral
 
+        preset = preset_config(config.model)
         if model_cfg is not None:
             self.model_cfg = model_cfg
         elif config.checkpoint_path:
             from inference_gateway_tpu.serving.checkpoint import load_checkpoint
 
             params, self.model_cfg = load_checkpoint(config.checkpoint_path, dtype=self.dtype)
-        elif config.model in llama.PRESETS:
-            self.model_cfg = llama.PRESETS[config.model]
-        elif config.model in mixtral.PRESETS:
-            self.model_cfg = mixtral.PRESETS[config.model]
+        elif preset is not None:
+            self.model_cfg = preset[1]
         else:
             self.model_cfg, params = self._load_hf(config.model)
 
-        # Model-family dispatch: MixtralConfig → MoE forward; plain
-        # LlamaConfig → dense forward. Same call contract either way.
+        # Model-family dispatch: MixtralConfig → MoE forward; a config with
+        # a Mamba state → the hybrid's; plain LlamaConfig → dense forward.
+        # Same call contract either way.
         self.is_moe = isinstance(self.model_cfg, mixtral.MixtralConfig)
+        self.is_hybrid = carries_state(self.model_cfg)
         self._model = mixtral if self.is_moe else llama
+        if self.is_hybrid:
+            from inference_gateway_tpu.models import phi4flash
+
+            self._model = phi4flash
+            # What cannot carry the state is refused by name (ISSUE 32).
+            if config.spec_draft is not None:
+                raise ValueError(f"{config.model}: speculative decoding has no Mamba state to roll back; "
+                                 "serve it without --spec-draft")
+            if config.vision_model is not None:
+                raise ValueError(f"{config.model}: multimodal prefill carries no Mamba state")
+            if config.quantize == "int4":
+                raise ValueError(f"{config.model}: int8 is the one weight-only quantisation built for it, not int4")
         # Presets have no tokenizer files: straight to the byte tokenizer,
         # never a hub look-up by preset name.
-        is_preset = config.model in llama.PRESETS or config.model in mixtral.PRESETS
-        self.tokenizer = load_tokenizer(config.tokenizer or (None if is_preset else config.model))
+        self.tokenizer = load_tokenizer(config.tokenizer or (None if preset is not None else config.model))
 
         lap(None)
         n_dev = len(jax.devices())
@@ -511,7 +562,10 @@ class Engine:
                 max_slots=config.max_slots, max_seq_len=config.max_seq_len,
             )
             self.allocator = PageAllocator(self.page_cfg)
-            cache = init_paged_cache(self.model_cfg, self.page_cfg, dtype=self.dtype)
+            if self.is_hybrid:  # pages for its caching layers alone, and the state store beside them
+                cache = self._model.init_paged_cache(self.model_cfg, self.page_cfg, dtype=self.dtype)
+            else:
+                cache = init_paged_cache(self.model_cfg, self.page_cfg, dtype=self.dtype)
             if self.mesh is not None:
                 from jax.sharding import PartitionSpec as P
 
@@ -522,7 +576,9 @@ class Engine:
             if config.prefix_cache:
                 from inference_gateway_tpu.serving.kv_cache import PrefixCache
 
-                self.prefix_cache = PrefixCache(self.allocator)
+                # Pages can be handed over, a recurrent state cannot: with a
+                # state store every hit is declined, and counted.
+                self.prefix_cache = PrefixCache(self.allocator, decline_hits=self.is_hybrid)
         else:
             cache = self._model.init_cache(self.model_cfg, config.max_slots, config.max_seq_len, dtype=self.dtype)
             if self.mesh is not None:
@@ -543,9 +599,12 @@ class Engine:
         # (ISSUE 28) — spec rounds keep their own loop, a mixture whose
         # experts ride an ep axis keeps the bucketed paged path,
         # pp/multimodal carry state the ragged program doesn't.
+        # A model with a state store admits through the ragged step alone
+        # (the one program that hands a segment its slot's state), so a
+        # paged engine of it is a mixed engine whatever the flag says.
         ep = self.mesh.shape.get("ep", 1) if self.mesh is not None else 1
         self.mixed_ok = (
-            self.paged and config.mixed_step and config.spec_draft is None
+            self.paged and (config.mixed_step or self.is_hybrid) and config.spec_draft is None
             and ep == 1 and not self.pp and config.vision_model is None
         )
         biggest_bucket = max((b for b in config.prefill_buckets
@@ -742,6 +801,10 @@ class Engine:
             "decode_steps": 0,
             "prefill_batches": 0,
         }
+        if self.is_hybrid:
+            # Segments that started at position 0, so from a zeroed state:
+            # every admission, and every resume after a preemption.
+            self.metrics["state_resets"] = 0
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -979,13 +1042,13 @@ class Engine:
             w = page * ps + pos_att % ps
             valid = (~done) & (pos < max_len) & (pos < reserved)
             w = jnp.where(valid, w, self._flat_size)
-            moe = {"valid": ~done, "with_aux": True} if self.is_moe else {}
+            counted = {"valid": ~done, "with_aux": True} if self.is_moe or self.is_hybrid else {}
             logits, cache, *rows = self._model.forward_paged(
                 params, self.model_cfg, tok[:, None], pos_att[:, None],
                 pos_att + 1, cache, w[:, None], page_table, mode="decode",
-                last_only=True, mesh=self.mesh, **moe)
-            if rows:
-                read = _experts_read(rows[0])
+                last_only=True, mesh=self.mesh, **counted)
+            if rows:  # a mixture's experts read; a windowed hybrid's pages (WINDOW_STATS)
+                read = _experts_read(rows[0]) if self.is_moe else rows[0]
         if masked:
             with jax.named_scope("mask_bias"):
                 logits = logits + self._mask_bias(mbits, ms, mbias[:-1])
@@ -1036,8 +1099,10 @@ class Engine:
         out_toks0 = jnp.broadcast_to(tokens[None, :], (n_steps, S)).astype(jnp.int32)
         out_lps0 = jnp.zeros((n_steps, S), jnp.float32)
 
-        # A paged mixture's carry also sums the experts its steps read.
-        counted = self.is_moe and write_args is not None
+        # A paged mixture's carry also sums the experts its steps read, a
+        # windowed hybrid's the pages they read and held.
+        counted = (self.is_moe or self.is_hybrid) and write_args is not None
+        read0 = jnp.zeros((len(WINDOW_STATS),), jnp.float32) if self.is_hybrid else jnp.float32(0)
 
         def cond(carry):
             i, _cache, _tok, _pos, _ms, done, _bud, _ot, _ol, *_read = carry
@@ -1057,12 +1122,15 @@ class Engine:
             jax.lax.while_loop(
                 cond, body,
                 (jnp.int32(0), cache, tokens, positions, mstates, done0,
-                 budgets, out_toks0, out_lps0, *([jnp.float32(0)] if counted else [])))
-        if counted:
+                 budgets, out_toks0, out_lps0, *([read0] if counted else [])))
+        if counted and self.is_moe:
             # One more row of the block the host fetches anyway: the mean,
             # over the steps that ran, of the experts a step read.
             mean = read_sum[0] / jnp.maximum(i_ran, 1).astype(jnp.float32)
             out_lps = jnp.concatenate([out_lps, jnp.broadcast_to(mean, (1, S))])
+        elif counted:  # the pages of the steps that ran, summed: a row each
+            out_lps = jnp.concatenate(
+                [out_lps, jnp.broadcast_to(read_sum[0][:, None], (len(WINDOW_STATS), S))])
         # Steps the loop never ran emit each row's FINAL frozen token
         # (not the chunk-entry one), so the emitted block reads exactly
         # like a chunk whose frozen rows kept repeating their last
@@ -1157,10 +1225,10 @@ class Engine:
         path — packed width is the fixed mixed_budget, so admission
         never recompiles and never pays bucket padding. Traced inside
         _mixed_step_fn."""
-        moe = {"with_aux": True} if self.is_moe else {}
+        counted = {"with_aux": True} if self.is_moe or self.is_hybrid else {}
         logits, cache, *rows = self._model.forward_ragged(
             params, self.model_cfg, tokens, positions, cache, write_idx,
-            page_table, q_starts, q_lens, kv_lens, mesh=self.mesh, **moe)
+            page_table, q_starts, q_lens, kv_lens, mesh=self.mesh, **counted)
         if masked:
             # Mixed rows are slot-aligned: mask by each slot's automaton
             # state, bias by its logit_bias row (constrained prefill-tail
@@ -1171,6 +1239,8 @@ class Engine:
             keys = per_row_keys(rng, seeds, use_seed, kv_lens)
             toks = sample_tokens(logits, rng, temps, top_ps, top_k=self.config.top_k, row_keys=keys)
             logprobs = compute_logprobs(logits, toks)
+        if rows and self.is_hybrid:
+            return toks, logprobs, cache, rows[0]  # WINDOW_STATS, counted by the model
         if rows:
             # Rows of the fetch block, in MIXED_STATS' order: per layer the
             # most rows one expert multiplied and the mean over experts,
@@ -1198,7 +1268,7 @@ class Engine:
         block = jnp.stack([toks.astype(jnp.float32), logprobs])
         if stats:
             block = jnp.concatenate(
-                [block, jnp.broadcast_to(stats[0][:, None], (len(MIXED_STATS), block.shape[1]))])
+                [block, jnp.broadcast_to(stats[0][:, None], (stats[0].shape[0], block.shape[1]))])
         return block, cache
 
     def _audit_transfer(self, direction: str, path: str, *arrays) -> int:
@@ -1275,6 +1345,8 @@ class Engine:
                     off += n
                     if r.kind == "prefill":
                         n_prefill += n
+                    if self.is_hybrid and r.start == 0:
+                        self.metrics["state_resets"] += 1
             with phase_span(phases, "upload_dispatch", "engine.mixed.upload_dispatch"):
                 masked, mnext, mbits, mbias = self._mask_args()
                 v["counter"][0] = self._bump_step()
@@ -1293,7 +1365,7 @@ class Engine:
                 self.metrics["decode_tokens"] += n_decode_tokens
             self.metrics["prefill_tokens"] += n_prefill
         h2d_arrays = self._audit_transfer("h2d", "mixed", staged)
-        return MixedStepHandle(both, list(rows), phases, h2d_arrays)
+        return MixedStepHandle(both, list(rows), phases, h2d_arrays, self._state_stats(len(rows)))
 
     def mixed_step_fetch(self, handle: "MixedStepHandle"):
         """Block until a mixed step's sampled tokens are on host.
@@ -1302,8 +1374,32 @@ class Engine:
             both = np.asarray(handle.toks_lp)
         self._audit_transfer("d2h", "mixed", both)
         if both.shape[0] > 2:
-            handle.stats = {k: round(float(v), 3) for k, v in zip(MIXED_STATS, both[2:, 0])}
+            names = WINDOW_STATS if self.is_hybrid else MIXED_STATS
+            handle.stats = {**(handle.stats or {}),
+                            **{k: round(float(v), 3) for k, v in zip(names, both[2:, 0])}}
         return both[0].astype(np.int32), both[1]
+
+    def _state_stats(self, rows: int) -> dict | None:
+        """``state_bytes`` of a step's timeline record: what its rows hold in
+        the state store, which the step reads and writes once a token."""
+        if not self.is_hybrid:
+            return None
+        return {"state_bytes": int(rows) * self.model_cfg.state_bytes_per_slot}
+
+    def state_status(self) -> dict | None:
+        """/debug/status of a model with a state store: the store, the layers
+        that write the page pool and who reads the shared one, and the two
+        counters (segments started from zero; prefix hits declined)."""
+        if not self.is_hybrid:
+            return None
+        cfg = self.model_cfg
+        return {
+            "state_store": {"slots": self.config.max_slots, "layers": cfg.state_layers,
+                            "bytes": self.config.max_slots * cfg.state_bytes_per_slot},
+            "cache_layers": {"window": cfg.mid, "full": 1, "shared_readers": cfg.cross_layers},
+            "state_resets": self.metrics["state_resets"],
+            "prefix_hits_declined": self.prefix_cache.declined if self.prefix_cache is not None else 0,
+        }
 
     def _prefill_one_ragged(self, prompt: list[int], slot: int, temp: float, top_p: float,
                             seed: int | None = None, grammar=None) -> PrefillResult:
@@ -1870,7 +1966,7 @@ class Engine:
         self.metrics["decode_tokens"] += n_active * n
         self.metrics["decode_steps"] += n
         both = jnp.concatenate([toks.astype(jnp.float32), logprobs], axis=0)
-        return _DecodeChunkHandle(both, n)
+        return _DecodeChunkHandle(both, n, stats=self._state_stats(n_active))
 
     def _fresh_submit_ee_locked(self, tokens, positions, active, temps, top_ps,
                                 n, seeds, use_seed, mstates, stop_tables, budgets):
@@ -2305,7 +2401,9 @@ class Engine:
         self._audit_transfer("d2h", "chunk", both)
         n = handle.n_steps
         if both.shape[0] > 2 * n:
-            handle.stats = {"experts_read": round(float(both[2 * n, 0]), 3)}
+            names = WINDOW_STATS if self.is_hybrid else ("experts_read",)
+            handle.stats = {**(handle.stats or {}),
+                            **{k: round(float(v), 3) for k, v in zip(names, both[2 * n:, 0])}}
         return both[:n].astype(np.int32), both[n:2 * n]
 
     def decode_chunk(self, tokens: np.ndarray, positions: np.ndarray, active: np.ndarray,
@@ -2373,6 +2471,8 @@ class Engine:
         400 check can never drift from actual admission behavior."""
         biggest = max(b for b in self.config.prefill_buckets
                       if b <= self.config.max_seq_len)
+        if self.is_hybrid and self.mixed_ok:
+            biggest = 0  # every prompt is a "long" one: the ragged step admits them all
         ring_ok = (
             self.mesh is not None
             and self.mesh.shape.get("sp", 1) > 1

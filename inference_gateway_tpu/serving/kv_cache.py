@@ -151,8 +151,15 @@ class PrefixCache:
     the cost of the tail.
     """
 
-    def __init__(self, allocator: PageAllocator, max_cached_pages: int | None = None):
+    def __init__(self, allocator: PageAllocator, max_cached_pages: int | None = None,
+                 decline_hits: bool = False):
         from collections import OrderedDict
+
+        # A model that keeps a recurrent state per slot (models/phi4flash.py)
+        # has nothing to hand over with the pages: the cache still learns the
+        # prefixes, holds no page for them, declines every hit and counts it.
+        self.decline_hits = decline_hits
+        self.declined = 0
 
         self.allocator = allocator
         self.page_size = allocator.cfg.page_size
@@ -188,6 +195,10 @@ class PrefixCache:
             self._entries.move_to_end(chain)
             pages.append(entry[0])
             matched += ps
+        if self.decline_hits:
+            self.declined += bool(pages)
+            self.misses += 1
+            return [], 0
         for p in pages:
             self.allocator.incref(p)
         if pages:
@@ -212,21 +223,24 @@ class PrefixCache:
                 if len(self._entries) >= self.max_cached_pages:
                     return
             page = slot_pages[i]
-            self.allocator.incref(page)  # cache's own hold
+            if not self.decline_hits:
+                self.allocator.incref(page)  # cache's own hold
             self._entries[chain] = (page, chunk)
 
     def _evict_one(self) -> None:
         if not self._entries:
             return
         _, (page, _tokens) = self._entries.popitem(last=False)
-        self.allocator.decref(page)
+        if not self.decline_hits:
+            self.allocator.decref(page)
 
     def evict_for_pressure(self, min_free: int) -> None:
         while self.allocator.free_page_count() < min_free and self._entries:
             self._evict_one()
 
     def stats(self) -> dict:
-        return {"cached_pages": len(self._entries), "hits": self.hits, "misses": self.misses}
+        out = {"cached_pages": len(self._entries), "hits": self.hits, "misses": self.misses}
+        return {**out, "declined": self.declined} if self.decline_hits else out
 
 
 def init_paged_cache(model_cfg: LlamaConfig, cache_cfg: PagedCacheConfig, dtype=jnp.bfloat16):

@@ -70,6 +70,8 @@ class ServingProfile:
 # Parameter / cache byte accounting (from model config, no arrays built)
 # ---------------------------------------------------------------------------
 def llama_param_count(cfg: llama.LlamaConfig) -> int:
+    if hasattr(cfg, "param_count"):  # a model that is not one block L times counts its own
+        return cfg.param_count()
     H, I, V = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
     Hkv_D = cfg.num_kv_heads * cfg.hd
     Hq_D = cfg.num_heads * cfg.hd
@@ -98,15 +100,19 @@ def mixtral_param_count(cfg: mixtral.MixtralConfig) -> int:
 
 
 def kv_bytes_per_token(cfg, dtype_bytes: int = 2) -> int:
-    """k + v bytes for ONE cached token across all layers (unsharded)."""
-    return 2 * cfg.num_layers * cfg.num_kv_heads * cfg.hd * dtype_bytes
+    """k + v bytes for ONE cached token across all layers that write the
+    cache (unsharded): every layer, unless the model says which
+    (``cache_layers``: models/phi4flash.py has 9 for 32 layers)."""
+    layers = getattr(cfg, "cache_layers", cfg.num_layers)
+    return 2 * layers * cfg.num_kv_heads * cfg.hd * dtype_bytes
 
 
 def resolve_model_cfg(model: str):
-    if model in llama.PRESETS:
-        return llama.PRESETS[model]
-    if model in mixtral.PRESETS:
-        return mixtral.PRESETS[model]
+    from inference_gateway_tpu.serving.engine import preset_config
+
+    preset = preset_config(model)
+    if preset is not None:
+        return preset[1]
     raise KeyError(f"unknown model preset: {model}")
 
 

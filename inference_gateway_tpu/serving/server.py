@@ -989,6 +989,7 @@ class SidecarServer:
                 # probe round, so compact by construction.
                 brief["device"] = self.observatory.fleet_summary()
             return Response.json(brief)
+        state_status = getattr(self.engine, "state_status", None)  # test doubles have none
         status: dict[str, Any] = {
             "model": self.model_name,
             "uptime_seconds": round(self._clock.now() - self._started, 3),
@@ -1017,6 +1018,10 @@ class SidecarServer:
             # this engine's (vocabulary, top_k) takes — "grouped" sorts
             # `candidates` of the vocabulary's columns, "direct" all of them.
             "sampler": getattr(self.engine, "sampler_plan", None),
+            # A model with a state store (ISSUE 32): ``state_store`` (slots,
+            # layers, bytes), ``cache_layers`` (window, full, shared_readers),
+            # ``state_resets`` and ``prefix_hits_declined``; absent otherwise.
+            **((state_status() if state_status else None) or {}),
             # Desynchronized decode (ISSUE 14): the shape at which the
             # decode loop stops on device and chains host-free.
             "decode": {

@@ -201,3 +201,66 @@ def test_dropless_expert_layer_compiles_on_the_four_chip_mesh(topo, as_on_tpu, s
     assert "moe_down_gmm" in text and ("moe_gate_up_gmm" in text) == ((I // tp) % 128 == 0)
     assert text.count("all-reduce(") == 1 and "all-gather(" not in text and "all-to-all(" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# phi-4-mini-flash (models/phi4flash.py PRESETS) under its cell's sidecar flags:
+# differential attention in the zero-padded form runs both kernels at 40 query
+# heads over 10 KV heads of 128 (20 x 64 as they lie in the cache row), pages of
+# 128, 64 slots, a pool of 9 caching layers; windowed (512) and full.
+PHI = dict(hq=40, hkv=10, d=128, slots=64, layers=9, budget=512 + 64, di=5120, n=16, k=4)
+
+
+def _phi_pool(sds):
+    return sds((PHI["layers"] * PHI["slots"] * MAX_PAGES, PAGE, PHI["hkv"] * PHI["d"]), BF16)
+
+
+@pytest.mark.parametrize("window", [512, None], ids=["window_512", "full"])
+def test_decode_kernel_compiles_at_ten_kv_heads_for_v5e(one_chip, window):
+    _compiles_to_a_kernel(
+        lambda q, k, v, table, lengths: paged_attention_tpu(
+            q, k, v, table, lengths, PHI["hkv"], interpret=False, window=window),
+        one_chip((PHI["slots"], PHI["hq"], PHI["d"]), BF16), _phi_pool(one_chip), _phi_pool(one_chip),
+        one_chip((PHI["slots"], MAX_PAGES), I32), one_chip((PHI["slots"],), I32))
+
+
+@pytest.mark.parametrize("window", [512, None], ids=["window_512", "full"])
+def test_ragged_kernel_compiles_at_ten_kv_heads_for_v5e(one_chip, window):
+    row = one_chip((PHI["slots"],), I32)
+    _compiles_to_a_kernel(
+        lambda q, k, v, table, starts, lens, kv: ragged_paged_attention_tpu(
+            q, k, v, table, starts, lens, kv, PHI["hkv"], interpret=False, window=window),
+        one_chip((PHI["budget"], PHI["hq"], PHI["d"]), BF16), _phi_pool(one_chip), _phi_pool(one_chip),
+        one_chip((PHI["slots"], MAX_PAGES), I32), row, row, row)
+
+
+@pytest.mark.parametrize("form", ["decode", "ragged"])
+def test_state_operations_compile_for_v5e_at_the_published_widths(one_chip, form):
+    """The convolution and the selective scan of one Mamba layer (ops/ssm.py) at
+    64 slots x 5,120 x 16 float32: the batched decode update, and the mixed
+    step's token loop over 576 packed tokens. Plain XLA: a pass says the chip's
+    compiler takes them and holds the store once (no second copy of it)."""
+    from inference_gateway_tpu.ops import ssm
+
+    S, T, Di, N, K = PHI["slots"], PHI["budget"], PHI["di"], PHI["n"], PHI["k"]
+    F32 = jnp.float32
+    states, tails = one_chip((S, Di, N), F32), one_chip((S, K - 1, Di), F32)
+    w, b, A, D = one_chip((K, Di), F32), one_chip((Di,), F32), one_chip((Di, N), F32), one_chip((Di,), F32)
+    row = one_chip((S,), I32)
+    if form == "decode":
+        def step(u, tails, w, b, states, delta, B, C, A, D, valid):
+            c, tails = ssm.conv_decode(u, tails, w, b, valid)
+            return ssm.scan_decode(states, delta, c, B, C, A, D, valid), tails
+
+        args = (one_chip((S, Di), BF16), tails, w, b, states, one_chip((S, Di), F32), one_chip((S, N), F32),
+                one_chip((S, N), F32), A, D, one_chip((S,), jnp.bool_))
+    else:
+        def step(u, tails, w, b, states, delta, B, C, A, D, starts, lens, kv, positions):
+            seg = ssm.token_segments(starts, lens, T)
+            c, tails = ssm.conv_ragged(u, tails, w, b, seg, (kv - lens) == 0, starts, lens)
+            return ssm.scan_ragged(states, delta, c, B, C, A, D, seg, positions, jnp.sum(lens)), tails
+
+        args = (one_chip((T, Di), BF16), tails, w, b, states, one_chip((T, Di), F32), one_chip((T, N), F32),
+                one_chip((T, N), F32), A, D, row, row, row, one_chip((T,), I32))
+    compiled = jax.jit(step, donate_argnums=(1, 4)).lower(*args).compile()
+    store = S * Di * N * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < store + (64 << 20)
