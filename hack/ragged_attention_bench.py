@@ -1,0 +1,193 @@
+"""Time the ragged attention kernel alone on the chip (ISSUE 34).
+
+By hand, through chiprun, parent and change in one call (a chip belongs to
+one process at a time, so each side is a process of its own):
+
+    chiprun -- sh -c 'python3 hack/ragged_attention_bench.py --root scratch_checkout/parent; \
+                      python3 hack/ragged_attention_bench.py'
+
+One mixed step's rows at each benchmark cell's shape (heads, window, slots,
+the step's width; contexts and chunk lengths as the cell's traffic gives
+them), `ragged_paged_attention_tpu` chained CALLS times inside one program as
+a step's layers chain it, the host clock around `block_until_ready`, the
+median of REPEATS. Beside the time: the call's arithmetic and the bytes of one
+reading of what its rows can see, the v5e's least time for them
+(`bench/chips.json`), and the largest absolute difference from
+`ragged_paged_attention_jax` on the same bf16 inputs. `--set NAME=JSON` sets a
+constant of the kernel's module first (a variant to try); `--window-operand`
+hands the cases of a model that mixes layer kinds their window as the operand
+its mixed step hands it. Not part of the benchmark, imported by nothing a cell
+runs; a CPU run of it times the CPU and is never a speed number.
+"""
+
+from __future__ import annotations
+
+import argparse
+import importlib
+import json
+import os
+import statistics
+import sys
+import time
+
+HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PAGE, MAX_PAGES, CALLS, REPEATS = 128, 16, 16, 15
+
+# name: (Hq, Hkv, D, window, slots, step width, [(q_len, kv_len), ...])
+def _cases() -> dict:
+    import numpy as np
+
+    rng = np.random.default_rng(33)
+
+    def decode(n, lo, hi):
+        return [(1, int(k)) for k in rng.integers(lo, hi, size=n)]
+
+    return {
+        # a 512-token chunk of a 1,024-1,792-token prompt, ~2.5 rows decoding
+        "mistral-7b.long-prompt": (32, 8, 128, 4096, 24, 536,
+                                   [(512, 1024)] + decode(3, 1100, 1800)),
+        # 48 slots never short of admissions: ~440 prompt tokens a step
+        "qwen2.5-7b.batch": (28, 4, 128, None, 48, 560,
+                             [(300, 300), (140, 140)] + decode(46, 100, 900)),
+        "smallthinker-21b-a3b.batch": (28, 4, 128, 4096, 48, 560,
+                                       [(300, 300), (140, 140)] + decode(46, 100, 900)),
+        # one ~280-token prompt over the rows a 4-5 req/s open loop keeps
+        "qwen2.5-7b.chat": (28, 4, 128, None, 48, 560, [(280, 280)] + decode(14, 100, 700)),
+        "mistral-7b.chat": (32, 8, 128, 4096, 24, 536, [(280, 280)] + decode(10, 100, 700)),
+        # 64 rows mostly past the 512-token window, one short prompt
+        "phi-4-mini-flash.reason": (40, 10, 128, 512, 64, 576,
+                                    [(190, 190)] + decode(62, 300, 1500)),
+        # the shape an 8-query tile was worst at, alone: a chunk deep in a prompt
+        "chunk-512-at-1536": (32, 8, 128, 4096, 24, 536, [(512, 1536)]),
+        # what no cell sends often: the last few tokens of three chunked prompts
+        # (a wide tile of which 2-20 queries are live) beside the decoding rows
+        "chunk-tails": (28, 4, 128, None, 48, 560,
+                        [(5, 517), (20, 1044), (2, 300)] + decode(45, 100, 900)),
+    }
+
+
+# The cases whose model mixes windowed and global layers.
+MIXED_KINDS = ("smallthinker-21b-a3b.batch", "phi-4-mini-flash.reason")
+
+
+def _reckon(rows, Hq, Hkv, D, window) -> tuple[float, float]:
+    """(floating-point operations, bytes) the call needs: 4·Hq·D a visible
+    (query, key) pair; each page a row can see read once, q read and the
+    output written once."""
+    pairs = pages = tokens = 0
+    for q_len, kv_len in rows:
+        start = kv_len - q_len
+        for j in range(q_len):
+            pos = start + j
+            pairs += min(pos + 1, window or pos + 1)
+        first = max(start + 1 - (window or kv_len), 0) // PAGE
+        pages += -(-kv_len // PAGE) - first
+        tokens += q_len
+    return 4.0 * Hq * D * pairs, 2.0 * pages * PAGE * Hkv * D * 2 + 2.0 * tokens * Hq * D * 2
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--root", default=HERE, help="checkout whose kernel to time")
+    ap.add_argument("--cases", nargs="*")
+    ap.add_argument("--set", nargs="*", default=[], metavar="NAME=JSON")
+    ap.add_argument("--window-operand", action="store_true")
+    ap.add_argument("--tag")
+    args = ap.parse_args()
+    root = os.path.abspath(args.root)
+    sys.path.insert(0, root)
+
+    import jax
+
+    pa = importlib.import_module("inference_gateway_tpu.ops.paged_attention")
+    for item in args.set:
+        name, value = item.split("=", 1)
+        assert hasattr(pa, name), name
+        setattr(pa, name, json.loads(value))
+    dev = jax.devices()[0]
+    out = {"root": os.path.relpath(root, HERE), "set": args.set, "window_operand": args.window_operand,
+           "device": {"platform": dev.platform, "kind": dev.device_kind},
+           "calls_chained": CALLS, "cases": measure(pa, args.cases, args.window_operand)}
+    dest = os.path.join(HERE, "chiprun_out", "ragged_attention_bench")
+    os.makedirs(dest, exist_ok=True)
+    tag = args.tag or ("change" if root == HERE else os.path.basename(root))
+    with open(os.path.join(dest, f"{tag}.json"), "w") as f:
+        json.dump(out, f, indent=1)
+    print(json.dumps(out))
+    return 0 if dev.platform == "tpu" else 1
+
+
+def measure(pa, names=None, window_operand=False) -> dict:
+    """Each case's line, for the kernel of the module ``pa``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    dev = jax.devices()[0]
+    chips = json.load(open(os.path.join(HERE, "bench", "chips.json")))
+    chip = chips.get(dev.device_kind)
+    lines = {}
+    for name, (Hq, Hkv, D, window, R, T, rows) in _cases().items():
+        if names and name not in names:
+            continue
+        rng = np.random.default_rng(len(name))
+        q_lens = np.zeros(R, np.int32)
+        kv_lens = np.zeros(R, np.int32)
+        q_starts = np.zeros(R, np.int32)
+        slots = rng.permutation(R)[:len(rows)]
+        off = 0
+        for slot, (ql, kl) in zip(slots, rows):
+            q_starts[slot], q_lens[slot], kv_lens[slot] = off, ql, kl
+            off += ql
+        assert off <= T, (name, off, T)
+        P = R * MAX_PAGES
+        bf = jnp.bfloat16
+        q = jnp.asarray(rng.normal(size=(T, Hq, D)), bf)
+        k = jnp.asarray(rng.normal(size=(P, PAGE, Hkv * D)), bf)
+        v = jnp.asarray(rng.normal(size=(P, PAGE, Hkv * D)), bf)
+        pt = jnp.asarray(rng.permutation(P).reshape(R, MAX_PAGES).astype(np.int32))
+        descr = (jnp.asarray(q_starts), jnp.asarray(q_lens), jnp.asarray(kv_lens))
+        interpret = dev.platform != "tpu"
+
+        kw = {"window": window}
+        if window_operand and name in MIXED_KINDS:
+            kw = {"window_dyn": jnp.int32(window)}
+
+        def call(q_, k_, v_):
+            return pa.ragged_paged_attention_tpu(q_, k_, v_, pt, *descr, Hkv,
+                                                 interpret=interpret, **kw)
+
+        @jax.jit
+        def chained(q_, k_, v_):
+            return jax.lax.fori_loop(0, CALLS, lambda i, x: call(x, k_, v_), q_)
+
+        got = jax.block_until_ready(jax.jit(call)(q, k, v))
+        ref = jax.jit(pa.ragged_paged_attention_jax, static_argnames=("num_kv_heads", "window"))(
+            q, k, v, pt, *descr, num_kv_heads=Hkv, window=window)
+        diff = float(jnp.max(jnp.abs(got.astype(jnp.float32) - ref.astype(jnp.float32))))
+        jax.block_until_ready(chained(q, k, v))
+        times = []
+        for _ in range(REPEATS):
+            t0 = time.perf_counter()
+            jax.block_until_ready(chained(q, k, v))
+            times.append((time.perf_counter() - t0) / CALLS)
+        flops, nbytes = _reckon(rows, Hq, Hkv, D, window)
+        line = {"us_per_call": round(statistics.median(times) * 1e6, 1),
+                "us_min": round(min(times) * 1e6, 1),
+                "gflop": round(flops / 1e9, 3), "mbytes": round(nbytes / 1e6, 2),
+                "max_abs_diff_vs_twin_bf16": diff,
+                "rows": len(rows), "prompt_tokens": sum(ql for ql, _ in rows if ql > 1)}
+        if chip is not None:
+            t_flop = flops / chip["bf16_flops_per_s"]
+            t_byte = nbytes / chip["hbm_bytes_per_s"]
+            least = max(t_flop, t_byte)
+            line.update(least_us=round(least * 1e6, 2),
+                        bound="flops" if t_flop >= t_byte else "bytes",
+                        roofline_share_pct=round(100 * least / statistics.median(times), 2))
+        lines[name] = line
+        print(name, json.dumps(line), flush=True)
+    return lines
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -101,13 +101,28 @@ def _rope_qk(q, k, cos, sin, cfg: "LlamaConfig", li):
 
 def _attend_by_window(cfg: "LlamaConfig", li, attend):
     """``attend(window)`` for layer ``li``. ``window`` is a static
-    argument of both Pallas kernels, so a model that mixes windowed and
-    global layers carries one instance of each under a ``lax.cond`` on
-    the layer's flag; a model whose layers are alike traces one."""
+    argument of the decode kernel and the dense paths, so a model that
+    mixes windowed and global layers carries one instance of each under a
+    ``lax.cond`` on the layer's flag; a model whose layers are alike
+    traces one. (The ragged kernel takes ``_window_operand`` instead.)"""
     if cfg.layer_window is None or not cfg.sliding_window:
         return attend(cfg.sliding_window)
     return jax.lax.cond(jnp.asarray(cfg.layer_window, bool)[li],
                         lambda: attend(cfg.sliding_window), lambda: attend(None))
+
+
+def _window_operand(cfg: "LlamaConfig", li) -> dict:
+    """Layer ``li``'s window as the ragged kernel takes it (ISSUE 34). A
+    model whose layers are alike hands it statically (``window=``: none
+    pays no mask); one that mixes windowed and global layers hands it as
+    an operand (``window_dyn=``), so its mixed step traces and lowers ONE
+    instance of the kernel, not one a kind."""
+    if cfg.layer_window is None or not cfg.sliding_window:
+        return {"window": cfg.sliding_window}
+    from inference_gateway_tpu.ops.paged_attention import GLOBAL_WINDOW
+
+    return {"window_dyn": jnp.where(jnp.asarray(cfg.layer_window, bool)[li],
+                                    cfg.sliding_window, GLOBAL_WINDOW).astype(jnp.int32)}
 
 
 def init_params(rng: jax.Array, cfg: LlamaConfig, dtype=jnp.bfloat16) -> Params:
@@ -667,9 +682,9 @@ def forward_ragged_impl(
             pages_v = cv.reshape(L * P, page_size, HkvD)
             layer_table = page_table + li * P
 
-            attn = _attend_by_window(cfg, li, lambda window: ragged_paged_attention(
+            attn = ragged_paged_attention(
                 q[0], pages_k, pages_v, layer_table, q_starts, q_lens, kv_lens,
-                Hkv, window=window, mesh=mesh)[None])  # (1, T, Hq, D)
+                Hkv, mesh=mesh, **_window_operand(cfg, li))[None]  # (1, T, Hq, D)
             x = x + qmatmul(attn.reshape(B, T, Hq * D), lp["wo"])
         with jax.named_scope("mlp"):
             y = ffn(x, h, lp, li, tok_valid)

@@ -89,6 +89,12 @@ class Phi4FlashConfig:
         return self.hidden_size // self.num_heads
 
     @property
+    def kernel_kv_heads(self) -> int:
+        """KV heads as the paged kernels see them: a differential pair of
+        ``hd`` columns each is one head of 2·hd."""
+        return self.num_kv_heads // 2
+
+    @property
     def d_inner(self) -> int:
         return self.mamba_expand * self.hidden_size
 
@@ -334,7 +340,7 @@ def _decoder(params: Params, cfg: Phi4FlashConfig, x, cache: Params, write_idx, 
 
     layers = params["layers"]
     eps = cfg.layer_norm_eps
-    Hq, Hkv2, D = cfg.num_heads, cfg.num_kv_heads // 2, cfg.hd
+    Hq, Hkv2, D = cfg.num_heads, cfg.kernel_kv_heads, cfg.hd
     Lc, P, page_size, HkvD = cache["k"].shape
     flat = P * page_size
     total = Lc * flat
@@ -364,10 +370,7 @@ def _decoder(params: Params, cfg: Phi4FlashConfig, x, cache: Params, write_idx, 
             cv = cv.at[w_idx].set(vv.astype(cv.dtype), mode="drop")
             pages = (ck.reshape(Lc * P, page_size, HkvD), cv.reshape(Lc * P, page_size, HkvD))
             q, table = pad_queries(q), page_table + k * P
-            # ``window`` is a static argument of both kernels: one instance of
-            # each kind under a cond on the pair (models/llama._attend_by_window).
-            o = jax.lax.cond(k < M, lambda: attend_self(q, *pages, table, cfg.sliding_window),
-                             lambda: attend_self(q, *pages, table, None))
+            o = attend_self(q, *pages, table, k < M)  # pairs under M are windowed, pair M is full
             a = diff_combine(o, lp["lam"], lp["sub_norm"], 2 * k + 1)
             x = x + qmatmul(a.astype(x.dtype), lp["wo"]) + lp["bo"]
         x = x + _mlp(x, n_odd, layers, k, 1, eps)
@@ -445,8 +448,14 @@ def forward_paged(params: Params, cfg: Phi4FlashConfig, tokens, positions, lengt
     B = tokens.shape[0]
     valid = jnp.ones((B,), bool) if valid is None else valid
 
-    def attend(q, pages_k, pages_v, table, window):
-        return paged_attention(q, pages_k, pages_v, table, lengths, cfg.num_kv_heads // 2, window=window)
+    def attend(q, pages_k, pages_v, table, windowed):
+        # ``window`` is a static argument of the decode kernel: one instance of
+        # each kind under a cond on the pair (models/llama._attend_by_window).
+        def kind(window):
+            return lambda: paged_attention(q, pages_k, pages_v, table, lengths, cfg.kernel_kv_heads,
+                                           window=window)
+
+        return jax.lax.cond(windowed, kind(cfg.sliding_window), kind(None))
 
     x, cache = _decoder(
         params, cfg, params["embed"][tokens[:, 0]], cache, write_idx[:, 0], page_table,
@@ -468,7 +477,7 @@ def forward_ragged(params: Params, cfg: Phi4FlashConfig, tokens, positions, cach
     self-decoder, each segment from its slot's state; then each row's last
     token through the cross-decoder. Returns per-row logits (R, V) and the
     new cache; with ``with_aux`` also ``window_pages``."""
-    from inference_gateway_tpu.ops.paged_attention import ragged_paged_attention
+    from inference_gateway_tpu.ops.paged_attention import GLOBAL_WINDOW, ragged_paged_attention
 
     assert mesh is None, "phi4flash: no mesh carries the Mamba state yet"
     T = tokens.shape[1]
@@ -478,9 +487,12 @@ def forward_ragged(params: Params, cfg: Phi4FlashConfig, tokens, positions, cach
     last = jnp.clip(q_starts + q_lens - 1, 0, T - 1)
     live_lens = jnp.where(q_lens > 0, kv_lens, 0)
 
-    def attend(q, pages_k, pages_v, table, window):
+    def attend(q, pages_k, pages_v, table, windowed):
+        # The ragged kernel takes the window as an operand: ONE instance for
+        # both kinds of layer (ISSUE 34; models/llama._window_operand).
+        window = jnp.where(windowed, cfg.sliding_window, GLOBAL_WINDOW).astype(jnp.int32)
         return ragged_paged_attention(q, pages_k, pages_v, table, q_starts, q_lens, kv_lens,
-                                      cfg.num_kv_heads // 2, window=window)
+                                      cfg.kernel_kv_heads, window_dyn=window)
 
     x, cache = _decoder(
         params, cfg, params["embed"][tokens[0]], cache, write_idx[0], page_table,

@@ -34,6 +34,7 @@ a kernel (see ``paged_dispatch``).
 
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
@@ -401,68 +402,157 @@ def ragged_paged_attention_jax(
     return jnp.where(valid_t[:, None, None], out, 0)
 
 
+# The ragged kernel's query tiles (ISSUE 34). A row's queries are walked
+# against its pages in tiles, and a tile reads each page it can see once —
+# so the width of the tile is how often a row's context is read and how
+# many rows one MXU pass carries. A row of ONE query (a decode row) is a
+# tile of its own: G rows a KV head, as the decode kernel has them. Every
+# other row is a prompt's chunk and is walked in wide tiles:
+# RAGGED_WIDE_TILE queries, so that one KV head's pass (tile × G rows)
+# fills the MXU's 128 rows several times over, cut down where the float32
+# score tile (rows × page_size) would pass RAGGED_SCORE_BYTES. There is no
+# tile between the two: each static width is a third of what the kernel
+# costs to trace and lower at every start (PERF.md §6, PR 34), and only a
+# chunk's tail of 2-8 queries (one admission in seventy) would walk faster
+# in a narrow one.
+RAGGED_WIDE_TILE = 128
+RAGGED_SCORE_BYTES = 512 << 10
+# The window operand of a global layer (``window_dyn``): past any context.
+GLOBAL_WINDOW = 1 << 30
+
+
+def ragged_tile_plan(groups: int, page_size: int) -> dict:
+    """The ragged kernel's tiling for a layout (G = Hq / Hkv and the page
+    size): static per engine, so it is a /debug/status entry, and the
+    scheduler counts from ``wide_from`` which rows take the wide tile."""
+    wide = min(RAGGED_WIDE_TILE, RAGGED_SCORE_BYTES // (4 * groups * page_size))
+    wide = max(wide // 8, 1) * 8
+    return {"narrow_tile": 1, "wide_tile": wide, "wide_from": 2,
+            "rows_per_pass": wide * groups}
+
+
+# One static width's scratch in the ragged kernel, rows = width · G: the
+# tile's queries (Hkv, rows, D), a page's scores and probabilities (Hkv,
+# rows, page_size), the running maximum and sum (Hkv, rows, 1), the running
+# output (Hkv, rows, D), each row's absolute position (rows, 1).
+_TileScratch = collections.namedtuple("_TileScratch", "q s p m l acc qpos")
+
+
+def _div(a, b: int):
+    """a // b of scalars that are never negative (one equation where
+    ``//`` traces eleven to round towards minus infinity)."""
+    return jax.lax.div(a, jnp.int32(b))
+
+
 def _ragged_paged_attn_kernel(
     # scalar prefetch
     page_table_ref,  # (R, max_pages) SMEM
-    descr_ref,  # (R, 3) SMEM: q_start, q_len, kv_len per row
+    descr_ref,  # (R, 3) SMEM: q_start, q_len, kv_len per row; (R, 4) with the window operand
     # inputs
-    q_ref,  # (T + QB, Hq, D) VMEM — whole packed batch (+QB tile slack)
+    q_ref,  # (T + slack, Hq, D) VMEM — whole packed batch (+ one tile of slack)
     k_pages_hbm,  # (P, page_size, Hkv*D) ANY/HBM
     v_pages_hbm,
     # output
-    out_ref,  # (T + QB, Hq, D) VMEM
+    out_ref,  # (T + slack, Hq, D) VMEM
     # scratch
     k_buf,  # (2, page_size, pad128(Hkv*D)) VMEM
     v_buf,
     sems,  # DMA semaphores (2, 2)
-    *,
+    chain_ref,  # (2,) SMEM: is this row's first page in flight, and in which buffer
+    *scratch,  # a _TileScratch's refs per width of ``widths``
     page_size: int,
     num_kv_heads: int,
     groups: int,
     head_dim: int,
     window: int | None,
-    q_block: int,
+    window_operand: bool,
+    widths: tuple[int, ...],  # (1, the wide tile); (1,) where the batch holds one query
 ):
     """Grid over rows; each instance flash-attends its row's packed query
-    span against the row's pages in ``q_block``-sized query tiles.
+    span against the row's pages, one query tile at a time.
 
-    Tiling scheme (the reason every fallback layout now runs a kernel):
+    Tiling scheme:
+    - The tile is chosen per row from its ``q_len`` (ISSUE 34): a row of
+      one query is one tile of one, any other row is walked in wide
+      tiles (``widths``). There is ONE walk, tiles over pages, whose
+      scalars follow the row's width; only what touches a tile's vectors
+      (staging its queries, a page's visit, writing it out) exists once
+      per static width, behind a ``pl.when`` on the row's. Each tile reads
+      every page up to its causal horizon (and from its window's first
+      page) once.
     - The packed query axis is NOT blocked by the grid — the whole batch
       (plus one tile of slack so a tile never clamps at the buffer edge)
       sits in VMEM and rows address their spans with dynamic slices from
       the prefetched descriptors. Mixed-step batches are budget-bounded
       (hundreds of tokens), so this is a few MiB, not a cache.
+    - A tile's queries are laid out once, before its page walk, as one
+      (tile·G, D) slab per KV head; scores, probabilities, the running
+      maximum, sum and output live in scratch in the same order. A page's
+      visit is a loop over heads for the scores, ONE flash update over
+      every head's scores, and a loop over heads for the second product;
+      the loops are traced once. The operands of both products reach the
+      MXU in the dtype they are stored in, the probabilities in V's — the
+      arithmetic of ``ragged_paged_attention_jax``.
+    - The window is static (``window``: a model whose layers are alike
+      pays no mask without one) or the fourth descriptor column
+      (``window_operand``: a model that mixes windowed and global layers
+      traces ONE instance, a global layer handing GLOBAL_WINDOW).
     - Page scratch is lane-padded: a misaligned folded axis (Hkv·D not a
       multiple of 128) DMAs into the valid prefix of a 128-aligned
       buffer; per-head compute slices stay inside the valid columns.
+    - The double-buffered page DMA is one chain over the whole launch:
+      a tile's last page starts the next tile's first, a row's last page
+      the next active row's first (``chain_ref`` carries that across grid
+      steps, which run in order).
     - Query tiles beyond a row's q_len are masked, and their output
       lanes preserve-and-defer: each row read-modify-writes its tile
       window, grid iterations are sequential, and every valid packed
       position is written exactly once by its owning row.
-
-    Decode rows (q_len=1) walk their pages like the classic decode
-    kernel; prefill rows reuse the same double-buffered DMA walk with a
-    per-query causal mask — one launch for the whole mixed batch.
     """
     r = pl.program_id(0)
-    QB = q_block
+    R = pl.num_programs(0)
     scale = head_dim ** -0.5
     Hkv, G, D = num_kv_heads, groups, head_dim
     folded = k_pages_hbm.shape[-1]
+    # The DMA'd page index is clamped for safety (matches the decode
+    # kernel): inactive descriptor rows may carry stale table entries.
     num_pages_total = k_pages_hbm.shape[0]
+    n = len(_TileScratch._fields)
+    scratch = {W: _TileScratch(*scratch[i * n:(i + 1) * n]) for i, W in enumerate(widths)}
 
-    # First grid step zeroes the output block: uncovered packed lanes
-    # must read as zeros, not leftover VMEM.
     @pl.when(r == 0)
     def _():
         out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+        chain_ref[0] = 0
+        chain_ref[1] = 0
 
     q_start = descr_ref[r, 0]
     q_len = descr_ref[r, 1]
     kv_len = descr_ref[r, 2]
+    kv_start = kv_len - q_len  # absolute position of the row's first query
+    if window_operand:
+        window = descr_ref[r, 3]
+    # The row's tile width, and whatever a tile does to vectors at it.
+    W = jax.lax.select(q_len > 1, jnp.int32(widths[-1]), jnp.int32(1))
 
-    def page_dma(buf_slot, page_pos):
-        page_idx = jnp.clip(page_table_ref[r, page_pos], 0, num_pages_total - 1)
+    def at_width(fn, *args):
+        for Ws in widths:
+            pl.when(W == Ws)(functools.partial(fn, Ws, scratch[Ws], *args))
+
+    def each_head(body):
+        """``body(h)`` for every KV head: a loop traced once where a head's
+        lane slice sits on the 128 grid, else static slices. Unrolled when
+        it is lowered, so that the chip's scheduler overlaps one head's
+        product with the next one's: left as a loop the kernel takes
+        1.4-2.5 times as long (PERF.md §6, PR 34)."""
+        if D % LANE:
+            for h in range(Hkv):
+                body(h)
+        else:
+            jax.lax.fori_loop(0, Hkv, lambda h, c: (body(h), c)[1], 0, unroll=True)
+
+    def page_dma(buf_slot, row, page_pos):
+        page_idx = jnp.clip(page_table_ref[row, page_pos], 0, num_pages_total - 1)
         return (pltpu.make_async_copy(k_pages_hbm.at[page_idx],
                                       _page_dst(k_buf, buf_slot, folded),
                                       sems.at[buf_slot, 0]),
@@ -470,94 +560,128 @@ def _ragged_paged_attn_kernel(
                                       _page_dst(v_buf, buf_slot, folded),
                                       sems.at[buf_slot, 1]))
 
+    def first_page(row, q0):
+        """Earliest page the tile that starts at ``row``'s q0-th query can
+        see: its first query's window start (later queries see later
+        keys only)."""
+        if window is None:
+            return jnp.int32(0)
+        first_pos = descr_ref[row, 2] - descr_ref[row, 1] + q0
+        return _div(jnp.maximum(first_pos + 1 - window, 0), page_size)
+
+    def stage_tile(Ws, t, c, tile0):
+        rows = Ws * G
+        q_tile = q_ref[pl.dslice(tile0, Ws)]  # (Ws, Hq, D)
+        for h in range(Hkv):
+            t.q[h] = q_tile[:, h * G:(h + 1) * G, :].reshape(rows, D).astype(t.q.dtype)
+        t.m[...] = jnp.full(t.m.shape, NEG_INF, jnp.float32)
+        t.l[...] = jnp.zeros(t.l.shape, jnp.float32)
+        t.acc[...] = jnp.zeros(t.acc.shape, jnp.float32)
+        # Per-row absolute positions, expanded per group so the (Ws·G,
+        # page_size) score mask indexes naturally; a query past the row's
+        # last sees no key.
+        qrow = c * Ws + _div(jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), G)
+        t.qpos[...] = jnp.where(qrow < q_len, kv_start + qrow, -1)
+
+    def visit(Ws, t, p, par):
+        def score(h):
+            k_h = k_buf[par, :, pl.ds(h * D, D)]  # (ps, D)
+            t.s[h] = jax.lax.dot_general(
+                t.q[h], k_h.astype(t.q.dtype), dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # (rows, ps)
+
+        each_head(score)
+        # A query's own position bounds its keys (and is under kv_len).
+        token_pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
+        qpos = t.qpos[...]  # (rows, 1)
+        valid = token_pos <= qpos
+        if window is not None:
+            valid = valid & (token_pos > qpos - window)
+        # Every KV head's scores under ONE flash update: the same vector
+        # work as a head at a time, in one chain of dependent steps.
+        s = jnp.where(valid, t.s[...] * scale, NEG_INF)  # (Hkv, rows, ps)
+        m = t.m[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        probs = jnp.exp(s - m_new)
+        t.l[...] = t.l[...] * alpha + jnp.sum(probs, axis=-1, keepdims=True)
+        t.m[...] = m_new
+        t.acc[...] = t.acc[...] * alpha
+        t.p[...] = probs.astype(t.p.dtype)
+
+        def weigh(h):
+            v_h = v_buf[par, :, pl.ds(h * D, D)]  # (ps, D)
+            t.acc[h] += jnp.dot(t.p[h], v_h, preferred_element_type=jnp.float32)
+
+        each_head(weigh)
+
+    def finish_tile(Ws, t, c, tile0):
+        out = (t.acc[...] / jnp.maximum(t.l[...], 1e-20)).astype(out_ref.dtype)  # (Hkv, rows, D)
+        tile = pl.dslice(tile0, Ws)
+        theirs = out_ref[tile]  # what the tile's tail holds belongs to the rows after this one
+        for h in range(Hkv):
+            out_ref[tile, h * G:(h + 1) * G, :] = out[h].reshape(Ws, G, D)
+        if Ws > 1:
+            valid_q = (c * Ws + jax.lax.broadcasted_iota(jnp.int32, (Ws, 1, 1), 0)) < q_len
+            out_ref[tile] = jnp.where(valid_q, out_ref[tile], theirs)
+
     @pl.when(q_len > 0)
     def _row():
-        kv_start = kv_len - q_len  # absolute position of the row's first query
-        if window is None:
-            p0 = jnp.int32(0)
-        else:
-            # Earliest page any of the row's queries can see: the first
-            # query's window start (later queries see later keys only).
-            p0 = jnp.maximum(kv_start + 1 - window, 0) // page_size
-        n_tiles = pl.cdiv(q_len, QB)
+        n_tiles = jax.lax.div(q_len + W - 1, W)
+        # Whose first page the row's last page starts: the next row with
+        # queries (R when none is left).
+        nxt = jax.lax.while_loop(
+            lambda i: (i < R) & (descr_ref[jnp.minimum(i, R - 1), 1] == 0),
+            lambda i: i + 1, r + 1)
+        nxt_row = jnp.minimum(nxt, R - 1)
 
-        def tile_body(c, _):
-            tile0 = q_start + c * QB
-            q_tile = q_ref[pl.dslice(tile0, QB)].astype(jnp.float32)  # (QB, Hq, D)
-            # Per-query-row absolute positions, expanded per group so the
-            # (QB·G, page_size) score mask indexes naturally.
-            qrow = c * QB + jax.lax.broadcasted_iota(jnp.int32, (QB * G, 1), 0) // G
-            qpos = kv_start + qrow  # (QB*G, 1)
-            in_row = qrow < q_len
+        @pl.when(chain_ref[0] == 0)
+        def _():
+            for dma in page_dma(0, r, first_page(r, 0)):
+                dma.start()
+
+        def tile_body(c, par):
+            tile0 = q_start + c * W
+            at_width(stage_tile, c, tile0)
             # The tile's causal horizon bounds its page walk: queries in
-            # tile c see keys < kv_start + (c+1)·QB, so later pages are
+            # tile c see keys < kv_start + (c+1)·W, so later pages are
             # fully masked and need not be DMA'd — the walk covers the
-            # causal triangle, not the full rectangle (review finding).
-            tile_kv = jnp.minimum(kv_start + (c + 1) * QB, kv_len)
-            n_pages_t = jnp.maximum(pl.cdiv(tile_kv, page_size), 1)
+            # causal triangle, not the full rectangle.
+            tile_kv = jnp.minimum(kv_start + (c + 1) * W, kv_len)
+            n_pages_t = _div(tile_kv + page_size - 1, page_size)
+            # Where the chain goes after this tile's last page: the row's
+            # next tile's first page, else the next active row's.
+            more_tiles = c + 1 < n_tiles
+            chain_on = more_tiles | (nxt < R)
+            after_row = jnp.where(more_tiles, r, nxt_row)
+            after_page = jnp.where(more_tiles, first_page(r, (c + 1) * W),
+                                   first_page(nxt_row, 0))
 
-            def page_body(p, carry):
-                par = carry[0]
-                accs = carry[1:]
+            def page_body(p, par):
+                in_tile = p + 1 < n_pages_t
 
-                @pl.when(p + 1 < n_pages_t)
+                @pl.when(in_tile | chain_on)
                 def _():
-                    for dma in page_dma(1 - par, p + 1):
+                    for dma in page_dma(1 - par, jnp.where(in_tile, r, after_row),
+                                        jnp.where(in_tile, p + 1, after_page)):
                         dma.start()
 
-                for dma in page_dma(par, p):
+                for dma in page_dma(par, r, p):
                     dma.wait()
-                k_page = k_buf[par].astype(jnp.float32)  # (ps, pad)
-                v_page = v_buf[par].astype(jnp.float32)
+                at_width(visit, p, par)
+                return 1 - par
 
-                token_pos = p * page_size + jax.lax.broadcasted_iota(
-                    jnp.int32, (1, page_size), 1)  # (1, ps)
-                valid = (token_pos <= qpos) & in_row
-                if window is not None:
-                    valid = valid & (token_pos > qpos - window)
+            par = jax.lax.fori_loop(first_page(r, c * W), n_pages_t, page_body, par)
+            at_width(finish_tile, c, tile0)
+            return par
 
-                new_accs = []
-                for h in range(Hkv):
-                    m, l, acc = accs[3 * h], accs[3 * h + 1], accs[3 * h + 2]
-                    q_h = q_tile[:, h * G:(h + 1) * G, :].reshape(QB * G, D)
-                    k_h = k_page[:, h * D:(h + 1) * D]  # (ps, D)
-                    s_h = jax.lax.dot_general(
-                        q_h, k_h, dimension_numbers=(((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32) * scale  # (QB*G, ps)
-                    s_h = jnp.where(valid, s_h, NEG_INF)
-                    m_new = jnp.maximum(m, jnp.max(s_h, axis=-1, keepdims=True))
-                    alpha = jnp.exp(m - m_new)
-                    p_h = jnp.exp(s_h - m_new)
-                    l_new = l * alpha + jnp.sum(p_h, axis=-1, keepdims=True)
-                    v_h = v_page[:, h * D:(h + 1) * D]  # (ps, D)
-                    pv = jnp.dot(p_h, v_h, preferred_element_type=jnp.float32)
-                    new_accs.extend((m_new, l_new, acc * alpha + pv))
-                return (1 - par,) + tuple(new_accs)
-
-            init = (jnp.int32(0),)
-            for _h in range(Hkv):
-                init += (jnp.full((QB * G, 1), NEG_INF, jnp.float32),
-                         jnp.zeros((QB * G, 1), jnp.float32),
-                         jnp.zeros((QB * G, D), jnp.float32))
-            for dma in page_dma(0, p0):
-                dma.start()
-            final = jax.lax.fori_loop(p0, n_pages_t, page_body, init)
-
-            valid_q = (c * QB + jax.lax.broadcasted_iota(
-                jnp.int32, (QB, 1, 1), 0)) < q_len  # (QB, 1, 1)
-            for h in range(Hkv):
-                _m, l, acc = final[1 + 3 * h], final[2 + 3 * h], final[3 + 3 * h]
-                out_h = (acc / jnp.maximum(l, 1e-20)).reshape(QB, G, D)
-                prev = out_ref[pl.dslice(tile0, QB), h * G:(h + 1) * G, :]
-                out_ref[pl.dslice(tile0, QB), h * G:(h + 1) * G, :] = jnp.where(
-                    valid_q, out_h, prev.astype(jnp.float32)).astype(out_ref.dtype)
-            return 0
-
-        jax.lax.fori_loop(0, n_tiles, tile_body, 0)
+        par = jax.lax.fori_loop(0, n_tiles, tile_body,
+                                jnp.where(chain_ref[0] == 1, chain_ref[1], 0))
+        chain_ref[0] = (nxt < R).astype(jnp.int32)
+        chain_ref[1] = par
 
 
-@functools.partial(jax.jit, static_argnames=("num_kv_heads", "interpret", "window",
-                                             "q_block"))
+@functools.partial(jax.jit, static_argnames=("num_kv_heads", "interpret", "window"))
 def ragged_paged_attention_tpu(
     q: jnp.ndarray,  # (T, Hq, D) packed queries
     k_pages: jnp.ndarray,  # (P, page_size, Hkv*D)
@@ -569,17 +693,22 @@ def ragged_paged_attention_tpu(
     num_kv_heads: int,
     interpret: bool = False,
     window: int | None = None,
-    q_block: int = 8,
+    window_dyn: jnp.ndarray | None = None,  # int32 scalar: the window as an operand
 ) -> jnp.ndarray:
     T, Hq, D = q.shape
     P, page_size, HkvD = k_pages.shape
     R = page_table.shape[0]
     G = Hq // num_kv_heads
-    QB = max(1, min(q_block, T))
+    assert window is None or window_dyn is None, "one window: static, or the operand"
+    # The wide tile, no wider than the batch (in eights).
+    tile = min(ragged_tile_plan(G, page_size)["wide_tile"], -(-T // 8) * 8)
+    widths = (1, tile) if T > 1 else (1,)
     # One tile of slack so a row's last tile never clamps at the buffer
     # edge (a clamped dynamic slice would shift the tile window off the
     # mask's indexing). Sliced back off below.
-    qp = jnp.pad(q, ((0, QB), (0, 0), (0, 0)))
+    qp = jnp.pad(q, ((0, tile), (0, 0), (0, 0)))
+    # The products' operand dtype: what q and the pages are stored in.
+    mxu_dtype = jnp.promote_types(q.dtype, k_pages.dtype)
 
     kernel = functools.partial(
         _ragged_paged_attn_kernel,
@@ -588,28 +717,46 @@ def ragged_paged_attention_tpu(
         groups=G,
         head_dim=D,
         window=window,
-        q_block=QB,
+        window_operand=window_dyn is not None,
+        widths=widths,
     )
-    descr = jnp.stack([q_starts, q_lens, kv_lens], axis=1).astype(jnp.int32)
+    descr = [q_starts, q_lens, kv_lens]
+    if window_dyn is not None:
+        descr.append(jnp.broadcast_to(window_dyn, q_lens.shape))
+    descr = jnp.stack(descr, axis=1).astype(jnp.int32)
+    scratch_shapes = [
+        pltpu.VMEM((2, page_size, _pad_lanes(HkvD)), k_pages.dtype),
+        pltpu.VMEM((2, page_size, _pad_lanes(HkvD)), v_pages.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.SMEM((2,), jnp.int32),
+    ]
+    for W in widths:
+        rows = W * G
+        scratch_shapes += _TileScratch(
+            q=pltpu.VMEM((num_kv_heads, rows, D), mxu_dtype),
+            s=pltpu.VMEM((num_kv_heads, rows, page_size), jnp.float32),
+            p=pltpu.VMEM((num_kv_heads, rows, page_size), v_pages.dtype),
+            m=pltpu.VMEM((num_kv_heads, rows, 1), jnp.float32),
+            l=pltpu.VMEM((num_kv_heads, rows, 1), jnp.float32),
+            acc=pltpu.VMEM((num_kv_heads, rows, D), jnp.float32),
+            qpos=pltpu.VMEM((rows, 1), jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(R,),
         in_specs=[
-            pl.BlockSpec((T + QB, Hq, D), lambda r, *_: (0, 0, 0)),
+            pl.BlockSpec((T + tile, Hq, D), lambda r, *_: (0, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.ANY),
             pl.BlockSpec(memory_space=pltpu.ANY),
         ],
-        out_specs=pl.BlockSpec((T + QB, Hq, D), lambda r, *_: (0, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, page_size, _pad_lanes(HkvD)), k_pages.dtype),
-            pltpu.VMEM((2, page_size, _pad_lanes(HkvD)), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-        ],
+        out_specs=pl.BlockSpec((T + tile, Hq, D), lambda r, *_: (0, 0, 0)),
+        scratch_shapes=scratch_shapes,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T + QB, Hq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((T + tile, Hq, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=64 << 20),
         interpret=interpret,
     )(page_table.astype(jnp.int32), descr, qp, k_pages, v_pages)
     return out[:T]
@@ -619,34 +766,35 @@ def ragged_paged_attention_sharded(q, k_pages, v_pages, page_table, q_starts, q_
                                    kv_lens, num_kv_heads: int, mesh,
                                    window: int | None = None,
                                    interpret: bool | None = None,
-                                   replicated: bool = False,
-                                   q_block: int = 8) -> jnp.ndarray:
+                                   replicated: bool = False, window_dyn=None) -> jnp.ndarray:
     """Ragged kernel under a mesh: kv-head-local per tp shard (no
     collectives, same layout algebra as paged_attention_sharded), or
     fully replicated for tp=1 meshes / non-tp-divisible heads.
-    Descriptors and the page table are replicated host metadata."""
+    Descriptors, the page table and the window operand are replicated
+    host metadata."""
     from jax.sharding import PartitionSpec as P
 
     if interpret is None:
         interpret = not on_tpu()
     hkv_local = num_kv_heads if replicated else num_kv_heads // mesh.shape["tp"]
 
-    def local(q_l, k_l, v_l, pt_l, qs_l, ql_l, kl_l):
+    def local(q_l, k_l, v_l, pt_l, qs_l, ql_l, kl_l, *w_l):
         return ragged_paged_attention_tpu(q_l, k_l, v_l, pt_l, qs_l, ql_l, kl_l,
                                           hkv_local, interpret=interpret,
-                                          window=window, q_block=q_block)
+                                          window=window, window_dyn=w_l[0] if w_l else None)
 
     rep = P()
+    dyn = () if window_dyn is None else (jnp.asarray(window_dyn, jnp.int32),)
     if replicated:
-        in_specs = (rep,) * 7
+        in_specs = (rep,) * (7 + len(dyn))
         out_spec = rep
     else:
         in_specs = (P(None, "tp", None), P(None, None, "tp"), P(None, None, "tp"),
-                    rep, rep, rep, rep)
+                    rep, rep, rep, rep) + (rep,) * len(dyn)
         out_spec = P(None, "tp", None)
     return jax.shard_map(
         local, mesh=mesh, in_specs=in_specs, out_specs=out_spec, check_vma=False,
-    )(q, k_pages, v_pages, page_table, q_starts, q_lens, kv_lens)
+    )(q, k_pages, v_pages, page_table, q_starts, q_lens, kv_lens, *dyn)
 
 
 # The one on-chip reading of this pair (2026-07-29, TinyLlama serving
@@ -746,11 +894,13 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, num_kv_heads: int,
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table, q_starts, q_lens, kv_lens,
                            num_kv_heads: int, window: int | None = None,
-                           mesh=None, q_block: int = 8) -> jnp.ndarray:
+                           mesh=None, window_dyn=None) -> jnp.ndarray:
     """Dispatch for the mixed-batch ragged op (ISSUE 12): same decision
     table as ``paged_attention`` (paged_dispatch is the single source),
     applied to the ragged kernel/reference pair. The pure-JAX ragged
-    reference is the correctness twin and the only non-TPU path."""
+    reference is the correctness twin and the only non-TPU path.
+    ``window`` is static; ``window_dyn`` is the window as an int32 scalar
+    operand (GLOBAL_WINDOW for none), for a model whose layers differ."""
     force = FORCE_PAGED_KERNEL
     platform = device_platform()
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
@@ -762,11 +912,11 @@ def ragged_paged_attention(q, k_pages, v_pages, page_table, q_starts, q_lens, kv
         return ragged_paged_attention_sharded(
             q, k_pages, v_pages, page_table, q_starts, q_lens, kv_lens,
             num_kv_heads, mesh, window=window,
-            replicated=path == "kernel_replicated", q_block=q_block)
+            replicated=path == "kernel_replicated", window_dyn=window_dyn)
     if path in ("kernel", "kernel_sharded", "kernel_replicated"):
         return ragged_paged_attention_tpu(
             q, k_pages, v_pages, page_table, q_starts, q_lens, kv_lens,
-            num_kv_heads, interpret=interpret, window=window, q_block=q_block)
+            num_kv_heads, interpret=interpret, window=window, window_dyn=window_dyn)
     return ragged_paged_attention_jax(
         q, k_pages, v_pages, page_table, q_starts, q_lens, kv_lens,
-        num_kv_heads, window=window)
+        num_kv_heads, window=window if window_dyn is None else window_dyn)

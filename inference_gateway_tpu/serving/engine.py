@@ -623,10 +623,12 @@ class Engine:
         # build. (Interpret mode cannot occur there: the kernels take it
         # from the same utils.platform.on_tpu.)
         self.device = device_info()
+        self.ragged_attention_plan: dict | None = None
         if self.paged:
             from inference_gateway_tpu.ops.paged_attention import (
                 FORCE_PAGED_KERNEL,
                 paged_dispatch,
+                ragged_tile_plan,
             )
 
             platform = self.device["platform"]
@@ -642,6 +644,13 @@ class Engine:
                 raise RuntimeError(
                     f"paged engine on a TPU ({self.device}) dispatched to the gather "
                     f"fallback: {self.attention_path_reason}")
+            # The ragged kernel's tiling for this layout (ISSUE 34): static
+            # per engine, so a /debug/status field; the scheduler counts
+            # from ``wide_from`` the tokens whose rows take the wide tile.
+            if self.mixed_ok:
+                kv_heads = getattr(self.model_cfg, "kernel_kv_heads", self.model_cfg.num_kv_heads)
+                self.ragged_attention_plan = ragged_tile_plan(
+                    self.model_cfg.num_heads // kv_heads, config.page_size)
         else:
             self.attention_path = "dense"
             self.attention_path_reason = "contiguous slot cache (paged attention not in use)"

@@ -1240,6 +1240,14 @@ class Scheduler:
                     spans["phases"] = phases
                     spans["h2d_arrays"] = handle.h2d_arrays
                     spans.update(handle.stats or {})  # a mixture's expert counts
+                    plan = getattr(self.engine, "ragged_attention_plan", None)
+                    if plan is not None:
+                        # Tokens of the rows the ragged kernel walks in wide
+                        # tiles (ISSUE 34), beside work_tokens: known from
+                        # the rows packed, no device read.
+                        spans["attn_wide_tokens"] = sum(
+                            len(row.token_ids) for row in rows[n_decode:]
+                            if len(row.token_ids) >= plan["wide_from"])
                     if prep is not None:  # the admission's first record carries it
                         spans["prep"] = {k: round(v, 4) for k, v in prep.items()}
                         prep = None

@@ -1018,6 +1018,13 @@ class SidecarServer:
             # this engine's (vocabulary, top_k) takes — "grouped" sorts
             # `candidates` of the vocabulary's columns, "direct" all of them.
             "sampler": getattr(self.engine, "sampler_plan", None),
+            # The ragged attention kernel's tiling (ISSUE 34): a row of one
+            # query is a tile of its own (`narrow_tile`), any other row is
+            # walked `wide_tile` queries at a time (`rows_per_pass` rows a KV
+            # head's MXU pass); None where no mixed step runs.
+            # `attn_wide_tokens` on the timeline's `mixed` records counts the
+            # tokens of the rows that took the wide tile.
+            "ragged_attention": getattr(self.engine, "ragged_attention_plan", None),
             # A model with a state store (ISSUE 32): ``state_store`` (slots,
             # layers, bytes), ``cache_layers`` (window, full, shared_readers),
             # ``state_resets`` and ``prefix_hits_declined``; absent otherwise.

@@ -46,12 +46,12 @@ def sidecar(aloop):
 
 
 async def _chat(port: int, max_tokens: int = 14, stream: bool = True, path: str = "",
-                model: str = "test-tiny") -> bytes:
+                model: str = "test-tiny", content: str = "count to twenty, slowly") -> bytes:
     headers = Headers()
     headers.set("Content-Type", "application/json")
     headers.set("traceparent", TRACEPARENT)
     body = {"model": model, "stream": stream, "max_tokens": max_tokens,
-            "messages": [{"role": "user", "content": "count to twenty, slowly"}],
+            "messages": [{"role": "user", "content": content}],
             # ASCII only, whatever the seed's weights prefer: every token is a visible frame.
             "logit_bias": {str(i): 100 for i in range(65, 91)}}
     resp = await HTTPClient().post(f"http://127.0.0.1:{port}{path}/v1/chat/completions",
@@ -148,6 +148,36 @@ async def test_a_host_stepped_record_says_how_many_arrays_its_submit_uploaded(si
     assert [r["h2d_arrays"] for r in mixed] == [1] * len(mixed)
     assert [r["h2d_arrays"] for r in fresh] == [1] * len(fresh)
     assert [r["h2d_arrays"] for r in chained] == [0] * len(chained)
+
+
+async def test_mixed_records_count_the_tokens_whose_rows_take_the_wide_tile(sidecar):
+    """ISSUE 34: /debug/status names the ragged kernel's tiling for the
+    engine's layout, and every `mixed` record says how many of its tokens
+    sat in rows of `wide_from` queries or more: the admission's prompt
+    chunk (what the prefix cache left of a prompt far longer than that, so
+    all but whole pages of it), never a decode row's one."""
+    from inference_gateway_tpu.ops.paged_attention import ragged_tile_plan
+
+    server, port, _ = sidecar
+    cfg = server.engine.model_cfg
+    plan = ragged_tile_plan(cfg.num_heads // cfg.num_kv_heads, 16)
+    status = (await HTTPClient().get(f"http://127.0.0.1:{port}/debug/status")).json()
+    assert status["ragged_attention"] == plan == server.engine.ragged_attention_plan
+    assert set(plan) == {"narrow_tile", "wide_tile", "wide_from", "rows_per_pass"}
+    before = server.timeline.stats()["records"]
+    for question in ("what do the tiles of a ragged kernel have to do with a mixed step",
+                     "and why would a row of many queries want another tile than a row of one"):
+        await asyncio.sleep(0.4)
+        await _chat(port, content=question)
+    await asyncio.sleep(0.1)
+    recs = server.timeline.tail(server.timeline.stats()["records"] - before)
+    mixed = [r for r in recs if r["kind"] == "mixed"]
+    assert len(mixed) == 2
+    assert all("attn_wide_tokens" not in r for r in recs if r["kind"] != "mixed")
+    for r in mixed:
+        assert r["batch"] == 1  # idle before: the prompt's row and no decode row
+        adopted = r["context_tokens"] - r["attn_wide_tokens"]
+        assert r["attn_wide_tokens"] >= plan["wide_from"] and adopted >= 0 and adopted % 16 == 0
 
 
 async def test_records_and_status_count_what_was_delivered_behind_a_dispatch(sidecar):
