@@ -48,6 +48,7 @@ class LlamaConfig:
     sliding_window: int | None = None  # Mistral-style windowed attention
     norm_offset: bool = False  # Gemma-style RMSNorm weight = (1 + w)
     embed_scale: bool = False  # Gemma scales embeddings by sqrt(hidden)
+    qk_norm: bool = False  # RMSNorm over head_dim on q and k before rotary (leaves q_norm, k_norm)
     # Per-layer attention kinds (ISSUE 28), one 0/1 flag a layer, or None
     # for a model whose layers are all alike: layer_rope 0 is a layer with
     # no position encoding; layer_window 0 is a global layer of a model
@@ -89,9 +90,14 @@ def _nw(w, cfg: "LlamaConfig"):
     return w + 1 if cfg.norm_offset else w
 
 
-def _rope_qk(q, k, cos, sin, cfg: "LlamaConfig", li):
+def _rope_qk(q, k, cos, sin, cfg: "LlamaConfig", li, lp=None):
     """Rotary on q and k; in a model with NoPE layers only where layer
-    ``li``'s flag is set (the rotation is elementwise: both are cheap)."""
+    ``li``'s flag is set (the rotation is elementwise: both are cheap).
+    With ``cfg.qk_norm`` each head is RMS-normed first (``lp``'s q_norm,
+    k_norm)."""
+    if cfg.qk_norm:
+        q = rms_norm(q, lp["q_norm"], cfg.rms_norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.rms_norm_eps)
     rq, rk = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
     if cfg.layer_rope is None:
         return rq, rk
@@ -439,11 +445,28 @@ def forward_paged_impl(
     unscanned: tuple = (),
     valid: jnp.ndarray | None = None,  # (B,) decode rows that carry a stream
     with_aux: bool = False,
+    mixer=None,  # the attention seam, below; a learned sparse attention plugs in here
 ):
     """Shared paged-decoder skeleton: attention + cache paging are
     family-independent; the FFN block (dense gated MLP vs MoE) is the
     ``ffn`` callable (models/mixtral.py reuses this for paged MoE
     serving).
+
+    The attention seam (ISSUE 36): ``mixer`` is None for every model whose
+    attention is the kernels' (nothing below changes for them), or a dict
+    ``{"cache": names, "decode": fn, "ragged": fn}``. ``cache`` names the
+    arrays the model keeps beside ``k`` and ``v`` under the same page ids,
+    (L, P, ...) each, a page's layout the model's own; the layer loop
+    carries them as it carries the pages, by page: (L * P, ...). ``decode(h, q, lp, li, positions, pools,
+    w_idx, table, lengths, page_size=)`` (here) and ``ragged(h, q, lp, li,
+    positions, pools, w_idx, table, q_starts, q_lens, kv_lens, page_size=)``
+    (forward_ragged_impl)
+    get the layer's normed input, its queries after norm and rotary, the
+    flat pools ``{"k", "v", *cache}`` with this layer's keys and values
+    already written, the flat write indices and the layer's page table;
+    they write their own arrays, attend, and return ``(attention, the
+    pools they wrote {name: array}, a per-layer auxiliary)``. With a mixer
+    a layer's auxiliary is the pair (the FFN's, the mixer's).
 
     The seam: ``ffn(x, attn_in, lp, li, valid)`` returns the block's
     residual contribution, or that and a per-layer auxiliary array.
@@ -502,8 +525,13 @@ def forward_paged_impl(
     # are metadata-only.
     total = L * flat
 
+    extra = tuple(mixer["cache"]) if mixer is not None else ()
+    if mixer is not None and mode != "decode":
+        raise NotImplementedError(
+            f"a model with an attention seam admits through the mixed step; forward_paged mode {mode!r} has no path for it")
+
     def body(carry, per_layer):
-        x, ck, cv = carry  # ck/cv: (L*P*page_size, HkvD) flat carry
+        x, ck, cv, *ce = carry  # ck/cv: (L*P*page_size, HkvD) flat carry
         lp, li = per_layer
         with jax.named_scope("attention"):
             h = rms_norm(x, _nw(lp["attn_norm"], cfg), cfg.rms_norm_eps)
@@ -517,7 +545,7 @@ def forward_paged_impl(
             q = q.reshape(B, T, Hq, D)
             k = k.reshape(B, T, Hkv, D)
             v = v.reshape(B, T, Hkv, D)
-            q, k = _rope_qk(q, k, cos, sin, cfg, li)
+            q, k = _rope_qk(q, k, cos, sin, cfg, li, lp)
 
             k_flat = k.reshape(B, T, HkvD).astype(ck.dtype)
             v_flat = v.reshape(B, T, HkvD).astype(cv.dtype)
@@ -554,21 +582,31 @@ def forward_paged_impl(
                     return flash_prefill_attention(q, k, v, lengths, window=window)
                 return gqa_attend(q, k, v, windowed(prefill_mask, positions[:, None, :], window))
 
-            attn = _attend_by_window(cfg, li, attend)
+            if mixer is None:
+                attn = _attend_by_window(cfg, li, attend)
+            else:
+                attn, wrote, mix_aux = mixer["decode"](
+                    h[:, 0], q[:, 0], lp, li, positions[:, 0],
+                    {"k": ck, "v": cv, **dict(zip(extra, ce))}, w_idx[:, 0], layer_table, lengths,
+                    page_size=page_size)
+                ce = [wrote.get(n, a) for n, a in zip(extra, ce)]
+                attn = attn[:, None]
             x = x + qmatmul(attn.reshape(B, T, Hq * D), lp["wo"])
         with jax.named_scope("mlp"):
             y = ffn(x, h, lp, li, tok_valid)
             y, aux = y if isinstance(y, tuple) else (y, None)
             x = x + y
-        return (x, ck, cv), aux
+        return (x, ck, cv, *ce), (aux if mixer is None else (aux, mix_aux))
 
     ck0 = cache["k"].reshape(total, HkvD)
     cv0 = cache["v"].reshape(total, HkvD)
-    (x, ck, cv), aux = jax.lax.scan(
-        body, (x, ck0, cv0), (_scan_layers(params, unscanned), jnp.arange(L))
+    (x, ck, cv, *ce), aux = jax.lax.scan(
+        body, (x, ck0, cv0, *(cache[n].reshape((L * P,) + cache[n].shape[2:]) for n in extra)),
+        (_scan_layers(params, unscanned), jnp.arange(L))
     )
     new_cache = {"k": ck.reshape(L, P, page_size, HkvD),
-                 "v": cv.reshape(L, P, page_size, HkvD)}
+                 "v": cv.reshape(L, P, page_size, HkvD),
+                 **{n: a.reshape(cache[n].shape) for n, a in zip(extra, ce)}}
 
     with jax.named_scope("head"):
         x = rms_norm(x, _nw(params["final_norm"], cfg), cfg.rms_norm_eps)
@@ -636,6 +674,7 @@ def forward_ragged_impl(
     ffn,  # the FFN seam of forward_paged_impl (MoE plugs in here)
     unscanned: tuple = (),
     with_aux: bool = False,
+    mixer=None,  # the attention seam of forward_paged_impl
 ):
     """Shared ragged skeleton, same flat-carry cache discipline as
     forward_paged_impl (the scatter lowers to an in-place row update;
@@ -656,8 +695,10 @@ def forward_ragged_impl(
     # Rows are packed back to back from 0: the tail past them is padding.
     tok_valid = jnp.arange(T) < jnp.sum(q_lens)
 
+    extra = tuple(mixer["cache"]) if mixer is not None else ()
+
     def body(carry, per_layer):
-        x, ck, cv = carry
+        x, ck, cv, *ce = carry
         lp, li = per_layer
         with jax.named_scope("attention"):
             h = rms_norm(x, _nw(lp["attn_norm"], cfg), cfg.rms_norm_eps)
@@ -671,7 +712,7 @@ def forward_ragged_impl(
             q = q.reshape(B, T, Hq, D)
             k = k.reshape(B, T, Hkv, D)
             v = v.reshape(B, T, Hkv, D)
-            q, k = _rope_qk(q, k, cos, sin, cfg, li)
+            q, k = _rope_qk(q, k, cos, sin, cfg, li, lp)
 
             k_flat = k.reshape(B, T, HkvD).astype(ck.dtype)
             v_flat = v.reshape(B, T, HkvD).astype(cv.dtype)
@@ -682,23 +723,33 @@ def forward_ragged_impl(
             pages_v = cv.reshape(L * P, page_size, HkvD)
             layer_table = page_table + li * P
 
-            attn = ragged_paged_attention(
-                q[0], pages_k, pages_v, layer_table, q_starts, q_lens, kv_lens,
-                Hkv, mesh=mesh, **_window_operand(cfg, li))[None]  # (1, T, Hq, D)
+            if mixer is None:
+                attn = ragged_paged_attention(
+                    q[0], pages_k, pages_v, layer_table, q_starts, q_lens, kv_lens,
+                    Hkv, mesh=mesh, **_window_operand(cfg, li))[None]  # (1, T, Hq, D)
+            else:
+                attn, wrote, mix_aux = mixer["ragged"](
+                    h[0], q[0], lp, li, positions[0],
+                    {"k": ck, "v": cv, **dict(zip(extra, ce))}, w_idx[0], layer_table,
+                    q_starts, q_lens, kv_lens, page_size=page_size)
+                ce = [wrote.get(n, a) for n, a in zip(extra, ce)]
+                attn = attn[None]
             x = x + qmatmul(attn.reshape(B, T, Hq * D), lp["wo"])
         with jax.named_scope("mlp"):
             y = ffn(x, h, lp, li, tok_valid)
             y, aux = y if isinstance(y, tuple) else (y, None)
             x = x + y
-        return (x, ck, cv), aux
+        return (x, ck, cv, *ce), (aux if mixer is None else (aux, mix_aux))
 
     ck0 = cache["k"].reshape(total, HkvD)
     cv0 = cache["v"].reshape(total, HkvD)
-    (x, ck, cv), aux = jax.lax.scan(
-        body, (x, ck0, cv0), (_scan_layers(params, unscanned), jnp.arange(L))
+    (x, ck, cv, *ce), aux = jax.lax.scan(
+        body, (x, ck0, cv0, *(cache[n].reshape((L * P,) + cache[n].shape[2:]) for n in extra)),
+        (_scan_layers(params, unscanned), jnp.arange(L))
     )
     new_cache = {"k": ck.reshape(L, P, page_size, HkvD),
-                 "v": cv.reshape(L, P, page_size, HkvD)}
+                 "v": cv.reshape(L, P, page_size, HkvD),
+                 **{n: a.reshape(cache[n].shape) for n, a in zip(extra, ce)}}
 
     with jax.named_scope("head"):
         x = rms_norm(x, _nw(params["final_norm"], cfg), cfg.rms_norm_eps)

@@ -1,6 +1,9 @@
-"""Sparse-MoE decoders in pure JAX: the Mixtral block, and the
-SmallThinker block (ISSUE 28: the router reads the attention's normed
-input, ReGLU experts, global NoPE layers beside windowed RoPE layers).
+"""Sparse-MoE decoders in pure JAX: the Mixtral block, the SmallThinker
+block (ISSUE 28: the router reads the attention's normed input, ReGLU
+experts, global NoPE layers beside windowed RoPE layers), and the
+Keye-VL-2.0 text block (ISSUE 36: q/k norms, and a learned sparse
+attention, ops/sparse_attention.py, whose indexer keys are a third paged
+array; it rides the attention seam of the shared skeletons).
 
 Same TPU-first skeleton as models/llama.py (stacked layers + lax.scan,
 the shared paged and ragged forwards) with the dense MLP replaced by a
@@ -25,8 +28,9 @@ from inference_gateway_tpu.models.llama import (
 from inference_gateway_tpu.ops.attention import causal_prefill_mask, decode_mask, gqa_attend
 from inference_gateway_tpu.ops.moe import ACTIVATIONS, moe_dense, moe_dropless
 from inference_gateway_tpu.ops.norms import rms_norm
-from inference_gateway_tpu.ops.quant import qeinsum, qmatmul, seeded_std
+from inference_gateway_tpu.ops.quant import qeinsum, qmatmul, seeded_qk_gain, seeded_std
 from inference_gateway_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
+from inference_gateway_tpu.ops.sparse_attention import sparse_decode, sparse_ragged
 
 
 @dataclass(frozen=True)
@@ -41,6 +45,13 @@ class MixtralConfig(LlamaConfig):
     # Which normed state the router reads: the experts' own input
     # ("ffn", Mixtral) or the attention's ("attn", SmallThinker).
     router_input: str = "ffn"
+    # The learned sparse attention's indexer (ISSUE 36); indexer_topk 0:
+    # none. Each query keeps the indexer_topk cached tokens its
+    # indexer_heads heads of indexer_head_dim score highest; one indexer
+    # key a token, shared by the heads.
+    indexer_heads: int = 0
+    indexer_head_dim: int = 64
+    indexer_topk: int = 0
 
     def __post_init__(self):
         if self.moe_impl not in ("dropless", "dense", "capacity"):
@@ -60,13 +71,28 @@ def init_params(rng: jax.Array, cfg: MixtralConfig, dtype=jnp.bfloat16) -> Param
     def norm(key, shape, std=0.02):
         return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
+    def extras() -> Params:
+        """q/k norms and the indexer's leaves, where the model has them."""
+        out: Params = {}
+        if cfg.qk_norm:
+            gain = jnp.full((L, D), seeded_qk_gain(cfg), dtype)
+            out.update(q_norm=gain, k_norm=gain)
+        if cfg.indexer_topk:
+            ikeys = jax.random.split(jax.random.fold_in(rng, 36), 3)
+            Hi, Di, std = cfg.indexer_heads, cfg.indexer_head_dim, seeded_std(cfg, "wq_idx")
+            out.update(wq_idx=norm(ikeys[0], (L, H, Hi * Di), std),
+                       wk_idx=norm(ikeys[1], (L, H, Di), std),
+                       ww_idx=norm(ikeys[2], (L, H, Hi), std),
+                       k_idx_norm=jnp.ones((L, Di), dtype), k_idx_bias=jnp.zeros((L, Di), dtype))
+        return out
+
     params: Params = {
         "embed": norm(keys[0], (V, H), seeded_std(cfg, "embed")),
         "layers": {
             "attn_norm": jnp.ones((L, H), dtype),
             "wq": norm(keys[1], (L, H, Hq * D)),
             "wk": norm(keys[2], (L, H, Hkv * D)),
-            "wv": norm(keys[3], (L, H, Hkv * D)),
+            "wv": norm(keys[3], (L, H, Hkv * D), seeded_std(cfg, "wv")),
             "wo": norm(keys[4], (L, Hq * D, H), seeded_std(cfg, "wo")),
             "moe_norm": jnp.ones((L, H), dtype),
             "router": norm(keys[5], (L, H, E), seeded_std(cfg, "router")),
@@ -74,6 +100,7 @@ def init_params(rng: jax.Array, cfg: MixtralConfig, dtype=jnp.bfloat16) -> Param
             "wg": norm(keys[6], (L, E, H, I)),
             "wu": norm(keys[7], (L, E, H, I)),
             "wd": norm(keys[8], (L, E, I, H)),
+            **extras(),
         },
         "final_norm": jnp.ones((H,), dtype),
     }
@@ -130,6 +157,10 @@ def forward(
     """Same contract as models/llama.forward (contiguous cache, masked
     einsum attention; ``--attention paged`` serves through forward_paged
     and forward_ragged). ``mesh``: the one the experts are sharded over."""
+    if cfg.indexer_topk or cfg.qk_norm:
+        raise NotImplementedError(
+            "a model with q/k norms or a sparse-attention indexer is served over the paged cache "
+            "(--attention paged: the mixed step and the decode chunk); the dense cache has no path for it")
     B, T = tokens.shape
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     L = cfg.num_layers
@@ -223,11 +254,91 @@ def _moe_ffn(x: jnp.ndarray, attn_in: jnp.ndarray, lp: Params, li, valid, *,
     return out.reshape(B, T, H), rows
 
 
-def _seam(params: Params, cfg: MixtralConfig, mesh) -> dict:
-    """What the shared skeletons take for a mixture: the seam, and the
-    leaves it reads whole by layer index (never sliced per layer)."""
+def _indexer(h: jnp.ndarray, lp: Params, positions: jnp.ndarray, cfg: MixtralConfig):
+    """The lightning indexer's side of N tokens: h (N, H) the attention's
+    normed input -> queries (N, Hi, Di) and this token's key (N, Di), both
+    rotated (rotate-half over the indexer's own Di, the model's theta; the
+    key LayerNormed first), and the heads' weights (N, Hi) in float32 with
+    both scales folded in."""
+    Hi, Di = cfg.indexer_heads, cfg.indexer_head_dim
+    cos, sin = rope_cos_sin(positions, rope_inv_freq(Di, cfg.rope_theta))
+    qi = apply_rope(qmatmul(h, lp["wq_idx"]).reshape(-1, Hi, Di), cos, sin)
+    kf = (h @ lp["wk_idx"]).astype(jnp.float32)
+    kf = (kf - jnp.mean(kf, axis=-1, keepdims=True)) * jax.lax.rsqrt(jnp.var(kf, axis=-1, keepdims=True) + 1e-6)
+    kf = kf * lp["k_idx_norm"].astype(jnp.float32) + lp["k_idx_bias"].astype(jnp.float32)
+    ki = apply_rope(kf.astype(h.dtype)[:, None, :], cos, sin)[:, 0]
+    wi = (h @ lp["ww_idx"]).astype(jnp.float32) * (Hi ** -0.5 * Di ** -0.5)
+    return qi, ki, wi
+
+
+DSA_CACHE = ("ki",)  # the indexer's keys, a page transposed: (L, P, indexer_head_dim, page_size)
+
+
+def _index_and_write(h, lp, positions, pool, w_idx, page_size: int, cfg: MixtralConfig):
+    """The indexer's side of a step's tokens, with their keys ``ki`` (N, Di)
+    written into the pool (pages, Di, page_size) at the flat token indices
+    ``w_idx`` (past the pool: dropped), a column of a page each. Returns
+    (queries, weights, the pool)."""
+    with jax.named_scope("dsa.index"):
+        qi, ki, wi = _indexer(h, lp, positions, cfg)
+        return qi, wi, pool.at[w_idx // page_size, :, w_idx % page_size].set(ki.astype(pool.dtype), mode="drop")
+
+
+def _dsa_decode(h, q, lp, li, positions, pools, w_idx, table, lengths, *, page_size, cfg, valid=None):
+    """The attention seam's decode side (llama.forward_paged_impl): write
+    this token's indexer key, then score, select and read the chosen tokens
+    (ops/sparse_attention.sparse_decode; a row that sees ``indexer_topk``
+    tokens or fewer keeps them all: plain causal attention, by the same
+    path). The auxiliary is (tokens the live rows' queries could see,
+    tokens whose keys and values their attention read)."""
+    del li
+    qi, wi, cki = _index_and_write(h, lp, positions, pools["ki"], w_idx, page_size, cfg)
+    attn = sparse_decode(q, qi, wi, pools["k"], pools["v"], cki, table, lengths, topk=cfg.indexer_topk,
+                         num_kv_heads=cfg.num_kv_heads, page_size=page_size)
+    live = jnp.ones_like(lengths, bool) if valid is None else valid
+    seen = jnp.sum(jnp.where(live, lengths, 0))
+    read = jnp.sum(live) * min(cfg.indexer_topk, table.shape[1] * page_size)
+    return attn, {"ki": cki}, jnp.stack([seen, read]).astype(jnp.float32)
+
+
+def _dsa_ragged(h, q, lp, li, positions, pools, w_idx, table, q_starts, q_lens, kv_lens, *,
+                page_size, cfg):
+    """The seam's mixed-step side (llama.forward_ragged_impl): the same over
+    the packed rows; a prompt chunk's queries each keep a set of their own
+    (ops/sparse_attention.sparse_ragged)."""
+    del li
+    qi, wi, cki = _index_and_write(h, lp, positions, pools["ki"], w_idx, page_size, cfg)
+    sees = jnp.where(jnp.arange(q.shape[0]) < jnp.sum(q_lens), positions + 1, 0)   # a packed query's context
+    attn, walked = sparse_ragged(q, qi, wi, positions, pools["k"], pools["v"], cki, table, q_starts,
+                                 q_lens, kv_lens, topk=cfg.indexer_topk, num_kv_heads=cfg.num_kv_heads,
+                                 page_size=page_size)
+    return attn, {"ki": cki}, jnp.stack([jnp.sum(sees), jnp.sum(walked)]).astype(jnp.float32)
+
+
+def _seam(params: Params, cfg: MixtralConfig, mesh, valid=None) -> dict:
+    """What the shared skeletons take for a mixture: the FFN seam, the
+    leaves it reads whole by layer index (never sliced per layer), and for
+    a model with an indexer the attention seam."""
     experts = {n: params["layers"][n] for n in EXPERT_LEAVES}
-    return {"ffn": partial(_moe_ffn, cfg=cfg, experts=experts, mesh=mesh), "unscanned": EXPERT_LEAVES}
+    seam = {"ffn": partial(_moe_ffn, cfg=cfg, experts=experts, mesh=mesh), "unscanned": EXPERT_LEAVES}
+    if cfg.indexer_topk:
+        seam["mixer"] = {"cache": DSA_CACHE,
+                         "decode": partial(_dsa_decode, cfg=cfg, valid=valid),
+                         "ragged": partial(_dsa_ragged, cfg=cfg)}
+    return seam
+
+
+def init_paged_cache(cfg: MixtralConfig, cache_cfg, dtype=jnp.bfloat16) -> Params:
+    """The page pool of a model with an indexer: k and v as every model's
+    (serving/kv_cache.init_paged_cache), and the indexer's keys under the
+    same page ids."""
+    from inference_gateway_tpu.serving.kv_cache import init_paged_cache as kv_pages
+
+    cache = kv_pages(cfg, cache_cfg, dtype=dtype)
+    L, P, page_size, _ = cache["k"].shape
+    # A page of indexer keys lies transposed, the tokens in the lanes: 64 bf16
+    # a token would be half a lane row, padded to a whole one on the device.
+    return {**cache, "ki": jnp.zeros((L, P, cfg.indexer_head_dim, page_size), dtype)}
 
 
 @partial(jax.jit, static_argnames=("cfg", "mode", "last_only", "mesh", "with_aux"))
@@ -252,7 +363,7 @@ def forward_paged(
     (L, E); ``valid`` (B,) marks the decode rows that carry a stream."""
     return forward_paged_impl(params, cfg, tokens, positions, lengths, cache,
                               write_idx, page_table, mode, last_only, mesh,
-                              valid=valid, with_aux=with_aux, **_seam(params, cfg, mesh))
+                              valid=valid, with_aux=with_aux, **_seam(params, cfg, mesh, valid))
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "with_aux"))
@@ -296,6 +407,12 @@ def param_specs(cfg: MixtralConfig) -> dict:
         },
         "final_norm": P(None),
     }
+    if cfg.qk_norm:
+        specs["layers"].update(q_norm=P(None, None), k_norm=P(None, None))
+    if cfg.indexer_topk:  # replicated: no mesh carries the indexer or its cache yet
+        specs["layers"].update(wq_idx=P(None, None, None), wk_idx=P(None, None, None),
+                               ww_idx=P(None, None, None), k_idx_norm=P(None, None),
+                               k_idx_bias=P(None, None))
     if not cfg.tie_word_embeddings:
         specs["lm_head"] = P(None, "tp")
     return specs
@@ -315,6 +432,14 @@ _SMALLTHINKER_21B = dict(
     vocab_size=151936, hidden_size=2560, num_heads=28, num_kv_heads=4, head_dim=128,
     intermediate_size=768, num_experts=64, experts_per_token=6, rope_theta=1500000.0,
     max_position_embeddings=16384, sliding_window=4096)
+
+def _keye(num_layers: int, **sizes) -> MixtralConfig:
+    """Keye-VL-2.0's text block (ISSUE 36): q/k norms, 16 indexer heads of
+    64 in front of GQA, SiLU experts routed from their own normed input,
+    softmax over the chosen (the published softmax over all, top-k,
+    renormalised: the same numbers)."""
+    return MixtralConfig(num_layers=num_layers, qk_norm=True, rms_norm_eps=1e-6, **sizes)
+
 
 PRESETS: dict[str, MixtralConfig] = {
     "mixtral-test-tiny": MixtralConfig(
@@ -338,4 +463,17 @@ PRESETS: dict[str, MixtralConfig] = {
     # periods, every width, all 64 experts and the whole vocabulary as
     # published, which one v5e serves.
     "smallthinker-21b-a3b-stage16": _smallthinker(16, **_SMALLTHINKER_21B),
+    # topk shorter than the tests' sequences, two tiles' worth of queries.
+    "keye-test-tiny": _keye(
+        2, vocab_size=256, hidden_size=64, num_heads=4, num_kv_heads=2, head_dim=16,
+        intermediate_size=32, num_experts=8, experts_per_token=2, rope_theta=10000.0,
+        max_position_embeddings=512, indexer_heads=4, indexer_head_dim=16, indexer_topk=24),
+    # Kwai-Keye/Keye-VL-2.0-30B-A3B's text decoder is 48 layers, 30 GB of
+    # int8: this is the first of six pipeline stages of 8, every width, all
+    # 128 experts and the whole vocabulary as published, which one v5e
+    # serves; the vision tower is not built.
+    "keye-vl2-30b-a3b-stage8": _keye(
+        8, vocab_size=151936, hidden_size=2048, num_heads=32, num_kv_heads=4, head_dim=128,
+        intermediate_size=768, num_experts=128, experts_per_token=8, rope_theta=10000000.0,
+        max_position_embeddings=262144, indexer_heads=16, indexer_head_dim=64, indexer_topk=2048),
 }

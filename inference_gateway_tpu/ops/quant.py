@@ -142,6 +142,10 @@ def qeinsum(eq: str, x: jnp.ndarray, w, out_dtype=None) -> jnp.ndarray:
 # Weight names quantized in the decoder pytrees (matmul weights only —
 # embeddings, norms, and routers stay full precision).
 QUANTIZABLE = ("wq", "wk", "wv", "wo", "wg", "wu", "wd",
+               # The sparse-attention indexer's query projection (models/mixtral.py,
+               # ISSUE 36); its key and weight projections, (H, 64) and (H, 16), and
+               # the norms stay full precision.
+               "wq_idx",
                # models/phi4flash.py's matmul leaves (its QUANT_LEAVES): the MLP,
                # the Mamba mixer's and the gated memory unit's projections, the
                # fused key/value projection. W_x, W_dt, the convolution, A_log, D,
@@ -168,7 +172,9 @@ def quantize_llama_params(params: dict, mode: str = "int8", group: int = 128) ->
 def seeded_std(cfg, leaf: str) -> float:
     """Scale of a seeded build's ``leaf``: 0.02, except in a mixture the
     token embedding (1.0), the attention's output projection (0.005) and
-    the router (0.06).
+    the router (0.06), and in a model with a sparse-attention indexer the
+    indexer's projections, the values and the output projection (each
+    its fan-in ** -0.5: the comment in the body).
 
     Embedding and output projection, so that the routers read states as
     different as the tokens are: at 0.02 throughout, the map (what all
@@ -207,9 +213,51 @@ def seeded_std(cfg, leaf: str) -> float:
     the model's initialiser writes them."""
     if hasattr(cfg, "mamba_d_state"):
         return {"m_conv_w": 0.29, "lam": 0.1, "embed": 0.02}.get(leaf, cfg.hidden_size ** -0.5)
+    if getattr(cfg, "indexer_topk", 0):
+        # A model whose attention reads only the tokens an indexer chooses
+        # (ISSUE 36) is seeded so that WHICH tokens it reads shows in the
+        # logits: a comparison of served logprobs with a reference is then a
+        # comparison of the chosen sets too. Each of these leaves reads a
+        # normed stream, so fan_in ** -0.5 has unit gain at the published
+        # width and at the test preset's alike:
+        # - the indexer's projections (0.022 at 2,048, 0.125 at 64): a head's
+        #   query is O(1), q . k over the LayerNormed key spreads by
+        #   sqrt(head_dim), half the products pass the relu and a row's scores
+        #   differ by far more than bf16 rounds, so the set is the same served
+        #   in bf16 and computed in float32 for all but the scores nearest the cut;
+        # - values, and the output projection at (heads x head size) ** -0.5
+        #   (0.0156 at 4,096): attention adds as much as the stream holds,
+        #   where a mixture's 0.005 below keeps it under 1%;
+        # - with them the q and k norms' gains (``seeded_qk_gain``).
+        # With attention at 0.005 and gains of 1, a reference that attends over
+        # EVERY key in place of the 2,048 chosen read 0.10 nats from the one
+        # that selects (sandbox, float32, a 3,050-token probe): correct by the
+        # benchmark's 0.45. Seeded like this it reads 1.02 on the chip (PERF.md
+        # section 6, PR 36). The routers still read states as different as the
+        # tokens are: a head that rests on few keys hands each token other
+        # tokens' values, not the mean of all.
+        fan_in = {"wq_idx": cfg.hidden_size, "wk_idx": cfg.hidden_size, "ww_idx": cfg.hidden_size,
+                  "wv": cfg.hidden_size, "wo": cfg.num_heads * cfg.hd}.get(leaf)
+        if fan_in:
+            return fan_in ** -0.5
     if getattr(cfg, "num_experts", 0):
         return {"embed": 1.0, "wo": 0.005, "router": 0.06}.get(leaf, 0.02)
     return 0.02
+
+
+def seeded_qk_gain(cfg) -> float:
+    """What a seeded build writes into ``q_norm`` and ``k_norm``: 1, and 1.4
+    for a model with a sparse-attention indexer. A normed query times a normed
+    key over sqrt(head size) spreads by the product of the two gains whatever
+    the head size: at 1 a seeded head weighs thousands of keys nearly alike
+    and its output is their mean whichever quarter of them was chosen; at 1.4
+    (a spread of 2) it rests on a few dozen of 2,048, as a trained head does,
+    and a set that lacks them gives another output. Not higher: at 1.75 (a
+    spread of 3, a head on one key or two) bf16's rounding of a score moves
+    whole heads, and served logprobs read 0.27 and 0.52 nats from the float32
+    reference over two runs on the chip (PERF.md section 6, PR 36), past the
+    benchmark's limit; at 1.4 they read 0.07."""
+    return 1.4 if getattr(cfg, "indexer_topk", 0) else 1.0
 
 
 def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
@@ -285,7 +333,7 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
         "attn_norm": jnp.ones((L, H), dtype),
         "wq": qstack(keys[1], (L, H, Hq * D)),
         "wk": qstack(keys[2], (L, H, Hkv * D)),
-        "wv": qstack(keys[3], (L, H, Hkv * D)),
+        "wv": qstack(keys[3], (L, H, Hkv * D), seeded_std(cfg, "wv")),
         "wo": qstack(keys[4], (L, Hq * D, H), seeded_std(cfg, "wo")),
     }
     E = getattr(cfg, "num_experts", 0)
@@ -298,6 +346,16 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
             "wu": qfill(ekeys[2], (L, E, H, I)),
             "wd": qfill(ekeys[3], (L, E, I, H)),
         })
+        if cfg.qk_norm:
+            gain = jnp.full((L, D), seeded_qk_gain(cfg), dtype)
+            layers.update(q_norm=gain, k_norm=gain)
+        if cfg.indexer_topk:
+            ikeys = jax.random.split(jax.random.fold_in(rng, 36), 3)
+            Hi, Di, std = cfg.indexer_heads, cfg.indexer_head_dim, seeded_std(cfg, "wq_idx")
+            layers.update(wq_idx=qstack(ikeys[0], (L, H, Hi * Di), std),
+                          wk_idx=norm(ikeys[1], (L, H, Di), std),
+                          ww_idx=norm(ikeys[2], (L, H, Hi), std),
+                          k_idx_norm=jnp.ones((L, Di), dtype), k_idx_bias=jnp.zeros((L, Di), dtype))
     else:
         layers.update({
             "mlp_norm": jnp.ones((L, H), dtype),

@@ -275,6 +275,12 @@ MIXED_STATS = ("expert_tokens_max", "expert_tokens_mean", "experts_read")
 # counts, in both step programs, the pages its windowed layers read and the
 # pages their rows' contexts hold (ISSUE 32): two rows of the fetch block.
 WINDOW_STATS = ("window_pages_read", "context_pages")
+# A model with a sparse-attention indexer (models/mixtral.py, ISSUE 36)
+# counts, in both step programs, the cached tokens its rows' queries could
+# see and the tokens whose keys and values their attention read, summed
+# over queries and layers: two more rows of the fetch block, float32 (exact
+# to 2**24, a part in 10**7 beyond).
+DSA_STATS = ("dsa_context_tokens", "dsa_kv_tokens_read")
 
 # Presets of models/phi4flash.py, by name: the module is imported when one
 # of them is asked for, and not on the way to any other model.
@@ -300,6 +306,12 @@ def carries_state(model_cfg) -> bool:
     """A model whose layers keep a recurrent state per slot beside the
     cache (models/phi4flash.py)."""
     return hasattr(model_cfg, "mamba_d_state")
+
+
+def has_indexer(model_cfg) -> bool:
+    """A model whose attention reads the tokens a learned indexer chooses,
+    with the indexer's keys paged beside k and v (models/mixtral.py)."""
+    return bool(getattr(model_cfg, "indexer_topk", 0))
 
 
 def _experts_read(rows: jnp.ndarray) -> jnp.ndarray:
@@ -332,6 +344,13 @@ def choose_mesh(config: EngineConfig, model_cfg, n_dev: int):
             raise ValueError(
                 f"{config.model}: no mesh ({shape}) carries the Mamba state store yet; "
                 "serve it on one device")
+        return None, False
+    if has_indexer(model_cfg):
+        # Nor the indexer and its paged keys (ISSUE 36): the same rule.
+        if shape:
+            raise ValueError(
+                f"{config.model}: no mesh ({shape}) carries the sparse-attention indexer and its "
+                "key cache yet; serve it on one device")
         return None, False
     if shape.get("pp", 1) > 1:
         # Pipeline-parallel serving (SURVEY §2.4 PP row). Only the
@@ -513,7 +532,18 @@ class Engine:
         # Same call contract either way.
         self.is_moe = isinstance(self.model_cfg, mixtral.MixtralConfig)
         self.is_hybrid = carries_state(self.model_cfg)
+        self.is_dsa = has_indexer(self.model_cfg)
         self._model = mixtral if self.is_moe else llama
+        if self.is_dsa:
+            # What the indexer's path does not carry is refused by name (ISSUE 36).
+            if config.spec_draft is not None:
+                raise ValueError(f"{config.model}: speculative decoding has no verify pass through the "
+                                 "sparse-attention indexer; serve it without --spec-draft")
+            if config.vision_model is not None:
+                raise ValueError(f"{config.model}: its vision tower is not built (the published config "
+                                 "gives no width for it); image input has no path, serve text")
+            if config.quantize == "int4":
+                raise ValueError(f"{config.model}: int8 is the one weight-only quantisation built for it, not int4")
         if self.is_hybrid:
             from inference_gateway_tpu.models import phi4flash
 
@@ -562,7 +592,9 @@ class Engine:
                 max_slots=config.max_slots, max_seq_len=config.max_seq_len,
             )
             self.allocator = PageAllocator(self.page_cfg)
-            if self.is_hybrid:  # pages for its caching layers alone, and the state store beside them
+            if self.is_hybrid or self.is_dsa:
+                # The hybrid: pages for its caching layers alone, and the state
+                # store beside them. An indexer: its keys under the same page ids.
                 cache = self._model.init_paged_cache(self.model_cfg, self.page_cfg, dtype=self.dtype)
             else:
                 cache = init_paged_cache(self.model_cfg, self.page_cfg, dtype=self.dtype)
@@ -601,10 +633,12 @@ class Engine:
         # pp/multimodal carry state the ragged program doesn't.
         # A model with a state store admits through the ragged step alone
         # (the one program that hands a segment its slot's state), so a
-        # paged engine of it is a mixed engine whatever the flag says.
+        # paged engine of it is a mixed engine whatever the flag says; so
+        # is one with an indexer (each query of a chunk selects for itself).
         ep = self.mesh.shape.get("ep", 1) if self.mesh is not None else 1
         self.mixed_ok = (
-            self.paged and (config.mixed_step or self.is_hybrid) and config.spec_draft is None
+            self.paged and (config.mixed_step or self.is_hybrid or self.is_dsa)
+            and config.spec_draft is None
             and ep == 1 and not self.pp and config.vision_model is None
         )
         biggest_bucket = max((b for b in config.prefill_buckets
@@ -810,6 +844,14 @@ class Engine:
             "decode_steps": 0,
             "prefill_batches": 0,
         }
+        # What a paged decode chunk (``_run_chunk_ee``) and a mixed step count
+        # beside their tokens, a row of the fetch block each.
+        dsa_stats = DSA_STATS if self.is_dsa else ()
+        if self.is_hybrid:
+            self._chunk_stats = self._mixed_stats = WINDOW_STATS
+        else:
+            self._chunk_stats = ("experts_read",) + dsa_stats if self.is_moe else ()
+            self._mixed_stats = MIXED_STATS + dsa_stats
         if self.is_hybrid:
             # Segments that started at position 0, so from a zeroed state:
             # every admission, and every resume after a preemption.
@@ -1056,8 +1098,11 @@ class Engine:
                 params, self.model_cfg, tok[:, None], pos_att[:, None],
                 pos_att + 1, cache, w[:, None], page_table, mode="decode",
                 last_only=True, mesh=self.mesh, **counted)
-            if rows:  # a mixture's experts read; a windowed hybrid's pages (WINDOW_STATS)
-                read = _experts_read(rows[0]) if self.is_moe else rows[0]
+            if rows and self.is_moe:  # ``_chunk_stats``: a mixture's experts read, an indexer's counts
+                per, dsa = self._split_aux(rows[0])
+                read = jnp.concatenate([_experts_read(per)[None], dsa])
+            elif rows:  # a windowed hybrid's pages (WINDOW_STATS)
+                read = rows[0]
         if masked:
             with jax.named_scope("mask_bias"):
                 logits = logits + self._mask_bias(mbits, ms, mbias[:-1])
@@ -1111,7 +1156,7 @@ class Engine:
         # A paged mixture's carry also sums the experts its steps read, a
         # windowed hybrid's the pages they read and held.
         counted = (self.is_moe or self.is_hybrid) and write_args is not None
-        read0 = jnp.zeros((len(WINDOW_STATS),), jnp.float32) if self.is_hybrid else jnp.float32(0)
+        read0 = jnp.zeros((len(self._chunk_stats),), jnp.float32) if counted else None
 
         def cond(carry):
             i, _cache, _tok, _pos, _ms, done, _bud, _ot, _ol, *_read = carry
@@ -1132,14 +1177,14 @@ class Engine:
                 cond, body,
                 (jnp.int32(0), cache, tokens, positions, mstates, done0,
                  budgets, out_toks0, out_lps0, *([read0] if counted else [])))
-        if counted and self.is_moe:
-            # One more row of the block the host fetches anyway: the mean,
-            # over the steps that ran, of the experts a step read.
-            mean = read_sum[0] / jnp.maximum(i_ran, 1).astype(jnp.float32)
-            out_lps = jnp.concatenate([out_lps, jnp.broadcast_to(mean, (1, S))])
-        elif counted:  # the pages of the steps that ran, summed: a row each
-            out_lps = jnp.concatenate(
-                [out_lps, jnp.broadcast_to(read_sum[0][:, None], (len(WINDOW_STATS), S))])
+        if counted:
+            # More rows of the block the host fetches anyway, one a name of
+            # ``_chunk_stats``: ``experts_read`` is the mean over the steps that
+            # ran, every other count their sum.
+            stats = read_sum[0]
+            if self.is_moe:
+                stats = stats.at[0].divide(jnp.maximum(i_ran, 1).astype(jnp.float32))
+            out_lps = jnp.concatenate([out_lps, jnp.broadcast_to(stats[:, None], (stats.shape[0], S))])
         # Steps the loop never ran emit each row's FINAL frozen token
         # (not the chunk-entry one), so the emitted block reads exactly
         # like a chunk whose frozen rows kept repeating their last
@@ -1254,9 +1299,11 @@ class Engine:
             # Rows of the fetch block, in MIXED_STATS' order: per layer the
             # most rows one expert multiplied and the mean over experts,
             # and the experts read; each a mean over the layers.
-            per = rows[0].astype(jnp.float32)  # (L, E)
+            # Then DSA_STATS, counted by the attention seam.
+            per, dsa = self._split_aux(rows[0])
+            per = per.astype(jnp.float32)  # (L, E)
             stats = jnp.stack([jnp.mean(jnp.max(per, axis=1)), jnp.mean(per), _experts_read(per)])
-            return toks, logprobs, cache, stats
+            return toks, logprobs, cache, jnp.concatenate([stats, dsa])
         return toks, logprobs, cache
 
     @partial(jax.jit, static_argnames=("self", "masked"), donate_argnums=(2,))
@@ -1383,9 +1430,8 @@ class Engine:
             both = np.asarray(handle.toks_lp)
         self._audit_transfer("d2h", "mixed", both)
         if both.shape[0] > 2:
-            names = WINDOW_STATS if self.is_hybrid else MIXED_STATS
             handle.stats = {**(handle.stats or {}),
-                            **{k: round(float(v), 3) for k, v in zip(names, both[2:, 0])}}
+                            **{k: round(float(v), 3) for k, v in zip(self._mixed_stats, both[2:, 0])}}
         return both[0].astype(np.int32), both[1]
 
     def _state_stats(self, rows: int) -> dict | None:
@@ -1408,6 +1454,30 @@ class Engine:
             "cache_layers": {"window": cfg.mid, "full": 1, "shared_readers": cfg.cross_layers},
             "state_resets": self.metrics["state_resets"],
             "prefix_hits_declined": self.prefix_cache.declined if self.prefix_cache is not None else 0,
+        }
+
+    def _split_aux(self, aux):
+        """A mixture's per-layer auxiliary -> (the rows each expert multiplied
+        (L, E), DSA_STATS summed over the layers: none without an indexer)."""
+        if self.is_dsa:  # the pair (the FFN's, the attention seam's (L, 2))
+            return aux[0], jnp.sum(aux[1], axis=0)
+        return aux, jnp.zeros((0,), jnp.float32)
+
+    def dsa_status(self) -> dict | None:
+        """/debug/status ``dsa`` of a model with a sparse-attention indexer:
+        its sizes, and how the selection and the read are done (static)."""
+        if not self.is_dsa:
+            return None
+        cfg = self.model_cfg
+        return {
+            "topk": cfg.indexer_topk, "indexer_heads": cfg.indexer_heads,
+            "indexer_head_dim": cfg.indexer_head_dim,
+            "index_cache_bytes_per_token": cfg.num_layers * cfg.indexer_head_dim * jnp.dtype(self.dtype).itemsize,
+            "short_context": "a query that sees topk tokens or fewer keeps them all, by the same path (no plain-attention branch)",
+            "select": {"one_query_row": "lax.top_k over the row's scores",
+                       "prompt_chunk": "exact k-th largest by 32 counting passes, ties by position, as a mask"},
+            "read": {"one_query_row": "token gather of the chosen keys and values (topk tokens)",
+                     "prompt_chunk": "tiles of 128 queries over every block of keys up to the tile's last position, masked"},
         }
 
     def _prefill_one_ragged(self, prompt: list[int], slot: int, temp: float, top_p: float,
@@ -2410,9 +2480,8 @@ class Engine:
         self._audit_transfer("d2h", "chunk", both)
         n = handle.n_steps
         if both.shape[0] > 2 * n:
-            names = WINDOW_STATS if self.is_hybrid else ("experts_read",)
             handle.stats = {**(handle.stats or {}),
-                            **{k: round(float(v), 3) for k, v in zip(names, both[2 * n:, 0])}}
+                            **{k: round(float(v), 3) for k, v in zip(self._chunk_stats, both[2 * n:, 0])}}
         return both[:n].astype(np.int32), both[n:2 * n]
 
     def decode_chunk(self, tokens: np.ndarray, positions: np.ndarray, active: np.ndarray,
@@ -2480,7 +2549,7 @@ class Engine:
         400 check can never drift from actual admission behavior."""
         biggest = max(b for b in self.config.prefill_buckets
                       if b <= self.config.max_seq_len)
-        if self.is_hybrid and self.mixed_ok:
+        if (self.is_hybrid or self.is_dsa) and self.mixed_ok:
             biggest = 0  # every prompt is a "long" one: the ragged step admits them all
         ring_ok = (
             self.mesh is not None

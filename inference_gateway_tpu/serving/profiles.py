@@ -96,15 +96,22 @@ def mixtral_param_count(cfg: mixtral.MixtralConfig) -> int:
         + H * E  # router
         + 2 * H
     )
+    if cfg.indexer_topk:  # the sparse attention's indexer and the q/k norms beside it (ISSUE 36)
+        Hi, Di = cfg.indexer_heads, cfg.indexer_head_dim
+        per_layer += H * (Hi * Di + Di + Hi) + 2 * Di + 2 * cfg.hd
     return V * H + cfg.num_layers * per_layer + H + V * H
 
 
 def kv_bytes_per_token(cfg, dtype_bytes: int = 2) -> int:
-    """k + v bytes for ONE cached token across all layers that write the
+    """Cached bytes for ONE token (k, v, and a sparse attention's indexer
+    key) across all layers that write the
     cache (unsharded): every layer, unless the model says which
     (``cache_layers``: models/phi4flash.py has 9 for 32 layers)."""
     layers = getattr(cfg, "cache_layers", cfg.num_layers)
-    return 2 * layers * cfg.num_kv_heads * cfg.hd * dtype_bytes
+    # A sparse-attention indexer keeps one more key a token a layer under
+    # the same page ids (models/mixtral.py, ISSUE 36).
+    index = cfg.indexer_head_dim if getattr(cfg, "indexer_topk", 0) else 0
+    return layers * (2 * cfg.num_kv_heads * cfg.hd + index) * dtype_bytes
 
 
 def resolve_model_cfg(model: str):

@@ -1029,6 +1029,11 @@ class SidecarServer:
             # layers, bytes), ``cache_layers`` (window, full, shared_readers),
             # ``state_resets`` and ``prefix_hits_declined``; absent otherwise.
             **((state_status() if state_status else None) or {}),
+            # A model with a sparse-attention indexer (ISSUE 36): its sizes and
+            # how selection and read are done; absent otherwise. Its `decode`
+            # and `mixed` timeline records carry `dsa_context_tokens` and
+            # `dsa_kv_tokens_read`.
+            **({"dsa": self.engine.dsa_status()} if getattr(self.engine, "is_dsa", False) else {}),
             # Desynchronized decode (ISSUE 14): the shape at which the
             # decode loop stops on device and chains host-free.
             "decode": {
@@ -1142,6 +1147,12 @@ class SidecarServer:
         messages = body.get("messages") or []
         prompt_ids = self.engine.tokenizer.apply_chat_template(messages)
         embeds = None
+        if getattr(self.engine, "is_dsa", False) and any(
+                isinstance(part, dict) and part.get("type") == "image_url"
+                for m in messages if isinstance(m.get("content"), list) for part in m["content"]):
+            raise _BadRequest(
+                f"{self.model_name}: its vision tower is not built; image input has no path, send text",
+                code="unsupported_content", param="messages")
         if self.engine.vision_cfg is not None:
             images = self._decode_images(messages)
             if images:

@@ -354,3 +354,92 @@ def test_state_operations_compile_for_v5e_at_the_published_widths(one_chip, form
     compiled = jax.jit(step, donate_argnums=(1, 4)).lower(*args).compile()
     store = S * Di * N * 4
     assert compiled.memory_analysis().temp_size_in_bytes < store + (64 << 20)
+
+
+# What each family's two step programs trace to, at its tiny preset with the
+# kernels' dispatch steered to the TPU's: (kernel instances, ``cond``s,
+# equations) of the mixed step and of the decode step. The five older families
+# read exactly what the parent's tree reads (PR 36 added an attention seam to
+# the shared skeletons: a model without one must not pay for it in trace,
+# lowering or start-up); the model with the indexer holds NO attention kernel
+# (its one path scores, selects and reads in plain XLA whatever the rows hold: a
+# branch to the paged kernels for rows at or under topk would run only in
+# warm-up and cost every start an instance), in the mixed step two
+# ``cond``s (a tile that sees topk or fewer selects nothing; a tie at the cut) and
+# the tile loops, under a ceiling of a tenth over what it reads (886 / 511).
+STEP_PROGRAMS = {
+    ("llama", "test-tiny"): ((1, 0, 156), (1, 0, 142)),
+    ("llama", "qwen2-test-tiny"): ((1, 0, 163), (1, 0, 149)),
+    ("mixtral", "mixtral-test-tiny"): ((1, 0, 282), (1, 0, 262)),
+    ("mixtral", "smallthinker-test-tiny"): ((1, 0, 301), (2, 1, 284)),
+    ("phi4flash", "phi4flash-test-tiny"): ((2, 0, 1030), (3, 1, 773)),
+    ("mixtral", "keye-test-tiny"): ((0, 2, 975), (0, 0, 562)),
+}
+
+
+def _census(jaxpr, out):
+    """Primitive -> count under ``jaxpr``; a kernel's own body is not entered."""
+    for eqn in jaxpr.eqns:
+        out[eqn.primitive.name] = out.get(eqn.primitive.name, 0) + 1
+        if eqn.primitive.name != "pallas_call":
+            for inner in _inner_jaxprs(eqn):
+                _census(inner, out)
+    return out
+
+
+@pytest.mark.parametrize("module,preset", list(STEP_PROGRAMS), ids=[p for _, p in STEP_PROGRAMS])
+def test_step_programs_hold_the_kernels_and_equations_they_held(monkeypatch, module, preset):
+    import importlib
+
+    from inference_gateway_tpu.serving.kv_cache import PagedCacheConfig
+
+    monkeypatch.setattr("inference_gateway_tpu.ops.paged_attention.device_platform", lambda: "tpu")
+    model = importlib.import_module(f"inference_gateway_tpu.models.{module}")
+    cfg = model.PRESETS[preset]
+    slots, page, seq, T = 4, 16, 64, 40
+    params = jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0), cfg))
+    own_pool = module == "phi4flash" or getattr(cfg, "indexer_topk", 0)
+    if own_pool:
+        cache = jax.eval_shape(lambda: model.init_paged_cache(cfg, PagedCacheConfig(page, 0, slots, seq)))
+    else:
+        pages = jax.ShapeDtypeStruct((cfg.num_layers, slots * seq // page, page, cfg.num_kv_heads * cfg.hd), BF16)
+        cache = {"k": pages, "v": pages}
+    sds = jax.ShapeDtypeStruct
+    tokens, row, col, table = sds((1, T), I32), sds((slots,), I32), sds((slots, 1), I32), sds((slots, seq // page), I32)
+    mixed = jax.make_jaxpr(lambda *a: model.forward_ragged(a[0], cfg, *a[1:]))(
+        params, tokens, tokens, cache, tokens, table, row, row, row)
+    decode = jax.make_jaxpr(lambda *a: model.forward_paged(a[0], cfg, *a[1:], mode="decode"))(
+        params, col, col, row, cache, col, table)
+    older = preset != "keye-test-tiny"
+    for traced, (kernels, conds, equations) in zip((mixed, decode), STEP_PROGRAMS[(module, preset)]):
+        census = _census(traced.jaxpr, {})
+        assert (census.get("pallas_call", 0), census.get("cond", 0)) == (kernels, conds)
+        total = sum(census.values())
+        assert total == equations if older else total <= equations
+
+
+@pytest.mark.parametrize("form", ["decode", "ragged"])
+def test_sparse_attention_compiles_for_v5e_at_the_published_widths(one_chip, form):
+    """Score, select and read (ops/sparse_attention.py) for Keye-VL-2.0's stage:
+    12 rows of 100 pages, 16 indexer heads of 64, top-2,048 of up to 12,800,
+    32 / 4 heads of 128; a decode step's rows, and a 524-token mixed step's.
+    Plain XLA: a pass says the chip's compiler takes it inside 1 GB of
+    temporaries beside the 2.7 GB pool (no copy of a pool)."""
+    from inference_gateway_tpu.ops import sparse_attention as sa
+
+    slots, pages, layers, T = 12, 100, 8, 524
+    tokens = layers * slots * pages * PAGE
+    pools = (one_chip((tokens, 4 * D), BF16), one_chip((tokens, 4 * D), BF16), one_chip((tokens // PAGE, 64, PAGE), BF16))
+    table, row = one_chip((slots, pages), I32), one_chip((slots,), I32)
+    kw = dict(topk=2048, num_kv_heads=4, page_size=PAGE)
+    if form == "decode":
+        fn = lambda q, qi, wi, k, v, ki, table, lens: sa.sparse_decode(q, qi, wi, k, v, ki, table, lens, **kw)
+        args = (one_chip((slots, 32, D), BF16), one_chip((slots, 16, 64), BF16), one_chip((slots, 16), jnp.float32),
+                *pools, table, row)
+    else:
+        fn = lambda q, qi, wi, pos, k, v, ki, table, qs, ql, kl: sa.sparse_ragged(
+            q, qi, wi, pos, k, v, ki, table, qs, ql, kl, **kw)
+        args = (one_chip((T, 32, D), BF16), one_chip((T, 16, 64), BF16), one_chip((T, 16), jnp.float32),
+                one_chip((T,), I32), *pools, table, row, row, row)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
