@@ -60,6 +60,12 @@ REAL = {
     "long_prompt_chars": 1500,   # > largest prefill bucket (512) + slots: chunked mixed steps
     "kernel_shapes": {"Hq": 32, "Hkv": 8, "D": 128, "page": 128, "slots": 24, "seq": 2048,
                       "layers": 32, "bucket": 512, "window": 4096, "dtype": "bfloat16"},
+    # The decode kernel at the other served layouts (ISSUE 37: its pages a visit
+    # follow the layout): name -> (Hq, Hkv, slots, window, the window as the operand).
+    "decode_layouts": {"qwen2.5-7b": (28, 4, 48, None, False),
+                       "smallthinker-21b-a3b windowed": (28, 4, 48, 4096, True),
+                       "phi-4-mini-flash windowed": (40, 10, 64, 512, True),
+                       "phi-4-mini-flash full": (40, 10, 64, None, True)},
 }
 REHEARSAL = {
     "model": "test-tiny",
@@ -72,10 +78,12 @@ REHEARSAL = {
     "long_prompt_chars": 300,
     "kernel_shapes": {"Hq": 4, "Hkv": 2, "D": 16, "page": 16, "slots": 4, "seq": 128,
                       "layers": 2, "bucket": 32, "window": 64, "dtype": "float32"},
+    "decode_layouts": {"six heads over three": (6, 3, 4, None, False),
+                       "windowed by the operand": (4, 2, 4, 40, True)},
 }
 # Kernel vs twin, compared in float32: |a - b| <= ATOL + RTOL * |b|. bf16 keeps 8
-# bits of mantissa; the twins round the probabilities to bf16 before the PV
-# matmul and the kernels do not, which is the whole of the difference.
+# bits of mantissa; kernels and twins round the probabilities to bf16 before the
+# PV matmul and sum in another order, which is the whole of the difference.
 KERNEL_ATOL, KERNEL_RTOL = 2e-2, 2e-2
 # tp = 4 against one device, same weights: the first generated token's logprob
 # (the next-token distribution after prefill) per request. Sharding changes the
@@ -535,6 +543,7 @@ def child_kernels(rehearse: bool) -> int:
     from inference_gateway_tpu.ops.attention import causal_prefill_mask, gqa_attend
     from inference_gateway_tpu.ops.flash_attention import flash_prefill_attention
     from inference_gateway_tpu.ops.paged_attention import (
+        GLOBAL_WINDOW,
         paged_attention_jax,
         paged_attention_tpu,
         ragged_paged_attention_jax,
@@ -581,16 +590,30 @@ def child_kernels(rehearse: bool) -> int:
                       "mean_abs_err": float(err.mean()), "elements": int(err.size),
                       "seconds": round(time.monotonic() - t0, 2)})
 
-    # Decode: one query per slot; an idle slot, page edges, a full context.
+    # Decode: one query per slot; an idle slot (its row is zeros), page edges, a
+    # full context.
     edge = [0, 1, ps - 1, ps, ps + 1, seq - 1, seq]
+    decode_twin = jax.jit(paged_attention_jax, static_argnames=("num_kv_heads", "window"))
+
+    def decode_case(name, q, k_pages, v_pages, table, lengths, Hkv, window, operand=False):
+        kw = {"window_dyn": jnp.int32(window or GLOBAL_WINDOW)} if operand else {"window": window}
+        compare(name,
+                paged_attention_tpu(q, k_pages, v_pages, table, jnp.asarray(lengths), Hkv,
+                                    interpret=interpret, **kw),
+                decode_twin(q, k_pages, v_pages, table, jnp.asarray(lengths), num_kv_heads=Hkv, window=window))
+
     lengths = np.asarray((edge + list(rng.integers(1, seq + 1, size=S)))[:S], np.int32)
     q = jax.random.normal(keys[2], (S, Hq, D), dtype)
-    compare("paged_attention_tpu vs paged_attention_jax",
-            paged_attention_tpu(q, k_pages, v_pages, table, jnp.asarray(lengths), Hkv,
-                                interpret=interpret, window=window),
-            jax.jit(paged_attention_jax, static_argnames=("num_kv_heads", "window"))(
-                q, k_pages, v_pages, table, jnp.asarray(lengths), num_kv_heads=Hkv, window=window),
-            mask=lengths > 0)
+    decode_case("paged_attention_tpu vs paged_attention_jax", q, k_pages, v_pages, table, lengths, Hkv, window)
+    for name, (hq, hkv, slots, win, operand) in (REHEARSAL if rehearse else REAL)["decode_layouts"].items():
+        lk = jax.random.split(jax.random.fold_in(keys[7], hq * slots), 3)
+        pages = [jax.random.normal(key, (slots * mp, ps, hkv * D), dtype) for key in lk[:2]]
+        lens = np.asarray((edge + list(rng.integers(1, seq + 1, size=slots)))[:slots], np.int32)
+        lens[rng.permutation(slots)[:slots // 4]] = 0  # the slots an open loop leaves empty
+        decode_case(f"paged_attention_tpu vs paged_attention_jax, {name}",
+                    jax.random.normal(lk[2], (slots, hq, D), dtype), *pages,
+                    jnp.asarray(rng.permutation(slots * mp).reshape(slots, mp).astype(np.int32)),
+                    lens, hkv, win, operand)
 
     # Ragged: the packed axis of one mixed step — prefill chunks (fresh, resumed,
     # ending at the context limit) beside decode rows and idle rows.

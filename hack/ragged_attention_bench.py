@@ -1,4 +1,4 @@
-"""Time the ragged attention kernel alone on the chip (ISSUE 34).
+"""Time the paged attention kernels alone on the chip (ISSUE 34, ISSUE 37).
 
 By hand, through chiprun, parent and change in one call (a chip belongs to
 one process at a time, so each side is a process of its own):
@@ -16,8 +16,15 @@ reading of what its rows can see, the v5e's least time for them
 `ragged_paged_attention_jax` on the same bf16 inputs. `--set NAME=JSON` sets a
 constant of the kernel's module first (a variant to try); `--window-operand`
 hands the cases of a model that mixes layer kinds their window as the operand
-its mixed step hands it. Not part of the benchmark, imported by nothing a cell
-runs; a CPU run of it times the CPU and is never a speed number.
+its mixed step hands it.
+
+The decode-only cases (ISSUE 37, ``decode:<cell>``) hold one decode step's
+slots at each cell's shape, live and empty, and time two kernels on the same
+rows: ``paged_attention_tpu`` (the decode kernel every decode program calls)
+and the ragged kernel's one-query path (each live slot a row of one query).
+
+Not part of the benchmark, imported by nothing a cell runs; a CPU run of it
+times the CPU and is never a speed number.
 """
 
 from __future__ import annotations
@@ -32,6 +39,9 @@ import time
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PAGE, MAX_PAGES, CALLS, REPEATS = 128, 16, 16, 15
+# A decode-only call is 50-500 us: chained 16 times, the host's ~0.5 ms to dispatch a
+# program and hear of its end would be a third of the reading (ISSUE 37, step 0).
+DECODE_CALLS = 128
 
 # name: (Hq, Hkv, D, window, slots, step width, [(q_len, kv_len), ...])
 def _cases() -> dict:
@@ -66,6 +76,31 @@ def _cases() -> dict:
     }
 
 
+# name: (Hq, Hkv, D, window, slots, live slots' contexts): one decode step's
+# slots, the live ones at the contexts of PERF.md section 4's traffic.
+def _decode_cases() -> dict:
+    import numpy as np
+
+    rng = np.random.default_rng(37)
+
+    def ctx(n, lo, hi):
+        return [int(k) for k in rng.integers(lo, hi, size=n)]
+
+    batch, reason = ctx(48, 100, 900), ctx(64, 300, 1500)
+    return {
+        "decode:qwen2.5-7b.batch": (28, 4, 128, None, 48, batch),
+        "decode:qwen2.5-7b.chat": (28, 4, 128, None, 48, ctx(15, 100, 700)),
+        "decode:mistral-7b.chat": (32, 8, 128, 4096, 24, ctx(11, 100, 700)),
+        "decode:mistral-7b.long-prompt": (32, 8, 128, 4096, 24, ctx(3, 1100, 1800)),
+        # the mixture's two kinds of layer: contexts under the window, a mask apart
+        "decode:smallthinker-21b-a3b.batch.window": (28, 4, 128, 4096, 48, batch),
+        "decode:smallthinker-21b-a3b.batch.global": (28, 4, 128, None, 48, batch),
+        # the hybrid's: most rows past the 512-token window, and the full layers
+        "decode:phi-4-mini-flash.reason.window": (40, 10, 128, 512, 64, reason),
+        "decode:phi-4-mini-flash.reason.full": (40, 10, 128, None, 64, reason),
+    }
+
+
 # The cases whose model mixes windowed and global layers.
 MIXED_KINDS = ("smallthinker-21b-a3b.batch", "phi-4-mini-flash.reason")
 
@@ -89,7 +124,10 @@ def _reckon(rows, Hq, Hkv, D, window) -> tuple[float, float]:
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--root", default=HERE, help="checkout whose kernel to time")
-    ap.add_argument("--cases", nargs="*")
+    ap.add_argument("--cases", nargs="*", help="ragged cases by name, decode-only cases by prefix ('decode:')")
+    ap.add_argument("--repeats", type=int, default=REPEATS)
+    ap.add_argument("--profile", action="store_true",
+                    help="one more run of each chained program under the profiler: device us a call by operation")
     ap.add_argument("--set", nargs="*", default=[], metavar="NAME=JSON")
     ap.add_argument("--window-operand", action="store_true")
     ap.add_argument("--tag")
@@ -107,7 +145,8 @@ def main() -> int:
     dev = jax.devices()[0]
     out = {"root": os.path.relpath(root, HERE), "set": args.set, "window_operand": args.window_operand,
            "device": {"platform": dev.platform, "kind": dev.device_kind},
-           "calls_chained": CALLS, "cases": measure(pa, args.cases, args.window_operand)}
+           "calls_chained": CALLS, "decode_calls_chained": DECODE_CALLS,
+           "cases": measure(pa, args.cases, args.window_operand, args.repeats, args.profile)}
     dest = os.path.join(HERE, "chiprun_out", "ragged_attention_bench")
     os.makedirs(dest, exist_ok=True)
     tag = args.tag or ("change" if root == HERE else os.path.basename(root))
@@ -117,8 +156,70 @@ def main() -> int:
     return 0 if dev.platform == "tpu" else 1
 
 
-def measure(pa, names=None, window_operand=False) -> dict:
-    """Each case's line, for the kernel of the module ``pa``."""
+def _timed(call, q, k, v, ref, rows, Hq, Hkv, D, window, chip, repeats, live=True, profile=False,
+           calls=CALLS) -> dict:
+    """One kernel's line: ``call(q, k, v)`` chained ``calls`` times in one
+    program; its distance from ``ref`` over the rows of ``live``."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def chained(q_, k_, v_):
+        return jax.lax.fori_loop(0, calls, lambda i, x: call(x, k_, v_), q_)
+
+    got = jax.block_until_ready(jax.jit(call)(q, k, v))
+    diff = float(jnp.max(jnp.where(live, jnp.abs(got.astype(jnp.float32) - ref.astype(jnp.float32)), 0)))
+    jax.block_until_ready(chained(q, k, v))
+    times = []
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        jax.block_until_ready(chained(q, k, v))
+        times.append((time.perf_counter() - t0) / calls)
+    flops, nbytes = _reckon(rows, Hq, Hkv, D, window)
+    line = {"us_per_call": round(statistics.median(times) * 1e6, 1),
+            "us_min": round(min(times) * 1e6, 1),
+            "gflop": round(flops / 1e9, 3), "mbytes": round(nbytes / 1e6, 2),
+            "max_abs_diff_vs_twin_bf16": diff,
+            "rows": len(rows), "prompt_tokens": sum(ql for ql, _ in rows if ql > 1)}
+    if chip is not None:
+        t_flop = flops / chip["bf16_flops_per_s"]
+        t_byte = nbytes / chip["hbm_bytes_per_s"]
+        least = max(t_flop, t_byte)
+        line.update(least_us=round(least * 1e6, 2),
+                    bound="flops" if t_flop >= t_byte else "bytes",
+                    roofline_share_pct=round(100 * least / statistics.median(times), 2))
+    if profile:
+        line["device_us_by_op"] = _device_us_by_op(lambda: jax.block_until_ready(chained(q, k, v)), calls)
+    return line
+
+
+def _device_us_by_op(run, calls) -> dict:
+    """Device time of one ``run()`` (``calls`` chained calls) by operation, us
+    a call, the six largest: the profiler's ``XLA Ops`` line of the device."""
+    import glob
+    import re
+    import tempfile
+
+    import jax
+    from jax.profiler import ProfileData
+
+    with tempfile.TemporaryDirectory() as tmp:
+        with jax.profiler.trace(tmp):
+            run()
+        (path,) = glob.glob(os.path.join(tmp, "**", "*.xplane.pb"), recursive=True)
+        data = ProfileData.from_file(path)
+        by_op: dict[str, float] = {}
+        for plane in data.planes:
+            if re.match(r"^/device:TPU:\d+$", plane.name):
+                for line in plane.lines:
+                    if line.name == "XLA Ops":
+                        for ev in line.events:
+                            by_op[ev.name] = by_op.get(ev.name, 0.0) + ev.duration_ns / 1e3 / calls
+    return {k[:60]: round(v, 2) for k, v in sorted(by_op.items(), key=lambda kv: -kv[1])[:6]}
+
+
+def measure(pa, names=None, window_operand=False, repeats=REPEATS, profile=False) -> dict:
+    """Each case's line, for the kernels of the module ``pa``."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -126,6 +227,8 @@ def measure(pa, names=None, window_operand=False) -> dict:
     dev = jax.devices()[0]
     chips = json.load(open(os.path.join(HERE, "bench", "chips.json")))
     chip = chips.get(dev.device_kind)
+    interpret = dev.platform != "tpu"
+    bf = jnp.bfloat16
     lines = {}
     for name, (Hq, Hkv, D, window, R, T, rows) in _cases().items():
         if names and name not in names:
@@ -141,13 +244,11 @@ def measure(pa, names=None, window_operand=False) -> dict:
             off += ql
         assert off <= T, (name, off, T)
         P = R * MAX_PAGES
-        bf = jnp.bfloat16
         q = jnp.asarray(rng.normal(size=(T, Hq, D)), bf)
         k = jnp.asarray(rng.normal(size=(P, PAGE, Hkv * D)), bf)
         v = jnp.asarray(rng.normal(size=(P, PAGE, Hkv * D)), bf)
         pt = jnp.asarray(rng.permutation(P).reshape(R, MAX_PAGES).astype(np.int32))
         descr = (jnp.asarray(q_starts), jnp.asarray(q_lens), jnp.asarray(kv_lens))
-        interpret = dev.platform != "tpu"
 
         kw = {"window": window}
         if window_operand and name in MIXED_KINDS:
@@ -157,33 +258,50 @@ def measure(pa, names=None, window_operand=False) -> dict:
             return pa.ragged_paged_attention_tpu(q_, k_, v_, pt, *descr, Hkv,
                                                  interpret=interpret, **kw)
 
-        @jax.jit
-        def chained(q_, k_, v_):
-            return jax.lax.fori_loop(0, CALLS, lambda i, x: call(x, k_, v_), q_)
-
-        got = jax.block_until_ready(jax.jit(call)(q, k, v))
         ref = jax.jit(pa.ragged_paged_attention_jax, static_argnames=("num_kv_heads", "window"))(
             q, k, v, pt, *descr, num_kv_heads=Hkv, window=window)
-        diff = float(jnp.max(jnp.abs(got.astype(jnp.float32) - ref.astype(jnp.float32))))
-        jax.block_until_ready(chained(q, k, v))
-        times = []
-        for _ in range(REPEATS):
-            t0 = time.perf_counter()
-            jax.block_until_ready(chained(q, k, v))
-            times.append((time.perf_counter() - t0) / CALLS)
-        flops, nbytes = _reckon(rows, Hq, Hkv, D, window)
-        line = {"us_per_call": round(statistics.median(times) * 1e6, 1),
-                "us_min": round(min(times) * 1e6, 1),
-                "gflop": round(flops / 1e9, 3), "mbytes": round(nbytes / 1e6, 2),
-                "max_abs_diff_vs_twin_bf16": diff,
-                "rows": len(rows), "prompt_tokens": sum(ql for ql, _ in rows if ql > 1)}
-        if chip is not None:
-            t_flop = flops / chip["bf16_flops_per_s"]
-            t_byte = nbytes / chip["hbm_bytes_per_s"]
-            least = max(t_flop, t_byte)
-            line.update(least_us=round(least * 1e6, 2),
-                        bound="flops" if t_flop >= t_byte else "bytes",
-                        roofline_share_pct=round(100 * least / statistics.median(times), 2))
+        lines[name] = _timed(call, q, k, v, ref, rows, Hq, Hkv, D, window, chip, repeats, profile=profile)
+        print(name, json.dumps(lines[name]), flush=True)
+
+    for name, (Hq, Hkv, D, window, B, contexts) in _decode_cases().items():
+        if names and not any(name.startswith(n) for n in names):
+            continue
+        rng = np.random.default_rng(len(name))
+        lengths = np.zeros(B, np.int32)
+        lengths[rng.permutation(B)[:len(contexts)]] = contexts
+        P = B * MAX_PAGES
+        q = jnp.asarray(rng.normal(size=(B, Hq, D)), bf)
+        k = jnp.asarray(rng.normal(size=(P, PAGE, Hkv * D)), bf)
+        v = jnp.asarray(rng.normal(size=(P, PAGE, Hkv * D)), bf)
+        pt = jnp.asarray(rng.permutation(P).reshape(B, MAX_PAGES).astype(np.int32))
+        lens = jnp.asarray(lengths)
+        rows = [(1, c) for c in contexts]
+        live = (lens > 0)[:, None, None]
+
+        kw = {"window": window}
+        if window_operand and any(name.startswith(f"decode:{m}") for m in MIXED_KINDS):
+            kw = {"window_dyn": jnp.int32(window or pa.GLOBAL_WINDOW)}
+
+        def decode(q_, k_, v_):
+            return pa.paged_attention_tpu(q_, k_, v_, pt, lens, Hkv, interpret=interpret, **kw)
+
+        def one_query(q_, k_, v_):  # slot b's query is packed position b
+            return pa.ragged_paged_attention_tpu(
+                q_, k_, v_, pt, jnp.arange(B, dtype=jnp.int32), (lens > 0).astype(jnp.int32), lens,
+                Hkv, interpret=interpret, **kw)
+
+        # An empty slot's row is the decode kernel's own business (undefined in
+        # the parent's, zeros since ISSUE 37): the distance is over live rows.
+        ref = jax.jit(pa.paged_attention_jax, static_argnames=("num_kv_heads", "window"))(
+            q, k, v, pt, lens, num_kv_heads=Hkv, window=window)
+        line = {"slots": B, "live": len(contexts), "pages_visible": sum(
+            -(-c // PAGE) - max(c - (window or c), 0) // PAGE for c in contexts)}
+        for label, fn in (("decode_kernel", decode), ("ragged_one_query", one_query)):
+            line[label] = _timed(fn, q, k, v, ref, rows, Hq, Hkv, D, window, chip, repeats, live, profile, DECODE_CALLS)
+            empty = fn(q, k, v)[np.flatnonzero(lengths == 0)]
+            line[label]["empty_rows_zero"] = bool(jnp.all(empty == 0))
+        if hasattr(pa, "decode_walk_plan"):
+            line["plan"] = pa.decode_walk_plan(Hq, Hkv, D, PAGE, MAX_PAGES, bf)
         lines[name] = line
         print(name, json.dumps(line), flush=True)
     return lines

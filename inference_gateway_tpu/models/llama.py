@@ -107,10 +107,10 @@ def _rope_qk(q, k, cos, sin, cfg: "LlamaConfig", li, lp=None):
 
 def _attend_by_window(cfg: "LlamaConfig", li, attend):
     """``attend(window)`` for layer ``li``. ``window`` is a static
-    argument of the decode kernel and the dense paths, so a model that
-    mixes windowed and global layers carries one instance of each under a
-    ``lax.cond`` on the layer's flag; a model whose layers are alike
-    traces one. (The ragged kernel takes ``_window_operand`` instead.)"""
+    argument of the dense prefill paths, so a model that mixes windowed
+    and global layers carries one instance of each under a ``lax.cond``
+    on the layer's flag; a model whose layers are alike traces one. (The
+    paged kernels take ``_window_operand`` instead.)"""
     if cfg.layer_window is None or not cfg.sliding_window:
         return attend(cfg.sliding_window)
     return jax.lax.cond(jnp.asarray(cfg.layer_window, bool)[li],
@@ -118,11 +118,12 @@ def _attend_by_window(cfg: "LlamaConfig", li, attend):
 
 
 def _window_operand(cfg: "LlamaConfig", li) -> dict:
-    """Layer ``li``'s window as the ragged kernel takes it (ISSUE 34). A
-    model whose layers are alike hands it statically (``window=``: none
-    pays no mask); one that mixes windowed and global layers hands it as
-    an operand (``window_dyn=``), so its mixed step traces and lowers ONE
-    instance of the kernel, not one a kind."""
+    """Layer ``li``'s window as the paged kernels take it (the ragged one
+    since ISSUE 34, the decode one since ISSUE 37). A model whose layers
+    are alike hands it statically (``window=``: none pays no mask); one
+    that mixes windowed and global layers hands it as an operand
+    (``window_dyn=``), so each of its step programs traces and lowers ONE
+    instance of its kernel, not one a kind."""
     if cfg.layer_window is None or not cfg.sliding_window:
         return {"window": cfg.sliding_window}
     from inference_gateway_tpu.ops.paged_attention import GLOBAL_WINDOW
@@ -560,9 +561,6 @@ def forward_paged_impl(
             layer_table = page_table + li * P  # (B, max_pages) into the big pool
 
             def attend(window):
-                if decode:
-                    return paged_attention(q[:, 0], pages_k, pages_v, layer_table, lengths, Hkv,
-                                           window=window, mesh=mesh)[:, None]  # (B, 1, Hq, D)
                 if mode == "prefill_chunk":
                     # Gather the slot's pages (prefix + just-written tail) and
                     # attend causally by absolute position.
@@ -582,7 +580,10 @@ def forward_paged_impl(
                     return flash_prefill_attention(q, k, v, lengths, window=window)
                 return gqa_attend(q, k, v, windowed(prefill_mask, positions[:, None, :], window))
 
-            if mixer is None:
+            if mixer is None and decode:
+                attn = paged_attention(q[:, 0], pages_k, pages_v, layer_table, lengths, Hkv,
+                                       mesh=mesh, **_window_operand(cfg, li))[:, None]  # (B, 1, Hq, D)
+            elif mixer is None:
                 attn = _attend_by_window(cfg, li, attend)
             else:
                 attn, wrote, mix_aux = mixer["decode"](

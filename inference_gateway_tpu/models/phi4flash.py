@@ -438,7 +438,7 @@ def forward_paged(params: Params, cfg: Phi4FlashConfig, tokens, positions, lengt
     a slot, one token each (``models/llama.forward_paged``'s contract for
     ``mode="decode"``). ``valid`` (B,) rows that carry a stream: the others
     keep their state. With ``with_aux`` a third result, ``window_pages``."""
-    from inference_gateway_tpu.ops.paged_attention import paged_attention
+    from inference_gateway_tpu.ops.paged_attention import GLOBAL_WINDOW, paged_attention
 
     if mode != "decode":
         raise NotImplementedError(
@@ -449,13 +449,11 @@ def forward_paged(params: Params, cfg: Phi4FlashConfig, tokens, positions, lengt
     valid = jnp.ones((B,), bool) if valid is None else valid
 
     def attend(q, pages_k, pages_v, table, windowed):
-        # ``window`` is a static argument of the decode kernel: one instance of
-        # each kind under a cond on the pair (models/llama._attend_by_window).
-        def kind(window):
-            return lambda: paged_attention(q, pages_k, pages_v, table, lengths, cfg.kernel_kv_heads,
-                                           window=window)
-
-        return jax.lax.cond(windowed, kind(cfg.sliding_window), kind(None))
+        # The decode kernel takes the window as an operand: ONE instance for
+        # both kinds of layer (ISSUE 37; models/llama._window_operand).
+        window = jnp.where(windowed, cfg.sliding_window, GLOBAL_WINDOW).astype(jnp.int32)
+        return paged_attention(q, pages_k, pages_v, table, lengths, cfg.kernel_kv_heads,
+                               window_dyn=window)
 
     x, cache = _decoder(
         params, cfg, params["embed"][tokens[:, 0]], cache, write_idx[:, 0], page_table,

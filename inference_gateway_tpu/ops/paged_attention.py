@@ -16,8 +16,8 @@ per-head views slice inside it. The page table is (B, max_pages) int32.
 Two call shapes, each with a kernel and a pure-JAX twin:
 
 - ``paged_attention_{jax,tpu}``: one query token per slot (the classic
-  decode step). Grid over slot blocks; double-buffered page DMA
-  pipelined across the flattened (slot, page) walk.
+  decode step). One instance a call; double-buffered page DMA pipelined
+  across the flattened (slot, visit) walk, several pages a visit.
 - ``ragged_paged_attention_{jax,tpu}`` (ISSUE 12): a MIXED batch — the
   packed query axis carries every row's queries back to back, and
   per-row descriptors (q_start, q_len, kv_len) say which queries belong
@@ -105,152 +105,212 @@ def paged_attention_jax(
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
     out = jnp.einsum("bkgs,bskd->bkgd", probs.astype(v.dtype), v, preferred_element_type=jnp.float32)
-    return out.reshape(B, Hq, D).astype(q.dtype)
+    # An empty slot's row is zeros, as the kernel writes it.
+    return jnp.where((lengths > 0)[:, None, None], out.reshape(B, Hq, D), 0).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
+# The window operand of a global layer (``window_dyn``): past any context.
+GLOBAL_WINDOW = 1 << 30
+
+# The decode kernel walks a slot's pages several at a time (ISSUE 37): a
+# VISIT has up to ``pages_per_visit`` consecutive pages in flight and scores,
+# soft-maxes and weighs them as one (Hq, N·page_size) tile. Both page buffers
+# (K and V, each double-buffered) and the visit's float32 scores and
+# probabilities have to fit this much VMEM; the layout decides N.
+DECODE_VISIT_BYTES = 4 << 20
+
+
+def decode_walk_plan(num_q_heads: int, num_kv_heads: int, head_dim: int, page_size: int,
+                     max_pages: int, dtype=jnp.bfloat16) -> dict:
+    """The decode kernel's page walk for a layout: static per engine, so it
+    is a /debug/status entry (``decode_attention``)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    page = page_size * _pad_lanes(num_kv_heads * head_dim) * itemsize
+    rows = num_kv_heads * 8 * -(-num_q_heads // num_kv_heads // 8)  # G on the sublane grid
+    tile = rows * page_size * (4 + itemsize)  # a page's scores and probabilities
+    n = max(1, min(DECODE_VISIT_BYTES // (2 * 2 * page + tile), max_pages))
+    return {"pages_per_visit": n, "operand_dtype": jnp.dtype(dtype).name, "skips_empty_slots": True}
+
+
+def _div(a, b: int):
+    """a // b of scalars that are never negative (one equation where
+    ``//`` traces eleven to round towards minus infinity)."""
+    return jax.lax.div(a, jnp.int32(b))
+
+
+def _each_head(num_kv_heads: int, head_dim: int, body) -> None:
+    """``body(h)`` for every KV head: a loop traced once where a head's
+    lane slice sits on the 128 grid, else static slices. Unrolled when
+    it is lowered, so that the chip's scheduler overlaps one head's
+    product with the next one's: left as a loop the ragged kernel takes
+    1.4-2.5 times as long (PERF.md §6, PR 34)."""
+    if head_dim % LANE:
+        for h in range(num_kv_heads):
+            body(h)
+    else:
+        jax.lax.fori_loop(0, num_kv_heads, lambda h, c: (body(h), c)[1], 0, unroll=True)
+
+
 def _paged_attn_kernel(
     # scalar prefetch
-    page_table_ref,  # (B, max_pages) SMEM
-    length_ref,  # (B, 1) SMEM
+    page_table_ref,  # (B + 1, max_pages) SMEM
+    length_ref,  # (B + 1,) SMEM: the slots' lengths, -1; then the window where it is an operand
     # inputs
-    q_ref,  # (SB, Hq, D) VMEM block: this instance's slots
+    q_ref,  # (B, Hkv, G, D) VMEM: every slot's query, by KV head
     k_pages_hbm,  # (P, page_size, Hkv*D) in ANY/HBM
     v_pages_hbm,
     # output
-    out_ref,  # (SB, Hq, D) VMEM
+    out_ref,  # (B, Hkv, G, D) VMEM
     # scratch
-    k_buf,  # (2, page_size, pad128(Hkv*D)) VMEM — DMA fills [:Hkv*D]
+    k_buf,  # (2, N, page_size, pad128(Hkv*D)) VMEM — a page's DMA fills [:Hkv*D]
     v_buf,
     sems,  # DMA semaphores (2, 2)
+    s_ref,  # (Hkv, G, N·page_size) float32: a visit's scores
+    p_ref,  # the same in V's dtype: its probabilities
+    m_ref,  # (Hkv, G, 1) float32: the slot's running maximum,
+    l_ref,  # sum
+    acc_ref,  # (Hkv, G, D) and output
     *,
-    page_size: int,
-    num_kv_heads: int,
-    groups: int,
-    head_dim: int,
-    window: int | None = None,
-    slots_per_block: int = 1,
+    window: int | None,
+    window_operand: bool,
 ):
-    """SB slots per grid instance, double-buffered page DMA pipelined
-    across the FLATTENED (slot, page) sequence: while slot s's page p is
-    in the MXU, the next page — slot s's p+1, or slot s+1's first page —
-    is in flight. One-slot-per-instance (round ≤4) paid the per-instance
-    fixed cost B times per layer-step and stalled on the first page of
-    EVERY slot; at decode occupancy (few live pages per slot) those
-    bubbles were most of the 92 µs/layer-step in-scan cost the round-3
-    profile flagged vs 25 µs standalone (round-4 verdict next #4).
-    Inactive slots (length 0) are treated as one fully-masked page so the
-    prefetch chain stays regular."""
-    g = pl.program_id(0)
-    SB = slots_per_block
-    scale = head_dim ** -0.5
-    Hkv, G, D = num_kv_heads, groups, head_dim
-    Hq = Hkv * G
-    num_pages_total = k_pages_hbm.shape[0]
+    """One instance for the whole batch. The walk is one chain of VISITS
+    over the slots that hold something: a visit is up to N consecutive
+    pages of a slot, DMA'd together into one half of the page buffers
+    while the other half is in the MXU; a slot's last visit starts the
+    first of the next slot WITH a length, so an empty slot costs neither a
+    DMA nor a flash update and its output row is zeros.
+
+    A visit's pages are scored, soft-maxed and weighed as ONE (G,
+    N·page_size) tile a KV head: one loop over heads for the scores, one
+    flash update over every head's tile, one loop over heads for the
+    second product, the loops traced once. Operands reach the MXU in the
+    dtype they are stored in, the probabilities in V's, sums in float32:
+    the arithmetic of ``paged_attention_jax``. A window's first visit and
+    a context's last are ragged: only their live pages are fetched, what
+    the rest of the buffer holds is masked (V's buffer starts as zeros, so
+    a masked column never weighs a NaN).
+
+    The window is static (``window``: a model whose layers are alike pays
+    no mask without one) or the last of ``length_ref``
+    (``window_operand``: a model that mixes windowed and global layers
+    traces ONE instance, a global layer handing GLOBAL_WINDOW)."""
+    B, Hkv, G, D = q_ref.shape
+    _, N, page_size, _ = k_buf.shape
+    scale = D ** -0.5
+    last_page = k_pages_hbm.shape[0] - 1
     folded = k_pages_hbm.shape[-1]  # valid columns of the padded scratch
+    zero = jnp.int32(0)
+    if window_operand:
+        window = length_ref[B + 1]
 
-    def slen(s):  # s is block-local
-        return length_ref[g * SB + s, 0]
+    def live_from(i):
+        """The first slot at or after ``i`` that holds something; B if none
+        (``length_ref[B]`` is -1: a slot of no pages that ends the search)."""
+        return jax.lax.while_loop(lambda i: length_ref[i] == 0, lambda i: i + 1, i)
 
-    def p_start_of(s):
-        # Sliding window: skip whole pages before the window start —
-        # decode bandwidth becomes O(window), not O(length) (Mistral
-        # semantics, dense counterpart models/llama.py forward decode).
+    def span(s):
+        """(first page, pages) of slot ``s``'s walk. Sliding window: whole
+        pages before the window start are skipped — decode bandwidth is
+        O(window), not O(length)."""
+        length = length_ref[s]
+        pages = _div(length + (page_size - 1), page_size)
         if window is None:
-            return jnp.int32(0)
-        return jnp.maximum(slen(s) - window, 0) // page_size
+            return zero, pages
+        first = _div(jnp.maximum(length - window, 0), page_size)
+        return first, pages - first
 
-    def n_pages_of(s):
-        return jnp.maximum(pl.cdiv(slen(s), page_size), 1)
+    def page_dma(par, idx, i):
+        # The copy fills only the valid folded columns of the padded buffer.
+        return (pltpu.make_async_copy(k_pages_hbm.at[idx], _page_dst(k_buf.at[par], i, folded),
+                                      sems.at[par, 0]),
+                pltpu.make_async_copy(v_pages_hbm.at[idx], _page_dst(v_buf.at[par], i, folded),
+                                      sems.at[par, 1]))
 
-    def page_dma(buf_slot, s, page_pos):
-        # Clamp: an inactive slot's table row may be stale; its fetched
-        # page is fully masked but the DMA must stay in bounds. The copy
-        # fills only the valid folded columns of the lane-padded buffer.
-        page_idx = jnp.clip(page_table_ref[g * SB + s, page_pos], 0, num_pages_total - 1)
-        k_dma = pltpu.make_async_copy(
-            k_pages_hbm.at[page_idx], _page_dst(k_buf, buf_slot, folded), sems.at[buf_slot, 0])
-        v_dma = pltpu.make_async_copy(
-            v_pages_hbm.at[page_idx], _page_dst(v_buf, buf_slot, folded), sems.at[buf_slot, 1])
-        return k_dma, v_dma
+    def fetch(par, s, page, n):
+        """Start the DMAs of ``n`` pages of slot ``s`` from ``page`` on."""
+        def one(i, c):
+            # Clamped: a stale table entry must not take the DMA out of bounds.
+            idx = jnp.minimum(jnp.maximum(page_table_ref[s, page + i], 0), last_page)
+            for dma in page_dma(par, idx, i):
+                dma.start()
+            return c
 
-    # Kick off the block's very first page.
-    for dma in page_dma(0, jnp.int32(0), p_start_of(0)):
-        dma.start()
+        jax.lax.fori_loop(zero, n, one, 0)
 
-    def slot_body(s, parity):
-        q = q_ref[pl.dslice(s, 1)][0].astype(jnp.float32)
-        length = slen(s)
-        p0 = p_start_of(s)
-        n_p = n_pages_of(s)
-        w_start = jnp.int32(0) if window is None else jnp.maximum(length - window, 0)
-
-        def body(p, carry):
-            m, l, acc, par = carry  # (Hq,1), (Hq,1), (Hq,D), buf parity
-
-            # Prefetch the next page of the flattened (slot, page) walk.
-            in_slot = p + 1 < n_p
-            s_next = jnp.where(in_slot, s, s + 1)
-            p_next = jnp.where(in_slot, p + 1,
-                               p_start_of(jnp.minimum(s + 1, SB - 1)))
-
-            @pl.when(s_next < SB)
-            def _():
-                for dma in page_dma(1 - par, s_next, p_next):
-                    dma.start()
-
-            for dma in page_dma(par, s, p):
+    def arrived(par, n):
+        def one(i, c):
+            for dma in page_dma(par, 0, i):  # a wait counts bytes: any page's
                 dma.wait()
+            return c
 
-            k_page = k_buf[par].astype(jnp.float32)  # (page_size, Hkv*D)
-            v_page = v_buf[par].astype(jnp.float32)
+        jax.lax.fori_loop(zero, n, one, 0)
 
-            token_pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-            valid = token_pos < length  # (1, page_size)
+    out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+    v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
+    first = live_from(zero)
+    first_page, first_pages = span(first)
+    fetch(0, first, first_page, jnp.minimum(first_pages, N))
+
+    def slot_body(carry):
+        s, p0, pages, par = carry
+        length = length_ref[s]
+        # Whose first visit this slot's last one starts.
+        nxt = live_from(s + 1)
+        nxt_p0, nxt_pages = span(nxt)
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        def visit(done, par):
+            """The slot's pages from its ``done``-th on, N at the most."""
+            left = pages - done - N
+            more = left > 0
+            fetch(1 - par, jax.lax.select(more, s, nxt), jax.lax.select(more, p0 + done + N, nxt_p0),
+                  jnp.minimum(jax.lax.select(more, left, nxt_pages), N))
+            arrived(par, jnp.minimum(left + N, N))
+
+            def score(h):
+                k_h = k_buf[par, :, :, pl.ds(h * D, D)].reshape(N * page_size, D)
+                s_ref[h] = jax.lax.dot_general(
+                    q_ref[s, h], k_h.astype(q_ref.dtype), dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)  # (G, N·page_size)
+
+            _each_head(Hkv, D, score)
+            tile = s_ref.shape
+            pos = (p0 + done) * page_size + jax.lax.broadcasted_iota(jnp.int32, tile, 2)
+            valid = pos < length
             if window is not None:
-                valid = valid & (token_pos >= w_start)
-
-            # Per-kv-head slices of the folded axis; static unroll over Hkv.
-            score_rows = []
-            for h in range(Hkv):
-                k_h = k_page[:, h * D:(h + 1) * D]  # (page_size, D)
-                q_h = q[h * G:(h + 1) * G]  # (G, D)
-                score_rows.append(jax.lax.dot_general(
-                    q_h, k_h, dimension_numbers=(((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ))  # (G, page_size)
-            scores = jnp.concatenate(score_rows, axis=0) * scale  # (Hq, page_size)
-            scores = jnp.where(valid, scores, NEG_INF)
-
-            m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+                valid = valid & (pos >= length - window)
+            # Every KV head's scores under ONE flash update a visit.
+            sc = jax.lax.select(valid, s_ref[...] * scale, jnp.full(tile, NEG_INF, jnp.float32))
+            m = m_ref[...]
+            m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
             alpha = jnp.exp(m - m_new)
-            p_ij = jnp.exp(scores - m_new)  # (Hq, page_size)
-            l_new = l * alpha + jnp.sum(p_ij, axis=-1, keepdims=True)
+            probs = jnp.exp(sc - m_new)
+            l_ref[...] = l_ref[...] * alpha + jnp.sum(probs, axis=-1, keepdims=True)
+            m_ref[...] = m_new
+            acc_ref[...] = acc_ref[...] * alpha
+            p_ref[...] = probs.astype(p_ref.dtype)
 
-            pv_rows = []
-            for h in range(Hkv):
-                v_h = v_page[:, h * D:(h + 1) * D]  # (page_size, D)
-                p_h = p_ij[h * G:(h + 1) * G]  # (G, page_size)
-                pv_rows.append(jnp.dot(p_h, v_h, preferred_element_type=jnp.float32))  # (G, D)
-            pv = jnp.concatenate(pv_rows, axis=0)  # (Hq, D)
+            def weigh(h):
+                v_h = v_buf[par, :, :, pl.ds(h * D, D)].reshape(N * page_size, D)
+                acc_ref[h] += jnp.dot(p_ref[h], v_h, preferred_element_type=jnp.float32)
 
-            return m_new, l_new, acc * alpha + pv, 1 - par
+            _each_head(Hkv, D, weigh)
+            return done + N, 1 - par
 
-        m0 = jnp.full((Hq, 1), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((Hq, 1), jnp.float32)
-        acc0 = jnp.zeros((Hq, D), jnp.float32)
-        m, l, acc, parity = jax.lax.fori_loop(p0, n_p, body, (m0, l0, acc0, parity))
+        _, par = jax.lax.while_loop(lambda c: c[0] < pages, lambda c: visit(*c), (zero, par))
+        out_ref[s] = (acc_ref[...] / l_ref[...]).astype(out_ref.dtype)
+        return nxt, nxt_p0, nxt_pages, par
 
-        out = acc / jnp.maximum(l, 1e-20)
-        out_ref[pl.dslice(s, 1)] = out[None].astype(out_ref.dtype)
-        return parity
-
-    jax.lax.fori_loop(0, SB, slot_body, jnp.int32(0))
+    jax.lax.while_loop(lambda c: c[0] < B, slot_body, (first, first_page, first_pages, zero))
 
 
-@functools.partial(jax.jit, static_argnames=("num_kv_heads", "interpret", "window"))
+@functools.partial(jax.jit, static_argnames=("num_kv_heads", "interpret", "window", "pages_per_visit"))
 def paged_attention_tpu(
     q: jnp.ndarray,  # (B, Hq, D)
     k_pages: jnp.ndarray,  # (P, page_size, Hkv*D)
@@ -260,50 +320,63 @@ def paged_attention_tpu(
     num_kv_heads: int,
     interpret: bool = False,
     window: int | None = None,
+    window_dyn: jnp.ndarray | None = None,  # int32 scalar: the window as an operand
+    pages_per_visit: int | None = None,  # the layout's (decode_walk_plan) unless a test says
 ) -> jnp.ndarray:
     B, Hq, D = q.shape
     P, page_size, HkvD = k_pages.shape
     G = Hq // num_kv_heads
-    # Largest SB dividing the batch: fewer grid instances (per-instance
-    # fixed cost /SB) and a DMA pipeline that flows across slots.
-    SB = next(s for s in (8, 4, 2, 1) if B % s == 0)
+    assert window is None or window_dyn is None, "one window: static, or the operand"
+    N = pages_per_visit or decode_walk_plan(Hq, num_kv_heads, D, page_size, page_table.shape[1],
+                                            k_pages.dtype)["pages_per_visit"]
+    # The products' operand dtype: what q and the pages are stored in.
+    mxu_dtype = jnp.promote_types(q.dtype, k_pages.dtype)
 
-    kernel = functools.partial(
-        _paged_attn_kernel,
-        page_size=page_size,
-        num_kv_heads=num_kv_heads,
-        groups=G,
-        head_dim=D,
-        window=window,
-        slots_per_block=SB,
-    )
+    kernel = functools.partial(_paged_attn_kernel, window=window,
+                               window_operand=window_dyn is not None)
+    # A slot more, of length -1: it ends the search for the next live slot and
+    # holds no page. After it the window, where it is an operand.
+    descr = jax.lax.pad(lengths.astype(jnp.int32), jnp.int32(-1), ((0, 1, 0),))
+    if window_dyn is not None:
+        descr = jnp.concatenate([descr, jnp.reshape(window_dyn, (1,)).astype(jnp.int32)])
+    by_head = (B, num_kv_heads, G, D)
+    tile = (num_kv_heads, G, N * page_size)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B // SB,),
+        grid=(1,),
         in_specs=[
-            pl.BlockSpec((SB, Hq, D), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec(by_head, lambda i, *_: (0, 0, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.ANY),
             pl.BlockSpec(memory_space=pltpu.ANY),
         ],
-        out_specs=pl.BlockSpec((SB, Hq, D), lambda b, *_: (b, 0, 0)),
+        out_specs=pl.BlockSpec(by_head, lambda i, *_: (0, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((2, page_size, _pad_lanes(HkvD)), k_pages.dtype),
-            pltpu.VMEM((2, page_size, _pad_lanes(HkvD)), v_pages.dtype),
+            pltpu.VMEM((2, N, page_size, _pad_lanes(HkvD)), k_pages.dtype),
+            pltpu.VMEM((2, N, page_size, _pad_lanes(HkvD)), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM(tile, jnp.float32),
+            pltpu.VMEM(tile, v_pages.dtype),
+            pltpu.VMEM((num_kv_heads, G, 1), jnp.float32),
+            pltpu.VMEM((num_kv_heads, G, 1), jnp.float32),
+            pltpu.VMEM((num_kv_heads, G, D), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(by_head, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=64 << 20),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), lengths.reshape(B, 1).astype(jnp.int32), q, k_pages, v_pages)
+    )(jax.lax.pad(page_table.astype(jnp.int32), jnp.int32(0), ((0, 1, 0), (0, 0, 0))),
+      descr, q.reshape(by_head).astype(mxu_dtype), k_pages, v_pages)
+    return out.reshape(B, Hq, D)
 
 
 def paged_attention_sharded(q, k_pages, v_pages, page_table, lengths, num_kv_heads: int,
                             mesh, window: int | None = None,
                             interpret: bool | None = None,
-                            replicated: bool = False) -> jnp.ndarray:
+                            replicated: bool = False, window_dyn=None) -> jnp.ndarray:
     """Pallas kernel under a mesh via shard_map (round-1 verdict next
     #5). Two modes:
 
@@ -317,24 +390,26 @@ def paged_attention_sharded(q, k_pages, v_pages, page_table, lengths, num_kv_hea
       Duplicate work, zero collectives — and still ~10× cheaper than
       the gather fallback these layouts used to take (ISSUE 12).
 
-    Page table and lengths are replicated host metadata either way."""
+    Page table, lengths and the window operand are replicated host
+    metadata either way."""
     from jax.sharding import PartitionSpec as P
 
     if interpret is None:
         interpret = not on_tpu()
     hkv_local = num_kv_heads if replicated else num_kv_heads // mesh.shape["tp"]
 
-    def local(q_l, k_l, v_l, pt_l, len_l):
-        return paged_attention_tpu(q_l, k_l, v_l, pt_l, len_l, hkv_local,
-                                   interpret=interpret, window=window)
+    def local(q_l, k_l, v_l, pt_l, len_l, *w_l):
+        return paged_attention_tpu(q_l, k_l, v_l, pt_l, len_l, hkv_local, interpret=interpret,
+                                   window=window, window_dyn=w_l[0] if w_l else None)
 
     rep = P()
+    dyn = () if window_dyn is None else (jnp.asarray(window_dyn, jnp.int32),)
     if replicated:
-        in_specs = (rep, rep, rep, rep, rep)
+        in_specs = (rep,) * (5 + len(dyn))
         out_spec = rep
     else:
         in_specs = (P(None, "tp", None), P(None, None, "tp"), P(None, None, "tp"),
-                    P(None, None), P(None))
+                    rep, rep) + (rep,) * len(dyn)
         out_spec = P(None, "tp", None)
     return jax.shard_map(
         local,
@@ -342,7 +417,7 @@ def paged_attention_sharded(q, k_pages, v_pages, page_table, lengths, num_kv_hea
         in_specs=in_specs,
         out_specs=out_spec,
         check_vma=False,
-    )(q, k_pages, v_pages, page_table, lengths)
+    )(q, k_pages, v_pages, page_table, lengths, *dyn)
 
 
 # ---------------------------------------------------------------------------
@@ -417,8 +492,6 @@ def ragged_paged_attention_jax(
 # in a narrow one.
 RAGGED_WIDE_TILE = 128
 RAGGED_SCORE_BYTES = 512 << 10
-# The window operand of a global layer (``window_dyn``): past any context.
-GLOBAL_WINDOW = 1 << 30
 
 
 def ragged_tile_plan(groups: int, page_size: int) -> dict:
@@ -436,12 +509,6 @@ def ragged_tile_plan(groups: int, page_size: int) -> dict:
 # rows, page_size), the running maximum and sum (Hkv, rows, 1), the running
 # output (Hkv, rows, D), each row's absolute position (rows, 1).
 _TileScratch = collections.namedtuple("_TileScratch", "q s p m l acc qpos")
-
-
-def _div(a, b: int):
-    """a // b of scalars that are never negative (one equation where
-    ``//`` traces eleven to round towards minus infinity)."""
-    return jax.lax.div(a, jnp.int32(b))
 
 
 def _ragged_paged_attn_kernel(
@@ -539,17 +606,7 @@ def _ragged_paged_attn_kernel(
         for Ws in widths:
             pl.when(W == Ws)(functools.partial(fn, Ws, scratch[Ws], *args))
 
-    def each_head(body):
-        """``body(h)`` for every KV head: a loop traced once where a head's
-        lane slice sits on the 128 grid, else static slices. Unrolled when
-        it is lowered, so that the chip's scheduler overlaps one head's
-        product with the next one's: left as a loop the kernel takes
-        1.4-2.5 times as long (PERF.md §6, PR 34)."""
-        if D % LANE:
-            for h in range(Hkv):
-                body(h)
-        else:
-            jax.lax.fori_loop(0, Hkv, lambda h, c: (body(h), c)[1], 0, unroll=True)
+    each_head = functools.partial(_each_head, Hkv, D)
 
     def page_dma(buf_slot, row, page_pos):
         page_idx = jnp.clip(page_table_ref[row, page_pos], 0, num_pages_total - 1)
@@ -860,7 +917,7 @@ def _mesh_devices(mesh) -> int:
 
 def paged_attention(q, k_pages, v_pages, page_table, lengths, num_kv_heads: int,
                     use_kernel: bool | None = None, window: int | None = None,
-                    mesh=None) -> jnp.ndarray:
+                    mesh=None, window_dyn=None) -> jnp.ndarray:
     """Dispatch (see paged_dispatch): Pallas kernel on single-device
     TPU, shard_mapped over ``tp`` under a mesh (kv-head-local), or a
     replicated shard_map launch for tp=1 meshes / non-tp-divisible
@@ -869,7 +926,9 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, num_kv_heads: int,
     exercise the shard_map path on a CPU mesh in interpret mode). The
     flag is captured at import (module attr FORCE_PAGED_KERNEL) —
     jitted forwards bake the dispatch into the trace, so a mid-session
-    env flip would not apply to compiled shapes (advisor round-2)."""
+    env flip would not apply to compiled shapes (advisor round-2).
+    ``window`` is static; ``window_dyn`` is the window as an int32 scalar
+    operand (GLOBAL_WINDOW for none), for a model whose layers differ."""
     force = FORCE_PAGED_KERNEL
     platform = device_platform()
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
@@ -884,12 +943,13 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, num_kv_heads: int,
     if path in ("kernel_sharded", "kernel_replicated") and mesh is not None:
         return paged_attention_sharded(q, k_pages, v_pages, page_table, lengths,
                                        num_kv_heads, mesh, window=window,
-                                       replicated=path == "kernel_replicated")
+                                       replicated=path == "kernel_replicated",
+                                       window_dyn=window_dyn)
     if path in ("kernel", "kernel_sharded", "kernel_replicated"):
         return paged_attention_tpu(q, k_pages, v_pages, page_table, lengths, num_kv_heads,
-                                   window=window, interpret=interpret)
+                                   window=window, interpret=interpret, window_dyn=window_dyn)
     return paged_attention_jax(q, k_pages, v_pages, page_table, lengths, num_kv_heads,
-                               window=window)
+                               window=window if window_dyn is None else window_dyn)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table, q_starts, q_lens, kv_lens,

@@ -658,9 +658,11 @@ class Engine:
         # from the same utils.platform.on_tpu.)
         self.device = device_info()
         self.ragged_attention_plan: dict | None = None
+        self.decode_attention_plan: dict | None = None
         if self.paged:
             from inference_gateway_tpu.ops.paged_attention import (
                 FORCE_PAGED_KERNEL,
+                decode_walk_plan,
                 paged_dispatch,
                 ragged_tile_plan,
             )
@@ -681,10 +683,20 @@ class Engine:
             # The ragged kernel's tiling for this layout (ISSUE 34): static
             # per engine, so a /debug/status field; the scheduler counts
             # from ``wide_from`` the tokens whose rows take the wide tile.
+            kv_heads = getattr(self.model_cfg, "kernel_kv_heads", self.model_cfg.num_kv_heads)
             if self.mixed_ok:
-                kv_heads = getattr(self.model_cfg, "kernel_kv_heads", self.model_cfg.num_kv_heads)
                 self.ragged_attention_plan = ragged_tile_plan(
                     self.model_cfg.num_heads // kv_heads, config.page_size)
+            # The decode kernel's page walk for this layout (ISSUE 37), a
+            # tp shard's where the kernel runs on a shard's heads: static as
+            # well. A model whose attention reads what an indexer chooses
+            # holds no paged kernel.
+            if not has_indexer(self.model_cfg):
+                shards = mesh_tp if self.attention_path == "kernel_sharded" else 1
+                self.decode_attention_plan = decode_walk_plan(
+                    self.model_cfg.num_heads // shards, kv_heads // shards,
+                    self.model_cfg.num_kv_heads * self.model_cfg.hd // kv_heads, config.page_size,
+                    self.page_cfg.max_pages_per_slot, self.dtype)
         else:
             self.attention_path = "dense"
             self.attention_path_reason = "contiguous slot cache (paged attention not in use)"
@@ -1094,9 +1106,14 @@ class Engine:
             valid = (~done) & (pos < max_len) & (pos < reserved)
             w = jnp.where(valid, w, self._flat_size)
             counted = {"valid": ~done, "with_aux": True} if self.is_moe or self.is_hybrid else {}
+            # A done row attends nothing: the decode kernel passes over a
+            # slot of length 0 and writes its row as zeros (ISSUE 37), where
+            # a frozen row's stale context cost every step its pages. (An
+            # indexer's selection is not made for an empty context.)
+            att_len = pos_att + 1 if self.is_dsa else jnp.where(done, 0, pos_att + 1)
             logits, cache, *rows = self._model.forward_paged(
                 params, self.model_cfg, tok[:, None], pos_att[:, None],
-                pos_att + 1, cache, w[:, None], page_table, mode="decode",
+                att_len, cache, w[:, None], page_table, mode="decode",
                 last_only=True, mesh=self.mesh, **counted)
             if rows and self.is_moe:  # ``_chunk_stats``: a mixture's experts read, an indexer's counts
                 per, dsa = self._split_aux(rows[0])

@@ -1025,6 +1025,13 @@ class SidecarServer:
             # `attn_wide_tokens` on the timeline's `mixed` records counts the
             # tokens of the rows that took the wide tile.
             "ragged_attention": getattr(self.engine, "ragged_attention_plan", None),
+            # The decode attention kernel's page walk (ISSUE 37): up to
+            # `pages_per_visit` pages of a slot are fetched, scored and
+            # weighed as one tile, operands in `operand_dtype`, and an
+            # empty slot costs no visit (`skips_empty_slots`; how often it
+            # engages is `batch` of the timeline's `decode` records against
+            # the slots). None where no decode program holds the kernel.
+            "decode_attention": getattr(self.engine, "decode_attention_plan", None),
             # A model with a state store (ISSUE 32): ``state_store`` (slots,
             # layers, bytes), ``cache_layers`` (window, full, shared_readers),
             # ``state_resets`` and ``prefix_hits_declined``; absent otherwise.

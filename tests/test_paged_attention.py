@@ -10,7 +10,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from inference_gateway_tpu.ops.paged_attention import paged_attention_jax, paged_attention_tpu
+from inference_gateway_tpu.ops.paged_attention import (
+    GLOBAL_WINDOW,
+    decode_walk_plan,
+    paged_attention_jax,
+    paged_attention_tpu,
+)
 from inference_gateway_tpu.serving.engine import Engine, EngineConfig
 from inference_gateway_tpu.serving.kv_cache import OutOfPagesError, PageAllocator, PagedCacheConfig
 from inference_gateway_tpu.serving.scheduler import Scheduler, generate_sync
@@ -27,14 +32,14 @@ def test_kernel_interpret_matches_reference():
 
     ref = paged_attention_jax(q, k, v, pt, lengths, Hkv)
     out = paged_attention_tpu(q, k, v, pt, lengths, Hkv, interpret=True)
-    # Inactive slots (length 0) are undefined; compare active rows.
     np.testing.assert_allclose(np.asarray(out[:2]), np.asarray(ref[:2]), rtol=1e-5, atol=1e-5)
+    assert not np.asarray(out[2]).any()  # an empty slot's row is zeros (ISSUE 37)
 
 
 def test_kernel_multi_slot_block_matches_reference():
-    """B=8 takes the SB=8 multi-slot-per-instance path: the DMA pipeline
-    crosses slot boundaries and inactive slots ride as masked pages —
-    every active row must still match the reference exactly."""
+    """Eight slots, two of them empty: the DMA chain crosses slot
+    boundaries and passes over the empty ones — every active row must
+    still match the reference exactly."""
     rng = np.random.default_rng(7)
     B, Hq, Hkv, D, ps, P, mp = 8, 8, 4, 64, 16, 64, 8
     q = jnp.asarray(rng.normal(size=(B, Hq, D)).astype(np.float32))
@@ -49,6 +54,123 @@ def test_kernel_multi_slot_block_matches_reference():
     active = [i for i, n in enumerate([128, 37, 32, 1, 0, 97, 16, 0]) if n]
     np.testing.assert_allclose(np.asarray(out)[active], np.asarray(ref)[active],
                                rtol=1e-5, atol=1e-5)
+
+
+# The decode kernel's walk (ISSUE 37): N pages a visit, only live pages fetched,
+# no visit for an empty slot. name -> (lengths, window, options). Small pages so
+# the interpreter is quick; ``pages_per_visit`` 3 of 8 so that contexts end
+# before, on and after a visit's edge (the layout's own N would be all 8).
+PS, MP, N_TEST = 16, 8, 3
+WALK_CASES = {
+    "empty_slot_first": ([0, 37, 100, 5], None, {}),
+    "empty_slots_in_the_middle": ([37, 0, 0, 100], None, {}),
+    "empty_slot_last": ([37, 100, 5, 0], None, {}),
+    "every_slot_empty": ([0, 0, 0, 0], None, {}),
+    "one_token": ([1, 128, 1, 16], None, {}),
+    "a_token_short_of_a_visit": ([N_TEST * PS - 1, 2 * N_TEST * PS - 1, 0, 9], None, {}),
+    "a_whole_visit": ([N_TEST * PS, 2 * N_TEST * PS, 0, 128], None, {}),
+    "a_token_past_a_visit": ([N_TEST * PS + 1, 2 * N_TEST * PS + 1, 0, 17], None, {}),
+    "window_starts_inside_a_visit": ([120, 70, 0, 9], 50, {}),  # 120 - 50 = 70: page 4, the second of its visit
+    "window_starts_on_a_page_edge": ([112, 80, 64, 128], 48, {}),  # 112 - 48 = 64 = 4 pages
+    "window_as_the_operand": ([120, 70, 0, 9], 50, {"operand": True}),
+    "no_window_as_the_operand": ([120, 70, 0, 9], None, {"operand": True}),
+    "bf16_pool": ([37, 0, 128, 100], None, {"dtype": jnp.bfloat16, "atol": 0.0156}),
+    "bf16_pool_windowed": ([120, 70, 0, 9], 50, {"dtype": jnp.bfloat16, "atol": 0.0156}),
+    # Hkv·D = 192: the page DMA fills the valid prefix of a 256-lane buffer (_page_dst)
+    "folded_axis_off_the_lane_grid": ([37, 0, 128, 100], None, {"hkv": 3}),
+    "folded_axis_off_the_lane_grid_windowed": ([120, 70, 0, 9], 50, {"hkv": 3}),
+}
+
+
+@pytest.mark.parametrize("case", list(WALK_CASES))
+def test_decode_kernel_walk_matches_reference(case):
+    lengths, window, opt = WALK_CASES[case]
+    rng = np.random.default_rng(len(case))
+    B, Hkv, G, D = len(lengths), opt.get("hkv", 2), 3, 64
+    dtype = opt.get("dtype", jnp.float32)
+    P = B * MP
+    q = jnp.asarray(rng.normal(size=(B, Hkv * G, D)), dtype)
+    k = jnp.asarray(rng.normal(size=(P, PS, Hkv * D)), dtype)
+    v = jnp.asarray(rng.normal(size=(P, PS, Hkv * D)), dtype)
+    pt = jnp.asarray(rng.permutation(P).reshape(B, MP).astype(np.int32))
+    lens = jnp.asarray(lengths, jnp.int32)
+
+    ref = paged_attention_jax(q, k, v, pt, lens, Hkv, window=window)
+    kw = {"window": window}
+    if opt.get("operand"):
+        kw = {"window_dyn": jnp.int32(window or GLOBAL_WINDOW)}
+    out = paged_attention_tpu(q, k, v, pt, lens, Hkv, interpret=True, pages_per_visit=N_TEST, **kw)
+    atol = opt.get("atol", 1e-5)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref, np.float32), rtol=atol, atol=atol)
+    assert not np.asarray(out, np.float32)[np.asarray(lengths) == 0].any()  # exactly zero
+
+
+@pytest.mark.parametrize("dtype,visits", [(jnp.float32, 3), (jnp.bfloat16, 2)], ids=["float32", "bfloat16"])
+def test_decode_kernel_at_the_layouts_own_pages_per_visit(dtype, visits):
+    """Pages of 128 tokens x 4 KV heads of 128, as the chip serves them: N is
+    the layout's own (3 pages of 256 KB in float32, 7 of 128 KB in bf16), the
+    head loops are the lane-aligned ones, and a context of 8 pages takes three
+    visits or two."""
+    rng = np.random.default_rng(3)
+    B, Hq, Hkv, D, ps, mp = 4, 8, 4, 128, 128, 8
+    n = decode_walk_plan(Hq, Hkv, D, ps, mp, dtype)["pages_per_visit"]
+    assert -(-mp // n) == visits
+    P = B * mp
+    q = jnp.asarray(rng.normal(size=(B, Hq, D)), dtype)
+    k = jnp.asarray(rng.normal(size=(P, ps, Hkv * D)), dtype)
+    v = jnp.asarray(rng.normal(size=(P, ps, Hkv * D)), dtype)
+    pt = jnp.asarray(rng.permutation(P).reshape(B, mp).astype(np.int32))
+    lens = jnp.asarray([1024, 0, n * ps + 1, 300], jnp.int32)
+    atol = 1e-5 if dtype == jnp.float32 else 0.0156
+    for window in (None, 300):
+        ref = paged_attention_jax(q, k, v, pt, lens, Hkv, window=window)
+        out = paged_attention_tpu(q, k, v, pt, lens, Hkv, interpret=True, window=window)
+        np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref, np.float32), rtol=atol, atol=atol)
+        assert not np.asarray(out[1], np.float32).any()
+
+
+def test_decode_walk_plan_follows_the_layout():
+    """N from the page's bytes alone: the serving layouts of the benchmark's
+    configurations (bf16 pool, pages of 128 tokens, 16 pages a row)."""
+    def n(hq, hkv, d=128, page=128, max_pages=16, dtype=jnp.bfloat16):
+        return decode_walk_plan(hq, hkv, d, page, max_pages, dtype)["pages_per_visit"]
+
+    assert n(28, 4) > n(32, 8) >= n(40, 10) >= 1  # Qwen2.5 / the mixture, Mistral, the hybrid
+    assert n(28, 4, max_pages=2) == 2  # never more than a row holds
+    assert n(32, 8, dtype=jnp.float32) <= n(32, 8)
+    assert n(64, 64, page=1024) == 1  # a page too large for the budget is still visited
+
+
+def test_a_done_row_hands_the_decode_kernel_no_context(monkeypatch):
+    """The decode chunk's step hands the paged forward length 0 for a row
+    that is done (ISSUE 37): the kernel passes over it, where a frozen
+    row's stale context cost every step its pages. Live rows keep
+    position + 1."""
+    from inference_gateway_tpu.ops.sampling import chunk_gumbels, chunk_row_keys, effective_top_k
+    from inference_gateway_tpu.serving.engine import STOP_TABLE_WIDTH
+
+    engine = Engine(EngineConfig(model="test-tiny", max_slots=4, max_seq_len=64, dtype="float32",
+                                 use_mesh=False, attention="paged", page_size=16))
+    seen = {}
+    real = engine._model.forward_paged
+
+    def spy(params, cfg, tokens, positions, lengths, *args, **kw):
+        seen["lengths"] = np.asarray(lengths)
+        return real(params, cfg, tokens, positions, lengths, *args, **kw)
+
+    monkeypatch.setattr(engine._model, "forward_paged", spy)
+    S = 4
+    pos = jnp.asarray([5, 40, 0, 17], jnp.int32)
+    done = jnp.asarray([False, True, True, False])
+    k_eff = effective_top_k(engine.config.top_k, engine.model_cfg.vocab_size)
+    keys = chunk_row_keys(jax.random.PRNGKey(0), jnp.zeros((S,), jnp.int32), jnp.zeros((S,), bool), pos, 1)
+    page_table = jnp.asarray(engine.allocator.page_table())
+    engine._chunk_step_ee(
+        engine.params, jnp.int32(0), engine.cache, jnp.ones((S,), jnp.int32), pos, jnp.zeros((S,), jnp.int32),
+        done, jnp.full((S,), 8, jnp.int32), chunk_gumbels(keys, k_eff), k_eff, jnp.zeros((S,), jnp.float32),
+        jnp.ones((S,), jnp.float32), jnp.full((S, STOP_TABLE_WIDTH), -1, jnp.int32),
+        (False, None, None, None, None), (page_table, jnp.full((S,), 64, jnp.int32)))
+    assert seen["lengths"].tolist() == [6, 0, 0, 18]
 
 
 def test_page_allocator():

@@ -164,6 +164,11 @@ async def test_mixed_records_count_the_tokens_whose_rows_take_the_wide_tile(side
     status = (await HTTPClient().get(f"http://127.0.0.1:{port}/debug/status")).json()
     assert status["ragged_attention"] == plan == server.engine.ragged_attention_plan
     assert set(plan) == {"narrow_tile", "wide_tile", "wide_from", "rows_per_pass"}
+    # ISSUE 37: and the decode kernel's page walk for the same layout.
+    walk = status["decode_attention"]
+    assert walk == server.engine.decode_attention_plan
+    assert walk["skips_empty_slots"] and 1 <= walk["pages_per_visit"] <= server.engine.page_cfg.max_pages_per_slot
+    assert walk["operand_dtype"] in ("float32", "bfloat16")
     before = server.timeline.stats()["records"]
     for question in ("what do the tiles of a ragged kernel have to do with a mixed step",
                      "and why would a row of many queries want another tile than a row of one"):
