@@ -16,7 +16,7 @@ Two scopes:
 2. **Submit-path functions** (named, host-side): the functions whose
    contract is "dispatch without waiting" — ``Engine.decode_chunk_submit``
    / ``Engine._scatter_admission`` / ``Engine.mixed_step_submit`` and
-   ``Scheduler._submit_chunk`` / ``Scheduler.run`` /
+   ``Scheduler._submit_chunk`` / ``Scheduler.run`` and its ``_pass`` /
    ``Scheduler._process_handles`` / ``Scheduler._build_mixed_rows``
    (the ISSUE 12 ragged descriptor assembly: building the per-row
    (start, length, kind) arrays must stay pure host bookkeeping — a
@@ -71,7 +71,7 @@ SUBMIT_SCOPES = {
         "structured_register",
     },
     "serving/scheduler.py": {
-        "_submit_chunk", "run", "_process_handles", "_build_mixed_rows",
+        "_submit_chunk", "run", "_pass", "_process_handles", "_build_mixed_rows",
     },
     # The mask scatter/upload path (ISSUE 13): grammar spans and
     # logit-bias rows are loaded into the device tables between steps —

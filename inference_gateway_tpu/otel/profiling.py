@@ -26,7 +26,8 @@ time went* when a number regresses, with four cooperating pieces:
   thread's span recorder (ISSUE 24): a record carries its start on the
   span clock (``span_clock_ns``), its host phases (``phase_span``
   stamps a phase and opens the same-named ``TraceAnnotation``
-  together), and how long the device was starved before it.
+  together), and how long the device was starved before it and by
+  which cause (``CauseCursor``, ISSUE 38).
 - ``SlowRequestLog`` — requests breaching configurable TTFT/TPOT/total
   thresholds get their phase clock, trace id, and the surrounding
   engine-step window captured into a bounded log surfaced in
@@ -92,36 +93,133 @@ def trace_annotation(name: str, **stats: Any):
     return _annotation(name, **stats)
 
 
-class _PhaseSpan:
-    __slots__ = ("_phases", "_key", "_ann", "_t0")
+def perf_ns_of(epoch_ns: int) -> int:
+    """The ``perf_counter_ns`` reading at which the span clock read
+    ``epoch_ns``: ``span_clock_ns`` backwards, for a stamp taken with
+    ``time.time_ns`` (a request's ``phase_ns``)."""
+    return _ANCHOR_PERF_NS + (epoch_ns - _ANCHOR_EPOCH_NS)
 
-    def __init__(self, phases: dict[str, float], key: str, name: str) -> None:
+
+# The idle ledger's closed list of causes (ISSUE 38; docs/observability.md
+# says what code each covers): the last word of every span the scheduler
+# thread opens, the lock's wait, and "loop" for what lies under none.
+IDLE_CAUSES = ("wake", "rows", "prep", "register_slot", "drain", "build", "lock_wait", "pages",
+               "assemble", "upload_dispatch", "deliver", "fetch", "fetch_copy", "emit", "release",
+               "release_slot", "record", "loop")
+
+
+class CauseCursor:
+    """The idle ledger's one primitive (ISSUE 38): which cause the
+    scheduler thread is in, and while the starvation clock runs, how long
+    it has been in each. A cause is the last word of a span's name
+    (``sched.emit`` -> ``emit``); spans nest and the innermost open one is
+    charged; with none open the thread is in ``loop``, the remainder that
+    is reported and never folded into a neighbour. ``start`` opens the
+    ledger at a ``perf_counter_ns`` reading (the fetch's return, or an
+    arrival's ``submit`` stamp: it may lie in the past), ``stop`` charges
+    up to its reading and hands the ledger over, ns by cause: it sums to
+    ``stop - start`` exactly, because every charge runs from the last mark
+    to the next. While the clock does not run a mark moves the stack and
+    charges nothing. Owned by one scheduler and touched by its thread
+    alone; ``phase_span`` and ``cause_span`` find it through
+    ``bind_cursor``."""
+
+    __slots__ = ("_stack", "_by", "_mark")
+
+    def __init__(self) -> None:
+        self._stack: list[str] = []
+        self._by: dict[str, int] | None = None
+        self._mark = 0
+
+    def _charge(self, now: int) -> None:
+        by = self._by
+        if by is not None and now > self._mark:
+            cause = self._stack[-1] if self._stack else "loop"
+            by[cause] = by.get(cause, 0) + now - self._mark
+            self._mark = now
+
+    def enter(self, cause: str, now: int) -> None:
+        self._charge(now)
+        self._stack.append(cause)
+
+    def exit(self, now: int) -> None:
+        self._charge(now)
+        self._stack.pop()
+
+    def start(self, t0: int) -> None:
+        self._by, self._mark = {}, t0
+
+    def stop(self, now: int) -> dict[str, int]:
+        self._charge(now)
+        by, self._by = self._by, None
+        return by or {}
+
+
+_thread = threading.local()  # .cursor: the CauseCursor of the scheduler this thread runs
+
+
+def bind_cursor(cursor: CauseCursor | None) -> None:
+    """Make ``cursor`` the calling thread's (a scheduler's ``run`` binds
+    its own while an observer is attached, None otherwise): from then on
+    every ``phase_span`` / ``cause_span`` / ``stamp_since`` on this thread
+    marks it."""
+    _thread.cursor = cursor
+
+
+class _PhaseSpan:
+    __slots__ = ("_phases", "_key", "_ann", "_t0", "_cursor", "_cause")
+
+    def __init__(self, phases: dict[str, float] | None, key: str | None, name: str) -> None:
         self._phases, self._key, self._ann = phases, key, trace_annotation(name)
+        self._cursor = getattr(_thread, "cursor", None)
+        self._cause = name.rpartition(".")[2]
 
     def __enter__(self) -> None:
         self._ann.__enter__()
         self._t0 = time.perf_counter_ns()
+        if self._cursor is not None:
+            self._cursor.enter(self._cause, self._t0)
 
     def __exit__(self, *exc: Any) -> None:
-        ms = (time.perf_counter_ns() - self._t0) / 1e6
-        self._phases[self._key] = self._phases.get(self._key, 0.0) + ms
+        now = time.perf_counter_ns()
+        if self._phases is not None:
+            self._phases[self._key] = self._phases.get(self._key, 0.0) + (now - self._t0) / 1e6
+        if self._cursor is not None:
+            self._cursor.exit(now)
         self._ann.__exit__(*exc)
 
 
 def stamp_since(phases: dict[str, float] | None, key: str, t0_ns: int) -> None:
     """``phases[key]`` = milliseconds since ``t0_ns`` (a ``perf_counter_ns``
-    reading), for a stretch no ``with`` block can wrap (a lock's wait)."""
+    reading), for a stretch no ``with`` block can wrap (a lock's wait); the
+    thread's cause cursor is charged the same stretch under ``key``."""
     if phases is not None:
-        phases[key] = (time.perf_counter_ns() - t0_ns) / 1e6
+        now = time.perf_counter_ns()
+        phases[key] = (now - t0_ns) / 1e6
+        cursor = getattr(_thread, "cursor", None)
+        if cursor is not None:
+            cursor.enter(key, t0_ns)
+            cursor.exit(now)
 
 
 def phase_span(phases: dict[str, float] | None, key: str, name: str):
     """One phase of a step on the scheduler thread: adds the elapsed
     milliseconds to ``phases[key]`` and wraps the same stretch in a
     ``TraceAnnotation(name)``, so the timeline record and the device trace
-    cannot drift apart. ``phases`` None (no observer attached) stamps
-    nothing and opens nothing."""
+    cannot drift apart; the thread's cause cursor (``bind_cursor``) is
+    marked on the way in and out, under the last word of ``name``.
+    ``phases`` None (no observer attached) stamps nothing and opens
+    nothing."""
     return _NO_SPAN if phases is None else _PhaseSpan(phases, key, name)
+
+
+def cause_span(name: str):
+    """A cause of the idle ledger that is no phase of a record (a finish's
+    release, the instruments' own writes, the wait for an arrival): the
+    same ``TraceAnnotation(name)`` and the same marks on the thread's cause
+    cursor as ``phase_span``, and no stamp. A thread with no cursor bound
+    (no observer attached, or not a scheduler's) opens nothing."""
+    return _NO_SPAN if getattr(_thread, "cursor", None) is None else _PhaseSpan(None, None, name)
 
 
 class CaptureBusyError(RuntimeError):
@@ -533,7 +631,9 @@ class StepTimeline:
         """``spans`` (ISSUE 24) are the scheduler thread's own stamps for
         this step, merged into the record as they are: ``phases`` (ms by
         phase), ``starved_ms`` / ``inflight_at_dispatch`` / ``host_gap_ms``
-        of the step's dispatch, ``context_tokens``, a decode chunk's
+        of the step's dispatch and, where ``starved_ms`` > 0, ``starved_by``
+        (ISSUE 38: the same milliseconds by cause, from the scheduler's
+        ``CauseCursor``), ``context_tokens``, a decode chunk's
         ``submit_ts``, an admission's ``prep``."""
         end_ns = span_clock_ns()
         rec = {

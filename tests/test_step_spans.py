@@ -23,7 +23,7 @@ from inference_gateway_tpu.serving.server import SidecarServer, serve
 TRACE_ID = "0af7651916cd43dd8448eb211c80319c"
 TRACEPARENT = f"00-{TRACE_ID}-b7ad6b7169203331-01"
 MIXED_PHASES = ("build", "lock_wait", "pages", "assemble", "upload_dispatch", "deliver",
-                "fetch_wait", "emit")
+                "fetch_wait", "fetch_copy", "emit")
 STARTUP_KEYS = ("import_s", "params_s", "quantize_s", "kv_pool_s", "structured_s",
                 "observatory_s", "warmup_s", "listen_s", "total_s")
 
@@ -114,10 +114,11 @@ async def test_a_decode_records_fetch_and_emit_add_up_and_its_submit_came_earlie
     decode = [r for r in await _records(server, port) if r["kind"] == "decode"]
     assert decode
     for r in decode:
-        assert {"build", "upload_dispatch", "fetch_wait", "emit"} <= set(r["phases"])
+        assert {"build", "upload_dispatch", "fetch_wait", "fetch_copy", "emit"} <= set(r["phases"])
         assert r["submit_ts"] < r["start_ts"]
-    assert _adds_up([(r["phases"]["fetch_wait"] + r["phases"]["emit"], r["duration_ms"])
-                     for r in decode]), decode
+    # ISSUE 38: the fetch is two stamps, the wait and the copy.
+    assert _adds_up([(r["phases"]["fetch_wait"] + r["phases"]["fetch_copy"] + r["phases"]["emit"],
+                      r["duration_ms"]) for r in decode]), decode
     assert any(r["phases"]["build"] > 0 for r in decode)   # a fresh submit assembles
     assert any(r["phases"]["build"] == 0 for r in decode)  # a chained one does not
 
@@ -258,9 +259,17 @@ async def test_request_stamps_are_ordered_and_the_access_log_gains_two_fields(si
         assert line[key] is not None  # nothing else on the line changed
 
 
-async def test_a_detached_observer_stamps_nothing(sidecar):
+async def test_a_detached_observer_stamps_nothing(sidecar, monkeypatch):
+    """... and opens no annotation (ISSUE 38): with no observer the scheduler
+    thread has no cause cursor bound, so every span on it is the shared no-op."""
+    from inference_gateway_tpu.otel import profiling
+
     server, port, _ = sidecar
     sched, engine = server.scheduler, server.engine
+    opened = []
+    annotation = profiling.trace_annotation
+    monkeypatch.setattr(profiling, "trace_annotation",
+                        lambda name, **stats: (opened.append(name), annotation(name, **stats))[1])
     handles = []
     mixed, chunk = engine.mixed_step_submit, engine.decode_chunk_submit
     engine.mixed_step_submit = lambda *a, **k: (handles.append(mixed(*a, **k)), handles[-1])[1]
@@ -270,10 +279,13 @@ async def test_a_detached_observer_stamps_nothing(sidecar):
     records = timeline.stats()["records"]  # the last stream's tail chunks are in by now
     sched.timeline = sched.accounting = None
     sched._dev_touch = sched._starve_t0 = None
+    await asyncio.sleep(0.3)  # the wait's next tick unbinds the cursor
+    assert "sched.wake" in opened  # the ticks before the observers went
+    del opened[:]
     try:
         await _chat(port)
         await asyncio.sleep(0.1)
-        assert not sched._observing
+        assert not sched._observing and not opened
         assert len(handles) >= 3 and all(h.phases is None for h in handles)
         assert sched._dev_touch is None and sched._starve_t0 is None
         assert timeline.stats()["records"] == records
