@@ -280,7 +280,7 @@ class OpenTelemetry:
         self.wasted_tokens_counter = r.counter(
             "engine.wasted_tokens",
             "Tokens computed but never delivered, by reason "
-            "(spec_rejected/chunk_overrun/disconnected/shed_after_prefill)",
+            "(spec_rejected/chunk_overrun/overshoot/disconnected/shed_after_prefill)",
             ("gen_ai_request_model", "reason"), unit="{token}",
         )
         # Serving-path fault tolerance (ISSUE 7): KV-pressure preemption,

@@ -88,6 +88,9 @@ WASTE_SPEC_REJECTED = "spec_rejected"  # verify-forward positions the target ref
 WASTE_CHUNK_OVERRUN = "chunk_overrun"  # decoded past a finish inside a fused chunk
 WASTE_DISCONNECTED = "disconnected"  # decoded for a client that already hung up
 WASTE_SHED_AFTER_PREFILL = "shed_after_prefill"  # prefilled, then failed/shed
+# One decode row of the mixed step dispatched behind the step whose token,
+# seen a step later, ended the stream (EOS, a stop id, a client gone; ISSUE 40).
+WASTE_OVERSHOOT = "overshoot"
 
 
 def detect_tpu() -> bool:

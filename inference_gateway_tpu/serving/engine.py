@@ -244,6 +244,10 @@ class MixedRow:
     # Grammar-constrained rows (ISSUE 13): the slot's GLOBAL automaton
     # state in the device mask tables; 0 = the free (unconstrained) row.
     mask_state: int = 0
+    # The row's one token is what the mixed step dispatched last sampled
+    # for this slot, still on the device (ISSUE 40): ``token_ids`` holds a
+    # placeholder and the program reads the token from that step's block.
+    from_block: bool = False
 
 
 @dataclass
@@ -850,10 +854,14 @@ class Engine:
             ("seeds", (S,), "i32", 0),
             ("use_seed", (S,), "bool", 0),
             ("mstates", (S,), "i32", 0),
+            # Rows whose one token is still on the device (ISSUE 40): it is
+            # read from the step before's result block, not from ``tokens``.
+            ("from_block", (S,), "bool", 0),
         ]) if self.paged else None
         self._fresh_pack = PackedLayout([
             ("counter", (1,), "i32", 0),
             ("tokens", (S,), "i32", 0),
+            ("from_block", (S,), "bool", 0),
             ("positions", (S,), "i32", 0),
             ("done", (S,), "bool", 1),
             # Effectively unbounded: the host max_tokens check remains the
@@ -887,6 +895,12 @@ class Engine:
             # Segments that started at position 0, so from a zeroed state:
             # every admission, and every resume after a preemption.
             self.metrics["state_resets"] = 0
+        # The result block of the mixed step dispatched last (ISSUE 40): both
+        # host-stepped programs take it as an operand, every call, and read
+        # from it the token of each row flagged ``from_block``, so they can be
+        # dispatched before that step is fetched. Zeros until a step has run.
+        counted = len(self._mixed_stats) if self.is_moe or self.is_hybrid else 0
+        self._last_block = jnp.zeros((2 + counted, S), jnp.float32)
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -1267,7 +1281,7 @@ class Engine:
             n_steps)
 
     @partial(jax.jit, static_argnames=("self",))
-    def _fresh_unpack_fn(self, packed, base_rng):
+    def _fresh_unpack_fn(self, packed, base_rng, block):
         """Fresh entry into the early-exit chunk, dense or paged (ISSUE
         25): the host's whole decode state rides ONE packed int32 buffer
         (``_fresh_pack``); this small program slices it apart and folds
@@ -1279,10 +1293,17 @@ class Engine:
         program, and the chained submit's own dispatch. (A fresh-entry
         variant of the chunk program would save this 0.3 ms dispatch and
         cost every start two more 7B executables to load, 2.5 s:
-        PERF.md §6, PR 25.)"""
+        PERF.md §6, PR 25.) ``block`` (ISSUE 40) is the result block of the
+        mixed step dispatched last: a row flagged ``from_block`` takes its
+        pending token from there, so this submit need not wait for that
+        step's fetch; such a row whose token is one of its stop ids starts
+        done, where the host will finish it once it has seen the token."""
         d = self._fresh_pack.unpack(packed)
         rng = jax.random.fold_in(base_rng, d["counter"][0])
-        carry = (d["tokens"], d["positions"], d["mstates"], d["done"], d["budgets"], rng)
+        tokens = jnp.where(d["from_block"], block[0].astype(jnp.int32), d["tokens"])
+        stopped = jnp.any(d["stop_table"] == tokens[:, None], axis=-1)
+        done = d["done"] | (d["from_block"] & stopped)
+        carry = (tokens, d["positions"], d["mstates"], done, d["budgets"], rng)
         sampling = (d["temps"], d["top_ps"], d["seeds"], d["use_seed"], d["stop_table"])
         tables = (d["page_table"], d["reserved"]) if self.paged else None
         return carry, sampling, tables
@@ -1343,17 +1364,24 @@ class Engine:
         return toks, logprobs, cache
 
     @partial(jax.jit, static_argnames=("self", "masked"), donate_argnums=(2,))
-    def _mixed_step_fn(self, params, cache, packed, base_rng,
+    def _mixed_step_fn(self, params, cache, packed, base_rng, block,
                        mnext=None, mbits=None, mbias=None, masked=False):
         """The mixed step as the submit dispatches it (ISSUE 25): every
         descriptor rides ONE packed int32 buffer (``_mixed_pack``), the
         step key is folded from the engine's base key and the counter in
         that buffer, and the (2, S) tokens-atop-logprobs block the fetch
-        reads is built here — one upload and one dispatch a submit."""
+        reads is built here — one upload and one dispatch a submit.
+        ``block`` (ISSUE 40) is the block the step before returned: a row
+        flagged ``from_block`` has one query, and its token is that block's
+        for its slot (a token id is exact in float32 below 2**24), so the
+        host builds and dispatches this step before it has fetched that
+        one. Nothing else of a row depends on the token."""
         d = self._mixed_pack.unpack(packed)
         rng = jax.random.fold_in(base_rng, d["counter"][0])
+        at = jnp.where(d["from_block"], d["q_starts"], d["tokens"].shape[1])  # unflagged: dropped
+        tokens = d["tokens"].at[0, at].set(block[0].astype(jnp.int32), mode="drop")
         toks, logprobs, cache, *stats = self._mixed_step_body(
-            params, cache, d["tokens"], d["positions"], d["write_idx"], d["page_table"],
+            params, cache, tokens, d["positions"], d["write_idx"], d["page_table"],
             d["q_starts"], d["q_lens"], d["kv_lens"], d["temps"], d["top_ps"],
             d["seeds"], d["use_seed"], rng, mstates=d["mstates"], mnext=mnext,
             mbits=mbits, mbias=mbias, masked=masked)
@@ -1390,7 +1418,10 @@ class Engine:
         and ONE jitted program computes every row and samples one token
         per row. The chained decode carry is invalidated — mixed steps
         advance cache positions outside the chain, so the next fused
-        chunk must resubmit from host state (chain=False).
+        chunk must resubmit from host state (chain=False). A row flagged
+        ``from_block`` (ISSUE 40) reads its one token from the block of
+        the mixed step dispatched before this one, which need not have
+        been fetched; this step's block takes its place for the next.
 
         ``phases`` (ISSUE 24): a dict the caller wants the submit's host
         phases stamped into (lock_wait, pages, assemble, upload_dispatch,
@@ -1435,6 +1466,9 @@ class Engine:
                         seeds[r.slot] = int(r.seed)
                         use_seed[r.slot] = True
                     mstates[r.slot] = r.mask_state
+                    if r.from_block:
+                        assert n == 1, (r.slot, n)
+                        v["from_block"][r.slot] = True
                     off += n
                     if r.kind == "prefill":
                         n_prefill += n
@@ -1445,8 +1479,9 @@ class Engine:
                 v["counter"][0] = self._bump_step()
                 staged = pack.snapshot()
                 both, self.cache = self._mixed_step_fn(
-                    self.params, self.cache, jnp.asarray(staged), self._rng,
+                    self.params, self.cache, jnp.asarray(staged), self._rng, self._last_block,
                     mnext=mnext, mbits=mbits, mbias=mbias, masked=masked)
+                self._last_block = both
             # Positions moved outside the chained-carry bookkeeping.
             self._dev_carry = None
             n_decode_tokens = total - n_prefill
@@ -2085,7 +2120,8 @@ class Engine:
         return _DecodeChunkHandle(both, n, stats=self._state_stats(n_active))
 
     def _fresh_submit_ee_locked(self, tokens, positions, active, temps, top_ps,
-                                n, seeds, use_seed, mstates, stop_tables, budgets):
+                                n, seeds, use_seed, mstates, stop_tables, budgets,
+                                from_block=None):
         """chain=False: host state is authoritative — pack it all into
         ONE buffer (ISSUE 25), upload that (first chunk, failure
         recovery), arm the on-device stop criteria, and (re)build the
@@ -2100,6 +2136,8 @@ class Engine:
         pack = self._fresh_pack
         v = pack.begin()
         v["tokens"][:] = tokens
+        if from_block is not None:  # the fill takes every token from the host
+            v["from_block"][:] = from_block
         v["positions"][:] = positions
         v["done"][:] = ~active
         if budgets is not None:  # the fill is the effectively-unbounded budget
@@ -2127,7 +2165,7 @@ class Engine:
         v["counter"][0] = self._bump_step()
         staged = pack.snapshot()
         self._dev_carry, self._dev_sampling, tables = self._fresh_unpack_fn(
-            jnp.asarray(staged), self._rng)
+            jnp.asarray(staged), self._rng, self._last_block)
         if self.paged:
             self._dev_page_table, self._dev_reserved = tables
         handle = self._dispatch_chunk_ee_locked(n)
@@ -2141,7 +2179,8 @@ class Engine:
                             mstates: np.ndarray | None = None,
                             stop_tables: np.ndarray | None = None,
                             budgets: np.ndarray | None = None,
-                            phases: dict | None = None):
+                            phases: dict | None = None,
+                            from_block: np.ndarray | None = None):
         """Dispatch ``n_steps`` fused decode steps WITHOUT waiting.
 
         JAX dispatch is asynchronous — the returned handle's arrays are
@@ -2155,7 +2194,10 @@ class Engine:
         the on-device stop criteria (None = EOS-only tables and an
         effectively-unbounded budget — the host finish checks remain
         the backstop either way); ``seeds`` with ``use_seed`` seed rows
-        (None = none seeded).
+        (None = none seeded). ``from_block`` (S,) flags the rows whose
+        pending token is what the mixed step dispatched last sampled for
+        their slot and the host has not fetched (ISSUE 40): ``tokens`` is
+        ignored there and the token read on the device.
         chain=True: the previous chunk's device-resident final carry is
         the input — no host upload, no sync. Every array argument is
         ignored (the carry, sampling params, stop state, and page-table
@@ -2182,7 +2224,7 @@ class Engine:
                 else:
                     handle = self._fresh_submit_ee_locked(
                         tokens, positions, active, temps, top_ps, n, seeds,
-                        use_seed, mstates, stop_tables, budgets)
+                        use_seed, mstates, stop_tables, budgets, from_block)
         handle.phases = phases
         return handle
 
@@ -2665,9 +2707,13 @@ class Engine:
         prefix_cache, self.prefix_cache = self.prefix_cache, None
         try:
             if self.mixed_ok:
-                self.mixed_step_fetch(self.mixed_step_submit([MixedRow(
-                    slot=0, token_ids=[1, 2, 3], start=0, kind="prefill")]))
-                self.release_slot(0)
+                # Twice: the second call's block operand is the first's
+                # result, as every later step's is (under a mesh its
+                # sharding may give that call an executable of its own).
+                for _ in range(2):
+                    self.mixed_step_fetch(self.mixed_step_submit([MixedRow(
+                        slot=0, token_ids=[1, 2, 3], start=0, kind="prefill")]))
+                    self.release_slot(0)
             self.decode_chunk_fetch(self.decode_chunk_submit(*idle))
             if not self.mixed_ok:
                 # One prompt per bucket, each a token longer than the

@@ -22,6 +22,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from inference_gateway_tpu.otel.perf_accounting import WASTE_OVERSHOOT
 from inference_gateway_tpu.otel.profiling import (
     CauseCursor, bind_cursor, cause_span, perf_ns_of, phase_span, span_clock_ns)
 from inference_gateway_tpu.serving.engine import Engine, STOP_TABLE_WIDTH, build_stop_row
@@ -189,6 +190,40 @@ class _Inflight:
 
 
 @dataclass
+class _MixedInflight:
+    """A mixed step dispatched and not yet fetched (ISSUE 40). At most one
+    exists: the program behind it (the next mixed step, or the fresh decode
+    chunk that ends the train) is dispatched first, reading on the device
+    the tokens this step sampled, and then this step is fetched and
+    processed while that program runs.
+
+    ``states`` holds the _SlotState OBJECTS of the rows that sample a token
+    for the host to emit, decode rows and the rows whose prompt ends here
+    (same identity contract as ``_Inflight``): until the step is processed
+    their ``pos`` / ``generated`` / ``pending_token`` are one token behind
+    the device (``Scheduler._ahead``). ``first`` are the rows whose prompt
+    ends here, registered in ``_slots`` at dispatch with ``_TOKEN_PENDING``.
+    ``retired`` are decode rows whose token is their request's last by its
+    budget: their slot went back at dispatch, their state waits in
+    ``Scheduler._retiring`` for that token."""
+
+    handle: object
+    rows: list
+    n_decode: int
+    states: dict
+    first: list
+    t0: float
+    counts: tuple  # (prefill tokens, context tokens, pair tokens) of the record
+    retired: dict = field(default_factory=dict)
+    # The record's stamps; None / empty with no observer at the dispatch.
+    phases: dict | None = None
+    spans: dict = field(default_factory=dict)
+    # Dispatched behind an unfetched step (its record then runs from its
+    # fetch, with the dispatch as ``submit_ts``, like a decode chunk's).
+    behind: bool = False
+
+
+@dataclass
 class _PendingPrefill:
     """A submitted-but-unfetched admission batch: the engine prefill
     handle plus the (request, slot) pairs awaiting their first token."""
@@ -346,6 +381,20 @@ class Scheduler:
         self._deferred: list[GenRequest] = []
         self.flushes_deferred = 0   # running totals, /debug/status
         self.flushes_immediate = 0
+        # One step ahead of the host (ISSUE 40): the mixed step dispatched
+        # and not yet fetched. It outlives _admit_mixed's loop and the call
+        # (a closed loop's train is mostly one mixed step a pass, pass after
+        # pass); whatever needs the host's state whole settles it first
+        # (_settle_mixed). _retiring: states whose LAST token that step
+        # holds and whose slot already went back (written on the scheduler
+        # thread only; abort_all reads it, as it reads _admitting).
+        self._mixed_inflight: _MixedInflight | None = None
+        self._retiring: list[_SlotState] = []
+        # Mixed steps and fresh decode chunks by where their rows' tokens
+        # came from (/debug/status): any from an unfetched block, or all
+        # from the host.
+        self.pipelined_submits = 0
+        self.host_stepped_submits = 0
 
     def active_requests(self) -> int:
         return len(self._slots)
@@ -428,7 +477,7 @@ class Scheduler:
             for req in list(self._admitting):
                 self._fail_request(req)
                 failed += 1
-            for st in list(self._slots.values()):
+            for st in list(self._slots.values()) + list(self._retiring):
                 self._fail_request(st.req)
                 failed += 1
         # The wedged thread may never reach its next dispatch.
@@ -502,11 +551,20 @@ class Scheduler:
         always processed after their prefill, so host bookkeeping sees
         first tokens in order. Only failure recovery (device carry
         invalidated) drains the queue and resubmits from host state.
+
+        On a mixed-step engine an admission is a TRAIN of mixed steps
+        (_admit_mixed) that runs one step ahead of the host (ISSUE 40):
+        each step, and the fresh decode chunk that ends the train, is
+        dispatched before the step in front of it is fetched and reads
+        that step's tokens on the device; the host processes step N while
+        step N+1 runs. What the train still waits for is the drain before
+        its first step (the chunks in flight) and that first step's own
+        build.
         """
         while True:
             with self._wake:
                 while (not self._stop and not self._waiting and not self._slots
-                       and not self._handles):
+                       and not self._handles and self._mixed_inflight is None):
                     self._wait_for_work()
                 if self._stop:
                     break
@@ -514,6 +572,8 @@ class Scheduler:
             with cause_span("sched.loop"):
                 self._pass()
         bind_cursor(None)
+        if not self._aborted:
+            self._settle_mixed()
         self._deliver_deferred()  # stopped
 
     def _wait_for_work(self) -> None:
@@ -585,14 +645,26 @@ class Scheduler:
                 self.logger.error("scheduler admission error", e)
         if self._slots:
             chain = self.engine._dev_carry is not None
-            if not chain:
+            if not chain and self._mixed_inflight is None:
                 # First chunk ever, or recovery after a device
                 # failure: host state must be authoritative, so
-                # process every outstanding handle first.
+                # process every outstanding handle first. (Behind an
+                # admission's train nothing is outstanding but its last
+                # mixed step, whose tokens the chunk reads on the device.)
                 self._drain_all()
             h = self._submit_chunk(chain=chain)
             if h is not None:
                 self._handles.append(h)
+            if h is not None or not self._admission_due():
+                # The chunk is behind it on the device (or no program
+                # follows): fetch and process the train's last step. With
+                # an admission due it stays, and the next pass's first
+                # mixed step is dispatched behind it.
+                self._settle_mixed()
+            if h is not None and not chain:
+                # Behind the fresh chunk: what waited for a dispatch, and
+                # the rows of the step just processed.
+                self._deliver_deferred(h.submit, getattr(h.handle, "phases", None))
         else:
             # No active request: any leftover tail chunks carry only
             # already-finished streams — drain them now, or the loop
@@ -629,6 +701,7 @@ class Scheduler:
             self._process_one(self._handles.popleft())
 
     def _drain_all(self) -> None:
+        self._settle_mixed()  # older than any chunk: one is dispatched behind it at most
         while self._handles:
             self._process_one(self._handles.popleft())
 
@@ -670,7 +743,7 @@ class Scheduler:
         wherever a pass ends without one."""
         todo, self._deferred = self._deferred, []
         if spans is not None:
-            spans["deferred_flushes"] = len(todo)
+            spans["deferred_flushes"] = spans.get("deferred_flushes", 0) + len(todo)
         with phase_span(phases, "deliver", "sched.deliver"):
             for req in todo:
                 try:
@@ -824,6 +897,7 @@ class Scheduler:
         if (not self._waiting or not self._slots
                 or self.engine.kv_utilization() < self.preempt_high_water):
             return
+        self._settle_mixed()  # a victim resumes from what the host has seen
         victim = self._pick_victim()
         if victim is not None:
             self._preempt(victim, "high_water")
@@ -864,6 +938,7 @@ class Scheduler:
         tokens). Speculative mode admits synchronously — spec rounds
         need the first token host-side for the draft catch-up block.
         """
+        self._settle_mixed()  # the bucketed path is host-stepped
         batch: list[GenRequest] = []
         slots: list[int] = []
         with self._wake:
@@ -993,13 +1068,55 @@ class Scheduler:
                               spans={**(p.submit or {}), "phases": phases})
 
     # -- ragged mixed-step admission (ISSUE 12) ------------------------
+    def _ahead(self, slot: int, st: _SlotState) -> int | None:
+        """Where the slot's pending token is. None: on the host
+        (``st.pending_token``). Else it is what the unfetched mixed step
+        samples for the slot, still on the device, and the value is how
+        far ``st.pos`` and ``st.generated`` lie behind it until that step
+        is processed: 1 for a decode row of that step, 0 for a row whose
+        prompt ends there (registered with the first token counted and
+        ``_TOKEN_PENDING`` in its place)."""
+        inf = self._mixed_inflight
+        if inf is None or inf.states.get(slot) is not st:
+            return None
+        return 0 if st.pending_token == _TOKEN_PENDING else 1
+
+    def _last_by_budget(self, req: GenRequest, pos: int, generated: int) -> bool:
+        """The finish the host can foresee (ISSUE 40): with the cache row at
+        ``pos`` and ``generated`` tokens out, counting a token still on the
+        device, that token is the request's last by ``max_tokens`` or by
+        the context's end, whatever it is. The same two tests as _emit's."""
+        return generated >= req.max_tokens or pos + 1 >= self.engine.config.max_seq_len
+
+    def _count_submit(self, from_device: bool) -> str:
+        """One mixed step or fresh decode chunk dispatched: counted by where
+        its rows' tokens came from, which is its record's ``tokens_from``."""
+        if from_device:
+            self.pipelined_submits += 1
+            return "device"
+        self.host_stepped_submits += 1
+        return "host"
+
+    def _runs_ahead(self, inf: _MixedInflight) -> bool:
+        """Whether the next program may be dispatched before this mixed
+        step is fetched. Not while one of the rows it samples for holds a
+        grammar: the automaton's state after this step's token is computed
+        on the host at emit, and the next program's mask row needs it (the
+        state on the device is a follow-up, PERF.md section 7). A branch on
+        what the step holds, nothing a user sets."""
+        return all(st.req.grammar is None for st in inf.states.values())
+
     def _build_mixed_rows(self, pending: list) -> tuple[list, int, int, int, int]:
         """Descriptor assembly for ONE mixed engine step: a decode row
         per active slot (its pending token advances one position), then
         prefill-chunk rows for the admitting requests, filling whatever
         packed budget remains. Pure host bookkeeping — no device reads
         (graftlint jax-hot-path pins this: a sync here would serialize
-        the step against the previous one's results).
+        the step against the previous one's results). A slot whose
+        pending token the unfetched mixed step holds (_ahead) gets a row
+        that reads it on the device (``from_block``), at the position and
+        count the slot will have once that step is processed; if that
+        token is the request's last by its budget the slot has no row.
 
         Returns (rows, n_decode, prefill_tokens, context_tokens,
         pair_tokens) — the latter two feed the mixed StepCostModel kind:
@@ -1014,16 +1131,21 @@ class Scheduler:
         context = 0
         pairs = 0
         for slot, st in self._slots.items():
-            if st.pending_token == _TOKEN_PENDING:
-                continue  # unresolved prefill future (handles were drained; defensive)
+            lag = self._ahead(slot, st)
+            if lag is None and st.pending_token == _TOKEN_PENDING:
+                continue  # a bucketed admission's prefill future (its handle was drained; defensive)
             req = st.req
+            pos = st.pos + (lag or 0)
+            if lag is not None and self._last_by_budget(req, pos, st.generated + lag):
+                continue
             rows.append(MixedRow(
-                slot=slot, token_ids=[st.pending_token], start=st.pos, kind="decode",
-                temp=req.temperature, top_p=req.top_p, seed=req.seed,
-                mask_state=req.grammar.global_state if req.grammar is not None else 0))
+                slot=slot, token_ids=[0 if lag is not None else st.pending_token], start=pos,
+                kind="decode", temp=req.temperature, top_p=req.top_p, seed=req.seed,
+                mask_state=req.grammar.global_state if req.grammar is not None else 0,
+                from_block=lag is not None))
             used += 1
-            context += st.pos + 1
-            pairs += st.pos + 1
+            context += pos + 1
+            pairs += pos + 1
         n_decode = len(rows)
         for item in pending:
             req, slot = item["req"], item["slot"]
@@ -1047,7 +1169,9 @@ class Scheduler:
         """Unrecoverable mixed-step failure: fail the admitting requests
         cleanly, then attribute the step failure to the active batch as
         usual — but an error tagged to an ADMITTING slot was just failed
-        here and must not nuke the active batch too."""
+        here and must not nuke the active batch too. A request whose
+        prompt's last chunk was dispatched is an active slot already."""
+        pending = [it for it in pending if not it.get("registered")]
         admitting_slots = {it["slot"] for it in pending}
         for item in pending:
             self._fail_request(item["req"])
@@ -1066,12 +1190,19 @@ class Scheduler:
         blocking), and every step is ONE launch of the one compiled
         mixed program (no bucket padding).
 
-        Runs synchronously on the scheduler thread: the chunk loop is
-        bounded by ceil(Σ prompt / free budget) steps, decode tokens
-        stream out at every step, and when the last chunk of a prompt
-        lands its sampled first token the request becomes a regular
-        active slot. The fused-chunk pipeline resumes afterwards
-        (chain=False — mixed steps invalidate the device carry).
+        The chunk loop runs on the scheduler thread, bounded by
+        ceil(Σ prompt / free budget) steps, ONE STEP AHEAD of the host
+        (ISSUE 40): build step N+1 → dispatch it → deliver what was
+        deferred → fetch step N → emit N (finishes, first tokens, slot
+        release, flushes) → build N+2. The step dispatched last stays
+        unfetched when the loop ends, and across calls: the next call's
+        first step, or run()'s fresh decode chunk, goes behind it
+        (_mixed_step, _submit_chunk). Decode tokens stream out at every
+        step, one step late; when the last chunk of a prompt is
+        dispatched the request becomes a regular active slot whose first
+        token is on its way. The device carry stays invalidated by every
+        mixed step, so the chunk behind the train is a fresh submit, and
+        the chunks in flight are drained before the train's first step.
         Requests the ragged program can't serve (multimodal embedding
         overrides) fall back to the bucketed admission path wholesale.
         """
@@ -1093,6 +1224,7 @@ class Scheduler:
                 slots.append(self._free.pop())
             self.queue_depth = len(self._waiting)
         if not batch:
+            self._settle_mixed()
             self._deliver_deferred()  # what the pass before carried in: no step follows
             if multimodal_head:
                 return self._admit()  # bucketed path carries the embeds
@@ -1137,13 +1269,16 @@ class Scheduler:
             self._admitting = batch
             if not batch:
                 self._admitting = []
+                self._settle_mixed()
                 self._deliver_deferred()
                 return
             # Host state must be authoritative before positions move under
             # the pipeline's feet — and the carry is about to be invalidated.
             # A chunk with another behind it delivers as it always did;
             # the last one leaves the device empty, so its delivery waits
-            # for the mixed step's dispatch.
+            # for the mixed step's dispatch. (Behind a train's unfetched
+            # step there is no chunk: one is dispatched only as that step
+            # is settled.)
             with phase_span(prep, "drain_ms", "sched.admit.drain"):
                 while self._handles:
                     h = self._handles.popleft()
@@ -1177,122 +1312,228 @@ class Scheduler:
                 pending = kept
                 if not pending:
                     break
-                observing = self._observing
-                t0 = time.perf_counter() if observing else 0.0
-                phases: dict | None = {} if observing else None
-                spans: dict = {}
-                states = dict(self._slots)  # identity snapshot at build time
-                with phase_span(phases, "build", "sched.mixed.build"):
-                    rows, n_decode, n_prefill, context, pairs = self._build_mixed_rows(pending)
-                try:
-                    t_submit = time.perf_counter_ns() if observing else 0
-                    handle = self.engine.mixed_step_submit(rows, phases=phases)
-                    if observing:
-                        spans = self._stamp_dispatch("mixed", t_submit)
-                    self._deliver_deferred(spans, phases)
-                    toks, logprobs = self.engine.mixed_step_fetch(handle)
-                    if observing:
-                        self._stamp_fetch()
-                except OutOfPagesError as e:
-                    if (self.preempt_max and getattr(e, "recoverable", True)
-                            and self._slots):
-                        # Same ISSUE 7 semantics as bucketed admission:
-                        # transient pressure REQUEUES the still-admitting
-                        # requests (head of queue, page-wait latch) — and
-                        # when the starved span belongs to an ACTIVE
-                        # decode row, the preemption path may deschedule
-                        # the youngest instead of failing anyone.
-                        self._requeue_admission(
-                            [it["req"] for it in pending],
-                            [it["slot"] for it in pending])
-                        tag = getattr(e, "slot", None)
-                        pending = []
-                        if tag is not None and tag in self._slots:
-                            self._fail_after_decode_error(e)
-                        return
-                    self._fail_mixed_admission(pending, e)
-                    pending = []
-                    return
-                except Exception as e:
-                    self._fail_mixed_admission(pending, e)
-                    pending = []
-                    return
-                self.last_step_time = self.clock.now()
-                self.steps_completed += 1
+                if not self._mixed_step(pending, prep):
+                    return  # requeued or failed, by the step's own handlers
+                prep = None  # the admission's first record carried it
                 stepped = True
-                emitted = 0
-                with phase_span(phases, "emit", "sched.emit"):
-                    # Decode rows advance exactly one token, same emission
-                    # contract as one step of a fused chunk. Nothing is in
-                    # flight and a dispatch follows (the next mixed step or
-                    # run()'s fresh chunk): a row that goes on decoding
-                    # delivers behind it. Finished rows and first tokens
-                    # leave at once.
-                    for row in rows[:n_decode]:
-                        st = self._slots.get(row.slot)
-                        if st is None or st is not states.get(row.slot):
-                            continue  # released mid-step (defensive identity check)
-                        st.pos += 1
-                        st.pending_token = int(toks[row.slot])
-                        st.pending_logprob = float(logprobs[row.slot])
-                        st.generated += 1
-                        emitted += 1
-                        finished, reason = self._emit(st, st.pending_token, st.pending_logprob)
-                        if finished:
-                            self._finish(row.slot, st.req, reason)
-                        else:
-                            self._flush_emits(st.req, defer=True)
-                    # Prefill rows whose final chunk just landed become
-                    # active slots with their sampled first token.
-                    done_items = [it for it in pending
-                                  if it["done"] >= len(it["req"].prompt_ids)]
-                    pending = [it for it in pending
-                               if it["done"] < len(it["req"].prompt_ids)]
-                    for item in done_items:
-                        req, slot = item["req"], item["slot"]
-                        self.engine.metrics["prefill_batches"] += 1
-                        st = _SlotState(
-                            req, pos=len(req.prompt_ids),
-                            pending_token=int(toks[slot]),
-                            pending_logprob=float(logprobs[slot]),
-                            generated=req.resume_generated + 1,
-                            seq=next(self._admit_seq))
-                        self._slots[slot] = st
-                        if self.engine.prefix_cache is not None:
-                            with self.engine._lock:
-                                self.engine.prefix_cache.insert(
-                                    req.prompt_ids, self.engine.allocator.pages_of(slot))
-                        emitted += 1
-                        finished, reason = self._emit(st, st.pending_token, st.pending_logprob)
-                        if finished:
-                            self._finish(slot, req, reason)
-                        else:
-                            self._flush_emits(req)
-                if observing:
-                    spans["phases"] = phases
-                    spans["h2d_arrays"] = handle.h2d_arrays
-                    spans.update(handle.stats or {})  # a mixture's expert counts
-                    plan = getattr(self.engine, "ragged_attention_plan", None)
-                    if plan is not None:
-                        # Tokens of the rows the ragged kernel walks in wide
-                        # tiles (ISSUE 34), beside work_tokens: known from
-                        # the rows packed, no device read.
-                        spans["attn_wide_tokens"] = sum(
-                            len(row.token_ids) for row in rows[n_decode:]
-                            if len(row.token_ids) >= plan["wide_from"])
-                    if prep is not None:  # the admission's first record carries it
-                        spans["prep"] = {k: round(v, 4) for k, v in prep.items()}
-                        prep = None
-                    self._record_step(
-                        "mixed", t0, n_steps=1, batch=len(rows),
-                        tokens=emitted, work_tokens=n_decode + n_prefill,
-                        context_tokens=context, pair_tokens=pairs, spans=spans)
+                pending = [it for it in pending if not it.get("registered")]
             dispatch_follows = stepped and bool(self._slots)
         finally:
             self._admitting = []
             if not dispatch_follows:
                 # Requeued, failed, no step at all, or nobody left decoding.
+                self._settle_mixed()
                 self._deliver_deferred()
+
+    def _mixed_step(self, pending: list, prep: dict | None) -> bool:
+        """One step of an admission's train: build it, dispatch it BEHIND
+        the unfetched step if there is one (its decode rows take their
+        token from that step's block on the device), deliver what was
+        deferred, then fetch and process that older step while this one
+        runs. This step stays unfetched in its turn unless it cannot be
+        run ahead of (_runs_ahead). Any failure at the submit with the
+        older step unfetched settles that step first and builds this one
+        again from what the host then has, as a host-stepped train built
+        it (what the older step's emit releases may be what the submit
+        lacked); a failure with nothing unfetched takes the handlers it
+        always took. False: the admission is over (requeued or failed)."""
+        observing = self._observing
+        t0 = time.perf_counter() if observing else 0.0
+        t_build = time.perf_counter_ns() if observing else 0
+        phases: dict | None = {} if observing else None
+        chunked = [it["done"] for it in pending]
+        with phase_span(phases, "build", "sched.mixed.build"):
+            rows, n_decode, n_prefill, context, pairs = self._build_mixed_rows(pending)
+        prev = self._mixed_inflight
+        try:
+            t_submit = time.perf_counter_ns() if observing else 0
+            handle = self.engine.mixed_step_submit(rows, phases=phases)
+        except Exception as e:
+            if prev is not None:
+                for item, done in zip(pending, chunked):
+                    item["done"] = done
+                if self._settle_mixed():
+                    return self._mixed_step(pending, prep)
+                e = RuntimeError("mixed step in flight failed")  # its slots are failed already
+            if (isinstance(e, OutOfPagesError) and self.preempt_max
+                    and getattr(e, "recoverable", True) and self._slots):
+                # Same ISSUE 7 semantics as bucketed admission:
+                # transient pressure REQUEUES the still-admitting
+                # requests (head of queue, page-wait latch) — and
+                # when the starved span belongs to an ACTIVE
+                # decode row, the preemption path may deschedule
+                # the youngest instead of failing anyone.
+                self._requeue_admission(
+                    [it["req"] for it in pending],
+                    [it["slot"] for it in pending])
+                tag = getattr(e, "slot", None)
+                if tag is not None and tag in self._slots:
+                    self._fail_after_decode_error(e)
+                return False
+            self._fail_mixed_admission(pending, e)
+            return False
+        tokens_from = self._count_submit(any(r.from_block for r in rows[:n_decode]))
+        spans: dict = {}
+        if observing:
+            spans = self._stamp_dispatch("mixed", t_submit, t_build)
+            spans["tokens_from"] = tokens_from
+            if prep is not None:
+                spans["prep"] = {k: round(v, 4) for k, v in prep.items()}
+        # The rows this step samples a token for, by identity. A prompt
+        # whose last chunk this is becomes an active slot now, its first
+        # token on the way (abort_all finds it in _slots from here on).
+        states = {row.slot: self._slots[row.slot] for row in rows[:n_decode]}
+        first = []
+        for item in pending:
+            req, slot = item["req"], item["slot"]
+            if item["done"] >= len(req.prompt_ids):
+                st = _SlotState(req, pos=len(req.prompt_ids), pending_token=_TOKEN_PENDING,
+                                pending_logprob=0.0, generated=req.resume_generated + 1,
+                                seq=next(self._admit_seq))
+                self._slots[slot] = states[slot] = st
+                first.append((req, slot, st))
+                item["registered"] = True
+        step = _MixedInflight(handle, rows, n_decode, states, first, t0, (n_prefill, context, pairs),
+                              phases=phases, spans=spans, behind=prev is not None)
+        self._mixed_inflight = step
+        if prev is not None and not self._process_mixed(prev):
+            self._fail_mixed_admission(pending, RuntimeError("mixed step in flight failed"))
+            return False
+        # Behind this dispatch: what waited for one (the chunk an admission
+        # drained), and the rows of the older step that go on decoding.
+        self._deliver_deferred(spans if observing else None, phases)
+        if self._runs_ahead(step):
+            self._retire_foreseen(step)
+        elif not self._settle_mixed():
+            self._fail_mixed_admission(pending, RuntimeError("mixed step failed"))
+            return False
+        return True
+
+    def _retire_foreseen(self, step: _MixedInflight) -> None:
+        """The finishes the host can foresee, decided when the step is
+        dispatched (ISSUE 40): a decode row whose token from this step is
+        its request's last by the budget. Its slot, pages and bias row go
+        back NOW, beside the step in flight, so the next step's own
+        admission can take the slot (the device runs the programs in
+        order: the new occupant's chunk is written after this step has
+        read the old one's pages); the state waits in _retiring for its
+        last token, delivered by identity when the step is processed."""
+        for row in step.rows[:step.n_decode]:
+            st = step.states[row.slot]
+            if (self._slots.get(row.slot) is st
+                    and self._last_by_budget(st.req, st.pos + 1, st.generated + 1)):
+                with cause_span("sched.release"):
+                    self._retiring.append(st)  # before it leaves _slots: abort_all must find it in one
+                    step.retired[row.slot] = st
+                    del self._slots[row.slot]
+                    self._release_guarded(row.slot, "length", frozen=True)
+
+    def _settle_mixed(self) -> bool:
+        """Fetch and process the unfetched mixed step, if there is one:
+        what every path that needs the host's state whole calls first (a
+        failed submit's handlers, a preemption, the bucketed admission,
+        the drain, the loop's end). False if the device failed under it
+        (its slots are failed then)."""
+        step, self._mixed_inflight = self._mixed_inflight, None
+        return step is None or self._process_mixed(step)
+
+    def _process_mixed(self, step: _MixedInflight) -> bool:
+        """Fetch a dispatched mixed step's block and stream it out: decode
+        rows advance exactly one token, same emission contract as one
+        step of a fused chunk; rows whose prompt ended here get their
+        first token. A row is emitted only into the state that was
+        snapshotted when the step was built (identity). A decode row
+        whose state has left its slot meanwhile, on a finish the host
+        could not foresee when this step was dispatched behind the one
+        that held it (EOS, a stop id, a stop string, a client gone),
+        computed a token for a stream that had ended: discarded and
+        counted, never delivered."""
+        observing = self._observing and step.phases is not None
+        phases = step.phases if observing else None
+        # Dispatched behind an unfetched step, or fetched with a program
+        # behind it: the record runs from here, its dispatch is ``submit_ts``.
+        overlapped = step.behind or bool(self._handles) or self._mixed_inflight is not None
+        t0 = time.perf_counter() if overlapped else step.t0
+        try:
+            toks, logprobs = self.engine.mixed_step_fetch(step.handle)
+        except Exception as e:
+            # The device failed under it: whatever was dispatched behind
+            # it is lost too, and every stream it carried ends in "error".
+            lost, self._mixed_inflight = self._mixed_inflight, None
+            self._handles.clear()
+            self.engine._dev_carry = None
+            for st in list(step.retired.values()) + list(lost.retired.values() if lost else ()):
+                self._retiring = [x for x in self._retiring if x is not st]
+                self._fail_request(st.req)
+            self._fail_after_decode_error(e)
+            return False
+        self.last_step_time = self.clock.now()
+        self.steps_completed += 1
+        if observing:
+            self._stamp_fetch()
+        emitted = 0
+        with phase_span(phases, "emit", "sched.emit"):
+            for row in step.rows[:step.n_decode]:
+                st = step.states[row.slot]
+                retired = step.retired.get(row.slot) is st
+                if not retired and self._slots.get(row.slot) is not st:
+                    self._wasted(WASTE_OVERSHOOT, 1)
+                    continue
+                st.pos += 1
+                st.pending_token = int(toks[row.slot])
+                st.pending_logprob = float(logprobs[row.slot])
+                st.generated += 1
+                emitted += 1
+                finished, reason = self._emit(st, st.pending_token, st.pending_logprob)
+                if retired:  # finished by construction: its slot went back at dispatch
+                    self._retiring = [x for x in self._retiring if x is not st]
+                    self._flush_emits(st.req)
+                elif finished:
+                    self._finish(row.slot, st.req, reason)
+                else:
+                    # A row that goes on decoding delivers behind a
+                    # dispatch: the one this step was processed behind
+                    # (the caller delivers as soon as this returns), else
+                    # the next (the next mixed step or run()'s fresh
+                    # chunk). Finished rows and first tokens leave at once.
+                    self._flush_emits(st.req, defer=True)
+            for req, slot, st in step.first:
+                if self._slots.get(slot) is not st:
+                    continue  # failed or preempted while its first token was on the way
+                self.engine.metrics["prefill_batches"] += 1
+                st.pending_token = int(toks[slot])
+                st.pending_logprob = float(logprobs[slot])
+                if self.engine.prefix_cache is not None:
+                    with self.engine._lock:
+                        self.engine.prefix_cache.insert(
+                            req.prompt_ids, self.engine.allocator.pages_of(slot))
+                emitted += 1
+                finished, reason = self._emit(st, st.pending_token, st.pending_logprob)
+                if finished:
+                    self._finish(slot, req, reason)
+                else:
+                    self._flush_emits(req)
+        if observing:
+            spans = step.spans
+            spans["phases"] = phases
+            spans["h2d_arrays"] = step.handle.h2d_arrays
+            spans.update(step.handle.stats or {})  # a mixture's expert counts
+            plan = getattr(self.engine, "ragged_attention_plan", None)
+            if plan is not None:
+                # Tokens of the rows the ragged kernel walks in wide
+                # tiles (ISSUE 34), beside work_tokens: known from
+                # the rows packed, no device read.
+                spans["attn_wide_tokens"] = sum(
+                    len(row.token_ids) for row in step.rows[step.n_decode:]
+                    if len(row.token_ids) >= plan["wide_from"])
+            if not overlapped:
+                spans.pop("submit_ts", None)
+            n_prefill, context, pairs = step.counts
+            self._record_step(
+                "mixed", t0, n_steps=1, batch=len(step.rows),
+                tokens=emitted, work_tokens=step.n_decode + n_prefill,
+                context_tokens=context, pair_tokens=pairs, spans=spans)
+        return True
 
     def _submit_chunk(self, chain: bool) -> "_Inflight | None":
         """Dispatch one fused decode chunk without waiting for it.
@@ -1352,6 +1593,10 @@ class Scheduler:
             mstates = np.zeros((S,), np.int32)
             stop_tables = np.full((S, STOP_TABLE_WIDTH), -1, np.int32)
             budgets = np.zeros((S,), np.int64)
+            # Rows whose pending token the train's last mixed step holds,
+            # unfetched (ISSUE 40): the chunk reads it on the device, at the
+            # position and budget the slot has once that step is processed.
+            from_block = np.zeros((S,), bool)
             eos_id = getattr(self.engine, "_eos_id", None)
             max_pos = self.engine.config.max_seq_len - 1
             for slot, st in self._slots.items():
@@ -1360,6 +1605,12 @@ class Scheduler:
                 # flight for the slot's previous occupant must not.
                 inflight_steps = sum(h.n_steps for h in chunk_handles
                                      if h.states.get(slot) is st)
+                lag = self._ahead(slot, st)
+                if lag is not None:
+                    if self._last_by_budget(st.req, st.pos + lag, st.generated + lag):
+                        continue  # the token on the device is its last: no row, no pages ahead
+                    from_block[slot] = True
+                    inflight_steps += lag
                 tokens[slot] = max(st.pending_token, 0)
                 positions[slot] = min(st.pos + inflight_steps, max_pos)
                 active[slot] = True
@@ -1374,19 +1625,29 @@ class Scheduler:
                     # been fed (chained submits take the device carry).
                     mstates[slot] = st.req.grammar.global_state
                 stop_tables[slot] = build_stop_row(eos_id, st.req.stop_token_ids)
-                budgets[slot] = max(st.req.max_tokens - st.generated, 0)
+                budgets[slot] = max(st.req.max_tokens - st.generated - (lag or 0), 0)
         t_submit = time.perf_counter_ns() if observing else 0
         try:
             handle = self.engine.decode_chunk_submit(
                 tokens, positions, active, temps, top_ps, n_steps=n,
                 seeds=seeds, use_seed=use_seed, mstates=mstates,
-                stop_tables=stop_tables, budgets=budgets, phases=phases)
+                stop_tables=stop_tables, budgets=budgets, phases=phases,
+                from_block=from_block)
         except Exception as e:
+            if self._mixed_inflight is not None:
+                # The train's last step first: fetch and process it, then
+                # this submit again from what the host then has, as behind
+                # a host-stepped train (a finish it emits may free what the
+                # submit lacked).
+                self._settle_mixed()
+                return self._submit_chunk(chain=False)
             self._fail_after_decode_error(e)
             return None
+        tokens_from = self._count_submit(bool(from_block.any()))
         submit = self._stamp_dispatch("decode", t_submit, t_build) if observing else None
-        self._deliver_deferred(submit, getattr(handle, "phases", None))
-        return _Inflight(handle, dict(self._slots), n, submit)
+        if submit is not None:
+            submit["tokens_from"] = tokens_from
+        return _Inflight(handle, dict(self._slots), n, submit)  # run() delivers behind it
 
     def _spec_step(self) -> None:
         """One speculative round: emits 1..K+1 tokens per live slot.
@@ -1588,7 +1849,7 @@ class Scheduler:
         later, ``submit_ts`` (``submit_t0`` on the span clock). Times are
         perf_counter_ns."""
         now = time.perf_counter_ns()
-        inflight = len(self._handles)
+        inflight = len(self._handles) + (self._mixed_inflight is not None)
         spans: dict[str, Any] = {"inflight_at_dispatch": inflight, "starved_ms": 0.0}
         by_cause = self._cursor.stop(now)
         if inflight == 0 and self._starve_t0 is not None:
@@ -1613,10 +1874,11 @@ class Scheduler:
         """One fetch just materialized: the host-gap clock restarts, and
         if nothing else is in flight while work is pending, so do the
         starvation clock and its ledger by cause (a fetched handle has
-        already left _handles)."""
+        already left _handles, a mixed step _mixed_inflight)."""
         now = time.perf_counter_ns()
         self._dev_touch = now
-        if not self._handles and (self._slots or self._waiting or self._admitting):
+        if (not self._handles and self._mixed_inflight is None
+                and (self._slots or self._waiting or self._admitting)):
             self._starve_t0 = now
             self._cursor.start(now)
 

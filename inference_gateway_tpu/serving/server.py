@@ -1049,11 +1049,17 @@ class SidecarServer:
             },
             # Delivery after dispatch (ISSUE 29): of the step-boundary
             # flushes, how many waited for the next dispatch (an
-            # admission's decode rows) and how many ran in place.
+            # admission's decode rows) and how many ran in place. One step
+            # ahead of the host (ISSUE 40): of the mixed steps and fresh
+            # decode chunks, how many read a row's token from an unfetched
+            # step's block on the device, and how many took every token
+            # from the host.
             "scheduler": {
                 "steps_completed": self.scheduler.steps_completed,
                 "flushes_deferred": self.scheduler.flushes_deferred,
                 "flushes_immediate": self.scheduler.flushes_immediate,
+                "pipelined_submits": self.scheduler.pipelined_submits,
+                "host_stepped_submits": self.scheduler.host_stepped_submits,
             },
         }
         if self.engine.structured is not None:

@@ -488,3 +488,34 @@ def test_sparse_attention_compiles_for_v5e_at_the_published_widths(one_chip, for
                 one_chip((T,), I32), *pools, table, row, row, row)
     compiled = jax.jit(fn).lower(*args).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# What the engine's two host-stepped programs trace to on the tiny dense
+# preset, the model's forward included: the parent's counts (350 and 26) and
+# what ISSUE 40's operand adds to each, the unpacking of one more field and
+# the one scatter (or select) that puts a flagged row's token where the packed
+# one lay. A traced equation costs every start ~0.35 ms here and 3-5 times
+# that on the chip's host (``PERF.md`` section 6, PR 34): the whole addition is
+# under 0.1 s, and there is no second compiled form to load (held by
+# ``tests/test_pipelined_admission.py``).
+HOST_STEPPED_PROGRAMS = {"_mixed_step_fn": 350 + 18, "_fresh_unpack_fn": 26 + 12}
+
+
+@pytest.mark.parametrize("name", list(HOST_STEPPED_PROGRAMS))
+def test_host_stepped_programs_stay_under_their_equation_ceilings_with_the_block_operand(name):
+    from inference_gateway_tpu.serving.engine import Engine, EngineConfig
+
+    engine = Engine(EngineConfig(model="test-tiny", attention="paged", page_size=16, max_slots=4, max_seq_len=256,
+                                 dtype="float32", use_mesh=False, mixed_step=True, decode_chunk=4))
+    block = engine._last_block
+    assert block.shape == (2, 4) and block.dtype == jnp.float32
+    if name == "_mixed_step_fn":
+        traced = jax.make_jaxpr(lambda *a: Engine._mixed_step_fn(engine, *a, masked=False))(
+            engine.params, engine.cache, jnp.zeros((engine._mixed_pack.size,), I32), engine._rng, block)
+    else:
+        traced = jax.make_jaxpr(lambda *a: Engine._fresh_unpack_fn(engine, *a))(
+            jnp.zeros((engine._fresh_pack.size,), I32), engine._rng, block)
+    census = _census(traced.jaxpr, {})
+    assert sum(census.values()) <= HOST_STEPPED_PROGRAMS[name], census
+    # the token's path is one scatter into the packed tokens (mixed) or one select (fresh): no branch
+    assert census.get("cond", 0) == 0

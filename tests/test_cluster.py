@@ -816,6 +816,16 @@ def _chat_body(max_tokens=24, **extra) -> dict:
             "messages": [{"role": "user", "content": "splice me"}], **extra}
 
 
+# The continuation resumes from the TEXT the relay forwarded, which the sidecar
+# re-encodes, so the prefix must round-trip through the byte tokenizer whatever
+# number of frames reached the client before the kill (2 on a quiet machine, 3-5
+# under load: an invalid UTF-8 byte decodes to U+FFFD, three bytes re-encoded,
+# and the spliced stream diverged: 3 of 9 runs beside 14 busy processes, on the
+# parent of ISSUE 40 as on its tree). Banning ids 128..255 keeps the greedy
+# stream ASCII, as tests/test_stream_continuation.py's e2e does.
+_ASCII_ONLY = {str(i): -100 for i in range(128, 256)}
+
+
 def _parse_frames(body: bytes):
     frames = []
     for part in body.split(b"\n\n"):
@@ -943,7 +953,7 @@ async def test_sigkill_mid_stream_completes_byte_identical_via_continuation(
     headers.set("traceparent", TRACEPARENT)
 
     # 96 tokens: room for the wedge _kill_relay_mid_stream places at 15.
-    body = json.dumps(_chat_body(max_tokens=96)).encode()
+    body = json.dumps(_chat_body(max_tokens=96, logit_bias=_ASCII_ONLY)).encode()
 
     # Reference run, unkilled.
     client = HTTPClient()
@@ -971,7 +981,7 @@ async def test_sigkill_mid_stream_completes_byte_identical_via_continuation(
     kept = _parse_frames(got)
     cid, created = kept[0][1]["id"], kept[0][1]["created"]
     prefix = "".join(contents)
-    cont_body = _chat_body(max_tokens=96,
+    cont_body = _chat_body(max_tokens=96, logit_bias=_ASCII_ONLY,
                            continuation={"text": prefix, "id": cid,
                                          "created": created})
     client = HTTPClient()
@@ -1035,7 +1045,7 @@ async def test_journey_survives_originating_worker_death(cluster_stack):
     headers.set("Content-Type", "application/json")
     headers.set("traceparent", f"00-{trace}-1234567890abcdef-01")
     url = f"http://127.0.0.1:{port}/v1/chat/completions"
-    body = json.dumps(_chat_body(max_tokens=96)).encode()
+    body = json.dumps(_chat_body(max_tokens=96, logit_bias=_ASCII_ONLY)).encode()
 
     got, contents, victim = await _kill_relay_mid_stream(
         seg, sidecar, url, body, headers)
@@ -1046,7 +1056,7 @@ async def test_journey_survives_originating_worker_death(cluster_stack):
     kept = _parse_frames(got)
     cid, created = kept[0][1]["id"], kept[0][1]["created"]
     prefix = "".join(contents)
-    cont_body = _chat_body(max_tokens=96,
+    cont_body = _chat_body(max_tokens=96, logit_bias=_ASCII_ONLY,
                            continuation={"text": prefix, "id": cid,
                                          "created": created})
     client = HTTPClient()

@@ -94,7 +94,7 @@ def records(loop):
 
 def test_starved_by_sums_to_starved_ms_on_every_record_and_only_starved_records_carry_it(records):
     starved = [r for r in records if r["starved_ms"] > 0]
-    assert len(starved) >= 50
+    assert len(starved) >= 20  # (50 and more before ISSUE 40: most of them now run behind a step in flight)
     for r in records:
         if r["starved_ms"] > 0:
             assert sum(r["starved_by"].values()) == pytest.approx(r["starved_ms"], abs=0.05), r
@@ -119,8 +119,34 @@ def test_a_chained_decode_submit_is_not_starved_and_carries_no_ledger(records):
     chained = [r for r in records if r["kind"] == "decode" and r["inflight_at_dispatch"] > 0]
     assert chained
     assert all(r["starved_ms"] == 0 and "starved_by" not in r for r in chained)
-    fresh = [r for r in records if r["kind"] == "decode" and r["phases"]["build"] > 0]
-    assert fresh and all(r["starved_by"]["build"] > 0 for r in fresh)  # its own build is a span now
+    # A fresh submit that found the device empty: its own build is a span now.
+    # (Behind a train it finds the train's last step in flight since ISSUE 40;
+    # the one behind a grammar's host-stepped step is held further down.)
+    fresh = [r for r in records if r["kind"] == "decode" and r["phases"]["build"] > 0 and r["starved_ms"] > 0]
+    assert all(r["starved_by"]["build"] > 0 for r in fresh)
+
+
+def test_a_submit_behind_an_unfetched_mixed_step_is_not_starved_and_the_first_of_a_train_is_as_it_was(records):
+    """ISSUE 40: every ``mixed`` and fresh ``decode`` record says where its
+    rows' tokens came from. One whose rows read an unfetched step's block was
+    dispatched behind that step: in flight at its dispatch, no starved time, no
+    ledger. A train's first step (tokens from the host, nothing in flight)
+    starves as every mixed step did, and its ledger still sums."""
+    stepped = [r for r in records if r["kind"] == "mixed" or (r["kind"] == "decode" and r["h2d_arrays"] == 1)]
+    assert stepped and all(r["tokens_from"] in ("device", "host") for r in stepped)
+    assert all("tokens_from" not in r for r in records if r not in stepped)  # a chained chunk carries none
+    ahead = [r for r in stepped if r["tokens_from"] == "device"]
+    assert len(ahead) >= 0.4 * len(stepped), (len(ahead), len(stepped))
+    assert {r["kind"] for r in ahead} == {"mixed", "decode"}
+    for r in ahead:
+        assert r["starved_ms"] == 0 and r["inflight_at_dispatch"] >= 1 and "starved_by" not in r, r
+        assert r["submit_ts"] <= r["start_ts"]  # dispatched, then fetched: the record runs from its fetch
+    first = [r for r in stepped if r["kind"] == "mixed" and r["tokens_from"] == "host"
+             and r["inflight_at_dispatch"] == 0]
+    assert len(first) >= 10
+    for r in first:
+        assert r["starved_ms"] > 0 and {"build", "upload_dispatch"} <= set(r["starved_by"]), r
+        assert sum(r["starved_by"].values()) == pytest.approx(r["starved_ms"], abs=0.05)
 
 
 def test_what_lies_under_no_span_stays_a_small_share_of_a_200_step_run(records):
@@ -135,8 +161,15 @@ def test_what_lies_under_no_span_stays_a_small_share_of_a_200_step_run(records):
 def test_a_mixed_records_phases_still_add_up_with_the_copy_among_them(records):
     mixed = [r for r in records if r["kind"] == "mixed"]
     assert mixed and all("fetch_copy" in r["phases"] and "fetch_wait" in r["phases"] for r in mixed)
-    assert all(sum(r["phases"].values()) <= r["duration_ms"] + 0.01 for r in mixed)
-    near = [r["duration_ms"] - sum(r["phases"].values()) <= max(0.1 * r["duration_ms"], 1.0) for r in mixed]
+
+    def inside(r):
+        # A step that was fetched with another program behind it carries its
+        # dispatch as ``submit_ts`` and runs from its fetch, like a decode
+        # chunk (ISSUE 40): the phases of its submit lie before its start.
+        own = ("fetch_wait", "fetch_copy", "emit") if "submit_ts" in r else tuple(r["phases"])
+        return sum(r["phases"][k] for k in own)
+    assert all(inside(r) <= r["duration_ms"] + 0.01 for r in mixed)
+    near = [r["duration_ms"] - inside(r) <= max(0.1 * r["duration_ms"], 1.0) for r in mixed]
     assert sum(near) >= 0.9 * len(near)
     # what the benchmark's mixed_step_host_ms sums is where it was: the copy is the device's way back, not summed
     assert "fetch_copy" not in readers.reader("mixed_step_host_ms").HOST_PHASES

@@ -568,23 +568,31 @@ def test_decode_rows_deliver_behind_the_next_dispatch_and_a_first_token_before_i
     fresh = [i for i in window if log[i] == ("submit", "fresh")]
     assert len(mixed) >= 5 and fresh
     # A decodes through both admissions: none of its flushes finds the
-    # device empty, and one follows every dispatch that found it so.
+    # device empty.
     assert all(at[i] >= 1 for i in window if log[i] == ("flush", "a")), log[start:end]
-    for i in mixed + fresh[:1]:
-        assert at[i] == 1 and log[i + 1] == ("flush", "a"), log[start:end]
-    # The drained chunk before the first mixed step delivered behind it too.
+    # The train's first step found the device empty (the chunks in flight
+    # were drained for it): what the drained chunk had to deliver went
+    # behind it.
     assert log[mixed[0] - 1] == ("fetch", "chunk")
-    # B's first token left at once, before the dispatch that follows its
-    # step. That dispatch is C's first mixed step: the submit stood back for
-    # C's admission, and A's row of B's last step was carried behind it.
+    assert at[mixed[0]] == 1 and log[mixed[0] + 1] == ("flush", "a"), log[start:end]
+    # Every later step of the train, across both admissions, and the fresh
+    # chunk that ends it were dispatched BEHIND an unfetched mixed step
+    # (ISSUE 40); that step is fetched next, and its rows flush at once, a
+    # program running behind them.
+    for i in mixed[1:] + fresh[:1]:
+        assert at[i] == 2 and log[i + 1] == ("fetch", "mixed"), log[start:end]
+        # (a first token of that step leaves inside its emit, ahead of A's row)
+        assert ("flush", "a") in log[i + 2:i + 4], log[start:end]
+    # B's first token leaves when its step is processed, behind the dispatch
+    # that followed it: C's first mixed step (the submit stood back for C's
+    # admission). From then on B is a decoding row like A.
     first_b = log.index(("flush", "b"))
-    assert at[first_b] == 0 and log[first_b - 1] == ("fetch", "mixed")
-    assert log[first_b + 1:first_b + 3] == [("submit", "mixed"), ("flush", "a")]
-    # From then on B is a decoding row like A; C's first token leaves before the fresh chunk.
-    assert all(at[i] >= 1 for i in window if log[i] == ("flush", "b") and i > first_b), log[start:end]
+    assert at[first_b] == 1 and ("submit", "mixed") in log[first_b - 3:first_b], log[start:end]
+    assert all(at[i] >= 1 for i in window if log[i] == ("flush", "b")), log[start:end]
+    # C's first token was in the train's last step, processed behind the fresh chunk.
     first_c = log.index(("flush", "c"))
-    assert at[first_c] == 0 and log[first_c + 1] == ("submit", "fresh")
-    assert sched.flushes_deferred >= len(mixed) + 1 and not sched._deferred
+    assert at[first_c] == 1 and ("submit", "fresh") in log[first_c - 4:first_c], log[start:end]
+    assert sched.flushes_deferred >= 1 and not sched._deferred
     assert len(c.delivered) == 6 and not any(s.pending for s in (a, b, c))
 
 

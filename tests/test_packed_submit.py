@@ -63,7 +63,8 @@ def _random_rows(engine: Engine, rng: np.random.Generator, with_mask_state: bool
             kind="prefill" if prefill else "decode",
             temp=float(rng.choice([0.0, 0.7, 1.3])), top_p=float(rng.choice([1.0, 0.9])),
             seed=int(rng.integers(0, 2**31 - 1)) if rng.random() < 0.5 else None,
-            mask_state=int(rng.integers(0, 7)) if with_mask_state else 0))
+            mask_state=int(rng.integers(0, 7)) if with_mask_state else 0,
+            from_block=not prefill and rng.random() < 0.5))  # its token is still on the device
         left -= n
     return rows
 
@@ -78,7 +79,8 @@ def _twelve_arrays(engine: Engine, rows: list[MixedRow]) -> dict[str, np.ndarray
         q_starts=np.zeros((S,), np.int32), q_lens=np.zeros((S,), np.int32),
         kv_lens=np.zeros((S,), np.int32), temps=np.zeros((S,), np.float32),
         top_ps=np.ones((S,), np.float32), seeds=np.zeros((S,), np.int32),
-        use_seed=np.zeros((S,), bool), mstates=np.zeros((S,), np.int32))
+        use_seed=np.zeros((S,), bool), mstates=np.zeros((S,), np.int32),
+        from_block=np.zeros((S,), bool))  # ISSUE 40's flag: a thirteenth field
     off = 0
     for r in rows:
         n = len(r.token_ids)
@@ -91,6 +93,7 @@ def _twelve_arrays(engine: Engine, rows: list[MixedRow]) -> dict[str, np.ndarray
         if r.seed is not None:
             a["seeds"][r.slot], a["use_seed"][r.slot] = int(r.seed), True
         a["mstates"][r.slot] = r.mask_state
+        a["from_block"][r.slot] = r.from_block
         off += n
     a["page_table"] = engine.allocator.page_table().copy()
     return a
@@ -106,9 +109,9 @@ def _capture_packed(engine: Engine, name: str, submit) -> np.ndarray:
     seen = {}
 
     def spy(*args, **kw):
-        # (params, cache, packed, base_rng) for the mixed step, (packed,
-        # base_rng) for the fresh unpack program
-        seen["packed"], seen["base"] = args[-2:]
+        # (params, cache, packed, base_rng, block) for the mixed step,
+        # (packed, base_rng, block) for the fresh unpack program
+        seen["packed"], seen["base"], seen["block"] = args[-3:]
         raise _Captured
 
     setattr(engine, name, spy)
@@ -118,6 +121,8 @@ def _capture_packed(engine: Engine, name: str, submit) -> np.ndarray:
     finally:
         delattr(engine, name)  # the class's jitted method shows again
     assert seen["base"] is engine._rng
+    # the operand that is always passed: the mixed step's block of last time
+    assert seen["block"] is engine._last_block and seen["block"].dtype == jnp.float32
     assert seen["packed"].dtype == jnp.int32 and seen["packed"].ndim == 1
     return seen["packed"]
 
@@ -166,13 +171,14 @@ def test_fresh_pack_round_trips_every_field(family, defaults):
             mstates=rng.integers(0, 7, S).astype(np.int32),
             stop_tables=np.stack([build_stop_row(engine._eos_id, [int(t) for t in rng.integers(1, 250, 3)])
                                   for _ in range(S)]),
-            budgets=rng.integers(1, 90, S).astype(np.int64))
+            budgets=rng.integers(1, 90, S).astype(np.int64),
+            from_block=rng.random(S) < 0.5)
     packed = _capture_packed(engine, "_fresh_unpack_fn",
                              lambda: engine.decode_chunk_submit(**args))
     assert packed.shape == (engine._fresh_pack.size,)
-    got = {k: np.asarray(v) for k, v in jax.jit(engine._fresh_pack.unpack)(packed).items()}
+    got = {k: np.array(v) for k, v in jax.jit(engine._fresh_pack.unpack)(packed).items()}
     assert int(got["counter"][0]) == engine._step_counter == 1
-    for name in ("tokens", "positions", "temps", "top_ps", "seeds", "use_seed"):
+    for name in ("tokens", "positions", "temps", "top_ps", "seeds", "use_seed", "from_block")[:7 - defaults]:
         np.testing.assert_array_equal(got[name], args[name], err_msg=name)
         assert got[name].dtype == args[name].dtype, name
     np.testing.assert_array_equal(got["done"], ~active)
@@ -180,6 +186,7 @@ def test_fresh_pack_round_trips_every_field(family, defaults):
     np.testing.assert_array_equal(got["reserved"], engine._reserved)
     assert (got["reserved"][active] >= positions[active] + engine.config.decode_chunk).all()
     if defaults:
+        assert not got["from_block"].any()  # every token from the host
         np.testing.assert_array_equal(got["mstates"], np.zeros((S,), np.int32))
         np.testing.assert_array_equal(got["budgets"], np.full((S,), 1 << 30, np.int32))
         np.testing.assert_array_equal(
@@ -191,7 +198,18 @@ def test_fresh_pack_round_trips_every_field(family, defaults):
     assert engine._dev_carry is None
     # The unpack program hands the chained dispatch its state in the
     # order it reads it, with the chunk key the host used to fold.
-    carry, sampling, tables = engine._fresh_unpack_fn(packed, engine._rng)
+    # A flagged row's token is the block's for its slot (here a block the test
+    # makes up; one of them is a stop id of its row, which then starts done).
+    block = np.zeros(engine._last_block.shape, np.float32)
+    block[0] = rng.integers(1, 250, S)
+    flagged = np.nonzero(got["from_block"])[0]
+    if len(flagged):
+        block[0, flagged[0]] = got["stop_table"][flagged[0], 0]
+    carry, sampling, tables = engine._fresh_unpack_fn(packed, engine._rng, jnp.asarray(block))
+    got["tokens"] = np.where(got["from_block"], block[0].astype(np.int32), got["tokens"])
+    if len(flagged):
+        assert not got["done"][flagged[0]] or not active[flagged[0]]
+        got["done"][flagged[0]] = True
     for dev, name in zip(carry[:5], ("tokens", "positions", "mstates", "done", "budgets")):
         np.testing.assert_array_equal(np.asarray(dev), got[name], err_msg=name)
     np.testing.assert_array_equal(np.asarray(carry[5]), np.asarray(jax.random.fold_in(engine._rng, 1)))
@@ -291,10 +309,12 @@ class _TwelveArrayReference:
         return np.asarray(toks), np.asarray(lps)  # (the engine's own fetch splits one block)
 
 
-def _drive(engine: Engine, reference: bool, masked: bool):
+def _drive(engine: Engine, reference: bool, masked: bool, ahead: bool = False):
     """Eight mixed steps (three admissions beside decode rows), a fresh
     decode chunk, two chained chunks. Returns every (tokens, logprobs)
-    block in order."""
+    block in order. ``ahead`` (ISSUE 40): every program behind a mixed
+    step is dispatched BEFORE that step is fetched, its decode rows'
+    tokens read on the device from that step's block (``from_block``)."""
     ref = _TwelveArrayReference(engine) if reference else None
     S = engine.config.max_slots
     if masked:
@@ -308,7 +328,21 @@ def _drive(engine: Engine, reference: bool, masked: bool):
     prompts = {s: [int(t) for t in rng.integers(1, 250, int(rng.integers(20, 50)))] for s in sampling}
     out = []
 
+    unfetched: list = []  # ahead: (handle, rows) of the step dispatched last
+
+    def fetch_last():
+        handle, rows = unfetched.pop()
+        toks, lps = engine.mixed_step_fetch(handle)
+        live = [r.slot for r in rows]
+        out.append((toks[live].astype(np.int32), lps[live]))
+
     def step(rows):
+        if ahead:
+            handle = engine.mixed_step_submit(rows)
+            if unfetched:
+                fetch_last()
+            unfetched.append((handle, rows))
+            return np.zeros((S,), np.int32)  # nobody reads a token on the host
         if ref is not None:
             toks, lps = ref.mixed_step(rows)
         else:
@@ -326,7 +360,7 @@ def _drive(engine: Engine, reference: bool, masked: bool):
         for slot, (tok, pos) in sorted(state.items()):
             t, p, sd = sampling[slot]
             rows.append(MixedRow(slot=slot, token_ids=[tok], start=pos, kind="decode",
-                                 temp=t, top_p=p, seed=sd))
+                                 temp=t, top_p=p, seed=sd, from_block=ahead))
         for slot, done in sorted(progress.items()):
             t, p, sd = sampling[slot]
             piece = prompts[slot][done:done + 24]  # chunked: a prompt takes two steps
@@ -356,6 +390,12 @@ def _drive(engine: Engine, reference: bool, masked: bool):
             seeds[slot], use_seed[slot] = sd, True
     if ref is not None:
         out.append(ref.fresh_chunk(tokens, positions, active, temps, top_ps, seeds, use_seed, budgets))
+    elif ahead:
+        assert not tokens.any()
+        h = engine.decode_chunk_submit(tokens, positions, active, temps, top_ps, seeds=seeds,
+                                       use_seed=use_seed, budgets=budgets, from_block=active)
+        fetch_last()
+        out.append(engine.decode_chunk_fetch(h))
     else:
         out.append(engine.decode_chunk_fetch(engine.decode_chunk_submit(
             tokens, positions, active, temps, top_ps, seeds=seeds, use_seed=use_seed, budgets=budgets)))
@@ -377,6 +417,21 @@ def test_packed_submits_equal_the_twelve_array_reference_byte_for_byte(family, m
         assert gl.tobytes() == wl.tobytes(), f"logprobs of block {i}"
     # unseeded sampling rows drew from the step key: the streams are not all greedy
     assert any(len(np.unique(t)) > 1 for t, _ in got)
+
+
+@pytest.mark.parametrize("family", list(FAMILIES))
+@pytest.mark.parametrize("masked", [False, True], ids=["unmasked", "masked"])
+def test_programs_dispatched_before_the_last_mixed_step_is_fetched_equal_the_reference(family, masked):
+    """ISSUE 40's operand: seven mixed steps and the fresh decode chunk
+    each dispatched behind an UNFETCHED mixed step, every decode row's
+    token taken from that step's block on the device. Same rows, same
+    seeds, same step counter: the blocks are the host-stepped reference's."""
+    got = _drive(_engine(family), reference=False, masked=masked, ahead=True)
+    want = _drive(_engine(family), reference=True, masked=masked)
+    assert len(got) == len(want) == 11
+    for i, ((gt, gl), (wt, wl)) in enumerate(zip(got, want)):
+        np.testing.assert_array_equal(gt, wt, err_msg=f"tokens of block {i}")
+        assert gl.tobytes() == wl.tobytes(), f"logprobs of block {i}"
 
 
 def test_dense_fresh_submit_is_packed_too_and_matches_the_chained_programs_inputs():
