@@ -49,6 +49,11 @@ class LlamaConfig:
     norm_offset: bool = False  # Gemma-style RMSNorm weight = (1 + w)
     embed_scale: bool = False  # Gemma scales embeddings by sqrt(hidden)
     qk_norm: bool = False  # RMSNorm over head_dim on q and k before rotary (leaves q_norm, k_norm)
+    # ISSUE 41: the attention's output times sigmoid(h @ attn_gate) before the
+    # output projection; and each sublayer's OUTPUT normed before it is added
+    # (leaves post_attn_norm, post_ffn_norm), beside the norms of its input.
+    attn_gate: bool = False
+    sandwich_norm: bool = False
     # Per-layer attention kinds (ISSUE 28), one 0/1 flag a layer, or None
     # for a model whose layers are all alike: layer_rope 0 is a layer with
     # no position encoding; layer_window 0 is a global layer of a model
@@ -130,6 +135,50 @@ def _window_operand(cfg: "LlamaConfig", li) -> dict:
 
     return {"window_dyn": jnp.where(jnp.asarray(cfg.layer_window, bool)[li],
                                     cfg.sliding_window, GLOBAL_WINDOW).astype(jnp.int32)}
+
+
+def window_pages(layers: int, window: int, page_size: int, first_len, last_len):
+    """What a step's ``layers`` windowed layers read of the pool, and what
+    their rows' contexts hold, in pages over all of them: a row whose
+    queries see lengths first_len..last_len (0 = no row) reads the pages
+    from the one holding position first_len - window on, as both paged
+    kernels do. Returns float32 (2,): window_pages_read, context_pages."""
+    live = last_len > 0
+    held = (last_len + page_size - 1) // page_size
+    skipped = jnp.maximum(first_len - window, 0) // page_size
+    read = jnp.sum(jnp.where(live, held - skipped, 0))
+    return layers * jnp.stack([read, jnp.sum(jnp.where(live, held, 0))]).astype(jnp.float32)
+
+
+def _attn_out(attn, h, lp: Params, cfg: "LlamaConfig"):
+    """The attention sublayer's residual contribution: ``attn`` (..., Hq*D)
+    through the output projection; with ``cfg.attn_gate`` first multiplied,
+    element by element, by a sigmoid gate read from the sublayer's normed
+    input ``h``; with ``cfg.sandwich_norm`` the projection's output normed."""
+    if cfg.attn_gate:
+        with jax.named_scope("attn.gate"):
+            gate = jax.nn.sigmoid(qmatmul(h, lp["attn_gate"]).astype(jnp.float32))
+            attn = (attn.astype(jnp.float32) * gate).astype(attn.dtype)
+    out = qmatmul(attn, lp["wo"])
+    if cfg.sandwich_norm:
+        out = rms_norm(out, _nw(lp["post_attn_norm"], cfg), cfg.rms_norm_eps)
+    return out
+
+
+def _ffn_out(y, lp: Params, cfg: "LlamaConfig"):
+    """The FFN sublayer's residual contribution, normed under ``sandwich_norm``."""
+    return rms_norm(y, _nw(lp["post_ffn_norm"], cfg), cfg.rms_norm_eps) if cfg.sandwich_norm else y
+
+
+def _layer_groups(params: Params, ffn, unscanned: tuple, prefix, num_layers: int) -> list:
+    """The scanned groups of a layer stack, in order, as (leaves, ffn, first
+    layer index, layers): one group for a model whose layers are alike; two
+    where a ``prefix`` of layers with an FFN of their own leads (ISSUE 41:
+    ``{"layers": leaves with their own leading axis, "ffn": the seam}``). The
+    layer index runs on across the groups: one page pool, one page table."""
+    lead = jax.tree.leaves(prefix["layers"])[0].shape[0] if prefix else 0
+    body = (_scan_layers(params, unscanned), ffn, lead, num_layers - lead)
+    return [(prefix["layers"], prefix["ffn"], 0, lead), body] if prefix else [body]
 
 
 def init_params(rng: jax.Array, cfg: LlamaConfig, dtype=jnp.bfloat16) -> Params:
@@ -447,6 +496,7 @@ def forward_paged_impl(
     valid: jnp.ndarray | None = None,  # (B,) decode rows that carry a stream
     with_aux: bool = False,
     mixer=None,  # the attention seam, below; a learned sparse attention plugs in here
+    prefix=None,  # leading layers with an FFN of their own (_layer_groups), or None
 ):
     """Shared paged-decoder skeleton: attention + cache paging are
     family-independent; the FFN block (dense gated MLP vs MoE) is the
@@ -475,7 +525,8 @@ def forward_paged_impl(
     normed input (a router placed before attention reads it), ``lp`` the
     layer's scanned leaves, ``li`` its index, ``valid`` (B*T,) which
     tokens are real. With ``with_aux`` the auxiliaries come back stacked
-    over layers as a third result."""
+    over layers as a third result (those of the layers behind a ``prefix``:
+    its own FFN returns none)."""
     from inference_gateway_tpu.ops.flash_attention import flash_prefill_attention, use_flash_prefill
     from inference_gateway_tpu.ops.paged_attention import paged_attention
 
@@ -531,7 +582,9 @@ def forward_paged_impl(
         raise NotImplementedError(
             f"a model with an attention seam admits through the mixed step; forward_paged mode {mode!r} has no path for it")
 
-    def body(carry, per_layer):
+    assert mixer is None or prefix is None, "an attention seam's counts of a prefix's layers would be lost"
+
+    def body(carry, per_layer, ffn):
         x, ck, cv, *ce = carry  # ck/cv: (L*P*page_size, HkvD) flat carry
         lp, li = per_layer
         with jax.named_scope("attention"):
@@ -592,19 +645,19 @@ def forward_paged_impl(
                     page_size=page_size)
                 ce = [wrote.get(n, a) for n, a in zip(extra, ce)]
                 attn = attn[:, None]
-            x = x + qmatmul(attn.reshape(B, T, Hq * D), lp["wo"])
+            x = x + _attn_out(attn.reshape(B, T, Hq * D), h, lp, cfg)
         with jax.named_scope("mlp"):
             y = ffn(x, h, lp, li, tok_valid)
             y, aux = y if isinstance(y, tuple) else (y, None)
-            x = x + y
+            x = x + _ffn_out(y, lp, cfg)
         return (x, ck, cv, *ce), (aux if mixer is None else (aux, mix_aux))
 
     ck0 = cache["k"].reshape(total, HkvD)
     cv0 = cache["v"].reshape(total, HkvD)
-    (x, ck, cv, *ce), aux = jax.lax.scan(
-        body, (x, ck0, cv0, *(cache[n].reshape((L * P,) + cache[n].shape[2:]) for n in extra)),
-        (_scan_layers(params, unscanned), jnp.arange(L))
-    )
+    carry = (x, ck0, cv0, *(cache[n].reshape((L * P,) + cache[n].shape[2:]) for n in extra))
+    for leaves, group_ffn, first, count in _layer_groups(params, ffn, unscanned, prefix, L):
+        carry, aux = jax.lax.scan(partial(body, ffn=group_ffn), carry, (leaves, jnp.arange(first, first + count)))
+    x, ck, cv, *ce = carry
     new_cache = {"k": ck.reshape(L, P, page_size, HkvD),
                  "v": cv.reshape(L, P, page_size, HkvD),
                  **{n: a.reshape(cache[n].shape) for n, a in zip(extra, ce)}}
@@ -676,6 +729,7 @@ def forward_ragged_impl(
     unscanned: tuple = (),
     with_aux: bool = False,
     mixer=None,  # the attention seam of forward_paged_impl
+    prefix=None,  # the leading group of forward_paged_impl
 ):
     """Shared ragged skeleton, same flat-carry cache discipline as
     forward_paged_impl (the scatter lowers to an in-place row update;
@@ -697,8 +751,9 @@ def forward_ragged_impl(
     tok_valid = jnp.arange(T) < jnp.sum(q_lens)
 
     extra = tuple(mixer["cache"]) if mixer is not None else ()
+    assert mixer is None or prefix is None, "an attention seam's counts of a prefix's layers would be lost"
 
-    def body(carry, per_layer):
+    def body(carry, per_layer, ffn):
         x, ck, cv, *ce = carry
         lp, li = per_layer
         with jax.named_scope("attention"):
@@ -735,19 +790,19 @@ def forward_ragged_impl(
                     q_starts, q_lens, kv_lens, page_size=page_size)
                 ce = [wrote.get(n, a) for n, a in zip(extra, ce)]
                 attn = attn[None]
-            x = x + qmatmul(attn.reshape(B, T, Hq * D), lp["wo"])
+            x = x + _attn_out(attn.reshape(B, T, Hq * D), h, lp, cfg)
         with jax.named_scope("mlp"):
             y = ffn(x, h, lp, li, tok_valid)
             y, aux = y if isinstance(y, tuple) else (y, None)
-            x = x + y
+            x = x + _ffn_out(y, lp, cfg)
         return (x, ck, cv, *ce), (aux if mixer is None else (aux, mix_aux))
 
     ck0 = cache["k"].reshape(total, HkvD)
     cv0 = cache["v"].reshape(total, HkvD)
-    (x, ck, cv, *ce), aux = jax.lax.scan(
-        body, (x, ck0, cv0, *(cache[n].reshape((L * P,) + cache[n].shape[2:]) for n in extra)),
-        (_scan_layers(params, unscanned), jnp.arange(L))
-    )
+    carry = (x, ck0, cv0, *(cache[n].reshape((L * P,) + cache[n].shape[2:]) for n in extra))
+    for leaves, group_ffn, first, count in _layer_groups(params, ffn, unscanned, prefix, L):
+        carry, aux = jax.lax.scan(partial(body, ffn=group_ffn), carry, (leaves, jnp.arange(first, first + count)))
+    x, ck, cv, *ce = carry
     new_cache = {"k": ck.reshape(L, P, page_size, HkvD),
                  "v": cv.reshape(L, P, page_size, HkvD),
                  **{n: a.reshape(cache[n].shape) for n, a in zip(extra, ce)}}

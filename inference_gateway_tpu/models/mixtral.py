@@ -1,9 +1,12 @@
 """Sparse-MoE decoders in pure JAX: the Mixtral block, the SmallThinker
 block (ISSUE 28: the router reads the attention's normed input, ReGLU
-experts, global NoPE layers beside windowed RoPE layers), and the
+experts, global NoPE layers beside windowed RoPE layers), the
 Keye-VL-2.0 text block (ISSUE 36: q/k norms, and a learned sparse
 attention, ops/sparse_attention.py, whose indexer keys are a third paged
-array; it rides the attention seam of the shared skeletons).
+array; it rides the attention seam of the shared skeletons), and the
+Trinity-Mini block (ISSUE 41, ``afmoe``: leading dense layers before the
+expert layers in one layer stack, a sigmoid router with a selection bias
+and an always-on shared expert, gated attention under sandwich norms).
 
 Same TPU-first skeleton as models/llama.py (stacked layers + lax.scan,
 the shared paged and ragged forwards) with the dense MLP replaced by a
@@ -22,11 +25,17 @@ import jax.numpy as jnp
 
 from inference_gateway_tpu.models.llama import (
     LlamaConfig,
+    _attn_out,
+    _dense_ffn,
+    _ffn_out,
+    _layer_groups,
+    _rope_qk,
     forward_paged_impl,
     forward_ragged_impl,
+    window_pages,
 )
 from inference_gateway_tpu.ops.attention import causal_prefill_mask, decode_mask, gqa_attend
-from inference_gateway_tpu.ops.moe import ACTIVATIONS, moe_dense, moe_dropless
+from inference_gateway_tpu.ops.moe import ACTIVATIONS, moe_dense, moe_dropless, router_sigmoid, router_topk
 from inference_gateway_tpu.ops.norms import rms_norm
 from inference_gateway_tpu.ops.quant import qeinsum, qmatmul, seeded_qk_gain, seeded_std
 from inference_gateway_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
@@ -52,60 +61,120 @@ class MixtralConfig(LlamaConfig):
     indexer_heads: int = 0
     indexer_head_dim: int = 64
     indexer_topk: int = 0
+    # Trinity-Mini's block (ISSUE 41). The first num_dense_layers of the
+    # num_layers have a dense gated MLP of dense_intermediate_size in place
+    # of the experts: their leaves are a stack of their own (``dense_*``,
+    # leading axis num_dense_layers) beside the expert layers' (leading
+    # axis expert_layers); the layer index, the page pool and the page
+    # table run over both.
+    num_dense_layers: int = 0
+    dense_intermediate_size: int = 0
+    # "softmax": over the chosen logits (ops/moe.router_topk). "sigmoid":
+    # sigmoid scores, chosen by score + the leaf ``router_bias``, weighed by
+    # the scores alone, normalised, times route_scale (ops/moe.router_sigmoid).
+    router_score: str = "softmax"
+    route_scale: float = 1.0
+    # Always-on experts beside the routed ones: ONE dense gated MLP of
+    # shared_experts * intermediate_size (leaves ``shared_w*``), added once.
+    shared_experts: int = 0
 
     def __post_init__(self):
+        super().__post_init__()
         if self.moe_impl not in ("dropless", "dense", "capacity"):
             raise ValueError(f"moe_impl {self.moe_impl!r}: dropless or dense")
         if self.router_input not in ("ffn", "attn"):
             raise ValueError(f"router_input {self.router_input!r}: ffn or attn")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_score {self.router_score!r}: softmax or sigmoid")
+        if not 0 <= self.num_dense_layers < self.num_layers:
+            raise ValueError(f"num_dense_layers {self.num_dense_layers} of {self.num_layers} layers")
+
+    @property
+    def expert_layers(self) -> int:
+        return self.num_layers - self.num_dense_layers
 
 
 Params = dict[str, Any]
 
 
+PREFIX = "dense_"  # names of the leading dense layers' leaves in params["layers"]
+
+
+def prefix_leaves(layers: Params) -> Params:
+    """The leading dense layers' stack under the names a dense layer's leaves
+    have (``attn_norm``, ``wq``, ..., ``mlp_norm``, ``wg``, ``wu``, ``wd``)."""
+    return {n[len(PREFIX):]: w for n, w in layers.items() if n.startswith(PREFIX)}
+
+
+def attention_leaves(cfg: MixtralConfig, count: int, dtype, matrix) -> Params:
+    """One stack's attention leaves and the norms around both sublayers, less
+    the FFN's own: ``matrix(name, shape)`` builds a (count, in, out) matrix
+    at ``seeded_std(cfg, name)`` (quantised or not: both builds call this)."""
+    H, Hq, Hkv, D = cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads, cfg.hd
+    ones = lambda width: jnp.ones((count, width), dtype)
+    out: Params = {
+        "attn_norm": ones(H),
+        "wq": matrix("wq", (count, H, Hq * D)), "wk": matrix("wk", (count, H, Hkv * D)),
+        "wv": matrix("wv", (count, H, Hkv * D)), "wo": matrix("wo", (count, Hq * D, H)),
+    }
+    if cfg.qk_norm:
+        gain = jnp.full((count, D), seeded_qk_gain(cfg), dtype)
+        out.update(q_norm=gain, k_norm=gain)
+    if cfg.attn_gate:
+        out["attn_gate"] = matrix("attn_gate", (count, H, Hq * D))
+    if cfg.sandwich_norm:
+        out.update(post_attn_norm=ones(H), post_ffn_norm=ones(H))
+    return out
+
+
 def init_params(rng: jax.Array, cfg: MixtralConfig, dtype=jnp.bfloat16) -> Params:
-    L, H, I, V, E = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size, cfg.num_experts
-    Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.hd
-    keys = jax.random.split(rng, 10)
+    L, H, I, V, E = cfg.expert_layers, cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size, cfg.num_experts
+    # One key a leaf the older mixtures have, as they always drew them; the
+    # leaves ISSUE 41 added draw from a stream of their own.
+    keys = dict(zip(("embed", "wq", "wk", "wv", "wo", "router", "wg", "wu", "wd", "lm_head"),
+                    jax.random.split(rng, 10)))
+    more = iter(jax.random.split(jax.random.fold_in(rng, 41), 24))
 
     def norm(key, shape, std=0.02):
         return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
 
-    def extras() -> Params:
-        """q/k norms and the indexer's leaves, where the model has them."""
-        out: Params = {}
-        if cfg.qk_norm:
-            gain = jnp.full((L, D), seeded_qk_gain(cfg), dtype)
-            out.update(q_norm=gain, k_norm=gain)
-        if cfg.indexer_topk:
-            ikeys = jax.random.split(jax.random.fold_in(rng, 36), 3)
-            Hi, Di, std = cfg.indexer_heads, cfg.indexer_head_dim, seeded_std(cfg, "wq_idx")
-            out.update(wq_idx=norm(ikeys[0], (L, H, Hi * Di), std),
-                       wk_idx=norm(ikeys[1], (L, H, Di), std),
-                       ww_idx=norm(ikeys[2], (L, H, Hi), std),
-                       k_idx_norm=jnp.ones((L, Di), dtype), k_idx_bias=jnp.zeros((L, Di), dtype))
-        return out
+    def matrix(name, shape):
+        return norm(keys[name] if name in keys else next(more), shape, seeded_std(cfg, name))
 
-    params: Params = {
-        "embed": norm(keys[0], (V, H), seeded_std(cfg, "embed")),
-        "layers": {
-            "attn_norm": jnp.ones((L, H), dtype),
-            "wq": norm(keys[1], (L, H, Hq * D)),
-            "wk": norm(keys[2], (L, H, Hkv * D)),
-            "wv": norm(keys[3], (L, H, Hkv * D), seeded_std(cfg, "wv")),
-            "wo": norm(keys[4], (L, Hq * D, H), seeded_std(cfg, "wo")),
-            "moe_norm": jnp.ones((L, H), dtype),
-            "router": norm(keys[5], (L, H, E), seeded_std(cfg, "router")),
-            # Expert FFNs: leading E axis → ep sharding.
-            "wg": norm(keys[6], (L, E, H, I)),
-            "wu": norm(keys[7], (L, E, H, I)),
-            "wd": norm(keys[8], (L, E, I, H)),
-            **extras(),
-        },
-        "final_norm": jnp.ones((H,), dtype),
+    def lead_matrix(name, shape):
+        return norm(next(more), shape, seeded_std(cfg, name))
+
+    layers: Params = {
+        **attention_leaves(cfg, L, dtype, matrix),
+        "moe_norm": jnp.ones((L, H), dtype),
+        "router": matrix("router", (L, H, E)),
+        # Expert FFNs: leading E axis → ep sharding.
+        "wg": matrix("wg", (L, E, H, I)),
+        "wu": matrix("wu", (L, E, H, I)),
+        "wd": matrix("wd", (L, E, I, H)),
     }
+    if cfg.indexer_topk:
+        Hi, Di = cfg.indexer_heads, cfg.indexer_head_dim
+        ikeys = iter(jax.random.split(jax.random.fold_in(rng, 36), 3))
+        imatrix = lambda name, shape: norm(next(ikeys), shape, seeded_std(cfg, name))
+        layers.update(wq_idx=imatrix("wq_idx", (L, H, Hi * Di)), wk_idx=imatrix("wk_idx", (L, H, Di)),
+                      ww_idx=imatrix("ww_idx", (L, H, Hi)),
+                      k_idx_norm=jnp.ones((L, Di), dtype), k_idx_bias=jnp.zeros((L, Di), dtype))
+    if cfg.router_score == "sigmoid":
+        layers["router_bias"] = matrix("router_bias", (L, E))
+    if cfg.shared_experts:
+        Is = cfg.shared_experts * I
+        layers.update(shared_wg=matrix("shared_wg", (L, H, Is)), shared_wu=matrix("shared_wu", (L, H, Is)),
+                      shared_wd=matrix("shared_wd", (L, Is, H)))
+    if cfg.num_dense_layers:
+        Lp, Id = cfg.num_dense_layers, cfg.dense_intermediate_size
+        lead = {**attention_leaves(cfg, Lp, dtype, lead_matrix), "mlp_norm": jnp.ones((Lp, H), dtype),
+                "wg": lead_matrix("wg", (Lp, H, Id)), "wu": lead_matrix("wu", (Lp, H, Id)),
+                "wd": lead_matrix("wd", (Lp, Id, H))}
+        layers.update({PREFIX + n: w for n, w in lead.items()})
+    params: Params = {"embed": matrix("embed", (V, H)), "layers": layers, "final_norm": jnp.ones((H,), dtype)}
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = norm(keys[9], (H, V))
+        params["lm_head"] = matrix("lm_head", (H, V))
     return params
 
 
@@ -118,10 +187,11 @@ EXPERT_LEAVES = ("wg", "wu", "wd")
 
 
 def _moe_block(flat: jnp.ndarray, routed: jnp.ndarray, router, experts: Params, li,
-               cfg: MixtralConfig, valid=None, mesh=None) -> tuple[jnp.ndarray, jnp.ndarray]:
+               cfg: MixtralConfig, valid=None, mesh=None, route=router_topk) -> tuple[jnp.ndarray, jnp.ndarray]:
     """flat: (N, H) the experts' normed input; routed: (N, H) the state
-    the router reads; experts: the stacked expert leaves, read at layer
-    ``li``; mesh: the one they are sharded over (param_specs), or None.
+    the router reads; experts: the stacked expert leaves, read at index
+    ``li`` of their stack; mesh: the one they are sharded over (param_specs),
+    or None; route: who is chosen and what each weighs (ops/moe.py).
     Returns (output (N, H), rows each expert multiplied (E,)). The router
     is never quantised and its logits are float32."""
     with jax.named_scope("moe.router"):
@@ -135,10 +205,10 @@ def _moe_block(flat: jnp.ndarray, routed: jnp.ndarray, router, experts: Params, 
             act = (ACTIVATIONS[cfg.hidden_act](g) * u).astype(inp.dtype)
             return qeinsum("eni,eih->enh", act, lw["wd"], out_dtype=inp.dtype)
 
-        return (moe_dense(flat, logits, cfg.experts_per_token, expert_fn),
+        return (moe_dense(flat, logits, cfg.experts_per_token, expert_fn, route=route),
                 jnp.zeros((cfg.num_experts,), jnp.int32))
     return moe_dropless(flat, logits, cfg.experts_per_token, experts["wg"], experts["wu"],
-                        experts["wd"], li, act=cfg.hidden_act, valid=valid, mesh=mesh)
+                        experts["wd"], li, act=cfg.hidden_act, valid=valid, mesh=mesh, route=route)
 
 
 @partial(jax.jit, static_argnames=("cfg", "mode", "last_only", "mesh"))
@@ -157,14 +227,16 @@ def forward(
     """Same contract as models/llama.forward (contiguous cache, masked
     einsum attention; ``--attention paged`` serves through forward_paged
     and forward_ragged). ``mesh``: the one the experts are sharded over."""
-    if cfg.indexer_topk or cfg.qk_norm:
+    if cfg.indexer_topk:
         raise NotImplementedError(
-            "a model with q/k norms or a sparse-attention indexer is served over the paged cache "
+            "a model with a sparse-attention indexer is served over the paged cache "
             "(--attention paged: the mixed step and the decode chunk); the dense cache has no path for it")
     B, T = tokens.shape
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     L = cfg.num_layers
     x = params["embed"][tokens]
+    if cfg.embed_scale:
+        x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
     inv_freq = rope_inv_freq(cfg.hd, cfg.rope_theta, cfg.rope_scaling_dict)
     cos, sin = rope_cos_sin(positions, inv_freq)
 
@@ -188,18 +260,15 @@ def forward(
             scatter_pos = None
     # One flag a layer, as the shared skeletons read them (llama._rope_qk,
     # _attend_by_window); here both kinds of layer are a mask apart.
-    rope_on = jnp.asarray(cfg.layer_rope or (1,) * L, bool)
     window_on = jnp.asarray(cfg.layer_window or (1,) * L, bool) & bool(cfg.sliding_window)
-    experts = {n: params["layers"][n] for n in EXPERT_LEAVES}
-    scanned = {n: w for n, w in params["layers"].items() if n not in EXPERT_LEAVES}
+    seam = _seam(params, cfg, mesh)
 
-    def layer(x, lp, li, kc, vc):
+    def layer(x, lp, li, kc, vc, ffn):
         h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
         q = qmatmul(h, lp["wq"]).reshape(B, T, Hq, D)
         k = qmatmul(h, lp["wk"]).reshape(B, T, Hkv, D)
         v = qmatmul(h, lp["wv"]).reshape(B, T, Hkv, D)
-        q = jnp.where(rope_on[li], apply_rope(q, cos, sin), q)
-        k = jnp.where(rope_on[li], apply_rope(k, cos, sin), k)
+        q, k = _rope_qk(q, k, cos, sin, cfg, li, lp)
         nk = nv = None
         if kc is not None:
             rows = (jnp.arange(B) if slot_ids is None else slot_ids)[:, None]
@@ -210,26 +279,32 @@ def forward(
             attn = gqa_attend(q, nk.astype(q.dtype), nv.astype(q.dtype), layer_mask)
         else:
             attn = gqa_attend(q, k, v, layer_mask)
-        x = x + qmatmul(attn.reshape(B, T, Hq * D), lp["wo"])
-        y, _ = _moe_ffn(x, h, lp, li, tok_valid, cfg=cfg, experts=experts, mesh=mesh)
-        return x + y, nk, nv
+        x = x + _attn_out(attn.reshape(B, T, Hq * D), h, lp, cfg)
+        y = ffn(x, h, lp, li, tok_valid)
+        return x + _ffn_out(y[0] if isinstance(y, tuple) else y, lp, cfg), nk, nv
 
-    if cache is not None:
-        def body(x, per_layer):
-            lp, li, kc, vc = per_layer
-            x, nk, nv = layer(x, lp, li, kc, vc)
-            return x, (nk, nv)
+    # The leading dense layers, where the model has them, then the expert
+    # layers: one layer index over both groups.
+    new_k, new_v = [], []
+    for leaves, ffn, first, count in _layer_groups(params, seam["ffn"], seam["unscanned"], seam.get("prefix"), L):
+        index = jnp.arange(first, first + count)
+        if cache is not None:
+            def body(x, per_layer, ffn=ffn):
+                lp, li, kc, vc = per_layer
+                x, nk, nv = layer(x, lp, li, kc, vc, ffn)
+                return x, (nk, nv)
 
-        x, (new_k, new_v) = jax.lax.scan(body, x, (scanned, jnp.arange(L), cache["k"], cache["v"]))
-        new_cache = {"k": new_k, "v": new_v}
-    else:
-        def body(x, per_layer):
-            lp, li = per_layer
-            x, _, _ = layer(x, lp, li, None, None)
-            return x, None
+            x, (nk, nv) = jax.lax.scan(body, x, (leaves, index, cache["k"][first:first + count],
+                                                 cache["v"][first:first + count]))
+            new_k.append(nk)
+            new_v.append(nv)
+        else:
+            def body(x, per_layer, ffn=ffn):
+                lp, li = per_layer
+                return layer(x, lp, li, None, None, ffn)[0], None
 
-        x, _ = jax.lax.scan(body, x, (scanned, jnp.arange(L)))
-        new_cache = None
+            x, _ = jax.lax.scan(body, x, (leaves, index))
+    new_cache = None if cache is None else {"k": jnp.concatenate(new_k), "v": jnp.concatenate(new_v)}
 
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     if last_only:
@@ -245,13 +320,25 @@ def forward(
 def _moe_ffn(x: jnp.ndarray, attn_in: jnp.ndarray, lp: Params, li, valid, *,
              cfg: MixtralConfig, experts: Params, mesh=None):
     """The FFN seam of the shared skeletons (llama.forward_paged_impl):
-    norm + mixture; the auxiliary is the rows each expert multiplied."""
+    norm + mixture (+ the shared expert, added once); the auxiliary is the
+    rows each expert multiplied. ``li`` is the layer's index in the model;
+    the expert stacks begin behind the leading dense layers."""
     B, T, H = x.shape
     h = rms_norm(x, lp["moe_norm"], cfg.rms_norm_eps)
     routed = attn_in if cfg.router_input == "attn" else h
+    route = router_topk
+    if cfg.router_score == "sigmoid":
+        route = partial(router_sigmoid, bias=lp["router_bias"], scale=cfg.route_scale)
+    # (No subtraction where nothing leads: an older mixture traces as it did.)
+    stack_index = li - cfg.num_dense_layers if cfg.num_dense_layers else li
     out, rows = _moe_block(h.reshape(B * T, H), routed.reshape(B * T, H), lp["router"],
-                           experts, li, cfg, valid, mesh)
-    return out.reshape(B, T, H), rows
+                           experts, stack_index, cfg, valid, mesh, route)
+    out = out.reshape(B, T, H)
+    if cfg.shared_experts:
+        with jax.named_scope("moe.shared"):
+            act = ACTIVATIONS[cfg.hidden_act]
+            out = out + qmatmul(act(qmatmul(h, lp["shared_wg"])) * qmatmul(h, lp["shared_wu"]), lp["shared_wd"])
+    return out, rows
 
 
 def _indexer(h: jnp.ndarray, lp: Params, positions: jnp.ndarray, cfg: MixtralConfig):
@@ -317,15 +404,40 @@ def _dsa_ragged(h, q, lp, li, positions, pools, w_idx, table, q_starts, q_lens, 
 
 def _seam(params: Params, cfg: MixtralConfig, mesh, valid=None) -> dict:
     """What the shared skeletons take for a mixture: the FFN seam, the
-    leaves it reads whole by layer index (never sliced per layer), and for
-    a model with an indexer the attention seam."""
+    leaves it reads whole by layer index (never sliced per layer), for a
+    model with leading dense layers their group, and for a model with an
+    indexer the attention seam."""
     experts = {n: params["layers"][n] for n in EXPERT_LEAVES}
     seam = {"ffn": partial(_moe_ffn, cfg=cfg, experts=experts, mesh=mesh), "unscanned": EXPERT_LEAVES}
+    if cfg.num_dense_layers:
+        # The leading dense layers: a scanned group of their own in front.
+        lead = prefix_leaves(params["layers"])
+        seam["prefix"] = {"layers": lead, "ffn": partial(_dense_ffn, cfg=cfg)}
+        seam["unscanned"] = EXPERT_LEAVES + tuple(PREFIX + n for n in lead)
     if cfg.indexer_topk:
         seam["mixer"] = {"cache": DSA_CACHE,
                          "decode": partial(_dsa_decode, cfg=cfg, valid=valid),
                          "ragged": partial(_dsa_ragged, cfg=cfg)}
     return seam
+
+
+def windowed_layers(cfg: MixtralConfig) -> int:
+    """How many of the model's layers see only ``sliding_window`` keys."""
+    if not cfg.sliding_window:
+        return 0
+    return sum(cfg.layer_window) if cfg.layer_window else cfg.num_layers
+
+
+def counts_window_pages(cfg: MixtralConfig) -> bool:
+    """A mixture with windowed layers counts, in both step programs, the pages
+    those layers read and hold (ISSUE 41; not one with an indexer: its
+    attention reads what the indexer chooses)."""
+    return windowed_layers(cfg) > 0 and not cfg.indexer_topk
+
+
+def counted_window_pages(cfg: MixtralConfig, page_size: int, first_len, last_len):
+    """``llama.window_pages`` over this model's windowed layers."""
+    return window_pages(windowed_layers(cfg), cfg.sliding_window, page_size, first_len, last_len)
 
 
 def init_paged_cache(cfg: MixtralConfig, cache_cfg, dtype=jnp.bfloat16) -> Params:
@@ -360,10 +472,16 @@ def forward_paged(
     """Paged-KV MoE serving: attention and paging are the shared
     skeleton (llama.forward_paged_impl); experts ride the seam. With
     ``with_aux`` a third result is the rows each expert multiplied,
-    (L, E); ``valid`` (B,) marks the decode rows that carry a stream."""
-    return forward_paged_impl(params, cfg, tokens, positions, lengths, cache,
-                              write_idx, page_table, mode, last_only, mesh,
-                              valid=valid, with_aux=with_aux, **_seam(params, cfg, mesh, valid))
+    (expert layers, E) (with an indexer, or windowed layers: a pair, that
+    and the seam's counts or ``counted_window_pages``); ``valid`` (B,)
+    marks the decode rows that carry a stream."""
+    out = forward_paged_impl(params, cfg, tokens, positions, lengths, cache,
+                             write_idx, page_table, mode, last_only, mesh,
+                             valid=valid, with_aux=with_aux, **_seam(params, cfg, mesh, valid))
+    if not (with_aux and counts_window_pages(cfg)):
+        return out
+    live = lengths if valid is None else jnp.where(valid, lengths, 0)
+    return (*out[:2], (out[2], counted_window_pages(cfg, cache["k"].shape[2], live, live)))
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "with_aux"))
@@ -381,10 +499,15 @@ def forward_ragged(
     mesh=None,
     with_aux: bool = False,
 ):
-    """The mixed step for a mixture (llama.forward_ragged's contract)."""
-    return forward_ragged_impl(params, cfg, tokens, positions, cache, write_idx,
-                               page_table, q_starts, q_lens, kv_lens, mesh,
-                               with_aux=with_aux, **_seam(params, cfg, mesh))
+    """The mixed step for a mixture (llama.forward_ragged's contract; the
+    third result as ``forward_paged``'s)."""
+    out = forward_ragged_impl(params, cfg, tokens, positions, cache, write_idx,
+                              page_table, q_starts, q_lens, kv_lens, mesh,
+                              with_aux=with_aux, **_seam(params, cfg, mesh))
+    if not (with_aux and counts_window_pages(cfg)):
+        return out
+    live = jnp.where(q_lens > 0, kv_lens, 0)
+    return (*out[:2], (out[2], counted_window_pages(cfg, cache["k"].shape[2], live - q_lens + 1, live)))
 
 
 def param_specs(cfg: MixtralConfig) -> dict:
@@ -441,6 +564,20 @@ def _keye(num_layers: int, **sizes) -> MixtralConfig:
     return MixtralConfig(num_layers=num_layers, qk_norm=True, rms_norm_eps=1e-6, **sizes)
 
 
+def _trinity(num_layers: int, **sizes) -> MixtralConfig:
+    """Trinity-Mini's block (ISSUE 41, ``afmoe``): two leading dense layers,
+    then expert layers routed by sigmoid scores with a selection bias, one
+    shared expert beside the chosen; the attention's output gated, q/k norms,
+    every sublayer's output normed; the embedding times sqrt(hidden); of
+    every four layers three windowed with rotary, then one global with no
+    position encoding (``layer_types``; the modelling code gives global
+    layers no rotary)."""
+    layout = tuple(int(i % 4 != 3) for i in range(num_layers))
+    return MixtralConfig(num_layers=num_layers, num_dense_layers=2, layer_rope=layout, layer_window=layout,
+                         qk_norm=True, attn_gate=True, sandwich_norm=True, embed_scale=True,
+                         router_score="sigmoid", shared_experts=1, rms_norm_eps=1e-5, **sizes)
+
+
 PRESETS: dict[str, MixtralConfig] = {
     "mixtral-test-tiny": MixtralConfig(
         vocab_size=256, hidden_size=64, num_layers=2, num_heads=4, num_kv_heads=2,
@@ -476,4 +613,21 @@ PRESETS: dict[str, MixtralConfig] = {
         8, vocab_size=151936, hidden_size=2048, num_heads=32, num_kv_heads=4, head_dim=128,
         intermediate_size=768, num_experts=128, experts_per_token=8, rope_theta=10000000.0,
         max_position_embeddings=262144, indexer_heads=16, indexer_head_dim=64, indexer_topk=2048),
+    # The stage's own shape at toy widths: 2 dense + 6 expert layers, two
+    # periods of S,S,S,F (the first straddles the two stacks), a window
+    # shorter than the tests' sequences; route_scale sqrt(2) for its top-2 as
+    # the published 2.826 is sqrt(8) for top-8: the routed sum of unit experts
+    # has unit size at either.
+    "trinity-test-tiny": _trinity(
+        8, vocab_size=256, hidden_size=64, num_heads=4, num_kv_heads=2, head_dim=16,
+        intermediate_size=32, dense_intermediate_size=96, num_experts=8, experts_per_token=2,
+        route_scale=1.414, rope_theta=10000.0, max_position_embeddings=512, sliding_window=16),
+    # arcee-ai/Trinity-Mini is 32 layers, 26.5 GB of int8: this is the first
+    # of four pipeline stages of 8 (the 2 leading dense layers + 6 expert
+    # layers: two whole periods), every width, all 128 experts and the whole
+    # vocabulary as published, which one v5e serves.
+    "trinity-mini-stage8": _trinity(
+        8, vocab_size=200192, hidden_size=2048, num_heads=32, num_kv_heads=4, head_dim=128,
+        intermediate_size=1024, dense_intermediate_size=6144, num_experts=128, experts_per_token=8,
+        route_scale=2.826, rope_theta=10000.0, max_position_embeddings=131072, sliding_window=2048),
 }

@@ -55,6 +55,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from inference_gateway_tpu.models import llama
 from inference_gateway_tpu.ops import ssm
 from inference_gateway_tpu.ops.quant import qmatmul, seeded_std
 
@@ -418,16 +419,8 @@ def _head(params: Params, cfg: Phi4FlashConfig, x):
 
 
 def window_pages(cfg: Phi4FlashConfig, page_size: int, first_len, last_len):
-    """What a step's windowed layers read of the pool, and what their rows'
-    contexts hold, in pages over all ``mid`` of them: a row whose queries
-    see lengths first_len..last_len (0 = no row) reads the pages from the
-    one holding position first_len - window on, as both kernels do.
-    Returns float32 (2,): window_pages_read, context_pages."""
-    live = last_len > 0
-    held = (last_len + page_size - 1) // page_size
-    skipped = jnp.maximum(first_len - cfg.sliding_window, 0) // page_size
-    read = jnp.sum(jnp.where(live, held - skipped, 0))
-    return cfg.mid * jnp.stack([read, jnp.sum(jnp.where(live, held, 0))]).astype(jnp.float32)
+    """``llama.window_pages`` for this model's ``mid`` windowed layers."""
+    return llama.window_pages(cfg.mid, cfg.sliding_window, page_size, first_len, last_len)
 
 
 @partial(jax.jit, static_argnames=("cfg", "mode", "last_only", "mesh", "with_aux"))

@@ -54,14 +54,31 @@ def router_topk(logits: jnp.ndarray, top_k: int) -> tuple[jnp.ndarray, jnp.ndarr
     return weights, idx
 
 
-def moe_dense(x: jnp.ndarray, router_logits: jnp.ndarray, top_k: int, expert_fn) -> jnp.ndarray:
+def router_sigmoid(logits: jnp.ndarray, top_k: int, *, bias: jnp.ndarray, scale: float
+                   ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Top-k routing over sigmoid scores with a selection bias (ISSUE 41).
+
+    logits: (N, E) float32; bias: (E,). The chosen are the ``top_k`` largest
+    of ``sigmoid(logits) + bias``; their weights are the scores WITHOUT the
+    bias, normalised over the chosen and multiplied by ``scale``. The bias
+    decides who is chosen and never what a chosen expert weighs.
+    """
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)  # (N, k)
+    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    return weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20) * scale, idx
+
+
+def moe_dense(x: jnp.ndarray, router_logits: jnp.ndarray, top_k: int, expert_fn,
+              route=router_topk) -> jnp.ndarray:
     """Exact MoE: run all experts, combine by routing weight.
 
-    x: (N, H); router_logits: (N, E); expert_fn: (E, N, H) -> (E, N, H).
+    x: (N, H); router_logits: (N, E); expert_fn: (E, N, H) -> (E, N, H);
+    ``route``: the routing seam of ``moe_dropless``.
     """
     N, H = x.shape
     E = router_logits.shape[-1]
-    weights, idx = router_topk(router_logits, top_k)  # (N, k)
+    weights, idx = route(router_logits, top_k)  # (N, k)
     # Scatter top-k weights into a dense (N, E) combine matrix.
     combine = jnp.zeros((N, E), jnp.float32)
     combine = combine.at[jnp.arange(N)[:, None], idx].add(weights)
@@ -311,6 +328,7 @@ def moe_dropless(
     valid: jnp.ndarray | None = None,  # (N,) bool: rows that carry a token
     mesh=None,  # the mesh the stacks are sharded over (experts on "ep", each split on "tp")
     kernel: bool | None = None,  # None: the Pallas kernel on a TPU
+    route=router_topk,  # (logits, top_k) -> (weights (N, k) float32, idx (N, k)): who is chosen, and what each weighs
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Every chosen (token, expert) pair, computed once. Returns the
     layer's output (N, H) and how many rows each expert multiplied (E,):
@@ -320,7 +338,7 @@ def moe_dropless(
     back zero."""
     E = router_logits.shape[-1]
     with jax.named_scope("moe.router"):
-        weights, idx = router_topk(router_logits, top_k)  # (N, k)
+        weights, idx = route(router_logits, top_k)  # (N, k)
         expert = idx.reshape(-1).astype(jnp.int32)  # (N*k,) token-major
         if valid is not None:
             expert = jnp.where(jnp.repeat(valid, top_k), expert, E)

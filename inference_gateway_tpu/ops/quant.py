@@ -146,6 +146,12 @@ QUANTIZABLE = ("wq", "wk", "wv", "wo", "wg", "wu", "wd",
                # ISSUE 36); its key and weight projections, (H, 64) and (H, 16), and
                # the norms stay full precision.
                "wq_idx",
+               # Trinity-Mini's block (models/mixtral.py, ISSUE 41): the attention's
+               # output gate, the shared expert, and the leading dense layers' stack
+               # (``mixtral.PREFIX`` + a dense layer's matmul names).
+               "attn_gate", "shared_wg", "shared_wu", "shared_wd",
+               "dense_wq", "dense_wk", "dense_wv", "dense_wo", "dense_attn_gate",
+               "dense_wg", "dense_wu", "dense_wd",
                # models/phi4flash.py's matmul leaves (its QUANT_LEAVES): the MLP,
                # the Mamba mixer's and the gated memory unit's projections, the
                # fused key/value projection. W_x, W_dt, the convolution, A_log, D,
@@ -240,14 +246,74 @@ def seeded_std(cfg, leaf: str) -> float:
                   "wv": cfg.hidden_size, "wo": cfg.num_heads * cfg.hd}.get(leaf)
         if fan_in:
             return fan_in ** -0.5
+    if getattr(cfg, "sandwich_norm", False):
+        return _sandwich_std(cfg, leaf)
     if getattr(cfg, "num_experts", 0):
         return {"embed": 1.0, "wo": 0.005, "router": 0.06}.get(leaf, 0.02)
     return 0.02
 
 
+def _sandwich_std(cfg, leaf: str) -> float:
+    """``seeded_std`` for a model whose sublayers' OUTPUTS are normed
+    (ISSUE 41, Trinity-Mini's block). Every sublayer then adds a vector of
+    RMS 1 (the post-norm's gain) whatever its matrices' scales, so the
+    scales that matter are those in front of a nonlinearity and the SHARES
+    inside a sublayer, each given per fan-in so that the 64-wide test preset
+    and the published 2,048 are in one regime:
+
+    - the embedding at hidden ** -0.5: the model multiplies it by
+      sqrt(hidden), so the stream starts at RMS 1 and each of the sixteen
+      sublayers of a stage of eight is a seventeenth of what the head reads.
+      (At the mixtures' 1.0 it would start at RMS 45 and no sublayer would
+      show in a logit.)
+    - the head at 2 x hidden ** -0.5: logits that spread by 2. The scale of
+      every difference below follows it; at 2 a sound run reads a fifth to a
+      third of the benchmark's limits and the thinnest control twice them
+      (at 1.25 the chip read 0.096 / 0.025 sound and 0.43 / 0.147 for the bias
+      dropped from the selection: PERF.md section 6, PR 41).
+    - the attention's output gate at 2 x hidden ** -0.5: its logits spread
+      by 2, the gate runs over 0.1-0.9, and an output that is not gated is
+      another vector (cosine 0.85).
+    - the router at hidden ** -0.5: logits spread by 1, sigmoid scores over
+      0.3-0.7 with the chosen eight of 128 at 0.8-0.9, within a factor two
+      of each other: the eighth weighs an eighth of the routed sum whatever
+      the router's scale.
+    - the selection bias at 0.04, against a spacing of 0.01 between the
+      scores nearest the cut of 128 experts: it changes 1.8 of a token's
+      eight and sends no token to a fixed eight (at 0.06 a 544-token step read
+      116-123 of 128 experts a layer where an unbiased router reads 126-128,
+      and 32 rows x 8 choices 70-84 for 84-95: sandbox, float32; independent
+      tokens would read 111, the stream's common component costs the rest).
+    - **the shared expert's down projection at 4 x 0.02**, so that the routed
+      sum is a quarter of the sublayer's output and one expert swapped is a
+      tenth of it (0.5 / sqrt(17)). With seeded weights the experts are
+      independent random functions, and routing over them is CHAOTIC where a
+      swap weighs more: bf16's rounding of the stream swaps one expert in
+      ~3% of the (token, layer) pairs; at equal shares (a swap = 0.35 of the
+      sublayer) that perturbation swaps two more experts in the next expert
+      layer, three in the one after, and 6% of the tokens end 0.3-0.9 nats
+      from a float32 reference (the benchmark's limit is 0.45; measured 0.44
+      on the chip and 0.91 in the sandbox, PERF.md section 6, PR 41). At a
+      quarter the cascade dies out (largest 0.16-0.29 over 96 tokens), and a
+      reference that drops the bias from the selection, which swaps about
+      two experts in EVERY token of every layer, still reads 3.3 times the
+      sound one's mean in the sandbox and 6 times on the chip. A trained mixture is smooth across a routing boundary;
+      seeded experts are not, so their share has to carry it.
+    - every other matrix at 0.02; the q and k norms' gains at 1.4
+      (``seeded_qk_gain``): a head rests on a few dozen keys, so which keys
+      it sees (a window, a rotation) shows.
+    """
+    fan_in = cfg.hidden_size ** -0.5
+    return {"embed": fan_in, "lm_head": 2 * fan_in, "attn_gate": 2 * fan_in, "router": fan_in,
+            "router_bias": 0.04, "shared_wd": 0.08}.get(leaf, 0.02)
+
+
 def seeded_qk_gain(cfg) -> float:
     """What a seeded build writes into ``q_norm`` and ``k_norm``: 1, and 1.4
-    for a model with a sparse-attention indexer. A normed query times a normed
+    for a model with a sparse-attention indexer and for one whose sublayers'
+    outputs are normed (ISSUE 41: what attention adds is then a vector of RMS 1
+    whichever keys it rests on, and only a head that rests on FEW keys makes a
+    window or a missing rotation show). A normed query times a normed
     key over sqrt(head size) spreads by the product of the two gains whatever
     the head size: at 1 a seeded head weighs thousands of keys nearly alike
     and its output is their mean whichever quarter of them was chosen; at 1.4
@@ -257,7 +323,7 @@ def seeded_qk_gain(cfg) -> float:
     whole heads, and served logprobs read 0.27 and 0.52 nats from the float32
     reference over two runs on the chip (PERF.md section 6, PR 36), past the
     benchmark's limit; at 1.4 they read 0.07."""
-    return 1.4 if getattr(cfg, "indexer_topk", 0) else 1.0
+    return 1.4 if getattr(cfg, "indexer_topk", 0) or getattr(cfg, "sandwich_norm", False) else 1.0
 
 
 def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
@@ -296,7 +362,9 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
             raise ValueError("phi4flash: int8 is the one weight-only quantisation built for it")
         return phi4flash.init_params(rng, cfg, dtype=dtype, quant=quantize_tensor)
 
-    L, H, I, V = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
+    # A mixture's leading dense layers are a stack of their own (ISSUE 41).
+    L = getattr(cfg, "expert_layers", cfg.num_layers)
+    H, I, V = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     keys = jax.random.split(rng, 8)
     quant = quantize_tensor if mode == "int8" else (
@@ -310,7 +378,7 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
         return quant(norm(key, shape, std))
 
     def qstack(key, shape, std=0.02):
-        per = [qinit(jax.random.fold_in(key, layer), shape[1:], std) for layer in range(L)]
+        per = [qinit(jax.random.fold_in(key, layer), shape[1:], std) for layer in range(shape[0])]
         q = jnp.stack([p.q for p in per])
         scale = jnp.stack([p.scale for p in per])
         return type(per[0])(q, scale)
@@ -324,31 +392,35 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
         """qstack for a leaf too large to hold twice: the stacked leaf is
         allocated once and each layer's block is written into it."""
         block = jax.eval_shape(lambda: qinit(key, shape[1:]))
-        stacked = jax.tree.map(lambda b: jnp.zeros((L,) + b.shape, b.dtype), block)
-        for layer in range(L):
+        stacked = jax.tree.map(lambda b: jnp.zeros((shape[0],) + b.shape, b.dtype), block)
+        for layer in range(shape[0]):
             stacked = put(stacked, qinit(jax.random.fold_in(key, layer), shape[1:]), layer)
         return stacked
 
-    layers = {
-        "attn_norm": jnp.ones((L, H), dtype),
-        "wq": qstack(keys[1], (L, H, Hq * D)),
-        "wk": qstack(keys[2], (L, H, Hkv * D)),
-        "wv": qstack(keys[3], (L, H, Hkv * D), seeded_std(cfg, "wv")),
-        "wo": qstack(keys[4], (L, Hq * D, H), seeded_std(cfg, "wo")),
-    }
     E = getattr(cfg, "num_experts", 0)
     if E:
+        from inference_gateway_tpu.models import mixtral
+
+        # One key a leaf the older mixtures have, as they always drew them; the
+        # leaves ISSUE 41 added draw from a stream of their own.
+        named = {"wq": keys[1], "wk": keys[2], "wv": keys[3], "wo": keys[4]}
+        more = iter(jax.random.split(jax.random.fold_in(rng, 41), 24))
+
+        def matrix(name, shape):
+            return qstack(named[name] if name in named else next(more), shape, seeded_std(cfg, name))
+
+        def lead_matrix(name, shape):
+            return qstack(next(more), shape, seeded_std(cfg, name))
+
         ekeys = jax.random.split(jax.random.fold_in(rng, 7), 4)
-        layers.update({
+        layers = {
+            **mixtral.attention_leaves(cfg, L, dtype, matrix),
             "moe_norm": jnp.ones((L, H), dtype),
             "router": norm(ekeys[0], (L, H, E), seeded_std(cfg, "router")),
             "wg": qfill(ekeys[1], (L, E, H, I)),
             "wu": qfill(ekeys[2], (L, E, H, I)),
             "wd": qfill(ekeys[3], (L, E, I, H)),
-        })
-        if cfg.qk_norm:
-            gain = jnp.full((L, D), seeded_qk_gain(cfg), dtype)
-            layers.update(q_norm=gain, k_norm=gain)
+        }
         if cfg.indexer_topk:
             ikeys = jax.random.split(jax.random.fold_in(rng, 36), 3)
             Hi, Di, std = cfg.indexer_heads, cfg.indexer_head_dim, seeded_std(cfg, "wq_idx")
@@ -356,13 +428,30 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
                           wk_idx=norm(ikeys[1], (L, H, Di), std),
                           ww_idx=norm(ikeys[2], (L, H, Hi), std),
                           k_idx_norm=jnp.ones((L, Di), dtype), k_idx_bias=jnp.zeros((L, Di), dtype))
+        if cfg.router_score == "sigmoid":
+            layers["router_bias"] = norm(next(more), (L, E), seeded_std(cfg, "router_bias"))
+        if cfg.shared_experts:
+            Is = cfg.shared_experts * I
+            layers.update(shared_wg=matrix("shared_wg", (L, H, Is)), shared_wu=matrix("shared_wu", (L, H, Is)),
+                          shared_wd=matrix("shared_wd", (L, Is, H)))
+        if cfg.num_dense_layers:
+            Lp, Id = cfg.num_dense_layers, cfg.dense_intermediate_size
+            lead = {**mixtral.attention_leaves(cfg, Lp, dtype, lead_matrix), "mlp_norm": jnp.ones((Lp, H), dtype),
+                    "wg": lead_matrix("wg", (Lp, H, Id)), "wu": lead_matrix("wu", (Lp, H, Id)),
+                    "wd": lead_matrix("wd", (Lp, Id, H))}
+            layers.update({mixtral.PREFIX + n: w for n, w in lead.items()})
     else:
-        layers.update({
+        layers = {
+            "attn_norm": jnp.ones((L, H), dtype),
+            "wq": qstack(keys[1], (L, H, Hq * D)),
+            "wk": qstack(keys[2], (L, H, Hkv * D)),
+            "wv": qstack(keys[3], (L, H, Hkv * D), seeded_std(cfg, "wv")),
+            "wo": qstack(keys[4], (L, Hq * D, H), seeded_std(cfg, "wo")),
             "mlp_norm": jnp.ones((L, H), dtype),
             "wg": qstack(keys[5], (L, H, I)),
             "wu": qstack(keys[6], (L, H, I)),
             "wd": qstack(keys[7], (L, I, H)),
-        })
+        }
     params = {"embed": norm(keys[0], (V, H), seeded_std(cfg, "embed")), "layers": layers,
               "final_norm": jnp.ones((H,), dtype)}
     if cfg.qkv_bias:
@@ -370,7 +459,7 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
         params["layers"]["bk"] = jnp.zeros((L, Hkv * D), dtype)
         params["layers"]["bv"] = jnp.zeros((L, Hkv * D), dtype)
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = qinit(jax.random.fold_in(rng, 99), (H, V))
+        params["lm_head"] = qinit(jax.random.fold_in(rng, 99), (H, V), seeded_std(cfg, "lm_head"))
     return params
 
 

@@ -337,6 +337,12 @@ def has_indexer(model_cfg) -> bool:
     return bool(getattr(model_cfg, "indexer_topk", 0))
 
 
+def has_dense_prefix(model_cfg) -> bool:
+    """A mixture whose first layers are dense, a stack of their own beside
+    the expert layers' (models/mixtral.py, ISSUE 41)."""
+    return bool(getattr(model_cfg, "num_dense_layers", 0))
+
+
 def _experts_read(rows: jnp.ndarray) -> jnp.ndarray:
     """rows (L, E): how many rows each expert of each layer multiplied in
     one step -> the distinct experts a layer read, mean over layers."""
@@ -374,6 +380,14 @@ def choose_mesh(config: EngineConfig, model_cfg, n_dev: int):
             raise ValueError(
                 f"{config.model}: no mesh ({shape}) carries the sparse-attention indexer and its "
                 "key cache yet; serve it on one device")
+        return None, False
+    if has_dense_prefix(model_cfg):
+        # Nor two stacks of layers, an output gate and a shared expert
+        # (ISSUE 41: models/mixtral.param_specs lays out neither): the same rule.
+        if shape:
+            raise ValueError(
+                f"{config.model}: no mesh ({shape}) lays out its leading dense layers' stack, its "
+                "attention gate and its shared expert yet; serve it on one device")
         return None, False
     if shape.get("pp", 1) > 1:
         # Pipeline-parallel serving (SURVEY §2.4 PP row). Only the
@@ -565,6 +579,15 @@ class Engine:
             if config.vision_model is not None:
                 raise ValueError(f"{config.model}: its vision tower is not built (the published config "
                                  "gives no width for it); image input has no path, serve text")
+            if config.quantize == "int4":
+                raise ValueError(f"{config.model}: int8 is the one weight-only quantisation built for it, not int4")
+        if has_dense_prefix(self.model_cfg):
+            # What the two stacks' path does not carry is refused by name (ISSUE 41).
+            if config.spec_draft is not None:
+                raise ValueError(f"{config.model}: speculative decoding has no verify pass over its two stacks "
+                                 "of layers; serve it without --spec-draft")
+            if config.vision_model is not None:
+                raise ValueError(f"{config.model}: it is a text model; image input has no path, serve text")
             if config.quantize == "int4":
                 raise ValueError(f"{config.model}: int8 is the one weight-only quantisation built for it, not int4")
         if self.is_hybrid:
@@ -885,12 +908,15 @@ class Engine:
         }
         # What a paged decode chunk (``_run_chunk_ee``) and a mixed step count
         # beside their tokens, a row of the fetch block each.
-        dsa_stats = DSA_STATS if self.is_dsa else ()
+        # A mixture with windowed layers carries MIXED_STATS and WINDOW_STATS
+        # together (ISSUE 41); one with an indexer DSA_STATS behind its own.
+        self.counts_window = self.is_moe and mixtral.counts_window_pages(self.model_cfg)
+        more_stats = (DSA_STATS if self.is_dsa else ()) + (WINDOW_STATS if self.counts_window else ())
         if self.is_hybrid:
             self._chunk_stats = self._mixed_stats = WINDOW_STATS
         else:
-            self._chunk_stats = ("experts_read",) + dsa_stats if self.is_moe else ()
-            self._mixed_stats = MIXED_STATS + dsa_stats
+            self._chunk_stats = ("experts_read",) + more_stats if self.is_moe else ()
+            self._mixed_stats = MIXED_STATS + more_stats
         if self.is_hybrid:
             # Segments that started at position 0, so from a zeroed state:
             # every admission, and every resume after a preemption.
@@ -1148,9 +1174,9 @@ class Engine:
                 params, self.model_cfg, tok[:, None], pos_att[:, None],
                 att_len, cache, w[:, None], page_table, mode="decode",
                 last_only=True, mesh=self.mesh, **counted)
-            if rows and self.is_moe:  # ``_chunk_stats``: a mixture's experts read, an indexer's counts
-                per, dsa = self._split_aux(rows[0])
-                read = jnp.concatenate([_experts_read(per)[None], dsa])
+            if rows and self.is_moe:  # ``_chunk_stats``: a mixture's experts read, an indexer's or a window's counts
+                per, more = self._split_aux(rows[0])
+                read = jnp.concatenate([_experts_read(per)[None], more])
             elif rows:  # a windowed hybrid's pages (WINDOW_STATS)
                 read = rows[0]
         if masked:
@@ -1356,11 +1382,12 @@ class Engine:
             # Rows of the fetch block, in MIXED_STATS' order: per layer the
             # most rows one expert multiplied and the mean over experts,
             # and the experts read; each a mean over the layers.
-            # Then DSA_STATS, counted by the attention seam.
-            per, dsa = self._split_aux(rows[0])
+            # Then DSA_STATS, counted by the attention seam, or WINDOW_STATS,
+            # counted by the model.
+            per, more = self._split_aux(rows[0])
             per = per.astype(jnp.float32)  # (L, E)
             stats = jnp.stack([jnp.mean(jnp.max(per, axis=1)), jnp.mean(per), _experts_read(per)])
-            return toks, logprobs, cache, jnp.concatenate([stats, dsa])
+            return toks, logprobs, cache, jnp.concatenate([stats, more])
         return toks, logprobs, cache
 
     @partial(jax.jit, static_argnames=("self", "masked"), donate_argnums=(2,))
@@ -1528,11 +1555,34 @@ class Engine:
         }
 
     def _split_aux(self, aux):
-        """A mixture's per-layer auxiliary -> (the rows each expert multiplied
-        (L, E), DSA_STATS summed over the layers: none without an indexer)."""
+        """A mixture's auxiliary -> (the rows each expert multiplied (expert
+        layers, E), the step's further counts: DSA_STATS summed over the layers,
+        or WINDOW_STATS, or none)."""
         if self.is_dsa:  # the pair (the FFN's, the attention seam's (L, 2))
             return aux[0], jnp.sum(aux[1], axis=0)
+        if self.counts_window:  # the pair (the FFN's, the windowed layers' pages (2,))
+            return aux
         return aux, jnp.zeros((0,), jnp.float32)
+
+    def mixture_status(self) -> dict | None:
+        """/debug/status fields of a mixture (static): ``router`` (how it
+        scores, chooses and weighs), ``shared_experts`` beside the routed
+        ones, ``dense_prefix`` (leading dense layers), and for one with
+        windowed layers ``cache_layers`` (how many of its layers read a
+        window of the pool, how many all of it)."""
+        if not self.is_moe:
+            return None
+        cfg = self.model_cfg
+        out = {"router": {"score": cfg.router_score, "experts": cfg.num_experts,
+                          "experts_per_token": cfg.experts_per_token, "route_scale": cfg.route_scale,
+                          "chosen_by": "score + bias" if cfg.router_score == "sigmoid" else "logit",
+                          "weights": ("the chosen scores, normalised" if cfg.router_score == "sigmoid"
+                                      else "softmax over the chosen logits")},
+               "shared_experts": cfg.shared_experts, "dense_prefix": cfg.num_dense_layers}
+        if self.counts_window:
+            windowed = self._model.windowed_layers(cfg)
+            out["cache_layers"] = {"window": windowed, "full": cfg.num_layers - windowed}
+        return out
 
     def dsa_status(self) -> dict | None:
         """/debug/status ``dsa`` of a model with a sparse-attention indexer:

@@ -87,19 +87,31 @@ def llama_param_count(cfg: llama.LlamaConfig) -> int:
 
 
 def mixtral_param_count(cfg: mixtral.MixtralConfig) -> int:
+    """Every layer's attention (with an output gate and post-norms where the
+    model has them), the expert layers' mixture (+ a shared expert), and a
+    leading dense stack's MLPs (ISSUE 41: the two stacks are counted apart)."""
     H, I, V, E = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size, cfg.num_experts
     Hkv_D = cfg.num_kv_heads * cfg.hd
     Hq_D = cfg.num_heads * cfg.hd
+    attention = H * Hq_D + 2 * H * Hkv_D + Hq_D * H + 2 * H  # q, k, v, o; input norms of both sublayers
+    if cfg.attn_gate:
+        attention += H * Hq_D
+    if cfg.sandwich_norm:
+        attention += 2 * H
+    if cfg.qk_norm:
+        attention += 2 * cfg.hd
     per_layer = (
-        H * Hq_D + 2 * H * Hkv_D + Hq_D * H
+        attention
         + E * 3 * H * I  # experts
         + H * E  # router
-        + 2 * H
+        + cfg.shared_experts * 3 * H * I
+        + (E if cfg.router_score == "sigmoid" else 0)  # the selection bias
     )
-    if cfg.indexer_topk:  # the sparse attention's indexer and the q/k norms beside it (ISSUE 36)
+    if cfg.indexer_topk:  # the sparse attention's indexer (ISSUE 36)
         Hi, Di = cfg.indexer_heads, cfg.indexer_head_dim
-        per_layer += H * (Hi * Di + Di + Hi) + 2 * Di + 2 * cfg.hd
-    return V * H + cfg.num_layers * per_layer + H + V * H
+        per_layer += H * (Hi * Di + Di + Hi) + 2 * Di
+    dense_layer = attention + 3 * H * cfg.dense_intermediate_size
+    return V * H + cfg.expert_layers * per_layer + cfg.num_dense_layers * dense_layer + H + V * H
 
 
 def kv_bytes_per_token(cfg, dtype_bytes: int = 2) -> int:
@@ -146,7 +158,7 @@ def hbm_plan(profile: ServingProfile) -> dict:
     embed_params = cfg.vocab_size * cfg.hidden_size
     if is_moe:
         n_params = mixtral_param_count(cfg)
-        expert_params = cfg.num_layers * cfg.num_experts * 3 * cfg.hidden_size * cfg.intermediate_size
+        expert_params = cfg.expert_layers * cfg.num_experts * 3 * cfg.hidden_size * cfg.intermediate_size
         dense_q_params = n_params - expert_params - embed_params
         weights_per_chip = int(
             embed_params * 2 // tp + dense_q_params * wbytes // tp

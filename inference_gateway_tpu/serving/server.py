@@ -990,6 +990,7 @@ class SidecarServer:
                 brief["device"] = self.observatory.fleet_summary()
             return Response.json(brief)
         state_status = getattr(self.engine, "state_status", None)  # test doubles have none
+        mixture_status = getattr(self.engine, "mixture_status", None)
         status: dict[str, Any] = {
             "model": self.model_name,
             "uptime_seconds": round(self._clock.now() - self._started, 3),
@@ -1041,6 +1042,12 @@ class SidecarServer:
             # and `mixed` timeline records carry `dsa_context_tokens` and
             # `dsa_kv_tokens_read`.
             **({"dsa": self.engine.dsa_status()} if getattr(self.engine, "is_dsa", False) else {}),
+            # A mixture (ISSUE 41): ``router`` (score, chosen_by, weights),
+            # ``shared_experts``, ``dense_prefix``, and with windowed layers
+            # ``cache_layers`` {window, full}: its `decode` and `mixed` records
+            # then carry `window_pages_read` and `context_pages` beside
+            # `experts_read`; absent otherwise.
+            **((mixture_status() if mixture_status else None) or {}),
             # Desynchronized decode (ISSUE 14): the shape at which the
             # decode loop stops on device and chains host-free.
             "decode": {

@@ -7,7 +7,8 @@ stopgap's refusals); a ``benchmark`` PR may add no file under ``tests/``, so
 they ran by hand alone. Here they are collected as they stand, and each new
 reference (``moe_early_router_gqa``, PR 28; ``sambay_diff``, PR 32) is a further
 case of the first. PR 32's file checks (``bench/tests/test_phi4flash_files.py``)
-are collected the same way, and PR 36's (``bench/tests/test_keye_files.py``).
+are collected the same way, and PR 36's (``bench/tests/test_keye_files.py``) and
+PR 41's (``bench/tests/test_trinity_files.py``).
 """
 
 import importlib.util
@@ -42,14 +43,22 @@ _spec.loader.exec_module(keye_files)
 globals().update({name if name not in globals() else f"{name}_keye": fn
                   for name, fn in vars(keye_files).items() if name.startswith("test_")})
 
+_spec = importlib.util.spec_from_file_location("bench_test_trinity_files", BENCH / "tests" / "test_trinity_files.py")
+trinity_files = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(trinity_files)
+globals().update({name if name not in globals() else f"{name}_trinity": fn
+                  for name, fn in vars(trinity_files).items() if name.startswith("test_")})
+
 
 # What later PRs appended after PR 32's entries, by list and in order: PR 36 a
-# configuration, its cell and two readers, PR 38 the idle ledger's five readers.
-# All the cut below may drop.
-APPENDED_SINCE_PR_32 = {"configs": ["keye-vl2-30b-a3b-int8"], "workloads": ["keye-vl2-30b-a3b.long-context"],
+# configuration, its cell and two readers, PR 38 the idle ledger's five readers,
+# PR 41 a configuration, its cell and two readers. All the cut below may drop.
+APPENDED_SINCE_PR_32 = {"configs": ["keye-vl2-30b-a3b-int8", "trinity-mini-int8"],
+                        "workloads": ["keye-vl2-30b-a3b.long-context", "trinity-mini.long-context"],
                         "per_layer": ["dsa_kv_read_share", "dsa_busy_share",
                                       "fetch_return_ms", "idle_unspanned_share", "starved_slot_share",
-                                      "starved_delivery_share", "starved_unspanned_share"]}
+                                      "starved_delivery_share", "starved_unspanned_share",
+                                      "swa_page_skip_share", "afmoe_step_hbm_roofline_share"]}
 
 
 def test_the_benchmark_gains_one_configuration_one_cell_and_three_readers(tmp_path, monkeypatch):

@@ -412,6 +412,10 @@ def test_state_operations_compile_for_v5e_at_the_published_widths(one_chip, form
 # table and the lengths by the slot that ends the walk: three equations more
 # around every decode kernel (the hybrid's mixed step holds one too, for its
 # cross layers).
+# ISSUE 41: the mixture with a dense prefix scans TWO groups of layers (prefix,
+# body) over one page pool, so each of its step programs holds one kernel
+# instance a group (the window an operand in both: no ``cond``) and its
+# equations are two layer bodies'; the five older rows are unedited.
 STEP_PROGRAMS = {
     ("llama", "test-tiny"): ((1, 0, 156), (1, 0, 145)),
     ("llama", "qwen2-test-tiny"): ((1, 0, 163), (1, 0, 152)),
@@ -419,6 +423,7 @@ STEP_PROGRAMS = {
     ("mixtral", "smallthinker-test-tiny"): ((1, 0, 301), (1, 0, 284)),
     ("phi4flash", "phi4flash-test-tiny"): ((2, 0, 1033), (2, 0, 779)),
     ("mixtral", "keye-test-tiny"): ((0, 2, 975), (0, 0, 562)),
+    ("mixtral", "trinity-test-tiny"): ((2, 0, 548), (2, 0, 527)),
 }
 
 
