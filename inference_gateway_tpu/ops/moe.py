@@ -2,18 +2,21 @@
 
 ``moe_dropless`` is the serving path (ISSUE 28): every (token, expert)
 assignment is computed and none is dropped. The assignments are sorted
-by expert, each expert multiplies its own contiguous group of rows
-(``grouped_matmul``), and the results are gathered back per token and
-summed under the routing weights. There is no ``(N, E, C)`` dispatch
-tensor and no capacity. One algorithm serves decode (a few rows an
-expert) and the mixed step (tens of rows an expert); only the row tile
-follows the row count.
+by expert, each expert's group begins at a granule boundary (``dispatch``:
+at most a granule less one padded rows an expert), each expert multiplies
+its own group (``grouped_matmul``), and the results are gathered back per
+token and summed under the routing weights. There is no ``(N, E, C)``
+dispatch tensor and no capacity. One algorithm serves decode (a few rows
+an expert) and the mixed step (tens of rows an expert); only the rows a
+visit multiplies follow the rows an expert holds (``visit_plan``).
 
 ``grouped_matmul`` on one TPU device is a Pallas kernel over the WHOLE
 stacked expert leaf ``(L, E, K, N)``: the layer index and each visit's
 expert ride in scalar prefetch, so no per-layer slice of the stack is
 ever materialised, an int8 expert is read from HBM once, at int8, and
-dequantised in VMEM where it is multiplied (``jax.lax.ragged_dot`` given
+dequantised in VMEM where it is multiplied, once unless it holds more
+rows than a visit (ISSUE 42: a visit is ONE expert's rows, its column
+block as wide as ``VISIT_BYTES`` allows) (``jax.lax.ragged_dot`` given
 ``q.astype(bf16)`` writes a bf16 copy of all E experts per call: compiled
 for a described v5e, PR 28). Off the TPU and for int4 the twin is
 ``jax.lax.ragged_dot`` on the dequantised layer, the same sums.
@@ -33,6 +36,7 @@ the tests' oracle.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -90,68 +94,117 @@ def moe_dense(x: jnp.ndarray, router_logits: jnp.ndarray, top_k: int, expert_fn,
 # Grouped matmul over a stacked expert leaf
 # ---------------------------------------------------------------------------
 
-def _row_tile(m: int) -> int:
-    """Rows a visit multiplies: small where an expert sees a few rows
-    (decode: a visit's MXU passes follow the tile, its weight read does
-    not), the MXU's height where it sees tens."""
-    return 32 if m <= 512 else 128
+# A visit's weight operands as they are stored (both matrices of the gated
+# pair), one buffer: the column block is the widest that fits. The pipeline
+# holds two such buffers and the body their dequantised copies, beside the
+# row tile and the product: inside ``vmem_limit_bytes`` with room to spare.
+VISIT_BYTES = 4 << 20
+# (rows an expert up to, granule, granules a visit), by what each cost on the
+# chip at the cells' shapes (``PERF.md`` section 5, PR 42): a visit's time is
+# its weight block's way through the MXU and grows ~7% from 64 rows to 128,
+# so it takes the fewest rows that hold an expert's share in one visit.
+VISIT_ROWS = ((16, 16, 2), (64, 16, 4), (None, 32, 4))
 
 
-def _col_tile(n: int) -> int:
-    for c in (512, 384, 256, 128):
-        if n % c == 0:
-            return c
-    return n
+class VisitPlan(NamedTuple):
+    """How the kernels walk a layer's rows, from its static shapes alone."""
+    granule: int  # every expert's rows lie from a multiple of this many rows on
+    chunks: int  # a visit multiplies up to this many granules of ONE expert
+    rows: int  # rows of the layout: each expert with rows pads its group to the granule
+    visits: int  # the most visits there can be (the grid)
 
 
-def _visits(group_sizes: jnp.ndarray, start, m: int, tm: int):
-    """Which (expert, row tile) pairs hold work, in row order: an expert
-    with rows visits every tile its span touches, so a tile shared by
-    several experts is visited once by each, back to back. The first
-    group's rows begin at row ``start`` (an ``ep`` shard's experts own a
-    span in the middle of the sorted rows). Returns (offsets (E+1,),
-    expert of visit (V,), tile of visit (V,), visits (1,)) with
-    V = m/tm + E - 1, the most there can be; the tail repeats the last
-    real visit (no new block is fetched) and is skipped."""
-    E = group_sizes.shape[0]
-    tiles = m // tm
-    V = tiles + E - 1
-    start = jnp.asarray(start, jnp.int32)
-    ends = start + jnp.cumsum(group_sizes).astype(jnp.int32)
-    starts = ends - group_sizes
-    offsets = jnp.concatenate([start[None], ends])
-    first = starts // tm
-    n_tiles = jnp.where(group_sizes > 0, (ends - 1) // tm - first + 1, 0)
-    visit_end = jnp.cumsum(n_tiles)
+def visit_plan(m: int, e: int, e_local: int | None = None) -> VisitPlan:
+    """``m`` rows over ``e`` experts, ``e_local`` of them on this shard.
+    Every expert's rows begin at a granule boundary, so that an expert is
+    visited once unless it holds more rows than a visit (no visit is shared,
+    none is masked), and the granule only bounds what the layout pads (at
+    most ``e_local`` x (granule - 1) rows, which the row gather writes)."""
+    g, c = next((g, c) for most, g, c in VISIT_ROWS if most is None or m <= most * e)
+    holders = min(e if e_local is None else e_local, m)
+    return VisitPlan(g, c, (m + holders * (g - 1)) // g * g, m // (g * c) + holders)
+
+
+def visit_count(rows: jnp.ndarray, m: int) -> jnp.ndarray:
+    """rows (..., E): the rows each expert holds of ``m`` in all -> the
+    visits the kernel makes (its ``num``): one for every ``granule`` x
+    ``chunks`` rows of an expert, begun or whole."""
+    plan = visit_plan(m, rows.shape[-1])
+    return jnp.sum(-(-rows // (plan.granule * plan.chunks)), axis=-1)
+
+
+def _col_block(k: int, n: int, n_w: int, itemsize: int) -> int:
+    """The widest divisor of ``n`` in whole lanes whose ``n_w`` (k, block)
+    operands fit ``VISIT_BYTES``; ``n`` itself where no divisor is whole lanes."""
+    if n % 128:
+        return n
+    return max([c for c in range(128, n + 1, 128) if n % c == 0 and n_w * k * c * itemsize <= VISIT_BYTES],
+               default=128)
+
+
+def _visits(sizes: jnp.ndarray, plan: VisitPlan):
+    """The visits in row order, for groups of ``sizes`` rows laid out from
+    granule boundaries. A visit is one expert and up to ``chunks``
+    consecutive granules of its rows; an expert with no rows has none.
+    Returns (expert of visit (V,), its first granule (V,), its granules
+    (V,), the granule each of the ``chunks`` row operands holds (chunks,
+    V), visits (1,)). A row operand a visit does not need keeps the
+    granule it held, so no copy is made for it; the tail past ``num``
+    repeats the last visit's blocks and has no granules."""
+    g, c, _, V = plan
+    experts = jnp.arange(sizes.shape[0], dtype=jnp.int32)
+    held = (-(-sizes // g)).astype(jnp.int32)  # granules an expert holds
+    n_visits = -(-held // c)
+    visit_end = jnp.cumsum(n_visits)
     num = visit_end[-1]
-    v = jnp.arange(V)
-    gid = jnp.searchsorted(visit_end, v, side="right").astype(jnp.int32)
-    gid = jnp.minimum(gid, E - 1)
-    tid = first[gid] + (v - (visit_end[gid] - n_tiles[gid]))
-    last = jnp.maximum(num - 1, 0)
-    gid = jnp.where(v < num, gid, gid[last])
-    tid = jnp.where(v < num, tid, tid[last])
-    tid = jnp.clip(tid, 0, tiles - 1).astype(jnp.int32)
-    return offsets, gid, tid, num.astype(jnp.int32)[None]
+    v = jnp.arange(V, dtype=jnp.int32)
+    # (E, V), a visit's column true at its expert: every look-up below is a
+    # masked sum over it (a gather of V elements costs the chip more than this).
+    mine = (v[None, :] >= (visit_end - n_visits)[:, None]) & (v[None, :] < visit_end[:, None])
+
+    def of_visit(a):
+        return jnp.sum(jnp.where(mine, a[:, None], 0), axis=0)
+
+    real = v < num
+    nth = v - of_visit(visit_end - n_visits)
+    gid = jnp.where(real, of_visit(experts), jnp.max(jnp.where(sizes > 0, experts, 0)))
+    chunk0 = jnp.where(real, of_visit(jnp.cumsum(held) - held) + nth * c, 0)
+    cnt = jnp.where(real, jnp.minimum(of_visit(held) - nth * c, c), 0)
+    each = jnp.arange(c, dtype=jnp.int32)[:, None]
+    holds = jax.lax.cummax(jnp.where(each < cnt[None, :], chunk0[None, :] + each, 0), axis=1)
+    return gid, chunk0, cnt, holds, num.astype(jnp.int32)[None]
 
 
-def _gmm_kernel(off_ref, gid_ref, tid_ref, num_ref, li_ref, x_ref, *refs, tm, n_w, scaled, act):
-    """One visit: the row tile times expert ``gid[v]``'s column block,
-    kept for the rows of that expert's span. ``n_w`` 2 is the gated pair
-    (act(x Wg) * x Wu) in one pass over x."""
+def _gmm_kernel(gid_ref, chunk0_ref, cnt_ref, holds_ref, num_ref, li_ref, *refs,
+                g, c, tn, n_w, scaled, act):
+    """One visit: up to ``c`` granules of ONE expert's rows times its column
+    block, written to the rows' own place. The granules arrive as ``c`` row
+    operands and leave by one copy each, started here and awaited by the
+    next visit before it refills the buffer (the copy has that visit's
+    products to finish in). ``n_w`` 2 is the gated pair (act(x Wg) * x Wu)
+    in one pass over x."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    del li_ref  # the index maps read it
-    o_ref = refs[-1]
-    w_refs = refs[:-1]
-    v = pl.program_id(1)
+    del gid_ref, holds_ref, li_ref  # the index maps read them
+    x_refs, w_refs = refs[:c], refs[c:-3]
+    o_ref, buf, sem = refs[-3:]
+    n, v = pl.program_id(0), pl.program_id(1)
+    num = num_ref[0]
 
-    @pl.when(v < num_ref[0])
+    def copy(i, granule):
+        dst = o_ref.at[pl.ds(pl.multiple_of(granule * g, g), g), pl.ds(pl.multiple_of(n * tn, 128), tn)]
+        return pltpu.make_async_copy(buf.at[pl.ds(i * g, g)], dst, sem.at[i])
+
+    def retire(count):
+        for i in range(c):
+            pl.when(i < count)(lambda i=i: copy(i, 0).wait())
+
+    before = jnp.where((v > 0) & (v <= num), cnt_ref[jnp.maximum(v - 1, 0)], 0)
+
+    @pl.when(v < num)
     def _():
-        g = gid_ref[v]
-        rows = tid_ref[v] * tm + jax.lax.broadcasted_iota(jnp.int32, o_ref.shape, 0)
-        mine = (rows >= off_ref[g]) & (rows < off_ref[g + 1])
-        x = x_ref[...]
+        x = jnp.concatenate([r[...] for r in x_refs], axis=0)
 
         def mul(i):
             w = w_refs[i * (2 if scaled else 1)][...]
@@ -163,32 +216,37 @@ def _gmm_kernel(off_ref, gid_ref, tid_ref, num_ref, li_ref, x_ref, *refs, tm, n_
         y = mul(0)
         if n_w == 2:
             y = ACTIVATIONS[act](y) * mul(1)
-        # A tile's first visit finds whatever the buffer held: rows of no
-        # expert (the padding past the last span) must read zero, not that.
-        fresh = (v == 0) | (tid_ref[jnp.maximum(v - 1, 0)] != tid_ref[v])
-        prev = jnp.where(fresh, jnp.zeros_like(o_ref), o_ref[...])
-        o_ref[...] = jnp.where(mine, y.astype(o_ref.dtype), prev)
+        retire(before)
+        buf[...] = y.astype(buf.dtype)
+        for i in range(c):
+            pl.when(i < cnt_ref[v])(lambda i=i: copy(i, chunk0_ref[v] + i).start())
+        # the grid's last step is the last visit only where every step is one
+        pl.when(v == pl.num_programs(1) - 1)(lambda: retire(cnt_ref[v]))
+
+    pl.when(v == num)(lambda: retire(before))
 
 
-@functools.partial(jax.jit, static_argnames=("act", "out_dtype", "interpret"))
-def _gmm_tpu(x, ws, layer, group_sizes, start, act, out_dtype, interpret=False):
-    """x: (M, K) rows sorted by expert, M a multiple of its row tile, the
-    first group's rows from row ``start``; ws: one or two stacked leaves
-    (L, E, K, N), arrays or QTensors."""
+@functools.partial(jax.jit, static_argnames=("plan", "act", "out_dtype", "interpret"))
+def _gmm_tpu(x, ws, layer, sizes, plan, act, out_dtype, interpret=False):
+    """x: (``plan.rows``, K), the groups of ``sizes`` rows from granule
+    boundaries on; ws: one or two stacked leaves (L, E, K, N), arrays or
+    QTensors. Rows of no group come back unwritten."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    M, K = x.shape
+    g, c, rows, V = plan
+    K = x.shape[1]
     scaled = isinstance(ws[0], QTensor)
     N = ws[0].shape[-1]
-    tm, tn = _row_tile(M), _col_tile(N)
-    offsets, gid, tid, num = _visits(group_sizes, start, M, tm)
-    V = gid.shape[0]
+    stored = ws[0].q if scaled else ws[0]
+    tn = _col_block(K, N, len(ws), stored.dtype.itemsize)
 
-    def w_index(n, v, off, gid, tid, num, li):
+    def w_index(n, v, gid, chunk0, cnt, holds, num, li):
         return li[0], gid[v], 0, n
 
-    operands, in_specs = [x], [pl.BlockSpec((tm, K), lambda n, v, off, gid, tid, num, li: (tid[v], 0))]
+    operands = [x] * c
+    in_specs = [pl.BlockSpec((g, K), lambda n, v, gid, chunk0, cnt, holds, num, li, i=i: (holds[i, v], 0))
+                for i in range(c)]
     for w in ws:
         if scaled:
             operands += [w.q, w.scale]
@@ -197,18 +255,19 @@ def _gmm_tpu(x, ws, layer, group_sizes, start, act, out_dtype, interpret=False):
         else:
             operands.append(w)
             in_specs.append(pl.BlockSpec((None, None, K, tn), w_index))
-    kernel = functools.partial(_gmm_kernel, tm=tm, n_w=len(ws), scaled=scaled, act=act)
+    kernel = functools.partial(_gmm_kernel, g=g, c=c, tn=tn, n_w=len(ws), scaled=scaled, act=act)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5, grid=(N // tn, V), in_specs=in_specs,
-            out_specs=pl.BlockSpec((tm, tn), lambda n, v, off, gid, tid, num, li: (tid[v], n))),
-        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
+            num_scalar_prefetch=6, grid=(N // tn, V), in_specs=in_specs,
+            out_specs=pl.BlockSpec(memory_space=pltpu.ANY),
+            scratch_shapes=[pltpu.VMEM((g * c, tn), out_dtype), pltpu.SemaphoreType.DMA((c,))]),
+        out_shape=jax.ShapeDtypeStruct((rows, N), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=64 << 20),
         name="moe_gate_up_gmm" if len(ws) == 2 else "moe_down_gmm",
         interpret=interpret,
-    )(offsets, gid, tid, num, jnp.asarray(layer, jnp.int32).reshape(1), *operands)
+    )(*_visits(sizes, plan), jnp.asarray(layer, jnp.int32).reshape(1), *operands)
 
 
 def _layer_weight(w, layer, dtype):
@@ -221,14 +280,13 @@ def _layer_weight(w, layer, dtype):
     return w[layer].astype(dtype), None
 
 
-def _gmm_twin(x, ws, layer, group_sizes, start, row_expert, act, out_dtype):
-    # ragged_dot's groups begin at row 0: the rows before ``start`` ride
-    # with the first group, and the caller keeps no row outside the span.
-    sizes = group_sizes.at[0].add(start)
+def _gmm_twin(x, ws, layer, sizes, plan, row_expert, act, out_dtype):
+    # the groups as they lie: each padded to the granule, back to back from row 0
+    padded = -(-sizes // plan.granule) * plan.granule
 
     def mul(w):
         mat, scale = _layer_weight(w, layer, x.dtype)
-        y = jax.lax.ragged_dot(x, mat, sizes, preferred_element_type=jnp.float32)
+        y = jax.lax.ragged_dot(x, mat, padded, preferred_element_type=jnp.float32)
         return y if scale is None else y * scale[row_expert]
 
     y = mul(ws[0])
@@ -237,13 +295,13 @@ def _gmm_twin(x, ws, layer, group_sizes, start, row_expert, act, out_dtype):
     return y.astype(out_dtype)
 
 
-def grouped_matmul(x, ws, layer, group_sizes, start, row_expert, *, act="silu", out_dtype=None,
-                   kernel=None):
-    """Rows of ``x`` (sorted by expert; from row ``start`` on,
-    ``group_sizes`` rows each; ``row_expert`` names each row's) times
-    their expert's matrix of layer ``layer`` of the stacked leaf. Two
-    leaves are the gated pair: act(x Wg) * (x Wu). Rows outside the groups
-    come back zero, unwritten or another expert's: the caller masks them.
+def grouped_matmul(x, ws, layer, sizes, plan, row_expert, *, act="silu", out_dtype=None, kernel=None):
+    """Rows of ``x`` (``plan.rows`` of them: expert after expert, ``sizes``
+    rows each, every expert's from a granule boundary on; ``row_expert``
+    names each row's) times their expert's matrix of layer ``layer`` of the
+    stacked leaf. Two leaves are the gated pair: act(x Wg) * (x Wu). Rows
+    of no expert come back unwritten or as some expert's product of
+    whatever they held: the caller reads none of them.
     ``kernel`` None: the Pallas kernel on a TPU, the twin elsewhere, for
     int4, and for a ``tp`` shard whose columns are not whole lanes (768
     over tp = 4: the compiler re-lays the WHOLE stack out per call for the
@@ -253,54 +311,83 @@ def grouped_matmul(x, ws, layer, group_sizes, start, row_expert, *, act="silu", 
     if kernel is None:
         kernel = on_tpu() and not isinstance(ws[0], Q4Tensor) and ws[0].shape[-1] % 128 == 0
     if kernel:
-        return _gmm_tpu(x, tuple(ws), layer, group_sizes, start, act, out_dtype, interpret=not on_tpu())
-    return _gmm_twin(x, ws, layer, group_sizes, start, row_expert, act, out_dtype)
+        return _gmm_tpu(x, tuple(ws), layer, sizes, plan, act, out_dtype, interpret=not on_tpu())
+    return _gmm_twin(x, ws, layer, sizes, plan, row_expert, act, out_dtype)
 
 
 # ---------------------------------------------------------------------------
 # The dropless layer
 # ---------------------------------------------------------------------------
 
+class Layout(NamedTuple):
+    """Where a shard's rows lie (``dispatch``)."""
+    plan: VisitPlan
+    token: jnp.ndarray  # (plan.rows,) the token whose row lies there; 0 where none does
+    row_expert: jnp.ndarray  # (plan.rows,) the shard's expert whose group the row lies in
+    back: jnp.ndarray  # (M,) where each (token, choice) lies; plan.rows where this shard holds no row of it
+    sizes: jnp.ndarray  # (E',) rows each of the shard's experts holds
+    all_sizes: jnp.ndarray  # (E,) rows each of ALL experts holds
+
+
+def dispatch(expert, top_k: int, e: int, e_local: int | None = None, first=0) -> Layout:
+    """Lay the rows of this shard's experts (``e_local`` of ``e``, from
+    expert ``first`` on; all of them by default) out for the kernels:
+    expert after expert, a token's row once for each of its choices, each
+    expert's rows from a granule boundary on. ``expert`` (M,) is each
+    (token, choice)'s expert, token-major; ``e`` means none. One sort lays
+    them out: beside the (token, choice) pairs every expert brings the
+    fillers that pad its group to the granule, and what the shard does not
+    hold (another shard's, no expert's, a spare filler) sorts last; a second
+    sort, by pair, says where each pair went."""
+    M = expert.shape[0]
+    e_local = e if e_local is None else e_local
+    plan = visit_plan(M, e, e_local)
+    g = plan.granule
+    # counted by comparison, turned back by a second sort: a scatter of M
+    # elements costs the chip five times either (``PERF.md`` section 5, PR 42)
+    all_sizes = jnp.sum(expert[:, None] == jnp.arange(e, dtype=jnp.int32)[None, :], axis=0, dtype=jnp.int32)
+    sizes = jax.lax.dynamic_slice(all_sizes, (first,), (e_local,))
+    local = expert - first
+    held = (local >= 0) & (local < e_local)
+    spare = jnp.arange(g - 1, dtype=jnp.int32)[None, :] >= (-sizes % g)[:, None]  # (E', g - 1)
+    fillers = jnp.where(spare, e_local, jnp.arange(e_local, dtype=jnp.int32)[:, None]).reshape(-1)
+    keys = jnp.concatenate([jnp.where(held, local, e_local), fillers])
+    pairs = jnp.concatenate([jnp.arange(M, dtype=jnp.int32), jnp.full(fillers.shape, M, jnp.int32)])  # M: a filler
+    # stable: an expert's pairs stay token-major and come before its fillers
+    row_expert, pair = jax.lax.sort((keys, pairs), num_keys=1, is_stable=True)
+    _, lies = jax.lax.sort((pair, jnp.arange(pair.shape[0], dtype=jnp.int32)), num_keys=1)  # the pairs first, in order
+    row_expert, pair = row_expert[:plan.rows], pair[:plan.rows]  # every held pair lies inside
+    return Layout(plan, jnp.where(pair < M, pair // top_k, 0), jnp.minimum(row_expert, e_local - 1),
+                  jnp.where(held, lies[:M], plan.rows), sizes, all_sizes)
+
+
 def _experts_of_shard(x, weights, expert, wg, wu, wd, layer, *, act, kernel, ep, tp):
     """Everything after the router, for the experts (and, under ``tp``,
-    the columns) this shard holds: sort, the two grouped matmuls, the
-    combine per token. x (N, H), weights (N, k) and expert (N*k,) are
-    whole on every shard; the stacks are the shard's own
+    the columns) this shard holds: lay the rows out, the two grouped
+    matmuls, the combine per token. x (N, H), weights (N, k) and expert
+    (N*k,) are whole on every shard; the stacks are the shard's own
     (L, E/ep, K, N). Returns (this shard's part of the output (N, H)
     float32, rows each of ALL experts multiplied (E,))."""
     N, H = x.shape
     top_k = weights.shape[1]
-    M = N * top_k
     e_local = jax.tree.leaves(wg)[0].shape[1]
-    E = e_local * (jax.lax.axis_size(ep) if ep else 1)
     with jax.named_scope("moe.dispatch"):
-        order = jnp.argsort(expert, stable=True)  # E (no expert) sorts last: no group
-        group_sizes = jnp.zeros((E + 1,), jnp.int32).at[expert].add(1)[:E]
-        first = jax.lax.axis_index(ep) * e_local if ep else 0
-        mine = jax.lax.dynamic_slice(group_sizes, (first,), (e_local,))
-        start = jnp.sum(jnp.where(jnp.arange(E) < first, group_sizes, 0))
-        row_expert = jnp.clip(expert[order] - first, 0, e_local - 1)
-        pad = -M % _row_tile(M)
-        rows = x[order // top_k]
-        if pad:
-            rows = jnp.pad(rows, ((0, pad), (0, 0)))
-            row_expert = jnp.pad(row_expert, (0, pad), constant_values=e_local - 1)
+        lay = dispatch(expert, top_k, e_local * (jax.lax.axis_size(ep) if ep else 1), e_local,
+                       jax.lax.axis_index(ep) * e_local if ep else 0)
+        rows = x[lay.token]
     with jax.named_scope("moe.experts"):
-        h = grouped_matmul(rows, (wg, wu), layer, mine, start, row_expert, act=act, kernel=kernel)
+        h = grouped_matmul(rows, (wg, wu), layer, lay.sizes, lay.plan, lay.row_expert, act=act, kernel=kernel)
         # tp shards hold partial sums of the down matrix: kept float32 until summed.
-        y = grouped_matmul(h, (wd,), layer, mine, start, row_expert, kernel=kernel,
+        y = grouped_matmul(h, (wd,), layer, lay.sizes, lay.plan, lay.row_expert, kernel=kernel,
                            out_dtype=jnp.float32 if tp else None)
     with jax.named_scope("moe.combine"):
-        at = jnp.arange(M + pad)
-        live = (at >= start) & (at < start + jnp.sum(mine))
-        y = jnp.where(live[:, None], y.astype(jnp.float32), 0.0)
-        back = jnp.zeros((M,), jnp.int32).at[order].set(jnp.arange(M, dtype=jnp.int32))
-        per_choice = y[back].reshape(N, top_k, H)
-        out = jnp.einsum("nk,nkh->nh", weights, per_choice)
+        held = lay.back < lay.plan.rows
+        per_choice = jnp.where(held[:, None], y[jnp.minimum(lay.back, lay.plan.rows - 1)].astype(jnp.float32), 0.0)
+        out = jnp.einsum("nk,nkh->nh", weights, per_choice.reshape(N, top_k, H))
         axes = tuple(a for a in (ep, tp) if a)
         if axes:
             out = jax.lax.psum(out, axes)
-    return out, group_sizes
+    return out, lay.all_sizes
 
 
 def _stack_spec(w, ep, k_axis, n_axis):

@@ -221,7 +221,7 @@ class _DecodeChunkHandle:
     # submit (the packed buffer), 0 for a chained one.
     h2d_arrays: int = 0
     # What the program counted and the fetch block carried beside the
-    # tokens (ISSUE 28), filled by the fetch: a mixture's ``experts_read``.
+    # tokens (ISSUE 28), filled by the fetch: a mixture's ``experts_read`` and ``expert_visits``.
     # Rides the step's timeline record.
     stats: dict | None = None
 
@@ -293,7 +293,9 @@ def _fetch_blocks(phases: dict | None, path: str, *blocks) -> list[np.ndarray]:
 
 # A mixture's mixed step counts, in the program, the rows each expert
 # multiplied; the fetch block carries one row for each of these (ISSUE 28).
-MIXED_STATS = ("expert_tokens_max", "expert_tokens_mean", "experts_read")
+MIXED_STATS = ("expert_tokens_max", "expert_tokens_mean", "experts_read", "expert_visits")
+# A mixture's decode chunk carries the last two, means over the steps it ran.
+CHUNK_EXPERT_STATS = MIXED_STATS[2:]
 # A model with windowed layers beside a state store (models/phi4flash.py)
 # counts, in both step programs, the pages its windowed layers read and the
 # pages their rows' contexts hold (ISSUE 32): two rows of the fetch block.
@@ -343,10 +345,16 @@ def has_dense_prefix(model_cfg) -> bool:
     return bool(getattr(model_cfg, "num_dense_layers", 0))
 
 
-def _experts_read(rows: jnp.ndarray) -> jnp.ndarray:
+def _experts_read(rows: jnp.ndarray, m: int) -> jnp.ndarray:
     """rows (L, E): how many rows each expert of each layer multiplied in
-    one step -> the distinct experts a layer read, mean over layers."""
-    return jnp.mean(jnp.sum(rows > 0, axis=1).astype(jnp.float32))
+    one step of ``m`` rows a layer -> (the distinct experts a layer read,
+    the visits the expert kernels made a layer (ISSUE 42: by the tile rule
+    ``ops/moe.py`` exports; visits / experts read is the passes an expert's
+    block cost)), each a mean over the layers."""
+    from inference_gateway_tpu.ops.moe import visit_count
+
+    return jnp.stack([jnp.mean(jnp.sum(rows > 0, axis=1).astype(jnp.float32)),
+                      jnp.mean(visit_count(rows, m).astype(jnp.float32))])
 
 
 def choose_mesh(config: EngineConfig, model_cfg, n_dev: int):
@@ -915,7 +923,7 @@ class Engine:
         if self.is_hybrid:
             self._chunk_stats = self._mixed_stats = WINDOW_STATS
         else:
-            self._chunk_stats = ("experts_read",) + more_stats if self.is_moe else ()
+            self._chunk_stats = CHUNK_EXPERT_STATS + more_stats if self.is_moe else ()
             self._mixed_stats = MIXED_STATS + more_stats
         if self.is_hybrid:
             # Segments that started at position 0, so from a zeroed state:
@@ -1176,7 +1184,7 @@ class Engine:
                 last_only=True, mesh=self.mesh, **counted)
             if rows and self.is_moe:  # ``_chunk_stats``: a mixture's experts read, an indexer's or a window's counts
                 per, more = self._split_aux(rows[0])
-                read = jnp.concatenate([_experts_read(per)[None], more])
+                read = jnp.concatenate([_experts_read(per, tok.shape[0] * self.model_cfg.experts_per_token), more])
             elif rows:  # a windowed hybrid's pages (WINDOW_STATS)
                 read = rows[0]
         if masked:
@@ -1255,11 +1263,11 @@ class Engine:
                  budgets, out_toks0, out_lps0, *([read0] if counted else [])))
         if counted:
             # More rows of the block the host fetches anyway, one a name of
-            # ``_chunk_stats``: ``experts_read`` is the mean over the steps that
-            # ran, every other count their sum.
+            # ``_chunk_stats``: ``experts_read`` and ``expert_visits`` are means
+            # over the steps that ran, every other count their sum.
             stats = read_sum[0]
             if self.is_moe:
-                stats = stats.at[0].divide(jnp.maximum(i_ran, 1).astype(jnp.float32))
+                stats = stats.at[:len(CHUNK_EXPERT_STATS)].divide(jnp.maximum(i_ran, 1).astype(jnp.float32))
             out_lps = jnp.concatenate([out_lps, jnp.broadcast_to(stats[:, None], (stats.shape[0], S))])
         # Steps the loop never ran emit each row's FINAL frozen token
         # (not the chunk-entry one), so the emitted block reads exactly
@@ -1380,14 +1388,14 @@ class Engine:
             return toks, logprobs, cache, rows[0]  # WINDOW_STATS, counted by the model
         if rows:
             # Rows of the fetch block, in MIXED_STATS' order: per layer the
-            # most rows one expert multiplied and the mean over experts,
-            # and the experts read; each a mean over the layers.
+            # most rows one expert multiplied and the mean over experts, the
+            # experts read and the kernels' visits; each a mean over the layers.
             # Then DSA_STATS, counted by the attention seam, or WINDOW_STATS,
             # counted by the model.
-            per, more = self._split_aux(rows[0])
-            per = per.astype(jnp.float32)  # (L, E)
-            stats = jnp.stack([jnp.mean(jnp.max(per, axis=1)), jnp.mean(per), _experts_read(per)])
-            return toks, logprobs, cache, jnp.concatenate([stats, more])
+            per, more = self._split_aux(rows[0])  # (L, E)
+            stats = jnp.stack([jnp.mean(jnp.max(per, axis=1).astype(jnp.float32)), jnp.mean(per.astype(jnp.float32))])
+            m = tokens.shape[-1] * self.model_cfg.experts_per_token
+            return toks, logprobs, cache, jnp.concatenate([stats, _experts_read(per, m), more])
         return toks, logprobs, cache
 
     @partial(jax.jit, static_argnames=("self", "masked"), donate_argnums=(2,))

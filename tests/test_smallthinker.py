@@ -157,6 +157,8 @@ def test_rows_admitted_through_the_mixed_step_match_the_reference(dtype, quantiz
     t, lp = eng.mixed_step_fetch(h)
     assert h.stats["expert_tokens_mean"] == pytest.approx(34 * 3 / 8)
     assert 3 * 34 / 8 <= h.stats["expert_tokens_max"] <= 34 and 3 <= h.stats["experts_read"] <= 8
+    # the kernels' visits (ISSUE 42): one an expert with rows while none holds more than a visit's rows
+    assert h.stats["expert_visits"] == h.stats["experts_read"]
     a_toks.append(int(t[0]))
     a_lps.append(float(lp[0]))
     toks, lps, _ = decode(eng, a_toks[-1], 41, 1)
@@ -309,6 +311,7 @@ def test_experts_read_is_the_count_by_hand():
     firsts = eng.prefill([a, b], [0, 1], [0.0, 0.0], [1.0, 1.0])
     _, _, stats = decode(eng, firsts[0].first_token, 24, 2)
     assert [s["experts_read"] for s in stats] == [3.0, 3.0]
+    assert [s["expert_visits"] for s in stats] == [3.0, 3.0]  # ISSUE 42: one visit an expert with rows
 
     S = eng.config.max_slots
     t, p, active = np.zeros(S, np.int32), np.zeros(S, np.int32), np.zeros(S, bool)
@@ -333,6 +336,7 @@ def test_experts_read_is_the_count_by_hand():
         chosen.append(per_layer)
     by_hand = np.mean([len(chosen[0][i] | chosen[1][i]) for i in range(4)])
     assert h.stats["experts_read"] == pytest.approx(by_hand) and 3 <= by_hand <= 6
+    assert h.stats["expert_visits"] == h.stats["experts_read"]
 
 
 def test_engine_builds_through_the_two_functions():
