@@ -181,6 +181,85 @@ def _layer_groups(params: Params, ffn, unscanned: tuple, prefix, num_layers: int
     return [(prefix["layers"], prefix["ffn"], 0, lead), body] if prefix else [body]
 
 
+def _project_qkv(h, ap: Params, li, cfg: "LlamaConfig", cos, sin, cache: Params):
+    """The attention operator's projections of a layer's normed input h (B, T,
+    H): ``ap`` holds its leaves. Returns (q, k, v by head after norm and
+    rotary; the keys' and the values' rows as the pool holds them)."""
+    B, T, _ = h.shape
+    Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.hd
+    q = qmatmul(h, ap["wq"])
+    k = qmatmul(h, ap["wk"])
+    v = qmatmul(h, ap["wv"])
+    if cfg.qkv_bias:
+        q = q + ap["bq"]
+        k = k + ap["bk"]
+        v = v + ap["bv"]
+    q = q.reshape(B, T, Hq, D)
+    k = k.reshape(B, T, Hkv, D)
+    v = v.reshape(B, T, Hkv, D)
+    q, k = _rope_qk(q, k, cos, sin, cfg, li, ap)
+    return (q, k, v, k.reshape(B, T, Hkv * D).astype(cache["k"].dtype),
+            v.reshape(B, T, Hkv * D).astype(cache["v"].dtype))
+
+
+def _write_kv(ck, cv, qkv, write_idx, plane, flat: int, total: int, skip=None):
+    """A step's keys and values into ``plane`` of the flat pools (the layer
+    index, but for ``kinds``); nothing where ``skip`` is set. Returns the
+    pools and the indices written."""
+    # Per-layer offset; rows that were OOB within the layer (== flat,
+    # the drop convention) must stay OOB for the WHOLE buffer, not
+    # land in layer li+1's first page.
+    w_idx = jnp.where(write_idx >= flat, total, write_idx + plane * flat)
+    if skip is not None:
+        w_idx = jnp.where(skip, total, w_idx)
+    return ck.at[w_idx].set(qkv[3], mode="drop"), cv.at[w_idx].set(qkv[4], mode="drop"), w_idx
+
+
+def _mix_by_kind(kinds: dict, group: tuple, li, h, ck, cv, store, ops):
+    """Layer ``li``'s token mixer in a model whose layers are of two KINDS
+    (ISSUE 43; ``kinds`` is the seam of ``forward_paged_impl``): an attention
+    layer projects, writes its plane of the pool and calls the kernel
+    (``ops``: the skeleton's own ``project``, ``write`` and ``attend``); a state layer
+    writes no key, calls no kernel, and reads and writes its entry of the
+    slot-indexed ``store``. Each operator's leaves and cache are stacks of
+    their own, read at the layer's ORDINAL among its kind, not at the layer
+    index. One scanned body holds both kinds under ``lax.cond``s on the
+    layer's flag, so a step program holds one instance of its kernel; a
+    ``group`` (first layer, layers) of state layers alone holds no branch.
+
+    The pools never pass THROUGH a ``cond``: the chip's compiler copies a
+    pool that one branch updates and the other hands back (0.8 GB a side an
+    attention layer: the first tree's decode step read 91 ms on the chip,
+    PERF.md section 6, PR 43). So the first ``cond`` projects (queries, keys
+    and values, or nothing), the write is outside it with a state layer's
+    indices past the pool (dropped), and the second reads the pools and
+    either attends or runs the state operator. Returns (the residual
+    contribution, the pools, the store)."""
+    flags = kinds["state"]
+    ordinal = jnp.asarray([sum(f == flags[l] for f in flags[:l]) for l in range(len(flags))], jnp.int32)[li]
+    at = lambda stack: jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, ordinal, 0, keepdims=False), stack)
+    project, write, attend = ops
+
+    def state_layer(store):
+        B, T, H = h.shape
+        out, mine = kinds["mix"](h.reshape(B * T, H), at(kinds["leaves"]), at(store))
+        return out.reshape(B, T, H), jax.lax.dynamic_update_index_in_dim(store, mine, ordinal, 0)
+
+    held = set(flags[group[0]:group[0] + group[1]])
+    if held == {1}:
+        out, store = state_layer(store)
+        return out, ck, cv, store
+    own = lambda: at(kinds["attention"])
+    is_state = jnp.asarray(flags, bool)[li]
+    nothing = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), jax.eval_shape(lambda: project(h, own(), li)))
+    qkv = jax.lax.cond(is_state, lambda: nothing, lambda: project(h, own(), li))
+    ck, cv, _ = write(ck, cv, qkv, plane=ordinal, skip=is_state)
+    out, store = jax.lax.cond(
+        is_state, lambda ck, cv, store: state_layer(store),
+        lambda ck, cv, store: (attend(h, own(), li, ordinal, qkv[0], ck, cv), store), ck, cv, store)
+    return out, ck, cv, store
+
+
 def init_params(rng: jax.Array, cfg: LlamaConfig, dtype=jnp.bfloat16) -> Params:
     """Random init (normal 0.02). Layers stacked on axis 0."""
     L, H, I, V = cfg.num_layers, cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size
@@ -497,6 +576,7 @@ def forward_paged_impl(
     with_aux: bool = False,
     mixer=None,  # the attention seam, below; a learned sparse attention plugs in here
     prefix=None,  # leading layers with an FFN of their own (_layer_groups), or None
+    kinds=None,  # a per-layer kind with a per-kind cache, below; a layer that owns no pages plugs in here
 ):
     """Shared paged-decoder skeleton: attention + cache paging are
     family-independent; the FFN block (dense gated MLP vs MoE) is the
@@ -518,6 +598,20 @@ def forward_paged_impl(
     they write their own arrays, attend, and return ``(attention, the
     pools they wrote {name: array}, a per-layer auxiliary)``. With a mixer
     a layer's auxiliary is the pair (the FFN's, the mixer's).
+
+    The per-layer kind (ISSUE 43): ``kinds`` is None for every model whose
+    layers all write keys (nothing below changes for them), or a dict
+    ``{"state": flags, "cache": name, "attention": leaves, "leaves": leaves,
+    "mix": fn}``. ``state`` is one 0/1 flag a layer: 1 a layer whose token
+    mixer owns no pages and keeps, a slot, a state in ``cache[name]`` (state
+    layers, slots, ...), which the layer loop carries beside the flat pools.
+    The pool's leading axis is then the CACHING layers alone, and the
+    operators' leaves are stacks of their own, ``attention`` (caching layers,
+    ...) under the attention's names and ``leaves`` (state layers, ...): a
+    layer's plane, store entry and leaves are read at its ordinal among its
+    kind (``_mix_by_kind``). ``mix(h (N, H), a state layer's leaves, its store
+    entry) -> (the residual contribution (N, H), the new entry)``; the
+    skeletons' scanned leaves keep ``attn_norm``, the operator's input norm.
 
     The seam: ``ffn(x, attn_in, lp, li, valid)`` returns the block's
     residual contribution, or that and a per-layer auxiliary array.
@@ -577,75 +671,80 @@ def forward_paged_impl(
     # are metadata-only.
     total = L * flat
 
-    extra = tuple(mixer["cache"]) if mixer is not None else ()
-    if mixer is not None and mode != "decode":
+    assert mixer is None or kinds is None, "an attention seam beside a per-layer kind: no model has both"
+    extra = tuple(mixer["cache"]) if mixer is not None else (kinds["cache"],) if kinds is not None else ()
+    if (mixer is not None or kinds is not None) and mode != "decode":
         raise NotImplementedError(
-            f"a model with an attention seam admits through the mixed step; forward_paged mode {mode!r} has no path for it")
+            f"a model with an attention seam or a state store admits through the mixed step; forward_paged mode {mode!r} has no path for it")
 
     assert mixer is None or prefix is None, "an attention seam's counts of a prefix's layers would be lost"
 
-    def body(carry, per_layer, ffn):
+    project = partial(_project_qkv, cfg=cfg, cos=cos, sin=sin, cache=cache)
+    write = partial(_write_kv, write_idx=write_idx, flat=flat, total=total)
+
+    def attend(h, ap, li, plane, q, ck, cv):
+        """The paged kernel over ``plane`` and the output projection."""
+        pages_k = ck.reshape(L * P, page_size, HkvD)
+        pages_v = cv.reshape(L * P, page_size, HkvD)
+        layer_table = page_table + plane * P  # (B, max_pages) into the big pool
+        attn = paged_attention(q[:, 0], pages_k, pages_v, layer_table, lengths, Hkv,
+                               mesh=mesh, **_window_operand(cfg, li))[:, None]  # (B, 1, Hq, D)
+        return _attn_out(attn.reshape(B, T, Hq * D), h, ap, cfg)
+
+    def attention(h, ap, li, plane, ck, cv, ce):
+        """The attention operator on a layer's normed input: ``ap`` holds its
+        leaves, ``plane`` is its plane of the pool. Returns (the residual
+        contribution, the pools, the attention seam's auxiliary or None)."""
+        q, k, v, *_ = qkv = project(h, ap, li)
+        ck, cv, w_idx = write(ck, cv, qkv, plane=plane)
+        if mixer is None and decode:
+            return attend(h, ap, li, plane, q, ck, cv), ck, cv, ce, None
+        pages_k = ck.reshape(L * P, page_size, HkvD)
+        pages_v = cv.reshape(L * P, page_size, HkvD)
+        layer_table = page_table + plane * P  # (B, max_pages) into the big pool
+
+        def attend_prompt(window):
+            if mode == "prefill_chunk":
+                # Gather the slot's pages (prefix + just-written tail) and
+                # attend causally by absolute position.
+                kg = pages_k[layer_table].reshape(B, -1, Hkv, D).astype(q.dtype)
+                vg = pages_v[layer_table].reshape(B, -1, Hkv, D).astype(q.dtype)
+                if use_flash_prefill(T, kg.shape[1], D):
+                    return flash_prefill_attention(q, kg, vg, lengths, q_offsets=positions[:, 0],
+                                                   window=window)
+                return gqa_attend(q, kg, vg, windowed(chunk_mask, key_pos[None, None, :], window))
+            if ring_mesh is not None:
+                # Fresh long-prompt prefill over the sp ring; pages were
+                # just written above, attention runs on this call's k/v.
+                from inference_gateway_tpu.ops.ring_attention import make_ring_attention
+
+                return make_ring_attention(ring_mesh, axis="sp", causal=True)(q, k, v, lengths)
+            if use_flash_prefill(T, T, D):
+                return flash_prefill_attention(q, k, v, lengths, window=window)
+            return gqa_attend(q, k, v, windowed(prefill_mask, positions[:, None, :], window))
+
+        mix_aux = None
+        if mixer is None:
+            attn = _attend_by_window(cfg, li, attend_prompt)
+        else:
+            attn, wrote, mix_aux = mixer["decode"](
+                h[:, 0], q[:, 0], ap, li, positions[:, 0],
+                {"k": ck, "v": cv, **dict(zip(extra, ce))}, w_idx[:, 0], layer_table, lengths,
+                page_size=page_size)
+            ce = [wrote.get(n, a) for n, a in zip(extra, ce)]
+            attn = attn[:, None]
+        return _attn_out(attn.reshape(B, T, Hq * D), h, ap, cfg), ck, cv, ce, mix_aux
+
+    def body(carry, per_layer, ffn, group):
         x, ck, cv, *ce = carry  # ck/cv: (L*P*page_size, HkvD) flat carry
         lp, li = per_layer
         with jax.named_scope("attention"):
             h = rms_norm(x, _nw(lp["attn_norm"], cfg), cfg.rms_norm_eps)
-            q = qmatmul(h, lp["wq"])
-            k = qmatmul(h, lp["wk"])
-            v = qmatmul(h, lp["wv"])
-            if cfg.qkv_bias:
-                q = q + lp["bq"]
-                k = k + lp["bk"]
-                v = v + lp["bv"]
-            q = q.reshape(B, T, Hq, D)
-            k = k.reshape(B, T, Hkv, D)
-            v = v.reshape(B, T, Hkv, D)
-            q, k = _rope_qk(q, k, cos, sin, cfg, li, lp)
-
-            k_flat = k.reshape(B, T, HkvD).astype(ck.dtype)
-            v_flat = v.reshape(B, T, HkvD).astype(cv.dtype)
-            # Per-layer offset; rows that were OOB within the layer (== flat,
-            # the drop convention) must stay OOB for the WHOLE buffer, not
-            # land in layer li+1's first page.
-            w_idx = jnp.where(write_idx >= flat, total, write_idx + li * flat)
-            ck = ck.at[w_idx].set(k_flat, mode="drop")
-            cv = cv.at[w_idx].set(v_flat, mode="drop")
-            pages_k = ck.reshape(L * P, page_size, HkvD)
-            pages_v = cv.reshape(L * P, page_size, HkvD)
-            layer_table = page_table + li * P  # (B, max_pages) into the big pool
-
-            def attend(window):
-                if mode == "prefill_chunk":
-                    # Gather the slot's pages (prefix + just-written tail) and
-                    # attend causally by absolute position.
-                    kg = pages_k[layer_table].reshape(B, -1, Hkv, D).astype(q.dtype)
-                    vg = pages_v[layer_table].reshape(B, -1, Hkv, D).astype(q.dtype)
-                    if use_flash_prefill(T, kg.shape[1], D):
-                        return flash_prefill_attention(q, kg, vg, lengths, q_offsets=positions[:, 0],
-                                                       window=window)
-                    return gqa_attend(q, kg, vg, windowed(chunk_mask, key_pos[None, None, :], window))
-                if ring_mesh is not None:
-                    # Fresh long-prompt prefill over the sp ring; pages were
-                    # just written above, attention runs on this call's k/v.
-                    from inference_gateway_tpu.ops.ring_attention import make_ring_attention
-
-                    return make_ring_attention(ring_mesh, axis="sp", causal=True)(q, k, v, lengths)
-                if use_flash_prefill(T, T, D):
-                    return flash_prefill_attention(q, k, v, lengths, window=window)
-                return gqa_attend(q, k, v, windowed(prefill_mask, positions[:, None, :], window))
-
-            if mixer is None and decode:
-                attn = paged_attention(q[:, 0], pages_k, pages_v, layer_table, lengths, Hkv,
-                                       mesh=mesh, **_window_operand(cfg, li))[:, None]  # (B, 1, Hq, D)
-            elif mixer is None:
-                attn = _attend_by_window(cfg, li, attend)
+            if kinds is None:
+                out, ck, cv, ce, mix_aux = attention(h, lp, li, li, ck, cv, ce)
             else:
-                attn, wrote, mix_aux = mixer["decode"](
-                    h[:, 0], q[:, 0], lp, li, positions[:, 0],
-                    {"k": ck, "v": cv, **dict(zip(extra, ce))}, w_idx[:, 0], layer_table, lengths,
-                    page_size=page_size)
-                ce = [wrote.get(n, a) for n, a in zip(extra, ce)]
-                attn = attn[:, None]
-            x = x + _attn_out(attn.reshape(B, T, Hq * D), h, lp, cfg)
+                out, ck, cv, ce[0] = _mix_by_kind(kinds, group, li, h, ck, cv, ce[0], (project, write, attend))
+            x = x + out
         with jax.named_scope("mlp"):
             y = ffn(x, h, lp, li, tok_valid)
             y, aux = y if isinstance(y, tuple) else (y, None)
@@ -654,9 +753,12 @@ def forward_paged_impl(
 
     ck0 = cache["k"].reshape(total, HkvD)
     cv0 = cache["v"].reshape(total, HkvD)
-    carry = (x, ck0, cv0, *(cache[n].reshape((L * P,) + cache[n].shape[2:]) for n in extra))
-    for leaves, group_ffn, first, count in _layer_groups(params, ffn, unscanned, prefix, L):
-        carry, aux = jax.lax.scan(partial(body, ffn=group_ffn), carry, (leaves, jnp.arange(first, first + count)))
+    # An attention seam's arrays are carried by page, a state store as it lies.
+    carry = (x, ck0, cv0, *(cache[n] if kinds is not None else cache[n].reshape((L * P,) + cache[n].shape[2:])
+                            for n in extra))
+    for leaves, group_ffn, first, count in _layer_groups(params, ffn, unscanned, prefix, cfg.num_layers):
+        carry, aux = jax.lax.scan(partial(body, ffn=group_ffn, group=(first, count)), carry,
+                                  (leaves, jnp.arange(first, first + count)))
     x, ck, cv, *ce = carry
     new_cache = {"k": ck.reshape(L, P, page_size, HkvD),
                  "v": cv.reshape(L, P, page_size, HkvD),
@@ -730,6 +832,7 @@ def forward_ragged_impl(
     with_aux: bool = False,
     mixer=None,  # the attention seam of forward_paged_impl
     prefix=None,  # the leading group of forward_paged_impl
+    kinds=None,  # the per-layer kind of forward_paged_impl
 ):
     """Shared ragged skeleton, same flat-carry cache discipline as
     forward_paged_impl (the scatter lowers to an in-place row update;
@@ -750,47 +853,44 @@ def forward_ragged_impl(
     # Rows are packed back to back from 0: the tail past them is padding.
     tok_valid = jnp.arange(T) < jnp.sum(q_lens)
 
-    extra = tuple(mixer["cache"]) if mixer is not None else ()
+    assert mixer is None or kinds is None, "an attention seam beside a per-layer kind: no model has both"
+    extra = tuple(mixer["cache"]) if mixer is not None else (kinds["cache"],) if kinds is not None else ()
     assert mixer is None or prefix is None, "an attention seam's counts of a prefix's layers would be lost"
 
-    def body(carry, per_layer, ffn):
+    project = partial(_project_qkv, cfg=cfg, cos=cos, sin=sin, cache=cache)
+    write = partial(_write_kv, write_idx=write_idx, flat=flat, total=total)
+
+    def attend(h, ap, li, plane, q, ck, cv):
+        pages_k = ck.reshape(L * P, page_size, HkvD)
+        pages_v = cv.reshape(L * P, page_size, HkvD)
+        layer_table = page_table + plane * P
+        attn = ragged_paged_attention(
+            q[0], pages_k, pages_v, layer_table, q_starts, q_lens, kv_lens,
+            Hkv, mesh=mesh, **_window_operand(cfg, li))[None]  # (1, T, Hq, D)
+        return _attn_out(attn.reshape(B, T, Hq * D), h, ap, cfg)
+
+    def attention(h, ap, li, plane, ck, cv, ce):
+        q, *_ = qkv = project(h, ap, li)
+        ck, cv, w_idx = write(ck, cv, qkv, plane=plane)
+        if mixer is None:
+            return attend(h, ap, li, plane, q, ck, cv), ck, cv, ce, None
+        attn, wrote, mix_aux = mixer["ragged"](
+            h[0], q[0], ap, li, positions[0],
+            {"k": ck, "v": cv, **dict(zip(extra, ce))}, w_idx[0], page_table + plane * P,
+            q_starts, q_lens, kv_lens, page_size=page_size)
+        ce = [wrote.get(n, a) for n, a in zip(extra, ce)]
+        return _attn_out(attn[None].reshape(B, T, Hq * D), h, ap, cfg), ck, cv, ce, mix_aux
+
+    def body(carry, per_layer, ffn, group):
         x, ck, cv, *ce = carry
         lp, li = per_layer
         with jax.named_scope("attention"):
             h = rms_norm(x, _nw(lp["attn_norm"], cfg), cfg.rms_norm_eps)
-            q = qmatmul(h, lp["wq"])
-            k = qmatmul(h, lp["wk"])
-            v = qmatmul(h, lp["wv"])
-            if cfg.qkv_bias:
-                q = q + lp["bq"]
-                k = k + lp["bk"]
-                v = v + lp["bv"]
-            q = q.reshape(B, T, Hq, D)
-            k = k.reshape(B, T, Hkv, D)
-            v = v.reshape(B, T, Hkv, D)
-            q, k = _rope_qk(q, k, cos, sin, cfg, li, lp)
-
-            k_flat = k.reshape(B, T, HkvD).astype(ck.dtype)
-            v_flat = v.reshape(B, T, HkvD).astype(cv.dtype)
-            w_idx = jnp.where(write_idx >= flat, total, write_idx + li * flat)
-            ck = ck.at[w_idx].set(k_flat, mode="drop")
-            cv = cv.at[w_idx].set(v_flat, mode="drop")
-            pages_k = ck.reshape(L * P, page_size, HkvD)
-            pages_v = cv.reshape(L * P, page_size, HkvD)
-            layer_table = page_table + li * P
-
-            if mixer is None:
-                attn = ragged_paged_attention(
-                    q[0], pages_k, pages_v, layer_table, q_starts, q_lens, kv_lens,
-                    Hkv, mesh=mesh, **_window_operand(cfg, li))[None]  # (1, T, Hq, D)
+            if kinds is None:
+                out, ck, cv, ce, mix_aux = attention(h, lp, li, li, ck, cv, ce)
             else:
-                attn, wrote, mix_aux = mixer["ragged"](
-                    h[0], q[0], lp, li, positions[0],
-                    {"k": ck, "v": cv, **dict(zip(extra, ce))}, w_idx[0], layer_table,
-                    q_starts, q_lens, kv_lens, page_size=page_size)
-                ce = [wrote.get(n, a) for n, a in zip(extra, ce)]
-                attn = attn[None]
-            x = x + _attn_out(attn.reshape(B, T, Hq * D), h, lp, cfg)
+                out, ck, cv, ce[0] = _mix_by_kind(kinds, group, li, h, ck, cv, ce[0], (project, write, attend))
+            x = x + out
         with jax.named_scope("mlp"):
             y = ffn(x, h, lp, li, tok_valid)
             y, aux = y if isinstance(y, tuple) else (y, None)
@@ -799,9 +899,11 @@ def forward_ragged_impl(
 
     ck0 = cache["k"].reshape(total, HkvD)
     cv0 = cache["v"].reshape(total, HkvD)
-    carry = (x, ck0, cv0, *(cache[n].reshape((L * P,) + cache[n].shape[2:]) for n in extra))
-    for leaves, group_ffn, first, count in _layer_groups(params, ffn, unscanned, prefix, L):
-        carry, aux = jax.lax.scan(partial(body, ffn=group_ffn), carry, (leaves, jnp.arange(first, first + count)))
+    carry = (x, ck0, cv0, *(cache[n] if kinds is not None else cache[n].reshape((L * P,) + cache[n].shape[2:])
+                            for n in extra))
+    for leaves, group_ffn, first, count in _layer_groups(params, ffn, unscanned, prefix, cfg.num_layers):
+        carry, aux = jax.lax.scan(partial(body, ffn=group_ffn, group=(first, count)), carry,
+                                  (leaves, jnp.arange(first, first + count)))
     x, ck, cv, *ce = carry
     new_cache = {"k": ck.reshape(L, P, page_size, HkvD),
                  "v": cv.reshape(L, P, page_size, HkvD),

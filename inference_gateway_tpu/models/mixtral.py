@@ -6,7 +6,10 @@ attention, ops/sparse_attention.py, whose indexer keys are a third paged
 array; it rides the attention seam of the shared skeletons), and the
 Trinity-Mini block (ISSUE 41, ``afmoe``: leading dense layers before the
 expert layers in one layer stack, a sigmoid router with a selection bias
-and an always-on shared expert, gated attention under sandwich norms).
+and an always-on shared expert, gated attention under sandwich norms), and
+the LFM2 block (ISSUE 43, ``lfm2_moe``: gated short convolutions beside a few
+GQA layers in one stack, a per-layer KIND with a per-kind cache on the shared
+skeletons; the sigmoid router with no shared expert).
 
 Same TPU-first skeleton as models/llama.py (stacked layers + lax.scan,
 the shared paged and ragged forwards) with the dense MLP replaced by a
@@ -30,6 +33,7 @@ from inference_gateway_tpu.models.llama import (
     _ffn_out,
     _layer_groups,
     _rope_qk,
+    _scan_layers,
     forward_paged_impl,
     forward_ragged_impl,
     window_pages,
@@ -40,6 +44,7 @@ from inference_gateway_tpu.ops.norms import rms_norm
 from inference_gateway_tpu.ops.quant import qeinsum, qmatmul, seeded_qk_gain, seeded_std
 from inference_gateway_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
 from inference_gateway_tpu.ops.sparse_attention import sparse_decode, sparse_ragged
+from inference_gateway_tpu.ops.ssm import conv_decode, conv_ragged, token_segments
 
 
 @dataclass(frozen=True)
@@ -77,6 +82,19 @@ class MixtralConfig(LlamaConfig):
     # Always-on experts beside the routed ones: ONE dense gated MLP of
     # shared_experts * intermediate_size (leaves ``shared_w*``), added once.
     shared_experts: int = 0
+    # The normaliser's epsilon of the sigmoid router: w / (sum(w) + route_eps).
+    route_eps: float = 1e-20
+    # LFM2's block (ISSUE 43): a per-layer KIND, one 0/1 flag a layer or None.
+    # 1 is a layer whose token mixer is a gated short convolution, y = W_out
+    # (C * conv(B * u)) with [B, C, u] = h W_in, depthwise and causal over
+    # conv_kernel taps: it writes no key and keeps, a slot, its last
+    # conv_kernel - 1 values of B * u (the cache pytree's ``conv``). 0 is an
+    # attention layer. Each operator's leaves are a stack of their own
+    # (``conv_in``, ``conv_w``, ``conv_out``: leading axis conv_layers; ``wq``
+    # ... ``k_norm``: leading axis cache_layers), read at the layer's ordinal
+    # among its kind, as are the page pool's planes (cache_layers of them).
+    layer_conv: tuple | None = None
+    conv_kernel: int = 3
 
     def __post_init__(self):
         super().__post_init__()
@@ -88,10 +106,32 @@ class MixtralConfig(LlamaConfig):
             raise ValueError(f"router_score {self.router_score!r}: softmax or sigmoid")
         if not 0 <= self.num_dense_layers < self.num_layers:
             raise ValueError(f"num_dense_layers {self.num_dense_layers} of {self.num_layers} layers")
+        if self.layer_conv is not None:
+            flags = tuple(int(bool(f)) for f in self.layer_conv)
+            object.__setattr__(self, "layer_conv", flags)
+            if len(flags) != self.num_layers or not 0 < sum(flags) < self.num_layers:
+                raise ValueError(f"layer_conv {flags}: one flag a layer of {self.num_layers}, both kinds present")
+            if self.indexer_topk or self.attn_gate or self.sandwich_norm or self.qkv_bias:
+                raise ValueError("layer_conv: the attention operators' stack carries no indexer, gate, post-norm or bias")
 
     @property
     def expert_layers(self) -> int:
         return self.num_layers - self.num_dense_layers
+
+    @property
+    def conv_layers(self) -> int:
+        """Layers that keep a convolution's tail a slot and own no pages."""
+        return sum(self.layer_conv or ())
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers that write the page pool: its leading axis."""
+        return self.num_layers - self.conv_layers
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """A slot's share of the state store (float32, ops/ssm.py)."""
+        return self.conv_layers * (self.conv_kernel - 1) * self.hidden_size * 4
 
 
 Params = dict[str, Any]
@@ -127,6 +167,35 @@ def attention_leaves(cfg: MixtralConfig, count: int, dtype, matrix) -> Params:
     return out
 
 
+def layer_leaves(cfg: MixtralConfig, count: int, dtype, matrix) -> Params:
+    """What a stack of ``count`` layers holds of the token mixer: its
+    ``attention_leaves``; in a model with a per-layer kind the operator's input
+    norm alone, the operators being stacks of their own (``operator_leaves``)."""
+    if cfg.layer_conv is None:
+        return attention_leaves(cfg, count, dtype, matrix)
+    return {"attn_norm": jnp.ones((count, cfg.hidden_size), dtype)}
+
+
+CONV_LEAVES = ("conv_in", "conv_w", "conv_out")
+ATTENTION_STACK = ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
+
+
+def operator_leaves(cfg: MixtralConfig, dtype, matrix, plain) -> Params:
+    """The two operators' stacks of a model with a per-layer kind (none for
+    any other): the attention operators' (``ATTENTION_STACK``, leading axis
+    cache_layers) and the gated short convolutions' (``CONV_LEAVES``, leading
+    axis conv_layers: W_in (H, 3H) whose thirds are B, C, u in that order, the
+    taps (K, H) oldest first, W_out (H, H)). ``matrix`` as ``attention_leaves``
+    takes it; ``plain(name, shape)`` builds a leaf that is never quantised."""
+    if cfg.layer_conv is None:
+        return {}
+    H, n = cfg.hidden_size, cfg.conv_layers
+    attention = attention_leaves(cfg, cfg.cache_layers, dtype, matrix)
+    del attention["attn_norm"]   # the layers' own stacks hold every operator's input norm
+    return {**attention, "conv_in": matrix("conv_in", (n, H, 3 * H)),
+            "conv_w": plain("conv_w", (n, cfg.conv_kernel, H)), "conv_out": matrix("conv_out", (n, H, H))}
+
+
 def init_params(rng: jax.Array, cfg: MixtralConfig, dtype=jnp.bfloat16) -> Params:
     L, H, I, V, E = cfg.expert_layers, cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size, cfg.num_experts
     # One key a leaf the older mixtures have, as they always drew them; the
@@ -145,7 +214,8 @@ def init_params(rng: jax.Array, cfg: MixtralConfig, dtype=jnp.bfloat16) -> Param
         return norm(next(more), shape, seeded_std(cfg, name))
 
     layers: Params = {
-        **attention_leaves(cfg, L, dtype, matrix),
+        **layer_leaves(cfg, L, dtype, matrix),
+        **operator_leaves(cfg, dtype, matrix, lead_matrix),
         "moe_norm": jnp.ones((L, H), dtype),
         "router": matrix("router", (L, H, E)),
         # Expert FFNs: leading E axis → ep sharding.
@@ -168,9 +238,9 @@ def init_params(rng: jax.Array, cfg: MixtralConfig, dtype=jnp.bfloat16) -> Param
                       shared_wd=matrix("shared_wd", (L, Is, H)))
     if cfg.num_dense_layers:
         Lp, Id = cfg.num_dense_layers, cfg.dense_intermediate_size
-        lead = {**attention_leaves(cfg, Lp, dtype, lead_matrix), "mlp_norm": jnp.ones((Lp, H), dtype),
-                "wg": lead_matrix("wg", (Lp, H, Id)), "wu": lead_matrix("wu", (Lp, H, Id)),
-                "wd": lead_matrix("wd", (Lp, Id, H))}
+        lead = {**layer_leaves(cfg, Lp, dtype, lead_matrix), "mlp_norm": jnp.ones((Lp, H), dtype),
+                "wg": lead_matrix("dense_wg", (Lp, H, Id)), "wu": lead_matrix("dense_wu", (Lp, H, Id)),
+                "wd": lead_matrix("dense_wd", (Lp, Id, H))}   # (sized by their own names: seeded_std)
         layers.update({PREFIX + n: w for n, w in lead.items()})
     params: Params = {"embed": matrix("embed", (V, H)), "layers": layers, "final_norm": jnp.ones((H,), dtype)}
     if not cfg.tie_word_embeddings:
@@ -179,8 +249,19 @@ def init_params(rng: jax.Array, cfg: MixtralConfig, dtype=jnp.bfloat16) -> Param
 
 
 def init_cache(cfg: MixtralConfig, batch: int, max_len: int, dtype=jnp.bfloat16) -> Params:
-    shape = (cfg.num_layers, batch, max_len, cfg.num_kv_heads, cfg.hd)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    shape = (cfg.cache_layers, batch, max_len, cfg.num_kv_heads, cfg.hd)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype), **init_state(cfg, batch)}
+
+
+def init_state(cfg: MixtralConfig, slots: int) -> Params:
+    """The state store of a model with convolution layers, ``conv`` (conv
+    layers, slots, K - 1, H) float32: a slot's last K - 1 values of B * u in
+    each, oldest first (models/phi4flash.py's name and discipline: a segment
+    that starts at position 0 starts from zero whatever the slot held). None
+    for any other model."""
+    if cfg.layer_conv is None:
+        return {}
+    return {"conv": jnp.zeros((cfg.conv_layers, slots, cfg.conv_kernel - 1, cfg.hidden_size), jnp.float32)}
 
 
 EXPERT_LEAVES = ("wg", "wu", "wd")
@@ -263,12 +344,13 @@ def forward(
     window_on = jnp.asarray(cfg.layer_window or (1,) * L, bool) & bool(cfg.sliding_window)
     seam = _seam(params, cfg, mesh)
 
-    def layer(x, lp, li, kc, vc, ffn):
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-        q = qmatmul(h, lp["wq"]).reshape(B, T, Hq, D)
-        k = qmatmul(h, lp["wk"]).reshape(B, T, Hkv, D)
-        v = qmatmul(h, lp["wv"]).reshape(B, T, Hkv, D)
-        q, k = _rope_qk(q, k, cos, sin, cfg, li, lp)
+    def attention(h, ap, li, kc, vc):
+        """The attention operator: ``ap`` its leaves, kc / vc its cache rows
+        (or None). Returns (the residual contribution, the new rows)."""
+        q = qmatmul(h, ap["wq"]).reshape(B, T, Hq, D)
+        k = qmatmul(h, ap["wk"]).reshape(B, T, Hkv, D)
+        v = qmatmul(h, ap["wv"]).reshape(B, T, Hkv, D)
+        q, k = _rope_qk(q, k, cos, sin, cfg, li, ap)
         nk = nv = None
         if kc is not None:
             rows = (jnp.arange(B) if slot_ids is None else slot_ids)[:, None]
@@ -279,9 +361,18 @@ def forward(
             attn = gqa_attend(q, nk.astype(q.dtype), nv.astype(q.dtype), layer_mask)
         else:
             attn = gqa_attend(q, k, v, layer_mask)
-        x = x + _attn_out(attn.reshape(B, T, Hq * D), h, lp, cfg)
+        return _attn_out(attn.reshape(B, T, Hq * D), h, ap, cfg), nk, nv
+
+    def layer(x, lp, li, kc, vc, ffn):
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        out, nk, nv = attention(h, lp, li, kc, vc)
+        x = x + out
         y = ffn(x, h, lp, li, tok_valid)
         return x + _ffn_out(y[0] if isinstance(y, tuple) else y, lp, cfg), nk, nv
+
+    if cfg.layer_conv is not None:
+        x, new_cache = _forward_by_kind(params, cfg, x, cache, attention, decode, lengths, slot_ids, tok_valid, seam)
+        return _dense_head(params, cfg, x, lengths, mode, last_only), new_cache
 
     # The leading dense layers, where the model has them, then the expert
     # layers: one layer index over both groups.
@@ -305,16 +396,80 @@ def forward(
 
             x, _ = jax.lax.scan(body, x, (leaves, index))
     new_cache = None if cache is None else {"k": jnp.concatenate(new_k), "v": jnp.concatenate(new_v)}
+    return _dense_head(params, cfg, x, lengths, mode, last_only), new_cache
 
+
+def _dense_head(params: Params, cfg: MixtralConfig, x, lengths, mode: str, last_only: bool):
+    """``forward``'s final norm and head: logits (B, T, V), or (B, V) of each
+    row's last token."""
     x = rms_norm(x, params["final_norm"], cfg.rms_norm_eps)
     if last_only:
         idx = jnp.maximum(lengths - 1, 0) if mode == "prefill" else jnp.zeros_like(lengths)
-        x = x[jnp.arange(B), idx]
+        x = x[jnp.arange(x.shape[0]), idx]
     if cfg.tie_word_embeddings:
-        logits = (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
-    else:
-        logits = qmatmul(x, params["lm_head"]).astype(jnp.float32)
-    return logits, new_cache
+        return (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
+    return qmatmul(x, params["lm_head"]).astype(jnp.float32)
+
+
+def _forward_by_kind(params, cfg: MixtralConfig, x, cache, attention, decode, lengths, slot_ids, tok_valid, seam):
+    """``forward``'s layer loop for a model with a per-layer kind, over the
+    dense slot cache (the tests' oracle and the engine the benchmark's
+    reference child builds; serving goes through the paged skeletons). The
+    layers are walked one by one, unrolled: each reads its operator's leaves
+    and its cache rows at its ordinal among its kind. A prefill starts every
+    row's convolution from zero and leaves the row's last K - 1 inputs."""
+    B, T, H = x.shape
+    layers, K = params["layers"], cfg.conv_kernel
+    rows = jnp.arange(B) if slot_ids is None else slot_ids
+    new = None if cache is None else dict(cache)
+
+    def conv(z, tails, w):   # z (B*T, H) float32, row-major by batch row
+        if decode:
+            return conv_decode(z, tails, w, None, jnp.ones((B,), bool), act=None)
+        z = z.reshape(B, T, H)
+        padded = jnp.concatenate([jnp.zeros((B, K - 1, H), z.dtype), z], axis=1)
+        c = sum(w[j] * padded[:, j:j + T] for j in range(K))
+        last = lengths[:, None] + jnp.arange(K - 1)[None, :]              # the row's last K - 1, in ``padded``
+        return c.reshape(B * T, H), jnp.take_along_axis(padded, last[:, :, None], axis=1)
+
+    at = lambda stack, names, i: {n: jax.tree.map(lambda a: a[i], stack[n]) for n in names}
+    body = _scan_layers(params, seam["unscanned"])   # the expert layers' norms, router and bias
+    ordinal = [0, 0]
+    for li, is_conv in enumerate(cfg.layer_conv):
+        if li < cfg.num_dense_layers:
+            lp, ffn = at(seam["prefix"]["layers"], seam["prefix"]["layers"], li), seam["prefix"]["ffn"]
+        else:
+            lp, ffn = at(body, body, li - cfg.num_dense_layers), seam["ffn"]
+        o = ordinal[is_conv]
+        ordinal[is_conv] += 1
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        if is_conv:
+            tails = jnp.zeros((B, K - 1, H), jnp.float32) if cache is None else new["conv"][o][rows]
+            out, tails = _shortconv(h.reshape(B * T, H), at(layers, CONV_LEAVES, o), tails, conv)
+            out = out.reshape(B, T, H)
+            if cache is not None:
+                new["conv"] = new["conv"].at[o, rows].set(tails)
+        else:
+            kc, vc = (None, None) if cache is None else (new["k"][o], new["v"][o])
+            out, nk, nv = attention(h, at(layers, ATTENTION_STACK, o), li, kc, vc)
+            if cache is not None:
+                new["k"], new["v"] = new["k"].at[o].set(nk), new["v"].at[o].set(nv)
+        x = x + out
+        y = ffn(x, h, lp, li, tok_valid)
+        x = x + (y[0] if isinstance(y, tuple) else y)
+    return x, new
+
+
+def _shortconv(h: jnp.ndarray, sp: Params, tails: jnp.ndarray, conv) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """LFM2's gated short convolution on N tokens: h (N, H) the operator's
+    normed input, ``sp`` its leaves, ``tails`` what its slots remember.
+    [B, C, u] = h W_in; z = B * u; c = the depthwise causal convolution of z
+    (``conv(z, tails, taps) -> (c, new tails)``: ops/ssm.py's decode or ragged
+    form, float32, no bias, no activation); the output (C * c) W_out."""
+    with jax.named_scope("conv.short"):
+        b, c, u = jnp.split(qmatmul(h, sp["conv_in"]).astype(jnp.float32), 3, axis=-1)
+        y, tails = conv(b * u, tails, sp["conv_w"].astype(jnp.float32))
+        return qmatmul((c * y).astype(h.dtype), sp["conv_out"]), tails
 
 
 def _moe_ffn(x: jnp.ndarray, attn_in: jnp.ndarray, lp: Params, li, valid, *,
@@ -328,7 +483,7 @@ def _moe_ffn(x: jnp.ndarray, attn_in: jnp.ndarray, lp: Params, li, valid, *,
     routed = attn_in if cfg.router_input == "attn" else h
     route = router_topk
     if cfg.router_score == "sigmoid":
-        route = partial(router_sigmoid, bias=lp["router_bias"], scale=cfg.route_scale)
+        route = partial(router_sigmoid, bias=lp["router_bias"], scale=cfg.route_scale, eps=cfg.route_eps)
     # (No subtraction where nothing leads: an older mixture traces as it did.)
     stack_index = li - cfg.num_dense_layers if cfg.num_dense_layers else li
     out, rows = _moe_block(h.reshape(B * T, H), routed.reshape(B * T, H), lp["router"],
@@ -402,18 +557,24 @@ def _dsa_ragged(h, q, lp, li, positions, pools, w_idx, table, q_starts, q_lens, 
     return attn, {"ki": cki}, jnp.stack([jnp.sum(sees), jnp.sum(walked)]).astype(jnp.float32)
 
 
-def _seam(params: Params, cfg: MixtralConfig, mesh, valid=None) -> dict:
+def _seam(params: Params, cfg: MixtralConfig, mesh, valid=None, conv=None) -> dict:
     """What the shared skeletons take for a mixture: the FFN seam, the
     leaves it reads whole by layer index (never sliced per layer), for a
-    model with leading dense layers their group, and for a model with an
-    indexer the attention seam."""
+    model with leading dense layers their group, for a model with an
+    indexer the attention seam, and for a model with convolution layers the
+    per-layer kind (``conv``: the step's form of the convolution, ``_shortconv``'s)."""
     experts = {n: params["layers"][n] for n in EXPERT_LEAVES}
     seam = {"ffn": partial(_moe_ffn, cfg=cfg, experts=experts, mesh=mesh), "unscanned": EXPERT_LEAVES}
+    if cfg.layer_conv is not None:
+        seam["kinds"] = {"state": cfg.layer_conv, "cache": "conv", "mix": partial(_shortconv, conv=conv),
+                         "attention": {n: params["layers"][n] for n in ATTENTION_STACK},
+                         "leaves": {n: params["layers"][n] for n in CONV_LEAVES}}
+        seam["unscanned"] += ATTENTION_STACK + CONV_LEAVES
     if cfg.num_dense_layers:
         # The leading dense layers: a scanned group of their own in front.
         lead = prefix_leaves(params["layers"])
         seam["prefix"] = {"layers": lead, "ffn": partial(_dense_ffn, cfg=cfg)}
-        seam["unscanned"] = EXPERT_LEAVES + tuple(PREFIX + n for n in lead)
+        seam["unscanned"] += tuple(PREFIX + n for n in lead)
     if cfg.indexer_topk:
         seam["mixer"] = {"cache": DSA_CACHE,
                          "decode": partial(_dsa_decode, cfg=cfg, valid=valid),
@@ -431,26 +592,34 @@ def windowed_layers(cfg: MixtralConfig) -> int:
 def counts_window_pages(cfg: MixtralConfig) -> bool:
     """A mixture with windowed layers counts, in both step programs, the pages
     those layers read and hold (ISSUE 41; not one with an indexer: its
-    attention reads what the indexer chooses)."""
-    return windowed_layers(cfg) > 0 and not cfg.indexer_topk
+    attention reads what the indexer chooses). So does one whose pool holds
+    its caching layers alone (ISSUE 43): no window there, so both counts are
+    the pages its rows hold, over the caching layers."""
+    return (windowed_layers(cfg) > 0 and not cfg.indexer_topk) or cfg.layer_conv is not None
 
 
 def counted_window_pages(cfg: MixtralConfig, page_size: int, first_len, last_len):
-    """``llama.window_pages`` over this model's windowed layers."""
+    """``llama.window_pages`` over this model's windowed layers (its caching
+    layers, with a window no context reaches, where it has convolution layers)."""
+    if cfg.layer_conv is not None:
+        return window_pages(cfg.cache_layers, 1 << 30, page_size, first_len, last_len)
     return window_pages(windowed_layers(cfg), cfg.sliding_window, page_size, first_len, last_len)
 
 
 def init_paged_cache(cfg: MixtralConfig, cache_cfg, dtype=jnp.bfloat16) -> Params:
-    """The page pool of a model with an indexer: k and v as every model's
-    (serving/kv_cache.init_paged_cache), and the indexer's keys under the
-    same page ids."""
-    from inference_gateway_tpu.serving.kv_cache import init_paged_cache as kv_pages
-
-    cache = kv_pages(cfg, cache_cfg, dtype=dtype)
-    L, P, page_size, _ = cache["k"].shape
-    # A page of indexer keys lies transposed, the tokens in the lanes: 64 bf16
-    # a token would be half a lane row, padded to a whole one on the device.
-    return {**cache, "ki": jnp.zeros((L, P, cfg.indexer_head_dim, page_size), dtype)}
+    """The page pool of a model that keeps more than every layer's k and v
+    (serving/kv_cache.init_paged_cache): with an indexer, its keys under the
+    same page ids; with convolution layers, planes for the caching layers
+    alone and the state store beside them (``init_state``)."""
+    pages = cache_cfg.resolve_num_pages()
+    plane = (cfg.cache_layers, pages, cache_cfg.page_size)
+    cache = {"k": jnp.zeros(plane + (cfg.num_kv_heads * cfg.hd,), dtype),
+             "v": jnp.zeros(plane + (cfg.num_kv_heads * cfg.hd,), dtype), **init_state(cfg, cache_cfg.max_slots)}
+    if cfg.indexer_topk:
+        # A page of indexer keys lies transposed, the tokens in the lanes: 64 bf16
+        # a token would be half a lane row, padded to a whole one on the device.
+        cache["ki"] = jnp.zeros(plane[:2] + (cfg.indexer_head_dim, cache_cfg.page_size), dtype)
+    return cache
 
 
 @partial(jax.jit, static_argnames=("cfg", "mode", "last_only", "mesh", "with_aux"))
@@ -475,9 +644,13 @@ def forward_paged(
     (expert layers, E) (with an indexer, or windowed layers: a pair, that
     and the seam's counts or ``counted_window_pages``); ``valid`` (B,)
     marks the decode rows that carry a stream."""
+    conv = None
+    if cfg.layer_conv is not None:
+        rows = jnp.ones(lengths.shape, bool) if valid is None else valid   # a row that carries no stream keeps its tail
+        conv = lambda z, tails, w: conv_decode(z, tails, w, None, rows, act=None)
     out = forward_paged_impl(params, cfg, tokens, positions, lengths, cache,
                              write_idx, page_table, mode, last_only, mesh,
-                             valid=valid, with_aux=with_aux, **_seam(params, cfg, mesh, valid))
+                             valid=valid, with_aux=with_aux, **_seam(params, cfg, mesh, valid, conv))
     if not (with_aux and counts_window_pages(cfg)):
         return out
     live = lengths if valid is None else jnp.where(valid, lengths, 0)
@@ -501,9 +674,15 @@ def forward_ragged(
 ):
     """The mixed step for a mixture (llama.forward_ragged's contract; the
     third result as ``forward_paged``'s)."""
+    conv = None
+    if cfg.layer_conv is not None:
+        # Each segment from its slot's tail; one that starts at position 0
+        # from zero, whatever the slot held (ops/ssm.py).
+        seg, fresh = token_segments(q_starts, q_lens, tokens.shape[1]), (kv_lens - q_lens) == 0
+        conv = lambda z, tails, w: conv_ragged(z, tails, w, None, seg, fresh, q_starts, q_lens, act=None)
     out = forward_ragged_impl(params, cfg, tokens, positions, cache, write_idx,
                               page_table, q_starts, q_lens, kv_lens, mesh,
-                              with_aux=with_aux, **_seam(params, cfg, mesh))
+                              with_aux=with_aux, **_seam(params, cfg, mesh, conv=conv))
     if not (with_aux and counts_window_pages(cfg)):
         return out
     live = jnp.where(q_lens > 0, kv_lens, 0)
@@ -578,6 +757,18 @@ def _trinity(num_layers: int, **sizes) -> MixtralConfig:
                          router_score="sigmoid", shared_experts=1, rms_norm_eps=1e-5, **sizes)
 
 
+def _lfm2(layer_types: str, **sizes) -> MixtralConfig:
+    """LFM2's mixture block (ISSUE 43, ``lfm2_moe``): a gated short convolution
+    ("c") or grouped-query attention ("a") a layer in the published order, the
+    operator the only difference between the two kinds; q/k norms before rotary
+    in the attention layers; two leading dense layers, then experts routed by
+    sigmoid scores with a selection bias, the chosen scores normalised
+    (+ 1e-6) and no shared expert; a tied head."""
+    return MixtralConfig(num_layers=len(layer_types), layer_conv=tuple(k == "c" for k in layer_types),
+                         num_dense_layers=2, qk_norm=True, tie_word_embeddings=True, router_score="sigmoid",
+                         route_scale=1.0, route_eps=1e-6, rms_norm_eps=1e-5, **sizes)
+
+
 PRESETS: dict[str, MixtralConfig] = {
     "mixtral-test-tiny": MixtralConfig(
         vocab_size=256, hidden_size=64, num_layers=2, num_heads=4, num_kv_heads=2,
@@ -630,4 +821,17 @@ PRESETS: dict[str, MixtralConfig] = {
         8, vocab_size=200192, hidden_size=2048, num_heads=32, num_kv_heads=4, head_dim=128,
         intermediate_size=1024, dense_intermediate_size=6144, num_experts=128, experts_per_token=8,
         route_scale=2.826, rope_theta=10000.0, max_position_embeddings=131072, sliding_window=2048),
+    # The published first eight layers at toy widths: the prefix holds
+    # convolution layers only, and the body runs of them between attention layers.
+    "lfm2-test-tiny": _lfm2(
+        "ccacccac", vocab_size=256, hidden_size=64, num_heads=4, num_kv_heads=2, head_dim=16,
+        intermediate_size=32, dense_intermediate_size=96, num_experts=8, experts_per_token=2,
+        rope_theta=10000.0, max_position_embeddings=512),
+    # LiquidAI/LFM2-8B-A1B whole: 24 layers (18 convolutions, attention at 2, 6,
+    # 10, 14, 18, 21), every width, all 32 experts and the whole vocabulary as
+    # published: 8.5 GB of int8, which one v5e serves beside a 6-layer page pool.
+    "lfm2-8b-a1b": _lfm2(
+        "ccacccacccacccacccaccacc", vocab_size=65536, hidden_size=2048, num_heads=32, num_kv_heads=8,
+        head_dim=64, intermediate_size=1792, dense_intermediate_size=7168, num_experts=32, experts_per_token=4,
+        rope_theta=1000000.0, max_position_embeddings=128000),
 }

@@ -58,19 +58,20 @@ def router_topk(logits: jnp.ndarray, top_k: int) -> tuple[jnp.ndarray, jnp.ndarr
     return weights, idx
 
 
-def router_sigmoid(logits: jnp.ndarray, top_k: int, *, bias: jnp.ndarray, scale: float
-                   ) -> tuple[jnp.ndarray, jnp.ndarray]:
+def router_sigmoid(logits: jnp.ndarray, top_k: int, *, bias: jnp.ndarray, scale: float,
+                   eps: float = 1e-20) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Top-k routing over sigmoid scores with a selection bias (ISSUE 41).
 
     logits: (N, E) float32; bias: (E,). The chosen are the ``top_k`` largest
     of ``sigmoid(logits) + bias``; their weights are the scores WITHOUT the
-    bias, normalised over the chosen and multiplied by ``scale``. The bias
+    bias, normalised over the chosen (their sum + ``eps``: 1e-20 in Trinity's
+    modelling code, 1e-6 in LFM2's) and multiplied by ``scale``. The bias
     decides who is chosen and never what a chosen expert weighs.
     """
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)  # (N, k)
     weights = jnp.take_along_axis(scores, idx, axis=-1)
-    return weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20) * scale, idx
+    return weights / (jnp.sum(weights, axis=-1, keepdims=True) + eps) * scale, idx
 
 
 def moe_dense(x: jnp.ndarray, router_logits: jnp.ndarray, top_k: int, expert_fn,

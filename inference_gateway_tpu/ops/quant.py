@@ -152,6 +152,9 @@ QUANTIZABLE = ("wq", "wk", "wv", "wo", "wg", "wu", "wd",
                "attn_gate", "shared_wg", "shared_wu", "shared_wd",
                "dense_wq", "dense_wk", "dense_wv", "dense_wo", "dense_attn_gate",
                "dense_wg", "dense_wu", "dense_wd",
+               # LFM2's gated short convolution (ISSUE 43): its input and output
+               # projections; the taps stay full precision.
+               "conv_in", "conv_out",
                # models/phi4flash.py's matmul leaves (its QUANT_LEAVES): the MLP,
                # the Mamba mixer's and the gated memory unit's projections, the
                # fused key/value projection. W_x, W_dt, the convolution, A_log, D,
@@ -216,7 +219,9 @@ def seeded_std(cfg, leaf: str) -> float:
     thousands of tokens. Two leaves keep their published spread: the
     convolution's taps 0.29 (uniform(-1/2, 1/2) of four taps) and the
     lambdas 0.1 (normal(0, 0.1)). A_log, D and b_dt are not normal at all:
-    the model's initialiser writes them."""
+    the model's initialiser writes them.
+
+    A mixture with convolution layers (ISSUE 43): ``_lfm2_std``."""
     if hasattr(cfg, "mamba_d_state"):
         return {"m_conv_w": 0.29, "lam": 0.1, "embed": 0.02}.get(leaf, cfg.hidden_size ** -0.5)
     if getattr(cfg, "indexer_topk", 0):
@@ -246,6 +251,8 @@ def seeded_std(cfg, leaf: str) -> float:
                   "wv": cfg.hidden_size, "wo": cfg.num_heads * cfg.hd}.get(leaf)
         if fan_in:
             return fan_in ** -0.5
+    if getattr(cfg, "layer_conv", None) is not None:
+        return _lfm2_std(cfg, leaf)
     if getattr(cfg, "sandwich_norm", False):
         return _sandwich_std(cfg, leaf)
     if getattr(cfg, "num_experts", 0):
@@ -308,6 +315,81 @@ def _sandwich_std(cfg, leaf: str) -> float:
             "router_bias": 0.04, "shared_wd": 0.08}.get(leaf, 0.02)
 
 
+def _lfm2_std(cfg, leaf: str) -> float:
+    """``seeded_std`` for LFM2's block (ISSUE 43): gated short convolutions
+    beside attention layers on an UN-normed stream, a tied head, a sigmoid
+    router whose routed sum is the whole FFN sublayer (no shared expert, no
+    post-norm). Every sublayer reads a normed stream, so each scale is given
+    per fan-in and the 64-wide test preset and the published 2,048 are in one
+    regime; what matters is each sublayer's SIZE against the stream it joins.
+
+    - the embedding, which is also the head, at 1.5 x hidden ** -0.5: logits
+      that spread by 1.5. Every difference below was read at a spread of 2 and
+      follows it (the chip read three quarters of them at 1.5): at 2 a sound
+      run's largest difference was 0.18-0.28 nats over five runs on the chip,
+      mean 0.053-0.073, under limits of 0.45 / 0.12 that one failing run in
+      dozens must not pass; at 1.5 eleven runs read 0.11-0.27 / 0.042-0.070. The
+      stream starts at that size and forgets it: every sublayer below adds
+      0.1 to 2, so a token's own embedding is a hundredth of what the head
+      reads (measured at the test preset: embedding 0.19, a conv operator 0.5,
+      attention 0.7, a dense MLP 2.5, the stream 3.6-4.0 behind them) and the
+      tied head does not vote for the token it was given.
+    - the two leading dense MLPs' down projection at 4.4 x width ** -0.5
+      (gate and up at hidden ** -0.5): their outputs, RMS ~2 each, set the
+      stream's size ONCE (~3), so that it is nearly the same in front of all
+      22 routers and one expert share serves every depth.
+    - the convolution: ``W_in`` at hidden ** -0.5, so B, C and u are O(1) and
+      their cubic product's spread stays near the stream's (at larger gates
+      the product's heavy tail is what bf16 rounds); the three taps ALIKE at
+      3 ** -0.5, so the two lagged taps are two thirds of the convolution and
+      a fault in the carried tail shows (zeroed they read 6.6-8.7 / 3.2-4.1
+      nats at either size; the limits are 0.45 / 0.12); ``W_out`` at 0.5 x
+      hidden ** -0.5: a conv operator adds ~0.5, a sixth of the stream.
+    - attention: values at hidden ** -0.5, the output projection at 1.5 x
+      (heads x head size) ** -0.5, the q and k norms' gains at 1.4
+      (``seeded_qk_gain``: a head rests on a few dozen keys, so the norms
+      show: dropped they read 1.2-1.6 / 0.36-0.44).
+    - the router at hidden ** -0.5 (logits spread by 1) and the selection
+      bias at 2 / experts (0.0625 at 32 experts, 0.25 at the test preset's
+      8): sized against the spacing of neighbouring scores, ~1 / experts, so
+      it changes 0.9 of a token's 4 (of 2) at either size and starves no
+      expert: 64 rows x 4 choices still read 31.3 of 32 (independent tokens;
+      at 0.08 they read 30.0, at 0.12 28.3).
+    - **the experts' down projection at 0.6 x width ** -0.5** (gate and up at
+      hidden ** -0.5): the routed sum adds ~0.2 (top-4 of experts of RMS 0.36;
+      0.25 at the test preset's top-2), a twentieth of the stream.
+      Seeded experts are independent random functions, so a router score
+      that bf16 rounds across the cut swaps in another function: the served
+      stream is rounded to bf16 after every one of 48 sublayers, ~0.5% of
+      noise at a router's input against a mean gap of 0.15 logit spreads
+      between a token's 4th and 5th score, so ~4% of the (token, layer) pairs
+      swap one expert whatever the experts' size, and a swap moves every
+      later router by (swap / stream). What is free is that ratio. Measured in
+      the sandbox at the published widths (bf16 / int8 ``forward`` against
+      the float32 reference, teacher-forced, 96 tokens x 2 prompts, largest /
+      mean nats): experts at 0.02 (0.85 x width ** -0.5) sound 0.28-0.32 /
+      0.066-0.076, bias dropped from the selection 0.85-0.87 / 0.23-0.28;
+      at 0.01 sound 0.23 / 0.044-0.048, bias dropped 0.41-0.44 / 0.115-0.116:
+      the sound reading is 0.02 + 2.5 x std, the control's 12.6 x std, both
+      linear, so their ratio only rises with the share (2.5 at 0.01, 3.6 at
+      0.02) and no share puts both a factor two from the limits. At 0.6 x
+      width ** -0.5 (0.0142) with gate and up at 0.0221: sound 0.28 / 0.069,
+      the control 0.63 / 0.23, and the chip reads the same (0.18-0.28 /
+      0.053-0.073 and 0.60-1.04 / 0.168-0.198; at the final spread of 1.5
+      0.11-0.27 / 0.042-0.070 and 0.45-0.51 / 0.171-0.184: PERF.md section
+      6, PR 43). At the test preset (top-2 of 8: a swap is HALF the routed sum)
+      the same rule reads sound 0.06-0.28 / 0.023-0.057 and the control
+      0.55-1.2 / 0.17-0.27 over five pairs of prompts; at 1.15 times it one
+      pair in twelve read 0.53 sound.
+    - every other matrix 0.02, norms 1."""
+    fan = cfg.hidden_size ** -0.5
+    return {"embed": 1.5 * fan, "router": fan, "router_bias": 2.0 / cfg.num_experts,
+            "conv_in": fan, "conv_w": 3 ** -0.5, "conv_out": 0.5 * fan,
+            "wv": fan, "wo": 1.5 * (cfg.num_heads * cfg.hd) ** -0.5,
+            "wg": fan, "wu": fan, "wd": 0.6 * cfg.intermediate_size ** -0.5,
+            "dense_wg": fan, "dense_wu": fan, "dense_wd": 4.4 * cfg.dense_intermediate_size ** -0.5}.get(leaf, 0.02)
+
+
 def seeded_qk_gain(cfg) -> float:
     """What a seeded build writes into ``q_norm`` and ``k_norm``: 1, and 1.4
     for a model with a sparse-attention indexer and for one whose sublayers'
@@ -323,7 +405,8 @@ def seeded_qk_gain(cfg) -> float:
     whole heads, and served logprobs read 0.27 and 0.52 nats from the float32
     reference over two runs on the chip (PERF.md section 6, PR 36), past the
     benchmark's limit; at 1.4 they read 0.07."""
-    return 1.4 if getattr(cfg, "indexer_topk", 0) or getattr(cfg, "sandwich_norm", False) else 1.0
+    return 1.4 if (getattr(cfg, "indexer_topk", 0) or getattr(cfg, "sandwich_norm", False)
+                   or getattr(cfg, "layer_conv", None) is not None) else 1.0
 
 
 def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
@@ -388,13 +471,13 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
         return jax.tree.map(lambda s, b: jax.lax.dynamic_update_index_in_dim(s, b, layer, 0),
                             stacked, block)
 
-    def qfill(key, shape):
+    def qfill(key, shape, std=0.02):
         """qstack for a leaf too large to hold twice: the stacked leaf is
         allocated once and each layer's block is written into it."""
-        block = jax.eval_shape(lambda: qinit(key, shape[1:]))
+        block = jax.eval_shape(lambda: qinit(key, shape[1:], std))
         stacked = jax.tree.map(lambda b: jnp.zeros((shape[0],) + b.shape, b.dtype), block)
         for layer in range(shape[0]):
-            stacked = put(stacked, qinit(jax.random.fold_in(key, layer), shape[1:]), layer)
+            stacked = put(stacked, qinit(jax.random.fold_in(key, layer), shape[1:], std), layer)
         return stacked
 
     E = getattr(cfg, "num_experts", 0)
@@ -414,12 +497,14 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
 
         ekeys = jax.random.split(jax.random.fold_in(rng, 7), 4)
         layers = {
-            **mixtral.attention_leaves(cfg, L, dtype, matrix),
+            **mixtral.layer_leaves(cfg, L, dtype, matrix),
+            **mixtral.operator_leaves(cfg, dtype, matrix,
+                                      lambda name, shape: norm(next(more), shape, seeded_std(cfg, name))),
             "moe_norm": jnp.ones((L, H), dtype),
             "router": norm(ekeys[0], (L, H, E), seeded_std(cfg, "router")),
-            "wg": qfill(ekeys[1], (L, E, H, I)),
-            "wu": qfill(ekeys[2], (L, E, H, I)),
-            "wd": qfill(ekeys[3], (L, E, I, H)),
+            "wg": qfill(ekeys[1], (L, E, H, I), seeded_std(cfg, "wg")),
+            "wu": qfill(ekeys[2], (L, E, H, I), seeded_std(cfg, "wu")),
+            "wd": qfill(ekeys[3], (L, E, I, H), seeded_std(cfg, "wd")),
         }
         if cfg.indexer_topk:
             ikeys = jax.random.split(jax.random.fold_in(rng, 36), 3)
@@ -436,9 +521,9 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
                           shared_wd=matrix("shared_wd", (L, Is, H)))
         if cfg.num_dense_layers:
             Lp, Id = cfg.num_dense_layers, cfg.dense_intermediate_size
-            lead = {**mixtral.attention_leaves(cfg, Lp, dtype, lead_matrix), "mlp_norm": jnp.ones((Lp, H), dtype),
-                    "wg": lead_matrix("wg", (Lp, H, Id)), "wu": lead_matrix("wu", (Lp, H, Id)),
-                    "wd": lead_matrix("wd", (Lp, Id, H))}
+            lead = {**mixtral.layer_leaves(cfg, Lp, dtype, lead_matrix), "mlp_norm": jnp.ones((Lp, H), dtype),
+                    "wg": lead_matrix("dense_wg", (Lp, H, Id)), "wu": lead_matrix("dense_wu", (Lp, H, Id)),
+                    "wd": lead_matrix("dense_wd", (Lp, Id, H))}   # (sized by their own names: seeded_std)
             layers.update({mixtral.PREFIX + n: w for n, w in lead.items()})
     else:
         layers = {

@@ -19,6 +19,10 @@ the bfloat16 one failing).
 
     c_t = silu(b + sum_j w[j] * u_{t-K+1+j})
     S_t = exp(Delta_t A) * S_{t-1} + (Delta_t c_t) B_t^T ;  y_t = S_t C_t + D * c_t
+
+The convolution alone is also LFM2's token mixer (models/mixtral.py, ISSUE
+43: K = 3, no bias, no activation), so both of its forms take the bias
+(``b`` None: none) and the activation (``act`` None: linear).
 """
 
 from __future__ import annotations
@@ -27,14 +31,20 @@ import jax
 import jax.numpy as jnp
 
 
-def conv_decode(u, tails, w, b, valid):
+def _activate(acc, b, act):
+    """``act(b + acc)``; b None: no bias, act None: no activation."""
+    acc = acc if b is None else b + acc
+    return acc if act is None else act(acc)
+
+
+def conv_decode(u, tails, w, b, valid, act=jax.nn.silu):
     """One token a slot. u (S, Di); tails (S, K-1, Di) float32, oldest first;
-    w (K, Di); b (Di,); valid (S,) rows that advance. Returns c (S, Di)
+    w (K, Di); b (Di,) or None; valid (S,) rows that advance. Returns c (S, Di)
     float32 and the new tails."""
     with jax.named_scope("ssm.conv"):
         u = u.astype(jnp.float32)
         window = jnp.concatenate([tails, u[:, None, :]], axis=1)           # (S, K, Di)
-        c = jax.nn.silu(b + jnp.sum(window * w[None], axis=1))
+        c = _activate(jnp.sum(window * w[None], axis=1), b, act)
         return c, jnp.where(valid[:, None, None], window[:, 1:], tails)
 
 
@@ -61,11 +71,11 @@ def token_segments(q_starts, q_lens, T: int):
     return slot.astype(jnp.int32), local.astype(jnp.int32), live
 
 
-def conv_ragged(u, tails, w, b, seg, fresh, q_starts, q_lens):
+def conv_ragged(u, tails, w, b, seg, fresh, q_starts, q_lens, act=jax.nn.silu):
     """The convolution over packed tokens. u (T, Di); tails (S, K-1, Di);
     ``seg`` = token_segments(...); fresh (S,) slots whose segment starts at
-    position 0 (their tail counts as zero). Returns c (T, Di) float32 and
-    the new tails: the last K - 1 inputs of each slot that had tokens."""
+    position 0 (their tail counts as zero); b (Di,) or None. Returns c (T, Di)
+    float32 and the new tails: the last K - 1 inputs of each slot that had tokens."""
     with jax.named_scope("ssm.conv"):
         slot, local, live = seg
         T, K = u.shape[0], w.shape[0]
@@ -73,14 +83,14 @@ def conv_ragged(u, tails, w, b, seg, fresh, q_starts, q_lens):
         u = u.astype(jnp.float32)
         tails0 = jnp.where(fresh[:, None, None], 0.0, tails)
         row = jnp.minimum(slot, S - 1)
-        acc = b + w[K - 1] * u
+        acc = w[K - 1] * u if b is None else b + w[K - 1] * u
         for lag in range(1, K):
             # The input ``lag`` tokens back: a packed neighbour while that is
             # still inside the segment, else what the slot's tail remembers.
             back = jnp.roll(u, lag, axis=0)
             remembered = tails0[row, jnp.clip(K - 1 + local - lag, 0, K - 2)]
             acc = acc + w[K - 1 - lag] * jnp.where((local >= lag)[:, None], back, remembered)
-        c = jax.nn.silu(acc)
+        c = acc if act is None else act(acc)
         # New tail entry m of a slot is its input at segment index n - (K-1) + m.
         want = q_lens[:, None] - (K - 1) + jnp.arange(K - 1)[None, :]      # (S, K-1)
         from_u = u[jnp.clip(q_starts[:, None] + want, 0, T - 1)]          # (S, K-1, Di)

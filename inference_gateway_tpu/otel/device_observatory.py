@@ -469,7 +469,7 @@ class DeviceObservatory:
             kv_pool = tokens * kv_bytes_per_token(cfg, dtype_bytes)
             plan = {"weights_bytes": weights, "kv_pool_bytes": kv_pool,
                     "plan_bytes": weights + kv_pool}
-            if getattr(engine, "is_hybrid", False):
+            if getattr(engine, "carries_state", False):
                 # The per-slot state store beside the pool (float32).
                 plan["state_store_bytes"] = econf.max_slots * cfg.state_bytes_per_slot
                 plan["plan_bytes"] += plan["state_store_bytes"]

@@ -328,8 +328,16 @@ def preset_config(name: str):
 
 
 def carries_state(model_cfg) -> bool:
-    """A model whose layers keep a recurrent state per slot beside the
-    cache (models/phi4flash.py)."""
+    """A model whose layers keep a state per slot beside the page pool: the
+    hybrid's Mamba layers (models/phi4flash.py), a mixture's convolution
+    layers (models/mixtral.py, ISSUE 43). A state cannot be handed over as
+    pages can (prefix hits are declined), is carried by the mixed step alone,
+    and by no mesh and no speculation."""
+    return is_hybrid(model_cfg) or getattr(model_cfg, "layer_conv", None) is not None
+
+
+def is_hybrid(model_cfg) -> bool:
+    """A model of models/phi4flash.py: its own module, decoder loop and counts."""
     return hasattr(model_cfg, "mamba_d_state")
 
 
@@ -379,7 +387,7 @@ def choose_mesh(config: EngineConfig, model_cfg, n_dev: int):
         # (``mesh_axes`` and /debug/status say {"tp": 1}).
         if shape:
             raise ValueError(
-                f"{config.model}: no mesh ({shape}) carries the Mamba state store yet; "
+                f"{config.model}: no mesh ({shape}) carries the per-slot state store yet; "
                 "serve it on one device")
         return None, False
     if has_indexer(model_cfg):
@@ -576,7 +584,8 @@ class Engine:
         # a Mamba state → the hybrid's; plain LlamaConfig → dense forward.
         # Same call contract either way.
         self.is_moe = isinstance(self.model_cfg, mixtral.MixtralConfig)
-        self.is_hybrid = carries_state(self.model_cfg)
+        self.is_hybrid = is_hybrid(self.model_cfg)
+        self.carries_state = carries_state(self.model_cfg)
         self.is_dsa = has_indexer(self.model_cfg)
         self._model = mixtral if self.is_moe else llama
         if self.is_dsa:
@@ -589,7 +598,17 @@ class Engine:
                                  "gives no width for it); image input has no path, serve text")
             if config.quantize == "int4":
                 raise ValueError(f"{config.model}: int8 is the one weight-only quantisation built for it, not int4")
-        if has_dense_prefix(self.model_cfg):
+        if self.carries_state and not self.is_hybrid:
+            # What the convolution's carried tail rules out is refused by name (ISSUE 43).
+            if config.spec_draft is not None:
+                raise ValueError(f"{config.model}: speculative decoding would need each convolution layer's state at "
+                                 "the last ACCEPTED token, and a rejected draft has moved it on; serve it without "
+                                 "--spec-draft")
+            if config.vision_model is not None:
+                raise ValueError(f"{config.model}: it is a text model; image input has no path, serve text")
+            if config.quantize == "int4":
+                raise ValueError(f"{config.model}: int8 is the one weight-only quantisation built for it, not int4")
+        elif has_dense_prefix(self.model_cfg):
             # What the two stacks' path does not carry is refused by name (ISSUE 41).
             if config.spec_draft is not None:
                 raise ValueError(f"{config.model}: speculative decoding has no verify pass over its two stacks "
@@ -646,9 +665,9 @@ class Engine:
                 max_slots=config.max_slots, max_seq_len=config.max_seq_len,
             )
             self.allocator = PageAllocator(self.page_cfg)
-            if self.is_hybrid or self.is_dsa:
-                # The hybrid: pages for its caching layers alone, and the state
-                # store beside them. An indexer: its keys under the same page ids.
+            if self.carries_state or self.is_dsa:
+                # A model with a state store: pages for its caching layers alone,
+                # and the store beside them. An indexer: its keys under the same page ids.
                 cache = self._model.init_paged_cache(self.model_cfg, self.page_cfg, dtype=self.dtype)
             else:
                 cache = init_paged_cache(self.model_cfg, self.page_cfg, dtype=self.dtype)
@@ -664,7 +683,7 @@ class Engine:
 
                 # Pages can be handed over, a recurrent state cannot: with a
                 # state store every hit is declined, and counted.
-                self.prefix_cache = PrefixCache(self.allocator, decline_hits=self.is_hybrid)
+                self.prefix_cache = PrefixCache(self.allocator, decline_hits=self.carries_state)
         else:
             cache = self._model.init_cache(self.model_cfg, config.max_slots, config.max_seq_len, dtype=self.dtype)
             if self.mesh is not None:
@@ -691,7 +710,7 @@ class Engine:
         # is one with an indexer (each query of a chunk selects for itself).
         ep = self.mesh.shape.get("ep", 1) if self.mesh is not None else 1
         self.mixed_ok = (
-            self.paged and (config.mixed_step or self.is_hybrid or self.is_dsa)
+            self.paged and (config.mixed_step or self.carries_state or self.is_dsa)
             and config.spec_draft is None
             and ep == 1 and not self.pp and config.vision_model is None
         )
@@ -925,7 +944,7 @@ class Engine:
         else:
             self._chunk_stats = CHUNK_EXPERT_STATS + more_stats if self.is_moe else ()
             self._mixed_stats = MIXED_STATS + more_stats
-        if self.is_hybrid:
+        if self.carries_state:
             # Segments that started at position 0, so from a zeroed state:
             # every admission, and every resume after a preemption.
             self.metrics["state_resets"] = 0
@@ -1507,7 +1526,7 @@ class Engine:
                     off += n
                     if r.kind == "prefill":
                         n_prefill += n
-                    if self.is_hybrid and r.start == 0:
+                    if self.carries_state and r.start == 0:
                         self.metrics["state_resets"] += 1
             with phase_span(phases, "upload_dispatch", "engine.mixed.upload_dispatch"):
                 masked, mnext, mbits, mbias = self._mask_args()
@@ -1543,7 +1562,7 @@ class Engine:
     def _state_stats(self, rows: int) -> dict | None:
         """``state_bytes`` of a step's timeline record: what its rows hold in
         the state store, which the step reads and writes once a token."""
-        if not self.is_hybrid:
+        if not self.carries_state:
             return None
         return {"state_bytes": int(rows) * self.model_cfg.state_bytes_per_slot}
 
@@ -1551,13 +1570,17 @@ class Engine:
         """/debug/status of a model with a state store: the store, the layers
         that write the page pool and who reads the shared one, and the two
         counters (segments started from zero; prefix hits declined)."""
-        if not self.is_hybrid:
+        if not self.carries_state:
             return None
         cfg = self.model_cfg
+        if self.is_hybrid:
+            state_layers, kinds = cfg.state_layers, {"window": cfg.mid, "full": 1, "shared_readers": cfg.cross_layers}
+        else:  # a mixture with convolution layers (ISSUE 43)
+            state_layers, kinds = cfg.conv_layers, {"full": cfg.cache_layers, "conv": cfg.conv_layers}
         return {
-            "state_store": {"slots": self.config.max_slots, "layers": cfg.state_layers,
+            "state_store": {"slots": self.config.max_slots, "layers": state_layers,
                             "bytes": self.config.max_slots * cfg.state_bytes_per_slot},
-            "cache_layers": {"window": cfg.mid, "full": 1, "shared_readers": cfg.cross_layers},
+            "cache_layers": kinds,
             "state_resets": self.metrics["state_resets"],
             "prefix_hits_declined": self.prefix_cache.declined if self.prefix_cache is not None else 0,
         }
@@ -1587,7 +1610,7 @@ class Engine:
                           "weights": ("the chosen scores, normalised" if cfg.router_score == "sigmoid"
                                       else "softmax over the chosen logits")},
                "shared_experts": cfg.shared_experts, "dense_prefix": cfg.num_dense_layers}
-        if self.counts_window:
+        if self.counts_window and not self.carries_state:   # (with a state store: ``state_status`` says)
             windowed = self._model.windowed_layers(cfg)
             out["cache_layers"] = {"window": windowed, "full": cfg.num_layers - windowed}
         return out
@@ -2686,7 +2709,7 @@ class Engine:
         400 check can never drift from actual admission behavior."""
         biggest = max(b for b in self.config.prefill_buckets
                       if b <= self.config.max_seq_len)
-        if (self.is_hybrid or self.is_dsa) and self.mixed_ok:
+        if (self.carries_state or self.is_dsa) and self.mixed_ok:
             biggest = 0  # every prompt is a "long" one: the ragged step admits them all
         ring_ok = (
             self.mesh is not None

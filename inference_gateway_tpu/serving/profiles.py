@@ -89,20 +89,24 @@ def llama_param_count(cfg: llama.LlamaConfig) -> int:
 def mixtral_param_count(cfg: mixtral.MixtralConfig) -> int:
     """Every layer's attention (with an output gate and post-norms where the
     model has them), the expert layers' mixture (+ a shared expert), and a
-    leading dense stack's MLPs (ISSUE 41: the two stacks are counted apart)."""
+    leading dense stack's MLPs (ISSUE 41: the two stacks are counted apart).
+    With convolution layers (ISSUE 43) the token mixers are counted by kind:
+    the caching layers' attention, the other layers' gated short convolution.
+    The count of the built tree's leaves (a tied head once)."""
     H, I, V, E = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size, cfg.num_experts
     Hkv_D = cfg.num_kv_heads * cfg.hd
     Hq_D = cfg.num_heads * cfg.hd
-    attention = H * Hq_D + 2 * H * Hkv_D + Hq_D * H + 2 * H  # q, k, v, o; input norms of both sublayers
+    attention = H * Hq_D + 2 * H * Hkv_D + Hq_D * H  # q, k, v, o
     if cfg.attn_gate:
         attention += H * Hq_D
     if cfg.sandwich_norm:
         attention += 2 * H
     if cfg.qk_norm:
         attention += 2 * cfg.hd
+    conv = 3 * H * H + cfg.conv_kernel * H + H * H  # W_in, the taps, W_out
+    mixers = cfg.cache_layers * attention + cfg.conv_layers * conv + cfg.num_layers * 2 * H  # + both sublayers' input norms
     per_layer = (
-        attention
-        + E * 3 * H * I  # experts
+        E * 3 * H * I  # experts
         + H * E  # router
         + cfg.shared_experts * 3 * H * I
         + (E if cfg.router_score == "sigmoid" else 0)  # the selection bias
@@ -110,8 +114,9 @@ def mixtral_param_count(cfg: mixtral.MixtralConfig) -> int:
     if cfg.indexer_topk:  # the sparse attention's indexer (ISSUE 36)
         Hi, Di = cfg.indexer_heads, cfg.indexer_head_dim
         per_layer += H * (Hi * Di + Di + Hi) + 2 * Di
-    dense_layer = attention + 3 * H * cfg.dense_intermediate_size
-    return V * H + cfg.expert_layers * per_layer + cfg.num_dense_layers * dense_layer + H + V * H
+    dense_layer = 3 * H * cfg.dense_intermediate_size
+    head = 0 if cfg.tie_word_embeddings else V * H
+    return V * H + mixers + cfg.expert_layers * per_layer + cfg.num_dense_layers * dense_layer + H + head
 
 
 def kv_bytes_per_token(cfg, dtype_bytes: int = 2) -> int:

@@ -7,8 +7,9 @@ stopgap's refusals); a ``benchmark`` PR may add no file under ``tests/``, so
 they ran by hand alone. Here they are collected as they stand, and each new
 reference (``moe_early_router_gqa``, PR 28; ``sambay_diff``, PR 32) is a further
 case of the first. PR 32's file checks (``bench/tests/test_phi4flash_files.py``)
-are collected the same way, and PR 36's (``bench/tests/test_keye_files.py``) and
-PR 41's (``bench/tests/test_trinity_files.py``).
+are collected the same way, and PR 36's (``bench/tests/test_keye_files.py``),
+PR 41's (``bench/tests/test_trinity_files.py``) and PR 43's
+(``bench/tests/test_lfm2_files.py``).
 """
 
 import importlib.util
@@ -49,16 +50,25 @@ _spec.loader.exec_module(trinity_files)
 globals().update({name if name not in globals() else f"{name}_trinity": fn
                   for name, fn in vars(trinity_files).items() if name.startswith("test_")})
 
+_spec = importlib.util.spec_from_file_location("bench_test_lfm2_files", BENCH / "tests" / "test_lfm2_files.py")
+lfm2_files = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(lfm2_files)
+globals().update({name if name not in globals() else f"{name}_lfm2": fn
+                  for name, fn in vars(lfm2_files).items() if name.startswith("test_")})
+
 
 # What later PRs appended after PR 32's entries, by list and in order: PR 36 a
 # configuration, its cell and two readers, PR 38 the idle ledger's five readers,
-# PR 41 a configuration, its cell and two readers. All the cut below may drop.
-APPENDED_SINCE_PR_32 = {"configs": ["keye-vl2-30b-a3b-int8", "trinity-mini-int8"],
-                        "workloads": ["keye-vl2-30b-a3b.long-context", "trinity-mini.long-context"],
+# PR 41 a configuration, its cell and two readers, PR 43 the same. All the cut
+# below may drop.
+APPENDED_SINCE_PR_32 = {"configs": ["keye-vl2-30b-a3b-int8", "trinity-mini-int8", "lfm2-8b-a1b-int8"],
+                        "workloads": ["keye-vl2-30b-a3b.long-context", "trinity-mini.long-context",
+                                      "lfm2-8b-a1b.reason"],
                         "per_layer": ["dsa_kv_read_share", "dsa_busy_share",
                                       "fetch_return_ms", "idle_unspanned_share", "starved_slot_share",
                                       "starved_delivery_share", "starved_unspanned_share",
-                                      "swa_page_skip_share", "afmoe_step_hbm_roofline_share"]}
+                                      "swa_page_skip_share", "afmoe_step_hbm_roofline_share",
+                                      "lfm2_decode_hbm_roofline_share", "shortconv_busy_share"]}
 
 
 def test_the_benchmark_gains_one_configuration_one_cell_and_three_readers(tmp_path, monkeypatch):
@@ -84,10 +94,33 @@ def test_the_benchmark_gains_one_configuration_one_cell_and_three_readers(tmp_pa
     files.test_the_benchmark_gains_one_configuration_one_cell_and_three_readers()
 
 
+def test_the_benchmark_gains_one_configuration_one_cell_and_two_readers_after_keyes(tmp_path, monkeypatch):
+    """The same STOPGAP for one assertion of PR 41's check: it holds
+    ``decode_step_ms`` and ``decode_batch_mean`` to list the seven cells its
+    parent had and NO other, which the next cell that makes ``decode`` records
+    ends (PR 43's does: its window is decode steps of 64 rows). The check reads
+    ``BENCHMARK.json`` with PR 43's cell taken off those two lists and nothing
+    else changed; every other assertion of it sees the file as it is."""
+    bench = json.loads((lfm2_files.ROOT / "BENCHMARK.json").read_text())
+    for metric in bench["per_layer"]:
+        if metric["name"] in ("decode_step_ms", "decode_batch_mean"):
+            assert metric["workloads"][-1] == lfm2_files.CELL, metric
+            metric["workloads"] = metric["workloads"][:-1]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    monkeypatch.setattr(trinity_files, "ROOT", tmp_path)
+    trinity_files.test_the_benchmark_gains_one_configuration_one_cell_and_two_readers_after_keyes()
+
+
 child.CONFIGS["moe_early_router_gqa"] = BENCH / "configs" / "smallthinker-21b-a3b-int8.json"
 child.CONFIGS["sambay_diff"] = BENCH / "configs" / "phi-4-mini-flash-int8.json"
 
 
+# (PR 43's ``lfm2_moe`` is not a third case of this one: the check holds a tiny
+# engine to 0.05 / 0.01 nats, which fixes the logit scale a tiny preset may have
+# (PERF.md section 7 (j)); LFM2's is seeded with logits that spread by 2, as the
+# published size is, and reads 0.06-0.35 / 0.02-0.06. Its own file's check
+# serves probes across chunk boundaries and holds the child to the benchmark's
+# limits, with a control: ``test_lfm2_files``, collected above.)
 @pytest.mark.parametrize("name", ["moe_early_router_gqa", "sambay_diff"])
 def test_the_new_references_child_says_ok_on_probes_of_a_tiny_engine(name, tmp_path):
     """SmallThinker's tiny preset (one period, window 16 under probes of 40 to
@@ -98,6 +131,6 @@ def test_the_new_references_child_says_ok_on_probes_of_a_tiny_engine(name, tmp_p
     child.test_reference_child_says_ok_on_probes_of_a_tiny_engine(name, tmp_path)
 
 
-@pytest.mark.parametrize("preset", ["smallthinker-test-tiny", "phi4flash-test-tiny"])
+@pytest.mark.parametrize("preset", ["smallthinker-test-tiny", "phi4flash-test-tiny", "lfm2-test-tiny"])
 def test_the_new_configurations_child_builds_the_sidecars_tree(preset):
     child.test_the_childs_weights_are_the_serving_engines_bit_for_bit(preset, "int8")
