@@ -1,4 +1,4 @@
-"""Time the paged attention kernels alone on the chip (ISSUE 34, ISSUE 37).
+"""Time the paged attention kernels alone on the chip (ISSUE 34, ISSUE 37, ISSUE 44).
 
 By hand, through chiprun, parent and change in one call (a chip belongs to
 one process at a time, so each side is a process of its own):
@@ -16,7 +16,18 @@ reading of what its rows can see, the v5e's least time for them
 `ragged_paged_attention_jax` on the same bf16 inputs. `--set NAME=JSON` sets a
 constant of the kernel's module first (a variant to try); `--window-operand`
 hands the cases of a model that mixes layer kinds their window as the operand
-its mixed step hands it.
+its mixed step hands it. ISSUE 44's levers by `--set`: `RAGGED_VISIT_BYTES=1`
+walks a wide tile one page a visit (`=9437184` two, `=16777216` four at
+Trinity-Mini's shape), `RAGGED_VISITS_A_ROW=1` lifts the cap that short rows
+put on it, `RAGGED_UNMASKED_VISITS=false` masks every visit; the one-query
+rows' path has no switch (the parent's tree is its "off": `--root`), and the
+`.chunk` / `.rows` cases hold each half of a step alone.
+
+The long-context cases (ISSUE 44, ``trinity-mini.long-context.*``) hold rows
+of up to 100 pages, a windowed and a global layer's call each whole, with the
+chunk alone and with the one-query rows alone; their twin is
+``ragged_paged_attention_jax`` called a row and 64 queries at a time (whole, it
+gathers every query's own copy of its row's keys: 7 GB here).
 
 The decode-only cases (ISSUE 37, ``decode:<cell>``) hold one decode step's
 slots at each cell's shape, live and empty, and time two kernels on the same
@@ -43,7 +54,8 @@ PAGE, MAX_PAGES, CALLS, REPEATS = 128, 16, 16, 15
 # program and hear of its end would be a third of the reading (ISSUE 37, step 0).
 DECODE_CALLS = 128
 
-# name: (Hq, Hkv, D, window, slots, step width, [(q_len, kv_len), ...])
+# name: (Hq, Hkv, D, window, slots, step width, [(q_len, kv_len), ...]), and
+# for the long-context cases the pages a row may hold (MAX_PAGES otherwise)
 def _cases() -> dict:
     import numpy as np
 
@@ -52,7 +64,21 @@ def _cases() -> dict:
     def decode(n, lo, hi):
         return [(1, int(k)) for k in rng.integers(lo, hi, size=n)]
 
+    # every step of trinity-mini.long-context: a 512-token chunk deep in a
+    # 4,096-12,288-token prompt beside ~17 one-query rows at 4,200-12,700
+    long_rows = [(1, int(k)) for k in np.random.default_rng(44).integers(4200, 12700, size=17)]
+    long_chunk = [(512, 8704)]
+
+    def trinity(window, rows):
+        return (32, 4, 128, window, 32, 544, rows, 100)
+
     return {
+        "trinity-mini.long-context.window": trinity(2048, long_chunk + long_rows),
+        "trinity-mini.long-context.window.chunk": trinity(2048, long_chunk),
+        "trinity-mini.long-context.window.rows": trinity(2048, long_rows),
+        "trinity-mini.long-context.global": trinity(None, long_chunk + long_rows),
+        "trinity-mini.long-context.global.chunk": trinity(None, long_chunk),
+        "trinity-mini.long-context.global.rows": trinity(None, long_rows),
         # a 512-token chunk of a 1,024-1,792-token prompt, ~2.5 rows decoding
         "mistral-7b.long-prompt": (32, 8, 128, 4096, 24, 536,
                                    [(512, 1024)] + decode(3, 1100, 1800)),
@@ -102,7 +128,7 @@ def _decode_cases() -> dict:
 
 
 # The cases whose model mixes windowed and global layers.
-MIXED_KINDS = ("smallthinker-21b-a3b.batch", "phi-4-mini-flash.reason")
+MIXED_KINDS = ("smallthinker-21b-a3b.batch", "phi-4-mini-flash.reason", "trinity-mini.long-context")
 
 
 def _reckon(rows, Hq, Hkv, D, window) -> tuple[float, float]:
@@ -157,23 +183,25 @@ def main() -> int:
 
 
 def _timed(call, q, k, v, ref, rows, Hq, Hkv, D, window, chip, repeats, live=True, profile=False,
-           calls=CALLS) -> dict:
-    """One kernel's line: ``call(q, k, v)`` chained ``calls`` times in one
-    program; its distance from ``ref`` over the rows of ``live``."""
+           calls=CALLS, operands=()) -> dict:
+    """One kernel's line: ``call(q, k, v, *operands)`` chained ``calls`` times
+    in one program (the table and the descriptors its ARGUMENTS, as a step
+    program holds them: closed over, what a call derives from them would be
+    folded away); its distance from ``ref`` over the rows of ``live``."""
     import jax
     import jax.numpy as jnp
 
     @jax.jit
-    def chained(q_, k_, v_):
-        return jax.lax.fori_loop(0, calls, lambda i, x: call(x, k_, v_), q_)
+    def chained(q_, k_, v_, *ops):
+        return jax.lax.fori_loop(0, calls, lambda i, x: call(x, k_, v_, *ops), q_)
 
-    got = jax.block_until_ready(jax.jit(call)(q, k, v))
+    got = jax.block_until_ready(jax.jit(call)(q, k, v, *operands))
     diff = float(jnp.max(jnp.where(live, jnp.abs(got.astype(jnp.float32) - ref.astype(jnp.float32)), 0)))
-    jax.block_until_ready(chained(q, k, v))
+    jax.block_until_ready(chained(q, k, v, *operands))
     times = []
     for _ in range(repeats):
         t0 = time.perf_counter()
-        jax.block_until_ready(chained(q, k, v))
+        jax.block_until_ready(chained(q, k, v, *operands))
         times.append((time.perf_counter() - t0) / calls)
     flops, nbytes = _reckon(rows, Hq, Hkv, D, window)
     line = {"us_per_call": round(statistics.median(times) * 1e6, 1),
@@ -189,7 +217,8 @@ def _timed(call, q, k, v, ref, rows, Hq, Hkv, D, window, chip, repeats, live=Tru
                     bound="flops" if t_flop >= t_byte else "bytes",
                     roofline_share_pct=round(100 * least / statistics.median(times), 2))
     if profile:
-        line["device_us_by_op"] = _device_us_by_op(lambda: jax.block_until_ready(chained(q, k, v)), calls)
+        line["device_us_by_op"] = _device_us_by_op(
+            lambda: jax.block_until_ready(chained(q, k, v, *operands)), calls)
     return line
 
 
@@ -218,6 +247,30 @@ def _device_us_by_op(run, calls) -> dict:
     return {k[:60]: round(v, 2) for k, v in sorted(by_op.items(), key=lambda kv: -kv[1])[:6]}
 
 
+def _twin(pa, q, k, v, pt, q_starts, q_lens, kv_lens, Hkv, window, whole=True, block=64):
+    """``ragged_paged_attention_jax`` on the case; a case of deep rows a row
+    and ``block`` queries at a time (a row's queries from its a-th on are a
+    row of their own that ends where they end: the mask is causal)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    twin = jax.jit(pa.ragged_paged_attention_jax, static_argnames=("num_kv_heads", "window"))
+    if whole:
+        return twin(q, k, v, pt, jnp.asarray(q_starts), jnp.asarray(q_lens), jnp.asarray(kv_lens),
+                    num_kv_heads=Hkv, window=window)
+    out = jnp.zeros_like(q)
+    one = lambda x: jnp.asarray([x], jnp.int32)
+    for r in np.flatnonzero(q_lens):
+        for a in range(0, int(q_lens[r]), block):
+            n = min(block, int(q_lens[r]) - a)
+            at = int(q_starts[r]) + a
+            part = twin(q[at:at + n], k, v, pt[r:r + 1], one(0), one(n),
+                        one(int(kv_lens[r]) - int(q_lens[r]) + a + n), num_kv_heads=Hkv, window=window)
+            out = out.at[at:at + n].set(part)
+    return out
+
+
 def measure(pa, names=None, window_operand=False, repeats=REPEATS, profile=False) -> dict:
     """Each case's line, for the kernels of the module ``pa``."""
     import jax
@@ -230,37 +283,45 @@ def measure(pa, names=None, window_operand=False, repeats=REPEATS, profile=False
     interpret = dev.platform != "tpu"
     bf = jnp.bfloat16
     lines = {}
-    for name, (Hq, Hkv, D, window, R, T, rows) in _cases().items():
+    for name, (Hq, Hkv, D, window, R, T, rows, *deep) in _cases().items():
         if names and name not in names:
             continue
+        max_pages = deep[0] if deep else MAX_PAGES
         rng = np.random.default_rng(len(name))
         q_lens = np.zeros(R, np.int32)
         kv_lens = np.zeros(R, np.int32)
         q_starts = np.zeros(R, np.int32)
         slots = rng.permutation(R)[:len(rows)]
         off = 0
-        for slot, (ql, kl) in zip(slots, rows):
+        # One-query rows are packed first, as the scheduler packs them.
+        for slot, (ql, kl) in sorted(zip(slots, rows), key=lambda sr: sr[1][0] > 1):
             q_starts[slot], q_lens[slot], kv_lens[slot] = off, ql, kl
             off += ql
         assert off <= T, (name, off, T)
-        P = R * MAX_PAGES
-        q = jnp.asarray(rng.normal(size=(T, Hq, D)), bf)
-        k = jnp.asarray(rng.normal(size=(P, PAGE, Hkv * D)), bf)
-        v = jnp.asarray(rng.normal(size=(P, PAGE, Hkv * D)), bf)
-        pt = jnp.asarray(rng.permutation(P).reshape(R, MAX_PAGES).astype(np.int32))
+        P = R * max_pages
+        normal = (lambda size: rng.standard_normal(size, dtype=np.float32)) if deep else (
+            lambda size: rng.normal(size=size))
+        q = jnp.asarray(normal((T, Hq, D)), bf)
+        k = jnp.asarray(normal((P, PAGE, Hkv * D)), bf)
+        v = jnp.asarray(normal((P, PAGE, Hkv * D)), bf)
+        pt = jnp.asarray(rng.permutation(P).reshape(R, max_pages).astype(np.int32))
         descr = (jnp.asarray(q_starts), jnp.asarray(q_lens), jnp.asarray(kv_lens))
 
         kw = {"window": window}
-        if window_operand and name in MIXED_KINDS:
-            kw = {"window_dyn": jnp.int32(window)}
+        if window_operand and any(name.startswith(m) for m in MIXED_KINDS):
+            kw = {"window_dyn": jnp.int32(window or pa.GLOBAL_WINDOW)}
 
-        def call(q_, k_, v_):
-            return pa.ragged_paged_attention_tpu(q_, k_, v_, pt, *descr, Hkv,
+        def call(q_, k_, v_, pt_, *descr_):
+            return pa.ragged_paged_attention_tpu(q_, k_, v_, pt_, *descr_, Hkv,
                                                  interpret=interpret, **kw)
 
-        ref = jax.jit(pa.ragged_paged_attention_jax, static_argnames=("num_kv_heads", "window"))(
-            q, k, v, pt, *descr, num_kv_heads=Hkv, window=window)
-        lines[name] = _timed(call, q, k, v, ref, rows, Hq, Hkv, D, window, chip, repeats, profile=profile)
+        ref = _twin(pa, q, k, v, pt, q_starts, q_lens, kv_lens, Hkv, window, whole=not deep)
+        lines[name] = _timed(call, q, k, v, ref, rows, Hq, Hkv, D, window, chip, repeats, profile=profile,
+                             operands=(pt, *descr))
+        try:
+            lines[name]["plan"] = pa.ragged_tile_plan(Hq, Hkv, D, PAGE, max_pages, bf)
+        except TypeError:  # a tree before ISSUE 44: the plan knew the groups and the page alone
+            lines[name]["plan"] = pa.ragged_tile_plan(Hq // Hkv, PAGE)
         print(name, json.dumps(lines[name]), flush=True)
 
     for name, (Hq, Hkv, D, window, B, contexts) in _decode_cases().items():

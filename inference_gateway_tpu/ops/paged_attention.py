@@ -34,7 +34,6 @@ a kernel (see ``paged_dispatch``).
 
 from __future__ import annotations
 
-import collections
 import functools
 
 import jax
@@ -154,6 +153,35 @@ def _each_head(num_kv_heads: int, head_dim: int, body) -> None:
         jax.lax.fori_loop(0, num_kv_heads, lambda h, c: (body(h), c)[1], 0, unroll=True)
 
 
+def _visit_dmas(k_pages_hbm, v_pages_hbm, k_buf, v_buf, sems):
+    """A visit's page copies, for both paged kernels: ``start(par, idx, i)``
+    starts the copy of pool page ``idx`` (K and V) into slot ``i`` of half
+    ``par`` of the (2, N, page_size, lanes) buffers, filling only the valid
+    folded columns of the padded buffer; ``arrived(par, n)`` waits for ``n``
+    of them."""
+    folded = k_pages_hbm.shape[-1]
+
+    def page_dma(par, idx, i):
+        return (pltpu.make_async_copy(k_pages_hbm.at[idx], _page_dst(k_buf.at[par], i, folded),
+                                      sems.at[par, 0]),
+                pltpu.make_async_copy(v_pages_hbm.at[idx], _page_dst(v_buf.at[par], i, folded),
+                                      sems.at[par, 1]))
+
+    def start(par, idx, i):
+        for dma in page_dma(par, idx, i):
+            dma.start()
+
+    def arrived(par, n):
+        def one(i, c):
+            for dma in page_dma(par, 0, i):  # a wait counts bytes: any page's
+                dma.wait()
+            return c
+
+        jax.lax.fori_loop(jnp.int32(0), n, one, 0)
+
+    return start, arrived
+
+
 def _paged_attn_kernel(
     # scalar prefetch
     page_table_ref,  # (B + 1, max_pages) SMEM
@@ -202,7 +230,6 @@ def _paged_attn_kernel(
     _, N, page_size, _ = k_buf.shape
     scale = D ** -0.5
     last_page = k_pages_hbm.shape[0] - 1
-    folded = k_pages_hbm.shape[-1]  # valid columns of the padded scratch
     zero = jnp.int32(0)
     if window_operand:
         window = length_ref[B + 1]
@@ -223,28 +250,13 @@ def _paged_attn_kernel(
         first = _div(jnp.maximum(length - window, 0), page_size)
         return first, pages - first
 
-    def page_dma(par, idx, i):
-        # The copy fills only the valid folded columns of the padded buffer.
-        return (pltpu.make_async_copy(k_pages_hbm.at[idx], _page_dst(k_buf.at[par], i, folded),
-                                      sems.at[par, 0]),
-                pltpu.make_async_copy(v_pages_hbm.at[idx], _page_dst(v_buf.at[par], i, folded),
-                                      sems.at[par, 1]))
+    start, arrived = _visit_dmas(k_pages_hbm, v_pages_hbm, k_buf, v_buf, sems)
 
     def fetch(par, s, page, n):
         """Start the DMAs of ``n`` pages of slot ``s`` from ``page`` on."""
         def one(i, c):
             # Clamped: a stale table entry must not take the DMA out of bounds.
-            idx = jnp.minimum(jnp.maximum(page_table_ref[s, page + i], 0), last_page)
-            for dma in page_dma(par, idx, i):
-                dma.start()
-            return c
-
-        jax.lax.fori_loop(zero, n, one, 0)
-
-    def arrived(par, n):
-        def one(i, c):
-            for dma in page_dma(par, 0, i):  # a wait counts bytes: any page's
-                dma.wait()
+            start(par, jnp.minimum(jnp.maximum(page_table_ref[s, page + i], 0), last_page), i)
             return c
 
         jax.lax.fori_loop(zero, n, one, 0)
@@ -477,38 +489,91 @@ def ragged_paged_attention_jax(
     return jnp.where(valid_t[:, None, None], out, 0)
 
 
-# The ragged kernel's query tiles (ISSUE 34). A row's queries are walked
-# against its pages in tiles, and a tile reads each page it can see once —
-# so the width of the tile is how often a row's context is read and how
-# many rows one MXU pass carries. A row of ONE query (a decode row) is a
-# tile of its own: G rows a KV head, as the decode kernel has them. Every
-# other row is a prompt's chunk and is walked in wide tiles:
-# RAGGED_WIDE_TILE queries, so that one KV head's pass (tile × G rows)
-# fills the MXU's 128 rows several times over, cut down where the float32
-# score tile (rows × page_size) would pass RAGGED_SCORE_BYTES. There is no
-# tile between the two: each static width is a third of what the kernel
-# costs to trace and lower at every start (PERF.md §6, PR 34), and only a
-# chunk's tail of 2-8 queries (one admission in seventy) would walk faster
-# in a narrow one.
+# The ragged kernel's query tiles (ISSUE 34, ISSUE 44). A row's queries are
+# walked against its pages in tiles, and a tile reads each page it can see
+# once — so the width of the tile is how often a row's context is read and how
+# many rows one MXU pass carries. There is ONE static width, the wide tile:
+# RAGGED_WIDE_TILE queries, so that one KV head's pass (tile × G rows) fills
+# the MXU's 128 rows several times over, cut down where the float32 score tile
+# of one page (rows × page_size) would pass RAGGED_SCORE_BYTES. A row of ONE
+# query (a decode row) is not the ragged kernel's: the launch hands the
+# one-query rows of the packed prefix to the decode kernel, which walks a
+# slot's pages several a visit (``ragged_paged_attention_tpu``). A second
+# static width is a third more of what the kernel costs to trace and lower at
+# every start (PERF.md §6, PR 34), and only a chunk's tail of 2-8 queries (one
+# admission in seventy) would walk faster in a narrow one.
+#
+# A wide tile's VISIT is up to N consecutive pages, scored, soft-maxed and
+# weighed as one (rows, N·page_size) tile a KV head: the running maximum, sum
+# and output are rescaled once a visit, and a lane reduction serves N pages
+# (ISSUE 44: at Trinity-Mini's shape a page costs 4.7 us at N = 1, 3.1 at 2,
+# 2.3 at 4; PERF.md §5). N is what RAGGED_VISIT_BYTES holds of a page's
+# buffers (K and V, each double-buffered) and its float32 scores and
+# probabilities, and a RAGGED_VISITS_A_ROW-th of the longest row at the most:
+# a walk's last visit pays for the whole tile, so where rows are short (a
+# chat prompt's tile sees one to three pages) a wide visit is mostly masked
+# columns (4 pages a visit are a quarter slower than 2 at the chat shapes).
 RAGGED_WIDE_TILE = 128
 RAGGED_SCORE_BYTES = 512 << 10
+RAGGED_VISIT_BYTES = 16 << 20
+RAGGED_VISITS_A_ROW = 8
+# A visit whose pages lie wholly inside every query's view (behind the tile's
+# first query, inside the window of its last) is not masked (ISSUE 44);
+# hack/ragged_attention_bench.py switches that off for its table.
+RAGGED_UNMASKED_VISITS = True
 
 
-def ragged_tile_plan(groups: int, page_size: int) -> dict:
-    """The ragged kernel's tiling for a layout (G = Hq / Hkv and the page
-    size): static per engine, so it is a /debug/status entry, and the
-    scheduler counts from ``wide_from`` which rows take the wide tile."""
+def ragged_tile_plan(num_q_heads: int, num_kv_heads: int, head_dim: int, page_size: int,
+                     max_pages: int, dtype=jnp.bfloat16) -> dict:
+    """The mixed step's attention for a layout: static per engine, so it is a
+    /debug/status entry (``ragged_attention``), and the scheduler counts from
+    it which rows take the wide tile and the pages their tiles walk."""
+    groups = num_q_heads // num_kv_heads
+    itemsize = jnp.dtype(dtype).itemsize
     wide = min(RAGGED_WIDE_TILE, RAGGED_SCORE_BYTES // (4 * groups * page_size))
     wide = max(wide // 8, 1) * 8
-    return {"narrow_tile": 1, "wide_tile": wide, "wide_from": 2,
-            "rows_per_pass": wide * groups}
+    rows = wide * groups
+    page = page_size * _pad_lanes(num_kv_heads * head_dim) * itemsize
+    tile = num_kv_heads * rows * page_size * (4 + itemsize)  # a page's scores and probabilities
+    visit = max(1, min(RAGGED_VISIT_BYTES // (2 * 2 * page + tile), max_pages // RAGGED_VISITS_A_ROW))
+    decode = decode_walk_plan(num_q_heads, num_kv_heads, head_dim, page_size, max_pages, dtype)
+    return {"wide_tile": wide, "wide_from": 2, "rows_per_pass": rows,
+            "wide_pages_per_visit": visit, "unmasked_visits": RAGGED_UNMASKED_VISITS,
+            "one_query_rows": "decode_kernel",
+            "one_query_pages_per_visit": decode["pages_per_visit"]}
 
 
-# One static width's scratch in the ragged kernel, rows = width · G: the
-# tile's queries (Hkv, rows, D), a page's scores and probabilities (Hkv,
-# rows, page_size), the running maximum and sum (Hkv, rows, 1), the running
-# output (Hkv, rows, D), each row's absolute position (rows, 1).
-_TileScratch = collections.namedtuple("_TileScratch", "q s p m l acc qpos")
+def ragged_walk_pages(rows, windows, plan: dict, page_size: int) -> tuple[int, int]:
+    """(pages walked, pages under the mask) by the wide tiles of one mixed
+    step, from the scalars the kernel branches on: ``rows`` the (q_len,
+    kv_len) of the rows that take the wide tile, ``windows`` the step's
+    attention layers as (window or None, layers). Host arithmetic for the
+    timeline's ``attn_pages_walked`` / ``attn_pages_masked``."""
+    W, N = plan["wide_tile"], plan["wide_pages_per_visit"]
+    walked = masked = 0
+    for q_len, kv_len in rows:
+        for start in range(kv_len - q_len, kv_len, W):  # a tile's first query's position
+            end = min(start + W, kv_len)
+            last = -(-end // page_size)
+            for window, layers in windows:
+                first = max(start + 1 - window, 0) // page_size if window else 0
+                for p0 in range(first // N * N, last, N):
+                    pages = layers * (min(p0 + N, last) - max(p0, first))
+                    walked += pages
+                    if not plan["unmasked_visits"] or _edge_visit(p0, N, page_size, start, end, window):
+                        masked += pages
+    return walked, masked
+
+
+def _edge_visit(p0, n: int, page_size: int, start, end, window):
+    """Does the visit of pages p0..p0+n hold a key that some query of the
+    tile (positions start..end-1) may not see: one past the tile's first
+    query, or one at or before its last query's window start. Every other
+    visit needs no mask. (Scalars in the kernel, ints on the host.)"""
+    edge = (p0 + n) * page_size - 1 > start
+    if window is not None:
+        edge = edge | (p0 * page_size <= end - 1 - window)
+    return edge
 
 
 def _ragged_paged_attn_kernel(
@@ -522,31 +587,34 @@ def _ragged_paged_attn_kernel(
     # output
     out_ref,  # (T + slack, Hq, D) VMEM
     # scratch
-    k_buf,  # (2, page_size, pad128(Hkv*D)) VMEM
+    k_buf,  # (2, N, page_size, pad128(Hkv*D)) VMEM — a page's DMA fills [:Hkv*D]
     v_buf,
     sems,  # DMA semaphores (2, 2)
-    chain_ref,  # (2,) SMEM: is this row's first page in flight, and in which buffer
-    *scratch,  # a _TileScratch's refs per width of ``widths``
-    page_size: int,
-    num_kv_heads: int,
+    chain_ref,  # (2,) SMEM: is this row's first visit in flight, and in which buffer
+    q_s,  # (Hkv, rows, D): the tile's queries, rows = tile · G
+    s_s,  # (Hkv, rows, N·page_size) float32: a visit's scores
+    p_s,  # the same in V's dtype: its probabilities
+    m_s,  # (Hkv, rows, 1) float32: the running maximum,
+    l_s,  # sum,
+    a_s,  # and what a visit rescales them by
+    acc_s,  # (Hkv, rows, D) float32: the running output
+    qpos_s,  # (rows, 1) int32: each row's absolute position
+    *,
+    tile: int,
     groups: int,
     head_dim: int,
     window: int | None,
     window_operand: bool,
-    widths: tuple[int, ...],  # (1, the wide tile); (1,) where the batch holds one query
+    unmasked: bool,
 ):
     """Grid over rows; each instance flash-attends its row's packed query
     span against the row's pages, one query tile at a time.
 
     Tiling scheme:
-    - The tile is chosen per row from its ``q_len`` (ISSUE 34): a row of
-      one query is one tile of one, any other row is walked in wide
-      tiles (``widths``). There is ONE walk, tiles over pages, whose
-      scalars follow the row's width; only what touches a tile's vectors
-      (staging its queries, a page's visit, writing it out) exists once
-      per static width, behind a ``pl.when`` on the row's. Each tile reads
-      every page up to its causal horizon (and from its window's first
-      page) once.
+    - Every row is walked in tiles of ``tile`` queries (ISSUE 34; a row of
+      one query reaches this kernel only from outside the packed prefix,
+      and is a tile of which one query is live). Each tile reads every
+      page up to its causal horizon (and from its window's first page) once.
     - The packed query axis is NOT blocked by the grid — the whole batch
       (plus one tile of slack so a tile never clamps at the buffer edge)
       sits in VMEM and rows address their spans with dynamic slices from
@@ -554,12 +622,24 @@ def _ragged_paged_attn_kernel(
       (hundreds of tokens), so this is a few MiB, not a cache.
     - A tile's queries are laid out once, before its page walk, as one
       (tile·G, D) slab per KV head; scores, probabilities, the running
-      maximum, sum and output live in scratch in the same order. A page's
-      visit is a loop over heads for the scores, ONE flash update over
-      every head's scores, and a loop over heads for the second product;
-      the loops are traced once. The operands of both products reach the
-      MXU in the dtype they are stored in, the probabilities in V's — the
-      arithmetic of ``ragged_paged_attention_jax``.
+      maximum, sum and output live in scratch in the same order. A VISIT
+      is up to N consecutive pages (ISSUE 44): a loop over heads for the
+      scores, ONE flash update over every head's (rows, N·page_size)
+      scores, and a loop over heads for the second product, which rescales
+      the running output as it adds to it; the loops are traced once. The
+      operands of both products reach the MXU in the dtype they are stored
+      in, the probabilities in V's — the arithmetic of
+      ``ragged_paged_attention_jax``. Visits begin at multiples of N
+      (``aligned``), so a walk's first and last visit are ragged: only
+      their live pages are fetched, the rest of the buffer lies before the
+      tile's window or past its horizon and is masked (V's buffer starts as
+      zeros, so a masked column never weighs a NaN).
+    - A visit is masked only where it can hold a key some query may not
+      see (``_edge_visit``): the one or two visits on the tile's diagonal,
+      the one at its window's near edge; the mask is a pass of its own over
+      the scores, under ``pl.when``. Every other visit has no iota, compare
+      or select. A tile's tail rows (past the row's last query) come out
+      of such a visit as if live, and are never written (``finish_tile``).
     - The window is static (``window``: a model whose layers are alike
       pays no mask without one) or the fourth descriptor column
       (``window_operand``: a model that mixes windowed and global layers
@@ -568,9 +648,9 @@ def _ragged_paged_attn_kernel(
       multiple of 128) DMAs into the valid prefix of a 128-aligned
       buffer; per-head compute slices stay inside the valid columns.
     - The double-buffered page DMA is one chain over the whole launch:
-      a tile's last page starts the next tile's first, a row's last page
-      the next active row's first (``chain_ref`` carries that across grid
-      steps, which run in order).
+      a visit starts the next one's pages, a tile's last visit the next
+      tile's first, a row's last the next active row's first
+      (``chain_ref`` carries that across grid steps, which run in order).
     - Query tiles beyond a row's q_len are masked, and their output
       lanes preserve-and-defer: each row read-modify-writes its tile
       window, grid iterations are sequential, and every valid packed
@@ -578,114 +658,135 @@ def _ragged_paged_attn_kernel(
     """
     r = pl.program_id(0)
     R = pl.num_programs(0)
+    Hkv, rows, D = q_s.shape
+    _, N, page_size, _ = k_buf.shape
+    G, W = groups, tile
     scale = head_dim ** -0.5
-    Hkv, G, D = num_kv_heads, groups, head_dim
-    folded = k_pages_hbm.shape[-1]
     # The DMA'd page index is clamped for safety (matches the decode
     # kernel): inactive descriptor rows may carry stale table entries.
-    num_pages_total = k_pages_hbm.shape[0]
-    n = len(_TileScratch._fields)
-    scratch = {W: _TileScratch(*scratch[i * n:(i + 1) * n]) for i, W in enumerate(widths)}
+    last_page = k_pages_hbm.shape[0] - 1
+    zero = jnp.int32(0)
 
     @pl.when(r == 0)
     def _():
         out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+        v_buf[...] = jnp.zeros(v_buf.shape, v_buf.dtype)
         chain_ref[0] = 0
         chain_ref[1] = 0
 
     q_start = descr_ref[r, 0]
     q_len = descr_ref[r, 1]
-    kv_len = descr_ref[r, 2]
-    kv_start = kv_len - q_len  # absolute position of the row's first query
     if window_operand:
         window = descr_ref[r, 3]
-    # The row's tile width, and whatever a tile does to vectors at it.
-    W = jax.lax.select(q_len > 1, jnp.int32(widths[-1]), jnp.int32(1))
-
-    def at_width(fn, *args):
-        for Ws in widths:
-            pl.when(W == Ws)(functools.partial(fn, Ws, scratch[Ws], *args))
 
     each_head = functools.partial(_each_head, Hkv, D)
 
-    def page_dma(buf_slot, row, page_pos):
-        page_idx = jnp.clip(page_table_ref[row, page_pos], 0, num_pages_total - 1)
-        return (pltpu.make_async_copy(k_pages_hbm.at[page_idx],
-                                      _page_dst(k_buf, buf_slot, folded),
-                                      sems.at[buf_slot, 0]),
-                pltpu.make_async_copy(v_pages_hbm.at[page_idx],
-                                      _page_dst(v_buf, buf_slot, folded),
-                                      sems.at[buf_slot, 1]))
+    start, arrived = _visit_dmas(k_pages_hbm, v_pages_hbm, k_buf, v_buf, sems)
 
-    def first_page(row, q0):
-        """Earliest page the tile that starts at ``row``'s q0-th query can
-        see: its first query's window start (later queries see later
-        keys only)."""
+    def live(p0, first, last):
+        """(first page, pages) that the visit of pages p0..p0+N fetches of a
+        walk first..last: its first visit may begin, and its last end, inside
+        the visit; what the buffer holds beside them is masked."""
+        lo = jnp.maximum(p0, first)
+        return lo, jnp.minimum(p0 + N, last) - lo
+
+    def fetch(par, row, p0, first, last):
+        """Start the DMAs of that visit of ``row``, page p0 + i into slot i."""
+        lo, n = live(p0, first, last)
+
+        def one(i, c):
+            start(par, jnp.minimum(jnp.maximum(page_table_ref[row, lo + i], 0), last_page), lo - p0 + i)
+            return c
+
+        jax.lax.fori_loop(zero, n, one, 0)
+
+    def aligned(page):
+        """The visit that holds ``page``: visits begin at multiples of N,
+        whatever tile walks them, so a query's keys fall into the same visits
+        (and its sums run in the same order) however its prompt was chunked:
+        a prompt adopted from the prefix cache answers bit for bit as it did
+        when it was computed whole."""
+        return _div(page, N) * N
+
+    def walk(row, c):
+        """(first page, page after the last, first query's position, position
+        after the last query's) of tile ``c`` of ``row``: queries of the tile
+        see keys before its horizon, so later pages are fully masked and need
+        not be DMA'd — the walk covers the causal triangle, not the rectangle;
+        and from its first query's window start (later queries see later keys
+        only)."""
+        kv_len = descr_ref[row, 2]
+        start = kv_len - descr_ref[row, 1] + c * W
+        end = jnp.minimum(start + W, kv_len)
+        last = _div(end + page_size - 1, page_size)
         if window is None:
-            return jnp.int32(0)
-        first_pos = descr_ref[row, 2] - descr_ref[row, 1] + q0
-        return _div(jnp.maximum(first_pos + 1 - window, 0), page_size)
+            return zero, last, start, end
+        return _div(jnp.maximum(start + 1 - window, 0), page_size), last, start, end
 
-    def stage_tile(Ws, t, c, tile0):
-        rows = Ws * G
-        q_tile = q_ref[pl.dslice(tile0, Ws)]  # (Ws, Hq, D)
+    def stage_tile(c, tile0):
+        q_tile = q_ref[pl.dslice(tile0, W)]  # (W, Hq, D)
         for h in range(Hkv):
-            t.q[h] = q_tile[:, h * G:(h + 1) * G, :].reshape(rows, D).astype(t.q.dtype)
-        t.m[...] = jnp.full(t.m.shape, NEG_INF, jnp.float32)
-        t.l[...] = jnp.zeros(t.l.shape, jnp.float32)
-        t.acc[...] = jnp.zeros(t.acc.shape, jnp.float32)
-        # Per-row absolute positions, expanded per group so the (Ws·G,
-        # page_size) score mask indexes naturally; a query past the row's
+            q_s[h] = q_tile[:, h * G:(h + 1) * G, :].reshape(rows, D).astype(q_s.dtype)
+        m_s[...] = jnp.full(m_s.shape, NEG_INF, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+        # Per-row absolute positions, expanded per group so the (W·G,
+        # N·page_size) score mask indexes naturally; a query past the row's
         # last sees no key.
-        qrow = c * Ws + _div(jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), G)
-        t.qpos[...] = jnp.where(qrow < q_len, kv_start + qrow, -1)
+        qrow = c * W + _div(jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), G)
+        qpos_s[...] = jnp.where(qrow < q_len, descr_ref[r, 2] - q_len + qrow, -1)
 
-    def visit(Ws, t, p, par):
-        def score(h):
-            k_h = k_buf[par, :, pl.ds(h * D, D)]  # (ps, D)
-            t.s[h] = jax.lax.dot_general(
-                t.q[h], k_h.astype(t.q.dtype), dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)  # (rows, ps)
-
-        each_head(score)
-        # A query's own position bounds its keys (and is under kv_len).
-        token_pos = p * page_size + jax.lax.broadcasted_iota(jnp.int32, (1, page_size), 1)
-        qpos = t.qpos[...]  # (rows, 1)
+    def mask(p0):
+        """A query's own position bounds its keys (and is under kv_len)."""
+        token_pos = p0 * page_size + jax.lax.broadcasted_iota(jnp.int32, (1, N * page_size), 1)
+        qpos = qpos_s[...]  # (rows, 1)
         valid = token_pos <= qpos
         if window is not None:
             valid = valid & (token_pos > qpos - window)
+        s_s[...] = jnp.where(valid, s_s[...], NEG_INF)
+
+    def visit(p0, par, edge):
+        def score(h):
+            k_h = k_buf[par, :, :, pl.ds(h * D, D)].reshape(N * page_size, D)
+            s_s[h] = scale * jax.lax.dot_general(
+                q_s[h], k_h.astype(q_s.dtype), dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)  # (rows, N·ps)
+
+        each_head(score)
+        # The mask is a pass of its own, and the only thing under the branch:
+        # the whole visit in two bodies, masked and not, ran a quarter slower.
+        pl.when(edge)(functools.partial(mask, p0))
         # Every KV head's scores under ONE flash update: the same vector
         # work as a head at a time, in one chain of dependent steps.
-        s = jnp.where(valid, t.s[...] * scale, NEG_INF)  # (Hkv, rows, ps)
-        m = t.m[...]
+        s = s_s[...]  # (Hkv, rows, N·ps)
+        m = m_s[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         probs = jnp.exp(s - m_new)
-        t.l[...] = t.l[...] * alpha + jnp.sum(probs, axis=-1, keepdims=True)
-        t.m[...] = m_new
-        t.acc[...] = t.acc[...] * alpha
-        t.p[...] = probs.astype(t.p.dtype)
+        l_s[...] = l_s[...] * alpha + jnp.sum(probs, axis=-1, keepdims=True)
+        m_s[...] = m_new
+        a_s[...] = alpha
+        p_s[...] = probs.astype(p_s.dtype)
 
         def weigh(h):
-            v_h = v_buf[par, :, pl.ds(h * D, D)]  # (ps, D)
-            t.acc[h] += jnp.dot(t.p[h], v_h, preferred_element_type=jnp.float32)
+            v_h = v_buf[par, :, :, pl.ds(h * D, D)].reshape(N * page_size, D)
+            acc_s[h] = acc_s[h] * a_s[h] + jnp.dot(p_s[h], v_h, preferred_element_type=jnp.float32)
 
         each_head(weigh)
 
-    def finish_tile(Ws, t, c, tile0):
-        out = (t.acc[...] / jnp.maximum(t.l[...], 1e-20)).astype(out_ref.dtype)  # (Hkv, rows, D)
-        tile = pl.dslice(tile0, Ws)
-        theirs = out_ref[tile]  # what the tile's tail holds belongs to the rows after this one
+    def finish_tile(c, tile0):
+        out = (acc_s[...] / jnp.maximum(l_s[...], 1e-20)).astype(out_ref.dtype)  # (Hkv, rows, D)
+        span = pl.dslice(tile0, W)
+        theirs = out_ref[span]  # what the tile's tail holds belongs to the rows after this one
         for h in range(Hkv):
-            out_ref[tile, h * G:(h + 1) * G, :] = out[h].reshape(Ws, G, D)
-        if Ws > 1:
-            valid_q = (c * Ws + jax.lax.broadcasted_iota(jnp.int32, (Ws, 1, 1), 0)) < q_len
-            out_ref[tile] = jnp.where(valid_q, out_ref[tile], theirs)
+            out_ref[span, h * G:(h + 1) * G, :] = out[h].reshape(W, G, D)
+        valid_q = (c * W + jax.lax.broadcasted_iota(jnp.int32, (W, 1, 1), 0)) < q_len
+        out_ref[span] = jnp.where(valid_q, out_ref[span], theirs)
 
     @pl.when(q_len > 0)
     def _row():
         n_tiles = jax.lax.div(q_len + W - 1, W)
-        # Whose first page the row's last page starts: the next row with
+        # Whose first visit the row's last visit starts: the next row with
         # queries (R when none is left).
         nxt = jax.lax.while_loop(
             lambda i: (i < R) & (descr_ref[jnp.minimum(i, R - 1), 1] == 0),
@@ -694,42 +795,35 @@ def _ragged_paged_attn_kernel(
 
         @pl.when(chain_ref[0] == 0)
         def _():
-            for dma in page_dma(0, r, first_page(r, 0)):
-                dma.start()
+            first, last, _, _ = walk(r, 0)
+            fetch(0, r, aligned(first), first, last)
 
         def tile_body(c, par):
             tile0 = q_start + c * W
-            at_width(stage_tile, c, tile0)
-            # The tile's causal horizon bounds its page walk: queries in
-            # tile c see keys < kv_start + (c+1)·W, so later pages are
-            # fully masked and need not be DMA'd — the walk covers the
-            # causal triangle, not the full rectangle.
-            tile_kv = jnp.minimum(kv_start + (c + 1) * W, kv_len)
-            n_pages_t = _div(tile_kv + page_size - 1, page_size)
-            # Where the chain goes after this tile's last page: the row's
-            # next tile's first page, else the next active row's.
+            stage_tile(c, tile0)
+            first, last, start, end = walk(r, c)
+            # Where the chain goes after this tile's last visit: the row's
+            # next tile's first pages, else the next active row's.
             more_tiles = c + 1 < n_tiles
             chain_on = more_tiles | (nxt < R)
             after_row = jnp.where(more_tiles, r, nxt_row)
-            after_page = jnp.where(more_tiles, first_page(r, (c + 1) * W),
-                                   first_page(nxt_row, 0))
+            after_first, after_last, _, _ = walk(after_row, jnp.where(more_tiles, c + 1, 0))
 
-            def page_body(p, par):
-                in_tile = p + 1 < n_pages_t
+            def visit_body(carry):
+                p0, par = carry
+                more = p0 + N < last  # the tile's walk goes on after this visit
 
-                @pl.when(in_tile | chain_on)
+                @pl.when(more | chain_on)
                 def _():
-                    for dma in page_dma(1 - par, jnp.where(in_tile, r, after_row),
-                                        jnp.where(in_tile, p + 1, after_page)):
-                        dma.start()
+                    fetch(1 - par, jnp.where(more, r, after_row), jnp.where(more, p0 + N, aligned(after_first)),
+                          jnp.where(more, first, after_first), jnp.where(more, last, after_last))
 
-                for dma in page_dma(par, r, p):
-                    dma.wait()
-                at_width(visit, p, par)
-                return 1 - par
+                arrived(par, live(p0, first, last)[1])
+                visit(p0, par, _edge_visit(p0, N, page_size, start, end, window) if unmasked else True)
+                return p0 + N, 1 - par
 
-            par = jax.lax.fori_loop(first_page(r, c * W), n_pages_t, page_body, par)
-            at_width(finish_tile, c, tile0)
+            _, par = jax.lax.while_loop(lambda carry: carry[0] < last, visit_body, (aligned(first), par))
+            finish_tile(c, tile0)
             return par
 
         par = jax.lax.fori_loop(0, n_tiles, tile_body,
@@ -738,7 +832,21 @@ def _ragged_paged_attn_kernel(
         chain_ref[1] = par
 
 
-@functools.partial(jax.jit, static_argnames=("num_kv_heads", "interpret", "window"))
+def _one_query_prefix(page_table, q_starts, q_lens, kv_lens, n: int):
+    """The one-query rows among the first ``n`` packed positions, BY packed
+    position (the scheduler packs a step's decode rows first, so they are
+    the packed prefix): each position's page-table row and length (0 where
+    no one-query row sits), and which rows they are. No scatter: position i
+    finds its row by comparison."""
+    one = q_lens == 1
+    hit = one[None, :] & (q_starts[None, :] == jnp.arange(n, dtype=q_starts.dtype)[:, None])  # (n, R)
+    row = jnp.argmax(hit, axis=1)
+    lengths = jnp.where(jnp.any(hit, axis=1), kv_lens[row], 0)
+    return page_table[row], lengths, one & (q_starts < n)
+
+
+@functools.partial(jax.jit, static_argnames=("num_kv_heads", "interpret", "window", "pages_per_visit",
+                                             "row_pages_per_visit"))
 def ragged_paged_attention_tpu(
     q: jnp.ndarray,  # (T, Hq, D) packed queries
     k_pages: jnp.ndarray,  # (P, page_size, Hkv*D)
@@ -751,15 +859,32 @@ def ragged_paged_attention_tpu(
     interpret: bool = False,
     window: int | None = None,
     window_dyn: jnp.ndarray | None = None,  # int32 scalar: the window as an operand
+    pages_per_visit: int | None = None,  # a wide tile's: the layout's (ragged_tile_plan) unless a test says
+    row_pages_per_visit: int | None = None,  # a one-query row's, likewise
 ) -> jnp.ndarray:
+    """Two launches a call (ISSUE 44): the one-query rows of the packed
+    prefix (a mixed step's decode rows) go to the decode kernel BY packed
+    position, which walks their pages several a visit and skips a position
+    with no such row; every other row to the ragged kernel, which sees the
+    first as rows of no query. Each writes zeros where the other writes, so
+    the two outputs are joined by one addition."""
     T, Hq, D = q.shape
     P, page_size, HkvD = k_pages.shape
-    R = page_table.shape[0]
+    R, max_pages = page_table.shape
     G = Hq // num_kv_heads
     assert window is None or window_dyn is None, "one window: static, or the operand"
+    plan = ragged_tile_plan(Hq, num_kv_heads, D, page_size, max_pages, k_pages.dtype)
     # The wide tile, no wider than the batch (in eights).
-    tile = min(ragged_tile_plan(G, page_size)["wide_tile"], -(-T // 8) * 8)
-    widths = (1, tile) if T > 1 else (1,)
+    tile = min(plan["wide_tile"], -(-T // 8) * 8)
+    N = pages_per_visit or plan["wide_pages_per_visit"]
+    rows = tile * G
+
+    n = min(R, T)
+    table_1, lengths_1, to_decode = _one_query_prefix(page_table, q_starts, q_lens, kv_lens, n)
+    out_1 = paged_attention_tpu(q[:n], k_pages, v_pages, table_1, lengths_1, num_kv_heads,
+                                interpret=interpret, window=window, window_dyn=window_dyn,
+                                pages_per_visit=row_pages_per_visit)
+
     # One tile of slack so a row's last tile never clamps at the buffer
     # edge (a clamped dynamic slice would shift the tile window off the
     # mask's indexing). Sliced back off below.
@@ -768,35 +893,13 @@ def ragged_paged_attention_tpu(
     mxu_dtype = jnp.promote_types(q.dtype, k_pages.dtype)
 
     kernel = functools.partial(
-        _ragged_paged_attn_kernel,
-        page_size=page_size,
-        num_kv_heads=num_kv_heads,
-        groups=G,
-        head_dim=D,
-        window=window,
-        window_operand=window_dyn is not None,
-        widths=widths,
-    )
-    descr = [q_starts, q_lens, kv_lens]
+        _ragged_paged_attn_kernel, tile=tile, groups=G, head_dim=D, window=window,
+        window_operand=window_dyn is not None, unmasked=RAGGED_UNMASKED_VISITS)
+    descr = [q_starts, jnp.where(to_decode, 0, q_lens), kv_lens]
     if window_dyn is not None:
         descr.append(jnp.broadcast_to(window_dyn, q_lens.shape))
     descr = jnp.stack(descr, axis=1).astype(jnp.int32)
-    scratch_shapes = [
-        pltpu.VMEM((2, page_size, _pad_lanes(HkvD)), k_pages.dtype),
-        pltpu.VMEM((2, page_size, _pad_lanes(HkvD)), v_pages.dtype),
-        pltpu.SemaphoreType.DMA((2, 2)),
-        pltpu.SMEM((2,), jnp.int32),
-    ]
-    for W in widths:
-        rows = W * G
-        scratch_shapes += _TileScratch(
-            q=pltpu.VMEM((num_kv_heads, rows, D), mxu_dtype),
-            s=pltpu.VMEM((num_kv_heads, rows, page_size), jnp.float32),
-            p=pltpu.VMEM((num_kv_heads, rows, page_size), v_pages.dtype),
-            m=pltpu.VMEM((num_kv_heads, rows, 1), jnp.float32),
-            l=pltpu.VMEM((num_kv_heads, rows, 1), jnp.float32),
-            acc=pltpu.VMEM((num_kv_heads, rows, D), jnp.float32),
-            qpos=pltpu.VMEM((rows, 1), jnp.int32))
+    by_head = (num_kv_heads, rows)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(R,),
@@ -806,7 +909,20 @@ def ragged_paged_attention_tpu(
             pl.BlockSpec(memory_space=pltpu.ANY),
         ],
         out_specs=pl.BlockSpec((T + tile, Hq, D), lambda r, *_: (0, 0, 0)),
-        scratch_shapes=scratch_shapes,
+        scratch_shapes=[
+            pltpu.VMEM((2, N, page_size, _pad_lanes(HkvD)), k_pages.dtype),
+            pltpu.VMEM((2, N, page_size, _pad_lanes(HkvD)), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((*by_head, D), mxu_dtype),
+            pltpu.VMEM((*by_head, N * page_size), jnp.float32),
+            pltpu.VMEM((*by_head, N * page_size), v_pages.dtype),
+            pltpu.VMEM((*by_head, 1), jnp.float32),
+            pltpu.VMEM((*by_head, 1), jnp.float32),
+            pltpu.VMEM((*by_head, 1), jnp.float32),
+            pltpu.VMEM((*by_head, D), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.int32),
+        ],
     )
     out = pl.pallas_call(
         kernel,
@@ -816,7 +932,7 @@ def ragged_paged_attention_tpu(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=64 << 20),
         interpret=interpret,
     )(page_table.astype(jnp.int32), descr, qp, k_pages, v_pages)
-    return out[:T]
+    return out[:T] + jnp.pad(out_1, ((0, T - n), (0, 0), (0, 0)))
 
 
 def ragged_paged_attention_sharded(q, k_pages, v_pages, page_table, q_starts, q_lens,

@@ -341,6 +341,17 @@ def is_hybrid(model_cfg) -> bool:
     return hasattr(model_cfg, "mamba_d_state")
 
 
+def attention_layer_windows(model_cfg) -> list:
+    """The model's attention layers (those that write the page pool and read
+    it through the paged kernels in a mixed step) as (window or None,
+    layers): what ``ragged_walk_pages`` counts a step's pages over."""
+    layers = getattr(model_cfg, "cache_layers", model_cfg.num_layers)
+    window = getattr(model_cfg, "sliding_window", None)
+    flags = getattr(model_cfg, "layer_window", None)
+    windowed = 0 if not window else model_cfg.mid if is_hybrid(model_cfg) else sum(flags) if flags else layers
+    return [(w, n) for w, n in ((window, windowed), (None, layers - windowed)) if n]
+
+
 def has_indexer(model_cfg) -> bool:
     """A model whose attention reads the tokens a learned indexer chooses,
     with the indexer's keys paged beside k and v (models/mixtral.py)."""
@@ -753,23 +764,22 @@ class Engine:
                 raise RuntimeError(
                     f"paged engine on a TPU ({self.device}) dispatched to the gather "
                     f"fallback: {self.attention_path_reason}")
-            # The ragged kernel's tiling for this layout (ISSUE 34): static
-            # per engine, so a /debug/status field; the scheduler counts
-            # from ``wide_from`` the tokens whose rows take the wide tile.
+            # The mixed step's attention for this layout (ISSUE 34, ISSUE 44)
+            # and the decode kernel's page walk (ISSUE 37), a tp shard's where
+            # the kernels run on a shard's heads: static per engine, so
+            # /debug/status fields; the scheduler counts from the first which
+            # rows take the wide tile and the pages their tiles walk. A model
+            # whose attention reads what an indexer chooses holds no paged
+            # kernel.
             kv_heads = getattr(self.model_cfg, "kernel_kv_heads", self.model_cfg.num_kv_heads)
-            if self.mixed_ok:
-                self.ragged_attention_plan = ragged_tile_plan(
-                    self.model_cfg.num_heads // kv_heads, config.page_size)
-            # The decode kernel's page walk for this layout (ISSUE 37), a
-            # tp shard's where the kernel runs on a shard's heads: static as
-            # well. A model whose attention reads what an indexer chooses
-            # holds no paged kernel.
+            shards = mesh_tp if self.attention_path == "kernel_sharded" else 1
+            layout = (self.model_cfg.num_heads // shards, kv_heads // shards,
+                      self.model_cfg.num_kv_heads * self.model_cfg.hd // kv_heads, config.page_size,
+                      self.page_cfg.max_pages_per_slot, self.dtype)
             if not has_indexer(self.model_cfg):
-                shards = mesh_tp if self.attention_path == "kernel_sharded" else 1
-                self.decode_attention_plan = decode_walk_plan(
-                    self.model_cfg.num_heads // shards, kv_heads // shards,
-                    self.model_cfg.num_kv_heads * self.model_cfg.hd // kv_heads, config.page_size,
-                    self.page_cfg.max_pages_per_slot, self.dtype)
+                if self.mixed_ok:
+                    self.ragged_attention_plan = ragged_tile_plan(*layout)
+                self.decode_attention_plan = decode_walk_plan(*layout)
         else:
             self.attention_path = "dense"
             self.attention_path_reason = "contiguous slot cache (paged attention not in use)"
@@ -1558,6 +1568,17 @@ class Engine:
             handle.stats = {**(handle.stats or {}),
                             **{k: round(float(v), 3) for k, v in zip(self._mixed_stats, both[2:, 0])}}
         return both[0].astype(np.int32), both[1]
+
+    def attn_pages(self, rows: "list[MixedRow]") -> tuple[int, int]:
+        """(pages walked, pages under the mask) by the ragged kernel's wide
+        tiles over ``rows`` in one mixed step, all attention layers: host
+        arithmetic on the rows' descriptors (``ragged_walk_pages``), the
+        timeline's ``attn_pages_walked`` / ``attn_pages_masked``."""
+        from inference_gateway_tpu.ops.paged_attention import ragged_walk_pages
+
+        return ragged_walk_pages([(len(r.token_ids), r.start + len(r.token_ids)) for r in rows],
+                                 attention_layer_windows(self.model_cfg), self.ragged_attention_plan,
+                                 self.config.page_size)
 
     def _state_stats(self, rows: int) -> dict | None:
         """``state_bytes`` of a step's timeline record: what its rows hold in

@@ -1521,11 +1521,13 @@ class Scheduler:
             plan = getattr(self.engine, "ragged_attention_plan", None)
             if plan is not None:
                 # Tokens of the rows the ragged kernel walks in wide
-                # tiles (ISSUE 34), beside work_tokens: known from
-                # the rows packed, no device read.
-                spans["attn_wide_tokens"] = sum(
-                    len(row.token_ids) for row in step.rows[step.n_decode:]
-                    if len(row.token_ids) >= plan["wide_from"])
+                # tiles (ISSUE 34), beside work_tokens, and the pages
+                # those tiles walk and mask (ISSUE 44): known from the
+                # rows packed, no device read.
+                wide = [row for row in step.rows[step.n_decode:]
+                        if len(row.token_ids) >= plan["wide_from"]]
+                spans["attn_wide_tokens"] = sum(len(row.token_ids) for row in wide)
+                spans["attn_pages_walked"], spans["attn_pages_masked"] = self.engine.attn_pages(wide)
             if not overlapped:
                 spans.pop("submit_ts", None)
             n_prefill, context, pairs = step.counts

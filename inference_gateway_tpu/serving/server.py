@@ -1019,12 +1019,16 @@ class SidecarServer:
             # this engine's (vocabulary, top_k) takes — "grouped" sorts
             # `candidates` of the vocabulary's columns, "direct" all of them.
             "sampler": getattr(self.engine, "sampler_plan", None),
-            # The ragged attention kernel's tiling (ISSUE 34): a row of one
-            # query is a tile of its own (`narrow_tile`), any other row is
-            # walked `wide_tile` queries at a time (`rows_per_pass` rows a KV
-            # head's MXU pass); None where no mixed step runs.
-            # `attn_wide_tokens` on the timeline's `mixed` records counts the
-            # tokens of the rows that took the wide tile.
+            # A mixed step's attention (ISSUE 34, ISSUE 44): a row of two
+            # queries or more is walked `wide_tile` queries at a time
+            # (`rows_per_pass` rows a KV head's MXU pass), up to
+            # `wide_pages_per_visit` pages a visit; the one-query rows of
+            # the packed prefix take `one_query_rows` (the decode kernel,
+            # `one_query_pages_per_visit` pages a visit); None where no
+            # mixed step runs. The timeline's `mixed` records count the
+            # tokens of the rows that took the wide tile (`attn_wide_tokens`)
+            # and the pages its tiles walked and masked
+            # (`attn_pages_walked`, `attn_pages_masked`).
             "ragged_attention": getattr(self.engine, "ragged_attention_plan", None),
             # The decode attention kernel's page walk (ISSUE 37): up to
             # `pages_per_visit` pages of a slot are fetched, scored and

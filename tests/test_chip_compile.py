@@ -84,31 +84,42 @@ def test_decode_kernel_compiles_for_v5e(one_chip):
         one_chip((SLOTS, MAX_PAGES), I32), one_chip((SLOTS,), I32))
 
 
-# The ragged kernel as each benchmark cell's mixed step hands it over (ISSUE 34:
-# the wide tile is 128 queries x G rows a KV head, so G and the folded axis decide
-# what the compiler is asked for): name -> (Hq, Hkv, window, slots, caching layers,
-# the most equations its traced body may hold). ``"operand"``: the window is the
-# kernel's scalar operand, as a model that mixes windowed and global layers hands it.
-# The last number is the start-up guard: every start traces and lowers the kernel
-# again (the trace once, the conversion to MLIR once a variant: the unmasked step
-# program and the masked one), at ~0.35 ms an equation in the sandbox and three to
-# five times that on the chip's host, and the persistent cache saves none of it. The parent's 8-query body held 411 / 245 /
-# 263 + 245 / 485 + 467; PR 33's three bodies 1,194 / 711 / 954 + 711 / 1,314 +
-# 1,071, and cost the mixture's cell 4.4 s of warm ``setup_s`` (PERF.md §6, PR 34).
-# This tree reads 434 / 340 / 379 / 463: the ceilings leave a tenth.
+# A mixed step's attention as each benchmark cell hands it over (ISSUE 34: the wide
+# tile is 128 queries x G rows a KV head, so G and the folded axis decide what the
+# compiler is asked for; ISSUE 44: the launch holds TWO kernels, the ragged one at one
+# static width and the decode kernel for the one-query rows of the packed prefix):
+# name -> (Hq, Hkv, D, window, slots, caching layers, a row's pages, the most equations
+# the ragged kernel's traced body may hold, and the two bodies together).
+# ``"operand"``: the window is the kernels' scalar operand, as a model that mixes
+# windowed and global layers hands it. The last two numbers are the start-up guard:
+# every start traces and lowers the kernels again (the trace once, the conversion to
+# MLIR once a variant: the unmasked step program and the masked one), at ~0.35 ms an
+# equation in the sandbox and three to five times that on the chip's host, and the
+# persistent cache saves none of it. The first tree's 8-query body held 411 / 245 /
+# 263 + 245 / 485 + 467; PR 33's three bodies 1,194 / 711 / 954 + 711 / 1,314 + 1,071,
+# and cost the mixture's cell 4.4 s of warm ``setup_s`` (PERF.md §6, PR 34); PR 34's two
+# widths 434 / 340 / 379 / 463 (Mistral, Qwen2.5, SmallThinker, Phi-4). This tree's
+# ragged body reads 323 / 275 / 296 / 338 (one width, a visit of several pages from a
+# multiple of them, the mask a pass of its own under ``pl.when``) and the decode kernel's
+# beside it 131 / 120 / 132 / 132: together 454 / 395 / 428 / 470, which is 20 / 55 / 49 /
+# 7 over PR 34's (a decode-kernel instance is more than a width was; PERF.md §6, PR 44,
+# has what that costs a start). The ceilings leave a tenth.
 RAGGED_LAYOUTS = {
-    "mistral-7b": (HQ, HKV, WINDOW, SLOTS, LAYERS, 480),
-    "qwen2.5-7b": (28, 4, None, 48, 28, 375),
-    "smallthinker-21b-a3b": (28, 4, "operand", 48, 16, 415),
-    "phi-4-mini-flash": (40, 10, "operand", 64, 9, 510),
+    "mistral-7b": (HQ, HKV, D, WINDOW, SLOTS, LAYERS, MAX_PAGES, 355, 499),
+    "qwen2.5-7b": (28, 4, D, None, 48, 28, MAX_PAGES, 302, 434),
+    "smallthinker-21b-a3b": (28, 4, D, "operand", 48, 16, MAX_PAGES, 325, 470),
+    "phi-4-mini-flash": (40, 10, D, "operand", 64, 9, MAX_PAGES, 372, 517),
+    # 100-page rows, two scanned groups a step (ISSUE 41); heads of 64 (ISSUE 43)
+    "trinity-mini": (32, 4, D, "operand", 32, 8, 100, 325, 470),
+    "lfm2-8b-a1b": (32, 8, 64, None, 64, 6, MAX_PAGES, 442, 660),
 }
 
 
-def _ragged_launch(sds, hq, hkv, window, slots, cache_layers):
+def _ragged_launch(sds, hq, hkv, d, window, slots, cache_layers, max_pages):
     """(fn, shapes) of one launch at a cell's layout; ``sds(shape, dtype)``."""
     row = sds((slots,), I32)
-    pool = sds((cache_layers * slots * MAX_PAGES, PAGE, hkv * D), BF16)  # the engine's, layer-stacked
-    shapes = [sds((BUCKET + slots, hq, D), BF16), pool, pool, sds((slots, MAX_PAGES), I32), row, row, row]
+    pool = sds((cache_layers * slots * max_pages, PAGE, hkv * d), BF16)  # the engine's, layer-stacked
+    shapes = [sds((BUCKET + slots, hq, d), BF16), pool, pool, sds((slots, max_pages), I32), row, row, row]
     if window == "operand":
         return (lambda q, k, v, table, starts, lens, kv, w: ragged_paged_attention_tpu(
             q, k, v, table, starts, lens, kv, hkv, interpret=False, window_dyn=w)), shapes + [sds((), I32)]
@@ -127,12 +138,13 @@ def _inner_jaxprs(eqn):
 
 def _kernel_bodies(jaxpr, name="_ragged_paged_attn_kernel"):
     """The body of every ``pallas_call`` of that kernel under ``jaxpr``, loops
-    and branches included."""
+    and branches included (a name that begins the kernel function's: the
+    decode kernel's ``_paged_attn_kernel`` is not the ragged one's tail)."""
     found = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name != "pallas_call":
             found += [body for inner in _inner_jaxprs(eqn) for body in _kernel_bodies(inner, name)]
-        elif name in eqn.params["jaxpr"].debug_info.func_src_info:
+        elif eqn.params["jaxpr"].debug_info.func_src_info.startswith(name):
             found.append(eqn.params["jaxpr"])
     return found
 
@@ -143,17 +155,24 @@ def _equations(jaxpr) -> int:
 
 @pytest.mark.parametrize("layout", list(RAGGED_LAYOUTS))
 def test_ragged_kernel_compiles_for_v5e(one_chip, layout):
-    fn, shapes = _ragged_launch(one_chip, *RAGGED_LAYOUTS[layout][:-1])
-    _compiles_to_a_kernel(fn, *shapes)
+    """Both kernels of the launch through the chip's compiler at every family's
+    layout, and their outputs joined with no scatter."""
+    fn, shapes = _ragged_launch(one_chip, *RAGGED_LAYOUTS[layout][:-2])
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "scatter" not in text
 
 
 @pytest.mark.parametrize("layout", list(RAGGED_LAYOUTS))
 def test_ragged_kernel_stays_inside_its_start_up_budget(layout):
     """No clock: the count of traced equations, which is what a start pays for."""
-    *launch, ceiling = RAGGED_LAYOUTS[layout]
+    *launch, ceiling, both = RAGGED_LAYOUTS[layout]
     fn, shapes = _ragged_launch(jax.ShapeDtypeStruct, *launch)
-    (body,) = _kernel_bodies(jax.make_jaxpr(fn)(*shapes).jaxpr)
+    jaxpr = jax.make_jaxpr(fn)(*shapes).jaxpr
+    (body,) = _kernel_bodies(jaxpr)
+    (rows,) = _kernel_bodies(jaxpr, "_paged_attn_kernel")  # the one-query rows' decode kernel
     assert _equations(body) <= ceiling
+    assert _equations(body) + _equations(rows) <= both
 
 
 # The decode kernel as each benchmark cell's decode program hands it over (ISSUE
@@ -200,7 +219,10 @@ def test_decode_kernel_stays_inside_its_start_up_budget(layout):
 def test_a_mixed_step_with_two_kinds_of_layer_holds_one_ragged_kernel(monkeypatch, module, preset):
     """A model that mixes windowed and global layers hands the ragged kernel
     its window as an operand: its mixed step traces (and every start lowers)
-    ONE instance, where a static window under a ``lax.cond`` held two."""
+    ONE instance, where a static window under a ``lax.cond`` held two; and
+    beside it ONE decode-kernel instance for the step's one-query rows
+    (ISSUE 44), which takes the same operand (the hybrid's cross layers hold
+    one more, as before)."""
     import importlib
 
     from inference_gateway_tpu.serving.kv_cache import PagedCacheConfig
@@ -220,6 +242,7 @@ def test_a_mixed_step_with_two_kinds_of_layer_holds_one_ragged_kernel(monkeypatc
     traced = jax.make_jaxpr(lambda *a: model.forward_ragged(a[0], cfg, *a[1:]))(
         params, tokens, tokens, cache, tokens, jax.ShapeDtypeStruct((slots, seq // page), I32), row, row, row)
     assert len(_kernel_bodies(traced.jaxpr)) == 1
+    assert len(_kernel_bodies(traced.jaxpr, "_paged_attn_kernel")) == (2 if module == "phi4flash" else 1)
 
 
 def test_flash_prefill_kernel_compiles_for_v5e(one_chip):
@@ -458,15 +481,24 @@ def test_state_operations_compile_for_v5e_at_the_published_widths(one_chip, form
 # never pass through one), so each of its step programs holds one kernel
 # instance for six attention layers; the seven older rows are unedited (the
 # seam is a Python branch they do not take).
+# ISSUE 44: a MIXED step's attention is two launches a scanned group, the ragged
+# kernel and, for the one-query rows of the packed prefix, the decode kernel:
+# one kernel instance more a group in every row that holds the ragged kernel
+# (the hybrid's three: its cross layers held a decode kernel already), and
+# 40-42 equations more a group around them (which packed positions hold a
+# one-query row, found by comparison; their table rows and lengths; the decode
+# kernel's launch; one addition that joins the outputs: no ``cond``, and no
+# scatter but the keys' and the values' writes). The decode steps' entries are
+# unedited, and so is the row of the model with the indexer.
 STEP_PROGRAMS = {
-    ("llama", "test-tiny"): ((1, 0, 156), (1, 0, 145)),
-    ("llama", "qwen2-test-tiny"): ((1, 0, 163), (1, 0, 152)),
-    ("mixtral", "mixtral-test-tiny"): ((1, 0, 319), (1, 0, 302)),
-    ("mixtral", "smallthinker-test-tiny"): ((1, 0, 336), (1, 0, 321)),
-    ("phi4flash", "phi4flash-test-tiny"): ((2, 0, 1033), (2, 0, 779)),
+    ("llama", "test-tiny"): ((2, 0, 196), (1, 0, 145)),
+    ("llama", "qwen2-test-tiny"): ((2, 0, 203), (1, 0, 152)),
+    ("mixtral", "mixtral-test-tiny"): ((2, 0, 359), (1, 0, 302)),
+    ("mixtral", "smallthinker-test-tiny"): ((2, 0, 378), (1, 0, 321)),
+    ("phi4flash", "phi4flash-test-tiny"): ((3, 0, 1075), (2, 0, 779)),
     ("mixtral", "keye-test-tiny"): ((0, 2, 1015), (0, 0, 600)),
-    ("mixtral", "trinity-test-tiny"): ((2, 0, 585), (2, 0, 562)),
-    ("mixtral", "lfm2-test-tiny"): ((1, 2, 795), (1, 2, 547)),
+    ("mixtral", "trinity-test-tiny"): ((4, 0, 669), (2, 0, 562)),
+    ("mixtral", "lfm2-test-tiny"): ((2, 2, 835), (1, 2, 547)),
 }
 
 
@@ -507,6 +539,8 @@ def test_step_programs_hold_the_kernels_and_equations_they_held(monkeypatch, mod
     for traced, (kernels, conds, equations) in zip((mixed, decode), STEP_PROGRAMS[(module, preset)]):
         census = _census(traced.jaxpr, {})
         assert (census.get("pallas_call", 0), census.get("cond", 0)) == (kernels, conds)
+        if traced is mixed and kernels:  # the two outputs are joined without one: the pools' writes alone
+            assert census["scatter"] == 2 * len(_kernel_bodies(traced.jaxpr)), census["scatter"]
         total = sum(census.values())
         assert (total == equations if older else total <= equations), total
 

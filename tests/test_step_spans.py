@@ -166,11 +166,18 @@ async def test_mixed_records_count_the_tokens_whose_rows_take_the_wide_tile(side
     from inference_gateway_tpu.ops.paged_attention import ragged_tile_plan
 
     server, port, _ = sidecar
-    cfg = server.engine.model_cfg
-    plan = ragged_tile_plan(cfg.num_heads // cfg.num_kv_heads, 16)
+    cfg, engine = server.engine.model_cfg, server.engine
+    plan = ragged_tile_plan(cfg.num_heads, cfg.num_kv_heads, cfg.hd, 16, engine.page_cfg.max_pages_per_slot,
+                            engine.dtype)
     status = (await HTTPClient().get(f"http://127.0.0.1:{port}/debug/status")).json()
-    assert status["ragged_attention"] == plan == server.engine.ragged_attention_plan
-    assert set(plan) == {"narrow_tile", "wide_tile", "wide_from", "rows_per_pass"}
+    assert status["ragged_attention"] == plan == engine.ragged_attention_plan
+    # ISSUE 44: one width, the pages a visit of a wide tile and of a one-query
+    # row, and which kernel the one-query rows take.
+    assert set(plan) == {"wide_tile", "wide_from", "rows_per_pass", "wide_pages_per_visit", "unmasked_visits",
+                         "one_query_rows", "one_query_pages_per_visit"}
+    assert plan["one_query_rows"] == "decode_kernel"
+    assert plan["one_query_pages_per_visit"] == status["decode_attention"]["pages_per_visit"]
+    assert 1 <= plan["wide_pages_per_visit"] <= engine.page_cfg.max_pages_per_slot
     # ISSUE 37: and the decode kernel's page walk for the same layout.
     walk = status["decode_attention"]
     assert walk == server.engine.decode_attention_plan
@@ -185,11 +192,18 @@ async def test_mixed_records_count_the_tokens_whose_rows_take_the_wide_tile(side
     recs = server.timeline.tail(server.timeline.stats()["records"] - before)
     mixed = [r for r in recs if r["kind"] == "mixed"]
     assert len(mixed) == 2
-    assert all("attn_wide_tokens" not in r for r in recs if r["kind"] != "mixed")
+    for name in ("attn_wide_tokens", "attn_pages_walked", "attn_pages_masked"):
+        assert all(name not in r for r in recs if r["kind"] != "mixed")
     for r in mixed:
         assert r["batch"] == 1  # idle before: the prompt's row and no decode row
         adopted = r["context_tokens"] - r["attn_wide_tokens"]
         assert r["attn_wide_tokens"] >= plan["wide_from"] and adopted >= 0 and adopted % 16 == 0
+        # ISSUE 44: the pages the chunk's tiles walked in every layer (each
+        # tile at least the page its queries sit on), and of them the pages
+        # of visits that took the mask (its diagonal, at the least).
+        tiles = -(-r["attn_wide_tokens"] // plan["wide_tile"])
+        assert cfg.num_layers * tiles <= r["attn_pages_masked"] <= r["attn_pages_walked"]
+        assert r["attn_pages_walked"] <= cfg.num_layers * tiles * -(-r["context_tokens"] // 16)
 
 
 async def test_records_and_status_count_what_was_delivered_behind_a_dispatch(sidecar):
