@@ -34,6 +34,14 @@ slots at each cell's shape, live and empty, and time two kernels on the same
 rows: ``paged_attention_tpu`` (the decode kernel every decode program calls)
 and the ragged kernel's one-query path (each live slot a row of one query).
 
+The learned sparse attention's one-query rows (ISSUE 45, ``keye-one-query:<context>/<pages>``):
+12 rows at Keye-VL-2.0's layout (Hq 32, Hkv 4, D 128, an indexer of 16 heads
+of 64, ``topk`` 2,048), every row at the case's context, on the cell's
+104-page table up to 12.7k and on a 256-page one above; ``sparse_decode``
+whole (scores, selection, read) with its read forced each way through
+``WALK_UNDER``: the token gather behind ``lax.top_k``, and the masked walk
+of whole pages behind the exact k-th score. The crossover sets the constant.
+
 Not part of the benchmark, imported by nothing a cell runs; a CPU run of it
 times the CPU and is never a speed number.
 """
@@ -127,6 +135,13 @@ def _decode_cases() -> dict:
     }
 
 
+# (context, pages a row of the table holds). The cell's rows end at 12,800 on 104 pages; the
+# last two are FULL rows of 9 x and 12 x topk, the walk at its worst for the table.
+KEYE_ONE_QUERY = ((4096, 104), (8192, 104), (12700, 104), (16384, 256), (24576, 256), (32700, 256),
+                  (18432, 144), (24576, 192))
+KEYE = dict(B=12, Hq=32, Hkv=4, D=128, Hi=16, Di=64, topk=2048)
+
+
 # The cases whose model mixes windowed and global layers.
 MIXED_KINDS = ("smallthinker-21b-a3b.batch", "phi-4-mini-flash.reason", "trinity-mini.long-context")
 
@@ -169,10 +184,12 @@ def main() -> int:
         assert hasattr(pa, name), name
         setattr(pa, name, json.loads(value))
     dev = jax.devices()[0]
+    cases = measure(pa, args.cases, args.window_operand, args.repeats, args.profile)
+    cases.update(measure_keye(importlib.import_module("inference_gateway_tpu.ops.sparse_attention"),
+                              args.cases, args.repeats, args.profile))
     out = {"root": os.path.relpath(root, HERE), "set": args.set, "window_operand": args.window_operand,
            "device": {"platform": dev.platform, "kind": dev.device_kind},
-           "calls_chained": CALLS, "decode_calls_chained": DECODE_CALLS,
-           "cases": measure(pa, args.cases, args.window_operand, args.repeats, args.profile)}
+           "calls_chained": CALLS, "decode_calls_chained": DECODE_CALLS, "cases": cases}
     dest = os.path.join(HERE, "chiprun_out", "ragged_attention_bench")
     os.makedirs(dest, exist_ok=True)
     tag = args.tag or ("change" if root == HERE else os.path.basename(root))
@@ -197,12 +214,7 @@ def _timed(call, q, k, v, ref, rows, Hq, Hkv, D, window, chip, repeats, live=Tru
 
     got = jax.block_until_ready(jax.jit(call)(q, k, v, *operands))
     diff = float(jnp.max(jnp.where(live, jnp.abs(got.astype(jnp.float32) - ref.astype(jnp.float32)), 0)))
-    jax.block_until_ready(chained(q, k, v, *operands))
-    times = []
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        jax.block_until_ready(chained(q, k, v, *operands))
-        times.append((time.perf_counter() - t0) / calls)
+    times = _clock(lambda: chained(q, k, v, *operands), repeats, calls)
     flops, nbytes = _reckon(rows, Hq, Hkv, D, window)
     line = {"us_per_call": round(statistics.median(times) * 1e6, 1),
             "us_min": round(min(times) * 1e6, 1),
@@ -222,9 +234,23 @@ def _timed(call, q, k, v, ref, rows, Hq, Hkv, D, window, chip, repeats, live=Tru
     return line
 
 
-def _device_us_by_op(run, calls) -> dict:
+def _clock(run, repeats, calls) -> list:
+    """Seconds a call of ``run()`` (``calls`` chained calls), ``repeats`` times after one that warms it."""
+    import jax
+
+    jax.block_until_ready(run())
+    times = []
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        jax.block_until_ready(run())
+        times.append((time.perf_counter() - t0) / calls)
+    return times
+
+
+def _device_us_by_op(run, calls, top=6) -> dict:
     """Device time of one ``run()`` (``calls`` chained calls) by operation, us
-    a call, the six largest: the profiler's ``XLA Ops`` line of the device."""
+    a call, the ``top`` largest and their sum beside the whole: the profiler's
+    ``XLA Ops`` line of the device."""
     import glob
     import re
     import tempfile
@@ -244,7 +270,10 @@ def _device_us_by_op(run, calls) -> dict:
                     if line.name == "XLA Ops":
                         for ev in line.events:
                             by_op[ev.name] = by_op.get(ev.name, 0.0) + ev.duration_ns / 1e3 / calls
-    return {k[:60]: round(v, 2) for k, v in sorted(by_op.items(), key=lambda kv: -kv[1])[:6]}
+    # A loop's event spans its body's: the whole is the operations that are no loop.
+    whole = sum(v for k, v in by_op.items() if not k.lstrip("%").startswith("while"))
+    return {**{k[:60]: round(v, 2) for k, v in sorted(by_op.items(), key=lambda kv: -kv[1])[:top]},
+            "all_but_loops": round(whole, 2)}
 
 
 def _twin(pa, q, k, v, pt, q_starts, q_lens, kv_lens, Hkv, window, whole=True, block=64):
@@ -363,6 +392,63 @@ def measure(pa, names=None, window_operand=False, repeats=REPEATS, profile=False
             line[label]["empty_rows_zero"] = bool(jnp.all(empty == 0))
         if hasattr(pa, "decode_walk_plan"):
             line["plan"] = pa.decode_walk_plan(Hq, Hkv, D, PAGE, MAX_PAGES, bf)
+        lines[name] = line
+        print(name, json.dumps(line), flush=True)
+    return lines
+
+
+def measure_keye(sa, names=None, repeats=REPEATS, profile=False) -> dict:
+    """``keye-one-query:<context>/<pages>``: ``sa.sparse_decode`` whole, its read
+    forced each way (a tree before ISSUE 45 has the gather alone)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    B, Hq, Hkv, D, Hi, Di, topk = (KEYE[n] for n in ("B", "Hq", "Hkv", "D", "Hi", "Di", "topk"))
+    bf = jnp.bfloat16
+    lines = {}
+    for ctx, pages in KEYE_ONE_QUERY:
+        name = f"keye-one-query:{ctx}/{pages}"
+        if names and not any(name.startswith(n) for n in names):
+            continue
+        rng = np.random.default_rng(ctx)
+        P = B * pages
+        normal = lambda *shape: jnp.asarray(rng.standard_normal(shape, dtype=np.float32), bf)
+        q, qi, wi = normal(B, Hq, D), normal(B, Hi, Di), jnp.asarray(rng.standard_normal((B, Hi), dtype=np.float32))
+        flat_k, flat_v, ki = normal(P * PAGE, Hkv * D), normal(P * PAGE, Hkv * D), normal(P, Di, PAGE)
+        table = jnp.asarray(rng.permutation(P).reshape(B, pages).astype(np.int32))
+        lengths = jnp.full((B,), ctx, jnp.int32)
+        line, outs = {"rows": B, "context": ctx, "row_tokens": pages * PAGE, "topk": topk}, {}
+        for read, under in (("gather", 0), ("walk", 1 << 20)):
+            if read == "walk" and not hasattr(sa, "WALK_UNDER"):
+                continue
+            if hasattr(sa, "WALK_UNDER"):
+                sa.WALK_UNDER = under
+
+            def call(q_, qi_, k_, v_, wi_, ki_, table_, lengths_):
+                return sa.sparse_decode(q_, qi_, wi_, k_, v_, ki_, table_, lengths_, topk=topk,
+                                        num_kv_heads=Hkv, page_size=PAGE)
+
+            def chain(i, carry, *ops):
+                # A call's output is the next one's query, and moves its indexer's too: scores, selection and
+                # read all follow the carry (what does not is hoisted out of the loop and timed once in 128).
+                q_, qi_ = carry
+                out = call(q_, qi_, *ops)
+                return out, (qi_ + out[:, :Hi, :Di] * 0.05).astype(qi_.dtype)
+
+            operands = (flat_k, flat_v, wi, ki, table, lengths)
+            chained = jax.jit(lambda q_, qi_, *ops: jax.lax.fori_loop(
+                0, DECODE_CALLS, lambda i, c: chain(i, c, *ops), (q_, qi_)))
+            outs[read] = jax.block_until_ready(jax.jit(call)(q, qi, *operands))
+            times = _clock(lambda: chained(q, qi, *operands), repeats, DECODE_CALLS)
+            line[read] = {"us_per_call": round(statistics.median(times) * 1e6, 1), "us_min": round(min(times) * 1e6, 1)}
+            if profile:
+                line[read]["device_us_by_op"] = _device_us_by_op(
+                    lambda: jax.block_until_ready(chained(q, qi, *operands)), DECODE_CALLS, top=12)
+        if len(outs) == 2:
+            line["max_abs_diff_walk_vs_gather_bf16"] = float(jnp.max(jnp.abs(
+                outs["walk"].astype(jnp.float32) - outs["gather"].astype(jnp.float32))))
+            line["gather_over_walk"] = round(line["gather"]["us_per_call"] / line["walk"]["us_per_call"], 3)
         lines[name] = line
         print(name, json.dumps(line), flush=True)
     return lines

@@ -43,7 +43,7 @@ from inference_gateway_tpu.ops.moe import ACTIVATIONS, moe_dense, moe_dropless, 
 from inference_gateway_tpu.ops.norms import rms_norm
 from inference_gateway_tpu.ops.quant import qeinsum, qmatmul, seeded_qk_gain, seeded_std
 from inference_gateway_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
-from inference_gateway_tpu.ops.sparse_attention import sparse_decode, sparse_ragged
+from inference_gateway_tpu.ops.sparse_attention import one_query_tokens_read, sparse_decode, sparse_ragged
 from inference_gateway_tpu.ops.ssm import conv_decode, conv_ragged, token_segments
 
 
@@ -535,11 +535,13 @@ def _dsa_decode(h, q, lp, li, positions, pools, w_idx, table, lengths, *, page_s
     tokens whose keys and values their attention read)."""
     del li
     qi, wi, cki = _index_and_write(h, lp, positions, pools["ki"], w_idx, page_size, cfg)
+    if valid is not None:   # a row that carries no stream sees nothing: the walk passes over it
+        lengths = jnp.where(valid, lengths, 0)
     attn = sparse_decode(q, qi, wi, pools["k"], pools["v"], cki, table, lengths, topk=cfg.indexer_topk,
                          num_kv_heads=cfg.num_kv_heads, page_size=page_size)
-    live = jnp.ones_like(lengths, bool) if valid is None else valid
-    seen = jnp.sum(jnp.where(live, lengths, 0))
-    read = jnp.sum(live) * min(cfg.indexer_topk, table.shape[1] * page_size)
+    seen = jnp.sum(lengths)
+    read = jnp.sum(jnp.where(lengths > 0, one_query_tokens_read(
+        cfg.indexer_topk, table.shape[1] * page_size, page_size, lengths), 0))
     return attn, {"ki": cki}, jnp.stack([seen, read]).astype(jnp.float32)
 
 

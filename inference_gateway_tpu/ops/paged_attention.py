@@ -84,6 +84,7 @@ def paged_attention_jax(
     lengths: jnp.ndarray,  # (B,) int32 — valid tokens (0 = inactive slot)
     num_kv_heads: int,
     window: int | None = None,  # sliding window: attend last `window` tokens only
+    mask: jnp.ndarray | None = None,  # (B, max_pages * page_size) bool: of what a slot sees, what it attends
 ) -> jnp.ndarray:
     B, Hq, D = q.shape
     _, page_size, HkvD = k_pages.shape
@@ -101,6 +102,8 @@ def paged_attention_jax(
     valid = jnp.arange(S)[None, :] < lengths[:, None]  # (B, S)
     if window is not None:
         valid = valid & (jnp.arange(S)[None, :] >= lengths[:, None] - window)
+    if mask is not None:
+        valid = valid & mask
     scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores.astype(jnp.float32), axis=-1)
     out = jnp.einsum("bkgs,bskd->bkgd", probs.astype(v.dtype), v, preferred_element_type=jnp.float32)
@@ -190,20 +193,10 @@ def _paged_attn_kernel(
     q_ref,  # (B, Hkv, G, D) VMEM: every slot's query, by KV head
     k_pages_hbm,  # (P, page_size, Hkv*D) in ANY/HBM
     v_pages_hbm,
-    # output
-    out_ref,  # (B, Hkv, G, D) VMEM
-    # scratch
-    k_buf,  # (2, N, page_size, pad128(Hkv*D)) VMEM — a page's DMA fills [:Hkv*D]
-    v_buf,
-    sems,  # DMA semaphores (2, 2)
-    s_ref,  # (Hkv, G, N·page_size) float32: a visit's scores
-    p_ref,  # the same in V's dtype: its probabilities
-    m_ref,  # (Hkv, G, 1) float32: the slot's running maximum,
-    l_ref,  # sum
-    acc_ref,  # (Hkv, G, D) and output
-    *,
+    *refs,  # where ``masked``, first the mask: (B, tokens) int32 VMEM, a slot's row by position; then:
     window: int | None,
     window_operand: bool,
+    masked: bool = False,
 ):
     """One instance for the whole batch. The walk is one chain of VISITS
     over the slots that hold something: a visit is up to N consecutive
@@ -225,7 +218,25 @@ def _paged_attn_kernel(
     The window is static (``window``: a model whose layers are alike pays
     no mask without one) or the last of ``length_ref``
     (``window_operand``: a model that mixes windowed and global layers
-    traces ONE instance, a global layer handing GLOBAL_WINDOW)."""
+    traces ONE instance, a global layer handing GLOBAL_WINDOW).
+
+    ``masked`` (ISSUE 45): of what a slot sees it attends only the positions
+    its row of the mask holds non-zero (a learned sparse attention's chosen
+    set; at least one, or the row's output means nothing). Every page the
+    slot holds is still walked whole: a visit's lanes of the mask are and-ed
+    into what it may see, and a visit that attends nothing before the first
+    that does is rescaled away by that one."""
+    mask_ref, *refs = refs if masked else (None, *refs)
+    (out_ref,  # output: (B, Hkv, G, D) VMEM; the rest is scratch
+     k_buf,  # (2, N, page_size, pad128(Hkv*D)) VMEM — a page's DMA fills [:Hkv*D]
+     v_buf,
+     sems,  # DMA semaphores (2, 2)
+     s_ref,  # (Hkv, G, N·page_size) float32: a visit's scores
+     p_ref,  # the same in V's dtype: its probabilities
+     m_ref,  # (Hkv, G, 1) float32: the slot's running maximum,
+     l_ref,  # sum
+     acc_ref,  # (Hkv, G, D) and output
+     ) = refs
     B, Hkv, G, D = q_ref.shape
     _, N, page_size, _ = k_buf.shape
     scale = D ** -0.5
@@ -297,6 +308,11 @@ def _paged_attn_kernel(
             valid = pos < length
             if window is not None:
                 valid = valid & (pos >= length - window)
+            if masked:
+                at = (p0 + done) * page_size
+                if page_size % LANE == 0:
+                    at = pl.multiple_of(at, LANE)
+                valid = valid & (mask_ref[pl.ds(s, 1), pl.ds(at, N * page_size)] != 0)[None]
             # Every KV head's scores under ONE flash update a visit.
             sc = jax.lax.select(valid, s_ref[...] * scale, jnp.full(tile, NEG_INF, jnp.float32))
             m = m_ref[...]
@@ -322,7 +338,7 @@ def _paged_attn_kernel(
     jax.lax.while_loop(lambda c: c[0] < B, slot_body, (first, first_page, first_pages, zero))
 
 
-@functools.partial(jax.jit, static_argnames=("num_kv_heads", "interpret", "window", "pages_per_visit"))
+@functools.partial(jax.jit, static_argnames=("num_kv_heads", "interpret", "window", "pages_per_visit", "name"))
 def paged_attention_tpu(
     q: jnp.ndarray,  # (B, Hq, D)
     k_pages: jnp.ndarray,  # (P, page_size, Hkv*D)
@@ -334,6 +350,8 @@ def paged_attention_tpu(
     window: int | None = None,
     window_dyn: jnp.ndarray | None = None,  # int32 scalar: the window as an operand
     pages_per_visit: int | None = None,  # the layout's (decode_walk_plan) unless a test says
+    mask: jnp.ndarray | None = None,  # (B, max_pages * page_size) bool: of what a slot sees, what it attends
+    name: str | None = None,  # the kernel's in a trace, where the caller's layer is read by it
 ) -> jnp.ndarray:
     B, Hq, D = q.shape
     P, page_size, HkvD = k_pages.shape
@@ -345,7 +363,7 @@ def paged_attention_tpu(
     mxu_dtype = jnp.promote_types(q.dtype, k_pages.dtype)
 
     kernel = functools.partial(_paged_attn_kernel, window=window,
-                               window_operand=window_dyn is not None)
+                               window_operand=window_dyn is not None, masked=mask is not None)
     # A slot more, of length -1: it ends the search for the next live slot and
     # holds no page. After it the window, where it is an operand.
     descr = jax.lax.pad(lengths.astype(jnp.int32), jnp.int32(-1), ((0, 1, 0),))
@@ -353,6 +371,13 @@ def paged_attention_tpu(
         descr = jnp.concatenate([descr, jnp.reshape(window_dyn, (1,)).astype(jnp.int32)])
     by_head = (B, num_kv_heads, G, D)
     tile = (num_kv_heads, G, N * page_size)
+    masks, mask_specs = (), []
+    if mask is not None:
+        # A slot's row in the lanes, a visit more than the table holds: a visit
+        # reads N pages' lanes from its first page, wherever the walk began.
+        row = (B, (page_table.shape[1] + N) * page_size)
+        masks = (jax.lax.pad(mask.astype(jnp.int32), jnp.int32(0), ((0, 0, 0), (0, N * page_size, 0))),)
+        mask_specs = [pl.BlockSpec(row, lambda i, *_: (0, 0))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(1,),
@@ -360,6 +385,7 @@ def paged_attention_tpu(
             pl.BlockSpec(by_head, lambda i, *_: (0, 0, 0, 0)),
             pl.BlockSpec(memory_space=pltpu.ANY),
             pl.BlockSpec(memory_space=pltpu.ANY),
+            *mask_specs,
         ],
         out_specs=pl.BlockSpec(by_head, lambda i, *_: (0, 0, 0, 0)),
         scratch_shapes=[
@@ -380,8 +406,9 @@ def paged_attention_tpu(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",), vmem_limit_bytes=64 << 20),
         interpret=interpret,
+        name=name,
     )(jax.lax.pad(page_table.astype(jnp.int32), jnp.int32(0), ((0, 1, 0), (0, 0, 0))),
-      descr, q.reshape(by_head).astype(mxu_dtype), k_pages, v_pages)
+      descr, q.reshape(by_head).astype(mxu_dtype), k_pages, v_pages, *masks)
     return out.reshape(B, Hq, D)
 
 
@@ -1033,7 +1060,7 @@ def _mesh_devices(mesh) -> int:
 
 def paged_attention(q, k_pages, v_pages, page_table, lengths, num_kv_heads: int,
                     use_kernel: bool | None = None, window: int | None = None,
-                    mesh=None, window_dyn=None) -> jnp.ndarray:
+                    mesh=None, window_dyn=None, mask=None, name: str | None = None) -> jnp.ndarray:
     """Dispatch (see paged_dispatch): Pallas kernel on single-device
     TPU, shard_mapped over ``tp`` under a mesh (kv-head-local), or a
     replicated shard_map launch for tp=1 meshes / non-tp-divisible
@@ -1044,10 +1071,15 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, num_kv_heads: int,
     jitted forwards bake the dispatch into the trace, so a mid-session
     env flip would not apply to compiled shapes (advisor round-2).
     ``window`` is static; ``window_dyn`` is the window as an int32 scalar
-    operand (GLOBAL_WINDOW for none), for a model whose layers differ."""
+    operand (GLOBAL_WINDOW for none), for a model whose layers differ.
+    ``mask`` (B, max_pages * page_size) bool: of what a slot sees, what it
+    attends (no mesh: no model with one shards); ``name`` the kernel's in a
+    trace."""
     force = FORCE_PAGED_KERNEL
     platform = device_platform()
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
+    if mask is not None and mesh is not None:
+        raise NotImplementedError("a masked paged attention under a mesh: the shard_map launches take no mask")
     if use_kernel is not None and force is None and tp == 1:
         # Explicit caller override (tests); force flag still wins above.
         path = "kernel" if use_kernel else "gather"
@@ -1063,9 +1095,10 @@ def paged_attention(q, k_pages, v_pages, page_table, lengths, num_kv_heads: int,
                                        window_dyn=window_dyn)
     if path in ("kernel", "kernel_sharded", "kernel_replicated"):
         return paged_attention_tpu(q, k_pages, v_pages, page_table, lengths, num_kv_heads,
-                                   window=window, interpret=interpret, window_dyn=window_dyn)
+                                   window=window, interpret=interpret, window_dyn=window_dyn,
+                                   mask=mask, name=name)
     return paged_attention_jax(q, k_pages, v_pages, page_table, lengths, num_kv_heads,
-                               window=window if window_dyn is None else window_dyn)
+                               window=window if window_dyn is None else window_dyn, mask=mask)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, page_table, q_starts, q_lens, kv_lens,

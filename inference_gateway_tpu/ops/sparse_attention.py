@@ -13,14 +13,24 @@ keeps the ``topk`` largest (equal scores: the lower position first, the
 rule of ``lax.top_k``), and attends over those tokens alone. A query that
 sees ``topk`` tokens or fewer keeps them all: plain causal attention.
 
-Plain XLA, no kernel of its own. Two ways to read the chosen tokens, by
-the shape of the row, and the counters say which was taken:
+Plain XLA but for a one-query row's read. Three ways to read the chosen
+tokens, by the shape of the row and of the page table, and the counters say
+which was taken:
 
 - a row of ONE query (a decode row, in the decode chunk and in a mixed
-  step): ``lax.top_k`` over the row's scores, then a token-granular gather
-  of the chosen keys and values through the page table into a dense
-  attention over ``topk`` tokens. The attention reads ``topk`` tokens,
-  whatever the context holds (``sparse_decode``).
+  step), where a row of the page table holds at most ``WALK_UNDER`` x
+  ``topk`` tokens (ISSUE 45): the chosen set is a MASK over the row (the
+  exact ``topk``-th largest score by ``topk_mask``: no sort), and the
+  paged decode kernel walks the row's pages whole under it
+  (``ops/paged_attention.py``, its ``mask`` operand). The attention reads
+  every page the row holds: at such contexts (a few ``topk``) every page
+  holds chosen tokens, and the chip walks a page at its bytes' time where
+  a token's gather costs nine times that and the sort before it grows
+  with the table (``one_query_read``).
+- a row of ONE query on a wider table: ``lax.top_k`` over the row's
+  scores, then a token-granular gather of the chosen keys and values
+  through the page table into a dense attention over ``topk`` tokens. The
+  attention reads ``topk`` tokens, whatever the context holds.
 - a row of several queries (a prompt chunk): each query has a set of its
   own, so the row is walked in tiles of ``TILE`` queries; a tile's scores
   go into a (TILE, S) buffer a block of keys at a time, each query's
@@ -40,9 +50,34 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from inference_gateway_tpu.ops.paged_attention import paged_attention
+
 NEG = -1e30
 TILE = 128          # queries a tile of a prompt chunk
 BLOCK_TOKENS = 1024  # keys a block of either loop of a tile, rounded to whole pages
+# A one-query row's read is the masked walk of its pages where a row of the
+# page table holds at most this many times ``topk`` tokens, the gather of the
+# chosen tokens above. On a v5e at Keye-VL-2.0's layout (PERF.md section 5, PR
+# 45) 12 FULL rows of 12 x topk walk in 0.89 ms where the sort and the two
+# gathers take 0.98, rows of 16 x topk filled to 12 x 0.91 against 1.08 and
+# filled whole 1.17: the walk's time follows the context, the gather's the
+# table, so the table at which a full row breaks even is the latest safe one.
+WALK_UNDER = 12
+
+
+def one_query_read(topk: int, row_tokens: int) -> str:
+    """How a one-query row reads its chosen tokens on a page table whose row
+    holds ``row_tokens``: static a program, so a /debug/status entry."""
+    return "walk" if row_tokens <= WALK_UNDER * topk else "gather"
+
+
+def one_query_tokens_read(topk: int, row_tokens: int, page_size: int, lengths: jnp.ndarray) -> jnp.ndarray:
+    """Tokens whose keys and values a one-query row's attention reads, for
+    each of ``lengths`` (a live row's context): the pages it holds on the
+    walk, ``topk`` on the gather."""
+    if one_query_read(topk, row_tokens) == "walk":
+        return -(-lengths // page_size) * page_size
+    return jnp.full_like(lengths, min(topk, row_tokens))
 
 
 def order_key(x: jnp.ndarray) -> jnp.ndarray:
@@ -100,13 +135,21 @@ def sparse_decode(q, qi, wi, flat_k, flat_v, ki_pages, table, lengths, *, topk: 
     (pages, Di, page_size) the indexer's keys by page; table (B, pages)
     page ids into them (a layer's offset added); lengths (B,) tokens visible,
     the query's own included (0: an idle row, whose output means nothing).
-    Returns (B, Hq, D)."""
+    Returns (B, Hq, D). The read is ``one_query_read``'s for the table."""
     B, Hq, D = q.shape
     S = table.shape[1] * page_size
     k = min(topk, S)
     with jax.named_scope("dsa.index"):
         scores = index_scores(qi[:, None], wi[:, None], ki_pages[table])[:, 0]    # (B, S), whole pages gathered
-        scores = jnp.where(jnp.arange(S)[None, :] < lengths[:, None], scores, -jnp.inf)
+        visible = jnp.arange(S)[None, :] < lengths[:, None]
+        scores = jnp.where(visible, scores, -jnp.inf)
+    if one_query_read(topk, S) == "walk":
+        with jax.named_scope("dsa.select"):
+            mask = topk_mask(scores, visible, k)   # fewer visible than k: all of them
+        with jax.named_scope("dsa.attend"):
+            pages_k, pages_v = (flat.reshape(-1, page_size, flat.shape[-1]) for flat in (flat_k, flat_v))
+            return paged_attention(q, pages_k, pages_v, table, lengths, num_kv_heads, mask=mask,
+                                   name="dsa_paged_attn_kernel")
     with jax.named_scope("dsa.select"):
         _, idx = jax.lax.top_k(scores, k)                                         # (B, k)
         chosen = idx < lengths[:, None]        # fewer visible than k: the tail is padding
@@ -145,18 +188,19 @@ def sparse_ragged(q, qi, wi, positions, flat_k, flat_v, ki_pages, table, q_start
     Hkv, G = num_kv_heads, Hq // num_kv_heads
     KB = block_tokens(page_size, table.shape[1] * page_size)
     ppb = KB // page_size
-    pages = -(-table.shape[1] // ppb) * ppb
-    table = jnp.pad(table, ((0, 0), (0, pages - table.shape[1])))   # whole blocks; the pad is never visible
-    S = pages * page_size
-
-    # Rows of one query: as a decode step's, all at once.
+    # Rows of one query: as a decode step's (and on its table: one width decides
+    # both programs' read), all at once.
     single = q_lens == 1
     at = jnp.clip(q_starts, 0, T - 1)
+    row_tokens = table.shape[1] * page_size
     narrow = sparse_decode(q[at], qi[at], wi[at], flat_k, flat_v, ki_pages, table,
                            jnp.where(single, kv_lens, 0), topk=topk,
                            num_kv_heads=num_kv_heads, page_size=page_size)
 
     # Rows of several: tiles of TILE queries, a row after another.
+    pages = -(-table.shape[1] // ppb) * ppb
+    table = jnp.pad(table, ((0, 0), (0, pages - table.shape[1])))   # whole blocks; the pad is never visible
+    S = pages * page_size
     tiles = jnp.where(q_lens > 1, -(-q_lens // TILE), 0)
     ends = jnp.cumsum(tiles)
     pad = lambda a: jnp.pad(a, ((0, TILE),) + ((0, 0),) * (a.ndim - 1))
@@ -230,5 +274,5 @@ def sparse_ragged(q, qi, wi, positions, flat_k, flat_v, ki_pages, table, q_start
         (jnp.zeros((T + TILE, Hq, D), q.dtype), jnp.zeros((T + TILE,), jnp.int32)))
     drop = jnp.where(single, q_starts, T + TILE)
     out = out.at[drop].set(narrow, mode="drop")
-    walked = walked.at[drop].set(min(topk, S), mode="drop")
+    walked = walked.at[drop].set(one_query_tokens_read(topk, row_tokens, page_size, kv_lens), mode="drop")
     return out[:T], walked[:T]

@@ -37,6 +37,7 @@ from inference_gateway_tpu.ops.sampling import (
     sample_tokens_pregumbel,
     top_k_plan,
 )
+from inference_gateway_tpu.ops import sparse_attention
 from inference_gateway_tpu.otel.profiling import cause_span, phase_span, stamp_since
 from inference_gateway_tpu.parallel.mesh import create_mesh, default_mesh_shape
 from inference_gateway_tpu.parallel.sharding import (
@@ -1204,9 +1205,9 @@ class Engine:
             counted = {"valid": ~done, "with_aux": True} if self.is_moe or self.is_hybrid else {}
             # A done row attends nothing: the decode kernel passes over a
             # slot of length 0 and writes its row as zeros (ISSUE 37), where
-            # a frozen row's stale context cost every step its pages. (An
-            # indexer's selection is not made for an empty context.)
-            att_len = pos_att + 1 if self.is_dsa else jnp.where(done, 0, pos_att + 1)
+            # a frozen row's stale context cost every step its pages (an
+            # indexer's masked walk passes over it the same way: ISSUE 45).
+            att_len = jnp.where(done, 0, pos_att + 1)
             logits, cache, *rows = self._model.forward_paged(
                 params, self.model_cfg, tok[:, None], pos_att[:, None],
                 att_len, cache, w[:, None], page_table, mode="decode",
@@ -1642,14 +1643,22 @@ class Engine:
         if not self.is_dsa:
             return None
         cfg = self.model_cfg
+        row_tokens = self.page_cfg.max_pages_per_slot * self.page_cfg.page_size
+        walk = sparse_attention.one_query_read(cfg.indexer_topk, row_tokens) == "walk"
         return {
             "topk": cfg.indexer_topk, "indexer_heads": cfg.indexer_heads,
             "indexer_head_dim": cfg.indexer_head_dim,
             "index_cache_bytes_per_token": cfg.num_layers * cfg.indexer_head_dim * jnp.dtype(self.dtype).itemsize,
             "short_context": "a query that sees topk tokens or fewer keeps them all, by the same path (no plain-attention branch)",
-            "select": {"one_query_row": "lax.top_k over the row's scores",
+            # Static a program: a row of the page table against WALK_UNDER x topk (ISSUE 45).
+            "one_query_row": {"read": "walk" if walk else "gather", "row_tokens": row_tokens,
+                              "walk_under": sparse_attention.WALK_UNDER,
+                              "walk_up_to_tokens": sparse_attention.WALK_UNDER * cfg.indexer_topk},
+            "select": {"one_query_row": ("mask by the exact k-th largest score (32 counting passes, ties by position)"
+                                         if walk else "lax.top_k over the row's scores"),
                        "prompt_chunk": "exact k-th largest by 32 counting passes, ties by position, as a mask"},
-            "read": {"one_query_row": "token gather of the chosen keys and values (topk tokens)",
+            "read": {"one_query_row": ("whole pages under the mask, by the paged decode kernel (every page the row holds)"
+                                       if walk else "token gather of the chosen keys and values (topk tokens)"),
                      "prompt_chunk": "tiles of 128 queries over every block of keys up to the tile's last position, masked"},
         }
 

@@ -490,13 +490,20 @@ def test_state_operations_compile_for_v5e_at_the_published_widths(one_chip, form
 # kernel's launch; one addition that joins the outputs: no ``cond``, and no
 # scatter but the keys' and the values' writes). The decode steps' entries are
 # unedited, and so is the row of the model with the indexer.
+# ISSUE 45: the model with the indexer alone: its one-query rows' read is the decode
+# kernel under a mask where a row of the page table holds at most WALK_UNDER x topk
+# tokens (this layout's 64 against 288), ONE instance in each step program, and the
+# selection before it one more ``cond`` (a tie at the cut: ``topk_mask``) where the
+# sort and the two token gathers went: 921 and 545 equations, from 1,015 and 600
+# (ceilings, as before: the row's counts move with the sparse branch's every edit).
+# The eight other rows are unedited.
 STEP_PROGRAMS = {
     ("llama", "test-tiny"): ((2, 0, 196), (1, 0, 145)),
     ("llama", "qwen2-test-tiny"): ((2, 0, 203), (1, 0, 152)),
     ("mixtral", "mixtral-test-tiny"): ((2, 0, 359), (1, 0, 302)),
     ("mixtral", "smallthinker-test-tiny"): ((2, 0, 378), (1, 0, 321)),
     ("phi4flash", "phi4flash-test-tiny"): ((3, 0, 1075), (2, 0, 779)),
-    ("mixtral", "keye-test-tiny"): ((0, 2, 1015), (0, 0, 600)),
+    ("mixtral", "keye-test-tiny"): ((1, 3, 930), (1, 1, 550)),
     ("mixtral", "trinity-test-tiny"): ((4, 0, 669), (2, 0, 562)),
     ("mixtral", "lfm2-test-tiny"): ((2, 2, 835), (1, 2, 547)),
 }
@@ -539,20 +546,24 @@ def test_step_programs_hold_the_kernels_and_equations_they_held(monkeypatch, mod
     for traced, (kernels, conds, equations) in zip((mixed, decode), STEP_PROGRAMS[(module, preset)]):
         census = _census(traced.jaxpr, {})
         assert (census.get("pallas_call", 0), census.get("cond", 0)) == (kernels, conds)
-        if traced is mixed and kernels:  # the two outputs are joined without one: the pools' writes alone
+        if traced is mixed and kernels and older:  # the two outputs are joined without one: the pools' writes alone
             assert census["scatter"] == 2 * len(_kernel_bodies(traced.jaxpr)), census["scatter"]
         total = sum(census.values())
         assert (total == equations if older else total <= equations), total
 
 
 @pytest.mark.parametrize("form", ["decode", "ragged"])
-def test_sparse_attention_compiles_for_v5e_at_the_published_widths(one_chip, form):
+def test_sparse_attention_compiles_for_v5e_at_the_published_widths(one_chip, monkeypatch, form):
     """Score, select and read (ops/sparse_attention.py) for Keye-VL-2.0's stage:
     12 rows of 100 pages, 16 indexer heads of 64, top-2,048 of up to 12,800,
     32 / 4 heads of 128; a decode step's rows, and a 524-token mixed step's.
-    Plain XLA: a pass says the chip's compiler takes it inside 1 GB of
-    temporaries beside the 2.7 GB pool (no copy of a pool)."""
+    Plain XLA but for the one-query rows' read: a pass says the chip's compiler
+    takes it inside 1 GB of temporaries beside the 2.7 GB pool (no copy of a
+    pool)."""
     from inference_gateway_tpu.ops import sparse_attention as sa
+
+    # The name ops/paged_attention.py bound at import: the dispatch takes the kernel.
+    monkeypatch.setattr("inference_gateway_tpu.ops.paged_attention.device_platform", lambda: "tpu")
 
     slots, pages, layers, T = 12, 100, 8, 524
     tokens = layers * slots * pages * PAGE
@@ -570,6 +581,38 @@ def test_sparse_attention_compiles_for_v5e_at_the_published_widths(one_chip, for
                 one_chip((T,), I32), *pools, table, row, row, row)
     compiled = jax.jit(fn).lower(*args).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    text = compiled.as_text()
+    # Rows of 12,800 tokens are under WALK_UNDER x topk: the one-query rows' read is ONE
+    # decode-kernel instance under the mask, named for the trace's reader, and no sort.
+    assert sa.one_query_read(2048, pages * PAGE) == "walk"
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "%dsa_paged_attn_kernel" in text
+    assert " sort(" not in text
+
+
+# The decode kernel under a mask at the long-context cell's layout (ISSUE 45): 12 slots,
+# 32 / 4 heads of 128, rows of 104 pages (the cell's 13,312 tokens), the pool of 8
+# layers. Its traced body holds 127 equations, 7 more than the unmasked one at that
+# layout (120, as qwen2.5-7b's row of DECODE_LAYOUTS, whose heads are the same): the
+# start-up guard, as DECODE_LAYOUTS' (a tenth of room).
+def _masked_decode_launch(sds, slots=12, pages=104, layers=8):
+    pool = sds((layers * slots * pages, PAGE, 4 * D), BF16)
+    shapes = [sds((slots, 32, D), BF16), pool, pool, sds((slots, pages), I32), sds((slots,), I32),
+              sds((slots, pages * PAGE), jnp.bool_)]
+    return (lambda q, k, v, table, lengths, mask: paged_attention_tpu(
+        q, k, v, table, lengths, 4, interpret=False, mask=mask, name="dsa_paged_attn_kernel")), shapes
+
+
+def test_masked_decode_kernel_compiles_for_v5e_at_the_long_context_cells_layout(one_chip):
+    fn, shapes = _masked_decode_launch(one_chip)
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "%dsa_paged_attn_kernel" in text   # what bench/dsa_bytes.py's pattern finds the event by
+
+
+def test_masked_decode_kernel_stays_inside_its_start_up_budget():
+    fn, shapes = _masked_decode_launch(jax.ShapeDtypeStruct)
+    (body,) = _kernel_bodies(jax.make_jaxpr(fn)(*shapes).jaxpr, "dsa_paged_attn_kernel")  # named: its source info begins so
+    assert _equations(body) <= 140
 
 
 # What the engine's two host-stepped programs trace to on the tiny dense

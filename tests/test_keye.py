@@ -8,6 +8,13 @@ chunked over four mixed steps beside other rows, then decode through the paged
 cache; the chosen sets of both selections against the reference's; contexts at
 or under topk against the skeleton's plain attention; the reference's M-RoPE
 against plain rotary; a prefix hit; the counters; the refusals.
+
+A one-query row's read follows the width of a page-table row (ISSUE 45,
+``sa.one_query_read``): rows of ``WALK_UNDER`` x topk = 288 tokens take the
+masked walk of whole pages behind the exact k-th score, rows a page longer
+the gather behind ``lax.top_k`` (on the CPU the walk is the
+decode kernel's twin, ``paged_attention_jax``; the kernel itself against the twin
+is ``tests/test_paged_attention.py``'s). ``READS`` holds both sides.
 """
 
 import dataclasses
@@ -64,6 +71,10 @@ def reference_logits(params, ids, model=MODEL):
 
 def prompt(n, seed):
     return [int(t) for t in np.random.default_rng(seed).integers(0, CFG.vocab_size, size=n)]
+
+
+# A one-query row's read -> the engine's ``max_seq_len`` that takes it (36 pages of 8 = 288 = 12 x 24, and a page more).
+READS = {"gather": sa.WALK_UNDER * TOPK + 8, "walk": sa.WALK_UNDER * TOPK}
 
 
 def engine(dtype="float32", quantize=None, model=PRESET, **kw):
@@ -147,16 +158,18 @@ def admit(eng, slot, ids, beside=None, chunk=36):
     return int(t[slot]), float(lp[slot]), stats
 
 
+@pytest.mark.parametrize("read", list(READS))
 @pytest.mark.parametrize("dtype,quantize", CASES)
-def test_chunked_prompt_then_decode_beside_other_rows_matches_the_reference(dtype, quantize):
+def test_chunked_prompt_then_decode_beside_other_rows_matches_the_reference(dtype, quantize, read):
     """Slot 0 takes a 30-token prompt and decodes; slot 1's 130-token prompt then
     goes in over four mixed steps (36, 36, 36, 22: a chunk of several tiles'
     worth it is not, a row of several queries past topk it is) with slot 0's
     decode row beside every chunk, one query at 31-34 tokens, past topk = 24;
     both rows then decode two chunks through the paged cache, slot 1 at 131
     and more. Every logprob of both rows is held to the reference."""
-    eng = engine(dtype, quantize)
+    eng = engine(dtype, quantize, max_seq_len=READS[read])
     assert eng.mixed_ok and eng.is_dsa and eng.mesh_axes == {"tp": 1}
+    assert eng.dsa_status()["one_query_row"]["read"] == read
     a, b = prompt(30, 3), prompt(130, 4)
     tok, lp, _ = admit(eng, 0, a)
     row_a = [tok, 30, [tok], [lp]]
@@ -167,16 +180,18 @@ def test_chunked_prompt_then_decode_beside_other_rows_matches_the_reference(dtyp
     assert logprob_gap(eng, b + [tok_b] + toks[1], 130, [lp_b] + lps[1]) < TOLERANCE[dtype]
 
 
+@pytest.mark.parametrize("read", list(READS))
 @pytest.mark.parametrize("topk,least", [(10 ** 6, 0.1), (TOPK // 2, 0.1), (TOPK - 1, 0.03)])
-def test_the_selection_matters(topk, least):
+def test_the_selection_matters(topk, least, read):
     """The control of the comparison above: the same served row against a
     reference that selects nothing (topk past every context), one that keeps
     half the set, and one that keeps one key fewer. Each differs from the
     served logprobs by thousands of times the float32 tolerance (measured on
     this prompt 0.152, 0.273 and 0.054 nats; over three prompts 0.11-0.22,
     0.14-0.27 and 0.04-0.17), and the first two by more than the bfloat16 one:
-    a path that read another set than the exact top-k would not pass."""
-    eng = engine()
+    a path that read another set than the exact top-k would not pass (either
+    read of a one-query row: the decode chunk's four tokens are its)."""
+    eng = engine(max_seq_len=READS[read])
     b = prompt(130, 4)
     tok, lp, _ = admit(eng, 1, b)
     toks, lps, _ = decode(eng, {1: (tok, 130)}, 1)
@@ -230,40 +245,73 @@ def test_equal_scores_go_to_the_lower_position():
     assert (got == want).all()
 
 
-def test_a_decode_rows_chosen_set_is_the_references():
-    """What ``sparse_decode`` gathers for a one-query row, through a page table
-    that scatters the tokens over the pool: with values that name their token
-    (v[s] = one-hot-ish code of s) the attention's output is a mixture over
-    exactly the reference's set."""
-    params = mixtral.init_params(jax.random.PRNGKey(36), CFG, dtype=jnp.float32)
-    n, ps = 100, 8
-    (qi, ki, wi), (pqi, pki, pwi), pos = layer0_indexer(params, prompt(n, 6))
-    want = np.asarray(REF.selection(qi[-1:], ki, wi[-1:], pos[-1:], TOPK))[0]
-    pages = np.random.default_rng(1).permutation(40)[:13]                  # 13 pages of 8 hold 100 tokens
+def _one_query_row(pki, pqi, pwi, n, table_pages, topk, ps=8):
+    """``sparse_decode`` of ONE row of ``n`` tokens whose indexer keys are
+    ``pki`` (n, 16), through a page table of ``table_pages`` that scatters the
+    tokens over a pool of 40 pages; equal keys (uniform weights) and values
+    that name their token: (mean of the chosen positions, of their squares)."""
+    pages = np.random.default_rng(1).permutation(40)[:table_pages]
     rows = (pages[:, None] * ps + np.arange(ps)[None, :]).reshape(-1)[:n]
     ki_pages = jnp.zeros((40, 16, ps), jnp.float32).at[rows // ps, :, rows % ps].set(pki)
-    flat_k = jnp.zeros((40 * ps, 2 * 16), jnp.float32)                     # equal keys: uniform weights
+    flat_k = jnp.zeros((40 * ps, 2 * 16), jnp.float32)
     flat_v = jnp.zeros((40 * ps, 2 * 16), jnp.float32).at[rows, 0].set(jnp.arange(n, dtype=jnp.float32))
     flat_v = flat_v.at[rows, 1].set(jnp.arange(n, dtype=jnp.float32) ** 2)
-    out = sa.sparse_decode(jnp.ones((1, 4, 16)), pqi[-1:], pwi[-1:], flat_k, flat_v, ki_pages,
-                           jnp.asarray(pages)[None, :], jnp.asarray([n]), topk=TOPK, num_kv_heads=2,
-                           page_size=ps)
+    out = sa.sparse_decode(jnp.ones((1, 4, 16)), pqi, pwi, flat_k, flat_v, ki_pages, jnp.asarray(pages)[None, :],
+                           jnp.asarray([n]), topk=topk, num_kv_heads=2, page_size=ps)
+    return float(out[0, 0, 0]), float(out[0, 0, 1])
+
+
+# A one-query row's read -> pages of 8 a row of the page table holds: 13 hold the
+# row's 100 tokens (104 <= 288: the walk), 37 are past WALK_UNDER x topk (296: the gather).
+TABLE_PAGES = {"walk": 13, "gather": sa.WALK_UNDER * TOPK // 8 + 1}
+
+
+@pytest.mark.parametrize("read", list(TABLE_PAGES))
+def test_a_one_query_rows_equal_scores_go_to_the_lower_position(read):
+    """The same through ``sparse_decode``, either read: an indexer of one live
+    head whose keys hold one of four values, so a row's scores are those values
+    and the cut (24 of 100) falls among equals."""
+    assert sa.one_query_read(TOPK, TABLE_PAGES[read] * 8) == read
+    values = np.random.default_rng(8).integers(0, 4, size=100).astype(np.float32)
+    pki = jnp.zeros((100, 16), jnp.float32).at[:, 0].set(values)
+    pqi = jnp.zeros((1, 4, 16), jnp.float32).at[0, 0, 0].set(1.0)
+    pwi = jnp.zeros((1, 4), jnp.float32).at[0, 0].set(1.0)
+    chosen = np.argsort(-values, kind="stable")[:TOPK]
+    assert values[chosen].min() == values[np.argsort(-values, kind="stable")[TOPK]]   # the cut is among equals
+    mean, mean_sq = _one_query_row(pki, pqi, pwi, 100, TABLE_PAGES[read], TOPK)
+    np.testing.assert_allclose(mean, chosen.mean(), rtol=1e-5)
+    np.testing.assert_allclose(mean_sq, (chosen ** 2).mean(), rtol=1e-5)
+
+
+@pytest.mark.parametrize("read", list(TABLE_PAGES))
+def test_a_decode_rows_chosen_set_is_the_references(read):
+    """What ``sparse_decode`` reads for a one-query row, through a page table
+    that scatters the tokens over the pool, by the gather and by the masked
+    walk: with values that name their token (v[s] = one-hot-ish code of s) the
+    attention's output is a mixture over exactly the reference's set."""
+    assert sa.one_query_read(TOPK, TABLE_PAGES[read] * 8) == read
+    params = mixtral.init_params(jax.random.PRNGKey(36), CFG, dtype=jnp.float32)
+    n = 100
+    (qi, ki, wi), (pqi, pki, pwi), pos = layer0_indexer(params, prompt(n, 6))
+    want = np.asarray(REF.selection(qi[-1:], ki, wi[-1:], pos[-1:], TOPK))[0]
+    mean, mean_sq = _one_query_row(pki, pqi[-1:], pwi[-1:], n, TABLE_PAGES[read], TOPK)
     chosen = np.nonzero(want)[0]
     assert len(chosen) == TOPK
-    np.testing.assert_allclose(float(out[0, 0, 0]), chosen.mean(), rtol=1e-5)
-    np.testing.assert_allclose(float(out[0, 0, 1]), (chosen ** 2).mean(), rtol=1e-5)
+    np.testing.assert_allclose(mean, chosen.mean(), rtol=1e-5)
+    np.testing.assert_allclose(mean_sq, (chosen ** 2).mean(), rtol=1e-5)
 
 
-def test_contexts_at_or_under_topk_are_the_skeletons_plain_attention():
+@pytest.mark.parametrize("read", list(READS))
+def test_contexts_at_or_under_topk_are_the_skeletons_plain_attention(read):
     """A row that holds topk tokens or fewer keeps every key, by the same path
     as any other (there is no plain-attention branch): the served logprobs equal,
     to float32's summation order (measured 5e-7), those of the same tree served
     with no indexer, the skeleton's own attention, through a mixed step with a
     decode row beside a prompt and a decode chunk that ends at exactly topk
     tokens, and the tokens are the same. One token more and the two part."""
-    with_indexer = engine()
+    with_indexer = engine(max_seq_len=READS[read])
     without = Engine(EngineConfig(model=PRESET, attention="paged", dtype="float32", page_size=8, max_slots=4,
-                                  max_seq_len=256, mixed_step=True, mixed_step_tokens=40, decode_chunk=4,
+                                  max_seq_len=READS[read], mixed_step=True, mixed_step_tokens=40, decode_chunk=4,
                                   use_mesh=False),
                      params=with_indexer.params, model_cfg=dataclasses.replace(CFG, indexer_topk=0))
     assert not without.is_dsa and set(without.cache) == {"k", "v"} and set(with_indexer.cache) == {"k", "v", "ki"}
@@ -328,24 +376,38 @@ def test_a_prefix_hit_adopts_the_indexers_pages():
     assert np.abs(ki[:, pages]).sum() > 0
 
 
-def test_the_counters_are_the_counts_by_hand():
+@pytest.mark.parametrize("read", list(READS))
+def test_the_counters_are_the_counts_by_hand(read):
     """``dsa_context_tokens``: what the rows' queries could see, summed over
     queries and layers. ``dsa_kv_tokens_read``: a one-query row reads topk
-    tokens and a prompt chunk's query every block of keys up to its tile's last
-    position, whatever the row holds."""
-    eng = engine()
+    tokens on the gather and the pages it holds on the walk, in the decode
+    chunk and beside a chunk in a mixed step, and a prompt chunk's query every
+    block of keys up to its tile's last position, whatever the row holds. The
+    status names the read and what chose it."""
+    eng = engine(max_seq_len=READS[read])
     L = CFG.num_layers
     _, _, stats = admit(eng, 0, prompt(20, 15))
-    block = sa.block_tokens(8, 256)
+    block = sa.block_tokens(8, READS[read])
     assert (stats[0]["dsa_context_tokens"], stats[0]["dsa_kv_tokens_read"]) == (L * sum(range(1, 21)), L * 20 * block)
     tok, _, stats = admit(eng, 1, prompt(70, 16))
     assert [s["dsa_context_tokens"] for s in stats] == [L * sum(range(1, 37)), L * sum(range(37, 71))]
     assert [s["dsa_kv_tokens_read"] for s in stats] == [L * 36 * block, L * 34 * block]
     _, _, stats = decode(eng, {1: (tok, 70)}, 1)
     assert stats[0]["dsa_context_tokens"] == L * sum(range(71, 75))
-    assert stats[0]["dsa_kv_tokens_read"] == L * 4 * TOPK and stats[0]["experts_read"] == 2.0
+    pages_held = sum(-(-ctx // 8) * 8 for ctx in range(71, 75))   # 72 + 72 + 80 + 80
+    assert stats[0]["dsa_kv_tokens_read"] == L * {"gather": 4 * TOPK, "walk": pages_held}[read]
+    assert stats[0]["experts_read"] == 2.0
+    # a mixed step: slot 1's one-query row at 75 tokens beside a 10-token chunk
+    row = [int(prompt(1, 17)[0]), 74, [], []]
+    _, _, stats = admit(eng, 2, prompt(10, 18), beside={1: row})
+    assert stats[0]["dsa_context_tokens"] == L * (75 + sum(range(1, 11)))
+    assert stats[0]["dsa_kv_tokens_read"] == L * ({"gather": TOPK, "walk": 80}[read] + 10 * block)
     status = eng.dsa_status()
     assert (status["topk"], status["indexer_heads"], status["indexer_head_dim"]) == (TOPK, 4, 16)
+    assert status["one_query_row"] == {"read": read, "row_tokens": READS[read], "walk_under": sa.WALK_UNDER,
+                                       "walk_up_to_tokens": sa.WALK_UNDER * TOPK}
+    assert ("whole pages under the mask" in status["read"]["one_query_row"]) == (read == "walk")
+    assert ("k-th largest" in status["select"]["one_query_row"]) == (read == "walk")
 
 
 @pytest.mark.parametrize("kw,what", [

@@ -105,6 +105,92 @@ def test_decode_kernel_walk_matches_reference(case):
     assert not np.asarray(out, np.float32)[np.asarray(lengths) == 0].any()  # exactly zero
 
 
+# The decode kernel under a mask (ISSUE 45: a learned sparse attention's chosen
+# set as a mask over the row; every page the slot holds is still walked whole).
+# name -> (lengths, what the mask keeps of a row's positions, options).
+def _keep(share, seed=0):
+    return lambda B, S: np.random.default_rng(seed).random((B, S)) < share
+
+
+def _but_pages(*pages, share=0.4):
+    """``share`` of the positions, and nothing of ``pages``."""
+    def mask(B, S):
+        m = _keep(share, 1)(B, S)
+        for p in pages:
+            m[:, p * PS:(p + 1) * PS] = False
+        return m
+    return mask
+
+
+MASKED_CASES = {
+    "ragged_lengths_with_an_empty_slot": ([0, 37, 100, 5], _keep(0.4), {}),
+    "empty_slots_in_the_middle": ([128, 0, 0, 61], _keep(0.4), {}),
+    "a_page_with_nothing_chosen": ([100, 37, 0, 128], _but_pages(1, 4), {}),
+    # one page a visit: the first VISIT attends nothing, and the next rescales it away
+    "a_first_visit_with_nothing_chosen": ([100, 37, 0, 128], _but_pages(0), {"pages_per_visit": 1}),
+    "a_visits_last_page_partly_live": ([N_TEST * PS + 5, 2 * N_TEST * PS - 7, 0, 2 * PS + 1], _keep(0.5), {}),
+    "one_position_chosen": ([100, 37, 9, 128], lambda B, S: np.arange(S)[None, :] == np.asarray([[63], [5], [8], [127]]), {}),
+    "a_row_at_or_under_k": ([100, 37, 0, 128], lambda B, S: np.ones((B, S), bool), {"unmasked": True}),
+    "bf16_pool": ([37, 0, 128, 100], _keep(0.4), {"dtype": jnp.bfloat16, "atol": 0.0156}),
+    "bf16_pool_a_page_with_nothing_chosen": ([100, 37, 0, 128], _but_pages(0, 5), {"dtype": jnp.bfloat16, "atol": 0.0156}),
+    "under_a_window": ([120, 70, 0, 9], _keep(0.6), {"window": 50}),
+    "folded_axis_off_the_lane_grid": ([37, 0, 128, 100], _keep(0.4), {"hkv": 3}),
+}
+
+
+@pytest.mark.parametrize("case", list(MASKED_CASES))
+def test_decode_kernel_walk_under_a_mask_matches_reference(case):
+    lengths, keep, opt = MASKED_CASES[case]
+    rng = np.random.default_rng(len(case))
+    B, Hkv, G, D = len(lengths), opt.get("hkv", 2), 3, 64
+    dtype = opt.get("dtype", jnp.float32)
+    P = B * MP
+    q = jnp.asarray(rng.normal(size=(B, Hkv * G, D)), dtype)
+    k = jnp.asarray(rng.normal(size=(P, PS, Hkv * D)), dtype)
+    v = jnp.asarray(rng.normal(size=(P, PS, Hkv * D)), dtype)
+    pt = jnp.asarray(rng.permutation(P).reshape(B, MP).astype(np.int32))
+    lens = jnp.asarray(lengths, jnp.int32)
+    mask = np.asarray(keep(B, MP * PS))
+    for b, n in enumerate(lengths):  # every live row attends something (as a top-k's mask does)
+        window = opt.get("window") or n
+        if n and not mask[b, max(n - window, 0):n].any():
+            mask[b, n - 1] = True
+    mask = jnp.asarray(mask)
+
+    kw = {"window": opt.get("window"), "pages_per_visit": opt.get("pages_per_visit", N_TEST)}
+    ref = paged_attention_jax(q, k, v, pt, lens, Hkv, window=kw["window"], mask=mask)
+    out = paged_attention_tpu(q, k, v, pt, lens, Hkv, interpret=True, mask=mask, name="masked_walk", **kw)
+    atol = opt.get("atol", 1e-5)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref, np.float32), rtol=atol, atol=atol)
+    assert not np.asarray(out, np.float32)[np.asarray(lengths) == 0].any()
+    plain = paged_attention_tpu(q, k, v, pt, lens, Hkv, interpret=True, **kw)
+    if opt.get("unmasked"):  # a mask that keeps everything: the unmasked call's sums, bit for bit
+        assert (np.asarray(out) == np.asarray(plain)).all()
+    else:  # and a mask that does not shows
+        assert np.abs(np.asarray(out, np.float32) - np.asarray(plain, np.float32)).max() > 0.1
+
+
+@pytest.mark.parametrize("how,equations", [("no_window", 130), ("window", 141), ("window_operand", 144), ("mask", 138)])
+def test_without_a_mask_the_decode_kernel_traces_what_it_traced(how, equations):
+    """The mask is an OPTIONAL operand: without one neither it, its block nor
+    the and is traced, and the launch is the parent's equation for equation
+    (130 / 141 / 144 on the tree before ISSUE 45, at this layout); with one,
+    eight more."""
+    from test_chip_compile import _equations  # the count the start-up ceilings there use
+
+    sds = jax.ShapeDtypeStruct
+    B, Hkv, D = 4, 2, 64
+    pool = sds((B * MP, PS, Hkv * D), jnp.float32)
+    args = [sds((B, 3 * Hkv, D), jnp.float32), pool, pool, sds((B, MP), jnp.int32), sds((B,), jnp.int32)]
+    kw = {"window": 50} if how == "window" else {}
+    extra = {"window_operand": ("window_dyn", sds((), jnp.int32)), "mask": ("mask", sds((B, MP * PS), jnp.bool_))}.get(how)
+    fn = lambda *a: paged_attention_tpu(*a[:5], Hkv, interpret=True, pages_per_visit=N_TEST, **kw,
+                                        **({extra[0]: a[5]} if extra else {}))
+    traced = jax.make_jaxpr(fn)(*args, *([extra[1]] if extra else []))
+    assert _equations(traced.jaxpr) == equations
+    assert (f"i32[{B},{(MP + N_TEST) * PS}]" in str(traced)) == (how == "mask")  # the padded mask, an operand
+
+
 @pytest.mark.parametrize("dtype,visits", [(jnp.float32, 3), (jnp.bfloat16, 2)], ids=["float32", "bfloat16"])
 def test_decode_kernel_at_the_layouts_own_pages_per_visit(dtype, visits):
     """Pages of 128 tokens x 4 KV heads of 128, as the chip serves them: N is
