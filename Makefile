@@ -1,6 +1,6 @@
 # Developer entry points (reference parity: Taskfile.yml).
 
-.PHONY: generate check test test-fast chip-smoke bench-gateway serve gateway lint graftlint typecheck
+.PHONY: generate check test test-fast chip-smoke serve gateway lint graftlint typecheck
 
 generate:  ## regenerate docs/env examples from openapi.yaml + drift check
 	python -m inference_gateway_tpu.codegen
@@ -42,9 +42,6 @@ test-fast: ## gateway/protocol tier only (~2 min) — no engine builds
 
 chip-smoke:  ## the main path on one TPU chip (fails without one)
 	python chip_smoke.py
-
-bench-gateway:  ## CPU gateway micro-benchmarks
-	python benchmarks/gateway_bench.py
 
 serve:     ## run the TPU sidecar (random weights unless --checkpoint/model path)
 	python -m inference_gateway_tpu.serving --model tinyllama-1.1b --port 8000

@@ -13,7 +13,6 @@ Allowlisted modules (the designated real-time sites):
 - ``otel/profiling.py``    — sampling-profiler / stall-watchdog daemon
   threads measure real wall time by definition
 - ``logger.py``            — the log-flush daemon thread
-- ``utils/benchtime.py``   — benchmark timing helpers
 
 Not banned: ``time.time_ns()`` (epoch span/phase stamps — wire formats
 need wall-clock epochs) and ``time.perf_counter()`` (profiling stamps).
@@ -39,7 +38,6 @@ ALLOWLIST = (
     "inference_gateway_tpu/resilience/clock.py",
     "inference_gateway_tpu/otel/profiling.py",
     "inference_gateway_tpu/logger.py",
-    "inference_gateway_tpu/utils/benchtime.py",
 )
 
 

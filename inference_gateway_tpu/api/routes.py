@@ -833,9 +833,8 @@ class RouterImpl:
     async def metrics_ingestion_handler(self, req: Request) -> Response:
         """POST /v1/metrics — OTLP push ingest, JSON encoding, gzip-aware
         (api/metrics.go:25-99). Besides the gen_ai.* histograms, accepts
-        the sidecar's last-value gauges: engine.mfu / engine.goodput_mfu
-        / engine.hbm_bandwidth_util (ISSUE 6) and the device
-        observatory's engine.hbm.{live,peak,plan}_bytes (ISSUE 19)."""
+        the sidecar's last-value gauges: the device observatory's
+        engine.hbm.{live,peak,plan}_bytes (ISSUE 19)."""
         if self.otel is None:
             return error_json("metrics push endpoint is not enabled", 403)
         body = req.body

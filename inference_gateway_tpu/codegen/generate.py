@@ -73,21 +73,14 @@ _TELEMETRY_OBSERVABILITY_DOC = [
     "(collapsed stacks → flamegraph.pl/speedscope, slow-request schema):",
     "[docs/observability.md](docs/observability.md).",
     "",
-    "### Compute efficiency",
+    "### Wasted work",
     "",
-    "`TELEMETRY_ACCOUNTING_*` (on by default) prices every engine step",
-    "against the chip's analytic roofline, computed from nothing but the",
-    "model config and the chip datasheet: live `engine.mfu`,",
-    "`engine.goodput_mfu` (useful tokens only), and",
-    "`engine.hbm_bandwidth_util` gauges over a rolling window,",
-    "per-step-kind `engine.step_roofline_ratio{kind}` gap factors, and",
-    "`engine.wasted_tokens{reason}` attribution (speculation rejections,",
-    "chunk overrun, disconnected clients, shed-after-prefill). The",
-    "sidecar's `GET /debug/roofline` aggregates measured-vs-analytic per",
-    "step kind (p50/p99 step ms, achieved TFLOP/s and GB/s, compute- vs",
-    "bandwidth-bound verdict); off-TPU the report is framed",
-    "`measured: false` so host wall clock is never mistaken for kernel",
-    "time. Schema and reading guide:",
+    "`engine.wasted_tokens{reason}` counts tokens the engine computed that",
+    "no client saw (speculation rejections, chunk overrun, overshoot,",
+    "disconnected clients, shed-after-prefill); the sidecar's",
+    "`/debug/status` shows the same counts as `wasted_tokens`. Where the",
+    "time goes on a chip is measured by `bench/` (see `PERF.md`), not",
+    "estimated by the server. Schema and reading guide:",
     "[docs/observability.md](docs/observability.md).",
     "",
 ]
@@ -145,8 +138,8 @@ _SERVING_DESYNC_DOC = [
     "write indices are computed on device from a pre-reserved page horizon).",
     "`SERVING_DECODE_PIPELINE_DEPTH` chunks stay in flight so the device",
     "never waits on the host between chunks; the `engine.host_gap_ms`",
-    "histogram and `/debug/roofline` host-gap percentiles measure exactly",
-    "that. Greedy and seeded streams are byte-identical at any chunk size;",
+    "histogram and each `/debug/timeline` record's `host_gap_ms` measure",
+    "exactly that. Greedy and seeded streams are byte-identical at any chunk size;",
     "stop *strings* remain a host-side backstop that truncates after the",
     "fact. Design: [docs/performance.md](docs/performance.md).",
     "",
@@ -478,11 +471,7 @@ def check_config_defaults(spec: dict) -> list[str]:
         "TELEMETRY_SLOW_REQUEST_TPOT": cfg.telemetry.slow_request_tpot,
         "TELEMETRY_SLOW_REQUEST_TOTAL": cfg.telemetry.slow_request_total,
         "TELEMETRY_SLOW_REQUEST_LOG_SIZE": cfg.telemetry.slow_request_log_size,
-        "TELEMETRY_ACCOUNTING_ENABLE": cfg.telemetry.accounting_enable,
-        "TELEMETRY_ACCOUNTING_WINDOW": cfg.telemetry.accounting_window,
-        "TELEMETRY_ACCOUNTING_CHIP": cfg.telemetry.accounting_chip,
         "TELEMETRY_DEVICE_ENABLE": cfg.telemetry.device_enable,
-        "TELEMETRY_DEVICE_COST_ANALYSIS": cfg.telemetry.device_cost_analysis,
         "TELEMETRY_DEVICE_LEDGER_SIZE": cfg.telemetry.device_ledger_size,
         "TELEMETRY_JOURNEY_ENABLE": cfg.telemetry.journey_enable,
         "TELEMETRY_JOURNEY_SLOTS": cfg.telemetry.journey_slots,

@@ -57,10 +57,7 @@ class TelemetryConfig:
     """TELEMETRY_* (config.go:46-52), plus the performance-introspection
     surface (ISSUE 4): TELEMETRY_PROFILING_* (sampling profiler,
     event-loop watchdog, decode-step timeline) and
-    TELEMETRY_SLOW_REQUEST_* (forensics thresholds; 0 disables a check),
-    plus compute-efficiency accounting (ISSUE 6):
-    TELEMETRY_ACCOUNTING_* (live MFU / roofline pricing of every engine
-    step; on by default, zero-overhead when off).
+    TELEMETRY_SLOW_REQUEST_* (forensics thresholds; 0 disables a check).
     """
 
     enable: bool = False
@@ -84,9 +81,6 @@ class TelemetryConfig:
     slow_request_tpot: float = 0.0
     slow_request_total: float = 0.0
     slow_request_log_size: int = 64
-    accounting_enable: bool = True
-    accounting_window: float = 10.0
-    accounting_chip: str = ""
     # Stream journeys (ISSUE 18): per-worker lifecycle rings published
     # into the cluster segment. On by default — the <5% p99 overhead
     # gate (bench_fleet_observability_overhead) is the contract.
@@ -96,10 +90,8 @@ class TelemetryConfig:
     journey_events: int = 32
     # Device observatory (ISSUE 19): compile/recompile ledger, live HBM
     # accounting, and the h2d/d2h transfer audit. On by default — off
-    # removes the jit wrappers entirely (zero overhead), cost_analysis
-    # gates the per-compile XLA lowering pass only.
+    # removes the jit wrappers entirely (zero overhead).
     device_enable: bool = True
-    device_cost_analysis: bool = True
     device_ledger_size: int = 256
 
     @classmethod
@@ -126,15 +118,11 @@ class TelemetryConfig:
             slow_request_tpot=_get_duration(env, prefix + "SLOW_REQUEST_TPOT", "0s"),
             slow_request_total=_get_duration(env, prefix + "SLOW_REQUEST_TOTAL", "0s"),
             slow_request_log_size=_get_int(env, prefix + "SLOW_REQUEST_LOG_SIZE", 64),
-            accounting_enable=_get_bool(env, prefix + "ACCOUNTING_ENABLE", True),
-            accounting_window=_get_duration(env, prefix + "ACCOUNTING_WINDOW", "10s"),
-            accounting_chip=_get_str(env, prefix + "ACCOUNTING_CHIP", ""),
             journey_enable=_get_bool(env, prefix + "JOURNEY_ENABLE", True),
             journey_slots=_get_int(env, prefix + "JOURNEY_SLOTS", 64),
             journey_slot_bytes=_get_int(env, prefix + "JOURNEY_SLOT_BYTES", 4096),
             journey_events=_get_int(env, prefix + "JOURNEY_EVENTS", 32),
             device_enable=_get_bool(env, prefix + "DEVICE_ENABLE", True),
-            device_cost_analysis=_get_bool(env, prefix + "DEVICE_COST_ANALYSIS", True),
             device_ledger_size=_get_int(env, prefix + "DEVICE_LEDGER_SIZE", 256),
         )
 

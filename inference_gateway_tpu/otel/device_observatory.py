@@ -1,27 +1,23 @@
 """Engine device observatory (ISSUE 19 tentpole).
 
 The observability stack built so far sees everything *except* the
-device: rooflines are analytic (ISSUE 6), the zero-h2d steady state is
+device: the zero-h2d steady state is
 proven only by tests (ISSUE 14), and a silent steady-state recompile —
 the classic TPU throughput killer — is invisible until someone reads a
 profile. This module makes the device boundaries first-class
 production telemetry:
 
 - **CompileLedger** — wraps every jitted engine entry point and records
-  each compilation: program name, static shape signature, compile
-  wall-ms, and the XLA ``cost_analysis()`` FLOPs / bytes-accessed for
-  the lowered program. Any compile *after* warmup completes is a
+  each compilation: program name, static shape signature and compile
+  wall-ms (a compile is seen, never repeated: nothing here lowers a
+  program again). Any compile *after* warmup completes is a
   **steady-state recompile**: it increments ``engine.recompiles`` and
   emits a wide event carrying the shape-signature diff that triggered
   it.
-- **XLA-grounded rooflines** — the per-kind cost-analysis numbers feed
-  ``/debug/roofline`` next to the StepCostModel analytics with an
-  ``analytic_vs_xla`` gap factor, so the analytic model is audited by
-  compiler truth even off-TPU.
 - **Live HBM accounting** — ``device.memory_stats()`` (bytes-in-use /
   peak) against the analytic plan (weights + KV pool) plus the KV
   page-pool high-water mark. Framed ``measured: false`` off-TPU —
-  never fabricated (same honesty contract as PerfAccounting).
+  never fabricated.
 - **Transfer audit** — lightweight h2d/d2h counting on the engine's
   submit/fetch seams as ``engine.transfers{direction,path}``. The PR 14
   invariant becomes a live production metric: chained early-exit
@@ -40,7 +36,7 @@ documented non-issue in production and an accepted caveat in tests.
 Everything here is optional and None-gated on the engine hot path: with
 ``TELEMETRY_DEVICE_ENABLE=false`` no wrapper is installed and every
 seam pays one ``is None`` check — the same zero-overhead-off discipline
-as the step timeline and accounting.
+as the step timeline.
 """
 
 from __future__ import annotations
@@ -55,7 +51,6 @@ __all__ = [
     "CompileLedger",
     "TransferAudit",
     "DeviceObservatory",
-    "program_kind",
 ]
 
 # Every jitted Engine entry point the ledger wraps (instance-attribute
@@ -77,29 +72,6 @@ JIT_ENTRY_POINTS: tuple[str, ...] = (
     "_spec_verify_ngram_fn",
     "_mark_done_fn",
 )
-
-# program name -> StepCostModel kind, for the analytic_vs_xla roofline
-# pane. Admission scatters and the done-mark have no analytic
-# counterpart; they group under "admit" and are excluded from the gap.
-_KIND_PREFIXES: tuple[tuple[str, str], ...] = (
-    ("prefill", "prefill"),
-    ("decode", "decode"),
-    ("mixed_step", "mixed"),
-    ("spec_verify_ngram", "spec_ngram"),
-    ("spec_round", "spec"),
-    ("draft_prefill", "spec"),
-    ("admit_scatter", "admit"),
-    ("mark_done", "admit"),
-    ("fresh_unpack", "admit"),
-)
-
-
-def program_kind(program: str) -> str:
-    for prefix, kind in _KIND_PREFIXES:
-        if program.startswith(prefix):
-            return kind
-    return "other"
-
 
 def _describe(x: Any) -> str:
     """One argument's contribution to a static shape signature.
@@ -144,15 +116,14 @@ class CompileLedger:
     points (prefill and decode run on different scheduler phases), and
     ``/debug/compile`` snapshots from the serving thread."""
 
-    def __init__(self, *, size: int = 256, cost_analysis: bool = True,
-                 otel: Any = None, model: str = "", logger: Any = None,
+    def __init__(self, *, size: int = 256, otel: Any = None,
+                 model: str = "", logger: Any = None,
                  now_fn: Callable[[], float] | None = None) -> None:
         self._lock = threading.Lock()
         self._records: deque[dict[str, Any]] = deque(maxlen=max(size, 1))
         self._recompile_events: deque[dict[str, Any]] = deque(maxlen=32)
         self._last_signature: dict[str, tuple[str, ...]] = {}
         self._fallback_seen: dict[str, set[tuple[str, ...]]] = {}
-        self.cost_analysis = cost_analysis
         self.otel = otel
         self.model = model
         self.logger = logger
@@ -181,13 +152,13 @@ class CompileLedger:
             wall_ms = (self._now() - t0) * 1e3
             if cache_size is not None:
                 if cache_size() != before:
-                    self._on_compile(program, fn, args, kwargs, wall_ms)
+                    self._on_compile(program, args, kwargs, wall_ms)
             else:
                 sig = _signature(args, kwargs)
                 seen = self._fallback_seen.setdefault(program, set())
                 if sig not in seen:
                     seen.add(sig)
-                    self._on_compile(program, fn, args, kwargs, wall_ms)
+                    self._on_compile(program, args, kwargs, wall_ms)
             return out
 
         wrapper.__name__ = f"observed_{program}"  # aid stack traces
@@ -196,40 +167,9 @@ class CompileLedger:
         setattr(wrapper, "_ledger_inner", fn)
         return wrapper
 
-    def _xla_cost(self, fn: Any, args: tuple[Any, ...],
-                  kwargs: dict[str, Any]) -> tuple[float | None, float | None]:
-        """FLOPs / bytes-accessed from the compiler's own cost model.
-
-        Uses ``Lowered.cost_analysis()`` (the dict form; the post-compile
-        ``Compiled`` variant returns a per-device *list* on this jax).
-        Lowering re-traces from avals only — donated (deleted) buffers
-        still carry shape/dtype, so this is safe after the call — but
-        any failure degrades to None, never to a serving error."""
-        if not self.cost_analysis:
-            return None, None
-        try:
-            # Engine entry points are bound methods over a PjitFunction
-            # with static self: __call__ injects the instance, but
-            # .lower resolves to the underlying jit object and needs
-            # self passed explicitly (it IS the first static argument).
-            bound_self = getattr(fn, "__self__", None)
-            if bound_self is not None:
-                lowered = fn.lower(bound_self, *args, **kwargs)
-            else:
-                lowered = fn.lower(*args, **kwargs)
-            cost = lowered.cost_analysis()
-            if isinstance(cost, (list, tuple)):  # per-device form
-                cost = cost[0] if cost else {}
-            flops = float(cost["flops"]) if "flops" in cost else None
-            nbytes = float(cost["bytes accessed"]) if "bytes accessed" in cost else None
-            return flops, nbytes
-        except Exception:
-            return None, None
-
-    def _on_compile(self, program: str, fn: Any, args: tuple[Any, ...],
+    def _on_compile(self, program: str, args: tuple[Any, ...],
                     kwargs: dict[str, Any], wall_ms: float) -> None:
         sig = _signature(args, kwargs)
-        flops, nbytes = self._xla_cost(fn, args, kwargs)
         with self._lock:
             self.compiles += 1
             recompile = self.warmed
@@ -237,11 +177,8 @@ class CompileLedger:
             self._last_signature[program] = sig
             record: dict[str, Any] = {
                 "program": program,
-                "kind": program_kind(program),
                 "signature": ", ".join(sig),
                 "compile_ms": round(wall_ms, 3),
-                "flops": flops,
-                "bytes_accessed": nbytes,
                 "recompile": recompile,
             }
             self._records.append(record)
@@ -309,26 +246,6 @@ class CompileLedger:
             events = list(self._recompile_events)
         return events[-n:] if n > 0 else []
 
-    def per_kind_xla(self) -> dict[str, dict[str, Any]]:
-        """Largest cost-analysis numbers per step kind, for the roofline
-        pane. Max-FLOPs wins within a kind: the full-size program (the
-        default decode chunk, the serving prefill bucket) is the one the
-        analytic model prices, not warmup's n_steps=1 probe."""
-        with self._lock:
-            records = list(self._records)
-        out: dict[str, dict[str, Any]] = {}
-        for rec in records:
-            if rec.get("flops") is None:
-                continue
-            kind = rec["kind"]
-            cur = out.get(kind)
-            if cur is None or rec["flops"] > cur["flops"]:
-                out[kind] = {"program": rec["program"],
-                             "flops": rec["flops"],
-                             "bytes_accessed": rec["bytes_accessed"],
-                             "signature": rec["signature"]}
-        return out
-
 
 class TransferAudit:
     """h2d/d2h transfer counters keyed by (direction, path).
@@ -395,13 +312,11 @@ class DeviceObservatory:
 
     def __init__(self, *, otel: Any = None, model: str = "",
                  logger: Any = None, ledger_size: int = 256,
-                 cost_analysis: bool = True,
                  now_fn: Callable[[], float] | None = None) -> None:
         self.otel = otel
         self.model = model
-        self.ledger = CompileLedger(size=ledger_size,
-                                    cost_analysis=cost_analysis,
-                                    otel=otel, model=model, logger=logger,
+        self.ledger = CompileLedger(size=ledger_size, otel=otel,
+                                    model=model, logger=logger,
                                     now_fn=now_fn)
         self.transfers = TransferAudit(otel=otel, model=model)
         self._engine: Any = None

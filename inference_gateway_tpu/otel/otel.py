@@ -49,10 +49,10 @@ SCHEMA_COMPILE_BOUNDARIES = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
 # milliseconds; a 32-layer step program takes tens of seconds on a chip.
 COMPILE_DURATION_BOUNDARIES = (0.01, 0.05, 0.1, 0.5, 1.0, 2.5, 5.0, 10.0,
                                30.0, 60.0, 120.0, 300.0)
-# Compute-efficiency gauges (ISSUE 6) refresh only while the engine
-# steps; a TTL lets an idle engine's window values age out of the
-# exposition instead of freezing at the last busy reading. Must exceed
-# the sidecar OTLP push interval (15s default) with margin.
+# Gauges that refresh only while their source is busy carry a TTL, so an
+# idle source's last reading ages out of the exposition instead of
+# freezing there. Must exceed the sidecar OTLP push interval (15s
+# default) with margin.
 EFFICIENCY_GAUGE_TTL = 60.0
 
 _BASE_LABELS = ("source", "team", "gen_ai_operation_name", "gen_ai_provider_name", "gen_ai_request_model")
@@ -247,36 +247,8 @@ class OpenTelemetry:
             "Requests breaching the configured TTFT/TPOT/total latency thresholds",
             ("source", "breach"), unit="{request}",
         )
-        # Compute-efficiency accounting (ISSUE 6): live MFU and HBM
-        # bandwidth utilization over the accounting window, per-kind
-        # gap-to-roofline, and wasted-work attribution — the observables
-        # the ROADMAP items 1-2 kernel work is judged against. The
-        # window gauges carry ``source`` (like the pushed histograms) so
-        # a standalone sidecar's OTLP push lands in its own series
-        # instead of clobbering a co-hosted engine's, and a TTL so an
-        # idle engine's last busy-window value ages out of /metrics
-        # instead of freezing there (refresh only happens on engine
-        # steps).
-        self.engine_mfu_gauge = r.gauge(
-            "engine.mfu",
-            "Model FLOPs utilization over the accounting window (0..1)",
-            ("gen_ai_request_model", "source"), ttl=EFFICIENCY_GAUGE_TTL,
-        )
-        self.engine_goodput_mfu_gauge = r.gauge(
-            "engine.goodput_mfu",
-            "MFU counting only useful (delivered, non-wasted) tokens (0..1)",
-            ("gen_ai_request_model", "source"), ttl=EFFICIENCY_GAUGE_TTL,
-        )
-        self.engine_hbm_util_gauge = r.gauge(
-            "engine.hbm_bandwidth_util",
-            "HBM bandwidth utilization over the accounting window (0..1)",
-            ("gen_ai_request_model", "source"), ttl=EFFICIENCY_GAUGE_TTL,
-        )
-        self.engine_roofline_ratio_gauge = r.gauge(
-            "engine.step_roofline_ratio",
-            "Measured step time / analytic roofline time per step kind",
-            ("gen_ai_request_model", "kind"), ttl=EFFICIENCY_GAUGE_TTL,
-        )
+        # Wasted-work attribution (ISSUE 6): what the scheduler counted,
+        # handed over by the sidecar.
         self.wasted_tokens_counter = r.counter(
             "engine.wasted_tokens",
             "Tokens computed but never delivered, by reason "
@@ -672,23 +644,7 @@ class OpenTelemetry:
     def record_slow_request(self, source: str, breach: str) -> None:
         self.slow_request_counter.add(1, {"source": source, "breach": breach})
 
-    # -- compute-efficiency accounting (ISSUE 6) -------------------------
-    def set_compute_efficiency(self, model: str, *, mfu: float | None = None,
-                               hbm_bandwidth_util: float | None = None,
-                               goodput_mfu: float | None = None,
-                               source: str = "tpu-sidecar") -> None:
-        labels = {"gen_ai_request_model": model, "source": source}
-        if mfu is not None:
-            self.engine_mfu_gauge.set(mfu, labels)
-        if hbm_bandwidth_util is not None:
-            self.engine_hbm_util_gauge.set(hbm_bandwidth_util, labels)
-        if goodput_mfu is not None:
-            self.engine_goodput_mfu_gauge.set(goodput_mfu, labels)
-
-    def set_step_roofline_ratio(self, model: str, kind: str, ratio: float) -> None:
-        self.engine_roofline_ratio_gauge.set(
-            ratio, {"gen_ai_request_model": model, "kind": kind})
-
+    # -- wasted-work attribution (ISSUE 6) -------------------------------
     def record_wasted_tokens(self, model: str, reason: str, tokens: int = 1) -> None:
         self.wasted_tokens_counter.add(
             tokens, {"gen_ai_request_model": model, "reason": reason})
@@ -771,16 +727,6 @@ class OpenTelemetry:
             self.schema_compile_duration.record(
                 seconds, {"gen_ai_request_model": model})
 
-    def remove_efficiency_gauges(self, model: str) -> None:
-        """Engine teardown: the accounting gauges describe a gone engine
-        — drop every label set naming the model, whatever source wrote
-        it (ISSUE 4 semantics, same as the saturation gauges)."""
-        for gauge in (self.engine_mfu_gauge, self.engine_goodput_mfu_gauge,
-                      self.engine_hbm_util_gauge, self.engine_roofline_ratio_gauge):
-            for key in list(gauge.values()):
-                if key and key[0] == model:
-                    gauge.remove(dict(zip(gauge.label_names, key)))
-
     # -- device observatory (ISSUE 19) -----------------------------------
     def record_compile(self, model: str, program: str, seconds: float,
                        recompile: bool = False) -> None:
@@ -857,12 +803,9 @@ class OpenTelemetry:
             "gen_ai.server.output_tokens_per_second": self.output_tokens_per_second,
         }
 
-        # Gauges pushed by a standalone sidecar's accounting snapshot
-        # (ISSUE 6): last-value semantics, so ingest is a plain set.
+        # Gauges pushed by a standalone sidecar: last-value semantics, so
+        # ingest is a plain set.
         name_to_gauge = {
-            "engine.mfu": self.engine_mfu_gauge,
-            "engine.goodput_mfu": self.engine_goodput_mfu_gauge,
-            "engine.hbm_bandwidth_util": self.engine_hbm_util_gauge,
             # Device observatory (ISSUE 19): a standalone sidecar pushes
             # its HBM accounting so the gateway-side exposition carries
             # every worker's device story. Note the live/peak series only
@@ -1072,16 +1015,7 @@ class NoopTelemetry(OpenTelemetry):
     def record_slow_request(self, *a, **k) -> None:
         pass
 
-    def set_compute_efficiency(self, *a, **k) -> None:
-        pass
-
-    def set_step_roofline_ratio(self, *a, **k) -> None:
-        pass
-
     def record_wasted_tokens(self, *a, **k) -> None:
-        pass
-
-    def remove_efficiency_gauges(self, *a, **k) -> None:
         pass
 
     def record_preemption(self, *a, **k) -> None:

@@ -634,7 +634,9 @@ class StepTimeline:
         of the step's dispatch and, where ``starved_ms`` > 0, ``starved_by``
         (ISSUE 38: the same milliseconds by cause, from the scheduler's
         ``CauseCursor``), ``context_tokens``, a decode chunk's
-        ``submit_ts``, an admission's ``prep``."""
+        ``submit_ts``, an admission's ``prep``. ``cost`` (ISSUE 19) is
+        ``{"recompiled": n}`` on a step whose wall time includes n
+        steady-state recompiles, else None."""
         end_ns = span_clock_ns()
         rec = {
             # Both on the span clock: the step's first host instruction
@@ -652,9 +654,6 @@ class StepTimeline:
         if spans:
             rec.update(spans)
         if cost:
-            # Analytic step cost from the accounting layer (ISSUE 6):
-            # flops / hbm_bytes / roofline_ms / bound ride every record
-            # so /debug/roofline can aggregate measured-vs-analytic.
             rec.update(cost)
         with self._lock:
             self._ring.append(rec)

@@ -663,7 +663,7 @@ class Engine:
         # entry points with compile-ledger wrappers and this attribute
         # feeds the transfer audit. None = observability off — every
         # seam pays exactly one attribute check (same discipline as the
-        # scheduler's timeline/accounting observers).
+        # scheduler's timeline).
         self.observatory = None
         if self.paged:
             from inference_gateway_tpu.serving.kv_cache import (

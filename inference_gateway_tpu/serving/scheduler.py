@@ -22,7 +22,6 @@ from typing import Any, Callable
 
 import numpy as np
 
-from inference_gateway_tpu.otel.perf_accounting import WASTE_OVERSHOOT
 from inference_gateway_tpu.otel.profiling import (
     CauseCursor, bind_cursor, cause_span, perf_ns_of, phase_span, span_clock_ns)
 from inference_gateway_tpu.serving.engine import Engine, STOP_TABLE_WIDTH, build_stop_row
@@ -30,6 +29,18 @@ from inference_gateway_tpu.serving.kv_cache import OutOfPagesError
 
 # Callback payload: (token_id, logprob, finished, finish_reason)
 TokenCallback = Callable[[int, float, bool, str | None], None]
+
+# engine.wasted_tokens{reason}: tokens the engine computed that no client
+# will see, by why (Scheduler._wasted counts them).
+WASTE_SPEC_REJECTED = "spec_rejected"  # verify-forward positions the target refused
+WASTE_CHUNK_OVERRUN = "chunk_overrun"  # decoded past a finish inside a fused chunk
+WASTE_DISCONNECTED = "disconnected"  # decoded for a client that already hung up
+WASTE_SHED_AFTER_PREFILL = "shed_after_prefill"  # prefilled, then failed/shed
+# One decode row of the mixed step dispatched behind the step whose token,
+# seen a step later, ended the stream (EOS, a stop id, a client gone; ISSUE 40).
+WASTE_OVERSHOOT = "overshoot"
+WASTE_REASONS = (WASTE_SPEC_REJECTED, WASTE_CHUNK_OVERRUN, WASTE_DISCONNECTED,
+                 WASTE_SHED_AFTER_PREFILL, WASTE_OVERSHOOT)
 
 
 class SchedulerSaturatedError(RuntimeError):
@@ -213,7 +224,7 @@ class _MixedInflight:
     states: dict
     first: list
     t0: float
-    counts: tuple  # (prefill tokens, context tokens, pair tokens) of the record
+    context: int  # Σ kv length over the rows, for the record
     retired: dict = field(default_factory=dict)
     # The record's stamps; None / empty with no observer at the dispatch.
     phases: dict | None = None
@@ -301,7 +312,7 @@ class Scheduler:
         # 7): unlike last_step_time (real monotonic clock) a counter can
         # be compared on an injected virtual clock, so the watchdog is
         # zero-sleep testable. step_ewma is a smoothed per-step wall
-        # time (updated in _record_step when an observer is attached)
+        # time (updated in _record_step when the timeline is attached)
         # the watchdog derives its device-step deadline from.
         self.steps_completed = 0
         self.step_ewma = 0.0
@@ -325,11 +336,12 @@ class Scheduler:
         # emitted, and KV utilization. None (the default) keeps the hot
         # path at a single attribute check per chunk.
         self.timeline = None
-        # Optional compute-efficiency accounting (ISSUE 6,
-        # otel/perf_accounting.PerfAccounting): prices every recorded
-        # step (flops/bytes/roofline merged into the timeline record)
-        # and attributes wasted work. Same None-is-free discipline.
-        self.accounting = None
+        # Work the engine did that no client saw, in tokens by reason
+        # (engine.wasted_tokens{reason}): written by the scheduler thread
+        # alone, every reason present from the start so a reader never
+        # meets a dict that changes size; the sidecar hands its growth to
+        # otel and shows it in /debug/status.
+        self.wasted: dict[str, int] = dict.fromkeys(WASTE_REASONS, 0)
         # Optional device observatory (ISSUE 19,
         # otel/device_observatory.DeviceObservatory): when a step's wall
         # time includes an XLA recompile, the timeline record says so —
@@ -770,16 +782,13 @@ class Scheduler:
             self._fail_request(st.req)
             # The prompt was prefilled and some tokens may have been
             # decoded, but the stream ends in "error": all of it was
-            # work no client benefits from (ISSUE 6). The generated
-            # tokens were emitted — and so counted as delivered — before
-            # the failure; the prompt tokens never were. For a resumed
+            # work no client benefits from (ISSUE 6). For a resumed
             # request (ISSUE 7), prompt_ids already contains the
             # pre-preemption tokens that generated also counts —
             # subtract resume_generated so they are not billed twice.
-            self._wasted("shed_after_prefill",
+            self._wasted(WASTE_SHED_AFTER_PREFILL,
                          len(st.req.prompt_ids) + st.generated
-                         - st.req.resume_generated,
-                         delivered=st.generated)
+                         - st.req.resume_generated)
         try:
             self._release(slot, reason,
                           frozen=st.device_stopped if st is not None else False)
@@ -1060,11 +1069,8 @@ class Scheduler:
                 else:
                     self._flush_emits(req)
         if observing:
-            prompt_lens = [len(req.prompt_ids) for req, _slot in p.items]
             self._record_step("prefill", t0, n_steps=1, batch=len(p.items),
                               tokens=len(results),
-                              work_tokens=sum(prompt_lens),
-                              sq_tokens=sum(t * t for t in prompt_lens),
                               spans={**(p.submit or {}), "phases": phases})
 
     # -- ragged mixed-step admission (ISSUE 12) ------------------------
@@ -1106,7 +1112,7 @@ class Scheduler:
         what the step holds, nothing a user sets."""
         return all(st.req.grammar is None for st in inf.states.values())
 
-    def _build_mixed_rows(self, pending: list) -> tuple[list, int, int, int, int]:
+    def _build_mixed_rows(self, pending: list) -> tuple[list, int, int]:
         """Descriptor assembly for ONE mixed engine step: a decode row
         per active slot (its pending token advances one position), then
         prefill-chunk rows for the admitting requests, filling whatever
@@ -1118,10 +1124,8 @@ class Scheduler:
         count the slot will have once that step is processed; if that
         token is the request's last by its budget the slot has no row.
 
-        Returns (rows, n_decode, prefill_tokens, context_tokens,
-        pair_tokens) — the latter two feed the mixed StepCostModel kind:
-        context is Σ kv length over all rows (the KV read stream), pairs
-        is Σ per-query attended span (the exact attention FLOPs term).
+        Returns (rows, n_decode, context_tokens): the last is Σ kv
+        length over all rows, the record's ``context_tokens``.
         """
         from inference_gateway_tpu.serving.engine import MixedRow
 
@@ -1129,7 +1133,6 @@ class Scheduler:
         rows: list = []
         used = 0
         context = 0
-        pairs = 0
         for slot, st in self._slots.items():
             lag = self._ahead(slot, st)
             if lag is None and st.pending_token == _TOKEN_PENDING:
@@ -1145,7 +1148,6 @@ class Scheduler:
                 from_block=lag is not None))
             used += 1
             context += pos + 1
-            pairs += pos + 1
         n_decode = len(rows)
         for item in pending:
             req, slot = item["req"], item["slot"]
@@ -1160,10 +1162,8 @@ class Scheduler:
                 mask_state=req.grammar.global_state if req.grammar is not None else 0))
             used += take
             context += done + take
-            # Query i of the chunk attends done + i + 1 keys.
-            pairs += take * done + take * (take + 1) // 2
             item["done"] = done + take
-        return rows, n_decode, used - n_decode, context, pairs
+        return rows, n_decode, context
 
     def _fail_mixed_admission(self, pending: list, e: Exception) -> None:
         """Unrecoverable mixed-step failure: fail the admitting requests
@@ -1343,7 +1343,7 @@ class Scheduler:
         phases: dict | None = {} if observing else None
         chunked = [it["done"] for it in pending]
         with phase_span(phases, "build", "sched.mixed.build"):
-            rows, n_decode, n_prefill, context, pairs = self._build_mixed_rows(pending)
+            rows, n_decode, context = self._build_mixed_rows(pending)
         prev = self._mixed_inflight
         try:
             t_submit = time.perf_counter_ns() if observing else 0
@@ -1393,7 +1393,7 @@ class Scheduler:
                 self._slots[slot] = states[slot] = st
                 first.append((req, slot, st))
                 item["registered"] = True
-        step = _MixedInflight(handle, rows, n_decode, states, first, t0, (n_prefill, context, pairs),
+        step = _MixedInflight(handle, rows, n_decode, states, first, t0, context,
                               phases=phases, spans=spans, behind=prev is not None)
         self._mixed_inflight = step
         if prev is not None and not self._process_mixed(prev):
@@ -1521,7 +1521,7 @@ class Scheduler:
             plan = getattr(self.engine, "ragged_attention_plan", None)
             if plan is not None:
                 # Tokens of the rows the ragged kernel walks in wide
-                # tiles (ISSUE 34), beside work_tokens, and the pages
+                # tiles (ISSUE 34), and the pages
                 # those tiles walk and mask (ISSUE 44): known from the
                 # rows packed, no device read.
                 wide = [row for row in step.rows[step.n_decode:]
@@ -1530,11 +1530,9 @@ class Scheduler:
                 spans["attn_pages_walked"], spans["attn_pages_masked"] = self.engine.attn_pages(wide)
             if not overlapped:
                 spans.pop("submit_ts", None)
-            n_prefill, context, pairs = step.counts
             self._record_step(
                 "mixed", t0, n_steps=1, batch=len(step.rows),
-                tokens=emitted, work_tokens=step.n_decode + n_prefill,
-                context_tokens=context, pair_tokens=pairs, spans=spans)
+                tokens=emitted, context_tokens=step.context, spans=spans)
         return True
 
     def _submit_chunk(self, chain: bool) -> "_Inflight | None":
@@ -1723,12 +1721,11 @@ class Scheduler:
                     del self._slots[slot]
                     self._release_guarded(slot, reason)
                     break
-            if self.accounting is not None:
-                # The verify forward priced K+1 positions: the target
-                # rejected K+1-n of them, and accepted tokens past a
-                # finish are discarded (ISSUE 6 wasted-work attribution).
-                self._wasted("spec_rejected", K + 1 - n)
-                self._wasted("chunk_overrun", n - delivered)
+            # The verify forward ran K+1 positions: the target
+            # rejected K+1-n of them, and accepted tokens past a
+            # finish are discarded (ISSUE 6 wasted-work attribution).
+            self._wasted(WASTE_SPEC_REJECTED, K + 1 - n)
+            self._wasted(WASTE_CHUNK_OVERRUN, n - delivered)
             if not finished:
                 st.draft_len = P + min(n, K)
                 st.catchup = tuple(int(t) for t in out[slot, max(n - 2, 0):n]) \
@@ -1811,9 +1808,8 @@ class Scheduler:
                     del self._slots[slot]
                     self._release_guarded(slot, reason)
                     break
-            if self.accounting is not None:
-                self._wasted("spec_rejected", K + 1 - n)
-                self._wasted("chunk_overrun", n - delivered)
+            self._wasted(WASTE_SPEC_REJECTED, K + 1 - n)
+            self._wasted(WASTE_CHUNK_OVERRUN, n - delivered)
             if n:
                 self._flush_emits(st.req)
         if observing:
@@ -1828,10 +1824,9 @@ class Scheduler:
 
     @property
     def _observing(self) -> bool:
-        """Whether any per-step observer (timeline, accounting) is
-        attached — the single hot-path gate for t0 stamping and
-        context-token summing."""
-        return self.timeline is not None or self.accounting is not None
+        """Whether the timeline is attached — the single hot-path gate
+        for t0 stamping and context-token summing."""
+        return self.timeline is not None
 
     def _stamp_dispatch(self, kind: str, dispatch_t0: int, submit_t0: int | None = None) -> dict:
         """One dispatch just returned (ISSUE 14 satellite, generalised by
@@ -1885,20 +1880,17 @@ class Scheduler:
             self._cursor.start(now)
 
     def _record_step(self, kind: str, t0: float, *, n_steps: int, batch: int,
-                     tokens: int, work_tokens: int = 0, context_tokens: int = 0,
-                     sq_tokens: int = 0, pair_tokens: int = 0,
+                     tokens: int, context_tokens: int = 0,
                      spans: dict | None = None) -> None:
         """One decode-timeline record (ISSUE 4): duration covers fetch +
         host-side emission — the full per-step cost a request observes.
-        kv_utilization/queue_depth reads are GIL-atomic, lock-free. With
-        accounting attached (ISSUE 6) the step is also priced — flops,
-        HBM bytes, and roofline ms ride the same timeline record.
+        kv_utilization/queue_depth reads are GIL-atomic, lock-free.
 
         A failing observer must never spam the log once per engine step
         forever (the pre-ISSUE-6 behavior): consecutive failures are
-        rate-limited, and after _TIMELINE_MAX_FAILURES in a row both
-        observers are detached — serving continues, observability
-        reports its own death exactly once."""
+        rate-limited, and after _TIMELINE_MAX_FAILURES in a row the
+        timeline and the observatory are detached — serving continues,
+        observability reports its own death exactly once."""
         duration = time.perf_counter() - t0
         with cause_span("sched.record"):  # the instruments' own cost, charged to them
             if n_steps > 0:
@@ -1910,11 +1902,6 @@ class Scheduler:
                     0.8 * self.step_ewma + 0.2 * per_step)
             try:
                 cost = None
-                if self.accounting is not None:
-                    cost = self.accounting.on_step(
-                        kind, duration, batch=batch, n_steps=n_steps, tokens=tokens,
-                        work_tokens=work_tokens, context_tokens=context_tokens,
-                        sq_tokens=sq_tokens, pair_tokens=pair_tokens)
                 if self.observatory is not None:
                     # Recompile-stall attribution (ISSUE 19): a ledger delta
                     # since the last record means THIS step paid the compile
@@ -1922,13 +1909,11 @@ class Scheduler:
                     # p99 spike and its cause land in the same row.
                     seen = self.observatory.ledger.recompile_count()
                     if seen != self._recompiles_seen:
-                        delta = seen - self._recompiles_seen
+                        cost = {"recompiled": seen - self._recompiles_seen}
                         self._recompiles_seen = seen
-                        cost = dict(cost) if cost else {}
-                        cost["recompiled"] = delta
                         self.logger.warn(
                             "engine step stalled on steady-state recompile",
-                            "kind", kind, "recompiles", delta,
+                            "kind", kind, "recompiles", cost["recompiled"],
                             "step_ms", round(duration * 1e3, 1))
                 if self.timeline is not None:
                     spans = {**(spans or {}), "context_tokens": context_tokens}
@@ -1946,24 +1931,17 @@ class Scheduler:
                 n = self._timeline_failures
                 if n >= self._TIMELINE_MAX_FAILURES:
                     self.logger.error(
-                        "timeline/accounting disabled after repeated record failures",
+                        "timeline disabled after repeated record failures",
                         e, "consecutive", n)
                     self.timeline = None
-                    self.accounting = None
                     self.observatory = None
                 elif n == 1 or n % self._TIMELINE_LOG_EVERY == 0:
                     self.logger.error("timeline record failed", e, "consecutive", n)
 
-    def _wasted(self, reason: str, tokens: int, delivered: int = 0) -> None:
-        """Attribute wasted work without ever letting accounting
-        bookkeeping hurt the serving loop. ``delivered`` marks the
-        subset already counted as delivered tokens (goodput subtracts
-        only those)."""
-        if self.accounting is not None and tokens > 0:
-            try:
-                self.accounting.record_wasted(reason, tokens, delivered=delivered)
-            except Exception:
-                pass
+    def _wasted(self, reason: str, tokens: int) -> None:
+        """Count tokens the engine computed that no client will see."""
+        if tokens > 0:
+            self.wasted[reason] += tokens
 
     def _process_chunk(self, inf: "_Inflight", defer: bool = False) -> None:
         """Fetch a submitted chunk's token block and stream it out.
@@ -2045,7 +2023,7 @@ class Scheduler:
                     # loop as one wakeup instead of n_steps of them (a
                     # stream that finished was flushed by its _finish).
                     self._flush_emits(st.req, defer=defer)
-        self._wasted("chunk_overrun", overrun)
+        self._wasted(WASTE_CHUNK_OVERRUN, overrun)
         if observing:
             self._record_step("decode", t0, n_steps=inf.n_steps,
                               batch=len(inf.states), tokens=emitted,
@@ -2139,10 +2117,8 @@ class Scheduler:
             req.disconnected = True
         if req.disconnected:
             # The serving edge marked the stream abandoned; nobody reads
-            # these tokens (ISSUE 6 wasted-work attribution). Each one
-            # was just counted as delivered — flag it so goodput
-            # subtracts it again.
-            self._wasted("disconnected", 1, delivered=1)
+            # these tokens (ISSUE 6 wasted-work attribution).
+            self._wasted(WASTE_DISCONNECTED, 1)
         return finished, reason
 
     def _device_stop_ids(self, req: GenRequest) -> set:

@@ -32,11 +32,6 @@ from inference_gateway_tpu.logger import Logger, new_logger
 from inference_gateway_tpu.netio import sse
 from inference_gateway_tpu.netio.server import HTTPServer, Request, Response, Router, StreamingResponse
 from inference_gateway_tpu.otel.device_observatory import DeviceObservatory
-from inference_gateway_tpu.otel.perf_accounting import (
-    PerfAccounting,
-    StepCostModel,
-    roofline_report,
-)
 from inference_gateway_tpu.otel.profiling import (
     SlowRequestLog,
     StepTimeline,
@@ -116,13 +111,8 @@ class SidecarServer:
                  timeline_size: int = 512, slow_log: SlowRequestLog | None = None,
                  profiler=None, watchdog=None, emit_coalesce: float = 0.0,
                  stream_coalesce: bool = True,
-                 accounting: PerfAccounting | None = None,
-                 accounting_enable: bool = True,
-                 accounting_window: float = 10.0,
-                 accounting_chip: str | None = None,
                  observatory: DeviceObservatory | None = None,
                  device_enable: bool = True,
-                 device_cost_analysis: bool = True,
                  device_ledger_size: int = 256,
                  preempt_max: int = 3, preempt_high_water: float = 0.0,
                  engine_watchdog=None, engine_factory=None, clock=None,
@@ -232,26 +222,9 @@ class SidecarServer:
         # last capture's place on the span clock); both ride /debug/status.
         self.startup: dict[str, float] | None = None
         self.last_jax_trace: dict[str, Any] | None = None
-        # Compute-efficiency accounting (ISSUE 6): price every engine
-        # step against the chip roofline (TELEMETRY_ACCOUNTING_ENABLE;
-        # on by default — the analytic side must move every round, not
-        # just when someone remembers to turn it on). Disabled, neither
-        # the scheduler nor the emit path pays anything.
-        if accounting is None and accounting_enable:
-            try:
-                accounting = PerfAccounting(
-                    StepCostModel.from_engine(engine, chip=accounting_chip),
-                    otel=otel, model=self.model_name, window_s=accounting_window)
-            except Exception as e:
-                # An unknown model config must degrade to "no accounting",
-                # never block serving.
-                self.logger.warn("perf accounting disabled", "error", str(e))
-        self.accounting = accounting
-        if self.scheduler.accounting is None:
-            self.scheduler.accounting = accounting
         # Device observatory (ISSUE 19): compile/recompile ledger over
-        # every jitted entry point, XLA-grounded rooflines, live HBM
-        # accounting, and the always-on transfer audit
+        # every jitted entry point, live HBM accounting, and the
+        # always-on transfer audit
         # (TELEMETRY_DEVICE_ENABLE; on by default). The standalone
         # sidecar builds and attaches it in serve() BEFORE warmup so
         # boot compiles land in the ledger; built here, it observes
@@ -261,8 +234,7 @@ class SidecarServer:
             try:
                 observatory = DeviceObservatory(
                     otel=otel, model=self.model_name, logger=self.logger,
-                    ledger_size=device_ledger_size,
-                    cost_analysis=device_cost_analysis)
+                    ledger_size=device_ledger_size)
             except Exception as e:
                 self.logger.warn("device observatory disabled", "error", str(e))
         if observatory is not None and getattr(engine, "observatory", None) is not observatory:
@@ -298,6 +270,9 @@ class SidecarServer:
         self._tpot_samples: list[float] = []
         self._queue_wait_samples: list[float] = []
         self._pushed_decode_tokens = 0
+        # engine.wasted_tokens{reason} as last handed to otel: the
+        # scheduler counts, sample_engine_gauges brings the growth over.
+        self._wasted_sent: dict[str, int] = {}
         self._push_task: asyncio.Task | None = None
 
     # ------------------------------------------------------------------
@@ -309,7 +284,6 @@ class SidecarServer:
         r.get("/props", self.props)
         r.get("/metrics", self.metrics)
         r.get("/debug/timeline", self.debug_timeline)
-        r.get("/debug/roofline", self.debug_roofline)
         r.get("/debug/compile", self.debug_compile)
         r.get("/debug/hbm", self.debug_hbm)
         r.get("/debug/status", self.debug_status)
@@ -358,10 +332,8 @@ class SidecarServer:
         if self.otel is not None:
             # Engine teardown: this replica's saturation gauges describe
             # nothing now — drop the label sets instead of freezing them
-            # on /metrics (ISSUE 4 satellite). Efficiency gauges (ISSUE
-            # 6) follow the same current-state semantics.
+            # on /metrics (ISSUE 4 satellite).
             self.otel.remove_engine_gauges(self.model_name)
-            self.otel.remove_efficiency_gauges(self.model_name)
             self.otel.remove_hbm_gauges(self.model_name)
 
     def depth_probe(self) -> int:
@@ -537,13 +509,13 @@ class SidecarServer:
                           preempt_high_water=old_sched.preempt_high_water,
                           clock=self._clock)
         sched.timeline = self.timeline
-        sched.accounting = self.accounting
         sched.observatory = self.observatory
         sched.on_preempt = self._on_preempt
         # Counter continuity: /metrics "preemptions" is cumulative for
         # the PROCESS — a scheduler swap must not make it go backwards
         # (engine_restarts is the signal that a swap happened).
         sched.preemptions = old_sched.preemptions
+        sched.wasted = dict(old_sched.wasted)
         sched.start()
         self.engine = new_engine
         self.scheduler = sched
@@ -606,6 +578,11 @@ class SidecarServer:
                 queue_depth=sched.queue_depth,
                 spec_tokens_per_slot_round=spec_rate,
             )
+            for reason, total in sched.wasted.items():
+                grown = total - self._wasted_sent.get(reason, 0)
+                if grown > 0:
+                    self._wasted_sent[reason] = total
+                    self.otel.record_wasted_tokens(self.model_name, reason, grown)
         if self.observatory is not None:
             # engine.hbm.{plan,live,peak}_bytes ride the same cadence
             # (ISSUE 19); off-TPU only the plan gauge exists — absent
@@ -651,17 +628,6 @@ class SidecarServer:
         ]
         metrics = [self._delta_histogram(name, samples, bounds, attrs)
                    for name, samples, bounds in batches if samples]
-        if self.accounting is not None:
-            # The mfu snapshot rides every push (ISSUE 6): last-value
-            # gauges the gateway ingest maps onto engine.mfu & friends.
-            eff = self.accounting.snapshot()
-            for name, val in (("engine.mfu", eff["mfu"]),
-                              ("engine.goodput_mfu", eff["goodput_mfu"]),
-                              ("engine.hbm_bandwidth_util", eff["hbm_bandwidth_util"])):
-                metrics.append({
-                    "name": name,
-                    "gauge": {"dataPoints": [{"asDouble": val, "attributes": attrs}]},
-                })
         if self.observatory is not None:
             # HBM accounting rides the push too (ISSUE 19): the gateway
             # ingest maps engine.hbm.* onto last-value gauges. live/peak
@@ -813,15 +779,7 @@ class SidecarServer:
             m["prefix_cache"] = self.engine.prefix_cache.stats()
         if self.engine.structured is not None:
             m["structured"] = self.engine.structured.stats()
-        if self.accounting is not None:
-            # The mfu snapshot every scrape carries (ISSUE 6): flattened
-            # numerics so the Prometheus text path exports them too.
-            eff = self.accounting.snapshot()
-            m["mfu"] = eff["mfu"]
-            m["goodput_mfu"] = eff["goodput_mfu"]
-            m["hbm_bandwidth_util"] = eff["hbm_bandwidth_util"]
-            m["wasted_tokens"] = sum(eff["wasted_tokens"].values())
-            m["compute_efficiency"] = eff
+        m["wasted_tokens"] = sum(self.scheduler.wasted.values())
         if self.observatory is not None:
             # Device observatory flat numerics (ISSUE 19): the transfer
             # table plus the chained-submit invariant as its own scalar
@@ -896,53 +854,12 @@ class SidecarServer:
             "entries": self.timeline.tail(n if n > 0 else None),
         })
 
-    async def debug_roofline(self, req: Request) -> Response:
-        """GET /debug/roofline — per-step-kind measured-vs-analytic
-        aggregates over the timeline ring (ISSUE 6): p50/p99 step ms,
-        achieved TFLOP/s and GB/s, gap-to-roofline factor, and the
-        compute- vs bandwidth-bound verdict. Off-TPU the report is
-        framed ``measured: false`` — host wall clock is not kernel
-        time and is never presented as a hardware measurement."""
-        if self.accounting is None:
-            return Response.json(
-                {"error": "accounting disabled (TELEMETRY_ACCOUNTING_ENABLE)"},
-                status=404)
-        entries = self.timeline.tail(None) if self.timeline is not None else []
-        report = roofline_report(self.accounting, entries)
-        report["model"] = self.model_name
-        if self.observatory is not None:
-            # XLA grounding (ISSUE 19): the compiler's own cost model
-            # for the largest program of each kind, next to the analytic
-            # per-step numbers. analytic_vs_xla > 1 is static-shape
-            # padding the per-token analytic model does not charge for —
-            # or analytic-model drift, which this pane exists to catch.
-            xla = self.observatory.ledger.per_kind_xla()
-            if xla:
-                analytic_by_kind: dict[str, list[float]] = {}
-                for rec in entries:
-                    if "flops" in rec:
-                        analytic_by_kind.setdefault(rec["kind"], []).append(rec["flops"])
-                for kind, info in xla.items():
-                    vals = analytic_by_kind.get(kind)
-                    if vals:
-                        mean = sum(vals) / len(vals)
-                        info["analytic_flops_mean"] = round(mean, 1)
-                        info["analytic_vs_xla"] = (round(info["flops"] / mean, 2)
-                                                   if mean > 0 else None)
-                report["xla"] = xla
-                report["xla_note"] = (
-                    "cost_analysis() prices the full static-shape program; "
-                    "analytic_vs_xla compares it to the mean analytic "
-                    "per-step FLOPs over the timeline window")
-        return Response.json(report)
-
     async def debug_compile(self, req: Request) -> Response:
         """GET /debug/compile — the device compile/recompile ledger
         (ISSUE 19): every XLA compilation of a jitted engine entry point
-        with program name, static shape signature, compile wall-ms, and
-        ``cost_analysis()`` FLOPs / bytes-accessed — plus the
-        steady-state recompile events with the per-argument signature
-        diff that triggered each one. A nonzero ``recompiles`` after
+        with program name, static shape signature and compile wall-ms —
+        plus the steady-state recompile events with the per-argument
+        signature diff that triggered each one. A nonzero ``recompiles`` after
         warmup is a shape-stability bug, not noise."""
         if self.observatory is None:
             return Response.json(
@@ -1091,8 +1008,9 @@ class SidecarServer:
             # The last /debug/jax_trace capture on the span clock, so a
             # reader can lay timeline records over the device trace.
             status["jax_trace"] = self.last_jax_trace
-        if self.accounting is not None:
-            status["compute_efficiency"] = self.accounting.snapshot()
+        # Work the engine did that no client saw, by reason: what the
+        # scheduler counted, as engine.wasted_tokens{reason} has it.
+        status["wasted_tokens"] = dict(self.scheduler.wasted)
         if self.observatory is not None:
             # The full device pane (ISSUE 19): compile ledger, transfer
             # audit, HBM accounting — one stop for "what has the device
@@ -1614,17 +1532,8 @@ class SidecarServer:
                 # tokens; resume_tokens were billed by the replica that
                 # generated them.
                 event["resume_tokens"] = meta["resume_tokens"]
-            if self.accounting is not None:
-                # Per-request compute attribution (ISSUE 6): the useful
-                # work this request bought, in the same FLOP currency the
-                # MFU gauges report — the substrate per-tenant quotas
-                # will bill against (ROADMAP item 4).
-                pf, df = self.accounting.request_flops(
-                    meta["prompt_tokens"], completion_tokens)
-                event["prefill_flops"] = round(pf)
-                event["decode_flops"] = round(df)
-                if gen.disconnected:
-                    event["disconnected"] = True
+            if gen.disconnected:
+                event["disconnected"] = True
             self.access_log.emit(event)
 
         if self.slow_log is not None:
@@ -1801,7 +1710,7 @@ class SidecarServer:
             if not completed:
                 # Abandoned mid-stream: the scheduler decodes on to the
                 # finish condition, but those tokens are wasted work —
-                # flag the request so the accounting bills them to
+                # flag the request so the scheduler counts them under
                 # engine.wasted_tokens{reason="disconnected"} (ISSUE 6).
                 # (A migrated stream was already descheduled by
                 # Scheduler.cancel; setting the flag again is harmless.)
@@ -1879,15 +1788,14 @@ async def serve(config: EngineConfig, host: str = "0.0.0.0", port: int = 8000,
     startup.update(engine.startup)
     t_stage = time.perf_counter()
     # Device observatory (ISSUE 19): attach BEFORE warmup so every boot
-    # compile lands in the ledger with its cost analysis — warmup()
-    # brackets itself, so these classify as warmup, not recompiles.
+    # compile lands in the ledger — warmup() brackets itself, so these
+    # classify as warmup, not recompiles.
     observatory = None
     if tcfg.device_enable:
         try:
             observatory = DeviceObservatory(
                 model=served_model_name or config.model, logger=logger,
-                ledger_size=tcfg.device_ledger_size,
-                cost_analysis=tcfg.device_cost_analysis)
+                ledger_size=tcfg.device_ledger_size)
             observatory.attach(engine)
         except Exception as e:
             logger.warn("device observatory disabled", "error", str(e))
@@ -1951,12 +1859,8 @@ async def serve(config: EngineConfig, host: str = "0.0.0.0", port: int = 8000,
                            slow_log=slow_log, profiler=profiler, watchdog=watchdog,
                            emit_coalesce=svcfg.emit_coalesce,
                            stream_coalesce=scfg.stream_coalesce,
-                           accounting_enable=tcfg.accounting_enable,
-                           accounting_window=tcfg.accounting_window,
-                           accounting_chip=tcfg.accounting_chip or None,
                            observatory=observatory,
                            device_enable=tcfg.device_enable,
-                           device_cost_analysis=tcfg.device_cost_analysis,
                            device_ledger_size=tcfg.device_ledger_size,
                            preempt_max=preempt_budget,
                            preempt_high_water=svcfg.preempt_high_water,

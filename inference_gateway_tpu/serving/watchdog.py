@@ -12,9 +12,9 @@ process. The watchdog closes the loop in-process:
   longer than the step deadline declares the engine wedged.
 - **The deadline derives from measurement**: ``multiplier`` × the
   scheduler's EWMA per-step wall time (ISSUE 6 ``_record_step``), with
-  the StepCostModel decode roofline as a fallback estimate and
-  ``min_deadline`` as an absolute floor so cold engines and slow CPU
-  runs can't misfire.
+  ``min_deadline`` as an absolute floor: it alone decides before the
+  first recorded step, and keeps cold engines and slow CPU runs from
+  misfiring after it.
 - **Supervised restart**: forensics first (timeline tail + the wedged
   scheduler thread's mid-stall stack, the PR 4 playbook), then the
   sidecar fails every in-flight request with a retryable error, rebuilds
@@ -97,19 +97,9 @@ class EngineWatchdog:
     # -- policy --------------------------------------------------------
     def deadline(self) -> float:
         """Seconds without a completed step (while requests are active)
-        that declare the engine wedged."""
-        sched = self.sidecar.scheduler
-        est = getattr(sched, "step_ewma", 0.0)
-        if est <= 0:
-            acct = getattr(self.sidecar, "accounting", None)
-            if acct is not None:
-                try:
-                    cfg = self.sidecar.engine.config
-                    est = acct.cost_model.decode(
-                        batch=cfg.max_slots, n_steps=cfg.decode_chunk,
-                    ).roofline_ms / 1000.0
-                except Exception:
-                    est = 0.0
+        that declare the engine wedged: ``max(min_deadline, multiplier x
+        step_ewma)``, the floor alone until a step has been recorded."""
+        est = getattr(self.sidecar.scheduler, "step_ewma", 0.0)
         return max(self.min_deadline, self.multiplier * est)
 
     def stats(self) -> dict:

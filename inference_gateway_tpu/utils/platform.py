@@ -3,7 +3,7 @@
 ``JAX_PLATFORMS`` works on this installation, so choosing a platform is
 setting it before JAX first touches a device; nothing here re-binds a
 backend that is already up. Everything that asks "am I on a TPU" —
-kernel dispatch, interpret mode, accounting — asks ``device_platform``
+kernel dispatch, interpret mode, device tracing — asks ``device_platform``
 (tests steer that one function), and every entry point that builds an
 engine calls ``configure_compile_cache`` first.
 """
@@ -40,7 +40,7 @@ def device_platform() -> str:
 
 def on_tpu() -> bool:
     """The one "am I on a TPU" decision: compiled Pallas kernels and
-    device-time accounting on a TPU, interpret mode and the pure-JAX
+    device traces on a TPU, interpret mode and the pure-JAX
     twins anywhere else."""
     return device_platform() == "tpu"
 

@@ -766,7 +766,7 @@ def hammer_compile_ledger(writer_threads: int = 6, reader_threads: int = 2,
     """
     from inference_gateway_tpu.otel.device_observatory import CompileLedger
 
-    ledger = CompileLedger(size=64, cost_analysis=False)
+    ledger = CompileLedger(size=64)
     errors: list[str] = []
     errors_lock = threading.Lock()
     barrier = threading.Barrier(writer_threads + reader_threads + 1)
@@ -822,7 +822,6 @@ def hammer_compile_ledger(writer_threads: int = 6, reader_threads: int = 2,
                         return
                 ledger.recompile_count()
                 ledger.recent_recompiles(5)
-                ledger.per_kind_xla()
             except Exception as e:
                 fail(f"reader: {e!r}")
                 return

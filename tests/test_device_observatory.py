@@ -1,12 +1,13 @@
 """Engine device observatory (ISSUE 19): the compile/recompile ledger,
-XLA-grounded rooflines, the honest HBM pane, always-on transfer
-auditing, and the zero-overhead off switch.
+the honest HBM pane, always-on transfer auditing, and the zero-overhead
+off switch.
 
 Pins the acceptance contracts:
 
 1. Warmup compiles land in /debug/compile with program name, static
-   shape signature, compile wall-ms, AND the compiler's own cost-model
-   FLOPs / bytes-accessed.
+   shape signature and compile wall-ms, and every program is lowered
+   once per compile: the ledger sees a compile, it never repeats one
+   (ISSUE 46).
 2. A forced shape change after warmup fires EXACTLY ONE steady-state
    recompile: `engine.recompiles` increments and a wide event with the
    shape-signature diff (naming the changed static argument) is kept.
@@ -54,15 +55,14 @@ def _req(path, query=None):
 
 
 def _sidecar(engine, **kw):
-    kw.setdefault("accounting_chip", "v5e")  # CPU has no datasheet row
     return SidecarServer(engine, served_model_name="test-tiny",
                          otel=OpenTelemetry(), **kw)
 
 
 # ---------------------------------------------------------------------------
-# 1. Warmup ledger: programs, signatures, wall-ms, XLA costs
+# 1. Warmup ledger: programs, signatures, wall-ms
 # ---------------------------------------------------------------------------
-async def test_warmup_compiles_land_in_ledger_with_xla_costs():
+async def test_warmup_compiles_land_in_ledger():
     eng = Engine(_cfg("paged"))
     sidecar = _sidecar(eng)
     eng.warmup()
@@ -78,24 +78,67 @@ async def test_warmup_compiles_land_in_ledger_with_xla_costs():
     records = snap["records"]
     assert len(records) == snap["compiles"]
     for rec in records:
-        assert rec["program"] and rec["kind"]
+        assert rec["program"]
         assert rec["signature"]  # static shape signature, never empty
         assert rec["compile_ms"] > 0
         assert rec["recompile"] is False
-    kinds = {r["kind"] for r in records}
-    assert {"decode", "prefill"} <= kinds
-    # The compiler's own cost model grounds the records: at least the
-    # decode/prefill programs must carry XLA FLOPs and bytes-accessed.
-    costed = [r for r in records if r["flops"] is not None]
-    assert costed, "no record carries cost_analysis() FLOPs"
-    assert all(r["flops"] > 0 and r["bytes_accessed"] > 0 for r in costed)
-    assert {r["kind"] for r in costed} >= {"decode", "prefill"}
+    programs = {r["program"] for r in records}
+    assert any(p.startswith("decode") for p in programs)
+    assert any(p.startswith("prefill") for p in programs)
+    assert set(records[0]) == {"program", "signature", "compile_ms", "recompile"}
 
-    # The same XLA numbers ground /debug/roofline's per-kind pane.
-    roof = json.loads((await sidecar.debug_roofline(_req("/debug/roofline"))).body)
-    assert "xla" in roof
-    assert roof["xla"]["decode"]["flops"] > 0
-    assert roof["xla"]["decode"]["bytes_accessed"] > 0
+
+# The seven tiny presets, one per family the benchmark's configurations
+# come from; the paged engine each is served with.
+_PRESETS = {
+    "test-tiny": {},
+    "qwen2-test-tiny": {},
+    "smallthinker-test-tiny": {},
+    "phi4flash-test-tiny": {},
+    "keye-test-tiny": {"page_size": 8},
+    "trinity-test-tiny": {"page_size": 8},
+    "lfm2-test-tiny": {"page_size": 8},
+}
+
+
+@pytest.mark.parametrize("preset", list(_PRESETS))
+def test_warmup_lowers_every_program_once(preset):
+    """With the observatory attached, warm-up lowers each program once:
+    the ledger's wrapper sees that a call compiled and leaves the program
+    alone. The parent went back to it after every compile
+    (``fn.lower(...)`` again, for the compiler's FLOP count): on this JAX
+    that second pass was answered from the trace and lowering caches and
+    paid only the HLO conversion and the cost pass, so the parent reads
+    the same count here; what is held is that no observer builds a
+    program's StableHLO a second time, Pallas kernels and all, inside
+    every start. Counted where JAX itself reports a lowering,
+    ``jaxpr_to_mlir_module`` duration events: a trace counter would not
+    see a second pass, which hits the trace cache."""
+    from jax import monitoring
+
+    from inference_gateway_tpu.otel.device_observatory import DeviceObservatory
+
+    eng = Engine(_cfg("paged", model=preset, **_PRESETS[preset]))
+    obs = DeviceObservatory(model=preset)
+    obs.attach(eng)
+    programs = {f"jit({name})" for name in JIT_ENTRY_POINTS
+                if hasattr(getattr(eng, name, None), "_ledger_inner")}
+    lowered = []
+
+    def on_event(event, duration, **kw):
+        # Only the engine's own entry points: warm-up also runs small
+        # host-side helpers (jnp ops outside any wrapped program).
+        if event.endswith("jaxpr_to_mlir_module_duration") and kw.get("fun_name") in programs:
+            lowered.append(kw["fun_name"])
+
+    monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        eng.warmup()
+    finally:
+        monitoring.unregister_event_duration_listener(on_event)
+    snap = obs.ledger.snapshot()
+    assert snap["compiles"] >= 2 and snap["recompiles"] == 0
+    assert len(lowered) == snap["compiles"], (sorted(lowered), [r["program"] for r in snap["records"]])
 
 
 # ---------------------------------------------------------------------------
@@ -346,31 +389,6 @@ async def test_attach_is_idempotent_and_wrappers_single_layer():
         if fn is None or not hasattr(fn, "_ledger_inner"):
             continue
         assert getattr(fn._ledger_inner, "_ledger_inner", None) is None, name
-
-
-# ---------------------------------------------------------------------------
-# 6. Overhead gate (satellite b): < 5% p99 on the streamed path
-# ---------------------------------------------------------------------------
-@pytest.mark.slow
-def test_bench_device_observatory_overhead_under_5pct(aloop):
-    """Acceptance: the always-on observatory (compile wrappers + transfer
-    audit on every seam) must cost < 5% p99 on the streamed sidecar
-    path. Same best-of-3 discipline as the accounting/profiling gates —
-    shared-CI p99 swings tens of percent from scheduler noise alone."""
-    import sys
-    from pathlib import Path
-
-    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "benchmarks"))
-    import gateway_bench
-
-    deltas = []
-    for _ in range(3):
-        result = aloop.run(gateway_bench.bench_device_observatory_overhead(n=60))
-        assert result["p99_delta_pct"] is not None
-        deltas.append(result["p99_delta_pct"])
-        if result["p99_delta_pct"] < 5.0:
-            return
-    raise AssertionError(f"p99 overhead above 5% in all 3 runs: {deltas}")
 
 
 # ---------------------------------------------------------------------------

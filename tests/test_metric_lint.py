@@ -109,8 +109,6 @@ def test_noop_recorders_record_nothing():
     noop.record_host_gap("m", "decode", 0.05)
     noop.record_slow_request("s", "total")
     noop.set_engine_gauges("m", slot_occupancy=1.0)
-    noop.set_compute_efficiency("m", mfu=0.5, hbm_bandwidth_util=0.5, goodput_mfu=0.5)
-    noop.set_step_roofline_ratio("m", "decode", 2.0)
     noop.record_wasted_tokens("m", "spec_rejected", 5)
     assert noop.token_usage.total_count() == 0
     assert noop.eventloop_lag.total_count() == 0
@@ -118,8 +116,6 @@ def test_noop_recorders_record_nothing():
     assert noop.engine_host_gap.total_count() == 0
     assert sum(noop.slow_request_counter.values().values()) == 0
     assert noop.engine_slot_occupancy_gauge.values() == {}
-    assert noop.engine_mfu_gauge.values() == {}
-    assert noop.engine_roofline_ratio_gauge.values() == {}
     assert noop.wasted_tokens_counter.values() == {}
 
 
@@ -230,23 +226,14 @@ def test_noop_fault_tolerance_recorders_record_nothing():
     assert noop.engine_degraded_gauge.values() == {}
 
 
-def test_efficiency_instruments_registered_with_expected_shapes():
-    """ISSUE 6: the compute-efficiency surface must expose exactly the
-    advertised names — dashboards and the BENCH trajectory key on them."""
+def test_wasted_work_instrument_registered_with_expected_shape():
+    """ISSUE 6 / ISSUE 46: the wasted-work counter is the one series left of
+    the compute-efficiency surface; the analytic gauges are not registered."""
     otel = OpenTelemetry()
     by_name = {inst.name: inst for inst in otel.registry._instruments}
-    for name in ("engine.mfu", "engine.goodput_mfu", "engine.hbm_bandwidth_util"):
-        inst = by_name[name]
-        assert isinstance(inst, Gauge)
-        # source distinguishes a pushed replica's series from a
-        # co-hosted engine's; the TTL ages an idle engine's last busy
-        # reading out of the exposition (refresh is step-driven).
-        assert inst.label_names == ("gen_ai_request_model", "source")
-        assert inst.ttl > 0
-    ratio = by_name["engine.step_roofline_ratio"]
-    assert isinstance(ratio, Gauge)
-    assert ratio.label_names == ("gen_ai_request_model", "kind")
-    assert ratio.ttl > 0
+    for name in ("engine.mfu", "engine.goodput_mfu", "engine.hbm_bandwidth_util",
+                 "engine.step_roofline_ratio"):
+        assert name not in by_name
     wasted = by_name["engine.wasted_tokens"]
     assert isinstance(wasted, Counter)
     assert wasted.label_names == ("gen_ai_request_model", "reason")

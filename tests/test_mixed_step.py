@@ -1,14 +1,11 @@
 """Ragged mixed-step serving (ISSUE 12): scheduler interleaving,
-byte-identity vs the bucketed path, mixed-step cost accounting, and the
-dispatch-verdict surfacing.
+byte-identity vs the bucketed path, and the dispatch-verdict surfacing.
 
 The acceptance pins:
 - a long chunked prefill admitted alongside active decode streams no
   longer serializes ahead of them (decode tokens emit during the
   prefill's chunk window);
 - greedy stream output is byte-identical to the bucketed path;
-- StepCostModel prices ``mixed`` steps and /debug/roofline reports them
-  per kind;
 - over-length prompts route through the structured ``prompt_too_long``
   path instead of a bare ValueError.
 """
@@ -19,11 +16,6 @@ import time
 import numpy as np
 import pytest
 
-from inference_gateway_tpu.otel.perf_accounting import (
-    CHIP_SPECS,
-    PerfAccounting,
-    StepCostModel,
-)
 from inference_gateway_tpu.serving.engine import (
     Engine,
     EngineConfig,
@@ -200,57 +192,6 @@ def test_sidecar_rejects_overlength_with_structured_400():
         server.scheduler.stop()
 
     asyncio.run(run())
-
-
-def test_step_cost_model_prices_mixed_steps():
-    """The mixed kind decomposes to its parts: decode-rows-only equals
-    decode(); a lone fresh prefill row equals prefill() on FLOPs."""
-    from inference_gateway_tpu.models import llama
-
-    cfg = llama.PRESETS["tinyllama-1.1b"]
-    m = StepCostModel(cfg, chip=CHIP_SPECS["v5e"], n_chips=1)
-    # All-decode mixed step == classic decode step.
-    B, ctx = 8, 4096
-    dec = m.decode(B, n_steps=1, context_tokens=ctx)
-    mix = m.step_cost("mixed", batch=B, tokens=B, context_tokens=ctx, pair_tokens=ctx)
-    assert mix.flops == pytest.approx(dec.flops)
-    assert mix.hbm_bytes == pytest.approx(dec.hbm_bytes)
-    # A lone fresh prefill row: pairs = T²/2-ish == prefill's sq term.
-    T = 512
-    pre = m.prefill(T, sq_tokens=T * T)
-    mix_p = m.step_cost("mixed", batch=0, tokens=T, context_tokens=T,
-                        pair_tokens=T * T // 2)
-    assert mix_p.flops == pytest.approx(pre.flops, rel=0.01)
-
-
-def test_mixed_steps_reach_roofline_report_and_gauge():
-    """A served mixed engine with accounting attached reports the mixed
-    kind in the rolling window (engine.step_roofline_ratio{kind=mixed})
-    and the /debug/roofline per_kind table."""
-    from inference_gateway_tpu.otel.profiling import StepTimeline
-
-    engine = _mk_engine(True, mixed_step_tokens=24)
-    acct = PerfAccounting(StepCostModel.from_engine(engine, chip="v5e"), measured=False)
-    timeline = StepTimeline(64)
-    sched = Scheduler(engine)
-    sched.accounting = acct
-    sched.timeline = timeline
-    sched.start()
-    try:
-        rng = np.random.default_rng(5)
-        prompt = [int(x) for x in rng.integers(1, 250, size=40)]  # 2 chunks
-        out, _ = generate_sync(sched, prompt, max_tokens=4, temperature=0.0)
-        assert out
-    finally:
-        sched.stop()
-    kinds = {e["kind"] for e in timeline.tail(None)}
-    assert "mixed" in kinds, kinds
-    from inference_gateway_tpu.otel.perf_accounting import roofline_report
-
-    report = roofline_report(acct, timeline.tail(None))
-    assert "mixed" in report["per_kind"]
-    assert report["per_kind"]["mixed"]["records"] >= 1
-    assert report["measured"] is False
 
 
 def test_attention_path_surfaced_in_status_and_gauge():

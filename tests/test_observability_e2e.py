@@ -251,28 +251,3 @@ async def test_access_log_captures_shed_requests():
     assert event["status"] == 429
     assert event["retry_after_s"] >= 1.0
     assert "duration_ms" in event
-
-
-@pytest.mark.slow
-def test_bench_fleet_observability_overhead_under_5pct(aloop):
-    """Acceptance (ISSUE 18): stream journeys + per-tenant SLO burn-rate
-    accounting ship ON by default, so their marginal cost over a
-    telemetry-on baseline must stay < 5% p99 on the double-hop chat
-    path. Shared-CI p99s swing tens of percent run to run from
-    scheduler noise alone (the off-variant does too), so this takes the
-    best of three bench runs — a real systematic overhead shows up in
-    all of them."""
-    import sys
-    from pathlib import Path
-
-    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "benchmarks"))
-    import gateway_bench
-
-    deltas = []
-    for _ in range(3):
-        result = aloop.run(gateway_bench.bench_fleet_observability_overhead(n=150))
-        assert result["p99_delta_pct"] is not None
-        deltas.append(result["p99_delta_pct"])
-        if result["p99_delta_pct"] < 5.0:
-            return
-    raise AssertionError(f"p99 overhead above 5% in all 3 runs: {deltas}")

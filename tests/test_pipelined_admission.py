@@ -110,19 +110,6 @@ def test_a_closed_loop_yields_each_request_the_tokens_of_the_host_stepped_loop(k
 # ---------------------------------------------------------------------------
 # Stepping the loop by hand: one pass at a time, on the test's thread
 # ---------------------------------------------------------------------------
-class Wasted:
-    """The accounting's two calls, counted."""
-
-    def __init__(self) -> None:
-        self.by_reason: dict = {}
-
-    def on_step(self, *a, **kw):
-        return None
-
-    def record_wasted(self, reason, tokens, delivered=0):
-        self.by_reason[reason] = self.by_reason.get(reason, 0) + tokens
-
-
 def _spy_rows(engine: Engine) -> list:
     """Every mixed step's rows as (slot, kind, from_block), in dispatch order,
     with ("fetch",) where a mixed step was fetched."""
@@ -170,7 +157,6 @@ def test_a_stop_id_drawn_inside_a_train_costs_one_discarded_row_and_one_release(
     engine = _engine(mixed_step_tokens=24)
     log = _spy_rows(engine)
     sched = Scheduler(engine)
-    sched.accounting = Wasted()
     a_probe = Client(0, 8, max_tokens=40)
     sched.submit(a_probe.req)
     sched._pass()
@@ -188,7 +174,7 @@ def test_a_stop_id_drawn_inside_a_train_costs_one_discarded_row_and_one_release(
     engine.release_slot = lambda slot, frozen=False: (released.append(slot), release(slot, frozen=frozen))[1]
     _run_out(sched)
     assert a_probe.tokens == stream[:k + 1] and a_probe.ends == ["stop"]  # nothing after the stop id
-    assert sched.accounting.by_reason.get("overshoot") == 1
+    assert sched.wasted["overshoot"] == 1
     assert b.ends == ["length"] and len(b.seen) == 3
     assert len(released) == 2 and a_slot in released and sorted(sched._free) == [0, 1, 2, 3]  # each once
     assert engine.allocator.free_page_count() == engine.allocator.num_pages

@@ -9,9 +9,10 @@ toy defaults hand-picked under time pressure.
 import pytest
 
 from inference_gateway_tpu.models import llama
-from inference_gateway_tpu.serving.engine import EngineConfig
+from inference_gateway_tpu.serving.engine import Engine, EngineConfig
 from inference_gateway_tpu.serving.profiles import (
     PROFILES,
+    ServingProfile,
     hbm_plan,
     kv_bytes_per_token,
     llama_param_count,
@@ -90,3 +91,29 @@ def test_flagship_oversubscription_is_deliberate():
     reserved = p.max_slots * p.max_seq_len
     assert pool_tokens < reserved  # oversubscribed
     assert pool_tokens >= reserved // 2  # but not absurdly so
+
+
+def test_hbm_plan_matches_engine_allocation_for_tiny_profile():
+    """hbm_plan <-> Engine allocation drift guard (ISSUE 6 satellite): the
+    plan prices weights and the paged pool as the engine allocates them,
+    byte for byte."""
+    import jax
+
+    profile = ServingProfile(
+        name="test-tiny-paged", model="test-tiny", n_chips=1,
+        max_slots=4, max_seq_len=128, prefill_buckets=(16, 32, 64, 128),
+        max_prefill_batch=2, page_size=32, decode_chunk=8,
+        attention="paged", mesh={},
+    )
+    plan = hbm_plan(profile)
+    engine = Engine(EngineConfig(**profile.engine_kwargs()))
+    # KV: the paged pool the engine actually allocated.
+    actual_kv = sum(int(leaf.size * leaf.dtype.itemsize)
+                    for leaf in jax.tree.leaves(engine.cache))
+    assert actual_kv == plan["kv_per_chip"]
+    assert plan["kv_tokens"] == (engine.allocator.num_pages * profile.page_size)
+    assert actual_kv == plan["kv_tokens"] * kv_bytes_per_token(engine.model_cfg)
+    # Weights: bf16 params as allocated.
+    actual_w = sum(int(leaf.size * leaf.dtype.itemsize)
+                   for leaf in jax.tree.leaves(engine.params))
+    assert actual_w == plan["weights_per_chip"]

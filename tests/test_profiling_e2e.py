@@ -212,26 +212,3 @@ async def test_sidecar_debug_status(stack):
     resp = await client.get(f"http://127.0.0.1:{sidecar_port}/debug/jax_trace?seconds=0.1")
     assert resp.status == 409
     assert "tpu" in resp.json()["reason"]
-
-
-@pytest.mark.slow
-def test_bench_profiling_overhead_under_5pct(aloop):
-    """Acceptance: continuous profiling + watchdog + forensics must cost
-    < 5% p99 on the double-hop chat path. Shared-CI p99s swing tens of
-    percent run to run from scheduler noise alone (the off-variant does
-    too), so this takes the best of three bench runs — a real systematic
-    overhead shows up in all of them."""
-    import sys
-    from pathlib import Path
-
-    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "benchmarks"))
-    import gateway_bench
-
-    deltas = []
-    for _ in range(3):
-        result = aloop.run(gateway_bench.bench_profiling_overhead(n=150))
-        assert result["p99_delta_pct"] is not None
-        deltas.append(result["p99_delta_pct"])
-        if result["p99_delta_pct"] < 5.0:
-            return
-    raise AssertionError(f"p99 overhead above 5% in all 3 runs: {deltas}")

@@ -401,12 +401,40 @@ def test_watchdog_deadline_floors_and_scales():
 
     class _FakeSidecar:
         scheduler = _FakeSched()
-        accounting = None
 
     wd.bind(_FakeSidecar())
     assert wd.deadline() == 5.0  # floor with no estimate
     _FakeSched.step_ewma = 2.0
     assert wd.deadline() == 20.0  # multiplier × EWMA
+
+
+@pytest.mark.parametrize("when", ["before_the_first_step", "after_it"])
+def test_watchdog_deadline_needs_no_cost_model(when):
+    """ISSUE 46: the deadline is max(min_deadline, multiplier x step_ewma)
+    and nothing else. A real sidecar carries no cost model to fall back
+    on: before a step has been recorded the floor alone decides; after,
+    the scheduler's own smoothed step time does."""
+    engine = Engine(EngineConfig(model="test-tiny", max_slots=2, max_seq_len=128,
+                                 dtype="float32", max_prefill_batch=2, use_mesh=False,
+                                 decode_chunk=2))
+    floor = 5.0 if when == "before_the_first_step" else 1e-9
+    wd = EngineWatchdog(multiplier=20.0, min_deadline=floor, clock=VirtualClock())
+    sidecar = SidecarServer(engine, served_model_name="test-tiny", engine_watchdog=wd)
+    assert not hasattr(sidecar, "accounting") and not hasattr(sidecar.scheduler, "accounting")
+    sched = sidecar.scheduler
+    assert sched.step_ewma == 0.0
+    if when == "before_the_first_step":
+        assert wd.deadline() == 5.0
+        assert wd.stats()["deadline_seconds"] == 5.0
+        return
+    sched.start()
+    try:
+        toks, _ = _collect_stream(sched, [1, 2, 3, 4], max_tokens=8)
+        assert toks
+    finally:
+        sched.stop()
+    assert sched.step_ewma > 0  # the timeline's records smoothed it
+    assert wd.deadline() == pytest.approx(20.0 * sched.step_ewma)
 
 
 def test_step_hang_trips_watchdog_and_engine_restarts_in_place(aloop):
@@ -587,27 +615,6 @@ def test_health_degraded_during_restart_window(aloop):
         assert resp.status == 200
     finally:
         aloop.run(sidecar.shutdown())
-
-
-@pytest.mark.slow
-def test_bench_preemption_overhead_under_5pct(aloop):
-    """ISSUE 7 gate: preemption armed-but-idle must cost < 5% p99 on
-    the streamed sidecar path (same best-of-3 discipline as the
-    profiling/accounting gates — shared-CI p99 is noisy)."""
-    import sys
-    from pathlib import Path
-
-    sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "benchmarks"))
-    import gateway_bench
-
-    deltas = []
-    for _ in range(3):
-        result = aloop.run(gateway_bench.bench_preemption_overhead(n=80))
-        assert result["p99_delta_pct"] is not None
-        deltas.append(result["p99_delta_pct"])
-        if result["p99_delta_pct"] < 5.0:
-            return
-    raise AssertionError(f"p99 overhead above 5% in all 3 runs: {deltas}")
 
 
 def test_non_streaming_engine_failure_is_retryable_503(aloop):

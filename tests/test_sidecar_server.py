@@ -204,3 +204,99 @@ async def test_spec_decoding_sidecar_end_to_end():
         assert any(c.get("usage") for c in chunks)
     finally:
         await server.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# What the sidecar says of itself is counted or stamped, never estimated
+# (ISSUE 46): the fields left on each surface, exactly.
+# ---------------------------------------------------------------------------
+ESTIMATES = {"mfu", "goodput_mfu", "hbm_bandwidth_util", "compute_efficiency",
+             "prefill_flops", "decode_flops", "flops", "hbm_bytes", "roofline_ms"}
+
+
+@pytest.fixture(scope="module")
+def told(aloop):
+    """One streamed chat through a sidecar with every surface on; what each
+    surface then carries."""
+    from inference_gateway_tpu.otel.access_log import AccessLog
+    from inference_gateway_tpu.otel.otel import OpenTelemetry
+
+    engine = Engine(EngineConfig(model="test-tiny", max_slots=4, max_seq_len=128,
+                                 dtype="float32", max_prefill_batch=2, use_mesh=False))
+    log = AccessLog(service="tpu-sidecar", tail_size=16)
+    server = SidecarServer(engine, served_model_name="test-tiny", otel=OpenTelemetry(), access_log=log)
+    port = aloop.run(server.start("127.0.0.1", 0))
+
+    async def drive():
+        http = HTTPClient()
+        body = json.dumps({"model": "test-tiny", "stream": True, "max_tokens": 8,
+                           "messages": [{"role": "user", "content": "probe"}]}).encode()
+        resp = await http.post(f"http://127.0.0.1:{port}/v1/chat/completions", body, stream=True)
+        async for _ in resp.iter_raw():
+            pass
+        return {"push": server._otlp_payload(),
+                "metrics": (await http.get(f"http://127.0.0.1:{port}/metrics")).json(),
+                "roofline": (await http.get(f"http://127.0.0.1:{port}/debug/roofline")).status}
+
+    try:
+        out = aloop.run(drive())
+        out["access"] = [e for e in log.tail if e.get("route") == "/v1/chat/completions"][-1]
+        yield out
+    finally:
+        aloop.run(server.shutdown())
+
+
+def test_access_log_line_carries_exactly_the_fields_left(told):
+    assert set(told["access"]) == {
+        "log", "ts", "service", "route", "request_id", "provider", "model", "stream", "finish_reason",
+        "input_tokens", "output_tokens", "queue_wait_ms", "prefill_ms", "decode_ms",
+        "recv_to_submit_ms", "first_token_to_write_ms"}
+    assert told["access"]["output_tokens"] > 0
+
+
+def test_otlp_push_carries_exactly_the_series_left(told):
+    from inference_gateway_tpu.otel.otel import OpenTelemetry
+
+    names = [m["name"] for rm in told["push"]["resourceMetrics"]
+             for sm in rm["scopeMetrics"] for m in sm["metrics"]]
+    assert sorted(names) == ["engine.hbm.plan_bytes", "gen_ai.server.time_in_queue",
+                             "gen_ai.server.time_per_output_token", "gen_ai.server.time_to_first_token"]
+    gateway = OpenTelemetry()
+    result = gateway.ingest_metrics(told["push"], "tpu-sidecar")
+    assert result["accepted"] == len(names) and result["rejected"] == 0
+    # A push from a sidecar of the parent's kind is refused by name, not mapped onto a gauge.
+    old = {"resourceMetrics": [{"scopeMetrics": [{"metrics": [
+        {"name": "engine.mfu", "gauge": {"dataPoints": [{"asDouble": 0.5, "attributes": []}]}}]}]}]}
+    refused = gateway.ingest_metrics(old, "tpu-sidecar")
+    assert refused["accepted"] == 0 and refused["rejected"] == 1
+    assert "engine_mfu" not in gateway.expose_prometheus()
+
+
+def test_metrics_json_carries_exactly_the_fields_left(told):
+    assert set(told["metrics"]) == {
+        "active_requests", "queue_depth", "uptime_seconds", "slot_occupancy", "kv_page_utilization",
+        "decode_steps", "decode_tokens", "prefill_batches", "prefill_tokens",
+        "preemptions", "engine_restarts", "streams_migrated_out", "structured", "wasted_tokens",
+        "compiles", "recompiles", "transfers", "h2d_chain_transfers", "hbm_plan_bytes"}
+    assert told["metrics"]["wasted_tokens"] == 0
+    assert not ESTIMATES & set(told["metrics"])
+
+
+def test_roofline_is_gone_and_no_remaining_variable_is_refused(told):
+    """/debug/roofline answers 404 like any unknown path, and the four
+    variables that went with it are not read: a deployment that still sets
+    them starts as before, and every TELEMETRY_* variable that is left loads."""
+    from inference_gateway_tpu.config import TelemetryConfig
+
+    assert told["roofline"] == 404
+    env = {"TELEMETRY_ENABLE": "true", "TELEMETRY_ACCESS_LOG": "true", "TELEMETRY_ACCESS_LOG_TAIL": "8",
+           "TELEMETRY_PROFILING_TIMELINE_SIZE": "64", "TELEMETRY_DEVICE_ENABLE": "false",
+           "TELEMETRY_DEVICE_LEDGER_SIZE": "32", "TELEMETRY_JOURNEY_ENABLE": "false",
+           "TELEMETRY_ACCOUNTING_ENABLE": "true", "TELEMETRY_ACCOUNTING_WINDOW": "10s",
+           "TELEMETRY_ACCOUNTING_CHIP": "v5e", "TELEMETRY_DEVICE_COST_ANALYSIS": "true"}
+    cfg = TelemetryConfig.load(env)
+    assert (cfg.enable, cfg.access_log, cfg.access_log_tail) == (True, True, 8)
+    assert cfg.profiling_timeline_size == 64
+    assert (cfg.device_enable, cfg.device_ledger_size, cfg.journey_enable) == (False, 32, False)
+    gone = {"accounting_enable", "accounting_window", "accounting_chip", "device_cost_analysis"}
+    assert not gone & set(vars(cfg))

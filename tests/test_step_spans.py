@@ -294,10 +294,10 @@ async def test_a_detached_observer_stamps_nothing(sidecar, monkeypatch):
     mixed, chunk = engine.mixed_step_submit, engine.decode_chunk_submit
     engine.mixed_step_submit = lambda *a, **k: (handles.append(mixed(*a, **k)), handles[-1])[1]
     engine.decode_chunk_submit = lambda *a, **k: (handles.append(chunk(*a, **k)), handles[-1])[1]
-    timeline, accounting = sched.timeline, sched.accounting
+    timeline = sched.timeline
     await asyncio.sleep(0.3)  # let the loop go idle with the observers still on
     records = timeline.stats()["records"]  # the last stream's tail chunks are in by now
-    sched.timeline = sched.accounting = None
+    sched.timeline = None
     sched._dev_touch = sched._starve_t0 = None
     await asyncio.sleep(0.3)  # the wait's next tick unbinds the cursor
     assert "sched.wake" in opened  # the ticks before the observers went
@@ -311,7 +311,7 @@ async def test_a_detached_observer_stamps_nothing(sidecar, monkeypatch):
         assert timeline.stats()["records"] == records
     finally:
         engine.mixed_step_submit, engine.decode_chunk_submit = mixed, chunk
-        sched.timeline, sched.accounting = timeline, accounting
+        sched.timeline = timeline
 
 
 async def test_jax_trace_without_the_python_tracer_is_still_a_409_off_tpu(sidecar):
@@ -404,3 +404,132 @@ def test_a_bare_record_keeps_every_field_it_had_and_gains_its_start():
     assert plain["ts"] - plain["start_ts"] == pytest.approx(0.012, abs=1e-6)
     assert "phases" not in plain and "host_gap_ms" not in plain
     assert spanned["phases"] == {"build": 1.0} and spanned["starved_ms"] == 2.5
+
+
+# ---------------------------------------------------------------------------
+# What a record carries is a closed, documented list (ISSUE 46): stamps and
+# counts, no estimate. docs/observability.md's table is held to the code.
+# ---------------------------------------------------------------------------
+COST_KEYS = {"flops", "hbm_bytes", "roofline_ms", "bound"}
+KINDS = ("prefill", "decode", "mixed", "spec", "spec_ngram")
+
+
+def _documented_keys() -> dict[str, tuple[set, set]]:
+    """kind -> (keys of every record, keys where they apply), from the table
+    under "Record keys by kind" in docs/observability.md."""
+    import re
+    from pathlib import Path
+
+    text = (Path(__file__).resolve().parents[1] / "docs" / "observability.md").read_text()
+    rows = text.split("#### Record keys by kind", 1)[1].split("\n\n| kind |", 1)[1].split("\n\n", 1)[0]
+    table = {}
+    for line in rows.splitlines()[2:]:
+        name, always, sometimes = [cell.strip() for cell in line.strip().strip("|").split("|")]
+        table[name.strip("`")] = (set(re.findall(r"`([a-z_0-9]+)`", always)),
+                                  set(re.findall(r"`([a-z_0-9]+)`", sometimes)))
+    return table
+
+
+@pytest.fixture(scope="module")
+def records_by_kind() -> dict[str, list[dict]]:
+    """Timeline records of every kind, from four tiny engines driven by their
+    schedulers: bucketed (prefill, decode), mixed, model-draft and n-gram
+    speculation."""
+    import threading
+
+    import numpy as np
+
+    from inference_gateway_tpu.serving.scheduler import Scheduler, generate_sync
+
+    def run(**over) -> list[dict]:
+        kw = dict(model="test-tiny", max_slots=4, max_seq_len=256, dtype="float32",
+                  max_prefill_batch=2, use_mesh=False, decode_chunk=4)
+        kw.update(over)
+        sched = Scheduler(Engine(EngineConfig(**kw)))
+        sched.timeline = StepTimeline(512)
+        sched.start()
+        try:
+            rng = np.random.default_rng(0)
+            prompts = [[int(t) for t in rng.integers(1, 250, n)] for n in (8, 40, 9)]
+            threads = [threading.Thread(target=generate_sync, args=(sched, p), kwargs={"max_tokens": 12})
+                       for p in prompts]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join()
+            generate_sync(sched, [7, 8, 9, 7, 8, 9, 7, 8], max_tokens=16)
+        finally:
+            sched.stop()
+        return sched.timeline.tail(None)
+
+    records = (run() + run(attention="paged", page_size=16, mixed_step=True, prefix_cache=False)
+               + run(max_slots=2, spec_draft="test-tiny", spec_k=4)
+               + run(max_slots=2, spec_draft="ngram", spec_k=4))
+    return {kind: [r for r in records if r["kind"] == kind] for kind in KINDS}
+
+
+def test_the_documented_kinds_are_the_schedulers():
+    table = _documented_keys()
+    assert set(table) == {"all kinds", *KINDS}
+    assert not COST_KEYS & set().union(*(a | b for a, b in table.values()))
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_timeline_record_keys_are_the_documented_ones(records_by_kind, kind):
+    table = _documented_keys()
+    always = table["all kinds"][0] | table[kind][0]
+    allowed = always | table["all kinds"][1] | table[kind][1]
+    records = records_by_kind[kind]
+    assert records, f"no {kind} record was written"
+    for r in records:
+        assert always <= set(r), (kind, sorted(always - set(r)))
+        assert set(r) <= allowed, (kind, sorted(set(r) - allowed))
+        assert not COST_KEYS & set(r)
+    # What the table calls "every record" is no wider than the code makes it:
+    # a key every record of this run carries is documented as such or applies
+    # only where the run happened to have it (a short run's few mixed steps).
+    seen_always = set.intersection(*(set(r) for r in records))
+    assert seen_always >= always
+
+
+def test_timeline_failures_rate_limited_then_disabled():
+    """ISSUE 6 satellite: a broken record path must not logger.error
+    once per engine step forever — the scheduler logs the first failure,
+    then disables the timeline after 8 consecutive ones, and serving
+    continues."""
+    from inference_gateway_tpu.serving.scheduler import Scheduler, generate_sync
+
+    class _BrokenTimeline:
+        def record(self, *a, **k):
+            raise RuntimeError("boom")
+
+    class _CountingLogger:
+        def __init__(self):
+            self.errors = []
+
+        def error(self, msg, *a):
+            self.errors.append(msg)
+
+        def warn(self, *a, **k):
+            pass
+
+        def info(self, *a, **k):
+            pass
+
+    logger = _CountingLogger()
+    sched = Scheduler(Engine(EngineConfig(model="test-tiny", max_slots=4, max_seq_len=128, dtype="float32",
+                                          max_prefill_batch=2, use_mesh=False)), logger=logger)
+    sched.timeline = _BrokenTimeline()
+    sched.start()
+    try:
+        # 96 tokens = ~13 decode chunks + the prefill: comfortably past
+        # the 8-consecutive-failures disable threshold.
+        out, reason = generate_sync(sched, [1, 2, 3], max_tokens=96)
+        assert len(out) > 0  # serving survived the observer
+        # Enough steps ran to cross the disable threshold.
+        assert sched.timeline is None and not sched._observing
+        # Rate limit: first failure + the disable notice, not one per step.
+        assert 1 <= len(logger.errors) <= 3, logger.errors
+        assert any("disabled" in m for m in logger.errors)
+    finally:
+        sched.stop()
