@@ -215,6 +215,29 @@ def _write_kv(ck, cv, qkv, write_idx, plane, flat: int, total: int, skip=None):
     return ck.at[w_idx].set(qkv[3], mode="drop"), cv.at[w_idx].set(qkv[4], mode="drop"), w_idx
 
 
+def _flat_index(write_idx, plane, flat: int, total: int):
+    """``_write_kv``'s indices for a mixer that writes its own pool."""
+    return jnp.where(write_idx >= flat, total, write_idx + plane * flat)
+
+
+def _owns_pool(mixer) -> bool:
+    """An attention seam that projects its own queries and owns the ONLY pool
+    (ISSUE 47: ``cache[mixer["pool"]]``, no ``k`` and no ``v``)."""
+    return mixer is not None and "pool" in mixer
+
+
+def _block_in(residual, x, lp: Params, block: str):
+    """The residual seam's read (ISSUE 47): what block ``block`` ("attn" |
+    "ffn") of a layer reads of the stream, and what its write-back needs.
+    ``residual`` None is the one stream of ``hidden_size``, read whole."""
+    return (x, None) if residual is None else residual["read"](x, lp, block)
+
+
+def _block_out(residual, x, y, maps):
+    """The seam's write-back of a block's output ``y``: ``x + y`` for None."""
+    return x + y if residual is None else residual["write"](x, y, maps)
+
+
 def _mix_by_kind(kinds: dict, group: tuple, li, h, ck, cv, store, ops):
     """Layer ``li``'s token mixer in a model whose layers are of two KINDS
     (ISSUE 43; ``kinds`` is the seam of ``forward_paged_impl``): an attention
@@ -577,6 +600,7 @@ def forward_paged_impl(
     mixer=None,  # the attention seam, below; a learned sparse attention plugs in here
     prefix=None,  # leading layers with an FFN of their own (_layer_groups), or None
     kinds=None,  # a per-layer kind with a per-kind cache, below; a layer that owns no pages plugs in here
+    residual=None,  # the residual seam, below; more than one stream a token plugs in here
 ):
     """Shared paged-decoder skeleton: attention + cache paging are
     family-independent; the FFN block (dense gated MLP vs MoE) is the
@@ -598,6 +622,26 @@ def forward_paged_impl(
     they write their own arrays, attend, and return ``(attention, the
     pools they wrote {name: array}, a per-layer auxiliary)``. With a mixer
     a layer's auxiliary is the pair (the FFN's, the mixer's).
+
+    A mixer that OWNS the pool (ISSUE 47, a latent attention): the dict also
+    has ``"pool": name``; the cache is then ``{name: (L, P, page_size, W)}``
+    alone, no ``k`` and no ``v``, and the skeleton projects nothing.
+    ``decode(h, lp, li, positions, pool, w_idx, table, lengths, page_size=)``
+    and ``ragged(h, lp, li, positions, pool, w_idx, table, q_starts, q_lens,
+    kv_lens, page_size=)`` get the layer's normed input and the flat pool
+    (L * P * page_size, W); they project, write their rows at ``w_idx``,
+    attend, and return ``(the heads' outputs, folded, for ``wo``; the pool)``.
+    It has no auxiliary, so it may stand behind a ``prefix``.
+
+    The residual seam (ISSUE 47): ``residual`` is None for every model with
+    ONE stream of ``hidden_size`` (``x + y``; nothing below changes for them),
+    or a dict ``{"enter": fn, "read": fn, "write": fn, "exit": fn}`` for one
+    whose layer loop carries several streams a token (in whatever layout the
+    seam chooses: the loop only carries it): ``enter(x)`` makes them of the
+    embedding, ``read(X, lp, block) -> (u, maps)`` is what block
+    ``"attn"`` or ``"ffn"`` of a layer reads (its own input norm follows),
+    ``write(X, y, maps)`` the stream after the block's output ``y``,
+    ``exit(X)`` what the final norm reads.
 
     The per-layer kind (ISSUE 43): ``kinds`` is None for every model whose
     layers all write keys (nothing below changes for them), or a dict
@@ -626,12 +670,15 @@ def forward_paged_impl(
 
     B, T = tokens.shape
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.hd
-    L, P, page_size, HkvD = cache["k"].shape
+    own = _owns_pool(mixer)
+    L, P, page_size, HkvD = cache[mixer["pool"] if own else "k"].shape
     flat = P * page_size
 
     x = params["embed"][tokens]
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
+    if residual is not None:
+        x = residual["enter"](x)
     inv_freq = rope_inv_freq(cfg.hd, cfg.rope_theta, cfg.rope_scaling_dict)
     cos, sin = rope_cos_sin(positions, inv_freq)
 
@@ -677,7 +724,7 @@ def forward_paged_impl(
         raise NotImplementedError(
             f"a model with an attention seam or a state store admits through the mixed step; forward_paged mode {mode!r} has no path for it")
 
-    assert mixer is None or prefix is None, "an attention seam's counts of a prefix's layers would be lost"
+    assert mixer is None or own or prefix is None, "an attention seam's counts of a prefix's layers would be lost"
 
     project = partial(_project_qkv, cfg=cfg, cos=cos, sin=sin, cache=cache)
     write = partial(_write_kv, write_idx=write_idx, flat=flat, total=total)
@@ -695,6 +742,11 @@ def forward_paged_impl(
         """The attention operator on a layer's normed input: ``ap`` holds its
         leaves, ``plane`` is its plane of the pool. Returns (the residual
         contribution, the pools, the attention seam's auxiliary or None)."""
+        if own:
+            attn, ck = mixer["decode"](h[:, 0], ap, li, positions[:, 0], ck,
+                                       _flat_index(write_idx[:, 0], plane, flat, total), page_table + plane * P,
+                                       lengths, page_size=page_size)
+            return _attn_out(attn.reshape(B, T, -1), h, ap, cfg), ck, cv, ce, None
         q, k, v, *_ = qkv = project(h, ap, li)
         ck, cv, w_idx = write(ck, cv, qkv, plane=plane)
         if mixer is None and decode:
@@ -739,20 +791,22 @@ def forward_paged_impl(
         x, ck, cv, *ce = carry  # ck/cv: (L*P*page_size, HkvD) flat carry
         lp, li = per_layer
         with jax.named_scope("attention"):
-            h = rms_norm(x, _nw(lp["attn_norm"], cfg), cfg.rms_norm_eps)
+            u, maps = _block_in(residual, x, lp, "attn")
+            h = rms_norm(u, _nw(lp["attn_norm"], cfg), cfg.rms_norm_eps)
             if kinds is None:
                 out, ck, cv, ce, mix_aux = attention(h, lp, li, li, ck, cv, ce)
             else:
                 out, ck, cv, ce[0] = _mix_by_kind(kinds, group, li, h, ck, cv, ce[0], (project, write, attend))
-            x = x + out
+            x = _block_out(residual, x, out, maps)
         with jax.named_scope("mlp"):
-            y = ffn(x, h, lp, li, tok_valid)
+            u, maps = _block_in(residual, x, lp, "ffn")
+            y = ffn(u, h, lp, li, tok_valid)
             y, aux = y if isinstance(y, tuple) else (y, None)
-            x = x + _ffn_out(y, lp, cfg)
-        return (x, ck, cv, *ce), (aux if mixer is None else (aux, mix_aux))
+            x = _block_out(residual, x, _ffn_out(y, lp, cfg), maps)
+        return (x, ck, cv, *ce), (aux if mixer is None or own else (aux, mix_aux))
 
-    ck0 = cache["k"].reshape(total, HkvD)
-    cv0 = cache["v"].reshape(total, HkvD)
+    ck0 = cache[mixer["pool"] if own else "k"].reshape(total, HkvD)
+    cv0 = None if own else cache["v"].reshape(total, HkvD)  # (None: an empty leaf of the carry)
     # An attention seam's arrays are carried by page, a state store as it lies.
     carry = (x, ck0, cv0, *(cache[n] if kinds is not None else cache[n].reshape((L * P,) + cache[n].shape[2:])
                             for n in extra))
@@ -760,11 +814,16 @@ def forward_paged_impl(
         carry, aux = jax.lax.scan(partial(body, ffn=group_ffn, group=(first, count)), carry,
                                   (leaves, jnp.arange(first, first + count)))
     x, ck, cv, *ce = carry
-    new_cache = {"k": ck.reshape(L, P, page_size, HkvD),
-                 "v": cv.reshape(L, P, page_size, HkvD),
-                 **{n: a.reshape(cache[n].shape) for n, a in zip(extra, ce)}}
+    if own:
+        new_cache = {mixer["pool"]: ck.reshape(L, P, page_size, HkvD)}
+    else:
+        new_cache = {"k": ck.reshape(L, P, page_size, HkvD),
+                     "v": cv.reshape(L, P, page_size, HkvD),
+                     **{n: a.reshape(cache[n].shape) for n, a in zip(extra, ce)}}
 
     with jax.named_scope("head"):
+        if residual is not None:
+            x = residual["exit"](x)
         x = rms_norm(x, _nw(params["final_norm"], cfg), cfg.rms_norm_eps)
         if last_only:
             if mode == "decode":
@@ -833,6 +892,7 @@ def forward_ragged_impl(
     mixer=None,  # the attention seam of forward_paged_impl
     prefix=None,  # the leading group of forward_paged_impl
     kinds=None,  # the per-layer kind of forward_paged_impl
+    residual=None,  # the residual seam of forward_paged_impl
 ):
     """Shared ragged skeleton, same flat-carry cache discipline as
     forward_paged_impl (the scatter lowers to an in-place row update;
@@ -841,13 +901,16 @@ def forward_ragged_impl(
 
     B, T = tokens.shape  # B == 1: the packed axis IS the batch
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.hd
-    L, P, page_size, HkvD = cache["k"].shape
+    own = _owns_pool(mixer)
+    L, P, page_size, HkvD = cache[mixer["pool"] if own else "k"].shape
     flat = P * page_size
     total = L * flat
 
     x = params["embed"][tokens]
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
+    if residual is not None:
+        x = residual["enter"](x)
     inv_freq = rope_inv_freq(cfg.hd, cfg.rope_theta, cfg.rope_scaling_dict)
     cos, sin = rope_cos_sin(positions, inv_freq)
     # Rows are packed back to back from 0: the tail past them is padding.
@@ -855,7 +918,7 @@ def forward_ragged_impl(
 
     assert mixer is None or kinds is None, "an attention seam beside a per-layer kind: no model has both"
     extra = tuple(mixer["cache"]) if mixer is not None else (kinds["cache"],) if kinds is not None else ()
-    assert mixer is None or prefix is None, "an attention seam's counts of a prefix's layers would be lost"
+    assert mixer is None or own or prefix is None, "an attention seam's counts of a prefix's layers would be lost"
 
     project = partial(_project_qkv, cfg=cfg, cos=cos, sin=sin, cache=cache)
     write = partial(_write_kv, write_idx=write_idx, flat=flat, total=total)
@@ -870,6 +933,11 @@ def forward_ragged_impl(
         return _attn_out(attn.reshape(B, T, Hq * D), h, ap, cfg)
 
     def attention(h, ap, li, plane, ck, cv, ce):
+        if own:
+            attn, ck = mixer["ragged"](h[0], ap, li, positions[0], ck,
+                                       _flat_index(write_idx[0], plane, flat, total), page_table + plane * P,
+                                       q_starts, q_lens, kv_lens, page_size=page_size)
+            return _attn_out(attn.reshape(B, T, -1), h, ap, cfg), ck, cv, ce, None
         q, *_ = qkv = project(h, ap, li)
         ck, cv, w_idx = write(ck, cv, qkv, plane=plane)
         if mixer is None:
@@ -885,31 +953,38 @@ def forward_ragged_impl(
         x, ck, cv, *ce = carry
         lp, li = per_layer
         with jax.named_scope("attention"):
-            h = rms_norm(x, _nw(lp["attn_norm"], cfg), cfg.rms_norm_eps)
+            u, maps = _block_in(residual, x, lp, "attn")
+            h = rms_norm(u, _nw(lp["attn_norm"], cfg), cfg.rms_norm_eps)
             if kinds is None:
                 out, ck, cv, ce, mix_aux = attention(h, lp, li, li, ck, cv, ce)
             else:
                 out, ck, cv, ce[0] = _mix_by_kind(kinds, group, li, h, ck, cv, ce[0], (project, write, attend))
-            x = x + out
+            x = _block_out(residual, x, out, maps)
         with jax.named_scope("mlp"):
-            y = ffn(x, h, lp, li, tok_valid)
+            u, maps = _block_in(residual, x, lp, "ffn")
+            y = ffn(u, h, lp, li, tok_valid)
             y, aux = y if isinstance(y, tuple) else (y, None)
-            x = x + _ffn_out(y, lp, cfg)
-        return (x, ck, cv, *ce), (aux if mixer is None else (aux, mix_aux))
+            x = _block_out(residual, x, _ffn_out(y, lp, cfg), maps)
+        return (x, ck, cv, *ce), (aux if mixer is None or own else (aux, mix_aux))
 
-    ck0 = cache["k"].reshape(total, HkvD)
-    cv0 = cache["v"].reshape(total, HkvD)
+    ck0 = cache[mixer["pool"] if own else "k"].reshape(total, HkvD)
+    cv0 = None if own else cache["v"].reshape(total, HkvD)  # (None: an empty leaf of the carry)
     carry = (x, ck0, cv0, *(cache[n] if kinds is not None else cache[n].reshape((L * P,) + cache[n].shape[2:])
                             for n in extra))
     for leaves, group_ffn, first, count in _layer_groups(params, ffn, unscanned, prefix, cfg.num_layers):
         carry, aux = jax.lax.scan(partial(body, ffn=group_ffn, group=(first, count)), carry,
                                   (leaves, jnp.arange(first, first + count)))
     x, ck, cv, *ce = carry
-    new_cache = {"k": ck.reshape(L, P, page_size, HkvD),
-                 "v": cv.reshape(L, P, page_size, HkvD),
-                 **{n: a.reshape(cache[n].shape) for n, a in zip(extra, ce)}}
+    if own:
+        new_cache = {mixer["pool"]: ck.reshape(L, P, page_size, HkvD)}
+    else:
+        new_cache = {"k": ck.reshape(L, P, page_size, HkvD),
+                     "v": cv.reshape(L, P, page_size, HkvD),
+                     **{n: a.reshape(cache[n].shape) for n, a in zip(extra, ce)}}
 
     with jax.named_scope("head"):
+        if residual is not None:
+            x = residual["exit"](x)
         x = rms_norm(x, _nw(params["final_norm"], cfg), cfg.rms_norm_eps)
         # Per-ROW logits at each row's last packed query (inactive rows are
         # clamped to index 0; the caller ignores them).

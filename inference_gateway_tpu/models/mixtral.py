@@ -9,7 +9,12 @@ expert layers in one layer stack, a sigmoid router with a selection bias
 and an always-on shared expert, gated attention under sandwich norms), and
 the LFM2 block (ISSUE 43, ``lfm2_moe``: gated short convolutions beside a few
 GQA layers in one stack, a per-layer KIND with a per-kind cache on the shared
-skeletons; the sigmoid router with no shared expert).
+skeletons; the sigmoid router with no shared expert), and the Xing4.0 block
+(ISSUE 47, ``xing4_0``: a latent attention whose one pool of compressed rows
+rides the attention seam as its owner, ops/mla_attention.py, and several
+residual streams a token mixed by hyper-connections, ops/hyper_connections.py,
+on the skeletons' residual seam; Trinity's dense prefix, sigmoid router and
+shared expert).
 
 Same TPU-first skeleton as models/llama.py (stacked layers + lax.scan,
 the shared paged and ragged forwards) with the dense MLP replaced by a
@@ -42,7 +47,7 @@ from inference_gateway_tpu.ops.attention import causal_prefill_mask, decode_mask
 from inference_gateway_tpu.ops.moe import ACTIVATIONS, moe_dense, moe_dropless, router_sigmoid, router_topk
 from inference_gateway_tpu.ops.norms import rms_norm
 from inference_gateway_tpu.ops.quant import qeinsum, qmatmul, seeded_qk_gain, seeded_std
-from inference_gateway_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
+from inference_gateway_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq, yarn_mscale
 from inference_gateway_tpu.ops.sparse_attention import one_query_tokens_read, sparse_decode, sparse_ragged
 from inference_gateway_tpu.ops.ssm import conv_decode, conv_ragged, token_segments
 
@@ -95,6 +100,25 @@ class MixtralConfig(LlamaConfig):
     # among its kind, as are the page pool's planes (cache_layers of them).
     layer_conv: tuple | None = None
     conv_kernel: int = 3
+    # Xing4.0's block (ISSUE 47). kv_lora_rank 0: none. A latent attention
+    # (the DeepSeek-V2 form): queries through a q_lora_rank bottleneck with a
+    # norm, num_heads of qk_nope_head_dim + qk_rope_head_dim (= head_dim);
+    # keys and values up from ONE normed latent of kv_lora_rank a token, beside
+    # one rotary key of qk_rope_head_dim all heads share; values of v_head_dim.
+    # The page pool is the one array ``kv``, a row [latent, rotary key, zeros
+    # to the lane grid] a token a layer (``latent_row``); no ``k``, no ``v``.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # hc_mult 0: one residual stream. Else that many streams a token, mixed
+    # around each block by manifold-constrained hyper-connections: leaves
+    # hc_{attn,ffn}_{phi,alpha,bias}, float32 (ops/hyper_connections.py).
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: tuple = (-30.0, 30.0)
 
     def __post_init__(self):
         super().__post_init__()
@@ -113,6 +137,13 @@ class MixtralConfig(LlamaConfig):
                 raise ValueError(f"layer_conv {flags}: one flag a layer of {self.num_layers}, both kinds present")
             if self.indexer_topk or self.attn_gate or self.sandwich_norm or self.qkv_bias:
                 raise ValueError("layer_conv: the attention operators' stack carries no indexer, gate, post-norm or bias")
+        if self.kv_lora_rank:
+            if self.hd != self.qk_nope_head_dim + self.qk_rope_head_dim or not self.q_lora_rank or not self.v_head_dim:
+                raise ValueError("kv_lora_rank: head_dim is qk_nope_head_dim + qk_rope_head_dim, under a q_lora_rank")
+            if (self.indexer_topk or self.layer_conv is not None or self.sliding_window or self.layer_rope is not None
+                    or self.qk_norm or self.attn_gate or self.sandwich_norm or self.qkv_bias):
+                raise ValueError("kv_lora_rank: the latent attention carries no indexer, kind, window, NoPE layer, "
+                                 "q/k norm, gate, post-norm or bias")
 
     @property
     def expert_layers(self) -> int:
@@ -127,6 +158,17 @@ class MixtralConfig(LlamaConfig):
     def cache_layers(self) -> int:
         """Layers that write the page pool: its leading axis."""
         return self.num_layers - self.conv_layers
+
+    @property
+    def latent_width(self) -> int:
+        """What a latent attention caches a token a layer: the latent and the rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_row(self) -> int:
+        """The pool's row: ``latent_width`` padded with zeros to the 128-lane
+        grid the chip stores and the kernels' DMAs move (ops/mla_attention.py)."""
+        return -(-self.latent_width // 128) * 128
 
     @property
     def state_bytes_per_slot(self) -> int:
@@ -152,6 +194,14 @@ def attention_leaves(cfg: MixtralConfig, count: int, dtype, matrix) -> Params:
     at ``seeded_std(cfg, name)`` (quantised or not: both builds call this)."""
     H, Hq, Hkv, D = cfg.hidden_size, cfg.num_heads, cfg.num_kv_heads, cfg.hd
     ones = lambda width: jnp.ones((count, width), dtype)
+    if cfg.kv_lora_rank:
+        # W_DQ, W_UQ (heads x [nope, rope]), W_DKV ([latent, rotary key]), W_UKV
+        # (heads x [k_nope, v]), W_O, and the two bottlenecks' norms.
+        return {"attn_norm": ones(H), "wdq": matrix("wdq", (count, H, cfg.q_lora_rank)),
+                "q_a_norm": ones(cfg.q_lora_rank), "wuq": matrix("wuq", (count, cfg.q_lora_rank, Hq * D)),
+                "wdkv": matrix("wdkv", (count, H, cfg.latent_width)), "kv_a_norm": ones(cfg.kv_lora_rank),
+                "wukv": matrix("wukv", (count, cfg.kv_lora_rank, Hq * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
+                "wo": matrix("wo", (count, Hq * cfg.v_head_dim, H))}
     out: Params = {
         "attn_norm": ones(H),
         "wq": matrix("wq", (count, H, Hq * D)), "wk": matrix("wk", (count, H, Hkv * D)),
@@ -167,13 +217,22 @@ def attention_leaves(cfg: MixtralConfig, count: int, dtype, matrix) -> Params:
     return out
 
 
-def layer_leaves(cfg: MixtralConfig, count: int, dtype, matrix) -> Params:
+def layer_leaves(cfg: MixtralConfig, count: int, dtype, matrix, plain=None) -> Params:
     """What a stack of ``count`` layers holds of the token mixer: its
     ``attention_leaves``; in a model with a per-layer kind the operator's input
-    norm alone, the operators being stacks of their own (``operator_leaves``)."""
-    if cfg.layer_conv is None:
-        return attention_leaves(cfg, count, dtype, matrix)
-    return {"attn_norm": jnp.ones((count, cfg.hidden_size), dtype)}
+    norm alone, the operators being stacks of their own (``operator_leaves``).
+    With hyper-connections also both blocks' maps (``plain(name, shape)``
+    builds a float32 leaf that is never quantised)."""
+    if cfg.layer_conv is not None:
+        return {"attn_norm": jnp.ones((count, cfg.hidden_size), dtype)}
+    out = attention_leaves(cfg, count, dtype, matrix)
+    if cfg.hc_mult:
+        n = cfg.hc_mult
+        for block in ("attn", "ffn"):
+            out[f"hc_{block}_phi"] = plain("hc_phi", (count, n * cfg.hidden_size, 2 * n + n * n))
+            out[f"hc_{block}_alpha"] = jnp.ones((count, 3), jnp.float32)
+            out[f"hc_{block}_bias"] = plain("hc_bias", (count, 2 * n + n * n))
+    return out
 
 
 CONV_LEAVES = ("conv_in", "conv_w", "conv_out")
@@ -213,8 +272,13 @@ def init_params(rng: jax.Array, cfg: MixtralConfig, dtype=jnp.bfloat16) -> Param
     def lead_matrix(name, shape):
         return norm(next(more), shape, seeded_std(cfg, name))
 
+    hc_keys = iter(jax.random.split(jax.random.fold_in(rng, 47), 8))
+
+    def plain(name, shape):  # the hyper-connections' float32 leaves, a stream of their own
+        return jax.random.normal(next(hc_keys), shape, jnp.float32) * seeded_std(cfg, name)
+
     layers: Params = {
-        **layer_leaves(cfg, L, dtype, matrix),
+        **layer_leaves(cfg, L, dtype, matrix, plain),
         **operator_leaves(cfg, dtype, matrix, lead_matrix),
         "moe_norm": jnp.ones((L, H), dtype),
         "router": matrix("router", (L, H, E)),
@@ -238,7 +302,7 @@ def init_params(rng: jax.Array, cfg: MixtralConfig, dtype=jnp.bfloat16) -> Param
                       shared_wd=matrix("shared_wd", (L, Is, H)))
     if cfg.num_dense_layers:
         Lp, Id = cfg.num_dense_layers, cfg.dense_intermediate_size
-        lead = {**layer_leaves(cfg, Lp, dtype, lead_matrix), "mlp_norm": jnp.ones((Lp, H), dtype),
+        lead = {**layer_leaves(cfg, Lp, dtype, lead_matrix, plain), "mlp_norm": jnp.ones((Lp, H), dtype),
                 "wg": lead_matrix("dense_wg", (Lp, H, Id)), "wu": lead_matrix("dense_wu", (Lp, H, Id)),
                 "wd": lead_matrix("dense_wd", (Lp, Id, H))}   # (sized by their own names: seeded_std)
         layers.update({PREFIX + n: w for n, w in lead.items()})
@@ -308,9 +372,9 @@ def forward(
     """Same contract as models/llama.forward (contiguous cache, masked
     einsum attention; ``--attention paged`` serves through forward_paged
     and forward_ragged). ``mesh``: the one the experts are sharded over."""
-    if cfg.indexer_topk:
+    if cfg.indexer_topk or cfg.kv_lora_rank:
         raise NotImplementedError(
-            "a model with a sparse-attention indexer is served over the paged cache "
+            "a model with a sparse-attention indexer or a latent attention is served over the paged cache "
             "(--attention paged: the mixed step and the decode chunk); the dense cache has no path for it")
     B, T = tokens.shape
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.hd
@@ -559,6 +623,103 @@ def _dsa_ragged(h, q, lp, li, positions, pools, w_idx, table, q_starts, q_lens, 
     return attn, {"ki": cki}, jnp.stack([jnp.sum(sees), jnp.sum(walked)]).astype(jnp.float32)
 
 
+MLA_POOL = "kv"  # the latent attention's one pool: (L, P, page_size, latent_row)
+
+
+def _latent_project(h, lp: Params, positions, cfg: MixtralConfig):
+    """The latent attention's projections of N tokens' normed input h (N, H):
+    the ABSORBED queries (N, heads, latent_width), a head's q_nope carried
+    into the latent's space by its block of W_UK beside its rotary part, and
+    the tokens' pool rows (N, latent_row): [the normed latent, the rotary key,
+    zeros]. W_UKV is dequantised whole (8 MB at the published sizes) and cut
+    into the heads' [k_nope, v] blocks; returns also W_UV (rank, heads, v)."""
+    N = h.shape[0]
+    Hq, Dn, Dr, Dv, rank = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    cos, sin = rope_cos_sin(positions, rope_inv_freq(Dr, cfg.rope_theta, cfg.rope_scaling_dict))
+    with jax.named_scope("mla.project"):
+        c_q = rms_norm(qmatmul(h, lp["wdq"]), lp["q_a_norm"], cfg.rms_norm_eps)
+        q = qmatmul(c_q, lp["wuq"]).reshape(N, Hq, Dn + Dr)
+        q_rope = apply_rope(q[..., Dn:], cos, sin)
+        down = qmatmul(h, lp["wdkv"])
+        latent = rms_norm(down[:, :rank], lp["kv_a_norm"], cfg.rms_norm_eps)
+        k_rope = apply_rope(down[:, None, rank:], cos, sin)[:, 0]
+        row = jnp.concatenate([latent, k_rope, jnp.zeros((N, cfg.latent_row - cfg.latent_width), h.dtype)], axis=-1)
+    with jax.named_scope("mla.absorb"):
+        w = lp["wukv"]
+        w = (w.q.astype(h.dtype) * w.scale.astype(h.dtype)) if hasattr(w, "scale") else w
+        w = w.reshape(rank, Hq, Dn + Dv)
+        q_lat = jnp.einsum("nhd,chd->nhc", q[..., :Dn], w[..., :Dn], preferred_element_type=jnp.float32)
+        q_abs = jnp.concatenate([q_lat.astype(h.dtype), q_rope], axis=-1)
+    return q_abs, row, w[..., Dn:]
+
+
+def _latent_out(attn, w_uv, dtype):
+    """The heads' weighted latents (N, heads, rank) up through W_UV: (N, heads * v)."""
+    with jax.named_scope("mla.absorb"):
+        out = jnp.einsum("nhc,chd->nhd", attn, w_uv, preferred_element_type=jnp.float32)
+        return out.reshape(out.shape[0], -1).astype(dtype)
+
+
+def mla_scale(cfg: MixtralConfig) -> float:
+    """The scores' factor: head_dim ** -0.5 times YaRN's m squared."""
+    return cfg.hd ** -0.5 * yarn_mscale(cfg.rope_scaling_dict) ** 2
+
+
+def _latent_attention(h, lp, positions, pool, w_idx, page_size: int, cfg: MixtralConfig, read):
+    """Both sides of the owning attention seam: project, write the tokens' rows
+    into the flat pool, ``read(queries, pages)`` them in the absorbed form (each
+    page once, as key and as value), and carry the weighted latents up."""
+    q, row, w_uv = _latent_project(h, lp, positions, cfg)
+    pool = pool.at[w_idx].set(row.astype(pool.dtype), mode="drop")
+    attn = read(q, pool.reshape(-1, page_size, pool.shape[-1]), value_dim=cfg.kv_lora_rank, scale=mla_scale(cfg))
+    return _latent_out(attn, w_uv, h.dtype), pool
+
+
+def _mla_decode(h, lp, li, positions, pool, w_idx, table, lengths, *, page_size, cfg, valid=None):
+    """The seam's decode side (llama.forward_paged_impl): one query a slot."""
+    del li
+    from inference_gateway_tpu.ops.mla_attention import mla_paged_attention
+
+    if valid is not None:  # a row that carries no stream sees nothing: the walk passes over it
+        lengths = jnp.where(valid, lengths, 0)
+    return _latent_attention(h, lp, positions, pool, w_idx, page_size, cfg,
+                             lambda q, pages, **kw: mla_paged_attention(q, pages, table, lengths, **kw))
+
+
+def _mla_ragged(h, lp, li, positions, pool, w_idx, table, q_starts, q_lens, kv_lens, *, page_size, cfg):
+    """The seam's mixed-step side: the packed rows; a chunk's queries go
+    absorbed through the same walk, a tile of them at a time."""
+    del li
+    from inference_gateway_tpu.ops.mla_attention import mla_ragged_attention
+
+    return _latent_attention(h, lp, positions, pool, w_idx, page_size, cfg,
+                             lambda q, pages, **kw: mla_ragged_attention(q, pages, table, q_starts, q_lens, kv_lens, **kw))
+
+
+def _hyper_connections(cfg: MixtralConfig) -> dict:
+    """The residual seam (llama.forward_paged_impl) for ``hc_mult`` streams a
+    token: every stream starts as the embedding, a block reads H_pre X and is
+    written back as H_res X + H_post^T y, the final norm reads their sum. The
+    layer loop carries them stream-major, (hc_mult, B, T, hidden)."""
+    from inference_gateway_tpu.ops import hyper_connections as hc
+
+    n = cfg.hc_mult
+
+    def read(X, lp, block):
+        flat = X.reshape(n, -1, X.shape[-1])
+        pre, post, res = hc.maps(flat, lp[f"hc_{block}_phi"], lp[f"hc_{block}_alpha"], lp[f"hc_{block}_bias"],
+                                 iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps, clamp=cfg.hc_clamp,
+                                 norm_eps=cfg.rms_norm_eps)
+        return hc.read(flat, pre).reshape(X.shape[1:]), (post, res)
+
+    def write(X, y, maps):
+        return hc.write(X.reshape(n, -1, X.shape[-1]), y.reshape(-1, y.shape[-1]), *maps).reshape(X.shape)
+
+    return {"enter": lambda x: jnp.broadcast_to(x[None], (n,) + x.shape),
+            "read": read, "write": write,
+            "exit": lambda X: jnp.sum(X.astype(jnp.float32), axis=0).astype(X.dtype)}
+
+
 def _seam(params: Params, cfg: MixtralConfig, mesh, valid=None, conv=None) -> dict:
     """What the shared skeletons take for a mixture: the FFN seam, the
     leaves it reads whole by layer index (never sliced per layer), for a
@@ -581,6 +742,12 @@ def _seam(params: Params, cfg: MixtralConfig, mesh, valid=None, conv=None) -> di
         seam["mixer"] = {"cache": DSA_CACHE,
                          "decode": partial(_dsa_decode, cfg=cfg, valid=valid),
                          "ragged": partial(_dsa_ragged, cfg=cfg)}
+    if cfg.kv_lora_rank:
+        seam["mixer"] = {"cache": (), "pool": MLA_POOL,
+                         "decode": partial(_mla_decode, cfg=cfg, valid=valid),
+                         "ragged": partial(_mla_ragged, cfg=cfg)}
+    if cfg.hc_mult:
+        seam["residual"] = _hyper_connections(cfg)
     return seam
 
 
@@ -597,15 +764,39 @@ def counts_window_pages(cfg: MixtralConfig) -> bool:
     attention reads what the indexer chooses). So does one whose pool holds
     its caching layers alone (ISSUE 43): no window there, so both counts are
     the pages its rows hold, over the caching layers."""
-    return (windowed_layers(cfg) > 0 and not cfg.indexer_topk) or cfg.layer_conv is not None
+    return (windowed_layers(cfg) > 0 and not cfg.indexer_topk) or cfg.layer_conv is not None or bool(cfg.kv_lora_rank)
 
 
-def counted_window_pages(cfg: MixtralConfig, page_size: int, first_len, last_len):
+def counted_window_pages(cfg: MixtralConfig, page_size: int, first_len, last_len, max_queries: int = 1):
     """``llama.window_pages`` over this model's windowed layers (its caching
     layers, with a window no context reaches, where it has convolution layers)."""
     if cfg.layer_conv is not None:
         return window_pages(cfg.cache_layers, 1 << 30, page_size, first_len, last_len)
+    if cfg.kv_lora_rank:
+        return latent_pages(cfg, page_size, first_len, last_len, max_queries)
     return window_pages(windowed_layers(cfg), cfg.sliding_window, page_size, first_len, last_len)
+
+
+def latent_pages(cfg: MixtralConfig, page_size: int, first_len, last_len, max_queries: int):
+    """What a step's rows WALK of the latent pool and what their contexts hold,
+    in pages over all layers (ISSUE 47; the engine's LATENT_STATS): a row whose
+    queries (``max_queries`` at the most) see lengths first_len..last_len (0 = no row) is walked a tile of
+    ``chunk_tile`` queries at a time, each tile from the row's first page to
+    its own horizon (ops/mla_attention.py); a one-query row is one tile.
+    Returns float32 (3,): latent_pages_read, context_pages, and of the first
+    what the one-query rows walked (latent_row_pages_read: the rest is the
+    chunks' tiles')."""
+    from inference_gateway_tpu.ops.mla_attention import chunk_tile
+
+    tile = chunk_tile(cfg.num_heads, page_size)
+    live = last_len > 0
+    queries = jnp.where(live, last_len - first_len + 1, 0)
+    c = jnp.arange(-(-max_queries // tile), dtype=last_len.dtype)[None, :]  # the tiles a row can have
+    ends = jnp.minimum(first_len[:, None] + (c + 1) * tile - 1, last_len[:, None])
+    walked = jnp.where(c * tile < queries[:, None], (ends + page_size - 1) // page_size, 0)
+    pages = jnp.where(live, (last_len + page_size - 1) // page_size, 0)
+    return cfg.num_layers * jnp.stack([jnp.sum(walked), jnp.sum(pages),
+                                       jnp.sum(jnp.where(queries == 1, pages, 0))]).astype(jnp.float32)
 
 
 def init_paged_cache(cfg: MixtralConfig, cache_cfg, dtype=jnp.bfloat16) -> Params:
@@ -614,6 +805,8 @@ def init_paged_cache(cfg: MixtralConfig, cache_cfg, dtype=jnp.bfloat16) -> Param
     same page ids; with convolution layers, planes for the caching layers
     alone and the state store beside them (``init_state``)."""
     pages = cache_cfg.resolve_num_pages()
+    if cfg.kv_lora_rank:  # one array, a latent row a token a layer: no k, no v
+        return {MLA_POOL: jnp.zeros((cfg.num_layers, pages, cache_cfg.page_size, cfg.latent_row), dtype)}
     plane = (cfg.cache_layers, pages, cache_cfg.page_size)
     cache = {"k": jnp.zeros(plane + (cfg.num_kv_heads * cfg.hd,), dtype),
              "v": jnp.zeros(plane + (cfg.num_kv_heads * cfg.hd,), dtype), **init_state(cfg, cache_cfg.max_slots)}
@@ -622,6 +815,10 @@ def init_paged_cache(cfg: MixtralConfig, cache_cfg, dtype=jnp.bfloat16) -> Param
         # a token would be half a lane row, padded to a whole one on the device.
         cache["ki"] = jnp.zeros(plane[:2] + (cfg.indexer_head_dim, cache_cfg.page_size), dtype)
     return cache
+
+
+def _page_size(cfg: MixtralConfig, cache: Params) -> int:
+    return cache[MLA_POOL if cfg.kv_lora_rank else "k"].shape[2]
 
 
 @partial(jax.jit, static_argnames=("cfg", "mode", "last_only", "mesh", "with_aux"))
@@ -656,7 +853,7 @@ def forward_paged(
     if not (with_aux and counts_window_pages(cfg)):
         return out
     live = lengths if valid is None else jnp.where(valid, lengths, 0)
-    return (*out[:2], (out[2], counted_window_pages(cfg, cache["k"].shape[2], live, live)))
+    return (*out[:2], (out[2], counted_window_pages(cfg, _page_size(cfg, cache), live, live)))
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "with_aux"))
@@ -688,7 +885,8 @@ def forward_ragged(
     if not (with_aux and counts_window_pages(cfg)):
         return out
     live = jnp.where(q_lens > 0, kv_lens, 0)
-    return (*out[:2], (out[2], counted_window_pages(cfg, cache["k"].shape[2], live - q_lens + 1, live)))
+    return (*out[:2], (out[2], counted_window_pages(cfg, _page_size(cfg, cache), live - q_lens + 1, live,
+                                                    tokens.shape[1])))
 
 
 def param_specs(cfg: MixtralConfig) -> dict:
@@ -771,6 +969,18 @@ def _lfm2(layer_types: str, **sizes) -> MixtralConfig:
                          route_scale=1.0, route_eps=1e-6, rms_norm_eps=1e-5, **sizes)
 
 
+def _xing(num_layers: int, **sizes) -> MixtralConfig:
+    """Xing4.0's block (ISSUE 47, ``xing4_0``): a latent attention under YaRN
+    (the DeepSeek-V2 form its keys name), four residual streams mixed by
+    Sinkhorn-projected hyper-connections around every block, two leading dense
+    layers, then experts routed by sigmoid scores with a selection bias
+    (``noaux_tc``, one group), the chosen scores normalised and times
+    routed_scaling_factor 2, one shared expert; an untied head. Its next-token
+    prediction module is not built: nothing here speculates with it."""
+    return MixtralConfig(num_layers=num_layers, num_dense_layers=2, router_score="sigmoid", route_scale=2.0,
+                         shared_experts=1, hc_mult=4, rms_norm_eps=1e-6, rope_theta=10000.0, **sizes)
+
+
 PRESETS: dict[str, MixtralConfig] = {
     "mixtral-test-tiny": MixtralConfig(
         vocab_size=256, hidden_size=64, num_layers=2, num_heads=4, num_kv_heads=2,
@@ -836,4 +1046,23 @@ PRESETS: dict[str, MixtralConfig] = {
         "ccacccacccacccacccaccacc", vocab_size=65536, hidden_size=2048, num_heads=32, num_kv_heads=8,
         head_dim=64, intermediate_size=1792, dense_intermediate_size=7168, num_experts=32, experts_per_token=4,
         rope_theta=1000000.0, max_position_embeddings=128000),
+    # The stage's own shape at toy widths: 2 dense + 2 expert layers, a latent of
+    # 32 beside a rotary key of 8 (a pool row of 40 in 128 lanes), YaRN by 4 over
+    # an original 64 positions, so the tests' sequences pass it.
+    "xing4-test-tiny": _xing(
+        4, vocab_size=256, hidden_size=64, num_heads=4, num_kv_heads=4, head_dim=24, q_lora_rank=32,
+        kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, intermediate_size=32,
+        dense_intermediate_size=96, num_experts=8, experts_per_token=2, max_position_embeddings=512,
+        rope_scaling={"type": "yarn", "factor": 4, "original_max_position_embeddings": 64, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1}),
+    # XingChen-AGI/Xing4.0-29B-A4B is 40 layers, 30.1 GB of int8: this is the first
+    # of four pipeline stages of 10 (the 2 leading dense layers + 8 expert layers),
+    # every width, all 64 experts and the whole vocabulary as published, which one
+    # v5e serves beside a pool of 640-lane latent rows.
+    "xing4.0-stage10": _xing(
+        10, vocab_size=131072, hidden_size=3584, num_heads=32, num_kv_heads=32, head_dim=192, q_lora_rank=768,
+        kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, intermediate_size=1024,
+        dense_intermediate_size=9216, num_experts=64, experts_per_token=4, max_position_embeddings=262144,
+        rope_scaling={"type": "yarn", "factor": 64, "original_max_position_embeddings": 4096, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1}),
 }

@@ -155,6 +155,9 @@ QUANTIZABLE = ("wq", "wk", "wv", "wo", "wg", "wu", "wd",
                # LFM2's gated short convolution (ISSUE 43): its input and output
                # projections; the taps stay full precision.
                "conv_in", "conv_out",
+               # Xing4.0's latent attention (ISSUE 47): the two bottlenecks' down and
+               # up projections, in both stacks; the hyper-connections' maps stay float32.
+               "wdq", "wuq", "wdkv", "wukv", "dense_wdq", "dense_wuq", "dense_wdkv", "dense_wukv",
                # models/phi4flash.py's matmul leaves (its QUANT_LEAVES): the MLP,
                # the Mamba mixer's and the gated memory unit's projections, the
                # fused key/value projection. W_x, W_dt, the convolution, A_log, D,
@@ -253,6 +256,8 @@ def seeded_std(cfg, leaf: str) -> float:
             return fan_in ** -0.5
     if getattr(cfg, "layer_conv", None) is not None:
         return _lfm2_std(cfg, leaf)
+    if getattr(cfg, "kv_lora_rank", 0):
+        return _xing_std(cfg, leaf)
     if getattr(cfg, "sandwich_norm", False):
         return _sandwich_std(cfg, leaf)
     if getattr(cfg, "num_experts", 0):
@@ -390,6 +395,51 @@ def _lfm2_std(cfg, leaf: str) -> float:
             "dense_wg": fan, "dense_wu": fan, "dense_wd": 4.4 * cfg.dense_intermediate_size ** -0.5}.get(leaf, 0.02)
 
 
+def _xing_std(cfg, leaf: str) -> float:
+    """``seeded_std`` for Xing4.0's block (ISSUE 47): a latent attention and
+    hyper-connected streams around a dense prefix, a sigmoid router and a
+    shared expert, an untied head on an UN-normed stream. ``_lfm2_std``'s
+    regime, each scale per fan-in so that the test preset and the published
+    3,584 are in one regime; what matters is each block's SIZE against the
+    streams it joins, and that each new mechanism shows in a logit.
+
+    - the embedding at 1.0 (every stream starts as it) and the head at 1.25 x
+      hidden ** -0.5: logits that spread by 1.25 (at 1.5 and experts twice as
+      large the first run on the chip read 0.43 / 0.078 nats against limits of
+      0.45 / 0.12: PERF.md section 6, PR 47).
+    - the latent attention: every projection at fan_in ** -0.5, so c_q, the
+      latent (both normed), q, k_nope, k_rope and v are O(1); a score is then a
+      sum of 192 unit products over sqrt(192), times YaRN's m * m = 2.0: a
+      spread of 2, a head that rests on a few dozen keys (``seeded_qk_gain``'s
+      regime), a third of it from the 64 rotary dims: dropped, or rotated
+      wrongly, they move every head. The output projection at 1.5 x (heads x
+      v_head_dim) ** -0.5: attention adds ~0.5 a layer.
+    - the two leading dense MLPs' down projection at 4.4 x width ** -0.5 (gate
+      and up at hidden ** -0.5): RMS ~2 each, they set the streams' size ONCE
+      (~3) in front of all the routers.
+    - the router at hidden ** -0.5 (logits spread by 1), the selection bias at
+      2 / experts; the experts' down projection at 0.3 x width ** -0.5 (half
+      ``_lfm2_std``'s, because the chosen weights sum to route_scale 2 and not
+      to 1) and the shared expert's at 2 x width ** -0.5: the routed sum is
+      ~0.12, and ONE expert swapped by bfloat16's rounding of a score at the
+      cut moves the stream by a fortieth, as it does there, and does not
+      cascade; the shared expert adds ~0.8.
+    - the hyper-connections: phi at (streams x hidden) ** -0.5, so each map's
+      dynamic logit spreads by 1 under alpha 1; the biases normal(0, 1): H_pre
+      0.15-0.85, H_post 0.3-1.7 a stream a layer, H_res the Sinkhorn projection
+      of exp(N(0, 1.4)): far from the identity and from 1/n, so the streams
+      differ in how much of each earlier block they hold and what a block reads
+      is another mix of them than their sum."""
+    fan = cfg.hidden_size ** -0.5
+    return {"embed": 1.0, "lm_head": 1.25 * fan, "router": fan, "router_bias": 2.0 / cfg.num_experts,
+            "wdq": fan, "wuq": cfg.q_lora_rank ** -0.5, "wdkv": fan, "wukv": cfg.kv_lora_rank ** -0.5,
+            "wo": 1.5 * (cfg.num_heads * cfg.v_head_dim) ** -0.5,
+            "wg": fan, "wu": fan, "wd": 0.3 * cfg.intermediate_size ** -0.5,
+            "shared_wg": fan, "shared_wu": fan, "shared_wd": 2.0 * (cfg.shared_experts * cfg.intermediate_size) ** -0.5,
+            "dense_wg": fan, "dense_wu": fan, "dense_wd": 4.4 * cfg.dense_intermediate_size ** -0.5,
+            "hc_phi": (max(cfg.hc_mult, 1) * cfg.hidden_size) ** -0.5, "hc_bias": 1.0}[leaf]
+
+
 def seeded_qk_gain(cfg) -> float:
     """What a seeded build writes into ``q_norm`` and ``k_norm``: 1, and 1.4
     for a model with a sparse-attention indexer and for one whose sublayers'
@@ -495,9 +545,14 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
         def lead_matrix(name, shape):
             return qstack(next(more), shape, seeded_std(cfg, name))
 
+        hc_keys = iter(jax.random.split(jax.random.fold_in(rng, 47), 8))
+
+        def plain(name, shape):  # the hyper-connections' float32 leaves (ISSUE 47), a stream of their own
+            return jax.random.normal(next(hc_keys), shape, jnp.float32) * seeded_std(cfg, name)
+
         ekeys = jax.random.split(jax.random.fold_in(rng, 7), 4)
         layers = {
-            **mixtral.layer_leaves(cfg, L, dtype, matrix),
+            **mixtral.layer_leaves(cfg, L, dtype, matrix, plain),
             **mixtral.operator_leaves(cfg, dtype, matrix,
                                       lambda name, shape: norm(next(more), shape, seeded_std(cfg, name))),
             "moe_norm": jnp.ones((L, H), dtype),
@@ -521,7 +576,7 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
                           shared_wd=matrix("shared_wd", (L, Is, H)))
         if cfg.num_dense_layers:
             Lp, Id = cfg.num_dense_layers, cfg.dense_intermediate_size
-            lead = {**mixtral.layer_leaves(cfg, Lp, dtype, lead_matrix), "mlp_norm": jnp.ones((Lp, H), dtype),
+            lead = {**mixtral.layer_leaves(cfg, Lp, dtype, lead_matrix, plain), "mlp_norm": jnp.ones((Lp, H), dtype),
                     "wg": lead_matrix("dense_wg", (Lp, H, Id)), "wu": lead_matrix("dense_wu", (Lp, H, Id)),
                     "wd": lead_matrix("dense_wd", (Lp, Id, H))}   # (sized by their own names: seeded_std)
             layers.update({mixtral.PREFIX + n: w for n, w in lead.items()})

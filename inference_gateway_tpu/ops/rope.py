@@ -8,6 +8,8 @@ with static shapes.
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 
 
@@ -15,9 +17,25 @@ def rope_inv_freq(head_dim: int, theta: float, scaling: dict | None = None) -> j
     """Inverse frequencies (head_dim//2,), optionally Llama-3.1-scaled.
 
     ``scaling`` mirrors HF's ``rope_scaling`` dict for rope_type="llama3":
-    factor, low_freq_factor, high_freq_factor, original_max_position_embeddings.
+    factor, low_freq_factor, high_freq_factor, original_max_position_embeddings;
+    or for "yarn" (ISSUE 47): factor, original_max_position_embeddings,
+    beta_fast, beta_slow.
     """
     inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if scaling and scaling.get("rope_type", scaling.get("type")) == "yarn":
+        # NTK-by-parts: a pair that turns more than beta_fast times over the
+        # original context keeps its frequency, one that turns fewer than
+        # beta_slow times has it divided by ``factor``, a linear ramp over
+        # the pair index between the two correction dims.
+        old_len = scaling["original_max_position_embeddings"]
+
+        def correction_dim(turns):
+            return head_dim * math.log(old_len / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+        low = max(math.floor(correction_dim(scaling.get("beta_fast", 32))), 0)
+        high = min(math.ceil(correction_dim(scaling.get("beta_slow", 1))), head_dim - 1)
+        ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / max(high - low, 0.001), 0.0, 1.0)
+        return inv_freq / scaling["factor"] * ramp + inv_freq * (1 - ramp)
     if scaling and scaling.get("rope_type", scaling.get("type")) == "llama3":
         factor = scaling["factor"]
         low = scaling["low_freq_factor"]
@@ -30,6 +48,19 @@ def rope_inv_freq(head_dim: int, theta: float, scaling: dict | None = None) -> j
         scaled = inv_freq / factor
         inv_freq = (1 - smooth) * scaled + smooth * inv_freq
     return inv_freq
+
+
+def yarn_mscale(scaling: dict | None) -> float:
+    """YaRN's attention temperature m = 0.1 * mscale_all_dim * ln(factor) + 1
+    (1 without YaRN or at a factor of 1 or less): the scores are multiplied
+    by m * m. cos and sin carry mscale's ratio to mscale_all_dim, which is 1
+    wherever the two are equal: the one case built."""
+    if not scaling or scaling.get("rope_type", scaling.get("type")) != "yarn" or scaling["factor"] <= 1:
+        return 1.0
+    all_dim = scaling.get("mscale_all_dim", 0)
+    if scaling.get("mscale", 1) != all_dim:
+        raise ValueError("yarn: mscale and mscale_all_dim differ; cos and sin would carry their ratio, which is not built")
+    return 0.1 * all_dim * math.log(scaling["factor"]) + 1.0
 
 
 def rope_cos_sin(positions: jnp.ndarray, inv_freq: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:

@@ -307,6 +307,12 @@ WINDOW_STATS = ("window_pages_read", "context_pages")
 # over queries and layers: two more rows of the fetch block, float32 (exact
 # to 2**24, a part in 10**7 beyond).
 DSA_STATS = ("dsa_context_tokens", "dsa_kv_tokens_read")
+# A model with a latent pool (models/mixtral.py, ISSUE 47) counts, in both step
+# programs, the pages of latent its rows' tiles walked and the pages their
+# contexts hold, over all layers, and of the first what the one-query rows
+# walked (``mixtral.latent_pages``): WINDOW_STATS' rows of the fetch block under
+# its own first name, and one more.
+LATENT_STATS = ("latent_pages_read", "context_pages", "latent_row_pages_read")
 
 # Presets of models/phi4flash.py, by name: the module is imported when one
 # of them is asked for, and not on the way to any other model.
@@ -357,6 +363,12 @@ def has_indexer(model_cfg) -> bool:
     """A model whose attention reads the tokens a learned indexer chooses,
     with the indexer's keys paged beside k and v (models/mixtral.py)."""
     return bool(getattr(model_cfg, "indexer_topk", 0))
+
+
+def has_latent_pool(model_cfg) -> bool:
+    """A model whose attention caches ONE compressed row a token a layer, the
+    pool's only array (models/mixtral.py, ISSUE 47)."""
+    return bool(getattr(model_cfg, "kv_lora_rank", 0))
 
 
 def has_dense_prefix(model_cfg) -> bool:
@@ -599,7 +611,13 @@ class Engine:
         self.is_hybrid = is_hybrid(self.model_cfg)
         self.carries_state = carries_state(self.model_cfg)
         self.is_dsa = has_indexer(self.model_cfg)
+        self.is_mla = has_latent_pool(self.model_cfg)
         self._model = mixtral if self.is_moe else llama
+        if self.is_mla and config.spec_draft is not None:
+            # Refused by name (ISSUE 47); a vision model and int4 by its dense prefix's branch below.
+            raise ValueError(f"{config.model}: speculative decoding has no verify pass over the latent pool, and "
+                             "the model's own next-token-prediction module is not built; serve it without "
+                             "--spec-draft")
         if self.is_dsa:
             # What the indexer's path does not carry is refused by name (ISSUE 36).
             if config.spec_draft is not None:
@@ -677,9 +695,10 @@ class Engine:
                 max_slots=config.max_slots, max_seq_len=config.max_seq_len,
             )
             self.allocator = PageAllocator(self.page_cfg)
-            if self.carries_state or self.is_dsa:
+            if self.carries_state or self.is_dsa or self.is_mla:
                 # A model with a state store: pages for its caching layers alone,
                 # and the store beside them. An indexer: its keys under the same page ids.
+                # A latent attention: one array of compressed rows, no k and no v.
                 cache = self._model.init_paged_cache(self.model_cfg, self.page_cfg, dtype=self.dtype)
             else:
                 cache = init_paged_cache(self.model_cfg, self.page_cfg, dtype=self.dtype)
@@ -722,7 +741,7 @@ class Engine:
         # is one with an indexer (each query of a chunk selects for itself).
         ep = self.mesh.shape.get("ep", 1) if self.mesh is not None else 1
         self.mixed_ok = (
-            self.paged and (config.mixed_step or self.carries_state or self.is_dsa)
+            self.paged and (config.mixed_step or self.carries_state or self.is_dsa or self.is_mla)
             and config.spec_draft is None
             and ep == 1 and not self.pp and config.vision_model is None
         )
@@ -754,9 +773,11 @@ class Engine:
 
             platform = self.device["platform"]
             mesh_tp = self.mesh.shape.get("tp", 1) if self.mesh is not None else 1
+            # (A latent pool: every head reads one key row, ops/mla_attention.py.)
+            pool_heads, pool_row = ((1, self.model_cfg.latent_row) if self.is_mla else
+                                    (self.model_cfg.num_kv_heads, self.model_cfg.num_kv_heads * self.model_cfg.hd))
             self.attention_path, self.attention_path_reason = paged_dispatch(
-                self.model_cfg.num_kv_heads, self.model_cfg.num_heads,
-                self.model_cfg.num_kv_heads * self.model_cfg.hd, tp=mesh_tp,
+                pool_heads, self.model_cfg.num_heads, pool_row, tp=mesh_tp,
                 platform=platform,
                 n_devices=int(self.mesh.devices.size) if self.mesh is not None else 1,
                 force=FORCE_PAGED_KERNEL)
@@ -777,7 +798,16 @@ class Engine:
             layout = (self.model_cfg.num_heads // shards, kv_heads // shards,
                       self.model_cfg.num_kv_heads * self.model_cfg.hd // kv_heads, config.page_size,
                       self.page_cfg.max_pages_per_slot, self.dtype)
-            if not has_indexer(self.model_cfg):
+            if self.is_mla:
+                from inference_gateway_tpu.ops.mla_attention import mla_walk_plan
+
+                self.ragged_attention_plan = mla_walk_plan(
+                    self.model_cfg.num_heads, self.model_cfg.latent_row, config.page_size,
+                    self.page_cfg.max_pages_per_slot, self.dtype)
+                self.decode_attention_plan = {
+                    "pages_per_visit": self.ragged_attention_plan["one_query_pages_per_visit"],
+                    "operand_dtype": jnp.dtype(self.dtype).name, "skips_empty_slots": True}
+            elif not has_indexer(self.model_cfg):
                 if self.mixed_ok:
                     self.ragged_attention_plan = ragged_tile_plan(*layout)
                 self.decode_attention_plan = decode_walk_plan(*layout)
@@ -949,7 +979,8 @@ class Engine:
         # A mixture with windowed layers carries MIXED_STATS and WINDOW_STATS
         # together (ISSUE 41); one with an indexer DSA_STATS behind its own.
         self.counts_window = self.is_moe and mixtral.counts_window_pages(self.model_cfg)
-        more_stats = (DSA_STATS if self.is_dsa else ()) + (WINDOW_STATS if self.counts_window else ())
+        more_stats = ((DSA_STATS if self.is_dsa else ())
+                      + ((LATENT_STATS if self.is_mla else WINDOW_STATS) if self.counts_window else ()))
         if self.is_hybrid:
             self._chunk_stats = self._mixed_stats = WINDOW_STATS
         else:
@@ -1636,6 +1667,24 @@ class Engine:
             windowed = self._model.windowed_layers(cfg)
             out["cache_layers"] = {"window": windowed, "full": cfg.num_layers - windowed}
         return out
+
+    def latent_status(self) -> dict | None:
+        """/debug/status ``latent_pool`` of a model with a latent attention: the
+        pool's row and bytes a token, and how it is read (static)."""
+        if not self.is_mla:
+            return None
+        cfg, itemsize = self.model_cfg, jnp.dtype(self.dtype).itemsize
+        return {
+            "row_values": cfg.latent_width, "row_lanes": cfg.latent_row,
+            "row": f"[{cfg.kv_lora_rank} latent, {cfg.qk_rope_head_dim} rotary key, "
+                   f"{cfg.latent_row - cfg.latent_width} zeros to the 128-lane grid]",
+            "bytes_per_token_per_layer": cfg.latent_row * itemsize,
+            "bytes_per_token": cfg.num_layers * cfg.latent_row * itemsize,
+            "arrays": [self._model.MLA_POOL], "heads": cfg.num_heads, "value_dim": cfg.kv_lora_rank,
+            "read": "absorbed: every head's query in the latent's space against one key row, "
+                    "the row's first value_dim values the value, a page fetched once a visit",
+            "residual_streams": cfg.hc_mult or 1,
+        }
 
     def dsa_status(self) -> dict | None:
         """/debug/status ``dsa`` of a model with a sparse-attention indexer:
@@ -2739,7 +2788,7 @@ class Engine:
         400 check can never drift from actual admission behavior."""
         biggest = max(b for b in self.config.prefill_buckets
                       if b <= self.config.max_seq_len)
-        if (self.carries_state or self.is_dsa) and self.mixed_ok:
+        if (self.carries_state or self.is_dsa or self.is_mla) and self.mixed_ok:
             biggest = 0  # every prompt is a "long" one: the ragged step admits them all
         ring_ok = (
             self.mesh is not None

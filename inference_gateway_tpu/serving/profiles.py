@@ -97,6 +97,13 @@ def mixtral_param_count(cfg: mixtral.MixtralConfig) -> int:
     Hkv_D = cfg.num_kv_heads * cfg.hd
     Hq_D = cfg.num_heads * cfg.hd
     attention = H * Hq_D + 2 * H * Hkv_D + Hq_D * H  # q, k, v, o
+    if cfg.kv_lora_rank:  # the latent attention's five matrices and two norms (ISSUE 47)
+        up = cfg.num_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)
+        attention = (H * cfg.q_lora_rank + cfg.q_lora_rank * Hq_D + H * cfg.latent_width + cfg.kv_lora_rank * up
+                     + cfg.num_heads * cfg.v_head_dim * H + cfg.q_lora_rank + cfg.kv_lora_rank)
+    if cfg.hc_mult:  # both blocks' hyper-connection maps: phi, alpha, bias
+        maps = 2 * cfg.hc_mult + cfg.hc_mult ** 2
+        attention += 2 * (cfg.hc_mult * H * maps + 3 + maps)
     if cfg.attn_gate:
         attention += H * Hq_D
     if cfg.sandwich_norm:
@@ -125,6 +132,10 @@ def kv_bytes_per_token(cfg, dtype_bytes: int = 2) -> int:
     cache (unsharded): every layer, unless the model says which
     (``cache_layers``: models/phi4flash.py has 9 for 32 layers)."""
     layers = getattr(cfg, "cache_layers", cfg.num_layers)
+    if getattr(cfg, "kv_lora_rank", 0):
+        # A latent attention (models/mixtral.py, ISSUE 47): one row a token a
+        # layer, [latent, rotary key] padded to the lane grid, and nothing else.
+        return layers * cfg.latent_row * dtype_bytes
     # A sparse-attention indexer keeps one more key a token a layer under
     # the same page ids (models/mixtral.py, ISSUE 36).
     index = cfg.indexer_head_dim if getattr(cfg, "indexer_topk", 0) else 0

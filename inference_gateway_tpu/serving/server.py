@@ -963,6 +963,11 @@ class SidecarServer:
             # and `mixed` timeline records carry `dsa_context_tokens` and
             # `dsa_kv_tokens_read`.
             **({"dsa": self.engine.dsa_status()} if getattr(self.engine, "is_dsa", False) else {}),
+            # A model with a latent attention (ISSUE 47): the pool's row (values,
+            # lanes), bytes a token, how it is read, the residual streams; absent
+            # otherwise. Its `decode` and `mixed` timeline records carry
+            # `latent_pages_read` beside `context_pages` and `experts_read`.
+            **({"latent_pool": self.engine.latent_status()} if getattr(self.engine, "is_mla", False) else {}),
             # A mixture (ISSUE 41): ``router`` (score, chosen_by, weights),
             # ``shared_experts``, ``dense_prefix``, and with windowed layers
             # ``cache_layers`` {window, full}: its `decode` and `mixed` records

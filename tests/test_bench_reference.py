@@ -8,8 +8,9 @@ they ran by hand alone. Here they are collected as they stand, and each new
 reference (``moe_early_router_gqa``, PR 28; ``sambay_diff``, PR 32) is a further
 case of the first. PR 32's file checks (``bench/tests/test_phi4flash_files.py``)
 are collected the same way, and PR 36's (``bench/tests/test_keye_files.py``),
-PR 41's (``bench/tests/test_trinity_files.py``) and PR 43's
-(``bench/tests/test_lfm2_files.py``).
+PR 41's (``bench/tests/test_trinity_files.py``), PR 43's
+(``bench/tests/test_lfm2_files.py``) and PR 47's
+(``bench/tests/test_xing4_files.py``).
 """
 
 import importlib.util
@@ -57,18 +58,28 @@ globals().update({name if name not in globals() else f"{name}_lfm2": fn
                   for name, fn in vars(lfm2_files).items() if name.startswith("test_")})
 
 
+_spec = importlib.util.spec_from_file_location("bench_test_xing4_files", BENCH / "tests" / "test_xing4_files.py")
+xing4_files = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(xing4_files)
+globals().update({name if name not in globals() else f"{name}_xing4": fn
+                  for name, fn in vars(xing4_files).items() if name.startswith("test_")})
+
+
 # What later PRs appended after PR 32's entries, by list and in order: PR 36 a
 # configuration, its cell and two readers, PR 38 the idle ledger's five readers,
-# PR 41 a configuration, its cell and two readers, PR 43 the same. All the cut
-# below may drop.
-APPENDED_SINCE_PR_32 = {"configs": ["keye-vl2-30b-a3b-int8", "trinity-mini-int8", "lfm2-8b-a1b-int8"],
+# PR 41 a configuration, its cell and two readers, PR 43 the same, PR 47 a
+# configuration, its cell and four readers. All the cut below may drop.
+APPENDED_SINCE_PR_32 = {"configs": ["keye-vl2-30b-a3b-int8", "trinity-mini-int8", "lfm2-8b-a1b-int8",
+                                    "xing4.0-29b-a4b-int8"],
                         "workloads": ["keye-vl2-30b-a3b.long-context", "trinity-mini.long-context",
-                                      "lfm2-8b-a1b.reason"],
+                                      "lfm2-8b-a1b.reason", "xing4.0-29b-a4b.long-context"],
                         "per_layer": ["dsa_kv_read_share", "dsa_busy_share",
                                       "fetch_return_ms", "idle_unspanned_share", "starved_slot_share",
                                       "starved_delivery_share", "starved_unspanned_share",
                                       "swa_page_skip_share", "afmoe_step_hbm_roofline_share",
-                                      "lfm2_decode_hbm_roofline_share", "shortconv_busy_share"]}
+                                      "lfm2_decode_hbm_roofline_share", "shortconv_busy_share",
+                                      "mla_step_hbm_roofline_share", "mla_attn_roofline_share",
+                                      "mla_attn_busy_share", "mhc_busy_share"]}
 
 
 def test_the_benchmark_gains_one_configuration_one_cell_and_three_readers(tmp_path, monkeypatch):
@@ -131,6 +142,7 @@ def test_the_new_references_child_says_ok_on_probes_of_a_tiny_engine(name, tmp_p
     child.test_reference_child_says_ok_on_probes_of_a_tiny_engine(name, tmp_path)
 
 
-@pytest.mark.parametrize("preset", ["smallthinker-test-tiny", "phi4flash-test-tiny", "lfm2-test-tiny"])
+@pytest.mark.parametrize("preset", ["smallthinker-test-tiny", "phi4flash-test-tiny", "lfm2-test-tiny",
+                                    "xing4-test-tiny"])
 def test_the_new_configurations_child_builds_the_sidecars_tree(preset):
     child.test_the_childs_weights_are_the_serving_engines_bit_for_bit(preset, "int8")

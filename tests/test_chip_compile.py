@@ -715,3 +715,73 @@ def test_lfm2_step_programs_compile_for_v5e_at_the_published_widths(topo, monkey
                 and not made.group(3).startswith("%p__")):
             copies.append(line.strip()[:160])
     assert not copies, copies[:3]
+
+
+@pytest.mark.parametrize("form", ["mixed", "decode"])
+def test_xing4_step_programs_compile_for_v5e_at_the_published_widths(topo, monkeypatch, as_on_tpu, form):
+    """Xing4.0-29B-A4B's first stage (ISSUE 47: 10 layers, a latent attention of 32
+    heads on rows of 576 values in 640 lanes, four residual streams, 64 experts of
+    1,024 top-4 beside a shared one) as its cell serves it: 32 slots of 100 pages
+    of 128, a mixed step of 544 packed tokens and a decode step of 32 rows, int8
+    weights. A pass says the chip's compiler takes both step programs: the latent
+    kernel under both of its names (a DMA may not slice inside a lane tile, which
+    is why the pool's row is 640 wide), the Sinkhorn kernel, the expert kernels;
+    the ONE pool array is updated in place (no copy of it, and the temporaries
+    stay far under it: the streams are kept out of every branch) and no int8
+    result is made that is not a parameter (a copy of a weight)."""
+    import re
+
+    from inference_gateway_tpu.models import mixtral
+    from inference_gateway_tpu.ops.quant import init_quantized_llama_params
+    from inference_gateway_tpu.serving.kv_cache import PagedCacheConfig
+
+    monkeypatch.setattr("inference_gateway_tpu.ops.paged_attention.device_platform", lambda: "tpu")
+    monkeypatch.setattr("inference_gateway_tpu.ops.mla_attention.device_platform", lambda: "tpu")
+    cfg = mixtral.PRESETS["xing4.0-stage10"]
+    slots, page, seq, T = 32, 128, 12800, 544
+    place = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=SingleDeviceSharding(topo.devices[0])), tree)
+    params = place(jax.eval_shape(lambda: init_quantized_llama_params(jax.random.PRNGKey(0), cfg)))
+    cache = place(jax.eval_shape(lambda: mixtral.init_paged_cache(cfg, PagedCacheConfig(page, 0, slots, seq))))
+    assert {n: a.shape for n, a in cache.items()} == {"kv": (10, slots * 100, page, 640)}
+    sds = lambda shape: place(jax.ShapeDtypeStruct(shape, I32))
+    row, table = sds((slots,)), sds((slots, seq // page))
+    if form == "mixed":
+        fn = lambda p, c, tok, pos, w, table, qs, ql, kl: mixtral.forward_ragged(
+            p, cfg, tok, pos, c, w, table, qs, ql, kl, with_aux=True)
+        args = (params, cache, sds((1, T)), sds((1, T)), sds((1, T)), table, row, row, row)
+        kernels = ("mla_decode_attn_kernel", "mla_chunk_attn_kernel", "mhc_sinkhorn_kernel")
+    else:
+        def fn(p, c, tok, pos, table, done):   # as the engine's chunk runs it: the cache in a while_loop's carry
+            def step(carry):
+                i, c, tok, pos = carry
+                w = jnp.take_along_axis(table, (pos // page)[:, None], axis=1)[:, 0] * page + pos % page
+                logits, c, _ = mixtral.forward_paged(p, cfg, tok[:, None], pos[:, None], jnp.where(done, 0, pos + 1),
+                                                     c, w[:, None], table, mode="decode", valid=~done, with_aux=True)
+                return i + 1, c, jnp.argmax(logits, -1).astype(I32), pos + 1
+
+            return jax.lax.while_loop(lambda carry: carry[0] < 8, step, (jnp.int32(0), c, tok, pos))[1:]
+
+        args = (params, cache, row, row, table, place(jax.ShapeDtypeStruct((slots,), jnp.bool_)))
+        kernels = ("mla_decode_attn_kernel", "mhc_sinkhorn_kernel")
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    for name in kernels + ("moe_gate_up_gmm", "moe_down_gmm"):
+        assert re.search(rf"%\S*{name}\S* = ", text), name
+    pool = 10 * slots * 100 * page * 640 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool // 20
+    assert not re.findall(rf"= bf16\[{10 * slots * 100 * page},640\]\S* copy\(", text), "the pool is copied"
+    # (As LFM2's test reads it; here an entry parameter laid out anew once a call may come through its
+    # prefetch: the 576-column W_DKV stack, 16.5 MB a chunk of 8 steps, is a copy-done of a parameter's copy-start.)
+    prefetched = set(re.findall(r"(%copy-start\S*) = .* copy-start\(%p__", text))
+    of_parameter = {done for done, start in re.findall(r"(%copy-done\S*) = .* copy-done\((%copy-start[\w.\-]*)\)", text)
+                    if start in prefetched}
+    computation, copies = "", []
+    for line in text.splitlines():
+        opened = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{", line)
+        computation = opened.group(1) if opened else computation
+        made = re.match(r"\s*(?:ROOT )?%\S+ = s8\[[\d,]+\](\{\S*\})? (fusion|copy|dynamic-slice)\((%[\w.\-]+)", line)
+        if (made and not computation.startswith("fused_computation") and "S(1)" not in (made.group(1) or "")
+                and not made.group(3).startswith("%p__") and made.group(3) not in of_parameter):
+            copies.append(line.strip()[:160])
+    assert not copies, copies[:3]
