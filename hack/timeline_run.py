@@ -9,10 +9,11 @@ window's account by cause (``starved_by``) of an UNTRACED run, and the records
 before and after a capture in a traced one, have to be fetched by hand. This
 runs the checkout's own ``bench/run.py`` unchanged but for one seam: when the
 run reads the sidecar's account (``procs.sidecar_account``, once at the start
-and once after the window), the timeline's ring and ``status.jax_trace`` are
-saved as ``<run dir>/timeline.json`` with where the window lay, cut as the
-traced run cuts it. ``python3 bench/idle_ledger.py <run dir>/timeline.json``
-then prints the table by cause. Stays off JAX, as ``run.py`` does.
+and once after the window), the timeline's ring, ``status.jax_trace`` and
+``status.scheduler`` are saved as ``<run dir>/timeline.json`` with where the
+window lay, cut as the traced run cuts it. ``python3 bench/idle_ledger.py
+<run dir>/timeline.json`` then prints the table by cause. Stays off JAX, as
+``run.py`` does.
 """
 import json
 import sys
@@ -48,7 +49,8 @@ def accounting(sport, platform, chips):
     _, doc, _ = procs.http_json(sport, "GET", "/debug/timeline")
     if doc and "dir" in window:
         Path(window["dir"], "timeline.json").write_text(json.dumps(
-            {**window, "jax_trace": status.get("jax_trace"), "entries": doc.get("entries", [])}))
+            {**window, "jax_trace": status.get("jax_trace"), "scheduler": status.get("scheduler"),
+             "entries": doc.get("entries", [])}))
     return status
 
 

@@ -69,6 +69,19 @@ def split(doc: dict) -> dict:
     if sum(stepped.values()):
         out["tokens_from"] = stepped
         out["tokens_from_device_share"] = round(stepped["device"] / sum(stepped.values()), 3)
+    # What each admission waited out before its first step (ISSUE 48): the
+    # decode steps dispatched and not yet fetched when it began, and the drain.
+    preps = [t["prep"] for t in recs if t["kind"] == "mixed" and "drained_steps" in (t.get("prep") or {})]
+    if preps:
+        steps = sorted(p["drained_steps"] for p in preps)
+        out["drained_steps"] = {
+            "admissions": len(steps), "p50": steps[len(steps) // 2], "p90": steps[len(steps) * 9 // 10],
+            "max": steps[-1], "mean": round(statistics.fmean(steps), 2),
+            "count_by_steps": {str(k): steps.count(k) for k in sorted(set(steps))},
+            "drain_ms_p50": round(statistics.median(p["drain_ms"] for p in preps), 2),
+        }
+    if doc.get("scheduler"):
+        out["chunks_in_flight_max"] = doc["scheduler"].get("chunks_in_flight_max")
     batches = [(t["batch"], t["steps"]) for t in recs if t["kind"] == "decode" and t.get("steps")]
     if batches:
         out["decode_batch_mean"] = round(sum(b * s for b, s in batches) / sum(s for _, s in batches), 3)

@@ -136,10 +136,14 @@ _SERVING_DESYNC_DOC = [
     "moment every slot is done — long `SERVING_DECODE_CHUNK` values pay no",
     "chunk-overrun waste, and chained chunk submits upload nothing (paged",
     "write indices are computed on device from a pre-reserved page horizon).",
-    "`SERVING_DECODE_PIPELINE_DEPTH` chunks stay in flight so the device",
-    "never waits on the host between chunks; the `engine.host_gap_ms`",
+    "Chunk N+1 is submitted before chunk N is fetched, and",
+    "`SERVING_DECODE_PIPELINE_DEPTH` chunks (one by default) are left",
+    "unfetched behind the running one,",
+    "so the device never waits on the host between chunks and a request",
+    "that finds a slot free waits out at most depth + 1 chunks in front of",
+    "its prefill; the `engine.host_gap_ms`",
     "histogram and each `/debug/timeline` record's `host_gap_ms` measure",
-    "exactly that. Greedy and seeded streams are byte-identical at any chunk size;",
+    "the first, a mixed record's `prep.drained_steps` the second. Greedy and seeded streams are byte-identical at any chunk size;",
     "stop *strings* remain a host-side backstop that truncates after the",
     "fact. Design: [docs/performance.md](docs/performance.md).",
     "",

@@ -86,14 +86,24 @@ class EngineConfig:
     # subset — stop STRINGS and disconnects are host-side backstops),
     # so a larger chunk wastes steps only on those finishes.
     decode_chunk: int = 8
-    # Decode chunks the scheduler keeps in flight simultaneously.
-    # Depth 1 overlaps chunk N's readback with chunk N+1's execution;
-    # depth 2 additionally hides the host-side submit gap (allocator
-    # bookkeeping + dispatch) behind device execution. Cost: finish
-    # detection lags by depth chunks, so up to depth*decode_chunk wasted
-    # steps per finished request for a finish only the host sees —
-    # ~zero otherwise: the chunk freezes finished slots on device.
-    pipeline_depth: int = 2
+    # Decode chunks the scheduler leaves dispatched and unfetched when a
+    # pass ends (Scheduler._process_handles). The next pass submits one
+    # more BEFORE it fetches the oldest, so at any depth the device has
+    # the next program queued when a chunk ends, and while the thread
+    # blocks in that fetch the device holds the running chunk and `depth`
+    # more behind it. What a queued chunk costs: it cannot be cancelled,
+    # an arrival is seen only when the fetch returns, and its admission
+    # drains whatever is queued then, so a request that finds a slot free
+    # waits out up to depth+1 chunks in front of its prefill: 8-16 decode
+    # steps at depth 1, 16-24 at depth 2 (~55 ms more of a 260 ms TTFT at
+    # 7B shapes). A row's finish is seen `depth` chunks late (one only the
+    # host sees, a stop string or a client gone, also wastes up to
+    # depth*decode_chunk steps). What depth 2 buys: a chunk of cushion
+    # against a host stall longer than a chunk's device time (the host's
+    # work a chunk is 2-4 ms against 85-110), and a closed loop's
+    # admissions gather a chunk longer (fewer, longer trains: 2-4% of
+    # its per-token time). _reserve_horizon_pages follows the same depth.
+    pipeline_depth: int = 1
     # Speculative decoding: spec_draft names a llama-family draft model
     # (preset name or HF path, same vocab as the target) that proposes
     # spec_k tokens per round, or the special value "ngram" for

@@ -407,6 +407,10 @@ class Scheduler:
         # from the host.
         self.pipelined_submits = 0
         self.host_stepped_submits = 0
+        # The most decode chunks ever dispatched and unfetched at once
+        # (/debug/status; ISSUE 48): pipeline_depth + 1, right after a
+        # pass's submit and before it fetches the oldest.
+        self.chunks_in_flight_max = 0
 
     def active_requests(self) -> int:
         return len(self._slots)
@@ -667,6 +671,9 @@ class Scheduler:
             h = self._submit_chunk(chain=chain)
             if h is not None:
                 self._handles.append(h)
+                self.chunks_in_flight_max = max(
+                    self.chunks_in_flight_max,
+                    sum(isinstance(x, _Inflight) for x in self._handles))
             if h is not None or not self._admission_due():
                 # The chunk is behind it on the device (or no program
                 # follows): fetch and process the train's last step. With
@@ -698,8 +705,13 @@ class Scheduler:
             return bool(self._waiting and self._free) and self._admit_ready()
 
     def _process_handles(self) -> None:
-        """Process outstanding handles FIFO, keeping up to the newest
-        `pipeline_depth` decode chunks in flight.
+        """Process outstanding handles FIFO, leaving the newest
+        `pipeline_depth` decode chunks unfetched. The next pass submits
+        one more and only then blocks in the oldest's fetch, so the
+        device has the next program queued whenever a chunk ends, and
+        while the thread blocks it holds the running chunk and that many
+        behind it: none of them can be cancelled, and an arrival is seen
+        only when the fetch returns (ISSUE 48).
 
         The queue may only be left holding a pure chunk tail — a pending
         prefill is always resolved before any chunk submitted after it,
@@ -1239,7 +1251,8 @@ class Scheduler:
         # contract as bucketed _admit).
         self._admitting = batch
         # What the admission costs before its first mixed step (ISSUE 24):
-        # rides that step's record as ``prep`` {ms, register_ms, drain_ms}.
+        # rides that step's record as ``prep`` {ms, register_ms, drain_ms,
+        # drained_steps}.
         prep: dict | None = (dict.fromkeys(("ms", "register_ms", "drain_ms"), 0.0)
                              if self._observing else None)
         with phase_span(prep, "ms", "sched.admit.prep"):
@@ -1278,7 +1291,11 @@ class Scheduler:
             # the last one leaves the device empty, so its delivery waits
             # for the mixed step's dispatch. (Behind a train's unfetched
             # step there is no chunk: one is dispatched only as that step
-            # is settled.)
+            # is settled.) ``drained_steps``: the decode steps dispatched
+            # and not yet fetched that this admission waits out (ISSUE 48).
+            if prep is not None:
+                prep["drained_steps"] = sum(
+                    h.n_steps for h in self._handles if isinstance(h, _Inflight))
             with phase_span(prep, "drain_ms", "sched.admit.drain"):
                 while self._handles:
                     h = self._handles.popleft()

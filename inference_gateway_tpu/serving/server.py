@@ -986,13 +986,17 @@ class SidecarServer:
             # ahead of the host (ISSUE 40): of the mixed steps and fresh
             # decode chunks, how many read a row's token from an unfetched
             # step's block on the device, and how many took every token
-            # from the host.
+            # from the host. ``chunks_in_flight_max`` (ISSUE 48): the most
+            # decode chunks ever dispatched and unfetched at once; an
+            # admission's mixed record says what it drained
+            # (``prep.drained_steps``).
             "scheduler": {
                 "steps_completed": self.scheduler.steps_completed,
                 "flushes_deferred": self.scheduler.flushes_deferred,
                 "flushes_immediate": self.scheduler.flushes_immediate,
                 "pipelined_submits": self.scheduler.pipelined_submits,
                 "host_stepped_submits": self.scheduler.host_stepped_submits,
+                "chunks_in_flight_max": self.scheduler.chunks_in_flight_max,
             },
         }
         if self.engine.structured is not None:

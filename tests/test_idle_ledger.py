@@ -107,7 +107,9 @@ def loop():
 
 @pytest.fixture(scope="module")
 def records(loop):
-    recs = loop.closed_loop(130)
+    # A request is 1.2-1.5 records with one chunk queued behind the running
+    # one (130 make ~190): 160 clear the 200 the tests below are named for.
+    recs = loop.closed_loop(160)
     assert len(recs) >= 200, len(recs)
     return recs
 

@@ -108,7 +108,7 @@ async def test_a_mixed_records_phases_add_up_to_its_duration(sidecar):
     assert all(r["submit_ts"] <= r["start_ts"] for r in mixed if "submit_ts" in r)
     assert _adds_up([(sum(r["phases"][k] for k in own(r)), r["duration_ms"]) for r in mixed]), mixed
     # The admission's first step carries what came before it.
-    assert all(set(r["prep"]) == {"ms", "register_ms", "drain_ms"} for r in mixed if "prep" in r)
+    assert all(set(r["prep"]) == {"ms", "register_ms", "drain_ms", "drained_steps"} for r in mixed if "prep" in r)
     first = next(r for r in mixed if "prep" in r)
     assert first["prep"]["ms"] >= first["prep"]["register_ms"] + first["prep"]["drain_ms"] > 0
 
