@@ -9,9 +9,11 @@ window's account by cause (``starved_by``) of an UNTRACED run, and the records
 before and after a capture in a traced one, have to be fetched by hand. This
 runs the checkout's own ``bench/run.py`` unchanged but for one seam: when the
 run reads the sidecar's account (``procs.sidecar_account``, once at the start
-and once after the window), the timeline's ring, ``status.jax_trace`` and
-``status.scheduler`` are saved as ``<run dir>/timeline.json`` with where the
-window lay, cut as the traced run cuts it. ``python3 bench/idle_ledger.py
+and once after the window), the timeline's ring, ``status.jax_trace``,
+``status.scheduler``, ``status.startup`` and the compile ledger
+(``status.device.compile``: ISSUE 49's ``recompile_events``) are saved as
+``<run dir>/timeline.json`` with where the window lay, cut as the traced run
+cuts it. ``python3 bench/idle_ledger.py
 <run dir>/timeline.json`` then prints the table by cause. Stays off JAX, as
 ``run.py`` does.
 """
@@ -50,6 +52,7 @@ def accounting(sport, platform, chips):
     if doc and "dir" in window:
         Path(window["dir"], "timeline.json").write_text(json.dumps(
             {**window, "jax_trace": status.get("jax_trace"), "scheduler": status.get("scheduler"),
+             "startup": status.get("startup"), "compile": (status.get("device") or {}).get("compile"),
              "entries": doc.get("entries", [])}))
     return status
 

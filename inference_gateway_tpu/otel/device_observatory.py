@@ -358,7 +358,8 @@ class DeviceObservatory:
     def _hbm_plan(engine: Any) -> dict[str, int]:
         """Analytic device-byte plan from the live engine's own config:
         weights at the serving dtype (matmul weights at the quantized
-        width) + the KV pool reservation. Mirrors profiles.hbm_plan's
+        width) + the KV pool reservation (+ a state store, + the
+        logit-bias table of the structured runtime). Mirrors profiles.hbm_plan's
         pricing but reads the engine, not a named profile — the sidecar
         serves ad-hoc configs too."""
         try:
@@ -388,6 +389,11 @@ class DeviceObservatory:
                 # The per-slot state store beside the pool (float32).
                 plan["state_store_bytes"] = econf.max_slots * cfg.state_bytes_per_slot
                 plan["plan_bytes"] += plan["state_store_bytes"]
+            if getattr(engine, "structured", None) is not None:
+                # The per-slot logit-bias table every step program reads
+                # (float32, one zero row past the slots: ISSUE 49).
+                plan["logit_bias_bytes"] = (econf.max_slots + 1) * cfg.vocab_size * 4
+                plan["plan_bytes"] += plan["logit_bias_bytes"]
             return plan
         except Exception:
             return {}

@@ -149,9 +149,11 @@ class EngineConfig:
     # device-resident token-mask automaton tables. structured_states is
     # the shared table budget in automaton states — device memory is
     # budget x vocab x 4 bytes for the transition table (size it
-    # consciously for 100k-vocab models); the tables only materialize on
-    # the first constrained (or logit_bias) request, and until then the
-    # engine's compiled programs are bit-identical to structured=False.
+    # consciously for 100k-vocab models); those tables only materialize
+    # on the first grammar-constrained request. The per-slot logit_bias
+    # table ((max_slots + 1) x vocab float32) is built with the engine
+    # and every step program adds its rows (ISSUE 49); structured=False
+    # builds neither, and its programs add nothing.
     structured: bool = True
     structured_states: int = 4096
     structured_cache: int = 64
@@ -912,10 +914,12 @@ class Engine:
         self._eos_id = eos if isinstance(eos, int) else None
         self._eos_stop_row = build_stop_row(self._eos_id)
         # Structured outputs (ISSUE 13): grammar mask tables + logit-bias
-        # rows. Construction is lazy-cheap; device buffers materialize on
-        # the first constrained/biased admission (StructuredRuntime.live
-        # flips sticky-True and every step program recompiles ONCE with
-        # the mask gather fused in).
+        # rows. The per-slot bias table is built here and is an operand
+        # of every step program from its first trace (ISSUE 49: a
+        # ``logit_bias`` request lowers nothing); the automaton's tables
+        # materialize on the first GRAMMAR admission (StructuredRuntime.live
+        # flips sticky-True and every step program is lowered ONCE more
+        # with the mask gather fused in).
         self.structured = None
         lap(None)
         if config.structured and config.structured_states > 1:
@@ -926,12 +930,12 @@ class Engine:
                 states_budget=config.structured_states,
                 cache_size=config.structured_cache,
                 max_schema_bytes=config.structured_max_schema_bytes)
+            jax.block_until_ready(self.structured.bias_dev)
         lap("structured_s")
-        # Placeholder mask args for unmasked programs (ignored at trace
-        # time when masked=False, but part of the jit signature).
+        # The automaton's placeholders for programs traced without a
+        # grammar (ignored at trace time, but part of the jit signature).
         self._no_mask_tables = (
-            jnp.zeros((1, 1), jnp.int32), jnp.zeros((1, 1), jnp.uint32),
-            jnp.zeros((1, 1), jnp.float32))
+            jnp.zeros((1, 1), jnp.int32), jnp.zeros((1, 1), jnp.uint32))
         self._no_term_table = jnp.zeros((1,), bool)
         self._zero_mstates = np.zeros((config.max_slots,), np.int32)
         # Packed step descriptors (ISSUE 25): the two host-stepped
@@ -1056,14 +1060,19 @@ class Engine:
 
     # -- structured outputs (ISSUE 13) ---------------------------------
     def _mask_args(self):
-        """(masked, next_table, bits_table, bias_table) for jitted step
-        calls. masked is trace-static: False until the first constrained
-        or logit_bias admission flips the runtime live (then sticky-True
-        — one recompile per step program, ever)."""
+        """(automaton, next_table, bits_table, bias_table) for jitted step
+        calls. The bias table is the runtime's own from the engine's
+        construction on (None on an engine built without the runtime),
+        so a ``logit_bias`` admission changes no signature. ``automaton``
+        is trace-static: False, with placeholders for its tables, until
+        the first grammar-constrained admission flips the runtime live
+        (then sticky-True — one more lowering per step program, ever)."""
         rt = self.structured
-        if rt is not None and rt.live:
+        if rt is None:
+            return (False,) + self._no_mask_tables + (None,)
+        if rt.live:
             return True, rt.next_dev, rt.bits_dev, rt.bias_dev
-        return (False,) + self._no_mask_tables
+        return (False,) + self._no_mask_tables + (rt.bias_dev,)
 
     def _mask_args_ee(self):
         """_mask_args plus the per-state TERMINAL table (ISSUE 14): the
@@ -1071,11 +1080,9 @@ class Engine:
         has nothing further to say" into the on-device done flag — the
         device mirror of GrammarSession.feed returning "end" on the next
         token."""
-        rt = self.structured
-        if rt is not None and rt.live:
-            return True, rt.next_dev, rt.bits_dev, rt.term_dev, rt.bias_dev
-        t = self._no_mask_tables
-        return False, t[0], t[1], self._no_term_table, t[2]
+        automaton, mnext, mbits, mbias = self._mask_args()
+        mterm = self.structured.term_dev if automaton else self._no_term_table
+        return automaton, mnext, mbits, mterm, mbias
 
     def structured_register(self, slot: int, grammar, logit_bias) -> None:
         """Admission hook: make the request's grammar span device-resident
@@ -1086,33 +1093,42 @@ class Engine:
         with self._lock:
             self.structured.register_slot(slot, grammar, logit_bias)
 
-    def _mask_bias(self, mbits, mstates, extra=None):
-        """Additive grammar bias for one step's logits: unpack the packed
-        allowed rows for each row's automaton state; ``extra`` appends
-        the per-slot logit_bias rows."""
-        bias = packed_mask_bias(mbits[mstates], self.model_cfg.vocab_size)
-        return bias if extra is None else bias + extra
+    def _bias_logits(self, logits, mbias, slot_ids, mbits, mstates, automaton):
+        """The sampling tail's additive bias, in every step program: each
+        row's ``logit_bias`` row of the per-slot table (``slot_ids``, or
+        the table's slots in order where None; zeros for an unbiased
+        slot, which is exact) and, traced under ``automaton`` alone, the
+        packed allowed set of the row's automaton state on top. An engine
+        built without the structured runtime has no table and adds
+        nothing."""
+        if mbias is None:
+            return logits
+        rows = mbias[:-1] if slot_ids is None else mbias[slot_ids]
+        if logits.ndim == 3:  # a verify forward's (S, K+1, V): the slot's row at every position
+            rows = rows[:, None, :]
+        if automaton:
+            rows = packed_mask_bias(mbits[mstates], self.model_cfg.vocab_size) + rows
+        return logits + rows
 
-    def _verify_mask_bias(self, mstates, draft_tokens, mnext, mbits, mbias):
-        """Per-position grammar bias for a speculative verify forward
-        (ISSUE 13): position 0 is masked by the slot's current automaton
-        state, position j by the state after consuming proposals d_1..d_j
-        — a scan of K transition gathers, so ACCEPTED tokens can never
-        break the grammar (a disallowed proposal has target probability
-        exactly 0 under its masked strip and is rejected + resampled
-        from the masked residual). Returns (S, K+1, V)."""
-        K = draft_tokens.shape[1]
-        states = [mstates]
-        for j in range(K):
-            states.append(mnext[states[-1], draft_tokens[:, j]])
-        stacked = jnp.stack(states, axis=1)  # (S, K+1)
-        bias = packed_mask_bias(mbits[stacked], self.model_cfg.vocab_size)
-        return bias + mbias[:-1][:, None, :]
+    def _verify_bias_logits(self, logits, mstates, draft_tokens, mnext, mbits, mbias, automaton):
+        """``_bias_logits`` for a speculative verify forward's (S, K+1, V)
+        logits (ISSUE 13): under a grammar position 0 is masked by the
+        slot's current automaton state, position j by the state after
+        consuming proposals d_1..d_j — a scan of K transition gathers, so
+        ACCEPTED tokens can never break the grammar (a disallowed proposal
+        has target probability exactly 0 under its masked strip and is
+        rejected + resampled from the masked residual)."""
+        if automaton:
+            states = [mstates]
+            for j in range(draft_tokens.shape[1]):
+                states.append(mnext[states[-1], draft_tokens[:, j]])
+            mstates = jnp.stack(states, axis=1)  # (S, K+1)
+        return self._bias_logits(logits, mbias, None, mbits, mstates, automaton)
 
     # ------------------------------------------------------------------
-    @partial(jax.jit, static_argnames=("self", "ring", "masked"), donate_argnums=(2,))
+    @partial(jax.jit, static_argnames=("self", "ring", "automaton"), donate_argnums=(2,))
     def _prefill_fn(self, params, cache, tokens, positions, lengths, slot_ids, temps, top_ps, seeds, use_seed, rng,
-                    mstates=None, mnext=None, mbits=None, mbias=None, ring=False, masked=False):
+                    mstates=None, mnext=None, mbits=None, mbias=None, ring=False, automaton=False):
         if self.pp:
             logits, cache = llama.forward_pp(
                 params, self.model_cfg, tokens, positions, lengths, cache,
@@ -1123,19 +1139,18 @@ class Engine:
                 params, self.model_cfg, tokens, positions, lengths, cache,
                 mode="prefill", last_only=True, slot_ids=slot_ids, **ring_kw,
             )
-        if masked:
-            logits = logits + self._mask_bias(mbits, mstates, mbias[slot_ids])
+        logits = self._bias_logits(logits, mbias, slot_ids, mbits, mstates, automaton)
         keys = per_row_keys(rng, seeds, use_seed, lengths)
         toks = sample_tokens(logits, rng, temps, top_ps, top_k=self.config.top_k, row_keys=keys)
         logprobs = compute_logprobs(logits, toks)
-        nstates = mnext[mstates, toks] if masked else jnp.zeros_like(toks)
+        nstates = mnext[mstates, toks] if automaton else jnp.zeros_like(toks)
         return toks, logprobs, nstates, cache
 
-    @partial(jax.jit, static_argnames=("self", "masked"), donate_argnums=(2,))
+    @partial(jax.jit, static_argnames=("self", "automaton"), donate_argnums=(2,))
     def _prefill_chunk_fn_paged(self, params, cache, tokens, positions, lengths, write_idx,
                                 page_table, temps, top_ps, seeds, use_seed, rng,
                                 mstates=None, mnext=None, mbits=None, mbias=None,
-                                slot_ids=None, masked=False):
+                                slot_ids=None, automaton=False):
         """Paged chunked prefill: fresh tail tokens attend the slot's
         gathered pages (cached prefix + tail) causally — the
         prefix-cache fast path."""
@@ -1143,17 +1158,16 @@ class Engine:
             params, self.model_cfg, tokens, positions, lengths, cache, write_idx,
             page_table, mode="prefill_chunk", last_only=True, mesh=self.mesh,
         )
-        if masked:
-            logits = logits + self._mask_bias(mbits, mstates, mbias[slot_ids])
+        logits = self._bias_logits(logits, mbias, slot_ids, mbits, mstates, automaton)
         keys = per_row_keys(rng, seeds, use_seed, lengths)
         toks = sample_tokens(logits, rng, temps, top_ps, top_k=self.config.top_k, row_keys=keys)
         logprobs = compute_logprobs(logits, toks)
-        nstates = mnext[mstates, toks] if masked else jnp.zeros_like(toks)
+        nstates = mnext[mstates, toks] if automaton else jnp.zeros_like(toks)
         return toks, logprobs, nstates, cache
 
-    @partial(jax.jit, static_argnames=("self", "masked"), donate_argnums=(2,))
+    @partial(jax.jit, static_argnames=("self", "automaton"), donate_argnums=(2,))
     def _prefill_chunk_fn(self, params, cache, tokens, positions, lengths, slot_ids, temps, top_ps, seeds, use_seed, rng,
-                          mstates=None, mnext=None, mbits=None, mbias=None, masked=False):
+                          mstates=None, mnext=None, mbits=None, mbias=None, automaton=False):
         """One chunk of a long prompt: write at positions, attend the
         whole cache row causally (self._model.forward mode=prefill_chunk)."""
         if self.pp:
@@ -1165,34 +1179,32 @@ class Engine:
                 params, self.model_cfg, tokens, positions, lengths, cache,
                 mode="prefill_chunk", last_only=True, slot_ids=slot_ids,
             )
-        if masked:
-            logits = logits + self._mask_bias(mbits, mstates, mbias[slot_ids])
+        logits = self._bias_logits(logits, mbias, slot_ids, mbits, mstates, automaton)
         keys = per_row_keys(rng, seeds, use_seed, lengths)
         toks = sample_tokens(logits, rng, temps, top_ps, top_k=self.config.top_k, row_keys=keys)
         logprobs = compute_logprobs(logits, toks)
-        nstates = mnext[mstates, toks] if masked else jnp.zeros_like(toks)
+        nstates = mnext[mstates, toks] if automaton else jnp.zeros_like(toks)
         return toks, logprobs, nstates, cache
 
-    @partial(jax.jit, static_argnames=("self", "masked"), donate_argnums=(2,))
+    @partial(jax.jit, static_argnames=("self", "automaton"), donate_argnums=(2,))
     def _prefill_fn_mm(self, params, cache, embeds, tokens, positions, lengths, slot_ids, temps, top_ps, seeds, use_seed, rng,
-                       mstates=None, mnext=None, mbits=None, mbias=None, masked=False):
+                       mstates=None, mnext=None, mbits=None, mbias=None, automaton=False):
         """Multimodal prefill: precomputed (image-spliced) embeddings
         replace the token-embedding lookup."""
         logits, cache = self._forward(
             params, self.model_cfg, tokens, positions, lengths, cache,
             mode="prefill", last_only=True, slot_ids=slot_ids, embeds=embeds,
         )
-        if masked:
-            logits = logits + self._mask_bias(mbits, mstates, mbias[slot_ids])
+        logits = self._bias_logits(logits, mbias, slot_ids, mbits, mstates, automaton)
         keys = per_row_keys(rng, seeds, use_seed, lengths)
         toks = sample_tokens(logits, rng, temps, top_ps, top_k=self.config.top_k, row_keys=keys)
         logprobs = compute_logprobs(logits, toks)
-        nstates = mnext[mstates, toks] if masked else jnp.zeros_like(toks)
+        nstates = mnext[mstates, toks] if automaton else jnp.zeros_like(toks)
         return toks, logprobs, nstates, cache
 
     # -- early-exit fused chunks (ISSUE 14) -----------------------------
     def _chunk_done0(self, tokens, positions, done, budgets, stop_table,
-                     mstates, mterm, masked):
+                     mstates, mterm, automaton):
         """Initial per-slot done flags at chunk entry: the carried flag,
         plus every condition the PENDING token may already have tripped
         (async admission scatters first tokens without a host check —
@@ -1201,7 +1213,7 @@ class Engine:
         max_len = self.config.max_seq_len
         d = done | jnp.any(stop_table == tokens[:, None], axis=-1)
         d = d | (budgets <= 0) | (positions + 1 >= max_len)
-        if masked:
+        if automaton:
             d = d | mterm[mstates]
         return d
 
@@ -1217,7 +1229,7 @@ class Engine:
         detection re-fires on it and truncates), and paged KV writes
         are masked. Returns (cache, out_tok, out_lp, new carry...,
         experts read by this step or None)."""
-        masked, mnext, mbits, mterm, mbias = mstates_args
+        automaton, mnext, mbits, mterm, mbias = mstates_args
         max_len = self.config.max_seq_len
         # Clamped so the attended length never passes the cache row when
         # a request rides the chunk past max_seq_len: past it the paged
@@ -1258,18 +1270,17 @@ class Engine:
                 read = jnp.concatenate([_experts_read(per, tok.shape[0] * self.model_cfg.experts_per_token), more])
             elif rows:  # a windowed hybrid's pages (WINDOW_STATS)
                 read = rows[0]
-        if masked:
-            with jax.named_scope("mask_bias"):
-                logits = logits + self._mask_bias(mbits, ms, mbias[:-1])
+        with jax.named_scope("mask_bias"):
+            logits = self._bias_logits(logits, mbias, None, mbits, ms, automaton)
         with jax.named_scope("sampling"):
             nxt = sample_tokens_pregumbel(logits, temps, top_ps, gumbels[i], k_eff)
             nxt = nxt.astype(jnp.int32)
             lp = compute_logprobs(logits, nxt)
-        nms = mnext[ms, nxt] if masked else ms
+        nms = mnext[ms, nxt] if automaton else ms
         nbud = bud - 1
         ndone = jnp.any(stop_table == nxt[:, None], axis=-1)
         ndone = ndone | (nbud <= 0) | (pos + 2 >= max_len)
-        if masked:
+        if automaton:
             ndone = ndone | mterm[nms]
         out_tok = jnp.where(done, tok, nxt)
         out_lp = jnp.where(done, 0.0, lp)
@@ -1295,15 +1306,15 @@ class Engine:
         dispatch instead of n_steps small ones, which cost ~0.56 ms/step
         on v5e (round-3 device profile); the streams are bit-identical
         (see ops/sampling.chunk_gumbels)."""
-        masked = mask_args[0]
+        automaton = mask_args[0]
         mstates = mask_args[1]
-        mask_tail = (masked,) + mask_args[2:]
+        mask_tail = (automaton,) + mask_args[2:]
         with jax.named_scope("sampling"):  # the chunk's noise, drawn once
             keys = chunk_row_keys(rng, seeds, use_seed, positions, n_steps)
             k_eff = effective_top_k(self.config.top_k, self.model_cfg.vocab_size)
             gumbels = chunk_gumbels(keys, k_eff)
         done0 = self._chunk_done0(tokens, positions, done, budgets, stop_table,
-                                  mstates, mask_args[4], masked)
+                                  mstates, mask_args[4], automaton)
         S = tokens.shape[0]
         out_toks0 = jnp.broadcast_to(tokens[None, :], (n_steps, S)).astype(jnp.int32)
         out_lps0 = jnp.zeros((n_steps, S), jnp.float32)
@@ -1349,11 +1360,11 @@ class Engine:
         rng_next = jax.random.fold_in(rng, 1)
         return out_toks, out_lps, tok_f, pos_f, ms_f, done_f, bud_f, rng_next, cache
 
-    @partial(jax.jit, static_argnames=("self", "n_steps", "masked"), donate_argnums=(2,))
+    @partial(jax.jit, static_argnames=("self", "n_steps", "automaton"), donate_argnums=(2,))
     def _decode_chunk_fn_ee(self, params, cache, tokens, positions, done, budgets,
                             stop_table, temps, top_ps, seeds, use_seed, rng,
                             mstates=None, mnext=None, mbits=None, mterm=None,
-                            mbias=None, n_steps=8, masked=False):
+                            mbias=None, n_steps=8, automaton=False):
         """The decode chunk over the dense cache: up to n_steps fused
         decode steps with on-device stopping (stop table / budget /
         grammar terminal state in the carry); frozen rows rewrite their
@@ -1362,24 +1373,24 @@ class Engine:
         early when every slot is done. Returns the (n, S) token and
         logprob blocks and the final carry, so the NEXT chunk chains off
         device-resident state with no host round-trip."""
-        mask_args = (masked, mstates, mnext, mbits, mterm, mbias)
+        mask_args = (automaton, mstates, mnext, mbits, mterm, mbias)
         return self._run_chunk_ee(
             params, cache, tokens, positions, done, budgets, stop_table, temps,
             top_ps, seeds, use_seed, rng, mask_args, None, n_steps)
 
-    @partial(jax.jit, static_argnames=("self", "n_steps", "masked"), donate_argnums=(2,))
+    @partial(jax.jit, static_argnames=("self", "n_steps", "automaton"), donate_argnums=(2,))
     def _decode_chunk_fn_paged_ee(self, params, cache, tokens, positions, done,
                                   budgets, stop_table, page_table, reserved,
                                   temps, top_ps, seeds, use_seed, rng,
                                   mstates=None, mnext=None, mbits=None, mterm=None,
-                                  mbias=None, n_steps=8, masked=False):
+                                  mbias=None, n_steps=8, automaton=False):
         """The decode chunk over the paged cache: the flat paged write
         index is computed ON DEVICE from the resident page table
         (page_table[slot, pos // page_size] · page_size + pos % page_size)
         and masked OOB for done rows and positions beyond the reserved
         horizon — the host assembles no write_idx per chunk, so a
         chained submit uploads nothing (ISSUE 14 tentpole b)."""
-        mask_args = (masked, mstates, mnext, mbits, mterm, mbias)
+        mask_args = (automaton, mstates, mnext, mbits, mterm, mbias)
         return self._run_chunk_ee(
             params, cache, tokens, positions, done, budgets, stop_table, temps,
             top_ps, seeds, use_seed, rng, mask_args, (page_table, reserved),
@@ -1413,27 +1424,26 @@ class Engine:
         tables = (d["page_table"], d["reserved"]) if self.paged else None
         return carry, sampling, tables
 
-    @partial(jax.jit, static_argnames=("self", "ring", "masked"), donate_argnums=(2,))
+    @partial(jax.jit, static_argnames=("self", "ring", "automaton"), donate_argnums=(2,))
     def _prefill_fn_paged(self, params, cache, tokens, positions, lengths, write_idx,
                           page_table, temps, top_ps, seeds, use_seed, rng,
                           mstates=None, mnext=None, mbits=None, mbias=None,
-                          slot_ids=None, ring=False, masked=False):
+                          slot_ids=None, ring=False, automaton=False):
         ring_kw = {"ring_mesh": self.mesh} if ring else {}
         logits, cache = self._model.forward_paged(
             params, self.model_cfg, tokens, positions, lengths, cache, write_idx,
             page_table, mode="prefill", last_only=True, mesh=self.mesh, **ring_kw,
         )
-        if masked:
-            logits = logits + self._mask_bias(mbits, mstates, mbias[slot_ids])
+        logits = self._bias_logits(logits, mbias, slot_ids, mbits, mstates, automaton)
         keys = per_row_keys(rng, seeds, use_seed, lengths)
         toks = sample_tokens(logits, rng, temps, top_ps, top_k=self.config.top_k, row_keys=keys)
         logprobs = compute_logprobs(logits, toks)
-        nstates = mnext[mstates, toks] if masked else jnp.zeros_like(toks)
+        nstates = mnext[mstates, toks] if automaton else jnp.zeros_like(toks)
         return toks, logprobs, nstates, cache
 
     def _mixed_step_body(self, params, cache, tokens, positions, write_idx, page_table,
                          q_starts, q_lens, kv_lens, temps, top_ps, seeds, use_seed, rng,
-                         mstates=None, mnext=None, mbits=None, mbias=None, masked=False):
+                         mstates=None, mnext=None, mbits=None, mbias=None, automaton=False):
         """One ragged MIXED step (ISSUE 12): prefill-chunk rows and
         decode rows in a single launch over the paged cache. This is the
         one compiled program that replaces the per-bucket
@@ -1445,12 +1455,11 @@ class Engine:
         logits, cache, *rows = self._model.forward_ragged(
             params, self.model_cfg, tokens, positions, cache, write_idx,
             page_table, q_starts, q_lens, kv_lens, mesh=self.mesh, **counted)
-        if masked:
-            # Mixed rows are slot-aligned: mask by each slot's automaton
-            # state, bias by its logit_bias row (constrained prefill-tail
-            # rows sample their FIRST token here — same mask semantics).
-            with jax.named_scope("mask_bias"):
-                logits = logits + self._mask_bias(mbits, mstates, mbias[:-1])
+        # Mixed rows are slot-aligned: bias by each slot's logit_bias row
+        # and, under a grammar, mask by its automaton state (constrained
+        # prefill-tail rows sample their FIRST token here — same semantics).
+        with jax.named_scope("mask_bias"):
+            logits = self._bias_logits(logits, mbias, None, mbits, mstates, automaton)
         with jax.named_scope("sampling"):
             keys = per_row_keys(rng, seeds, use_seed, kv_lens)
             toks = sample_tokens(logits, rng, temps, top_ps, top_k=self.config.top_k, row_keys=keys)
@@ -1469,9 +1478,9 @@ class Engine:
             return toks, logprobs, cache, jnp.concatenate([stats, _experts_read(per, m), more])
         return toks, logprobs, cache
 
-    @partial(jax.jit, static_argnames=("self", "masked"), donate_argnums=(2,))
+    @partial(jax.jit, static_argnames=("self", "automaton"), donate_argnums=(2,))
     def _mixed_step_fn(self, params, cache, packed, base_rng, block,
-                       mnext=None, mbits=None, mbias=None, masked=False):
+                       mnext=None, mbits=None, mbias=None, automaton=False):
         """The mixed step as the submit dispatches it (ISSUE 25): every
         descriptor rides ONE packed int32 buffer (``_mixed_pack``), the
         step key is folded from the engine's base key and the counter in
@@ -1490,7 +1499,7 @@ class Engine:
             params, cache, tokens, d["positions"], d["write_idx"], d["page_table"],
             d["q_starts"], d["q_lens"], d["kv_lens"], d["temps"], d["top_ps"],
             d["seeds"], d["use_seed"], rng, mstates=d["mstates"], mnext=mnext,
-            mbits=mbits, mbias=mbias, masked=masked)
+            mbits=mbits, mbias=mbias, automaton=automaton)
         block = jnp.stack([toks.astype(jnp.float32), logprobs])
         if stats:
             block = jnp.concatenate(
@@ -1581,12 +1590,12 @@ class Engine:
                     if self.carries_state and r.start == 0:
                         self.metrics["state_resets"] += 1
             with phase_span(phases, "upload_dispatch", "engine.mixed.upload_dispatch"):
-                masked, mnext, mbits, mbias = self._mask_args()
+                automaton, mnext, mbits, mbias = self._mask_args()
                 v["counter"][0] = self._bump_step()
                 staged = pack.snapshot()
                 both, self.cache = self._mixed_step_fn(
                     self.params, self.cache, jnp.asarray(staged), self._rng, self._last_block,
-                    mnext=mnext, mbits=mbits, mbias=mbias, masked=masked)
+                    mnext=mnext, mbits=mbits, mbias=mbias, automaton=automaton)
                 self._last_block = both
             # Positions moved outside the chained-carry bookkeeping.
             self._dev_carry = None
@@ -1930,9 +1939,9 @@ class Engine:
             if sessions[i] is not None:
                 ms_arr[i] = sessions[i].global_state
         positions = np.broadcast_to(np.arange(bucket, dtype=np.int32), (Bp, bucket))
-        masked, mnext, mbits, mbias = self._mask_args()
+        automaton, mnext, mbits, mbias = self._mask_args()
         mask_kw = dict(mstates=jnp.asarray(ms_arr), mnext=mnext, mbits=mbits,
-                       mbias=mbias, masked=masked)
+                       mbias=mbias, automaton=automaton)
 
         has_mm = embeds is not None and any(e is not None for e in embeds)
         with self._lock:
@@ -2087,7 +2096,7 @@ class Engine:
         chunk = max(b for b in self.config.prefill_buckets if b <= self.config.max_seq_len)
         total = len(prompt)
         mask_state = grammar.global_state if grammar is not None else 0
-        masked, mnext, mbits, mbias = self._mask_args()
+        automaton, mnext, mbits, mbias = self._mask_args()
         toks = logprobs = None
         with self._lock:
             for start in range(0, total, chunk):
@@ -2103,7 +2112,7 @@ class Engine:
                     jnp.asarray([seed if seed is not None else 0], np.int32),
                     jnp.asarray([seed is not None]), self._next_rng(),
                     mstates=jnp.asarray([mask_state], np.int32), mnext=mnext,
-                    mbits=mbits, mbias=mbias, masked=masked,
+                    mbits=mbits, mbias=mbias, automaton=automaton,
                 )
                 # Bumped per chunk, not once at the end: the hang
                 # watchdog reads these as a progress signal, and a long
@@ -2136,9 +2145,9 @@ class Engine:
         seed_arr = np.asarray([seed if seed is not None else 0], np.int32)
         use_seed = np.asarray([seed is not None])
         mask_state = grammar.global_state if grammar is not None else 0
-        masked, mnext, mbits, mbias = self._mask_args()
+        automaton, mnext, mbits, mbias = self._mask_args()
         mask_kw = dict(mstates=jnp.asarray([mask_state], np.int32), mnext=mnext,
-                       mbits=mbits, mbias=mbias, masked=masked)
+                       mbits=mbits, mbias=mbias, automaton=automaton)
         with self._lock:
             if self.paged:
                 self._ensure_with_evict(slot, T)
@@ -2265,7 +2274,7 @@ class Engine:
         no upload, no host-array construction, no loop."""
         tok_in, pos_in, ms_in, done_in, bud_in, rng = self._dev_carry
         temps_d, tps_d, seeds_d, used_d, stop_d = self._dev_sampling
-        masked, mnext, mbits, mterm, mbias = self._mask_args_ee()
+        automaton, mnext, mbits, mterm, mbias = self._mask_args_ee()
         if self.paged:
             toks, logprobs, tok_f, pos_f, ms_f, done_f, bud_f, rng_f, self.cache = \
                 self._decode_chunk_fn_paged_ee(
@@ -2273,14 +2282,14 @@ class Engine:
                     stop_d, self._dev_page_table, self._dev_reserved,
                     temps_d, tps_d, seeds_d, used_d, rng,
                     mstates=ms_in, mnext=mnext, mbits=mbits, mterm=mterm,
-                    mbias=mbias, n_steps=n, masked=masked)
+                    mbias=mbias, n_steps=n, automaton=automaton)
         else:
             toks, logprobs, tok_f, pos_f, ms_f, done_f, bud_f, rng_f, self.cache = \
                 self._decode_chunk_fn_ee(
                     self.params, self.cache, tok_in, pos_in, done_in, bud_in,
                     stop_d, temps_d, tps_d, seeds_d, used_d, rng,
                     mstates=ms_in, mnext=mnext, mbits=mbits, mterm=mterm,
-                    mbias=mbias, n_steps=n, masked=masked)
+                    mbias=mbias, n_steps=n, automaton=automaton)
         self._pred_pos = self._pred_pos + n * self._chain_active
         self._dev_carry = (tok_f, pos_f, ms_f, done_f, bud_f, rng_f)
         n_active = int(self._chain_active.sum())
@@ -2407,11 +2416,11 @@ class Engine:
         )
         return dcache
 
-    @partial(jax.jit, static_argnames=("self", "masked"), donate_argnums=(3, 4))
+    @partial(jax.jit, static_argnames=("self", "automaton"), donate_argnums=(3, 4))
     def _spec_round_fn(self, params, dparams, cache, dcache, catchup, catchup_len,
                        catchup_pos, temps, top_ps, write_idx, page_table,
                        uniforms, draft_gumbels, extra_gumbel,
-                       mstates=None, mnext=None, mbits=None, mbias=None, masked=False):
+                       mstates=None, mnext=None, mbits=None, mbias=None, automaton=False):
         """One speculative round for ALL slots (static shapes).
 
         catchup (S, 2): the emitted tokens the draft hasn't ingested
@@ -2439,18 +2448,17 @@ class Engine:
             dparams, dcfg, catchup, cu_positions, D + catchup_len, dcache,
             mode="prefill_chunk", last_only=True, slot_ids=slot_ids,
         )
-        if masked:
-            # Draft proposals are grammar-masked too (ISSUE 13): the
-            # draft samples from the same allowed set the target will
-            # verify against, so acceptance doesn't collapse on
-            # constrained rows. The mask state advances along the
-            # proposal inside the scan carry.
-            dlogits = dlogits + self._mask_bias(mbits, mstates, mbias[:-1])
+        # Draft proposals are biased and grammar-masked too (ISSUE 13):
+        # the draft samples from the same allowed set the target will
+        # verify against, so acceptance doesn't collapse on constrained
+        # rows. The mask state advances along the proposal inside the
+        # scan carry.
+        dlogits = self._bias_logits(dlogits, mbias, None, mbits, mstates, automaton)
 
         # --- K draft proposals (scan over draft decode steps) ----------
         q0_probs, q0_idx = strip_dist(dlogits, temps, top_ps, k)
         d1 = strip_sample(q0_probs, q0_idx, draft_gumbels[:, 0], greedy)
-        ds1 = mnext[mstates, d1] if masked else jnp.zeros_like(d1)
+        ds1 = mnext[mstates, d1] if automaton else jnp.zeros_like(d1)
 
         def dstep(carry, xs):
             dcache, tok, pos, dstate = carry
@@ -2460,11 +2468,10 @@ class Engine:
                 mode="decode", slot_ids=slot_ids,
             )
             lg = lg[:, 0]
-            if masked:
-                lg = lg + self._mask_bias(mbits, dstate, mbias[:-1])
+            lg = self._bias_logits(lg, mbias, None, mbits, dstate, automaton)
             qp, qi = strip_dist(lg, temps, top_ps, k)
             nxt = strip_sample(qp, qi, gum, greedy)
-            nstate = mnext[dstate, nxt] if masked else dstate
+            nstate = mnext[dstate, nxt] if automaton else dstate
             return (dcache, nxt, jnp.minimum(pos + 1, max_len - 1), nstate), (nxt, qp, qi)
 
         if K > 1:
@@ -2496,9 +2503,8 @@ class Engine:
                 params, self.model_cfg, ver_tokens, ver_positions, ver_lengths,
                 cache, mode="prefill_chunk", last_only=False, slot_ids=slot_ids,
             )
-        if masked:
-            logits = logits + self._verify_mask_bias(
-                mstates, draft_tokens, mnext, mbits, mbias)
+        logits = self._verify_bias_logits(
+            logits, mstates, draft_tokens, mnext, mbits, mbias, automaton)
         p_probs, p_idx = strip_dist(
             logits, jnp.broadcast_to(temps[:, None], (S, K + 1)),
             jnp.broadcast_to(top_ps[:, None], (S, K + 1)), k)
@@ -2556,7 +2562,7 @@ class Engine:
             uniforms = jax.vmap(lambda kk: jax.random.uniform(jax.random.fold_in(kk, 0), (K,)))(keys)
             draft_gumbels = jax.vmap(lambda kk: jax.random.gumbel(jax.random.fold_in(kk, 1), (K, k)))(keys)
             extra_gumbel = jax.vmap(lambda kk: jax.random.gumbel(jax.random.fold_in(kk, 2), (k,)))(keys)
-            masked, mnext, mbits, mbias = self._mask_args()
+            automaton, mnext, mbits, mbias = self._mask_args()
             out, logprobs, counts, self.cache, self.draft_cache = self._spec_round_fn(
                 self.params, self.draft_params, self.cache, self.draft_cache,
                 jnp.asarray(catchup.astype(np.int32)), jnp.asarray(catchup_len.astype(np.int32)),
@@ -2565,7 +2571,7 @@ class Engine:
                 uniforms, draft_gumbels, extra_gumbel,
                 mstates=jnp.asarray(mstates if mstates is not None
                                     else self._zero_mstates),
-                mnext=mnext, mbits=mbits, mbias=mbias, masked=masked,
+                mnext=mnext, mbits=mbits, mbias=mbias, automaton=automaton,
             )
             self._dev_carry = None  # spec rounds don't chain with decode chunks
             n_active = int(active.sum())
@@ -2582,12 +2588,12 @@ class Engine:
         self.metrics["decode_tokens"] += int(counts_np[active].sum()) if n_active else 0
         return out_np, logp_np, counts_np
 
-    @partial(jax.jit, static_argnames=("self", "masked"), donate_argnums=(2,))
+    @partial(jax.jit, static_argnames=("self", "automaton"), donate_argnums=(2,))
     def _spec_verify_ngram_fn(self, params, cache, pending, positions, draft_tokens,
                               temps, top_ps, write_idx, page_table, uniforms,
                               extra_gumbel,
                               mstates=None, mnext=None, mbits=None, mbias=None,
-                              masked=False):
+                              automaton=False):
         """One prompt-lookup round: verify K host-proposed tokens in ONE
         target forward. The draft "distribution" is a point mass on each
         proposal, expressed as a one-hot strip so spec_accept's ratio
@@ -2619,13 +2625,12 @@ class Engine:
                 params, self.model_cfg, ver_tokens, ver_positions, ver_lengths,
                 cache, mode="prefill_chunk", last_only=False, slot_ids=slot_ids,
             )
-        if masked:
-            # Grammar masks per verify position (ISSUE 13): the scheduler
-            # repairs host-side proposals against the automaton, and this
-            # mask guarantees the ACCEPTED prefix is grammar-valid even
-            # when a repair was impossible.
-            logits = logits + self._verify_mask_bias(
-                mstates, draft_tokens, mnext, mbits, mbias)
+        # Grammar masks per verify position (ISSUE 13): the scheduler
+        # repairs host-side proposals against the automaton, and this
+        # mask guarantees the ACCEPTED prefix is grammar-valid even
+        # when a repair was impossible.
+        logits = self._verify_bias_logits(
+            logits, mstates, draft_tokens, mnext, mbits, mbias, automaton)
         p_probs, p_idx = strip_dist(
             logits, jnp.broadcast_to(temps[:, None], (S, K + 1)),
             jnp.broadcast_to(top_ps[:, None], (S, K + 1)), k)
@@ -2689,7 +2694,7 @@ class Engine:
             )
             uniforms = jax.vmap(lambda kk: jax.random.uniform(jax.random.fold_in(kk, 0), (K,)))(keys)
             extra_gumbel = jax.vmap(lambda kk: jax.random.gumbel(jax.random.fold_in(kk, 2), (k,)))(keys)
-            masked, mnext, mbits, mbias = self._mask_args()
+            automaton, mnext, mbits, mbias = self._mask_args()
             out, logprobs, counts, self.cache = self._spec_verify_ngram_fn(
                 self.params, self.cache, jnp.asarray(pending.astype(np.int32)),
                 jnp.asarray(positions.astype(np.int32)),
@@ -2698,7 +2703,7 @@ class Engine:
                 uniforms, extra_gumbel,
                 mstates=jnp.asarray(mstates if mstates is not None
                                     else self._zero_mstates),
-                mnext=mnext, mbits=mbits, mbias=mbias, masked=masked,
+                mnext=mnext, mbits=mbits, mbias=mbias, automaton=automaton,
             )
             self._dev_carry = None  # spec rounds don't chain with decode chunks
             n_active = int(active.sum())
@@ -2855,11 +2860,14 @@ class Engine:
         the scatter that folds first tokens into the chained carry), the
         fused decode chunk submitted fresh AND chained (under a mesh the
         carry's sharding gives the chained call its own executable), and
-        the done-mark a host-side finish dispatches. Not covered: the
+        the done-mark a host-side finish dispatches. A ``logit_bias``
+        request runs these same executables: the per-slot bias table is
+        their operand from this first trace (ISSUE 49). Not covered: the
         prefix-cache tail programs of bucketed paged engines, ring
         prefill (its width follows the prompt), speculative rounds, and
-        the masked variants every program gains on the first structured
-        request (ROADMAP D4). Synthetic prompts never enter the prefix
+        the grammar variant every program gains on the first
+        grammar-constrained request, whose automaton tables are built
+        lazily (ROADMAP D4). Synthetic prompts never enter the prefix
         cache.
 
         Brackets the compile ledger (ISSUE 19) when an observatory is

@@ -19,7 +19,10 @@ semantic cost.
 The per-slot additive logit-bias buffer (OpenAI ``logit_bias``) lives
 here too: one ``(max_slots + 1, V)`` float32 row set at admission and
 cleared at release (the +1 row is the all-zero OOB target padding rows
-gather). Everything in this module dispatches asynchronously — no
+gather). Unlike the automaton it exists from construction (ISSUE 49):
+every step program takes it as an operand from its first trace, so the
+first biased request meets the executables warm-up built. Everything in
+this module dispatches asynchronously — no
 ``.item()`` / ``np.asarray`` on device values (graftlint jax-hot-path
 pins the acquire/register/release path).
 """
@@ -69,10 +72,14 @@ def _scatter_span(table: jax.Array, vec: jax.Array, base: jax.Array) -> jax.Arra
 class StructuredRuntime:
     """Owns the compiler cache, the device tables, and span bookkeeping.
 
-    Construction is cheap (no device allocation, no vocab walk); the
-    token byte table and the device buffers materialize on first use, so
-    engines that never see a constrained request pay nothing — and keep
-    their unmasked compiled programs (``live`` stays False)."""
+    Construction allocates the logit-bias table alone, zeros (29 MB at
+    48 slots of a 152k vocabulary): it is an operand of every step
+    program, so ``register_slot`` / ``release_slot`` write rows and no
+    program is lowered again for a bias. No vocab walk here; the token
+    byte table and the AUTOMATON's device tables (4,096 states of that
+    vocabulary are 2.5 GB) materialize on the first grammar, so engines
+    that never see one keep the programs traced without the mask
+    gathers (``live`` stays False)."""
 
     def __init__(self, tokenizer: Any, vocab_size: int, max_slots: int, *,
                  states_budget: int = 1024, cache_size: int = 64,
@@ -89,13 +96,14 @@ class StructuredRuntime:
         # makes the one-time compiler construction (the full-vocab token
         # byte walk) happen exactly once.
         self._compiler_lock = threading.Lock()
-        # Sticky device activation: flips True on the first constrained
-        # (or logit_bias) admission and never back — the engine's jitted
-        # programs recompile ONCE from unmasked to masked.
+        # Sticky device activation of the automaton: flips True on the
+        # first grammar-constrained admission and never back — the
+        # engine's jitted programs are lowered ONCE more, with the mask
+        # gathers (a logit_bias alone flips nothing).
         self.live = False
         self.next_dev: jax.Array | None = None
         self.bits_dev: jax.Array | None = None
-        self.bias_dev: jax.Array | None = None
+        self.bias_dev: jax.Array = jnp.zeros((max_slots + 1, vocab_size), jnp.float32)
         # Per-GLOBAL-state terminal flags (ISSUE 14): True where the
         # grammar is complete (accepting, nothing but EOS left to say) —
         # gathered by the early-exit chunk carry so constrained rows
@@ -143,7 +151,6 @@ class StructuredRuntime:
         free_bits[0, :] = np.uint32(0xFFFFFFFF)  # state 0: everything allowed
         self.next_dev = jnp.zeros((self.states_budget, self.vocab_size), jnp.int32)
         self.bits_dev = jnp.asarray(free_bits)
-        self.bias_dev = jnp.zeros((self.max_slots + 1, self.vocab_size), jnp.float32)
         self.term_dev = jnp.zeros((self.states_budget,), bool)
         self.live = True
 
@@ -219,11 +226,9 @@ class StructuredRuntime:
             self.acquire(session)
             self._slot_sessions[slot] = session
         if logit_bias and slot not in self._slot_biased:
-            self._ensure_live()
             row = np.zeros((self.vocab_size,), np.float32)
             for token_id, bias in logit_bias.items():
                 row[token_id] = bias
-            assert self.bias_dev is not None
             self.bias_dev = _set_row(self.bias_dev, jnp.asarray(row),
                                      jnp.int32(slot))
             self._slot_biased.add(slot)
@@ -238,7 +243,6 @@ class StructuredRuntime:
                 span[2] -= 1
         if slot in self._slot_biased:
             self._slot_biased.discard(slot)
-            assert self.bias_dev is not None
             self.bias_dev = _set_row(
                 self.bias_dev, jnp.zeros((self.vocab_size,), jnp.float32),
                 jnp.int32(slot))

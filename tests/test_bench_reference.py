@@ -9,8 +9,9 @@ reference (``moe_early_router_gqa``, PR 28; ``sambay_diff``, PR 32) is a further
 case of the first. PR 32's file checks (``bench/tests/test_phi4flash_files.py``)
 are collected the same way, and PR 36's (``bench/tests/test_keye_files.py``),
 PR 41's (``bench/tests/test_trinity_files.py``), PR 43's
-(``bench/tests/test_lfm2_files.py``) and PR 47's
-(``bench/tests/test_xing4_files.py``).
+(``bench/tests/test_lfm2_files.py``), PR 47's
+(``bench/tests/test_xing4_files.py``) and PR 49's reader check
+(``bench/tests/test_post_warmup_compile_reader.py``).
 """
 
 import importlib.util
@@ -65,10 +66,19 @@ globals().update({name if name not in globals() else f"{name}_xing4": fn
                   for name, fn in vars(xing4_files).items() if name.startswith("test_")})
 
 
+_spec = importlib.util.spec_from_file_location("bench_test_post_warmup_compile_reader",
+                                               BENCH / "tests" / "test_post_warmup_compile_reader.py")
+post_warmup = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(post_warmup)
+globals().update({name if name not in globals() else f"{name}_post_warmup": fn
+                  for name, fn in vars(post_warmup).items() if name.startswith("test_")})
+
+
 # What later PRs appended after PR 32's entries, by list and in order: PR 36 a
 # configuration, its cell and two readers, PR 38 the idle ledger's five readers,
 # PR 41 a configuration, its cell and two readers, PR 43 the same, PR 47 a
-# configuration, its cell and four readers. All the cut below may drop.
+# configuration, its cell and four readers, PR 49 one reader. All the cut below
+# may drop.
 APPENDED_SINCE_PR_32 = {"configs": ["keye-vl2-30b-a3b-int8", "trinity-mini-int8", "lfm2-8b-a1b-int8",
                                     "xing4.0-29b-a4b-int8"],
                         "workloads": ["keye-vl2-30b-a3b.long-context", "trinity-mini.long-context",
@@ -79,7 +89,8 @@ APPENDED_SINCE_PR_32 = {"configs": ["keye-vl2-30b-a3b-int8", "trinity-mini-int8"
                                       "swa_page_skip_share", "afmoe_step_hbm_roofline_share",
                                       "lfm2_decode_hbm_roofline_share", "shortconv_busy_share",
                                       "mla_step_hbm_roofline_share", "mla_attn_roofline_share",
-                                      "mla_attn_busy_share", "mhc_busy_share"]}
+                                      "mla_attn_busy_share", "mhc_busy_share",
+                                      "post_warmup_compile_s"]}
 
 
 def test_the_benchmark_gains_one_configuration_one_cell_and_three_readers(tmp_path, monkeypatch):

@@ -622,8 +622,11 @@ def test_masked_decode_kernel_stays_inside_its_start_up_budget():
 # one lay. A traced equation costs every start ~0.35 ms here and 3-5 times
 # that on the chip's host (``PERF.md`` section 6, PR 34): the whole addition is
 # under 0.1 s, and there is no second compiled form to load (held by
-# ``tests/test_pipelined_admission.py``).
-HOST_STEPPED_PROGRAMS = {"_mixed_step_fn": 350 + 18, "_fresh_unpack_fn": 26 + 12}
+# ``tests/test_pipelined_admission.py``). ISSUE 49 adds two to the mixed step,
+# traced with the engine's own logit-bias table as every start now traces it:
+# the slice that drops the table's zero row and the add (368 -> 370); the
+# packed mask's expansion and the automaton's gathers stay out until a grammar.
+HOST_STEPPED_PROGRAMS = {"_mixed_step_fn": 350 + 18 + 2, "_fresh_unpack_fn": 26 + 12}
 
 
 @pytest.mark.parametrize("name", list(HOST_STEPPED_PROGRAMS))
@@ -635,8 +638,11 @@ def test_host_stepped_programs_stay_under_their_equation_ceilings_with_the_block
     block = engine._last_block
     assert block.shape == (2, 4) and block.dtype == jnp.float32
     if name == "_mixed_step_fn":
-        traced = jax.make_jaxpr(lambda *a: Engine._mixed_step_fn(engine, *a, masked=False))(
-            engine.params, engine.cache, jnp.zeros((engine._mixed_pack.size,), I32), engine._rng, block)
+        automaton, mnext, mbits, mbias = engine._mask_args()
+        assert not automaton and mbias.shape == (4 + 1, engine.model_cfg.vocab_size)
+        traced = jax.make_jaxpr(lambda *a: Engine._mixed_step_fn(engine, *a, automaton=False))(
+            engine.params, engine.cache, jnp.zeros((engine._mixed_pack.size,), I32), engine._rng, block,
+            mnext, mbits, mbias)
     else:
         traced = jax.make_jaxpr(lambda *a: Engine._fresh_unpack_fn(engine, *a))(
             jnp.zeros((engine._fresh_pack.size,), I32), engine._rng, block)

@@ -320,7 +320,11 @@ async def test_hbm_pane_reports_plan_and_never_fabricates_live_bytes():
     snap = json.loads(resp.body)
     plan = snap["plan"]
     assert plan["weights_bytes"] > 0 and plan["kv_pool_bytes"] > 0
-    assert plan["plan_bytes"] == plan["weights_bytes"] + plan["kv_pool_bytes"]
+    # The logit-bias table is in the plan from the engine's construction
+    # (ISSUE 49): every step program reads it, biased request or not.
+    assert plan["logit_bias_bytes"] == eng.structured.bias_dev.nbytes
+    assert plan["plan_bytes"] == (plan["weights_bytes"] + plan["kv_pool_bytes"]
+                                  + plan["logit_bias_bytes"])
     pages = snap["kv_pages"]
     assert pages["total"] == eng.allocator.num_pages
     assert 1 <= pages["high_water"] <= pages["total"]

@@ -273,17 +273,17 @@ class _TwelveArrayReference:
     def __init__(self, engine: Engine) -> None:
         self.e = engine
         self.mixed = jax.jit(partial(Engine._mixed_step_body, engine),
-                             static_argnames=("masked",), donate_argnums=(1,))
+                             static_argnames=("automaton",), donate_argnums=(1,))
 
     def mixed_step(self, rows):
         e = self.e
         a = _twelve_arrays(e, rows)
-        masked, mnext, mbits, mbias = e._mask_args()
+        automaton, mnext, mbits, mbias = e._mask_args()
         toks, lps, e.cache = self.mixed(
             e.params, e.cache, *(jnp.asarray(a[k]) for k in (
                 "tokens", "positions", "write_idx", "page_table", "q_starts", "q_lens", "kv_lens",
                 "temps", "top_ps", "seeds", "use_seed")), e._next_rng(),
-            mstates=jnp.asarray(a["mstates"]), mnext=mnext, mbits=mbits, mbias=mbias, masked=masked)
+            mstates=jnp.asarray(a["mstates"]), mnext=mnext, mbits=mbits, mbias=mbias, automaton=automaton)
         e._dev_carry = None
         return np.asarray(toks), np.asarray(lps)
 
@@ -296,20 +296,20 @@ class _TwelveArrayReference:
         e._chain_active = active.copy()
         e._pred_pos = np.asarray(positions, np.int64).copy()
         rng = e._next_rng()
-        masked, mnext, mbits, mterm, mbias = e._mask_args_ee()
+        automaton, mnext, mbits, mterm, mbias = e._mask_args_ee()
         e._reserved[:] = 0
         e._reserve_chain_horizon(active, n)
         toks, lps, *carry, e.cache = e._decode_chunk_fn_paged_ee(
             e.params, e.cache, jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(~active),
             jnp.asarray(np.asarray(budgets, np.int32)), stop_d, e._dev_page_table, e._dev_reserved,
             *e._dev_sampling[:4], rng, mstates=jnp.asarray(e._zero_mstates), mnext=mnext,
-            mbits=mbits, mterm=mterm, mbias=mbias, n_steps=n, masked=masked)
+            mbits=mbits, mterm=mterm, mbias=mbias, n_steps=n, automaton=automaton)
         e._pred_pos = e._pred_pos + n * e._chain_active
         e._dev_carry = tuple(carry)
         return np.asarray(toks), np.asarray(lps)  # (the engine's own fetch splits one block)
 
 
-def _drive(engine: Engine, reference: bool, masked: bool, ahead: bool = False):
+def _drive(engine: Engine, reference: bool, biased: bool, ahead: bool = False):
     """Eight mixed steps (three admissions beside decode rows), a fresh
     decode chunk, two chained chunks. Returns every (tokens, logprobs)
     block in order. ``ahead`` (ISSUE 40): every program behind a mixed
@@ -317,10 +317,13 @@ def _drive(engine: Engine, reference: bool, masked: bool, ahead: bool = False):
     tokens read on the device from that step's block (``from_block``)."""
     ref = _TwelveArrayReference(engine) if reference else None
     S = engine.config.max_slots
-    if masked:
+    if biased:
         for slot in range(S):
             engine.structured_register(slot, None, {7: 3.0, 40 + slot: -2.0})
-        assert engine._mask_args()[0]
+        # ISSUE 49: rows of the table every program already takes; no
+        # grammar flag, no automaton tables, so no second lowering.
+        assert not engine._mask_args()[0] and not engine.structured.live
+        assert engine._mask_args()[3] is engine.structured.bias_dev
     rng = np.random.default_rng(5)
     # slot -> (temp, top_p, seed): greedy, seeded and unseeded sampling
     sampling = {0: (0.0, 1.0, None), 1: (0.8, 0.9, 1234567), 2: (1.1, 1.0, None)}
@@ -407,10 +410,10 @@ def _drive(engine: Engine, reference: bool, masked: bool, ahead: bool = False):
 
 
 @pytest.mark.parametrize("family", list(FAMILIES))
-@pytest.mark.parametrize("masked", [False, True], ids=["unmasked", "masked"])
-def test_packed_submits_equal_the_twelve_array_reference_byte_for_byte(family, masked):
-    got = _drive(_engine(family), reference=False, masked=masked)
-    want = _drive(_engine(family), reference=True, masked=masked)
+@pytest.mark.parametrize("biased", [False, True], ids=["unbiased", "biased"])
+def test_packed_submits_equal_the_twelve_array_reference_byte_for_byte(family, biased):
+    got = _drive(_engine(family), reference=False, biased=biased)
+    want = _drive(_engine(family), reference=True, biased=biased)
     assert len(got) == len(want) == 11
     for i, ((gt, gl), (wt, wl)) in enumerate(zip(got, want)):
         np.testing.assert_array_equal(gt, wt, err_msg=f"tokens of block {i}")
@@ -420,14 +423,14 @@ def test_packed_submits_equal_the_twelve_array_reference_byte_for_byte(family, m
 
 
 @pytest.mark.parametrize("family", list(FAMILIES))
-@pytest.mark.parametrize("masked", [False, True], ids=["unmasked", "masked"])
-def test_programs_dispatched_before_the_last_mixed_step_is_fetched_equal_the_reference(family, masked):
+@pytest.mark.parametrize("biased", [False, True], ids=["unbiased", "biased"])
+def test_programs_dispatched_before_the_last_mixed_step_is_fetched_equal_the_reference(family, biased):
     """ISSUE 40's operand: seven mixed steps and the fresh decode chunk
     each dispatched behind an UNFETCHED mixed step, every decode row's
     token taken from that step's block on the device. Same rows, same
     seeds, same step counter: the blocks are the host-stepped reference's."""
-    got = _drive(_engine(family), reference=False, masked=masked, ahead=True)
-    want = _drive(_engine(family), reference=True, masked=masked)
+    got = _drive(_engine(family), reference=False, biased=biased, ahead=True)
+    want = _drive(_engine(family), reference=True, biased=biased)
     assert len(got) == len(want) == 11
     for i, ((gt, gl), (wt, wl)) in enumerate(zip(got, want)):
         np.testing.assert_array_equal(gt, wt, err_msg=f"tokens of block {i}")

@@ -129,7 +129,7 @@ async def test_non_streaming_json_object_mode(stack):
         assert session.feed(byte) != "end"
 
 
-async def test_unconstrained_traffic_unchanged_after_masked_recompile(stack):
+async def test_unconstrained_traffic_unchanged_after_grammar_recompile(stack):
     sidecar, port, _otel = stack
     status, raw = await _post(port, _chat_body(max_tokens=8), stream=True)
     assert status == 200
