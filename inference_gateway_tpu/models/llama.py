@@ -60,6 +60,15 @@ class LlamaConfig:
     # whose other layers see only ``sliding_window`` keys.
     layer_rope: tuple | None = None
     layer_window: tuple | None = None
+    # Granite's four multipliers (ISSUE 50), each at the value that changes
+    # nothing: the embedding times embedding_multiplier; attention scores
+    # scaled by attention_multiplier in place of head_dim ** -0.5 (None);
+    # both sublayers' outputs times residual_multiplier before they join the
+    # stream; the logits divided by logits_scaling.
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float | None = None
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # Stored as a hashable tuple of (key, value) pairs so the config can be
     # a jit static argument; accepts a dict at construction.
     rope_scaling: Any = None
@@ -93,6 +102,32 @@ _ACT = {
 def _nw(w, cfg: "LlamaConfig"):
     """Norm weight convention: Gemma stores (w - 1)."""
     return w + 1 if cfg.norm_offset else w
+
+
+def _embed(params: Params, cfg: "LlamaConfig", tokens):
+    """The tokens' rows of the embedding, times Gemma's sqrt(hidden) or
+    Granite's embedding_multiplier where the model has one."""
+    x = params["embed"][tokens]
+    if cfg.embed_scale:
+        x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
+
+
+def _head(params: Params, cfg: "LlamaConfig", x):
+    """Float32 logits of normed states ``x``: the tied or the untied head,
+    divided by logits_scaling where the model has one."""
+    if cfg.tie_word_embeddings:
+        logits = (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
+    else:
+        logits = qmatmul(x, params["lm_head"]).astype(jnp.float32)
+    return logits if cfg.logits_scaling == 1.0 else logits / cfg.logits_scaling
+
+
+def _scaled(y, cfg: "LlamaConfig"):
+    """A sublayer's output as it joins the stream: times residual_multiplier."""
+    return y if cfg.residual_multiplier == 1.0 else y * jnp.asarray(cfg.residual_multiplier, y.dtype)
 
 
 def _rope_qk(q, k, cos, sin, cfg: "LlamaConfig", li, lp=None):
@@ -170,15 +205,29 @@ def _ffn_out(y, lp: Params, cfg: "LlamaConfig"):
     return rms_norm(y, _nw(lp["post_ffn_norm"], cfg), cfg.rms_norm_eps) if cfg.sandwich_norm else y
 
 
-def _layer_groups(params: Params, ffn, unscanned: tuple, prefix, num_layers: int) -> list:
+def _layer_groups(params: Params, ffn, unscanned: tuple, prefix, num_layers: int, kinds=None) -> list:
     """The scanned groups of a layer stack, in order, as (leaves, ffn, first
     layer index, layers): one group for a model whose layers are alike; two
     where a ``prefix`` of layers with an FFN of their own leads (ISSUE 41:
     ``{"layers": leaves with their own leading axis, "ffn": the seam}``). The
-    layer index runs on across the groups: one page pool, one page table."""
+    layer index runs on across the groups: one page pool, one page table.
+
+    A per-layer kind that asks for it (``kinds["runs"]``, ISSUE 50) has its
+    body cut into RUNS of one kind, a scan each: no scanned body then holds
+    both kinds, so no ``lax.cond`` stands around a state store too large to be
+    copied (``_mix_by_kind``). A run's scanned leaves are slices of the body's
+    (norms and a router: the matrices are read whole at an index, ``unscanned``)."""
     lead = jax.tree.leaves(prefix["layers"])[0].shape[0] if prefix else 0
-    body = (_scan_layers(params, unscanned), ffn, lead, num_layers - lead)
-    return [(prefix["layers"], prefix["ffn"], 0, lead), body] if prefix else [body]
+    scanned = _scan_layers(params, unscanned)
+    groups = [(prefix["layers"], prefix["ffn"], 0, lead)] if prefix else []
+    if kinds is None or not kinds.get("runs"):
+        return groups + [(scanned, ffn, lead, num_layers - lead)]
+    flags, first = kinds["state"], lead
+    for li in range(lead + 1, num_layers + 1):
+        if li == num_layers or flags[li] != flags[first]:
+            groups.append((jax.tree.map(lambda a: a[first - lead:li - lead], scanned), ffn, first, li - first))
+            first = li
+    return groups
 
 
 def _project_qkv(h, ap: Params, li, cfg: "LlamaConfig", cos, sin, cache: Params):
@@ -198,6 +247,9 @@ def _project_qkv(h, ap: Params, li, cfg: "LlamaConfig", cos, sin, cache: Params)
     k = k.reshape(B, T, Hkv, D)
     v = v.reshape(B, T, Hkv, D)
     q, k = _rope_qk(q, k, cos, sin, cfg, li, ap)
+    if cfg.attention_multiplier is not None:
+        # The kernels scale scores by head_dim ** -0.5: the queries carry the rest.
+        q = q * jnp.asarray(cfg.attention_multiplier * D ** 0.5, q.dtype)
     return (q, k, v, k.reshape(B, T, Hkv * D).astype(cache["k"].dtype),
             v.reshape(B, T, Hkv * D).astype(cache["v"].dtype))
 
@@ -238,17 +290,17 @@ def _block_out(residual, x, y, maps):
     return x + y if residual is None else residual["write"](x, y, maps)
 
 
-def _mix_by_kind(kinds: dict, group: tuple, li, h, ck, cv, store, ops):
+def _mix_by_kind(kinds: dict, group: tuple, li, h, ck, cv, store: dict, ops):
     """Layer ``li``'s token mixer in a model whose layers are of two KINDS
     (ISSUE 43; ``kinds`` is the seam of ``forward_paged_impl``): an attention
     layer projects, writes its plane of the pool and calls the kernel
     (``ops``: the skeleton's own ``project``, ``write`` and ``attend``); a state layer
     writes no key, calls no kernel, and reads and writes its entry of the
-    slot-indexed ``store``. Each operator's leaves and cache are stacks of
+    slot-indexed ``store`` (name -> (state layers, slots, ...)). Each operator's leaves and cache are stacks of
     their own, read at the layer's ORDINAL among its kind, not at the layer
     index. One scanned body holds both kinds under ``lax.cond``s on the
     layer's flag, so a step program holds one instance of its kernel; a
-    ``group`` (first layer, layers) of state layers alone holds no branch.
+    ``group`` (first layer, layers) of one kind alone holds no branch.
 
     The pools never pass THROUGH a ``cond``: the chip's compiler copies a
     pool that one branch updates and the other hands back (0.8 GB a side an
@@ -256,7 +308,9 @@ def _mix_by_kind(kinds: dict, group: tuple, li, h, ck, cv, store, ops):
     PERF.md section 6, PR 43). So the first ``cond`` projects (queries, keys
     and values, or nothing), the write is outside it with a state layer's
     indices past the pool (dropped), and the second reads the pools and
-    either attends or runs the state operator. Returns (the residual
+    either attends or runs the state operator. The STORE does pass through the
+    second: a model whose store is too large for that has its layers grouped
+    by runs of one kind (``_layer_groups``). Returns (the residual
     contribution, the pools, the store)."""
     flags = kinds["state"]
     ordinal = jnp.asarray([sum(f == flags[l] for f in flags[:l]) for l in range(len(flags))], jnp.int32)[li]
@@ -265,14 +319,18 @@ def _mix_by_kind(kinds: dict, group: tuple, li, h, ck, cv, store, ops):
 
     def state_layer(store):
         B, T, H = h.shape
-        out, mine = kinds["mix"](h.reshape(B * T, H), at(kinds["leaves"]), at(store))
-        return out.reshape(B, T, H), jax.lax.dynamic_update_index_in_dim(store, mine, ordinal, 0)
+        out, store = kinds["mix"](h.reshape(B * T, H), at(kinds["leaves"]), store, ordinal)
+        return out.reshape(B, T, H), store
 
+    own = lambda: at(kinds["attention"])
     held = set(flags[group[0]:group[0] + group[1]])
     if held == {1}:
         out, store = state_layer(store)
         return out, ck, cv, store
-    own = lambda: at(kinds["attention"])
+    if held == {0}:
+        q, *_ = qkv = project(h, own(), li)
+        ck, cv, _ = write(ck, cv, qkv, plane=ordinal)
+        return attend(h, own(), li, ordinal, q, ck, cv), ck, cv, store
     is_state = jnp.asarray(flags, bool)[li]
     nothing = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), jax.eval_shape(lambda: project(h, own(), li)))
     qkv = jax.lax.cond(is_state, lambda: nothing, lambda: project(h, own(), li))
@@ -645,16 +703,19 @@ def forward_paged_impl(
 
     The per-layer kind (ISSUE 43): ``kinds`` is None for every model whose
     layers all write keys (nothing below changes for them), or a dict
-    ``{"state": flags, "cache": name, "attention": leaves, "leaves": leaves,
-    "mix": fn}``. ``state`` is one 0/1 flag a layer: 1 a layer whose token
-    mixer owns no pages and keeps, a slot, a state in ``cache[name]`` (state
-    layers, slots, ...), which the layer loop carries beside the flat pools.
+    ``{"state": flags, "cache": names, "attention": leaves, "leaves": leaves,
+    "mix": fn}`` (and ``"runs": True``: ``_layer_groups``). ``state`` is one
+    0/1 flag a layer: 1 a layer whose token mixer owns no pages and keeps, a
+    slot, its state in the arrays ``cache[name]`` (state layers, slots, ...)
+    of ``names``, which the layer loop carries beside the flat pools.
     The pool's leading axis is then the CACHING layers alone, and the
     operators' leaves are stacks of their own, ``attention`` (caching layers,
     ...) under the attention's names and ``leaves`` (state layers, ...): a
     layer's plane, store entry and leaves are read at its ordinal among its
-    kind (``_mix_by_kind``). ``mix(h (N, H), a state layer's leaves, its store
-    entry) -> (the residual contribution (N, H), the new entry)``; the
+    kind (``_mix_by_kind``). ``mix(h (N, H), a state layer's leaves, the store
+    {name: array}, the ordinal) -> (the residual contribution (N, H), the
+    store)``: the mixer reads and writes its own entry (one with a kernel
+    does so in place); the
     skeletons' scanned leaves keep ``attn_norm``, the operator's input norm.
 
     The seam: ``ffn(x, attn_in, lp, li, valid)`` returns the block's
@@ -674,9 +735,7 @@ def forward_paged_impl(
     L, P, page_size, HkvD = cache[mixer["pool"] if own else "k"].shape
     flat = P * page_size
 
-    x = params["embed"][tokens]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
+    x = _embed(params, cfg, tokens)
     if residual is not None:
         x = residual["enter"](x)
     inv_freq = rope_inv_freq(cfg.hd, cfg.rope_theta, cfg.rope_scaling_dict)
@@ -719,7 +778,7 @@ def forward_paged_impl(
     total = L * flat
 
     assert mixer is None or kinds is None, "an attention seam beside a per-layer kind: no model has both"
-    extra = tuple(mixer["cache"]) if mixer is not None else (kinds["cache"],) if kinds is not None else ()
+    extra = tuple(mixer["cache"]) if mixer is not None else tuple(kinds["cache"]) if kinds is not None else ()
     if (mixer is not None or kinds is not None) and mode != "decode":
         raise NotImplementedError(
             f"a model with an attention seam or a state store admits through the mixed step; forward_paged mode {mode!r} has no path for it")
@@ -796,13 +855,15 @@ def forward_paged_impl(
             if kinds is None:
                 out, ck, cv, ce, mix_aux = attention(h, lp, li, li, ck, cv, ce)
             else:
-                out, ck, cv, ce[0] = _mix_by_kind(kinds, group, li, h, ck, cv, ce[0], (project, write, attend))
-            x = _block_out(residual, x, out, maps)
+                out, ck, cv, store = _mix_by_kind(kinds, group, li, h, ck, cv, dict(zip(extra, ce)),
+                                                  (project, write, attend))
+                ce = [store[n] for n in extra]
+            x = _block_out(residual, x, _scaled(out, cfg), maps)
         with jax.named_scope("mlp"):
             u, maps = _block_in(residual, x, lp, "ffn")
             y = ffn(u, h, lp, li, tok_valid)
             y, aux = y if isinstance(y, tuple) else (y, None)
-            x = _block_out(residual, x, _ffn_out(y, lp, cfg), maps)
+            x = _block_out(residual, x, _scaled(_ffn_out(y, lp, cfg), cfg), maps)
         return (x, ck, cv, *ce), (aux if mixer is None or own else (aux, mix_aux))
 
     ck0 = cache[mixer["pool"] if own else "k"].reshape(total, HkvD)
@@ -810,7 +871,7 @@ def forward_paged_impl(
     # An attention seam's arrays are carried by page, a state store as it lies.
     carry = (x, ck0, cv0, *(cache[n] if kinds is not None else cache[n].reshape((L * P,) + cache[n].shape[2:])
                             for n in extra))
-    for leaves, group_ffn, first, count in _layer_groups(params, ffn, unscanned, prefix, cfg.num_layers):
+    for leaves, group_ffn, first, count in _layer_groups(params, ffn, unscanned, prefix, cfg.num_layers, kinds):
         carry, aux = jax.lax.scan(partial(body, ffn=group_ffn, group=(first, count)), carry,
                                   (leaves, jnp.arange(first, first + count)))
     x, ck, cv, *ce = carry
@@ -831,10 +892,7 @@ def forward_paged_impl(
             else:  # prefill starts at 0; prefill_chunk at positions[:, 0]
                 idx = jnp.maximum(lengths - 1 - positions[:, 0], 0)
             x = x[jnp.arange(B), idx]
-        if cfg.tie_word_embeddings:
-            logits = (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
-        else:
-            logits = qmatmul(x, params["lm_head"]).astype(jnp.float32)
+        logits = _head(params, cfg, x)
     return (logits, new_cache, aux) if with_aux else (logits, new_cache)
 
 
@@ -906,9 +964,7 @@ def forward_ragged_impl(
     flat = P * page_size
     total = L * flat
 
-    x = params["embed"][tokens]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
+    x = _embed(params, cfg, tokens)
     if residual is not None:
         x = residual["enter"](x)
     inv_freq = rope_inv_freq(cfg.hd, cfg.rope_theta, cfg.rope_scaling_dict)
@@ -917,7 +973,7 @@ def forward_ragged_impl(
     tok_valid = jnp.arange(T) < jnp.sum(q_lens)
 
     assert mixer is None or kinds is None, "an attention seam beside a per-layer kind: no model has both"
-    extra = tuple(mixer["cache"]) if mixer is not None else (kinds["cache"],) if kinds is not None else ()
+    extra = tuple(mixer["cache"]) if mixer is not None else tuple(kinds["cache"]) if kinds is not None else ()
     assert mixer is None or own or prefix is None, "an attention seam's counts of a prefix's layers would be lost"
 
     project = partial(_project_qkv, cfg=cfg, cos=cos, sin=sin, cache=cache)
@@ -958,20 +1014,22 @@ def forward_ragged_impl(
             if kinds is None:
                 out, ck, cv, ce, mix_aux = attention(h, lp, li, li, ck, cv, ce)
             else:
-                out, ck, cv, ce[0] = _mix_by_kind(kinds, group, li, h, ck, cv, ce[0], (project, write, attend))
-            x = _block_out(residual, x, out, maps)
+                out, ck, cv, store = _mix_by_kind(kinds, group, li, h, ck, cv, dict(zip(extra, ce)),
+                                                  (project, write, attend))
+                ce = [store[n] for n in extra]
+            x = _block_out(residual, x, _scaled(out, cfg), maps)
         with jax.named_scope("mlp"):
             u, maps = _block_in(residual, x, lp, "ffn")
             y = ffn(u, h, lp, li, tok_valid)
             y, aux = y if isinstance(y, tuple) else (y, None)
-            x = _block_out(residual, x, _ffn_out(y, lp, cfg), maps)
+            x = _block_out(residual, x, _scaled(_ffn_out(y, lp, cfg), cfg), maps)
         return (x, ck, cv, *ce), (aux if mixer is None or own else (aux, mix_aux))
 
     ck0 = cache[mixer["pool"] if own else "k"].reshape(total, HkvD)
     cv0 = None if own else cache["v"].reshape(total, HkvD)  # (None: an empty leaf of the carry)
     carry = (x, ck0, cv0, *(cache[n] if kinds is not None else cache[n].reshape((L * P,) + cache[n].shape[2:])
                             for n in extra))
-    for leaves, group_ffn, first, count in _layer_groups(params, ffn, unscanned, prefix, cfg.num_layers):
+    for leaves, group_ffn, first, count in _layer_groups(params, ffn, unscanned, prefix, cfg.num_layers, kinds):
         carry, aux = jax.lax.scan(partial(body, ffn=group_ffn, group=(first, count)), carry,
                                   (leaves, jnp.arange(first, first + count)))
     x, ck, cv, *ce = carry
@@ -990,10 +1048,7 @@ def forward_ragged_impl(
         # clamped to index 0; the caller ignores them).
         last = jnp.clip(q_starts + q_lens - 1, 0, T - 1)
         x = x[0, last]  # (R, H)
-        if cfg.tie_word_embeddings:
-            logits = (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
-        else:
-            logits = qmatmul(x, params["lm_head"]).astype(jnp.float32)
+        logits = _head(params, cfg, x)
     return (logits, new_cache, aux) if with_aux else (logits, new_cache)
 
 

@@ -14,7 +14,11 @@ skeletons; the sigmoid router with no shared expert), and the Xing4.0 block
 rides the attention seam as its owner, ops/mla_attention.py, and several
 residual streams a token mixed by hyper-connections, ops/hyper_connections.py,
 on the skeletons' residual seam; Trinity's dense prefix, sigmoid router and
-shared expert).
+shared expert), and the Granite-4.0-H block (ISSUE 50, ``granitemoehybrid``:
+the per-layer kind's third value, a Mamba-2 mixer whose matrix state a head
+rides the state store beside the convolution's tail, ops/ssd.py; the four
+multipliers on the skeletons; a softmax router beside a shared MLP in every
+layer).
 
 Same TPU-first skeleton as models/llama.py (stacked layers + lax.scan,
 the shared paged and ragged forwards) with the dense MLP replaced by a
@@ -35,9 +39,12 @@ from inference_gateway_tpu.models.llama import (
     LlamaConfig,
     _attn_out,
     _dense_ffn,
+    _embed,
     _ffn_out,
+    _head,
     _layer_groups,
     _rope_qk,
+    _scaled,
     _scan_layers,
     forward_paged_impl,
     forward_ragged_impl,
@@ -49,6 +56,7 @@ from inference_gateway_tpu.ops.norms import rms_norm
 from inference_gateway_tpu.ops.quant import qeinsum, qmatmul, seeded_qk_gain, seeded_std
 from inference_gateway_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq, yarn_mscale
 from inference_gateway_tpu.ops.sparse_attention import one_query_tokens_read, sparse_decode, sparse_ragged
+from inference_gateway_tpu.ops.ssd import block_plan, recurrence, ssd_chunk, state_update, update_store
 from inference_gateway_tpu.ops.ssm import conv_decode, conv_ragged, token_segments
 
 
@@ -98,8 +106,20 @@ class MixtralConfig(LlamaConfig):
     # (``conv_in``, ``conv_w``, ``conv_out``: leading axis conv_layers; ``wq``
     # ... ``k_norm``: leading axis cache_layers), read at the layer's ordinal
     # among its kind, as are the page pool's planes (cache_layers of them).
+    # Granite-4.0-H's block (ISSUE 50): 2 is a layer whose token mixer is a
+    # Mamba-2 mixer (ops/ssd.py): ssm_heads heads of ssm_head_dim, a float32
+    # state of ssm_head_dim x ssm_state a head, B and C shared by all heads (one
+    # group), a depthwise causal convolution of conv_kernel taps with bias and
+    # SiLU over [x, B, C], a gated RMSNorm behind the scan. It keeps, a slot,
+    # the state (``ssm``) and the convolution's tail (``conv``). A model holds
+    # attention layers and ONE kind of state layer. ssd_block: the tokens a block
+    # of the scan's blocked form holds (any value gives the recurrence's numbers).
     layer_conv: tuple | None = None
     conv_kernel: int = 3
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssd_block: int = 256
     # Xing4.0's block (ISSUE 47). kv_lora_rank 0: none. A latent attention
     # (the DeepSeek-V2 form): queries through a q_lora_rank bottleneck with a
     # norm, num_heads of qk_nope_head_dim + qk_rope_head_dim (= head_dim);
@@ -131,10 +151,14 @@ class MixtralConfig(LlamaConfig):
         if not 0 <= self.num_dense_layers < self.num_layers:
             raise ValueError(f"num_dense_layers {self.num_dense_layers} of {self.num_layers} layers")
         if self.layer_conv is not None:
-            flags = tuple(int(bool(f)) for f in self.layer_conv)
+            flags = tuple(int(f) for f in self.layer_conv)
             object.__setattr__(self, "layer_conv", flags)
-            if len(flags) != self.num_layers or not 0 < sum(flags) < self.num_layers:
-                raise ValueError(f"layer_conv {flags}: one flag a layer of {self.num_layers}, both kinds present")
+            if (len(flags) != self.num_layers or 0 not in flags or len(set(flags) - {0}) != 1
+                    or not set(flags) <= {0, 1, 2}):
+                raise ValueError(f"layer_conv {flags}: one of 0 (attention), 1 (short convolution), 2 (Mamba-2) a "
+                                 f"layer of {self.num_layers}; attention and ONE kind of state layer present")
+            if (2 in flags) != bool(self.ssm_heads):
+                raise ValueError("layer_conv 2 and ssm_heads: a Mamba-2 layer has heads, and no other layer")
             if self.indexer_topk or self.attn_gate or self.sandwich_norm or self.qkv_bias:
                 raise ValueError("layer_conv: the attention operators' stack carries no indexer, gate, post-norm or bias")
         if self.kv_lora_rank:
@@ -151,8 +175,25 @@ class MixtralConfig(LlamaConfig):
 
     @property
     def conv_layers(self) -> int:
-        """Layers that keep a convolution's tail a slot and own no pages."""
-        return sum(self.layer_conv or ())
+        """Layers that keep a state a slot (a convolution's tail; a Mamba-2
+        layer's matrix state too) and own no pages."""
+        return sum(f > 0 for f in self.layer_conv or ())
+
+    @property
+    def mamba2(self) -> bool:
+        """The model's state layers are Mamba-2 mixers (ISSUE 50)."""
+        return 2 in (self.layer_conv or ())
+
+    @property
+    def ssm_inner(self) -> int:
+        """A Mamba-2 mixer's inner width: heads x head size."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def conv_channels(self) -> int:
+        """What a state layer's convolution runs over: hidden_size for the
+        gated short convolution, [x, B, C] for a Mamba-2 mixer."""
+        return self.ssm_inner + 2 * self.ssm_state if self.mamba2 else self.hidden_size
 
     @property
     def cache_layers(self) -> int:
@@ -173,7 +214,8 @@ class MixtralConfig(LlamaConfig):
     @property
     def state_bytes_per_slot(self) -> int:
         """A slot's share of the state store (float32, ops/ssm.py)."""
-        return self.conv_layers * (self.conv_kernel - 1) * self.hidden_size * 4
+        tails = (self.conv_kernel - 1) * self.conv_channels
+        return self.conv_layers * (tails + self.ssm_inner * self.ssm_state * self.mamba2) * 4
 
 
 Params = dict[str, Any]
@@ -236,7 +278,18 @@ def layer_leaves(cfg: MixtralConfig, count: int, dtype, matrix, plain=None) -> P
 
 
 CONV_LEAVES = ("conv_in", "conv_w", "conv_out")
+MAMBA_LEAVES = ("in_proj", "conv_w", "conv_b", "dt_bias", "A_log", "D", "ssm_norm", "out_proj")
 ATTENTION_STACK = ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
+
+
+def state_leaves(cfg: "MixtralConfig") -> tuple:
+    """The names of the state layers' stack."""
+    return MAMBA_LEAVES if cfg.mamba2 else CONV_LEAVES
+
+
+def attention_stack(cfg: "MixtralConfig") -> tuple:
+    """The names of the attention operators' stack in a model with a per-layer kind."""
+    return ATTENTION_STACK if cfg.qk_norm else ATTENTION_STACK[:4]
 
 
 def operator_leaves(cfg: MixtralConfig, dtype, matrix, plain) -> Params:
@@ -251,8 +304,36 @@ def operator_leaves(cfg: MixtralConfig, dtype, matrix, plain) -> Params:
     H, n = cfg.hidden_size, cfg.conv_layers
     attention = attention_leaves(cfg, cfg.cache_layers, dtype, matrix)
     del attention["attn_norm"]   # the layers' own stacks hold every operator's input norm
+    if cfg.mamba2:
+        # W_in (H, [z Di, x Di, B N, C N, dt heads]), the taps (K, Di + 2 N) oldest
+        # first and their bias, W_out (Di, H); dt_bias, A_log and D a head, float32,
+        # at Mamba-2's published initialisation (``mamba_heads_init``).
+        Di, C, Hh = cfg.ssm_inner, cfg.conv_channels, cfg.ssm_heads
+        taps = plain("conv_w", (n, cfg.conv_kernel, C))
+        gain = jnp.concatenate([jnp.ones((Di,)), jnp.full((C - Di,), seeded_std(cfg, "conv_bc_gain"))])
+        return {**attention, "in_proj": matrix("in_proj", (n, H, 2 * Di + 2 * cfg.ssm_state + Hh)),
+                "conv_w": (taps * gain).astype(taps.dtype), "conv_b": plain("conv_b", (n, C)),
+                **mamba_heads_init(n, Hh), "ssm_norm": jnp.ones((n, Di), dtype),
+                "out_proj": matrix("out_proj", (n, Di, H))}
     return {**attention, "conv_in": matrix("conv_in", (n, H, 3 * H)),
             "conv_w": plain("conv_w", (n, cfg.conv_kernel, H)), "conv_out": matrix("conv_out", (n, H, H))}
+
+
+def mamba_heads_init(layers: int, heads: int) -> Params:
+    """``A_log``, ``dt_bias`` and ``D`` of ``layers`` Mamba-2 mixers as the
+    published initialisation spreads them (mamba_ssm's Mamba2: A uniform in
+    [1, 16], Delta log-uniform in [0.001, 0.1] with dt_bias its inverse
+    softplus, D = 1), laid out evenly over a layer's heads in place of drawn,
+    the A's and the Delta's in orders coprime to each other, so that every
+    build holds the same fast and slow heads and the slowest (Delta A = 0.001
+    a token) is among them. Float32, (layers, heads) each."""
+    i = jnp.arange(heads, dtype=jnp.float32)
+    u = (i + 0.5) / heads
+    v = ((i * 37 + heads // 2) % heads + 0.5) / heads   # 37 is coprime to every count of heads used (a power of two)
+    dt = jnp.exp(jnp.log(0.001) + v * (jnp.log(0.1) - jnp.log(0.001)))
+    lay = lambda a: jnp.broadcast_to(a[None, :], (layers, heads)).astype(jnp.float32)
+    return {"A_log": lay(jnp.log(1.0 + 15.0 * u)), "dt_bias": lay(dt + jnp.log(-jnp.expm1(-dt))),
+            "D": jnp.ones((layers, heads), jnp.float32)}
 
 
 def init_params(rng: jax.Array, cfg: MixtralConfig, dtype=jnp.bfloat16) -> Params:
@@ -325,10 +406,14 @@ def init_state(cfg: MixtralConfig, slots: int) -> Params:
     for any other model."""
     if cfg.layer_conv is None:
         return {}
-    return {"conv": jnp.zeros((cfg.conv_layers, slots, cfg.conv_kernel - 1, cfg.hidden_size), jnp.float32)}
+    state = {"conv": jnp.zeros((cfg.conv_layers, slots, cfg.conv_kernel - 1, cfg.conv_channels), jnp.float32)}
+    if cfg.mamba2:   # a head's matrix state (ops/ssd.py)
+        state["ssm"] = jnp.zeros((cfg.conv_layers, slots, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
+    return state
 
 
 EXPERT_LEAVES = ("wg", "wu", "wd")
+SHARED_LEAVES = ("shared_wg", "shared_wu", "shared_wd")
 
 
 def _moe_block(flat: jnp.ndarray, routed: jnp.ndarray, router, experts: Params, li,
@@ -379,9 +464,7 @@ def forward(
     B, T = tokens.shape
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     L = cfg.num_layers
-    x = params["embed"][tokens]
-    if cfg.embed_scale:
-        x = x * jnp.asarray(cfg.hidden_size ** 0.5, x.dtype)
+    x = _embed(params, cfg, tokens)
     inv_freq = rope_inv_freq(cfg.hd, cfg.rope_theta, cfg.rope_scaling_dict)
     cos, sin = rope_cos_sin(positions, inv_freq)
 
@@ -415,6 +498,8 @@ def forward(
         k = qmatmul(h, ap["wk"]).reshape(B, T, Hkv, D)
         v = qmatmul(h, ap["wv"]).reshape(B, T, Hkv, D)
         q, k = _rope_qk(q, k, cos, sin, cfg, li, ap)
+        if cfg.attention_multiplier is not None:   # gqa_attend scales by head_dim ** -0.5
+            q = q * jnp.asarray(cfg.attention_multiplier * D ** 0.5, q.dtype)
         nk = nv = None
         if kc is not None:
             rows = (jnp.arange(B) if slot_ids is None else slot_ids)[:, None]
@@ -430,9 +515,9 @@ def forward(
     def layer(x, lp, li, kc, vc, ffn):
         h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
         out, nk, nv = attention(h, lp, li, kc, vc)
-        x = x + out
+        x = x + _scaled(out, cfg)
         y = ffn(x, h, lp, li, tok_valid)
-        return x + _ffn_out(y[0] if isinstance(y, tuple) else y, lp, cfg), nk, nv
+        return x + _scaled(_ffn_out(y[0] if isinstance(y, tuple) else y, lp, cfg), cfg), nk, nv
 
     if cfg.layer_conv is not None:
         x, new_cache = _forward_by_kind(params, cfg, x, cache, attention, decode, lengths, slot_ids, tok_valid, seam)
@@ -470,9 +555,7 @@ def _dense_head(params: Params, cfg: MixtralConfig, x, lengths, mode: str, last_
     if last_only:
         idx = jnp.maximum(lengths - 1, 0) if mode == "prefill" else jnp.zeros_like(lengths)
         x = x[jnp.arange(x.shape[0]), idx]
-    if cfg.tie_word_embeddings:
-        return (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
-    return qmatmul(x, params["lm_head"]).astype(jnp.float32)
+    return _head(params, cfg, x)
 
 
 def _forward_by_kind(params, cfg: MixtralConfig, x, cache, attention, decode, lengths, slot_ids, tok_valid, seam):
@@ -481,59 +564,145 @@ def _forward_by_kind(params, cfg: MixtralConfig, x, cache, attention, decode, le
     reference child builds; serving goes through the paged skeletons). The
     layers are walked one by one, unrolled: each reads its operator's leaves
     and its cache rows at its ordinal among its kind. A prefill starts every
-    row's convolution from zero and leaves the row's last K - 1 inputs."""
+    row's state from zero and leaves the row's last (its last K - 1 inputs;
+    a Mamba-2 layer's state after the row's last token, by the token
+    recurrence: this path is the definition, not the serving one)."""
     B, T, H = x.shape
     layers, K = params["layers"], cfg.conv_kernel
     rows = jnp.arange(B) if slot_ids is None else slot_ids
-    new = None if cache is None else dict(cache)
+    new = dict(init_state(cfg, B) if cache is None else cache)
+    every = jnp.ones((B,), bool)
 
-    def conv(z, tails, w):   # z (B*T, H) float32, row-major by batch row
+    def conv(z, tails, w, b=None, act=None):   # z (B*T, C); tails (slots, K - 1, C): the layer's whole entry
         if decode:
-            return conv_decode(z, tails, w, None, jnp.ones((B,), bool), act=None)
-        z = z.reshape(B, T, H)
-        padded = jnp.concatenate([jnp.zeros((B, K - 1, H), z.dtype), z], axis=1)
+            c, mine = conv_decode(z, tails[rows], w, b, every, act=act)
+            return c, tails.at[rows].set(mine)
+        z = z.astype(jnp.float32).reshape(B, T, -1)
+        padded = jnp.concatenate([jnp.zeros((B, K - 1, z.shape[-1]), z.dtype), z], axis=1)
         c = sum(w[j] * padded[:, j:j + T] for j in range(K))
+        c = c if b is None else b + c
         last = lengths[:, None] + jnp.arange(K - 1)[None, :]              # the row's last K - 1, in ``padded``
-        return c.reshape(B * T, H), jnp.take_along_axis(padded, last[:, :, None], axis=1)
+        mine = jnp.take_along_axis(padded, last[:, :, None], axis=1)
+        return (c if act is None else act(c)).reshape(B * T, -1), tails.at[rows].set(mine)
 
+    def scan(ssm, ordinal, xs, delta, Bm, Cm, A, D, mm):
+        if decode:
+            y, mine = state_update(ssm[ordinal][rows], delta, xs, Bm, Cm, A, D, every)
+        else:   # a padded token is an identity step: Delta = 0
+            delta = jnp.where((jnp.arange(T)[None, :] < lengths[:, None])[..., None], delta.reshape(B, T, -1), 0.0)
+            by_row = lambda a: a.reshape((B, T) + a.shape[1:])
+            y, mine = jax.vmap(lambda xr, dr, br, cr: recurrence(jnp.zeros(ssm.shape[2:], jnp.float32), xr, dr, br, cr, A))(
+                by_row(xs), delta, by_row(Bm), by_row(Cm))
+            y = y.reshape(xs.shape) + D[None, :, None] * xs
+        return y, ssm.at[ordinal, rows].set(mine)
+
+    mix = (partial(_mamba2, cfg=cfg, conv=partial(conv, act=jax.nn.silu), scan=scan) if cfg.mamba2
+           else partial(_shortconv, conv=conv))
     at = lambda stack, names, i: {n: jax.tree.map(lambda a: a[i], stack[n]) for n in names}
     body = _scan_layers(params, seam["unscanned"])   # the expert layers' norms, router and bias
     ordinal = [0, 0]
-    for li, is_conv in enumerate(cfg.layer_conv):
+    for li, flag in enumerate(cfg.layer_conv):
         if li < cfg.num_dense_layers:
             lp, ffn = at(seam["prefix"]["layers"], seam["prefix"]["layers"], li), seam["prefix"]["ffn"]
         else:
             lp, ffn = at(body, body, li - cfg.num_dense_layers), seam["ffn"]
-        o = ordinal[is_conv]
-        ordinal[is_conv] += 1
+        is_state = int(flag > 0)
+        o = ordinal[is_state]
+        ordinal[is_state] += 1
         h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
-        if is_conv:
-            tails = jnp.zeros((B, K - 1, H), jnp.float32) if cache is None else new["conv"][o][rows]
-            out, tails = _shortconv(h.reshape(B * T, H), at(layers, CONV_LEAVES, o), tails, conv)
+        if is_state:
+            store = {n: new[n] for n in ("conv", "ssm") if n in new}
+            out, store = mix(h.reshape(B * T, H), at(layers, state_leaves(cfg), o), store, o)
             out = out.reshape(B, T, H)
-            if cache is not None:
-                new["conv"] = new["conv"].at[o, rows].set(tails)
+            new.update(store)
         else:
             kc, vc = (None, None) if cache is None else (new["k"][o], new["v"][o])
-            out, nk, nv = attention(h, at(layers, ATTENTION_STACK, o), li, kc, vc)
+            out, nk, nv = attention(h, at(layers, attention_stack(cfg), o), li, kc, vc)
             if cache is not None:
                 new["k"], new["v"] = new["k"].at[o].set(nk), new["v"].at[o].set(nv)
-        x = x + out
+        x = x + _scaled(out, cfg)
         y = ffn(x, h, lp, li, tok_valid)
-        x = x + (y[0] if isinstance(y, tuple) else y)
-    return x, new
+        x = x + _scaled(y[0] if isinstance(y, tuple) else y, cfg)
+    return x, (None if cache is None else new)
 
 
-def _shortconv(h: jnp.ndarray, sp: Params, tails: jnp.ndarray, conv) -> tuple[jnp.ndarray, jnp.ndarray]:
+def _entry(array, ordinal):
+    """A state layer's entry of one of the store's arrays."""
+    return jax.lax.dynamic_index_in_dim(array, ordinal, 0, keepdims=False)
+
+
+def _with_entry(array, ordinal, entry):
+    return jax.lax.dynamic_update_index_in_dim(array, entry, ordinal, 0)
+
+
+def _shortconv(h: jnp.ndarray, sp: Params, store: Params, ordinal, conv) -> tuple[jnp.ndarray, Params]:
     """LFM2's gated short convolution on N tokens: h (N, H) the operator's
-    normed input, ``sp`` its leaves, ``tails`` what its slots remember.
-    [B, C, u] = h W_in; z = B * u; c = the depthwise causal convolution of z
+    normed input, ``sp`` its leaves, ``store["conv"][ordinal]`` what its slots
+    remember. [B, C, u] = h W_in; z = B * u; c = the depthwise causal convolution of z
     (``conv(z, tails, taps) -> (c, new tails)``: ops/ssm.py's decode or ragged
     form, float32, no bias, no activation); the output (C * c) W_out."""
     with jax.named_scope("conv.short"):
         b, c, u = jnp.split(qmatmul(h, sp["conv_in"]).astype(jnp.float32), 3, axis=-1)
-        y, tails = conv(b * u, tails, sp["conv_w"].astype(jnp.float32))
-        return qmatmul((c * y).astype(h.dtype), sp["conv_out"]), tails
+        y, tails = conv(b * u, _entry(store["conv"], ordinal), sp["conv_w"].astype(jnp.float32))
+        return qmatmul((c * y).astype(h.dtype), sp["conv_out"]), {"conv": _with_entry(store["conv"], ordinal, tails)}
+
+
+def _mamba2(h: jnp.ndarray, mp: Params, store: Params, ordinal, *, cfg: MixtralConfig, conv, scan):
+    """Granite-4.0-H's Mamba-2 mixer on N tokens (ISSUE 50): h (N, H) the
+    mixer's normed input, ``mp`` its leaves, the store's ``conv`` and ``ssm``
+    entries at ``ordinal`` what its slots remember. [z, u, dt] = h W_in; [x, B,
+    C] = silu(b + conv(u)) (``conv(u, tails, taps, bias) -> (c, new tails)``:
+    ops/ssm.py's decode or ragged form); Delta = softplus(dt + dt_bias); the scan
+    (``scan(ssm store, ordinal, x, Delta, B, C, A, D, the activations' type) ->
+    (y, ssm store)``: ops/ssd.py's one-token update or its blocked form, the
+    store whole so that a kernel updates it in place); the output (w * rmsnorm(y * silu(z))) W_out,
+    the gate BEFORE the norm, over all of Di (one group). Everything between the
+    two projections is float32."""
+    Di, N, Hh = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_heads
+    proj = qmatmul(h, mp["in_proj"])
+    z, u, dt = proj[:, :Di], proj[:, Di:2 * Di + 2 * N], proj[:, 2 * Di + 2 * N:]
+    c, tails = conv(u, _entry(store["conv"], ordinal), mp["conv_w"].astype(jnp.float32),
+                    mp["conv_b"].astype(jnp.float32))
+    x, B, C = c[:, :Di].reshape(-1, Hh, cfg.ssm_head_dim), c[:, Di:Di + N], c[:, Di + N:]
+    delta = jax.nn.softplus(dt.astype(jnp.float32) + mp["dt_bias"])
+    y, ssm = scan(store["ssm"], ordinal, x, delta, B, C, -jnp.exp(mp["A_log"]), mp["D"], h.dtype)
+    with jax.named_scope("ssm.gate"):
+        y = y.reshape(-1, Di) * jax.nn.silu(z.astype(jnp.float32))
+        y = rms_norm(y, mp["ssm_norm"], cfg.rms_norm_eps)
+    return qmatmul(y.astype(h.dtype), mp["out_proj"]), {"conv": _with_entry(store["conv"], ordinal, tails), "ssm": ssm}
+
+
+def _mamba2_decode(rows) -> dict:
+    """The mixer's two state operations for the decode chunk: one token a
+    slot; ``rows`` (S,) the slots that carry a stream (the others keep theirs)."""
+    def scan(ssm, ordinal, x, delta, B, C, A, D, mm):
+        return update_store(ssm, ordinal, delta, x, B, C, A, D, rows)
+
+    return {"conv": lambda u, tails, w, b: conv_decode(u, tails, w, b, rows), "scan": scan}
+
+
+def _mamba2_ragged(cfg: MixtralConfig, q_starts, q_lens, kv_lens, T: int) -> tuple[dict, jnp.ndarray]:
+    """The same for a mixed step's packed rows (row r = slot r): a row of one
+    token takes the batched one-token update at its packed position, a row of
+    more the blocked scan (ops/ssd.py); each from its slot's state, from zero
+    where it starts at position 0. Returns the operations and the step's
+    counts, float32 (2,): the blocks the chunk kernel walks over the state
+    layers and the rows whose state the update reads and writes in each."""
+    seg, fresh = token_segments(q_starts, q_lens, T), (kv_lens - q_lens) == 0
+    slot, _, live = seg
+    one = q_lens == 1
+    in_chunk = live & (q_lens[jnp.minimum(slot, q_lens.shape[0] - 1)] > 1)
+    meta, blocks = block_plan(q_starts, q_lens, kv_lens, T, cfg.ssd_block)
+    first = jnp.minimum(q_starts, T - 1)
+
+    def scan(ssm, ordinal, x, delta, B, C, A, D, mm):
+        y, ssm = ssd_chunk(ssm, ordinal, x, delta, B, C, A, meta, Q=cfg.ssd_block, mm=mm)
+        y = jnp.where(in_chunk[:, None, None], y + D[None, :, None] * x, 0.0)
+        y1, ssm = update_store(ssm, ordinal, delta[first], x[first], B[first], C[first], A, D, one, fresh)
+        return y.at[jnp.where(one, q_starts, T)].set(y1, mode="drop"), ssm
+
+    ops = {"conv": lambda u, tails, w, b: conv_ragged(u, tails, w, b, seg, fresh, q_starts, q_lens), "scan": scan}
+    return ops, jnp.stack([blocks * cfg.conv_layers, jnp.sum(one).astype(jnp.float32)])
 
 
 def _moe_ffn(x: jnp.ndarray, attn_in: jnp.ndarray, lp: Params, li, valid, *,
@@ -556,7 +725,9 @@ def _moe_ffn(x: jnp.ndarray, attn_in: jnp.ndarray, lp: Params, li, valid, *,
     if cfg.shared_experts:
         with jax.named_scope("moe.shared"):
             act = ACTIVATIONS[cfg.hidden_act]
-            out = out + qmatmul(act(qmatmul(h, lp["shared_wg"])) * qmatmul(h, lp["shared_wu"]), lp["shared_wd"])
+            sp = lp if "shared_wg" in lp else {n: jax.tree.map(lambda a: a[stack_index], experts[n])
+                                               for n in SHARED_LEAVES}
+            out = out + qmatmul(act(qmatmul(h, sp["shared_wg"])) * qmatmul(h, sp["shared_wu"]), sp["shared_wd"])
     return out, rows
 
 
@@ -725,14 +896,23 @@ def _seam(params: Params, cfg: MixtralConfig, mesh, valid=None, conv=None) -> di
     leaves it reads whole by layer index (never sliced per layer), for a
     model with leading dense layers their group, for a model with an
     indexer the attention seam, and for a model with convolution layers the
-    per-layer kind (``conv``: the step's form of the convolution, ``_shortconv``'s)."""
+    per-layer kind (``conv``: the step's form of the convolution, ``_shortconv``'s;
+    for Mamba-2 layers the step's forms of both state operations, ``_mamba2``'s)."""
     experts = {n: params["layers"][n] for n in EXPERT_LEAVES}
+    if cfg.mamba2:   # read whole at the layer's index, as the experts are (``_layer_groups``' runs)
+        experts.update({n: params["layers"][n] for n in SHARED_LEAVES})
     seam = {"ffn": partial(_moe_ffn, cfg=cfg, experts=experts, mesh=mesh), "unscanned": EXPERT_LEAVES}
     if cfg.layer_conv is not None:
-        seam["kinds"] = {"state": cfg.layer_conv, "cache": "conv", "mix": partial(_shortconv, conv=conv),
-                         "attention": {n: params["layers"][n] for n in ATTENTION_STACK},
-                         "leaves": {n: params["layers"][n] for n in CONV_LEAVES}}
-        seam["unscanned"] += ATTENTION_STACK + CONV_LEAVES
+        # (A Mamba-2 model's store is too large to stand under a ``lax.cond``: its
+        # layers are scanned in runs of one kind, and the shared MLP's matrices,
+        # which a run's scan would slice, are read at the layer's index instead.)
+        names, operators = state_leaves(cfg), attention_stack(cfg)
+        mix = partial(_mamba2, cfg=cfg, **(conv or {})) if cfg.mamba2 else partial(_shortconv, conv=conv)
+        seam["kinds"] = {"state": tuple(int(f > 0) for f in cfg.layer_conv),
+                         "cache": ("conv", "ssm") if cfg.mamba2 else ("conv",), "mix": mix, "runs": cfg.mamba2,
+                         "attention": {n: params["layers"][n] for n in operators},
+                         "leaves": {n: params["layers"][n] for n in names}}
+        seam["unscanned"] += operators + names + (SHARED_LEAVES if cfg.mamba2 else ())
     if cfg.num_dense_layers:
         # The leading dense layers: a scanned group of their own in front.
         lead = prefix_leaves(params["layers"])
@@ -846,14 +1026,18 @@ def forward_paged(
     conv = None
     if cfg.layer_conv is not None:
         rows = jnp.ones(lengths.shape, bool) if valid is None else valid   # a row that carries no stream keeps its tail
-        conv = lambda z, tails, w: conv_decode(z, tails, w, None, rows, act=None)
+        conv = _mamba2_decode(rows) if cfg.mamba2 else (
+            lambda z, tails, w: conv_decode(z, tails, w, None, rows, act=None))
     out = forward_paged_impl(params, cfg, tokens, positions, lengths, cache,
                              write_idx, page_table, mode, last_only, mesh,
                              valid=valid, with_aux=with_aux, **_seam(params, cfg, mesh, valid, conv))
     if not (with_aux and counts_window_pages(cfg)):
         return out
     live = lengths if valid is None else jnp.where(valid, lengths, 0)
-    return (*out[:2], (out[2], counted_window_pages(cfg, _page_size(cfg, cache), live, live)))
+    counts = counted_window_pages(cfg, _page_size(cfg, cache), live, live)
+    if cfg.mamba2:   # no block is walked; every live row's state is read and written, in every state layer
+        counts = jnp.concatenate([counts, jnp.stack([0.0, jnp.sum(live > 0).astype(jnp.float32)])])
+    return (*out[:2], (out[2], counts))
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "with_aux"))
@@ -874,7 +1058,9 @@ def forward_ragged(
     """The mixed step for a mixture (llama.forward_ragged's contract; the
     third result as ``forward_paged``'s)."""
     conv = None
-    if cfg.layer_conv is not None:
+    if cfg.mamba2:
+        conv, state_counts = _mamba2_ragged(cfg, q_starts, q_lens, kv_lens, tokens.shape[1])
+    elif cfg.layer_conv is not None:
         # Each segment from its slot's tail; one that starts at position 0
         # from zero, whatever the slot held (ops/ssm.py).
         seg, fresh = token_segments(q_starts, q_lens, tokens.shape[1]), (kv_lens - q_lens) == 0
@@ -885,8 +1071,10 @@ def forward_ragged(
     if not (with_aux and counts_window_pages(cfg)):
         return out
     live = jnp.where(q_lens > 0, kv_lens, 0)
-    return (*out[:2], (out[2], counted_window_pages(cfg, _page_size(cfg, cache), live - q_lens + 1, live,
-                                                    tokens.shape[1])))
+    counts = counted_window_pages(cfg, _page_size(cfg, cache), live - q_lens + 1, live, tokens.shape[1])
+    if cfg.mamba2:   # blocks the chunk kernel walked, over the state layers; rows the one-token update served
+        counts = jnp.concatenate([counts, state_counts])
+    return (*out[:2], (out[2], counts))
 
 
 def param_specs(cfg: MixtralConfig) -> dict:
@@ -981,6 +1169,19 @@ def _xing(num_layers: int, **sizes) -> MixtralConfig:
                          shared_experts=1, hc_mult=4, rms_norm_eps=1e-6, rope_theta=10000.0, **sizes)
 
 
+def _granite(layer_types: str, **sizes) -> MixtralConfig:
+    """Granite-4.0-H's block (ISSUE 50, ``granitemoehybrid``): a Mamba-2 mixer
+    ("m") or grouped-query attention with NO position encoding ("a") a layer in
+    the published order; the embedding times embedding_multiplier, both
+    sublayers' outputs times residual_multiplier, attention scores scaled by
+    attention_multiplier, the tied head's logits over logits_scaling; experts
+    routed by a softmax over the chosen logits beside a shared gated MLP of
+    2 x the experts' width in EVERY layer (no dense prefix)."""
+    n = len(layer_types)
+    return MixtralConfig(num_layers=n, layer_conv=tuple(2 * (k == "m") for k in layer_types), layer_rope=(0,) * n,
+                         conv_kernel=4, shared_experts=2, tie_word_embeddings=True, rms_norm_eps=1e-5, **sizes)
+
+
 PRESETS: dict[str, MixtralConfig] = {
     "mixtral-test-tiny": MixtralConfig(
         vocab_size=256, hidden_size=64, num_layers=2, num_heads=4, num_kv_heads=2,
@@ -1065,4 +1266,25 @@ PRESETS: dict[str, MixtralConfig] = {
         dense_intermediate_size=9216, num_experts=64, experts_per_token=4, max_position_embeddings=262144,
         rope_scaling={"type": "yarn", "factor": 64, "original_max_position_embeddings": 4096, "beta_fast": 32,
                       "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1}),
+    # The stage's own shape at toy widths: runs of Mamba-2 layers around one
+    # attention layer, 4 heads of 8 with a state of 16, a block of the scan (8)
+    # shorter than the tests' sequences, 8 experts top-3 beside the shared MLP.
+    "granite4-test-tiny": _granite(
+        "mmamm", vocab_size=256, hidden_size=64, num_heads=4, num_kv_heads=2, head_dim=16, intermediate_size=32,
+        num_experts=8, experts_per_token=3, max_position_embeddings=512, ssm_heads=4, ssm_head_dim=32, ssm_state=16,
+        ssd_block=8, embedding_multiplier=12.0, attention_multiplier=1 / 16, residual_multiplier=0.22,
+        logits_scaling=16.0),
+    # ibm-granite/granite-4.0-h-small is 40 layers (36 Mamba-2, attention at 5,
+    # 15, 25, 35), 31.8 GB of int8: no chip holds it. This is the first of four
+    # pipeline stages of 10 (one period: m m m m m a m m m m), every width, all 72
+    # experts and the whole vocabulary as published: 8.77 GB, which one v5e serves
+    # beside a page pool of ONE layer and a state store of 38.7 MB a slot. The
+    # scan's blocks are 128 tokens, the side of the v5e's matrix unit (the
+    # published mamba_chunk_size 256 is the training kernel's; any block gives
+    # the recurrence's numbers).
+    "granite-4.0-h-small-stage10": _granite(
+        "mmmmmammmm", vocab_size=100352, hidden_size=4096, num_heads=32, num_kv_heads=8, head_dim=128,
+        intermediate_size=768, num_experts=72, experts_per_token=10, max_position_embeddings=131072,
+        ssm_heads=128, ssm_head_dim=64, ssm_state=128, ssd_block=128, embedding_multiplier=12.0,
+        attention_multiplier=0.0078125, residual_multiplier=0.22, logits_scaling=16.0),
 }

@@ -155,6 +155,9 @@ QUANTIZABLE = ("wq", "wk", "wv", "wo", "wg", "wu", "wd",
                # LFM2's gated short convolution (ISSUE 43): its input and output
                # projections; the taps stay full precision.
                "conv_in", "conv_out",
+               # Granite-4.0-H's Mamba-2 mixer (ISSUE 50): its two projections; the
+               # taps, their bias, the norm and the heads' A_log, D and dt_bias stay as they are.
+               "in_proj", "out_proj",
                # Xing4.0's latent attention (ISSUE 47): the two bottlenecks' down and
                # up projections, in both stacks; the hyper-connections' maps stay float32.
                "wdq", "wuq", "wdkv", "wukv", "dense_wdq", "dense_wuq", "dense_wdkv", "dense_wukv",
@@ -254,6 +257,8 @@ def seeded_std(cfg, leaf: str) -> float:
                   "wv": cfg.hidden_size, "wo": cfg.num_heads * cfg.hd}.get(leaf)
         if fan_in:
             return fan_in ** -0.5
+    if getattr(cfg, "mamba2", False):
+        return _granite_std(cfg, leaf)
     if getattr(cfg, "layer_conv", None) is not None:
         return _lfm2_std(cfg, leaf)
     if getattr(cfg, "kv_lora_rank", 0):
@@ -438,6 +443,63 @@ def _xing_std(cfg, leaf: str) -> float:
             "shared_wg": fan, "shared_wu": fan, "shared_wd": 2.0 * (cfg.shared_experts * cfg.intermediate_size) ** -0.5,
             "dense_wg": fan, "dense_wu": fan, "dense_wd": 4.4 * cfg.dense_intermediate_size ** -0.5,
             "hc_phi": (max(cfg.hc_mult, 1) * cfg.hidden_size) ** -0.5, "hc_bias": 1.0}[leaf]
+
+
+def _granite_std(cfg, leaf: str) -> float:
+    """``seeded_std`` for Granite-4.0-H's block (ISSUE 50): Mamba-2 mixers
+    beside one NoPE attention layer on an UN-normed stream whose sublayers join
+    it times residual_multiplier r, a tied head over logits_scaling, a softmax
+    router beside a shared MLP. ``_lfm2_std``'s regime: each scale per fan-in
+    and per multiplier, so the test preset and the published 4,096 are one
+    regime; what matters is each sublayer's SIZE against the stream it joins,
+    and that each mechanism shows in a logit.
+
+    - the embedding, which is also the head, at 1.5 x logits_scaling x hidden **
+      -0.5: logits that spread by 1.5. The stream starts at s0 =
+      embedding_multiplier x that (4.5 at the published sizes).
+    - every sublayer adds c = s0 x 0.6 x sqrt(hidden / (2 x layers)): its last
+      matrix is sized by ``out`` = c / r over what it reads. The TIED head votes
+      for the token it was given with 1.5 x sqrt(hidden) x (the embedding's
+      share of the last stream's RMS): coherent, so it grows with the width
+      where everything else is random. At this c the share is 1.67 / sqrt(hidden)
+      (a fortieth at 4,096) and the vote 2.5, a token among the likely ones and
+      not the only one (at c = 0.3 x s0 the vote was 8 at the test preset and a
+      greedy row repeated one token at -0.3 nats whatever the layers did: every
+      control read under the limits). Not smaller: r and embedding_multiplier
+      show ONLY in that share (every sublayer reads a normed stream), so r
+      dropped (1 for 0.22) takes the vote from 2.5 to 0.6.
+    - the mixer: W_in at hidden ** -0.5 (z, u and dt O(1)); the taps normal at
+      K ** -0.5 (three quarters of the convolution is its carried tail), B's and
+      C's times ``conv_bc_gain`` 2, so that what the STATE holds (C_t . B_s over
+      128 columns, mostly of one sign behind the SiLU) outweighs the D x skip
+      as in a trained model: a state lost between two steps moves the next
+      tokens' outputs by their own size. W_out reads a normed vector: out x Di
+      ** -0.5.
+    - attention: q and k at sqrt(2 / (attention_multiplier x sqrt(head_dim))) x
+      hidden ** -0.5, scores that spread by 2 (``seeded_qk_gain``'s regime: a
+      head rests on a few dozen keys); values at hidden ** -0.5; the output
+      projection at 3 x out x (heads x head_dim) ** -0.5 (a head's mean over a
+      few dozen values is a third of one).
+    - the router at hidden ** -0.5 (logits spread by 1: the ten chosen weigh
+      .25 .. .04); experts' and the shared MLP's gate and up at hidden ** -0.5
+      (a product of RMS 0.6). The shared MLP's down projection so that it adds
+      c; the experts' so that the routed sum (weights' squares sum to ~0.13 at
+      top-10, 0.38 at the test preset's top-3) adds 0.15 x c: an expert swapped
+      by bfloat16's rounding of a logit at the cut then moves the stream by a
+      hundredth (at 0.5 x c the test preset's third expert of eight moved it by a
+      twentieth, and served logprobs read up to 1.3 nats from the reference)."""
+    fan = cfg.hidden_size ** -0.5
+    embed = 1.5 * cfg.logits_scaling * fan
+    out = (0.6 * (cfg.hidden_size / (2 * cfg.num_layers)) ** 0.5 * cfg.embedding_multiplier * embed
+           / cfg.residual_multiplier)
+    multiplier = cfg.attention_multiplier or cfg.hd ** -0.5
+    qk = (2.0 / (multiplier * cfg.hd ** 0.5)) ** 0.5 * fan
+    return {"embed": embed, "in_proj": fan, "conv_w": cfg.conv_kernel ** -0.5, "conv_b": 0.1, "conv_bc_gain": 2.0,
+            "out_proj": out * cfg.ssm_inner ** -0.5,
+            "wq": qk, "wk": qk, "wv": fan, "wo": 3.0 * out * (cfg.num_heads * cfg.hd) ** -0.5,
+            "router": fan, "wg": fan, "wu": fan, "wd": 0.15 * out / 0.22 * cfg.intermediate_size ** -0.5,
+            "shared_wg": fan, "shared_wu": fan,
+            "shared_wd": out / 0.6 * (cfg.shared_experts * cfg.intermediate_size) ** -0.5}[leaf]
 
 
 def seeded_qk_gain(cfg) -> float:

@@ -16,6 +16,7 @@ more than one device is visible.
 
 from __future__ import annotations
 
+import os
 import threading
 import time
 from dataclasses import dataclass
@@ -48,7 +49,7 @@ from inference_gateway_tpu.parallel.sharding import (
 )
 from inference_gateway_tpu.serving.packed import PackedLayout
 from inference_gateway_tpu.serving.tokenizer import load_tokenizer
-from inference_gateway_tpu.utils.platform import device_info
+from inference_gateway_tpu.utils.platform import device_info, on_tpu
 
 
 @dataclass
@@ -325,6 +326,13 @@ DSA_STATS = ("dsa_context_tokens", "dsa_kv_tokens_read")
 # walked (``mixtral.latent_pages``): WINDOW_STATS' rows of the fetch block under
 # its own first name, and one more.
 LATENT_STATS = ("latent_pages_read", "context_pages", "latent_row_pages_read")
+# A model with Mamba-2 layers (models/mixtral.py, ISSUE 50) counts, in both step
+# programs, the blocks its chunk kernel walked over all its state layers
+# (ops/ssd.block_plan: a row of more than one token visits every packed block
+# it touches; 0 in a decode chunk) and the rows whose matrix state the
+# one-token update read and wrote in each of them (a decode chunk's: summed
+# over its steps): two rows of the fetch block behind WINDOW_STATS.
+SSD_STATS = ("ssd_blocks", "state_rows")
 
 # Presets of models/phi4flash.py, by name: the module is imported when one
 # of them is asked for, and not on the way to any other model.
@@ -994,7 +1002,8 @@ class Engine:
         # together (ISSUE 41); one with an indexer DSA_STATS behind its own.
         self.counts_window = self.is_moe and mixtral.counts_window_pages(self.model_cfg)
         more_stats = ((DSA_STATS if self.is_dsa else ())
-                      + ((LATENT_STATS if self.is_mla else WINDOW_STATS) if self.counts_window else ()))
+                      + ((LATENT_STATS if self.is_mla else WINDOW_STATS) if self.counts_window else ())
+                      + (SSD_STATS if getattr(self.model_cfg, "mamba2", False) else ()))
         if self.is_hybrid:
             self._chunk_stats = self._mixed_stats = WINDOW_STATS
         else:
@@ -1014,17 +1023,23 @@ class Engine:
     # ------------------------------------------------------------------
     @staticmethod
     def _load_hf(path: str):
-        """Load a local HF Llama/Mixtral checkpoint (no network)."""
+        """Load a local HF Llama/Mixtral checkpoint (no network). A name that is
+        neither a preset nor a directory is refused HERE: handed on,
+        ``from_pretrained`` takes it for a hub id and asks the network for it
+        (my chip runs, PR 50: the parent commit on a preset it did not know
+        spent 40 s retrying a name resolution before it failed)."""
+        if not os.path.isdir(path):
+            raise ValueError(f"{path!r} is neither a model preset nor a local checkpoint directory")
         import torch  # CPU-only wheel is in the image
         from transformers import AutoConfig, AutoModelForCausalLM
 
         from inference_gateway_tpu.models import hf_loader
 
-        hf_cfg = AutoConfig.from_pretrained(path)
+        hf_cfg = AutoConfig.from_pretrained(path, local_files_only=True)
         is_moe = getattr(hf_cfg, "model_type", "") == "mixtral"
         cfg = (hf_loader.mixtral_config_from_hf if is_moe else hf_loader.llama_config_from_hf)(hf_cfg)
         with torch.no_grad():
-            model = AutoModelForCausalLM.from_pretrained(path, torch_dtype=torch.float32)
+            model = AutoModelForCausalLM.from_pretrained(path, torch_dtype=torch.float32, local_files_only=True)
         convert = hf_loader.mixtral_params_from_hf if is_moe else hf_loader.llama_params_from_hf
         params = convert(model.state_dict(), cfg, dtype=jnp.bfloat16)
         del model
@@ -1647,15 +1662,21 @@ class Engine:
         cfg = self.model_cfg
         if self.is_hybrid:
             state_layers, kinds = cfg.state_layers, {"window": cfg.mid, "full": 1, "shared_readers": cfg.cross_layers}
-        else:  # a mixture with convolution layers (ISSUE 43)
-            state_layers, kinds = cfg.conv_layers, {"full": cfg.cache_layers, "conv": cfg.conv_layers}
-        return {
+        else:  # a mixture with convolution layers (ISSUE 43) or Mamba-2 layers (ISSUE 50)
+            state_layers = cfg.conv_layers
+            kinds = {"full": cfg.cache_layers, "mamba2" if cfg.mamba2 else "conv": state_layers}
+        out = {
             "state_store": {"slots": self.config.max_slots, "layers": state_layers,
                             "bytes": self.config.max_slots * cfg.state_bytes_per_slot},
             "cache_layers": kinds,
             "state_resets": self.metrics["state_resets"],
             "prefix_hits_declined": self.prefix_cache.declined if self.prefix_cache is not None else 0,
         }
+        if getattr(cfg, "mamba2", False):   # how the Mamba-2 scan is done (ISSUE 50; static)
+            out["ssd"] = {"heads": cfg.ssm_heads, "state": [cfg.ssm_head_dim, cfg.ssm_state], "block": cfg.ssd_block,
+                          "rows_of_more_than_one_token": "ssd_chunk_kernel" if on_tpu() else "ssd_chunk_twin",
+                          "one_token_rows": "ssm_state_update_kernel" if on_tpu() else "state_update"}
+        return out
 
     def _split_aux(self, aux):
         """A mixture's auxiliary -> (the rows each expert multiplied (expert

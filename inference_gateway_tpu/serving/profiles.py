@@ -91,7 +91,8 @@ def mixtral_param_count(cfg: mixtral.MixtralConfig) -> int:
     model has them), the expert layers' mixture (+ a shared expert), and a
     leading dense stack's MLPs (ISSUE 41: the two stacks are counted apart).
     With convolution layers (ISSUE 43) the token mixers are counted by kind:
-    the caching layers' attention, the other layers' gated short convolution.
+    the caching layers' attention, the other layers' gated short convolution
+    or Mamba-2 mixer (ISSUE 50).
     The count of the built tree's leaves (a tied head once)."""
     H, I, V, E = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size, cfg.num_experts
     Hkv_D = cfg.num_kv_heads * cfg.hd
@@ -111,6 +112,9 @@ def mixtral_param_count(cfg: mixtral.MixtralConfig) -> int:
     if cfg.qk_norm:
         attention += 2 * cfg.hd
     conv = 3 * H * H + cfg.conv_kernel * H + H * H  # W_in, the taps, W_out
+    if cfg.mamba2:  # a Mamba-2 mixer (ISSUE 50): W_in, the taps and their bias, dt_bias / A_log / D, the norm, W_out
+        Di, C = cfg.ssm_inner, cfg.conv_channels
+        conv = H * (Di + C + cfg.ssm_heads) + (cfg.conv_kernel + 1) * C + 3 * cfg.ssm_heads + Di + Di * H
     mixers = cfg.cache_layers * attention + cfg.conv_layers * conv + cfg.num_layers * 2 * H  # + both sublayers' input norms
     per_layer = (
         E * 3 * H * I  # experts

@@ -957,6 +957,9 @@ class SidecarServer:
             # A model with a state store (ISSUE 32): ``state_store`` (slots,
             # layers, bytes), ``cache_layers`` (window, full, shared_readers),
             # ``state_resets`` and ``prefix_hits_declined``; absent otherwise.
+            # With Mamba-2 layers (ISSUE 50) also ``ssd`` (heads, a head's state,
+            # the scan's block, what serves a row of one token and of more), and
+            # the `mixed` and `decode` records carry `ssd_blocks` and `state_rows`.
             **((state_status() if state_status else None) or {}),
             # A model with a sparse-attention indexer (ISSUE 36): its sizes and
             # how selection and read are done; absent otherwise. Its `decode`

@@ -10,8 +10,9 @@ case of the first. PR 32's file checks (``bench/tests/test_phi4flash_files.py``)
 are collected the same way, and PR 36's (``bench/tests/test_keye_files.py``),
 PR 41's (``bench/tests/test_trinity_files.py``), PR 43's
 (``bench/tests/test_lfm2_files.py``), PR 47's
-(``bench/tests/test_xing4_files.py``) and PR 49's reader check
-(``bench/tests/test_post_warmup_compile_reader.py``).
+(``bench/tests/test_xing4_files.py``), PR 49's reader check
+(``bench/tests/test_post_warmup_compile_reader.py``) and PR 50's
+(``bench/tests/test_granite4_files.py``).
 """
 
 import importlib.util
@@ -73,16 +74,23 @@ _spec.loader.exec_module(post_warmup)
 globals().update({name if name not in globals() else f"{name}_post_warmup": fn
                   for name, fn in vars(post_warmup).items() if name.startswith("test_")})
 
+_spec = importlib.util.spec_from_file_location("bench_test_granite4_files", BENCH / "tests" / "test_granite4_files.py")
+granite4_files = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(granite4_files)
+globals().update({name if name not in globals() else f"{name}_granite4": fn
+                  for name, fn in vars(granite4_files).items() if name.startswith("test_")})
+
 
 # What later PRs appended after PR 32's entries, by list and in order: PR 36 a
 # configuration, its cell and two readers, PR 38 the idle ledger's five readers,
 # PR 41 a configuration, its cell and two readers, PR 43 the same, PR 47 a
-# configuration, its cell and four readers, PR 49 one reader. All the cut below
-# may drop.
+# configuration, its cell and four readers, PR 49 one reader, PR 50 a
+# configuration, its cell and four readers. All the cut below may drop.
 APPENDED_SINCE_PR_32 = {"configs": ["keye-vl2-30b-a3b-int8", "trinity-mini-int8", "lfm2-8b-a1b-int8",
-                                    "xing4.0-29b-a4b-int8"],
+                                    "xing4.0-29b-a4b-int8", "granite-4.0-h-small-int8"],
                         "workloads": ["keye-vl2-30b-a3b.long-context", "trinity-mini.long-context",
-                                      "lfm2-8b-a1b.reason", "xing4.0-29b-a4b.long-context"],
+                                      "lfm2-8b-a1b.reason", "xing4.0-29b-a4b.long-context",
+                                      "granite-4.0-h-small.long-context"],
                         "per_layer": ["dsa_kv_read_share", "dsa_busy_share",
                                       "fetch_return_ms", "idle_unspanned_share", "starved_slot_share",
                                       "starved_delivery_share", "starved_unspanned_share",
@@ -90,7 +98,9 @@ APPENDED_SINCE_PR_32 = {"configs": ["keye-vl2-30b-a3b-int8", "trinity-mini-int8"
                                       "lfm2_decode_hbm_roofline_share", "shortconv_busy_share",
                                       "mla_step_hbm_roofline_share", "mla_attn_roofline_share",
                                       "mla_attn_busy_share", "mhc_busy_share",
-                                      "post_warmup_compile_s"]}
+                                      "post_warmup_compile_s",
+                                      "ssd_busy_share", "ssd_chunk_roofline_share",
+                                      "ssd_state_hbm_roofline_share", "granite_mixed_step_mfu"]}
 
 
 def test_the_benchmark_gains_one_configuration_one_cell_and_three_readers(tmp_path, monkeypatch):
@@ -154,6 +164,6 @@ def test_the_new_references_child_says_ok_on_probes_of_a_tiny_engine(name, tmp_p
 
 
 @pytest.mark.parametrize("preset", ["smallthinker-test-tiny", "phi4flash-test-tiny", "lfm2-test-tiny",
-                                    "xing4-test-tiny"])
+                                    "xing4-test-tiny", "granite4-test-tiny"])
 def test_the_new_configurations_child_builds_the_sidecars_tree(preset):
     child.test_the_childs_weights_are_the_serving_engines_bit_for_bit(preset, "int8")

@@ -723,6 +723,28 @@ def test_lfm2_step_programs_compile_for_v5e_at_the_published_widths(topo, monkey
     assert not copies, copies[:3]
 
 
+def _weight_copies(text: str) -> list:
+    """The int8 results of a compiled program that are copies of a weight in
+    HBM. A weight's slice may be a producer INSIDE the matmul's fusion, be
+    fetched ahead into the fast memory (a result in ``S(1)``), or be an entry
+    parameter laid out anew ONCE a call, directly or through its prefetch (a
+    copy-done of a parameter's copy-start); any other is a copy a layer."""
+    import re
+
+    prefetched = set(re.findall(r"(%copy-start\S*) = .* copy-start\(%p__", text))
+    of_parameter = {done for done, start in re.findall(r"(%copy-done\S*) = .* copy-done\((%copy-start[\w.\-]*)\)", text)
+                    if start in prefetched}
+    computation, copies = "", []
+    for line in text.splitlines():
+        opened = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{", line)
+        computation = opened.group(1) if opened else computation
+        made = re.match(r"\s*(?:ROOT )?%\S+ = s8\[[\d,]+\](\{\S*\})? (fusion|copy|dynamic-slice)\((%[\w.\-]+)", line)
+        if (made and not computation.startswith("fused_computation") and "S(1)" not in (made.group(1) or "")
+                and not made.group(3).startswith("%p__") and made.group(3) not in of_parameter):
+            copies.append(line.strip()[:160])
+    return copies
+
+
 @pytest.mark.parametrize("form", ["mixed", "decode"])
 def test_xing4_step_programs_compile_for_v5e_at_the_published_widths(topo, monkeypatch, as_on_tpu, form):
     """Xing4.0-29B-A4B's first stage (ISSUE 47: 10 layers, a latent attention of 32
@@ -779,15 +801,77 @@ def test_xing4_step_programs_compile_for_v5e_at_the_published_widths(topo, monke
     assert not re.findall(rf"= bf16\[{10 * slots * 100 * page},640\]\S* copy\(", text), "the pool is copied"
     # (As LFM2's test reads it; here an entry parameter laid out anew once a call may come through its
     # prefetch: the 576-column W_DKV stack, 16.5 MB a chunk of 8 steps, is a copy-done of a parameter's copy-start.)
-    prefetched = set(re.findall(r"(%copy-start\S*) = .* copy-start\(%p__", text))
-    of_parameter = {done for done, start in re.findall(r"(%copy-done\S*) = .* copy-done\((%copy-start[\w.\-]*)\)", text)
-                    if start in prefetched}
-    computation, copies = "", []
-    for line in text.splitlines():
-        opened = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{", line)
-        computation = opened.group(1) if opened else computation
-        made = re.match(r"\s*(?:ROOT )?%\S+ = s8\[[\d,]+\](\{\S*\})? (fusion|copy|dynamic-slice)\((%[\w.\-]+)", line)
-        if (made and not computation.startswith("fused_computation") and "S(1)" not in (made.group(1) or "")
-                and not made.group(3).startswith("%p__") and made.group(3) not in of_parameter):
-            copies.append(line.strip()[:160])
-    assert not copies, copies[:3]
+    assert not _weight_copies(text), _weight_copies(text)[:3]
+
+
+@pytest.mark.parametrize("form", ["mixed", "decode"])
+def test_granite4_step_programs_compile_for_v5e_at_the_published_widths(topo, monkeypatch, as_on_tpu, form):
+    """granite-4.0-h-small's first stage (ISSUE 50: 10 layers, nine Mamba-2 mixers
+    of 128 heads of 64 with a state of 128 beside one NoPE GQA layer of 32 / 8
+    heads of 128, 72 experts of 768 top-10 and a shared MLP of 1,536 in every
+    layer) as its cell serves it: 32 slots of 100 pages of 128, a mixed step of
+    544 packed tokens and a decode step of 32 rows, int8 weights. A pass says the
+    chip's compiler takes both step programs: the blocked scan's kernel
+    (``ssd_chunk_kernel``: a head's rows a sublane slice, the store read and
+    written by DMA at a dynamic layer and slot) in the mixed step's two runs of
+    Mamba layers and in no decode step; the one-token update's kernel
+    (``ssm_state_update_kernel``: a row turned into a column by a transpose, a
+    slot that does not advance riding its neighbour's block) in both programs,
+    and no fusion that touches the store;
+    the expert kernels at 72 experts of 768; and holds NO copy of the 1.2 GB state
+    store (the runs of one kind are scanned apart, so no ``cond`` stands around
+    it: each layer's update lands in the carried buffer) nor of the pool, the
+    temporaries far under the store, and no int8 result that is not a parameter
+    (a copy of a weight: the shared MLP's matrices are read at the layer's index,
+    not sliced by a run's scan)."""
+    import re
+
+    from inference_gateway_tpu.models import mixtral
+    from inference_gateway_tpu.ops.quant import init_quantized_llama_params
+    from inference_gateway_tpu.serving.kv_cache import PagedCacheConfig
+
+    monkeypatch.setattr("inference_gateway_tpu.ops.paged_attention.device_platform", lambda: "tpu")
+    cfg = mixtral.PRESETS["granite-4.0-h-small-stage10"]
+    slots, page, seq, T = 32, 128, 12800, 544
+    place = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=SingleDeviceSharding(topo.devices[0])), tree)
+    params = place(jax.eval_shape(lambda: init_quantized_llama_params(jax.random.PRNGKey(0), cfg)))
+    cache = place(jax.eval_shape(lambda: mixtral.init_paged_cache(cfg, PagedCacheConfig(page, 0, slots, seq))))
+    assert {n: a.shape for n, a in cache.items()} == {
+        "k": (1, slots * 100, page, 1024), "v": (1, slots * 100, page, 1024),
+        "conv": (9, slots, 3, 8448), "ssm": (9, slots, 128, 64, 128)}
+    store = 9 * slots * 128 * 64 * 128 * 4
+    assert store + 9 * slots * 3 * 8448 * 4 == slots * cfg.state_bytes_per_slot == 1_237_155_840
+    sds = lambda shape: place(jax.ShapeDtypeStruct(shape, I32))
+    row, table = sds((slots,)), sds((slots, seq // page))
+    if form == "mixed":
+        fn = lambda p, c, tok, pos, w, table, qs, ql, kl: mixtral.forward_ragged(
+            p, cfg, tok, pos, c, w, table, qs, ql, kl, with_aux=True)
+        args = (params, cache, sds((1, T)), sds((1, T)), sds((1, T)), table, row, row, row)
+    else:
+        def fn(p, c, tok, pos, table, done):   # as the engine's chunk runs it: the cache in a while_loop's carry
+            def step(carry):
+                i, c, tok, pos = carry
+                w = jnp.take_along_axis(table, (pos // page)[:, None], axis=1)[:, 0] * page + pos % page
+                logits, c, _ = mixtral.forward_paged(p, cfg, tok[:, None], pos[:, None], jnp.where(done, 0, pos + 1),
+                                                     c, w[:, None], table, mode="decode", valid=~done, with_aux=True)
+                return i + 1, c, jnp.argmax(logits, -1).astype(I32), pos + 1
+
+            return jax.lax.while_loop(lambda carry: carry[0] < 8, step, (jnp.int32(0), c, tok, pos))[1:]
+
+        args = (params, cache, row, row, table, place(jax.ShapeDtypeStruct((slots,), jnp.bool_)))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    for name in ("moe_gate_up_gmm", "moe_down_gmm"):
+        assert re.search(rf"%\S*{name}\S* = ", text), name
+    scans = set(re.findall(r"%ssd_chunk_kernel[\w.\-]* = ", text))
+    assert len(scans) == (2 if form == "mixed" else 0), scans          # one a run of Mamba layers; none in a decode step
+    assert len(set(re.findall(r"%ssm_state_update_kernel[\w.\-]* = ", text))) == 2    # the one-token rows, both programs
+    assert not re.findall(r"%[\w.\-]+ = f32\[(?:9,)?32,128,64,128\]\S* fusion\(", text), "a fusion reads or writes the store"
+    assert compiled.memory_analysis().temp_size_in_bytes < store // 4
+    assert not re.findall(r"= f32\[9,32,128,64,128\]\S* copy\(", text), "the state store is copied"
+    assert not re.findall(r"= f32\[32,128,64,128\]\S* copy\(", text), "a layer's states are copied"
+    assert not re.findall(rf"= bf16\[{slots * 100 * page},1024\]\S* copy\(", text), "the pool is copied"
+    # No loop over one state a token: the mixed step holds no f32[64,128] or f32[128,64,128] result in a while body
+    assert not re.findall(r"= f32\[(?:128,)?64,128\]\{", text), "a state a token: a loop over tokens"
+    assert not _weight_copies(text), _weight_copies(text)[:3]
