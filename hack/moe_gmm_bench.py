@@ -8,11 +8,14 @@ one process at a time, so each side is a process of its own):
 
 One layer's rows at each mixture cell's shape (experts, widths, top-k, the
 step's tokens; which experts the rows choose from a seeded draw the script
-prints), int8 stacks of two layers with the second read. Three programs a case:
+prints), int8 stacks of two layers with the second read. Four programs a case:
 `moe_gate_up_gmm` alone, `moe_down_gmm` alone (each chained CALLS times, the
-host clock around `block_until_ready`, the median of REPEATS) and everything
+host clock around `block_until_ready`, the median of REPEATS), everything
 after the router (`_experts_of_shard`: sort, row gather, both kernels,
-combine), so that a row layout's cost outside the kernels is counted. Beside
+combine), so that a row layout's cost outside the kernels is counted, and the
+combine alone (ISSUE 51: the gather of the down kernel's rows at a fixed
+`back`, the select, the weights, the sum; the rows are the chain's carry, so
+nothing is hoisted), with its us per million gathered elements. Beside
 each time: the bytes of the experts with rows (matrices and scales, one
 reading) and the v5e's least time for them (`bench/chips.json`), and the
 largest absolute difference of the layer from the same layer through the
@@ -52,6 +55,10 @@ CASES = {
     "trinity mixed broad": (544, 128, 2048, 1024, 8, "silu", (21, 1.0, 1.0)),
     "trinity decode": (32, 128, 2048, 1024, 8, "silu", (21, 14.0)),
     "keye decode": (12, 128, 2048, 768, 8, "silu", (0, 1.0)),
+    # ISSUE 51: the top-10 and top-4 mixed steps and a top-4 decode step
+    "granite mixed": (544, 72, 4096, 768, 10, "silu", (0, 1.0)),
+    "xing mixed": (544, 64, 3584, 1024, 4, "silu", (0, 1.0)),
+    "lfm2 decode": (64, 32, 2048, 1792, 4, "silu", (0, 1.0)),
 }
 # --rehearse: the control flow at a size the interpreter finishes (a mixed shape past 512 rows, a decode shape)
 TINY = {
@@ -130,6 +137,26 @@ def _kernel_inputs(moe, x, expert, top_k, stacks, act):
             return lambda r, *ws: moe.grouped_matmul(r, ws, layer, sizes, 0, row_expert, act=act_, kernel=True)
     up, down = gmm(act), gmm("silu")
     return (rows, up, (wg, wu)), (jax.jit(up)(rows, wg, wu), down, (wd,))
+
+
+def _combine_inputs(moe, h, down, wd, expert, weights):
+    """(y, the module's combine of it): the down kernel's rows and where each
+    (token, choice) lies. A tree from before ISSUE 51 has no ``combine``: its
+    four lines of ``_experts_of_shard`` stand here."""
+    import jax
+    import jax.numpy as jnp
+
+    N, k = weights.shape
+    lay = moe.dispatch(expert, k, wd.shape[1])
+    y = jax.jit(down)(h, wd)
+    if hasattr(moe, "combine"):
+        return y, lambda y_: moe.combine(y_, lay.back, weights)
+
+    def parents(y_):
+        held = lay.back < lay.plan.rows
+        per_choice = jnp.where(held[:, None], y_[jnp.minimum(lay.back, lay.plan.rows - 1)].astype(jnp.float32), 0.0)
+        return jnp.einsum("nk,nkh->nh", weights, per_choice.reshape(N, k, -1))
+    return y, parents
 
 
 def measure(moe, cases, names, repeats, profile, oracle=None) -> dict:
@@ -213,6 +240,10 @@ def measure(moe, cases, names, repeats, profile, oracle=None) -> dict:
                     got["device_us"], got["device_share_of_bytes_pct"] = own, round(100 * least / own, 1)
             line[label] = got
         line["after_router"] = timed(layer_fn(True), x, stacks, most=18)
+        if hasattr(moe, "dispatch"):
+            y, combine = _combine_inputs(moe, h, down, one[0], expert, weights)
+            line["combine"] = timed(combine, y, (), most=8)
+            line["combine"]["us_per_m_elements"] = round(line["combine"]["us_per_call"] / (T * k * H / 1e6), 2)
         if chip is not None:
             least = sum(nbytes.values()) / chip["hbm_bytes_per_s"] * 1e6
             line["after_router"].update(least_us=round(least, 1), share_of_bytes_pct=round(

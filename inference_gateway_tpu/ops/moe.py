@@ -362,6 +362,33 @@ def dispatch(expert, top_k: int, e: int, e_local: int | None = None, first=0) ->
                   jnp.where(held, lies[:M], plan.rows), sizes, all_sizes)
 
 
+def combine(y, back, weights):
+    """Each token's chosen rows of ``y`` (the layout's rows, (rows, H)) summed
+    under the routing weights (N, k) -> (N, H) float32. ``back`` (N*k,) says
+    where each (token, choice) lies, ``rows`` where this shard holds none of
+    it. The rows are gathered choice-major, (k, N, H) in ``y``'s own dtype: k
+    is a major axis, so the tiles are (N, H) whatever k is (token-major, a
+    float32 (N, k, H) pads each token's k rows to whole sublane tiles: a
+    copy of 16 rows for 10, ``PERF.md`` section 6, PR 51), and N is padded to
+    whole bf16 tiles of 16 rows, so that (k, N, H) is the gather as it lies.
+    The sum is one elementwise chain over the choices in their order, so a
+    token's sum is the same to the bit wherever it lies in the step and
+    whatever else the step holds. A row this shard does not hold is selected
+    away, not weighted away: the clamped row may hold anything."""
+    rows = y.shape[0]
+    N, k = weights.shape
+    pad = ((0, 0), (0, -N % 16))
+    back = jnp.pad(back.reshape(N, k).T, pad, constant_values=rows)  # (k, N')
+    by_choice = jnp.pad(weights.T, pad)
+    picked = y[jnp.minimum(back, rows - 1)]  # (k, N', H)
+    held = back < rows
+    out = None
+    for c in range(k):
+        term = jnp.where(held[c][:, None], picked[c].astype(jnp.float32), 0.0) * by_choice[c][:, None]
+        out = term if out is None else out + term
+    return out[:N]
+
+
 def _experts_of_shard(x, weights, expert, wg, wu, wd, layer, *, act, kernel, ep, tp):
     """Everything after the router, for the experts (and, under ``tp``,
     the columns) this shard holds: lay the rows out, the two grouped
@@ -369,7 +396,6 @@ def _experts_of_shard(x, weights, expert, wg, wu, wd, layer, *, act, kernel, ep,
     (N*k,) are whole on every shard; the stacks are the shard's own
     (L, E/ep, K, N). Returns (this shard's part of the output (N, H)
     float32, rows each of ALL experts multiplied (E,))."""
-    N, H = x.shape
     top_k = weights.shape[1]
     e_local = jax.tree.leaves(wg)[0].shape[1]
     with jax.named_scope("moe.dispatch"):
@@ -382,9 +408,7 @@ def _experts_of_shard(x, weights, expert, wg, wu, wd, layer, *, act, kernel, ep,
         y = grouped_matmul(h, (wd,), layer, lay.sizes, lay.plan, lay.row_expert, kernel=kernel,
                            out_dtype=jnp.float32 if tp else None)
     with jax.named_scope("moe.combine"):
-        held = lay.back < lay.plan.rows
-        per_choice = jnp.where(held[:, None], y[jnp.minimum(lay.back, lay.plan.rows - 1)].astype(jnp.float32), 0.0)
-        out = jnp.einsum("nk,nkh->nh", weights, per_choice.reshape(N, top_k, H))
+        out = combine(y, lay.back, weights)
         axes = tuple(a for a in (ep, tp) if a)
         if axes:
             out = jax.lax.psum(out, axes)

@@ -344,6 +344,48 @@ def test_expert_kernels_stay_inside_their_start_up_budget(as_on_tpu, case):
         assert _equations(body) <= EXPERT_KERNEL_EQUATIONS
 
 
+def _values(jaxpr):
+    """Every value a jaxpr makes, loops, branches and jitted calls included; never a kernel's own."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        if eqn.primitive.name != "pallas_call":
+            for inner in _inner_jaxprs(eqn):
+                yield from _values(inner)
+
+
+# ISSUE 51. (tokens, experts, hidden, expert width, top-k) of the two mixed steps whose top-k is not 8.
+COMBINE_SHAPES = {"granite_top_10": (544, 72, 4096, 768, 10), "xing_top_4": (544, 64, 3584, 1024, 4)}
+
+
+@pytest.mark.parametrize("case", list(COMBINE_SHAPES))
+def test_the_combine_holds_no_float32_copy_of_the_chosen_rows_and_never_k_on_a_tiled_axis(as_on_tpu, case):
+    """No clock: the values ``_experts_of_shard`` makes. The parent's combine
+    wrote the gathered rows as float32 ``(N*k, H)`` and reshaped them to
+    ``(N, k, H)``, where top-10 pads each token's block to 16 sublanes and
+    top-4 to 8: 4-5 times the chip's time per element of top-8 (``PERF.md``
+    section 6, PR 51). The largest float32 value is now the ``(N, H)`` sum,
+    and a value as large as the gathered rows has k on no minor axis."""
+    from inference_gateway_tpu.ops.moe import _experts_of_shard
+    from inference_gateway_tpu.ops.quant import QTensor
+
+    N, E, H, I, k = COMBINE_SHAPES[case]
+    sds = jax.ShapeDtypeStruct
+
+    def stack(kk, n):
+        return QTensor(sds((2, E, kk, n), jnp.int8), sds((2, E, 1, n), jnp.float32))
+
+    jaxpr = jax.make_jaxpr(lambda x, w, e, wg, wu, wd, li: _experts_of_shard(
+        x, w, e, wg, wu, wd, li, act="silu", kernel=None, ep=None, tp=None))(
+        sds((N, H), BF16), sds((N, k), jnp.float32), sds((N * k,), I32), stack(H, I), stack(H, I), stack(I, H),
+        sds((), I32)).jaxpr
+    assert all(len(_kernel_bodies(jaxpr, name)) == 1 for name in ("moe_gate_up_gmm", "moe_down_gmm"))
+    large = [v for v in _values(jaxpr) if v.size >= N * k * H]
+    assert any(v.shape == (k, N, H) and v.dtype == BF16 for v in large)  # the one gather, choice-major
+    assert not [v for v in large if v.dtype == jnp.float32]
+    assert not [v for v in large if k in v.shape[-2:]]
+    assert max(v.size for v in _values(jaxpr) if v.dtype == jnp.float32) <= N * H
+
+
 @pytest.mark.parametrize("sizes,tokens,ep,tp", [
     (SMALLTHINKER, 48, 4, 1), (SMALLTHINKER, 560, 4, 1), (SMALLTHINKER, 560, 2, 2), (SMALLTHINKER, 48, 1, 4),
     (MIXTRAL, MIXED_BUDGET, 4, 1), (MIXTRAL, MIXED_BUDGET, 1, 4),
@@ -497,15 +539,22 @@ def test_state_operations_compile_for_v5e_at_the_published_widths(one_chip, form
 # sort and the two token gathers went: 921 and 545 equations, from 1,015 and 600
 # (ceilings, as before: the row's counts move with the sparse branch's every edit).
 # The eight other rows are unedited.
+# ISSUE 51: the expert layer's combine sums a token's choices one after another
+# (slice, convert, select, weight, add: the order is the program's, not the
+# compiler's) over tokens padded to whole tiles, where one einsum stood: 32
+# equations more an expert layer body at the presets' top-2 (48 at
+# SmallThinker's top-3: 16 a choice) in both step programs of the five
+# mixtures, all elementwise; the indexer's ceilings move by the same 32 (953 /
+# 577 read). The three rows without an expert layer are unedited.
 STEP_PROGRAMS = {
     ("llama", "test-tiny"): ((2, 0, 196), (1, 0, 145)),
     ("llama", "qwen2-test-tiny"): ((2, 0, 203), (1, 0, 152)),
-    ("mixtral", "mixtral-test-tiny"): ((2, 0, 359), (1, 0, 302)),
-    ("mixtral", "smallthinker-test-tiny"): ((2, 0, 378), (1, 0, 321)),
+    ("mixtral", "mixtral-test-tiny"): ((2, 0, 391), (1, 0, 334)),
+    ("mixtral", "smallthinker-test-tiny"): ((2, 0, 426), (1, 0, 369)),
     ("phi4flash", "phi4flash-test-tiny"): ((3, 0, 1075), (2, 0, 779)),
-    ("mixtral", "keye-test-tiny"): ((1, 3, 930), (1, 1, 550)),
-    ("mixtral", "trinity-test-tiny"): ((4, 0, 669), (2, 0, 562)),
-    ("mixtral", "lfm2-test-tiny"): ((2, 2, 835), (1, 2, 547)),
+    ("mixtral", "keye-test-tiny"): ((1, 3, 960), (1, 1, 580)),
+    ("mixtral", "trinity-test-tiny"): ((4, 0, 701), (2, 0, 594)),
+    ("mixtral", "lfm2-test-tiny"): ((2, 2, 867), (1, 2, 579)),
 }
 
 

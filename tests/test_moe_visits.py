@@ -159,6 +159,19 @@ def test_the_interpreted_kernel_multiplies_every_held_row_by_its_own_expert(case
     np.testing.assert_allclose(got[True][back[held]], got[False][back[held]], atol=2e-5, rtol=1e-5)
 
 
+def _all_experts(ws, layer=1):
+    """Every expert of ``layer`` of the stacks on every token: ``moe_dense``'s ``expert_fn``."""
+    from inference_gateway_tpu.ops.quant import qeinsum
+
+    lw = [jax.tree.map(lambda a: a[layer], w) for w in ws]
+
+    def expert_fn(inp):
+        h = jax.nn.silu(qeinsum("enh,ehi->eni", inp, lw[0])) * qeinsum("enh,ehi->eni", inp, lw[1])
+        return qeinsum("eni,eih->enh", h, lw[2])
+
+    return expert_fn
+
+
 @pytest.mark.parametrize("kernel", [False, True], ids=["ragged_dot", "pallas_interpret"])
 @pytest.mark.parametrize("quantized", [False, True], ids=["float32", "int8"])
 @pytest.mark.parametrize("tokens,experts,k", [(288, 128, 8), (136, 8, 4)], ids=["128_experts", "8_experts"])
@@ -166,8 +179,6 @@ def test_dropless_layer_at_a_mixed_steps_rows_an_expert_is_the_all_experts_oracl
     """The whole layer where a visit is 128 rows: a router that sends every
     token to expert 2 first (ten and more times the mean, several visits) and
     never to a fifth of the experts; rows without a token choose none."""
-    from inference_gateway_tpu.ops.quant import qeinsum
-
     rng = np.random.default_rng(experts)
     L, H, I = 2, 64, 128
     ws = _stacks(rng, L, experts, H, I, quantized)
@@ -177,15 +188,96 @@ def test_dropless_layer_at_a_mixed_steps_rows_an_expert_is_the_all_experts_oracl
     logits[:, rng.permutation(np.arange(3, experts))[:experts // 5]] -= 100.0
     logits = jnp.asarray(logits)
 
-    def expert_fn(inp):
-        lw = [jax.tree.map(lambda a: a[1], w) for w in ws]
-        h = jax.nn.silu(qeinsum("enh,ehi->eni", inp, lw[0])) * qeinsum("enh,ehi->eni", inp, lw[1])
-        return qeinsum("eni,eih->enh", h, lw[2])
-
     valid = jnp.arange(tokens) < tokens - 5
-    want = moe.moe_dense(x, logits, k, expert_fn)
+    want = moe.moe_dense(x, logits, k, _all_experts(ws))
     got, rows = moe.moe_dropless(x, logits, k, *ws, 1, valid=valid, kernel=kernel)
     np.testing.assert_allclose(np.asarray(got[:-5]), np.asarray(want[:-5]), atol=3e-5, rtol=1e-4)
     assert not np.asarray(got[-5:]).any()
     assert int(rows.sum()) == (tokens - 5) * k and int(rows[2]) == tokens - 5 and int((rows == 0).sum()) >= experts // 5
     assert tokens * k > 16 * experts and int(moe.visit_count(rows, tokens * k)) >= int((rows > 0).sum()) + 1
+
+
+def _layer_case(seed, tokens, experts, k, quantized=False, H=64, I=128):
+    """(x, router logits, stacks of two layers, the all-experts function of layer 1)."""
+    rng = np.random.default_rng(seed)
+    ws = _stacks(rng, 2, experts, H, I, quantized)
+    x = jnp.asarray(rng.normal(size=(tokens, H)).astype(np.float32))
+    logits = jnp.asarray(rng.normal(size=(tokens, experts)).astype(np.float32))
+    return x, logits, ws, _all_experts(ws)
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["ragged_dot", "pallas_interpret"])
+@pytest.mark.parametrize("tokens", [6, 48, 136])
+@pytest.mark.parametrize("k", [3, 4, 6, 8, 10])
+def test_the_combine_sums_every_choice_at_any_top_k(k, tokens, kernel):
+    """ISSUE 51: the combine gathers choice-major, so k lies on no tiled axis:
+    the layer is the all-experts oracle at top-3 to top-10, at a decode step's
+    rows and past a mixed step's 128, with rows that carry no token among the
+    others (every third) and at the end."""
+    x, logits, ws, expert_fn = _layer_case(100 * k + tokens, tokens, 16, k)
+    valid = (jnp.arange(tokens) % 3 != 1) & (jnp.arange(tokens) < tokens - 2)
+    want = moe.moe_dense(x, logits, k, expert_fn)
+    got, rows = moe.moe_dropless(x, logits, k, *ws, 1, valid=valid, kernel=kernel)
+    live = np.asarray(valid)
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live], atol=3e-5, rtol=1e-4)
+    assert not np.asarray(got)[~live].any() and int(rows.sum()) == int(live.sum()) * k
+
+
+@pytest.mark.parametrize("k", [4, 8, 10])
+def test_a_tokens_sum_is_the_same_to_the_bit_wherever_it_lies_and_whatever_else_the_step_holds(k):
+    """The benchmark sends each probe twice and the second, which adopts the
+    first's pages, lies elsewhere in a step that holds other rows: the sum over
+    a token's choices is an elementwise chain in the choices' order, so the
+    same rows under the same weights give the same bits at another packed
+    position, in a shorter step, and with half of the other tokens dropped. A
+    clamped row of no choice may hold anything (here NaN): it is selected away."""
+    N, H, rows = 48, 128, 640
+    rng = np.random.default_rng(k)
+    y = jnp.asarray(rng.normal(size=(rows, H)).astype(np.float32)).astype(jnp.bfloat16).at[rows - 1].set(jnp.nan)
+    back = rng.permutation(rows - 1)[:N * k].astype(np.int32).reshape(N, k)
+    weights = jax.nn.softmax(jnp.asarray(rng.normal(size=(N, k)).astype(np.float32)), axis=-1)
+    base = np.asarray(moe.combine(y, jnp.asarray(back.reshape(-1)), weights))
+    assert base.dtype == np.float32 and np.isfinite(base).all()
+
+    def bits(a):
+        return np.ascontiguousarray(a).view(np.uint32)
+
+    perm = rng.permutation(N)
+    moved = np.asarray(moe.combine(y, jnp.asarray(back[perm].reshape(-1)), weights[perm]))
+    assert (bits(moved) == bits(base[perm])).all()
+    short = np.asarray(moe.combine(y, jnp.asarray(back[5:18].reshape(-1)), weights[5:18]))
+    assert (bits(short) == bits(base[5:18])).all()
+    kept = np.arange(N) % 2 == 0
+    halved = np.where(kept[:, None], back, rows)  # ``rows``: this shard holds no row of the pair
+    half = np.asarray(moe.combine(y, jnp.asarray(halved.reshape(-1)), weights))
+    assert (bits(half[kept]) == bits(base[kept])).all() and not half[~kept].any()
+    # the same through the whole layer: the kernels multiply a row by itself alone
+    x, logits, ws, _ = _layer_case(k, N, 16, k)
+    whole, _ = moe.moe_dropless(x, logits, k, *ws, 1, kernel=True)
+    again, _ = moe.moe_dropless(x[perm], logits[perm], k, *ws, 1, valid=jnp.asarray(kept), kernel=True)
+    assert (bits(np.asarray(again)[kept]) == bits(np.asarray(whole)[perm][kept])).all()
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["float32", "int8"])
+def test_the_combine_on_an_ep_by_tp_mesh_is_the_all_experts_oracle(quantized):
+    """ep = 2 x tp = 2: each shard combines the rows of its own experts (every
+    other pair selected away) from its float32 partial sums of the down
+    matrix's rows, and one psum finishes the layer: top-6 of 8 experts, 48
+    tokens, three of them carrying none."""
+    from jax.sharding import NamedSharding
+
+    from inference_gateway_tpu.parallel.mesh import create_moe_mesh
+
+    tokens, experts, k = 48, 8, 6
+    x, logits, ws, expert_fn = _layer_case(51, tokens, experts, k, quantized)
+    mesh = create_moe_mesh(dp=1, sp=1, ep=2, tp=2)
+    specs = (moe._stack_spec(ws[0], "ep", None, "tp"), moe._stack_spec(ws[1], "ep", None, "tp"),
+             moe._stack_spec(ws[2], "ep", "tp", None))
+    sharded = [jax.tree.map(lambda a, s: jax.device_put(a, NamedSharding(mesh, s)), w, spec)
+               for w, spec in zip(ws, specs)]
+    valid = jnp.arange(tokens) < tokens - 3
+    want = moe.moe_dense(x, logits, k, expert_fn)
+    got, rows = jax.jit(lambda x_, l_, *w_: moe.moe_dropless(x_, l_, k, *w_, 1, valid=valid, mesh=mesh))(
+        x, logits, *sharded)
+    np.testing.assert_allclose(np.asarray(got[:-3]), np.asarray(want[:-3]), atol=3e-5, rtol=1e-4)
+    assert not np.asarray(got[-3:]).any() and int(rows.sum()) == (tokens - 3) * k
