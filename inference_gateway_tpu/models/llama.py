@@ -261,10 +261,11 @@ def _write_kv(ck, cv, qkv, write_idx, plane, flat: int, total: int, skip=None):
     # Per-layer offset; rows that were OOB within the layer (== flat,
     # the drop convention) must stay OOB for the WHOLE buffer, not
     # land in layer li+1's first page.
-    w_idx = jnp.where(write_idx >= flat, total, write_idx + plane * flat)
-    if skip is not None:
-        w_idx = jnp.where(skip, total, w_idx)
-    return ck.at[w_idx].set(qkv[3], mode="drop"), cv.at[w_idx].set(qkv[4], mode="drop"), w_idx
+    with jax.named_scope("kv.write"):
+        w_idx = jnp.where(write_idx >= flat, total, write_idx + plane * flat)
+        if skip is not None:
+            w_idx = jnp.where(skip, total, w_idx)
+        return ck.at[w_idx].set(qkv[3], mode="drop"), cv.at[w_idx].set(qkv[4], mode="drop"), w_idx
 
 
 def _flat_index(write_idx, plane, flat: int, total: int):
@@ -735,33 +736,35 @@ def forward_paged_impl(
     L, P, page_size, HkvD = cache[mixer["pool"] if own else "k"].shape
     flat = P * page_size
 
-    x = _embed(params, cfg, tokens)
-    if residual is not None:
-        x = residual["enter"](x)
-    inv_freq = rope_inv_freq(cfg.hd, cfg.rope_theta, cfg.rope_scaling_dict)
-    cos, sin = rope_cos_sin(positions, inv_freq)
+    with jax.named_scope("embed"):
+        x = _embed(params, cfg, tokens)
+        if residual is not None:
+            x = residual["enter"](x)
 
     def windowed(mask, key_pos, window):
         if not window:
             return mask
         return mask & (key_pos > positions[:, :, None] - window)
 
-    if mode == "prefill":
-        # Keys are this call's tokens at absolute `positions`
-        # (same windowing as the dense path, forward() above).
-        prefill_mask = causal_prefill_mask(positions, lengths)
-        tok_valid = jnp.arange(T)[None, :] < lengths[:, None]
-    elif mode == "prefill_chunk":
-        S_gather = page_table.shape[1] * page_size
-        key_pos = jnp.arange(S_gather)
-        chunk_mask = (key_pos[None, None, :] <= positions[:, :, None]) & (
-            key_pos[None, None, :] < lengths[:, None, None]
-        )
-        tok_valid = positions < lengths[:, None]
-    else:
-        tok_valid = None if valid is None else jnp.broadcast_to(valid[:, None], (B, T))
-    if tok_valid is not None:
-        tok_valid = tok_valid.reshape(B * T)
+    with jax.named_scope("carry"):  # what the step derives from its rows' positions and lengths, once for every layer
+        inv_freq = rope_inv_freq(cfg.hd, cfg.rope_theta, cfg.rope_scaling_dict)
+        cos, sin = rope_cos_sin(positions, inv_freq)
+        if mode == "prefill":
+            # Keys are this call's tokens at absolute `positions`
+            # (same windowing as the dense path, forward() above).
+            prefill_mask = causal_prefill_mask(positions, lengths)
+            tok_valid = jnp.arange(T)[None, :] < lengths[:, None]
+        elif mode == "prefill_chunk":
+            S_gather = page_table.shape[1] * page_size
+            key_pos = jnp.arange(S_gather)
+            chunk_mask = (key_pos[None, None, :] <= positions[:, :, None]) & (
+                key_pos[None, None, :] < lengths[:, None, None]
+            )
+            tok_valid = positions < lengths[:, None]
+        else:
+            tok_valid = None if valid is None else jnp.broadcast_to(valid[:, None], (B, T))
+        if tok_valid is not None:
+            tok_valid = tok_valid.reshape(B * T)
     decode = mode == "decode"
 
     # The layer loop CARRIES the cache as one flat buffer instead of
@@ -964,13 +967,15 @@ def forward_ragged_impl(
     flat = P * page_size
     total = L * flat
 
-    x = _embed(params, cfg, tokens)
-    if residual is not None:
-        x = residual["enter"](x)
-    inv_freq = rope_inv_freq(cfg.hd, cfg.rope_theta, cfg.rope_scaling_dict)
-    cos, sin = rope_cos_sin(positions, inv_freq)
-    # Rows are packed back to back from 0: the tail past them is padding.
-    tok_valid = jnp.arange(T) < jnp.sum(q_lens)
+    with jax.named_scope("embed"):
+        x = _embed(params, cfg, tokens)
+        if residual is not None:
+            x = residual["enter"](x)
+    with jax.named_scope("carry"):
+        inv_freq = rope_inv_freq(cfg.hd, cfg.rope_theta, cfg.rope_scaling_dict)
+        cos, sin = rope_cos_sin(positions, inv_freq)
+        # Rows are packed back to back from 0: the tail past them is padding.
+        tok_valid = jnp.arange(T) < jnp.sum(q_lens)
 
     assert mixer is None or kinds is None, "an attention seam beside a per-layer kind: no model has both"
     extra = tuple(mixer["cache"]) if mixer is not None else tuple(kinds["cache"]) if kinds is not None else ()

@@ -659,17 +659,19 @@ def _mamba2(h: jnp.ndarray, mp: Params, store: Params, ordinal, *, cfg: MixtralC
     the gate BEFORE the norm, over all of Di (one group). Everything between the
     two projections is float32."""
     Di, N, Hh = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_heads
-    proj = qmatmul(h, mp["in_proj"])
-    z, u, dt = proj[:, :Di], proj[:, Di:2 * Di + 2 * N], proj[:, 2 * Di + 2 * N:]
-    c, tails = conv(u, _entry(store["conv"], ordinal), mp["conv_w"].astype(jnp.float32),
-                    mp["conv_b"].astype(jnp.float32))
-    x, B, C = c[:, :Di].reshape(-1, Hh, cfg.ssm_head_dim), c[:, Di:Di + N], c[:, Di + N:]
-    delta = jax.nn.softplus(dt.astype(jnp.float32) + mp["dt_bias"])
-    y, ssm = scan(store["ssm"], ordinal, x, delta, B, C, -jnp.exp(mp["A_log"]), mp["D"], h.dtype)
-    with jax.named_scope("ssm.gate"):
-        y = y.reshape(-1, Di) * jax.nn.silu(z.astype(jnp.float32))
-        y = rms_norm(y, mp["ssm_norm"], cfg.rms_norm_eps)
-    return qmatmul(y.astype(h.dtype), mp["out_proj"]), {"conv": _with_entry(store["conv"], ordinal, tails), "ssm": ssm}
+    with jax.named_scope("mamba"):  # the two projections and what stands between the state operations' own scopes
+        proj = qmatmul(h, mp["in_proj"])
+        z, u, dt = proj[:, :Di], proj[:, Di:2 * Di + 2 * N], proj[:, 2 * Di + 2 * N:]
+        c, tails = conv(u, _entry(store["conv"], ordinal), mp["conv_w"].astype(jnp.float32),
+                        mp["conv_b"].astype(jnp.float32))
+        x, B, C = c[:, :Di].reshape(-1, Hh, cfg.ssm_head_dim), c[:, Di:Di + N], c[:, Di + N:]
+        delta = jax.nn.softplus(dt.astype(jnp.float32) + mp["dt_bias"])
+        y, ssm = scan(store["ssm"], ordinal, x, delta, B, C, -jnp.exp(mp["A_log"]), mp["D"], h.dtype)
+        with jax.named_scope("ssm.gate"):
+            y = y.reshape(-1, Di) * jax.nn.silu(z.astype(jnp.float32))
+            y = rms_norm(y, mp["ssm_norm"], cfg.rms_norm_eps)
+        return (qmatmul(y.astype(h.dtype), mp["out_proj"]),
+                {"conv": _with_entry(store["conv"], ordinal, tails), "ssm": ssm})
 
 
 def _mamba2_decode(rows) -> dict:
@@ -1025,7 +1027,8 @@ def forward_paged(
     marks the decode rows that carry a stream."""
     conv = None
     if cfg.layer_conv is not None:
-        rows = jnp.ones(lengths.shape, bool) if valid is None else valid   # a row that carries no stream keeps its tail
+        with jax.named_scope("carry"):
+            rows = jnp.ones(lengths.shape, bool) if valid is None else valid   # a row that carries no stream keeps its tail
         conv = _mamba2_decode(rows) if cfg.mamba2 else (
             lambda z, tails, w: conv_decode(z, tails, w, None, rows, act=None))
     out = forward_paged_impl(params, cfg, tokens, positions, lengths, cache,
@@ -1033,10 +1036,11 @@ def forward_paged(
                              valid=valid, with_aux=with_aux, **_seam(params, cfg, mesh, valid, conv))
     if not (with_aux and counts_window_pages(cfg)):
         return out
-    live = lengths if valid is None else jnp.where(valid, lengths, 0)
-    counts = counted_window_pages(cfg, _page_size(cfg, cache), live, live)
-    if cfg.mamba2:   # no block is walked; every live row's state is read and written, in every state layer
-        counts = jnp.concatenate([counts, jnp.stack([0.0, jnp.sum(live > 0).astype(jnp.float32)])])
+    with jax.named_scope("carry"):
+        live = lengths if valid is None else jnp.where(valid, lengths, 0)
+        counts = counted_window_pages(cfg, _page_size(cfg, cache), live, live)
+        if cfg.mamba2:   # no block is walked; every live row's state is read and written, in every state layer
+            counts = jnp.concatenate([counts, jnp.stack([0.0, jnp.sum(live > 0).astype(jnp.float32)])])
     return (*out[:2], (out[2], counts))
 
 
@@ -1058,22 +1062,24 @@ def forward_ragged(
     """The mixed step for a mixture (llama.forward_ragged's contract; the
     third result as ``forward_paged``'s)."""
     conv = None
-    if cfg.mamba2:
-        conv, state_counts = _mamba2_ragged(cfg, q_starts, q_lens, kv_lens, tokens.shape[1])
-    elif cfg.layer_conv is not None:
-        # Each segment from its slot's tail; one that starts at position 0
-        # from zero, whatever the slot held (ops/ssm.py).
-        seg, fresh = token_segments(q_starts, q_lens, tokens.shape[1]), (kv_lens - q_lens) == 0
-        conv = lambda z, tails, w: conv_ragged(z, tails, w, None, seg, fresh, q_starts, q_lens, act=None)
+    with jax.named_scope("carry"):  # the rows' segments and the blocked scan's plan: once a step, for every state layer
+        if cfg.mamba2:
+            conv, state_counts = _mamba2_ragged(cfg, q_starts, q_lens, kv_lens, tokens.shape[1])
+        elif cfg.layer_conv is not None:
+            # Each segment from its slot's tail; one that starts at position 0
+            # from zero, whatever the slot held (ops/ssm.py).
+            seg, fresh = token_segments(q_starts, q_lens, tokens.shape[1]), (kv_lens - q_lens) == 0
+            conv = lambda z, tails, w: conv_ragged(z, tails, w, None, seg, fresh, q_starts, q_lens, act=None)
     out = forward_ragged_impl(params, cfg, tokens, positions, cache, write_idx,
                               page_table, q_starts, q_lens, kv_lens, mesh,
                               with_aux=with_aux, **_seam(params, cfg, mesh, conv=conv))
     if not (with_aux and counts_window_pages(cfg)):
         return out
-    live = jnp.where(q_lens > 0, kv_lens, 0)
-    counts = counted_window_pages(cfg, _page_size(cfg, cache), live - q_lens + 1, live, tokens.shape[1])
-    if cfg.mamba2:   # blocks the chunk kernel walked, over the state layers; rows the one-token update served
-        counts = jnp.concatenate([counts, state_counts])
+    with jax.named_scope("carry"):
+        live = jnp.where(q_lens > 0, kv_lens, 0)
+        counts = counted_window_pages(cfg, _page_size(cfg, cache), live - q_lens + 1, live, tokens.shape[1])
+        if cfg.mamba2:   # blocks the chunk kernel walked, over the state layers; rows the one-token update served
+            counts = jnp.concatenate([counts, state_counts])
     return (*out[:2], (out[2], counts))
 
 

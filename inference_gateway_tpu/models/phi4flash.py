@@ -265,16 +265,16 @@ def layer_norm(x, gain, bias, eps):
 
 
 def _mlp(x, norms, layers: Params, k, which: int, eps):
-    """The MLP of pair ``k``'s even (``which`` 0) or odd (1) layer. Its two
+    """x plus the MLP of pair ``k``'s even (``which`` 0) or odd (1) layer. Its two
     matrices are indexed out of the stacks at (k, which) in ONE step, the
     (P, 2, ...) leaf seen as (2P, ...): a pair's slice taken first is a copy
     of both layers' int8 matrices that the matmuls then read again (0.2 ms
     a pair on a v5e: PERF.md section 6, PR 32)."""
-    fc1, fc2 = (jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
-        a.reshape((-1,) + a.shape[2:]), 2 * k + which, 0, keepdims=False), layers[n]) for n in ("fc1", "fc2"))
-    with jax.named_scope("mlp"):
+    with jax.named_scope("mlp"):  # the block whole, its residual add included, as models/llama.py's encloses it
+        fc1, fc2 = (jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(
+            a.reshape((-1,) + a.shape[2:]), 2 * k + which, 0, keepdims=False), layers[n]) for n in ("fc1", "fc2"))
         g, u = jnp.split(qmatmul(layer_norm(x, norms[2], norms[3], eps), fc1), 2, axis=-1)
-        return qmatmul(u * jax.nn.silu(g), fc2)
+        return x + qmatmul(u * jax.nn.silu(g), fc2)
 
 
 def lambda_init(layer):
@@ -349,9 +349,9 @@ def _decoder(params: Params, cfg: Phi4FlashConfig, x, cache: Params, write_idx, 
 
     def self_pair(carry, k):
         x, ck, cv, sstates, ctails, _ = carry
-        lp = {**_take(layers, TAKEN, k), **_take(layers, MAMBA_LEAVES, k)}
-        n_even, n_odd = lp["norms"][0], lp["norms"][1]
-        with jax.named_scope("mamba"):
+        with jax.named_scope("mamba"):  # (the pair's leaves are taken under its first block's name)
+            lp = {**_take(layers, TAKEN, k), **_take(layers, MAMBA_LEAVES, k)}
+            n_even, n_odd = lp["norms"][0], lp["norms"][1]
             out, y, s_new, t_new = _mamba(
                 layer_norm(x, n_even[0], n_even[1], eps), lp,
                 jax.lax.dynamic_index_in_dim(sstates, k, 0, keepdims=False),
@@ -359,7 +359,7 @@ def _decoder(params: Params, cfg: Phi4FlashConfig, x, cache: Params, write_idx, 
             sstates = jax.lax.dynamic_update_index_in_dim(sstates, s_new, k, 0)
             ctails = jax.lax.dynamic_update_index_in_dim(ctails, t_new, k, 0)
             x = x + out
-        x = x + _mlp(x, n_even, layers, k, 0, eps)
+        x = _mlp(x, n_even, layers, k, 0, eps)
         with jax.named_scope("diff_attn"):
             h = layer_norm(x, n_odd[0], n_odd[1], eps)
             q = (qmatmul(h, lp["wq"]) + lp["bq"]).reshape(-1, Hq, D)
@@ -374,7 +374,7 @@ def _decoder(params: Params, cfg: Phi4FlashConfig, x, cache: Params, write_idx, 
             o = attend_self(q, *pages, table, k < M)  # pairs under M are windowed, pair M is full
             a = diff_combine(o, lp["lam"], lp["sub_norm"], 2 * k + 1)
             x = x + qmatmul(a.astype(x.dtype), lp["wo"]) + lp["bo"]
-        x = x + _mlp(x, n_odd, layers, k, 1, eps)
+        x = _mlp(x, n_odd, layers, k, 1, eps)
         return (x, ck, cv, sstates, ctails, y), None
 
     # One loop over the pairs 0..M (a pair M of its own would index the
@@ -387,24 +387,25 @@ def _decoder(params: Params, cfg: Phi4FlashConfig, x, cache: Params, write_idx, 
     new_cache = {"k": ck.reshape(Lc, P, page_size, HkvD), "v": cv.reshape(Lc, P, page_size, HkvD),
                  "ssm": sstates, "conv": ctails}
 
-    x, m = rows(x, m)
+    with jax.named_scope("head"):  # the rows that go on: models/llama.py's ``head`` picks its rows too
+        x, m = rows(x, m)
     pages_k, pages_v = new_cache["k"].reshape(Lc * P, page_size, HkvD), new_cache["v"].reshape(Lc * P, page_size, HkvD)
     shared_table = page_table + M * P
 
     def cross_pair(x, k):
-        lp = {**_take(layers, TAKEN, k), **_take(layers, ("g_in",), k - M - 1)}
-        n_even, n_odd = lp["norms"][0], lp["norms"][1]
         with jax.named_scope("gmu"):
+            lp = {**_take(layers, TAKEN, k), **_take(layers, ("g_in",), k - M - 1)}
+            n_even, n_odd = lp["norms"][0], lp["norms"][1]
             gate = qmatmul(layer_norm(x, n_even[0], n_even[1], eps), lp["g_in"])
             x = x + qmatmul((m * jax.nn.silu(gate.astype(jnp.float32))).astype(x.dtype), lp["mix_out"])
-        x = x + _mlp(x, n_even, layers, k, 0, eps)
+        x = _mlp(x, n_even, layers, k, 0, eps)
         with jax.named_scope("diff_attn"):
             h = layer_norm(x, n_odd[0], n_odd[1], eps)
             q = (qmatmul(h, lp["wq"]) + lp["bq"]).reshape(-1, Hq, D)
             o = paged_attention(pad_queries(q), pages_k, pages_v, shared_table, lengths, Hkv2)
             a = diff_combine(o, lp["lam"], lp["sub_norm"], 2 * k + 1)
             x = x + qmatmul(a.astype(x.dtype), lp["wo"]) + lp["bo"]
-        x = x + _mlp(x, n_odd, layers, k, 1, eps)
+        x = _mlp(x, n_odd, layers, k, 1, eps)
         return x, None
 
     x, _ = jax.lax.scan(cross_pair, x, jnp.arange(M + 1, cfg.pairs))
@@ -448,16 +449,19 @@ def forward_paged(params: Params, cfg: Phi4FlashConfig, tokens, positions, lengt
         return paged_attention(q, pages_k, pages_v, table, lengths, cfg.kernel_kv_heads,
                                window_dyn=window)
 
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens[:, 0]]
     x, cache = _decoder(
-        params, cfg, params["embed"][tokens[:, 0]], cache, write_idx[:, 0], page_table,
+        params, cfg, x, cache, write_idx[:, 0], page_table,
         conv=lambda u, tails, w, b: ssm.conv_decode(u, tails, w, b, valid),
         scan=lambda states, *a: ssm.scan_decode(states, *a, valid),
         attend_self=attend, rows=lambda x, m: (x, m), lengths=lengths)
     logits = _head(params, cfg, x)
     if not with_aux:
         return logits, cache
-    live = jnp.where(valid, lengths, 0)
-    return logits, cache, window_pages(cfg, cache["k"].shape[2], live, live)
+    with jax.named_scope("carry"):
+        live = jnp.where(valid, lengths, 0)
+        return logits, cache, window_pages(cfg, cache["k"].shape[2], live, live)
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "with_aux"))
@@ -472,11 +476,12 @@ def forward_ragged(params: Params, cfg: Phi4FlashConfig, tokens, positions, cach
 
     assert mesh is None, "phi4flash: no mesh carries the Mamba state yet"
     T = tokens.shape[1]
-    seg = ssm.token_segments(q_starts, q_lens, T)
-    fresh = (kv_lens - q_lens) == 0
-    n_tokens = jnp.sum(q_lens)
-    last = jnp.clip(q_starts + q_lens - 1, 0, T - 1)
-    live_lens = jnp.where(q_lens > 0, kv_lens, 0)
+    with jax.named_scope("carry"):  # the rows' segments, once a step for every layer
+        seg = ssm.token_segments(q_starts, q_lens, T)
+        fresh = (kv_lens - q_lens) == 0
+        n_tokens = jnp.sum(q_lens)
+        last = jnp.clip(q_starts + q_lens - 1, 0, T - 1)
+        live_lens = jnp.where(q_lens > 0, kv_lens, 0)
 
     def attend(q, pages_k, pages_v, table, windowed):
         # The ragged kernel takes the window as an operand: ONE instance for
@@ -485,12 +490,15 @@ def forward_ragged(params: Params, cfg: Phi4FlashConfig, tokens, positions, cach
         return ragged_paged_attention(q, pages_k, pages_v, table, q_starts, q_lens, kv_lens,
                                       cfg.kernel_kv_heads, window_dyn=window)
 
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens[0]]
     x, cache = _decoder(
-        params, cfg, params["embed"][tokens[0]], cache, write_idx[0], page_table,
+        params, cfg, x, cache, write_idx[0], page_table,
         conv=lambda u, tails, w, b: ssm.conv_ragged(u, tails, w, b, seg, fresh, q_starts, q_lens),
         scan=lambda states, *a: ssm.scan_ragged(states, *a, seg, positions[0], n_tokens),
         attend_self=attend, rows=lambda x, m: (x[last], m[last]), lengths=live_lens)
     logits = _head(params, cfg, x)
     if not with_aux:
         return logits, cache
-    return logits, cache, window_pages(cfg, cache["k"].shape[2], live_lens - q_lens + 1, live_lens)
+    with jax.named_scope("carry"):
+        return logits, cache, window_pages(cfg, cache["k"].shape[2], live_lens - q_lens + 1, live_lens)

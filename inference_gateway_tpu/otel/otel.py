@@ -53,7 +53,7 @@ COMPILE_DURATION_BOUNDARIES = (0.01, 0.05, 0.1, 0.5, 1.0, 2.5, 5.0, 10.0,
 # idle source's last reading ages out of the exposition instead of
 # freezing there. Must exceed the sidecar OTLP push interval (15s
 # default) with margin.
-EFFICIENCY_GAUGE_TTL = 60.0
+IDLE_GAUGE_TTL = 60.0
 
 _BASE_LABELS = ("source", "team", "gen_ai_operation_name", "gen_ai_provider_name", "gen_ai_request_model")
 
@@ -345,7 +345,7 @@ class OpenTelemetry:
             "(queue_depth / kv_page_utilization / active_slots / max_slots) "
             "— parsed from the /health body by the health prober",
             ("gen_ai_provider_name", "gen_ai_request_model", "signal"),
-            ttl=EFFICIENCY_GAUGE_TTL,
+            ttl=IDLE_GAUGE_TTL,
         )
         # Structured outputs (ISSUE 13): constrained-request outcomes,
         # schema→token-mask compile cost, and mask-cache effectiveness
@@ -383,23 +383,23 @@ class OpenTelemetry:
             "inference_gateway.slo.burn_rate",
             "Error-budget burn rate per tenant SLO and window (1.0 = "
             "consuming the budget exactly as fast as the window allows)",
-            ("slo", "window", "tenant"), ttl=EFFICIENCY_GAUGE_TTL,
+            ("slo", "window", "tenant"), ttl=IDLE_GAUGE_TTL,
         )
         self.slo_budget_gauge = r.gauge(
             "inference_gateway.slo.error_budget_remaining",
             "Error budget remaining per tenant SLO and window "
             "(1 - burn_rate; negative = overspent)",
-            ("slo", "window", "tenant"), ttl=EFFICIENCY_GAUGE_TTL,
+            ("slo", "window", "tenant"), ttl=IDLE_GAUGE_TTL,
         )
         self.slo_pool_burn_rate_gauge = r.gauge(
             "inference_gateway.slo.pool_burn_rate",
             "Error-budget burn rate per pool SLO and window",
-            ("slo", "window", "pool"), ttl=EFFICIENCY_GAUGE_TTL,
+            ("slo", "window", "pool"), ttl=IDLE_GAUGE_TTL,
         )
         self.slo_pool_budget_gauge = r.gauge(
             "inference_gateway.slo.pool_error_budget_remaining",
             "Error budget remaining per pool SLO and window",
-            ("slo", "window", "pool"), ttl=EFFICIENCY_GAUGE_TTL,
+            ("slo", "window", "pool"), ttl=IDLE_GAUGE_TTL,
         )
         self.journey_event_counter = r.counter(
             "inference_gateway.journey.events",
@@ -444,13 +444,13 @@ class OpenTelemetry:
             "engine.hbm.live_bytes",
             "Device bytes in use from device.memory_stats() — only set "
             "when the backend measures it (absent off-TPU, never fabricated)",
-            ("gen_ai_request_model",), ttl=EFFICIENCY_GAUGE_TTL,
+            ("gen_ai_request_model",), ttl=IDLE_GAUGE_TTL,
         )
         self.engine_hbm_peak_gauge = r.gauge(
             "engine.hbm.peak_bytes",
             "Peak device bytes in use from device.memory_stats() — only "
             "set when the backend measures it",
-            ("gen_ai_request_model",), ttl=EFFICIENCY_GAUGE_TTL,
+            ("gen_ai_request_model",), ttl=IDLE_GAUGE_TTL,
         )
         self.engine_hbm_plan_gauge = r.gauge(
             "engine.hbm.plan_bytes",

@@ -1250,7 +1250,8 @@ class Engine:
         # a request rides the chunk past max_seq_len: past it the paged
         # kernel's cdiv(len, page_size) would exceed max_pages_per_slot
         # and read page_table out of bounds (a garbage-page DMA).
-        pos_att = jnp.minimum(pos, max_len - 1)
+        with jax.named_scope("carry"):
+            pos_att = jnp.minimum(pos, max_len - 1)
         read = None  # a paged mixture's step counts the experts it read
         if write_args is None and self.pp:
             logits, cache = llama.forward_pp(
@@ -1264,25 +1265,27 @@ class Engine:
         else:
             page_table, reserved = write_args
             ps = self.config.page_size
-            page = jnp.take_along_axis(
-                page_table, (pos_att // ps)[:, None], axis=1)[:, 0]
-            # int32 throughout: a flat paged cache index always fits.
-            w = page * ps + pos_att % ps
-            valid = (~done) & (pos < max_len) & (pos < reserved)
-            w = jnp.where(valid, w, self._flat_size)
-            counted = {"valid": ~done, "with_aux": True} if self.is_moe or self.is_hybrid else {}
-            # A done row attends nothing: the decode kernel passes over a
-            # slot of length 0 and writes its row as zeros (ISSUE 37), where
-            # a frozen row's stale context cost every step its pages (an
-            # indexer's masked walk passes over it the same way: ISSUE 45).
-            att_len = jnp.where(done, 0, pos_att + 1)
+            with jax.named_scope("carry"):
+                page = jnp.take_along_axis(
+                    page_table, (pos_att // ps)[:, None], axis=1)[:, 0]
+                # int32 throughout: a flat paged cache index always fits.
+                w = page * ps + pos_att % ps
+                valid = (~done) & (pos < max_len) & (pos < reserved)
+                w = jnp.where(valid, w, self._flat_size)
+                counted = {"valid": ~done, "with_aux": True} if self.is_moe or self.is_hybrid else {}
+                # A done row attends nothing: the decode kernel passes over a
+                # slot of length 0 and writes its row as zeros (ISSUE 37), where
+                # a frozen row's stale context cost every step its pages (an
+                # indexer's masked walk passes over it the same way: ISSUE 45).
+                att_len = jnp.where(done, 0, pos_att + 1)
             logits, cache, *rows = self._model.forward_paged(
                 params, self.model_cfg, tok[:, None], pos_att[:, None],
                 att_len, cache, w[:, None], page_table, mode="decode",
                 last_only=True, mesh=self.mesh, **counted)
             if rows and self.is_moe:  # ``_chunk_stats``: a mixture's experts read, an indexer's or a window's counts
-                per, more = self._split_aux(rows[0])
-                read = jnp.concatenate([_experts_read(per, tok.shape[0] * self.model_cfg.experts_per_token), more])
+                with jax.named_scope("carry"):
+                    per, more = self._split_aux(rows[0])
+                    read = jnp.concatenate([_experts_read(per, tok.shape[0] * self.model_cfg.experts_per_token), more])
             elif rows:  # a windowed hybrid's pages (WINDOW_STATS)
                 read = rows[0]
         with jax.named_scope("mask_bias"):
@@ -1291,19 +1294,20 @@ class Engine:
             nxt = sample_tokens_pregumbel(logits, temps, top_ps, gumbels[i], k_eff)
             nxt = nxt.astype(jnp.int32)
             lp = compute_logprobs(logits, nxt)
-        nms = mnext[ms, nxt] if automaton else ms
-        nbud = bud - 1
-        ndone = jnp.any(stop_table == nxt[:, None], axis=-1)
-        ndone = ndone | (nbud <= 0) | (pos + 2 >= max_len)
-        if automaton:
-            ndone = ndone | mterm[nms]
-        out_tok = jnp.where(done, tok, nxt)
-        out_lp = jnp.where(done, 0.0, lp)
-        tok = jnp.where(done, tok, nxt)
-        pos = jnp.where(done, pos, pos + 1)
-        ms = jnp.where(done, ms, nms)
-        bud = jnp.where(done, bud, nbud)
-        done = done | ndone
+        with jax.named_scope("carry"):  # the rows' flags, budgets and positions: what the next step starts from
+            nms = mnext[ms, nxt] if automaton else ms
+            nbud = bud - 1
+            ndone = jnp.any(stop_table == nxt[:, None], axis=-1)
+            ndone = ndone | (nbud <= 0) | (pos + 2 >= max_len)
+            if automaton:
+                ndone = ndone | mterm[nms]
+            out_tok = jnp.where(done, tok, nxt)
+            out_lp = jnp.where(done, 0.0, lp)
+            tok = jnp.where(done, tok, nxt)
+            pos = jnp.where(done, pos, pos + 1)
+            ms = jnp.where(done, ms, nms)
+            bud = jnp.where(done, bud, nbud)
+            done = done | ndone
         return cache, out_tok, out_lp, tok, pos, ms, done, bud, read
 
     def _run_chunk_ee(self, params, cache, tokens, positions, done, budgets,
@@ -1328,51 +1332,54 @@ class Engine:
             keys = chunk_row_keys(rng, seeds, use_seed, positions, n_steps)
             k_eff = effective_top_k(self.config.top_k, self.model_cfg.vocab_size)
             gumbels = chunk_gumbels(keys, k_eff)
-        done0 = self._chunk_done0(tokens, positions, done, budgets, stop_table,
-                                  mstates, mask_args[4], automaton)
-        S = tokens.shape[0]
-        out_toks0 = jnp.broadcast_to(tokens[None, :], (n_steps, S)).astype(jnp.int32)
-        out_lps0 = jnp.zeros((n_steps, S), jnp.float32)
-
         # A paged mixture's carry also sums the experts its steps read, a
         # windowed hybrid's the pages they read and held.
         counted = (self.is_moe or self.is_hybrid) and write_args is not None
-        read0 = jnp.zeros((len(self._chunk_stats),), jnp.float32) if counted else None
+        with jax.named_scope("carry"):
+            done0 = self._chunk_done0(tokens, positions, done, budgets, stop_table,
+                                      mstates, mask_args[4], automaton)
+            S = tokens.shape[0]
+            out_toks0 = jnp.broadcast_to(tokens[None, :], (n_steps, S)).astype(jnp.int32)
+            out_lps0 = jnp.zeros((n_steps, S), jnp.float32)
+            read0 = jnp.zeros((len(self._chunk_stats),), jnp.float32) if counted else None
 
         def cond(carry):
             i, _cache, _tok, _pos, _ms, done, _bud, _ot, _ol, *_read = carry
-            return (i < n_steps) & jnp.any(~done)
+            with jax.named_scope("carry"):
+                return (i < n_steps) & jnp.any(~done)
 
         def body(carry):
             i, cache, tok, pos, ms, done, bud, out_t, out_l, *read_sum = carry
             cache, o_tok, o_lp, tok, pos, ms, done, bud, read = self._chunk_step_ee(
                 params, i, cache, tok, pos, ms, done, bud, gumbels, k_eff,
                 temps, top_ps, stop_table, mask_tail, write_args)
-            out_t = jax.lax.dynamic_update_index_in_dim(out_t, o_tok, i, 0)
-            out_l = jax.lax.dynamic_update_index_in_dim(out_l, o_lp, i, 0)
-            return (i + 1, cache, tok, pos, ms, done, bud, out_t, out_l,
-                    *([read_sum[0] + read] if counted else []))
+            with jax.named_scope("carry"):
+                out_t = jax.lax.dynamic_update_index_in_dim(out_t, o_tok, i, 0)
+                out_l = jax.lax.dynamic_update_index_in_dim(out_l, o_lp, i, 0)
+                return (i + 1, cache, tok, pos, ms, done, bud, out_t, out_l,
+                        *([read_sum[0] + read] if counted else []))
 
         (i_ran, cache, tok_f, pos_f, ms_f, done_f, bud_f, out_toks, out_lps, *read_sum) = \
             jax.lax.while_loop(
                 cond, body,
                 (jnp.int32(0), cache, tokens, positions, mstates, done0,
                  budgets, out_toks0, out_lps0, *([read0] if counted else [])))
-        if counted:
-            # More rows of the block the host fetches anyway, one a name of
-            # ``_chunk_stats``: ``experts_read`` and ``expert_visits`` are means
-            # over the steps that ran, every other count their sum.
-            stats = read_sum[0]
-            if self.is_moe:
-                stats = stats.at[:len(CHUNK_EXPERT_STATS)].divide(jnp.maximum(i_ran, 1).astype(jnp.float32))
-            out_lps = jnp.concatenate([out_lps, jnp.broadcast_to(stats[:, None], (stats.shape[0], S))])
-        # Steps the loop never ran emit each row's FINAL frozen token
-        # (not the chunk-entry one), so the emitted block reads exactly
-        # like a chunk whose frozen rows kept repeating their last
-        # token — the host's stop detection re-fires on it either way.
-        skipped = jnp.arange(n_steps)[:, None] >= i_ran
-        out_toks = jnp.where(skipped, tok_f[None, :], out_toks)
-        rng_next = jax.random.fold_in(rng, 1)
+        with jax.named_scope("carry"):  # the block the host fetches, and the key of the chunk behind this one
+            if counted:
+                # More rows of the block the host fetches anyway, one a name of
+                # ``_chunk_stats``: ``experts_read`` and ``expert_visits`` are means
+                # over the steps that ran, every other count their sum.
+                stats = read_sum[0]
+                if self.is_moe:
+                    stats = stats.at[:len(CHUNK_EXPERT_STATS)].divide(jnp.maximum(i_ran, 1).astype(jnp.float32))
+                out_lps = jnp.concatenate([out_lps, jnp.broadcast_to(stats[:, None], (stats.shape[0], S))])
+            # Steps the loop never ran emit each row's FINAL frozen token
+            # (not the chunk-entry one), so the emitted block reads exactly
+            # like a chunk whose frozen rows kept repeating their last
+            # token — the host's stop detection re-fires on it either way.
+            skipped = jnp.arange(n_steps)[:, None] >= i_ran
+            out_toks = jnp.where(skipped, tok_f[None, :], out_toks)
+            rng_next = jax.random.fold_in(rng, 1)
         return out_toks, out_lps, tok_f, pos_f, ms_f, done_f, bud_f, rng_next, cache
 
     @partial(jax.jit, static_argnames=("self", "n_steps", "automaton"), donate_argnums=(2,))
@@ -1487,10 +1494,11 @@ class Engine:
             # experts read and the kernels' visits; each a mean over the layers.
             # Then DSA_STATS, counted by the attention seam, or WINDOW_STATS,
             # counted by the model.
-            per, more = self._split_aux(rows[0])  # (L, E)
-            stats = jnp.stack([jnp.mean(jnp.max(per, axis=1).astype(jnp.float32)), jnp.mean(per.astype(jnp.float32))])
-            m = tokens.shape[-1] * self.model_cfg.experts_per_token
-            return toks, logprobs, cache, jnp.concatenate([stats, _experts_read(per, m), more])
+            with jax.named_scope("carry"):
+                per, more = self._split_aux(rows[0])  # (L, E)
+                stats = jnp.stack([jnp.mean(jnp.max(per, axis=1).astype(jnp.float32)), jnp.mean(per.astype(jnp.float32))])
+                m = tokens.shape[-1] * self.model_cfg.experts_per_token
+                return toks, logprobs, cache, jnp.concatenate([stats, _experts_read(per, m), more])
         return toks, logprobs, cache
 
     @partial(jax.jit, static_argnames=("self", "automaton"), donate_argnums=(2,))
@@ -1506,19 +1514,21 @@ class Engine:
         for its slot (a token id is exact in float32 below 2**24), so the
         host builds and dispatches this step before it has fetched that
         one. Nothing else of a row depends on the token."""
-        d = self._mixed_pack.unpack(packed)
-        rng = jax.random.fold_in(base_rng, d["counter"][0])
-        at = jnp.where(d["from_block"], d["q_starts"], d["tokens"].shape[1])  # unflagged: dropped
-        tokens = d["tokens"].at[0, at].set(block[0].astype(jnp.int32), mode="drop")
+        with jax.named_scope("carry"):  # the packed descriptors taken apart, the step's key, the flagged rows' tokens
+            d = self._mixed_pack.unpack(packed)
+            rng = jax.random.fold_in(base_rng, d["counter"][0])
+            at = jnp.where(d["from_block"], d["q_starts"], d["tokens"].shape[1])  # unflagged: dropped
+            tokens = d["tokens"].at[0, at].set(block[0].astype(jnp.int32), mode="drop")
         toks, logprobs, cache, *stats = self._mixed_step_body(
             params, cache, tokens, d["positions"], d["write_idx"], d["page_table"],
             d["q_starts"], d["q_lens"], d["kv_lens"], d["temps"], d["top_ps"],
             d["seeds"], d["use_seed"], rng, mstates=d["mstates"], mnext=mnext,
             mbits=mbits, mbias=mbias, automaton=automaton)
-        block = jnp.stack([toks.astype(jnp.float32), logprobs])
-        if stats:
-            block = jnp.concatenate(
-                [block, jnp.broadcast_to(stats[0][:, None], (stats[0].shape[0], block.shape[1]))])
+        with jax.named_scope("carry"):  # the block the fetch reads
+            block = jnp.stack([toks.astype(jnp.float32), logprobs])
+            if stats:
+                block = jnp.concatenate(
+                    [block, jnp.broadcast_to(stats[0][:, None], (stats[0].shape[0], block.shape[1]))])
         return block, cache
 
     def _audit_transfer(self, direction: str, path: str, *arrays) -> int:

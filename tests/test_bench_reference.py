@@ -85,7 +85,8 @@ globals().update({name if name not in globals() else f"{name}_granite4": fn
 # configuration, its cell and two readers, PR 38 the idle ledger's five readers,
 # PR 41 a configuration, its cell and two readers, PR 43 the same, PR 47 a
 # configuration, its cell and four readers, PR 49 one reader, PR 50 a
-# configuration, its cell and four readers. All the cut below may drop.
+# configuration, its cell and four readers, PR 52 the six by-scope readers. All
+# the cut below may drop.
 APPENDED_SINCE_PR_32 = {"configs": ["keye-vl2-30b-a3b-int8", "trinity-mini-int8", "lfm2-8b-a1b-int8",
                                     "xing4.0-29b-a4b-int8", "granite-4.0-h-small-int8"],
                         "workloads": ["keye-vl2-30b-a3b.long-context", "trinity-mini.long-context",
@@ -100,7 +101,9 @@ APPENDED_SINCE_PR_32 = {"configs": ["keye-vl2-30b-a3b-int8", "trinity-mini-int8"
                                       "mla_attn_busy_share", "mhc_busy_share",
                                       "post_warmup_compile_s",
                                       "ssd_busy_share", "ssd_chunk_roofline_share",
-                                      "ssd_state_hbm_roofline_share", "granite_mixed_step_mfu"]}
+                                      "ssd_state_hbm_roofline_share", "granite_mixed_step_mfu",
+                                      "scope_attention_share", "scope_mlp_share", "scope_moe_share",
+                                      "scope_state_share", "scope_head_sampling_share", "scope_unscoped_share"]}
 
 
 def test_the_benchmark_gains_one_configuration_one_cell_and_three_readers(tmp_path, monkeypatch):

@@ -601,6 +601,112 @@ def test_step_programs_hold_the_kernels_and_equations_they_held(monkeypatch, mod
         assert (total == equations if older else total <= equations), total
 
 
+# ISSUE 52: every equation of every family's two step programs stands under a name of
+# the closed vocabulary of scopes (``bench/scope_classes.json``; the rule and the list:
+# docs/observability.md, "Device time by named scope"), so that a capture's device time
+# can be read by scope. What may stand under none, a closed list by primitive: the
+# program's own ``jit``, a loop's own equation (``scan``, ``while``: its self time on the
+# device is what its body does not cover), the pools' and stacks' metadata-only views
+# (``reshape``, ``squeeze``, ``slice`` of a run's leaves, ``broadcast_in_dim`` of a row
+# vector to a column) and the layer indices a scan walks (``iota``, ``add``). The
+# count is held too: the two families beyond the census's eight (Xing's streams,
+# Granite's runs of one kind) read the most, 8 and 22.
+UNSCOPED_PRIMITIVES = {"jit", "scan", "while", "reshape", "squeeze", "slice", "broadcast_in_dim", "iota", "add"}
+SCOPED_PROGRAMS = list(STEP_PROGRAMS) + [("mixtral", "xing4-test-tiny"), ("mixtral", "granite4-test-tiny")]
+
+
+def _scope_vocabulary() -> dict:
+    import json
+    from pathlib import Path
+
+    spec = json.loads((Path(__file__).resolve().parents[1] / "bench" / "scope_classes.json").read_text())
+    return {scope: cls for cls, scopes in spec["classes"].items() for scope in scopes}
+
+
+def _unscoped(jaxpr, vocabulary, prefix="", out=None):
+    """(primitive, name stack) of every equation under ``jaxpr`` whose name stack, read
+    from the innermost component out, holds no name of ``vocabulary``; and the classes
+    seen. A kernel's own body is not entered: on the device it is one event."""
+    out = {"unscoped": [], "classes": set()} if out is None else out
+    for eqn in jaxpr.eqns:
+        stack = f"{prefix}/{eqn.source_info.name_stack}"
+        scope = next((part for part in reversed(stack.split("/")) if part in vocabulary), None)
+        if scope is None:
+            out["unscoped"].append((eqn.primitive.name, stack))
+        else:
+            out["classes"].add(vocabulary[scope])
+        if eqn.primitive.name != "pallas_call":
+            for inner in _inner_jaxprs(eqn):
+                _unscoped(inner, vocabulary, stack, out)
+    return out
+
+
+@pytest.mark.parametrize("module,preset", SCOPED_PROGRAMS, ids=[p for _, p in SCOPED_PROGRAMS])
+def test_every_equation_of_both_step_programs_stands_under_a_scope_of_the_closed_list(monkeypatch, module, preset):
+    """The programs the census above traces (the same shapes and the same steering, so
+    the second trace of a program is JAX's cached one), read for their name stacks."""
+    import importlib
+
+    from inference_gateway_tpu.serving.kv_cache import PagedCacheConfig
+
+    monkeypatch.setattr("inference_gateway_tpu.ops.paged_attention.device_platform", lambda: "tpu")
+    model = importlib.import_module(f"inference_gateway_tpu.models.{module}")
+    cfg = model.PRESETS[preset]
+    slots, page, seq, T = 4, 16, 64, 40
+    params = jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0), cfg))
+    own_pool = (module == "phi4flash" or getattr(cfg, "indexer_topk", 0) or getattr(cfg, "layer_conv", None)
+                or getattr(cfg, "kv_lora_rank", 0))
+    if own_pool:
+        cache = jax.eval_shape(lambda: model.init_paged_cache(cfg, PagedCacheConfig(page, 0, slots, seq)))
+    else:
+        pages = jax.ShapeDtypeStruct((cfg.num_layers, slots * seq // page, page, cfg.num_kv_heads * cfg.hd), BF16)
+        cache = {"k": pages, "v": pages}
+    sds = jax.ShapeDtypeStruct
+    tokens, row, col, table = sds((1, T), I32), sds((slots,), I32), sds((slots, 1), I32), sds((slots, seq // page), I32)
+    mixed = jax.make_jaxpr(lambda *a: model.forward_ragged(a[0], cfg, *a[1:]))(
+        params, tokens, tokens, cache, tokens, table, row, row, row)
+    decode = jax.make_jaxpr(lambda *a: model.forward_paged(a[0], cfg, *a[1:], mode="decode"))(
+        params, col, col, row, cache, col, table)
+    vocabulary = _scope_vocabulary()
+    for traced in (mixed, decode):
+        got = _unscoped(traced.jaxpr, vocabulary)
+        assert {p for p, _ in got["unscoped"]} <= UNSCOPED_PRIMITIVES, sorted(
+            (p, s) for p, s in got["unscoped"] if p not in UNSCOPED_PRIMITIVES)
+        assert len(got["unscoped"]) <= 24, len(got["unscoped"])
+        assert {"attention", "head_sampling", "step"} <= got["classes"], got["classes"]
+        assert ("moe" in got["classes"]) == (module == "mixtral")
+        assert ("state" in got["classes"]) == (preset in ("phi4flash-test-tiny", "lfm2-test-tiny", "granite4-test-tiny"))
+        assert ("residual" in got["classes"]) == (preset == "xing4-test-tiny")
+
+
+def test_every_equation_of_the_engines_two_step_programs_stands_under_a_scope_too():
+    """The programs as the engine dispatches them, the model's forward inside: the
+    packed descriptors' unpacking, the step's key, the bias and the draw, the chunk's
+    loop with its flags and budgets, the block the fetch reads."""
+    from inference_gateway_tpu.serving.engine import STOP_TABLE_WIDTH, Engine, EngineConfig
+
+    slots = 4
+    engine = Engine(EngineConfig(model="test-tiny", attention="paged", page_size=16, max_slots=slots, max_seq_len=256,
+                                 dtype="float32", use_mesh=False, mixed_step=True, decode_chunk=4))
+    automaton, mnext, mbits, mterm, mbias = engine._mask_args_ee()
+    mixed = jax.make_jaxpr(lambda *a: Engine._mixed_step_fn(engine, *a, automaton=False))(
+        engine.params, engine.cache, jnp.zeros((engine._mixed_pack.size,), I32), engine._rng, engine._last_block,
+        mnext, mbits, mbias)
+    row, real, flag = jnp.zeros((slots,), I32), jnp.zeros((slots,), jnp.float32), jnp.zeros((slots,), bool)
+    decode = jax.make_jaxpr(lambda *a: Engine._decode_chunk_fn_paged_ee(
+        engine, *a[:14], mstates=a[14], mnext=a[15], mbits=a[16], mterm=a[17], mbias=a[18], n_steps=4,
+        automaton=False))(
+        engine.params, engine.cache, row, row, flag, row, jnp.zeros((slots, STOP_TABLE_WIDTH), I32),
+        jnp.zeros((slots, 256 // 16), I32), row, real, real, row, flag, engine._rng,
+        jnp.asarray(engine._zero_mstates), mnext, mbits, mterm, mbias)
+    vocabulary = _scope_vocabulary()
+    for traced in (mixed, decode):
+        got = _unscoped(traced.jaxpr, vocabulary)
+        assert {p for p, _ in got["unscoped"]} <= UNSCOPED_PRIMITIVES, got["unscoped"]
+        assert len(got["unscoped"]) <= 12, got["unscoped"]
+        assert {"attention", "mlp", "head_sampling", "step"} <= got["classes"]
+
+
 @pytest.mark.parametrize("form", ["decode", "ragged"])
 def test_sparse_attention_compiles_for_v5e_at_the_published_widths(one_chip, monkeypatch, form):
     """Score, select and read (ops/sparse_attention.py) for Keye-VL-2.0's stage:
