@@ -312,7 +312,12 @@ def _mix_by_kind(kinds: dict, group: tuple, li, h, ck, cv, store: dict, ops):
     either attends or runs the state operator. The STORE does pass through the
     second: a model whose store is too large for that has its layers grouped
     by runs of one kind (``_layer_groups``). Returns (the residual
-    contribution, the pools, the store)."""
+    contribution, the pools, the store).
+
+    An attention seam that OWNS the pool beside the kinds (ISSUE 54: ``project``
+    is None and ``attend(h, leaves, li, plane, pool) -> (contribution, pool)``
+    is the seam's own projection, write and read): the layers are grouped by
+    runs, so an attention layer is that one call."""
     flags = kinds["state"]
     ordinal = jnp.asarray([sum(f == flags[l] for f in flags[:l]) for l in range(len(flags))], jnp.int32)[li]
     at = lambda stack: jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, ordinal, 0, keepdims=False), stack)
@@ -328,6 +333,10 @@ def _mix_by_kind(kinds: dict, group: tuple, li, h, ck, cv, store: dict, ops):
     if held == {1}:
         out, store = state_layer(store)
         return out, ck, cv, store
+    if held == {0} and project is None:
+        out, ck = attend(h, own(), li, ordinal, ck)
+        return out, ck, cv, store
+    assert project is not None, "an attention seam that owns the pool stands beside state layers in runs of one kind"
     if held == {0}:
         q, *_ = qkv = project(h, own(), li)
         ck, cv, _ = write(ck, cv, qkv, plane=ordinal)
@@ -690,7 +699,10 @@ def forward_paged_impl(
     kv_lens, page_size=)`` get the layer's normed input and the flat pool
     (L * P * page_size, W); they project, write their rows at ``w_idx``,
     attend, and return ``(the heads' outputs, folded, for ``wo``; the pool)``.
-    It has no auxiliary, so it may stand behind a ``prefix``.
+    It has no auxiliary, so it may stand behind a ``prefix``, and beside a
+    per-layer kind whose layers are grouped by runs (ISSUE 54: it is then the
+    OTHER kind of layer, its leaves ``kinds["attention"]`` and the pool's
+    leading axis the layers of its kind alone: ``_mix_by_kind``).
 
     The residual seam (ISSUE 47): ``residual`` is None for every model with
     ONE stream of ``hidden_size`` (``x + y``; nothing below changes for them),
@@ -780,8 +792,8 @@ def forward_paged_impl(
     # are metadata-only.
     total = L * flat
 
-    assert mixer is None or kinds is None, "an attention seam beside a per-layer kind: no model has both"
-    extra = tuple(mixer["cache"]) if mixer is not None else tuple(kinds["cache"]) if kinds is not None else ()
+    assert mixer is None or kinds is None or own, "an attention seam beside a per-layer kind: only one that owns the pool"
+    extra = tuple(kinds["cache"]) if kinds is not None else tuple(mixer["cache"]) if mixer is not None else ()
     if (mixer is not None or kinds is not None) and mode != "decode":
         raise NotImplementedError(
             f"a model with an attention seam or a state store admits through the mixed step; forward_paged mode {mode!r} has no path for it")
@@ -800,15 +812,20 @@ def forward_paged_impl(
                                mesh=mesh, **_window_operand(cfg, li))[:, None]  # (B, 1, Hq, D)
         return _attn_out(attn.reshape(B, T, Hq * D), h, ap, cfg)
 
+    def owned(h, ap, li, plane, ck):
+        """A seam that owns the pool: its projection, write and read, and ``wo``."""
+        attn, ck = mixer["decode"](h[:, 0], ap, li, positions[:, 0], ck,
+                                   _flat_index(write_idx[:, 0], plane, flat, total), page_table + plane * P,
+                                   lengths, page_size=page_size)
+        return _attn_out(attn.reshape(B, T, -1), h, ap, cfg), ck
+
     def attention(h, ap, li, plane, ck, cv, ce):
         """The attention operator on a layer's normed input: ``ap`` holds its
         leaves, ``plane`` is its plane of the pool. Returns (the residual
         contribution, the pools, the attention seam's auxiliary or None)."""
         if own:
-            attn, ck = mixer["decode"](h[:, 0], ap, li, positions[:, 0], ck,
-                                       _flat_index(write_idx[:, 0], plane, flat, total), page_table + plane * P,
-                                       lengths, page_size=page_size)
-            return _attn_out(attn.reshape(B, T, -1), h, ap, cfg), ck, cv, ce, None
+            out, ck = owned(h, ap, li, plane, ck)
+            return out, ck, cv, ce, None
         q, k, v, *_ = qkv = project(h, ap, li)
         ck, cv, w_idx = write(ck, cv, qkv, plane=plane)
         if mixer is None and decode:
@@ -859,7 +876,7 @@ def forward_paged_impl(
                 out, ck, cv, ce, mix_aux = attention(h, lp, li, li, ck, cv, ce)
             else:
                 out, ck, cv, store = _mix_by_kind(kinds, group, li, h, ck, cv, dict(zip(extra, ce)),
-                                                  (project, write, attend))
+                                                  (None, None, owned) if own else (project, write, attend))
                 ce = [store[n] for n in extra]
             x = _block_out(residual, x, _scaled(out, cfg), maps)
         with jax.named_scope("mlp"):
@@ -879,7 +896,8 @@ def forward_paged_impl(
                                   (leaves, jnp.arange(first, first + count)))
     x, ck, cv, *ce = carry
     if own:
-        new_cache = {mixer["pool"]: ck.reshape(L, P, page_size, HkvD)}
+        new_cache = {mixer["pool"]: ck.reshape(L, P, page_size, HkvD),
+                     **({n: a for n, a in zip(extra, ce)} if kinds is not None else {})}
     else:
         new_cache = {"k": ck.reshape(L, P, page_size, HkvD),
                      "v": cv.reshape(L, P, page_size, HkvD),
@@ -977,8 +995,8 @@ def forward_ragged_impl(
         # Rows are packed back to back from 0: the tail past them is padding.
         tok_valid = jnp.arange(T) < jnp.sum(q_lens)
 
-    assert mixer is None or kinds is None, "an attention seam beside a per-layer kind: no model has both"
-    extra = tuple(mixer["cache"]) if mixer is not None else tuple(kinds["cache"]) if kinds is not None else ()
+    assert mixer is None or kinds is None or own, "an attention seam beside a per-layer kind: only one that owns the pool"
+    extra = tuple(kinds["cache"]) if kinds is not None else tuple(mixer["cache"]) if mixer is not None else ()
     assert mixer is None or own or prefix is None, "an attention seam's counts of a prefix's layers would be lost"
 
     project = partial(_project_qkv, cfg=cfg, cos=cos, sin=sin, cache=cache)
@@ -993,12 +1011,16 @@ def forward_ragged_impl(
             Hkv, mesh=mesh, **_window_operand(cfg, li))[None]  # (1, T, Hq, D)
         return _attn_out(attn.reshape(B, T, Hq * D), h, ap, cfg)
 
+    def owned(h, ap, li, plane, ck):
+        attn, ck = mixer["ragged"](h[0], ap, li, positions[0], ck,
+                                   _flat_index(write_idx[0], plane, flat, total), page_table + plane * P,
+                                   q_starts, q_lens, kv_lens, page_size=page_size)
+        return _attn_out(attn.reshape(B, T, -1), h, ap, cfg), ck
+
     def attention(h, ap, li, plane, ck, cv, ce):
         if own:
-            attn, ck = mixer["ragged"](h[0], ap, li, positions[0], ck,
-                                       _flat_index(write_idx[0], plane, flat, total), page_table + plane * P,
-                                       q_starts, q_lens, kv_lens, page_size=page_size)
-            return _attn_out(attn.reshape(B, T, -1), h, ap, cfg), ck, cv, ce, None
+            out, ck = owned(h, ap, li, plane, ck)
+            return out, ck, cv, ce, None
         q, *_ = qkv = project(h, ap, li)
         ck, cv, w_idx = write(ck, cv, qkv, plane=plane)
         if mixer is None:
@@ -1020,7 +1042,7 @@ def forward_ragged_impl(
                 out, ck, cv, ce, mix_aux = attention(h, lp, li, li, ck, cv, ce)
             else:
                 out, ck, cv, store = _mix_by_kind(kinds, group, li, h, ck, cv, dict(zip(extra, ce)),
-                                                  (project, write, attend))
+                                                  (None, None, owned) if own else (project, write, attend))
                 ce = [store[n] for n in extra]
             x = _block_out(residual, x, _scaled(out, cfg), maps)
         with jax.named_scope("mlp"):
@@ -1039,7 +1061,8 @@ def forward_ragged_impl(
                                   (leaves, jnp.arange(first, first + count)))
     x, ck, cv, *ce = carry
     if own:
-        new_cache = {mixer["pool"]: ck.reshape(L, P, page_size, HkvD)}
+        new_cache = {mixer["pool"]: ck.reshape(L, P, page_size, HkvD),
+                     **({n: a for n, a in zip(extra, ce)} if kinds is not None else {})}
     else:
         new_cache = {"k": ck.reshape(L, P, page_size, HkvD),
                      "v": cv.reshape(L, P, page_size, HkvD),

@@ -18,7 +18,11 @@ shared expert), and the Granite-4.0-H block (ISSUE 50, ``granitemoehybrid``:
 the per-layer kind's third value, a Mamba-2 mixer whose matrix state a head
 rides the state store beside the convolution's tail, ops/ssd.py; the four
 multipliers on the skeletons; a softmax router beside a shared MLP in every
-layer).
+layer), and the Kimi-Linear block (ISSUE 54, ``kimi_linear``: the kind's fourth
+value, Kimi Delta Attention, ops/kda.py, a delta rule with a decay a channel
+whose matrix state rides the state store; the latent attention, no query
+bottleneck and no rotary, as the OTHER kind of layer, its pool of those layers
+alone; a chip's SHARE of the experts under the whole router).
 
 Same TPU-first skeleton as models/llama.py (stacked layers + lax.scan,
 the shared paged and ragged forwards) with the dense MLP replaced by a
@@ -50,6 +54,7 @@ from inference_gateway_tpu.models.llama import (
     forward_ragged_impl,
     window_pages,
 )
+from inference_gateway_tpu.ops import kda as kda_ops
 from inference_gateway_tpu.ops.attention import causal_prefill_mask, decode_mask, gqa_attend
 from inference_gateway_tpu.ops.moe import ACTIVATIONS, moe_dense, moe_dropless, router_sigmoid, router_topk
 from inference_gateway_tpu.ops.norms import rms_norm
@@ -114,12 +119,30 @@ class MixtralConfig(LlamaConfig):
     # the state (``ssm``) and the convolution's tail (``conv``). A model holds
     # attention layers and ONE kind of state layer. ssd_block: the tokens a block
     # of the scan's blocked form holds (any value gives the recurrence's numbers).
+    # Kimi-Linear's block (ISSUE 54): 3 is a layer whose token mixer is Kimi
+    # Delta Attention (ops/kda.py): kda_heads heads of kda_head_dim, a float32
+    # state of kda_head_dim x kda_head_dim a head decayed by a vector a head a
+    # token and corrected by a rank-one delta rule; q, k and v each behind a
+    # depthwise causal convolution of conv_kernel taps and SiLU. It keeps, a
+    # slot, the state (``kda``) and the convolution's tail (``conv``).
+    # kda_block: the tokens a block of its chunk form holds.
     layer_conv: tuple | None = None
     conv_kernel: int = 3
     ssm_heads: int = 0
     ssm_head_dim: int = 64
     ssm_state: int = 128
     ssd_block: int = 256
+    kda_heads: int = 0
+    kda_head_dim: int = 128
+    kda_block: int = 64
+    kda_rank: int = 0   # the decay's and the gate's low-rank bottleneck; 0: kda_head_dim
+    # A chip's share of the experts (ISSUE 54). 0: every expert is held.
+    # Else the expert stacks hold experts_held of the num_experts, from expert
+    # experts_first on; the router is num_experts wide and chooses among all;
+    # a chosen expert that is not held is computed by NO ONE here (its chip
+    # adds it): the layer's output is the held experts' part.
+    experts_held: int = 0
+    experts_first: int = 0
     # Xing4.0's block (ISSUE 47). kv_lora_rank 0: none. A latent attention
     # (the DeepSeek-V2 form): queries through a q_lora_rank bottleneck with a
     # norm, num_heads of qk_nope_head_dim + qk_rope_head_dim (= head_dim);
@@ -154,20 +177,32 @@ class MixtralConfig(LlamaConfig):
             flags = tuple(int(f) for f in self.layer_conv)
             object.__setattr__(self, "layer_conv", flags)
             if (len(flags) != self.num_layers or 0 not in flags or len(set(flags) - {0}) != 1
-                    or not set(flags) <= {0, 1, 2}):
-                raise ValueError(f"layer_conv {flags}: one of 0 (attention), 1 (short convolution), 2 (Mamba-2) a "
-                                 f"layer of {self.num_layers}; attention and ONE kind of state layer present")
+                    or not set(flags) <= {0, 1, 2, 3}):
+                raise ValueError(f"layer_conv {flags}: one of 0 (attention), 1 (short convolution), 2 (Mamba-2), 3 "
+                                 f"(Kimi Delta Attention) a layer of {self.num_layers}; attention and ONE kind of "
+                                 "state layer present")
             if (2 in flags) != bool(self.ssm_heads):
                 raise ValueError("layer_conv 2 and ssm_heads: a Mamba-2 layer has heads, and no other layer")
+            if (3 in flags) != bool(self.kda_heads):
+                raise ValueError("layer_conv 3 and kda_heads: a Kimi Delta Attention layer has heads, and no other layer")
             if self.indexer_topk or self.attn_gate or self.sandwich_norm or self.qkv_bias:
                 raise ValueError("layer_conv: the attention operators' stack carries no indexer, gate, post-norm or bias")
         if self.kv_lora_rank:
-            if self.hd != self.qk_nope_head_dim + self.qk_rope_head_dim or not self.q_lora_rank or not self.v_head_dim:
-                raise ValueError("kv_lora_rank: head_dim is qk_nope_head_dim + qk_rope_head_dim, under a q_lora_rank")
-            if (self.indexer_topk or self.layer_conv is not None or self.sliding_window or self.layer_rope is not None
-                    or self.qk_norm or self.attn_gate or self.sandwich_norm or self.qkv_bias):
-                raise ValueError("kv_lora_rank: the latent attention carries no indexer, kind, window, NoPE layer, "
-                                 "q/k norm, gate, post-norm or bias")
+            if self.hd != self.qk_nope_head_dim + self.qk_rope_head_dim or not self.v_head_dim:
+                raise ValueError("kv_lora_rank: head_dim is qk_nope_head_dim + qk_rope_head_dim, values of v_head_dim")
+            if (self.indexer_topk or self.sliding_window or self.qk_norm or self.attn_gate or self.sandwich_norm
+                    or self.qkv_bias):
+                raise ValueError("kv_lora_rank: the latent attention carries no indexer, window, q/k norm, gate, "
+                                 "post-norm or bias")
+            if self.layer_rope is not None and any(self.layer_rope):
+                raise ValueError("kv_lora_rank: the shared lanes are rotated in every layer (layer_rope None) or in "
+                                 "none (all 0)")
+            if self.layer_conv is not None and (not self.scans_runs or self.hc_mult):
+                raise ValueError("kv_lora_rank beside a per-layer kind: the state layers are scanned in runs "
+                                 "(Mamba-2 or Kimi Delta Attention), one residual stream")
+        if self.experts_held and not (0 < self.experts_held <= self.num_experts - self.experts_first
+                                      and self.experts_first >= 0):
+            raise ValueError(f"experts_held {self.experts_held} from {self.experts_first} of {self.num_experts} experts")
 
     @property
     def expert_layers(self) -> int:
@@ -185,6 +220,27 @@ class MixtralConfig(LlamaConfig):
         return 2 in (self.layer_conv or ())
 
     @property
+    def kda(self) -> bool:
+        """The model's state layers are Kimi Delta Attention (ISSUE 54)."""
+        return 3 in (self.layer_conv or ())
+
+    @property
+    def scans_runs(self) -> bool:
+        """The state store is too large to stand under a ``lax.cond``: the layers
+        are scanned in runs of one kind (``llama._layer_groups``)."""
+        return self.mamba2 or self.kda
+
+    @property
+    def kda_inner(self) -> int:
+        """A Kimi Delta Attention layer's inner width: heads x head size."""
+        return self.kda_heads * self.kda_head_dim
+
+    @property
+    def held_experts(self) -> int:
+        """The experts the stacks hold: all of them, or this chip's share."""
+        return self.experts_held or self.num_experts
+
+    @property
     def ssm_inner(self) -> int:
         """A Mamba-2 mixer's inner width: heads x head size."""
         return self.ssm_heads * self.ssm_head_dim
@@ -193,6 +249,8 @@ class MixtralConfig(LlamaConfig):
     def conv_channels(self) -> int:
         """What a state layer's convolution runs over: hidden_size for the
         gated short convolution, [x, B, C] for a Mamba-2 mixer."""
+        if self.kda:   # q, k and v, side by side
+            return 3 * self.kda_inner
         return self.ssm_inner + 2 * self.ssm_state if self.mamba2 else self.hidden_size
 
     @property
@@ -215,7 +273,8 @@ class MixtralConfig(LlamaConfig):
     def state_bytes_per_slot(self) -> int:
         """A slot's share of the state store (float32, ops/ssm.py)."""
         tails = (self.conv_kernel - 1) * self.conv_channels
-        return self.conv_layers * (tails + self.ssm_inner * self.ssm_state * self.mamba2) * 4
+        matrix = self.ssm_inner * self.ssm_state * self.mamba2 + self.kda_inner * self.kda_head_dim * self.kda
+        return self.conv_layers * (tails + matrix) * 4
 
 
 Params = dict[str, Any]
@@ -239,8 +298,10 @@ def attention_leaves(cfg: MixtralConfig, count: int, dtype, matrix) -> Params:
     if cfg.kv_lora_rank:
         # W_DQ, W_UQ (heads x [nope, rope]), W_DKV ([latent, rotary key]), W_UKV
         # (heads x [k_nope, v]), W_O, and the two bottlenecks' norms.
-        return {"attn_norm": ones(H), "wdq": matrix("wdq", (count, H, cfg.q_lora_rank)),
-                "q_a_norm": ones(cfg.q_lora_rank), "wuq": matrix("wuq", (count, cfg.q_lora_rank, Hq * D)),
+        queries = ({"wdq": matrix("wdq", (count, H, cfg.q_lora_rank)), "q_a_norm": ones(cfg.q_lora_rank),
+                    "wuq": matrix("wuq", (count, cfg.q_lora_rank, Hq * D))} if cfg.q_lora_rank
+                   else {"wq": matrix("wq", (count, H, Hq * D))})   # (ISSUE 54: no bottleneck)
+        return {"attn_norm": ones(H), **queries,
                 "wdkv": matrix("wdkv", (count, H, cfg.latent_width)), "kv_a_norm": ones(cfg.kv_lora_rank),
                 "wukv": matrix("wukv", (count, cfg.kv_lora_rank, Hq * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
                 "wo": matrix("wo", (count, Hq * cfg.v_head_dim, H))}
@@ -279,16 +340,21 @@ def layer_leaves(cfg: MixtralConfig, count: int, dtype, matrix, plain=None) -> P
 
 CONV_LEAVES = ("conv_in", "conv_w", "conv_out")
 MAMBA_LEAVES = ("in_proj", "conv_w", "conv_b", "dt_bias", "A_log", "D", "ssm_norm", "out_proj")
+KDA_LEAVES = ("kda_qkv", "conv_w", "kda_fa", "kda_fb", "dt_bias", "A_log", "kda_b", "kda_ga", "kda_gb", "kda_gbias",
+              "kda_norm", "kda_out")
 ATTENTION_STACK = ("wq", "wk", "wv", "wo", "q_norm", "k_norm")
+LATENT_STACK = ("wdq", "q_a_norm", "wuq", "wdkv", "kv_a_norm", "wukv", "wo")
 
 
 def state_leaves(cfg: "MixtralConfig") -> tuple:
     """The names of the state layers' stack."""
-    return MAMBA_LEAVES if cfg.mamba2 else CONV_LEAVES
+    return KDA_LEAVES if cfg.kda else MAMBA_LEAVES if cfg.mamba2 else CONV_LEAVES
 
 
 def attention_stack(cfg: "MixtralConfig") -> tuple:
     """The names of the attention operators' stack in a model with a per-layer kind."""
+    if cfg.kv_lora_rank:
+        return LATENT_STACK if cfg.q_lora_rank else ("wq",) + LATENT_STACK[3:]
     return ATTENTION_STACK if cfg.qk_norm else ATTENTION_STACK[:4]
 
 
@@ -304,6 +370,18 @@ def operator_leaves(cfg: MixtralConfig, dtype, matrix, plain) -> Params:
     H, n = cfg.hidden_size, cfg.conv_layers
     attention = attention_leaves(cfg, cfg.cache_layers, dtype, matrix)
     del attention["attn_norm"]   # the layers' own stacks hold every operator's input norm
+    if cfg.kda:
+        # W_qkv (H, [q P, k P, v P]), the taps (K, 3 P) oldest first, no bias; the decay's
+        # and the gate's low-rank pairs (H, r), (r, P); beta's projection (H, heads),
+        # never quantised; dt_bias (P,) and A_log (heads,), float32, at
+        # ``kda_heads_init``; the gate's bias; the output norm a head; W_o (P, H).
+        P, Hh, r = cfg.kda_inner, cfg.kda_heads, cfg.kda_rank or cfg.kda_head_dim
+        return {**attention, "kda_qkv": matrix("kda_qkv", (n, H, 3 * P)), "conv_w": plain("conv_w", (n, cfg.conv_kernel, 3 * P)),
+                "kda_fa": matrix("kda_fa", (n, H, r)), "kda_fb": matrix("kda_fb", (n, r, P)),
+                **kda_heads_init(n, Hh, cfg.kda_head_dim), "kda_b": plain("kda_b", (n, H, Hh)),
+                "kda_ga": matrix("kda_ga", (n, H, r)), "kda_gb": matrix("kda_gb", (n, r, P)),
+                "kda_gbias": jnp.zeros((n, P), jnp.float32), "kda_norm": jnp.ones((n, cfg.kda_head_dim), dtype),
+                "kda_out": matrix("kda_out", (n, P, H))}
     if cfg.mamba2:
         # W_in (H, [z Di, x Di, B N, C N, dt heads]), the taps (K, Di + 2 N) oldest
         # first and their bias, W_out (Di, H); dt_bias, A_log and D a head, float32,
@@ -336,8 +414,30 @@ def mamba_heads_init(layers: int, heads: int) -> Params:
             "D": jnp.ones((layers, heads), jnp.float32)}
 
 
+def kda_heads_init(layers: int, heads: int, head_dim: int) -> Params:
+    """``A_log`` (layers, heads) and ``dt_bias`` (layers, heads x head_dim) of
+    ``layers`` Kimi Delta Attention layers, float32, in the form of the family's
+    modelling code (A uniform in [1, 16] a head; the decay's step log-uniform a
+    channel with dt_bias its inverse softplus), laid out evenly in place of
+    drawn (``mamba_heads_init``'s reasons). The steps spread over [0.0001, 0.1],
+    a decade below the modelling code's [0.001, 0.1]: a channel's memory 1 / (A
+    dt) then runs from a token to 10,000, the deployment's context, and a sixth
+    of the channels remember more than a chunk, as a trained long-context
+    model's must. At [0.001, 0.1] one channel in sixty did, and a state lost
+    at a chunk boundary 320-500 tokens in front of the compared tokens read
+    CORRECT on the chip (0.18 / 0.055 nats against a sound 0.17 / 0.033: my
+    chip run, PR 54)."""
+    u = (jnp.arange(heads, dtype=jnp.float32) + 0.5) / heads
+    c = jnp.arange(heads * head_dim, dtype=jnp.float32)
+    v = ((c * 37 + head_dim // 2) % head_dim + 0.5) / head_dim   # 37 is coprime to every head size used (a power of two)
+    dt = jnp.exp(jnp.log(0.0001) + v * (jnp.log(0.1) - jnp.log(0.0001)))
+    lay = lambda a: jnp.broadcast_to(a[None, :], (layers,) + a.shape).astype(jnp.float32)
+    return {"A_log": lay(jnp.log(1.0 + 15.0 * u)), "dt_bias": lay(dt + jnp.log(-jnp.expm1(-dt)))}
+
+
 def init_params(rng: jax.Array, cfg: MixtralConfig, dtype=jnp.bfloat16) -> Params:
     L, H, I, V, E = cfg.expert_layers, cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size, cfg.num_experts
+    Eh = cfg.held_experts   # the stacks hold a chip's share; the router is E wide
     # One key a leaf the older mixtures have, as they always drew them; the
     # leaves ISSUE 41 added draw from a stream of their own.
     keys = dict(zip(("embed", "wq", "wk", "wv", "wo", "router", "wg", "wu", "wd", "lm_head"),
@@ -364,9 +464,9 @@ def init_params(rng: jax.Array, cfg: MixtralConfig, dtype=jnp.bfloat16) -> Param
         "moe_norm": jnp.ones((L, H), dtype),
         "router": matrix("router", (L, H, E)),
         # Expert FFNs: leading E axis → ep sharding.
-        "wg": matrix("wg", (L, E, H, I)),
-        "wu": matrix("wu", (L, E, H, I)),
-        "wd": matrix("wd", (L, E, I, H)),
+        "wg": matrix("wg", (L, Eh, H, I)),
+        "wu": matrix("wu", (L, Eh, H, I)),
+        "wd": matrix("wd", (L, Eh, I, H)),
     }
     if cfg.indexer_topk:
         Hi, Di = cfg.indexer_heads, cfg.indexer_head_dim
@@ -409,6 +509,8 @@ def init_state(cfg: MixtralConfig, slots: int) -> Params:
     state = {"conv": jnp.zeros((cfg.conv_layers, slots, cfg.conv_kernel - 1, cfg.conv_channels), jnp.float32)}
     if cfg.mamba2:   # a head's matrix state (ops/ssd.py)
         state["ssm"] = jnp.zeros((cfg.conv_layers, slots, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
+    if cfg.kda:   # a head's matrix state, key x value (ops/kda.py)
+        state["kda"] = jnp.zeros((cfg.conv_layers, slots, cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim), jnp.float32)
     return state
 
 
@@ -435,10 +537,12 @@ def _moe_block(flat: jnp.ndarray, routed: jnp.ndarray, router, experts: Params, 
             act = (ACTIVATIONS[cfg.hidden_act](g) * u).astype(inp.dtype)
             return qeinsum("eni,eih->enh", act, lw["wd"], out_dtype=inp.dtype)
 
-        return (moe_dense(flat, logits, cfg.experts_per_token, expert_fn, route=route),
+        return (moe_dense(flat, logits, cfg.experts_per_token, expert_fn, route=route, first=cfg.experts_first,
+                          held=cfg.held_experts),
                 jnp.zeros((cfg.num_experts,), jnp.int32))
     return moe_dropless(flat, logits, cfg.experts_per_token, experts["wg"], experts["wu"],
-                        experts["wd"], li, act=cfg.hidden_act, valid=valid, mesh=mesh, route=route)
+                        experts["wd"], li, act=cfg.hidden_act, valid=valid, mesh=mesh, route=route,
+                        first=cfg.experts_first)
 
 
 @partial(jax.jit, static_argnames=("cfg", "mode", "last_only", "mesh"))
@@ -674,6 +778,77 @@ def _mamba2(h: jnp.ndarray, mp: Params, store: Params, ordinal, *, cfg: MixtralC
                 {"conv": _with_entry(store["conv"], ordinal, tails), "ssm": ssm})
 
 
+def _rows_by_length(q_starts, q_lens, kv_lens, T: int, block: int):
+    """A mixed step's packed rows (row r = slot r) as a state layer with a chunk
+    form splits them: a row of one token takes the batched one-token update at
+    its packed position ``first``, a row of more the chunk form's visits
+    (``ops/ssd.block_plan`` at ``block`` tokens); each from its slot's state,
+    from zero where it starts at position 0 (``fresh``). Returns (the tokens'
+    segments, fresh (S,), the one-token rows (S,), the tokens of rows of more
+    (T,), the plan's meta, the visits it holds, each row's first packed token)."""
+    seg, fresh = token_segments(q_starts, q_lens, T), (kv_lens - q_lens) == 0
+    slot, _, live = seg
+    one = q_lens == 1
+    in_chunk = live & (q_lens[jnp.minimum(slot, q_lens.shape[0] - 1)] > 1)
+    meta, blocks = block_plan(q_starts, q_lens, kv_lens, T, block)
+    return seg, fresh, one, in_chunk, meta, blocks, jnp.minimum(q_starts, T - 1)
+
+
+def _kda(h: jnp.ndarray, kp: Params, store: Params, ordinal, *, cfg: MixtralConfig, conv, scan):
+    """Kimi Delta Attention on N tokens (ISSUE 54): h (N, H) the mixer's normed
+    input, ``kp`` its leaves, the store's ``conv`` and ``kda`` entries at
+    ``ordinal`` what its slots remember. [q, k, v] = silu(conv(h W_qkv))
+    (``conv(u, tails, taps, None) -> (c, new tails)``: ops/ssm.py's decode or ragged
+    form); a head's q and k l2-normalised, q times head_dim ** -0.5; the decay's
+    log g = -exp(A_log) softplus((h W_fa) W_fb + dt_bias), a channel; beta =
+    sigmoid(h w_b), a head; the delta rule (``scan(kda store, ordinal, q, k, v, g,
+    beta, the activations' type) -> (o, kda store)``: ops/kda.py's one-token
+    update or its chunk form, the store whole so that a kernel updates it in
+    place); the output ((w * rmsnorm(o)) * sigmoid((h W_ga) W_gb + b)) W_o, the
+    norm a head. Everything between the projections is float32."""
+    P, Hh, D = cfg.kda_inner, cfg.kda_heads, cfg.kda_head_dim
+    with jax.named_scope("mamba"):  # the projections and what stands between the state operations' own scopes
+        c, tails = conv(qmatmul(h, kp["kda_qkv"]), _entry(store["conv"], ordinal), kp["conv_w"].astype(jnp.float32), None)
+        q, k, v = (c[:, i * P:(i + 1) * P].reshape(-1, Hh, D) for i in range(3))
+        unit = lambda a: a * jax.lax.rsqrt(jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+        f = qmatmul(qmatmul(h, kp["kda_fa"]), kp["kda_fb"]).astype(jnp.float32)
+        g = -jnp.exp(kp["A_log"])[None, :, None] * jax.nn.softplus(f + kp["dt_bias"]).reshape(-1, Hh, D)
+        beta = jax.nn.sigmoid(jnp.dot(h, kp["kda_b"].astype(h.dtype), preferred_element_type=jnp.float32))
+        o, states = scan(store["kda"], ordinal, unit(q) * D ** -0.5, unit(k), v, g, beta, h.dtype)
+        with jax.named_scope("ssm.gate"):
+            gate = qmatmul(qmatmul(h, kp["kda_ga"]), kp["kda_gb"]).astype(jnp.float32) + kp["kda_gbias"]
+            o = rms_norm(o, kp["kda_norm"], cfg.rms_norm_eps) * jax.nn.sigmoid(gate.reshape(-1, Hh, D))
+        return (qmatmul(o.reshape(-1, P).astype(h.dtype), kp["kda_out"]),
+                {"conv": _with_entry(store["conv"], ordinal, tails), "kda": states})
+
+
+def _kda_decode(rows) -> dict:
+    """The mixer's two state operations for the decode chunk: one token a
+    slot; ``rows`` (S,) the slots that carry a stream (the others keep theirs)."""
+    def scan(states, ordinal, q, k, v, g, beta, mm):
+        return kda_ops.update_store(states, ordinal, q, k, v, g, beta, rows)
+
+    return {"conv": lambda u, tails, w, b: conv_decode(u, tails, w, b, rows), "scan": scan}
+
+
+def _kda_ragged(cfg: MixtralConfig, q_starts, q_lens, kv_lens, T: int) -> tuple[dict, jnp.ndarray]:
+    """The same for a mixed step's packed rows (``_mamba2_ragged``'s split: a row
+    of one token takes the batched one-token update, a row of more the chunk
+    form, ops/kda.py). Returns the operations and the step's counts, float32
+    (2,): the blocks the chunk kernel walks over the state layers and the rows
+    whose state the update reads and writes in each."""
+    seg, fresh, one, in_chunk, meta, blocks, first = _rows_by_length(q_starts, q_lens, kv_lens, T, cfg.kda_block)
+
+    def scan(states, ordinal, q, k, v, g, beta, mm):
+        o, states = kda_ops.kda_chunk(states, ordinal, q, k, v, g, beta, meta, Q=cfg.kda_block, mm=mm)
+        o = jnp.where(in_chunk[:, None, None], o, 0.0)
+        o1, states = kda_ops.update_store(states, ordinal, q[first], k[first], v[first], g[first], beta[first], one, fresh)
+        return o.at[jnp.where(one, q_starts, T)].set(o1, mode="drop"), states
+
+    ops = {"conv": lambda u, tails, w, b: conv_ragged(u, tails, w, b, seg, fresh, q_starts, q_lens), "scan": scan}
+    return ops, jnp.stack([blocks * cfg.conv_layers, jnp.sum(one).astype(jnp.float32)])
+
+
 def _mamba2_decode(rows) -> dict:
     """The mixer's two state operations for the decode chunk: one token a
     slot; ``rows`` (S,) the slots that carry a stream (the others keep theirs)."""
@@ -690,12 +865,7 @@ def _mamba2_ragged(cfg: MixtralConfig, q_starts, q_lens, kv_lens, T: int) -> tup
     where it starts at position 0. Returns the operations and the step's
     counts, float32 (2,): the blocks the chunk kernel walks over the state
     layers and the rows whose state the update reads and writes in each."""
-    seg, fresh = token_segments(q_starts, q_lens, T), (kv_lens - q_lens) == 0
-    slot, _, live = seg
-    one = q_lens == 1
-    in_chunk = live & (q_lens[jnp.minimum(slot, q_lens.shape[0] - 1)] > 1)
-    meta, blocks = block_plan(q_starts, q_lens, kv_lens, T, cfg.ssd_block)
-    first = jnp.minimum(q_starts, T - 1)
+    seg, fresh, one, in_chunk, meta, blocks, first = _rows_by_length(q_starts, q_lens, kv_lens, T, cfg.ssd_block)
 
     def scan(ssm, ordinal, x, delta, B, C, A, D, mm):
         y, ssm = ssd_chunk(ssm, ordinal, x, delta, B, C, A, meta, Q=cfg.ssd_block, mm=mm)
@@ -808,14 +978,21 @@ def _latent_project(h, lp: Params, positions, cfg: MixtralConfig):
     into the heads' [k_nope, v] blocks; returns also W_UV (rank, heads, v)."""
     N = h.shape[0]
     Hq, Dn, Dr, Dv, rank = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
-    cos, sin = rope_cos_sin(positions, rope_inv_freq(Dr, cfg.rope_theta, cfg.rope_scaling_dict))
+    if cfg.layer_rope is None:
+        cos, sin = rope_cos_sin(positions, rope_inv_freq(Dr, cfg.rope_theta, cfg.rope_scaling_dict))
+        rotate = lambda a: apply_rope(a, cos, sin)
+    else:   # no position encoding (ISSUE 54): the shared lanes are scored as they are projected
+        rotate = lambda a: a
     with jax.named_scope("mla.project"):
-        c_q = rms_norm(qmatmul(h, lp["wdq"]), lp["q_a_norm"], cfg.rms_norm_eps)
-        q = qmatmul(c_q, lp["wuq"]).reshape(N, Hq, Dn + Dr)
-        q_rope = apply_rope(q[..., Dn:], cos, sin)
+        if cfg.q_lora_rank:
+            c_q = rms_norm(qmatmul(h, lp["wdq"]), lp["q_a_norm"], cfg.rms_norm_eps)
+            q = qmatmul(c_q, lp["wuq"]).reshape(N, Hq, Dn + Dr)
+        else:
+            q = qmatmul(h, lp["wq"]).reshape(N, Hq, Dn + Dr)
+        q_rope = rotate(q[..., Dn:])
         down = qmatmul(h, lp["wdkv"])
         latent = rms_norm(down[:, :rank], lp["kv_a_norm"], cfg.rms_norm_eps)
-        k_rope = apply_rope(down[:, None, rank:], cos, sin)[:, 0]
+        k_rope = rotate(down[:, None, rank:])[:, 0]
         row = jnp.concatenate([latent, k_rope, jnp.zeros((N, cfg.latent_row - cfg.latent_width), h.dtype)], axis=-1)
     with jax.named_scope("mla.absorb"):
         w = lp["wukv"]
@@ -901,7 +1078,7 @@ def _seam(params: Params, cfg: MixtralConfig, mesh, valid=None, conv=None) -> di
     per-layer kind (``conv``: the step's form of the convolution, ``_shortconv``'s;
     for Mamba-2 layers the step's forms of both state operations, ``_mamba2``'s)."""
     experts = {n: params["layers"][n] for n in EXPERT_LEAVES}
-    if cfg.mamba2:   # read whole at the layer's index, as the experts are (``_layer_groups``' runs)
+    if cfg.scans_runs and cfg.shared_experts:   # read whole at the layer's index, as the experts are (``_layer_groups``' runs)
         experts.update({n: params["layers"][n] for n in SHARED_LEAVES})
     seam = {"ffn": partial(_moe_ffn, cfg=cfg, experts=experts, mesh=mesh), "unscanned": EXPERT_LEAVES}
     if cfg.layer_conv is not None:
@@ -909,12 +1086,14 @@ def _seam(params: Params, cfg: MixtralConfig, mesh, valid=None, conv=None) -> di
         # layers are scanned in runs of one kind, and the shared MLP's matrices,
         # which a run's scan would slice, are read at the layer's index instead.)
         names, operators = state_leaves(cfg), attention_stack(cfg)
-        mix = partial(_mamba2, cfg=cfg, **(conv or {})) if cfg.mamba2 else partial(_shortconv, conv=conv)
+        mix = (partial(_kda, cfg=cfg, **(conv or {})) if cfg.kda else
+               partial(_mamba2, cfg=cfg, **(conv or {})) if cfg.mamba2 else partial(_shortconv, conv=conv))
         seam["kinds"] = {"state": tuple(int(f > 0) for f in cfg.layer_conv),
-                         "cache": ("conv", "ssm") if cfg.mamba2 else ("conv",), "mix": mix, "runs": cfg.mamba2,
+                         "cache": ("conv", "kda") if cfg.kda else ("conv", "ssm") if cfg.mamba2 else ("conv",),
+                         "mix": mix, "runs": cfg.scans_runs,
                          "attention": {n: params["layers"][n] for n in operators},
                          "leaves": {n: params["layers"][n] for n in names}}
-        seam["unscanned"] += operators + names + (SHARED_LEAVES if cfg.mamba2 else ())
+        seam["unscanned"] += operators + names + (SHARED_LEAVES if cfg.scans_runs and cfg.shared_experts else ())
     if cfg.num_dense_layers:
         # The leading dense layers: a scanned group of their own in front.
         lead = prefix_leaves(params["layers"])
@@ -952,10 +1131,10 @@ def counts_window_pages(cfg: MixtralConfig) -> bool:
 def counted_window_pages(cfg: MixtralConfig, page_size: int, first_len, last_len, max_queries: int = 1):
     """``llama.window_pages`` over this model's windowed layers (its caching
     layers, with a window no context reaches, where it has convolution layers)."""
-    if cfg.layer_conv is not None:
-        return window_pages(cfg.cache_layers, 1 << 30, page_size, first_len, last_len)
     if cfg.kv_lora_rank:
         return latent_pages(cfg, page_size, first_len, last_len, max_queries)
+    if cfg.layer_conv is not None:
+        return window_pages(cfg.cache_layers, 1 << 30, page_size, first_len, last_len)
     return window_pages(windowed_layers(cfg), cfg.sliding_window, page_size, first_len, last_len)
 
 
@@ -977,7 +1156,7 @@ def latent_pages(cfg: MixtralConfig, page_size: int, first_len, last_len, max_qu
     ends = jnp.minimum(first_len[:, None] + (c + 1) * tile - 1, last_len[:, None])
     walked = jnp.where(c * tile < queries[:, None], (ends + page_size - 1) // page_size, 0)
     pages = jnp.where(live, (last_len + page_size - 1) // page_size, 0)
-    return cfg.num_layers * jnp.stack([jnp.sum(walked), jnp.sum(pages),
+    return cfg.cache_layers * jnp.stack([jnp.sum(walked), jnp.sum(pages),
                                        jnp.sum(jnp.where(queries == 1, pages, 0))]).astype(jnp.float32)
 
 
@@ -988,7 +1167,8 @@ def init_paged_cache(cfg: MixtralConfig, cache_cfg, dtype=jnp.bfloat16) -> Param
     alone and the state store beside them (``init_state``)."""
     pages = cache_cfg.resolve_num_pages()
     if cfg.kv_lora_rank:  # one array, a latent row a token a layer: no k, no v
-        return {MLA_POOL: jnp.zeros((cfg.num_layers, pages, cache_cfg.page_size, cfg.latent_row), dtype)}
+        return {MLA_POOL: jnp.zeros((cfg.cache_layers, pages, cache_cfg.page_size, cfg.latent_row), dtype),
+                **init_state(cfg, cache_cfg.max_slots)}
     plane = (cfg.cache_layers, pages, cache_cfg.page_size)
     cache = {"k": jnp.zeros(plane + (cfg.num_kv_heads * cfg.hd,), dtype),
              "v": jnp.zeros(plane + (cfg.num_kv_heads * cfg.hd,), dtype), **init_state(cfg, cache_cfg.max_slots)}
@@ -1029,7 +1209,7 @@ def forward_paged(
     if cfg.layer_conv is not None:
         with jax.named_scope("carry"):
             rows = jnp.ones(lengths.shape, bool) if valid is None else valid   # a row that carries no stream keeps its tail
-        conv = _mamba2_decode(rows) if cfg.mamba2 else (
+        conv = _kda_decode(rows) if cfg.kda else _mamba2_decode(rows) if cfg.mamba2 else (
             lambda z, tails, w: conv_decode(z, tails, w, None, rows, act=None))
     out = forward_paged_impl(params, cfg, tokens, positions, lengths, cache,
                              write_idx, page_table, mode, last_only, mesh,
@@ -1039,7 +1219,7 @@ def forward_paged(
     with jax.named_scope("carry"):
         live = lengths if valid is None else jnp.where(valid, lengths, 0)
         counts = counted_window_pages(cfg, _page_size(cfg, cache), live, live)
-        if cfg.mamba2:   # no block is walked; every live row's state is read and written, in every state layer
+        if cfg.scans_runs:   # no block is walked; every live row's state is read and written, in every state layer
             counts = jnp.concatenate([counts, jnp.stack([0.0, jnp.sum(live > 0).astype(jnp.float32)])])
     return (*out[:2], (out[2], counts))
 
@@ -1063,7 +1243,9 @@ def forward_ragged(
     third result as ``forward_paged``'s)."""
     conv = None
     with jax.named_scope("carry"):  # the rows' segments and the blocked scan's plan: once a step, for every state layer
-        if cfg.mamba2:
+        if cfg.kda:
+            conv, state_counts = _kda_ragged(cfg, q_starts, q_lens, kv_lens, tokens.shape[1])
+        elif cfg.mamba2:
             conv, state_counts = _mamba2_ragged(cfg, q_starts, q_lens, kv_lens, tokens.shape[1])
         elif cfg.layer_conv is not None:
             # Each segment from its slot's tail; one that starts at position 0
@@ -1078,7 +1260,7 @@ def forward_ragged(
     with jax.named_scope("carry"):
         live = jnp.where(q_lens > 0, kv_lens, 0)
         counts = counted_window_pages(cfg, _page_size(cfg, cache), live - q_lens + 1, live, tokens.shape[1])
-        if cfg.mamba2:   # blocks the chunk kernel walked, over the state layers; rows the one-token update served
+        if cfg.scans_runs:   # blocks the chunk kernel walked, over the state layers; rows the one-token update served
             counts = jnp.concatenate([counts, state_counts])
     return (*out[:2], (out[2], counts))
 
@@ -1188,6 +1370,20 @@ def _granite(layer_types: str, **sizes) -> MixtralConfig:
                          conv_kernel=4, shared_experts=2, tie_word_embeddings=True, rms_norm_eps=1e-5, **sizes)
 
 
+def _kimi(layer_types: str, **sizes) -> MixtralConfig:
+    """Kimi-Linear's block (ISSUE 54, ``kimi_linear``): Kimi Delta Attention
+    ("k") or the latent attention with no query bottleneck and NO rotary ("m")
+    a layer in the published order (three to one); the first layer's FFN a dense
+    MLP, the others experts routed by sigmoid scores with a selection bias over
+    one group, the chosen scores normalised and times routed_scaling_factor
+    2.446, one shared expert; an untied head. ``experts_held``: a chip's share of
+    the experts, under the whole router."""
+    n = len(layer_types)
+    return MixtralConfig(num_layers=n, layer_conv=tuple(3 * (k == "k") for k in layer_types), layer_rope=(0,) * n,
+                         num_dense_layers=1, conv_kernel=4, router_score="sigmoid", route_scale=2.446,
+                         shared_experts=1, rms_norm_eps=1e-5, rope_theta=10000.0, **sizes)
+
+
 PRESETS: dict[str, MixtralConfig] = {
     "mixtral-test-tiny": MixtralConfig(
         vocab_size=256, hidden_size=64, num_layers=2, num_heads=4, num_kv_heads=2,
@@ -1293,4 +1489,25 @@ PRESETS: dict[str, MixtralConfig] = {
         intermediate_size=768, num_experts=72, experts_per_token=10, max_position_embeddings=131072,
         ssm_heads=128, ssm_head_dim=64, ssm_state=128, ssd_block=128, embedding_multiplier=12.0,
         attention_multiplier=0.0078125, residual_multiplier=0.22, logits_scaling=16.0),
+    # The stage's own shape at toy widths: dense K, then K K M K K K M (five runs in
+    # eight layers), 4 heads of 32 with a block of 8 tokens in sub-blocks shorter
+    # than it, a latent of 32 beside 8 shared lanes, 8 experts top-2 of which this
+    # "chip" holds the first 4, beside a shared one.
+    "kimi-linear-test-tiny": _kimi(
+        "kkkmkkkm", vocab_size=256, hidden_size=64, num_heads=4, num_kv_heads=4, head_dim=24, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, intermediate_size=32, dense_intermediate_size=96,
+        num_experts=8, experts_per_token=2, experts_held=4, max_position_embeddings=512,
+        kda_heads=4, kda_head_dim=32, kda_block=8, kda_rank=16),
+    # moonshotai/Kimi-Linear-48B-A3B-Instruct is 27 layers, 48 GB of int8: no chip
+    # holds it. This is ONE of the two chips that share each layer of the first of
+    # four pipeline stages (8, 8, 8, 3 layers): layers 1-8 = two whole periods (K K K
+    # M K K K M, layer 1 dense), every width and the whole vocabulary as published,
+    # experts 0-127 of the 256 under the whole router (the other chip holds 128-255
+    # and everything else twice): 7.9 GB, which one v5e serves beside a latent pool
+    # of TWO layers and a state store of 13.5 MB a slot.
+    "kimi-linear-48b-a3b-stage8": _kimi(
+        "kkkmkkkm", vocab_size=163840, hidden_size=2304, num_heads=32, num_kv_heads=32, head_dim=192, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, intermediate_size=1024,
+        dense_intermediate_size=9216, num_experts=256, experts_per_token=8, experts_held=128,
+        max_position_embeddings=1048576, kda_heads=32, kda_head_dim=128, kda_block=64),
 }

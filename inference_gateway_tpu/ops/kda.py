@@ -1,0 +1,374 @@
+"""Kimi Delta Attention (KDA, arXiv:2510.26692) for serving: a linear
+attention whose state is a MATRIX a head, decayed by a VECTOR a head a token
+(one factor a key channel) and corrected by a rank-one delta rule (ISSUE 54).
+Beside ``ops/ssd.py`` (Mamba-2: one scalar decay a head, no correction), whose
+plan of visits over the packed axis this file shares, and ``ops/ssm.py``, whose
+convolution the mixer shares.
+
+    S_t = (I - beta_t k_t k_t^T) Diag(a_t) S_{t-1} + beta_t k_t v_t^T ;   o_t = S_t^T q_t
+
+a head: q_t, k_t (K,) (the caller has normalised them), v_t (V,), a_t =
+exp(g_t) in (0, 1)^K, beta_t in (0, 1), S (K, V) float32. A slot's state is
+``(heads, K, V)``; the store of a layer stack ``(state layers, slots, heads, K, V)``.
+The one-token form: ``S' = Diag(a_t) S; u_t = beta_t (v_t - S'^T k_t); S_t = S' + k_t u_t^T``.
+
+Two forms, as ``ops/ssd.py`` has them:
+
+- ``update_store``: every row is one slot advancing ONE token, batched over the
+  slots. On a TPU it is the Pallas kernel ``kda_state_update_kernel``: a grid
+  of (head blocks, slots), a visit reading and writing ONE row's states of a
+  head block in place; a slot that does not advance rides the block of the
+  nearest one that does (no fetch, no write). Elsewhere ``state_update``, the
+  definition in plain ``jax.numpy`` over the layer's whole entry.
+- ``kda_chunk``: the rows of a mixed step that hold MORE than one token, in
+  blocks of Q tokens. With G_t the running sum of g inside the block (a vector),
+
+      (I + L) U = Diag(beta) (V - (K * e^G) S_in),   L[t, s] = beta_t sum_c k_t[c] k_s[c] e^{G_t[c] - G_s[c]}, s < t
+      O = (Q * e^G) S_in + A U,                      A[t, s] = sum_c q_t[c] k_s[c] e^{G_t[c] - G_s[c]}, s <= t
+      S_out = Diag(e^{G_Q}) S_in + (K * e^{G_Q - G})^T U
+
+  In exact arithmetic that IS the recurrence whatever Q. The decay differs by
+  channel, so L and A are not one product of two scaled operands: ``K e^{G}``
+  against ``K e^{-G}`` overflows float32 once a channel has decayed by e^88
+  inside a block. A block is therefore cut into SUB-blocks of ``SUB`` tokens:
+  a row sub-block against the columns in front of it is a product of operands
+  scaled about the sub-block's first token (``e^{G_t - G_r}`` and ``e^{G_r -
+  G_s}``: both exponents <= 0), and inside a sub-block every pair is formed
+  alone, ``e^{G_t - G_s}`` a channel, and summed over the channels. No
+  exponent that is formed is positive. The unit triangular system is solved
+  by the product ``(I - L)(I + L^2)(I + L^4) ..`` (L is nilpotent: log2 Q
+  factors, every one a plain product).
+
+**How the blocks lie**: ``ops/ssd.block_plan``'s visits, unchanged. A row of
+more than one token visits every packed block it touches; the tokens of
+another row in that block are identity steps (g = 0, beta = 0) whose outputs
+are not written.
+
+On a TPU ``kda_chunk`` is the Pallas kernel ``kda_chunk_kernel``: a grid of
+(head blocks, visits), the head block's states in a VMEM scratch across a
+row's visits, the store read and written in place by DMA at (layer ordinal,
+slot). A head's key width is the 128 lanes, so tokens lie in the sublanes and
+every operand is a plain blocked read. The products with the state and with
+U take operands rounded to the model's activation type (``mm``); the running
+sums, the decays, L, the solve, the accumulation and the state are float32
+(``ops/ssd.py``'s rule). Elsewhere ``kda_chunk`` is the same plan walked by
+``lax.fori_loop`` in plain ``jax.numpy`` with every pair formed alone (the
+twin; tests hold the interpreted kernel to it and both to the recurrence).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from inference_gateway_tpu.ops.ssd import HIGHEST, META, _padded
+from inference_gateway_tpu.utils.platform import on_tpu
+
+HEAD_BLOCK = 4   # heads a grid step holds: their states are 4 x K x V float32 of VMEM (256 KB at 128 x 128)
+SUB = 16         # tokens of a sub-block: inside one, every pair's decay is formed alone
+
+
+def state_update(entry, q, k, v, g, beta, valid, fresh=None):
+    """One token a slot. entry (S, Hh, K, V) float32, the layer's states; q, k,
+    g (S, Hh, K) (g the log of the decay, <= 0); v (S, Hh, V); beta (S, Hh);
+    valid (S,) rows that advance (the others keep their state); fresh (S,) rows
+    whose token is at position 0 (they start from zero). Returns o (S, Hh, V)
+    float32 and the new entry. Plain ``jax.numpy``: the definition, and what
+    runs off the TPU."""
+    with jax.named_scope("ssm.scan"):
+        prev = entry if fresh is None else jnp.where(fresh[:, None, None, None], 0.0, entry)
+        decayed = jnp.exp(g)[..., None] * prev
+        u = beta[..., None] * (v - jnp.sum(decayed * k[..., None], axis=2))
+        new = decayed + k[..., None] * u[:, :, None, :]
+        o = jnp.sum(new * q[..., None], axis=2)
+        return o, jnp.where(valid[:, None, None, None], new, entry)
+
+
+def _state_update_kernel(ord_ref, row_ref, flag_ref, q_ref, k_ref, a_ref, v_ref, b_ref, s_in, o_ref, s_out,
+                         *, hb: int, K: int):
+    s = pl.program_id(1)
+    own = row_ref[s] == s                      # else: a row that does not advance, riding the block of one that does
+    valid, fresh = flag_ref[s] % 2 == 1, flag_ref[s] // 2 == 1
+
+    @pl.when(own & valid)
+    def _():
+        # A head's (1, K) row as a COLUMN beside its state's rows: spread over K sublanes, turned.
+        col = lambda row: jnp.broadcast_to(row, (K, K)).T
+        for i in range(hb):
+            lanes = slice(i * K, (i + 1) * K)
+            state = s_in[i * K:(i + 1) * K, :]                                  # (K, V)
+            decayed = col(a_ref[:, lanes]) * jnp.where(fresh, jnp.zeros_like(state), state)
+            kc = col(k_ref[:, lanes])
+            u = b_ref[:, lanes] * (v_ref[:, lanes] - jnp.sum(decayed * kc, axis=0, keepdims=True))   # (1, V)
+            new = decayed + kc * u
+            s_out[i * K:(i + 1) * K, :] = new
+            o_ref[:, lanes] = jnp.sum(new * col(q_ref[:, lanes]), axis=0, keepdims=True)
+
+    @pl.when(own & jnp.logical_not(valid))     # no row advances at all: the block is written back as it came
+    def _():
+        s_out[...] = s_in[...]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def state_update_tpu(store, ordinal, q, k, v, g, beta, valid, fresh, *, interpret: bool = False):
+    """``state_update`` on the whole store by the Pallas kernel
+    ``kda_state_update_kernel`` (``ops/ssd.state_update_tpu``'s grid and its
+    riding rows): the store's traffic is the LIVE rows' states, once in and
+    once out. K = V (a head's state is square). Returns o (S, Hh, V), defined
+    at the valid rows, and the store."""
+    L, S, Hh, K, V = store.shape
+    hb = HEAD_BLOCK if Hh % HEAD_BLOCK == 0 else Hh
+    rows = store.reshape(L, S, Hh * K, V)
+    idx = jnp.arange(S)
+    before = jax.lax.cummax(jnp.where(valid, idx, -1))                          # the last advancing row at or before s
+    row_of = jnp.where(before >= 0, before, jnp.argmax(valid)).astype(jnp.int32)
+    row_of = jnp.where(jnp.any(valid), row_of, 0)
+    flags = valid.astype(jnp.int32) + 2 * fresh.astype(jnp.int32)
+    flat = lambda a: a.reshape(S, 1, Hh * K).astype(jnp.float32)
+    at = lambda f: (lambda h, s, o, r, fl: f(h, s, o, r))
+    vec = pl.BlockSpec((None, 1, hb * K), at(lambda h, s, o, r: (r[s], 0, h)))
+    states = pl.BlockSpec((None, None, hb * K, V), at(lambda h, s, o, r: (o[0], r[s], h, 0)))
+    o, rows = pl.pallas_call(
+        functools.partial(_state_update_kernel, hb=hb, K=K),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(Hh // hb, S),
+            in_specs=[vec, vec, vec, vec, vec, states], out_specs=[vec, states]),
+        out_shape=[jax.ShapeDtypeStruct((S, 1, Hh * V), jnp.float32), jax.ShapeDtypeStruct(rows.shape, rows.dtype)],
+        input_output_aliases={8: 1},   # the store: operand 8 counting the three scalar operands
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="kda_state_update_kernel",
+    )(jnp.reshape(ordinal, (1,)).astype(jnp.int32), row_of, flags,
+      flat(q), flat(k), flat(jnp.exp(g)), flat(v), flat(jnp.broadcast_to(beta[..., None], v.shape)), rows)
+    return o.reshape(S, Hh, V), rows.reshape(store.shape)
+
+
+def update_store(store, ordinal, q, k, v, g, beta, valid, fresh=None, kernel: bool | None = None):
+    """``state_update`` on entry ``ordinal`` of the store (state layers, slots,
+    Hh, K, V). Returns (o (S, Hh, V), defined at the valid rows; the store).
+    ``kernel`` None: the Pallas kernel on a TPU where a head's state is 128 x
+    128, plain ``jax.numpy`` elsewhere; tests force the kernel, interpreted off
+    the TPU."""
+    L, S, Hh, K, V = store.shape
+    if kernel is None:
+        kernel = on_tpu() and K == 128 and V == 128
+    if kernel:
+        fresh = jnp.zeros_like(valid) if fresh is None else fresh
+        with jax.named_scope("ssm.scan"):
+            return state_update_tpu(store, ordinal, q, k, v, g, beta, valid, fresh, interpret=not on_tpu())
+    entry = jax.lax.dynamic_index_in_dim(store, ordinal, 0, keepdims=False)
+    o, entry = state_update(entry, q, k, v, g, beta, valid, fresh)
+    return o, jax.lax.dynamic_update_index_in_dim(store, entry, ordinal, 0)
+
+
+def _nt(a, b, **kw):
+    """a (M, K) against b (N, K): a b^T, float32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32, **kw)
+
+
+def _kda_chunk_kernel(ord_ref, meta_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref, store_in, o_ref, store_out,
+                      s_ref, sem, *, hb: int, Q: int, K: int, sub: int, mm):
+    h, j = pl.program_id(0), pl.program_id(1)
+    word = lambda w: meta_ref[j * META + w]
+    slot, lo, hi = word(1), word(2), word(3)
+    first, last, fresh, init = word(4) == 1, word(5) == 1, word(6) == 1, word(7) == 1
+    live = hi > lo
+    mine_at = lambda ref: ref.at[ord_ref[0], slot, pl.ds(h * hb * K, hb * K)]
+    exact = dict(precision=HIGHEST)
+    rounded = dict(precision=HIGHEST) if mm == jnp.float32 else {}
+    dot = lambda a, b, kw: jnp.dot(a, b, preferred_element_type=jnp.float32, **kw)
+
+    @pl.when(init)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live & first & jnp.logical_not(fresh))
+    def _():
+        load = pltpu.make_async_copy(mine_at(store_in), s_ref, sem.at[0])
+        load.start()
+        load.wait()
+
+    @pl.when(live & first & fresh)
+    def _():
+        s_ref[...] = jnp.zeros_like(s_ref)
+
+    @pl.when(live)
+    def _():
+        tok = jax.lax.broadcasted_iota(jnp.int32, (Q, 1), 0)
+        mine = (tok >= lo) & (tok < hi)                                        # (Q, 1)
+        t_of = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+        s_of = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+        running = (s_of <= t_of).astype(jnp.float32)                           # G = running @ g
+        for i in range(hb):
+            lanes = slice(i * K, (i + 1) * K)
+            g = jnp.where(mine, g_ref[:, lanes], 0.0)                          # (Q, K): a foreign token neither decays
+            kb = jnp.where(mine, kb_ref[:, lanes], 0.0)                        # .. nor corrects (beta = 0)
+            vb = jnp.where(mine, vb_ref[:, lanes], 0.0)
+            k, q = k_ref[:, lanes], q_ref[:, lanes]
+            G = dot(running, g, exact)                                         # (Q, K), never rises
+            state = s_ref[i * K:(i + 1) * K, :]                                # (K, V)
+            sm = state.astype(mm)
+            eG = jnp.exp(G)
+            rhs = vb - dot((kb * eG).astype(mm), sm, rounded)
+            from_state = dot((q * eG).astype(mm), sm, rounded)
+            l_rows, a_rows = [], []
+            for a in range(Q // sub):
+                rows = slice(a * sub, (a + 1) * sub)
+                ref = G[a * sub:a * sub + 1, :]                                # the sub-block's first token
+                d_row = jnp.exp(jnp.minimum(G[rows] - ref, 0.0))
+                kc = k * jnp.exp(jnp.minimum(ref - G, 0.0))                    # right for the columns in front of it
+                l_sub = _nt(kb[rows] * d_row, kc, **exact)                     # (sub, Q)
+                a_sub = _nt((q[rows] * d_row).astype(mm), kc.astype(mm), **rounded)
+                col = jax.lax.broadcasted_iota(jnp.int32, (sub, Q), 1)
+                in_front = col < a * sub
+                l_sub, a_sub = jnp.where(in_front, l_sub, 0.0), jnp.where(in_front, a_sub, 0.0)
+                for c in range(sub):                                           # the sub-block's own columns, pair by pair
+                    s_tok = a * sub + c
+                    w = jnp.exp(jnp.minimum(G[rows] - G[s_tok:s_tok + 1, :], 0.0)) * k[s_tok:s_tok + 1, :]
+                    l_sub = jnp.where(col == s_tok, jnp.sum(kb[rows] * w, axis=1, keepdims=True), l_sub)
+                    a_sub = jnp.where(col == s_tok, jnp.sum(q[rows] * w, axis=1, keepdims=True), a_sub)
+                l_rows.append(l_sub)
+                a_rows.append(a_sub)
+            Lm = jnp.where(s_of < t_of, jnp.concatenate(l_rows, axis=0), 0.0)   # strictly lower
+            Am = jnp.where(s_of <= t_of, jnp.concatenate(a_rows, axis=0), 0.0)
+            # (I + L)^-1 = (I - L)(I + L^2)(I + L^4) ..: L^Q = 0.
+            u = rhs - dot(Lm, rhs, exact)
+            power, span = Lm, 2
+            while span < Q:
+                power = dot(power, power, exact)
+                u = u + dot(power, u, exact)
+                span *= 2
+            um = u.astype(mm)
+            out = from_state + dot(Am.astype(mm), um, rounded)
+            o_ref[:, lanes] = jnp.where(mine, out, o_ref[:, lanes])
+            end = G[Q - 1:Q, :]                                                # (1, K)
+            carry = (k * jnp.exp(jnp.minimum(end - G, 0.0))).astype(mm)        # (Q, K)
+            grown = jax.lax.dot_general(carry, um, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+                                        **rounded)                              # (K, V)
+            s_ref[i * K:(i + 1) * K, :] = jnp.broadcast_to(jnp.exp(end), (K, K)).T * state + grown
+
+    @pl.when(live & last)
+    def _():
+        save = pltpu.make_async_copy(s_ref, mine_at(store_out), sem.at[1])
+        save.start()
+        save.wait()
+
+
+@functools.partial(jax.jit, static_argnames=("Q", "mm", "sub", "interpret"))
+def kda_chunk_tpu(store, ordinal, q, k, v, g, beta, meta, *, Q: int, mm=jnp.float32, sub: int = SUB,
+                  interpret: bool = False):
+    """``kda_chunk`` by the Pallas kernel. The store is updated in place."""
+    T, Hh, K = q.shape
+    V = meta.shape[0]
+    hb = HEAD_BLOCK if Hh % HEAD_BLOCK == 0 else Hh
+    Tp, (q, k, kb, vb, g) = _padded(T, Q, q, k, k * beta[..., None], v * beta[..., None], g)
+    flat = lambda a: a.reshape(Tp, Hh * K)
+    at = lambda f: (lambda h, j, o, m: f(h, j, m))
+    block = pl.BlockSpec((Q, hb * K), at(lambda h, j, m: (m[j * META], h)))
+    rows = store.reshape(store.shape[:2] + (Hh * K, K))                        # a head's K rows under one another
+    o, rows = pl.pallas_call(
+        functools.partial(_kda_chunk_kernel, hb=hb, Q=Q, K=K, sub=min(sub, Q), mm=mm),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(Hh // hb, V),
+            in_specs=[block, block, block, block, block, pl.BlockSpec(memory_space=pltpu.ANY)],
+            out_specs=[block, pl.BlockSpec(memory_space=pltpu.ANY)],
+            scratch_shapes=[pltpu.VMEM((hb * K, K), jnp.float32), pltpu.SemaphoreType.DMA((2,))]),
+        out_shape=[jax.ShapeDtypeStruct((Tp, Hh * K), jnp.float32), jax.ShapeDtypeStruct(rows.shape, rows.dtype)],
+        input_output_aliases={7: 1},   # the store: operand 7 counting the two scalar operands
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary"),
+                                             vmem_limit_bytes=64 << 20),
+        interpret=interpret,
+        name="kda_chunk_kernel",
+    )(jnp.reshape(ordinal, (1,)).astype(jnp.int32), meta.reshape(-1), flat(q), flat(k), flat(kb), flat(vb), flat(g), rows)
+    return o[:T].reshape(T, Hh, K), rows.reshape(store.shape)
+
+
+def _block(state, q, k, v, g, beta, mm):
+    """One block of one row, every head at once, every pair formed alone:
+    state (Hh, K, V); q, k, v, g (Q, Hh, K); beta (Q, Hh), foreign tokens at g =
+    0 and beta = 0. Returns (o (Q, Hh, V), the state after the block)."""
+    es = functools.partial(jnp.einsum, precision=HIGHEST)
+    rounded = lambda a: a.astype(mm).astype(jnp.float32)
+    Q = q.shape[0]
+    G = jnp.cumsum(g, axis=0)
+    pair = jnp.exp(jnp.minimum(G[:, None] - G[None, :], 0.0))                  # (t, s, Hh, K)
+    t_of, s_of = jnp.arange(Q)[:, None], jnp.arange(Q)[None, :]
+    kb = k * beta[..., None]
+    Lm = jnp.where((s_of < t_of)[..., None], jnp.sum(kb[:, None] * k[None, :] * pair, axis=-1), 0.0)   # (t, s, Hh)
+    Am = jnp.where((s_of <= t_of)[..., None], jnp.sum(q[:, None] * k[None, :] * pair, axis=-1), 0.0)
+    eG, sm = jnp.exp(G), rounded(state)
+    rhs = v * beta[..., None] - es("thk,hkv->thv", rounded(kb * eG), sm)
+    unit = jnp.eye(Q)[..., None] + Lm
+    u = jax.vmap(lambda m, r: jax.scipy.linalg.solve_triangular(m, r, lower=True, unit_diagonal=True),
+                 in_axes=(2, 1), out_axes=1)(unit, rhs)
+    um = rounded(u)
+    o = es("thk,hkv->thv", rounded(q * eG), sm) + es("tsh,shv->thv", rounded(Am), um)
+    end = G[-1]
+    new = jnp.exp(end)[..., None] * state + es("shk,shv->hkv", rounded(k * jnp.exp(jnp.minimum(end[None] - G, 0.0))), um)
+    return o, new
+
+
+def kda_chunk_twin(store, ordinal, q, k, v, g, beta, meta, *, Q: int, mm=jnp.float32):
+    """``kda_chunk`` in plain ``jax.numpy``: the same visits, one after
+    another, every head at once (``_block``)."""
+    T = q.shape[0]
+    Tp, (q, k, v, g, beta) = _padded(T, Q, q, k, v, g, beta)
+    entry = jax.lax.dynamic_index_in_dim(store, ordinal, 0, keepdims=False)    # (S, Hh, K, V)
+
+    def visit(j, carry):
+        entry, state, o = carry
+        block, slot, lo, hi, first, last, fresh, _ = (meta[j, w] for w in range(META))
+        live = hi > lo
+        take = lambda a: jax.lax.dynamic_slice_in_dim(a, block * Q, Q, 0)
+        mine = (jnp.arange(Q) >= lo) & (jnp.arange(Q) < hi)
+        state = jnp.where((first == 1) & (fresh == 0), entry[slot], jnp.where(first == 1, 0.0, state))
+        ob, new = _block(state, take(q), take(k), take(v), jnp.where(mine[:, None, None], take(g), 0.0),
+                         jnp.where(mine[:, None], take(beta), 0.0), mm)
+        state = jnp.where(live, new, state)
+        o = jax.lax.dynamic_update_slice_in_dim(o, jnp.where((mine & live)[:, None, None], ob, take(o)), block * Q, 0)
+        entry = jnp.where(live & (last == 1), entry.at[slot].set(state), entry)
+        return entry, state, o
+
+    entry, _, o = jax.lax.fori_loop(0, meta.shape[0], visit,
+                                    (entry, jnp.zeros(entry.shape[1:], jnp.float32), jnp.zeros_like(v)))
+    return o[:T], jax.lax.dynamic_update_index_in_dim(store, entry, ordinal, 0)
+
+
+def kda_chunk(store, ordinal, q, k, v, g, beta, meta, *, Q: int, mm=jnp.float32, kernel: bool | None = None,
+              sub: int = SUB):
+    """The rows of more than one token of a mixed step, blocked. store (state
+    layers, slots, Hh, K, V) float32, ``ordinal`` the layer's entry; q, k, g
+    (T, Hh, K) and v (T, Hh, V) the packed tokens' operands (q, k normalised by
+    the caller; g <= 0 the log of the decay), beta (T, Hh); ``meta`` =
+    ``ops/ssd.block_plan(...)[0]`` at the same Q. Returns (o (T, Hh, V) float32,
+    defined at the tokens of rows with more than one token and arbitrary
+    elsewhere; the store with those rows' slots at their last state). ``mm``:
+    the type the operands of the products with the state and with U are
+    rounded to; sums, decays, the solve and the state are float32 either way.
+    ``kernel`` None: the Pallas kernel on a TPU, the twin elsewhere; tests
+    force the kernel, interpreted off the TPU. ``sub``: the kernel's sub-block."""
+    with jax.named_scope("ssm.scan"):
+        if kernel is None:
+            kernel = on_tpu()
+        if kernel:
+            return kda_chunk_tpu(store, ordinal, q, k, v, g, beta, meta, Q=Q, mm=mm, sub=sub, interpret=not on_tpu())
+        return kda_chunk_twin(store, ordinal, q, k, v, g, beta, meta, Q=Q, mm=mm)
+
+
+def recurrence(state, q, k, v, g, beta):
+    """The definition, a token at a time, for ONE sequence: state (Hh, K, V);
+    q, k, g (T, Hh, K), v (T, Hh, V), beta (T, Hh). Returns (o (T, Hh, V), the
+    last state). The tests' oracle."""
+    def step(S, tok):
+        qt, kt, vt, gt, bt = tok
+        S = jnp.exp(gt)[..., None] * S
+        u = bt[:, None] * (vt - jnp.einsum("hkv,hk->hv", S, kt, precision=HIGHEST))
+        S = S + kt[..., None] * u[:, None, :]
+        return S, jnp.einsum("hkv,hk->hv", S, qt, precision=HIGHEST)
+
+    state, o = jax.lax.scan(step, state, (q, k, v, g, beta))
+    return o, state

@@ -75,11 +75,12 @@ def router_sigmoid(logits: jnp.ndarray, top_k: int, *, bias: jnp.ndarray, scale:
 
 
 def moe_dense(x: jnp.ndarray, router_logits: jnp.ndarray, top_k: int, expert_fn,
-              route=router_topk) -> jnp.ndarray:
+              route=router_topk, first: int = 0, held: int | None = None) -> jnp.ndarray:
     """Exact MoE: run all experts, combine by routing weight.
 
-    x: (N, H); router_logits: (N, E); expert_fn: (E, N, H) -> (E, N, H);
-    ``route``: the routing seam of ``moe_dropless``.
+    x: (N, H); router_logits: (N, E); expert_fn: (E', N, H) -> (E', N, H), the
+    E' = ``held`` experts from expert ``first`` on (all E but for a chip's
+    share, ``moe_dropless``); ``route``: the routing seam of ``moe_dropless``.
     """
     N, H = x.shape
     E = router_logits.shape[-1]
@@ -87,8 +88,9 @@ def moe_dense(x: jnp.ndarray, router_logits: jnp.ndarray, top_k: int, expert_fn,
     # Scatter top-k weights into a dense (N, E) combine matrix.
     combine = jnp.zeros((N, E), jnp.float32)
     combine = combine.at[jnp.arange(N)[:, None], idx].add(weights)
-    expert_out = expert_fn(jnp.broadcast_to(x, (E, N, H)))  # (E, N, H)
-    return jnp.einsum("ne,enh->nh", combine, expert_out.astype(jnp.float32)).astype(x.dtype)
+    held = E if held is None else held
+    expert_out = expert_fn(jnp.broadcast_to(x, (held, N, H)))  # (E', N, H)
+    return jnp.einsum("ne,enh->nh", combine[:, first:first + held], expert_out.astype(jnp.float32)).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -389,18 +391,21 @@ def combine(y, back, weights):
     return out[:N]
 
 
-def _experts_of_shard(x, weights, expert, wg, wu, wd, layer, *, act, kernel, ep, tp):
+def _experts_of_shard(x, weights, expert, wg, wu, wd, layer, *, act, kernel, ep, tp, total=None, first=0):
     """Everything after the router, for the experts (and, under ``tp``,
     the columns) this shard holds: lay the rows out, the two grouped
     matmuls, the combine per token. x (N, H), weights (N, k) and expert
     (N*k,) are whole on every shard; the stacks are the shard's own
     (L, E/ep, K, N). Returns (this shard's part of the output (N, H)
-    float32, rows each of ALL experts multiplied (E,))."""
+    float32, rows each of ALL experts multiplied (E,)). With no ``ep`` axis
+    the stacks may hold a chip's SHARE of ``total`` routed experts, from expert
+    ``first`` on (ISSUE 54): a pair whose expert is not held lies nowhere and
+    adds nothing, and the rows counted are the held experts' alone."""
     top_k = weights.shape[1]
     e_local = jax.tree.leaves(wg)[0].shape[1]
     with jax.named_scope("moe.dispatch"):
-        lay = dispatch(expert, top_k, e_local * (jax.lax.axis_size(ep) if ep else 1), e_local,
-                       jax.lax.axis_index(ep) * e_local if ep else 0)
+        e = e_local * jax.lax.axis_size(ep) if ep else (total or e_local)
+        lay = dispatch(expert, top_k, e, e_local, jax.lax.axis_index(ep) * e_local if ep else first)
         rows = x[lay.token]
     with jax.named_scope("moe.experts"):
         h = grouped_matmul(rows, (wg, wu), layer, lay.sizes, lay.plan, lay.row_expert, act=act, kernel=kernel)
@@ -412,6 +417,9 @@ def _experts_of_shard(x, weights, expert, wg, wu, wd, layer, *, act, kernel, ep,
         axes = tuple(a for a in (ep, tp) if a)
         if axes:
             out = jax.lax.psum(out, axes)
+    if not ep and e != e_local:   # a share: no one here multiplied the other experts' rows
+        at = jnp.arange(e, dtype=jnp.int32) - first
+        return out, jnp.where((at >= 0) & (at < e_local), lay.all_sizes, 0)
     return out, lay.all_sizes
 
 
@@ -441,6 +449,7 @@ def moe_dropless(
     mesh=None,  # the mesh the stacks are sharded over (experts on "ep", each split on "tp")
     kernel: bool | None = None,  # None: the Pallas kernel on a TPU
     route=router_topk,  # (logits, top_k) -> (weights (N, k) float32, idx (N, k)): who is chosen, and what each weighs
+    first: int = 0,  # the stacks hold a share of the E routed experts, from this one on (one device; ISSUE 54)
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Every chosen (token, expert) pair, computed once. Returns the
     layer's output (N, H) and how many rows each expert multiplied (E,):
@@ -456,7 +465,7 @@ def moe_dropless(
             expert = jnp.where(jnp.repeat(valid, top_k), expert, E)
     if mesh is None:
         out, rows = _experts_of_shard(x, weights, expert, wg, wu, wd, layer,
-                                      act=act, kernel=kernel, ep=None, tp=None)
+                                      act=act, kernel=kernel, ep=None, tp=None, total=E, first=first)
     else:
         from jax.sharding import PartitionSpec as P
 

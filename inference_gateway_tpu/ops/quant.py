@@ -158,6 +158,10 @@ QUANTIZABLE = ("wq", "wk", "wv", "wo", "wg", "wu", "wd",
                # Granite-4.0-H's Mamba-2 mixer (ISSUE 50): its two projections; the
                # taps, their bias, the norm and the heads' A_log, D and dt_bias stay as they are.
                "in_proj", "out_proj",
+               # Kimi-Linear's Kimi Delta Attention (ISSUE 54): the q/k/v and output
+               # projections and the decay's and the gate's low-rank pairs; beta's
+               # projection (H, heads), the taps, the norm, A_log and the biases stay as they are.
+               "kda_qkv", "kda_out", "kda_fa", "kda_fb", "kda_ga", "kda_gb",
                # Xing4.0's latent attention (ISSUE 47): the two bottlenecks' down and
                # up projections, in both stacks; the hyper-connections' maps stay float32.
                "wdq", "wuq", "wdkv", "wukv", "dense_wdq", "dense_wuq", "dense_wdkv", "dense_wukv",
@@ -257,6 +261,8 @@ def seeded_std(cfg, leaf: str) -> float:
                   "wv": cfg.hidden_size, "wo": cfg.num_heads * cfg.hd}.get(leaf)
         if fan_in:
             return fan_in ** -0.5
+    if getattr(cfg, "kda", False):
+        return _kimi_std(cfg, leaf)
     if getattr(cfg, "mamba2", False):
         return _granite_std(cfg, leaf)
     if getattr(cfg, "layer_conv", None) is not None:
@@ -502,6 +508,54 @@ def _granite_std(cfg, leaf: str) -> float:
             "shared_wd": out / 0.6 * (cfg.shared_experts * cfg.intermediate_size) ** -0.5}[leaf]
 
 
+def _kimi_std(cfg, leaf: str) -> float:
+    """``seeded_std`` for Kimi-Linear's block (ISSUE 54): Kimi Delta Attention
+    beside a latent attention with no rotary, one dense MLP in front, a sigmoid
+    router over ALL the experts with a chip's share of them held, a shared
+    expert, an untied head on an UN-normed stream. ``_xing_std``'s regime, each
+    scale per fan-in so that the test preset and the published 2,304 are one
+    regime; what matters is each block's SIZE against the stream it joins (it
+    ends near 3.8), and that each mechanism shows in a logit.
+
+    - the embedding at 1.0, the head at 1.25 x hidden ** -0.5 (``_xing_std``'s).
+    - Kimi Delta Attention: W_qkv at hidden ** -0.5 and the taps normal at K **
+      -0.5, so q, k and v are O(1) behind the SiLU, whose positive mean makes a
+      head's keys ALIKE (a cosine of ~0.1 beyond chance): the delta rule's
+      correction, S'^T k_t against v_t, is then of v_t's own size once a head
+      remembers a hundred tokens, and dropped it moves every output. The
+      decay's low-rank pair at hidden ** -0.5 and 0.5 x rank ** -0.5: a token
+      moves a channel's step by e^+-0.5 around ``kda_heads_init``'s, which
+      spreads a head's channels over [0.001, 0.1] x A, so one decay a head is
+      another model. beta's projection and the gate's pair at fan-in ** -0.5
+      (both sigmoids read N(0, 1)). What W_o reads is a normed head under a gate
+      of ~0.5: at 1.5 x P ** -0.5 the layer adds ~0.8, all of it read from the
+      STATE (a state lost shows whole).
+    - the latent attention: ``_xing_std``'s, with the queries at 2 x hidden **
+      -0.5: no YaRN doubles the scores here, and without position encoding a
+      head that weighs thousands of keys alike adds their mean (a spread of 2
+      rests it on a few dozen).
+    - the leading dense MLP's down projection at 4.4 x width ** -0.5 (RMS ~2, in
+      front of every router), the shared expert's at 2 x width ** -0.5 (~0.8).
+    - the router at hidden ** -0.5, the selection bias at 2 / experts; the
+      experts' down projection at 0.77 x width ** -0.5: the chosen eight weigh
+      0.31 each, four of them held here on average, so the held half's sum is
+      ~0.28 and the ABSENT half's the same: computed in place of left out it
+      moves the last stream by a fifth over seven layers (the control's mean,
+      0.2 nats, against a limit of 0.12), while one expert swapped by
+      bfloat16's rounding of a score at the cut moves it by a twenty-fifth
+      (between ``_xing_std``'s fortieth and the twentieth that read 0.43 there)."""
+    fan = cfg.hidden_size ** -0.5
+    rank = cfg.kda_rank or cfg.kda_head_dim
+    return {"embed": 1.0, "lm_head": 1.25 * fan, "router": fan, "router_bias": 2.0 / cfg.num_experts,
+            "kda_qkv": fan, "conv_w": cfg.conv_kernel ** -0.5, "kda_fa": fan, "kda_fb": 0.5 * rank ** -0.5,
+            "kda_b": fan, "kda_ga": fan, "kda_gb": rank ** -0.5, "kda_out": 1.5 * cfg.kda_inner ** -0.5,
+            "wq": 2.0 * fan, "wdkv": fan, "wukv": cfg.kv_lora_rank ** -0.5,
+            "wo": 1.5 * (cfg.num_heads * cfg.v_head_dim) ** -0.5,
+            "wg": fan, "wu": fan, "wd": 0.77 * cfg.intermediate_size ** -0.5,
+            "shared_wg": fan, "shared_wu": fan, "shared_wd": 2.0 * (cfg.shared_experts * cfg.intermediate_size) ** -0.5,
+            "dense_wg": fan, "dense_wu": fan, "dense_wd": 4.4 * cfg.dense_intermediate_size ** -0.5}[leaf]
+
+
 def seeded_qk_gain(cfg) -> float:
     """What a seeded build writes into ``q_norm`` and ``k_norm``: 1, and 1.4
     for a model with a sparse-attention indexer and for one whose sublayers'
@@ -596,6 +650,8 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
     if E:
         from inference_gateway_tpu.models import mixtral
 
+        Eh = cfg.held_experts   # the stacks hold a chip's share (ISSUE 54); the router is E wide
+
         # One key a leaf the older mixtures have, as they always drew them; the
         # leaves ISSUE 41 added draw from a stream of their own.
         named = {"wq": keys[1], "wk": keys[2], "wv": keys[3], "wo": keys[4]}
@@ -619,9 +675,9 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
                                       lambda name, shape: norm(next(more), shape, seeded_std(cfg, name))),
             "moe_norm": jnp.ones((L, H), dtype),
             "router": norm(ekeys[0], (L, H, E), seeded_std(cfg, "router")),
-            "wg": qfill(ekeys[1], (L, E, H, I), seeded_std(cfg, "wg")),
-            "wu": qfill(ekeys[2], (L, E, H, I), seeded_std(cfg, "wu")),
-            "wd": qfill(ekeys[3], (L, E, I, H), seeded_std(cfg, "wd")),
+            "wg": qfill(ekeys[1], (L, Eh, H, I), seeded_std(cfg, "wg")),
+            "wu": qfill(ekeys[2], (L, Eh, H, I), seeded_std(cfg, "wu")),
+            "wd": qfill(ekeys[3], (L, Eh, I, H), seeded_std(cfg, "wd")),
         }
         if cfg.indexer_topk:
             ikeys = jax.random.split(jax.random.fold_in(rng, 36), 3)

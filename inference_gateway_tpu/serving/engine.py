@@ -333,6 +333,10 @@ LATENT_STATS = ("latent_pages_read", "context_pages", "latent_row_pages_read")
 # one-token update read and wrote in each of them (a decode chunk's: summed
 # over its steps): two rows of the fetch block behind WINDOW_STATS.
 SSD_STATS = ("ssd_blocks", "state_rows")
+# The same two rows for a model with Kimi Delta Attention layers (ISSUE 54): the
+# blocks ``ops/kda.py``'s chunk kernel walked over all its state layers, and the
+# rows whose matrix state its one-token update read and wrote in each of them.
+KDA_STATS = ("kda_blocks", "state_rows")
 
 # Presets of models/phi4flash.py, by name: the module is imported when one
 # of them is asked for, and not on the way to any other model.
@@ -1003,7 +1007,8 @@ class Engine:
         self.counts_window = self.is_moe and mixtral.counts_window_pages(self.model_cfg)
         more_stats = ((DSA_STATS if self.is_dsa else ())
                       + ((LATENT_STATS if self.is_mla else WINDOW_STATS) if self.counts_window else ())
-                      + (SSD_STATS if getattr(self.model_cfg, "mamba2", False) else ()))
+                      + (SSD_STATS if getattr(self.model_cfg, "mamba2", False) else ())
+                      + (KDA_STATS if getattr(self.model_cfg, "kda", False) else ()))
         if self.is_hybrid:
             self._chunk_stats = self._mixed_stats = WINDOW_STATS
         else:
@@ -1672,9 +1677,9 @@ class Engine:
         cfg = self.model_cfg
         if self.is_hybrid:
             state_layers, kinds = cfg.state_layers, {"window": cfg.mid, "full": 1, "shared_readers": cfg.cross_layers}
-        else:  # a mixture with convolution layers (ISSUE 43) or Mamba-2 layers (ISSUE 50)
+        else:  # a mixture with convolution layers (ISSUE 43), Mamba-2 layers (ISSUE 50) or Kimi Delta Attention (ISSUE 54)
             state_layers = cfg.conv_layers
-            kinds = {"full": cfg.cache_layers, "mamba2" if cfg.mamba2 else "conv": state_layers}
+            kinds = {"full": cfg.cache_layers, "kda" if cfg.kda else "mamba2" if cfg.mamba2 else "conv": state_layers}
         out = {
             "state_store": {"slots": self.config.max_slots, "layers": state_layers,
                             "bytes": self.config.max_slots * cfg.state_bytes_per_slot},
@@ -1686,6 +1691,11 @@ class Engine:
             out["ssd"] = {"heads": cfg.ssm_heads, "state": [cfg.ssm_head_dim, cfg.ssm_state], "block": cfg.ssd_block,
                           "rows_of_more_than_one_token": "ssd_chunk_kernel" if on_tpu() else "ssd_chunk_twin",
                           "one_token_rows": "ssm_state_update_kernel" if on_tpu() else "state_update"}
+        if getattr(cfg, "kda", False):   # how the delta rule is run (ISSUE 54; static)
+            out["kda"] = {"heads": cfg.kda_heads, "state": [cfg.kda_head_dim, cfg.kda_head_dim], "block": cfg.kda_block,
+                          "decay": "a channel", "state_bytes_per_slot_per_layer": cfg.kda_inner * cfg.kda_head_dim * 4,
+                          "rows_of_more_than_one_token": "kda_chunk_kernel" if on_tpu() else "kda_chunk_twin",
+                          "one_token_rows": "kda_state_update_kernel" if on_tpu() else "state_update"}
         return out
 
     def _split_aux(self, aux):
@@ -1708,6 +1718,9 @@ class Engine:
             return None
         cfg = self.model_cfg
         out = {"router": {"score": cfg.router_score, "experts": cfg.num_experts,
+                          # (ISSUE 54) a chip's share: the router chooses among ``experts``, this
+                          # process computes ``experts_held`` of them and nothing in place of the rest
+                          "experts_held": cfg.held_experts, "experts_first": cfg.experts_first,
                           "experts_per_token": cfg.experts_per_token, "route_scale": cfg.route_scale,
                           "chosen_by": "score + bias" if cfg.router_score == "sigmoid" else "logit",
                           "weights": ("the chosen scores, normalised" if cfg.router_score == "sigmoid"
@@ -1729,7 +1742,7 @@ class Engine:
             "row": f"[{cfg.kv_lora_rank} latent, {cfg.qk_rope_head_dim} rotary key, "
                    f"{cfg.latent_row - cfg.latent_width} zeros to the 128-lane grid]",
             "bytes_per_token_per_layer": cfg.latent_row * itemsize,
-            "bytes_per_token": cfg.num_layers * cfg.latent_row * itemsize,
+            "bytes_per_token": cfg.cache_layers * cfg.latent_row * itemsize, "layers": cfg.cache_layers,
             "arrays": [self._model.MLA_POOL], "heads": cfg.num_heads, "value_dim": cfg.kv_lora_rank,
             "read": "absorbed: every head's query in the latent's space against one key row, "
                     "the row's first value_dim values the value, a page fetched once a visit",

@@ -91,8 +91,9 @@ def mixtral_param_count(cfg: mixtral.MixtralConfig) -> int:
     model has them), the expert layers' mixture (+ a shared expert), and a
     leading dense stack's MLPs (ISSUE 41: the two stacks are counted apart).
     With convolution layers (ISSUE 43) the token mixers are counted by kind:
-    the caching layers' attention, the other layers' gated short convolution
-    or Mamba-2 mixer (ISSUE 50).
+    the caching layers' attention, the other layers' gated short convolution,
+    Mamba-2 mixer (ISSUE 50) or Kimi Delta Attention (ISSUE 54). Of the experts,
+    those the stacks HOLD (a chip's share, ISSUE 54), under the whole router.
     The count of the built tree's leaves (a tied head once)."""
     H, I, V, E = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size, cfg.num_experts
     Hkv_D = cfg.num_kv_heads * cfg.hd
@@ -100,8 +101,9 @@ def mixtral_param_count(cfg: mixtral.MixtralConfig) -> int:
     attention = H * Hq_D + 2 * H * Hkv_D + Hq_D * H  # q, k, v, o
     if cfg.kv_lora_rank:  # the latent attention's five matrices and two norms (ISSUE 47)
         up = cfg.num_heads * (cfg.qk_nope_head_dim + cfg.v_head_dim)
-        attention = (H * cfg.q_lora_rank + cfg.q_lora_rank * Hq_D + H * cfg.latent_width + cfg.kv_lora_rank * up
-                     + cfg.num_heads * cfg.v_head_dim * H + cfg.q_lora_rank + cfg.kv_lora_rank)
+        queries = H * cfg.q_lora_rank + cfg.q_lora_rank * Hq_D + cfg.q_lora_rank if cfg.q_lora_rank else H * Hq_D
+        attention = (queries + H * cfg.latent_width + cfg.kv_lora_rank * up
+                     + cfg.num_heads * cfg.v_head_dim * H + cfg.kv_lora_rank)
     if cfg.hc_mult:  # both blocks' hyper-connection maps: phi, alpha, bias
         maps = 2 * cfg.hc_mult + cfg.hc_mult ** 2
         attention += 2 * (cfg.hc_mult * H * maps + 3 + maps)
@@ -115,9 +117,13 @@ def mixtral_param_count(cfg: mixtral.MixtralConfig) -> int:
     if cfg.mamba2:  # a Mamba-2 mixer (ISSUE 50): W_in, the taps and their bias, dt_bias / A_log / D, the norm, W_out
         Di, C = cfg.ssm_inner, cfg.conv_channels
         conv = H * (Di + C + cfg.ssm_heads) + (cfg.conv_kernel + 1) * C + 3 * cfg.ssm_heads + Di + Di * H
+    if cfg.kda:  # W_qkv, the taps, both low-rank pairs, beta's projection, dt_bias / A_log, the gate's bias, the norm, W_o
+        P, r = cfg.kda_inner, cfg.kda_rank or cfg.kda_head_dim
+        conv = (H * 3 * P + cfg.conv_kernel * 3 * P + 2 * (H * r + r * P) + H * cfg.kda_heads + P + cfg.kda_heads
+                + P + cfg.kda_head_dim + P * H)
     mixers = cfg.cache_layers * attention + cfg.conv_layers * conv + cfg.num_layers * 2 * H  # + both sublayers' input norms
     per_layer = (
-        E * 3 * H * I  # experts
+        cfg.held_experts * 3 * H * I  # experts
         + H * E  # router
         + cfg.shared_experts * 3 * H * I
         + (E if cfg.router_score == "sigmoid" else 0)  # the selection bias
@@ -178,7 +184,7 @@ def hbm_plan(profile: ServingProfile) -> dict:
     embed_params = cfg.vocab_size * cfg.hidden_size
     if is_moe:
         n_params = mixtral_param_count(cfg)
-        expert_params = cfg.expert_layers * cfg.num_experts * 3 * cfg.hidden_size * cfg.intermediate_size
+        expert_params = cfg.expert_layers * cfg.held_experts * 3 * cfg.hidden_size * cfg.intermediate_size
         dense_q_params = n_params - expert_params - embed_params
         weights_per_chip = int(
             embed_params * 2 // tp + dense_q_params * wbytes // tp

@@ -960,6 +960,9 @@ class SidecarServer:
             # With Mamba-2 layers (ISSUE 50) also ``ssd`` (heads, a head's state,
             # the scan's block, what serves a row of one token and of more), and
             # the `mixed` and `decode` records carry `ssd_blocks` and `state_rows`.
+            # With Kimi Delta Attention layers (ISSUE 54) ``kda`` likewise, the records
+            # `kda_blocks` and `state_rows`, `latent_pool` beside it, and `router`
+            # says `experts_held` of `experts`.
             **((state_status() if state_status else None) or {}),
             # A model with a sparse-attention indexer (ISSUE 36): its sizes and
             # how selection and read are done; absent otherwise. Its `decode`

@@ -11,8 +11,9 @@ are collected the same way, and PR 36's (``bench/tests/test_keye_files.py``),
 PR 41's (``bench/tests/test_trinity_files.py``), PR 43's
 (``bench/tests/test_lfm2_files.py``), PR 47's
 (``bench/tests/test_xing4_files.py``), PR 49's reader check
-(``bench/tests/test_post_warmup_compile_reader.py``) and PR 50's
-(``bench/tests/test_granite4_files.py``).
+(``bench/tests/test_post_warmup_compile_reader.py``), PR 50's
+(``bench/tests/test_granite4_files.py``) and PR 54's
+(``bench/tests/test_kimi_linear_files.py``).
 """
 
 import importlib.util
@@ -80,18 +81,26 @@ _spec.loader.exec_module(granite4_files)
 globals().update({name if name not in globals() else f"{name}_granite4": fn
                   for name, fn in vars(granite4_files).items() if name.startswith("test_")})
 
+_spec = importlib.util.spec_from_file_location("bench_test_kimi_linear_files", BENCH / "tests" / "test_kimi_linear_files.py")
+kimi_files = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(kimi_files)
+globals().update({name if name not in globals() else f"{name}_kimi": fn
+                  for name, fn in vars(kimi_files).items() if name.startswith("test_")})
+
 
 # What later PRs appended after PR 32's entries, by list and in order: PR 36 a
 # configuration, its cell and two readers, PR 38 the idle ledger's five readers,
 # PR 41 a configuration, its cell and two readers, PR 43 the same, PR 47 a
 # configuration, its cell and four readers, PR 49 one reader, PR 50 a
-# configuration, its cell and four readers, PR 52 the six by-scope readers. All
-# the cut below may drop.
+# configuration, its cell and four readers, PR 52 the six by-scope readers, PR 54
+# a configuration, its cell and four readers. All the cut below may drop.
 APPENDED_SINCE_PR_32 = {"configs": ["keye-vl2-30b-a3b-int8", "trinity-mini-int8", "lfm2-8b-a1b-int8",
-                                    "xing4.0-29b-a4b-int8", "granite-4.0-h-small-int8"],
+                                    "xing4.0-29b-a4b-int8", "granite-4.0-h-small-int8",
+                                    "kimi-linear-48b-a3b-int8"],
                         "workloads": ["keye-vl2-30b-a3b.long-context", "trinity-mini.long-context",
                                       "lfm2-8b-a1b.reason", "xing4.0-29b-a4b.long-context",
-                                      "granite-4.0-h-small.long-context"],
+                                      "granite-4.0-h-small.long-context",
+                                      "kimi-linear-48b-a3b.long-context"],
                         "per_layer": ["dsa_kv_read_share", "dsa_busy_share",
                                       "fetch_return_ms", "idle_unspanned_share", "starved_slot_share",
                                       "starved_delivery_share", "starved_unspanned_share",
@@ -103,7 +112,9 @@ APPENDED_SINCE_PR_32 = {"configs": ["keye-vl2-30b-a3b-int8", "trinity-mini-int8"
                                       "ssd_busy_share", "ssd_chunk_roofline_share",
                                       "ssd_state_hbm_roofline_share", "granite_mixed_step_mfu",
                                       "scope_attention_share", "scope_mlp_share", "scope_moe_share",
-                                      "scope_state_share", "scope_head_sampling_share", "scope_unscoped_share"]}
+                                      "scope_state_share", "scope_head_sampling_share", "scope_unscoped_share",
+                                      "kda_busy_share", "kda_chunk_roofline_share",
+                                      "kda_state_hbm_roofline_share", "kimi_mixed_step_mfu"]}
 
 
 def test_the_benchmark_gains_one_configuration_one_cell_and_three_readers(tmp_path, monkeypatch):
