@@ -1490,8 +1490,8 @@ PRESETS: dict[str, MixtralConfig] = {
         ssm_heads=128, ssm_head_dim=64, ssm_state=128, ssd_block=128, embedding_multiplier=12.0,
         attention_multiplier=0.0078125, residual_multiplier=0.22, logits_scaling=16.0),
     # The stage's own shape at toy widths: dense K, then K K M K K K M (five runs in
-    # eight layers), 4 heads of 32 with a block of 8 tokens in sub-blocks shorter
-    # than it, a latent of 32 beside 8 shared lanes, 8 experts top-2 of which this
+    # eight layers), 4 heads of 32 with a block of 8 tokens (halved at 4, 2 and
+    # 1), a latent of 32 beside 8 shared lanes, 8 experts top-2 of which this
     # "chip" holds the first 4, beside a shared one.
     "kimi-linear-test-tiny": _kimi(
         "kkkmkkkm", vocab_size=256, hidden_size=64, num_heads=4, num_kv_heads=4, head_dim=24, kv_lora_rank=32,

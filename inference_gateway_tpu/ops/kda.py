@@ -30,14 +30,18 @@ Two forms, as ``ops/ssd.py`` has them:
   In exact arithmetic that IS the recurrence whatever Q. The decay differs by
   channel, so L and A are not one product of two scaled operands: ``K e^{G}``
   against ``K e^{-G}`` overflows float32 once a channel has decayed by e^88
-  inside a block. A block is therefore cut into SUB-blocks of ``SUB`` tokens:
-  a row sub-block against the columns in front of it is a product of operands
-  scaled about the sub-block's first token (``e^{G_t - G_r}`` and ``e^{G_r -
-  G_s}``: both exponents <= 0), and inside a sub-block every pair is formed
-  alone, ``e^{G_t - G_s}`` a channel, and summed over the channels. No
-  exponent that is formed is positive. The unit triangular system is solved
-  by the product ``(I - L)(I + L^2)(I + L^4) ..`` (L is nilpotent: log2 Q
-  factors, every one a plain product).
+  inside a block. A block is therefore HALVED (ISSUE 55): the rows of its
+  second half against the columns of its first are ONE product of operands
+  scaled about the second half's first token r (``e^{G_t - G_r}`` and ``e^{G_r
+  - G_s}``: both exponents <= 0), and so again inside each half, down to
+  single tokens: log2 Q levels, ONE product at each (the columns' operand
+  against the rows' for L and for A side by side), masked to the level's
+  squares, which tile the strict lower triangle; A's diagonal is ``q_t .
+  k_t``. No exponent that is formed is positive and no pair is formed alone. The two exponents of a level are sums of g INSIDE a half
+  (``_halved``), never the difference of two block-long running sums, so a
+  pair's decay keeps float32's digits at any decay strength. The unit
+  triangular system is solved by the product ``(I - L)(I + L^2)(I + L^4) ..``
+  (L is nilpotent: log2 Q factors, every one a plain product).
 
 **How the blocks lie**: ``ops/ssd.block_plan``'s visits, unchanged. A row of
 more than one token visits every packed block it touches; the tokens of
@@ -49,11 +53,19 @@ On a TPU ``kda_chunk`` is the Pallas kernel ``kda_chunk_kernel``: a grid of
 row's visits, the store read and written in place by DMA at (layer ordinal,
 slot). A head's key width is the 128 lanes, so tokens lie in the sublanes and
 every operand is a plain blocked read. The products with the state and with
-U take operands rounded to the model's activation type (``mm``); the running
-sums, the decays, L, the solve, the accumulation and the state are float32
-(``ops/ssd.py``'s rule). Elsewhere ``kda_chunk`` is the same plan walked by
+U take operands rounded to the model's activation type (``mm``; A is
+rounded to it once formed); the sums of g, the decays, L and A (a level's
+one product is float32: as precise as a pair formed alone), the solve, the
+accumulation and the state are float32 (``ops/ssd.py``'s rule). The heads of
+a head block go a phase at a time (every head's L and A, the solve a step at
+a time over the heads, the outputs): a head's products wait on one another,
+and the other heads' fill the matrix unit meanwhile. That unit's time
+follows the ROWS it is fed, so whole registers of rows that are zero by
+construction stay out of the products: the second halves' columns at the
+levels of 8 tokens and wider, and the first rows of L's powers in the solve. Elsewhere ``kda_chunk`` is the same plan walked by
 ``lax.fori_loop`` in plain ``jax.numpy`` with every pair formed alone (the
-twin; tests hold the interpreted kernel to it and both to the recurrence).
+twin; tests hold the interpreted kernel, and its L and A themselves, to it
+and both to the recurrence).
 """
 
 from __future__ import annotations
@@ -69,7 +81,6 @@ from inference_gateway_tpu.ops.ssd import HIGHEST, META, _padded
 from inference_gateway_tpu.utils.platform import on_tpu
 
 HEAD_BLOCK = 4   # heads a grid step holds: their states are 4 x K x V float32 of VMEM (256 KB at 128 x 128)
-SUB = 16         # tokens of a sub-block: inside one, every pair's decay is formed alone
 
 
 def state_update(entry, q, k, v, g, beta, valid, fresh=None):
@@ -170,8 +181,62 @@ def _nt(a, b, **kw):
     return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32, **kw)
 
 
+def _under(top: int, low):
+    """``low`` under ``top`` rows of zeros (whole registers of them: a concatenation, no product)."""
+    return jnp.concatenate([jnp.zeros((top,) + low.shape[1:], low.dtype), low], axis=0) if top else low
+
+
+def _levels(Q: int):
+    """The halving of a block of Q tokens (a power of two), widest last: for h
+    in 1, 2, .., Q/2 the mask of level h's squares (the rows t of a second half
+    of h tokens against the columns s of the first half in front of it; over
+    the levels they tile the strict lower triangle), TURNED and twice side by
+    side, (s, [t | t]) of (Q, 2Q), as ``_halved`` forms L and A; and the (Q, 1)
+    mask of the tokens of second halves."""
+    tok = jax.lax.broadcasted_iota(jnp.int32, (Q, 1), 0)
+    s_of = jax.lax.broadcasted_iota(jnp.int32, (Q, 2 * Q), 0)
+    t_of = jax.lax.broadcasted_iota(jnp.int32, (Q, 2 * Q), 1) % Q
+    return [(h, (t_of // h == s_of // h + 1) & (t_of // h % 2 == 1), tok // h % 2 == 1)
+            for h in (1 << n for n in range(Q.bit_length() - 1))]
+
+
+def _halved(q, k, kb, g, levels):
+    """L and A of one head's block inside the kernel, no pair formed alone: q,
+    k, kb (= beta k), g (Q, K) float32 with foreign tokens at g = 0 and kb = 0.
+    Level h is ONE float32 product about each second half's first token r: the
+    columns' ``k_s e^{G_r - G_s}`` against the rows' ``kb_t e^{G_t - G_r}`` and
+    ``q_t e^{G_t - G_r}`` side by side (L and A turned, 2Q lanes wide: the
+    matrix unit's whole width). With b the first token of t's aligned run of h
+    tokens, d[t] = g_{b+1} + .. + g_t and e[t] = g_{t+1} + .. + g_{b+h} are
+    those two exponents at level h; d + e is the same all over a run, so the
+    next level's d and e are two sublane shifts of it, summed inside a half
+    and never a difference of two long sums. Returns (L strictly lower, A
+    lower, d = G - g_0, e = G_Q - G)."""
+    Q = q.shape[0]
+    tok = jax.lax.broadcasted_iota(jnp.int32, (Q, 1), 0)
+    d = jnp.zeros_like(g)
+    e = jnp.where(tok < Q - 1, pltpu.roll(g, Q - 1, 0), 0.0)                   # g of the token behind
+    turned = jnp.zeros((Q, 2 * Q), jnp.float32)
+    for h, squares, back in levels:
+        front, kc = jnp.exp(d), k * jnp.exp(e)
+        rows = jnp.concatenate([kb * front, q * front], axis=0)
+        if h % 8:
+            level = _nt(kc, rows, precision=HIGHEST)
+        else:   # whole registers: the first halves' columns alone are fed to the product, the others read zero
+            live = _nt(jnp.concatenate([kc[b:b + h] for b in range(0, Q, 2 * h)], axis=0), rows, precision=HIGHEST)
+            gap = jnp.zeros((h, 2 * Q), jnp.float32)
+            level = jnp.concatenate([part for n in range(0, Q // 2, h) for part in (live[n:n + h], gap)], axis=0)
+        turned = jnp.where(squares, level, turned)
+        run = d + e
+        d = d + jnp.where(back, pltpu.roll(run, h, 0), 0.0)
+        e = e + jnp.where(back, 0.0, pltpu.roll(run, Q - h, 0))
+    both = turned.T                                                            # (2Q, Q): L's rows, then A's
+    diagonal = tok == jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    return both[:Q], jnp.where(diagonal, jnp.sum(q * k, axis=1, keepdims=True), both[Q:]), d, e
+
+
 def _kda_chunk_kernel(ord_ref, meta_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref, store_in, o_ref, store_out,
-                      s_ref, sem, *, hb: int, Q: int, K: int, sub: int, mm):
+                      s_ref, sem, *, hb: int, Q: int, K: int, mm):
     h, j = pl.program_id(0), pl.program_id(1)
     word = lambda w: meta_ref[j * META + w]
     slot, lo, hi = word(1), word(2), word(3)
@@ -200,56 +265,38 @@ def _kda_chunk_kernel(ord_ref, meta_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref, st
     def _():
         tok = jax.lax.broadcasted_iota(jnp.int32, (Q, 1), 0)
         mine = (tok >= lo) & (tok < hi)                                        # (Q, 1)
-        t_of = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
-        s_of = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-        running = (s_of <= t_of).astype(jnp.float32)                           # G = running @ g
-        for i in range(hb):
-            lanes = slice(i * K, (i + 1) * K)
+        levels = _levels(Q)
+        heads = [slice(i * K, (i + 1) * K) for i in range(hb)]
+        # A phase at a time over the heads: a head's products wait on one another (eleven in the solve alone).
+        to_solve, to_write = [], []
+        for lanes in heads:
             g = jnp.where(mine, g_ref[:, lanes], 0.0)                          # (Q, K): a foreign token neither decays
             kb = jnp.where(mine, kb_ref[:, lanes], 0.0)                        # .. nor corrects (beta = 0)
             vb = jnp.where(mine, vb_ref[:, lanes], 0.0)
             k, q = k_ref[:, lanes], q_ref[:, lanes]
-            G = dot(running, g, exact)                                         # (Q, K), never rises
-            state = s_ref[i * K:(i + 1) * K, :]                                # (K, V)
-            sm = state.astype(mm)
+            Lm, Am, behind, ahead = _halved(q, k, kb, g, levels)
+            G = behind + g[0:1, :]                                             # (Q, K) the running sum, never rises
+            sm = s_ref[lanes, :].astype(mm)                                    # (K, V): a head's K rows lie as its K lanes do
             eG = jnp.exp(G)
-            rhs = vb - dot((kb * eG).astype(mm), sm, rounded)
-            from_state = dot((q * eG).astype(mm), sm, rounded)
-            l_rows, a_rows = [], []
-            for a in range(Q // sub):
-                rows = slice(a * sub, (a + 1) * sub)
-                ref = G[a * sub:a * sub + 1, :]                                # the sub-block's first token
-                d_row = jnp.exp(jnp.minimum(G[rows] - ref, 0.0))
-                kc = k * jnp.exp(jnp.minimum(ref - G, 0.0))                    # right for the columns in front of it
-                l_sub = _nt(kb[rows] * d_row, kc, **exact)                     # (sub, Q)
-                a_sub = _nt((q[rows] * d_row).astype(mm), kc.astype(mm), **rounded)
-                col = jax.lax.broadcasted_iota(jnp.int32, (sub, Q), 1)
-                in_front = col < a * sub
-                l_sub, a_sub = jnp.where(in_front, l_sub, 0.0), jnp.where(in_front, a_sub, 0.0)
-                for c in range(sub):                                           # the sub-block's own columns, pair by pair
-                    s_tok = a * sub + c
-                    w = jnp.exp(jnp.minimum(G[rows] - G[s_tok:s_tok + 1, :], 0.0)) * k[s_tok:s_tok + 1, :]
-                    l_sub = jnp.where(col == s_tok, jnp.sum(kb[rows] * w, axis=1, keepdims=True), l_sub)
-                    a_sub = jnp.where(col == s_tok, jnp.sum(q[rows] * w, axis=1, keepdims=True), a_sub)
-                l_rows.append(l_sub)
-                a_rows.append(a_sub)
-            Lm = jnp.where(s_of < t_of, jnp.concatenate(l_rows, axis=0), 0.0)   # strictly lower
-            Am = jnp.where(s_of <= t_of, jnp.concatenate(a_rows, axis=0), 0.0)
-            # (I + L)^-1 = (I - L)(I + L^2)(I + L^4) ..: L^Q = 0.
-            u = rhs - dot(Lm, rhs, exact)
-            power, span = Lm, 2
-            while span < Q:
-                power = dot(power, power, exact)
-                u = u + dot(power, u, exact)
-                span *= 2
+            to_solve.append((Lm, vb - dot((kb * eG).astype(mm), sm, rounded)))
+            to_write.append((Am, dot((q * eG).astype(mm), sm, rounded), ahead, G[Q - 1:Q, :]))
+        # (I + L)^-1 = (I - L)(I + L^2)(I + L^4) ..: L^Q = 0.
+        powers = [Lm for Lm, _ in to_solve]
+        us = [rhs - dot(Lm, rhs, exact) for Lm, rhs in to_solve]
+        span = 2
+        while span < Q:
+            top = span // 8 * 8   # L^span's first span rows are zero: whole registers of them are fed to neither product
+            powers = [_under(top, dot(p[top:], p, exact)) for p in powers]
+            us = [u + _under(top, dot(p[top:], u, exact)) for p, u in zip(powers, us)]
+            span *= 2
+        for lanes, (Am, from_state, ahead, end), u in zip(heads, to_write, us):
             um = u.astype(mm)
             out = from_state + dot(Am.astype(mm), um, rounded)
             o_ref[:, lanes] = jnp.where(mine, out, o_ref[:, lanes])
-            end = G[Q - 1:Q, :]                                                # (1, K)
-            carry = (k * jnp.exp(jnp.minimum(end - G, 0.0))).astype(mm)        # (Q, K)
+            carry = (k_ref[:, lanes] * jnp.exp(ahead)).astype(mm)              # (Q, K): k e^{G_Q - G}
             grown = jax.lax.dot_general(carry, um, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
                                         **rounded)                              # (K, V)
-            s_ref[i * K:(i + 1) * K, :] = jnp.broadcast_to(jnp.exp(end), (K, K)).T * state + grown
+            s_ref[lanes, :] = jnp.broadcast_to(jnp.exp(end), (K, K)).T * s_ref[lanes, :] + grown
 
     @pl.when(live & last)
     def _():
@@ -258,10 +305,12 @@ def _kda_chunk_kernel(ord_ref, meta_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref, st
         save.wait()
 
 
-@functools.partial(jax.jit, static_argnames=("Q", "mm", "sub", "interpret"))
-def kda_chunk_tpu(store, ordinal, q, k, v, g, beta, meta, *, Q: int, mm=jnp.float32, sub: int = SUB,
-                  interpret: bool = False):
-    """``kda_chunk`` by the Pallas kernel. The store is updated in place."""
+@functools.partial(jax.jit, static_argnames=("Q", "mm", "interpret"))
+def kda_chunk_tpu(store, ordinal, q, k, v, g, beta, meta, *, Q: int, mm=jnp.float32, interpret: bool = False):
+    """``kda_chunk`` by the Pallas kernel. The store is updated in place. Q is
+    a power of two: a block is halved down to single tokens."""
+    if Q & (Q - 1):
+        raise ValueError(f"a block of {Q} tokens does not halve down to single tokens")
     T, Hh, K = q.shape
     V = meta.shape[0]
     hb = HEAD_BLOCK if Hh % HEAD_BLOCK == 0 else Hh
@@ -271,7 +320,7 @@ def kda_chunk_tpu(store, ordinal, q, k, v, g, beta, meta, *, Q: int, mm=jnp.floa
     block = pl.BlockSpec((Q, hb * K), at(lambda h, j, m: (m[j * META], h)))
     rows = store.reshape(store.shape[:2] + (Hh * K, K))                        # a head's K rows under one another
     o, rows = pl.pallas_call(
-        functools.partial(_kda_chunk_kernel, hb=hb, Q=Q, K=K, sub=min(sub, Q), mm=mm),
+        functools.partial(_kda_chunk_kernel, hb=hb, Q=Q, K=K, mm=mm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(Hh // hb, V),
             in_specs=[block, block, block, block, block, pl.BlockSpec(memory_space=pltpu.ANY)],
@@ -287,19 +336,27 @@ def kda_chunk_tpu(store, ordinal, q, k, v, g, beta, meta, *, Q: int, mm=jnp.floa
     return o[:T].reshape(T, Hh, K), rows.reshape(store.shape)
 
 
-def _block(state, q, k, v, g, beta, mm):
-    """One block of one row, every head at once, every pair formed alone:
-    state (Hh, K, V); q, k, v, g (Q, Hh, K); beta (Q, Hh), foreign tokens at g =
-    0 and beta = 0. Returns (o (Q, Hh, V), the state after the block)."""
-    es = functools.partial(jnp.einsum, precision=HIGHEST)
-    rounded = lambda a: a.astype(mm).astype(jnp.float32)
+def _pairs_alone(q, k, kb, g):
+    """L and A of one block, every head at once, every pair formed alone: q, k,
+    kb (= beta k), g (Q, Hh, K). Returns (L, A (t, s, Hh); G (Q, Hh, K))."""
     Q = q.shape[0]
     G = jnp.cumsum(g, axis=0)
     pair = jnp.exp(jnp.minimum(G[:, None] - G[None, :], 0.0))                  # (t, s, Hh, K)
     t_of, s_of = jnp.arange(Q)[:, None], jnp.arange(Q)[None, :]
-    kb = k * beta[..., None]
     Lm = jnp.where((s_of < t_of)[..., None], jnp.sum(kb[:, None] * k[None, :] * pair, axis=-1), 0.0)   # (t, s, Hh)
     Am = jnp.where((s_of <= t_of)[..., None], jnp.sum(q[:, None] * k[None, :] * pair, axis=-1), 0.0)
+    return Lm, Am, G
+
+
+def _block(state, q, k, v, g, beta, mm):
+    """One block of one row, every head at once (``_pairs_alone``): state (Hh,
+    K, V); q, k, v, g (Q, Hh, K); beta (Q, Hh), foreign tokens at g = 0 and
+    beta = 0. Returns (o (Q, Hh, V), the state after the block)."""
+    es = functools.partial(jnp.einsum, precision=HIGHEST)
+    rounded = lambda a: a.astype(mm).astype(jnp.float32)
+    Q = q.shape[0]
+    kb = k * beta[..., None]
+    Lm, Am, G = _pairs_alone(q, k, kb, g)
     eG, sm = jnp.exp(G), rounded(state)
     rhs = v * beta[..., None] - es("thk,hkv->thv", rounded(kb * eG), sm)
     unit = jnp.eye(Q)[..., None] + Lm
@@ -338,8 +395,7 @@ def kda_chunk_twin(store, ordinal, q, k, v, g, beta, meta, *, Q: int, mm=jnp.flo
     return o[:T], jax.lax.dynamic_update_index_in_dim(store, entry, ordinal, 0)
 
 
-def kda_chunk(store, ordinal, q, k, v, g, beta, meta, *, Q: int, mm=jnp.float32, kernel: bool | None = None,
-              sub: int = SUB):
+def kda_chunk(store, ordinal, q, k, v, g, beta, meta, *, Q: int, mm=jnp.float32, kernel: bool | None = None):
     """The rows of more than one token of a mixed step, blocked. store (state
     layers, slots, Hh, K, V) float32, ``ordinal`` the layer's entry; q, k, g
     (T, Hh, K) and v (T, Hh, V) the packed tokens' operands (q, k normalised by
@@ -350,12 +406,12 @@ def kda_chunk(store, ordinal, q, k, v, g, beta, meta, *, Q: int, mm=jnp.float32,
     the type the operands of the products with the state and with U are
     rounded to; sums, decays, the solve and the state are float32 either way.
     ``kernel`` None: the Pallas kernel on a TPU, the twin elsewhere; tests
-    force the kernel, interpreted off the TPU. ``sub``: the kernel's sub-block."""
+    force the kernel, interpreted off the TPU."""
     with jax.named_scope("ssm.scan"):
         if kernel is None:
             kernel = on_tpu()
         if kernel:
-            return kda_chunk_tpu(store, ordinal, q, k, v, g, beta, meta, Q=Q, mm=mm, sub=sub, interpret=not on_tpu())
+            return kda_chunk_tpu(store, ordinal, q, k, v, g, beta, meta, Q=Q, mm=mm, interpret=not on_tpu())
         return kda_chunk_twin(store, ordinal, q, k, v, g, beta, meta, Q=Q, mm=mm)
 
 

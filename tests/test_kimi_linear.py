@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
 
 from inference_gateway_tpu.models import mixtral
 from inference_gateway_tpu.ops import kda, ssd
@@ -129,24 +130,27 @@ def engine_page_cfg():
 # ---------------------------------------------------------------------------
 # ops/kda.py against the token recurrence
 # ---------------------------------------------------------------------------
-LAYERS, SLOTS, HH, D, Q = 2, 5, 2, 32, 16   # blocks of 16 tokens in sub-blocks of 16 or (SUB) 8
+LAYERS, SLOTS, HH, D, Q = 2, 5, 2, 32, 16   # blocks of 16 tokens, halved at 8, 4, 2 and 1
 
 
-def operands(T, seed, strength):
+def operands(T, seed, strength, strong_from=0):
     """q, k normalised as the mixer does; log decays spread over two decades a
-    head, times ``strength``: at 40 a channel forgets by e^-100 and more a token,
-    and e^{-G} over a block overflows float32 many times over."""
+    head, times ``strength`` at the tokens ``strong_from`` and beyond of every
+    run of 8 (a half of a block of 16) and times 1 in front of them: at 40 a
+    channel forgets by e^-100 and more a token, and e^{-G} over a block
+    overflows float32 many times over."""
     rng = np.random.default_rng(seed)
     unit = lambda a: a / np.linalg.norm(a, axis=-1, keepdims=True)
     q = (unit(rng.normal(size=(T, HH, D))) * D ** -0.5).astype(np.float32)
     k = unit(rng.normal(size=(T, HH, D))).astype(np.float32)
     v = rng.normal(size=(T, HH, D)).astype(np.float32)
-    g = (-np.exp(rng.normal(size=(T, HH, D)) * 1.5 - 2.0) * strength).astype(np.float32)
+    strong = np.where(np.arange(T) % 8 >= strong_from, strength, 1.0)[:, None, None]
+    g = (-np.exp(rng.normal(size=(T, HH, D)) * 1.5 - 2.0) * strong).astype(np.float32)
     beta = (1 / (1 + np.exp(-rng.normal(size=(T, HH))))).astype(np.float32)
     return q, k, v, g, beta
 
 
-def chunk_case(lens, starts, kernel, strength=1.0, T=64, seed=0, sub=kda.SUB):
+def chunk_case(lens, starts, kernel, strength=1.0, T=64, seed=0, strong_from=0, block=Q):
     """Rows of ``lens`` tokens packed back to back in slot order, row r from
     position ``starts[r]``, through ``kda_chunk`` on entry 1 of a store of random
     states. Returns (blocks walked, the largest difference from the token
@@ -154,10 +158,10 @@ def chunk_case(lens, starts, kernel, strength=1.0, T=64, seed=0, sub=kda.SUB):
     asserts that every other slot and the other entry are untouched."""
     q_lens, position = np.asarray(lens, np.int32), np.asarray(starts, np.int32)
     q_starts = np.concatenate([[0], np.cumsum(q_lens)[:-1]]).astype(np.int32)
-    ops = operands(T, seed, strength)
+    ops = operands(T, seed, strength, strong_from)
     store = np.random.default_rng(seed + 1).normal(size=(LAYERS, SLOTS, HH, D, D)).astype(np.float32)
-    meta, blocks = ssd.block_plan(jnp.asarray(q_starts), jnp.asarray(q_lens), jnp.asarray(position + q_lens), T, Q)
-    o, new = kda.kda_chunk(jnp.asarray(store), 1, *map(jnp.asarray, ops), meta, Q=Q, kernel=kernel, sub=sub)
+    meta, blocks = ssd.block_plan(jnp.asarray(q_starts), jnp.asarray(q_lens), jnp.asarray(position + q_lens), T, block)
+    o, new = kda.kda_chunk(jnp.asarray(store), 1, *map(jnp.asarray, ops), meta, Q=block, kernel=kernel)
     worst = 0.0
     for r in range(SLOTS):
         if q_lens[r] <= 1:
@@ -173,13 +177,15 @@ def chunk_case(lens, starts, kernel, strength=1.0, T=64, seed=0, sub=kda.SUB):
 
 
 # (lengths a slot, positions a slot, the blocks of 16 the rows touch): lengths 1, Q - 1, Q, Q + 1 and beyond
-# with and without a carried state; decode rows in front of one long chunk; no row at all; rows of two.
+# with and without a carried state; decode rows in front of one long chunk; no row at all; rows of two; rows that
+# begin and end inside one half of a block (of 8, and of 4) beside foreign rows: identity steps at every level.
 RAGGED = {
     "one_to_two_blocks_and_three": ([1, 15, 16, 17, 14], [5, 0, 3, 0, 11], 5),
     "the_same_all_carried": ([1, 15, 16, 17, 14], [5, 2, 3, 9, 11], 5),
     "decode_rows_then_a_chunk": ([1, 1, 1, 0, 50], [5, 2, 3, 0, 11], 4),
     "no_row": ([0, 0, 0, 0, 0], [5, 2, 3, 0, 11], 0),
     "rows_of_two": ([2, 2, 2, 2, 2], [0, 2, 3, 0, 11], 5),
+    "rows_inside_one_half": ([1, 5, 3, 3, 9], [5, 2, 0, 3, 11], 5),
 }
 
 
@@ -194,17 +200,65 @@ def test_the_chunk_form_is_the_token_recurrence_for_ragged_rows(case, kernel):
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["twin", "interpreted_kernel"])
-@pytest.mark.parametrize("sub", [16, 8], ids=["one_sub_block", "two_sub_blocks"])
-def test_decays_whose_inverse_overflows_float32_stay_finite_and_right(kernel, sub):
+def test_blocks_of_64_tokens_as_the_cell_walks_them(kernel):
+    """The published block: six levels, of which those of 8, 16 and 32 tokens
+    feed only the first halves' columns to their product, and a solve whose
+    powers of 8, 16 and 32 leave their zero rows out."""
+    blocks, worst = chunk_case([1, 150, 1, 40, 2], [5, 7, 3, 0, 11], kernel, T=200, block=64)
+    assert blocks == 3 + 1 + 1 and worst < 2e-5
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["twin", "interpreted_kernel"])
+@pytest.mark.parametrize("strong_from", [0, 1, 2, 4, 7], ids=lambda n: f"strong_from_token_{n}_of_a_half")
+def test_decays_whose_inverse_overflows_float32_stay_finite_and_right(kernel, strong_from):
     """Log decays of -100 and more a token in many channels: e^{-G} over a block
     is inf in float32, so a form that divides by the running decay reads nan.
-    Every exponent this form takes is <= 0: between sub-blocks about the
-    sub-block's first token, inside one a pair at a time."""
-    _, worst = chunk_case([1, 15, 16, 17, 14], [5, 2, 3, 0, 11], kernel, strength=40.0, sub=sub)
+    Every exponent the halving takes is <= 0: level h's rows about their half's
+    first token, its columns up to it. The strong decays begin at token 0, 1,
+    2, 4 or 7 of every run of 8, behind weak ones: at one level they fall all
+    into the rows' exponent, at another all into the columns', at a third they
+    straddle the boundary."""
+    _, worst = chunk_case([1, 15, 16, 17, 14], [5, 2, 3, 0, 11], kernel, strength=40.0, strong_from=strong_from)
     assert worst < 2e-5
-    g = operands(64, 0, 40.0)[3]
+    g = operands(64, 0, 40.0, strong_from)[3]
     with np.errstate(over="ignore"):
         assert not np.isfinite(np.exp(-np.cumsum(g[:16], axis=0), dtype=np.float32)).all()
+
+
+def halved_alone(q, k, kb, g):
+    """``kda._halved`` on one head's block, in a kernel of its own (interpreted): (L, A)."""
+    n = q.shape[0]
+
+    def body(q_ref, k_ref, kb_ref, g_ref, l_ref, a_ref):
+        l_ref[...], a_ref[...], _, _ = kda._halved(q_ref[...], k_ref[...], kb_ref[...], g_ref[...], kda._levels(n))
+
+    square = jax.ShapeDtypeStruct((n, n), jnp.float32)
+    return pl.pallas_call(body, out_shape=[square, square], interpret=True)(q, k, kb, g)
+
+
+@pytest.mark.parametrize("strength", [1.0, 40.0, 400.0])
+@pytest.mark.parametrize("block", [16, 64])
+def test_l_and_a_by_halving_are_the_pairs_formed_alone(block, strength):
+    """The kernel's L and A themselves, a head's block with foreign tokens in
+    it (g = 0, beta = 0), against the twin's matrices (every pair alone, float32:
+    the tolerance that stands) and against the same pairs in float64. The
+    halving sums g inside a half, so it stays at float32's rounding of ONE
+    pair (measured 3e-8 to 7e-8) where the twin's difference of two block-long
+    sums reads 1e-6 to 6e-6 at the strong decays."""
+    q, k, _, g, beta = (a[:block, 0] for a in operands(block, 3, strength))
+    mine = (np.arange(block) >= 3) & (np.arange(block) < block - 2)
+    g, beta = np.where(mine[:, None], g, 0.0).astype(np.float32), np.where(mine, beta, 0.0).astype(np.float32)
+    kb = k * beta[:, None]
+    got_l, got_a = map(np.asarray, halved_alone(*map(jnp.asarray, (q, k, kb, g))))
+    twin_l, twin_a, _ = kda._pairs_alone(*(jnp.asarray(a)[:, None] for a in (q, k, kb, g)))
+    G = np.cumsum(g.astype(np.float64), axis=0)
+    lower = np.tri(block, k=-1)[..., None]
+    pair = np.exp(np.minimum(G[:, None] - G[None, :], 0.0)) * k[None, :].astype(np.float64)
+    want_l = (lower * kb[:, None] * pair).sum(-1)
+    want_a = ((lower + np.eye(block)[..., None]) * q[:, None] * pair).sum(-1)
+    assert np.abs(got_l - np.asarray(twin_l[..., 0])).max() < 2e-5 and np.abs(got_a - np.asarray(twin_a[..., 0])).max() < 2e-5
+    assert np.abs(got_l - want_l).max() < 5e-7 and np.abs(got_a - want_a).max() < 5e-7
+    assert not got_l[np.triu_indices(block)].any() and not got_a[np.triu_indices(block, 1)].any()
 
 
 def test_bfloat16_operands_round_the_products_and_nothing_that_is_carried():
