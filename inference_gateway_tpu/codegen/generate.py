@@ -121,6 +121,15 @@ _SERVING_RAGGED_DOC = [
     "`gather` means the ~10.6×-slower GSPMD fallback is live (off-TPU",
     "only, post-ISSUE-12). Design: [docs/performance.md](docs/performance.md).",
     "",
+    "A latent-family preset whose `num_nextn_predict_layers` is 1",
+    "(`openpangu-ultra-moe-718b-stage7`) drafts for itself (ISSUE 56): its own",
+    "next-token module drafts one token a row a round, and the mixed step and",
+    "the decode chunk verify it as the row's second query and emit one or two",
+    "tokens. No variable and no flag turns this on or off, `--spec-draft` is",
+    "refused by name on such a preset, and `SERVING_DECODE_CHUNK` then counts",
+    "ROUNDS. `/debug/status.mtp` and the `spec_*` counters say what the drafts",
+    "came to ([docs/observability.md](docs/observability.md)).",
+    "",
 ]
 
 

@@ -972,10 +972,18 @@ def forward_ragged_impl(
     prefix=None,  # the leading group of forward_paged_impl
     kinds=None,  # the per-layer kind of forward_paged_impl
     residual=None,  # the residual seam of forward_paged_impl
+    hidden: bool = False,  # the last block's stream of EVERY packed token in place of the rows' logits
+    tok_valid=None,  # (T,) which packed tokens are real, where the rows are not packed back to back from 0
 ):
     """Shared ragged skeleton, same flat-carry cache discipline as
     forward_paged_impl (the scatter lowers to an in-place row update;
-    attention reads pages straight out of the big buffer)."""
+    attention reads pages straight out of the big buffer).
+
+    ``hidden`` (ISSUE 56): the first result is the stream behind the last block,
+    (T, hidden_size), BEFORE the final norm: a model that drafts with its own
+    next-token module reads it there, and takes logits at the queries it
+    chooses. The pool may hold more planes than the layers walked here (the
+    module's own, behind them)."""
     from inference_gateway_tpu.ops.paged_attention import ragged_paged_attention
 
     B, T = tokens.shape  # B == 1: the packed axis IS the batch
@@ -993,7 +1001,8 @@ def forward_ragged_impl(
         inv_freq = rope_inv_freq(cfg.hd, cfg.rope_theta, cfg.rope_scaling_dict)
         cos, sin = rope_cos_sin(positions, inv_freq)
         # Rows are packed back to back from 0: the tail past them is padding.
-        tok_valid = jnp.arange(T) < jnp.sum(q_lens)
+        if tok_valid is None:
+            tok_valid = jnp.arange(T) < jnp.sum(q_lens)
 
     assert mixer is None or kinds is None or own, "an attention seam beside a per-layer kind: only one that owns the pool"
     extra = tuple(kinds["cache"]) if kinds is not None else tuple(mixer["cache"]) if mixer is not None else ()
@@ -1068,6 +1077,9 @@ def forward_ragged_impl(
                      "v": cv.reshape(L, P, page_size, HkvD),
                      **{n: a.reshape(cache[n].shape) for n, a in zip(extra, ce)}}
 
+    if hidden:
+        assert residual is None, "the stream handed out is the one stream of hidden_size"
+        return (x[0], new_cache, aux) if with_aux else (x[0], new_cache)
     with jax.named_scope("head"):
         if residual is not None:
             x = residual["exit"](x)

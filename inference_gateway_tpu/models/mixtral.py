@@ -162,6 +162,19 @@ class MixtralConfig(LlamaConfig):
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_clamp: tuple = (-30.0, 30.0)
+    # openPangu-Ultra-MoE's next-token module (ISSUE 56; ``num_nextn_predict_layers``;
+    # the DeepSeek-V3 report's section 2.2). 0: none. 1: behind the last layer one
+    # further block of the expert layers' kind (leaves ``params["mtp"]``), its own
+    # plane of the latent pool behind the layers' (``cache_layers`` counts it). For
+    # a position i whose next token is known it reads W_eh [norm_e(Emb(t_{i+1}));
+    # norm_h(h_i)], h_i the last block's stream BEFORE the final norm, and gives,
+    # through its own final norm and the model's head, logits for t_{i+2}: the
+    # draft a serving round verifies as a second query of the row (``mtp_ragged``).
+    mtp_layers: int = 0
+    # A chip's share of the vocabulary (ISSUE 56): ``vocab_size`` rows of the
+    # embedding and columns of the head are HELD, the first of vocab_published
+    # (0: all of them); ids, logits, sampling and logit_bias run over the slice.
+    vocab_published: int = 0
 
     def __post_init__(self):
         super().__post_init__()
@@ -190,16 +203,19 @@ class MixtralConfig(LlamaConfig):
         if self.kv_lora_rank:
             if self.hd != self.qk_nope_head_dim + self.qk_rope_head_dim or not self.v_head_dim:
                 raise ValueError("kv_lora_rank: head_dim is qk_nope_head_dim + qk_rope_head_dim, values of v_head_dim")
-            if (self.indexer_topk or self.sliding_window or self.qk_norm or self.attn_gate or self.sandwich_norm
-                    or self.qkv_bias):
-                raise ValueError("kv_lora_rank: the latent attention carries no indexer, window, q/k norm, gate, "
-                                 "post-norm or bias")
+            if self.indexer_topk or self.sliding_window or self.qk_norm or self.attn_gate or self.qkv_bias:
+                raise ValueError("kv_lora_rank: the latent attention carries no indexer, window, q/k norm, gate "
+                                 "or bias")
             if self.layer_rope is not None and any(self.layer_rope):
                 raise ValueError("kv_lora_rank: the shared lanes are rotated in every layer (layer_rope None) or in "
                                  "none (all 0)")
             if self.layer_conv is not None and (not self.scans_runs or self.hc_mult):
                 raise ValueError("kv_lora_rank beside a per-layer kind: the state layers are scanned in runs "
                                  "(Mamba-2 or Kimi Delta Attention), one residual stream")
+        if self.mtp_layers not in (0, 1) or (self.mtp_layers and (
+                not self.kv_lora_rank or self.layer_conv is not None or self.hc_mult or self.tie_word_embeddings)):
+            raise ValueError("mtp_layers: ONE next-token module, behind a latent attention's layers of one kind, one "
+                             "residual stream, an untied head")
         if self.experts_held and not (0 < self.experts_held <= self.num_experts - self.experts_first
                                       and self.experts_first >= 0):
             raise ValueError(f"experts_held {self.experts_held} from {self.experts_first} of {self.num_experts} experts")
@@ -255,8 +271,9 @@ class MixtralConfig(LlamaConfig):
 
     @property
     def cache_layers(self) -> int:
-        """Layers that write the page pool: its leading axis."""
-        return self.num_layers - self.conv_layers
+        """Layers that write the page pool, its leading axis: the next-token
+        module's block is one of them, behind the model's own."""
+        return self.num_layers - self.conv_layers + self.mtp_layers
 
     @property
     def latent_width(self) -> int:
@@ -301,10 +318,15 @@ def attention_leaves(cfg: MixtralConfig, count: int, dtype, matrix) -> Params:
         queries = ({"wdq": matrix("wdq", (count, H, cfg.q_lora_rank)), "q_a_norm": ones(cfg.q_lora_rank),
                     "wuq": matrix("wuq", (count, cfg.q_lora_rank, Hq * D))} if cfg.q_lora_rank
                    else {"wq": matrix("wq", (count, H, Hq * D))})   # (ISSUE 54: no bottleneck)
+        # (ISSUE 56) under ``sandwich_norm`` both sublayers' outputs are normed: gains
+        # that are NOT 1 (``seeded_std``), so that a norm dropped is another model.
+        post = ({"post_attn_norm": jnp.full((count, H), seeded_std(cfg, "post_attn_norm"), dtype),
+                 "post_ffn_norm": jnp.full((count, H), seeded_std(cfg, "post_ffn_norm"), dtype)}
+                if cfg.sandwich_norm else {})
         return {"attn_norm": ones(H), **queries,
                 "wdkv": matrix("wdkv", (count, H, cfg.latent_width)), "kv_a_norm": ones(cfg.kv_lora_rank),
                 "wukv": matrix("wukv", (count, cfg.kv_lora_rank, Hq * (cfg.qk_nope_head_dim + cfg.v_head_dim))),
-                "wo": matrix("wo", (count, Hq * cfg.v_head_dim, H))}
+                "wo": matrix("wo", (count, Hq * cfg.v_head_dim, H)), **post}
     out: Params = {
         "attn_norm": ones(H),
         "wq": matrix("wq", (count, H, Hq * D)), "wk": matrix("wk", (count, H, Hkv * D)),
@@ -435,8 +457,12 @@ def kda_heads_init(layers: int, heads: int, head_dim: int) -> Params:
     return {"A_log": lay(jnp.log(1.0 + 15.0 * u)), "dt_bias": lay(dt + jnp.log(-jnp.expm1(-dt)))}
 
 
-def init_params(rng: jax.Array, cfg: MixtralConfig, dtype=jnp.bfloat16) -> Params:
-    L, H, I, V, E = cfg.expert_layers, cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size, cfg.num_experts
+def _init_layers(rng: jax.Array, cfg: MixtralConfig, L: int, dtype, lead: bool = True) -> tuple[Params, dict]:
+    """A stack of ``L`` expert layers drawn from ``rng`` (with ``lead``, the
+    leading dense layers' stack beside it), and the keys the tree's other
+    leaves draw from: what ``init_params`` always drew for the model's own
+    layers, and with another key the next-token module's one block (ISSUE 56)."""
+    H, I, E = cfg.hidden_size, cfg.intermediate_size, cfg.num_experts
     Eh = cfg.held_experts   # the stacks hold a chip's share; the router is E wide
     # One key a leaf the older mixtures have, as they always drew them; the
     # leaves ISSUE 41 added draw from a stream of their own.
@@ -481,15 +507,33 @@ def init_params(rng: jax.Array, cfg: MixtralConfig, dtype=jnp.bfloat16) -> Param
         Is = cfg.shared_experts * I
         layers.update(shared_wg=matrix("shared_wg", (L, H, Is)), shared_wu=matrix("shared_wu", (L, H, Is)),
                       shared_wd=matrix("shared_wd", (L, Is, H)))
-    if cfg.num_dense_layers:
+    if cfg.num_dense_layers and lead:
         Lp, Id = cfg.num_dense_layers, cfg.dense_intermediate_size
-        lead = {**layer_leaves(cfg, Lp, dtype, lead_matrix, plain), "mlp_norm": jnp.ones((Lp, H), dtype),
-                "wg": lead_matrix("dense_wg", (Lp, H, Id)), "wu": lead_matrix("dense_wu", (Lp, H, Id)),
-                "wd": lead_matrix("dense_wd", (Lp, Id, H))}   # (sized by their own names: seeded_std)
-        layers.update({PREFIX + n: w for n, w in lead.items()})
-    params: Params = {"embed": matrix("embed", (V, H)), "layers": layers, "final_norm": jnp.ones((H,), dtype)}
+        first = {**layer_leaves(cfg, Lp, dtype, lead_matrix, plain), "mlp_norm": jnp.ones((Lp, H), dtype),
+                 "wg": lead_matrix("dense_wg", (Lp, H, Id)), "wu": lead_matrix("dense_wu", (Lp, H, Id)),
+                 "wd": lead_matrix("dense_wd", (Lp, Id, H))}   # (sized by their own names: seeded_std)
+        layers.update({PREFIX + n: w for n, w in first.items()})
+    return layers, {"embed": lambda shape: matrix("embed", shape), "lm_head": lambda shape: matrix("lm_head", shape),
+                    "more": lead_matrix}
+
+
+MTP_KEY = 56  # the next-token module's leaves draw from fold_in(rng, MTP_KEY): the model's own draw as ever
+
+
+def init_params(rng: jax.Array, cfg: MixtralConfig, dtype=jnp.bfloat16) -> Params:
+    H, V = cfg.hidden_size, cfg.vocab_size
+    layers, draw = _init_layers(rng, cfg, cfg.expert_layers, dtype)
+    params: Params = {"embed": draw["embed"]((V, H)), "layers": layers, "final_norm": jnp.ones((H,), dtype)}
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = matrix("lm_head", (H, V))
+        params["lm_head"] = draw["lm_head"]((H, V))
+    if cfg.mtp_layers:
+        # One block of the expert layers' kind, seeded by their rule, the two norms
+        # in front of W_eh ([embedding half; stream half] -> hidden) and its own
+        # final norm; embedding and head are the model's.
+        block, draw = _init_layers(jax.random.fold_in(rng, MTP_KEY), cfg, cfg.mtp_layers, dtype, lead=False)
+        params["mtp"] = {"enorm": jnp.ones((H,), dtype), "hnorm": jnp.ones((H,), dtype),
+                         "eh_proj": draw["more"]("eh_proj", (2 * H, H)), "layers": block,
+                         "final_norm": jnp.ones((H,), dtype)}
     return params
 
 
@@ -1036,14 +1080,16 @@ def _mla_decode(h, lp, li, positions, pool, w_idx, table, lengths, *, page_size,
                              lambda q, pages, **kw: mla_paged_attention(q, pages, table, lengths, **kw))
 
 
-def _mla_ragged(h, lp, li, positions, pool, w_idx, table, q_starts, q_lens, kv_lens, *, page_size, cfg):
+def _mla_ragged(h, lp, li, positions, pool, w_idx, table, q_starts, q_lens, kv_lens, *, page_size, cfg, pair=None):
     """The seam's mixed-step side: the packed rows; a chunk's queries go
-    absorbed through the same walk, a tile of them at a time."""
+    absorbed through the same walk, a tile of them at a time (``pair``: a
+    drafting model's rows of two queries, ops/mla_attention.py)."""
     del li
     from inference_gateway_tpu.ops.mla_attention import mla_ragged_attention
 
     return _latent_attention(h, lp, positions, pool, w_idx, page_size, cfg,
-                             lambda q, pages, **kw: mla_ragged_attention(q, pages, table, q_starts, q_lens, kv_lens, **kw))
+                             lambda q, pages, **kw: mla_ragged_attention(q, pages, table, q_starts, q_lens, kv_lens,
+                                                                         pair=pair, **kw))
 
 
 def _hyper_connections(cfg: MixtralConfig) -> dict:
@@ -1070,7 +1116,7 @@ def _hyper_connections(cfg: MixtralConfig) -> dict:
             "exit": lambda X: jnp.sum(X.astype(jnp.float32), axis=0).astype(X.dtype)}
 
 
-def _seam(params: Params, cfg: MixtralConfig, mesh, valid=None, conv=None) -> dict:
+def _seam(params: Params, cfg: MixtralConfig, mesh, valid=None, conv=None, pair=None) -> dict:
     """What the shared skeletons take for a mixture: the FFN seam, the
     leaves it reads whole by layer index (never sliced per layer), for a
     model with leading dense layers their group, for a model with an
@@ -1106,7 +1152,7 @@ def _seam(params: Params, cfg: MixtralConfig, mesh, valid=None, conv=None) -> di
     if cfg.kv_lora_rank:
         seam["mixer"] = {"cache": (), "pool": MLA_POOL,
                          "decode": partial(_mla_decode, cfg=cfg, valid=valid),
-                         "ragged": partial(_mla_ragged, cfg=cfg)}
+                         "ragged": partial(_mla_ragged, cfg=cfg, pair=pair)}
     if cfg.hc_mult:
         seam["residual"] = _hyper_connections(cfg)
     return seam
@@ -1224,7 +1270,7 @@ def forward_paged(
     return (*out[:2], (out[2], counts))
 
 
-@partial(jax.jit, static_argnames=("cfg", "mesh", "with_aux"))
+@partial(jax.jit, static_argnames=("cfg", "mesh", "with_aux", "hidden", "pair"))
 def forward_ragged(
     params: Params,
     cfg: MixtralConfig,
@@ -1238,9 +1284,13 @@ def forward_ragged(
     kv_lens: jnp.ndarray,
     mesh=None,
     with_aux: bool = False,
+    hidden: bool = False,
+    tok_valid: jnp.ndarray | None = None,
+    pair: str | None = None,
 ):
     """The mixed step for a mixture (llama.forward_ragged's contract; the
-    third result as ``forward_paged``'s)."""
+    third result as ``forward_paged``'s). ``hidden``, ``tok_valid``:
+    ``forward_ragged_impl``'s; ``pair``: ``mla_ragged_attention_tpu``'s (ISSUE 56)."""
     conv = None
     with jax.named_scope("carry"):  # the rows' segments and the blocked scan's plan: once a step, for every state layer
         if cfg.kda:
@@ -1254,7 +1304,8 @@ def forward_ragged(
             conv = lambda z, tails, w: conv_ragged(z, tails, w, None, seg, fresh, q_starts, q_lens, act=None)
     out = forward_ragged_impl(params, cfg, tokens, positions, cache, write_idx,
                               page_table, q_starts, q_lens, kv_lens, mesh,
-                              with_aux=with_aux, **_seam(params, cfg, mesh, conv=conv))
+                              with_aux=with_aux, hidden=hidden, tok_valid=tok_valid,
+                              **_seam(params, cfg, mesh, conv=conv, pair=pair))
     if not (with_aux and counts_window_pages(cfg)):
         return out
     with jax.named_scope("carry"):
@@ -1263,6 +1314,48 @@ def forward_ragged(
         if cfg.scans_runs:   # blocks the chunk kernel walked, over the state layers; rows the one-token update served
             counts = jnp.concatenate([counts, state_counts])
     return (*out[:2], (out[2], counts))
+
+
+def head_logits(params: Params, cfg: MixtralConfig, x, final_norm):
+    """Float32 logits of streams ``x`` (..., H) through ``final_norm`` and the
+    model's head (the model's own final norm, or the next-token module's)."""
+    with jax.named_scope("head"):
+        return _head(params, cfg, rms_norm(x, final_norm, cfg.rms_norm_eps))
+
+
+def mtp_ragged(params: Params, cfg: MixtralConfig, stream, next_tokens, positions, cache: Params, write_idx,
+               page_table, q_starts, q_lens, kv_lens, tok_valid, mesh=None, pair: str | None = None):
+    """The next-token module over a step's packed tokens (ISSUE 56): ``stream``
+    (T, H) the last block's stream of each, ``next_tokens`` (T,) the token that
+    FOLLOWS each; positions, write indices and row descriptors as the step's
+    own, less the queries whose next token is not known (the caller shortens
+    ``q_lens`` / ``kv_lens`` and drops their write indices). u = W_eh
+    [norm_e(Emb(next)); norm_h(stream)], one block of the expert layers' kind
+    over the module's plane of the pool (the last), causal over u. Returns (the
+    block's stream (T, H), before the module's final norm; the cache; the rows
+    each expert multiplied (E,))."""
+    mp = params["mtp"]
+    block = mp["layers"]
+    pool = cache[MLA_POOL]
+    L, P, page_size, width = pool.shape
+    flat = P * page_size
+    plane = L - 1
+    with jax.named_scope("embed"):
+        e = rms_norm(_embed(params, cfg, next_tokens), mp["enorm"], cfg.rms_norm_eps)
+        s = rms_norm(stream, mp["hnorm"], cfg.rms_norm_eps)
+        x = qmatmul(jnp.concatenate([e, s.astype(e.dtype)], axis=-1), mp["eh_proj"])
+    lp = {n: jax.tree.map(lambda a: a[0], w) for n, w in block.items() if n not in EXPERT_LEAVES}
+    with jax.named_scope("attention"):
+        h = rms_norm(x, lp["attn_norm"], cfg.rms_norm_eps)
+        w_idx = jnp.where(write_idx >= flat, L * flat, write_idx + plane * flat)
+        attn, pool = _mla_ragged(h, lp, plane, positions, pool.reshape(L * flat, width), w_idx, page_table + plane * P,
+                                 q_starts, q_lens, kv_lens, page_size=page_size, cfg=cfg, pair=pair)
+        x = x + _attn_out(attn, h, lp, cfg)
+    with jax.named_scope("mlp"):
+        experts = {n: block[n] for n in EXPERT_LEAVES}
+        y, rows = _moe_ffn(x[None], h[None], lp, cfg.num_dense_layers, tok_valid, cfg=cfg, experts=experts, mesh=mesh)
+        x = x + _ffn_out(y[0], lp, cfg)
+    return x, {**cache, MLA_POOL: pool.reshape(L, P, page_size, width)}, rows
 
 
 def param_specs(cfg: MixtralConfig) -> dict:
@@ -1382,6 +1475,22 @@ def _kimi(layer_types: str, **sizes) -> MixtralConfig:
     return MixtralConfig(num_layers=n, layer_conv=tuple(3 * (k == "k") for k in layer_types), layer_rope=(0,) * n,
                          num_dense_layers=1, conv_kernel=4, router_score="sigmoid", route_scale=2.446,
                          shared_experts=1, rms_norm_eps=1e-5, rope_theta=10000.0, **sizes)
+
+
+def _pangu(num_layers: int, **sizes) -> MixtralConfig:
+    """openPangu-Ultra-MoE's block (ISSUE 56, ``pangu_ultra_moe``): the
+    DeepSeek-V3 shape (a latent attention with a query bottleneck under plain
+    rotary, leading dense layers of which a stage here holds ONE, experts routed
+    by sigmoid scores, the 8 largest of all with no groups and no selection bias
+    (``router_bias`` a leaf of zeros), the chosen scores normalised and times
+    routed_scaling_factor 2.5, one shared expert, an untied head) with every
+    sublayer's OUTPUT normed (``sandwich_norm``) and ONE next-token module
+    (``num_nextn_predict_layers`` 1), with which a served engine drafts for
+    itself. ``experts_held`` / ``vocab_published``: a chip's share of the experts
+    and of the vocabulary."""
+    return MixtralConfig(num_layers=num_layers, num_dense_layers=1, router_score="sigmoid", route_scale=2.5,
+                         shared_experts=1, sandwich_norm=True, mtp_layers=1, rms_norm_eps=1e-5,
+                         rope_theta=25600000.0, **sizes)
 
 
 PRESETS: dict[str, MixtralConfig] = {
@@ -1510,4 +1619,24 @@ PRESETS: dict[str, MixtralConfig] = {
         qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, intermediate_size=1024,
         dense_intermediate_size=9216, num_experts=256, experts_per_token=8, experts_held=128,
         max_position_embeddings=1048576, kda_heads=32, kda_head_dim=128, kda_block=64),
+    # The share's own shape at toy widths: 1 dense + 2 expert layers and the
+    # next-token module, a latent of 32 beside a rotary key of 8 under a query
+    # bottleneck of 32, 8 experts top-2 of which this "chip" holds the first 4, a
+    # vocabulary of 512 of which it holds the first 256.
+    "openpangu-test-tiny": _pangu(
+        3, vocab_size=256, vocab_published=512, hidden_size=64, num_heads=4, num_kv_heads=4, head_dim=24,
+        q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, intermediate_size=32,
+        dense_intermediate_size=96, num_experts=8, experts_per_token=2, experts_held=4, max_position_embeddings=512),
+    # FreedomIntelligence/openPangu-Ultra-MoE-718B is 61 layers of 256 experts, 738 GB
+    # of int8: a layer's experts alone are 12 GB. This is ONE of the sixteen chips
+    # that share each layer of a first pipeline stage: layer 0 (one of the three
+    # leading dense layers) + six expert layers + the next-token module, every
+    # width, 128 heads and the 256-wide router as published, experts 0-15 of 256
+    # and rows 0-19,199 of the 153,600-row vocabulary (two chips' rows: the
+    # guide's floor of an eighth): 8.2 GB beside a latent pool of EIGHT layers.
+    "openpangu-ultra-moe-718b-stage7": _pangu(
+        7, vocab_size=19200, vocab_published=153600, hidden_size=7680, num_heads=128, num_kv_heads=128, head_dim=192,
+        q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        intermediate_size=2048, dense_intermediate_size=18432, num_experts=256, experts_per_token=8, experts_held=16,
+        max_position_embeddings=131072),
 }

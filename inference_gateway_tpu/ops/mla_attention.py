@@ -15,7 +15,11 @@ be aligned to tiling (128), but is 576"), so the padding is in the shape,
 where every count of the pool's bytes sees it.
 
 ``mla_paged_attention``: one query a slot (the decode chunk).
-``mla_ragged_attention``: a mixed step's packed rows. As
+``mla_ragged_attention``: a mixed step's packed rows; with ``pair`` (ISSUE 56,
+a model that verifies its own draft) the rows of exactly TWO queries, a decoding
+row's pending token and its draft, are a tile of two each under a third name,
+``mla_pair_attn_kernel``: the two queries' heads are the rows of one product
+against each visit of the row's pages, which are fetched once for both. As
 ``ops/paged_attention.ragged_paged_attention_tpu`` it hands the one-query rows
 of the packed prefix to the decode launch by packed position; every other row
 is cut into TILES of ``chunk_tile`` queries, laid out in packed order, and a
@@ -76,6 +80,7 @@ def mla_walk_plan(num_heads: int, width: int, page_size: int, max_pages: int, dt
             "wide_pages_per_visit": max(1, min(CHUNK_PAGES_PER_VISIT, max_pages)),
             "unmasked_visits": True, "one_query_rows": "mla_decode_attn_kernel",
             "one_query_pages_per_visit": max(1, min(DECODE_VISIT_BYTES // (2 * page + one), max_pages)),
+            "pair_pages_per_visit": max(1, min(DECODE_VISIT_BYTES // (2 * page + 2 * one), max_pages)),
             "latent_fetched": "once a visit, key and value"}
 
 
@@ -298,32 +303,72 @@ def _chunk_tiles(q_starts, q_lens, kv_lens, tile: int, count: int):
     return jnp.stack([order[i], kv[i] - lens[i] + c * tile, n_q, starts[i] + c * tile], axis=1).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("value_dim", "scale", "interpret", "pages_per_visit", "row_pages_per_visit"))
+def _written(T: int, q_starts, lens):
+    """(T,) the packed positions some row's ``lens`` queries sit at."""
+    t = jnp.arange(T, dtype=jnp.int32)[:, None]
+    return jnp.any((t >= q_starts[None, :]) & (t < (q_starts + lens)[None, :]), axis=1)
+
+
+@functools.partial(jax.jit, static_argnames=("value_dim", "scale", "interpret", "pages_per_visit"))
+def mla_pair_attention_tpu(q, pages, page_table, q_starts, q_lens, kv_lens, *, value_dim: int, scale: float,
+                           interpret: bool = False, pages_per_visit: int | None = None):
+    """The rows of at most TWO queries (ISSUE 56: a decoding row's pending token
+    and its draft; ONE where the module leaves a refused draft out): q (T, H,
+    width) packed, a tile of two a row of the page table, its queries the row's
+    last positions. Returns (T, H, value_dim); zeros wherever no such row's
+    queries sit."""
+    T, H, _ = q.shape
+    R, max_pages = page_table.shape
+    _, page_size, width = pages.shape
+    N = pages_per_visit or mla_walk_plan(H, width, page_size, max_pages, pages.dtype)["pair_pages_per_visit"]
+    lens = jnp.where(q_lens <= 2, q_lens, 0).astype(jnp.int32)
+    descr = jnp.stack([jnp.arange(R, dtype=jnp.int32), kv_lens.astype(jnp.int32) - lens, lens,
+                       jnp.where(lens > 0, q_starts, 0).astype(jnp.int32)], axis=1)
+    qp = jnp.pad(_lane_padded(q, pages), ((0, 2), (0, 0), (0, 0)))
+    out = _launch(qp, pages, page_table, descr, tile=2, value_dim=value_dim, scale=scale, pages_per_visit=N,
+                  staged=True, interpret=interpret, name="mla_pair_attn_kernel")[:T]
+    return jnp.where(_written(T, q_starts.astype(jnp.int32), lens)[:, None, None], out, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("value_dim", "scale", "interpret", "pages_per_visit", "row_pages_per_visit",
+                                             "pair"))
 def mla_ragged_attention_tpu(q, pages, page_table, q_starts, q_lens, kv_lens, *, value_dim: int, scale: float,
                              interpret: bool = False, pages_per_visit: int | None = None,
-                             row_pages_per_visit: int | None = None):
+                             row_pages_per_visit: int | None = None, pair: str | None = None):
     """q (T, H, width) packed absorbed queries; the descriptors of
     ``ragged_paged_attention_tpu``. Returns (T, H, value_dim); zeros where no
-    row's query sits."""
+    row's query sits. ``pair``: None, the one-query rows of the packed prefix go
+    to the decode launch and every other row is cut into the chunk's tiles;
+    "mixed", the rows of one or two go to the pair launch and the rest to the
+    chunk's tiles; "only", every live row has one or two (a decode chunk's
+    round) and the pair launch is the one launch."""
     T, H, _ = q.shape
     R, max_pages = page_table.shape
     _, page_size, width = pages.shape
     plan = mla_walk_plan(H, width, page_size, max_pages, pages.dtype)
+    if pair == "only":
+        return mla_pair_attention_tpu(q, pages, page_table, q_starts, q_lens, kv_lens, value_dim=value_dim,
+                                      scale=scale, interpret=interpret)
     tile = min(plan["wide_tile"], -(-T // 8) * 8)
     n = min(R, T)
-    table_1, lengths_1, to_decode = _one_query_prefix(page_table, q_starts, q_lens, kv_lens, n)
-    out_1 = mla_paged_attention_tpu(q[:n], pages, table_1, lengths_1, value_dim=value_dim, scale=scale,
-                                    interpret=interpret, pages_per_visit=row_pages_per_visit)
-    lens = jnp.where(to_decode, 0, q_lens).astype(jnp.int32)
+    if pair is not None:
+        out_1 = mla_pair_attention_tpu(q, pages, page_table, q_starts, q_lens, kv_lens, value_dim=value_dim,
+                                       scale=scale, interpret=interpret)
+        lens = jnp.where(q_lens <= 2, 0, q_lens).astype(jnp.int32)
+    else:
+        table_1, lengths_1, to_decode = _one_query_prefix(page_table, q_starts, q_lens, kv_lens, n)
+        out_1 = jnp.pad(mla_paged_attention_tpu(q[:n], pages, table_1, lengths_1, value_dim=value_dim, scale=scale,
+                                                interpret=interpret, pages_per_visit=row_pages_per_visit),
+                        ((0, T - n), (0, 0), (0, 0)))
+        lens = jnp.where(to_decode, 0, q_lens).astype(jnp.int32)
     descr = _chunk_tiles(q_starts.astype(jnp.int32), lens, kv_lens.astype(jnp.int32), tile, -(-T // tile) + R)
     qp = jnp.pad(_lane_padded(q, pages), ((0, tile), (0, 0), (0, 0)))  # a tile of slack: a row's last tile may overhang
     out = _launch(qp, pages, page_table, descr, tile=tile, value_dim=value_dim, scale=scale,
                   pages_per_visit=pages_per_visit or plan["wide_pages_per_visit"], staged=True,
                   interpret=interpret, name="mla_chunk_attn_kernel")[:T]
-    t = jnp.arange(T, dtype=jnp.int32)[:, None]
-    theirs = jnp.any((t >= q_starts[None, :]) & (t < (q_starts + lens)[None, :]), axis=1)
     # What no tile wrote is whatever the buffer held: chosen away, never multiplied.
-    return jnp.where(theirs[:, None, None], out, 0) + jnp.pad(out_1, ((0, T - n), (0, 0), (0, 0)))
+    theirs = _written(T, q_starts.astype(jnp.int32), lens)
+    return jnp.where(theirs[:, None, None], out, 0) + out_1
 
 
 def _as_one_key_head(q, pages, scale: float):
@@ -349,11 +394,12 @@ def mla_paged_attention(q, pages, page_table, lengths, *, value_dim: int, scale:
     return paged_attention_jax(_as_one_key_head(q, pages, scale), pages, pages, page_table, lengths, 1)[..., :value_dim]
 
 
-def mla_ragged_attention(q, pages, page_table, q_starts, q_lens, kv_lens, *, value_dim: int, scale: float):
-    """Dispatch of the mixed step's read."""
+def mla_ragged_attention(q, pages, page_table, q_starts, q_lens, kv_lens, *, value_dim: int, scale: float,
+                         pair: str | None = None):
+    """Dispatch of the mixed step's read (``pair``: ``mla_ragged_attention_tpu``'s)."""
     kernel, interpret = _use_kernel(q, pages)
     if kernel:
         return mla_ragged_attention_tpu(q, pages, page_table, q_starts, q_lens, kv_lens, value_dim=value_dim,
-                                        scale=scale, interpret=interpret)
+                                        scale=scale, interpret=interpret, pair=pair)
     return ragged_paged_attention_jax(_as_one_key_head(q, pages, scale), pages, pages, page_table, q_starts, q_lens,
                                       kv_lens, 1)[..., :value_dim]

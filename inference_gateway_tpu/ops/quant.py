@@ -185,6 +185,9 @@ def quantize_llama_params(params: dict, mode: str = "int8", group: int = 128) ->
     out["layers"] = layers
     if "lm_head" in out:
         out["lm_head"] = quant(out["lm_head"])
+    if "mtp" in out:   # the next-token module (ISSUE 56): its block's matmuls and W_eh
+        block = {n: quant(w) if n in QUANTIZABLE else w for n, w in out["mtp"]["layers"].items()}
+        out["mtp"] = {**out["mtp"], "layers": block, "eh_proj": quant(out["mtp"]["eh_proj"])}
     return out
 
 
@@ -267,6 +270,8 @@ def seeded_std(cfg, leaf: str) -> float:
         return _granite_std(cfg, leaf)
     if getattr(cfg, "layer_conv", None) is not None:
         return _lfm2_std(cfg, leaf)
+    if getattr(cfg, "kv_lora_rank", 0) and getattr(cfg, "sandwich_norm", False):
+        return _pangu_std(cfg, leaf)
     if getattr(cfg, "kv_lora_rank", 0):
         return _xing_std(cfg, leaf)
     if getattr(cfg, "sandwich_norm", False):
@@ -556,6 +561,47 @@ def _kimi_std(cfg, leaf: str) -> float:
             "dense_wg": fan, "dense_wu": fan, "dense_wd": 4.4 * cfg.dense_intermediate_size ** -0.5}[leaf]
 
 
+def _pangu_std(cfg, leaf: str) -> float:
+    """``seeded_std`` for openPangu-Ultra-MoE's block (ISSUE 56): a latent
+    attention with a query bottleneck under rotary, every sublayer's OUTPUT
+    normed, one dense MLP in front, a sigmoid router over ALL the experts with
+    a sixteenth of them held, a shared expert, an untied head over a slice of
+    the vocabulary, and the next-token module, whose block is seeded by these
+    same rules. ``_xing_std``'s regime per fan-in, so the test preset and the
+    published 7,680 are one regime; under the output norms what matters is the
+    two GAINS and the shares inside a sublayer.
+
+    - the embedding at 1.0, the head at 1.25 x hidden ** -0.5 (``_xing_std``'s).
+    - the output norms' gains: 0.5 behind attention, 0.75 behind the FFN (a gain is
+      what its sublayer ADDS, whatever its matrices' scales): the stream ends near
+      2.6 behind seven layers, a layer's FFN under a third of it. Dropped,
+      attention adds what W_O gives and the FFN ~1.1: other sizes, another model.
+    - the latent attention: ``_xing_std``'s projections with the queries' up
+      projection at 1.4 x rank ** -0.5: no YaRN doubles the scores here, and a
+      spread of 2 rests a head on a few dozen keys, so a second query that reads
+      the cache as the first does (one position short) is another output.
+    - the router at hidden ** -0.5, the selection bias 0 (the config has none: a
+      leaf of zeros); the experts' down projection at 1.4 x width ** -0.5 and the
+      shared expert's at 2 x width ** -0.5: a token's eight chosen weigh 0.31
+      each and add ~0.7 together beside the shared expert's ~0.8, of which this
+      chip's sixteenth holds half an expert a token on average: the ABSENT
+      experts computed in place of left out turn the FFN's normed output by
+      about 40 degrees, while one held expert swapped by bfloat16's rounding of
+      a score at the cut moves it by a twelfth, once in sixteen swaps.
+    - W_eh at (2 x hidden) ** -0.5 x 1.4: both normed halves weigh alike and
+      the module's stream starts at RMS ~1.4, under its block's two additions.
+    No scale here was chosen for its effect on the drafts' acceptance."""
+    fan = cfg.hidden_size ** -0.5
+    return {"embed": 1.0, "lm_head": 1.25 * fan, "router": fan, "router_bias": 0.0,
+            "post_attn_norm": 0.5, "post_ffn_norm": 0.75,
+            "wdq": fan, "wuq": 1.4 * cfg.q_lora_rank ** -0.5, "wdkv": fan, "wukv": cfg.kv_lora_rank ** -0.5,
+            "wo": 1.5 * (cfg.num_heads * cfg.v_head_dim) ** -0.5,
+            "wg": fan, "wu": fan, "wd": 1.4 * cfg.intermediate_size ** -0.5,
+            "shared_wg": fan, "shared_wu": fan, "shared_wd": 2.0 * (cfg.shared_experts * cfg.intermediate_size) ** -0.5,
+            "dense_wg": fan, "dense_wu": fan, "dense_wd": 4.4 * cfg.dense_intermediate_size ** -0.5,
+            "eh_proj": 1.4 * (2 * cfg.hidden_size) ** -0.5}[leaf]
+
+
 def seeded_qk_gain(cfg) -> float:
     """What a seeded build writes into ``q_norm`` and ``k_norm``: 1, and 1.4
     for a model with a sparse-attention indexer and for one whose sublayers'
@@ -647,57 +693,71 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
         return stacked
 
     E = getattr(cfg, "num_experts", 0)
+    mtp = None
     if E:
         from inference_gateway_tpu.models import mixtral
 
-        Eh = cfg.held_experts   # the stacks hold a chip's share (ISSUE 54); the router is E wide
+        def mixture_layers(rng, L, lead=True):
+            """A stack of ``L`` expert layers drawn from ``rng`` (with ``lead`` the leading
+            dense layers' beside it), and what draws a further matrix from its stream."""
+            keys = jax.random.split(rng, 8)
+            Eh = cfg.held_experts   # the stacks hold a chip's share (ISSUE 54); the router is E wide
 
-        # One key a leaf the older mixtures have, as they always drew them; the
-        # leaves ISSUE 41 added draw from a stream of their own.
-        named = {"wq": keys[1], "wk": keys[2], "wv": keys[3], "wo": keys[4]}
-        more = iter(jax.random.split(jax.random.fold_in(rng, 41), 24))
+            # One key a leaf the older mixtures have, as they always drew them; the
+            # leaves ISSUE 41 added draw from a stream of their own.
+            named = {"wq": keys[1], "wk": keys[2], "wv": keys[3], "wo": keys[4]}
+            more = iter(jax.random.split(jax.random.fold_in(rng, 41), 24))
 
-        def matrix(name, shape):
-            return qstack(named[name] if name in named else next(more), shape, seeded_std(cfg, name))
+            def matrix(name, shape):
+                return qstack(named[name] if name in named else next(more), shape, seeded_std(cfg, name))
 
-        def lead_matrix(name, shape):
-            return qstack(next(more), shape, seeded_std(cfg, name))
+            def lead_matrix(name, shape):
+                return qstack(next(more), shape, seeded_std(cfg, name))
 
-        hc_keys = iter(jax.random.split(jax.random.fold_in(rng, 47), 8))
+            hc_keys = iter(jax.random.split(jax.random.fold_in(rng, 47), 8))
 
-        def plain(name, shape):  # the hyper-connections' float32 leaves (ISSUE 47), a stream of their own
-            return jax.random.normal(next(hc_keys), shape, jnp.float32) * seeded_std(cfg, name)
+            def plain(name, shape):  # the hyper-connections' float32 leaves (ISSUE 47), a stream of their own
+                return jax.random.normal(next(hc_keys), shape, jnp.float32) * seeded_std(cfg, name)
 
-        ekeys = jax.random.split(jax.random.fold_in(rng, 7), 4)
-        layers = {
-            **mixtral.layer_leaves(cfg, L, dtype, matrix, plain),
-            **mixtral.operator_leaves(cfg, dtype, matrix,
-                                      lambda name, shape: norm(next(more), shape, seeded_std(cfg, name))),
-            "moe_norm": jnp.ones((L, H), dtype),
-            "router": norm(ekeys[0], (L, H, E), seeded_std(cfg, "router")),
-            "wg": qfill(ekeys[1], (L, Eh, H, I), seeded_std(cfg, "wg")),
-            "wu": qfill(ekeys[2], (L, Eh, H, I), seeded_std(cfg, "wu")),
-            "wd": qfill(ekeys[3], (L, Eh, I, H), seeded_std(cfg, "wd")),
-        }
-        if cfg.indexer_topk:
-            ikeys = jax.random.split(jax.random.fold_in(rng, 36), 3)
-            Hi, Di, std = cfg.indexer_heads, cfg.indexer_head_dim, seeded_std(cfg, "wq_idx")
-            layers.update(wq_idx=qstack(ikeys[0], (L, H, Hi * Di), std),
-                          wk_idx=norm(ikeys[1], (L, H, Di), std),
-                          ww_idx=norm(ikeys[2], (L, H, Hi), std),
-                          k_idx_norm=jnp.ones((L, Di), dtype), k_idx_bias=jnp.zeros((L, Di), dtype))
-        if cfg.router_score == "sigmoid":
-            layers["router_bias"] = norm(next(more), (L, E), seeded_std(cfg, "router_bias"))
-        if cfg.shared_experts:
-            Is = cfg.shared_experts * I
-            layers.update(shared_wg=matrix("shared_wg", (L, H, Is)), shared_wu=matrix("shared_wu", (L, H, Is)),
-                          shared_wd=matrix("shared_wd", (L, Is, H)))
-        if cfg.num_dense_layers:
-            Lp, Id = cfg.num_dense_layers, cfg.dense_intermediate_size
-            lead = {**mixtral.layer_leaves(cfg, Lp, dtype, lead_matrix, plain), "mlp_norm": jnp.ones((Lp, H), dtype),
-                    "wg": lead_matrix("dense_wg", (Lp, H, Id)), "wu": lead_matrix("dense_wu", (Lp, H, Id)),
-                    "wd": lead_matrix("dense_wd", (Lp, Id, H))}   # (sized by their own names: seeded_std)
-            layers.update({mixtral.PREFIX + n: w for n, w in lead.items()})
+            ekeys = jax.random.split(jax.random.fold_in(rng, 7), 4)
+            layers = {
+                **mixtral.layer_leaves(cfg, L, dtype, matrix, plain),
+                **mixtral.operator_leaves(cfg, dtype, matrix,
+                                          lambda name, shape: norm(next(more), shape, seeded_std(cfg, name))),
+                "moe_norm": jnp.ones((L, H), dtype),
+                "router": norm(ekeys[0], (L, H, E), seeded_std(cfg, "router")),
+                "wg": qfill(ekeys[1], (L, Eh, H, I), seeded_std(cfg, "wg")),
+                "wu": qfill(ekeys[2], (L, Eh, H, I), seeded_std(cfg, "wu")),
+                "wd": qfill(ekeys[3], (L, Eh, I, H), seeded_std(cfg, "wd")),
+            }
+            if cfg.indexer_topk:
+                ikeys = jax.random.split(jax.random.fold_in(rng, 36), 3)
+                Hi, Di, std = cfg.indexer_heads, cfg.indexer_head_dim, seeded_std(cfg, "wq_idx")
+                layers.update(wq_idx=qstack(ikeys[0], (L, H, Hi * Di), std),
+                              wk_idx=norm(ikeys[1], (L, H, Di), std),
+                              ww_idx=norm(ikeys[2], (L, H, Hi), std),
+                              k_idx_norm=jnp.ones((L, Di), dtype), k_idx_bias=jnp.zeros((L, Di), dtype))
+            if cfg.router_score == "sigmoid":
+                layers["router_bias"] = norm(next(more), (L, E), seeded_std(cfg, "router_bias"))
+            if cfg.shared_experts:
+                Is = cfg.shared_experts * I
+                layers.update(shared_wg=matrix("shared_wg", (L, H, Is)), shared_wu=matrix("shared_wu", (L, H, Is)),
+                              shared_wd=matrix("shared_wd", (L, Is, H)))
+            if cfg.num_dense_layers and lead:
+                Lp, Id = cfg.num_dense_layers, cfg.dense_intermediate_size
+                first = {**mixtral.layer_leaves(cfg, Lp, dtype, lead_matrix, plain), "mlp_norm": jnp.ones((Lp, H), dtype),
+                         "wg": lead_matrix("dense_wg", (Lp, H, Id)), "wu": lead_matrix("dense_wu", (Lp, H, Id)),
+                         "wd": lead_matrix("dense_wd", (Lp, Id, H))}   # (sized by their own names: seeded_std)
+                layers.update({mixtral.PREFIX + n: w for n, w in first.items()})
+            return layers, lambda name, shape: qinit(next(more), shape, seeded_std(cfg, name))
+
+        layers, _ = mixture_layers(rng, L)
+        if getattr(cfg, "mtp_layers", 0):
+            # The next-token module (ISSUE 56): one block seeded by the expert layers'
+            # rule from a key of its own, its two norms, W_eh and its final norm.
+            block, further = mixture_layers(jax.random.fold_in(rng, mixtral.MTP_KEY), cfg.mtp_layers, lead=False)
+            mtp = {"enorm": jnp.ones((H,), dtype), "hnorm": jnp.ones((H,), dtype),
+                   "eh_proj": further("eh_proj", (2 * H, H)), "layers": block, "final_norm": jnp.ones((H,), dtype)}
     else:
         layers = {
             "attn_norm": jnp.ones((L, H), dtype),
@@ -718,6 +778,8 @@ def init_quantized_llama_params(rng: jax.Array, cfg, mode: str = "int8",
         params["layers"]["bv"] = jnp.zeros((L, Hkv * D), dtype)
     if not cfg.tie_word_embeddings:
         params["lm_head"] = qinit(jax.random.fold_in(rng, 99), (H, V), seeded_std(cfg, "lm_head"))
+    if mtp is not None:
+        params["mtp"] = mtp
     return params
 
 

@@ -238,6 +238,13 @@ class _DecodeChunkHandle:
     # tokens (ISSUE 28), filled by the fetch: a mixture's ``experts_read`` and ``expert_visits``.
     # Rides the step's timeline record.
     stats: dict | None = None
+    # A drafting model's chunk (ISSUE 56): how many of each round's two tokens a
+    # row emitted, (n_steps, S), filled by the fetch; the fresh submit it chains
+    # from and its place among the chunks dispatched, by which the fetch tightens
+    # the page horizon.
+    counts: object = None
+    epoch: int = 0
+    sent: int = 0
 
 
 @dataclass
@@ -262,6 +269,10 @@ class MixedRow:
     # for this slot, still on the device (ISSUE 40): ``token_ids`` holds a
     # placeholder and the program reads the token from that step's block.
     from_block: bool = False
+    # A prompt's chunk in a model that drafts for itself (ISSUE 56): the prompt's
+    # token behind this chunk's last, which the next-token module reads beside it;
+    # None where the prompt ends with this chunk (the token the step samples).
+    next_token: int | None = None
 
 
 @dataclass
@@ -284,6 +295,8 @@ class MixedStepHandle:
     # mixture's ``expert_tokens_max`` / ``expert_tokens_mean`` /
     # ``experts_read``, filled by the fetch for the timeline record.
     stats: dict | None = None
+    # A drafting model's step (ISSUE 56): how many tokens each row emitted, filled by the fetch.
+    counts: object = None
 
 
 def _fetch_blocks(phases: dict | None, path: str, *blocks) -> list[np.ndarray]:
@@ -337,6 +350,11 @@ SSD_STATS = ("ssd_blocks", "state_rows")
 # blocks ``ops/kda.py``'s chunk kernel walked over all its state layers, and the
 # rows whose matrix state its one-token update read and wrote in each of them.
 KDA_STATS = ("kda_blocks", "state_rows")
+# A model that drafts with its own next-token module (models/mixtral.py, ISSUE 56)
+# counts, in both step programs, the rounds of a row that verified a draft, the
+# drafts accepted, and the tokens those rounds emitted (1 or 2 a round): three
+# rows of the fetch block, each a sum.
+MTP_STATS = ("mtp_drafted", "mtp_accepted", "mtp_tokens")
 
 # Presets of models/phi4flash.py, by name: the module is imported when one
 # of them is asked for, and not on the way to any other model.
@@ -395,6 +413,12 @@ def has_latent_pool(model_cfg) -> bool:
     return bool(getattr(model_cfg, "kv_lora_rank", 0))
 
 
+def has_mtp(model_cfg) -> bool:
+    """A model with its own next-token module: it drafts one token a row a round
+    for itself, and both step programs verify it (models/mixtral.py, ISSUE 56)."""
+    return bool(getattr(model_cfg, "mtp_layers", 0))
+
+
 def has_dense_prefix(model_cfg) -> bool:
     """A mixture whose first layers are dense, a stack of their own beside
     the expert layers' (models/mixtral.py, ISSUE 41)."""
@@ -444,6 +468,13 @@ def choose_mesh(config: EngineConfig, model_cfg, n_dev: int):
             raise ValueError(
                 f"{config.model}: no mesh ({shape}) carries the sparse-attention indexer and its "
                 "key cache yet; serve it on one device")
+        return None, False
+    if has_mtp(model_cfg):
+        # Nor the next-token module and the rounds' carried state (ISSUE 56): the same rule.
+        if shape:
+            raise ValueError(
+                f"{config.model}: no mesh ({shape}) lays out its next-token module and the drafts' carried state "
+                "yet; serve it on one device")
         return None, False
     if has_dense_prefix(model_cfg):
         # Nor two stacks of layers, an output gate and a shared expert
@@ -637,11 +668,13 @@ class Engine:
         self.is_dsa = has_indexer(self.model_cfg)
         self.is_mla = has_latent_pool(self.model_cfg)
         self._model = mixtral if self.is_moe else llama
+        self.mtp = has_mtp(self.model_cfg)
         if self.is_mla and config.spec_draft is not None:
             # Refused by name (ISSUE 47); a vision model and int4 by its dense prefix's branch below.
-            raise ValueError(f"{config.model}: speculative decoding has no verify pass over the latent pool, and "
-                             "the model's own next-token-prediction module is not built; serve it without "
-                             "--spec-draft")
+            own = ("it drafts for itself with its own next-token module, in both step programs" if self.mtp else
+                   "its own next-token-prediction module is not part of this preset (num_nextn_predict_layers 0)")
+            raise ValueError(f"{config.model}: speculative decoding with a separate draft model or prompt lookup has no "
+                             f"verify pass over the latent pool ({own}); serve it without --spec-draft")
         if self.is_dsa:
             # What the indexer's path does not carry is refused by name (ISSUE 36).
             if config.spec_draft is not None:
@@ -738,7 +771,7 @@ class Engine:
 
                 # Pages can be handed over, a recurrent state cannot: with a
                 # state store every hit is declined, and counted.
-                self.prefix_cache = PrefixCache(self.allocator, decline_hits=self.carries_state)
+                self.prefix_cache = PrefixCache(self.allocator, decline_hits=self.carries_state, successor=self.mtp)
         else:
             cache = self._model.init_cache(self.model_cfg, config.max_slots, config.max_seq_len, dtype=self.dtype)
             if self.mesh is not None:
@@ -773,8 +806,9 @@ class Engine:
                               if b <= config.max_seq_len), default=config.max_seq_len)
         self.mixed_budget = config.mixed_step_tokens or (biggest_bucket + config.max_slots)
         # Progress requires room for one prefill token past a full decode
-        # batch; pad a little so chunks aren't degenerate.
-        self.mixed_budget = max(self.mixed_budget, config.max_slots + 8)
+        # batch (two queries a row where the model drafts for itself: ISSUE 56);
+        # pad a little so chunks aren't degenerate.
+        self.mixed_budget = max(self.mixed_budget, (2 if has_mtp(self.model_cfg) else 1) * config.max_slots + 8)
 
         # The dispatch verdict this engine's layouts take (ISSUE 12
         # satellite): surfaced as the engine.attention_path gauge and a
@@ -861,7 +895,8 @@ class Engine:
         self.draft_params = None
         self.draft_cache = None
         if self.spec and not self.spec_ngram:
-            assert not self.is_moe, "speculative decoding: MoE targets not supported yet"
+            assert not self.is_moe, ("speculative decoding with a separate draft model: mixture targets not supported "
+                                     "(a latent-family preset with a next-token module drafts for itself)")
             if config.spec_draft in llama.PRESETS:
                 self.draft_cfg = llama.PRESETS[config.spec_draft]
                 self.draft_params = llama.init_params(
@@ -974,6 +1009,12 @@ class Engine:
             # Rows whose one token is still on the device (ISSUE 40): it is
             # read from the step before's result block, not from ``tokens``.
             ("from_block", (S,), "bool", 0),
+            # (ISSUE 56, a drafting model alone) the rows that DECODE: two queries, the
+            # pending token and its draft, both read on the device with their positions,
+            # write indices and length (``_mtp``); the rows whose prompt ENDS in this
+            # step; and the token that follows each packed token, for the module.
+            *([("mtp_decode", (S,), "bool", 0), ("mtp_ends", (S,), "bool", 0),
+               ("next_tokens", (1, T), "i32", 0)] if self.mtp else []),
         ]) if self.paged else None
         self._fresh_pack = PackedLayout([
             ("counter", (1,), "i32", 0),
@@ -1008,7 +1049,8 @@ class Engine:
         more_stats = ((DSA_STATS if self.is_dsa else ())
                       + ((LATENT_STATS if self.is_mla else WINDOW_STATS) if self.counts_window else ())
                       + (SSD_STATS if getattr(self.model_cfg, "mamba2", False) else ())
-                      + (KDA_STATS if getattr(self.model_cfg, "kda", False) else ()))
+                      + (KDA_STATS if getattr(self.model_cfg, "kda", False) else ())
+                      + (MTP_STATS if self.mtp else ()))
         if self.is_hybrid:
             self._chunk_stats = self._mixed_stats = WINDOW_STATS
         else:
@@ -1024,6 +1066,23 @@ class Engine:
         # dispatched before that step is fetched. Zeros until a step has run.
         counted = len(self._mixed_stats) if self.is_moe or self.is_hybrid else 0
         self._last_block = jnp.zeros((2 + counted, S), jnp.float32)
+        # A drafting model's rounds (ISSUE 56): what the round dispatched last left
+        # on the device, a slot each, and what BOTH step programs start a decoding
+        # row from, so that neither waits for a fetch: the pending token and its
+        # position (they depend on what was accepted), the module's draft for the
+        # position behind it with the strip it was drawn from, and the tokens that
+        # round emitted. A prompt's last chunk writes a slot's entry first.
+        self._mtp = None
+        # Tokens a row may emit a round: the page horizon and the fetch block count by it.
+        self.round_tokens = 2 if self.mtp else 1
+        if self.mtp and self.paged:   # (a dense engine of it holds the tree alone: the benchmark's reference child)
+            assert self.mixed_ok, f"{config.model}: its next-token module drafts in the paged step programs"
+            k = effective_top_k(config.top_k, self.model_cfg.vocab_size)
+            self._mtp = {"tok": jnp.zeros((S,), jnp.int32), "pos": jnp.zeros((S,), jnp.int32),
+                         "draft": jnp.zeros((S,), jnp.int32), "qp": jnp.full((S, k), 1.0 / k, jnp.float32),
+                         "qi": jnp.zeros((S, k), jnp.int32), "cnt": jnp.ones((S,), jnp.int32)}
+            self._chain_epoch = 0   # fresh submits so far: a fetched chunk tightens the horizon of its own chain alone
+            self._chunks_sent = 0   # decode chunks dispatched so far
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -1410,21 +1469,27 @@ class Engine:
                                   budgets, stop_table, page_table, reserved,
                                   temps, top_ps, seeds, use_seed, rng,
                                   mstates=None, mnext=None, mbits=None, mterm=None,
-                                  mbias=None, n_steps=8, automaton=False):
+                                  mbias=None, n_steps=8, automaton=False, mtp=None):
         """The decode chunk over the paged cache: the flat paged write
         index is computed ON DEVICE from the resident page table
         (page_table[slot, pos // page_size] · page_size + pos % page_size)
         and masked OOB for done rows and positions beyond the reserved
         horizon — the host assembles no write_idx per chunk, so a
-        chained submit uploads nothing (ISSUE 14 tentpole b)."""
+        chained submit uploads nothing (ISSUE 14 tentpole b). A model that
+        drafts for itself (ISSUE 56) hands ``mtp`` and runs ROUNDS
+        (``_run_chunk_mtp``: one fetch block, the carry, the new ``mtp``)."""
         mask_args = (automaton, mstates, mnext, mbits, mterm, mbias)
+        if self.mtp:
+            return self._run_chunk_mtp(
+                params, cache, tokens, positions, done, budgets, stop_table, temps,
+                top_ps, seeds, use_seed, rng, mask_args, (page_table, reserved), n_steps, mtp)
         return self._run_chunk_ee(
             params, cache, tokens, positions, done, budgets, stop_table, temps,
             top_ps, seeds, use_seed, rng, mask_args, (page_table, reserved),
             n_steps)
 
     @partial(jax.jit, static_argnames=("self",))
-    def _fresh_unpack_fn(self, packed, base_rng, block):
+    def _fresh_unpack_fn(self, packed, base_rng, block, mtp=None):
         """Fresh entry into the early-exit chunk, dense or paged (ISSUE
         25): the host's whole decode state rides ONE packed int32 buffer
         (``_fresh_pack``); this small program slices it apart and folds
@@ -1443,10 +1508,18 @@ class Engine:
         done, where the host will finish it once it has seen the token."""
         d = self._fresh_pack.unpack(packed)
         rng = jax.random.fold_in(base_rng, d["counter"][0])
-        tokens = jnp.where(d["from_block"], block[0].astype(jnp.int32), d["tokens"])
+        positions, budgets = d["positions"], d["budgets"]
+        if self.mtp:
+            # (ISSUE 56) every row's pending token and its position are what the
+            # round dispatched last left on the device; a flagged row's round is
+            # unfetched, and the host budgeted it one token: it emitted ``cnt``.
+            tokens, positions = mtp["tok"], mtp["pos"]
+            budgets = budgets - jnp.where(d["from_block"], mtp["cnt"] - 1, 0)
+        else:
+            tokens = jnp.where(d["from_block"], block[0].astype(jnp.int32), d["tokens"])
         stopped = jnp.any(d["stop_table"] == tokens[:, None], axis=-1)
         done = d["done"] | (d["from_block"] & stopped)
-        carry = (tokens, d["positions"], d["mstates"], done, d["budgets"], rng)
+        carry = (tokens, positions, d["mstates"], done, budgets, rng)
         sampling = (d["temps"], d["top_ps"], d["seeds"], d["use_seed"], d["stop_table"])
         tables = (d["page_table"], d["reserved"]) if self.paged else None
         return carry, sampling, tables
@@ -1506,9 +1579,253 @@ class Engine:
                 return toks, logprobs, cache, jnp.concatenate([stats, _experts_read(per, m), more])
         return toks, logprobs, cache
 
+    # -- a model that drafts with its own next-token module (ISSUE 56) ---
+    def _mtp_round(self, params, cache, tokens, positions, write_idx, page_table, q_starts, q_lens, kv_lens,
+                   decode, ends, next_tokens, tok_valid, draft, qp, qi, temps, top_ps, seeds, use_seed, rng,
+                   ms, mask_tail, pair):
+        """One ROUND of a model that drafts for itself, traced inside both step
+        programs. The packed tokens (T,) hold, for a row that decodes, TWO
+        queries: its pending token at position p and the module's draft at p + 1
+        (``draft`` (S,), drawn from the strip ``qp`` / ``qi`` the round before
+        left); for a prompt's chunk, its tokens. The model runs on all of them
+        and gives logits at each row's first and last query; a decoding row's
+        draft is accepted or refused by ``speculative.spec_accept`` against the
+        module's strip (a greedy row: accepted where it IS the argmax) and the
+        row emits [draft, a token from the second distribution] or [a token from
+        the first's residual]: its second query's cache row is then dropped by
+        position, the next round writes p + 1 again. A row whose prompt ends here
+        (``ends``) samples its first token as every model's mixed step does. The
+        module then runs over the same packed tokens, each with the token that
+        FOLLOWS it (``next_tokens``, the host's for a prompt's own; the emitted
+        ones where they were just decided; a refused draft's query left out), and
+        leaves every live row's next draft and its strip.
+
+        Returns (cache, emitted tokens (2, S), their logprobs (2, S), how many
+        each row emitted (S,: 0, 1 or 2), which drafts were accepted (S,), the new
+        draft, strip probabilities and strip ids, the automaton's new states, the
+        mixture's auxiliary with the module's layer counted in)."""
+        from inference_gateway_tpu.models import mixtral
+        from inference_gateway_tpu.serving.speculative import spec_accept, strip_dist, strip_sample
+
+        cfg = self.model_cfg
+        automaton, mnext, mbits, mbias = mask_tail
+        T, S = tokens.shape[0], q_starts.shape[0]
+        k = effective_top_k(self.config.top_k, cfg.vocab_size)
+        stream, cache, aux = self._model.forward_ragged(
+            params, cfg, tokens[None], positions[None], cache, write_idx[None], page_table, q_starts, q_lens,
+            kv_lens, mesh=None, with_aux=True, hidden=True, tok_valid=tok_valid, pair=pair)
+        with jax.named_scope("carry"):
+            live = q_lens > 0
+            first = jnp.clip(q_starts, 0, T - 1)
+            last = jnp.clip(q_starts + q_lens - 1, 0, T - 1)
+        logits = mixtral.head_logits(params, cfg, stream[jnp.stack([first, last], axis=1)], params["final_norm"])
+        with jax.named_scope("mask_bias"):
+            # Under a grammar the first query is masked by the row's state, the
+            # second by the state behind the draft.
+            ms2 = jnp.stack([ms, mnext[ms, draft]], axis=1) if automaton else ms
+            logits = self._bias_logits(logits, mbias, None, mbits, ms2, automaton)
+        with jax.named_scope("sampling"):
+            greedy = temps <= 1e-4
+            # A round's draws come from (the row's key, the pending token's position):
+            # a seeded stream is the same whichever program ran each of its rounds.
+            base = jnp.where(use_seed[:, None], jax.vmap(jax.random.PRNGKey)(seeds),
+                             jax.vmap(lambda i: jax.random.fold_in(rng, i))(jnp.arange(S)))
+            keys = jax.vmap(jax.random.fold_in)(base, kv_lens - q_lens)
+            draw = lambda n, f: jax.vmap(lambda key: f(jax.random.fold_in(key, n)))(keys)
+            uniform = draw(0, lambda key: jax.random.uniform(key, (1,)))
+            extra_gumbel = draw(1, lambda key: jax.random.gumbel(key, (k,)))
+            draft_gumbel = draw(2, lambda key: jax.random.gumbel(key, (k,)))
+            p_probs, p_idx = strip_dist(logits, jnp.broadcast_to(temps[:, None], (S, 2)),
+                                        jnp.broadcast_to(top_ps[:, None], (S, 2)), k)
+            out, count = spec_accept(p_probs, p_idx, qp[:, None], qi[:, None], draft[:, None], uniform,
+                                     extra_gumbel, greedy)
+            # A greedy row is the model's argmax stream token for token, ties as
+            # jnp.argmax breaks them in every other program.
+            top = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            hit = draft == top[:, 0]
+            out = jnp.where(greedy[:, None], jnp.where(hit[:, None], jnp.stack([draft, top[:, 1]], axis=1),
+                                                       jnp.stack([top[:, 0], top[:, 0]], axis=1)), out)
+            count = jnp.where(greedy, 1 + hit.astype(jnp.int32), count)
+            # A second query past the cache row's end was never a position: refused.
+            count = jnp.where(kv_lens > self.config.max_seq_len, 1, count)
+            if pair != "only":
+                # A prompt's row (a mixed step's; a decode chunk has none): the token
+                # behind its last query, as every model's mixed step samples it.
+                row_keys = per_row_keys(rng, seeds, use_seed, kv_lens)
+                first_tok = sample_tokens(logits[:, 1], rng, temps, top_ps, top_k=self.config.top_k, row_keys=row_keys)
+                out = jnp.where(decode[:, None], out, first_tok.astype(jnp.int32)[:, None])
+            count = jnp.where(decode, count, 1) * live
+            accepted = decode & live & (count == 2)
+            lp = jnp.take_along_axis(jax.nn.log_softmax(logits, axis=-1), out[:, :, None], axis=2)[:, :, 0]
+            lp = jnp.where(decode[:, None], lp, lp[:, 1:])   # a prompt's row: its one token under the LAST query's logits
+        with jax.named_scope("carry"):
+            # What the module reads: each packed token beside the token that follows
+            # it. A decoding row's are the two just emitted; a row whose prompt ends
+            # here, its first token behind the last one's.
+            refused = decode & live & (count == 1)
+            at = lambda rows, idx: jnp.where(rows, idx, T)
+            follows = next_tokens.at[at(decode & live, first)].set(out[:, 0], mode="drop")
+            follows = follows.at[at(accepted, last)].set(out[:, 1], mode="drop")
+            follows = follows.at[at(ends & live, last)].set(out[:, 0], mode="drop")
+            m_lens = q_lens - refused
+            m_kv = kv_lens - refused
+            m_write = write_idx.at[at(refused, last)].set(self._flat_size, mode="drop")
+            m_valid = tok_valid.at[at(refused, last)].set(False, mode="drop")
+            nms = ms
+            if automaton:
+                after = mnext[ms, out[:, 0]]
+                nms = jnp.where(live & (decode | ends), jnp.where(accepted, mnext[after, out[:, 1]], after), ms)
+        block, cache, module_rows = mixtral.mtp_ragged(
+            params, cfg, stream, follows, positions, cache, m_write, page_table, q_starts, m_lens, m_kv, m_valid,
+            mesh=None, pair=pair)
+        d_logits = mixtral.head_logits(params, cfg, block[jnp.clip(q_starts + m_lens - 1, 0, T - 1)],
+                                       params["mtp"]["final_norm"])
+        with jax.named_scope("mask_bias"):
+            d_logits = self._bias_logits(d_logits, mbias, None, mbits, nms, automaton)
+        with jax.named_scope("sampling"):
+            n_qp, n_qi = strip_dist(d_logits, temps, top_ps, k)
+            n_draft = strip_sample(n_qp, n_qi, draft_gumbel, greedy).astype(jnp.int32)
+        with jax.named_scope("carry"):
+            keep = lambda new, old: jnp.where(live.reshape((S,) + (1,) * (new.ndim - 1)), new, old)
+            per, more = aux
+            aux = (jnp.concatenate([per, module_rows[None]]), more)
+        return (cache, out.T, lp.T, count, accepted, keep(n_draft, draft), keep(n_qp, qp), keep(n_qi, qi), nms, aux)
+
+    def _mtp_stats(self, aux, m: int, count, accepted, decode, mixed: bool):
+        """A drafting model's rows of the fetch block: the mixture's own
+        (``MIXED_STATS`` or ``CHUNK_EXPERT_STATS``, then LATENT_STATS), then MTP_STATS."""
+        per, more = aux
+        lead = ([jnp.mean(jnp.max(per, axis=1).astype(jnp.float32)), jnp.mean(per.astype(jnp.float32))] if mixed else [])
+        rounds = decode & (count > 0)
+        return jnp.concatenate([jnp.stack(lead) if lead else jnp.zeros((0,), jnp.float32), _experts_read(per, m), more,
+                                jnp.stack([jnp.sum(rounds), jnp.sum(accepted),
+                                           jnp.sum(jnp.where(rounds, count, 0))]).astype(jnp.float32)])
+
+    def _pair_positions(self, page_table, pos):
+        """A decoding row's two positions (S, 2), p and p + 1 (p held inside the cache
+        row), and the flat write index of each from the row's pages; out of bounds
+        past the row's end."""
+        ps, max_len = self.config.page_size, self.config.max_seq_len
+        two = jnp.minimum(pos, max_len - 1)[:, None] + jnp.arange(2, dtype=jnp.int32)[None, :]
+        page = jnp.take_along_axis(page_table, jnp.minimum(two, max_len - 1) // ps, axis=1)
+        return two, jnp.where(two < max_len, page * ps + two % ps, self._flat_size)
+
+    def _mixed_step_mtp(self, params, cache, d, rng, mtp, mnext, mbits, mbias, automaton):
+        """The mixed step of a model that drafts for itself (ISSUE 56), traced
+        inside ``_mixed_step_fn``: a decoding row's two queries, their positions,
+        write indices and length come from what the round before left on the device
+        (``mtp``), never from the host, which has not fetched that round when it
+        dispatches this one; the host says WHICH rows decode and where their two
+        queries sit in the packed axis, and has reserved pages for the farthest
+        they can have come."""
+        S, T = d["q_starts"].shape[0], d["tokens"].shape[1]
+        with jax.named_scope("carry"):
+            decode, q_starts = d["mtp_decode"], d["q_starts"]
+            pos = jnp.minimum(mtp["pos"], self.config.max_seq_len - 1)
+            two, w = self._pair_positions(d["page_table"], pos)
+            at = jnp.where(decode[:, None], q_starts[:, None] + jnp.arange(2)[None, :], T)      # undecoded: dropped
+            put = lambda host, mine: host.at[0, at].set(mine, mode="drop")[0]
+            tokens = put(d["tokens"], jnp.stack([mtp["tok"], mtp["draft"]], axis=1))
+            positions = put(d["positions"], two)
+            write_idx = put(d["write_idx"], w)
+            kv_lens = jnp.where(decode, pos + 2, d["kv_lens"])
+            tok_valid = jnp.arange(T) < jnp.sum(d["q_lens"])
+        cache, toks, lps, count, accepted, draft, qp, qi, _nms, aux = self._mtp_round(
+            params, cache, tokens, positions, write_idx, d["page_table"], q_starts, d["q_lens"], kv_lens,
+            decode, d["mtp_ends"], d["next_tokens"][0], tok_valid, mtp["draft"], mtp["qp"], mtp["qi"],
+            d["temps"], d["top_ps"], d["seeds"], d["use_seed"], rng, d["mstates"],
+            (automaton, mnext, mbits, mbias), "mixed")
+        with jax.named_scope("carry"):
+            # The rows that now hold a pending token: the decoding ones and those whose
+            # prompt ended. Their entry is where the next program starts them from.
+            moved = (decode | d["mtp_ends"]) & (count > 0)
+            last_tok = jnp.where(count == 2, toks[1], toks[0])
+            new_pos = jnp.where(decode, pos + count, kv_lens)
+            mtp = {"tok": jnp.where(moved, last_tok, mtp["tok"]), "pos": jnp.where(moved, new_pos, mtp["pos"]),
+                   "draft": draft, "qp": qp, "qi": qi, "cnt": jnp.where(moved, count, mtp["cnt"])}
+            stats = self._mtp_stats(aux, T * self.model_cfg.experts_per_token, count, accepted, decode, mixed=True)
+            block = jnp.concatenate([jnp.stack([toks[0].astype(jnp.float32), lps[0], toks[1].astype(jnp.float32), lps[1],
+                                                count.astype(jnp.float32)]),
+                                     jnp.broadcast_to(stats[:, None], (stats.shape[0], S))])
+        return block, cache, mtp
+
+    def _run_chunk_mtp(self, params, cache, tokens, positions, done, budgets, stop_table, temps, top_ps, seeds,
+                       use_seed, rng, mask_args, write_args, n_steps, mtp):
+        """``_run_chunk_ee`` for a model that drafts for itself (ISSUE 56): up to
+        ``n_steps`` ROUNDS, each of every live row's two queries through the model and
+        the module (``_mtp_round``); a row emits one or two tokens a round and its
+        position, budget and stop state move by what it emitted, on the device. The
+        fetch block: the rounds' tokens (2 n, S) and logprobs (2 n, S), how many of
+        each round's two a row emitted (n, S), the stats' rows, and the rows' final
+        positions (1, S), by which the host tightens its page horizon."""
+        automaton, mstates = mask_args[0], mask_args[1]
+        mnext, mbits, mterm, mbias = mask_args[2:]
+        page_table, reserved = write_args
+        S = tokens.shape[0]
+        max_len = self.config.max_seq_len
+        with jax.named_scope("carry"):
+            done0 = self._chunk_done0(tokens, positions, done, budgets, stop_table, mstates, mterm, automaton)
+            zeros = jnp.zeros((n_steps, 2, S), jnp.float32)
+            starts = 2 * jnp.arange(S, dtype=jnp.int32)
+            nothing = jnp.zeros((2 * S,), jnp.int32)
+            no_row = jnp.zeros((S,), bool)
+
+        def cond(carry):
+            with jax.named_scope("carry"):
+                return (carry[0] < n_steps) & jnp.any(~carry[5])
+
+        def body(carry):
+            i, cache, tok, pos, ms, done, bud, draft, qp, qi, out_t, out_l, out_c, read_sum = carry
+            with jax.named_scope("carry"):
+                p = jnp.minimum(pos, max_len - 1)
+                two, w = self._pair_positions(page_table, pos)
+                w = jnp.where((~done)[:, None] & (two < reserved[:, None]), w, self._flat_size)
+                q_lens = jnp.where(done, 0, 2)
+            cache, toks, lps, count, accepted, draft, qp, qi, nms, aux = self._mtp_round(
+                params, cache, jnp.stack([tok, draft], axis=1).reshape(-1), two.reshape(-1), w.reshape(-1), page_table,
+                starts, q_lens, jnp.where(done, 0, p + 2), ~done, no_row, nothing, jnp.repeat(~done, 2),
+                draft, qp, qi, temps, top_ps, seeds, use_seed, jax.random.fold_in(rng, i), ms,
+                (automaton, mnext, mbits, mbias), "only")
+            with jax.named_scope("carry"):
+                # A stop id or the budget's end at the first emitted token discards the second.
+                stop = jnp.any(stop_table[:, None, :] == toks.T[:, :, None], axis=-1)             # (S, 2)
+                count = jnp.where(stop[:, 0], jnp.minimum(count, 1), jnp.minimum(count, bud))
+                count = jnp.where(done, 0, count)
+                accepted = accepted & (count == 2)
+                last_tok = jnp.where(count == 2, toks[1], toks[0])
+                npos, nbud = pos + count, bud - count
+                ndone = stop[:, 0] | (stop[:, 1] & (count == 2)) | (nbud <= 0) | (npos + 1 >= max_len)
+                if automaton:
+                    ndone = ndone | mterm[nms]
+                tok = jnp.where(done, tok, last_tok)
+                pos = jnp.where(done, pos, npos)
+                ms = jnp.where(done, ms, nms)
+                bud = jnp.where(done, bud, nbud)
+                stats = self._mtp_stats(aux, 2 * S * self.model_cfg.experts_per_token, count, accepted, ~done, mixed=False)
+                out_t = jax.lax.dynamic_update_index_in_dim(out_t, toks.astype(jnp.float32), i, 0)
+                out_l = jax.lax.dynamic_update_index_in_dim(out_l, lps, i, 0)
+                out_c = jax.lax.dynamic_update_index_in_dim(out_c, count.astype(jnp.float32), i, 0)
+                return (i + 1, cache, tok, pos, ms, done | ndone, bud, draft, qp, qi, out_t, out_l, out_c,
+                        read_sum + stats)
+
+        (i_ran, cache, tok_f, pos_f, ms_f, done_f, bud_f, draft, qp, qi, out_t, out_l, out_c, read_sum) = \
+            jax.lax.while_loop(cond, body, (
+                jnp.int32(0), cache, tokens, positions, mstates, done0, budgets, mtp["draft"], mtp["qp"], mtp["qi"],
+                zeros, zeros, jnp.zeros((n_steps, S), jnp.float32),
+                jnp.zeros((len(self._chunk_stats),), jnp.float32)))
+        with jax.named_scope("carry"):
+            stats = read_sum.at[:len(CHUNK_EXPERT_STATS)].divide(jnp.maximum(i_ran, 1).astype(jnp.float32))
+            block = jnp.concatenate([out_t.reshape(2 * n_steps, S), out_l.reshape(2 * n_steps, S), out_c,
+                                     jnp.broadcast_to(stats[:, None], (stats.shape[0], S)),
+                                     pos_f.astype(jnp.float32)[None, :]])
+            last = jnp.where(i_ran > 0, out_c[jnp.maximum(i_ran - 1, 0)].astype(jnp.int32), mtp["cnt"])
+            mtp = {"tok": tok_f, "pos": pos_f, "draft": draft, "qp": qp, "qi": qi, "cnt": last}
+            rng_next = jax.random.fold_in(rng, n_steps + 1)
+        return block, tok_f, pos_f, ms_f, done_f, bud_f, rng_next, cache, mtp
+
     @partial(jax.jit, static_argnames=("self", "automaton"), donate_argnums=(2,))
     def _mixed_step_fn(self, params, cache, packed, base_rng, block,
-                       mnext=None, mbits=None, mbias=None, automaton=False):
+                       mnext=None, mbits=None, mbias=None, automaton=False, mtp=None):
         """The mixed step as the submit dispatches it (ISSUE 25): every
         descriptor rides ONE packed int32 buffer (``_mixed_pack``), the
         step key is folded from the engine's base key and the counter in
@@ -1518,10 +1835,17 @@ class Engine:
         flagged ``from_block`` has one query, and its token is that block's
         for its slot (a token id is exact in float32 below 2**24), so the
         host builds and dispatches this step before it has fetched that
-        one. Nothing else of a row depends on the token."""
+        one. Nothing else of a row depends on the token, except in a model
+        that drafts for itself (ISSUE 56): a decoding row's position depends
+        on what was accepted, so such an engine hands ``mtp``, what the round
+        before left on the device, gets the new one back as a third result,
+        and reads no ``block`` (``_mixed_step_mtp``)."""
         with jax.named_scope("carry"):  # the packed descriptors taken apart, the step's key, the flagged rows' tokens
             d = self._mixed_pack.unpack(packed)
             rng = jax.random.fold_in(base_rng, d["counter"][0])
+        if self.mtp:
+            return self._mixed_step_mtp(params, cache, d, rng, mtp, mnext, mbits, mbias, automaton)
+        with jax.named_scope("carry"):
             at = jnp.where(d["from_block"], d["q_starts"], d["tokens"].shape[1])  # unflagged: dropped
             tokens = d["tokens"].at[0, at].set(block[0].astype(jnp.int32), mode="drop")
         toks, logprobs, cache, *stats = self._mixed_step_body(
@@ -1587,8 +1911,9 @@ class Engine:
                 for r in rows:
                     n = len(r.token_ids)
                     self._ensure_with_evict(r.slot, r.start + n)
-                    write_idx[0, off:off + n] = self.allocator.flat_write_indices(
-                        r.slot, r.start, n)
+                    if not (self.mtp and r.kind == "decode"):   # (a drafting model's decoding rows: on the device)
+                        write_idx[0, off:off + n] = self.allocator.flat_write_indices(
+                            r.slot, r.start, n)
                     off += n
                 v["page_table"][...] = self.allocator.page_table()
             with phase_span(phases, "assemble", "engine.mixed.assemble"):
@@ -1612,8 +1937,22 @@ class Engine:
                         use_seed[r.slot] = True
                     mstates[r.slot] = r.mask_state
                     if r.from_block:
-                        assert n == 1, (r.slot, n)
+                        assert n == self.round_tokens, (r.slot, n)
                         v["from_block"][r.slot] = True
+                    if self.mtp:
+                        # A decoding row's two queries are filled in on the device. A
+                        # prompt's chunk hands the module each token's successor: its own
+                        # next, and behind its last the prompt's next (``next_token``;
+                        # None where the prompt ends here: the token sampled).
+                        if r.kind == "decode":
+                            assert n == 2, (r.slot, n)
+                            v["mtp_decode"][r.slot] = True
+                        else:
+                            v["next_tokens"][0, off:off + n - 1] = r.token_ids[1:]
+                            if r.next_token is None:
+                                v["mtp_ends"][r.slot] = True
+                            else:
+                                v["next_tokens"][0, off + n - 1] = r.next_token
                     off += n
                     if r.kind == "prefill":
                         n_prefill += n
@@ -1623,10 +1962,15 @@ class Engine:
                 automaton, mnext, mbits, mbias = self._mask_args()
                 v["counter"][0] = self._bump_step()
                 staged = pack.snapshot()
-                both, self.cache = self._mixed_step_fn(
-                    self.params, self.cache, jnp.asarray(staged), self._rng, self._last_block,
-                    mnext=mnext, mbits=mbits, mbias=mbias, automaton=automaton)
-                self._last_block = both
+                if self.mtp:
+                    both, self.cache, self._mtp = self._mixed_step_fn(
+                        self.params, self.cache, jnp.asarray(staged), self._rng, self._last_block,
+                        mnext=mnext, mbits=mbits, mbias=mbias, automaton=automaton, mtp=self._mtp)
+                else:
+                    both, self.cache = self._mixed_step_fn(
+                        self.params, self.cache, jnp.asarray(staged), self._rng, self._last_block,
+                        mnext=mnext, mbits=mbits, mbias=mbias, automaton=automaton)
+                    self._last_block = both
             # Positions moved outside the chained-carry bookkeeping.
             self._dev_carry = None
             n_decode_tokens = total - n_prefill
@@ -1642,12 +1986,18 @@ class Engine:
 
     def mixed_step_fetch(self, handle: "MixedStepHandle"):
         """Block until a mixed step's sampled tokens are on host.
-        Returns (tokens, logprobs) as numpy (max_slots,), row == slot."""
+        Returns (tokens, logprobs) as numpy (max_slots,), row == slot; from a
+        model that drafts for itself (ISSUE 56) (2, max_slots) each, a row's
+        one or two tokens, with ``handle.counts`` (max_slots,) how many."""
         both, = _fetch_blocks(handle.phases, "mixed", handle.toks_lp)
         self._audit_transfer("d2h", "mixed", both)
-        if both.shape[0] > 2:
+        lead = 5 if self.mtp else 2
+        if both.shape[0] > lead:
             handle.stats = {**(handle.stats or {}),
-                            **{k: round(float(v), 3) for k, v in zip(self._mixed_stats, both[2:, 0])}}
+                            **{k: round(float(v), 3) for k, v in zip(self._mixed_stats, both[lead:, 0])}}
+        if self.mtp:
+            handle.counts = both[4].astype(np.int32)
+            return both[0:4:2].astype(np.int32), both[1:4:2]
         return both[0].astype(np.int32), both[1]
 
     def attn_pages(self, rows: "list[MixedRow]") -> tuple[int, int]:
@@ -1748,6 +2098,21 @@ class Engine:
                     "the row's first value_dim values the value, a page fetched once a visit",
             "residual_streams": cfg.hc_mult or 1,
         }
+
+    def mtp_status(self, scheduler=None) -> dict | None:
+        """/debug/status ``mtp`` and ``vocab`` of a model that drafts with its own
+        next-token module (ISSUE 56): the module (static), with a ``scheduler`` what
+        its rounds have come to, and the share of the vocabulary held."""
+        if not self.mtp:
+            return None
+        cfg = self.model_cfg
+        mtp = {"layers": cfg.mtp_layers, "drafts": "one token a row a round, from the model's own next-token module",
+               "verified": "as the row's second query, in both step programs; accepted by speculative.spec_accept"}
+        if scheduler is not None:
+            drafted = scheduler.spec_slot_rounds
+            mtp.update(drafted=drafted, accepted=scheduler.spec_accepted,
+                       tokens_per_round=round(scheduler.spec_emitted / drafted, 3) if drafted else None)
+        return {"mtp": mtp, "vocab": {"held": cfg.vocab_size, "published": cfg.vocab_published or cfg.vocab_size}}
 
     def dsa_status(self) -> dict | None:
         """/debug/status ``dsa`` of a model with a sparse-attention indexer:
@@ -2249,8 +2614,9 @@ class Engine:
         ps = self.config.page_size
         max_len = self.config.max_seq_len
         depth = max(self.config.pipeline_depth, 1)
-        cap = np.minimum(self._pred_pos + n * (depth + 1), max_len)
-        base = np.minimum(self._pred_pos + n, max_len)
+        per = self.round_tokens   # (two positions a round where the model drafts for itself: ISSUE 56)
+        cap = np.minimum(self._pred_pos + per * n * (depth + 1), max_len)
+        base = np.minimum(self._pred_pos + per * n, max_len)
         for slot in np.nonzero(need)[0]:
             s = int(slot)
             try:
@@ -2303,7 +2669,7 @@ class Engine:
             # excluding them keeps the reservation check from re-firing
             # every chunk once pred_pos runs past max_seq_len.
             need = (self._chain_active
-                    & (self._pred_pos + n > self._reserved)
+                    & (self._pred_pos + self.round_tokens * n > self._reserved)
                     & (self._pred_pos < self.config.max_seq_len))
             if need.any():
                 self._reserve_chain_horizon(need, n)
@@ -2319,28 +2685,38 @@ class Engine:
         tok_in, pos_in, ms_in, done_in, bud_in, rng = self._dev_carry
         temps_d, tps_d, seeds_d, used_d, stop_d = self._dev_sampling
         automaton, mnext, mbits, mterm, mbias = self._mask_args_ee()
-        if self.paged:
+        chunk = dict(mstates=ms_in, mnext=mnext, mbits=mbits, mterm=mterm, mbias=mbias, n_steps=n, automaton=automaton)
+        if self.mtp:
+            # Rounds (ISSUE 56): the program builds the fetch block itself and hands the
+            # carried draft state on; a row moves one or two positions a round.
+            both, tok_f, pos_f, ms_f, done_f, bud_f, rng_f, self.cache, self._mtp = \
+                self._decode_chunk_fn_paged_ee(
+                    self.params, self.cache, tok_in, pos_in, done_in, bud_in,
+                    stop_d, self._dev_page_table, self._dev_reserved,
+                    temps_d, tps_d, seeds_d, used_d, rng, mtp=self._mtp, **chunk)
+            self._chunks_sent += 1
+        elif self.paged:
             toks, logprobs, tok_f, pos_f, ms_f, done_f, bud_f, rng_f, self.cache = \
                 self._decode_chunk_fn_paged_ee(
                     self.params, self.cache, tok_in, pos_in, done_in, bud_in,
                     stop_d, self._dev_page_table, self._dev_reserved,
-                    temps_d, tps_d, seeds_d, used_d, rng,
-                    mstates=ms_in, mnext=mnext, mbits=mbits, mterm=mterm,
-                    mbias=mbias, n_steps=n, automaton=automaton)
+                    temps_d, tps_d, seeds_d, used_d, rng, **chunk)
         else:
             toks, logprobs, tok_f, pos_f, ms_f, done_f, bud_f, rng_f, self.cache = \
                 self._decode_chunk_fn_ee(
                     self.params, self.cache, tok_in, pos_in, done_in, bud_in,
-                    stop_d, temps_d, tps_d, seeds_d, used_d, rng,
-                    mstates=ms_in, mnext=mnext, mbits=mbits, mterm=mterm,
-                    mbias=mbias, n_steps=n, automaton=automaton)
-        self._pred_pos = self._pred_pos + n * self._chain_active
+                    stop_d, temps_d, tps_d, seeds_d, used_d, rng, **chunk)
+        if not self.mtp:
+            both = jnp.concatenate([toks.astype(jnp.float32), logprobs], axis=0)
+        self._pred_pos = self._pred_pos + self.round_tokens * n * self._chain_active
         self._dev_carry = (tok_f, pos_f, ms_f, done_f, bud_f, rng_f)
         n_active = int(self._chain_active.sum())
         self.metrics["decode_tokens"] += n_active * n
         self.metrics["decode_steps"] += n
-        both = jnp.concatenate([toks.astype(jnp.float32), logprobs], axis=0)
-        return _DecodeChunkHandle(both, n, stats=self._state_stats(n_active))
+        handle = _DecodeChunkHandle(both, n, stats=self._state_stats(n_active))
+        if self.mtp:
+            handle.epoch, handle.sent = self._chain_epoch, self._chunks_sent
+        return handle
 
     def _fresh_submit_ee_locked(self, tokens, positions, active, temps, top_ps,
                                 n, seeds, use_seed, mstates, stop_tables, budgets,
@@ -2387,8 +2763,10 @@ class Engine:
             v["reserved"][:] = self._reserved
         v["counter"][0] = self._bump_step()
         staged = pack.snapshot()
+        if self.mtp:
+            self._chain_epoch += 1
         self._dev_carry, self._dev_sampling, tables = self._fresh_unpack_fn(
-            jnp.asarray(staged), self._rng, self._last_block)
+            jnp.asarray(staged), self._rng, self._last_block, **({"mtp": self._mtp} if self.mtp else {}))
         if self.paged:
             self._dev_page_table, self._dev_reserved = tables
         handle = self._dispatch_chunk_ee_locked(n)
@@ -2776,6 +3154,20 @@ class Engine:
         both, = _fetch_blocks(handle.phases, "chunk", handle.toks_lp)
         self._audit_transfer("d2h", "chunk", both)
         n = handle.n_steps
+        if self.mtp:
+            # (ISSUE 56) rounds: 2 n rows of tokens (a round's two, one under the
+            # other), 2 n of logprobs, n of counts, the stats, the final positions.
+            handle.counts = both[4 * n:5 * n].astype(np.int32)
+            handle.stats = {**(handle.stats or {}),
+                            **{k: round(float(v), 3) for k, v in zip(self._chunk_stats, both[5 * n:-1, 0])}}
+            with self._lock:
+                if handle.epoch == self._chain_epoch:
+                    # The horizon's mirror counted two positions a round; the rows came
+                    # this far, and the chunks dispatched since move them 2 n each at most.
+                    ahead = 2 * n * (self._chunks_sent - handle.sent)
+                    self._pred_pos = np.where(self._chain_active,
+                                              np.minimum(self._pred_pos, both[-1].astype(np.int64) + ahead), self._pred_pos)
+            return both[:2 * n].astype(np.int32), both[2 * n:4 * n]
         if both.shape[0] > 2 * n:
             handle.stats = {**(handle.stats or {}),
                             **{k: round(float(v), 3) for k, v in zip(self._chunk_stats, both[2 * n:, 0])}}

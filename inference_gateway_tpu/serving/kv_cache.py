@@ -152,8 +152,13 @@ class PrefixCache:
     """
 
     def __init__(self, allocator: PageAllocator, max_cached_pages: int | None = None,
-                 decline_hits: bool = False):
+                 decline_hits: bool = False, successor: bool = False):
         from collections import OrderedDict
+
+        # A model whose next-token module caches, a position, a row that depends on
+        # the token BEHIND it (ISSUE 56): a page is the same only where the token
+        # behind its last is too, so an entry's tokens are the page's and that one.
+        self.successor = int(successor)
 
         # A model that keeps a recurrent state per slot (models/phi4flash.py)
         # has nothing to hand over with the pages: the cache still learns the
@@ -187,7 +192,7 @@ class PrefixCache:
         chain = b""
         n_full = (len(prompt) - 1) // ps  # last token never comes from cache
         for i in range(n_full):
-            chunk = tuple(prompt[i * ps:(i + 1) * ps])
+            chunk = tuple(prompt[i * ps:(i + 1) * ps + self.successor])
             chain = self._chain(chain, chunk)
             entry = self._entries.get(chain)
             if entry is None or entry[1] != chunk:  # exact-token guard
@@ -211,9 +216,9 @@ class PrefixCache:
         """Register the request's full prefix pages for reuse."""
         ps = self.page_size
         chain = b""
-        n_full = min(len(prompt) // ps, len(slot_pages))
+        n_full = min((len(prompt) - self.successor) // ps, len(slot_pages))
         for i in range(n_full):
-            chunk = tuple(prompt[i * ps:(i + 1) * ps])
+            chunk = tuple(prompt[i * ps:(i + 1) * ps + self.successor])
             chain = self._chain(chain, chunk)
             if chain in self._entries:
                 self._entries.move_to_end(chain)

@@ -133,7 +133,10 @@ def mixtral_param_count(cfg: mixtral.MixtralConfig) -> int:
         per_layer += H * (Hi * Di + Di + Hi) + 2 * Di
     dense_layer = 3 * H * cfg.dense_intermediate_size
     head = 0 if cfg.tie_word_embeddings else V * H
-    return V * H + mixers + cfg.expert_layers * per_layer + cfg.num_dense_layers * dense_layer + H + head
+    # The next-token module (ISSUE 56): one more expert layer (its attention is among ``cache_layers``) with its
+    # two input norms, the two norms in front of W_eh, W_eh and its own final norm.
+    module = cfg.mtp_layers * (per_layer + 2 * H + 2 * H + 2 * H * H + H)
+    return V * H + mixers + cfg.expert_layers * per_layer + cfg.num_dense_layers * dense_layer + H + head + module
 
 
 def kv_bytes_per_token(cfg, dtype_bytes: int = 2) -> int:

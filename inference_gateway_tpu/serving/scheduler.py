@@ -297,6 +297,7 @@ class Scheduler:
         self.spec_rounds = 0
         self.spec_emitted = 0
         self.spec_slot_rounds = 0
+        self.spec_accepted = 0   # drafts accepted, of a model that drafts for itself (ISSUE 56)
         # Acceptance-adaptive n-gram speculation (EngineConfig
         # spec_adaptive): rolling window + probe state machine.
         self._spec_on = True
@@ -1142,6 +1143,12 @@ class Scheduler:
         from inference_gateway_tpu.serving.engine import MixedRow
 
         budget = self.engine.mixed_budget
+        # A model that drafts for itself (ISSUE 56): a decoding row has TWO queries,
+        # its pending token and the draft, both on the device with their positions;
+        # an unfetched round moved the row by one or two, so ``pos`` below is the
+        # least it can have come to (what a budget is foreseen by) and the row's
+        # ``start`` the farthest (what its pages are reserved for).
+        per = getattr(self.engine, "round_tokens", 1)
         rows: list = []
         used = 0
         context = 0
@@ -1152,6 +1159,16 @@ class Scheduler:
             req = st.req
             pos = st.pos + (lag or 0)
             if lag is not None and self._last_by_budget(req, pos, st.generated + lag):
+                continue
+            if per == 2:
+                start = min(st.pos + 2 * (lag or 0), self.engine.config.max_seq_len - 2)
+                rows.append(MixedRow(
+                    slot=slot, token_ids=[0, 0], start=start, kind="decode", temp=req.temperature,
+                    top_p=req.top_p, seed=req.seed,
+                    mask_state=req.grammar.global_state if req.grammar is not None else 0,
+                    from_block=lag is not None))
+                used += 2
+                context += pos + 2
                 continue
             rows.append(MixedRow(
                 slot=slot, token_ids=[0 if lag is not None else st.pending_token], start=pos,
@@ -1171,7 +1188,9 @@ class Scheduler:
             rows.append(MixedRow(
                 slot=slot, token_ids=req.prompt_ids[done:done + take], start=done,
                 kind="prefill", temp=req.temperature, top_p=req.top_p, seed=req.seed,
-                mask_state=req.grammar.global_state if req.grammar is not None else 0))
+                mask_state=req.grammar.global_state if req.grammar is not None else 0,
+                # (a drafting model's module reads each token beside its successor)
+                next_token=req.prompt_ids[done + take] if per == 2 and remaining > take else None))
             used += take
             context += done + take
             item["done"] = done + take
@@ -1489,19 +1508,23 @@ class Scheduler:
         if observing:
             self._stamp_fetch()
         emitted = 0
+        # A drafting model's step (ISSUE 56): (2, S) tokens and how many of them a
+        # row emitted; every other model's: one token a row.
+        counts = getattr(step.handle, "counts", None)
+        if counts is not None:
+            self._count_rounds(step.handle.stats)
+        else:
+            toks, logprobs = toks[None], logprobs[None]
         with phase_span(phases, "emit", "sched.emit"):
             for row in step.rows[:step.n_decode]:
                 st = step.states[row.slot]
                 retired = step.retired.get(row.slot) is st
                 if not retired and self._slots.get(row.slot) is not st:
-                    self._wasted(WASTE_OVERSHOOT, 1)
+                    self._wasted(WASTE_OVERSHOOT, len(row.token_ids))
                     continue
-                st.pos += 1
-                st.pending_token = int(toks[row.slot])
-                st.pending_logprob = float(logprobs[row.slot])
-                st.generated += 1
-                emitted += 1
-                finished, reason = self._emit(st, st.pending_token, st.pending_logprob)
+                sent, finished, reason = self._emit_round(st, toks[:, row.slot], logprobs[:, row.slot],
+                                                          1 if counts is None else int(counts[row.slot]))
+                emitted += sent
                 if retired:  # finished by construction: its slot went back at dispatch
                     self._retiring = [x for x in self._retiring if x is not st]
                     self._flush_emits(st.req)
@@ -1518,8 +1541,8 @@ class Scheduler:
                 if self._slots.get(slot) is not st:
                     continue  # failed or preempted while its first token was on the way
                 self.engine.metrics["prefill_batches"] += 1
-                st.pending_token = int(toks[slot])
-                st.pending_logprob = float(logprobs[slot])
+                st.pending_token = int(toks[0, slot])
+                st.pending_logprob = float(logprobs[0, slot])
                 if self.engine.prefix_cache is not None:
                     with self.engine._lock:
                         self.engine.prefix_cache.insert(
@@ -1547,6 +1570,8 @@ class Scheduler:
                 spans["attn_pages_walked"], spans["attn_pages_masked"] = self.engine.attn_pages(wide)
             if not overlapped:
                 spans.pop("submit_ts", None)
+            if counts is not None:
+                spans["rows"] = step.n_decode
             self._record_step(
                 "mixed", t0, n_steps=1, batch=len(step.rows),
                 tokens=emitted, context_tokens=step.context, spans=spans)
@@ -1616,6 +1641,7 @@ class Scheduler:
             from_block = np.zeros((S,), bool)
             eos_id = getattr(self.engine, "_eos_id", None)
             max_pos = self.engine.config.max_seq_len - 1
+            per = getattr(self.engine, "round_tokens", 1)
             for slot, st in self._slots.items():
                 # Only chunks carrying THIS request (state identity, not slot
                 # id) advance its predicted position — a chunk still in
@@ -1629,7 +1655,9 @@ class Scheduler:
                     from_block[slot] = True
                     inflight_steps += lag
                 tokens[slot] = max(st.pending_token, 0)
-                positions[slot] = min(st.pos + inflight_steps, max_pos)
+                # (A drafting model moves up to two positions a round: the farthest the
+                # row can have come, for the page horizon; the device holds where it is.)
+                positions[slot] = min(st.pos + per * inflight_steps, max_pos)
                 active[slot] = True
                 temps[slot] = st.req.temperature
                 top_ps[slot] = st.req.top_p
@@ -1999,6 +2027,12 @@ class Scheduler:
         ctx = sum(s.pos for s in inf.states.values()) if observing else 0
         emitted = 0
         overrun = 0
+        # A drafting model's chunk (ISSUE 56): two rows of the block a round, of which
+        # a row emitted ``counts[round, slot]``.
+        counts = getattr(inf.handle, "counts", None)
+        per = 1 if counts is None else 2
+        if counts is not None:
+            self._count_rounds(getattr(inf.handle, "stats", None))
         with phase_span(phases, "emit", "sched.emit"):
             for slot, snap_st in inf.states.items():
                 st = self._slots.get(slot)
@@ -2015,6 +2049,8 @@ class Scheduler:
                     continue
                 slot_emitted = emitted
                 for j in range(toks.shape[0]):
+                    if counts is not None and j % per >= counts[j // per, slot]:
+                        continue
                     st.pos += 1
                     st.pending_token = int(toks[j, slot])
                     st.pending_logprob = float(logprobs[j, slot])
@@ -2032,7 +2068,7 @@ class Scheduler:
                             # Device-detected finishes froze the row at this
                             # very step (ISSUE 14): the trailing block is
                             # repeats, not computed tokens — zero overrun.
-                            overrun += toks.shape[0] - (j + 1)
+                            overrun += toks.shape[0] - per * (j // per + 1)
                         break
                 if emitted > slot_emitted and slot in self._slots:
                     # One flush per request per CHUNK: a pipelined
@@ -2046,9 +2082,36 @@ class Scheduler:
                               batch=len(inf.states), tokens=emitted,
                               context_tokens=ctx,
                               spans={**(inf.submit or {}), "phases": phases,
+                                     **({"rows": len(inf.states)} if counts is not None else {}),
                                      "h2d_arrays": getattr(inf.handle, "h2d_arrays", 0),
                                      # a mixture's experts_read, from the fetch block
                                      **(getattr(inf.handle, "stats", None) or {})})
+
+    def _emit_round(self, st: _SlotState, toks, logprobs, count: int) -> tuple[int, bool, str | None]:
+        """A mixed step's tokens for one decoding row, in order: one, or from a
+        model that drafts for itself (ISSUE 56) one or two; a finish at the first
+        discards the second. Returns (tokens sent, finished, reason)."""
+        finished, reason = False, None
+        for j in range(count):
+            st.pos += 1
+            st.pending_token = int(toks[j])
+            st.pending_logprob = float(logprobs[j])
+            st.generated += 1
+            finished, reason = self._emit(st, st.pending_token, st.pending_logprob)
+            if finished:
+                return j + 1, finished, reason
+        return count, finished, reason
+
+    def _count_rounds(self, stats: dict | None) -> None:
+        """A drafting model's step, as its program counted it (ISSUE 56; MTP_STATS of
+        the fetch block): the counters /metrics and /debug/status serve, and the
+        refused drafts' positions as wasted work."""
+        drafted, accepted = int((stats or {}).get("mtp_drafted", 0)), int((stats or {}).get("mtp_accepted", 0))
+        self.spec_rounds += 1
+        self.spec_slot_rounds += drafted
+        self.spec_accepted += accepted
+        self.spec_emitted += int((stats or {}).get("mtp_tokens", 0))
+        self._wasted(WASTE_SPEC_REJECTED, drafted - accepted)
 
     def _finish(self, slot: int, req: GenRequest, reason: str | None,
                 frozen: bool = False) -> None:

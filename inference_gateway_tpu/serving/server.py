@@ -567,7 +567,8 @@ class SidecarServer:
             "queue_depth": float(sched.queue_depth),
         }
         spec_rate = None
-        if self.engine.spec and sched.spec_slot_rounds:
+        if (self.engine.spec or getattr(self.engine, "mtp", False)) and sched.spec_slot_rounds:
+            # (a model that drafts for itself feeds these from its step programs: ISSUE 56)
             spec_rate = sched.spec_emitted / sched.spec_slot_rounds
             gauges["spec_tokens_per_slot_round"] = spec_rate
         if self.otel is not None:
@@ -757,11 +758,12 @@ class SidecarServer:
         m = dict(self.engine.metrics)
         m["queue_depth"] = self.scheduler.queue_depth
         m["active_requests"] = self.scheduler.active_requests()
-        if self.engine.spec:
+        if self.engine.spec or getattr(self.engine, "mtp", False):
             # Mean tokens per draft+verify round per slot = 1 + mean
             # accepted draft tokens (the speculative speedup upper bound).
             m["spec_rounds"] = self.scheduler.spec_rounds
             m["spec_emitted_tokens"] = self.scheduler.spec_emitted
+            m["spec_accepted"] = getattr(self.scheduler, "spec_accepted", 0)
             if self.scheduler.spec_slot_rounds:
                 m["spec_tokens_per_slot_round"] = round(
                     self.scheduler.spec_emitted / self.scheduler.spec_slot_rounds, 3)
@@ -974,6 +976,8 @@ class SidecarServer:
             # otherwise. Its `decode` and `mixed` timeline records carry
             # `latent_pages_read` beside `context_pages` and `experts_read`.
             **({"latent_pool": self.engine.latent_status()} if getattr(self.engine, "is_mla", False) else {}),
+            # (ISSUE 56) a model that drafts with its own next-token module: ``mtp`` and ``vocab``.
+            **(self.engine.mtp_status(self.scheduler) if getattr(self.engine, "mtp", False) else {}),
             # A mixture (ISSUE 41): ``router`` (score, chosen_by, weights),
             # ``shared_experts``, ``dense_prefix``, and with windowed layers
             # ``cache_layers`` {window, full}: its `decode` and `mixed` records

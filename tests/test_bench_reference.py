@@ -13,7 +13,8 @@ PR 41's (``bench/tests/test_trinity_files.py``), PR 43's
 (``bench/tests/test_xing4_files.py``), PR 49's reader check
 (``bench/tests/test_post_warmup_compile_reader.py``), PR 50's
 (``bench/tests/test_granite4_files.py``) and PR 54's
-(``bench/tests/test_kimi_linear_files.py``).
+(``bench/tests/test_kimi_linear_files.py``) and PR 56's
+(``bench/tests/test_openpangu_files.py``).
 """
 
 import importlib.util
@@ -87,20 +88,26 @@ _spec.loader.exec_module(kimi_files)
 globals().update({name if name not in globals() else f"{name}_kimi": fn
                   for name, fn in vars(kimi_files).items() if name.startswith("test_")})
 
+_spec = importlib.util.spec_from_file_location("bench_test_openpangu_files", BENCH / "tests" / "test_openpangu_files.py")
+openpangu_files = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(openpangu_files)
+globals().update({name if name not in globals() else f"{name}_openpangu": fn
+                  for name, fn in vars(openpangu_files).items() if name.startswith("test_")})
+
 
 # What later PRs appended after PR 32's entries, by list and in order: PR 36 a
 # configuration, its cell and two readers, PR 38 the idle ledger's five readers,
 # PR 41 a configuration, its cell and two readers, PR 43 the same, PR 47 a
 # configuration, its cell and four readers, PR 49 one reader, PR 50 a
 # configuration, its cell and four readers, PR 52 the six by-scope readers, PR 54
-# a configuration, its cell and four readers. All the cut below may drop.
+# a configuration, its cell and four readers, PR 56 the same. All the cut below may drop.
 APPENDED_SINCE_PR_32 = {"configs": ["keye-vl2-30b-a3b-int8", "trinity-mini-int8", "lfm2-8b-a1b-int8",
                                     "xing4.0-29b-a4b-int8", "granite-4.0-h-small-int8",
-                                    "kimi-linear-48b-a3b-int8"],
+                                    "kimi-linear-48b-a3b-int8", "openpangu-ultra-moe-718b-int8"],
                         "workloads": ["keye-vl2-30b-a3b.long-context", "trinity-mini.long-context",
                                       "lfm2-8b-a1b.reason", "xing4.0-29b-a4b.long-context",
                                       "granite-4.0-h-small.long-context",
-                                      "kimi-linear-48b-a3b.long-context"],
+                                      "kimi-linear-48b-a3b.long-context", "openpangu-ultra-moe-718b.reason"],
                         "per_layer": ["dsa_kv_read_share", "dsa_busy_share",
                                       "fetch_return_ms", "idle_unspanned_share", "starved_slot_share",
                                       "starved_delivery_share", "starved_unspanned_share",
@@ -114,7 +121,9 @@ APPENDED_SINCE_PR_32 = {"configs": ["keye-vl2-30b-a3b-int8", "trinity-mini-int8"
                                       "scope_attention_share", "scope_mlp_share", "scope_moe_share",
                                       "scope_state_share", "scope_head_sampling_share", "scope_unscoped_share",
                                       "kda_busy_share", "kda_chunk_roofline_share",
-                                      "kda_state_hbm_roofline_share", "kimi_mixed_step_mfu"]}
+                                      "kda_state_hbm_roofline_share", "kimi_mixed_step_mfu",
+                                      "mtp_accept_share", "mtp_tokens_per_round",
+                                      "pangu_decode_hbm_roofline_share", "pangu_step_mfu"]}
 
 
 def test_the_benchmark_gains_one_configuration_one_cell_and_three_readers(tmp_path, monkeypatch):
@@ -144,14 +153,15 @@ def test_the_benchmark_gains_one_configuration_one_cell_and_two_readers_after_ke
     """The same STOPGAP for one assertion of PR 41's check: it holds
     ``decode_step_ms`` and ``decode_batch_mean`` to list the seven cells its
     parent had and NO other, which the next cell that makes ``decode`` records
-    ends (PR 43's does: its window is decode steps of 64 rows). The check reads
-    ``BENCHMARK.json`` with PR 43's cell taken off those two lists and nothing
-    else changed; every other assertion of it sees the file as it is."""
+    ends (PR 43's does: its window is decode steps of 64 rows; PR 56's too: rounds
+    of 64 rows). The check reads ``BENCHMARK.json`` with PR 56's and PR 43's cells
+    taken off those two lists and nothing else changed; every other assertion of
+    it sees the file as it is."""
     bench = json.loads((lfm2_files.ROOT / "BENCHMARK.json").read_text())
     for metric in bench["per_layer"]:
         if metric["name"] in ("decode_step_ms", "decode_batch_mean"):
-            assert metric["workloads"][-1] == lfm2_files.CELL, metric
-            metric["workloads"] = metric["workloads"][:-1]
+            assert metric["workloads"][-2:] == [lfm2_files.CELL, openpangu_files.CELL], metric
+            metric["workloads"] = metric["workloads"][:-2]
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     monkeypatch.setattr(trinity_files, "ROOT", tmp_path)
     trinity_files.test_the_benchmark_gains_one_configuration_one_cell_and_two_readers_after_keyes()

@@ -1161,3 +1161,86 @@ def test_kimi_linear_step_programs_compile_for_v5e_at_the_published_widths(topo,
     # ONE layer reads it, as Xing's stack is through its prefetch; every other int8 result is a parameter.)
     copies = [c for c in _weight_copies(text) if "s8[1,2304,576]" not in c]
     assert not copies, copies[:3]
+
+
+@pytest.mark.parametrize("form", ["mixed", "decode"])
+def test_openpangu_step_programs_compile_for_v5e_at_the_published_widths(topo, monkeypatch, as_on_tpu, form):
+    """openPangu-Ultra-MoE-718B as ONE of the sixteen chips that share each layer
+    (ISSUE 56: layer 0, six expert layers and the next-token module; a latent
+    attention of 128 heads on rows of 576 values in 640 lanes under a query
+    bottleneck of 1,536; 16 of 256 experts of 2,048 top-8 under the whole router;
+    19,200 of 153,600 rows of the vocabulary) as its cell serves it: 64 slots of
+    16 pages of 128, the ENGINE's two step programs in their real nesting (the
+    packed buffer's unpacking, the carried draft state, the round's acceptance,
+    the chunk's while_loop of 8 rounds), int8 weights. A pass says the chip's
+    compiler takes both: a decoding row's two queries through
+    ``mla_pair_attn_kernel`` (once in the dense layer's group, once in the
+    expert layers' scan, once in the module), beside it in a mixed step the
+    chunk's tiles; the expert kernels; ONE pool of eight planes updated in place
+    (no copy of it a round, temporaries far under it); no int8 result that is not
+    a parameter; and ONE program each, whatever was accepted: acceptance is data
+    (positions, lengths and write indices are operands), so nothing in the
+    signature depends on it."""
+    import re
+
+    import inference_gateway_tpu.serving.engine as engine_module
+    from inference_gateway_tpu.models import mixtral
+    from inference_gateway_tpu.ops.quant import init_quantized_llama_params
+    from inference_gateway_tpu.serving.engine import STOP_TABLE_WIDTH, Engine, EngineConfig
+    from inference_gateway_tpu.serving.kv_cache import PagedCacheConfig
+
+    monkeypatch.setattr("inference_gateway_tpu.ops.paged_attention.device_platform", lambda: "tpu")
+    monkeypatch.setattr("inference_gateway_tpu.ops.mla_attention.device_platform", lambda: "tpu")
+    preset = "openpangu-ultra-moe-718b-stage7"
+    cfg = mixtral.PRESETS[preset]
+    slots, page, seq = 64, 128, 2048
+    place = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=SingleDeviceSharding(topo.devices[0])), tree)
+    params = jax.eval_shape(lambda: init_quantized_llama_params(jax.random.PRNGKey(0), cfg))
+    # An engine of the cell's shapes around SHAPES: the tree handed in as structs, a pool of one page while it
+    # is built (the programs take the real pool's struct), the host's account of its device left as it is.
+    real_pool = mixtral.init_paged_cache
+    monkeypatch.setattr(mixtral, "init_paged_cache", lambda c, pc, dtype=BF16: {
+        "kv": jnp.zeros((c.cache_layers, 1, pc.page_size, c.latent_row), dtype)})
+    monkeypatch.setattr(engine_module, "device_info", lambda: {"platform": "cpu", "device_kind": "cpu", "device_count": 1})
+    engine = Engine(EngineConfig(model=preset, attention="paged", page_size=page, max_slots=slots, max_seq_len=seq,
+                                 use_mesh=False, decode_chunk=8), params=params)
+    assert engine.mtp and engine.mixed_budget == 576 and engine.round_tokens == 2
+    cache = place(jax.eval_shape(lambda: real_pool(cfg, PagedCacheConfig(page, 0, slots, seq))))
+    assert {n: a.shape for n, a in cache.items()} == {"kv": (8, slots * 16, page, 640)}
+    params = place(params)
+    sds = lambda shape, dtype=I32: place(jax.ShapeDtypeStruct(shape, dtype))
+    like = lambda a: sds(a.shape, a.dtype)
+    mtp = place(jax.eval_shape(lambda: engine._mtp))
+    _, mnext, mbits, mterm, mbias = engine._mask_args_ee()
+    if form == "mixed":
+        lowered = Engine._mixed_step_fn.lower(
+            engine, params, cache, sds((engine._mixed_pack.size,)), like(engine._rng), like(engine._last_block),
+            mnext=like(mnext), mbits=like(mbits), mbias=like(mbias), automaton=False, mtp=mtp)
+        kernels = {"mla_pair_attn_kernel": 3, "mla_chunk_attn_kernel": 3, "mla_decode_attn_kernel": 0}
+    else:
+        row, real, flag = sds((slots,)), sds((slots,), jnp.float32), sds((slots,), jnp.bool_)
+        lowered = Engine._decode_chunk_fn_paged_ee.lower(
+            engine, params, cache, row, row, flag, row, sds((slots, STOP_TABLE_WIDTH)), sds((slots, seq // page)), row,
+            real, real, row, flag, like(engine._rng), mstates=row, mnext=like(mnext), mbits=like(mbits),
+            mterm=like(mterm), mbias=like(mbias), n_steps=8, automaton=False, mtp=mtp)
+        kernels = {"mla_pair_attn_kernel": 3, "mla_chunk_attn_kernel": 0, "mla_decode_attn_kernel": 0}
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    for name, count in kernels.items():
+        assert len(set(re.findall(rf"%\S*{name}[\w.\-]* = ", text))) == count, name
+    for name in ("moe_gate_up_gmm", "moe_down_gmm"):
+        assert re.search(rf"%\S*{name}\S* = ", text), name
+    pool = 8 * slots * 16 * page * 640 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < pool // 3
+    assert not re.findall(rf"= bf16\[(?:{8 * slots * 16 * page}|8,{slots * 16},{page}),640\]\S* copy\(", text), "the pool is copied"
+    # No int8 result that is not a parameter INSIDE a loop (a copy a layer or a round). What ``_weight_copies``
+    # finds here (it knows a parameter as ``p__``; the engine's programs name theirs ``params__...``) are entry
+    # parameters laid out anew ONCE a call, in the entry computation: W_DKV's stacks (576 columns are not whole
+    # lane tiles) and W_UKV's (read by head in the absorbed form), under 150 MB in all: a fiftieth of a round's bytes
+    # over a chunk of eight.
+    entry = text[text.index("\nENTRY "):]
+    copies = _weight_copies(text)
+    assert all(f" {c.split(' = ')[0]} = " in entry for c in copies), "a weight laid out anew inside a loop"
+    moved = sum(int(np.prod([int(d) for d in re.match(r"%\S+ = s8\[([\d,]+)\]", c).group(1).split(",")])) for c in copies)
+    assert moved < 150e6, (moved, copies[:3])

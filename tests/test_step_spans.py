@@ -492,6 +492,44 @@ def test_timeline_record_keys_are_the_documented_ones(records_by_kind, kind):
     assert seen_always >= always
 
 
+def test_a_self_drafting_models_records_say_what_its_rounds_drafted_accepted_and_emitted():
+    """ISSUE 56: every ``decode`` and ``mixed`` record of a model that drafts with
+    its own next-token module carries the program's counts of its rounds, a
+    ``decode`` record's ``steps`` are rounds, and the keys are documented ones."""
+    import threading
+
+    from inference_gateway_tpu.serving.scheduler import Scheduler, generate_sync
+
+    sched = Scheduler(Engine(EngineConfig(model="openpangu-test-tiny", attention="paged", page_size=16, max_slots=4,
+                                          max_seq_len=256, dtype="float32", use_mesh=False, decode_chunk=4)))
+    sched.timeline = StepTimeline(512)
+    sched.start()
+    try:
+        threads = [threading.Thread(target=generate_sync, args=(sched, list(range(3, 3 + n))), kwargs={"max_tokens": 14})
+                   for n in (8, 40, 9)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+    finally:
+        sched.stop()
+    records = [r for r in sched.timeline.tail(None) if r["kind"] in ("decode", "mixed")]
+    table = _documented_keys()
+    assert {r["kind"] for r in records} == {"decode", "mixed"}
+    drafted = accepted = tokens = 0
+    for r in records:
+        assert {"mtp_drafted", "mtp_accepted", "mtp_tokens"} <= set(r), sorted(r)
+        assert r["mtp_drafted"] <= r["mtp_tokens"] == r["mtp_drafted"] + r["mtp_accepted"] <= 2 * r["mtp_drafted"]
+        if r["mtp_drafted"]:
+            assert r["rows"] >= 1 and r["mtp_drafted"] <= r["rows"] * r["steps"]
+        allowed = table["all kinds"][0] | table["all kinds"][1] | table[r["kind"]][0] | table[r["kind"]][1]
+        assert set(r) <= allowed, sorted(set(r) - allowed)
+        drafted, accepted, tokens = drafted + r["mtp_drafted"], accepted + r["mtp_accepted"], tokens + r["mtp_tokens"]
+    assert drafted == sched.spec_slot_rounds > 0 and accepted == sched.spec_accepted and tokens == sched.spec_emitted
+    # What the host sent on: every request's 14 tokens, the three first tokens among them.
+    assert sum(r["tokens"] for r in records) == 3 * 14
+
+
 def test_timeline_failures_rate_limited_then_disabled():
     """ISSUE 6 satellite: a broken record path must not logger.error
     once per engine step forever — the scheduler logs the first failure,

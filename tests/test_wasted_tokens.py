@@ -106,6 +106,23 @@ def test_spec_waste_attribution():
         sched.stop()
 
 
+def test_a_self_drafting_models_refused_positions_are_spec_rejected_waste_from_the_step_programs():
+    """ISSUE 56: no separate speculative loop runs; the two step programs count
+    their own rounds (a fetch block's rows), and every refused draft's position
+    lands under the same reason, nothing under the others."""
+    sched = Scheduler(Engine(EngineConfig(model="openpangu-test-tiny", attention="paged", page_size=16, max_slots=2,
+                                          max_seq_len=128, dtype="float32", use_mesh=False, decode_chunk=4)))
+    assert sched.engine.mtp and not sched.engine.spec
+    sched.start()
+    try:
+        generate_sync(sched, [7, 8, 9, 7, 8, 9, 7, 8], max_tokens=16)
+        assert sched.spec_slot_rounds > 0 and sched.spec_rounds > 0
+        assert sched.wasted["spec_rejected"] == sched.spec_slot_rounds - sched.spec_accepted > 0
+        assert sched.wasted["chunk_overrun"] == 0 and sched.wasted["overshoot"] == 0
+    finally:
+        sched.stop()
+
+
 def test_early_exit_zeroes_chunk_overrun_waste():
     """ISSUE 14 regression: a stream that finishes mid-chunk on a
     criterion the DEVICE holds (its max_tokens budget) records zero
