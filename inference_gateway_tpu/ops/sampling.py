@@ -9,9 +9,13 @@ The k candidates come from ``select_top_k``: an EXACT grouped top-k.
 The k-th largest group maximum t is a lower bound of the k-th largest
 element, every element above t lies in a group whose maximum is above t,
 and the k groups with the largest maxima hold all of those and k elements
->= t. Ties go to the lower column id, as lax.top_k's do: groups are taken
-lowest id first and kept in column order, so values and indices are
-lax.top_k's on every input and every sampled stream is unchanged.
+>= t. Ties go to the lower column id, as lax.top_k's do: groups are runs
+of neighbouring columns, taken lowest id first and kept in column order,
+so values and indices are lax.top_k's on every input and every sampled
+stream is unchanged. The groups' width follows the static (V, k)
+(``top_k_plan``: 128 columns for the large vocabularies, 64 or 32 under
+512 groups of 128), and whatever axes lead the vocabulary's are selected
+as rows of one two-axis operand.
 """
 
 from __future__ import annotations
@@ -83,50 +87,70 @@ def chunk_row_keys(
     return jnp.where(use_seed[None, :, None], seeded, unseeded)  # (n, B, 2)
 
 
-# The grouped selection's two constants, from the operation alone on a
-# v5e (hack/topk_bench.py; PERF.md §6, PR 31), k = 64, device time:
-# [48, 152064] lax.top_k 1.43 ms, grouped 0.38 ms (the sort of the 8,192
-# candidates 0.22 of it); [24, 32000] 0.215 ms against 0.26 ms. The sort
-# of the candidates costs the same whatever the vocabulary, so grouping
-# pays from about 512 groups on: 8 groups per candidate asked for.
-GROUP_LANES = 128
+# The grouped selection's constants, from the operation alone on a v5e
+# (hack/topk_bench.py, device time, k = 64). PERF.md §6, PR 31:
+# [48, 152064] lax.top_k 1.43 ms, 128-wide groups 0.38 ms (the TopK call
+# over the 8,192 candidates 0.22 of it, whatever the vocabulary), so
+# 128-wide groups pay from 8 groups per candidate asked for (512) on.
+# PERF.md §5-§6, PR 57, under that, by width 128 / 64 / 32 / 16 against
+# lax.top_k: [128, 19200] 0.36 / 0.28 / 0.27 / 0.26 against 0.52 ms;
+# [64, 19200] 0.30 / 0.21 / 0.18 / 0.17 against 0.43; [24, 32000] 0.26 /
+# 0.088 / 0.081 / 0.099 against 0.215; [48, 32000] 0.29 / 0.18 / 0.14 /
+# 0.22 against 0.43: up to 4,096 candidates are sorted in place and no
+# TopK call is made, and 32 is the width both take by the same 8 groups
+# per candidate. At 152,064 columns 32 loses to 128 (0.48 against 0.38).
+# Three axes: lax.top_k over [64, 2, 19200] is a sort of every column,
+# 8.65 ms; the same rows as [128, 19200] are a TopK call, and the grouped
+# selection reads them through its first reduction at no cost (0.25 ms).
+GROUP_WIDTHS = (128, 64, 32)
 GROUPED_MIN_GROUPS_PER_K = 8
 
 
 def top_k_plan(vocab_size: int, k: int) -> dict:
     """Which selection ``select_top_k`` takes for a (V, k): a function of
-    the static shape alone. ``grouped`` where the vocabulary holds enough
-    lane-tile groups for the second sort to be much smaller than the
-    first; ``direct`` (plain lax.top_k) otherwise, k >= V included."""
-    groups = -(-vocab_size // GROUP_LANES)
-    grouped = 0 < k < vocab_size and groups >= GROUPED_MIN_GROUPS_PER_K * k
-    return {
-        "top_k": k,
-        "path": "grouped" if grouped else "direct",
-        "groups": groups if grouped else 0,
-        "candidates": k * GROUP_LANES if grouped else vocab_size,
-    }
+    the static shape alone. ``grouped`` at the widest of GROUP_WIDTHS at
+    which the vocabulary holds enough groups for the second selection to
+    be much smaller than the first; ``direct`` (plain lax.top_k)
+    where none does, k >= V included."""
+    if 0 < k < vocab_size:
+        for width in GROUP_WIDTHS:
+            groups = -(-vocab_size // width)
+            if groups >= GROUPED_MIN_GROUPS_PER_K * k:
+                return {"top_k": k, "path": "grouped", "group_width": width, "groups": groups,
+                        "candidates": k * width}
+    return {"top_k": k, "path": "direct", "group_width": 0, "groups": 0, "candidates": vocab_size}
+
+
+def grouped_top_k(x: jnp.ndarray, k: int, g: int):
+    """The exact top-k of (rows, V) through groups of ``g`` columns: one
+    pass over ``x`` for the groups' maxima, then a selection among the
+    k * g columns of the k groups with the largest."""
+    rows, V = x.shape
+    G = -(-V // g)
+    if G * g != V:  # pad, never truncate: -inf at the highest ids loses every tie
+        x = jnp.pad(x, [(0, 0), (0, G * g - V)], constant_values=-jnp.inf)
+    xg = x.reshape(rows, G, g)
+    _, gid = jax.lax.top_k(jnp.max(xg, axis=-1), k)  # the k groups with the largest maxima
+    gid = jnp.sort(gid, axis=-1)  # ascending: candidate order is column order
+    cand = jnp.take_along_axis(xg, gid[..., None], axis=-2)  # (rows, k, g)
+    vals, pos = jax.lax.top_k(cand.reshape(rows, k * g), k)
+    idx = jnp.take_along_axis(gid, pos // g, axis=-1) * g + pos % g
+    return vals, idx
 
 
 def select_top_k(x: jnp.ndarray, k: int):
     """``jax.lax.top_k(x, k)`` — the same values AND indices on every
-    input — in one pass over ``x`` and a sort of k * GROUP_LANES
-    candidates where ``top_k_plan`` says grouping pays."""
-    V = x.shape[-1]
+    input — by the selection ``top_k_plan`` names for the shape. Leading
+    axes are folded into rows first: every lax.top_k inside sees two axes
+    (over three a TPU sorts the whole vocabulary)."""
+    lead, V = x.shape[:-1], x.shape[-1]
     plan = top_k_plan(V, k)
+    x = x.reshape(-1, V)
     if plan["path"] == "direct":
-        return jax.lax.top_k(x, k)
-    g, G = GROUP_LANES, plan["groups"]
-    if G * g != V:  # pad, never truncate: -inf at the highest ids loses every tie
-        pad = [(0, 0)] * (x.ndim - 1) + [(0, G * g - V)]
-        x = jnp.pad(x, pad, constant_values=-jnp.inf)
-    xg = x.reshape(*x.shape[:-1], G, g)
-    _, gid = jax.lax.top_k(jnp.max(xg, axis=-1), k)  # the k groups with the largest maxima
-    gid = jnp.sort(gid, axis=-1)  # ascending: candidate order is column order
-    cand = jnp.take_along_axis(xg, gid[..., None], axis=-2)  # (..., k, g)
-    vals, pos = jax.lax.top_k(cand.reshape(*x.shape[:-1], k * g), k)
-    idx = jnp.take_along_axis(gid, pos // g, axis=-1) * g + pos % g
-    return vals, idx
+        vals, idx = jax.lax.top_k(x, k)
+    else:
+        vals, idx = grouped_top_k(x, k, plan["group_width"])
+    return vals.reshape(*lead, k), idx.reshape(*lead, k)
 
 
 def top_k_nucleus(scaled: jnp.ndarray, top_p: jnp.ndarray, top_k: int):

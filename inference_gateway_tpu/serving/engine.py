@@ -873,9 +873,9 @@ class Engine:
             self.attention_path = "dense"
             self.attention_path_reason = "contiguous slot cache (paged attention not in use)"
 
-        # The sampler's verdict (ISSUE 31): which selection this engine's
-        # (vocabulary, top_k) takes in ops/sampling.select_top_k — static
-        # per engine, so it is a /debug/status field and not a counter.
+        # The sampler's verdict (ISSUE 31, 57): which selection, at which group
+        # width, this engine's (vocabulary, top_k) takes in select_top_k —
+        # static per engine, so it is a /debug/status field and not a counter.
         self.sampler_plan = top_k_plan(
             self.model_cfg.vocab_size,
             effective_top_k(config.top_k, self.model_cfg.vocab_size))

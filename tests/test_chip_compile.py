@@ -1244,3 +1244,7 @@ def test_openpangu_step_programs_compile_for_v5e_at_the_published_widths(topo, m
     assert all(f" {c.split(' = ')[0]} = " in entry for c in copies), "a weight laid out anew inside a loop"
     moved = sum(int(np.prod([int(d) for d in re.match(r"%\S+ = s8\[([\d,]+)\]", c).group(1).split(",")])) for c in copies)
     assert moved < 150e6, (moved, copies[:3])
+    # No selection sorts the vocabulary's slice (ISSUE 57): the verify queries' (64, 2, 19200) were a sort of every
+    # column, 1.8 ms a round; the sampler's groups of 32 columns leave sorts of 600 maxima and 2,048 candidates.
+    sorts = re.findall(r"= \(?f32\[([\d,]+)\][^\n]* sort\(", text)
+    assert sorts and all(int(shape.split(",")[-1]) <= 2048 for shape in sorts), sorts

@@ -231,9 +231,10 @@ def test_attention_path_surfaced_in_status_and_gauge():
 
 
 @pytest.mark.parametrize("vocab, top_k, want", [
-    (256, 64, {"top_k": 64, "path": "direct", "groups": 0, "candidates": 256}),
-    (256, 0, {"top_k": 256, "path": "direct", "groups": 0, "candidates": 256}),
-    (16384, 8, {"top_k": 8, "path": "grouped", "groups": 128, "candidates": 1024}),
+    (256, 64, {"top_k": 64, "path": "direct", "group_width": 0, "groups": 0, "candidates": 256}),
+    (256, 0, {"top_k": 256, "path": "direct", "group_width": 0, "groups": 0, "candidates": 256}),
+    (16384, 8, {"top_k": 8, "path": "grouped", "group_width": 128, "groups": 128, "candidates": 1024}),
+    (2048, 8, {"top_k": 8, "path": "grouped", "group_width": 32, "groups": 64, "candidates": 256}),
 ])
 def test_sampler_verdict_surfaced_in_status(vocab, top_k, want, monkeypatch):
     """Which top-k selection an engine's (vocabulary, top_k) takes is
