@@ -17,6 +17,7 @@ reference providers/registry/registry.go:143-208).
 
 from __future__ import annotations
 
+import sys
 from dataclasses import dataclass
 from functools import partial
 from typing import Any
@@ -24,6 +25,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from inference_gateway_tpu.models.family import Family, attention_layers
 from inference_gateway_tpu.ops.attention import causal_prefill_mask, decode_mask, gqa_attend
 from inference_gateway_tpu.ops.norms import rms_norm
 from inference_gateway_tpu.ops.quant import qmatmul
@@ -1425,3 +1427,29 @@ def _is_quantized(params: Params) -> str | None:
     if isinstance(w, QTensor):
         return "int8"
     return None
+
+
+def init_paged_cache(cfg: LlamaConfig, cache_cfg, dtype=jnp.bfloat16) -> Params:
+    """The page pool (serving/kv_cache.PagedCacheConfig): k/v of shape (L, num_pages, page_size, Hkv*D)."""
+    shape = (cfg.num_layers, cache_cfg.resolve_num_pages(), cache_cfg.page_size, cfg.num_kv_heads * cfg.hd)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def param_specs(cfg: LlamaConfig) -> dict:
+    from inference_gateway_tpu.parallel.sharding import llama_param_specs
+
+    return llama_param_specs(cfg)
+
+
+def windowed_layers(cfg: LlamaConfig) -> int:
+    """How many of the model's layers see only ``sliding_window`` keys."""
+    if not cfg.sliding_window:
+        return 0
+    return sum(cfg.layer_window) if cfg.layer_window else cfg.num_layers
+
+
+def family(cfg: LlamaConfig) -> Family:
+    """What serving ``cfg`` takes: every layer's k and v in the pool, nothing
+    beside it, nothing counted, nothing refused."""
+    return Family(module=sys.modules[__name__],
+                  attention_layers=attention_layers(cfg.num_layers, cfg.sliding_window, windowed_layers(cfg)))

@@ -32,6 +32,7 @@ expert axis; under a mesh with an ``ep`` axis it is sharded there.
 
 from __future__ import annotations
 
+import sys
 from dataclasses import dataclass
 from functools import partial
 from typing import Any
@@ -39,6 +40,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from inference_gateway_tpu.models.family import INT8_ALONE, Family, attention_layers
 from inference_gateway_tpu.models.llama import (
     LlamaConfig,
     _attn_out,
@@ -53,6 +55,7 @@ from inference_gateway_tpu.models.llama import (
     forward_paged_impl,
     forward_ragged_impl,
     window_pages,
+    windowed_layers,
 )
 from inference_gateway_tpu.ops import kda as kda_ops
 from inference_gateway_tpu.ops.attention import causal_prefill_mask, decode_mask, gqa_attend
@@ -1158,13 +1161,6 @@ def _seam(params: Params, cfg: MixtralConfig, mesh, valid=None, conv=None, pair=
     return seam
 
 
-def windowed_layers(cfg: MixtralConfig) -> int:
-    """How many of the model's layers see only ``sliding_window`` keys."""
-    if not cfg.sliding_window:
-        return 0
-    return sum(cfg.layer_window) if cfg.layer_window else cfg.num_layers
-
-
 def counts_window_pages(cfg: MixtralConfig) -> bool:
     """A mixture with windowed layers counts, in both step programs, the pages
     those layers read and hold (ISSUE 41; not one with an indexer: its
@@ -1640,3 +1636,173 @@ PRESETS: dict[str, MixtralConfig] = {
         intermediate_size=2048, dense_intermediate_size=18432, num_experts=256, experts_per_token=8, experts_held=16,
         max_position_embeddings=131072),
 }
+
+
+# -- what serving a mixture takes (models/family.py, ISSUE 58) -----------------
+# Rows of a step's fetch block beside its tokens, one a name, in the order the step
+# programs put them there (serving/engine.py reduces the experts' rows, the rest is
+# ``forward_paged`` / ``forward_ragged``'s auxiliary behind them: ``split_aux``).
+# A mixed step counts the rows each expert multiplied (ISSUE 28), the experts read
+# and the visits the expert kernels made (ISSUE 42); a decode chunk carries the last
+# two, means over the steps it ran.
+MIXED_STATS = ("expert_tokens_max", "expert_tokens_mean", "experts_read", "expert_visits")
+# With an indexer (ISSUE 36): the cached tokens its rows' queries could see and the
+# tokens whose keys and values their attention read, summed over queries and layers
+# (float32: exact to 2**24, a part in 10**7 beyond).
+DSA_STATS = ("dsa_context_tokens", "dsa_kv_tokens_read")
+# With windowed layers (ISSUE 41), or a pool of the caching layers alone (ISSUE 43):
+# the pages those layers read and the pages their rows' contexts hold.
+WINDOW_STATS = ("window_pages_read", "context_pages")
+# With a latent pool (ISSUE 47): the pages of latent the rows' tiles walked, the pages
+# their contexts hold, and of the first what the one-query rows walked (``latent_pages``).
+LATENT_STATS = ("latent_pages_read", "context_pages", "latent_row_pages_read")
+# With Mamba-2 layers (ISSUE 50): the blocks the chunk kernel walked over all state
+# layers (0 in a decode chunk) and the rows whose matrix state the one-token update
+# read and wrote in each; the same two for Kimi Delta Attention layers (ISSUE 54).
+SSD_STATS = ("ssd_blocks", "state_rows")
+KDA_STATS = ("kda_blocks", "state_rows")
+# With a next-token module (ISSUE 56): the rounds of a row that verified a draft, the
+# drafts accepted, and the tokens those rounds emitted (1 or 2 a round): each a sum.
+MTP_STATS = ("mtp_drafted", "mtp_accepted", "mtp_tokens")
+
+
+def split_aux(cfg: MixtralConfig, aux):
+    """``forward_paged`` / ``forward_ragged``'s third result -> (the rows each expert
+    multiplied (expert layers, E), the step's further counts in the stat names' order)."""
+    if cfg.indexer_topk:  # the pair (the FFN's, the attention seam's (L, 2))
+        return aux[0], jnp.sum(aux[1], axis=0)
+    if counts_window_pages(cfg):  # the pair (the FFN's, ``counted_window_pages`` and the state layers' counts)
+        return aux
+    return aux, jnp.zeros((0,), jnp.float32)
+
+
+def _refuses(cfg: MixtralConfig) -> dict:
+    """``Family.refuses``: what this model's path does not carry, each reason as it is served."""
+    text_alone = "it is a text model; image input has no path, serve text"
+    if cfg.indexer_topk:  # ISSUE 36
+        out = {"spec_draft": "speculative decoding has no verify pass through the sparse-attention indexer; serve it "
+                             "without --spec-draft",
+               "vision_model": "its vision tower is not built (the published config gives no width for it); image "
+                               "input has no path, serve text",
+               "image_part": "its vision tower is not built; image input has no path, send text", "int4": INT8_ALONE}
+    elif cfg.layer_conv is not None:  # what a carried state rules out (ISSUE 43)
+        out = {"spec_draft": "speculative decoding would need each convolution layer's state at the last ACCEPTED "
+                             "token, and a rejected draft has moved it on; serve it without --spec-draft",
+               "vision_model": text_alone, "int4": INT8_ALONE}
+    elif cfg.num_dense_layers:  # ISSUE 41
+        out = {"spec_draft": "speculative decoding has no verify pass over its two stacks of layers; serve it without "
+                             "--spec-draft",
+               "vision_model": text_alone, "int4": INT8_ALONE}
+    else:
+        out = {}
+    if cfg.kv_lora_rank:  # ISSUE 47
+        own = ("it drafts for itself with its own next-token module, in both step programs" if cfg.mtp_layers else
+               "its own next-token-prediction module is not part of this preset (num_nextn_predict_layers 0)")
+        out["spec_draft"] = ("speculative decoding with a separate draft model or prompt lookup has no verify pass over "
+                             f"the latent pool ({own}); serve it without --spec-draft")
+    # No mesh carries these yet: a stated shape is refused, and left to the default the
+    # model is served on one device (``mesh_axes`` and /debug/status say {"tp": 1}).
+    carries = ("carries the per-slot state store" if cfg.layer_conv is not None else
+               "carries the sparse-attention indexer and its key cache" if cfg.indexer_topk else
+               "lays out its next-token module and the drafts' carried state" if cfg.mtp_layers else
+               # (ISSUE 41: ``param_specs`` lays out neither stack)
+               "lays out its leading dense layers' stack, its attention gate and its shared expert"
+               if cfg.num_dense_layers else None)
+    if carries:
+        out["mesh"] = f"no mesh ({{shape}}) {carries} yet; serve it on one device"
+    return out
+
+
+def _status(cfg: MixtralConfig, view) -> dict:
+    """``Family.status``: a mixture's /debug/status blocks. With a state store
+    ``state_store``, ``cache_layers``, the two counters and how its scan is run
+    (``ssd`` | ``kda``); with an indexer ``dsa``; with a latent attention
+    ``latent_pool``; with a next-token module ``mtp`` and ``vocab``; always ``router``,
+    ``shared_experts``, ``dense_prefix``, and ``cache_layers`` of windowed layers."""
+    from inference_gateway_tpu.ops import sparse_attention
+    from inference_gateway_tpu.utils.platform import on_tpu
+
+    out, itemsize = {}, jnp.dtype(view.dtype).itemsize
+    if cfg.layer_conv is not None:
+        out = {"state_store": {"slots": view.max_slots, "layers": cfg.conv_layers,
+                               "bytes": view.max_slots * cfg.state_bytes_per_slot},
+               "cache_layers": {"full": cfg.cache_layers,
+                                "kda" if cfg.kda else "mamba2" if cfg.mamba2 else "conv": cfg.conv_layers},
+               "state_resets": view.state_resets, "prefix_hits_declined": view.prefix_hits_declined}
+    if cfg.mamba2:   # how the Mamba-2 scan is done (ISSUE 50; static)
+        out["ssd"] = {"heads": cfg.ssm_heads, "state": [cfg.ssm_head_dim, cfg.ssm_state], "block": cfg.ssd_block,
+                      "rows_of_more_than_one_token": "ssd_chunk_kernel" if on_tpu() else "ssd_chunk_twin",
+                      "one_token_rows": "ssm_state_update_kernel" if on_tpu() else "state_update"}
+    if cfg.kda:   # how the delta rule is run (ISSUE 54; static)
+        out["kda"] = {"heads": cfg.kda_heads, "state": [cfg.kda_head_dim, cfg.kda_head_dim], "block": cfg.kda_block,
+                      "decay": "a channel", "state_bytes_per_slot_per_layer": cfg.kda_inner * cfg.kda_head_dim * 4,
+                      "rows_of_more_than_one_token": "kda_chunk_kernel" if on_tpu() else "kda_chunk_twin",
+                      "one_token_rows": "kda_state_update_kernel" if on_tpu() else "state_update"}
+    if cfg.indexer_topk:   # its sizes, and how the selection and the read are done (static)
+        row_tokens = view.page_cfg.max_pages_per_slot * view.page_cfg.page_size
+        walk = sparse_attention.one_query_read(cfg.indexer_topk, row_tokens) == "walk"
+        out["dsa"] = {
+            "topk": cfg.indexer_topk, "indexer_heads": cfg.indexer_heads,
+            "indexer_head_dim": cfg.indexer_head_dim,
+            "index_cache_bytes_per_token": cfg.num_layers * cfg.indexer_head_dim * itemsize,
+            "short_context": "a query that sees topk tokens or fewer keeps them all, by the same path (no plain-attention branch)",
+            # Static a program: a row of the page table against WALK_UNDER x topk (ISSUE 45).
+            "one_query_row": {"read": "walk" if walk else "gather", "row_tokens": row_tokens,
+                              "walk_under": sparse_attention.WALK_UNDER,
+                              "walk_up_to_tokens": sparse_attention.WALK_UNDER * cfg.indexer_topk},
+            "select": {"one_query_row": ("mask by the exact k-th largest score (32 counting passes, ties by position)"
+                                         if walk else "lax.top_k over the row's scores"),
+                       "prompt_chunk": "exact k-th largest by 32 counting passes, ties by position, as a mask"},
+            "read": {"one_query_row": ("whole pages under the mask, by the paged decode kernel (every page the row holds)"
+                                       if walk else "token gather of the chosen keys and values (topk tokens)"),
+                     "prompt_chunk": "tiles of 128 queries over every block of keys up to the tile's last position, masked"},
+        }
+    if cfg.kv_lora_rank:   # the pool's row and bytes a token, and how it is read (static)
+        out["latent_pool"] = {
+            "row_values": cfg.latent_width, "row_lanes": cfg.latent_row,
+            "row": f"[{cfg.kv_lora_rank} latent, {cfg.qk_rope_head_dim} rotary key, "
+                   f"{cfg.latent_row - cfg.latent_width} zeros to the 128-lane grid]",
+            "bytes_per_token_per_layer": cfg.latent_row * itemsize,
+            "bytes_per_token": cfg.cache_layers * cfg.latent_row * itemsize, "layers": cfg.cache_layers,
+            "arrays": [MLA_POOL], "heads": cfg.num_heads, "value_dim": cfg.kv_lora_rank,
+            "read": "absorbed: every head's query in the latent's space against one key row, "
+                    "the row's first value_dim values the value, a page fetched once a visit",
+            "residual_streams": cfg.hc_mult or 1,
+        }
+    if cfg.mtp_layers:   # the module (static), what its rounds have come to, the share of the vocabulary held
+        mtp = {"layers": cfg.mtp_layers, "drafts": "one token a row a round, from the model's own next-token module",
+               "verified": "as the row's second query, in both step programs; accepted by speculative.spec_accept"}
+        if view.scheduler is not None:
+            drafted = view.scheduler.spec_slot_rounds
+            mtp.update(drafted=drafted, accepted=view.scheduler.spec_accepted,
+                       tokens_per_round=round(view.scheduler.spec_emitted / drafted, 3) if drafted else None)
+        out.update(mtp=mtp, vocab={"held": cfg.vocab_size, "published": cfg.vocab_published or cfg.vocab_size})
+    out.update({"router": {"score": cfg.router_score, "experts": cfg.num_experts,
+                           # (ISSUE 54) a chip's share: the router chooses among ``experts``, this
+                           # process computes ``experts_held`` of them and nothing in place of the rest
+                           "experts_held": cfg.held_experts, "experts_first": cfg.experts_first,
+                           "experts_per_token": cfg.experts_per_token, "route_scale": cfg.route_scale,
+                           "chosen_by": "score + bias" if cfg.router_score == "sigmoid" else "logit",
+                           "weights": ("the chosen scores, normalised" if cfg.router_score == "sigmoid"
+                                       else "softmax over the chosen logits")},
+                "shared_experts": cfg.shared_experts, "dense_prefix": cfg.num_dense_layers})
+    if counts_window_pages(cfg) and cfg.layer_conv is None:   # (with a state store: said above)
+        windowed = windowed_layers(cfg)
+        out["cache_layers"] = {"window": windowed, "full": cfg.num_layers - windowed}
+    return out
+
+
+def family(cfg: MixtralConfig) -> Family:
+    """What serving ``cfg`` takes: the one place that names this config's fields
+    for ``serving/``."""
+    state, indexer, latent = cfg.layer_conv is not None, bool(cfg.indexer_topk), bool(cfg.kv_lora_rank)
+    more = ((DSA_STATS if indexer else ())
+            + ((LATENT_STATS if latent else WINDOW_STATS) if counts_window_pages(cfg) else ())
+            + (SSD_STATS if cfg.mamba2 else ()) + (KDA_STATS if cfg.kda else ()) + (MTP_STATS if cfg.mtp_layers else ()))
+    return Family(
+        module=sys.modules[__name__], experts=True, pool="latent" if latent else "kv+index" if indexer else "kv",
+        state_store=state, mixed_alone=state or indexer or latent, round_tokens=2 if cfg.mtp_layers else 1,
+        refuses=_refuses(cfg), chunk_stats=MIXED_STATS[2:] + more, mixed_stats=MIXED_STATS + more,
+        attention_layers=attention_layers(cfg.cache_layers, cfg.sliding_window,
+                                          min(windowed_layers(cfg), cfg.cache_layers)),
+        status=partial(_status, cfg))

@@ -49,6 +49,7 @@ name (serving/engine.py routes every admission through the ragged step).
 from __future__ import annotations
 
 import math
+import sys
 from dataclasses import dataclass
 from functools import partial
 
@@ -56,6 +57,7 @@ import jax
 import jax.numpy as jnp
 
 from inference_gateway_tpu.models import llama
+from inference_gateway_tpu.models.family import INT8_ALONE, Family, attention_layers
 from inference_gateway_tpu.ops import ssm
 from inference_gateway_tpu.ops.quant import qmatmul, seeded_std
 
@@ -502,3 +504,31 @@ def forward_ragged(params: Params, cfg: Phi4FlashConfig, tokens, positions, cach
         return logits, cache
     with jax.named_scope("carry"):
         return logits, cache, window_pages(cfg, cache["k"].shape[2], live_lens - q_lens + 1, live_lens)
+
+
+# Rows of both step programs' fetch block (ISSUE 32): the pages the windowed
+# layers read and the pages their rows' contexts hold (``window_pages``).
+WINDOW_STATS = ("window_pages_read", "context_pages")
+
+
+def _status(cfg: Phi4FlashConfig, view) -> dict:
+    """``Family.status``: the state store, the layers that write the page pool and who
+    reads the shared one, and the two counters (segments started from zero; prefix
+    hits declined)."""
+    return {"state_store": {"slots": view.max_slots, "layers": cfg.state_layers,
+                            "bytes": view.max_slots * cfg.state_bytes_per_slot},
+            "cache_layers": {"window": cfg.mid, "full": 1, "shared_readers": cfg.cross_layers},
+            "state_resets": view.state_resets, "prefix_hits_declined": view.prefix_hits_declined}
+
+
+def family(cfg: Phi4FlashConfig) -> Family:
+    """What serving ``cfg`` takes. What cannot carry the Mamba state is refused by
+    name (ISSUE 32)."""
+    return Family(
+        module=sys.modules[__name__], state_store=True, mixed_alone=True,
+        refuses={"spec_draft": "speculative decoding has no Mamba state to roll back; serve it without --spec-draft",
+                 "vision_model": "multimodal prefill carries no Mamba state", "int4": INT8_ALONE,
+                 "mesh": "no mesh ({shape}) carries the per-slot state store yet; serve it on one device"},
+        chunk_stats=WINDOW_STATS, mixed_stats=WINDOW_STATS,
+        attention_layers=attention_layers(cfg.cache_layers, cfg.sliding_window, cfg.mid),
+        status=partial(_status, cfg))

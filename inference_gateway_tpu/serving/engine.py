@@ -1,17 +1,21 @@
-"""The TPU model engine: jitted prefill/decode steps over a slot cache.
+"""The TPU model engine: weights, a cache and the jitted step programs over them.
 
-Continuous-batching substrate (SURVEY.md §7 stage 5):
+Continuous-batching substrate (SURVEY.md §7 stage 5), static shapes throughout:
 
-- A fixed pool of ``max_slots`` cache rows (static shapes — XLA compiles
-  exactly one decode program and one prefill program per prompt bucket).
-- Prefill writes a small padded batch of fresh prompts into their slot
-  rows (``slot_ids`` scatter) and samples each prompt's first token.
-- Decode advances *all* slots every step (inactive rows are masked) and
-  samples with per-slot temperature/top-p, so heterogeneous requests
-  share one MXU-saturating batch.
+- ``max_slots`` rows over a PAGED pool (``--attention paged``: the serving
+  path) or a dense slot cache (the benchmark's reference child, pp, tests).
+- A paged engine steps through two programs: the ragged MIXED step (any mix of
+  prompt chunks and decode rows in one launch, packed to ``mixed_budget``
+  tokens) and the early-exit DECODE CHUNK (up to ``decode_chunk`` steps a
+  launch, sampling and stopping on the device, chained without a host round
+  trip). Bucketed prefill, prefix-tail, ring, multimodal and the speculative
+  rounds are programs of their own beside them.
+- Which model module the programs trace, what the pool holds, which rows a
+  step counts and what a configuration may not ask are the model family's to
+  say: one record, ``models/family.py``'s ``family_of(model_cfg)``.
 
 Weights/caches are bf16 by default, sharded over a (dp, sp, tp) mesh when
-more than one device is visible.
+more than one device is visible (``choose_mesh``).
 """
 
 from __future__ import annotations
@@ -27,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from inference_gateway_tpu.models import llama
+from inference_gateway_tpu.models.family import StatusView, family_of, preset_config
 from inference_gateway_tpu.ops.sampling import (
     chunk_gumbels,
     chunk_row_keys,
@@ -38,18 +43,16 @@ from inference_gateway_tpu.ops.sampling import (
     sample_tokens_pregumbel,
     top_k_plan,
 )
-from inference_gateway_tpu.ops import sparse_attention
 from inference_gateway_tpu.otel.profiling import cause_span, phase_span, stamp_since
 from inference_gateway_tpu.parallel.mesh import create_mesh, default_mesh_shape
 from inference_gateway_tpu.parallel.sharding import (
     check_divisibility,
-    llama_param_specs,
     named,
     shard_params,
 )
 from inference_gateway_tpu.serving.packed import PackedLayout
 from inference_gateway_tpu.serving.tokenizer import load_tokenizer
-from inference_gateway_tpu.utils.platform import device_info, on_tpu
+from inference_gateway_tpu.utils.platform import device_info
 
 
 @dataclass
@@ -318,113 +321,6 @@ def _fetch_blocks(phases: dict | None, path: str, *blocks) -> list[np.ndarray]:
         return [np.asarray(block) for block in blocks]
 
 
-# A mixture's mixed step counts, in the program, the rows each expert
-# multiplied; the fetch block carries one row for each of these (ISSUE 28).
-MIXED_STATS = ("expert_tokens_max", "expert_tokens_mean", "experts_read", "expert_visits")
-# A mixture's decode chunk carries the last two, means over the steps it ran.
-CHUNK_EXPERT_STATS = MIXED_STATS[2:]
-# A model with windowed layers beside a state store (models/phi4flash.py)
-# counts, in both step programs, the pages its windowed layers read and the
-# pages their rows' contexts hold (ISSUE 32): two rows of the fetch block.
-WINDOW_STATS = ("window_pages_read", "context_pages")
-# A model with a sparse-attention indexer (models/mixtral.py, ISSUE 36)
-# counts, in both step programs, the cached tokens its rows' queries could
-# see and the tokens whose keys and values their attention read, summed
-# over queries and layers: two more rows of the fetch block, float32 (exact
-# to 2**24, a part in 10**7 beyond).
-DSA_STATS = ("dsa_context_tokens", "dsa_kv_tokens_read")
-# A model with a latent pool (models/mixtral.py, ISSUE 47) counts, in both step
-# programs, the pages of latent its rows' tiles walked and the pages their
-# contexts hold, over all layers, and of the first what the one-query rows
-# walked (``mixtral.latent_pages``): WINDOW_STATS' rows of the fetch block under
-# its own first name, and one more.
-LATENT_STATS = ("latent_pages_read", "context_pages", "latent_row_pages_read")
-# A model with Mamba-2 layers (models/mixtral.py, ISSUE 50) counts, in both step
-# programs, the blocks its chunk kernel walked over all its state layers
-# (ops/ssd.block_plan: a row of more than one token visits every packed block
-# it touches; 0 in a decode chunk) and the rows whose matrix state the
-# one-token update read and wrote in each of them (a decode chunk's: summed
-# over its steps): two rows of the fetch block behind WINDOW_STATS.
-SSD_STATS = ("ssd_blocks", "state_rows")
-# The same two rows for a model with Kimi Delta Attention layers (ISSUE 54): the
-# blocks ``ops/kda.py``'s chunk kernel walked over all its state layers, and the
-# rows whose matrix state its one-token update read and wrote in each of them.
-KDA_STATS = ("kda_blocks", "state_rows")
-# A model that drafts with its own next-token module (models/mixtral.py, ISSUE 56)
-# counts, in both step programs, the rounds of a row that verified a draft, the
-# drafts accepted, and the tokens those rounds emitted (1 or 2 a round): three
-# rows of the fetch block, each a sum.
-MTP_STATS = ("mtp_drafted", "mtp_accepted", "mtp_tokens")
-
-# Presets of models/phi4flash.py, by name: the module is imported when one
-# of them is asked for, and not on the way to any other model.
-PHI4FLASH_PRESETS = ("phi-4-mini-flash", "phi4flash-test-tiny")
-
-
-def preset_config(name: str):
-    """``(model module, config)`` of a preset name, or None."""
-    from inference_gateway_tpu.models import mixtral
-
-    if name in llama.PRESETS:
-        return llama, llama.PRESETS[name]
-    if name in mixtral.PRESETS:
-        return mixtral, mixtral.PRESETS[name]
-    if name in PHI4FLASH_PRESETS:
-        from inference_gateway_tpu.models import phi4flash
-
-        return phi4flash, phi4flash.PRESETS[name]
-    return None
-
-
-def carries_state(model_cfg) -> bool:
-    """A model whose layers keep a state per slot beside the page pool: the
-    hybrid's Mamba layers (models/phi4flash.py), a mixture's convolution
-    layers (models/mixtral.py, ISSUE 43). A state cannot be handed over as
-    pages can (prefix hits are declined), is carried by the mixed step alone,
-    and by no mesh and no speculation."""
-    return is_hybrid(model_cfg) or getattr(model_cfg, "layer_conv", None) is not None
-
-
-def is_hybrid(model_cfg) -> bool:
-    """A model of models/phi4flash.py: its own module, decoder loop and counts."""
-    return hasattr(model_cfg, "mamba_d_state")
-
-
-def attention_layer_windows(model_cfg) -> list:
-    """The model's attention layers (those that write the page pool and read
-    it through the paged kernels in a mixed step) as (window or None,
-    layers): what ``ragged_walk_pages`` counts a step's pages over."""
-    layers = getattr(model_cfg, "cache_layers", model_cfg.num_layers)
-    window = getattr(model_cfg, "sliding_window", None)
-    flags = getattr(model_cfg, "layer_window", None)
-    windowed = 0 if not window else model_cfg.mid if is_hybrid(model_cfg) else sum(flags) if flags else layers
-    return [(w, n) for w, n in ((window, windowed), (None, layers - windowed)) if n]
-
-
-def has_indexer(model_cfg) -> bool:
-    """A model whose attention reads the tokens a learned indexer chooses,
-    with the indexer's keys paged beside k and v (models/mixtral.py)."""
-    return bool(getattr(model_cfg, "indexer_topk", 0))
-
-
-def has_latent_pool(model_cfg) -> bool:
-    """A model whose attention caches ONE compressed row a token a layer, the
-    pool's only array (models/mixtral.py, ISSUE 47)."""
-    return bool(getattr(model_cfg, "kv_lora_rank", 0))
-
-
-def has_mtp(model_cfg) -> bool:
-    """A model with its own next-token module: it drafts one token a row a round
-    for itself, and both step programs verify it (models/mixtral.py, ISSUE 56)."""
-    return bool(getattr(model_cfg, "mtp_layers", 0))
-
-
-def has_dense_prefix(model_cfg) -> bool:
-    """A mixture whose first layers are dense, a stack of their own beside
-    the expert layers' (models/mixtral.py, ISSUE 41)."""
-    return bool(getattr(model_cfg, "num_dense_layers", 0))
-
-
 def _experts_read(rows: jnp.ndarray, m: int) -> jnp.ndarray:
     """rows (L, E): how many rows each expert of each layer multiplied in
     one step of ``m`` rows a layer -> (the distinct experts a layer read,
@@ -443,53 +339,27 @@ def choose_mesh(config: EngineConfig, model_cfg, n_dev: int):
     ``mesh_shape`` with pp > 1: layers AND the KV cache shard by stage
     over "pp", tp within a stage (dense-cache llama family only). A
     mixture without a ``mesh_shape``: experts on a dedicated ``ep`` axis,
-    tp inside each expert. A model with a state store: none, and a
+    tp inside each expert. A family whose record refuses "mesh" (a state
+    store, an indexer, a next-token module, two stacks): none, and a
     ``mesh_shape`` is refused by name. Everything else: ``mesh_shape``'s dp/sp/tp (a
     mixture's ep too) or the default factoring, tp degraded toward dp
     until it tiles the model."""
-    from inference_gateway_tpu.models import mixtral
-
-    is_moe = isinstance(model_cfg, mixtral.MixtralConfig)
+    family = family_of(model_cfg)
     shape = config.mesh_shape or {}
     if not config.use_mesh or n_dev <= 1:
         return None, False
-    if carries_state(model_cfg):
-        # No mesh carries the state store yet. Asked for by name it is
-        # refused; left to the default, the model is served on one device
-        # (``mesh_axes`` and /debug/status say {"tp": 1}).
+    if "mesh" in family.refuses:
+        # Asked for by name it is refused; left to the default, the model is served
+        # on one device (``mesh_axes`` and /debug/status say {"tp": 1}).
         if shape:
-            raise ValueError(
-                f"{config.model}: no mesh ({shape}) carries the per-slot state store yet; "
-                "serve it on one device")
-        return None, False
-    if has_indexer(model_cfg):
-        # Nor the indexer and its paged keys (ISSUE 36): the same rule.
-        if shape:
-            raise ValueError(
-                f"{config.model}: no mesh ({shape}) carries the sparse-attention indexer and its "
-                "key cache yet; serve it on one device")
-        return None, False
-    if has_mtp(model_cfg):
-        # Nor the next-token module and the rounds' carried state (ISSUE 56): the same rule.
-        if shape:
-            raise ValueError(
-                f"{config.model}: no mesh ({shape}) lays out its next-token module and the drafts' carried state "
-                "yet; serve it on one device")
-        return None, False
-    if has_dense_prefix(model_cfg):
-        # Nor two stacks of layers, an output gate and a shared expert
-        # (ISSUE 41: models/mixtral.param_specs lays out neither): the same rule.
-        if shape:
-            raise ValueError(
-                f"{config.model}: no mesh ({shape}) lays out its leading dense layers' stack, its "
-                "attention gate and its shared expert yet; serve it on one device")
+            raise ValueError(f"{config.model}: " + family.refuses["mesh"].format(shape=shape))
         return None, False
     if shape.get("pp", 1) > 1:
         # Pipeline-parallel serving (SURVEY §2.4 PP row). Only the
         # dense-cache llama family for now — the paged pool, MoE
         # dispatch, and the draft model would each need their own
         # stage-sharded layout.
-        assert not is_moe, "pp serving: MoE not supported"
+        assert not family.experts, "pp serving: MoE not supported"
         assert config.attention == "dense", "pp serving requires dense cache"
         assert config.spec_draft is None, "pp serving: speculative not supported"
         assert config.vision_model is None, "pp serving: multimodal not supported"
@@ -500,7 +370,7 @@ def choose_mesh(config: EngineConfig, model_cfg, n_dev: int):
         mesh = create_pp_mesh(dp=shape.get("dp", 1), pp=shape["pp"], tp=shape.get("tp", 1))
         check_divisibility(model_cfg, mesh)
         return mesh, True
-    if is_moe:
+    if family.experts:
         # Experts ride a dedicated ep axis; tp shards within each
         # expert (BASELINE config 5 layout). A mesh_shape is honoured:
         # {"tp": 4} splits every expert over tp alone.
@@ -566,10 +436,7 @@ def build_params(model_cfg, model, seed: int, dtype, quantize: str | None, mesh,
     Weight-only int8 halves the per-step weight HBM stream. Quantize
     BEFORE sharding so the mesh path lays out (q, scale) pairs with
     quantized_specs — int8 composes with meshes and MoE."""
-    from inference_gateway_tpu.models import mixtral
-
     lap = lap or (lambda key, *wait_for: None)
-    is_moe = isinstance(model_cfg, mixtral.MixtralConfig)
     seeded = params is None  # ours to give up leaf by leaf when it is laid out
     if quantize in ("int8", "int4"):
         from inference_gateway_tpu.ops.quant import (
@@ -615,7 +482,7 @@ def build_params(model_cfg, model, seed: int, dtype, quantize: str | None, mesh,
         if pp:
             specs = pp_param_specs(model_cfg, quantized=quantize)
         else:
-            specs = model.param_specs(model_cfg) if is_moe else llama_param_specs(model_cfg)
+            specs = model.param_specs(model_cfg)
             if quantize in ("int8", "int4"):
                 specs = quantized_specs(specs, mode=quantize)
         params = shard_params(params, mesh, specs, donate=seeded)
@@ -624,7 +491,8 @@ def build_params(model_cfg, model, seed: int, dtype, quantize: str | None, mesh,
 
 
 class Engine:
-    """Owns params, cache, and the two jitted step functions."""
+    """Owns the weights, the cache, the family's record and the jitted step
+    programs; the scheduler drives it through the submit / fetch pairs."""
 
     def __init__(self, config: EngineConfig, params=None, model_cfg: llama.LlamaConfig | None = None):
         self.config = config
@@ -645,8 +513,6 @@ class Engine:
                 self.startup[key] += now - mark
             mark = now
 
-        from inference_gateway_tpu.models import mixtral
-
         preset = preset_config(config.model)
         if model_cfg is not None:
             self.model_cfg = model_cfg
@@ -655,67 +521,24 @@ class Engine:
 
             params, self.model_cfg = load_checkpoint(config.checkpoint_path, dtype=self.dtype)
         elif preset is not None:
-            self.model_cfg = preset[1]
+            self.model_cfg = preset
         else:
             self.model_cfg, params = self._load_hf(config.model)
 
-        # Model-family dispatch: MixtralConfig → MoE forward; a config with
-        # a Mamba state → the hybrid's; plain LlamaConfig → dense forward.
-        # Same call contract either way.
-        self.is_moe = isinstance(self.model_cfg, mixtral.MixtralConfig)
-        self.is_hybrid = is_hybrid(self.model_cfg)
-        self.carries_state = carries_state(self.model_cfg)
-        self.is_dsa = has_indexer(self.model_cfg)
-        self.is_mla = has_latent_pool(self.model_cfg)
-        self._model = mixtral if self.is_moe else llama
-        self.mtp = has_mtp(self.model_cfg)
-        if self.is_mla and config.spec_draft is not None:
-            # Refused by name (ISSUE 47); a vision model and int4 by its dense prefix's branch below.
-            own = ("it drafts for itself with its own next-token module, in both step programs" if self.mtp else
-                   "its own next-token-prediction module is not part of this preset (num_nextn_predict_layers 0)")
-            raise ValueError(f"{config.model}: speculative decoding with a separate draft model or prompt lookup has no "
-                             f"verify pass over the latent pool ({own}); serve it without --spec-draft")
-        if self.is_dsa:
-            # What the indexer's path does not carry is refused by name (ISSUE 36).
-            if config.spec_draft is not None:
-                raise ValueError(f"{config.model}: speculative decoding has no verify pass through the "
-                                 "sparse-attention indexer; serve it without --spec-draft")
-            if config.vision_model is not None:
-                raise ValueError(f"{config.model}: its vision tower is not built (the published config "
-                                 "gives no width for it); image input has no path, serve text")
-            if config.quantize == "int4":
-                raise ValueError(f"{config.model}: int8 is the one weight-only quantisation built for it, not int4")
-        if self.carries_state and not self.is_hybrid:
-            # What the convolution's carried tail rules out is refused by name (ISSUE 43).
-            if config.spec_draft is not None:
-                raise ValueError(f"{config.model}: speculative decoding would need each convolution layer's state at "
-                                 "the last ACCEPTED token, and a rejected draft has moved it on; serve it without "
-                                 "--spec-draft")
-            if config.vision_model is not None:
-                raise ValueError(f"{config.model}: it is a text model; image input has no path, serve text")
-            if config.quantize == "int4":
-                raise ValueError(f"{config.model}: int8 is the one weight-only quantisation built for it, not int4")
-        elif has_dense_prefix(self.model_cfg):
-            # What the two stacks' path does not carry is refused by name (ISSUE 41).
-            if config.spec_draft is not None:
-                raise ValueError(f"{config.model}: speculative decoding has no verify pass over its two stacks "
-                                 "of layers; serve it without --spec-draft")
-            if config.vision_model is not None:
-                raise ValueError(f"{config.model}: it is a text model; image input has no path, serve text")
-            if config.quantize == "int4":
-                raise ValueError(f"{config.model}: int8 is the one weight-only quantisation built for it, not int4")
-        if self.is_hybrid:
-            from inference_gateway_tpu.models import phi4flash
-
-            self._model = phi4flash
-            # What cannot carry the state is refused by name (ISSUE 32).
-            if config.spec_draft is not None:
-                raise ValueError(f"{config.model}: speculative decoding has no Mamba state to roll back; "
-                                 "serve it without --spec-draft")
-            if config.vision_model is not None:
-                raise ValueError(f"{config.model}: multimodal prefill carries no Mamba state")
-            if config.quantize == "int4":
-                raise ValueError(f"{config.model}: int8 is the one weight-only quantisation built for it, not int4")
+        # What serving this config takes, as its model module says it (models/family.py):
+        # the module whose forwards the step programs trace (same call contract for
+        # every family), the pool, the counted rows, and what the path does not carry.
+        self.family = family = family_of(self.model_cfg)
+        self._model = family.module
+        self.is_moe = family.experts
+        self.carries_state = family.state_store
+        self.is_mla = family.pool == "latent"
+        self.mtp = family.round_tokens == 2
+        asked = {"spec_draft": config.spec_draft is not None, "vision_model": config.vision_model is not None,
+                 "int4": config.quantize == "int4"}
+        for feature, wanted in asked.items():
+            if wanted and feature in family.refuses:   # refused by name, before anything is built
+                raise ValueError(f"{config.model}: {family.refuses[feature]}")
         # Presets have no tokenizer files: straight to the byte tokenizer,
         # never a hub look-up by preset name.
         self.tokenizer = load_tokenizer(config.tokenizer or (None if preset is not None else config.model))
@@ -741,24 +564,15 @@ class Engine:
         # scheduler's timeline).
         self.observatory = None
         if self.paged:
-            from inference_gateway_tpu.serving.kv_cache import (
-                PagedCacheConfig,
-                PageAllocator,
-                init_paged_cache,
-            )
+            from inference_gateway_tpu.serving.kv_cache import PagedCacheConfig, PageAllocator
 
             self.page_cfg = PagedCacheConfig(
                 page_size=config.page_size, num_pages=config.num_pages,
                 max_slots=config.max_slots, max_seq_len=config.max_seq_len,
             )
             self.allocator = PageAllocator(self.page_cfg)
-            if self.carries_state or self.is_dsa or self.is_mla:
-                # A model with a state store: pages for its caching layers alone,
-                # and the store beside them. An indexer: its keys under the same page ids.
-                # A latent attention: one array of compressed rows, no k and no v.
-                cache = self._model.init_paged_cache(self.model_cfg, self.page_cfg, dtype=self.dtype)
-            else:
-                cache = init_paged_cache(self.model_cfg, self.page_cfg, dtype=self.dtype)
+            # The family's own pool (``family.pool``), and beside it whatever it keeps a slot.
+            cache = self._model.init_paged_cache(self.model_cfg, self.page_cfg, dtype=self.dtype)
             if self.mesh is not None:
                 from jax.sharding import PartitionSpec as P
 
@@ -795,10 +609,11 @@ class Engine:
         # A model with a state store admits through the ragged step alone
         # (the one program that hands a segment its slot's state), so a
         # paged engine of it is a mixed engine whatever the flag says; so
-        # is one with an indexer (each query of a chunk selects for itself).
+        # is one with an indexer (each query of a chunk selects for itself)
+        # or a latent pool: ``family.mixed_alone``.
         ep = self.mesh.shape.get("ep", 1) if self.mesh is not None else 1
         self.mixed_ok = (
-            self.paged and (config.mixed_step or self.carries_state or self.is_dsa or self.is_mla)
+            self.paged and (config.mixed_step or family.mixed_alone)
             and config.spec_draft is None
             and ep == 1 and not self.pp and config.vision_model is None
         )
@@ -808,7 +623,8 @@ class Engine:
         # Progress requires room for one prefill token past a full decode
         # batch (two queries a row where the model drafts for itself: ISSUE 56);
         # pad a little so chunks aren't degenerate.
-        self.mixed_budget = max(self.mixed_budget, (2 if has_mtp(self.model_cfg) else 1) * config.max_slots + 8)
+        self.round_tokens = family.round_tokens   # the page horizon and the fetch block count by it too
+        self.mixed_budget = max(self.mixed_budget, self.round_tokens * config.max_slots + 8)
 
         # The dispatch verdict this engine's layouts take (ISSUE 12
         # satellite): surfaced as the engine.attention_path gauge and a
@@ -856,7 +672,7 @@ class Engine:
             layout = (self.model_cfg.num_heads // shards, kv_heads // shards,
                       self.model_cfg.num_kv_heads * self.model_cfg.hd // kv_heads, config.page_size,
                       self.page_cfg.max_pages_per_slot, self.dtype)
-            if self.is_mla:
+            if family.pool == "latent":
                 from inference_gateway_tpu.ops.mla_attention import mla_walk_plan
 
                 self.ragged_attention_plan = mla_walk_plan(
@@ -865,7 +681,7 @@ class Engine:
                 self.decode_attention_plan = {
                     "pages_per_visit": self.ragged_attention_plan["one_query_pages_per_visit"],
                     "operand_dtype": jnp.dtype(self.dtype).name, "skips_empty_slots": True}
-            elif not has_indexer(self.model_cfg):
+            elif family.pool == "kv":
                 if self.mixed_ok:
                     self.ragged_attention_plan = ragged_tile_plan(*layout)
                 self.decode_attention_plan = decode_walk_plan(*layout)
@@ -1042,20 +858,9 @@ class Engine:
             "prefill_batches": 0,
         }
         # What a paged decode chunk (``_run_chunk_ee``) and a mixed step count
-        # beside their tokens, a row of the fetch block each.
-        # A mixture with windowed layers carries MIXED_STATS and WINDOW_STATS
-        # together (ISSUE 41); one with an indexer DSA_STATS behind its own.
-        self.counts_window = self.is_moe and mixtral.counts_window_pages(self.model_cfg)
-        more_stats = ((DSA_STATS if self.is_dsa else ())
-                      + ((LATENT_STATS if self.is_mla else WINDOW_STATS) if self.counts_window else ())
-                      + (SSD_STATS if getattr(self.model_cfg, "mamba2", False) else ())
-                      + (KDA_STATS if getattr(self.model_cfg, "kda", False) else ())
-                      + (MTP_STATS if self.mtp else ()))
-        if self.is_hybrid:
-            self._chunk_stats = self._mixed_stats = WINDOW_STATS
-        else:
-            self._chunk_stats = CHUNK_EXPERT_STATS + more_stats if self.is_moe else ()
-            self._mixed_stats = MIXED_STATS + more_stats
+        # beside their tokens, a row of the fetch block each: the names are the
+        # model module's, in the order its forwards return the counts.
+        self._chunk_stats, self._mixed_stats = family.chunk_stats, family.mixed_stats
         if self.carries_state:
             # Segments that started at position 0, so from a zeroed state:
             # every admission, and every resume after a preemption.
@@ -1064,8 +869,7 @@ class Engine:
         # host-stepped programs take it as an operand, every call, and read
         # from it the token of each row flagged ``from_block``, so they can be
         # dispatched before that step is fetched. Zeros until a step has run.
-        counted = len(self._mixed_stats) if self.is_moe or self.is_hybrid else 0
-        self._last_block = jnp.zeros((2 + counted, S), jnp.float32)
+        self._last_block = jnp.zeros((2 + len(self._mixed_stats), S), jnp.float32)
         # A drafting model's rounds (ISSUE 56): what the round dispatched last left
         # on the device, a slot each, and what BOTH step programs start a decoding
         # row from, so that neither waits for a fetch: the pending token and its
@@ -1073,8 +877,6 @@ class Engine:
         # position behind it with the strip it was drawn from, and the tokens that
         # round emitted. A prompt's last chunk writes a slot's entry first.
         self._mtp = None
-        # Tokens a row may emit a round: the page horizon and the fetch block count by it.
-        self.round_tokens = 2 if self.mtp else 1
         if self.mtp and self.paged:   # (a dense engine of it holds the tree alone: the benchmark's reference child)
             assert self.mixed_ok, f"{config.model}: its next-token module drafts in the paged step programs"
             k = effective_top_k(config.top_k, self.model_cfg.vocab_size)
@@ -1336,7 +1138,7 @@ class Engine:
                 w = page * ps + pos_att % ps
                 valid = (~done) & (pos < max_len) & (pos < reserved)
                 w = jnp.where(valid, w, self._flat_size)
-                counted = {"valid": ~done, "with_aux": True} if self.is_moe or self.is_hybrid else {}
+                counted = {"valid": ~done, "with_aux": True} if self._chunk_stats else {}
                 # A done row attends nothing: the decode kernel passes over a
                 # slot of length 0 and writes its row as zeros (ISSUE 37), where
                 # a frozen row's stale context cost every step its pages (an
@@ -1348,9 +1150,9 @@ class Engine:
                 last_only=True, mesh=self.mesh, **counted)
             if rows and self.is_moe:  # ``_chunk_stats``: a mixture's experts read, an indexer's or a window's counts
                 with jax.named_scope("carry"):
-                    per, more = self._split_aux(rows[0])
+                    per, more = self._model.split_aux(self.model_cfg, rows[0])
                     read = jnp.concatenate([_experts_read(per, tok.shape[0] * self.model_cfg.experts_per_token), more])
-            elif rows:  # a windowed hybrid's pages (WINDOW_STATS)
+            elif rows:  # counted by the model, in ``_chunk_stats``' order (a windowed hybrid's pages)
                 read = rows[0]
         with jax.named_scope("mask_bias"):
             logits = self._bias_logits(logits, mbias, None, mbits, ms, automaton)
@@ -1398,7 +1200,7 @@ class Engine:
             gumbels = chunk_gumbels(keys, k_eff)
         # A paged mixture's carry also sums the experts its steps read, a
         # windowed hybrid's the pages they read and held.
-        counted = (self.is_moe or self.is_hybrid) and write_args is not None
+        counted = bool(self._chunk_stats) and write_args is not None
         with jax.named_scope("carry"):
             done0 = self._chunk_done0(tokens, positions, done, budgets, stop_table,
                                       mstates, mask_args[4], automaton)
@@ -1435,7 +1237,7 @@ class Engine:
                 # over the steps that ran, every other count their sum.
                 stats = read_sum[0]
                 if self.is_moe:
-                    stats = stats.at[:len(CHUNK_EXPERT_STATS)].divide(jnp.maximum(i_ran, 1).astype(jnp.float32))
+                    stats = stats.at[:2].divide(jnp.maximum(i_ran, 1).astype(jnp.float32))
                 out_lps = jnp.concatenate([out_lps, jnp.broadcast_to(stats[:, None], (stats.shape[0], S))])
             # Steps the loop never ran emit each row's FINAL frozen token
             # (not the chunk-entry one), so the emitted block reads exactly
@@ -1551,7 +1353,7 @@ class Engine:
         path — packed width is the fixed mixed_budget, so admission
         never recompiles and never pays bucket padding. Traced inside
         _mixed_step_fn."""
-        counted = {"with_aux": True} if self.is_moe or self.is_hybrid else {}
+        counted = {"with_aux": True} if self._mixed_stats else {}
         logits, cache, *rows = self._model.forward_ragged(
             params, self.model_cfg, tokens, positions, cache, write_idx,
             page_table, q_starts, q_lens, kv_lens, mesh=self.mesh, **counted)
@@ -1564,16 +1366,15 @@ class Engine:
             keys = per_row_keys(rng, seeds, use_seed, kv_lens)
             toks = sample_tokens(logits, rng, temps, top_ps, top_k=self.config.top_k, row_keys=keys)
             logprobs = compute_logprobs(logits, toks)
-        if rows and self.is_hybrid:
-            return toks, logprobs, cache, rows[0]  # WINDOW_STATS, counted by the model
+        if rows and not self.is_moe:
+            return toks, logprobs, cache, rows[0]  # counted by the model, in ``_mixed_stats``' order
         if rows:
-            # Rows of the fetch block, in MIXED_STATS' order: per layer the
+            # Rows of the fetch block, in ``_mixed_stats``' order: per layer the
             # most rows one expert multiplied and the mean over experts, the
             # experts read and the kernels' visits; each a mean over the layers.
-            # Then DSA_STATS, counted by the attention seam, or WINDOW_STATS,
-            # counted by the model.
+            # Then what the attention seam or the model counted.
             with jax.named_scope("carry"):
-                per, more = self._split_aux(rows[0])  # (L, E)
+                per, more = self._model.split_aux(self.model_cfg, rows[0])  # (L, E)
                 stats = jnp.stack([jnp.mean(jnp.max(per, axis=1).astype(jnp.float32)), jnp.mean(per.astype(jnp.float32))])
                 m = tokens.shape[-1] * self.model_cfg.experts_per_token
                 return toks, logprobs, cache, jnp.concatenate([stats, _experts_read(per, m), more])
@@ -1693,7 +1494,7 @@ class Engine:
 
     def _mtp_stats(self, aux, m: int, count, accepted, decode, mixed: bool):
         """A drafting model's rows of the fetch block: the mixture's own
-        (``MIXED_STATS`` or ``CHUNK_EXPERT_STATS``, then LATENT_STATS), then MTP_STATS."""
+        (``_mixed_stats`` or ``_chunk_stats``: the experts', the latent pool's), then the rounds' three."""
         per, more = aux
         lead = ([jnp.mean(jnp.max(per, axis=1).astype(jnp.float32)), jnp.mean(per.astype(jnp.float32))] if mixed else [])
         rounds = decode & (count > 0)
@@ -1814,7 +1615,7 @@ class Engine:
                 zeros, zeros, jnp.zeros((n_steps, S), jnp.float32),
                 jnp.zeros((len(self._chunk_stats),), jnp.float32)))
         with jax.named_scope("carry"):
-            stats = read_sum.at[:len(CHUNK_EXPERT_STATS)].divide(jnp.maximum(i_ran, 1).astype(jnp.float32))
+            stats = read_sum.at[:2].divide(jnp.maximum(i_ran, 1).astype(jnp.float32))   # (``_experts_read``'s pair: means)
             block = jnp.concatenate([out_t.reshape(2 * n_steps, S), out_l.reshape(2 * n_steps, S), out_c,
                                      jnp.broadcast_to(stats[:, None], (stats.shape[0], S)),
                                      pos_f.astype(jnp.float32)[None, :]])
@@ -2008,7 +1809,7 @@ class Engine:
         from inference_gateway_tpu.ops.paged_attention import ragged_walk_pages
 
         return ragged_walk_pages([(len(r.token_ids), r.start + len(r.token_ids)) for r in rows],
-                                 attention_layer_windows(self.model_cfg), self.ragged_attention_plan,
+                                 self.family.attention_layers, self.ragged_attention_plan,
                                  self.config.page_size)
 
     def _state_stats(self, rows: int) -> dict | None:
@@ -2018,126 +1819,15 @@ class Engine:
             return None
         return {"state_bytes": int(rows) * self.model_cfg.state_bytes_per_slot}
 
-    def state_status(self) -> dict | None:
-        """/debug/status of a model with a state store: the store, the layers
-        that write the page pool and who reads the shared one, and the two
-        counters (segments started from zero; prefix hits declined)."""
-        if not self.carries_state:
-            return None
-        cfg = self.model_cfg
-        if self.is_hybrid:
-            state_layers, kinds = cfg.state_layers, {"window": cfg.mid, "full": 1, "shared_readers": cfg.cross_layers}
-        else:  # a mixture with convolution layers (ISSUE 43), Mamba-2 layers (ISSUE 50) or Kimi Delta Attention (ISSUE 54)
-            state_layers = cfg.conv_layers
-            kinds = {"full": cfg.cache_layers, "kda" if cfg.kda else "mamba2" if cfg.mamba2 else "conv": state_layers}
-        out = {
-            "state_store": {"slots": self.config.max_slots, "layers": state_layers,
-                            "bytes": self.config.max_slots * cfg.state_bytes_per_slot},
-            "cache_layers": kinds,
-            "state_resets": self.metrics["state_resets"],
-            "prefix_hits_declined": self.prefix_cache.declined if self.prefix_cache is not None else 0,
-        }
-        if getattr(cfg, "mamba2", False):   # how the Mamba-2 scan is done (ISSUE 50; static)
-            out["ssd"] = {"heads": cfg.ssm_heads, "state": [cfg.ssm_head_dim, cfg.ssm_state], "block": cfg.ssd_block,
-                          "rows_of_more_than_one_token": "ssd_chunk_kernel" if on_tpu() else "ssd_chunk_twin",
-                          "one_token_rows": "ssm_state_update_kernel" if on_tpu() else "state_update"}
-        if getattr(cfg, "kda", False):   # how the delta rule is run (ISSUE 54; static)
-            out["kda"] = {"heads": cfg.kda_heads, "state": [cfg.kda_head_dim, cfg.kda_head_dim], "block": cfg.kda_block,
-                          "decay": "a channel", "state_bytes_per_slot_per_layer": cfg.kda_inner * cfg.kda_head_dim * 4,
-                          "rows_of_more_than_one_token": "kda_chunk_kernel" if on_tpu() else "kda_chunk_twin",
-                          "one_token_rows": "kda_state_update_kernel" if on_tpu() else "state_update"}
-        return out
-
-    def _split_aux(self, aux):
-        """A mixture's auxiliary -> (the rows each expert multiplied (expert
-        layers, E), the step's further counts: DSA_STATS summed over the layers,
-        or WINDOW_STATS, or none)."""
-        if self.is_dsa:  # the pair (the FFN's, the attention seam's (L, 2))
-            return aux[0], jnp.sum(aux[1], axis=0)
-        if self.counts_window:  # the pair (the FFN's, the windowed layers' pages (2,))
-            return aux
-        return aux, jnp.zeros((0,), jnp.float32)
-
-    def mixture_status(self) -> dict | None:
-        """/debug/status fields of a mixture (static): ``router`` (how it
-        scores, chooses and weighs), ``shared_experts`` beside the routed
-        ones, ``dense_prefix`` (leading dense layers), and for one with
-        windowed layers ``cache_layers`` (how many of its layers read a
-        window of the pool, how many all of it)."""
-        if not self.is_moe:
-            return None
-        cfg = self.model_cfg
-        out = {"router": {"score": cfg.router_score, "experts": cfg.num_experts,
-                          # (ISSUE 54) a chip's share: the router chooses among ``experts``, this
-                          # process computes ``experts_held`` of them and nothing in place of the rest
-                          "experts_held": cfg.held_experts, "experts_first": cfg.experts_first,
-                          "experts_per_token": cfg.experts_per_token, "route_scale": cfg.route_scale,
-                          "chosen_by": "score + bias" if cfg.router_score == "sigmoid" else "logit",
-                          "weights": ("the chosen scores, normalised" if cfg.router_score == "sigmoid"
-                                      else "softmax over the chosen logits")},
-               "shared_experts": cfg.shared_experts, "dense_prefix": cfg.num_dense_layers}
-        if self.counts_window and not self.carries_state:   # (with a state store: ``state_status`` says)
-            windowed = self._model.windowed_layers(cfg)
-            out["cache_layers"] = {"window": windowed, "full": cfg.num_layers - windowed}
-        return out
-
-    def latent_status(self) -> dict | None:
-        """/debug/status ``latent_pool`` of a model with a latent attention: the
-        pool's row and bytes a token, and how it is read (static)."""
-        if not self.is_mla:
-            return None
-        cfg, itemsize = self.model_cfg, jnp.dtype(self.dtype).itemsize
-        return {
-            "row_values": cfg.latent_width, "row_lanes": cfg.latent_row,
-            "row": f"[{cfg.kv_lora_rank} latent, {cfg.qk_rope_head_dim} rotary key, "
-                   f"{cfg.latent_row - cfg.latent_width} zeros to the 128-lane grid]",
-            "bytes_per_token_per_layer": cfg.latent_row * itemsize,
-            "bytes_per_token": cfg.cache_layers * cfg.latent_row * itemsize, "layers": cfg.cache_layers,
-            "arrays": [self._model.MLA_POOL], "heads": cfg.num_heads, "value_dim": cfg.kv_lora_rank,
-            "read": "absorbed: every head's query in the latent's space against one key row, "
-                    "the row's first value_dim values the value, a page fetched once a visit",
-            "residual_streams": cfg.hc_mult or 1,
-        }
-
-    def mtp_status(self, scheduler=None) -> dict | None:
-        """/debug/status ``mtp`` and ``vocab`` of a model that drafts with its own
-        next-token module (ISSUE 56): the module (static), with a ``scheduler`` what
-        its rounds have come to, and the share of the vocabulary held."""
-        if not self.mtp:
-            return None
-        cfg = self.model_cfg
-        mtp = {"layers": cfg.mtp_layers, "drafts": "one token a row a round, from the model's own next-token module",
-               "verified": "as the row's second query, in both step programs; accepted by speculative.spec_accept"}
-        if scheduler is not None:
-            drafted = scheduler.spec_slot_rounds
-            mtp.update(drafted=drafted, accepted=scheduler.spec_accepted,
-                       tokens_per_round=round(scheduler.spec_emitted / drafted, 3) if drafted else None)
-        return {"mtp": mtp, "vocab": {"held": cfg.vocab_size, "published": cfg.vocab_published or cfg.vocab_size}}
-
-    def dsa_status(self) -> dict | None:
-        """/debug/status ``dsa`` of a model with a sparse-attention indexer:
-        its sizes, and how the selection and the read are done (static)."""
-        if not self.is_dsa:
-            return None
-        cfg = self.model_cfg
-        row_tokens = self.page_cfg.max_pages_per_slot * self.page_cfg.page_size
-        walk = sparse_attention.one_query_read(cfg.indexer_topk, row_tokens) == "walk"
-        return {
-            "topk": cfg.indexer_topk, "indexer_heads": cfg.indexer_heads,
-            "indexer_head_dim": cfg.indexer_head_dim,
-            "index_cache_bytes_per_token": cfg.num_layers * cfg.indexer_head_dim * jnp.dtype(self.dtype).itemsize,
-            "short_context": "a query that sees topk tokens or fewer keeps them all, by the same path (no plain-attention branch)",
-            # Static a program: a row of the page table against WALK_UNDER x topk (ISSUE 45).
-            "one_query_row": {"read": "walk" if walk else "gather", "row_tokens": row_tokens,
-                              "walk_under": sparse_attention.WALK_UNDER,
-                              "walk_up_to_tokens": sparse_attention.WALK_UNDER * cfg.indexer_topk},
-            "select": {"one_query_row": ("mask by the exact k-th largest score (32 counting passes, ties by position)"
-                                         if walk else "lax.top_k over the row's scores"),
-                       "prompt_chunk": "exact k-th largest by 32 counting passes, ties by position, as a mask"},
-            "read": {"one_query_row": ("whole pages under the mask, by the paged decode kernel (every page the row holds)"
-                                       if walk else "token gather of the chosen keys and values (topk tokens)"),
-                     "prompt_chunk": "tiles of 128 queries over every block of keys up to the tile's last position, masked"},
-        }
+    def family_status(self, scheduler=None) -> dict:
+        """The family's /debug/status blocks, written by its model module
+        (``Family.status``) over this engine's facts; with a ``scheduler``, what a
+        drafting model's rounds have come to."""
+        return self.family.status(StatusView(
+            max_slots=self.config.max_slots, page_cfg=self.page_cfg if self.paged else None, dtype=self.dtype,
+            state_resets=self.metrics.get("state_resets", 0),
+            prefix_hits_declined=self.prefix_cache.declined if self.prefix_cache is not None else 0,
+            scheduler=scheduler))
 
     def _prefill_one_ragged(self, prompt: list[int], slot: int, temp: float, top_p: float,
                             seed: int | None = None, grammar=None) -> PrefillResult:
@@ -3239,7 +2929,7 @@ class Engine:
         400 check can never drift from actual admission behavior."""
         biggest = max(b for b in self.config.prefill_buckets
                       if b <= self.config.max_seq_len)
-        if (self.carries_state or self.is_dsa or self.is_mla) and self.mixed_ok:
+        if self.family.mixed_alone and self.mixed_ok:
             biggest = 0  # every prompt is a "long" one: the ragged step admits them all
         ring_ok = (
             self.mesh is not None

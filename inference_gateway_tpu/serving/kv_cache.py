@@ -2,7 +2,7 @@
 
 vLLM-style paging re-designed for XLA's static-shape world (SURVEY.md §7
 "the hard parts"): the device holds a fixed pool of KV pages per layer,
-(L, num_pages, page_size, Hkv*D) — heads folded into the minor axis for
+(L, num_pages, page_size, Hkv*D; each model module's ``init_paged_cache``) — heads folded into the minor axis for
 lane-aligned page DMA (ops/paged_attention.py). The allocator is plain
 host Python: slots own ordered page lists, pages are allocated at
 prefill admission and lazily when decode crosses a page boundary, and
@@ -15,10 +15,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-import jax.numpy as jnp
 import numpy as np
-
-from inference_gateway_tpu.models.llama import LlamaConfig
 
 
 class OutOfPagesError(RuntimeError):
@@ -247,9 +244,3 @@ class PrefixCache:
         out = {"cached_pages": len(self._entries), "hits": self.hits, "misses": self.misses}
         return {**out, "declined": self.declined} if self.decline_hits else out
 
-
-def init_paged_cache(model_cfg: LlamaConfig, cache_cfg: PagedCacheConfig, dtype=jnp.bfloat16):
-    """Device arrays: k/v of shape (L, num_pages, page_size, Hkv*D)."""
-    P = cache_cfg.resolve_num_pages()
-    shape = (model_cfg.num_layers, P, cache_cfg.page_size, model_cfg.num_kv_heads * model_cfg.hd)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}

@@ -156,11 +156,11 @@ def kv_bytes_per_token(cfg, dtype_bytes: int = 2) -> int:
 
 
 def resolve_model_cfg(model: str):
-    from inference_gateway_tpu.serving.engine import preset_config
+    from inference_gateway_tpu.models.family import preset_config
 
     preset = preset_config(model)
     if preset is not None:
-        return preset[1]
+        return preset
     raise KeyError(f"unknown model preset: {model}")
 
 

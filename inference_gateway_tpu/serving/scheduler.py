@@ -2103,7 +2103,7 @@ class Scheduler:
         return count, finished, reason
 
     def _count_rounds(self, stats: dict | None) -> None:
-        """A drafting model's step, as its program counted it (ISSUE 56; MTP_STATS of
+        """A drafting model's step, as its program counted it (ISSUE 56; ``mixtral.MTP_STATS`` of
         the fetch block): the counters /metrics and /debug/status serve, and the
         refused drafts' positions as wasted work."""
         drafted, accepted = int((stats or {}).get("mtp_drafted", 0)), int((stats or {}).get("mtp_accepted", 0))

@@ -908,8 +908,7 @@ class SidecarServer:
                 # probe round, so compact by construction.
                 brief["device"] = self.observatory.fleet_summary()
             return Response.json(brief)
-        state_status = getattr(self.engine, "state_status", None)  # test doubles have none
-        mixture_status = getattr(self.engine, "mixture_status", None)
+        family_status = getattr(self.engine, "family_status", None)  # test doubles have none
         status: dict[str, Any] = {
             "model": self.model_name,
             "uptime_seconds": round(self._clock.now() - self._started, 3),
@@ -956,34 +955,15 @@ class SidecarServer:
             # engages is `batch` of the timeline's `decode` records against
             # the slots). None where no decode program holds the kernel.
             "decode_attention": getattr(self.engine, "decode_attention_plan", None),
-            # A model with a state store (ISSUE 32): ``state_store`` (slots,
-            # layers, bytes), ``cache_layers`` (window, full, shared_readers),
-            # ``state_resets`` and ``prefix_hits_declined``; absent otherwise.
-            # With Mamba-2 layers (ISSUE 50) also ``ssd`` (heads, a head's state,
-            # the scan's block, what serves a row of one token and of more), and
-            # the `mixed` and `decode` records carry `ssd_blocks` and `state_rows`.
-            # With Kimi Delta Attention layers (ISSUE 54) ``kda`` likewise, the records
-            # `kda_blocks` and `state_rows`, `latent_pool` beside it, and `router`
-            # says `experts_held` of `experts`.
-            **((state_status() if state_status else None) or {}),
-            # A model with a sparse-attention indexer (ISSUE 36): its sizes and
-            # how selection and read are done; absent otherwise. Its `decode`
-            # and `mixed` timeline records carry `dsa_context_tokens` and
-            # `dsa_kv_tokens_read`.
-            **({"dsa": self.engine.dsa_status()} if getattr(self.engine, "is_dsa", False) else {}),
-            # A model with a latent attention (ISSUE 47): the pool's row (values,
-            # lanes), bytes a token, how it is read, the residual streams; absent
-            # otherwise. Its `decode` and `mixed` timeline records carry
-            # `latent_pages_read` beside `context_pages` and `experts_read`.
-            **({"latent_pool": self.engine.latent_status()} if getattr(self.engine, "is_mla", False) else {}),
-            # (ISSUE 56) a model that drafts with its own next-token module: ``mtp`` and ``vocab``.
-            **(self.engine.mtp_status(self.scheduler) if getattr(self.engine, "mtp", False) else {}),
-            # A mixture (ISSUE 41): ``router`` (score, chosen_by, weights),
-            # ``shared_experts``, ``dense_prefix``, and with windowed layers
-            # ``cache_layers`` {window, full}: its `decode` and `mixed` records
-            # then carry `window_pages_read` and `context_pages` beside
-            # `experts_read`; absent otherwise.
-            **((mixture_status() if mixture_status else None) or {}),
+            # The model family's blocks, written by its model module (``Family.status``,
+            # models/family.py; docs/observability.md lists them). A state store (ISSUE 32,
+            # 43, 50, 54): ``state_store``, ``cache_layers``, ``state_resets``,
+            # ``prefix_hits_declined``, and ``ssd`` or ``kda``; an indexer (ISSUE 36): ``dsa``;
+            # a latent attention (ISSUE 47): ``latent_pool``; a next-token module (ISSUE 56):
+            # ``mtp`` and ``vocab``; a mixture (ISSUE 41): ``router``, ``shared_experts``,
+            # ``dense_prefix``, and with windowed layers ``cache_layers`` {window, full}. The
+            # `decode` and `mixed` timeline records carry the counts each names there.
+            **(family_status(self.scheduler) if family_status else {}),
             # Desynchronized decode (ISSUE 14): the shape at which the
             # decode loop stops on device and chains host-free.
             "decode": {
@@ -1108,12 +1088,11 @@ class SidecarServer:
         messages = body.get("messages") or []
         prompt_ids = self.engine.tokenizer.apply_chat_template(messages)
         embeds = None
-        if getattr(self.engine, "is_dsa", False) and any(
+        refused = getattr(self.engine, "family", None) and self.engine.family.refuses.get("image_part")
+        if refused and any(
                 isinstance(part, dict) and part.get("type") == "image_url"
                 for m in messages if isinstance(m.get("content"), list) for part in m["content"]):
-            raise _BadRequest(
-                f"{self.model_name}: its vision tower is not built; image input has no path, send text",
-                code="unsupported_content", param="messages")
+            raise _BadRequest(f"{self.model_name}: {refused}", code="unsupported_content", param="messages")
         if self.engine.vision_cfg is not None:
             images = self._decode_images(messages)
             if images:

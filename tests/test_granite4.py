@@ -338,7 +338,7 @@ def benchmark_limits():
 @pytest.mark.parametrize("dtype,quantize", CASES)
 def test_chunked_prompt_then_decode_across_chunk_and_block_boundaries_matches_the_reference(dtype, quantize):
     eng = engine(dtype, quantize)
-    assert eng.mixed_ok and eng.carries_state and not eng.is_hybrid and eng.mesh is None
+    assert eng.mixed_ok and eng.carries_state and eng._model is mixtral and eng.mesh is None
     diffs = np.concatenate([gaps(eng, seq, start, served) for seq, start, served in serve_two_rows(eng)])
     assert len(diffs) == 23   # row 0: 1 + 5 beside the chunks + 8; row 1: 1 + 8
     if dtype == "float32":
@@ -423,7 +423,7 @@ def test_a_slot_admitted_again_and_a_row_recomputed_need_no_reset():
     eng.allocator.release(0)
     tok, lp, _ = admit(eng, 0, second[:33], chunks=(2, 36))    # recomputed: the same tokens again, other chunks
     assert gaps(eng, second[:33] + [tok], 33, [lp]).max() < 5e-5
-    assert eng.metrics["state_resets"] == 3 and eng.state_status()["state_resets"] == 3
+    assert eng.metrics["state_resets"] == 3 and eng.family_status()["state_resets"] == 3
 
 
 def test_the_mixed_step_matches_the_decode_program():
@@ -469,7 +469,7 @@ def test_the_counters_by_hand():
     assert stats[0]["context_pages"] == stats[0]["window_pages_read"] == (41 + 7) // 8 + (36 + 7) // 8
     _, _, stats = decode(eng, {0: (5, 41), 1: (6, 36)}, 1)
     assert stats[0]["ssd_blocks"] == 0.0 and stats[0]["state_rows"] == 2.0 * 4 and 1 <= stats[0]["experts_read"] <= 8
-    status = eng.state_status()
+    status = eng.family_status()
     assert status["state_store"] == {"slots": 4, "layers": 4, "bytes": 4 * CFG.state_bytes_per_slot}
     assert status["cache_layers"] == {"full": 1, "mamba2": 4}
     assert eng.cache["k"].shape[0] == 1 and eng.cache["ssm"].shape == (4, 4, 4, 32, 16)
@@ -477,9 +477,10 @@ def test_the_counters_by_hand():
 
 
 def test_what_the_state_rules_out_is_refused_or_declined_by_name():
-    from inference_gateway_tpu.serving.engine import carries_state, choose_mesh
+    from inference_gateway_tpu.models.family import family_of
+    from inference_gateway_tpu.serving.engine import choose_mesh
 
-    assert carries_state(CFG) and carries_state(mixtral.PRESETS["granite-4.0-h-small-stage10"])
+    assert family_of(CFG).state_store and family_of(mixtral.PRESETS["granite-4.0-h-small-stage10"]).state_store
     config = EngineConfig(model=PRESET, attention="paged", mesh_shape={"tp": 2})
     with pytest.raises(ValueError, match="no mesh"):
         choose_mesh(config, CFG, 4)

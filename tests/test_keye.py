@@ -168,8 +168,8 @@ def test_chunked_prompt_then_decode_beside_other_rows_matches_the_reference(dtyp
     both rows then decode two chunks through the paged cache, slot 1 at 131
     and more. Every logprob of both rows is held to the reference."""
     eng = engine(dtype, quantize, max_seq_len=READS[read])
-    assert eng.mixed_ok and eng.is_dsa and eng.mesh_axes == {"tp": 1}
-    assert eng.dsa_status()["one_query_row"]["read"] == read
+    assert eng.mixed_ok and eng.family.pool == "kv+index" and eng.mesh_axes == {"tp": 1}
+    assert eng.family_status()["dsa"]["one_query_row"]["read"] == read
     a, b = prompt(30, 3), prompt(130, 4)
     tok, lp, _ = admit(eng, 0, a)
     row_a = [tok, 30, [tok], [lp]]
@@ -314,7 +314,7 @@ def test_contexts_at_or_under_topk_are_the_skeletons_plain_attention(read):
                                   max_seq_len=READS[read], mixed_step=True, mixed_step_tokens=40, decode_chunk=4,
                                   use_mesh=False),
                      params=with_indexer.params, model_cfg=dataclasses.replace(CFG, indexer_topk=0))
-    assert not without.is_dsa and set(without.cache) == {"k", "v"} and set(with_indexer.cache) == {"k", "v", "ki"}
+    assert without.family.pool == "kv" and set(without.cache) == {"k", "v"} and set(with_indexer.cache) == {"k", "v", "ki"}
     outs = []
     for eng in (with_indexer, without):
         a, b = prompt(12, 8), prompt(TOPK - 4, 9)
@@ -402,7 +402,7 @@ def test_the_counters_are_the_counts_by_hand(read):
     _, _, stats = admit(eng, 2, prompt(10, 18), beside={1: row})
     assert stats[0]["dsa_context_tokens"] == L * (75 + sum(range(1, 11)))
     assert stats[0]["dsa_kv_tokens_read"] == L * ({"gather": TOPK, "walk": 80}[read] + 10 * block)
-    status = eng.dsa_status()
+    status = eng.family_status()["dsa"]
     assert (status["topk"], status["indexer_heads"], status["indexer_head_dim"]) == (TOPK, 4, 16)
     assert status["one_query_row"] == {"read": read, "row_tokens": READS[read], "walk_under": sa.WALK_UNDER,
                                        "walk_up_to_tokens": sa.WALK_UNDER * TOPK}

@@ -378,7 +378,7 @@ def benchmark_limits():
 @pytest.mark.parametrize("dtype,quantize", CASES)
 def test_chunked_prompt_then_decode_across_chunk_and_block_boundaries_matches_the_reference(dtype, quantize, eng32):
     eng = eng32 if dtype == "float32" else engine(dtype, quantize)
-    assert eng.mixed_ok and eng.carries_state and eng.is_mla and not eng.is_hybrid and eng.mesh is None
+    assert eng.mixed_ok and eng.carries_state and eng.is_mla and eng._model is mixtral and eng.mesh is None
     diffs = np.concatenate([gaps(eng, seq, start, served) for seq, start, served in serve_two_rows(eng)])
     assert len(diffs) == 23   # row 0: 1 + 5 beside the chunks + 8; row 1: 1 + 8
     if dtype == "float32":
@@ -421,7 +421,7 @@ def test_a_slot_admitted_again_a_row_recomputed_and_a_probe_sent_twice(eng32):
     eng.allocator.release(0)
     tok, lp, _ = admit(eng, 0, second[:33], chunks=(2, 36))    # recomputed: the same tokens again, other chunks
     assert gaps(eng, second[:33] + [tok], 33, [lp]).max() < 5e-5
-    assert eng.metrics["state_resets"] - resets == 4 == eng.state_status()["state_resets"] - resets
+    assert eng.metrics["state_resets"] - resets == 4 == eng.family_status()["state_resets"] - resets
     assert eng.prefix_cache is None or eng.prefix_cache.decline_hits
 
 
@@ -464,14 +464,14 @@ def test_the_counters_and_the_status_by_hand(eng32):
     assert all(0 <= s["experts_read"] <= 4 for s in stats)
     _, _, stats = decode(eng, {0: (5, 41), 1: (6, 36)}, 1)
     assert stats[0]["kda_blocks"] == 0.0 and stats[0]["state_rows"] == 2.0 * 4 and 0 <= stats[0]["experts_read"] <= 4
-    status = eng.state_status()
+    status = eng.family_status()
     assert status["state_store"] == {"slots": 4, "layers": 6, "bytes": 4 * CFG.state_bytes_per_slot}
     assert status["cache_layers"] == {"full": 2, "kda": 6}
     assert status["kda"]["block"] == 8 and status["kda"]["state"] == [32, 32] and status["kda"]["decay"] == "a channel"
     assert status["kda"]["rows_of_more_than_one_token"] == "kda_chunk_twin"
-    router = eng.mixture_status()["router"]
+    router = eng.family_status()["router"]
     assert (router["experts"], router["experts_held"], router["experts_first"]) == (8, 4, 0)
-    pool = eng.latent_status()
+    pool = eng.family_status()["latent_pool"]
     assert (pool["layers"], pool["row_values"], pool["row_lanes"]) == (2, 40, 128)
     assert pool["bytes_per_token"] == 2 * 128 * 4
     assert eng.cache["kv"].shape[0] == 2 and eng.cache["kda"].shape == (6, 4, 4, 32, 32)
@@ -479,10 +479,11 @@ def test_the_counters_and_the_status_by_hand(eng32):
 
 
 def test_what_the_state_and_the_pool_rule_out_is_refused_or_declined_by_name():
-    from inference_gateway_tpu.serving.engine import carries_state, choose_mesh, has_latent_pool
+    from inference_gateway_tpu.models.family import family_of
+    from inference_gateway_tpu.serving.engine import choose_mesh
 
     big = mixtral.PRESETS["kimi-linear-48b-a3b-stage8"]
-    assert carries_state(CFG) and carries_state(big) and has_latent_pool(CFG) and has_latent_pool(big)
+    assert all(family_of(c).state_store and family_of(c).pool == "latent" for c in (CFG, big))
     config = EngineConfig(model=PRESET, attention="paged", mesh_shape={"tp": 2})
     with pytest.raises(ValueError, match="no mesh"):
         choose_mesh(config, CFG, 4)

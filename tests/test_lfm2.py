@@ -240,7 +240,7 @@ def benchmark_limits():
 @pytest.mark.parametrize("dtype,quantize", CASES)
 def test_chunked_prompt_then_decode_across_convolution_runs_matches_the_reference(dtype, quantize):
     eng = engine(dtype, quantize)
-    assert eng.mixed_ok and eng.carries_state and not eng.is_hybrid and eng.mesh is None
+    assert eng.mixed_ok and eng.carries_state and eng._model is mixtral and eng.mesh is None
     diffs = np.concatenate([gaps(eng, seq, start, served) for seq, start, served in serve_two_rows(eng)])
     assert len(diffs) == 23   # row 0: 1 + 5 beside the chunks + 8; row 1: 1 + 8
     if dtype == "float32":
@@ -298,7 +298,7 @@ def test_a_slot_admitted_again_and_a_row_recomputed_need_no_reset():
     eng.allocator.release(0)
     tok, lp, _ = admit(eng, 0, second[:33], chunks=(2, 36))    # recomputed: the same tokens again, other chunks
     assert gaps(eng, second[:33] + [tok], 33, [lp]).max() < 5e-5
-    assert eng.metrics["state_resets"] == 3 and eng.state_status()["state_resets"] == 3
+    assert eng.metrics["state_resets"] == 3 and eng.family_status()["state_resets"] == 3
 
 
 def test_the_mixed_step_matches_the_decode_program():
@@ -431,7 +431,7 @@ def test_the_counters_are_the_counts_by_hand():
     held = sum(-(-n // 8) for n in range(71, 75))
     assert (stats[0]["context_pages"], stats[0]["window_pages_read"]) == (2 * held, 2 * held)
     assert stats[0]["experts_read"] == 2.0 and stats[0]["expert_visits"] >= 2.0      # one row, top-2
-    status = {**eng.state_status(), **eng.mixture_status()}
+    status = eng.family_status()
     assert status["cache_layers"] == {"full": 2, "conv": 6}
     assert status["state_store"] == {"slots": 4, "layers": 6, "bytes": 4 * 6 * 2 * 64 * 4}
     assert (status["router"]["score"], status["shared_experts"], status["dense_prefix"]) == ("sigmoid", 0, 2)
@@ -446,7 +446,7 @@ def test_prefix_hits_are_declined_and_counted():
     admit(eng, 0, shared + prompt(5, 13))
     eng.prefix_cache.insert(shared, eng.allocator.pages_of(0))
     pages, matched = eng.prefix_cache.match(shared + prompt(9, 14))
-    assert (pages, matched) == ([], 0) and eng.state_status()["prefix_hits_declined"] == 1
+    assert (pages, matched) == ([], 0) and eng.family_status()["prefix_hits_declined"] == 1
 
 
 @pytest.mark.parametrize("kw,what", [

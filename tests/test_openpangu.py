@@ -482,10 +482,11 @@ def test_f_a_refused_drafts_row_leaves_no_trace(eng32):
 
 
 def test_g_a_separate_draft_and_a_mesh_are_refused_by_name():
-    from inference_gateway_tpu.serving.engine import choose_mesh, has_mtp
+    from inference_gateway_tpu.models.family import family_of
+    from inference_gateway_tpu.serving.engine import choose_mesh
 
     big = mixtral.PRESETS["openpangu-ultra-moe-718b-stage7"]
-    assert has_mtp(CFG) and has_mtp(big) and not has_mtp(mixtral.PRESETS["xing4-test-tiny"])
+    assert [family_of(c).round_tokens for c in (CFG, big, mixtral.PRESETS["xing4-test-tiny"])] == [2, 2, 1]
     for draft in ("test-tiny", "ngram"):
         with pytest.raises(ValueError, match="speculative decoding.*drafts for itself"):
             Engine(EngineConfig(model=PRESET, attention="paged", spec_draft=draft, use_mesh=False))
@@ -505,9 +506,9 @@ def test_status_and_records_say_what_the_module_did(eng32):
     assert h.stats["mtp_drafted"] == 0
     h = rows.chunk((3,), 3)
     assert h.stats["mtp_drafted"] == 3 and h.stats["mtp_tokens"] == 3 + h.stats["mtp_accepted"] == int(h.counts[:, 3].sum())
-    status = eng32.mtp_status()
+    status = eng32.family_status()
     assert status["mtp"]["layers"] == 1 and status["vocab"] == {"held": 256, "published": 512}
-    assert eng32.latent_status()["layers"] == 4 and eng32.mixture_status()["router"]["experts_held"] == 4
+    assert eng32.family_status()["latent_pool"]["layers"] == 4 and eng32.family_status()["router"]["experts_held"] == 4
 
 
 @pytest.mark.parametrize("pair", ["mixed", "only"])

@@ -25,10 +25,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from inference_gateway_tpu.models import family as family_mod
 from inference_gateway_tpu.models import phi4flash
 from inference_gateway_tpu.ops import ssm
 from inference_gateway_tpu.ops.quant import QTensor
-from inference_gateway_tpu.serving import engine as engine_mod
 from inference_gateway_tpu.serving.engine import Engine, EngineConfig, MixedRow
 from inference_gateway_tpu.serving.scheduler import GenRequest, Scheduler
 
@@ -138,7 +138,7 @@ def decode(eng, rows, chunks, n_steps=None, between=None):
 
 def test_presets_follow_the_source_and_the_engine_knows_them_by_name():
     cfg = phi4flash.PRESETS["phi-4-mini-flash"]
-    assert engine_mod.PHI4FLASH_PRESETS == tuple(phi4flash.PRESETS)
+    assert family_mod.PHI4FLASH_PRESETS == tuple(phi4flash.PRESETS)
     assert (cfg.hidden_size, cfg.num_layers, cfg.num_heads, cfg.num_kv_heads, cfg.hd) == (2560, 32, 40, 20, 64)
     assert (cfg.intermediate_size, cfg.vocab_size, cfg.sliding_window) == (10240, 200064, 512)
     assert (cfg.d_inner, cfg.mamba_d_state, cfg.mamba_d_conv, cfg.mamba_dt_rank) == (5120, 16, 4, 160)
@@ -180,7 +180,7 @@ def test_prefill_chunked_prefill_and_decode_match_the_reference(dtype, quantize)
     decode eight tokens through fresh and chained chunks: positions 40-47 and
     70-77 lie three and more windows of 16 deep, in pages of 8."""
     eng = engine(dtype, quantize)
-    assert eng.mixed_ok and eng.is_hybrid and eng.mesh_axes == {"tp": 1}
+    assert eng.mixed_ok and eng._model is phi4flash and eng.mesh_axes == {"tp": 1}
     a, b = prompt(40, 1), prompt(70, 2)
     ta, la = admit(eng, 0, a, chunk=64)
     tb, lb = admit(eng, 1, b, chunk=32)
@@ -305,7 +305,7 @@ def test_identical_greedy_requests_give_identical_tokens_and_the_prefix_hit_is_d
     finally:
         s.stop()
     assert first[0] == again[0] and first[1] == again[1] and first[2] == "length"
-    status = eng.state_status()
+    status = eng.family_status()
     assert status["prefix_hits_declined"] == 1 and status["state_resets"] == 2
     assert eng.prefix_cache.stats()["hits"] == 0
     assert eng.allocator.free_page_count() == eng.allocator.num_pages  # a declining cache holds no page
@@ -482,7 +482,7 @@ def test_status_and_the_hbm_plan_count_what_is_held():
     big = phi4flash.PRESETS["phi-4-mini-flash"]
     assert kv_bytes_per_token(big) == 46_080  # 9 caching layers, not 32
     eng = engine("bfloat16", "int8")
-    assert eng.state_status() == {
+    assert eng.family_status() == {
         "state_store": {"slots": 4, "layers": 3, "bytes": 4 * CFG.state_bytes_per_slot},
         "cache_layers": {"window": 2, "full": 1, "shared_readers": 1},
         "state_resets": 0, "prefix_hits_declined": 0}

@@ -212,7 +212,7 @@ def benchmark_limits():
 @pytest.mark.parametrize("dtype,quantize", CASES)
 def test_chunked_prompt_then_decode_past_the_window_matches_the_reference(dtype, quantize):
     eng = engine(dtype, quantize, max_seq_len=512)
-    assert eng.mixed_ok and eng.counts_window and eng.mesh is None
+    assert eng.mixed_ok and "context_pages" in eng.family.mixed_stats and eng.mesh is None
     diffs = np.concatenate([gaps(eng, seq, start, served) for seq, start, served in serve_two_rows(eng)])
     assert len(diffs) == 25   # row 0: 1 + 7 beside the chunks + 8; row 1: 1 + 8
     if dtype == "float32":
@@ -320,7 +320,7 @@ def test_the_counters_are_the_counts_by_hand():
     assert stats[0]["context_pages"] == 6 * sum(held)
     assert stats[0]["window_pages_read"] == 6 * sum(h - (n - 16) // 8 for h, n in zip(held, range(71, 75)))
     assert stats[0]["experts_read"] == 2.0                           # one row, top-2
-    status = eng.mixture_status()
+    status = eng.family_status()
     assert status["cache_layers"] == {"window": 6, "full": 2}
     assert (status["router"]["score"], status["shared_experts"], status["dense_prefix"]) == ("sigmoid", 1, 2)
     assert status["router"]["chosen_by"] == "score + bias" and status["router"]["route_scale"] == 1.414
@@ -331,12 +331,12 @@ def test_other_mixtures_status_and_counters():
     too; Mixtral (no window) does not, and neither has a prefix or a shared expert."""
     small = Engine(EngineConfig(model="smallthinker-test-tiny", attention="paged", dtype="float32", page_size=8,
                                 max_slots=2, max_seq_len=64, mixed_step=True, use_mesh=False))
-    assert small.counts_window and small.mixture_status()["cache_layers"] == {"window": 3, "full": 1}
-    assert small.mixture_status()["router"]["score"] == "softmax"
+    assert "context_pages" in small.family.mixed_stats and small.family_status()["cache_layers"] == {"window": 3, "full": 1}
+    assert small.family_status()["router"]["score"] == "softmax"
     plain = Engine(EngineConfig(model="mixtral-test-tiny", attention="paged", dtype="float32", page_size=8,
                                 max_slots=2, max_seq_len=64, mixed_step=True, use_mesh=False))
-    assert not plain.counts_window and "cache_layers" not in plain.mixture_status()
-    assert (plain.mixture_status()["shared_experts"], plain.mixture_status()["dense_prefix"]) == (0, 0)
+    assert "context_pages" not in plain.family.mixed_stats and "cache_layers" not in plain.family_status()
+    assert (plain.family_status()["shared_experts"], plain.family_status()["dense_prefix"]) == (0, 0)
 
 
 def test_a_prefix_hit_adopts_pages_of_both_stacks():

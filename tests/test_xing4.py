@@ -189,11 +189,11 @@ def test_the_pool_is_one_array_of_latent_rows():
     whole = mixtral.PRESETS["xing4.0-stage10"]
     # 576 values a token a layer, stored and moved in 640 lanes: both are said.
     assert kv_bytes_per_token(whole) == 10 * 640 * 2 and kv_bytes_per_token(CFG) == 4 * 128 * 2
-    status = eng.latent_status()
+    status = eng.family_status()["latent_pool"]
     assert (status["row_values"], status["row_lanes"], status["arrays"], status["residual_streams"]) == (40, 128, ["kv"], 4)
     assert status["bytes_per_token"] == 4 * 128 * 4     # this engine's pool is float32
     assert eng.ragged_attention_plan["one_query_rows"] == "mla_decode_attn_kernel"
-    assert eng.mixed_ok and eng.is_mla and eng.mesh is None and eng.counts_window
+    assert eng.mixed_ok and eng.is_mla and eng.mesh is None and "context_pages" in eng.family.mixed_stats
 
 
 def test_a_whole_prompt_in_one_step_matches_the_reference():
@@ -328,7 +328,7 @@ def test_the_counters_are_the_counts_by_hand():
     assert (stats[0]["context_pages"], stats[0]["latent_pages_read"], stats[0]["latent_row_pages_read"]) == (
         4 * held, 4 * held, 4 * held)
     assert stats[0]["experts_read"] == 2.0
-    status = eng.mixture_status()
+    status = eng.family_status()
     assert (status["router"]["score"], status["shared_experts"], status["dense_prefix"]) == ("sigmoid", 1, 2)
     # Tiles of the published plan: a 512-token chunk at 12k of context walks 16 tiles.
     whole = mixtral.PRESETS["xing4.0-stage10"]
