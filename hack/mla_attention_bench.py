@@ -2,6 +2,7 @@
 sizes of ``xing4.0-stage10`` (32 heads, rows of 576 in 640 lanes, pages of 128):
 
     chiprun -- python3 hack/mla_attention_bench.py [--context 12288] [--chunk 512] [--rehearse]
+    chiprun -- python3 hack/mla_attention_bench.py --absorb [--rehearse]
 
 - a mixed step's CHUNK (``--chunk`` queries whose last sees ``--context`` keys),
   ABSORBED through ``mla_chunk_attn_kernel`` at several pages a visit, against
@@ -15,9 +16,20 @@ sizes of ``xing4.0-stage10`` (32 heads, rows of 576 in 640 lanes, pages of 128):
   ``mla_decode_attn_kernel`` at several pages a visit, with the bytes of latent
   they must read over the time (the kernel's share of the HBM roofline).
 
+- ``--absorb`` (ISSUE 59), alone: both products of the absorbed form from an
+  int8 W_UKV, a block after a block over a stack of four layers' matrices as a
+  step's scan reads them, at openPangu's decode round (128 query rows, 128
+  heads) and Xing's mixed step (544 rows, 32 heads): the parent's form kept
+  HERE (W_UKV dequantised whole in bf16, cut into the heads' halves, two
+  einsums) against the program's (``mixtral._latent_absorb`` /
+  ``_latent_out``: the int8 converted inside each product, the scales on the
+  query and on the float32 result), us a block beside the least time of the
+  block's bytes (W_UKV once as int8, the queries in, the absorbed queries out,
+  the weighted latents in, the values out), and how far the two forms lie apart.
+
 Times are wall clock around ``block_until_ready`` over ``--repeats`` calls of a
-jitted function, the pool an ARGUMENT. ``--rehearse`` walks it on the CPU at a
-toy size (interpreted kernels; never a number).
+jitted function, the pool and the matrices ARGUMENTS. ``--rehearse`` walks it on
+the CPU at a toy size (interpreted kernels; never a number).
 """
 
 from __future__ import annotations
@@ -31,8 +43,64 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 
+def absorb_bench(rehearse: bool, repeats: int) -> dict:
+    """``--absorb``: {case: {parent_us_a_block, stored_us_a_block, least_us_a_block, ...}}."""
+    import jax
+    import jax.numpy as jnp
+
+    from inference_gateway_tpu.models import mixtral
+    from inference_gateway_tpu.ops.quant import QTensor
+
+    cases = ({"toy (12 rows)": ("xing4-test-tiny", 12)} if rehearse else
+             {"openpangu decode round (128 rows, 128 heads)": ("openpangu-ultra-moe-718b-stage7", 128),
+              "xing4 mixed step (544 rows, 32 heads)": ("xing4.0-stage10", 544)})
+    layers, dtype, result = 4, jnp.bfloat16, {}
+    for case, (preset, rows) in cases.items():
+        cfg = mixtral.PRESETS[preset]
+        H, Dn, Dv, rank = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+        assert Dn == Dv   # a block's values are the next block's queries here, so nothing is hoisted out of the scan
+        key = jax.random.split(jax.random.PRNGKey(0), 3)
+        unit = jnp.tile(jnp.concatenate([jnp.full(Dn, Dn ** -0.5 / 73), jnp.full(Dv, rank ** -0.5 / 73)]), H)   # a block keeps the rows' size
+        stack = QTensor(jax.random.randint(key[0], (layers, rank, H * (Dn + Dv)), -127, 128, jnp.int8),
+                        jax.random.uniform(key[1], (layers, 1, H * (Dn + Dv)), jnp.float32, 0.5, 1.5) * unit)
+        q_nope = jax.random.normal(key[2], (rows, H, Dn), jnp.float32).astype(dtype)
+
+        def parent(q, w):   # the parent's form, kept as the definition
+            w = (w.q.astype(dtype) * w.scale.astype(dtype)).reshape(rank, H, Dn + Dv)
+            q_lat = jnp.einsum("nhd,chd->nhc", q, w[..., :Dn], preferred_element_type=jnp.float32).astype(dtype)
+            return q_lat, lambda attn: jnp.einsum("nhc,chd->nhd", attn, w[..., Dn:], preferred_element_type=jnp.float32).astype(dtype)
+
+        def stored(q, w):
+            q_lat, w_uv = mixtral._latent_absorb(q, w, cfg)
+            return q_lat, lambda attn: mixtral._latent_out(attn, w_uv, dtype).reshape(rows, H, Dv)
+
+        def blocks(form):
+            def block(q, w):   # the read between the products stands as a barrier: the absorbed queries are written
+                q_lat, up = form(q, w)
+                return up(jax.lax.optimization_barrier(q_lat)), None
+            return jax.jit(lambda q, stack: jax.lax.scan(block, q, stack)[0])
+
+        def timed(fn):
+            out = jax.block_until_ready(fn(q_nope, stack))
+            t0 = time.perf_counter()
+            for _ in range(repeats):
+                last = fn(q_nope, stack)
+            jax.block_until_ready(last)
+            return out.astype(jnp.float32), (time.perf_counter() - t0) / repeats / layers * 1e6
+
+        (want, parent_us), (got, stored_us) = timed(blocks(parent)), timed(blocks(stored))
+        moved = rank * H * (Dn + Dv) + 2 * rows * H * (Dn + 2 * rank + Dv)
+        result[case] = {"parent_us_a_block": round(parent_us, 1), "stored_us_a_block": round(stored_us, 1),
+                        "block_bytes": moved, "wukv_bytes": rank * H * (Dn + Dv),
+                        "forms_max_abs_diff": float(jnp.max(jnp.abs(got - want))), "output_rms": float(jnp.sqrt(jnp.mean(want ** 2)))}
+        if not rehearse:
+            result[case]["least_us_a_block"] = round(moved / 819e9 * 1e6, 1)
+    return result
+
+
 def main() -> int:
     cli = argparse.ArgumentParser()
+    cli.add_argument("--absorb", action="store_true")
     cli.add_argument("--context", type=int, default=12288)
     cli.add_argument("--chunk", type=int, default=512)
     cli.add_argument("--repeats", type=int, default=10)
@@ -45,6 +113,10 @@ def main() -> int:
     import jax
     import jax.numpy as jnp
     import numpy as np
+
+    if args.absorb:
+        print(json.dumps({"device": str(jax.devices()[0].device_kind), "absorb": absorb_bench(args.rehearse, 1 if args.rehearse else args.repeats)}))
+        return 0
 
     from inference_gateway_tpu.ops import mla_attention as mla
     from inference_gateway_tpu.ops.paged_attention import ragged_paged_attention_tpu

@@ -61,7 +61,7 @@ from inference_gateway_tpu.ops import kda as kda_ops
 from inference_gateway_tpu.ops.attention import causal_prefill_mask, decode_mask, gqa_attend
 from inference_gateway_tpu.ops.moe import ACTIVATIONS, moe_dense, moe_dropless, router_sigmoid, router_topk
 from inference_gateway_tpu.ops.norms import rms_norm
-from inference_gateway_tpu.ops.quant import qeinsum, qmatmul, seeded_qk_gain, seeded_std
+from inference_gateway_tpu.ops.quant import QTensor, qeinsum, qmatmul, seeded_qk_gain, seeded_std
 from inference_gateway_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq, yarn_mscale
 from inference_gateway_tpu.ops.sparse_attention import one_query_tokens_read, sparse_decode, sparse_ragged
 from inference_gateway_tpu.ops.ssd import block_plan, recurrence, ssd_chunk, state_update, update_store
@@ -1016,15 +1016,35 @@ def _dsa_ragged(h, q, lp, li, positions, pools, w_idx, table, q_starts, q_lens, 
 MLA_POOL = "kv"  # the latent attention's one pool: (L, P, page_size, latent_row)
 
 
+def _latent_absorb(q_nope, w, cfg: MixtralConfig):
+    """The heads' q_nope (N, heads, nope) carried into the latent's space by
+    their blocks of W_UK, (N, heads, rank), beside W_UV (rank, heads, v) for
+    ``_latent_out``. W_UKV ``w`` (rank, heads * (nope + v)) is multiplied AS IT
+    IS STORED (ISSUE 59): a ``QTensor``'s int8 is converted inside each
+    product's own fusion and no array of the matrix's size is written (the
+    parent wrote it in the activations' dtype, then laid it out by head and
+    scaled it, every block of every step). A column's scale lies on the
+    CONTRACTED axis of W_UK's product, so the query carries it (float32, cast
+    once); on W_UV's it lies on the result's, so W_UV goes on as a ``QTensor``
+    of its own and ``qeinsum`` scales the float32 product, as for every other
+    matrix. A plain leaf takes the same two einsums on the array as it is."""
+    Hq, Dn, Dv, dtype = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim, q_nope.dtype
+    scale = w.scale.reshape(Hq, Dn + Dv) if hasattr(w, "scale") else None
+    if scale is not None:
+        q_nope, w = (q_nope.astype(jnp.float32) * scale[:, :Dn]).astype(dtype), w.q
+    w = w.reshape(cfg.kv_lora_rank, Hq, Dn + Dv)
+    q_lat = jnp.einsum("nhd,chd->nhc", q_nope, w[..., :Dn].astype(dtype), preferred_element_type=jnp.float32)
+    return q_lat.astype(dtype), w[..., Dn:] if scale is None else QTensor(w[..., Dn:], scale[:, Dn:])
+
+
 def _latent_project(h, lp: Params, positions, cfg: MixtralConfig):
     """The latent attention's projections of N tokens' normed input h (N, H):
     the ABSORBED queries (N, heads, latent_width), a head's q_nope carried
     into the latent's space by its block of W_UK beside its rotary part, and
     the tokens' pool rows (N, latent_row): [the normed latent, the rotary key,
-    zeros]. W_UKV is dequantised whole (8 MB at the published sizes) and cut
-    into the heads' [k_nope, v] blocks; returns also W_UV (rank, heads, v)."""
+    zeros]; returns also W_UV as ``_latent_absorb`` hands it on."""
     N = h.shape[0]
-    Hq, Dn, Dr, Dv, rank = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank
+    Hq, Dn, Dr, rank = cfg.num_heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.kv_lora_rank
     if cfg.layer_rope is None:
         cos, sin = rope_cos_sin(positions, rope_inv_freq(Dr, cfg.rope_theta, cfg.rope_scaling_dict))
         rotate = lambda a: apply_rope(a, cos, sin)
@@ -1042,18 +1062,16 @@ def _latent_project(h, lp: Params, positions, cfg: MixtralConfig):
         k_rope = rotate(down[:, None, rank:])[:, 0]
         row = jnp.concatenate([latent, k_rope, jnp.zeros((N, cfg.latent_row - cfg.latent_width), h.dtype)], axis=-1)
     with jax.named_scope("mla.absorb"):
-        w = lp["wukv"]
-        w = (w.q.astype(h.dtype) * w.scale.astype(h.dtype)) if hasattr(w, "scale") else w
-        w = w.reshape(rank, Hq, Dn + Dv)
-        q_lat = jnp.einsum("nhd,chd->nhc", q[..., :Dn], w[..., :Dn], preferred_element_type=jnp.float32)
-        q_abs = jnp.concatenate([q_lat.astype(h.dtype), q_rope], axis=-1)
-    return q_abs, row, w[..., Dn:]
+        q_lat, w_uv = _latent_absorb(q[..., :Dn], lp["wukv"], cfg)
+        q_abs = jnp.concatenate([q_lat, q_rope], axis=-1)
+    return q_abs, row, w_uv
 
 
 def _latent_out(attn, w_uv, dtype):
-    """The heads' weighted latents (N, heads, rank) up through W_UV: (N, heads * v)."""
+    """The heads' weighted latents (N, heads, rank) up through W_UV (rank, heads, v),
+    a plain array or the stored int8 under its columns' scales (heads, v): (N, heads * v)."""
     with jax.named_scope("mla.absorb"):
-        out = jnp.einsum("nhc,chd->nhd", attn, w_uv, preferred_element_type=jnp.float32)
+        out = qeinsum("nhc,chd->nhd", attn, w_uv)
         return out.reshape(out.shape[0], -1).astype(dtype)
 
 

@@ -25,6 +25,7 @@ from inference_gateway_tpu.ops import kda, ssd
 from inference_gateway_tpu.ops.quant import QTensor
 from inference_gateway_tpu.serving.engine import Engine, EngineConfig, MixedRow
 from tests.test_lfm2 import admit, decode   # the same drivers: rows through mixed steps, then decode chunks
+from tests.test_xing4 import ABSORB_CHECKS, absorbed_from_an_int8_wukv
 
 BENCH = Path(__file__).resolve().parent.parent / "bench"
 PRESET = "kimi-linear-test-tiny"
@@ -423,6 +424,12 @@ def test_a_slot_admitted_again_a_row_recomputed_and_a_probe_sent_twice(eng32):
     assert gaps(eng, second[:33] + [tok], 33, [lp]).max() < 5e-5
     assert eng.metrics["state_resets"] - resets == 4 == eng.family_status()["state_resets"] - resets
     assert eng.prefix_cache is None or eng.prefix_cache.decline_hits
+
+
+@pytest.mark.parametrize("check", ABSORB_CHECKS)
+def test_absorbed_from_an_int8_wukv_against_its_definitions(check):
+    """ISSUE 59 on this preset's latent layers (no query bottleneck, no rotary): tests/test_xing4.py has the cases."""
+    absorbed_from_an_int8_wukv(PRESET, check, lambda h, w, pos: REF.mla(h, w, REF.sizes(MODEL)))
 
 
 def test_the_mixed_step_matches_the_decode_program(eng32):

@@ -24,6 +24,7 @@ from inference_gateway_tpu.ops.quant import QTensor
 from inference_gateway_tpu.serving.engine import Engine, EngineConfig, MixedRow
 from inference_gateway_tpu.serving.scheduler import GenRequest, Scheduler
 from inference_gateway_tpu.serving.speculative import strip_dist
+from tests.test_xing4 import ABSORB_CHECKS, absorbed_from_an_int8_wukv
 
 BENCH = Path(__file__).resolve().parent.parent / "bench"
 PRESET = "openpangu-test-tiny"
@@ -419,6 +420,12 @@ def test_d_sampled_rounds_draw_from_the_models_own_distributions():
     # The 99.9th percentile of chi-square at ``dof`` (Wilson-Hilferty): a sound sampler passes 999 times in 1,000.
     bound = dof * (1 - 2 / (9 * dof) + 3.09 * (2 / (9 * dof)) ** 0.5) ** 3
     assert dof >= 5 and chi < bound < chi_wrong, (chi, dof, bound, chi_wrong)
+
+
+@pytest.mark.parametrize("check", ABSORB_CHECKS)
+def test_absorbed_from_an_int8_wukv_against_its_definitions(check):
+    """ISSUE 59 on this preset's blocks (a query sees its own key): tests/test_xing4.py has the cases."""
+    absorbed_from_an_int8_wukv(PRESET, check, lambda h, w, pos: REF.latent_attention(h, w, pos, REF.sizes(MODEL)))
 
 
 # ---------------------------------------------------------------------------

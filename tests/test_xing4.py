@@ -14,8 +14,10 @@ hyper-connection maps; YaRN by hand; the pool's bytes; the counters; the
 controls; the refusals.
 """
 
+import functools
 import importlib.util
 import math
+import re
 import sys
 from pathlib import Path
 
@@ -27,7 +29,7 @@ import pytest
 from inference_gateway_tpu.models import llama, mixtral
 from inference_gateway_tpu.ops import hyper_connections as hc
 from inference_gateway_tpu.ops import mla_attention
-from inference_gateway_tpu.ops.quant import QTensor
+from inference_gateway_tpu.ops.quant import QTensor, quantize_tensor
 from inference_gateway_tpu.ops.rope import rope_inv_freq, yarn_mscale
 from inference_gateway_tpu.serving.engine import Engine, EngineConfig, MixedRow
 
@@ -269,6 +271,75 @@ def test_absorbed_against_materialised_attention():
     with jax.default_matmul_precision("highest"):
         materialised = REF.latent_attention(h, w, pos, REF.sizes(MODEL))
     assert float(jnp.max(jnp.abs(absorbed - materialised))) < 2e-5
+
+
+LATENT_LEAVES = ("wq", "wdq", "q_a_norm", "wuq", "wdkv", "kv_a_norm", "wukv", "wo")
+ABSORB_CHECKS = ("definition_float32", "definition_bfloat16", "materialised", "lowering")
+
+
+@functools.cache
+def int8_wukv_layer(preset):
+    """One latent layer's float32 leaves of a seeded tree of ``preset``, W_UKV
+    alone rounded to int8 under its columns' scales."""
+    layers = mixtral.init_params(jax.random.PRNGKey(3), mixtral.PRESETS[preset], jnp.float32)["layers"]
+    lp = {n: layers[n][0] for n in LATENT_LEAVES if n in layers}
+    return {**lp, "wukv": quantize_tensor(lp["wukv"])}
+
+
+def absorbed_from_an_int8_wukv(preset, check, materialised):
+    """ISSUE 59, a case a check (``ABSORB_CHECKS``); tests/test_kimi_linear.py
+    and tests/test_openpangu.py run it on their presets. ``_latent_attention``
+    with a ``QTensor`` W_UKV (12 tokens of one row through the twin's read)
+    against (a) the parent's form kept HERE as the definition: W_UKV
+    dequantised whole in the activations' dtype, cut into the heads' blocks, two
+    einsums; the same mathematics, so float32 agrees to its own rounding and
+    bfloat16 to a few of its steps (the definition rounds the scale and the
+    scaled weight, the program rounds the scaled query); (b)
+    ``materialised(h, w, positions)``, the file's float32 reference with k_nope
+    and v made a head from the dequantised leaves, at the plain leaf's limit.
+    And the lowering: no float value of W_UKV's size among the program's, where
+    the definition's jaxpr holds the dequantised copy."""
+    cfg, lp = mixtral.PRESETS[preset], int8_wukv_layer(preset)
+    Hq, Dn, Dv, rank, page = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim, cfg.kv_lora_rank, 8
+    N, pos = 12, jnp.arange(12, dtype=jnp.int32)
+    one = lambda v: jnp.asarray([v], jnp.int32)
+    read = lambda q, pages, **kw: mla_attention.mla_ragged_attention(
+        q, pages, jnp.asarray([[0, 1, 2]], jnp.int32), one(0), one(N), one(N), **kw)
+
+    def program(h, lp):
+        pool = jnp.zeros((3 * page, cfg.latent_row), h.dtype)
+        return mixtral._latent_attention(h, lp, pos, pool, pos, page, cfg, read)[0]
+
+    def definition(h, lp):
+        dense = lp["wukv"].q.astype(h.dtype) * lp["wukv"].scale.astype(h.dtype)   # the whole matrix, as the parent wrote it
+        q, row, plain = mixtral._latent_project(h, {**lp, "wukv": dense}, pos, cfg)   # a plain leaf: the einsum on the array as it is
+        assert not isinstance(plain, QTensor)
+        pool = jnp.zeros((3 * page, cfg.latent_row), h.dtype).at[pos].set(row)
+        attn = read(q, pool.reshape(-1, page, cfg.latent_row), value_dim=rank, scale=mixtral.mla_scale(cfg))
+        out = jnp.einsum("nhc,chd->nhd", attn, dense.reshape(rank, Hq, Dn + Dv)[..., Dn:], preferred_element_type=jnp.float32)
+        return out.reshape(N, -1).astype(h.dtype)
+
+    h = jnp.asarray(np.random.default_rng(2).normal(size=(N, cfg.hidden_size)), jnp.float32)
+    if check == "materialised":
+        with jax.default_matmul_precision("highest"):
+            want = materialised(h, {n: f32(a) for n, a in lp.items()}, pos)
+        assert float(jnp.max(jnp.abs(program(h, lp) @ lp["wo"] - want))) < 2e-5
+        return
+    dtype = jnp.float32 if check == "definition_float32" else jnp.bfloat16
+    h, lp = h.astype(dtype), {n: a if isinstance(a, QTensor) else a.astype(dtype) for n, a in lp.items() if n != "wo"}
+    if check == "lowering":
+        sized = lambda fn: [(d, shape) for d, shape in re.findall(r"\b(f32|bf16|f16)\[([\d,]+)\]", str(jax.make_jaxpr(fn)(h, lp)))
+                            if math.prod(map(int, shape.split(","))) == rank * Hq * (Dn + Dv)]
+        assert sized(definition) and not sized(program), sized(program)
+        return
+    got, want = (np.asarray(fn(h, lp), np.float32) for fn in (program, definition))
+    steps = 2.0 ** -23 if dtype == jnp.float32 else 2.0 ** -8
+    assert np.abs(want).max() > 0.1 and np.abs(got - want).max() < 8 * steps * np.abs(want).max(), np.abs(got - want).max()
+
+
+@pytest.mark.parametrize("check", ABSORB_CHECKS)
+def test_absorbed_from_an_int8_wukv_against_its_definitions(check):
+    absorbed_from_an_int8_wukv(PRESET, check, lambda h, w, pos: REF.latent_attention(h, w, pos, REF.sizes(MODEL)))
 
 
 def test_a_prompt_adopted_from_the_prefix_cache_answers_identically():
