@@ -130,6 +130,15 @@ _SERVING_RAGGED_DOC = [
     "ROUNDS. `/debug/status.mtp` and the `spec_*` counters say what the drafts",
     "came to ([docs/observability.md](docs/observability.md)).",
     "",
+    "An EVA preset (`evabyte-6.5b`, ISSUE 61) keeps its page pool under rows of its",
+    "own: a summary per 16 tokens of every window of 2,048 behind the current one,",
+    "then that window's tokens. `SERVING_PAGE_SIZE` has to be window / chunk (128;",
+    "another is refused by name), `SERVING_MAX_SEQ_LEN` 12,800 then costs a slot 21",
+    "pages where a row a token would cost 100, prompt chunks end at a window's end",
+    "whatever `SERVING_MIXED_STEP_TOKENS` says, and prefix hits are declined and",
+    "counted. No variable and no flag turns any of it on or off",
+    "([docs/observability.md](docs/observability.md), `eva`).",
+    "",
 ]
 
 

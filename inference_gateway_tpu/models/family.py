@@ -51,6 +51,15 @@ class Family:
     # The layers that read the page pool through the paged kernels, as (window or
     # None, layers): what ``ragged_walk_pages`` counts a mixed step's pages over.
     attention_layers: tuple = ()
+    # A pool whose rows are not one a token (ISSUE 61): ``cached_rows(n)`` is the rows
+    # a context of n tokens holds (integer arithmetic: ints, numpy and traced arrays
+    # alike; the row of token t is ``cached_rows(t + 1) - 1``). It says of itself where
+    # it falls, at every multiple of ``cached_rows.window`` tokens, which no prompt chunk
+    # may straddle, and the one ``cached_rows.page_size`` its pool is laid out for
+    # (ops/eva_attention.FoldedRows). Pages of such rows are not pages of tokens: prefix
+    # hits are declined, and counted. None: a row a token at index ``position``, as
+    # every other family caches.
+    cached_rows: Callable[[Any], Any] | None = None
     status: Callable[[StatusView], dict] = lambda view: {}   # the family's /debug/status blocks
 
 

@@ -25,7 +25,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from inference_gateway_tpu.models.family import Family, attention_layers
+from inference_gateway_tpu.models.family import INT8_ALONE, Family, attention_layers
 from inference_gateway_tpu.ops.attention import causal_prefill_mask, decode_mask, gqa_attend
 from inference_gateway_tpu.ops.norms import rms_norm
 from inference_gateway_tpu.ops.quant import qmatmul
@@ -71,6 +71,16 @@ class LlamaConfig:
     attention_multiplier: float | None = None
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # EvaByte (ISSUE 61). ``eva_window``: EVA attention (ops/eva_attention.py), exact
+    # keys inside the query's own window of that many tokens and one learned summary a
+    # ``eva_chunk`` tokens of every window behind it (leaves eva_mu, eva_phi; the pool's
+    # rows are ``cached_rows``, not one a token). ``num_pred_heads``: the head is that many
+    # heads of ``vocab_size`` columns side by side; the first predicts the next token.
+    # (The model's ``fp32_skip_add``, a sublayer's output summed into the stream in float32
+    # and rounded once, is what adding two bf16 arrays gives, bit for bit: no field.)
+    eva_window: int | None = None
+    eva_chunk: int = 16
+    num_pred_heads: int = 1
     # Stored as a hashable tuple of (key, value) pairs so the config can be
     # a jit static argument; accepts a dict at construction.
     rope_scaling: Any = None
@@ -124,6 +134,8 @@ def _head(params: Params, cfg: "LlamaConfig", x):
         logits = (x @ params["embed"].T.astype(x.dtype)).astype(jnp.float32)
     else:
         logits = qmatmul(x, params["lm_head"]).astype(jnp.float32)
+        if cfg.num_pred_heads > 1:   # the heads lie side by side: the first is the next token's
+            logits = logits[..., :cfg.vocab_size]
     return logits if cfg.logits_scaling == 1.0 else logits / cfg.logits_scaling
 
 
@@ -359,31 +371,57 @@ def init_params(rng: jax.Array, cfg: LlamaConfig, dtype=jnp.bfloat16) -> Params:
     Hq, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.hd
     keys = jax.random.split(rng, 8)
 
-    def norm(key, shape):
-        return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
+    from inference_gateway_tpu.ops.quant import seeded_std
+
+    def norm(key, shape, leaf=None):
+        # 0.02; an EVA model's leaves as the seeded build sizes them (ops/quant._eva_std)
+        std = seeded_std(cfg, leaf) if cfg.eva_window and leaf else 0.02
+        return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+    # A norm's stored weight for an effective weight of one: 0 where the model adds one to it.
+    one = jnp.zeros if cfg.norm_offset else jnp.ones
 
     params: Params = {
-        "embed": norm(keys[0], (V, H)),
+        "embed": norm(keys[0], (V, H), "embed"),
         "layers": {
-            "attn_norm": jnp.ones((L, H), dtype),
-            "wq": norm(keys[1], (L, H, Hq * D)),
-            "wk": norm(keys[2], (L, H, Hkv * D)),
-            "wv": norm(keys[3], (L, H, Hkv * D)),
-            "wo": norm(keys[4], (L, Hq * D, H)),
-            "mlp_norm": jnp.ones((L, H), dtype),
-            "wg": norm(keys[5], (L, H, I)),
-            "wu": norm(keys[6], (L, H, I)),
-            "wd": norm(keys[7], (L, I, H)),
+            "attn_norm": one((L, H), dtype),
+            "wq": norm(keys[1], (L, H, Hq * D), "wq"),
+            "wk": norm(keys[2], (L, H, Hkv * D), "wk"),
+            "wv": norm(keys[3], (L, H, Hkv * D), "wv"),
+            "wo": norm(keys[4], (L, Hq * D, H), "wo"),
+            "mlp_norm": one((L, H), dtype),
+            "wg": norm(keys[5], (L, H, I), "wg"),
+            "wu": norm(keys[6], (L, H, I), "wu"),
+            "wd": norm(keys[7], (L, I, H), "wd"),
         },
-        "final_norm": jnp.ones((H,), dtype),
+        "final_norm": one((H,), dtype),
     }
     if cfg.qkv_bias:
         params["layers"]["bq"] = jnp.zeros((L, Hq * D), dtype)
         params["layers"]["bk"] = jnp.zeros((L, Hkv * D), dtype)
         params["layers"]["bv"] = jnp.zeros((L, Hkv * D), dtype)
+    if cfg.eva_window:
+        params["layers"].update(eva_vectors(jax.random.fold_in(rng, 61), cfg, dtype))
     if not cfg.tie_word_embeddings:
-        params["lm_head"] = norm(jax.random.fold_in(rng, 99), (H, V))
+        params["lm_head"] = norm(jax.random.fold_in(rng, 99), (H, V * cfg.num_pred_heads), "lm_head")
     return params
+
+
+def eva_vectors(rng: jax.Array, cfg: LlamaConfig, dtype) -> Params:
+    """EVA's two learned vectors a head and layer, as the model's initialiser draws
+    them: a unit normal cut at one. Not scaled down: the pooling weights of a chunk
+    must differ, or a mean would pass for the model."""
+    shape = (cfg.num_layers, cfg.num_kv_heads, cfg.hd)
+    mu, phi = (jnp.clip(jax.random.normal(k, shape, jnp.float32), -1.0, 1.0).astype(dtype)
+               for k in jax.random.split(rng))
+    return {"eva_mu": mu, "eva_phi": phi}
+
+
+def further_params(cfg: LlamaConfig) -> int:
+    """Parameters beside the dense stack's (``serving/profiles.llama_param_count``): the
+    head's further prediction heads and EVA's two vectors a head and layer."""
+    heads = 0 if cfg.tie_word_embeddings else (cfg.num_pred_heads - 1) * cfg.vocab_size * cfg.hidden_size
+    return heads + (cfg.num_layers * 2 * cfg.num_kv_heads * cfg.hd if cfg.eva_window else 0)
 
 
 def init_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=jnp.bfloat16) -> Params:
@@ -479,6 +517,10 @@ def forward(
              with cache rows. ``lengths`` = tokens valid after this
              chunk. Bounds prefill memory to O(chunk × cache).
     """
+    if cfg.eva_window:
+        raise NotImplementedError(
+            "EVA attention is served over the paged pool alone (a window is folded into a page of summaries); "
+            "the dense cache has no path for it")
     B, T = tokens.shape
     x = params["embed"][tokens] if embeds is None else embeds.astype(params["embed"].dtype)
     if cfg.embed_scale:
@@ -617,7 +659,28 @@ def _dense_ffn(x: jnp.ndarray, attn_in: jnp.ndarray, lp: Params, li, valid, cfg:
     return qmatmul(act(qmatmul(h, lp["wg"])) * qmatmul(h, lp["wu"]), lp["wd"])
 
 
-@partial(jax.jit, static_argnames=("cfg", "mode", "last_only", "mesh", "ring_mesh"))
+def _eva_fold(params: Params, cfg: LlamaConfig, cache: Params, page_table, first_pos, live, rows, contexts, queries):
+    """EVA's part of a step before its layers (ISSUE 61): the window behind every
+    row that starts one is folded into its page of summaries, in every layer's
+    plane, BEFORE the step writes (ops/eva_attention.fold). The layers then read
+    the pool as any dense model's: the lengths handed in are cached rows. Returns
+    (the cache, float32 (5,): ``EVA_STATS``). ``rows`` (R,) the rows a row of the step
+    holds after it, ``contexts`` its tokens, ``queries`` how many of them are new."""
+    from inference_gateway_tpu.ops.eva_attention import fold
+
+    assert cfg.num_heads == cfg.num_kv_heads, "EVA's vectors are a head's: no grouped heads"
+    with jax.named_scope("attention"):
+        cache, rolls = fold(cache, page_table, first_pos, live, params["layers"]["eva_mu"],
+                            params["layers"]["eva_phi"], window=cfg.eva_window, chunk=cfg.eva_chunk)
+    with jax.named_scope("carry"):
+        pairs = queries * rows - queries * (queries - 1) // 2   # a chunk's queries see one row more each
+        stats = jnp.stack([jnp.sum(jnp.where(live, rows, 0)), jnp.sum(jnp.where(live, contexts, 0)),
+                           rolls, jnp.sum(jnp.where(live, pairs, 0)),
+                           jnp.sum(jnp.where(live, queries, 0))]).astype(jnp.float32)
+    return cache, stats
+
+
+@partial(jax.jit, static_argnames=("cfg", "mode", "last_only", "mesh", "ring_mesh", "with_aux"))
 def forward_paged(
     params: Params,
     cfg: LlamaConfig,
@@ -631,16 +694,31 @@ def forward_paged(
     last_only: bool = True,
     mesh=None,  # tp mesh: decode runs the shard_mapped Pallas kernel
     ring_mesh=None,  # mesh with sp>1: fresh prefill attends via ring attention
+    valid: jnp.ndarray | None = None,  # (B,) decode rows that carry a stream: the counted call's contract (a done row's length is 0, which says the same)
+    with_aux: bool = False,  # a third result: what the model counts (``Family.chunk_stats``)
 ) -> tuple[jnp.ndarray, Params]:
     """Like ``forward`` but against the paged KV cache
     (serving/kv_cache.py). Decode attention runs the Pallas ragged
     paged-attention kernel (ops/paged_attention.py). ``prefill_chunk``
     attends causally over the slot's gathered pages — the prefix-cache
     path: shared prefix pages are already populated, only the tail is
-    computed here."""
-    return forward_paged_impl(params, cfg, tokens, positions, lengths, cache,
-                              write_idx, page_table, mode, last_only, mesh,
-                              partial(_dense_ffn, cfg=cfg), ring_mesh=ring_mesh)
+    computed here.
+
+    An EVA model (``cfg.eva_window``): ``lengths`` and ``write_idx`` are in the
+    pool's rows (``cached_rows``), ``positions`` the tokens' own, for the rotation
+    and for the roll; ``mode="decode"`` alone (prompts go through the mixed step)."""
+    stats = None
+    if cfg.eva_window:
+        if mode != "decode":
+            raise NotImplementedError(
+                f"EVA attention admits prompts through the mixed step alone; forward_paged mode {mode!r} has no path for it")
+        with jax.named_scope("carry"):
+            rows = (positions[:, 0], lengths > 0, lengths, positions[:, 0] + 1, jnp.ones_like(lengths))
+        cache, stats = _eva_fold(params, cfg, cache, page_table, *rows)
+    out = forward_paged_impl(params, cfg, tokens, positions, lengths, cache,
+                             write_idx, page_table, mode, last_only, mesh,
+                             partial(_dense_ffn, cfg=cfg), ring_mesh=ring_mesh)
+    return (*out, stats) if with_aux else out
 
 
 def _scan_layers(params: Params, unscanned: tuple) -> Params:
@@ -924,7 +1002,7 @@ def forward_paged_impl(
 # one program over the paged cache.
 # ---------------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("cfg", "mesh"))
+@partial(jax.jit, static_argnames=("cfg", "mesh", "with_aux"))
 def forward_ragged(
     params: Params,
     cfg: LlamaConfig,
@@ -937,6 +1015,7 @@ def forward_ragged(
     q_lens: jnp.ndarray,  # (R,) query count (0 = inactive row)
     kv_lens: jnp.ndarray,  # (R,) total kv length after this step
     mesh=None,
+    with_aux: bool = False,  # a third result: what the model counts (``Family.mixed_stats``)
 ) -> tuple[jnp.ndarray, Params]:
     """One MIXED engine step over the paged cache: the packed token axis
     carries every row's new tokens (a decode row contributes its pending
@@ -949,10 +1028,23 @@ def forward_ragged(
     This replaces the bucketed ``_prefill_fn`` family for
     paged serving: one compiled program at one static packed width
     instead of one program per prompt bucket, and no bucket padding —
-    only the packed tail beyond the live tokens is dead work."""
-    return forward_ragged_impl(params, cfg, tokens, positions, cache, write_idx,
-                               page_table, q_starts, q_lens, kv_lens, mesh,
-                               partial(_dense_ffn, cfg=cfg))
+    only the packed tail beyond the live tokens is dead work.
+
+    An EVA model (``cfg.eva_window``): ``kv_lens`` and ``write_idx`` are in the
+    pool's rows, and no row's chunk straddles a window's end (the scheduler cuts
+    it there), so a chunk's rows lie back to back as its tokens do."""
+    stats = None
+    if cfg.eva_window:
+        with jax.named_scope("carry"):
+            T = tokens.shape[1]
+            first = positions[0, jnp.clip(q_starts, 0, T - 1)]
+            last = positions[0, jnp.clip(q_starts + q_lens - 1, 0, T - 1)]
+            rows = (first, q_lens > 0, kv_lens, last + 1, q_lens)
+        cache, stats = _eva_fold(params, cfg, cache, page_table, *rows)
+    out = forward_ragged_impl(params, cfg, tokens, positions, cache, write_idx,
+                              page_table, q_starts, q_lens, kv_lens, mesh,
+                              partial(_dense_ffn, cfg=cfg))
+    return (*out, stats) if with_aux else out
 
 
 def forward_ragged_impl(
@@ -1165,6 +1257,19 @@ PRESETS: dict[str, LlamaConfig] = {
         vocab_size=151936, hidden_size=896, num_layers=24, num_heads=14, num_kv_heads=2,
         intermediate_size=4864, rope_theta=1000000.0, max_position_embeddings=32768,
         qkv_bias=True, tie_word_embeddings=True,
+    ),
+    # EvaByte-6.5B (ISSUE 61; https://huggingface.co/EvaByte/EvaByte): a byte-level model,
+    # 256 bytes + 64 special ids, EVA attention in every layer, no grouped heads. Of its 8
+    # prediction heads the first alone is held (multi-byte self-speculation waits).
+    "evabyte-6.5b": LlamaConfig(
+        vocab_size=320, hidden_size=4096, num_layers=32, num_heads=32, num_kv_heads=32,
+        intermediate_size=11008, rope_theta=100000.0, max_position_embeddings=32768,
+        norm_offset=True, eva_window=2048, eva_chunk=16,
+    ),
+    "evabyte-test-tiny": LlamaConfig(
+        vocab_size=320, hidden_size=64, num_layers=2, num_heads=4, num_kv_heads=4,
+        intermediate_size=128, rope_theta=100000.0, max_position_embeddings=512,
+        norm_offset=True, eva_window=32, eva_chunk=4, num_pred_heads=2,
     ),
     "llama-3-70b": LlamaConfig(
         vocab_size=128256, hidden_size=8192, num_layers=80, num_heads=64, num_kv_heads=8,
@@ -1448,8 +1553,42 @@ def windowed_layers(cfg: LlamaConfig) -> int:
     return sum(cfg.layer_window) if cfg.layer_window else cfg.num_layers
 
 
+# What an EVA model's step programs count beside their tokens (ISSUE 61): the rows of
+# the pool the step's rows read (summaries and exact rows; summed over rows), those
+# rows' true contexts in tokens, the windows folded, the (query, row) pairs the
+# step's attention scored (a chunk's queries see one row more each), and its queries.
+EVA_STATS = ("eva_rows", "eva_context", "eva_rolls", "eva_pairs", "eva_queries")
+
+
+def _eva_status(cfg: LlamaConfig, view) -> dict:
+    """/debug/status ``eva``: the attention's two sizes and, for the longest slot the
+    engine admits, the rows and pages it holds against a row a token."""
+    out = {"window": cfg.eva_window, "chunk": cfg.eva_chunk, "prefix_hits_declined": view.prefix_hits_declined}
+    if view.page_cfg is not None:
+        n, page = view.page_cfg.max_seq_len, view.page_cfg.page_size
+        out.update(longest_slot={"tokens": n, "cached_rows": int(view.page_cfg.rows(n)),
+                                 "pages_held_at_most": view.page_cfg.max_pages_per_slot,
+                                 "pages_full_attention": -(-n // page)})
+    return {"eva": out}
+
+
 def family(cfg: LlamaConfig) -> Family:
     """What serving ``cfg`` takes: every layer's k and v in the pool, nothing
-    beside it, nothing counted, nothing refused."""
-    return Family(module=sys.modules[__name__],
-                  attention_layers=attention_layers(cfg.num_layers, cfg.sliding_window, windowed_layers(cfg)))
+    beside it, nothing counted, nothing refused. An EVA model (ISSUE 61) keeps
+    the same pool under rows of its own (``cached_rows``): it is admitted through
+    the mixed step alone, counts what its rows read, and refuses by name what
+    would read the pool a row a token."""
+    layers = attention_layers(cfg.num_layers, cfg.sliding_window, windowed_layers(cfg))
+    if not cfg.eva_window:
+        return Family(module=sys.modules[__name__], attention_layers=layers)
+    from inference_gateway_tpu.ops.eva_attention import FoldedRows
+
+    why = "reads the pool a row a token; EVA folds a window into a page of summaries"
+    return Family(
+        module=sys.modules[__name__], mixed_alone=True, attention_layers=layers,
+        refuses={"spec_draft": f"speculative decoding {why}; serve it without --spec-draft",
+                 "vision_model": f"multimodal prefill {why}", "int4": INT8_ALONE,
+                 "mesh": "no mesh ({shape}) carries the fold yet; serve it on one device"},
+        chunk_stats=EVA_STATS, mixed_stats=EVA_STATS,
+        cached_rows=FoldedRows(cfg.eva_window, cfg.eva_chunk),
+        status=partial(_eva_status, cfg))

@@ -539,6 +539,14 @@ class Engine:
         for feature, wanted in asked.items():
             if wanted and feature in family.refuses:   # refused by name, before anything is built
                 raise ValueError(f"{config.model}: {family.refuses[feature]}")
+        rows = family.cached_rows
+        if config.attention == "paged" and rows is not None and rows.page_size != config.page_size:
+            raise ValueError(f"{config.model}: its pool is laid out for pages of {rows.page_size} rows, "
+                             f"not --page-size {config.page_size}")
+        # Rows of the pool by context (identity for every family but one whose rows are
+        # not one a token: ``Family.cached_rows``), for the host's lengths and indices
+        # and, traced, for those a decode chunk derives on the device.
+        self._rows = rows or (lambda n: n)
         # Presets have no tokenizer files: straight to the byte tokenizer,
         # never a hub look-up by preset name.
         self.tokenizer = load_tokenizer(config.tokenizer or (None if preset is not None else config.model))
@@ -568,7 +576,7 @@ class Engine:
 
             self.page_cfg = PagedCacheConfig(
                 page_size=config.page_size, num_pages=config.num_pages,
-                max_slots=config.max_slots, max_seq_len=config.max_seq_len,
+                max_slots=config.max_slots, max_seq_len=config.max_seq_len, rows=family.cached_rows,
             )
             self.allocator = PageAllocator(self.page_cfg)
             # The family's own pool (``family.pool``), and beside it whatever it keeps a slot.
@@ -584,8 +592,11 @@ class Engine:
                 from inference_gateway_tpu.serving.kv_cache import PrefixCache
 
                 # Pages can be handed over, a recurrent state cannot: with a
-                # state store every hit is declined, and counted.
-                self.prefix_cache = PrefixCache(self.allocator, decline_hits=self.carries_state, successor=self.mtp)
+                # state store every hit is declined, and counted; so is one on a
+                # pool whose pages of rows are not pages of tokens.
+                self.prefix_cache = PrefixCache(
+                    self.allocator, decline_hits=self.carries_state or family.cached_rows is not None,
+                    successor=self.mtp)
         else:
             cache = self._model.init_cache(self.model_cfg, config.max_slots, config.max_seq_len, dtype=self.dtype)
             if self.mesh is not None:
@@ -1132,10 +1143,11 @@ class Engine:
             page_table, reserved = write_args
             ps = self.config.page_size
             with jax.named_scope("carry"):
+                row = self._rows(pos_att + 1) - 1   # where the pool holds this position (itself, but for ``Family.cached_rows``)
                 page = jnp.take_along_axis(
-                    page_table, (pos_att // ps)[:, None], axis=1)[:, 0]
+                    page_table, (row // ps)[:, None], axis=1)[:, 0]
                 # int32 throughout: a flat paged cache index always fits.
-                w = page * ps + pos_att % ps
+                w = page * ps + row % ps
                 valid = (~done) & (pos < max_len) & (pos < reserved)
                 w = jnp.where(valid, w, self._flat_size)
                 counted = {"valid": ~done, "with_aux": True} if self._chunk_stats else {}
@@ -1143,7 +1155,7 @@ class Engine:
                 # slot of length 0 and writes its row as zeros (ISSUE 37), where
                 # a frozen row's stale context cost every step its pages (an
                 # indexer's masked walk passes over it the same way: ISSUE 45).
-                att_len = jnp.where(done, 0, pos_att + 1)
+                att_len = jnp.where(done, 0, row + 1)
             logits, cache, *rows = self._model.forward_paged(
                 params, self.model_cfg, tok[:, None], pos_att[:, None],
                 att_len, cache, w[:, None], page_table, mode="decode",
@@ -1363,7 +1375,10 @@ class Engine:
         with jax.named_scope("mask_bias"):
             logits = self._bias_logits(logits, mbias, None, mbits, mstates, automaton)
         with jax.named_scope("sampling"):
-            keys = per_row_keys(rng, seeds, use_seed, kv_lens)
+            lens = kv_lens
+            if self.family.cached_rows is not None:   # a seeded row's key is its context's, in tokens
+                lens = positions[0, jnp.clip(q_starts + q_lens - 1, 0, positions.shape[1] - 1)] + 1
+            keys = per_row_keys(rng, seeds, use_seed, lens)
             toks = sample_tokens(logits, rng, temps, top_ps, top_k=self.config.top_k, row_keys=keys)
             logprobs = compute_logprobs(logits, toks)
         if rows and not self.is_moe:
@@ -1730,7 +1745,7 @@ class Engine:
                     positions[0, off:off + n] = r.start + np.arange(n, dtype=np.int32)
                     q_starts[r.slot] = off
                     q_lens[r.slot] = n
-                    kv_lens[r.slot] = r.start + n
+                    kv_lens[r.slot] = self._rows(r.start + n)
                     temps[r.slot] = r.temp
                     top_ps[r.slot] = r.top_p
                     if r.seed is not None:
@@ -1808,9 +1823,17 @@ class Engine:
         timeline's ``attn_pages_walked`` / ``attn_pages_masked``."""
         from inference_gateway_tpu.ops.paged_attention import ragged_walk_pages
 
-        return ragged_walk_pages([(len(r.token_ids), r.start + len(r.token_ids)) for r in rows],
+        held = self.family.cached_rows or (lambda n: n)   # the rows a context holds: the walk is over them
+        return ragged_walk_pages([(len(r.token_ids), int(held(r.start + len(r.token_ids)))) for r in rows],
                                  self.family.attention_layers, self.ragged_attention_plan,
                                  self.config.page_size)
+
+    def prompt_chunk(self, start: int, want: int) -> int:
+        """How many of ``want`` prompt tokens from position ``start`` one step may take:
+        all of them, but for a family whose rows fall at every ``cached_rows.window``
+        tokens, where a chunk ends with the window (the next one starts it, and rolls)."""
+        rows = self.family.cached_rows
+        return want if rows is None else min(want, rows.window - start % rows.window)
 
     def _state_stats(self, rows: int) -> dict | None:
         """``state_bytes`` of a step's timeline record: what its rows hold in
@@ -1835,15 +1858,16 @@ class Engine:
         (ISSUE 12): chunks of the mixed-step budget attend the slot's
         pages causally — paged engines previously had NO long-prompt
         path at all (max_prompt_len capped at the largest bucket)."""
-        chunk = self.mixed_budget
         mask_state = grammar.global_state if grammar is not None else 0
         toks = logprobs = None
-        for start in range(0, len(prompt), chunk):
-            piece = prompt[start:start + chunk]
+        start = 0
+        while start < len(prompt):
+            piece = prompt[start:start + self.prompt_chunk(start, self.mixed_budget)]
             h = self.mixed_step_submit([MixedRow(
                 slot=slot, token_ids=list(piece), start=start, kind="prefill",
                 temp=temp, top_p=top_p, seed=seed, mask_state=mask_state)])
             toks, logprobs = self.mixed_step_fetch(h)
+            start += len(piece)
         with self._lock:
             self.metrics["prefill_batches"] += 1
             if self.prefix_cache is not None:

@@ -14,6 +14,7 @@ recompilation happens as requests come and go.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Any, Callable
 
 import numpy as np
 
@@ -40,15 +41,32 @@ class PagedCacheConfig:
     num_pages: int = 0  # 0 = full reservation: max_slots * max_seq_len / page_size
     max_slots: int = 8
     max_seq_len: int = 512
+    # Rows of the pool a context of n tokens holds, where that is not n
+    # (``models/family.Family.cached_rows``, ISSUE 61: it may FALL as n grows,
+    # so what a slot holds pages for is the most it has held on the way: ``peak_rows``).
+    rows: Callable[[Any], Any] | None = None
+
+    def __post_init__(self) -> None:
+        self._peak = None if self.rows is None else np.maximum.accumulate(
+            np.asarray(self.rows(np.arange(self.max_seq_len + 1)), np.int64))
+
+    def peak_rows(self, n_tokens: int) -> int:
+        """The most rows a slot has held by the time its context is ``n_tokens``
+        (past ``max_seq_len``: more than a slot's pages hold)."""
+        if self._peak is None:
+            return n_tokens
+        if n_tokens <= self.max_seq_len:
+            return int(self._peak[n_tokens])
+        return self.max_pages_per_slot * self.page_size + n_tokens - self.max_seq_len
 
     def resolve_num_pages(self) -> int:
         if self.num_pages:
             return self.num_pages
-        return self.max_slots * ((self.max_seq_len + self.page_size - 1) // self.page_size)
+        return self.max_slots * self.max_pages_per_slot
 
     @property
     def max_pages_per_slot(self) -> int:
-        return (self.max_seq_len + self.page_size - 1) // self.page_size
+        return (self.peak_rows(self.max_seq_len) + self.page_size - 1) // self.page_size
 
 
 class PageAllocator:
@@ -94,7 +112,7 @@ class PageAllocator:
     def ensure_capacity(self, slot: int, n_tokens: int) -> None:
         """Grow the slot's page list to cover n_tokens total tokens."""
         pages = self._slot_pages.setdefault(slot, [])
-        needed = (n_tokens + self.cfg.page_size - 1) // self.cfg.page_size
+        needed = (self.cfg.peak_rows(n_tokens) + self.cfg.page_size - 1) // self.cfg.page_size
         if needed > self.cfg.max_pages_per_slot:
             raise OutOfPagesError(
                 f"slot {slot} needs {needed} pages > per-slot max",
@@ -126,6 +144,9 @@ class PageAllocator:
         [start, start+count) of this slot."""
         ps = self.cfg.page_size
         pages = self._slot_pages.get(slot, [])
+        if self.cfg.rows is not None:   # a token's row is not its position (ISSUE 61)
+            t = np.asarray(self.cfg.rows(np.arange(start + 1, start + count + 1)), np.int64) - 1
+            return np.asarray(pages, np.int64)[t // ps] * ps + t % ps
         out = np.empty((count,), np.int64)
         for i in range(count):
             t = start + i

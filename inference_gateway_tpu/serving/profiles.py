@@ -23,6 +23,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 
 from inference_gateway_tpu.models import llama, mixtral
+from inference_gateway_tpu.models.family import family_of
 
 # v5e: 16 GiB HBM, ~819 GB/s, 197 bf16 TFLOP/s per chip.
 V5E_HBM_BYTES = 16 * 1024**3
@@ -83,7 +84,7 @@ def llama_param_count(cfg: llama.LlamaConfig) -> int:
     total = V * H + cfg.num_layers * per_layer + H  # embed + layers + final norm
     if not cfg.tie_word_embeddings:
         total += V * H  # lm_head
-    return total
+    return total + llama.further_params(cfg)
 
 
 def mixtral_param_count(cfg: mixtral.MixtralConfig) -> int:
@@ -140,7 +141,8 @@ def mixtral_param_count(cfg: mixtral.MixtralConfig) -> int:
 
 
 def kv_bytes_per_token(cfg, dtype_bytes: int = 2) -> int:
-    """Cached bytes for ONE token (k, v, and a sparse attention's indexer
+    """Cached bytes for ONE token (one ROW of the pool, where a family's rows are
+    not one a token: ``Family.cached_rows``) (k, v, and a sparse attention's indexer
     key) across all layers that write the
     cache (unsharded): every layer, unless the model says which
     (``cache_layers``: models/phi4flash.py has 9 for 32 layers)."""
@@ -213,6 +215,14 @@ def hbm_plan(profile: ServingProfile) -> dict:
     tokens = profile.num_pages * profile.page_size if profile.num_pages else (
         profile.max_slots * profile.max_seq_len
     )
+    rows = family_of(cfg).cached_rows
+    if rows is not None and not profile.num_pages:
+        # A pool whose rows are not one a token (ISSUE 61): a slot holds pages for the
+        # most rows a context up to max_seq_len ever holds (serving/kv_cache.py).
+        from inference_gateway_tpu.serving.kv_cache import PagedCacheConfig
+
+        tokens = profile.page_size * PagedCacheConfig(
+            profile.page_size, 0, profile.max_slots, profile.max_seq_len, rows=rows).resolve_num_pages()
     # KV: heads shard over tp; under pp the layer axis shards by stage.
     kv_per_chip = tokens * kv_bytes_per_token(cfg) // (tp * pp)
 

@@ -1182,7 +1182,7 @@ class Scheduler:
             req, slot = item["req"], item["slot"]
             done = item["done"]
             remaining = len(req.prompt_ids) - done
-            take = min(remaining, budget - used)
+            take = self.engine.prompt_chunk(done, min(remaining, budget - used))
             if take <= 0:
                 continue
             rows.append(MixedRow(

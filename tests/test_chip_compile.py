@@ -610,6 +610,10 @@ STEP_PROGRAMS = {
     # (ISSUE 54) six traced bodies (the dense layer, K K, M, K K K, M), no branch; the chunk form, the update and the
     # latent read are their twins off the TPU (``ops.mla_attention`` and ``utils.platform`` are not steered here)
     ("mixtral", "kimi-linear-test-tiny"): ((0, 0, 3845), (0, 0, 1995)),
+    # (ISSUE 61) test-tiny's two programs and, in front of the layers, the fold: a loop over the rows that start a
+    # window (its trip count their number) around a loop over the layers, no ``cond`` and no scatter (the summaries
+    # are written where a page lies); the residual sums in float32; the counts. No row above is edited.
+    ("llama", "evabyte-test-tiny"): ((2, 0, 472), (1, 0, 396)),
 }
 # A mixed step's scatters beyond the pools' writes: the convolution's heads, one a traced state-layer body (ISSUE
 # 53); Granite's two runs of Mamba layers also place their one-token rows' results and update the store at them.
@@ -636,6 +640,7 @@ def test_step_programs_hold_the_kernels_and_equations_they_held(monkeypatch, mod
     model = importlib.import_module(f"inference_gateway_tpu.models.{module}")
     cfg = model.PRESETS[preset]
     slots, page, seq, T = 4, 16, 64, 40
+    page = getattr(model.family(cfg).cached_rows, "page_size", page)   # (a pool laid out for one page size: ISSUE 61)
     params = jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0), cfg))
     own_pool = module == "phi4flash" or getattr(cfg, "indexer_topk", 0) or getattr(cfg, "layer_conv", None)
     if own_pool:
@@ -711,6 +716,7 @@ def test_every_equation_of_both_step_programs_stands_under_a_scope_of_the_closed
     model = importlib.import_module(f"inference_gateway_tpu.models.{module}")
     cfg = model.PRESETS[preset]
     slots, page, seq, T = 4, 16, 64, 40
+    page = getattr(model.family(cfg).cached_rows, "page_size", page)   # (a pool laid out for one page size: ISSUE 61)
     params = jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0), cfg))
     own_pool = (module == "phi4flash" or getattr(cfg, "indexer_topk", 0) or getattr(cfg, "layer_conv", None)
                 or getattr(cfg, "kv_lora_rank", 0))
@@ -1259,3 +1265,71 @@ def test_openpangu_step_programs_compile_for_v5e_at_the_published_widths(topo, m
     # column, 1.8 ms a round; the sampler's groups of 32 columns leave sorts of 600 maxima and 2,048 candidates.
     sorts = re.findall(r"= \(?f32\[([\d,]+)\][^\n]* sort\(", text)
     assert sorts and all(int(shape.split(",")[-1]) <= 2048 for shape in sorts), sorts
+
+
+@pytest.mark.parametrize("form", ["mixed", "decode"])
+def test_evabyte_step_programs_compile_for_v5e_at_the_published_widths(topo, monkeypatch, as_on_tpu, form):
+    """EvaByte-6.5B whole on one chip (ISSUE 61: 32 layers of 4,096 with 32 KV heads
+    of 128 and NO grouping, EVA attention over windows of 2,048 and chunks of 16, a
+    vocabulary of 320) as its cell serves it: 5 slots of 21 pages of 128 where full
+    attention would hold 100, the ENGINE's two step programs in their real nesting
+    (the packed buffer's unpacking, the chunk's while_loop of 8 steps, the rows'
+    cached lengths derived on the device), int8 weights. A pass says the chip's
+    compiler takes both: the paged kernels at 32 KV heads over a page table of 21
+    columns, the fold in front of the layers (a loop over the rows that start a
+    window around a loop over the layers), 13.5 GB of arguments with temporaries
+    far under the pool, the pool updated in place, and no weight copied a layer.
+    (The first tree's fold gathered a window's 16 pages by index: the compiler laid
+    half the pool out anew for it, 1.6 GB twice, and the mixed step did not fit the
+    chip. It slices the pages where they lie.)"""
+    import re
+
+    import inference_gateway_tpu.serving.engine as engine_module
+    from inference_gateway_tpu.models import llama
+    from inference_gateway_tpu.ops.quant import init_quantized_llama_params
+    from inference_gateway_tpu.serving.engine import STOP_TABLE_WIDTH, Engine, EngineConfig
+
+    monkeypatch.setattr("inference_gateway_tpu.ops.paged_attention.device_platform", lambda: "tpu")
+    preset = "evabyte-6.5b"
+    cfg = llama.PRESETS[preset]
+    slots, page, seq = 5, 128, 12800
+    place = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=SingleDeviceSharding(topo.devices[0])), tree)
+    params = jax.eval_shape(lambda: init_quantized_llama_params(jax.random.PRNGKey(0), cfg))
+    real_pool = llama.init_paged_cache
+    monkeypatch.setattr(llama, "init_paged_cache", lambda c, pc, dtype=BF16: {
+        n: jnp.zeros((c.num_layers, 1, pc.page_size, c.num_kv_heads * c.hd), dtype) for n in "kv"})
+    monkeypatch.setattr(engine_module, "device_info", lambda: {"platform": "cpu", "device_kind": "cpu", "device_count": 1})
+    engine = Engine(EngineConfig(model=preset, attention="paged", page_size=page, max_slots=slots, max_seq_len=seq,
+                                 use_mesh=False, decode_chunk=8), params=params)
+    pages = engine.page_cfg.max_pages_per_slot
+    assert (pages, engine.page_cfg.resolve_num_pages(), engine.mixed_budget) == (21, 105, 517)
+    assert engine.sampler_plan["path"] == "direct"          # 320 columns: 2.5 lane groups
+    cache = place(jax.eval_shape(lambda: real_pool(cfg, engine.page_cfg)))
+    assert {n: a.shape for n, a in cache.items()} == {n: (32, 105, page, 4096) for n in "kv"}
+    params = place(params)
+    sds = lambda shape, dtype=I32: place(jax.ShapeDtypeStruct(shape, dtype))
+    like = lambda a: sds(a.shape, a.dtype)
+    _, mnext, mbits, mterm, mbias = engine._mask_args_ee()
+    if form == "mixed":
+        lowered = Engine._mixed_step_fn.lower(
+            engine, params, cache, sds((engine._mixed_pack.size,)), like(engine._rng), like(engine._last_block),
+            mnext=like(mnext), mbits=like(mbits), mbias=like(mbias), automaton=False)
+        kernels = {"ragged_paged_attention_tpu": 1, "paged_attention_tpu": 1}
+    else:
+        row, real, flag = sds((slots,)), sds((slots,), jnp.float32), sds((slots,), jnp.bool_)
+        lowered = Engine._decode_chunk_fn_paged_ee.lower(
+            engine, params, cache, row, row, flag, row, sds((slots, STOP_TABLE_WIDTH)), sds((slots, pages)), row,
+            real, real, row, flag, like(engine._rng), mstates=row, mnext=like(mnext), mbits=like(mbits),
+            mterm=like(mterm), mbias=like(mbias), n_steps=8, automaton=False)
+        kernels = {"ragged_paged_attention_tpu": 0, "paged_attention_tpu": 1}
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    for name, count in kernels.items():
+        assert len(set(re.findall(rf"%{name}[\w.\-]* = ", text))) == count, name
+    pool = 2 * 32 * 105 * page * 4096 * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool and memory.temp_size_in_bytes < pool // 20
+    assert not re.findall(rf"= bf16\[(?:{32 * 105 * page}|32,105,{page}),4096\]\S* copy\(", text), "the pool is copied"
+    assert re.search(r"kv\.write/while/body/while/body", text), "the fold stands in front of the layers"
+    assert not [c for c in _weight_copies(text) if "params__" not in c], "a weight is copied"

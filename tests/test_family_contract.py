@@ -72,9 +72,10 @@ def engines():
 
     def engine(preset: str) -> Engine:
         if preset not in built:
+            # (pages of 16 rows, but where a family's pool is laid out for one size: ISSUE 61)
             built[preset] = Engine(EngineConfig(
-                model=preset, attention="paged", page_size=16, max_slots=2, max_seq_len=64, dtype="float32",
-                use_mesh=False, prefill_buckets=(16, 32)))
+                model=preset, attention="paged", page_size=getattr(family_of(preset_config(preset)).cached_rows, "page_size", 16),
+                max_slots=2, max_seq_len=64, dtype="float32", use_mesh=False, prefill_buckets=(16, 32)))
         return built[preset]
 
     return engine

@@ -49,3 +49,13 @@ def test_gemma_engine_serves():
         assert len(out) == 5
     finally:
         s.stop()
+
+
+@pytest.mark.parametrize("quantize", [None, "int8"])
+def test_a_seeded_gemma_norm_has_an_effective_weight_of_one(quantize):
+    """A model that adds one to a norm's stored weight (``norm_offset``) is seeded with
+    zeros there, in both initialisers: stored ones would double every norm (ISSUE 61)."""
+    e = Engine(EngineConfig(model="gemma-test-tiny", max_slots=1, max_seq_len=16, dtype="float32",
+                            quantize=quantize, use_mesh=False))
+    norms = [e.params["layers"]["attn_norm"], e.params["layers"]["mlp_norm"], e.params["final_norm"]]
+    assert all(float(jnp.abs(llama._nw(w, e.model_cfg) - 1.0).max()) == 0.0 for w in norms)

@@ -39,17 +39,19 @@ def test_every_entry_is_in_the_benchmark_with_its_readers_unit_layer_and_every_c
     from PR 43; ``bench/`` is the benchmark's and is not edited from here): it
     holds each reader's ``workloads`` to be the benchmark's FIRST cells, in
     order. PR 41's cell, the eighth, is never starved and lists none of them;
-    PR 43's, the ninth, is and lists four, so its name follows the seventh's.
-    PR 56's, the thirteenth, is starved as PR 43's is and lists the same four
-    behind it. The check reads ``BENCHMARK.json`` with PR 56's cell and then PR
-    43's taken off the lists that end with them, and nothing else changed."""
+    PR 43's, the ninth, PR 56's, the thirteenth, and PR 61's, the fourteenth, are
+    and list four, so their names follow the seventh's. The check reads
+    ``BENCHMARK.json`` with each of these readers' lists cut where it stops being
+    the benchmark's first cells in order (found by name since PR 61: a later cell
+    needs no edit here), and nothing else changed."""
     import json
 
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    cells = [w["name"] for w in bench["workloads"]]
     for metric in bench["per_layer"]:
-        for later in ("openpangu-ultra-moe-718b.reason", "lfm2-8b-a1b.reason"):
-            if metric["name"] in readers.NAMES and metric["workloads"][-1] == later:
-                metric["workloads"] = metric["workloads"][:-1]
+        if metric["name"] in readers.NAMES:
+            agree = [a == b for a, b in zip(metric["workloads"], cells)]
+            metric["workloads"] = metric["workloads"][:agree.index(False) if False in agree else len(agree)]
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     monkeypatch.setattr(readers, "BENCH", tmp_path / "bench")
     readers.test_every_entry_is_in_the_benchmark_with_its_readers_unit_layer_and_every_cell(name)
