@@ -6,7 +6,8 @@
 a scope's sum is made of (ISSUE 53's step 0: which of `ops/ssm.conv_ragged`'s
 operations hold its 392 us a layer). An operation belongs to a scope as there:
 its instruction's `op_name` in the capture's own HLO, the innermost component
-the vocabulary names. Self seconds, us a program of the module, the sum first.
+the vocabulary names. Self seconds, us a program of the module, the sum first;
+the same in `<capture>.<scope>.<module>.ops.json`, a file a scope and module.
 
 Not part of the benchmark; reads a capture, never the chip.
 """
@@ -85,7 +86,7 @@ def main() -> int:
     print(f"{got['scope']} in {got['programs']} x {got['module']}: {got['us_a_program']:.1f} us a program")
     for name, us, count in got["ops"][:args.most]:
         print(f"  {us:9.1f} us  x{count:5.1f}  {name[:150]}")
-    with open(re.sub(r"\.xplane\.pb$", "", args.xplane) + f".{args.scope}.ops.json", "w") as f:
+    with open(re.sub(r"\.xplane\.pb$", "", args.xplane) + f".{args.scope}.{args.module}.ops.json", "w") as f:
         json.dump(got, f, indent=1)
     return 0
 

@@ -34,6 +34,10 @@ class Family:
     # auxiliary leads with the rows each expert multiplied (``module.split_aux``).
     experts: bool = False
     pool: str = "kv"   # what the page pool holds: "kv" | "kv+index" (an indexer's keys beside them) | "latent" (one array)
+    # Its step programs write a step's keys and values through ``models/llama._write_kv``
+    # (ISSUE 62: ``ops/pool_write.write_rows``, whose verdict the engine reports as
+    # ``pool_write_plan``); False where the module writes its pool itself.
+    row_writes: bool = True
     # A state a slot beside the pool: it cannot be handed over as pages can
     # (prefix hits are declined, and counted) and starts from zero at position 0
     # (``state_resets``); ``cfg.state_bytes_per_slot`` says how large.

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from inference_gateway_tpu.models.family import INT8_ALONE, Family, attention_layers
 from inference_gateway_tpu.ops.attention import causal_prefill_mask, decode_mask, gqa_attend
 from inference_gateway_tpu.ops.norms import rms_norm
+from inference_gateway_tpu.ops.pool_write import write_rows
 from inference_gateway_tpu.ops.quant import qmatmul
 from inference_gateway_tpu.ops.rope import apply_rope, rope_cos_sin, rope_inv_freq
 
@@ -268,10 +269,12 @@ def _project_qkv(h, ap: Params, li, cfg: "LlamaConfig", cos, sin, cache: Params)
             v.reshape(B, T, Hkv * D).astype(cache["v"].dtype))
 
 
-def _write_kv(ck, cv, qkv, write_idx, plane, flat: int, total: int, skip=None):
+def _write_kv(ck, cv, qkv, write_idx, plane, flat: int, total: int, skip=None, mesh=None):
     """A step's keys and values into ``plane`` of the flat pools (the layer
     index, but for ``kinds``); nothing where ``skip`` is set. Returns the
-    pools and the indices written."""
+    pools and the indices written. Both pools in one ``write_rows`` (ISSUE 62:
+    on the chip a copy a run of rows, where its dispatch says so; pools sharded
+    over a ``mesh`` keep the scatter)."""
     # Per-layer offset; rows that were OOB within the layer (== flat,
     # the drop convention) must stay OOB for the WHOLE buffer, not
     # land in layer li+1's first page.
@@ -279,7 +282,8 @@ def _write_kv(ck, cv, qkv, write_idx, plane, flat: int, total: int, skip=None):
         w_idx = jnp.where(write_idx >= flat, total, write_idx + plane * flat)
         if skip is not None:
             w_idx = jnp.where(skip, total, w_idx)
-        return ck.at[w_idx].set(qkv[3], mode="drop"), cv.at[w_idx].set(qkv[4], mode="drop"), w_idx
+        ck, cv = write_rows((ck, cv), (qkv[3], qkv[4]), w_idx, mesh=mesh)
+        return ck, cv, w_idx
 
 
 def _flat_index(write_idx, plane, flat: int, total: int):
@@ -881,7 +885,7 @@ def forward_paged_impl(
     assert mixer is None or own or prefix is None, "an attention seam's counts of a prefix's layers would be lost"
 
     project = partial(_project_qkv, cfg=cfg, cos=cos, sin=sin, cache=cache)
-    write = partial(_write_kv, write_idx=write_idx, flat=flat, total=total)
+    write = partial(_write_kv, write_idx=write_idx, flat=flat, total=total, mesh=mesh)
 
     def attend(h, ap, li, plane, q, ck, cv):
         """The paged kernel over ``plane`` and the output projection."""
@@ -1103,7 +1107,7 @@ def forward_ragged_impl(
     assert mixer is None or own or prefix is None, "an attention seam's counts of a prefix's layers would be lost"
 
     project = partial(_project_qkv, cfg=cfg, cos=cos, sin=sin, cache=cache)
-    write = partial(_write_kv, write_idx=write_idx, flat=flat, total=total)
+    write = partial(_write_kv, write_idx=write_idx, flat=flat, total=total, mesh=mesh)
 
     def attend(h, ap, li, plane, q, ck, cv):
         pages_k = ck.reshape(L * P, page_size, HkvD)

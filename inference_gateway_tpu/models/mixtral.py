@@ -1819,6 +1819,7 @@ def family(cfg: MixtralConfig) -> Family:
             + (SSD_STATS if cfg.mamba2 else ()) + (KDA_STATS if cfg.kda else ()) + (MTP_STATS if cfg.mtp_layers else ()))
     return Family(
         module=sys.modules[__name__], experts=True, pool="latent" if latent else "kv+index" if indexer else "kv",
+        row_writes=not latent,
         state_store=state, mixed_alone=state or indexer or latent, round_tokens=2 if cfg.mtp_layers else 1,
         refuses=_refuses(cfg), chunk_stats=MIXED_STATS[2:] + more, mixed_stats=MIXED_STATS + more,
         attention_layers=attention_layers(cfg.cache_layers, cfg.sliding_window,

@@ -525,7 +525,7 @@ def family(cfg: Phi4FlashConfig) -> Family:
     """What serving ``cfg`` takes. What cannot carry the Mamba state is refused by
     name (ISSUE 32)."""
     return Family(
-        module=sys.modules[__name__], state_store=True, mixed_alone=True,
+        module=sys.modules[__name__], state_store=True, mixed_alone=True, row_writes=False,
         refuses={"spec_draft": "speculative decoding has no Mamba state to roll back; serve it without --spec-draft",
                  "vision_model": "multimodal prefill carries no Mamba state", "int4": INT8_ALONE,
                  "mesh": "no mesh ({shape}) carries the per-slot state store yet; serve it on one device"},

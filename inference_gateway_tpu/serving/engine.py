@@ -648,6 +648,7 @@ class Engine:
         self.device = device_info()
         self.ragged_attention_plan: dict | None = None
         self.decode_attention_plan: dict | None = None
+        self.pool_write_plan: dict | None = None
         if self.paged:
             from inference_gateway_tpu.ops.paged_attention import (
                 FORCE_PAGED_KERNEL,
@@ -696,6 +697,19 @@ class Engine:
                 if self.mixed_ok:
                     self.ragged_attention_plan = ragged_tile_plan(*layout)
                 self.decode_attention_plan = decode_walk_plan(*layout)
+            # The pools' row writes (ISSUE 62): which path a launch's rows take
+            # into the flat pools (ops/pool_write.py), by step program; static per
+            # engine. The scheduler counts a mixed step's copies from its tiles.
+            if family.row_writes:
+                from inference_gateway_tpu.ops.pool_write import pool_write_plan, sharded_over
+
+                layers, pages, page, row = self.cache["k"].shape
+                programs = {"decode_chunk": config.max_slots * self.round_tokens}
+                if self.mixed_ok:
+                    programs["mixed_step"] = self.mixed_budget
+                self.pool_write_plan = pool_write_plan(
+                    programs, row, self.cache["k"].dtype, layers * pages * page, platform,
+                    sharded=sharded_over(self.mesh))
         else:
             self.attention_path = "dense"
             self.attention_path_reason = "contiguous slot cache (paged attention not in use)"
@@ -1827,6 +1841,23 @@ class Engine:
         return ragged_walk_pages([(len(r.token_ids), int(held(r.start + len(r.token_ids)))) for r in rows],
                                  self.family.attention_layers, self.ragged_attention_plan,
                                  self.config.page_size)
+
+    def write_copies(self, rows: "list[MixedRow]") -> int | None:
+        """The copies one pool takes for ``rows`` in one mixed step on the run-copy
+        path (``ops/pool_write.py``: a tile of ``tile_rows`` rows a copy, so a
+        row's run of n rows from row r of its page is ceil((r % tile + n) / tile)
+        of them): host arithmetic on the rows' descriptors, the timeline's
+        ``write_copies``. None where a page is not whole tiles (its tiles then
+        depend on where the pages lie)."""
+        tile = self.pool_write_plan["tile_rows"]
+        if self.config.page_size % tile:
+            return None
+        held = self.family.cached_rows or (lambda n: n)
+        copies = 0
+        for r in rows:
+            first, last = int(held(r.start)), int(held(r.start + len(r.token_ids)))
+            copies += -(-(first % tile + last - first) // tile)
+        return copies
 
     def prompt_chunk(self, start: int, want: int) -> int:
         """How many of ``want`` prompt tokens from position ``start`` one step may take:

@@ -1568,6 +1568,12 @@ class Scheduler:
                         if len(row.token_ids) >= plan["wide_from"]]
                 spans["attn_wide_tokens"] = sum(len(row.token_ids) for row in wide)
                 spans["attn_pages_walked"], spans["attn_pages_masked"] = self.engine.attn_pages(wide)
+            if getattr(self.engine, "pool_write_plan", None) is not None:
+                # The copies a pool takes for the step's rows on the run-copy
+                # path (ISSUE 62); rows written over copies is the run length.
+                copies = self.engine.write_copies(step.rows)
+                if copies is not None:
+                    spans["write_copies"] = copies
             if not overlapped:
                 spans.pop("submit_ts", None)
             if counts is not None:

@@ -955,6 +955,14 @@ class SidecarServer:
             # engages is `batch` of the timeline's `decode` records against
             # the slots). None where no decode program holds the kernel.
             "decode_attention": getattr(self.engine, "decode_attention_plan", None),
+            # The pools' row writes (ISSUE 62): for each step program whose
+            # family writes through ``ops/pool_write.write_rows`` the `path` its
+            # `rows` of `row_bytes` take a layer ("runs": a copy a tile of
+            # `tile_rows` rows that a run covers; "scatter": XLA's, a row at a
+            # time) and the `reason`. The timeline's `mixed` records count the
+            # copies a pool takes (`write_copies`). None where the model writes
+            # its pool itself.
+            "pool_write": getattr(self.engine, "pool_write_plan", None),
             # The model family's blocks, written by its model module (``Family.status``,
             # models/family.py; docs/observability.md lists them). A state store (ISSUE 32,
             # 43, 50, 54): ``state_store``, ``cache_layers``, ``state_resets``,

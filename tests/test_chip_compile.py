@@ -13,6 +13,8 @@ fixture, never at import: one process at a time may load the TPU's library,
 and every xdist worker imports every test file.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -342,6 +344,87 @@ def test_expert_kernels_stay_inside_their_start_up_budget(as_on_tpu, case):
     for name in ("moe_gate_up_gmm", "moe_down_gmm"):
         (body,) = _kernel_bodies(jaxpr, name)
         assert _equations(body) <= EXPERT_KERNEL_EQUATIONS
+
+
+# ISSUE 62: a step's rows into both flat pools by ``ops/pool_write.py``'s kernel, at each
+# cell's mixed-step shape (rows, lanes of a row, the pool's rows) and at 64 decode rows (a
+# shape the dispatch leaves to the scatter: the kernel must still compile there). One
+# body whatever the shape: no loop is unrolled over rows or lanes.
+POOL_WRITES = {
+    "evabyte_517_rows_of_8_KB": (517, 4096, 430080), "mistral_536_rows_of_2_KB": (536, 1024, 1572864),
+    "qwen2.5_560_rows_of_1_KB": (560, 512, 28 * 48 * 2048), "trinity_544_rows_of_1_KB": (544, 512, 8 * 32 * 12800),
+    "lfm2_576_rows_of_1_KB": (576, 512, 6 * 64 * 4096), "decode_64_rows_of_1_KB": (64, 512, 6 * 64 * 4096),
+}
+POOL_WRITE_EQUATIONS = 660
+
+
+def _pool_write(sds, rows, width, pool_rows):
+    from inference_gateway_tpu.ops.pool_write import write_rows_tpu
+
+    pool, step = sds((pool_rows, width), BF16), sds((rows, width), BF16)
+    return (lambda ck, cv, k, v, idx: write_rows_tpu((ck, cv), (k, v), idx)), (pool, pool, step, step, sds((rows,), I32))
+
+
+@pytest.mark.parametrize("case", list(POOL_WRITES))
+def test_pool_write_kernel_compiles_for_v5e_with_no_copy_of_a_pool(one_chip, case):
+    """Both pools through ONE kernel, aliased through the call: the compiled module
+    holds no copy of a pool and no scatter, and its temporaries are the indices'."""
+    rows, width, pool_rows = POOL_WRITES[case]
+    fn, shapes = _pool_write(one_chip, rows, width, pool_rows)
+    compiled = jax.jit(fn, donate_argnums=(0, 1)).lower(*shapes).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1 and "pool_write_kernel" in text
+    assert "scatter" not in text
+    assert not re.search(rf"= bf16\[{pool_rows},{width}\]\S* copy\(", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("case", list(POOL_WRITES))
+def test_pool_write_kernel_stays_inside_its_start_up_budget(case):
+    """No clock: the traced equations of the kernel's body and of what stands around it
+    (the indices' bound; EvaByte's 517 rows padded to whole tiles of 8)."""
+    fn, shapes = _pool_write(jax.ShapeDtypeStruct, *POOL_WRITES[case])
+    jaxpr = jax.make_jaxpr(fn)(*shapes).jaxpr
+    (body,) = _kernel_bodies(jaxpr, "pool_write_kernel")
+    assert _equations(body) <= POOL_WRITE_EQUATIONS
+    assert _equations(jaxpr) - _equations(body) <= 40
+
+
+@pytest.mark.parametrize("module,preset", [("llama", "test-tiny"), ("mixtral", "lfm2-test-tiny")])
+def test_on_the_chip_a_mixed_step_holds_one_pool_write_kernel_a_traced_body_and_the_decode_step_the_scatter(
+        monkeypatch, as_on_tpu, module, preset):
+    """What the step programs hold where ``pool_write_dispatch`` sees a TPU (the census
+    below traces them as this sandbox does, off the chip: the scatter). The presets at a
+    head size that puts a row of the pool on the 128 lanes: a mixed step of 160 rows
+    writes both pools by ONE kernel a traced layer body, beside its ragged kernel, and
+    scatters into no pool; a decode step's 4 rows keep the two scatters. A model of two
+    kinds (LFM2) hands the same kernel its state layers' skipped rows."""
+    import dataclasses
+    import importlib
+
+    from inference_gateway_tpu.serving.kv_cache import PagedCacheConfig
+
+    monkeypatch.setattr("inference_gateway_tpu.ops.paged_attention.device_platform", lambda: "tpu")
+    model = importlib.import_module(f"inference_gateway_tpu.models.{module}")
+    cfg = dataclasses.replace(model.PRESETS[preset], head_dim=64)
+    assert cfg.num_kv_heads * cfg.hd == 128
+    slots, page, seq, T = 4, 16, 256, 160
+    params = jax.eval_shape(lambda: model.init_params(jax.random.PRNGKey(0), cfg))
+    if module == "llama":
+        pages = jax.ShapeDtypeStruct((cfg.num_layers, slots * seq // page, page, 128), BF16)
+        cache = {"k": pages, "v": pages}
+    else:
+        cache = jax.eval_shape(lambda: model.init_paged_cache(cfg, PagedCacheConfig(page, 0, slots, seq), dtype=BF16))
+    sds = jax.ShapeDtypeStruct
+    tokens, row, col, table = sds((1, T), I32), sds((slots,), I32), sds((slots, 1), I32), sds((slots, seq // page), I32)
+    mixed = jax.make_jaxpr(lambda *a: model.forward_ragged(a[0], cfg, *a[1:]))(
+        params, tokens, tokens, cache, tokens, table, row, row, row)
+    decode = jax.make_jaxpr(lambda *a: model.forward_paged(a[0], cfg, *a[1:], mode="decode"))(
+        params, col, col, row, cache, col, table)
+    writes = _kernel_bodies(mixed.jaxpr, "pool_write_kernel")
+    assert len(writes) == len(_kernel_bodies(mixed.jaxpr)) >= 1
+    assert _census(mixed.jaxpr, {}).get("scatter", 0) == STATE_SCATTERS.get(preset, 0)
+    assert not _kernel_bodies(decode.jaxpr, "pool_write_kernel") and _census(decode.jaxpr, {})["scatter"] == 2
 
 
 def _values(jaxpr):
@@ -1315,14 +1398,14 @@ def test_evabyte_step_programs_compile_for_v5e_at_the_published_widths(topo, mon
         lowered = Engine._mixed_step_fn.lower(
             engine, params, cache, sds((engine._mixed_pack.size,)), like(engine._rng), like(engine._last_block),
             mnext=like(mnext), mbits=like(mbits), mbias=like(mbias), automaton=False)
-        kernels = {"ragged_paged_attention_tpu": 1, "paged_attention_tpu": 1}
+        kernels = {"ragged_paged_attention_tpu": 1, "paged_attention_tpu": 1, "pool_write_kernel": 1}   # (ISSUE 62: 517 rows)
     else:
         row, real, flag = sds((slots,)), sds((slots,), jnp.float32), sds((slots,), jnp.bool_)
         lowered = Engine._decode_chunk_fn_paged_ee.lower(
             engine, params, cache, row, row, flag, row, sds((slots, STOP_TABLE_WIDTH)), sds((slots, pages)), row,
             real, real, row, flag, like(engine._rng), mstates=row, mnext=like(mnext), mbits=like(mbits),
             mterm=like(mterm), mbias=like(mbias), n_steps=8, automaton=False)
-        kernels = {"ragged_paged_attention_tpu": 0, "paged_attention_tpu": 1}
+        kernels = {"ragged_paged_attention_tpu": 0, "paged_attention_tpu": 1, "pool_write_kernel": 0}   # (5 rows: the scatter)
     compiled = lowered.compile()
     text = compiled.as_text()
     for name, count in kernels.items():

@@ -231,3 +231,24 @@ def test_each_reader_agrees_with_its_entry_found_by_name(name):
     account = {"hlo_from": "capture", "modules": {"mixed_step": {"class_s": {cls: 0.5}}, "decode_chunk": {"class_s": {cls: 0.25}}}}
     assert mod.compute(_sources(account, busy_s=3.0)) == pytest.approx(25.0)
     assert mod.compute({"trace": None}) is None
+
+
+def test_the_pool_write_reader_is_one_scopes_seconds_in_both_step_programs_over_busy():
+    """``kv_write_busy_share`` (ISSUE 62): the scope ``kv.write`` alone, by ``scope_s``;
+    nothing where no operation of either step program stands under it."""
+    name = "kv_write_busy_share"
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    (entry,) = [m for m in bench["per_layer"] if m["name"] == name]
+    spec = importlib.util.spec_from_file_location(f"bench_layers_{name}", BENCH / "layers" / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    assert (entry["unit"], entry["layer"], entry["moves"], entry["source"]) == (mod.UNIT, mod.LAYER, mod.MOVES, mod.SOURCE)
+    assert entry["better"] == "lower" and len(entry["workloads"]) == 10 and "jax" not in mod.__dict__
+    assert mod.SCOPE in CLASSES["attention"]
+    modules = {"mixed_step": {"scope_s": {"kv.write": 0.5, "attention": 2.0}}, "decode_chunk": {"scope_s": {"kv.write": 0.25}},
+               "other_programs": {"scope_s": {"kv.write": 9.0}}}
+    assert mod.compute(_sources({"hlo_from": "capture", "modules": modules}, busy_s=3.0)) == pytest.approx(25.0)
+    modules = {"mixed_step": {"scope_s": {"attention": 2.0}}, "decode_chunk": {"scope_s": {}}}
+    assert mod.compute(_sources({"hlo_from": "capture", "modules": modules}, busy_s=3.0)) is None
+    assert mod.compute(_sources({"hlo_from": None, "modules": modules}, busy_s=3.0)) is None
+    assert mod.compute({"trace": None}) is None and mod.compute({"trace": {"busy_s": 0.0}}) is None
