@@ -1738,6 +1738,7 @@ def _status(cfg: MixtralConfig, view) -> dict:
     ``latent_pool``; with a next-token module ``mtp`` and ``vocab``; always ``router``,
     ``shared_experts``, ``dense_prefix``, and ``cache_layers`` of windowed layers."""
     from inference_gateway_tpu.ops import sparse_attention
+    from inference_gateway_tpu.ops import ssd as ssd_ops
     from inference_gateway_tpu.utils.platform import on_tpu
 
     out, itemsize = {}, jnp.dtype(view.dtype).itemsize
@@ -1747,15 +1748,21 @@ def _status(cfg: MixtralConfig, view) -> dict:
                "cache_layers": {"full": cfg.cache_layers,
                                 "kda" if cfg.kda else "mamba2" if cfg.mamba2 else "conv": cfg.conv_layers},
                "state_resets": view.state_resets, "prefix_hits_declined": view.prefix_hits_declined}
+    def one_token(plan_of, heads, rows, lanes):
+        """The one-token update's plan for the engine's store (ISSUE 63): the path
+        by the store's static shape and the platform, and what a visit moves."""
+        plan = plan_of((cfg.conv_layers, view.max_slots, heads, rows, lanes))
+        return {"one_token_rows": plan.pop("path"), "one_token_update": plan}
+
     if cfg.mamba2:   # how the Mamba-2 scan is done (ISSUE 50; static)
         out["ssd"] = {"heads": cfg.ssm_heads, "state": [cfg.ssm_head_dim, cfg.ssm_state], "block": cfg.ssd_block,
                       "rows_of_more_than_one_token": "ssd_chunk_kernel" if on_tpu() else "ssd_chunk_twin",
-                      "one_token_rows": "ssm_state_update_kernel" if on_tpu() else "state_update"}
+                      **one_token(ssd_ops.update_plan, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)}
     if cfg.kda:   # how the delta rule is run (ISSUE 54; static)
         out["kda"] = {"heads": cfg.kda_heads, "state": [cfg.kda_head_dim, cfg.kda_head_dim], "block": cfg.kda_block,
                       "decay": "a channel", "state_bytes_per_slot_per_layer": cfg.kda_inner * cfg.kda_head_dim * 4,
                       "rows_of_more_than_one_token": "kda_chunk_kernel" if on_tpu() else "kda_chunk_twin",
-                      "one_token_rows": "kda_state_update_kernel" if on_tpu() else "state_update"}
+                      **one_token(kda_ops.update_plan, cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim)}
     if cfg.indexer_topk:   # its sizes, and how the selection and the read are done (static)
         row_tokens = view.page_cfg.max_pages_per_slot * view.page_cfg.page_size
         walk = sparse_attention.one_query_read(cfg.indexer_topk, row_tokens) == "walk"

@@ -15,10 +15,10 @@ The one-token form: ``S' = Diag(a_t) S; u_t = beta_t (v_t - S'^T k_t); S_t = S' 
 Two forms, as ``ops/ssd.py`` has them:
 
 - ``update_store``: every row is one slot advancing ONE token, batched over the
-  slots. On a TPU it is the Pallas kernel ``kda_state_update_kernel``: a grid
-  of (head blocks, slots), a visit reading and writing ONE row's states of a
-  head block in place; a slot that does not advance rides the block of the
-  nearest one that does (no fetch, no write). Elsewhere ``state_update``, the
+  slots. On a TPU it is the Pallas kernel ``kda_state_update_kernel``:
+  ``ops/ssd.walk_rows``'s walk of the rows that advance, a visit moving about
+  a megabyte of ONE row's states in and back out by DMA, in place; a slot that
+  does not advance is never visited. Elsewhere ``state_update``, the
   definition in plain ``jax.numpy`` over the layer's whole entry.
 - ``kda_chunk``: the rows of a mixed step that hold MORE than one token, in
   blocks of Q tokens. With G_t the running sum of g inside the block (a vector),
@@ -77,10 +77,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from inference_gateway_tpu.ops.ssd import HIGHEST, META, _padded
+from inference_gateway_tpu.ops.ssd import (HIGHEST, META, RING, _padded, advancing_rows, handed_back, staged, visit_heads,
+                                           visit_plan, walk_rows)
 from inference_gateway_tpu.utils.platform import on_tpu
 
-HEAD_BLOCK = 4   # heads a grid step holds: their states are 4 x K x V float32 of VMEM (256 KB at 128 x 128)
+HEAD_BLOCK = 4   # heads a grid step of the chunk kernel holds: their states are 4 x K x V float32 of VMEM (256 KB at 128 x 128)
 
 
 def state_update(entry, q, k, v, g, beta, valid, fresh=None):
@@ -99,74 +100,83 @@ def state_update(entry, q, k, v, g, beta, valid, fresh=None):
         return o, jnp.where(valid[:, None, None, None], new, entry)
 
 
-def _state_update_kernel(ord_ref, row_ref, flag_ref, q_ref, k_ref, a_ref, v_ref, b_ref, s_in, o_ref, s_out,
-                         *, hb: int, K: int):
-    s = pl.program_id(1)
-    own = row_ref[s] == s                      # else: a row that does not advance, riding the block of one that does
-    valid, fresh = flag_ref[s] % 2 == 1, flag_ref[s] // 2 == 1
+def update_plan(shape, platform: str | None = None) -> dict:
+    """How ``update_store`` advances a store of ``shape`` (state layers, slots,
+    Hh, K, V), as ``ops/ssd.update_plan`` says it of Mamba-2's."""
+    _, _, Hh, K, V = shape
+    off = None if K == V == 128 else f"a head's state of {K} x {V} is not the 128 x 128 square"
+    return visit_plan(Hh, K, V, "kda_state_update_kernel", platform, off)
 
-    @pl.when(own & valid)
-    def _():
-        # A head's (1, K) row as a COLUMN beside its state's rows: spread over K sublanes, turned.
-        col = lambda row: jnp.broadcast_to(row, (K, K)).T
-        for i in range(hb):
-            lanes = slice(i * K, (i + 1) * K)
-            state = s_in[i * K:(i + 1) * K, :]                                  # (K, V)
-            decayed = col(a_ref[:, lanes]) * jnp.where(fresh, jnp.zeros_like(state), state)
-            kc = col(k_ref[:, lanes])
-            u = b_ref[:, lanes] * (v_ref[:, lanes] - jnp.sum(decayed * kc, axis=0, keepdims=True))   # (1, V)
+
+def _state_update_kernel(n_ref, rows_ref, ord_ref, fresh_ref, beta_ref, q_hbm, k_hbm, a_hbm, v_hbm, store_in, o_hbm, store_out,
+                         ring, sem, q_ref, k_ref, a_ref, v_ref, o_ref, row_sem, *, hb: int, K: int, heads: int):
+    o_ref[...] = jnp.zeros_like(o_ref)
+    staged((q_hbm, k_hbm, a_hbm, v_hbm), (q_ref, k_ref, a_ref, v_ref), row_sem)
+
+    def advance(slot, piece, ref):
+        fresh = fresh_ref[slot] == 1
+        V = ref.shape[1]
+        # A head's (1, K) row as a COLUMN beside its state's rows: spread over the sublanes, turned.
+        col = lambda row: jnp.broadcast_to(row, (V, K)).T
+
+        def one(i, _):
+            head = piece * hb + i
+            mine = lambda r: r[slot, pl.ds(head, 1), :]                             # (1, K) or (1, V)
+            rows = pl.ds(pl.multiple_of(i * K, 8), K)
+            state = ref[rows, :]                                                    # (K, V)
+            decayed = col(mine(a_ref)) * jnp.where(fresh, jnp.zeros_like(state), state)
+            kc = col(mine(k_ref))
+            u = beta_ref[slot * heads + head] * (mine(v_ref) - jnp.sum(decayed * kc, axis=0, keepdims=True))   # (1, V)
             new = decayed + kc * u
-            s_out[i * K:(i + 1) * K, :] = new
-            o_ref[:, lanes] = jnp.sum(new * col(q_ref[:, lanes]), axis=0, keepdims=True)
+            ref[rows, :] = new
+            o_ref[slot, pl.ds(head, 1), :] = jnp.sum(new * col(mine(q_ref)), axis=0, keepdims=True)
+            return 0
 
-    @pl.when(own & jnp.logical_not(valid))     # no row advances at all: the block is written back as it came
-    def _():
-        s_out[...] = s_in[...]
+        jax.lax.fori_loop(0, hb, one, 0, unroll=True)      # (the heads' turns wait on nothing of one another's)
+
+    walk_rows(n_ref, rows_ref, ord_ref, store_in, store_out, ring, sem, visits=heads // hb, rows=hb * K, advance=advance)
+    handed_back(o_ref, o_hbm, row_sem.at[4])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def state_update_tpu(store, ordinal, q, k, v, g, beta, valid, fresh, *, interpret: bool = False):
     """``state_update`` on the whole store by the Pallas kernel
-    ``kda_state_update_kernel`` (``ops/ssd.state_update_tpu``'s grid and its
-    riding rows): the store's traffic is the LIVE rows' states, once in and
-    once out. K = V (a head's state is square). Returns o (S, Hh, V), defined
-    at the valid rows, and the store."""
+    ``kda_state_update_kernel`` (``ops/ssd.walk_rows``: one program over the
+    rows that advance, ``visit_heads`` heads' states of one row a visit, in and
+    back out by DMA): the store's traffic is the advancing rows' states, once in
+    and once out. Returns o (S, Hh, V), zero at the rows that do not advance,
+    and the store."""
     L, S, Hh, K, V = store.shape
-    hb = HEAD_BLOCK if Hh % HEAD_BLOCK == 0 else Hh
+    hb = visit_heads(Hh, K, V)
     rows = store.reshape(L, S, Hh * K, V)
-    idx = jnp.arange(S)
-    before = jax.lax.cummax(jnp.where(valid, idx, -1))                          # the last advancing row at or before s
-    row_of = jnp.where(before >= 0, before, jnp.argmax(valid)).astype(jnp.int32)
-    row_of = jnp.where(jnp.any(valid), row_of, 0)
-    flags = valid.astype(jnp.int32) + 2 * fresh.astype(jnp.int32)
-    flat = lambda a: a.reshape(S, 1, Hh * K).astype(jnp.float32)
-    at = lambda f: (lambda h, s, o, r, fl: f(h, s, o, r))
-    vec = pl.BlockSpec((None, 1, hb * K), at(lambda h, s, o, r: (r[s], 0, h)))
-    states = pl.BlockSpec((None, None, hb * K, V), at(lambda h, s, o, r: (o[0], r[s], h, 0)))
+    order, n = advancing_rows(valid)
+    f32 = lambda a: a.astype(jnp.float32)
+    smem, hbm = (pl.BlockSpec(memory_space=m) for m in (pltpu.SMEM, pltpu.ANY))
+    held = lambda *shape: pltpu.VMEM(shape, jnp.float32)
     o, rows = pl.pallas_call(
-        functools.partial(_state_update_kernel, hb=hb, K=K),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(Hh // hb, S),
-            in_specs=[vec, vec, vec, vec, vec, states], out_specs=[vec, states]),
-        out_shape=[jax.ShapeDtypeStruct((S, 1, Hh * V), jnp.float32), jax.ShapeDtypeStruct(rows.shape, rows.dtype)],
-        input_output_aliases={8: 1},   # the store: operand 8 counting the three scalar operands
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
+        functools.partial(_state_update_kernel, hb=hb, K=K, heads=Hh),
+        in_specs=[smem] * 5 + [hbm] * 5,
+        out_specs=[hbm, hbm],
+        scratch_shapes=[held(RING, hb * K, V), pltpu.SemaphoreType.DMA((2, RING)),
+                        *[held(S, Hh, K)] * 3, held(S, Hh, V), held(S, Hh, V), pltpu.SemaphoreType.DMA((5,))],
+        out_shape=[jax.ShapeDtypeStruct((S, Hh, V), jnp.float32), jax.ShapeDtypeStruct(rows.shape, rows.dtype)],
+        input_output_aliases={9: 1},   # the store
         interpret=interpret,
         name="kda_state_update_kernel",
-    )(jnp.reshape(ordinal, (1,)).astype(jnp.int32), row_of, flags,
-      flat(q), flat(k), flat(jnp.exp(g)), flat(v), flat(jnp.broadcast_to(beta[..., None], v.shape)), rows)
-    return o.reshape(S, Hh, V), rows.reshape(store.shape)
+    )(n, order, jnp.reshape(ordinal, (1,)).astype(jnp.int32), fresh.astype(jnp.int32), f32(beta).reshape(-1),
+      f32(q), f32(k), f32(jnp.exp(g)), f32(v), rows)
+    return o, rows.reshape(store.shape)
 
 
 def update_store(store, ordinal, q, k, v, g, beta, valid, fresh=None, kernel: bool | None = None):
     """``state_update`` on entry ``ordinal`` of the store (state layers, slots,
     Hh, K, V). Returns (o (S, Hh, V), defined at the valid rows; the store).
-    ``kernel`` None: the Pallas kernel on a TPU where a head's state is 128 x
-    128, plain ``jax.numpy`` elsewhere; tests force the kernel, interpreted off
-    the TPU."""
-    L, S, Hh, K, V = store.shape
+    ``kernel`` None: what ``update_plan`` says of the store's shape and the
+    platform (the Pallas kernel on a TPU where a head's state is 128 x 128,
+    plain ``jax.numpy`` elsewhere); tests force the kernel, interpreted off the
+    TPU."""
     if kernel is None:
-        kernel = on_tpu() and K == 128 and V == 128
+        kernel = update_plan(store.shape)["path"] != "state_update"
     if kernel:
         fresh = jnp.zeros_like(valid) if fresh is None else fresh
         with jax.named_scope("ssm.scan"):

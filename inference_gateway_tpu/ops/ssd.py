@@ -14,12 +14,15 @@ Two forms, as ``ops/ssm.py`` has them:
 
 - ``update_store``: every row is one slot advancing ONE token, batched over
   the slots. The decode chunk's rows and a mixed step's one-token rows take
-  it. On a TPU it is the Pallas kernel ``ssm_state_update_kernel``: a grid of
-  (head blocks, slots), a visit reading and writing ONE row's states of a head
-  block in place; a slot that does not advance rides the block of the nearest
-  one that does (no fetch, no write), so the store's traffic is the advancing
-  rows' states, once in and once out. Elsewhere it is ``state_update``, the
-  definition in plain ``jax.numpy`` over the layer's whole entry.
+  it. On a TPU it is the Pallas kernel ``ssm_state_update_kernel`` (ISSUE 63):
+  ONE program that walks the rows that advance (``walk_rows``, which
+  ``ops/kda.py``'s update shares), a visit moving about a megabyte of ONE
+  row's states (``visit_heads``: by the state's shape) into VMEM and back in
+  place by DMA while the next comes in and the one before goes back; a slot
+  that does not advance is never visited, so the store's traffic is the
+  advancing rows' states, once in and once out, and so is the launch's time.
+  Elsewhere it is ``state_update``, the definition in plain ``jax.numpy`` over
+  the layer's whole entry.
 - ``ssd_chunk``: the rows of a mixed step that hold MORE than one token, in
   the blocked form. The scalar decay lets a block of Q tokens be matrix
   products: with s_t the running sum of a inside the block,
@@ -72,7 +75,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from inference_gateway_tpu.utils.platform import on_tpu
 
-HEAD_BLOCK = 8   # heads a grid step holds: their states are 8 x P x N float32 of VMEM (256 KB at 64 x 128)
+HEAD_BLOCK = 8   # heads a grid step of the chunk kernel holds: their states are 8 x P x N float32 of VMEM (256 KB at 64 x 128)
 META = 8         # int32 words a visit: packed block, slot, lo, hi, first, last, fresh, init
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -92,85 +95,202 @@ def state_update(entry, dt, x, B, C, A, D, valid, fresh=None):
         return y, jnp.where(valid[:, None, None, None], new, entry)
 
 
-def _state_update_kernel(ord_ref, row_ref, flag_ref, decay_ref, x_ref, b_ref, c_ref, s_in, y_ref, s_out,
-                         *, hb: int, P: int, heads: int):
-    h, s = pl.program_id(0), pl.program_id(1)
-    own = row_ref[s] == s                      # else: a row that does not advance, riding the block of one that does
-    valid, fresh = flag_ref[s] % 2 == 1, flag_ref[s] // 2 == 1
-    N = s_in.shape[-1]
+VISIT_BYTES = 1 << 20   # the states one visit of a one-token update moves, each way: about a megabyte
+RING = 3                # visits in VMEM at once: one coming in, one being advanced, one going back
+TILE = 128              # rows of a visit advanced at a time: a square with the state's 128 lanes
 
-    @pl.when(own & valid)
-    def _():
-        state = s_in[...]                                                      # (hb * P, N)
-        prev = jnp.where(fresh, jnp.zeros_like(state), state)
-        # x as a COLUMN beside the state's rows: the row spread over 128 sublanes, turned, its first N lanes.
-        x_col = jnp.broadcast_to(x_ref[...], (128, hb * P)).T[:, :N]           # (hb * P, N): Delta x_p in every lane
-        decay = jnp.concatenate([jnp.full((P, N), decay_ref[s * heads + h * hb + i], jnp.float32) for i in range(hb)])
-        new = decay * prev + x_col * b_ref[...]
-        s_out[...] = new
-        y_col = jnp.sum(new * c_ref[...], axis=1, keepdims=True)               # (hb * P, 1)
-        y_ref[...] = jnp.broadcast_to(y_col, (hb * P, 128)).T[:1, :]           # back to a row
 
-    @pl.when(own & jnp.logical_not(valid))     # no row advances at all: the block is written back as it came
+def visit_heads(Hh: int, R: int, N: int) -> int:
+    """Heads a visit of a one-token update holds: the most that divide ``Hh``
+    whose states (R x N float32 a head) are VISIT_BYTES at most; one head where
+    a head's own state is more."""
+    return max([h for h in range(1, Hh + 1) if Hh % h == 0 and h * R * N * 4 <= VISIT_BYTES], default=1)
+
+
+def visit_plan(Hh: int, R: int, N: int, kernel: str, platform: str | None, off_the_tiles: str | None) -> dict:
+    """A one-token update's plan (``update_plan`` here and in ``ops/kda.py``):
+    the heads and bytes a visit moves each way, and ``path``: ``kernel`` on a
+    TPU (``platform`` None: this process's) unless the shape is
+    ``off_the_tiles``, else ``state_update``; ``reason`` says why."""
+    hb = visit_heads(Hh, R, N)
+    plan = {"path": "state_update", "visit_heads": hb, "visit_bytes": hb * R * N * 4, "visits_a_row": Hh // hb}
+    platform = platform or ("tpu" if on_tpu() else "cpu")
+    if platform != "tpu":
+        return {**plan, "reason": f"platform {platform} is not TPU"}
+    if off_the_tiles:
+        return {**plan, "reason": off_the_tiles}
+    return {**plan, "path": kernel,
+            "reason": f"{hb} of {Hh} heads a visit by the state's shape; the launch walks the rows that advance"}
+
+
+def _visit_tile(hb: int, P: int) -> int:
+    """Rows of a visit advanced at a time: TILE where it divides the visit, else the visit whole."""
+    return TILE if (hb * P) % TILE == 0 else hb * P
+
+
+def update_plan(shape, platform: str | None = None) -> dict:
+    """How ``update_store`` advances a store of ``shape`` (state layers, slots,
+    Hh, P, N): ``path`` is the kernel's name or ``state_update`` with the
+    ``reason``, a function of the static shape and the platform alone; and the
+    heads and bytes a visit moves each way. ``/debug/status`` reports it."""
+    _, _, Hh, P, N = shape
+    hb, off = visit_heads(Hh, P, N), None
+    if N != 128 or _visit_tile(hb, P) != TILE:
+        off = f"a state of {N} lanes, or a visit of {hb * P} rows, is off the ({TILE}, 128) squares"
+    elif TILE % P and P % TILE:
+        off = f"a head of {P} rows neither divides {TILE} rows nor is divided by them"
+    return visit_plan(Hh, P, N, "ssm_state_update_kernel", platform, off)
+
+
+def advancing_rows(valid):
+    """The slots that advance, in slot order, in front of the others, and how
+    many they are: what a one-token kernel walks (int32 (S,), (1,))."""
+    order = jnp.argsort(jnp.logical_not(valid), stable=True)
+    return order.astype(jnp.int32), jnp.sum(valid).astype(jnp.int32).reshape(1)
+
+
+def walk_rows(n_ref, rows_ref, ord_ref, store_in, store_out, ring, sem, *, visits: int, rows: int, advance):
+    """The loop the one-token kernels share (``ops/kda.py``'s too). Row
+    ``rows_ref[i]``, i < ``n_ref[0]``, of entry ``ord_ref[0]`` of the store (HBM:
+    state layers, slots, heads x a head's rows, lanes) is taken in ``visits``
+    pieces of ``rows`` rows: a piece comes into a place of ``ring`` by DMA,
+    ``advance(slot, piece, ref)`` rewrites it there, and it goes back, while the
+    next piece comes in and the one before goes back. A slot that does not
+    advance is never named: the launch's time follows the rows that do."""
+    total = n_ref[0] * visits
+
+    div, rem = jax.lax.div, jax.lax.rem        # (of counts: one traced equation each where // and % are a dozen)
+
+    def move(v, back: bool):
+        slot, place = rows_ref[div(v, visits)], rem(v, RING)
+        at = lambda ref: ref.at[ord_ref[0], slot, pl.ds(pl.multiple_of(rem(v, visits) * rows, 8), rows)]
+        if back:
+            return pltpu.make_async_copy(ring.at[place], at(store_out), sem.at[1, place])
+        return pltpu.make_async_copy(at(store_in), ring.at[place], sem.at[0, place])
+
+    @pl.when(total > 0)
     def _():
-        s_out[...] = s_in[...]
+        move(0, False).start()
+
+    def one(v, _):
+        @pl.when(v >= RING - 1)
+        def _():
+            move(v - (RING - 1), True).wait()      # the place the next piece takes is free again
+
+        @pl.when(v + 1 < total)
+        def _():
+            move(v + 1, False).start()
+
+        move(v, False).wait()
+        advance(rows_ref[div(v, visits)], rem(v, visits), ring.at[rem(v, RING)])
+        move(v, True).start()
+        return 0
+
+    jax.lax.fori_loop(0, total, one, 0)
+
+    def drain(v, _):
+        move(v, True).wait()
+        return 0
+
+    jax.lax.fori_loop(jnp.maximum(total - (RING - 1), 0), total, drain, 0)
+
+
+def staged(sources, places, sem):
+    """The slots' row operands from HBM into VMEM, all in flight together (the
+    kernels' own copies: an operand XLA has to hold in VMEM for a kernel counts
+    against the program's, and Kimi's decode program then does not fit)."""
+    loads = [pltpu.make_async_copy(src, dst, sem.at[i]) for i, (src, dst) in enumerate(zip(sources, places))]
+    for load in loads:
+        load.start()
+    for load in loads:
+        load.wait()
+
+
+def handed_back(place, result, sem):
+    """The slots' results from VMEM to HBM, once the walk is over."""
+    back = pltpu.make_async_copy(place, result, sem)
+    back.start()
+    back.wait()
+
+
+def _state_update_kernel(n_ref, rows_ref, ord_ref, fresh_ref, decay_ref, x_hbm, b_hbm, c_hbm, store_in, y_hbm, store_out,
+                         ring, sem, x_ref, b_ref, c_ref, y_ref, row_sem, *, hb: int, P: int, heads: int, tile: int):
+    tiles = hb * P // tile                     # a visit's tiles; x and y lie (slots, a row's tiles, tile)
+    y_ref[...] = jnp.zeros_like(y_ref)
+    staged((x_hbm, b_hbm, c_hbm), (x_ref, b_ref, c_ref), row_sem)
+
+    def advance(slot, piece, ref):
+        fresh = fresh_ref[slot] == 1
+        b, c = b_ref[pl.ds(slot, 1), :], c_ref[pl.ds(slot, 1), :]                          # (1, N)
+
+        def one(t, _):
+            at = piece * tiles + t                                                         # the tile among the row's
+            rows = pl.ds(pl.multiple_of(t * tile, tile), tile)
+            state = ref[rows, :]                                                           # (tile, N)
+            prev = jnp.where(fresh, jnp.zeros_like(state), state)
+            if P <= tile:
+                first = slot * heads + at * (tile // P)
+                decay = jnp.concatenate([jnp.full((P, ref.shape[1]), decay_ref[first + i], jnp.float32) for i in range(tile // P)])
+            else:
+                decay = decay_ref[slot * heads + jax.lax.div(at, P // tile)]
+            # Delta x of the tile's rows lies in the lanes: ONE turn beside the state's rows.
+            x_col = jnp.broadcast_to(x_ref[slot, pl.ds(at, 1), :], (ref.shape[1], tile)).T  # (tile, N)
+            new = decay * prev + x_col * b
+            ref[rows, :] = new
+            y_ref[slot, pl.ds(at, 1), :] = jnp.sum((new * c).T, axis=0, keepdims=True)     # the lane sums, as a row
+            return 0
+
+        jax.lax.fori_loop(0, tiles, one, 0, unroll=True)   # (the tiles' turns wait on nothing of one another's)
+
+    walk_rows(n_ref, rows_ref, ord_ref, store_in, store_out, ring, sem, visits=heads // hb, rows=hb * P, advance=advance)
+    handed_back(y_ref, y_hbm, row_sem.at[3])
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def state_update_tpu(store, ordinal, dt, x, B, C, A, D, valid, fresh, *, interpret: bool = False):
     """``state_update`` on the whole store by the Pallas kernel
-    ``ssm_state_update_kernel``: a grid of (head blocks, slots) whose visit
-    reads and writes ONE row's states of a head block, in place; a slot whose
-    row does not advance rides the block of the nearest one that does (the
-    same block index as the visit before: nothing is fetched and nothing
-    written for it), so the store's traffic is the LIVE rows' states, once in
-    and once out. Returns y (S, Hh, P), defined at the valid rows, and the store."""
+    ``ssm_state_update_kernel``: one program that walks the rows that advance
+    (``walk_rows``), a visit moving ``visit_heads`` heads' states of ONE row in
+    and back out by DMA, in place; a slot that does not advance costs nothing
+    and keeps its bits. The store's traffic is the advancing rows' states, once
+    in and once out; the slots' row operands (Delta x, B, C) and y stand in VMEM
+    whole, brought and handed back by the kernel's own copies. Returns y (S, Hh,
+    P), zero at the rows that do not advance, and the store."""
     L, S, Hh, P, N = store.shape
-    hb = HEAD_BLOCK if Hh % HEAD_BLOCK == 0 else Hh
+    hb = visit_heads(Hh, P, N)
+    tile = _visit_tile(hb, P)
+    if (tile % P and P % tile) or (hb * P) % 8:
+        raise ValueError(f"a head of {P} rows does not tile a visit of {hb * P} rows by {tile}")
     rows = store.reshape(L, S, Hh * P, N)
-    idx = jnp.arange(S)
-    before = jax.lax.cummax(jnp.where(valid, idx, -1))                          # the last advancing row at or before s
-    first = jnp.argmax(valid)                                                   # 0 where none advances
-    row_of = jnp.where(before >= 0, before, first).astype(jnp.int32)
-    row_of = jnp.where(jnp.any(valid), row_of, 0)
-    flags = valid.astype(jnp.int32) + 2 * fresh.astype(jnp.int32)
+    order, n = advancing_rows(valid)
     decay = jnp.exp(dt * A[None, :]).reshape(-1).astype(jnp.float32)            # (S * Hh,), read as scalars
-    xdt = (dt[:, :, None] * x).reshape(S, 1, Hh * P).astype(jnp.float32)
-    at = lambda k: (lambda h, s, o, r, f, d: k(h, s, o, r))
-    kernel = functools.partial(_state_update_kernel, hb=hb, P=P, heads=Hh)
+    xdt = (dt[:, :, None] * x).reshape(S, Hh * P // tile, tile).astype(jnp.float32)
+    smem, hbm = (pl.BlockSpec(memory_space=m) for m in (pltpu.SMEM, pltpu.ANY))
+    held = lambda *shape: pltpu.VMEM(shape, jnp.float32)
     y, rows = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(Hh // hb, S),
-            in_specs=[
-                pl.BlockSpec((None, 1, hb * P), at(lambda h, s, o, r: (r[s], 0, h))),             # Delta x, a row
-                pl.BlockSpec((None, 1, N), at(lambda h, s, o, r: (r[s], 0, 0))),                  # B
-                pl.BlockSpec((None, 1, N), at(lambda h, s, o, r: (r[s], 0, 0))),                  # C
-                pl.BlockSpec((None, None, hb * P, N), at(lambda h, s, o, r: (o[0], r[s], h, 0))),  # the states
-            ],
-            out_specs=[pl.BlockSpec((None, 1, hb * P), at(lambda h, s, o, r: (r[s], 0, h))),
-                       pl.BlockSpec((None, None, hb * P, N), at(lambda h, s, o, r: (o[0], r[s], h, 0)))],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((S, 1, Hh * P), jnp.float32), jax.ShapeDtypeStruct(rows.shape, rows.dtype)],
-        input_output_aliases={7: 1},   # the store: operand 7 counting the four scalar operands
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
+        functools.partial(_state_update_kernel, hb=hb, P=P, heads=Hh, tile=tile),
+        in_specs=[smem] * 5 + [hbm] * 4,
+        out_specs=[hbm, hbm],
+        scratch_shapes=[held(RING, hb * P, N), pltpu.SemaphoreType.DMA((2, RING)),
+                        held(*xdt.shape), held(S, N), held(S, N), held(*xdt.shape), pltpu.SemaphoreType.DMA((4,))],
+        out_shape=[jax.ShapeDtypeStruct(xdt.shape, jnp.float32), jax.ShapeDtypeStruct(rows.shape, rows.dtype)],
+        input_output_aliases={8: 1},   # the store
         interpret=interpret,
         name="ssm_state_update_kernel",
-    )(jnp.reshape(ordinal, (1,)).astype(jnp.int32), row_of, flags, decay,
-      xdt, B.reshape(S, 1, N).astype(jnp.float32), C.reshape(S, 1, N).astype(jnp.float32), rows)
+    )(n, order, jnp.reshape(ordinal, (1,)).astype(jnp.int32), fresh.astype(jnp.int32), decay,
+      xdt, B.astype(jnp.float32), C.astype(jnp.float32), rows)
     return y.reshape(S, Hh, P) + D[None, :, None] * x, rows.reshape(store.shape)
 
 
 def update_store(store, ordinal, dt, x, B, C, A, D, valid, fresh=None, kernel: bool | None = None):
     """``state_update`` on entry ``ordinal`` of the store (state layers, slots,
     Hh, P, N). Returns (y (S, Hh, P), defined at the valid rows; the store).
-    ``kernel`` None: the Pallas kernel on a TPU where the state's columns fill
-    the 128 lanes, plain ``jax.numpy`` elsewhere; tests force the kernel,
-    interpreted off the TPU."""
-    L, S, Hh, P, N = store.shape
+    ``kernel`` None: what ``update_plan`` says of the store's shape and the
+    platform (the Pallas kernel on a TPU where the state's columns fill the 128
+    lanes, plain ``jax.numpy`` elsewhere); tests force the kernel, interpreted
+    off the TPU."""
     if kernel is None:
-        kernel = on_tpu() and N == 128 and (Hh % HEAD_BLOCK == 0) and (HEAD_BLOCK * P) % 128 == 0
+        kernel = update_plan(store.shape)["path"] != "state_update"
     if kernel:
         fresh = jnp.zeros_like(valid) if fresh is None else fresh
         with jax.named_scope("ssm.scan"):

@@ -1118,8 +1118,8 @@ def test_granite4_step_programs_compile_for_v5e_at_the_published_widths(topo, mo
     (``ssd_chunk_kernel``: a head's rows a sublane slice, the store read and
     written by DMA at a dynamic layer and slot) in the mixed step's two runs of
     Mamba layers and in no decode step; the one-token update's kernel
-    (``ssm_state_update_kernel``: a row turned into a column by a transpose, a
-    slot that does not advance riding its neighbour's block) in both programs,
+    (``ssm_state_update_kernel``: a walk of the rows that advance, a megabyte of
+    a row's states a visit by DMA, ISSUE 63) in both programs,
     and no fusion that touches the store;
     the expert kernels at 72 experts of 768; and holds NO copy of the 1.2 GB state
     store (the runs of one kind are scanned apart, so no ``cond`` stands around
@@ -1416,3 +1416,52 @@ def test_evabyte_step_programs_compile_for_v5e_at_the_published_widths(topo, mon
     assert not re.findall(rf"= bf16\[(?:{32 * 105 * page}|32,105,{page}),4096\]\S* copy\(", text), "the pool is copied"
     assert re.search(r"kv\.write/while/body/while/body", text), "the fold stands in front of the layers"
     assert not [c for c in _weight_copies(text) if "params__" not in c], "a weight is copied"
+
+
+# ISSUE 63: the one-token state updates alone, as the two cells' step programs hand them over (Granite's and
+# Kimi's stores at 32 slots) and at ROADMAP W9's 64 slots, which no cell runs yet: a visit of a megabyte by the
+# store's shape (32 of 128 heads of 64 x 128; 16 of 32 heads of 128 x 128), three of them in VMEM beside the
+# slots' row operands, the store in HBM and aliased through the call. The ceiling is the traced equations of a
+# kernel's body (122 and 126 as written, 111 and 153 before: the operands' copies, the walk's loop, one tile's or one head's
+# arithmetic; counts are divided and taken modulo by ``lax.div`` / ``lax.rem``, one equation where ``//`` is a dozen).
+STATE_UPDATES = {"granite": ("ssd", (9, 32, 128, 64, 128)), "granite_64_slots": ("ssd", (9, 64, 128, 64, 128)),
+                 "kimi": ("kda", (6, 32, 32, 128, 128)), "kimi_64_slots": ("kda", (6, 64, 32, 128, 128))}
+STATE_UPDATE_EQUATIONS = 130
+
+
+def _state_update(sds, which, shape):
+    from inference_gateway_tpu.ops import kda, ssd
+
+    L, S, Hh, R, N = shape
+    f32 = lambda *dims: sds(dims, jnp.float32)
+    flags = (sds((S,), jnp.bool_), sds((S,), jnp.bool_))
+    if which == "ssd":
+        return ssd.state_update_tpu, (f32(*shape), sds((), I32), f32(S, Hh), f32(S, Hh, R), f32(S, N), f32(S, N),
+                                      f32(Hh), f32(Hh), *flags)
+    return kda.state_update_tpu, (f32(*shape), sds((), I32), *[f32(S, Hh, R)] * 4, f32(S, Hh), *flags)
+
+
+@pytest.mark.parametrize("case", list(STATE_UPDATES))
+def test_state_update_kernels_compile_for_v5e_with_no_copy_of_the_store(one_chip, case):
+    """One kernel a launch under the name the benchmark's readers find it by, the
+    store aliased through it: no copy of the store and no temporary of its size."""
+    which, shape = STATE_UPDATES[case]
+    fn, shapes = _state_update(one_chip, which, shape)
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(*shapes).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert re.search(rf"%{'ssm' if which == 'ssd' else 'kda'}_state_update_kernel[\w.\-]* = ", text)
+    L, S, Hh, R, N = shape
+    assert not re.findall(rf"= f32\[{L},{S},(?:{Hh},{R}|{Hh * R}),{N}\]\S* (?:copy|fusion)\(", text), "the store is copied"
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("case", list(STATE_UPDATES))
+def test_state_update_kernels_stay_inside_their_start_up_budget(case):
+    """No clock: the traced equations of the kernel's body and of what stands around it."""
+    which, shape = STATE_UPDATES[case]
+    fn, shapes = _state_update(jax.ShapeDtypeStruct, which, shape)
+    jaxpr = jax.make_jaxpr(fn)(*shapes).jaxpr
+    (body,) = _kernel_bodies(jaxpr, ("ssm" if which == "ssd" else "kda") + "_state_update_kernel")
+    assert _equations(body) <= STATE_UPDATE_EQUATIONS
+    assert _equations(jaxpr) - _equations(body) <= 40

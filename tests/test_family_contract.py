@@ -4,7 +4,9 @@ parent commit (25f1e56), where ``serving/engine.py`` held it as nine refusal
 blocks, eight stats tuples and five status writers: for every preset the type and
 text of each refusal, the attention layers by window, and for every tiny preset
 the family blocks of ``/debug/status`` (as ``serving/server.py`` merged them) and
-the facts of a paged engine. The record is held to that capture, to the letter.
+the facts of a paged engine. The record is held to that capture, to the letter
+(ISSUE 63 added ``one_token_update`` to the ``ssd`` and ``kda`` blocks, the
+one-token kernels' plan for the engine's store; the capture holds it since).
 """
 
 from __future__ import annotations

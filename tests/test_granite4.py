@@ -234,8 +234,9 @@ def test_the_one_token_update_is_one_step_of_the_recurrence(advancing, kernel):
     """``update_store`` on entry 1 of a store of random states: a row that
     advances holds the recurrence's next state (from zero where it is fresh) and
     reads y from it; every other row, and the other entry, stay as they were to
-    the bit. The kernel's slots that do not advance ride the block of one that
-    does: leading, trailing and no advancing rows at all."""
+    the bit. The kernel walks the rows that advance alone: leading, trailing
+    and no advancing rows at all (``tests/test_state_update_kernels.py`` has the
+    other patterns and shapes)."""
     rng = np.random.default_rng(1)
     N = 128 if kernel else 16        # the kernel turns a row into a column over the 128 lanes
     store = rng.normal(size=(LAYERS, SLOTS, HH, 16, N)).astype(np.float32)

@@ -287,7 +287,7 @@ def test_the_one_token_update_is_one_step_of_the_recurrence(advancing, kernel):
     """``update_store`` on entry 1 of a store of random states: a row that
     advances holds the recurrence's next state (from zero where it is fresh) and
     reads o from it; every other row, and the other entry, stay as they were to
-    the bit. The kernel's slots that do not advance ride the block of one that does."""
+    the bit. The kernel walks the rows that advance alone."""
     q, k, v, g, beta = (a[:SLOTS] for a in operands(SLOTS, 2, 3.0))
     store = np.random.default_rng(3).normal(size=(LAYERS, SLOTS, HH, D, D)).astype(np.float32)
     valid, fresh = np.asarray(advancing, bool), np.asarray([0, 1, 0, 0, 1], bool)
