@@ -139,6 +139,15 @@ _SERVING_RAGGED_DOC = [
     "counted. No variable and no flag turns any of it on or off",
     "([docs/observability.md](docs/observability.md), `eva`).",
     "",
+    "A Jamba preset (`jamba2-3b`, ISSUE 64) keeps 10.1 MB of float32 state a slot",
+    "(26 Mamba-1 mixers) beside a page pool of its TWO attention layers (1,024 B a",
+    "cached token): `SERVING_MAX_SLOTS` sizes the store, `SERVING_MAX_SEQ_LEN` the",
+    "pool alone. It is admitted through the mixed step whatever",
+    "`SERVING_MIXED_STEP` says; a prompt chunk of `SERVING_MIXED_STEP_TOKENS` less",
+    "the slots goes through the selective scan's chunk kernel and prefix hits are",
+    "declined and counted. No variable and no flag turns any of it on or off",
+    "([docs/observability.md](docs/observability.md), `ssm_scan`).",
+    "",
 ]
 
 

@@ -2,8 +2,8 @@
 
 ``serving/engine.py`` (``Engine.__init__``, ``choose_mesh``, ``build_params``)
 and the sidecar's ``/debug/status`` read one record, ``family_of(model_cfg)``;
-``models/llama.py``, ``models/mixtral.py`` and ``models/phi4flash.py`` each
-define ``family(cfg)``, which fills it from the fields of its own config. A new
+``models/llama.py``, ``models/mixtral.py``, ``models/phi4flash.py`` and
+``models/jamba.py`` each define ``family(cfg)``, which fills it from the fields of its own config. A new
 family changes its model module and nothing under ``serving/`` (ISSUE 58).
 """
 
@@ -80,9 +80,10 @@ def family_of(model_cfg) -> Family:
     return sys.modules[type(model_cfg).__module__].family(model_cfg)
 
 
-# Presets of models/phi4flash.py, by name: the module is imported when one
-# of them is asked for, and not on the way to any other model.
+# Presets of models/phi4flash.py and models/jamba.py, by name: a module is
+# imported when one of its presets is asked for, and not on the way to any other model.
 PHI4FLASH_PRESETS = ("phi-4-mini-flash", "phi4flash-test-tiny")
+JAMBA_PRESETS = ("jamba2-3b", "jamba-test-tiny")
 
 
 def preset_config(name: str):
@@ -93,4 +94,8 @@ def preset_config(name: str):
         from inference_gateway_tpu.models import phi4flash
 
         return phi4flash.PRESETS[name]
+    if name in JAMBA_PRESETS:
+        from inference_gateway_tpu.models import jamba
+
+        return jamba.PRESETS[name]
     return llama.PRESETS.get(name) or mixtral.PRESETS.get(name)

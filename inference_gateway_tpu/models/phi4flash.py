@@ -16,7 +16,7 @@ holds this file to.
 What it asks of serving, and where that lives:
 
 - **A state beside the page pool.** Each Mamba layer keeps, for every
-  slot, S (Di x N float32) and the convolution's last K - 1 inputs. They
+  slot, S (N x Di float32, the channels in the lanes) and the convolution's last K - 1 inputs. They
   ride the cache pytree (``ssm``, ``conv``), so both step programs carry
   them as they carry the pages (donated, and in the decode chunk's loop
   carry). A segment that starts at position 0 starts from zero
@@ -231,14 +231,15 @@ def init_params(rng: jax.Array, cfg: Phi4FlashConfig, dtype=jnp.bfloat16, quant=
 def init_paged_cache(cfg: Phi4FlashConfig, cache_cfg, dtype=jnp.bfloat16) -> Params:
     """Pages for the ``cache_layers`` layers that write them, k/v
     (Lc, num_pages, page_size, Hkv*D), and the per-slot state store beside
-    them: ``ssm`` (Lm, slots, Di, N) and ``conv`` (Lm, slots, K-1, Di), float32."""
+    them: ``ssm`` (Lm, slots, N, Di) and ``conv`` (Lm, slots, K-1, Di), float32
+    (a state's channels in the lanes: ops/ssm.py)."""
     pages = cache_cfg.resolve_num_pages()
     shape = (cfg.cache_layers, pages, cache_cfg.page_size, cfg.num_kv_heads * cfg.hd)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype), **init_state(cfg, cache_cfg.max_slots)}
 
 
 def init_state(cfg: Phi4FlashConfig, slots: int) -> Params:
-    return {"ssm": jnp.zeros((cfg.state_layers, slots, cfg.d_inner, cfg.mamba_d_state), jnp.float32),
+    return {"ssm": jnp.zeros((cfg.state_layers, slots, cfg.mamba_d_state, cfg.d_inner), jnp.float32),
             "conv": jnp.zeros((cfg.state_layers, slots, cfg.mamba_d_conv - 1, cfg.d_inner), jnp.float32)}
 
 
@@ -306,18 +307,9 @@ def diff_combine(o, lam, gain, layer, eps=1e-5):
     return (a * (1.0 - linit)).reshape(T, Hq // 2 * D2)
 
 
-def _mamba(h, lp, states, tails, cfg: Phi4FlashConfig, conv, scan):
-    """The mixer on rows h (T, H). ``conv(u, tails, w, b)`` and
-    ``scan(states, delta, c, B, C, A, D)`` are the step's form of the two
-    state operations. Returns (out (T, H), y (T, Di) float32, states, tails)."""
-    R, N = cfg.mamba_dt_rank, cfg.mamba_d_state
-    u, z = jnp.split(qmatmul(h, lp["m_in"]), 2, axis=-1)
-    c, tails = conv(u, tails, lp["m_conv_w"], lp["m_conv_b"])
-    d, B, C = jnp.split(qmatmul(c.astype(h.dtype), lp["m_x"]).astype(jnp.float32), [R, R + N], axis=-1)
-    delta = jax.nn.softplus(qmatmul(d.astype(h.dtype), lp["m_dt"]).astype(jnp.float32) + lp["m_dt_b"])
-    y, states = scan(states, delta, c, B, C, -jnp.exp(lp["m_A_log"]), lp["m_D"])
-    out = qmatmul((y * jax.nn.silu(z.astype(jnp.float32))).astype(h.dtype), lp["mix_out"])
-    return out, y, states, tails
+# The mixer's leaves under the names ``ops/ssm.mamba_mixer`` reads (no inner norms here).
+MIXER_NAMES = {"in_proj": "m_in", "conv_w": "m_conv_w", "conv_b": "m_conv_b", "x_proj": "m_x", "dt_proj": "m_dt",
+               "dt_bias": "m_dt_b", "A_log": "m_A_log", "D": "m_D", "out_proj": "mix_out"}
 
 
 def _take(leaves: Params, names, index):
@@ -354,12 +346,10 @@ def _decoder(params: Params, cfg: Phi4FlashConfig, x, cache: Params, write_idx, 
         with jax.named_scope("mamba"):  # (the pair's leaves are taken under its first block's name)
             lp = {**_take(layers, TAKEN, k), **_take(layers, MAMBA_LEAVES, k)}
             n_even, n_odd = lp["norms"][0], lp["norms"][1]
-            out, y, s_new, t_new = _mamba(
-                layer_norm(x, n_even[0], n_even[1], eps), lp,
-                jax.lax.dynamic_index_in_dim(sstates, k, 0, keepdims=False),
-                jax.lax.dynamic_index_in_dim(ctails, k, 0, keepdims=False), cfg, conv, scan)
-            sstates = jax.lax.dynamic_update_index_in_dim(sstates, s_new, k, 0)
-            ctails = jax.lax.dynamic_update_index_in_dim(ctails, t_new, k, 0)
+            out, y, store = ssm.mamba_mixer(
+                layer_norm(x, n_even[0], n_even[1], eps), {n: lp[m] for n, m in MIXER_NAMES.items()},
+                {"ssm": sstates, "conv": ctails}, k, conv, scan)
+            sstates, ctails = store["ssm"], store["conv"]
             x = x + out
         x = _mlp(x, n_even, layers, k, 0, eps)
         with jax.named_scope("diff_attn"):
@@ -456,7 +446,7 @@ def forward_paged(params: Params, cfg: Phi4FlashConfig, tokens, positions, lengt
     x, cache = _decoder(
         params, cfg, x, cache, write_idx[:, 0], page_table,
         conv=lambda u, tails, w, b: ssm.conv_decode(u, tails, w, b, valid),
-        scan=lambda states, *a: ssm.scan_decode(states, *a, valid),
+        scan=ssm.decode_scan(valid),
         attend_self=attend, rows=lambda x, m: (x, m), lengths=lengths)
     logits = _head(params, cfg, x)
     if not with_aux:
@@ -478,10 +468,8 @@ def forward_ragged(params: Params, cfg: Phi4FlashConfig, tokens, positions, cach
 
     assert mesh is None, "phi4flash: no mesh carries the Mamba state yet"
     T = tokens.shape[1]
-    with jax.named_scope("carry"):  # the rows' segments, once a step for every layer
-        seg = ssm.token_segments(q_starts, q_lens, T)
-        fresh = (kv_lens - q_lens) == 0
-        n_tokens = jnp.sum(q_lens)
+    with jax.named_scope("carry"):  # the rows' segments and the chunk kernel's plan, once a step for every layer
+        scan, seg, fresh, _ = ssm.ragged_scan(q_starts, q_lens, kv_lens, T)
         last = jnp.clip(q_starts + q_lens - 1, 0, T - 1)
         live_lens = jnp.where(q_lens > 0, kv_lens, 0)
 
@@ -497,7 +485,7 @@ def forward_ragged(params: Params, cfg: Phi4FlashConfig, tokens, positions, cach
     x, cache = _decoder(
         params, cfg, x, cache, write_idx[0], page_table,
         conv=lambda u, tails, w, b: ssm.conv_ragged(u, tails, w, b, seg, fresh, q_starts, q_lens),
-        scan=lambda states, *a: ssm.scan_ragged(states, *a, seg, positions[0], n_tokens),
+        scan=scan,
         attend_self=attend, rows=lambda x, m: (x[last], m[last]), lengths=live_lens)
     logits = _head(params, cfg, x)
     if not with_aux:

@@ -540,19 +540,24 @@ def test_ragged_kernel_compiles_at_ten_kv_heads_for_v5e(one_chip, window):
         one_chip((PHI["slots"], MAX_PAGES), I32), row, row, row)
 
 
-@pytest.mark.parametrize("form", ["decode", "ragged"])
-def test_state_operations_compile_for_v5e_at_the_published_widths(one_chip, form):
+@pytest.mark.parametrize("form", ["decode", "ragged", "definition"])
+def test_state_operations_compile_for_v5e_at_the_published_widths(one_chip, as_on_tpu, form):
     """The convolution and the selective scan of one Mamba layer (ops/ssm.py) at
-    64 slots x 5,120 x 16 float32: the batched decode update, and the mixed
-    step's token loop over 576 packed tokens. Plain XLA: a pass says the chip's
-    compiler takes them and holds the store once (no second copy of it)."""
+    64 slots x (16, 5,120) float32, the channels in the lanes: the batched decode
+    update; the mixed step's form over 576 packed tokens (ISSUE 64: the chunk
+    kernel ``ssm_scan_chunk_kernel`` for the rows of more than one token beside the
+    one-token update, the store whole and in place); and the token loop that is
+    their definition. A pass says the chip's compiler takes them and holds the
+    store once (no second copy of it)."""
     from inference_gateway_tpu.ops import ssm
 
     S, T, Di, N, K = PHI["slots"], PHI["budget"], PHI["di"], PHI["n"], PHI["k"]
     F32 = jnp.float32
-    states, tails = one_chip((S, Di, N), F32), one_chip((S, K - 1, Di), F32)
-    w, b, A, D = one_chip((K, Di), F32), one_chip((Di,), F32), one_chip((Di, N), F32), one_chip((Di,), F32)
+    states, tails = one_chip((S, N, Di), F32), one_chip((S, K - 1, Di), F32)
+    w, b, A, D = one_chip((K, Di), F32), one_chip((Di,), F32), one_chip((N, Di), F32), one_chip((Di,), F32)
     row = one_chip((S,), I32)
+    packed = (one_chip((T, Di), BF16), tails, w, b, states, one_chip((T, Di), F32), one_chip((T, N), F32),
+              one_chip((T, N), F32), A, D, row, row, row, one_chip((T,), I32))
     if form == "decode":
         def step(u, tails, w, b, states, delta, B, C, A, D, valid):
             c, tails = ssm.conv_decode(u, tails, w, b, valid)
@@ -560,17 +565,83 @@ def test_state_operations_compile_for_v5e_at_the_published_widths(one_chip, form
 
         args = (one_chip((S, Di), BF16), tails, w, b, states, one_chip((S, Di), F32), one_chip((S, N), F32),
                 one_chip((S, N), F32), A, D, one_chip((S,), jnp.bool_))
+    elif form == "ragged":
+        def step(u, tails, w, b, states, delta, B, C, A, D, starts, lens, kv, positions):
+            scan, seg, fresh, _ = ssm.ragged_scan(starts, lens, kv, T)
+            c, tails = ssm.conv_ragged(u, tails, w, b, seg, fresh, starts, lens)
+            y, store = scan(states[None], 0, delta, c, B, C, A, D)
+            return (y, store[0]), tails
+
+        args = packed
     else:
         def step(u, tails, w, b, states, delta, B, C, A, D, starts, lens, kv, positions):
             seg = ssm.token_segments(starts, lens, T)
             c, tails = ssm.conv_ragged(u, tails, w, b, seg, (kv - lens) == 0, starts, lens)
             return ssm.scan_ragged(states, delta, c, B, C, A, D, seg, positions, jnp.sum(lens)), tails
 
-        args = (one_chip((T, Di), BF16), tails, w, b, states, one_chip((T, Di), F32), one_chip((T, N), F32),
-                one_chip((T, N), F32), A, D, row, row, row, one_chip((T,), I32))
+        args = packed
     compiled = jax.jit(step, donate_argnums=(1, 4)).lower(*args).compile()
     store = S * Di * N * 4
     assert compiled.memory_analysis().temp_size_in_bytes < store + (64 << 20)
+    assert ("ssm_scan_chunk_kernel" in compiled.as_text()) == (form == "ragged")
+
+
+# ISSUE 64: Jamba2-3B's shapes. The chunk kernel alone at a mixed step of 544 tokens over a store of 26 layers x 32
+# slots x (16, 5,120); both paged kernels at 20 query heads over ONE KV head of 128, 100 pages of 128 a row.
+JAMBA = dict(slots=32, budget=544, di=5120, n=16, layers=26, hq=20, hkv=1, d=128, pages=100)
+
+
+def test_selective_scan_chunk_kernel_compiles_for_v5e_at_jamba2_widths(one_chip):
+    from inference_gateway_tpu.ops import ssd, ssm
+
+    S, T, Di, N, L = (JAMBA[k] for k in ("slots", "budget", "di", "n", "layers"))
+    F32 = jnp.float32
+    visits = ssd.max_visits(T, S, ssm.CHUNK_TOKENS)
+    compiled = jax.jit(
+        lambda store, o, d, c, B, C, A, D, meta: ssm.scan_chunk_tpu(store, o, d, c, B, C, A, D, meta, interpret=False),
+        donate_argnums=(0,)).lower(
+        one_chip((L, S, N, Di), F32), one_chip((), I32), one_chip((T, Di), F32), one_chip((T, Di), F32),
+        one_chip((T, N), F32), one_chip((T, N), F32), one_chip((N, Di), F32), one_chip((Di,), F32),
+        one_chip((visits, ssd.META), I32)).compile()
+    text = compiled.as_text()
+    assert "ssm_scan_chunk_kernel" in text
+    assert not re.findall(rf"= f32\[{L},{S},{N},{Di}\]\S* copy\(", text), "the state store is copied"
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20     # B's and C's columns and the padded operands, no store
+
+
+@pytest.mark.parametrize("slots,layers", [(JAMBA["slots"], JAMBA["layers"]), (PHI["slots"], 9)], ids=["jamba2", "phi4flash"])
+def test_selective_scan_one_token_kernel_compiles_for_v5e(one_chip, slots, layers):
+    """``ssm_scan_decode_kernel`` at both Mamba-1 models' stores: the walk of the rows that advance over (16, 5,120)
+    float32 states, the store in place (no copy of it, no second one among the temporaries)."""
+    from inference_gateway_tpu.ops import ssm
+
+    Di, N, F32 = JAMBA["di"], JAMBA["n"], jnp.float32
+    row = one_chip((slots,), jnp.bool_)
+    compiled = jax.jit(
+        lambda store, o, d, c, B, C, A, D, valid, fresh: ssm.scan_decode_tpu(store, o, d, c, B, C, A, D, valid, fresh),
+        donate_argnums=(0,)).lower(
+        one_chip((layers, slots, N, Di), F32), one_chip((), I32), one_chip((slots, Di), F32), one_chip((slots, Di), F32),
+        one_chip((slots, N), F32), one_chip((slots, N), F32), one_chip((N, Di), F32), one_chip((Di,), F32), row, row).compile()
+    text = compiled.as_text()
+    assert "ssm_scan_decode_kernel" in text
+    assert not re.findall(rf"= f32\[{layers},{slots},{N},{Di}\]\S* copy\(", text), "the state store is copied"
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+@pytest.mark.parametrize("kernel", ["decode", "ragged"])
+def test_paged_kernels_compile_at_twenty_queries_over_one_kv_head_for_v5e(one_chip, kernel):
+    """The first group wider than a tile's 8 sublanes and not a multiple of them, the first pool of ONE KV
+    head: no pad of the group is needed (on the chip both agree with the gather path: PERF.md section 6, PR 64)."""
+    S, T, Hq, Hkv, Dh, maxp = (JAMBA[k] for k in ("slots", "budget", "hq", "hkv", "d", "pages"))
+    pool = one_chip((2 * S * maxp, PAGE, Hkv * Dh), BF16)
+    row, table = one_chip((S,), I32), one_chip((S, maxp), I32)
+    if kernel == "decode":
+        _compiles_to_a_kernel(lambda q, k, v, t, l: paged_attention_tpu(q, k, v, t, l, Hkv, interpret=False),
+                              one_chip((S, Hq, Dh), BF16), pool, pool, table, row)
+    else:
+        _compiles_to_a_kernel(
+            lambda q, k, v, t, s, l, kv: ragged_paged_attention_tpu(q, k, v, t, s, l, kv, Hkv, interpret=False),
+            one_chip((T, Hq, Dh), BF16), pool, pool, table, row, row, row)
 
 
 # ISSUE 53: the packed convolution alone at the three state cells' mixed shapes (packed tokens, channels, slots,
@@ -685,7 +756,7 @@ STEP_PROGRAMS = {
     ("llama", "qwen2-test-tiny"): ((2, 0, 203), (1, 0, 152)),
     ("mixtral", "mixtral-test-tiny"): ((2, 0, 391), (1, 0, 334)),
     ("mixtral", "smallthinker-test-tiny"): ((2, 0, 426), (1, 0, 369)),
-    ("phi4flash", "phi4flash-test-tiny"): ((3, 0, 1140), (2, 0, 779)),
+    ("phi4flash", "phi4flash-test-tiny"): ((3, 0, 1490), (2, 0, 782)),   # (ISSUE 64: the mixed step holds the chunk form, its twin here, for the loop)
     ("mixtral", "keye-test-tiny"): ((1, 3, 960), (1, 1, 580)),
     ("mixtral", "trinity-test-tiny"): ((4, 0, 701), (2, 0, 594)),
     ("mixtral", "lfm2-test-tiny"): ((2, 2, 919), (1, 2, 579)),
@@ -700,7 +771,9 @@ STEP_PROGRAMS = {
 }
 # A mixed step's scatters beyond the pools' writes: the convolution's heads, one a traced state-layer body (ISSUE
 # 53); Granite's two runs of Mamba layers also place their one-token rows' results and update the store at them.
-STATE_SCATTERS = {"phi4flash-test-tiny": 1, "lfm2-test-tiny": 2, "granite4-test-tiny": 6}
+# (ISSUE 64) the hybrid's one traced pair also places its one-token rows' results and, off the TPU, the chunk
+# form's twin puts a row's last state back.
+STATE_SCATTERS = {"phi4flash-test-tiny": 3, "lfm2-test-tiny": 2, "granite4-test-tiny": 6}
 
 
 def _census(jaxpr, out):
@@ -1465,3 +1538,61 @@ def test_state_update_kernels_stay_inside_their_start_up_budget(case):
     (body,) = _kernel_bodies(jaxpr, ("ssm" if which == "ssd" else "kda") + "_state_update_kernel")
     assert _equations(body) <= STATE_UPDATE_EQUATIONS
     assert _equations(jaxpr) - _equations(body) <= 40
+
+
+@pytest.mark.parametrize("form", ["mixed", "decode"])
+def test_jamba2_step_programs_compile_for_v5e_at_the_published_widths(topo, monkeypatch, as_on_tpu, form):
+    """AI21-Jamba2-3B whole (ISSUE 64: 28 layers, 26 Mamba-1 mixers of 5,120
+    channels x 16 beside two NoPE layers of 20 query heads over ONE KV head, a dense
+    MLP of 8,192 in every layer, a 65,536-row tied head) as its cell serves it: 32
+    slots of 100 pages of 128, a mixed step of 544 packed tokens and a decode step
+    of 32 rows, int8 weights. A pass says the chip's compiler takes both step
+    programs: the chunk kernel in the mixed step's three runs of Mamba layers and
+    in no decode step, NO loop over one state a token, NO copy of the 0.3 GB store
+    nor of the pool, and no int8 result that is not a parameter (the MLP's
+    matrices are read at the layer's index, not sliced by a run's scan)."""
+    from inference_gateway_tpu.models import jamba
+    from inference_gateway_tpu.ops.quant import init_quantized_llama_params
+    from inference_gateway_tpu.serving.kv_cache import PagedCacheConfig
+
+    monkeypatch.setattr("inference_gateway_tpu.ops.paged_attention.device_platform", lambda: "tpu")
+    cfg = jamba.PRESETS["jamba2-3b"]
+    slots, page, seq, T = 32, 128, 12800, 544
+    place = lambda tree: jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=SingleDeviceSharding(topo.devices[0])), tree)
+    params = place(jax.eval_shape(lambda: init_quantized_llama_params(jax.random.PRNGKey(0), cfg)))
+    cache = place(jax.eval_shape(lambda: jamba.init_paged_cache(cfg, PagedCacheConfig(page, 0, slots, seq))))
+    assert {n: a.shape for n, a in cache.items()} == {
+        "k": (2, slots * 100, page, 128), "v": (2, slots * 100, page, 128),
+        "conv": (26, slots, 3, 5120), "ssm": (26, slots, 16, 5120)}
+    assert sum(a.size * a.dtype.itemsize for a in (cache["ssm"], cache["conv"])) == slots * cfg.state_bytes_per_slot == 323_747_840
+    sds = lambda shape: place(jax.ShapeDtypeStruct(shape, I32))
+    row, table = sds((slots,)), sds((slots, seq // page))
+    if form == "mixed":
+        fn = lambda p, c, tok, pos, w, table, qs, ql, kl: jamba.forward_ragged(
+            p, cfg, tok, pos, c, w, table, qs, ql, kl, with_aux=True)
+        args = (params, cache, sds((1, T)), sds((1, T)), sds((1, T)), table, row, row, row)
+    else:
+        def fn(p, c, tok, pos, table, done):   # as the engine's chunk runs it: the cache in a while_loop's carry
+            def step(carry):
+                i, c, tok, pos = carry
+                w = jnp.take_along_axis(table, (pos // page)[:, None], axis=1)[:, 0] * page + pos % page
+                logits, c, _ = jamba.forward_paged(p, cfg, tok[:, None], pos[:, None], jnp.where(done, 0, pos + 1),
+                                                   c, w[:, None], table, mode="decode", valid=~done, with_aux=True)
+                return i + 1, c, jnp.argmax(logits, -1).astype(I32), pos + 1
+
+            return jax.lax.while_loop(lambda carry: carry[0] < 8, step, (jnp.int32(0), c, tok, pos))[1:]
+
+        args = (params, cache, row, row, table, place(jax.ShapeDtypeStruct((slots,), jnp.bool_)))
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    scans = set(re.findall(r"%ssm_scan_chunk_kernel[\w.\-]* = ", text))
+    assert len(scans) == (3 if form == "mixed" else 0), scans          # one a run of Mamba layers; none in a decode step
+    assert len(set(re.findall(r"%ssm_scan_decode_kernel[\w.\-]* = ", text))) == 3   # the one-token rows, in both programs
+    store = 26 * slots * 16 * 5120 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < store
+    assert not re.findall(r"= f32\[26,32,16,5120\]\S* copy\(", text), "the state store is copied"
+    assert not re.findall(rf"= bf16\[{2 * slots * 100 * page},128\]\S* copy\(", text), "the pool is copied"
+    # No loop over one state a token: no result of a state's shape is written into an entry of the store in a while body
+    assert not re.findall(r"= f32\[32,16,5120\]\S* dynamic-update-slice\(", text), "a state a token: a loop over tokens"
+    assert not _weight_copies(text), _weight_copies(text)[:3]

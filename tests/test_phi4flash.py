@@ -406,8 +406,8 @@ def test_the_ragged_state_operations_equal_a_token_by_token_loop(seed):
     for s in range(S):
         positions[starts[s]:starts[s] + lens[s]] = base[s] + np.arange(lens[s])
     arr = lambda *shape: jnp.asarray(rng.normal(size=shape).astype(np.float32))
-    u, w, b, tails, states = arr(T, Di), arr(K, Di), arr(Di), arr(S, K - 1, Di), arr(S, Di, N)
-    delta, B, C, A, D = jnp.abs(arr(T, Di)) * 0.1, arr(T, N), arr(T, N), -jnp.abs(arr(Di, N)), arr(Di)
+    u, w, b, tails, states = arr(T, Di), arr(K, Di), arr(Di), arr(S, K - 1, Di), arr(S, N, Di)   # a state's channels in the lanes
+    delta, B, C, A, D = jnp.abs(arr(T, Di)) * 0.1, arr(T, N), arr(T, N), -jnp.abs(arr(N, Di)), arr(Di)
     seg = ssm.token_segments(jnp.asarray(starts), jnp.asarray(lens), T)
     kv = jnp.asarray(base + lens)
     c, new_tails = ssm.conv_ragged(u, tails, w, b, seg, (kv - jnp.asarray(lens)) == 0, jnp.asarray(starts),
@@ -416,15 +416,15 @@ def test_the_ragged_state_operations_equal_a_token_by_token_loop(seed):
 
     for s in range(S):
         tail = np.zeros((K - 1, Di), np.float32) if fresh[s] else np.asarray(tails[s])
-        state = np.zeros((Di, N), np.float32) if fresh[s] else np.asarray(states[s])
+        state = np.zeros((N, Di), np.float32) if fresh[s] else np.asarray(states[s])
         for t in range(starts[s], starts[s] + lens[s]):
             window = np.concatenate([tail, np.asarray(u[t])[None]])
             ct = np.asarray(jax.nn.silu(jnp.asarray(np.asarray(b) + (window * np.asarray(w)).sum(0))))
             tail = window[1:]
             np.testing.assert_allclose(np.asarray(c[t]), ct, atol=1e-5)
-            state = np.exp(np.asarray(delta[t])[:, None] * np.asarray(A)) * state \
-                + (np.asarray(delta[t]) * ct)[:, None] * np.asarray(B[t])[None, :]
-            np.testing.assert_allclose(np.asarray(y[t]), state @ np.asarray(C[t]) + np.asarray(D) * ct, atol=1e-4)
+            state = np.exp(np.asarray(delta[t])[None, :] * np.asarray(A)) * state \
+                + (np.asarray(delta[t]) * ct)[None, :] * np.asarray(B[t])[:, None]
+            np.testing.assert_allclose(np.asarray(y[t]), np.asarray(C[t]) @ state + np.asarray(D) * ct, atol=1e-4)
         if lens[s]:
             np.testing.assert_allclose(np.asarray(new_tails[s]), tail, atol=1e-6)
             np.testing.assert_allclose(np.asarray(new_states[s]), state, atol=1e-4)

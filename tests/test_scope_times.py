@@ -243,7 +243,8 @@ def test_the_pool_write_reader_is_one_scopes_seconds_in_both_step_programs_over_
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     assert (entry["unit"], entry["layer"], entry["moves"], entry["source"]) == (mod.UNIT, mod.LAYER, mod.MOVES, mod.SOURCE)
-    assert entry["better"] == "lower" and len(entry["workloads"]) == 10 and "jax" not in mod.__dict__
+    # (ten cells at ISSUE 62; ISSUE 64's cell, whose two attention layers write the pool, is the eleventh)
+    assert entry["better"] == "lower" and len(entry["workloads"]) == 11 and "jax" not in mod.__dict__
     assert mod.SCOPE in CLASSES["attention"]
     modules = {"mixed_step": {"scope_s": {"kv.write": 0.5, "attention": 2.0}}, "decode_chunk": {"scope_s": {"kv.write": 0.25}},
                "other_programs": {"scope_s": {"kv.write": 9.0}}}
