@@ -22,7 +22,11 @@ least time for them: the cell's `kda_chunk_roofline_share`, one launch.
 
 `--file` times another copy of `ops/kda.py` (an ablation: a copy with a part
 of the kernel cut out gives wrong answers and a right time); its imports are
-this checkout's. The check that rides along is the largest difference of the
+this checkout's. `--live-visits-only` (ISSUE 65, step 0) hands the launch the
+plan CUT to its live visits (a static grid of 9 visits where the plan holds
+40): what a launch costs with no visit past the last in it; `--oracle-root`
+holds the timed launch to another checkout's on the same operands, outputs and
+store, TO THE BIT. The check that rides along is the largest difference of the
 outputs and of the chunk row's last state from this checkout's
 `kda_chunk_twin` on the same device, every pair formed alone.
 
@@ -52,14 +56,14 @@ TINY = dict(T=48, Hh=4, K=32, Q=16, S=6, L=2, chunk=40, ones=3)
 
 
 def _load(path: str):
-    spec = importlib.util.spec_from_file_location("timed_kda", path)
+    spec = importlib.util.spec_from_file_location(f"timed_kda_{abs(hash(path))}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
 
 
-def _kernel_us(run) -> dict:
-    """One ``run()`` under the profiler: the chunk kernel's events by name, us."""
+def _kernel_us(run, kernel: str = "kda_chunk_kernel") -> dict:
+    """One ``run()`` under the profiler: the events of ``kernel`` by name, us."""
     import jax
     from jax.profiler import ProfileData
 
@@ -70,7 +74,7 @@ def _kernel_us(run) -> dict:
         events = [ev.duration_ns / 1e3
                   for plane in ProfileData.from_file(path).planes if plane.name.startswith("/device:TPU:")
                   for line in plane.lines if line.name == "XLA Ops"
-                  for ev in line.events if "kda_chunk_kernel" in ev.name]
+                  for ev in line.events if kernel in ev.name]
     if not events:
         return {"kernel_events": 0}
     return {"kernel_events": len(events), "kernel_us": round(statistics.median(events), 1),
@@ -91,7 +95,7 @@ def _rows(rng, S: int, chunk: int, ones: int):
     return starts, lens, position
 
 
-def measure(kda, shape: dict, repeats: int, seed: int) -> dict:
+def measure(kda, shape: dict, repeats: int, seed: int, live_only: bool = False, oracle=None) -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -110,6 +114,9 @@ def measure(kda, shape: dict, repeats: int, seed: int) -> dict:
     beta = jnp.asarray(1 / (1 + np.exp(-rng.normal(size=(T, Hh)))), jnp.float32)
     starts, lens, position = _rows(rng, S, shape["chunk"], shape["ones"])
     meta, blocks = ssd.block_plan(jnp.asarray(starts), jnp.asarray(lens), jnp.asarray(position + lens), T, Q)
+    planned = int(meta.shape[0])
+    if live_only:
+        meta = meta[:max(int(blocks), 1)]
     store = jnp.asarray(rng.normal(size=(L, S, Hh, K, K)), jnp.float32)
     mm = jnp.bfloat16
     row = int(lens.argmax())                                                    # the chunk's slot
@@ -129,7 +136,7 @@ def measure(kda, shape: dict, repeats: int, seed: int) -> dict:
         t0 = time.perf_counter()
         jax.block_until_ready(chained(store))
         times.append((time.perf_counter() - t0) / CALLS)
-    line = {"shape": shape, "visits_live": float(blocks), "visits_in_grid": int(meta.shape[0]),
+    line = {"shape": shape, "visits_live": float(blocks), "visits_planned": planned, "visits_handed": int(meta.shape[0]),
             "chunk_starts_at": at.start,
             "host_us_a_launch": round(statistics.median(times) * 1e6, 1), "host_us_min": round(min(times) * 1e6, 1)}
     if dev.platform == "tpu":
@@ -150,6 +157,10 @@ def measure(kda, shape: dict, repeats: int, seed: int) -> dict:
     line["max_abs_diff_vs_twin"] = {"o": float(jnp.max(jnp.abs(got_o[at] - want_o[at]))),
                                     "state": float(jnp.max(jnp.abs(got_s[1, row] - want_s[1, row]))),
                                     "o_scale": float(jnp.max(jnp.abs(want_o[at])))}
+    if oracle is not None:   # another checkout's launch on the same operands: the chunk row's outputs and the WHOLE store
+        their_o, their_s = jax.jit(lambda s: oracle.kda_chunk(s, 1, q, k, v, g, beta, meta, Q=Q, mm=mm, kernel=True))(store)
+        line["equal_to_the_oracle_bit_for_bit"] = {"o": bool(jnp.all(got_o[at] == their_o[at])),
+                                                   "store": bool(jnp.all(got_s == their_s))}
     return line
 
 
@@ -157,6 +168,8 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--root", default=HERE, help="checkout whose ops/kda.py to time")
     ap.add_argument("--file", help="a copy of ops/kda.py to time in its place (an ablation)")
+    ap.add_argument("--oracle-root", help="a checkout whose launch the timed one has to equal to the bit")
+    ap.add_argument("--live-visits-only", action="store_true", help="hand the launch the plan cut to its live visits")
     ap.add_argument("--repeats", type=int, default=REPEATS)
     ap.add_argument("--seed", type=int, default=55)
     ap.add_argument("--rehearse", action="store_true", help="a tiny shape, for a walk through on the CPU")
@@ -169,11 +182,14 @@ def main() -> int:
 
     dev = jax.devices()[0]
     out = {"file": os.path.relpath(path, HERE), "device": {"platform": dev.platform, "kind": dev.device_kind},
-           "calls_chained": CALLS, **measure(_load(path), TINY if args.rehearse else CELL, args.repeats, args.seed)}
+           "calls_chained": CALLS,
+           **measure(_load(path), TINY if args.rehearse else CELL, args.repeats, args.seed, args.live_visits_only,
+                     _load(os.path.join(args.oracle_root, "inference_gateway_tpu", "ops", "kda.py")) if args.oracle_root else None)}
     dest = os.path.join(HERE, "chiprun_out", "kda_chunk_bench")
     os.makedirs(dest, exist_ok=True)
-    tag = args.tag or ("change" if path.startswith(os.path.join(HERE, "inference_gateway_tpu")) else
-                       os.path.basename(os.path.dirname(path) if args.file else os.path.abspath(args.root)))
+    tag = args.tag or (("change" if path.startswith(os.path.join(HERE, "inference_gateway_tpu")) else
+                        os.path.basename(os.path.dirname(path) if args.file else os.path.abspath(args.root)))
+                       + ("-live" if args.live_visits_only else ""))
     with open(os.path.join(dest, f"{tag}.json"), "w") as f:
         json.dump(out, f, indent=1)
     print(tag, json.dumps(out), flush=True)

@@ -18,6 +18,12 @@ broadcasts, 2 + 2 + 2 + 2 multiplies, 2 additions, the sum over N in 1 + 3, a
 select: 17, and 2 exponentials) at four vector slots a cycle of 940 MHz: the
 body is vector work, so that count is the ceiling, not the bytes.
 
+`--live-visits-only` (ISSUE 65, step 0) hands the chunk kernel the plan CUT to
+its live visits (a static grid of 9 visits where the plan holds 40): what a
+launch costs with no visit past the last in it; `--forms chunk_kernel` times
+that form alone; `--oracle-root` holds the chunk kernel's launch to another
+checkout's `ops/ssm.py` on the same operands, outputs and store, TO THE BIT.
+
 `--attention` (step 0 (a)): both paged kernels at (Hq 20, Hkv 1, D 128, page
 128), 100 pages a row, against the gather path on the same operands.
 
@@ -88,7 +94,17 @@ def _timed(fn, arg, repeats: int) -> float:
     return statistics.median(times) * 1e6
 
 
-def measure(shape: dict, repeats: int, seed: int, widths: list) -> dict:
+def _load(root: str):
+    """Another checkout's ``ops/ssm.py`` (its imports are this checkout's)."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location("oracle_ssm", os.path.join(root, "inference_gateway_tpu", "ops", "ssm.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def measure(shape: dict, repeats: int, seed: int, widths: list, only=None, live_only: bool = False, oracle=None) -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -107,6 +123,9 @@ def measure(shape: dict, repeats: int, seed: int, widths: list) -> dict:
     starts, lens, position = _rows(rng, S, shape["chunk"], shape["ones"])
     qs, ql, kv = jnp.asarray(starts), jnp.asarray(lens), jnp.asarray(position + lens)
     meta, blocks = ssd.block_plan(qs, ql, kv, T, ssm.CHUNK_TOKENS)
+    planned = int(meta.shape[0])
+    if live_only:
+        meta = meta[:max(int(blocks), 1)]
     seg = ssm.token_segments(qs, ql, T)
     positions = jnp.asarray(np.concatenate(
         [np.arange(p, p + n) for p, n in zip(position, lens)] + [np.zeros(T - lens.sum(), np.int64)]).astype(np.int32))
@@ -153,7 +172,7 @@ def measure(shape: dict, repeats: int, seed: int, widths: list) -> dict:
     chunk_tokens, chunk_rows = int(lens[lens > 1].sum()), int((lens > 1).sum())
     chunk_bytes = chunk_tokens * (3 * Di + 2 * N * ssm.LANES) * 4 + chunk_rows * 2 * N * Di * 4
     units = chunk_tokens * Di // ssm.LANES
-    line = {"shape": shape, "visits_live": float(blocks), "visits_in_grid": int(meta.shape[0]),
+    line = {"shape": shape, "visits_live": float(blocks), "visits_planned": planned, "visits_handed": int(meta.shape[0]),
             "chunk_starts_at": at.start, "chunk_tokens": chunk_tokens,
             "chunk_bytes": chunk_bytes, "chunk_vector_ops": units * VECTOR_OPS, "chunk_exps": units * EXPS}
     forms = {"chunk_kernel": chunk, "one_token": one_token, "token_loop": loop,
@@ -161,6 +180,8 @@ def measure(shape: dict, repeats: int, seed: int, widths: list) -> dict:
     for u in widths:
         forms[f"one_token_kernel_w{u}"] = one_token_kernel(u)
         forms[f"decode_step_kernel_w{u}"] = functools.partial(one_token_kernel(u), rows=every)
+    if only:
+        forms, widths = {name: forms[name] for name in only}, [u for u in widths if f"one_token_kernel_w{u}" in only]
     for name, launch in forms.items():
         line[f"{name}_host_us"] = round(_timed(chain(launch), store, repeats), 1)
     if dev.platform == "tpu":
@@ -175,29 +196,38 @@ def measure(shape: dict, repeats: int, seed: int, widths: list) -> dict:
                 line[f"{name}_us"] = round(statistics.median(events), 1) if events else None
                 line[f"{name}_us_min_max"] = [round(min(events), 1), round(max(events), 1)] if events else None
         # The whole chain's device time a launch (every operation but the loop that holds them): the forms like for like.
-        for name in ["one_token", "decode_step"] + [f"{n}_kernel_w{u}" for u in widths for n in ("one_token", "decode_step")]:
+        for name in [n for n in ("one_token", "decode_step") if n in forms] + [
+                f"{n}_kernel_w{u}" for u in widths for n in ("one_token", "decode_step")]:
             line[f"{name}_chain_device_us"] = round(sum(_events(
                 lambda: jax.block_until_ready(chain(forms[name])(store)), r"(?!%?(?:while|conditional)\b)")) / CALLS, 1)
         line["one_token_rows"], line["decode_step_rows"] = int(one.sum()), S
         line["chunk_bytes_us"] = round(chunk_bytes / chip["hbm_bytes_per_s"] * 1e6, 1)
         line["chunk_vector_us"] = round(units * VECTOR_OPS / SLOTS_A_CYCLE / CLOCK_HZ * 1e6, 1)
         line["one_token_bytes_us"] = round(2 * S * N * Di * 4 / chip["hbm_bytes_per_s"] * 1e6, 1)   # the whole entry, as the plain form moves it
-        line["token_loop_us_a_trip"] = round(line["token_loop_host_us"] / n_tokens, 3)
+        if "token_loop" in forms:
+            line["token_loop_us_a_trip"] = round(line["token_loop_host_us"] / n_tokens, 3)
     # Against the definition on this device: the chunk row's outputs and its slot's last state; a one-token row's too.
     want_y, want_s = jax.jit(lambda s: ssm.scan_ragged(s[1], delta, c, B, C, A, D, seg, positions, n_tokens))(store)
     got_y, got_s = jax.jit(lambda s: chunk(s, 1))(store)
+    if oracle is not None:   # another checkout's launch on the same operands: the chunk row's outputs and the WHOLE store
+        their_y, their_s = jax.jit(lambda s: oracle.scan_chunk_tpu(s, 1, delta, c, B, C, A, D, meta, interpret=interpret))(store)
+        line["chunk_equal_to_the_oracle_bit_for_bit"] = {"y": bool(jnp.all(got_y[at] == their_y[at])),
+                                                         "store": bool(jnp.all(got_s == their_s))}
+    line["max_abs_diff_vs_recurrence"] = {
+        "y": float(jnp.max(jnp.abs(got_y[at] - want_y[at]))), "state": float(jnp.max(jnp.abs(got_s[1, row] - want_s[row]))),
+        "y_scale": float(jnp.max(jnp.abs(want_y[at])))}
+    if only:
+        return line
     y1, s1 = jax.jit(lambda s: one_token(s, 1))(store)
     yk, sk = jax.jit(lambda s: one_token_kernel(widths[0])(s, 1))(store)
     a_one = int(np.flatnonzero(lens == 1)[0])
-    line["max_abs_diff_vs_recurrence"] = {
-        "y": float(jnp.max(jnp.abs(got_y[at] - want_y[at]))), "state": float(jnp.max(jnp.abs(got_s[1, row] - want_s[row]))),
-        "y_scale": float(jnp.max(jnp.abs(want_y[at]))),
+    line["max_abs_diff_vs_recurrence"].update({
         "one_token_y": float(jnp.max(jnp.abs(y1[starts[a_one]] - want_y[starts[a_one]]))),
         "one_token_state": float(jnp.max(jnp.abs(s1[1, a_one] - want_s[a_one]))),
         "one_token_kernel_y": float(jnp.max(jnp.abs(jnp.where(one[:, None], yk[qs] - want_y[qs], 0.0)))),
         "one_token_kernel_state": float(jnp.max(jnp.abs(jnp.where(one[:, None, None], sk[1] - want_s, 0.0)))),
         "one_token_kernel_other_rows_and_layers_moved": bool(
-            jnp.any(jnp.where(one[:, None, None], 0.0, sk[1] - store[1]) != 0) | jnp.any(sk[0] != store[0]))}
+            jnp.any(jnp.where(one[:, None, None], 0.0, sk[1] - store[1]) != 0) | jnp.any(sk[0] != store[0]))})
     return line
 
 
@@ -253,6 +283,9 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=64)
     ap.add_argument("--rehearse", action="store_true", help="a tiny shape, for a walk through on the CPU")
     ap.add_argument("--lanes", type=int, nargs="+", default=[1024], help="channels the one-token kernel advances at a time")
+    ap.add_argument("--forms", nargs="+", help="time these forms alone (chunk_kernel, one_token, token_loop, ..)")
+    ap.add_argument("--live-visits-only", action="store_true", help="hand the chunk kernel the plan cut to its live visits")
+    ap.add_argument("--oracle-root", help="a checkout whose chunk kernel this one's has to equal to the bit")
     ap.add_argument("--tag", default="change")
     args = ap.parse_args()
     sys.path.insert(0, HERE)
@@ -262,7 +295,8 @@ def main() -> int:
     dev = jax.devices()[0]
     out = {"device": {"platform": dev.platform, "kind": dev.device_kind}, "calls_chained": CALLS}
     for name in (["tiny"] if args.rehearse else args.shape):
-        out[name] = measure(SHAPES[name], 2 if args.rehearse else args.repeats, args.seed, args.lanes)
+        out[name] = measure(SHAPES[name], 2 if args.rehearse else args.repeats, args.seed, args.lanes, args.forms,
+                            args.live_visits_only, _load(args.oracle_root) if args.oracle_root else None)
         print(name, json.dumps(out[name]), flush=True)
     if args.attention and not args.rehearse:
         out["attention_20_to_1"] = attention(args.seed)

@@ -221,8 +221,10 @@ def _seam(params: Params, cfg: JambaConfig, conv, scan) -> dict:
 # Rows of both step programs' fetch block (``Family.chunk_stats`` / ``mixed_stats``):
 # the tokens and the rows the chunk form walked, over the Mamba layers (the
 # ``ssm_scan_chunk_kernel``'s work), and the rows the one-token form served (a row a
-# Mamba layer reads and writes its state once: ``state_rows`` as every state family's).
-SSM_STATS = ("ssm_chunk_tokens", "ssm_chunk_rows", "state_rows")
+# Mamba layer reads and writes its state once: ``state_rows`` as every state family's),
+# and (ISSUE 65) the static bound of the chunk plan's visits over the Mamba layers
+# (``ops/ssd.max_visits``; 0 in a decode chunk): the kernel's grid runs the live ones.
+SSM_STATS = ("ssm_chunk_tokens", "ssm_chunk_rows", "state_rows", "chunk_visits_bound")
 
 
 @partial(jax.jit, static_argnames=("cfg", "mode", "last_only", "mesh", "with_aux"))
@@ -242,7 +244,7 @@ def forward_paged(params: Params, cfg: JambaConfig, tokens, positions, lengths, 
     if not with_aux:
         return out
     with jax.named_scope("carry"):
-        return (*out, jnp.stack([0.0, 0.0, jnp.sum(rows & (lengths > 0)).astype(jnp.float32)]))
+        return (*out, jnp.stack([0.0, 0.0, jnp.sum(rows & (lengths > 0)).astype(jnp.float32), 0.0]))
 
 
 @partial(jax.jit, static_argnames=("cfg", "mesh", "with_aux"))
@@ -260,7 +262,7 @@ def forward_ragged(params: Params, cfg: JambaConfig, tokens, positions, cache: P
     if not with_aux:
         return out
     with jax.named_scope("carry"):
-        return (*out, counts * jnp.asarray([cfg.state_layers, cfg.state_layers, 1.0], jnp.float32))
+        return (*out, counts * jnp.asarray([cfg.state_layers, cfg.state_layers, 1.0, cfg.state_layers], jnp.float32))
 
 
 def _status(cfg: JambaConfig, view) -> dict:

@@ -882,8 +882,9 @@ def _kda_ragged(cfg: MixtralConfig, q_starts, q_lens, kv_lens, T: int) -> tuple[
     """The same for a mixed step's packed rows (``_mamba2_ragged``'s split: a row
     of one token takes the batched one-token update, a row of more the chunk
     form, ops/kda.py). Returns the operations and the step's counts, float32
-    (2,): the blocks the chunk kernel walks over the state layers and the rows
-    whose state the update reads and writes in each."""
+    (3,): the blocks the chunk kernel walks over the state layers, the rows
+    whose state the update reads and writes in each, and the plan's bound of
+    those blocks."""
     seg, fresh, one, in_chunk, meta, blocks, first = _rows_by_length(q_starts, q_lens, kv_lens, T, cfg.kda_block)
 
     def scan(states, ordinal, q, k, v, g, beta, mm):
@@ -893,7 +894,7 @@ def _kda_ragged(cfg: MixtralConfig, q_starts, q_lens, kv_lens, T: int) -> tuple[
         return o.at[jnp.where(one, q_starts, T)].set(o1, mode="drop"), states
 
     ops = {"conv": lambda u, tails, w, b: conv_ragged(u, tails, w, b, seg, fresh, q_starts, q_lens), "scan": scan}
-    return ops, jnp.stack([blocks * cfg.conv_layers, jnp.sum(one).astype(jnp.float32)])
+    return ops, jnp.stack([blocks * cfg.conv_layers, jnp.sum(one).astype(jnp.float32), float(meta.shape[0] * cfg.conv_layers)])
 
 
 def _mamba2_decode(rows) -> dict:
@@ -910,8 +911,9 @@ def _mamba2_ragged(cfg: MixtralConfig, q_starts, q_lens, kv_lens, T: int) -> tup
     token takes the batched one-token update at its packed position, a row of
     more the blocked scan (ops/ssd.py); each from its slot's state, from zero
     where it starts at position 0. Returns the operations and the step's
-    counts, float32 (2,): the blocks the chunk kernel walks over the state
-    layers and the rows whose state the update reads and writes in each."""
+    counts, float32 (3,): the blocks the chunk kernel walks over the state
+    layers, the rows whose state the update reads and writes in each, and the
+    plan's bound of those blocks."""
     seg, fresh, one, in_chunk, meta, blocks, first = _rows_by_length(q_starts, q_lens, kv_lens, T, cfg.ssd_block)
 
     def scan(ssm, ordinal, x, delta, B, C, A, D, mm):
@@ -921,7 +923,7 @@ def _mamba2_ragged(cfg: MixtralConfig, q_starts, q_lens, kv_lens, T: int) -> tup
         return y.at[jnp.where(one, q_starts, T)].set(y1, mode="drop"), ssm
 
     ops = {"conv": lambda u, tails, w, b: conv_ragged(u, tails, w, b, seg, fresh, q_starts, q_lens), "scan": scan}
-    return ops, jnp.stack([blocks * cfg.conv_layers, jnp.sum(one).astype(jnp.float32)])
+    return ops, jnp.stack([blocks * cfg.conv_layers, jnp.sum(one).astype(jnp.float32), float(meta.shape[0] * cfg.conv_layers)])
 
 
 def _moe_ffn(x: jnp.ndarray, attn_in: jnp.ndarray, lp: Params, li, valid, *,
@@ -1280,7 +1282,7 @@ def forward_paged(
         live = lengths if valid is None else jnp.where(valid, lengths, 0)
         counts = counted_window_pages(cfg, _page_size(cfg, cache), live, live)
         if cfg.scans_runs:   # no block is walked; every live row's state is read and written, in every state layer
-            counts = jnp.concatenate([counts, jnp.stack([0.0, jnp.sum(live > 0).astype(jnp.float32)])])
+            counts = jnp.concatenate([counts, jnp.stack([0.0, jnp.sum(live > 0).astype(jnp.float32), 0.0])])
     return (*out[:2], (out[2], counts))
 
 
@@ -1676,9 +1678,12 @@ WINDOW_STATS = ("window_pages_read", "context_pages")
 LATENT_STATS = ("latent_pages_read", "context_pages", "latent_row_pages_read")
 # With Mamba-2 layers (ISSUE 50): the blocks the chunk kernel walked over all state
 # layers (0 in a decode chunk) and the rows whose matrix state the one-token update
-# read and wrote in each; the same two for Kimi Delta Attention layers (ISSUE 54).
-SSD_STATS = ("ssd_blocks", "state_rows")
-KDA_STATS = ("kda_blocks", "state_rows")
+# read and wrote in each; the same two for Kimi Delta Attention layers (ISSUE 54). And
+# (ISSUE 65) the plan's static bound of those visits over the same layers
+# (``ops/ssd.max_visits``; 0 in a decode chunk): the kernel's grid runs the live visits,
+# so blocks over bound is the share of the plan's length that was a grid step.
+SSD_STATS = ("ssd_blocks", "state_rows", "chunk_visits_bound")
+KDA_STATS = ("kda_blocks", "state_rows", "chunk_visits_bound")
 # With a next-token module (ISSUE 56): the rounds of a row that verified a draft, the
 # drafts accepted, and the tokens those rounds emitted (1 or 2 a round): each a sum.
 MTP_STATS = ("mtp_drafted", "mtp_accepted", "mtp_tokens")
@@ -1754,14 +1759,20 @@ def _status(cfg: MixtralConfig, view) -> dict:
         plan = plan_of((cfg.conv_layers, view.max_slots, heads, rows, lanes))
         return {"one_token_rows": plan.pop("path"), "one_token_update": plan}
 
+    def chunk_form(name):
+        """Which chunk form runs, and (ISSUE 65) where the kernel's visit axis ends."""
+        if on_tpu():
+            return {"rows_of_more_than_one_token": f"{name}_chunk_kernel", "grid": "live visits"}
+        return {"rows_of_more_than_one_token": f"{name}_chunk_twin"}
+
     if cfg.mamba2:   # how the Mamba-2 scan is done (ISSUE 50; static)
         out["ssd"] = {"heads": cfg.ssm_heads, "state": [cfg.ssm_head_dim, cfg.ssm_state], "block": cfg.ssd_block,
-                      "rows_of_more_than_one_token": "ssd_chunk_kernel" if on_tpu() else "ssd_chunk_twin",
+                      **chunk_form("ssd"),
                       **one_token(ssd_ops.update_plan, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)}
     if cfg.kda:   # how the delta rule is run (ISSUE 54; static)
         out["kda"] = {"heads": cfg.kda_heads, "state": [cfg.kda_head_dim, cfg.kda_head_dim], "block": cfg.kda_block,
                       "decay": "a channel", "state_bytes_per_slot_per_layer": cfg.kda_inner * cfg.kda_head_dim * 4,
-                      "rows_of_more_than_one_token": "kda_chunk_kernel" if on_tpu() else "kda_chunk_twin",
+                      **chunk_form("kda"),
                       **one_token(kda_ops.update_plan, cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim)}
     if cfg.indexer_topk:   # its sizes, and how the selection and the read are done (static)
         row_tokens = view.page_cfg.max_pages_per_slot * view.page_cfg.page_size

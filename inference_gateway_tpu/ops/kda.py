@@ -49,7 +49,7 @@ another row in that block are identity steps (g = 0, beta = 0) whose outputs
 are not written.
 
 On a TPU ``kda_chunk`` is the Pallas kernel ``kda_chunk_kernel``: a grid of
-(head blocks, visits), the head block's states in a VMEM scratch across a
+(head blocks, the plan's live visits: ``ops/ssd.live_visits``), the head block's states in a VMEM scratch across a
 row's visits, the store read and written in place by DMA at (layer ordinal,
 slot). A head's key width is the 128 lanes, so tokens lie in the sublanes and
 every operand is a plain blocked read. The products with the state and with
@@ -77,8 +77,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from inference_gateway_tpu.ops.ssd import (HIGHEST, META, RING, _padded, advancing_rows, handed_back, staged, visit_heads,
-                                           visit_plan, walk_rows)
+from inference_gateway_tpu.ops.ssd import (HIGHEST, META, RING, _padded, advancing_rows, handed_back, live_visits, staged,
+                                           visit_heads, visit_plan, walk_rows)
 from inference_gateway_tpu.utils.platform import on_tpu
 
 HEAD_BLOCK = 4   # heads a grid step of the chunk kernel holds: their states are 4 x K x V float32 of VMEM (256 KB at 128 x 128)
@@ -322,7 +322,6 @@ def kda_chunk_tpu(store, ordinal, q, k, v, g, beta, meta, *, Q: int, mm=jnp.floa
     if Q & (Q - 1):
         raise ValueError(f"a block of {Q} tokens does not halve down to single tokens")
     T, Hh, K = q.shape
-    V = meta.shape[0]
     hb = HEAD_BLOCK if Hh % HEAD_BLOCK == 0 else Hh
     Tp, (q, k, kb, vb, g) = _padded(T, Q, q, k, k * beta[..., None], v * beta[..., None], g)
     flat = lambda a: a.reshape(Tp, Hh * K)
@@ -332,7 +331,7 @@ def kda_chunk_tpu(store, ordinal, q, k, v, g, beta, meta, *, Q: int, mm=jnp.floa
     o, rows = pl.pallas_call(
         functools.partial(_kda_chunk_kernel, hb=hb, Q=Q, K=K, mm=mm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(Hh // hb, V),
+            num_scalar_prefetch=2, grid=(Hh // hb, live_visits(meta)),
             in_specs=[block, block, block, block, block, pl.BlockSpec(memory_space=pltpu.ANY)],
             out_specs=[block, pl.BlockSpec(memory_space=pltpu.ANY)],
             scratch_shapes=[pltpu.VMEM((hb * K, K), jnp.float32), pltpu.SemaphoreType.DMA((2,))]),

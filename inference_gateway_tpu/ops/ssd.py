@@ -48,8 +48,9 @@ which stays in VMEM between their visits (the plan orders rows by their
 packed start).
 
 On a TPU ``ssd_chunk`` is the Pallas kernel ``ssd_chunk_kernel``: a grid of
-(head blocks, visits), the head block's states in a VMEM scratch across a row's
-visits, the store read and written in place by DMA at (layer ordinal, slot).
+(head blocks, the plan's LIVE visits: the visit axis is a dynamic grid dimension,
+``live_visits``, so a visit past the last is no grid step, ISSUE 65), the head
+block's states in a VMEM scratch across a row's visits, the store read and written in place by DMA at (layer ordinal, slot).
 Tokens lie in the LANES (operands transposed outside, 17 MB a layer at the
 published sizes), so a head's P rows are a sublane slice and every product is
 a plain (M, K) x (K, N); the two products whose second operand every head
@@ -341,6 +342,15 @@ def block_plan(q_starts, q_lens, kv_lens, T: int, Q: int):
     return meta.astype(jnp.int32), jnp.sum(n).astype(jnp.float32)
 
 
+def live_visits(meta):
+    """Where the chunk kernels' visit axis ends (ISSUE 65): the plan's live
+    visits, int32, at least 1 (visit 0 stays: it carries ``init``, which zeroes
+    the first output block). ``block_plan`` puts the live visits first, so the
+    grid's visit dimension is this many steps and a visit past the last is no
+    grid step at all; ``meta`` keeps its static length ``max_visits``."""
+    return jnp.maximum(jnp.sum(meta[:, 3] > meta[:, 2]), 1).astype(jnp.int32)
+
+
 def _padded(T: int, Q: int, *arrays):
     """The packed tokens' operands in float32, zeros up to a whole number of blocks."""
     Tp = -(-T // Q) * Q
@@ -435,7 +445,7 @@ def ssd_chunk_tpu(store, ordinal, x, dt, B, C, A, meta, *, Q: int, mm=jnp.float3
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(Hh // hb, V),
+            grid=(Hh // hb, live_visits(meta)),
             in_specs=[
                 pl.BlockSpec((hb * P, Q), at(lambda h, j, m: (h, block_of(j, m)))),           # x^T
                 pl.BlockSpec((hb, Q), at(lambda h, j, m: (h, block_of(j, m)))),               # Delta^T

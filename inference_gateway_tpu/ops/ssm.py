@@ -28,7 +28,7 @@ The scan has THREE forms:
   TPU the Pallas kernel ``ssm_scan_chunk_kernel``: the packed token axis is cut
   into blocks of ``CHUNK_TOKENS`` from 0 and a row visits every block it
   touches, in order (``ops/ssd.block_plan``: the plan Mamba-2's blocked scan
-  walks); a row's first visit brings its slot's state (N, Di) into VMEM by DMA
+  walks; a grid step a LIVE visit, ``ops/ssd.live_visits``); a row's first visit brings its slot's state (N, Di) into VMEM by DMA
   (zeros where the row starts at position 0), every visit walks its tokens
   there, eight at a time and 128 channels at a time, exp(Delta_t A) formed on
   the fly, and the last visit puts the state back in place. Nothing becomes a
@@ -90,7 +90,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from inference_gateway_tpu.ops.norms import rms_norm
 from inference_gateway_tpu.ops.quant import qmatmul
-from inference_gateway_tpu.ops.ssd import META, RING, _padded, advancing_rows, block_plan, handed_back, staged, walk_rows
+from inference_gateway_tpu.ops.ssd import (META, RING, _padded, advancing_rows, block_plan, handed_back, live_visits, staged,
+                                           walk_rows)
 from inference_gateway_tpu.utils.platform import device_platform, on_tpu
 
 
@@ -228,7 +229,8 @@ def chunk_plan(Di: int, N: int) -> dict:
     """How a mixed step's selective scan walks a state of (N, Di): ``path`` is
     the chunk kernel's name on a TPU where Di is whole lane tiles, else the
     twin's with the ``reason``; the tokens a visit holds, the channels the state
-    in VMEM spans (all of them: one grid step a visit), and the form a one-token
+    in VMEM spans (all of them: one grid step a visit; ``grid``: the plan's live
+    visits are the grid's steps, ISSUE 65), and the form a one-token
     row takes (the one-token kernel under the same condition, else
     ``scan_decode``). ``/debug/status`` reports it."""
     plan = {"token_block": CHUNK_TOKENS, "channel_block": Di, "walks": f"{TILE} tokens x {LANES} channels",
@@ -237,7 +239,7 @@ def chunk_plan(Di: int, N: int) -> dict:
         return {**plan, "path": "scan_chunk_twin", "reason": f"platform {device_platform()} is not TPU"}
     if Di % LANES:
         return {**plan, "path": "scan_chunk_twin", "reason": f"{Di} channels are not whole tiles of {LANES} lanes"}
-    return {**plan, "path": "ssm_scan_chunk_kernel", "one_token_rows": "ssm_scan_decode_kernel",
+    return {**plan, "path": "ssm_scan_chunk_kernel", "grid": "live visits", "one_token_rows": "ssm_scan_decode_kernel",
             "reason": "a row's state in VMEM across its visits"}
 
 
@@ -312,7 +314,7 @@ def scan_chunk_tpu(store, ordinal, delta, c, B, C, A, D, meta, *, Q: int = CHUNK
         functools.partial(_scan_chunk_kernel, Q=Q, Di=Di),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(meta.shape[0],),
+            grid=(live_visits(meta),),
             in_specs=[tokens, tokens, columns, columns, whole(N), whole(1), pl.BlockSpec(memory_space=pltpu.ANY)],
             out_specs=[tokens, pl.BlockSpec(memory_space=pltpu.ANY)],
             scratch_shapes=[pltpu.VMEM((N, Di), jnp.float32), pltpu.SemaphoreType.DMA((2,))],
@@ -381,8 +383,10 @@ def ragged_scan(q_starts, q_lens, kv_lens, T: int):
     ``scan_chunk``, a row of one token through ``scan_decode`` at its packed
     position; each from its slot's state, from zero where it starts at position
     0. Returns (``scan(store, ordinal, delta, c, B, C, A, D) -> (y, store)``, the
-    tokens' segments, fresh (S,), and the step's counts, float32 (3,): the tokens
-    and the rows the chunk form walked and the rows the one-token form served)."""
+    tokens' segments, fresh (S,), and the step's counts, float32 (4,): the tokens
+    and the rows the chunk form walked, the rows the one-token form served, and
+    the static bound of the chunk plan's visits, of which the kernel's grid runs
+    the live ones)."""
     seg, fresh = token_segments(q_starts, q_lens, T), (kv_lens - q_lens) == 0
     slot, _, live = seg
     one, more = q_lens == 1, q_lens > 1
@@ -395,7 +399,7 @@ def ragged_scan(q_starts, q_lens, kv_lens, T: int):
         y1, store = _decode_entry(store, ordinal, delta[first], c[first], B[first], C[first], A, D, one, fresh)
         return jnp.where(in_chunk[:, None], y, 0.0).at[jnp.where(one, q_starts, T)].set(y1, mode="drop"), store
 
-    counts = jnp.stack([jnp.sum(jnp.where(more, q_lens, 0)), jnp.sum(more), jnp.sum(one)]).astype(jnp.float32)
+    counts = jnp.stack([jnp.sum(jnp.where(more, q_lens, 0)), jnp.sum(more), jnp.sum(one), meta.shape[0]]).astype(jnp.float32)
     return scan, seg, fresh, counts
 
 

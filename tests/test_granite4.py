@@ -159,8 +159,11 @@ def scan_case(lens, starts, kernel, T=64, seed=0):
     position ``starts[r]``, through ``ssd_chunk`` on entry 1 of a store of
     random states. Returns (blocks walked, the largest difference from the
     token recurrence over outputs and last states of the rows of more than one
-    token); asserts that every other slot and the other entry are untouched."""
+    token, the visits the kernel's grid runs and the plan's bound, the first
+    output block); asserts that every other slot and the other entry are
+    untouched."""
     rng = np.random.default_rng(seed)
+    SLOTS = len(lens)
     q_lens, position = np.asarray(lens, np.int32), np.asarray(starts, np.int32)
     q_starts = np.concatenate([[0], np.cumsum(q_lens)[:-1]]).astype(np.int32)
     x = rng.normal(size=(T, HH, P)).astype(np.float32)
@@ -183,11 +186,14 @@ def scan_case(lens, starts, kernel, T=64, seed=0):
         worst = max(worst, float(np.abs(np.asarray(want_y) - np.asarray(y[at])).max()),
                     float(np.abs(np.asarray(want_s) - np.asarray(new[1, r])).max()))
     assert np.array_equal(np.asarray(new[0]), store[0])
-    return float(blocks), worst
+    return float(blocks), worst, (int(ssd.live_visits(meta)), meta.shape[0]), np.asarray(y[:Q])
 
 
-# (lengths a slot, positions a slot, the blocks of 8 the rows touch): lengths 1, Q - 1, Q, Q + 1 and 2 Q + 3
-# with and without a carried state; decode rows in front of one long chunk; no row at all; rows of two.
+# (lengths a slot, positions a slot, the blocks of 8 the rows touch[, the packed tokens]): lengths 1, Q - 1, Q,
+# Q + 1 and 2 Q + 3 with and without a carried state; decode rows in front of one long chunk; no row at all; rows
+# of two. And (ISSUE 65: the kernel's visit axis ends at the plan's last live visit) plans whose live visits are
+# far under the bound (one long row beside 15 of one token), AT it (every row of two tokens starts mid-block, the
+# last across a block's edge) and none at all (every row one token: visit 0 alone runs, and zeroes one block).
 RAGGED = {
     "one_to_two_blocks_and_three": ([1, 7, 8, 9, 19], [5, 0, 3, 0, 11], 7),
     "the_same_all_carried": ([1, 7, 8, 9, 19], [5, 2, 3, 9, 11], 7),
@@ -195,18 +201,28 @@ RAGGED = {
     "decode_rows_then_a_chunk": ([1, 1, 1, 0, 40], [5, 2, 3, 0, 11], 6),
     "no_row": ([0, 0, 0, 0, 0], [5, 2, 3, 0, 11], 0),
     "rows_of_two": ([2, 2, 2, 2, 2], [0, 2, 3, 0, 11], 5),
+    "live_visits_far_under_the_bound": ([1] * 5 + [40] + [1] * 10, [5, 2, 3, 0, 11, 9] + [4] * 10, 6),
+    "live_visits_at_the_bound": ([1, 2, 2, 2, 2], [5, 0, 3, 9, 11], 5, 9),
+    "no_live_visit": ([1, 1, 1, 1, 1], [5, 2, 3, 0, 11], 0),
 }
+GRID = {"live_visits_far_under_the_bound": (6, 8 + 15), "live_visits_at_the_bound": (5, 5), "no_live_visit": (1, 8 + 4)}
 
 
-@pytest.mark.parametrize("kernel", [False, True], ids=["twin", "interpreted_kernel"])
-@pytest.mark.parametrize("case", list(RAGGED))
+# (the twin walks the plan's visits as it did: ISSUE 65's plans go through the kernel alone)
+FORMS = [(case, kernel) for case in RAGGED for kernel in (False, True) if kernel or case not in GRID]
+
+
+@pytest.mark.parametrize("case,kernel", FORMS, ids=[f"{c}-{'interpreted_kernel' if k else 'twin'}" for c, k in FORMS])
 def test_the_blocked_scan_is_the_token_recurrence_for_ragged_rows(case, kernel):
     """Float32 products at the highest precision on both sides: they differ in
     the order of their sums (a block's products against 8 to 40 single steps);
     measured 4e-6 on outputs of size ~10."""
-    lens, starts, want_blocks = RAGGED[case]
-    blocks, worst = scan_case(lens, starts, kernel)
+    lens, starts, want_blocks, *T = RAGGED[case]
+    blocks, worst, grid, first_block = scan_case(lens, starts, kernel, *T)
     assert blocks == want_blocks and worst < 5e-5
+    assert grid == GRID.get(case, grid)
+    if not blocks:
+        assert not first_block.any()
 
 
 def test_the_plan_orders_rows_by_their_packed_start_and_bounds_its_visits():
@@ -466,15 +482,17 @@ def test_the_counters_by_hand():
     row_a = [5, 40, [], []]
     _, _, stats = admit(eng, 1, prompt(36, 5), beside={0: row_a})
     assert stats[0]["ssd_blocks"] == 5 * 4 and stats[0]["state_rows"] == 1.0    # packed 1..36 touches blocks 0-4
+    bounds = {s["chunk_visits_bound"] for s in stats}                           # the plan's length, whatever the rows
     assert stats[0]["state_bytes"] == 2 * CFG.state_bytes_per_slot == 2 * 4 * (4 * 32 * 16 + 3 * 160) * 4
     assert stats[0]["context_pages"] == stats[0]["window_pages_read"] == (41 + 7) // 8 + (36 + 7) // 8
     _, _, stats = decode(eng, {0: (5, 41), 1: (6, 36)}, 1)
     assert stats[0]["ssd_blocks"] == 0.0 and stats[0]["state_rows"] == 2.0 * 4 and 1 <= stats[0]["experts_read"] <= 8
+    assert stats[0]["chunk_visits_bound"] == 0.0 and bounds == {4.0 * ssd.max_visits(eng.mixed_budget, 4, 8)}   # x 4 layers
     status = eng.family_status()
     assert status["state_store"] == {"slots": 4, "layers": 4, "bytes": 4 * CFG.state_bytes_per_slot}
     assert status["cache_layers"] == {"full": 1, "mamba2": 4}
     assert eng.cache["k"].shape[0] == 1 and eng.cache["ssm"].shape == (4, 4, 4, 32, 16)
-    assert eng._mixed_stats[-2:] == eng._chunk_stats[-2:] == ("ssd_blocks", "state_rows")
+    assert eng._mixed_stats[-3:] == eng._chunk_stats[-3:] == ("ssd_blocks", "state_rows", "chunk_visits_bound")
 
 
 def test_what_the_state_rules_out_is_refused_or_declined_by_name():

@@ -117,12 +117,19 @@ def test_three_stacks_under_names_of_their_own():
 # ---- the chunk form against the recurrence --------------------------------------------------------
 
 # (lengths a slot, where each row's first token stands): rows that share packed blocks of 64, start at
-# position 0 and mid-row, one-token rows, an empty slot; packed in slot order.
+# position 0 and mid-row, one-token rows, an empty slot; packed in slot order. And (ISSUE 65: the chunk kernel's
+# visit axis ends at the plan's last live visit) plans whose live visits are far under the bound (one long row
+# beside 15 of one token), AT it (every row starts mid-block, in the block the row before ends in) and none at all
+# (every row one token: visit 0 alone runs). GRID: the visits the kernel's grid runs, and the plan's bound.
 RAGGED = {
     "one_long_row_beside_one_token_rows": ([1, 150, 1, 0, 1], [9, 0, 0, 0, 30]),
     "rows_that_share_a_block": ([20, 30, 2, 70, 3], [0, 64, 5, 100, 0]),
     "a_row_that_ends_on_a_block": ([64, 64, 1, 0, 0], [0, 7, 0, 0, 0]),
+    "live_visits_far_under_the_bound": ([1] * 7 + [120] + [1] * 8, [9, 0, 4, 0, 30, 2, 1, 50] + [3] * 8),
+    "live_visits_at_the_bound": ([40, 60, 92], [0, 64, 5]),
+    "no_live_visit": ([1, 1, 1, 1, 1], [9, 0, 4, 0, 30]),
 }
+GRID = {"live_visits_far_under_the_bound": (2, 3 + 15), "live_visits_at_the_bound": (5, 5), "no_live_visit": (1, 3 + 4)}
 
 
 @contextlib.contextmanager
@@ -163,8 +170,11 @@ def scan_case(lens, base, kernel, T=192, seed=0):
     return got_y, got_s, want_y, want_s, store, int(lens.sum()), counts
 
 
-@pytest.mark.parametrize("kernel", [False, True], ids=["twin", "interpreted_kernel"])
-@pytest.mark.parametrize("case", list(RAGGED))
+# (the twin walks the plan's visits as it did: ISSUE 65's plans go through the kernel alone)
+FORMS = [(case, kernel) for case in RAGGED for kernel in (False, True) if kernel or case not in GRID]
+
+
+@pytest.mark.parametrize("case,kernel", FORMS, ids=[f"{c}-{'interpreted_kernel' if k else 'twin'}" for c, k in FORMS])
 def test_the_chunk_form_is_the_token_recurrence_for_ragged_rows(case, kernel):
     lens, base = RAGGED[case]
     got_y, got_s, want_y, want_s, store, n, counts = scan_case(lens, base, kernel)
@@ -172,7 +182,12 @@ def test_the_chunk_form_is_the_token_recurrence_for_ragged_rows(case, kernel):
     np.testing.assert_allclose(np.asarray(got_s[1]), np.asarray(want_s), atol=2e-6, rtol=1e-5)
     np.testing.assert_array_equal(np.asarray(got_s[0]), np.asarray(store[0]))      # the other layer's entry as it was
     more = [l for l in lens if l > 1]
-    assert [float(v) for v in counts] == [sum(more), len(more), sum(l == 1 for l in lens)]
+    bound = ssd.max_visits(192, len(lens), ssm.CHUNK_TOKENS)
+    assert [float(v) for v in counts] == [sum(more), len(more), sum(l == 1 for l in lens), bound]
+    if case in GRID:
+        starts = np.concatenate([[0], np.cumsum(lens)[:-1]])
+        meta, _ = ssd.block_plan(*(jnp.asarray(a, jnp.int32) for a in (starts, lens, np.add(base, lens))), 192, ssm.CHUNK_TOKENS)
+        assert (int(ssd.live_visits(meta)), meta.shape[0]) == GRID[case] and meta.shape[0] == bound
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["twin", "interpreted_kernel"])
@@ -248,7 +263,7 @@ def test_the_plan_is_the_kernels_on_a_tpu_and_the_twins_elsewhere(monkeypatch):
     assert (ssm.chunk_plan(100, 16)["path"], ssm.chunk_plan(100, 16)["one_token_rows"]) == ("scan_chunk_twin", "scan_decode")
     plan = ssm.chunk_plan(5120, 16)
     assert (plan["path"], plan["one_token_rows"]) == ("ssm_scan_chunk_kernel", "ssm_scan_decode_kernel")
-    assert (plan["token_block"], plan["channel_block"]) == (64, 5120)
+    assert (plan["token_block"], plan["channel_block"], plan["grid"]) == (64, 5120, "live visits")
     assert ssd.max_visits(544, 32, plan["token_block"]) == 40
 
 
@@ -401,9 +416,10 @@ def test_the_counters_and_the_status_by_hand():
     assert [s["state_rows"] for s in stats] == [1.0, 0.0, 0.0]                    # the first chunk is ONE token
     _, _, stats = admit(eng, 1, prompt(36, 5), beside={0: [5, 40, [], []]})
     assert (stats[0]["ssm_chunk_tokens"], stats[0]["ssm_chunk_rows"], stats[0]["state_rows"]) == (36.0 * 5, 5.0, 1.0)
+    assert stats[0]["chunk_visits_bound"] == 5.0 * ssd.max_visits(eng.mixed_budget, 4, ssm.CHUNK_TOKENS)   # x 5 layers
     assert stats[0]["state_bytes"] == 2 * CFG.state_bytes_per_slot == 2 * 5 * (8 + 3) * 128 * 4
     _, _, stats = decode(eng, {0: (5, 41), 1: (6, 36)}, 1)
-    assert stats[0]["ssm_chunk_tokens"] == 0.0 and stats[0]["state_rows"] == 2.0 * 4
+    assert stats[0]["ssm_chunk_tokens"] == 0.0 and stats[0]["state_rows"] == 2.0 * 4 and stats[0]["chunk_visits_bound"] == 0.0
     status = eng.family_status()
     assert status["state_store"] == {"slots": 4, "layers": 5, "bytes_per_slot": CFG.state_bytes_per_slot,
                                      "bytes": 4 * CFG.state_bytes_per_slot}

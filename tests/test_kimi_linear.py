@@ -155,8 +155,10 @@ def chunk_case(lens, starts, kernel, strength=1.0, T=64, seed=0, strong_from=0, 
     """Rows of ``lens`` tokens packed back to back in slot order, row r from
     position ``starts[r]``, through ``kda_chunk`` on entry 1 of a store of random
     states. Returns (blocks walked, the largest difference from the token
-    recurrence over outputs and last states of the rows of more than one token);
-    asserts that every other slot and the other entry are untouched."""
+    recurrence over outputs and last states of the rows of more than one token,
+    the visits the kernel's grid runs and the plan's bound, the first output
+    block); asserts that every other slot and the other entry are untouched."""
+    SLOTS = len(lens)
     q_lens, position = np.asarray(lens, np.int32), np.asarray(starts, np.int32)
     q_starts = np.concatenate([[0], np.cumsum(q_lens)[:-1]]).astype(np.int32)
     ops = operands(T, seed, strength, strong_from)
@@ -174,12 +176,16 @@ def chunk_case(lens, starts, kernel, strength=1.0, T=64, seed=0, strong_from=0, 
         worst = max(worst, float(np.abs(np.asarray(want_o) - np.asarray(o[at])).max()),
                     float(np.abs(np.asarray(want_s) - np.asarray(new[1, r])).max()))
     assert np.array_equal(np.asarray(new[0]), store[0])
-    return float(blocks), worst
+    return float(blocks), worst, (int(ssd.live_visits(meta)), meta.shape[0]), np.asarray(o[:block])
 
 
-# (lengths a slot, positions a slot, the blocks of 16 the rows touch): lengths 1, Q - 1, Q, Q + 1 and beyond
-# with and without a carried state; decode rows in front of one long chunk; no row at all; rows of two; rows that
-# begin and end inside one half of a block (of 8, and of 4) beside foreign rows: identity steps at every level.
+# (lengths a slot, positions a slot, the blocks of 16 the rows touch[, the packed tokens]): lengths 1, Q - 1, Q,
+# Q + 1 and beyond with and without a carried state; decode rows in front of one long chunk; no row at all; rows
+# of two; rows that begin and end inside one half of a block (of 8, and of 4) beside foreign rows: identity steps
+# at every level. And (ISSUE 65: the kernel's visit axis ends at the plan's last live visit) plans whose live
+# visits are far under the bound (one long row beside 15 of one token), AT it (every row of two tokens starts
+# mid-block, the last across a block's edge) and none at all (every row one token: visit 0 alone runs, and
+# zeroes one block).
 RAGGED = {
     "one_to_two_blocks_and_three": ([1, 15, 16, 17, 14], [5, 0, 3, 0, 11], 5),
     "the_same_all_carried": ([1, 15, 16, 17, 14], [5, 2, 3, 9, 11], 5),
@@ -187,17 +193,27 @@ RAGGED = {
     "no_row": ([0, 0, 0, 0, 0], [5, 2, 3, 0, 11], 0),
     "rows_of_two": ([2, 2, 2, 2, 2], [0, 2, 3, 0, 11], 5),
     "rows_inside_one_half": ([1, 5, 3, 3, 9], [5, 2, 0, 3, 11], 5),
+    "live_visits_far_under_the_bound": ([1] * 5 + [40] + [1] * 10, [5, 2, 3, 0, 11, 9] + [4] * 10, 3),
+    "live_visits_at_the_bound": ([1] + [2] * 8, [5, 0, 3, 9, 11, 0, 7, 2, 4], 9, 17),
+    "no_live_visit": ([1, 1, 1, 1, 1], [5, 2, 3, 0, 11], 0),
 }
+GRID = {"live_visits_far_under_the_bound": (3, 4 + 15), "live_visits_at_the_bound": (9, 9), "no_live_visit": (1, 4 + 4)}
 
 
-@pytest.mark.parametrize("kernel", [False, True], ids=["twin", "interpreted_kernel"])
-@pytest.mark.parametrize("case", list(RAGGED))
+# (the twin walks the plan's visits as it did: ISSUE 65's plans go through the kernel alone)
+FORMS = [(case, kernel) for case in RAGGED for kernel in (False, True) if kernel or case not in GRID]
+
+
+@pytest.mark.parametrize("case,kernel", FORMS, ids=[f"{c}-{'interpreted_kernel' if k else 'twin'}" for c, k in FORMS])
 def test_the_chunk_form_is_the_token_recurrence_for_ragged_rows(case, kernel):
     """Float32 products at the highest precision on both sides: they differ in
     the order of their sums and in the solve; measured 5e-7 on outputs of ~0.4."""
-    lens, starts, want_blocks = RAGGED[case]
-    blocks, worst = chunk_case(lens, starts, kernel)
+    lens, starts, want_blocks, *T = RAGGED[case]
+    blocks, worst, grid, first_block = chunk_case(lens, starts, kernel, T=T[0] if T else 64)
     assert blocks == want_blocks and worst < 2e-5
+    assert grid == GRID.get(case, grid)
+    if not blocks:
+        assert not first_block.any()
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["twin", "interpreted_kernel"])
@@ -205,7 +221,7 @@ def test_blocks_of_64_tokens_as_the_cell_walks_them(kernel):
     """The published block: six levels, of which those of 8, 16 and 32 tokens
     feed only the first halves' columns to their product, and a solve whose
     powers of 8, 16 and 32 leave their zero rows out."""
-    blocks, worst = chunk_case([1, 150, 1, 40, 2], [5, 7, 3, 0, 11], kernel, T=200, block=64)
+    blocks, worst, *_ = chunk_case([1, 150, 1, 40, 2], [5, 7, 3, 0, 11], kernel, T=200, block=64)
     assert blocks == 3 + 1 + 1 and worst < 2e-5
 
 
@@ -219,7 +235,7 @@ def test_decays_whose_inverse_overflows_float32_stay_finite_and_right(kernel, st
     2, 4 or 7 of every run of 8, behind weak ones: at one level they fall all
     into the rows' exponent, at another all into the columns', at a third they
     straddle the boundary."""
-    _, worst = chunk_case([1, 15, 16, 17, 14], [5, 2, 3, 0, 11], kernel, strength=40.0, strong_from=strong_from)
+    _, worst, *_ = chunk_case([1, 15, 16, 17, 14], [5, 2, 3, 0, 11], kernel, strength=40.0, strong_from=strong_from)
     assert worst < 2e-5
     g = operands(64, 0, 40.0, strong_from)[3]
     with np.errstate(over="ignore"):
@@ -466,11 +482,13 @@ def test_the_counters_and_the_status_by_hand(eng32):
     row_a = [5, 40, [], []]
     _, _, stats = admit(eng, 1, prompt(36, 5), beside={0: row_a})
     assert stats[0]["kda_blocks"] == 5 * 6 and stats[0]["state_rows"] == 1.0    # packed 1..36 touches blocks 0-4
+    bounds = {s["chunk_visits_bound"] for s in stats}                           # the plan's length, whatever the rows
     assert stats[0]["state_bytes"] == 2 * CFG.state_bytes_per_slot == 2 * 6 * (4 * 32 * 32 + 3 * 384) * 4
     assert stats[0]["context_pages"] == 2 * ((41 + 7) // 8 + (36 + 7) // 8)
     assert all(0 <= s["experts_read"] <= 4 for s in stats)
     _, _, stats = decode(eng, {0: (5, 41), 1: (6, 36)}, 1)
     assert stats[0]["kda_blocks"] == 0.0 and stats[0]["state_rows"] == 2.0 * 4 and 0 <= stats[0]["experts_read"] <= 4
+    assert stats[0]["chunk_visits_bound"] == 0.0 and bounds == {6.0 * ssd.max_visits(eng.mixed_budget, 4, 8)}   # x 6 layers
     status = eng.family_status()
     assert status["state_store"] == {"slots": 4, "layers": 6, "bytes": 4 * CFG.state_bytes_per_slot}
     assert status["cache_layers"] == {"full": 2, "kda": 6}
@@ -482,7 +500,7 @@ def test_the_counters_and_the_status_by_hand(eng32):
     assert (pool["layers"], pool["row_values"], pool["row_lanes"]) == (2, 40, 128)
     assert pool["bytes_per_token"] == 2 * 128 * 4
     assert eng.cache["kv"].shape[0] == 2 and eng.cache["kda"].shape == (6, 4, 4, 32, 32)
-    assert eng._mixed_stats[-2:] == eng._chunk_stats[-2:] == ("kda_blocks", "state_rows")
+    assert eng._mixed_stats[-3:] == eng._chunk_stats[-3:] == ("kda_blocks", "state_rows", "chunk_visits_bound")
 
 
 def test_what_the_state_and_the_pool_rule_out_is_refused_or_declined_by_name():
